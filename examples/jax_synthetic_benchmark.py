@@ -56,7 +56,11 @@ def main():
     # VGG has no batch norm; ResNets carry BN statistics.
     batch_stats = variables.get("batch_stats", {})
     opt = hvd.DistributedOptimizer(optax.sgd(0.1, momentum=0.9))
-    opt_state = opt.init(params)
+    # Place the state on the mesh before the first step: fed un-placed
+    # (single-device) arrays, jit compiles the step once for those and a
+    # second time for the replicated arrays the step itself returns.
+    params, batch_stats, opt_state = hvd.replicate(
+        (params, batch_stats, opt.init(params)))
 
     def train_step(p, bstats, s, batch):
         imgs, lbls = batch

@@ -55,19 +55,18 @@ def _ensure_built() -> str:
     override = ev.get_str(ev.HVDTPU_NATIVE_LIB)
     if override:
         return override
-    if not os.path.exists(_LIB_PATH):
-        # Serialize across processes: a cold start under a multi-worker
-        # launcher has every worker discover the missing .so at once, and
-        # concurrent `make` runs corrupt each other's objects (observed as
-        # a worker dlopen-ing a half-linked library).
-        import fcntl
-        lock_path = os.path.join(_NATIVE_DIR, ".build.lock")
-        with open(lock_path, "w") as lock:
-            fcntl.flock(lock, fcntl.LOCK_EX)
-            if not os.path.exists(_LIB_PATH):
-                log.info("building native core in %s", _NATIVE_DIR)
-                subprocess.run(["make", "-C", _NATIVE_DIR], check=True,
-                               capture_output=True)
+    # Always defer to make (incremental: a no-op when the .so is current), so
+    # a stale ignored .so or .o that travelled with the tree is rebuilt
+    # instead of loaded as is. Serialize across processes: under a
+    # multi-worker launcher every worker arrives here at once, and
+    # concurrent `make` runs corrupt each other's objects (observed as a
+    # worker dlopen-ing a half-linked library).
+    import fcntl
+    lock_path = os.path.join(_NATIVE_DIR, ".build.lock")
+    with open(lock_path, "w") as lock:
+        fcntl.flock(lock, fcntl.LOCK_EX)
+        subprocess.run(["make", "-C", _NATIVE_DIR], check=True,
+                       capture_output=True)
     return _LIB_PATH
 
 

@@ -14,8 +14,7 @@ Two layers:
   compressed moves ``2 * comp_bytes`` plus the quantize/dequantize compute.
   Compression wins exactly below the crossover link speed — the fork's
   raison d'être (its published wins are on 25 Gb/s RoCE; ICI at ~100+ GB/s
-  correctly favors dense). ``bench.py``'s compression A/B phase reports this
-  same model fed with on-chip-measured compute times.
+  correctly favors dense).
 
 * A live **A/B calibration** (:func:`autotune_compressed`) that times the
   real dense-hierarchical vs compressed-hierarchical programs on the mesh,

@@ -52,19 +52,15 @@ def _dequant_sum_stacked(compressor, gathered, ctx, n: int):
     from .quantize import MaxMinQuantizer, unpack_bits
     if isinstance(compressor, MaxMinQuantizer) and \
             compressor._pallas_enabled():
-        try:
-            from . import pallas_kernels as pk
-            padded = -(-ctx.count // ctx.bucket_size) * ctx.bucket_size
-            q = jax.vmap(lambda p: unpack_bits(p, ctx.bits, padded))(
-                gathered["q"])
-            q = q.reshape(n, -1, ctx.bucket_size)
-            mn = gathered["min"].reshape(n, -1)
-            unit = gathered["unit"].reshape(n, -1)
-            out = pk.maxmin_dequantize_sum_pallas(q, mn, unit)
-            return out.reshape(-1)[:ctx.count].reshape(ctx.shape)
-        except Exception as exc:
-            from .quantize import _warn_pallas_fallback
-            _warn_pallas_fallback("maxmin_dequantize_sum", exc)
+        from . import pallas_kernels as pk
+        padded = -(-ctx.count // ctx.bucket_size) * ctx.bucket_size
+        q = jax.vmap(lambda p: unpack_bits(p, ctx.bits, padded))(
+            gathered["q"])
+        q = q.reshape(n, -1, ctx.bucket_size)
+        mn = gathered["min"].reshape(n, -1)
+        unit = gathered["unit"].reshape(n, -1)
+        out = pk.maxmin_dequantize_sum_pallas(q, mn, unit)
+        return out.reshape(-1)[:ctx.count].reshape(ctx.shape)
     total = jnp.zeros(ctx.shape, jnp.float32)
     for i in range(n):
         total = total + compressor.decompress(

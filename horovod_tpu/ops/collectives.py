@@ -41,6 +41,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax._src.lax.parallel import all_gather_invariant
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from .. import runtime
@@ -78,7 +79,7 @@ def in_named_trace(axis: Optional[str] = None) -> bool:
     try:
         lax.axis_size(_resolve_axis(axis))
         return True
-    except Exception:
+    except NameError:  # "unbound axis name": not under such a trace
         return False
 
 
@@ -131,10 +132,7 @@ def pvary(tree, axis: Optional[str] = None):
     def _cast(x):
         if not _dp_invariant(x, ax):
             return x  # already varying (idempotent)
-        try:
-            return lax.pcast(x, ax, to="varying")
-        except TypeError:  # older signature
-            return lax.pvary(x, (ax,))
+        return lax.pcast(x, ax, to="varying")
 
     return jax.tree.map(_cast, tree)
 
@@ -213,36 +211,16 @@ def allgather_p(x, axis: Optional[str] = None):
     ``collective_operations.h:138``).
 
     Lowers to a true **all-gather** with provably-replicated output via
-    ``all_gather_invariant`` (round-2 verdict weak #5: the previous
-    masked-psum form compiled to an all-reduce over the n-sized output —
-    ~2x the wire bytes — verified in compiled HLO; it remains only as the
-    fallback for JAX versions without the invariant primitive).
+    ``all_gather_invariant`` (a masked psum would compile to an all-reduce
+    over the n-sized output, ~2x the wire bytes).
     """
     ax = _resolve_axis(axis)
     n = lax.axis_size(ax)
+    xt = x[None] if x.ndim == 0 else x
     if _dp_invariant(x, ax):
         # Every rank holds the same tensor: gather == n stacked copies.
-        xt = x[None] if x.ndim == 0 else x
         return jnp.concatenate([xt] * n, axis=0)
-    xt = x[None] if x.ndim == 0 else x
-    try:
-        from jax._src.lax.parallel import all_gather_invariant
-    except ImportError:  # older JAX: masked-psum fallback below
-        all_gather_invariant = None
-    if all_gather_invariant is not None:
-        # Call OUTSIDE the try: a real tracing/shape error must propagate,
-        # not silently revert to the 2x-wire-cost all-reduce form.
-        return all_gather_invariant(xt, ax, axis=0, tiled=True)
-    idx = lax.axis_index(ax)
-    orig_dtype = xt.dtype
-    xf = xt.astype(jnp.int32) if orig_dtype == jnp.bool_ else xt
-    out_shape = (xf.shape[0] * n,) + xf.shape[1:]
-    big = jnp.zeros(out_shape, dtype=xf.dtype)
-    start = (idx * xf.shape[0],) + tuple(
-        jnp.zeros((), idx.dtype) for _ in range(xf.ndim - 1))
-    big = lax.dynamic_update_slice(big, xf, start)
-    out = lax.psum(big, ax)
-    return out.astype(orig_dtype) if orig_dtype == jnp.bool_ else out
+    return all_gather_invariant(xt, ax, axis=0, tiled=True)
 
 
 def allgather_varying_p(x, axis: Optional[str] = None):

@@ -28,11 +28,11 @@ Design notes (TPU):
   ``(bh, k_block, q_block)``, dQ walks ``(bh, q_block, k_block)``, each
   recomputing the probability tile from q, k and the saved row logsumexp —
   no S x S tensor is ever materialized in either direction.
-* Gate: compiled on TPU backends, ``interpret=True`` elsewhere — the same
-  policy as the quantize kernels (``compression/quantize.py``
-  ``_pallas_backend_enabled``). NOTE interpret mode does not validate
-  Mosaic lowering — keep ``attention="dense"`` in anything driver-critical
-  until the kernel has run on a real chip.
+* Gate: compiled through Mosaic on the TPU backend, ``interpret=True`` on
+  every other backend (the CPU-mesh tests) — the same gate as the quantize
+  kernels (``compression/quantize.py`` ``_pallas_backend_enabled``).
+  Interpret mode says nothing about Mosaic lowering; ``chip_smoke.py``
+  leg B runs all three kernels compiled, inside a training step.
 """
 
 from __future__ import annotations
@@ -52,8 +52,8 @@ _NEG_INF = -1e30  # large-negative instead of -inf: keeps exp/where NaN-free
 
 
 def _use_interpret() -> bool:
-    # Same gate as the quantize kernels: compiled only on TPU backends;
-    # everything else (cpu tests, gpu) runs the interpreter.
+    # Same gate as the quantize kernels: compiled on the TPU backend only;
+    # everything else (the CPU-mesh tests) runs the interpreter.
     from ..compression.quantize import _pallas_backend_enabled
     return not _pallas_backend_enabled(None)
 

@@ -27,6 +27,7 @@ modes, selected automatically:
 from __future__ import annotations
 
 import dataclasses
+import os
 import subprocess
 import threading
 import time
@@ -273,6 +274,26 @@ def _maybe_init_jax_distributed() -> None:
              jax.process_index(), jax.process_count(), len(jax.devices()))
 
 
+# Where the persistent XLA compilation cache lives when nobody places it from
+# outside: one fixed path inside the checkout. The path is part of the cache
+# key, so it must not move between runs — no temp dir, pid or timestamp.
+_DEFAULT_COMPILATION_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))), ".jax_cache")
+
+
+def _place_compilation_cache() -> None:
+    """Persistent XLA compilation cache: restarts — elastic resets, respawned
+    jobs, the next run of the same command — reuse prior compiles instead of
+    paying the first-compile again. ``JAX_COMPILATION_CACHE_DIR`` places it
+    from outside and JAX reads that variable itself, so then nothing is set
+    here; otherwise it sits at ``<checkout>/.jax_cache``."""
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        return
+    import jax
+    jax.config.update("jax_compilation_cache_dir",
+                      _DEFAULT_COMPILATION_CACHE_DIR)
+
+
 def _build_mesh(mesh_shape, axis_names, devices):
     import jax
     from jax.sharding import Mesh
@@ -336,20 +357,7 @@ def init(comm: Optional[Sequence[int]] = None,
         _init_kwargs = dict(comm=comm, mode=mode, mesh_shape=mesh_shape,
                             axis_names=axis_names, dp_axis=dp_axis,
                             devices=devices)
-        # Persistent XLA compilation cache (HVDTPU_COMPILATION_CACHE_DIR):
-        # restarts — elastic resets, respawned jobs — reuse prior compiles
-        # instead of paying the 20-40 s first-compile again. Mirrors the
-        # reference's persist-tuned-state ethos (HOROVOD_AUTOTUNE_LOG);
-        # here the expensive state is the compiled XLA program.
-        cache_dir = ev.get_str(ev.HVDTPU_COMPILATION_CACHE_DIR)
-        if cache_dir:
-            try:
-                import jax as _jax
-                _jax.config.update("jax_compilation_cache_dir", cache_dir)
-                _jax.config.update(
-                    "jax_persistent_cache_min_compile_time_secs", 1.0)
-            except Exception as exc:  # never fail init over a cache knob
-                log.warning("compilation cache unavailable: %s", exc)
+        _place_compilation_cache()
         mode = mode or _detect_mode()
         st = _RuntimeState(mode=mode, epoch=_state.epoch + 1)
         if mode == "process":
@@ -508,7 +516,10 @@ def init(comm: Optional[Sequence[int]] = None,
                 st.size + jax.process_index()
             st.cross_rank = jax.process_index()
             st.cross_size = jax.process_count()
-            log.debug("init: spmd mode mesh=%s size=%d", st.mesh.shape, st.size)
+            first = st.mesh.devices.flat[0]
+            log.info("init: spmd mode platform=%s device_kind=%s devices=%d "
+                     "mesh=%s", first.platform, first.device_kind, st.size,
+                     dict(st.mesh.shape))
         st.initialized = True
         _state = st
 

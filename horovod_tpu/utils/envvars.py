@@ -361,10 +361,6 @@ HVDTPU_NATIVE_LIB = "HVDTPU_NATIVE_LIB"
 HVDTPU_POWERSGD_RESIDUAL_CAP = "HVDTPU_POWERSGD_RESIDUAL_CAP"
 HVDTPU_POWERSGD_RESIDUAL_WARN = "HVDTPU_POWERSGD_RESIDUAL_WARN"
 
-# XLA compilation-cache directory exported to workers so elastic restarts /
-# onchip_watch attempts reuse warm compiles (scripts/onchip_watch.py STAGE_A).
-HVDTPU_COMPILATION_CACHE_DIR = "HVDTPU_COMPILATION_CACHE_DIR"
-
 # ---------------------------------------------------------------------------
 # Internal variables: set by the launcher / test harness for its own child
 # processes, never meant to be set by users (docs/envvars.md "Internal").

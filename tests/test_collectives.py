@@ -5,6 +5,8 @@ Test shapes mirror the reference's framework-op unit tests
 pre/postscale :327/:381, plus allgather/broadcast/alltoall menus).
 """
 
+import os
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -414,24 +416,43 @@ class TestTopology:
         with pytest.raises(hvd.NotInitializedError):
             hvd.rank()
 
-    def test_compilation_cache_env_knob(self, tmp_path, monkeypatch):
-        """HVDTPU_COMPILATION_CACHE_DIR points the persistent XLA compile
-        cache (restart-warm compiles; the supervisor bench shares one
-        through its state dir the same way)."""
+    def test_compilation_cache_placed_from_outside(self, tmp_path,
+                                                   monkeypatch):
+        """With JAX_COMPILATION_CACHE_DIR set, JAX reads the variable itself
+        and hvd.init() sets no cache directory in code."""
         import jax
 
-        monkeypatch.setenv("HVDTPU_COMPILATION_CACHE_DIR",
-                           str(tmp_path / "cc"))
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "cc"))
+        before = jax.config.jax_compilation_cache_dir
+        sentinel = str(tmp_path / "what-jax-had")
+        jax.config.update("jax_compilation_cache_dir", sentinel)
         hvd.shutdown()
         try:
             hvd.init()
-            assert jax.config.jax_compilation_cache_dir == \
-                str(tmp_path / "cc")
+            assert jax.config.jax_compilation_cache_dir == sentinel
         finally:
             hvd.shutdown()
-            # Unset for the rest of the process: later tests must not
-            # write cache entries into this test's deleted tmp dir.
-            jax.config.update("jax_compilation_cache_dir", None)
+            jax.config.update("jax_compilation_cache_dir", before)
+
+    def test_compilation_cache_fixed_path_in_checkout(self, monkeypatch):
+        """Unset, the cache sits at one fixed path inside the checkout —
+        the path is part of the cache key, so two inits must agree."""
+        import jax
+
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        before = jax.config.jax_compilation_cache_dir
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        seen = []
+        try:
+            for _ in range(2):
+                jax.config.update("jax_compilation_cache_dir", None)
+                hvd.shutdown()
+                hvd.init()
+                seen.append(jax.config.jax_compilation_cache_dir)
+        finally:
+            hvd.shutdown()
+            jax.config.update("jax_compilation_cache_dir", before)
+        assert seen == [os.path.join(repo, ".jax_cache")] * 2
 
     def test_custom_mesh(self, make_runtime):
         h = make_runtime(mesh_shape={"dp": 4, "tp": 2})

@@ -117,6 +117,47 @@ class TestPallasKernels:
                                    np.asarray(expect), atol=1e-5)
 
 
+class TestNoSilentFallback:
+    """The backend picks Pallas or XLA up front; a Pallas kernel that fails
+    where Pallas was picked raises — it is not caught and answered by the
+    XLA quantizer (which once hid a Mosaic lowering break on the chip)."""
+
+    @staticmethod
+    def _boom(*args, **kwargs):
+        raise RuntimeError("mosaic refused")
+
+    def test_compress_raises(self, monkeypatch):
+        from horovod_tpu.compression import pallas_kernels as pk
+        monkeypatch.setattr(pk, "maxmin_quantize_pallas", self._boom)
+        q = MaxMinQuantizer(bits=4, use_pallas=True)
+        with pytest.raises(RuntimeError, match="mosaic refused"):
+            q.compress(jnp.ones((1024,), jnp.float32))
+
+    def test_dequant_sum_raises(self, monkeypatch):
+        from horovod_tpu.compression import pallas_kernels as pk
+        from horovod_tpu.compression.reducers import _dequant_sum_stacked
+        xla = MaxMinQuantizer(bits=4, use_pallas=False)
+        payload, ctx = xla.compress(jnp.ones((1024,), jnp.float32))
+        gathered = jax.tree.map(lambda leaf: jnp.stack([leaf, leaf]), payload)
+        monkeypatch.setattr(pk, "maxmin_dequantize_sum_pallas", self._boom)
+        with pytest.raises(RuntimeError, match="mosaic refused"):
+            _dequant_sum_stacked(MaxMinQuantizer(bits=4, use_pallas=True),
+                                 gathered, ctx, 2)
+        # use_pallas=False never reaches the kernel.
+        out = _dequant_sum_stacked(xla, gathered, ctx, 2)
+        np.testing.assert_allclose(np.asarray(out), 2.0, atol=1e-6)
+
+    @pytest.mark.parametrize("backend,expect", [("tpu", True), ("cpu", False),
+                                                ("gpu", False),
+                                                ("tpu_plugin", False)])
+    def test_gate_is_tpu_only(self, monkeypatch, backend, expect):
+        from horovod_tpu.compression.quantize import _pallas_backend_enabled
+        monkeypatch.setattr(jax, "default_backend", lambda: backend)
+        assert _pallas_backend_enabled(None) is expect
+        assert _pallas_backend_enabled(True) is True
+        assert _pallas_backend_enabled(False) is False
+
+
 class TestNormQuantizeKernel:
     @pytest.mark.parametrize("norm,bits", [("linf", 4), ("l2", 4),
                                            ("linf", 8)])
@@ -478,15 +519,16 @@ class TestEagerProgramCache:
         """Round-3 verdict #3: the warm eager compressed_allreduce must be
         pure execution — zero XLA compilations — so its dispatch cost stays
         within a small constant of the dense path's (r02 measured ~4,000x
-        before the cached-program rewrite). Verified with jax's compile-event
-        monitoring: cold call emits compile events, warm calls emit none."""
+        before the cached-program rewrite). Verified with jax's compile
+        duration events (emitted with or without a persistent cache): the
+        cold call emits them, warm calls emit none."""
         from jax._src import monitoring
 
         q = MaxMinQuantizer(bits=4, use_pallas=False)
         x = jnp.ones((65536,), jnp.float32)
         events = []
-        listener = lambda name, **kw: events.append(name)  # noqa: E731
-        monitoring.register_event_listener(listener)
+        listener = lambda name, secs, **kw: events.append(name)  # noqa: E731
+        monitoring.register_event_duration_secs_listener(listener)
         try:
             compressed_allreduce(x, q)  # cold: compiles the group program
             cold = [e for e in events if "compile" in e.lower()]
@@ -498,7 +540,7 @@ class TestEagerProgramCache:
             warm = [e for e in events if "compile" in e.lower()]
             assert warm == [], f"warm calls recompiled: {warm}"
         finally:
-            monitoring.unregister_event_listener(listener)
+            monitoring.unregister_event_duration_listener(listener)
 
     def test_warm_dispatch_time_bounded(self, spmd8):
         """Wall-time canary for the same regression: the warm call at 64 KiB
