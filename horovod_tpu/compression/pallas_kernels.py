@@ -18,6 +18,15 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 BUCKET_BLOCK = 256  # buckets per grid step (BUCKET_BLOCK x bucket_size fp32)
+# The kernels' names in the compiled program: each becomes the name of its
+# HLO instruction, which is the name of its event in a device trace
+# (tests/test_program_names.py pins the strings).
+KERNEL_MAXMIN_QUANTIZE = "hvd_maxmin_quantize"
+KERNEL_MAXMIN_QUANTIZE_STOCHASTIC = "hvd_maxmin_quantize_stochastic"
+KERNEL_MAXMIN_DEQUANTIZE = "hvd_maxmin_dequantize"
+KERNEL_MAXMIN_DEQUANTIZE_SUM = "hvd_maxmin_dequantize_sum"
+KERNEL_NORM_QUANTIZE = "hvd_norm_quantize"
+KERNEL_NORM_DEQUANTIZE = "hvd_norm_dequantize"
 
 
 from ..ops.pallas_util import out_vma as _out_vma  # noqa: E402
@@ -108,6 +117,7 @@ def norm_quantize_pallas(flat: jnp.ndarray, levels: jnp.ndarray,
                                  vma=_out_vma(x)),
         ],
         interpret=interpret,
+        name=KERNEL_NORM_QUANTIZE,
     )(x, levels.astype(jnp.float32))
     return q[:n_buckets], norm[:n_buckets, 0]
 
@@ -156,6 +166,7 @@ def norm_dequantize_pallas(q: jnp.ndarray, levels: jnp.ndarray,
                                        jnp.float32,
                                        vma=_out_vma(qp, np_)),
         interpret=interpret,
+        name=KERNEL_NORM_DEQUANTIZE,
     )(qp, levels.astype(jnp.float32), np_)
     return out[:n_buckets]
 
@@ -196,6 +207,7 @@ def maxmin_quantize_pallas(flat: jnp.ndarray, bits: int, bucket_size: int,
                                  vma=_out_vma(x)),
         ],
         interpret=interpret,
+        name=KERNEL_MAXMIN_QUANTIZE,
     )(x)
     return (q[:n_buckets], mn[:n_buckets, 0], unit[:n_buckets, 0])
 
@@ -267,6 +279,7 @@ def maxmin_quantize_stochastic_pallas(flat: jnp.ndarray, bits: int,
             jax.ShapeDtypeStruct((padded_buckets, 1), jnp.float32,
                                  vma=_out_vma(x, seed)),
         ],
+        name=KERNEL_MAXMIN_QUANTIZE_STOCHASTIC,
     )(x, seed.reshape(1).astype(jnp.int32))
     return (q[:n_buckets], mn[:n_buckets, 0], unit[:n_buckets, 0])
 
@@ -310,6 +323,7 @@ def maxmin_dequantize_sum_pallas(q: jnp.ndarray, mn: jnp.ndarray,
         out_shape=jax.ShapeDtypeStruct((padded_buckets, bucket), jnp.float32,
                                        vma=_out_vma(qp, mnp, up)),
         interpret=interpret,
+        name=KERNEL_MAXMIN_DEQUANTIZE_SUM,
     )(qp, mnp, up)
     return out[:n_buckets]
 
@@ -339,5 +353,6 @@ def maxmin_dequantize_pallas(q: jnp.ndarray, mn: jnp.ndarray,
                                        jnp.float32,
                                        vma=_out_vma(qp, mnp, up)),
         interpret=interpret,
+        name=KERNEL_MAXMIN_DEQUANTIZE,
     )(qp, mnp, up)
     return out[:n_buckets]

@@ -193,7 +193,11 @@ def _attention(cfg: GPTConfig, q, k, v):
         return ulysses_attention_p(q, k, v, causal=True, axis=sp,
                                    attn_fn=flash_attention)
     if not _axis_bound(sp) or cfg.attention == "dense":
-        return default_attention(q, k, v, causal=True)
+        # GQA: the plain path takes equal head counts, so the key and value
+        # heads tile up here as flash, ring and Ulysses do themselves.
+        from ..ops.flash_attention import repeat_kv_heads
+        return default_attention(q, repeat_kv_heads(k, q.shape[2]),
+                                 repeat_kv_heads(v, q.shape[2]), causal=True)
     if cfg.attention == "ring":
         from ..parallel.ring_attention import ring_attention_p
         return ring_attention_p(q, k, v, causal=True, axis=sp)
@@ -204,29 +208,35 @@ def _attention(cfg: GPTConfig, q, k, v):
 
 
 def _block(cfg: GPTConfig, layer_params, x, positions):
+    # The scopes sit inside the function ``jax.checkpoint`` wraps, so the
+    # recomputed copy of a block carries them too (``forward`` has the rest).
     lp = layer_params
-    h = _rmsnorm(x, lp["attn_norm"], cfg.dtype)
-    q = jnp.einsum("bse,ehd->bshd", h, lp["wq"].astype(cfg.dtype))
-    k = jnp.einsum("bse,ehd->bshd", h, lp["wk"].astype(cfg.dtype))
-    v = jnp.einsum("bse,ehd->bshd", h, lp["wv"].astype(cfg.dtype))
-    q = rope(q, positions)
-    k = rope(k, positions)
-    attn = _attention(cfg, q, k, v)
-    o = jnp.einsum("bshd,hde->bse", attn, lp["wo"].astype(cfg.dtype))
-    x = x + _tp_psum(o, cfg)
+    with jax.named_scope("attn"):
+        h = _rmsnorm(x, lp["attn_norm"], cfg.dtype)
+        q = jnp.einsum("bse,ehd->bshd", h, lp["wq"].astype(cfg.dtype))
+        k = jnp.einsum("bse,ehd->bshd", h, lp["wk"].astype(cfg.dtype))
+        v = jnp.einsum("bse,ehd->bshd", h, lp["wv"].astype(cfg.dtype))
+        q = rope(q, positions)
+        k = rope(k, positions)
+        attn = _attention(cfg, q, k, v)
+        o = jnp.einsum("bshd,hde->bse", attn, lp["wo"].astype(cfg.dtype))
+        x = x + _tp_psum(o, cfg)
 
-    h = _rmsnorm(x, lp["mlp_norm"], cfg.dtype)
     if "moe" in lp:
-        from ..parallel.moe import switch_moe
-        out, _aux = switch_moe(
-            h, lp["moe"]["gate"], lp["moe"]["w_up"], lp["moe"]["w_down"],
-            axis=cfg.ep_axis, tp_axis=cfg.tp_axis,
-            capacity_factor=cfg.capacity_factor, dtype=cfg.dtype)
-        return x + out
-    up = jnp.einsum("bse,em->bsm", h, lp["w_up"].astype(cfg.dtype))
-    up = jax.nn.gelu(up)
-    down = jnp.einsum("bsm,me->bse", up, lp["w_down"].astype(cfg.dtype))
-    return x + _tp_psum(down, cfg)
+        with jax.named_scope("moe"):
+            h = _rmsnorm(x, lp["mlp_norm"], cfg.dtype)
+            from ..parallel.moe import switch_moe
+            out, _aux = switch_moe(
+                h, lp["moe"]["gate"], lp["moe"]["w_up"], lp["moe"]["w_down"],
+                axis=cfg.ep_axis, tp_axis=cfg.tp_axis,
+                capacity_factor=cfg.capacity_factor, dtype=cfg.dtype)
+            return x + out
+    with jax.named_scope("mlp"):
+        h = _rmsnorm(x, lp["mlp_norm"], cfg.dtype)
+        up = jnp.einsum("bse,em->bsm", h, lp["w_up"].astype(cfg.dtype))
+        up = jax.nn.gelu(up)
+        down = jnp.einsum("bsm,me->bse", up, lp["w_down"].astype(cfg.dtype))
+        return x + _tp_psum(down, cfg)
 
 
 def _block_fn(cfg: GPTConfig):
@@ -247,13 +257,21 @@ def _block_fn(cfg: GPTConfig):
 def forward(params, tokens, positions, cfg: GPTConfig):
     """Logits ``[B, S_local, vocab]`` (fp32). ``tokens``/``positions`` are this
     rank's sequence shard (global positions) when sp is active."""
-    x = params["embed"].astype(cfg.dtype)[tokens]
+    # Scopes name the program's parts in every instruction's ``op_name``:
+    # ``embed``, ``layer<i>`` (with ``attn`` and ``mlp`` or ``moe`` inside,
+    # from ``_block``), ``head``; ``loss_fn`` adds ``loss``. A device trace
+    # is read by them (PERF.md section 3).
+    with jax.named_scope("embed"):
+        x = params["embed"].astype(cfg.dtype)[tokens]
     block = _block_fn(cfg)
-    for lp in params["layers"]:
-        x = block(cfg, lp, x, positions)
-    x = _rmsnorm(x, params["out_norm"], cfg.dtype)
-    return jnp.einsum("bse,ev->bsv", x,
-                      params["lm_head"].astype(cfg.dtype)).astype(jnp.float32)
+    for i, lp in enumerate(params["layers"]):
+        with jax.named_scope(f"layer{i}"):
+            x = block(cfg, lp, x, positions)
+    with jax.named_scope("head"):
+        x = _rmsnorm(x, params["out_norm"], cfg.dtype)
+        return jnp.einsum(
+            "bse,ev->bsv", x,
+            params["lm_head"].astype(cfg.dtype)).astype(jnp.float32)
 
 
 def loss_fn(params, tokens, targets, positions, cfg: GPTConfig,
@@ -266,22 +284,24 @@ def loss_fn(params, tokens, targets, positions, cfg: GPTConfig,
     identical global-mean loss.
     """
     logits = forward(params, tokens, positions, cfg)
-    mask = (targets != ignore_index)
-    safe_targets = jnp.where(mask, targets, 0)
-    logp = jax.nn.log_softmax(logits, axis=-1)
-    tok_loss = -jnp.take_along_axis(logp, safe_targets[..., None],
-                                    axis=-1)[..., 0]
-    tok_loss = jnp.where(mask, tok_loss, 0.0)
-    num = jnp.sum(tok_loss)
-    den = jnp.sum(mask.astype(jnp.float32))
-    # The token population is sharded over sp (sequence) and, when experts are
-    # parallel, over ep (batch rides (dp, ep)); reduce over both so every rank
-    # returns the same global-mean — dp averaging is the caller's (optimizer's).
-    for ax in (cfg.sp_axis, cfg.ep_axis):
-        if _axis_bound(ax):
-            num = lax.psum(num, ax)
-            den = lax.psum(den, ax)
-    return num / jnp.maximum(den, 1.0)
+    with jax.named_scope("loss"):
+        mask = (targets != ignore_index)
+        safe_targets = jnp.where(mask, targets, 0)
+        logp = jax.nn.log_softmax(logits, axis=-1)
+        tok_loss = -jnp.take_along_axis(logp, safe_targets[..., None],
+                                        axis=-1)[..., 0]
+        tok_loss = jnp.where(mask, tok_loss, 0.0)
+        num = jnp.sum(tok_loss)
+        den = jnp.sum(mask.astype(jnp.float32))
+        # The token population is sharded over sp (sequence) and, when experts
+        # are parallel, over ep (batch rides (dp, ep)); reduce over both so
+        # every rank returns the same global-mean — dp averaging is the
+        # caller's (optimizer's).
+        for ax in (cfg.sp_axis, cfg.ep_axis):
+            if _axis_bound(ax):
+                num = lax.psum(num, ax)
+                den = lax.psum(den, ax)
+        return num / jnp.maximum(den, 1.0)
 
 
 def data_specs(cfg: GPTConfig) -> Tuple[P, P]:

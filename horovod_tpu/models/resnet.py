@@ -13,6 +13,7 @@ import functools
 from typing import Any, Callable, Sequence, Tuple
 
 import flax.linen as nn
+import jax
 import jax.numpy as jnp
 
 ModuleDef = Any
@@ -89,10 +90,15 @@ class ResNet(nn.Module):
         x = self.act(x)
         x = nn.max_pool(x, (3, 3), strides=(2, 2), padding="SAME")
         for i, block_size in enumerate(self.stage_sizes):
-            for j in range(block_size):
-                strides = (2, 2) if i > 0 and j == 0 else (1, 1)
-                x = self.block_cls(self.num_filters * 2 ** i, conv=conv,
-                                   norm=norm, act=self.act, strides=strides)(x)
+            # flax runs each module under a scope of its name, so every
+            # instruction's ``op_name`` already says ``<Block>_<n>/Conv_0``;
+            # the stage groups the blocks of one width and resolution.
+            with jax.named_scope(f"stage{i}"):
+                for j in range(block_size):
+                    strides = (2, 2) if i > 0 and j == 0 else (1, 1)
+                    x = self.block_cls(
+                        self.num_filters * 2 ** i, conv=conv, norm=norm,
+                        act=self.act, strides=strides)(x)
         x = jnp.mean(x, axis=(1, 2))
         x = nn.Dense(self.num_classes, dtype=self.dtype,
                      param_dtype=jnp.float32)(x)

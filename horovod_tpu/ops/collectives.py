@@ -638,6 +638,15 @@ def _ctx(axis: Optional[str]) -> DispatchContext:
     return DispatchContext(in_step=False, mode=runtime.mode(), axis=axis)
 
 
+def _step_scope(kind: str, name: Optional[str]):
+    """The scope an in-step collective compiles under: Horovod's ``name=`` is
+    its handle for a tensor in the timeline, and here it reaches the device
+    trace through every instruction's ``op_name``. Without a name the scope
+    is the same in every trace (never the process-mode ``_auto_name``
+    counter, which would differ between two traces of one step)."""
+    return jax.named_scope(f"hvd_{kind}/{name or 'unnamed'}")
+
+
 class _InStepBackend(CollectiveBackend):
     """XLA collectives inside a shard_map/pmap trace — the ICI data plane
     (the NCCL analog; SURVEY §2.7)."""
@@ -649,22 +658,26 @@ class _InStepBackend(CollectiveBackend):
         return ctx.in_step
 
     def allreduce(self, x, name, op, prescale_factor, postscale_factor, axis):
-        return allreduce_p(x, op=op, axis=axis,
-                           prescale_factor=prescale_factor,
-                           postscale_factor=postscale_factor)
+        with _step_scope("allreduce", name):
+            return allreduce_p(x, op=op, axis=axis,
+                               prescale_factor=prescale_factor,
+                               postscale_factor=postscale_factor)
 
     def grouped_allreduce(self, leaves, name, op, prescale_factor,
                           postscale_factor, axis):
-        return [allreduce_p(t, op=op, axis=axis,
-                            prescale_factor=prescale_factor,
-                            postscale_factor=postscale_factor)
-                for t in leaves]
+        with _step_scope("allreduce", name):
+            return [allreduce_p(t, op=op, axis=axis,
+                                prescale_factor=prescale_factor,
+                                postscale_factor=postscale_factor)
+                    for t in leaves]
 
     def allgather(self, x, name, axis):
-        return allgather_p(x, axis=axis)
+        with _step_scope("allgather", name):
+            return allgather_p(x, axis=axis)
 
     def broadcast(self, x, root_rank, name, axis):
-        return broadcast_p(x, root_rank=root_rank, axis=axis)
+        with _step_scope("broadcast", name):
+            return broadcast_p(x, root_rank=root_rank, axis=axis)
 
     def alltoall(self, x, splits, name, axis):
         if splits is not None:
@@ -676,10 +689,12 @@ class _InStepBackend(CollectiveBackend):
                 "uneven splits cannot compile in-step (per-rank output "
                 "shapes differ; XLA requires static shapes) — use the eager "
                 "path, or pad to equal splits inside the step")
-        return alltoall_p(x, axis=axis)
+        with _step_scope("alltoall", name):
+            return alltoall_p(x, axis=axis)
 
     def reducescatter(self, x, op, name, axis):
-        return reducescatter_p(x, op=op, axis=axis)
+        with _step_scope("reducescatter", name):
+            return reducescatter_p(x, op=op, axis=axis)
 
 
 class _NativeProcessBackend(CollectiveBackend):

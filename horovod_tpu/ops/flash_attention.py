@@ -49,6 +49,12 @@ BLOCK_Q = 128
 BLOCK_K = 128
 _LANES = 128  # TPU lane width: softmax stats ride lane-replicated [*, 128]
 _NEG_INF = -1e30  # large-negative instead of -inf: keeps exp/where NaN-free
+# The kernels' names in the compiled program: each becomes the name of its
+# HLO instruction, which is the name of its event in a device trace. The
+# benchmark's readers match these strings (tests/test_program_names.py).
+KERNEL_FWD = "hvd_flash_fwd"
+KERNEL_DKDV = "hvd_flash_dkdv"
+KERNEL_DQ = "hvd_flash_dq"
 
 
 def _use_interpret() -> bool:
@@ -268,6 +274,7 @@ def _fwd_call(q, k, v, sm_scale, causal, kv_len, interpret):
             pltpu.VMEM((BLOCK_Q, d), jnp.float32),   # output accumulator
         ],
         interpret=interpret,
+        name=KERNEL_FWD,
     )(q, k, v)
 
 
@@ -330,6 +337,7 @@ def _flash_bhsd_bwd(sm_scale, causal, kv_len, res, do):
             pltpu.VMEM((BLOCK_K, d), jnp.float32),
         ],
         interpret=interpret,
+        name=KERNEL_DKDV,
     )(q, k, v, do, lse, delta)
 
     dqk = functools.partial(_dq_kernel, sm_scale=sm_scale, n_k_blocks=n_k,
@@ -352,6 +360,7 @@ def _flash_bhsd_bwd(sm_scale, causal, kv_len, res, do):
                                        vma=_out_vma(q, k, v, do)),
         scratch_shapes=[pltpu.VMEM((BLOCK_Q, d), jnp.float32)],
         interpret=interpret,
+        name=KERNEL_DQ,
     )(q, k, v, do, lse, delta)
     return dq, dk, dv
 

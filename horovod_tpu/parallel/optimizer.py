@@ -26,6 +26,13 @@ import optax
 from .. import runtime
 from ..ops import collectives as C
 
+# The scopes the two halves of an update compile under, in every
+# instruction's ``op_name``: the gradient reduction (dense or compressed,
+# quantize and dequantize kernels included) and the inner optimizer's update.
+# A device trace is split by them (benchmarks/scope_reduce.py).
+SCOPE_EXCHANGE = "hvd_exchange"
+SCOPE_OPTIMIZER = "hvd_optimizer"
+
 
 def allreduce_gradients(grads, op: C.ReduceOp = C.ReduceOp.AVERAGE,
                         compression=None, prescale_factor: float = 1.0,
@@ -374,9 +381,11 @@ def DistributedOptimizer(optimizer: optax.GradientTransformation,
 
         def update_fn(grads, state, params=None, **extra):
             inner_state, residuals = state
-            reduced, new_residuals = _compressed_reduce(grads, residuals)
-            updates, inner_state = optimizer.update(reduced, inner_state,
-                                                    params, **extra)
+            with jax.named_scope(SCOPE_EXCHANGE):
+                reduced, new_residuals = _compressed_reduce(grads, residuals)
+            with jax.named_scope(SCOPE_OPTIMIZER):
+                updates, inner_state = optimizer.update(
+                    reduced, inner_state, params, **extra)
             return updates, (inner_state, new_residuals)
     else:
         def init_fn(params):
@@ -385,11 +394,13 @@ def DistributedOptimizer(optimizer: optax.GradientTransformation,
             return state
 
         def update_fn(grads, state, params=None, **extra):
-            if quantized:
-                reduced, _ = _compressed_reduce(grads, None)
-            else:
-                reduced = _reduce(grads)
-            return optimizer.update(reduced, state, params, **extra)
+            with jax.named_scope(SCOPE_EXCHANGE):
+                if quantized:
+                    reduced, _ = _compressed_reduce(grads, None)
+                else:
+                    reduced = _reduce(grads)
+            with jax.named_scope(SCOPE_OPTIMIZER):
+                return optimizer.update(reduced, state, params, **extra)
 
     wrapped = optax.GradientTransformation(init_fn, update_fn)
     if backward_passes_per_step > 1:

@@ -42,6 +42,7 @@ from jax.sharding import PartitionSpec as P
 
 from .. import runtime
 from ..ops import collectives as C
+from .optimizer import SCOPE_EXCHANGE, SCOPE_OPTIMIZER
 
 
 def _flat_sizes(leaves):
@@ -200,30 +201,35 @@ class ShardedDistributedOptimizer:
         # only the fused buffer would double-reduce the invariant leaves of
         # a mixed tree — same contract as allreduce_p's per-tensor branch.
         inv = [C._dp_invariant(g, ax) for g in leaves]
-        if all(inv):
-            # Everything already reduced: the "reduce-scatter" is a slice.
-            flat_g = _flatten_pad(leaves, padded)
-            g_shard = lax.dynamic_slice(flat_g, (idx * shard_len,),
-                                        (shard_len,))
-        else:
-            # Pre-divide invariant leaves by n and mark them varying, so one
-            # reduce-scatter (the all-reduce's bandwidth-optimal first half)
-            # gives SUM semantics uniformly across the mixed tree.
-            norm = [C.pvary(g.astype(jnp.float32) / n, ax) if f else g
-                    for g, f in zip(leaves, inv)]
-            flat_g = _flatten_pad(norm, padded)
-            g_shard = lax.psum_scatter(flat_g, ax, scatter_dimension=0,
-                                       tiled=True)
-        if self._op == C.ReduceOp.AVERAGE:
-            g_shard = g_shard / n
+        with jax.named_scope(SCOPE_EXCHANGE):
+            if all(inv):
+                # Everything already reduced: the "reduce-scatter" is a
+                # slice.
+                flat_g = _flatten_pad(leaves, padded)
+                g_shard = lax.dynamic_slice(flat_g, (idx * shard_len,),
+                                            (shard_len,))
+            else:
+                # Pre-divide invariant leaves by n and mark them varying, so
+                # one reduce-scatter (the all-reduce's bandwidth-optimal
+                # first half) gives SUM semantics uniformly across the mixed
+                # tree.
+                norm = [C.pvary(g.astype(jnp.float32) / n, ax) if f else g
+                        for g, f in zip(leaves, inv)]
+                flat_g = _flatten_pad(norm, padded)
+                g_shard = lax.psum_scatter(flat_g, ax, scatter_dimension=0,
+                                           tiled=True)
+            if self._op == C.ReduceOp.AVERAGE:
+                g_shard = g_shard / n
 
         flat_p = _flatten_pad(jax.tree.leaves(params), padded)
         p_shard = lax.dynamic_slice(flat_p, (idx * shard_len,), (shard_len,))
 
-        upd_shard, new_state = self._inner.update(g_shard, state, p_shard)
+        with jax.named_scope(SCOPE_OPTIMIZER):
+            upd_shard, new_state = self._inner.update(g_shard, state, p_shard)
         # All-gather the updated shards back to a replicated full vector
         # (true all-gather; the all-reduce's second half).
-        full = C.allgather_p(upd_shard, axis=ax)[:total]
+        with jax.named_scope(SCOPE_EXCHANGE):
+            full = C.allgather_p(upd_shard, axis=ax)[:total]
 
         outs, off = [], 0
         for g, size in zip(leaves, sizes):

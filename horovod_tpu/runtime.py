@@ -67,8 +67,10 @@ class _RuntimeState:
     metrics_server: Optional[object] = None
     # Monotonic epoch, bumped on shutdown/re-init (elastic resets).
     epoch: int = 0
-    # SPMD-mode timeline: an XLA profiler trace is active.
-    xla_trace_active: bool = False
+    # SPMD mode: compile, start-up and placement counters behind
+    # hvd.metrics(), host spans for hvd.start_timeline
+    # (horovod_tpu.spmd_recorder.SpmdRecorder).
+    recorder: Optional[object] = None
 
 
 _state = _RuntimeState()
@@ -357,7 +359,9 @@ def init(comm: Optional[Sequence[int]] = None,
         _init_kwargs = dict(comm=comm, mode=mode, mesh_shape=mesh_shape,
                             axis_names=axis_names, dp_axis=dp_axis,
                             devices=devices)
+        cache_t0 = time.time_ns()
         _place_compilation_cache()
+        cache_t1 = time.time_ns()
         mode = mode or _detect_mode()
         st = _RuntimeState(mode=mode, epoch=_state.epoch + 1)
         if mode == "process":
@@ -499,7 +503,17 @@ def init(comm: Optional[Sequence[int]] = None,
                       st.rank, st.size, st.local_rank, st.local_size)
         else:
             import jax
+            from .spmd_recorder import SpmdRecorder
+            st.recorder = SpmdRecorder()
+            st.recorder.phase("compile_cache", cache_t0, cache_t1)
+            # The first jax.devices() of a process starts the backend: on a
+            # TPU host that is the chips' start-up, seconds that vary from
+            # run to run and are not the program's.
+            t0 = time.time_ns()
             _maybe_init_jax_distributed()
+            jax.devices()
+            t1 = time.time_ns()
+            st.recorder.phase("backend", t0, t1)
             st.mesh, st.axis_names = _build_mesh(mesh_shape, axis_names, devices)
             st.dp_axis = dp_axis if dp_axis in st.axis_names else st.axis_names[0]
             st.size = int(np.prod(list(st.mesh.shape.values())))
@@ -517,6 +531,8 @@ def init(comm: Optional[Sequence[int]] = None,
             st.cross_rank = jax.process_index()
             st.cross_size = jax.process_count()
             first = st.mesh.devices.flat[0]
+            st.recorder.phase("mesh", t1, time.time_ns())
+            st.recorder.start()
             log.info("init: spmd mode platform=%s device_kind=%s devices=%d "
                      "mesh=%s", first.platform, first.device_kind, st.size,
                      dict(st.mesh.shape))
@@ -534,6 +550,8 @@ def shutdown() -> None:
             _state.metrics_server.stop()
         if _state.core is not None:
             _state.core.shutdown()
+        if _state.recorder is not None:
+            _state.recorder.stop()
         _state = _RuntimeState(epoch=_state.epoch)
         # Compiled eager-collective programs close over the old Mesh; drop them
         # so elastic re-inits don't accumulate stale executables.
@@ -628,26 +646,38 @@ def epoch() -> int:
     return _state.epoch
 
 
-def metrics_dump() -> str:
-    """Prometheus text exposition of this worker's live metrics (process
-    mode; see ``docs/metrics.md`` for the catalog). The same text the
-    per-worker ``/metrics`` endpoint serves. SPMD mode has no native
-    background loop to instrument and returns an empty string — use the
-    XLA profiler there."""
-    st = _require_init()
-    if st.core is not None and hasattr(st.core, "metrics_dump"):
-        return st.core.metrics_dump()
-    return ""
+def recorder():
+    """The SPMD recorder (:mod:`horovod_tpu.spmd_recorder`), or None before
+    ``init`` and in process mode."""
+    return _state.recorder
 
 
 def metrics() -> dict:
-    """Parsed live-metrics snapshot:
+    """Live-metrics snapshot:
     ``{family: {"type", "help", "samples": [(suffix, labels, value)]}}``
-    (see :func:`horovod_tpu.observability.parse_prometheus_text`). Empty outside
-    process mode."""
+    (the shape :func:`horovod_tpu.observability.parse_prometheus_text`
+    gives; ``docs/metrics.md`` has the catalog). Process mode: the native
+    core's registry. SPMD mode: the ``hvdtpu_spmd_*`` families, what JAX
+    compiled for how long, the persistent cache's hits and misses, the
+    phases of ``hvd.init()`` and what ``hvd.shard_batch`` placed."""
+    st = _require_init()
+    if st.recorder is not None:
+        return st.recorder.families()
     from .observability import parse_prometheus_text
     text = metrics_dump()
     return parse_prometheus_text(text) if text else {}
+
+
+def metrics_dump() -> str:
+    """Prometheus text exposition of :func:`metrics`: in process mode the
+    text the per-worker ``/metrics`` endpoint serves."""
+    st = _require_init()
+    if st.recorder is not None:
+        from .observability import render_exposition
+        return render_exposition(st.recorder.families())
+    if st.core is not None and hasattr(st.core, "metrics_dump"):
+        return st.core.metrics_dump()
+    return ""
 
 
 def metrics_server():
@@ -720,22 +750,24 @@ def flightrec_dump(path: Optional[str] = None) -> bool:
 
 
 def start_timeline(file_path: str, mark_cycles: bool = False) -> None:
-    """Start writing the collective-op timeline (Chrome-trace JSON) at runtime.
+    """Start writing the timeline (Chrome-trace JSON at ``file_path``) at
+    runtime.
 
     Reference: ``hvd.start_timeline`` → ``horovod_start_timeline``
     (operations.cc:735-777). Process mode records negotiation/queue/op phases
-    from the native background loop. In SPMD mode the collectives are compiled
-    into XLA programs, so there is no per-op host timeline — use
-    :func:`jax.profiler.start_trace` (the XLA/TPU profiler) instead; this
-    function starts one rooted at ``file_path`` + ``.xplane`` for parity.
+    from the native background loop. In SPMD mode the collectives are
+    compiled into the XLA program, so the device's side is a profiler trace
+    of the device planes under ``file_path`` + ``.xplane`` (every instruction
+    carries the program's scopes and kernel names), and ``file_path`` gets
+    the host's side when the timeline stops: ``hvd.init`` phases, compiles,
+    ``hvd.shard_batch`` calls and when each batch was ready, on the trace's
+    own clock (docs/timeline.md).
     """
     st = _require_init()
     if st.core is not None:
         st.core.start_timeline(file_path, mark_cycles)
-    else:
-        import jax.profiler
-        jax.profiler.start_trace(file_path + ".xplane")
-        st.xla_trace_active = True
+    elif st.recorder is not None:
+        st.recorder.start_timeline(file_path)
 
 
 def stop_timeline() -> None:
@@ -744,10 +776,8 @@ def stop_timeline() -> None:
     st = _require_init()
     if st.core is not None:
         st.core.stop_timeline()
-    elif getattr(st, "xla_trace_active", False):
-        import jax.profiler
-        jax.profiler.stop_trace()
-        st.xla_trace_active = False
+    elif st.recorder is not None:
+        st.recorder.stop_timeline()
 
 
 def start_trace(file_path: str, sample: Optional[int] = None,

@@ -1,0 +1,303 @@
+"""What only the SPMD program knows about itself, kept in the runtime state.
+
+The SPMD half of two APIs that exist: the counters behind ``hvd.metrics()`` /
+``hvd.metrics_dump()`` and the host spans of ``hvd.start_timeline``. One
+recorder lives in the runtime state from ``hvd.init()`` to ``hvd.shutdown()``
+(process mode has the native core's registry and timeline instead).
+
+Counters, always on. JAX tells its listeners (``jax.monitoring``) whenever it
+traces, lowers or compiles a function, with the function's name, and whenever
+the persistent cache hits or is written; ``hvd.init`` times its own phases;
+``hvd.shard_batch`` adds a call and its bytes. Nothing here runs on a step's
+path: the listeners fire only while JAX compiles, and ``shard_batch`` pays two
+integer additions and takes no lock.
+
+Spans, only between ``start_timeline`` and ``stop_timeline``: the ``hvd.init``
+phases, every compile with its function and cause, every ``shard_batch`` from
+call to return and, from one watcher thread that exists only in that interval,
+from return to the batch being ready on every chip. All on ``time.time_ns``,
+the clock of the device trace's ``profile_start_time``, so the span file and
+the ``.xplane.pb`` lie over each other by a subtraction. docs/timeline.md has
+the file's format, docs/metrics.md the metric catalog.
+"""
+
+from __future__ import annotations
+
+import collections
+import json
+import os
+import queue
+import re
+import threading
+import time
+from typing import Optional
+
+# The spans a timeline keeps at most; older ones fall out. A constant, not an
+# option: 65,536 spans are hours of steps at one shard_batch a step.
+SPAN_RING = 65536
+WATCHER_THREAD = "hvd-timeline-batch-ready"
+
+_STAGES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    # A persistent-cache load happens inside this one too.
+    "/jax/core/compile/backend_compile_duration": "backend_compile",
+}
+_CACHE_HIT = "/jax/compilation_cache/cache_hits"
+# JAX records a miss where it writes the new entry, so a compile under the
+# cache's thresholds (jax_persistent_cache_min_compile_time_secs) is neither.
+_CACHE_MISS = "/jax/compilation_cache/cache_misses"
+# JAX names the lowering and the compile "jit(f)", the trace "f".
+_WRAPPED = re.compile(r"^\w+\((.*)\)$")
+
+
+def _function(fun_name: str) -> str:
+    m = _WRAPPED.match(fun_name)
+    return m.group(1) if m else fun_name
+
+
+def _process_age_s() -> Optional[float]:
+    """Seconds since the operating system started this process (Linux), or
+    None. A caller may have started the backend before ``hvd.init`` (the
+    first ``jax.devices()`` of a process pays for the chip's start-up), so
+    the one number that holds all of start-up is measured from the process's
+    own start."""
+    try:
+        with open("/proc/self/stat") as f:
+            ticks = int(f.read().rsplit(")", 1)[1].split()[19])
+        with open("/proc/uptime") as f:
+            up = float(f.read().split()[0])
+        return up - ticks / os.sysconf("SC_CLK_TCK")
+    except (OSError, ValueError, IndexError):
+        return None
+
+
+class SpmdRecorder:
+    def __init__(self):
+        # (function, stage) -> [count, seconds]
+        self.compiles: dict = collections.defaultdict(lambda: [0, 0.0])
+        self.cache_hits = 0
+        self.cache_misses = 0
+        self.init_phases: list = []     # (phase, start_ns, end_ns)
+        self.init_done_process_s: Optional[float] = None
+        self.placed_calls = 0
+        self.placed_bytes = 0
+        # function -> the argument signatures run_step has traced it with
+        self._signatures: dict = {}
+        self._cause: dict = {}          # function -> cause of the next compile
+        self._lock = threading.Lock()   # the listeners', at compile time only
+        # The timeline, None when none runs.
+        self.spans: Optional[collections.deque] = None
+        self._timeline_path = ""        # the device trace: this + ".xplane"
+        self._trace_started_ns = 0
+        self._pending: Optional[queue.SimpleQueue] = None
+        self._watcher: Optional[threading.Thread] = None
+
+    # ---- lifecycle (hvd.init / hvd.shutdown) -----------------------------
+
+    def phase(self, name: str, start_ns: int, end_ns: int) -> None:
+        self.init_phases.append((name, start_ns, end_ns))
+
+    def start(self) -> None:
+        from jax import monitoring
+        self.init_done_process_s = _process_age_s()
+        monitoring.register_event_time_span_listener(self._on_time_span)
+        monitoring.register_event_listener(self._on_event)
+
+    def stop(self) -> None:
+        from jax import monitoring
+        if self.spans is not None:
+            self.stop_timeline()
+        monitoring.unregister_event_time_span_listener(self._on_time_span)
+        monitoring.unregister_event_listener(self._on_event)
+
+    # ---- what JAX and the program report ---------------------------------
+
+    def _on_time_span(self, event: str, start: float, end: float,
+                      fun_name: str = "", **_) -> None:
+        stage = _STAGES.get(event)
+        if stage is None:
+            return
+        function = _function(fun_name)
+        with self._lock:
+            entry = self.compiles[(function, stage)]
+            entry[0] += 1
+            entry[1] += end - start
+            cause = self._cause.get(function, "")
+            if stage == "backend_compile":
+                self._cause.pop(function, None)
+        if self.spans is not None:
+            self.span(f"compile/{stage}", int(start * 1e9), int(end * 1e9),
+                      function=function, cause=cause)
+
+    def _on_event(self, event: str, **_) -> None:
+        with self._lock:
+            if event == _CACHE_HIT:
+                self.cache_hits += 1
+            elif event == _CACHE_MISS:
+                self.cache_misses += 1
+
+    def note_trace(self, function: str, signature) -> None:
+        """``run_step`` calls this while JAX traces the step's body: why this
+        trace, and the compile that follows it, happened."""
+        with self._lock:
+            seen = self._signatures.setdefault(function, set())
+            self._cause[function] = (
+                "first call" if not seen
+                else "new shardings" if signature in seen else "new shapes")
+            seen.add(signature)
+
+    def note_placed(self, nbytes: int) -> None:
+        self.placed_calls += 1
+        self.placed_bytes += nbytes
+
+    # ---- hvd.metrics() ---------------------------------------------------
+
+    def families(self) -> dict:
+        """The counters in the shape ``observability.parse_prometheus_text``
+        gives (docs/metrics.md, "SPMD mode")."""
+        def family(kind: str, help_: str, samples: list) -> dict:
+            return {"type": kind, "help": help_, "samples": samples}
+
+        with self._lock:
+            compiles = sorted(self.compiles.items())
+            hits, misses = self.cache_hits, self.cache_misses
+        counts, seconds = [], []
+        for (function, stage), (count, secs) in compiles:
+            labels = {"function": function, "stage": stage}
+            counts.append(("", labels, float(count)))
+            seconds.append(("", labels, secs))
+        out = {
+            "hvdtpu_spmd_compiles_total": family(
+                "counter", "Times JAX traced, lowered or compiled (or loaded "
+                "from the persistent cache) a function, by function and "
+                "stage.", counts),
+            "hvdtpu_spmd_compile_seconds_total": family(
+                "counter", "Seconds of those, by function and stage.",
+                seconds),
+            "hvdtpu_spmd_compile_cache_hits_total": family(
+                "counter", "Executables loaded from the persistent "
+                "compilation cache.", [("", {}, float(hits))]),
+            "hvdtpu_spmd_compile_cache_misses_total": family(
+                "counter", "Executables compiled and written to the "
+                "persistent compilation cache.", [("", {}, float(misses))]),
+            "hvdtpu_spmd_init_seconds": family(
+                "gauge", "Seconds of each phase of hvd.init(): backend (the "
+                "first jax.devices(), the chip's start-up unless the caller "
+                "made it before), mesh, compile_cache.",
+                [("", {"phase": p}, (b - a) * 1e-9)
+                 for p, a, b in self.init_phases]),
+            "hvdtpu_spmd_shard_batch_calls_total": family(
+                "counter", "Calls of hvd.shard_batch.",
+                [("", {}, float(self.placed_calls))]),
+            "hvdtpu_spmd_shard_batch_bytes_total": family(
+                "counter", "Host bytes hvd.shard_batch was given to place.",
+                [("", {}, float(self.placed_bytes))]),
+        }
+        if self.init_done_process_s is not None:
+            out["hvdtpu_spmd_init_done_process_seconds"] = family(
+                "gauge", "Seconds from the start of the process to the "
+                "return of hvd.init().",
+                [("", {}, self.init_done_process_s)])
+        return out
+
+    # ---- hvd.start_timeline / hvd.stop_timeline --------------------------
+
+    def span(self, name: str, start_ns: int, end_ns: int, **args) -> None:
+        spans = self.spans
+        if spans is not None:
+            spans.append((name, start_ns, end_ns, args))
+
+    def start_timeline(self, path: str) -> None:
+        import jax
+
+        if self.spans is not None:
+            raise RuntimeError("a timeline is already running")
+        # The device planes alone. With the host tracer on, at its default
+        # level (2) or at 1, the runtime's host-side layout change of a uint8
+        # image batch writes a million Transpose events a batch and runs five
+        # to ten times slower: 20 ResNet-50 steps gave a 627 MB trace that
+        # took 85 s to stop, with the chip waiting for a feed no untraced run
+        # has (PERF.md, "Reading the trace"). The host's side is in the spans.
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        options.host_tracer_level = 0
+        self._timeline_path = path
+        self.spans = collections.deque(maxlen=SPAN_RING)
+        self._pending = queue.SimpleQueue()
+        self._watcher = threading.Thread(
+            target=self._watch, args=(self._pending,),
+            name=WATCHER_THREAD, daemon=True)
+        self._watcher.start()
+        self._trace_started_ns = time.time_ns()
+        jax.profiler.start_trace(path + ".xplane", profiler_options=options)
+
+    def watch_batch(self, placed, returned_ns: int) -> None:
+        pending = self._pending
+        if pending is not None:
+            pending.put((placed, returned_ns))
+
+    def _watch(self, pending: "queue.SimpleQueue") -> None:
+        import jax
+
+        while True:
+            item = pending.get()
+            if item is None:
+                return
+            placed, returned_ns = item
+            try:
+                jax.block_until_ready(placed)
+            except RuntimeError:    # a donated batch: a step took it
+                continue
+            self.span("batch_ready", returned_ns, time.time_ns())
+
+    def stop_timeline(self) -> None:
+        import jax
+
+        if self.spans is None:
+            return
+        try:
+            jax.profiler.stop_trace()
+        finally:
+            self._pending.put(None)
+            self._watcher.join()
+            spans, self.spans = self.spans, None
+            self._pending = self._watcher = None
+        xplane, profile_start_ns = _read_profile_start(
+            self._timeline_path + ".xplane")
+        events = [
+            {"name": name, "ph": "X", "pid": os.getpid(), "tid": 0,
+             "ts": start / 1e3, "dur": (end - start) / 1e3,
+             "args": dict(args, start_ns=start, end_ns=end)}
+            for name, start, end, args in
+            [(f"init/{p}", a, b, {}) for p, a, b in self.init_phases]
+            + list(spans)]
+        with open(self._timeline_path, "w") as f:
+            json.dump({
+                "traceEvents": events, "displayTimeUnit": "ms",
+                "metadata": {
+                    "clock": "time.time_ns: ts and dur in us, args.start_ns "
+                             "and args.end_ns in ns since the epoch",
+                    # Subtract from a span to put it on the device trace's
+                    # clock (ns since the profile started).
+                    "profile_start_time": profile_start_ns
+                    or self._trace_started_ns,
+                    "profile_start_time_from": "xplane" if profile_start_ns
+                    else "time.time_ns at start_timeline",
+                    "xplane": xplane}}, f)
+
+
+def _read_profile_start(trace_dir: str) -> tuple:
+    """(path of the newest ``.xplane.pb`` under ``trace_dir``, its
+    ``profile_start_time`` in ns since the epoch); None where absent."""
+    import glob
+
+    found = sorted(glob.glob(os.path.join(
+        trace_dir, "plugins", "profile", "*", "*.xplane.pb")))
+    if not found:
+        return None, None
+    from jax.profiler import ProfileData
+    for plane in ProfileData.from_file(found[-1]).planes:
+        if plane.name == "Task Environment":
+            return found[-1], dict(plane.stats).get("profile_start_time")
+    return found[-1], None

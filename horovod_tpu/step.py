@@ -14,6 +14,7 @@ a single SPMD program over the device mesh; these helpers wrap ``jax.shard_map``
 from __future__ import annotations
 
 import functools
+import time
 from typing import Any, Optional, Sequence
 
 import jax
@@ -49,22 +50,26 @@ def run_step(fn=None, *, in_specs, out_specs, mesh=None,
                                  static_argnums=static_argnums,
                                  check_vma=check_vma)
     m = mesh if mesh is not None else runtime.mesh()
-    if not check_vma:
-        # Without varying-axes tracking the collectives can't see invariance;
-        # flag plain (Horovod-exact) semantics for the duration of the trace.
-        from .ops.collectives import _plain_semantics
+    from .ops.collectives import _plain_semantics
 
-        @functools.wraps(fn)
-        def flagged(*a, **k):
-            prev = getattr(_plain_semantics, "on", False)
-            _plain_semantics.on = True
-            try:
-                return fn(*a, **k)
-            finally:
-                _plain_semantics.on = prev
-        body = flagged
-    else:
-        body = fn
+    @functools.wraps(fn)
+    def body(*a, **k):
+        # Runs only while JAX traces the step, never on a step's path. The
+        # recorder learns why this trace happened (first call, new shapes,
+        # new shardings); without varying-axes tracking the collectives
+        # can't see invariance, so plain (Horovod-exact) semantics are
+        # flagged for the duration of the trace.
+        recorder = runtime.recorder()
+        if recorder is not None:
+            recorder.note_trace(getattr(fn, "__name__", str(fn)), tuple(
+                (getattr(x, "shape", None), str(getattr(x, "dtype", None)))
+                for x in jax.tree.leaves((a, k))))
+        prev = getattr(_plain_semantics, "on", False)
+        _plain_semantics.on = prev or not check_vma
+        try:
+            return fn(*a, **k)
+        finally:
+            _plain_semantics.on = prev
     mapped = jax.shard_map(body, mesh=m, in_specs=in_specs,
                            out_specs=out_specs, check_vma=check_vma)
     return jax.jit(mapped, donate_argnums=tuple(donate_argnums),
@@ -92,12 +97,21 @@ def shard_batch(batch, dim: int = 0, axis: Optional[str] = None, mesh=None):
     mesh (or its local slice under multi-host jax).
     """
     m = mesh if mesh is not None else runtime.mesh()
-    spec = batch_spec(dim, axis)
-
-    def _put(x):
-        return jax.device_put(x, NamedSharding(m, spec))
-
-    return jax.tree.map(_put, batch)
+    sharding = NamedSharding(m, batch_spec(dim, axis))
+    # Counted always (two additions, no lock); timed, and watched until every
+    # chip has the batch, only while a timeline runs (hvd.start_timeline).
+    recorder = runtime.recorder()
+    timed = recorder is not None and recorder.spans is not None
+    t0 = time.time_ns() if timed else 0
+    placed = jax.tree.map(lambda x: jax.device_put(x, sharding), batch)
+    if recorder is not None:
+        recorder.note_placed(sum(getattr(x, "nbytes", 0)
+                                 for x in jax.tree.leaves(batch)))
+    if timed:
+        t1 = time.time_ns()
+        recorder.span("shard_batch", t0, t1)
+        recorder.watch_batch(placed, t1)
+    return placed
 
 
 def replicate(tree, mesh=None):

@@ -192,6 +192,37 @@ def test_gpt_flash_attention_matches_dense(make_runtime):
         step(params, tokens4, positions4)
 
 
+@pytest.mark.parametrize("kv_heads", [1, 2])
+def test_gpt_gqa_dense_matches_flash(make_runtime, kv_heads):
+    """Grouped-query attention on the dense path: ``_attention`` tiles the
+    key and value heads up before ``default_attention`` (which takes equal
+    head counts), as flash does itself; both agree on loss and gradient."""
+    make_runtime()
+    base = dict(vocab_size=64, num_layers=2, num_heads=4,
+                num_kv_heads=kv_heads, head_dim=8, embed_dim=32, mlp_dim=64,
+                dtype=jnp.float32, tp_axis=None, sp_axis=None)
+    cfg_dense = gpt.GPTConfig(attention="dense", **base)
+    cfg_flash = gpt.GPTConfig(attention="flash", **base)
+    params = gpt.init_params(jax.random.PRNGKey(5), cfg_dense)
+    assert params["layers"][0]["wk"].shape == (32, kv_heads, 8)
+    B, S = 2, 16
+    tokens = jax.random.randint(jax.random.PRNGKey(6), (B, S), 0, 64)
+    targets = jnp.roll(tokens, -1, axis=1).at[:, -1].set(-1)
+    positions = jnp.broadcast_to(jnp.arange(S), (B, S))
+
+    def loss_grads(cfg):
+        return jax.value_and_grad(
+            lambda p: gpt.loss_fn(p, tokens, targets, positions, cfg))(
+                params)
+
+    l_d, g_d = loss_grads(cfg_dense)
+    l_f, g_f = loss_grads(cfg_flash)
+    np.testing.assert_allclose(float(l_f), float(l_d), rtol=1e-5)
+    for gd, gf in zip(jax.tree.leaves(g_d), jax.tree.leaves(g_f)):
+        np.testing.assert_allclose(np.asarray(gf), np.asarray(gd),
+                                   rtol=5e-4, atol=5e-5)
+
+
 def test_gpt_moe_ep_forward_parity(make_runtime):
     """dp=2 x ep=2 x sp=2 MoE-GPT == single-device forward (no drops)."""
     make_runtime(mesh_shape={"dp": 2, "ep": 2, "sp": 2})

@@ -1,0 +1,318 @@
+"""The compiled step names its own parts, and the SPMD recorder behind
+``hvd.metrics()`` and ``hvd.start_timeline`` (ISSUE 23): scopes and kernel
+names reach the compiled program as metadata only; compile, start-up and
+placement counters; host spans on the device trace's clock."""
+
+import functools
+import json
+import re
+import threading
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+import pytest
+
+import horovod_tpu as hvd
+from horovod_tpu import spmd_recorder
+from horovod_tpu.compression import pallas_kernels as pk
+from horovod_tpu.models import gpt
+from horovod_tpu.observability import parse_prometheus_text, sample_value
+from horovod_tpu.ops import flash_attention as fa
+
+CFG = dict(vocab_size=64, num_layers=2, num_heads=2, num_kv_heads=1,
+           head_dim=16, embed_dim=32, mlp_dim=64, tp_axis=None, sp_axis=None,
+           attention="flash", dtype=jnp.float32)
+B, S = 4, 128
+
+
+@pytest.fixture
+def spmd4(make_runtime):
+    return make_runtime(devices=jax.devices()[:4])
+
+
+def gpt_step(remat: str):
+    """A tiny GPT training step under run_step + DistributedOptimizer, its
+    state and a batch for the 4-device mesh."""
+    cfg = gpt.GPTConfig(remat=remat, **CFG)
+    opt = hvd.DistributedOptimizer(optax.adamw(1e-3))
+
+    def _train_step(params, opt_state, data):
+        loss, grads = jax.value_and_grad(
+            lambda p: gpt.loss_fn(p, *data, cfg))(params)
+        updates, opt_state = opt.update(grads, opt_state, params)
+        return (optax.apply_updates(params, updates), opt_state,
+                hvd.allreduce(loss, op=hvd.Average))
+
+    step = hvd.run_step(
+        _train_step,
+        in_specs=(hvd.REPLICATED, hvd.REPLICATED, hvd.batch_spec(0)),
+        out_specs=hvd.REPLICATED)
+    params = hvd.replicate(gpt.init_params(jax.random.PRNGKey(0), cfg))
+    rng = np.random.default_rng(0)
+    tokens = rng.integers(0, cfg.vocab_size, (B, S), dtype=np.int32)
+    positions = np.broadcast_to(np.arange(S, dtype=np.int32), (B, S)).copy()
+    data = hvd.shard_batch((tokens, np.roll(tokens, -1, -1), positions))
+    return step, params, hvd.replicate(opt.init(params)), data
+
+
+def op_names(step, *args) -> set:
+    text = step.lower(*args).compile().as_text()
+    return set(re.findall(r'op_name="([^"]*)"', text))
+
+
+# ---- (a) scopes in the compiled step ----------------------------------------
+
+@pytest.mark.parametrize("remat", ["none", "full"])
+def test_compiled_step_carries_the_scopes(spmd4, remat):
+    names = op_names(*gpt_step(remat))
+
+    def some(*parts):
+        return any(all(p in n for p in parts) for n in names)
+
+    assert some("hvd_exchange") and some("hvd_optimizer")
+    assert some("hvd_exchange/hvd_allreduce/grads")
+    for scope in ("layer0)/attn", "layer0)/mlp", "layer1)/attn", "jvp(head)",
+                  "jvp(embed)", "jvp(loss)"):
+        assert some(scope), scope
+    # Every forward scope again in the backward pass ...
+    for scope in ("layer0", "layer1", "head", "embed", "loss"):
+        assert some(f"transpose(jvp({scope}))"), scope
+    assert some("transpose(jvp(layer0))", "/attn/")
+    assert some("transpose(jvp(layer0))", "/mlp/")
+    # ... and, under full recomputation, in the recomputed copy of a block.
+    assert some("rematted_computation/attn") == (remat == "full")
+    assert some("rematted_computation/mlp") == (remat == "full")
+    # A scope never carries a counter that changes between two traces.
+    assert not some("noname")
+
+
+def test_in_step_collective_scope_uses_the_callers_name(spmd4):
+    def body(x):
+        return (hvd.allreduce(x, name="loss_avg"), hvd.allgather(x),
+                hvd.reducescatter(x, name="rs"))
+
+    step = hvd.run_step(body, in_specs=hvd.batch_spec(0),
+                        out_specs=(hvd.REPLICATED, hvd.REPLICATED,
+                                   hvd.batch_spec(0)), check_vma=False)
+    text = step.lower(jnp.ones((16, 4))).as_text(debug_info=True)
+    for scope in ("hvd_allreduce/loss_avg", "hvd_allgather/unnamed",
+                  "hvd_reducescatter/rs"):
+        assert scope in text, scope
+
+
+# ---- (b) kernel names --------------------------------------------------------
+
+def _flash(grad: bool):
+    q = jnp.ones((1, 128, 2, 16), jnp.float32)
+    if grad:
+        return jax.make_jaxpr(jax.grad(
+            lambda q: fa.flash_attention(q, q, q).sum()))(q)
+    return jax.make_jaxpr(lambda q: fa.flash_attention(q, q, q))(q)
+
+
+FLAT = jnp.linspace(-1.0, 1.0, 512 * 4, dtype=jnp.float32)
+LEVELS = jnp.linspace(0.0, 1.0, 8, dtype=jnp.float32)
+Q8 = jnp.zeros((4, 512), jnp.uint8)
+MN = jnp.zeros((4,), jnp.float32)
+
+
+@pytest.mark.parametrize("name, make", [
+    ("hvd_flash_fwd", lambda: _flash(False)),
+    ("hvd_flash_dkdv", lambda: _flash(True)),
+    ("hvd_flash_dq", lambda: _flash(True)),
+    ("hvd_maxmin_quantize", lambda: jax.make_jaxpr(
+        lambda x: pk.maxmin_quantize_pallas(x, 4, 512, True))(FLAT)),
+    # TPU-only (pltpu.prng_* has no CPU lowering), but it traces anywhere.
+    ("hvd_maxmin_quantize_stochastic", lambda: jax.make_jaxpr(
+        lambda x, seed: pk.maxmin_quantize_stochastic_pallas(
+            x, 4, 512, seed))(FLAT, jnp.int32(1))),
+    ("hvd_maxmin_dequantize", lambda: jax.make_jaxpr(
+        lambda q: pk.maxmin_dequantize_pallas(q, MN, MN, 512, True))(Q8)),
+    ("hvd_maxmin_dequantize_sum", lambda: jax.make_jaxpr(
+        lambda q: pk.maxmin_dequantize_sum_pallas(
+            q, jnp.stack([MN, MN]), jnp.stack([MN, MN]), True))(
+                jnp.stack([Q8, Q8]))),
+    ("hvd_norm_quantize", lambda: jax.make_jaxpr(
+        lambda x: pk.norm_quantize_pallas(x, LEVELS, 512, True, True))(FLAT)),
+    ("hvd_norm_dequantize", lambda: jax.make_jaxpr(
+        lambda q: pk.norm_dequantize_pallas(q, LEVELS, MN, True))(Q8)),
+])
+def test_kernel_names(name, make):
+    """The nine names the benchmark's readers match as strings."""
+    assert re.search(rf"\bname={name}\b", str(make())), name
+
+
+def test_kernel_name_constants():
+    assert (fa.KERNEL_FWD, fa.KERNEL_DKDV, fa.KERNEL_DQ) == (
+        "hvd_flash_fwd", "hvd_flash_dkdv", "hvd_flash_dq")
+    assert {v for k, v in vars(pk).items() if k.startswith("KERNEL_")} == {
+        "hvd_maxmin_quantize", "hvd_maxmin_quantize_stochastic",
+        "hvd_maxmin_dequantize", "hvd_maxmin_dequantize_sum",
+        "hvd_norm_quantize", "hvd_norm_dequantize"}
+
+
+# ---- (c) hvd.metrics() in SPMD mode -----------------------------------------
+
+def compiles(function: str, stage: str = "backend_compile") -> float:
+    return sample_value(hvd.metrics(), "hvdtpu_spmd_compiles_total",
+                        function=function, stage=stage) or 0.0
+
+
+def test_spmd_metrics_count_compiles_by_function(spmd4):
+    def _counted_step(x):
+        return hvd.allreduce(x.sum(), name="s")
+
+    step = hvd.run_step(_counted_step, in_specs=hvd.batch_spec(0),
+                        out_specs=hvd.REPLICATED)
+    assert compiles("_counted_step") == 0
+    step(hvd.shard_batch(np.ones((8, 3), np.float32)))
+    assert compiles("_counted_step") == 1
+    assert compiles("_counted_step", "trace") == 1
+    assert compiles("_counted_step", "lower") == 1
+    step(hvd.shard_batch(np.ones((8, 3), np.float32)))
+    assert compiles("_counted_step") == 1          # the same step: no compile
+    step(hvd.shard_batch(np.ones((16, 3), np.float32)))
+    assert compiles("_counted_step") == 2          # a new shape: one more
+    m = hvd.metrics()
+    assert sample_value(m, "hvdtpu_spmd_compile_seconds_total",
+                        function="_counted_step", stage="trace") > 0
+    assert sample_value(m, "hvdtpu_spmd_shard_batch_calls_total") == 3
+    assert sample_value(m, "hvdtpu_spmd_shard_batch_bytes_total") \
+        == (8 + 8 + 16) * 3 * 4
+    phases = {lb["phase"] for _, lb, _ in
+              m["hvdtpu_spmd_init_seconds"]["samples"]}
+    assert phases == {"backend", "mesh", "compile_cache"}
+    assert sample_value(m, "hvdtpu_spmd_compile_cache_misses_total") == 0
+
+
+def test_spmd_metrics_dump_round_trips(spmd4):
+    hvd.run_step(lambda x: hvd.allreduce(x.sum()),
+                 in_specs=hvd.batch_spec(0), out_specs=hvd.REPLICATED)(
+                     hvd.shard_batch(np.ones((4, 2), np.float32)))
+    text = hvd.metrics_dump()
+    assert "# TYPE hvdtpu_spmd_compiles_total counter" in text
+    assert parse_prometheus_text(text) == hvd.metrics()
+
+
+def test_listeners_leave_with_shutdown(make_runtime):
+    from jax._src import monitoring
+
+    before = (len(monitoring.get_event_listeners()),
+              len(monitoring.get_event_time_span_listeners()))
+    make_runtime(devices=jax.devices()[:4])
+    assert len(monitoring.get_event_listeners()) == before[0] + 1
+    hvd.shutdown()
+    assert (len(monitoring.get_event_listeners()),
+            len(monitoring.get_event_time_span_listeners())) == before
+    assert not hvd.is_initialized()
+
+
+# ---- (d) hvd.start_timeline / hvd.stop_timeline ------------------------------
+
+def watcher_threads() -> list:
+    return [t for t in threading.enumerate()
+            if t.name == spmd_recorder.WATCHER_THREAD]
+
+
+def test_timeline_writes_spans_on_the_wall_clock(spmd4, tmp_path,
+                                                 monkeypatch):
+    monkeypatch.setattr(spmd_recorder, "SPAN_RING", 16)
+    step = hvd.run_step(lambda x: hvd.allreduce(x.sum()),
+                        in_specs=hvd.batch_spec(0), out_specs=hvd.REPLICATED)
+    batch = np.ones((8, 3), np.float32)
+    step(hvd.shard_batch(batch))
+    assert not watcher_threads()            # no timeline: no extra thread
+    assert hvd.runtime.recorder().spans is None
+    path = str(tmp_path / "timeline.json")
+    t0 = time.time_ns()
+    hvd.start_timeline(path)
+    assert len(watcher_threads()) == 1
+    for _ in range(12):
+        step(hvd.shard_batch(batch)).block_until_ready()
+    step(hvd.shard_batch(np.ones((16, 3), np.float32)))    # a compile
+    assert len(hvd.runtime.recorder().spans) == 16         # the ring's bound
+    hvd.stop_timeline()
+    t1 = time.time_ns()
+    assert not watcher_threads()
+    assert hvd.runtime.recorder().spans is None
+
+    with open(path) as f:
+        doc = json.load(f)
+    events = doc["traceEvents"]
+    by_name = {}
+    for e in events:
+        by_name.setdefault(e["name"], []).append(e)
+    assert {"init/backend", "init/mesh", "init/compile_cache", "shard_batch",
+            "batch_ready", "compile/trace", "compile/lower",
+            "compile/backend_compile"} <= set(by_name)
+    for e in by_name["shard_batch"] + by_name["batch_ready"]:
+        a, b = e["args"]["start_ns"], e["args"]["end_ns"]
+        assert t0 <= a <= b <= t1                   # time.time_ns, in ns
+        assert e["ph"] == "X" and e["ts"] == pytest.approx(a / 1e3)
+        assert e["dur"] == pytest.approx((b - a) / 1e3)
+    # A batch is ready after shard_batch returned it, from that moment on
+    # (the ring may have dropped an older one of a pair).
+    assert by_name["batch_ready"][-1]["args"]["start_ns"] \
+        == by_name["shard_batch"][-1]["args"]["end_ns"]
+    compiled = by_name["compile/backend_compile"][-1]["args"]
+    assert compiled["function"] == "<lambda>"
+    assert compiled["cause"] == "new shapes"
+    # The device trace lies beside it, and the file says when it started.
+    meta = doc["metadata"]
+    assert meta["xplane"].startswith(path + ".xplane")
+    assert meta["xplane"].endswith(".xplane.pb")
+    assert t0 <= meta["profile_start_time"] <= t1
+
+
+def test_compile_causes(spmd4, tmp_path):
+    def _caused(x):
+        return hvd.allreduce(x.sum())
+
+    step = hvd.run_step(_caused, in_specs=hvd.batch_spec(0),
+                        out_specs=hvd.REPLICATED)
+    x = np.ones((8, 3), np.float32)
+    hvd.start_timeline(str(tmp_path / "t.json"))
+    step(x)                                     # un-placed
+    step(hvd.shard_batch(x))                    # the same shapes, placed
+    step(hvd.shard_batch(np.ones((16, 3), np.float32)))
+    hvd.stop_timeline()
+    with open(tmp_path / "t.json") as f:
+        causes = [e["args"]["cause"] for e in json.load(f)["traceEvents"]
+                  if e["name"] == "compile/backend_compile"
+                  and e["args"]["function"] == "_caused"]
+    assert causes == ["first call", "new shardings", "new shapes"]
+
+
+def test_shutdown_stops_a_running_timeline(make_runtime, tmp_path):
+    make_runtime(devices=jax.devices()[:4])
+    path = tmp_path / "left_running.json"
+    hvd.start_timeline(str(path))
+    hvd.shutdown()
+    assert not watcher_threads()
+    assert "traceEvents" in json.loads(path.read_text())
+
+
+# ---- (e) metadata only --------------------------------------------------------
+
+def test_scopes_change_no_computation(spmd4, monkeypatch):
+    import contextlib
+
+    def run():
+        step, params, opt_state, data = gpt_step("full")
+        jaxpr = jax.make_jaxpr(step)(params, opt_state, data)
+        return str(jaxpr), step(params, opt_state, data)
+
+    with_scopes, out = run()
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    without, out_plain = run()
+    assert not any("hvd_optimizer" in n for n in op_names(*gpt_step("full")))
+    # A jaxpr prints no name stack: the two are the same text, kernel names
+    # apart, and the same numbers.
+    strip = functools.partial(re.sub, r"name=hvd_\w+", "name=k")
+    assert strip(with_scopes) == strip(without)
+    for a, b in zip(jax.tree.leaves(out), jax.tree.leaves(out_plain)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
