@@ -194,7 +194,8 @@ def _attention(cfg: GPTConfig, q, k, v):
                                    attn_fn=flash_attention)
     if not _axis_bound(sp) or cfg.attention == "dense":
         # GQA: the plain path takes equal head counts, so the key and value
-        # heads tile up here as flash, ring and Ulysses do themselves.
+        # heads tile up here as ring and Ulysses do themselves (the flash
+        # kernels read them at their own head count).
         from ..ops.flash_attention import repeat_kv_heads
         return default_attention(q, repeat_kv_heads(k, q.shape[2]),
                                  repeat_kv_heads(v, q.shape[2]), causal=True)

@@ -11,21 +11,45 @@ exists to avoid. Algorithm: FlashAttention online-softmax tiling
 
 Design notes (TPU):
 
-* Layout ``[B*H, S, D]``. Each kernel walks a 3-D grid whose innermost
-  dimension streams the contraction blocks: the forward visits
-  ``(bh, q_block, k_block)`` so only ONE ``BLOCK x D`` slab of K and V is
-  DMA'd into VMEM per step, with the online-softmax state (running max,
-  denominator, output accumulator) carried across k-steps in VMEM scratch
-  and written on the final visit — VMEM use is O(BLOCK x D) regardless of
-  sequence length, not O(S x D).
-* All matmuls accumulate in fp32 (``preferred_element_type``) on the MXU.
-* Causal mode skips the upper-triangle blocks entirely (``pl.when`` — no
-  DMA, no FLOPs) and gets tail-padding to the 128-row block for free (a
-  real query row never attends a key beyond itself). Bidirectional mode
-  (``causal=False``, encoder models) computes every block and masks the
-  padded key columns instead. Any sequence length works in both.
+* Layout: q, o, dO, dQ are ``[B*H, S, D]``; k, v, dK, dV stay at their own
+  head count, ``[B*Hkv, S, D]``. Grouped-query attention is an index map:
+  query head ``h`` reads K/V head ``h // group``; nothing is repeated in
+  HBM, and dK/dV of one K/V head accumulate over its whole group inside the
+  dKdV kernel and are written once.
+* The tile follows the call. ``block_sizes`` gives ``(block_q, block_k)``
+  per kernel from what the trace can see (padded S, D, operand dtype,
+  causal): the largest candidate that divides the padded length and whose
+  VMEM estimate fits the limit the call sets. The candidates are a table
+  in the source, from runs of the kernels alone on a v5e
+  (``scripts/flash_block_sweep.py``; PERF.md, Findings, PR 24); nothing is
+  searched at run time. The sequence is padded to 128 rows whatever the
+  block.
+* MXU operands have the input's dtype: ``q·kᵀ`` and ``dO·vᵀ`` take the refs
+  as they are, ``p`` and ``ds`` are cast to it for the second products
+  (what ``default_attention`` does with ``probs.astype(q.dtype)``); float32
+  inputs get float32 products (at Mosaic's default precision, which on the
+  v5e measured as one bfloat16 pass: PERF.md, Findings, PR 24). Everything
+  else is float32 whatever the input: scores, max, exp, denominator,
+  log-sum-exp, delta, accumulators.
+* Each kernel walks a 3-D grid whose innermost dimension streams the
+  contraction blocks: the forward visits ``(bh, q_block, k_block)`` with the
+  online-softmax state (running max and denominator, lane-replicated
+  ``[block_q, 128]``, and the output accumulator) carried across k-steps
+  in VMEM scratch and written on the final visit — VMEM use is
+  O(block x D + block_q x block_k) regardless of sequence length.
+* Causal mode skips the tiles wholly above the diagonal (``pl.when``: no
+  FLOPs) and masks every tile it computes (masking only those the diagonal
+  crosses measured no cheaper). A skipped step fetches nothing either: the
+  index maps of the streamed operands clamp to the last (forward, dQ) or
+  first (dKdV) kept tile, so a skipped step names the block already held
+  and the pipeline issues no DMA. Tail padding is free (a real query row
+  never attends a key beyond itself). Bidirectional mode (``causal=False``,
+  encoder models) computes every block and masks the padded key columns,
+  where there are any. Any sequence length works in both.
 * Backward = two kernels, same streaming structure: dKdV walks
-  ``(bh, k_block, q_block)``, dQ walks ``(bh, q_block, k_block)``, each
+  ``(b*hkv, k_block, group x q_block)`` on the transposed score tile
+  (``k·qᵀ``, so dV and dK are plain products; the row statistics come as
+  ``[1, block_q]`` rows), dQ walks ``(bh, q_block, k_block)``, each
   recomputing the probability tile from q, k and the saved row logsumexp —
   no S x S tensor is ever materialized in either direction.
 * Gate: compiled through Mosaic on the TPU backend, ``interpret=True`` on
@@ -45,8 +69,9 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-BLOCK_Q = 128
-BLOCK_K = 128
+from .pallas_util import out_vma as _out_vma
+
+_PAD = 128    # the sequence is padded to this many rows, whatever the block
 _LANES = 128  # TPU lane width: softmax stats ride lane-replicated [*, 128]
 _NEG_INF = -1e30  # large-negative instead of -inf: keeps exp/where NaN-free
 # The kernels' names in the compiled program: each becomes the name of its
@@ -56,6 +81,21 @@ KERNEL_FWD = "hvd_flash_fwd"
 KERNEL_DKDV = "hvd_flash_dkdv"
 KERNEL_DQ = "hvd_flash_dq"
 
+# The scoped VMEM every call asks Mosaic for (the default, 16 MiB, does not
+# hold a 512-1024-wide float32 score tile); half of a v5e core's 128 MiB.
+VMEM_LIMIT_BYTES = 64 * 1024 * 1024
+# The block table: a side of a tile is the largest of these that divides the
+# padded length, for all three kernels. From runs of the kernels alone on a
+# v5e at B*H,S,D = 48x4096x128 and 384x512x128 (and 2048, 1024 between),
+# bfloat16, K/V at 2 of 24 heads (scripts/flash_block_sweep.py; PERF.md,
+# Findings, PR 24): what a grid step costs beside its products decides, so
+# the larger tile wins up to 1024 (2048 loses 5-15%: more of the tile is
+# masked) and up to the whole of a short sequence (512x512 at S=512 is
+# twice as fast as 256x256, though it computes the masked half too).
+_CANDIDATES = (1024, 512, 256, 128)
+
+_NT = (((1,), (1,)), ((), ()))  # a · bᵀ: contract the last dim of both
+
 
 def _use_interpret() -> bool:
     # Same gate as the quantize kernels: compiled on the TPU backend only;
@@ -64,13 +104,11 @@ def _use_interpret() -> bool:
     return not _pallas_backend_enabled(None)
 
 
-from .pallas_util import out_vma as _out_vma  # noqa: E402
-
-
 def repeat_kv_heads(k, n_q_heads: int):
     """Grouped-query attention: tile K/V heads up to the query head count
     (the compact heads are what cross the wire; the repeat is local).
-    Shared by flash, ring and Ulysses attention."""
+    Shared by ring, Ulysses and dense attention; the flash kernels read
+    K/V at their own head count instead."""
     n_kv = k.shape[2]
     if n_kv == n_q_heads:
         return k
@@ -80,27 +118,123 @@ def repeat_kv_heads(k, n_q_heads: int):
     return jnp.repeat(k, n_q_heads // n_kv, axis=2)
 
 
-def _mask_tile(s, q_block, k_block, causal: bool, kv_len: int):
-    """Mask logits tile ``s`` [BLOCK_Q, BLOCK_K] (global positions from the
-    block indices). Causal mode masks the upper triangle — which also
-    covers the tail padding for free (a real query row never attends a key
-    at or beyond its own position's pad). Non-causal mode must mask the
-    padded key columns explicitly (``k_pos >= kv_len``), or every query
-    would attend the zero-filled tail."""
-    k_pos = k_block * BLOCK_K + jax.lax.broadcasted_iota(
-        jnp.int32, s.shape, 1)
+def vmem_estimate(kernel: str, block_q: int, block_k: int, d: int,
+                  itemsize: int) -> int:
+    """Bytes of VMEM one grid step of ``kernel`` holds: every streamed block
+    twice (the pipeline's double buffer), the scratch, and the score-sized
+    temporaries of the body (float32, plus the casts to the operand dtype).
+    ``d`` counts as a whole lane tile."""
+    d = -(-d // _LANES) * _LANES
+    q_blk, k_blk = block_q * d, block_k * d
+    tile = block_q * block_k
+    if kernel == KERNEL_FWD:
+        blocks = (2 * q_blk + 2 * k_blk) * itemsize + block_q * _LANES * 4
+        scratch = (2 * block_q * _LANES + q_blk) * 4
+        temps = tile * (3 * 4 + itemsize)
+    elif kernel == KERNEL_DKDV:
+        blocks = (2 * q_blk + 4 * k_blk) * itemsize + 2 * 8 * block_q * 4
+        scratch = 2 * k_blk * 4
+        temps = tile * (4 * 4 + 2 * itemsize)
+    else:
+        blocks = (3 * q_blk + 2 * k_blk) * itemsize \
+            + 2 * block_q * _LANES * 4
+        scratch = q_blk * 4
+        temps = tile * (4 * 4 + 2 * itemsize)
+    return 2 * blocks + scratch + temps
+
+
+def block_sizes(kernel: str, s_pad: int, d: int, dtype,
+                causal: bool) -> tuple[int, int]:
+    """``(block_q, block_k)`` of ``kernel`` for a call the trace sees as
+    padded length ``s_pad``, head size ``d``, operand ``dtype``: the
+    largest candidate that divides ``s_pad``, halved (the key side first)
+    while the VMEM estimate is over the limit the call sets. A pure
+    function of its arguments."""
+    del causal  # the call can see it; the table does not split on it
+    itemsize = jnp.dtype(dtype).itemsize
+    bq = bk = next(c for c in _CANDIDATES if s_pad % c == 0)
+    while vmem_estimate(kernel, bq, bk, d, itemsize) > VMEM_LIMIT_BYTES:
+        if bk >= bq and bk > _PAD:
+            bk //= 2
+        elif bq > _PAD:
+            bq //= 2
+        else:
+            break
+    return bq, bk
+
+
+def _compiler_params():
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"),
+        vmem_limit_bytes=VMEM_LIMIT_BYTES)
+
+
+def _div(x, n: int):
+    """Grid indices are int32 and not negative: lax's truncating division
+    with the divisor in the index's dtype (a Python int would be int64
+    under ``jax_enable_x64``, which the tests set)."""
+    return jax.lax.div(x, jnp.asarray(n, x.dtype))
+
+
+def _rem(x, n: int):
+    return jax.lax.rem(x, jnp.asarray(n, x.dtype))
+
+
+def _lanes(x, n: int):
+    """A lane-replicated ``[rows, 128]`` statistic widened (or cut) to
+    ``[rows, n]``."""
+    if n == _LANES:
+        return x
+    if n % _LANES == 0:
+        return jnp.tile(x, (1, n // _LANES))
+    if n < _LANES:
+        return x[:, :n]
+    return jnp.broadcast_to(x[:, :1], (x.shape[0], n))
+
+
+def _mask_tile(s, q_start, k_start, causal: bool, kv_len: int,
+               transposed: bool = False):
+    """Mask a score tile whose first query row and key column are at global
+    positions ``q_start``/``k_start``; ``transposed`` tiles are [keys,
+    queries]. Causal mode masks above the diagonal — which also covers the
+    tail padding for free (a real query row never attends a key at or
+    beyond its own position's pad). Non-causal mode must mask the padded
+    key columns explicitly (``k_pos >= kv_len``), or every query would
+    attend the zero-filled tail."""
+    q_dim, k_dim = (1, 0) if transposed else (0, 1)
+    k_pos = k_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, k_dim)
     if causal:
-        q_pos = q_block * BLOCK_Q + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 0)
+        q_pos = q_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, q_dim)
         return jnp.where(q_pos >= k_pos, s, _NEG_INF)
     return jnp.where(k_pos < kv_len, s, _NEG_INF)
 
 
+def _needs_mask(causal: bool, kv_len: int, s_pad: int) -> bool:
+    """Every kept tile is masked alike (masking only the tiles the diagonal
+    crosses measured no cheaper on the v5e: PERF.md, Findings, PR 24); a
+    bidirectional call without padding has nothing to mask."""
+    return causal or kv_len != s_pad
+
+
+def _kept(qi, kj, block_q: int, block_k: int, causal: bool):
+    """Whether the tile at block indices ``(qi, kj)`` holds any kept pair."""
+    if causal:
+        # The tile's last query row reaches its first key.
+        return (qi + 1) * block_q - 1 >= kj * block_k
+    # Trivially-true predicate, NOT an unguarded body: interpret mode's vma
+    # tracing (CPU-mesh shard_map) only standardizes the block-fetch
+    # slice's varying axes along the pl.when path — an unguarded body trips
+    # "dynamic_slice requires varying manual axes to match". Compiled
+    # Mosaic folds the constant predicate.
+    return kj >= 0
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
-                *, sm_scale: float, n_k_blocks: int, causal: bool,
-                kv_len: int):
+                *, sm_scale: float, block_q: int, block_k: int,
+                n_k_blocks: int, causal: bool, kv_len: int, mask: bool):
     qi = pl.program_id(1)
     kj = pl.program_id(2)
+    d = acc_scr.shape[-1]
 
     @pl.when(kj == 0)
     def _init():
@@ -109,95 +243,80 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
     def _step():
-        q = q_ref[0].astype(jnp.float32) * sm_scale      # [BQ, D]
-        k = k_ref[0].astype(jnp.float32)                 # [BK, D]
-        v = v_ref[0].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+        v = v_ref[0]                                     # [BK, D]
+        s = jax.lax.dot_general(q_ref[0], k_ref[0], _NT,
                                 preferred_element_type=jnp.float32)
-        s = _mask_tile(s, qi, kj, causal, kv_len)
-        m_prev, l_prev = m_scr[:], l_scr[:]
+        s = s * sm_scale                                 # [BQ, BK] float32
+        if mask:
+            s = _mask_tile(s, qi * block_q, kj * block_k, causal, kv_len)
+        m_prev = m_scr[:]                                # [BQ, 128]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
+        p = jnp.exp(s - _lanes(m_new, block_k))
         alpha = jnp.exp(m_prev - m_new)
         m_scr[:] = m_new
-        l_scr[:] = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc_scr[:] = acc_scr[:] * alpha + jnp.dot(
-            p, v, preferred_element_type=jnp.float32)
+        l_scr[:] = l_scr[:] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        acc_scr[:] = acc_scr[:] * _lanes(alpha, d) + jnp.dot(
+            p.astype(v.dtype), v, preferred_element_type=jnp.float32)
 
-    if causal:
-        # Upper-triangle blocks contribute nothing — skip their DMA+FLOPs.
-        pl.when(kj <= qi)(_step)
-    else:
-        # Trivially-true predicate, NOT a bare _step() call: interpret
-        # mode's vma tracing (CPU-mesh shard_map) only standardizes the
-        # block-fetch slice's varying axes along the pl.when path — an
-        # unguarded body trips "dynamic_slice requires varying manual
-        # axes to match". Compiled Mosaic folds the constant predicate.
-        pl.when(kj >= 0)(_step)
+    pl.when(_kept(qi, kj, block_q, block_k, causal))(_step)
 
     @pl.when(kj == n_k_blocks - 1)
     def _finish():
         l = l_scr[:]
         safe_l = jnp.where(l == 0, 1.0, l)
-        o_ref[0] = (acc_scr[:] / safe_l).astype(o_ref.dtype)
+        o_ref[0] = (acc_scr[:] / _lanes(safe_l, d)).astype(o_ref.dtype)
         # Lane-replicated [BQ, 128]: Mosaic requires output block shapes
         # whose last two dims are (8, 128)-tileable — a [BQ]-vector block
         # is rejected on a real chip (interpret mode hid this). Same
         # layout as jax's bundled TPU flash kernel's l/m stats
         # (pallas/ops/tpu/flash_attention.py, MIN_BLOCK_SIZE lanes).
-        lse_ref[0] = jnp.broadcast_to(m_scr[:] + jnp.log(safe_l),
-                                      (m_scr.shape[0], _LANES))
+        lse_ref[0] = m_scr[:] + jnp.log(safe_l)
 
 
 def _dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                  dk_ref, dv_ref, dk_scr, dv_scr, *, sm_scale: float,
-                 n_q_blocks: int, causal: bool, kv_len: int):
+                 block_q: int, block_k: int, n_q_blocks: int, n_steps: int,
+                 causal: bool, kv_len: int, mask: bool):
     kj = pl.program_id(1)
-    qi = pl.program_id(2)
+    step = pl.program_id(2)        # (query head of the group, q block)
+    qi = _rem(step, n_q_blocks)
 
-    @pl.when(qi == 0)
+    @pl.when(step == 0)
     def _init():
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
     def _step():
-        k = k_ref[0].astype(jnp.float32)                 # [BK, D]
-        v = v_ref[0].astype(jnp.float32)
-        q = q_ref[0].astype(jnp.float32) * sm_scale      # [BQ, D]
-        do = do_ref[0].astype(jnp.float32)
-        lse = lse_ref[0][:, :1]    # lane-replicated stats: any lane works
-        delta = delta_ref[0][:, :1]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        s = _mask_tile(s, qi, kj, causal, kv_len)
-        p = jnp.exp(s - lse)                             # [BQ, BK]
-        # dv += p^T @ dO
-        dv_scr[:] = dv_scr[:] + jax.lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
+        q = q_ref[0]                                     # [BQ, D]
+        do = do_ref[0]
+        # The tile transposed, [BK, BQ]: dV and dK are then plain products,
+        # and the row statistics broadcast down from [1, BQ] rows.
+        st = jax.lax.dot_general(k_ref[0], q, _NT,
                                  preferred_element_type=jnp.float32)
-        ds = p * (dp - delta)
-        # dk += ds^T @ q  (q already carries sm_scale)
-        dk_scr[:] = dk_scr[:] + jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        st = st * sm_scale
+        if mask:
+            st = _mask_tile(st, qi * block_q, kj * block_k, causal, kv_len,
+                            transposed=True)
+        pt = jnp.exp(st - lse_ref[0])
+        dv_scr[:] = dv_scr[:] + jnp.dot(
+            pt.astype(do.dtype), do, preferred_element_type=jnp.float32)
+        dpt = jax.lax.dot_general(v_ref[0], do, _NT,
+                                  preferred_element_type=jnp.float32)
+        dst = pt * (dpt - delta_ref[0])
+        dk_scr[:] = dk_scr[:] + jnp.dot(
+            dst.astype(q.dtype), q, preferred_element_type=jnp.float32)
 
-    if causal:
-        # Earlier query blocks never see these keys — skip them.
-        pl.when(qi >= kj)(_step)
-    else:
-        pl.when(qi >= 0)(_step)  # trivially true; see _fwd_kernel note
+    pl.when(_kept(qi, kj, block_q, block_k, causal))(_step)
 
-    @pl.when(qi == n_q_blocks - 1)
+    @pl.when(step == n_steps - 1)
     def _finish():
-        dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
+        dk_ref[0] = (dk_scr[:] * sm_scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
 
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-               dq_scr, *, sm_scale: float, n_k_blocks: int, causal: bool,
-               kv_len: int):
+               dq_scr, *, sm_scale: float, block_q: int, block_k: int,
+               n_k_blocks: int, causal: bool, kv_len: int, mask: bool):
     qi = pl.program_id(1)
     kj = pl.program_id(2)
 
@@ -206,61 +325,93 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
         dq_scr[:] = jnp.zeros_like(dq_scr)
 
     def _step():
-        q = q_ref[0].astype(jnp.float32) * sm_scale
-        do = do_ref[0].astype(jnp.float32)
-        lse = lse_ref[0][:, :1]
-        delta = delta_ref[0][:, :1]
-        k = k_ref[0].astype(jnp.float32)
-        v = v_ref[0].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+        k = k_ref[0]
+        s = jax.lax.dot_general(q_ref[0], k, _NT,
                                 preferred_element_type=jnp.float32)
-        s = _mask_tile(s, qi, kj, causal, kv_len)
-        p = jnp.exp(s - lse)
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
+        s = s * sm_scale
+        if mask:
+            s = _mask_tile(s, qi * block_q, kj * block_k, causal, kv_len)
+        p = jnp.exp(s - _lanes(lse_ref[0], block_k))
+        dp = jax.lax.dot_general(do_ref[0], v_ref[0], _NT,
                                  preferred_element_type=jnp.float32)
-        ds = p * (dp - delta)
+        ds = p * (dp - _lanes(delta_ref[0], block_k))
         dq_scr[:] = dq_scr[:] + jnp.dot(
-            ds, k, preferred_element_type=jnp.float32)
+            ds.astype(k.dtype), k, preferred_element_type=jnp.float32)
 
-    if causal:
-        pl.when(kj <= qi)(_step)
-    else:
-        pl.when(kj >= 0)(_step)  # trivially true; see _fwd_kernel note
+    pl.when(_kept(qi, kj, block_q, block_k, causal))(_step)
 
     @pl.when(kj == n_k_blocks - 1)
     def _finish():
         dq_ref[0] = (dq_scr[:] * sm_scale).astype(dq_ref.dtype)
 
 
-def _pad_seq(x, block):
-    s = x.shape[1]
-    pad = (-s) % block
+def _pad_seq(x):
+    pad = (-x.shape[1]) % _PAD
     if pad:
         x = jnp.pad(x, ((0, 0), (0, pad), (0, 0)))
     return x
 
 
-def _fwd_call(q, k, v, sm_scale, causal, kv_len, interpret):
-    """q/k/v: [BH, S, D] (S already padded; ``kv_len`` is the real key
-    count before padding). Returns (o, lse)."""
+def _blocks_for(kernel, q, k, causal, forced):
+    """The call's tile, forced or from the table; and, trace time only, the
+    record of it behind ``hvd.metrics()``."""
+    from .. import runtime
+    bq, bk = forced or block_sizes(kernel, q.shape[1], q.shape[2], q.dtype,
+                                   causal)
+    recorder = runtime.recorder()
+    if recorder is not None:
+        recorder.note_flash_kernel(kernel, bq, bk, jnp.dtype(q.dtype).name,
+                                   q.shape[0] // k.shape[0])
+    return bq, bk
+
+
+def _last_kept_k(i, block_q: int, block_k: int):
+    """Causal: the last k block query block ``i`` attends."""
+    return _div((i + 1) * block_q - 1, block_k)
+
+
+def _first_kept_q(j, block_q: int, block_k: int):
+    """Causal: the first q block that attends k block ``j``."""
+    return _div(j * block_k, block_q)
+
+
+def _kv_map(group: int, block_q: int, block_k: int, causal: bool):
+    """Index map of K and V on a ``(bh, q_block, k_block)`` grid: the K/V
+    head of query head ``b``; a skipped causal step re-names the last kept
+    block, so the pipeline issues no DMA for it."""
+    def kv_map(b, i, j):
+        if causal:
+            j = jnp.minimum(j, _last_kept_k(i, block_q, block_k))
+        return _div(b, group), j, 0
+    return kv_map
+
+
+def _fwd_call(q, k, v, sm_scale, causal, kv_len, forced=None):
+    """q: [B*H, S, D], k/v: [B*Hkv, S, D] (S already padded; ``kv_len`` is
+    the real key count before padding). Returns (o, lse), lse
+    lane-replicated [B*H, S, 128]."""
     bh, s, d = q.shape
-    n_q = s // BLOCK_Q
-    n_k = s // BLOCK_K
-    kernel = functools.partial(_fwd_kernel, sm_scale=sm_scale,
-                               n_k_blocks=n_k, causal=causal,
-                               kv_len=kv_len)
+    group = bh // k.shape[0]
+    bq, bk = _blocks_for(KERNEL_FWD, q, k, causal, forced)
+    n_q, n_k = s // bq, s // bk
+
+    kv_map = _kv_map(group, bq, bk, causal)
+    kernel = functools.partial(_fwd_kernel, sm_scale=sm_scale, block_q=bq,
+                               block_k=bk, n_k_blocks=n_k, causal=causal,
+                               kv_len=kv_len,
+                               mask=_needs_mask(causal, kv_len, s))
     return pl.pallas_call(
         kernel,
         grid=(bh, n_q, n_k),
         in_specs=[
-            pl.BlockSpec((1, BLOCK_Q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, BLOCK_K, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, BLOCK_K, d), lambda b, i, j: (b, j, 0)),
+            pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, bk, d), kv_map),
+            pl.BlockSpec((1, bk, d), kv_map),
         ],
         out_specs=[
-            pl.BlockSpec((1, BLOCK_Q, d), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
             # lse rides lane-replicated [bh, s, 128] (see _fwd_kernel).
-            pl.BlockSpec((1, BLOCK_Q, _LANES), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, bq, _LANES), lambda b, i, j: (b, i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bh, s, d), q.dtype,
@@ -269,126 +420,176 @@ def _fwd_call(q, k, v, sm_scale, causal, kv_len, interpret):
                                  vma=_out_vma(q, k, v)),
         ],
         scratch_shapes=[
-            pltpu.VMEM((BLOCK_Q, 1), jnp.float32),   # running max
-            pltpu.VMEM((BLOCK_Q, 1), jnp.float32),   # running denominator
-            pltpu.VMEM((BLOCK_Q, d), jnp.float32),   # output accumulator
+            pltpu.VMEM((bq, _LANES), jnp.float32),   # running max
+            pltpu.VMEM((bq, _LANES), jnp.float32),   # running denominator
+            pltpu.VMEM((bq, d), jnp.float32),        # output accumulator
         ],
-        interpret=interpret,
+        compiler_params=_compiler_params(),
+        interpret=_use_interpret(),
         name=KERNEL_FWD,
     )(q, k, v)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def _flash_bhsd(q, k, v, sm_scale, causal, kv_len):
-    o, _ = _fwd_call(q, k, v, sm_scale, causal, kv_len, _use_interpret())
-    return o
-
-
-def _flash_bhsd_fwd(q, k, v, sm_scale, causal, kv_len):
-    o, lse = _fwd_call(q, k, v, sm_scale, causal, kv_len, _use_interpret())
-    # Residual carries ONE lane of the lane-replicated stats: holding the
-    # [bh, s, 128] form across the whole fwd->bwd interval would cost 128x
-    # the logical bytes per layer; the backward re-broadcasts transiently.
-    return o, (q, k, v, o, lse[..., :1])
-
-
-def _flash_bhsd_bwd(sm_scale, causal, kv_len, res, do):
-    q, k, v, o, lse = res
-    interpret = _use_interpret()
+def _dkdv_call(q, k, v, do, lse, delta, sm_scale, causal, kv_len,
+               forced=None):
+    """dK, dV at the K/V head count. ``lse``/``delta``: [B*H, 1, S] rows."""
     bh, s, d = q.shape
-    n_q = s // BLOCK_Q
-    n_k = s // BLOCK_K
-    # delta_i = rowsum(dO_i * O_i) — tiny elementwise pass, XLA fuses it.
-    # Both stats enter the kernels lane-replicated [bh, s, 128] (Mosaic
-    # rejects vector blocks whose sublane dim is 1 — see _fwd_kernel) but
-    # only transiently for the backward: the residual holds one lane.
-    lse = jnp.broadcast_to(lse, (bh, s, _LANES))
-    delta = jnp.broadcast_to(
-        jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
-                axis=-1, keepdims=True), (bh, s, _LANES))
+    bkv = k.shape[0]
+    group = bh // bkv
+    bq, bk = _blocks_for(KERNEL_DKDV, q, k, causal, forced)
+    n_q, n_k = s // bq, s // bk
 
-    dkdv = functools.partial(_dkdv_kernel, sm_scale=sm_scale,
-                             n_q_blocks=n_q, causal=causal, kv_len=kv_len)
-    dk, dv = pl.pallas_call(
-        dkdv,
-        grid=(bh, n_k, n_q),
+    def q_block(b, j, t):
+        # Step t of a k block: query head t // n_q of the group, q block
+        # t % n_q; a skipped (earlier) q block re-names the first kept one.
+        i = _rem(t, n_q)
+        if causal:
+            i = jnp.maximum(i, _first_kept_q(j, bq, bk))
+        return b * group + _div(t, n_q), i
+
+    def q_map(b, j, t):
+        return (*q_block(b, j, t), 0)
+
+    def row_map(b, j, t):
+        head, i = q_block(b, j, t)
+        return head, 0, i
+
+    kernel = functools.partial(_dkdv_kernel, sm_scale=sm_scale, block_q=bq,
+                               block_k=bk, n_q_blocks=n_q,
+                               n_steps=group * n_q, causal=causal,
+                               kv_len=kv_len,
+                               mask=_needs_mask(causal, kv_len, s))
+    vma = _out_vma(q, k, v, do)
+    return pl.pallas_call(
+        kernel,
+        grid=(bkv, n_k, group * n_q),
         in_specs=[
-            pl.BlockSpec((1, BLOCK_Q, d), lambda b, j, i: (b, i, 0)),  # q
-            pl.BlockSpec((1, BLOCK_K, d), lambda b, j, i: (b, j, 0)),  # k
-            pl.BlockSpec((1, BLOCK_K, d), lambda b, j, i: (b, j, 0)),  # v
-            pl.BlockSpec((1, BLOCK_Q, d), lambda b, j, i: (b, i, 0)),  # do
-            pl.BlockSpec((1, BLOCK_Q, _LANES),
-                         lambda b, j, i: (b, i, 0)),                   # lse
-            pl.BlockSpec((1, BLOCK_Q, _LANES),
-                         lambda b, j, i: (b, i, 0)),                   # delta
+            pl.BlockSpec((1, bq, d), q_map),                           # q
+            pl.BlockSpec((1, bk, d), lambda b, j, t: (b, j, 0)),       # k
+            pl.BlockSpec((1, bk, d), lambda b, j, t: (b, j, 0)),       # v
+            pl.BlockSpec((1, bq, d), q_map),                           # do
+            pl.BlockSpec((1, 1, bq), row_map),                         # lse
+            pl.BlockSpec((1, 1, bq), row_map),                         # delta
         ],
         out_specs=[
-            pl.BlockSpec((1, BLOCK_K, d), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, BLOCK_K, d), lambda b, j, i: (b, j, 0)),
+            pl.BlockSpec((1, bk, d), lambda b, j, t: (b, j, 0)),
+            pl.BlockSpec((1, bk, d), lambda b, j, t: (b, j, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, s, d), q.dtype,
-                                 vma=_out_vma(q, k, v, do)),
-            jax.ShapeDtypeStruct((bh, s, d), q.dtype,
-                                 vma=_out_vma(q, k, v, do)),
+            jax.ShapeDtypeStruct((bkv, s, d), k.dtype, vma=vma),
+            jax.ShapeDtypeStruct((bkv, s, d), v.dtype, vma=vma),
         ],
         scratch_shapes=[
-            pltpu.VMEM((BLOCK_K, d), jnp.float32),
-            pltpu.VMEM((BLOCK_K, d), jnp.float32),
+            pltpu.VMEM((bk, d), jnp.float32),
+            pltpu.VMEM((bk, d), jnp.float32),
         ],
-        interpret=interpret,
+        compiler_params=_compiler_params(),
+        interpret=_use_interpret(),
         name=KERNEL_DKDV,
     )(q, k, v, do, lse, delta)
 
-    dqk = functools.partial(_dq_kernel, sm_scale=sm_scale, n_k_blocks=n_k,
-                            causal=causal, kv_len=kv_len)
-    dq = pl.pallas_call(
-        dqk,
+
+def _dq_call(q, k, v, do, lse, delta, sm_scale, causal, kv_len, forced=None):
+    """dQ. ``lse``/``delta``: lane-replicated [B*H, S, 128]."""
+    bh, s, d = q.shape
+    group = bh // k.shape[0]
+    bq, bk = _blocks_for(KERNEL_DQ, q, k, causal, forced)
+    n_q, n_k = s // bq, s // bk
+
+    kv_map = _kv_map(group, bq, bk, causal)
+
+    def q_map(b, i, j):
+        return b, i, 0
+
+    kernel = functools.partial(_dq_kernel, sm_scale=sm_scale, block_q=bq,
+                               block_k=bk, n_k_blocks=n_k, causal=causal,
+                               kv_len=kv_len,
+                               mask=_needs_mask(causal, kv_len, s))
+    return pl.pallas_call(
+        kernel,
         grid=(bh, n_q, n_k),
         in_specs=[
-            pl.BlockSpec((1, BLOCK_Q, d), lambda b, i, j: (b, i, 0)),  # q
-            pl.BlockSpec((1, BLOCK_K, d), lambda b, i, j: (b, j, 0)),  # k
-            pl.BlockSpec((1, BLOCK_K, d), lambda b, i, j: (b, j, 0)),  # v
-            pl.BlockSpec((1, BLOCK_Q, d), lambda b, i, j: (b, i, 0)),  # do
-            pl.BlockSpec((1, BLOCK_Q, _LANES),
-                         lambda b, i, j: (b, i, 0)),                   # lse
-            pl.BlockSpec((1, BLOCK_Q, _LANES),
-                         lambda b, i, j: (b, i, 0)),                   # delta
+            pl.BlockSpec((1, bq, d), q_map),                           # q
+            pl.BlockSpec((1, bk, d), kv_map),                          # k
+            pl.BlockSpec((1, bk, d), kv_map),                          # v
+            pl.BlockSpec((1, bq, d), q_map),                           # do
+            pl.BlockSpec((1, bq, _LANES), q_map),                      # lse
+            pl.BlockSpec((1, bq, _LANES), q_map),                      # delta
         ],
-        out_specs=pl.BlockSpec((1, BLOCK_Q, d), lambda b, i, j: (b, i, 0)),
+        out_specs=pl.BlockSpec((1, bq, d), q_map),
         out_shape=jax.ShapeDtypeStruct((bh, s, d), q.dtype,
                                        vma=_out_vma(q, k, v, do)),
-        scratch_shapes=[pltpu.VMEM((BLOCK_Q, d), jnp.float32)],
-        interpret=interpret,
+        scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
+        compiler_params=_compiler_params(),
+        interpret=_use_interpret(),
         name=KERNEL_DQ,
     )(q, k, v, do, lse, delta)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _flash_bhsd(q, k, v, sm_scale, causal, kv_len, forced):
+    o, _ = _fwd_call(q, k, v, sm_scale, causal, kv_len, forced)
+    return o
+
+
+def _flash_bhsd_fwd(q, k, v, sm_scale, causal, kv_len, forced):
+    o, lse = _fwd_call(q, k, v, sm_scale, causal, kv_len, forced)
+    # Residual carries ONE lane of the lane-replicated stats: holding the
+    # [bh, s, 128] form across the whole fwd->bwd interval would cost 128x
+    # the logical bytes per layer; the backward re-broadcasts transiently.
+    return o, (q, k, v, o, lse[..., 0])
+
+
+def _flash_bhsd_bwd(sm_scale, causal, kv_len, forced, res, do):
+    q, k, v, o, lse = res
+    bh, s, _ = q.shape
+    # delta_i = rowsum(dO_i * O_i) — tiny elementwise pass, XLA fuses it.
+    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
+    # The statistics enter dKdV as [bh, 1, s] rows (its tile is transposed)
+    # and dQ lane-replicated [bh, s, 128] (Mosaic rejects vector blocks
+    # whose sublane dim is 1 — see _fwd_kernel), transiently: the residual
+    # holds one float a row.
+    dk, dv = _dkdv_call(q, k, v, do, lse[:, None, :], delta[:, None, :],
+                        sm_scale, causal, kv_len, forced)
+    dq = _dq_call(q, k, v, do,
+                  jnp.broadcast_to(lse[..., None], (bh, s, _LANES)),
+                  jnp.broadcast_to(delta[..., None], (bh, s, _LANES)),
+                  sm_scale, causal, kv_len, forced)
     return dq, dk, dv
 
 
 _flash_bhsd.defvjp(_flash_bhsd_fwd, _flash_bhsd_bwd)
 
 
-def flash_attention(q, k, v, causal: bool = True):
+def flash_attention(q, k, v, causal: bool = True, *, _blocks=None):
     """Fused attention. q: ``[B, S, H, D]`` (the layout the GPT blocks
     use); k/v: ``[B, S, Hkv, D]`` where ``Hkv`` may divide ``H``
-    (grouped-query attention — kv heads tile up locally, mirroring ring
-    attention's contract). Differentiable (custom VJP, flash backward).
+    (grouped-query attention: the kernels read K/V head ``h // group`` for
+    query head ``h``, nothing is repeated, and dK/dV come back at ``Hkv``
+    heads). Differentiable (custom VJP, flash backward).
 
-    ``causal=True`` (decoder) skips the upper-triangle blocks entirely;
+    ``causal=True`` (decoder) skips the tiles above the diagonal;
     ``causal=False`` (encoder/bidirectional) computes all blocks with the
-    tail padding masked out of the key axis.
+    tail padding masked out of the key axis. Tile sizes and the MXU
+    operands' dtype follow the call's shapes and dtype (``block_sizes``);
+    ``_blocks=(block_q, block_k)`` forces one tile on all three kernels,
+    for the tests and the sweep.
     """
-    # GQA: repeat before the kernel (no-op when heads match; also
-    # validates BOTH k and v against the query head count).
-    k = repeat_kv_heads(k, q.shape[2])
-    v = repeat_kv_heads(v, q.shape[2])
     b, s, h, d = q.shape
+    if k.shape[2] != v.shape[2] or h % k.shape[2]:
+        raise ValueError(f"query heads ({h}) not a multiple of kv heads "
+                         f"(k {k.shape[2]}, v {v.shape[2]})")
+    if _blocks is not None:
+        _blocks = tuple(int(x) for x in _blocks)
+        s_pad = s + (-s) % _PAD
+        if any(x % _PAD or s_pad % x for x in _blocks):
+            raise ValueError(f"blocks {_blocks} must be multiples of {_PAD} "
+                             f"that divide the padded length {s_pad}")
     sm_scale = 1.0 / float(np.sqrt(d))
 
     def to_bhsd(x):
-        return _pad_seq(x.transpose(0, 2, 1, 3).reshape(b * h, s, d),
-                        BLOCK_Q)
+        return _pad_seq(x.transpose(0, 2, 1, 3).reshape(-1, s, d))
 
     o = _flash_bhsd(to_bhsd(q), to_bhsd(k), to_bhsd(v), sm_scale,
-                    bool(causal), s)
+                    bool(causal), s, _blocks)
     return o[:, :s, :].reshape(b, h, s, d).transpose(0, 2, 1, 3)

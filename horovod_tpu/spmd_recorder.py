@@ -82,6 +82,8 @@ class SpmdRecorder:
         self.init_done_process_s: Optional[float] = None
         self.placed_calls = 0
         self.placed_bytes = 0
+        # (kernel, block_q, block_k, operand_dtype, kv_group) -> traces
+        self.flash_kernels: collections.Counter = collections.Counter()
         # function -> the argument signatures run_step has traced it with
         self._signatures: dict = {}
         self._cause: dict = {}          # function -> cause of the next compile
@@ -147,6 +149,14 @@ class SpmdRecorder:
                 else "new shardings" if signature in seen else "new shapes")
             seen.add(signature)
 
+    def note_flash_kernel(self, kernel: str, block_q: int, block_k: int,
+                          operand_dtype: str, kv_group: int) -> None:
+        """``ops/flash_attention.py`` calls this while JAX traces one of its
+        ``pallas_call``s: which tiling the call got."""
+        with self._lock:
+            self.flash_kernels[(kernel, block_q, block_k, operand_dtype,
+                                kv_group)] += 1
+
     def note_placed(self, nbytes: int) -> None:
         self.placed_calls += 1
         self.placed_bytes += nbytes
@@ -162,6 +172,7 @@ class SpmdRecorder:
         with self._lock:
             compiles = sorted(self.compiles.items())
             hits, misses = self.cache_hits, self.cache_misses
+            flash = sorted(self.flash_kernels.items())
         counts, seconds = [], []
         for (function, stage), (count, secs) in compiles:
             labels = {"function": function, "stage": stage}
@@ -187,6 +198,14 @@ class SpmdRecorder:
                 "made it before), mesh, compile_cache.",
                 [("", {"phase": p}, (b - a) * 1e-9)
                  for p, a, b in self.init_phases]),
+            "hvdtpu_spmd_flash_kernel_traces_total": family(
+                "counter", "Times JAX traced a flash attention kernel, by "
+                "kernel and the tiling the call got: block sizes, the MXU "
+                "operands' dtype, query heads per K/V head.",
+                [("", {"kernel": kernel, "block_q": str(bq),
+                       "block_k": str(bk), "operand_dtype": dtype,
+                       "kv_group": str(group)}, float(count))
+                 for (kernel, bq, bk, dtype, group), count in flash]),
             "hvdtpu_spmd_shard_batch_calls_total": family(
                 "counter", "Calls of hvd.shard_batch.",
                 [("", {}, float(self.placed_calls))]),

@@ -1,0 +1,225 @@
+#!/usr/bin/env python3
+"""Time the three flash kernels alone on the chip, by tile.
+
+The source of ``ops/flash_attention.py``'s block table (PERF.md, Findings,
+PR 24): each kernel jitted alone at a benchmark cell's shape, ``B*H,S,D`` =
+48x4096x128 and 384x512x128 with K/V at 2 of 24 heads, over forced tiles,
+operand dtypes and K/V head counts, timed on the host clock around
+``block_until_ready`` (ms a call; a call is milliseconds, its dispatch tens
+of microseconds). Also holds the compiled kernels to dense attention once.
+
+    chiprun --chips 1 -- python scripts/flash_block_sweep.py [--quick]
+
+One JSON object a line on stdout and in ``chiprun_out/flash_sweep.jsonl``.
+Works on a tree that still has the fixed-tile kernels (``--parent``): there
+only the forward and the whole backward can be timed, at 128x128.
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import os
+import sys
+import time
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from horovod_tpu.ops import flash_attention as fa
+
+# The two cells, and two lengths between them at the same 8192 tokens.
+SHAPES = {"s4096": (2, 4096, 24, 2, 128), "s512": (16, 512, 24, 2, 128),
+          "s2048": (4, 2048, 24, 2, 128), "s1024": (8, 1024, 24, 2, 128)}
+CELLS = ("s4096", "s512")
+TILES = ((128, 128), (256, 256), (256, 512), (512, 256), (512, 512),
+         (512, 1024), (1024, 512), (1024, 1024), (1024, 2048), (2048, 1024),
+         (512, 2048), (2048, 512))
+OUT = os.path.join("chiprun_out", "flash_sweep.jsonl")
+KERNELS = {"fwd": fa.KERNEL_FWD, "dkdv": fa.KERNEL_DKDV, "dq": fa.KERNEL_DQ}
+
+
+def emit(**row):
+    line = json.dumps(row)
+    print(line, flush=True)
+    os.makedirs(os.path.dirname(OUT), exist_ok=True)
+    with open(OUT, "a") as f:
+        f.write(line + "\n")
+
+
+def timed(fn, *args, iters: int = 10) -> float:
+    """Median of three windows of ``iters`` calls, ms a call."""
+    fn = jax.jit(fn)
+    jax.block_until_ready(fn(*args))
+    windows = []
+    for _ in range(3):
+        t0 = time.perf_counter()
+        for _ in range(iters):
+            out = fn(*args)
+        jax.block_until_ready(out)
+        windows.append((time.perf_counter() - t0) / iters * 1e3)
+    return sorted(windows)[1]
+
+
+def operands(shape, dtype, group_in_hbm: bool, seed: int = 0):
+    """q, k, v, dO as the kernels take them, ``[B*H, S, D]``; K/V at their
+    own head count, or repeated to the query's where ``group_in_hbm``."""
+    b, s, h, hkv, d = shape
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+    hk = h if group_in_hbm else hkv
+    q, do = (jax.random.normal(k, (b * h, s, d), dtype) * 0.5
+             for k in ks[:2])
+    k, v = (jax.random.normal(k, (b * hk, s, d), dtype) * 0.5
+            for k in ks[2:])
+    return q, k, v, do
+
+
+def time_parent(name, shape, dtype):
+    b, s, h, hkv, d = shape
+    q, k, v, do = operands(shape, dtype, True)
+    sc = 1.0 / d ** 0.5
+    fwd = lambda q, k, v: fa._fwd_call(q, k, v, sc, True, s,
+                                        fa._use_interpret())
+    o, lse = jax.jit(fwd)(q, k, v)
+    res = (q, k, v, o, lse[..., :1])
+    bwd = lambda res, do: fa._flash_bhsd_bwd(sc, True, s, res, do)
+    emit(tree="parent", shape=name, dtype=jnp.dtype(dtype).name,
+         fwd_ms=timed(fwd, q, k, v), bwd_ms=timed(bwd, res, do))
+
+
+def time_variant(label, name, shape, dtype, tiles, group_in_hbm, kernels):
+    b, s, h, hkv, d = shape
+    q, k, v, do = operands(shape, dtype, group_in_hbm)
+    sc = 1.0 / d ** 0.5
+    o, lse = jax.jit(lambda q, k, v: fa._fwd_call(
+        q, k, v, sc, True, s, None))(q, k, v)
+    lse = lse[..., 0]
+    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), -1)
+    rows = (lse[:, None, :], delta[:, None, :])
+    cols = tuple(jnp.broadcast_to(x[..., None], (*x.shape, 128))
+                 for x in (lse, delta))
+    row = dict(tree="change", variant=label, shape=name,
+               dtype=jnp.dtype(dtype).name, kv_repeated=group_in_hbm)
+    for kernel in kernels:
+        for t in tiles:
+            if t is not None and (s % t[0] or s % t[1]):
+                continue
+            if kernel == "fwd":
+                f = lambda q, k, v: fa._fwd_call(q, k, v, sc, True, s, t)
+                args = (q, k, v)
+            elif kernel == "dkdv":
+                f = lambda *a: fa._dkdv_call(*a, sc, True, s, t)
+                args = (q, k, v, do, *rows)
+            else:
+                f = lambda *a: fa._dq_call(*a, sc, True, s, t)
+                args = (q, k, v, do, *cols)
+            try:
+                ms = timed(f, *args)
+            except Exception as e:  # a tile Mosaic refuses is a result too
+                emit(**row, kernel=kernel, tile=t, error=str(e)[:300])
+                continue
+            chosen = t or fa.block_sizes(KERNELS[kernel], s, d, dtype, True)
+            emit(**row, kernel=kernel, tile=list(chosen),
+                 table=t is None, ms=ms)
+
+
+DENSE_CASES = (
+    (jnp.float32, 1024, True, None), (jnp.float32, 1024, True, (256, 512)),
+    (jnp.float32, 1024, True, (512, 256)), (jnp.float32, 600, False, None),
+    (jnp.float32, 600, True, None), (jnp.bfloat16, 4096, True, None),
+    (jnp.bfloat16, 512, True, None), (jnp.bfloat16, 1024, False, (512, 256)))
+
+
+def check_against_dense(cases=DENSE_CASES):
+    """The compiled kernels against dense attention: GQA, causal and not,
+    an unaligned length, tiles the table gives and unequal forced ones."""
+    from horovod_tpu.models.transformer import default_attention
+    for dtype, s, causal, blocks in cases:
+        ks = jax.random.split(jax.random.PRNGKey(s), 4)
+        q = jax.random.normal(ks[0], (1, s, 4, 128), dtype) * 0.5
+        k = jax.random.normal(ks[1], (1, s, 2, 128), dtype) * 0.5
+        v = jax.random.normal(ks[2], (1, s, 2, 128), dtype) * 0.5
+        w = jax.random.normal(ks[3], q.shape, jnp.float32)
+
+        def loss(fn, q, k, v):
+            return jnp.sum(fn(q, k, v).astype(jnp.float32) * w)
+
+        flash = lambda q, k, v: fa.flash_attention(
+            q, k, v, causal=causal, _blocks=blocks)
+        # The reference in float32 at the highest precision (XLA's default
+        # on the chip is one bfloat16 pass, coarser than the kernels).
+        def dense(q, k, v):
+            q, k, v = (x.astype(jnp.float32) for x in (q, k, v))
+            with jax.default_matmul_precision("highest"):
+                return default_attention(
+                    q, fa.repeat_kv_heads(k, 4), fa.repeat_kv_heads(v, 4),
+                    causal=causal)
+        got = jax.jit(jax.value_and_grad(
+            lambda *a: loss(flash, *a), argnums=(0, 1, 2)))(q, k, v)
+        want = jax.jit(jax.value_and_grad(
+            lambda *a: loss(dense, *a), argnums=(0, 1, 2)))(q, k, v)
+        errs = [float(jnp.max(jnp.abs(a.astype(jnp.float32)
+                                      - b.astype(jnp.float32))))
+                for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want))]
+        scale = [float(jnp.max(jnp.abs(b.astype(jnp.float32))))
+                 for b in jax.tree.leaves(want)]
+        emit(check="dense", dtype=jnp.dtype(dtype).name, s=s, causal=causal,
+             blocks=blocks, max_abs_err=dict(zip(("loss", "dq", "dk", "dv"),
+                                                 errs)),
+             max_abs=dict(zip(("loss", "dq", "dk", "dv"), scale)))
+
+
+def main():
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--parent", action="store_true",
+                    help="the tree has the fixed-tile kernels")
+    ap.add_argument("--quick", action="store_true",
+                    help="the table's choice only, no sweep")
+    args = ap.parse_args()
+    dev = jax.devices()[0]
+    emit(platform=dev.platform, device_kind=dev.device_kind)
+    if dev.platform != "tpu":
+        sys.exit("flash_block_sweep.py times kernels on a TPU; found "
+                 f"{dev.platform}")
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    all_kernels = tuple(KERNELS)
+    if args.parent:
+        for name, shape in SHAPES.items():
+            time_parent(name, shape, bf16)
+        return
+    check_against_dense()
+    for name, shape in SHAPES.items():
+        time_variant("table", name, shape, bf16, (None,), False, all_kernels)
+        if args.quick:
+            continue
+        time_variant("sweep", name, shape, bf16, TILES, False, all_kernels)
+        if name not in CELLS:
+            continue
+        best = tuple(fa.block_sizes(k, shape[1], 128, bf16, True)
+                     for k in KERNELS.values())
+        # The parts alone, from the old tile: float32 operands, 128x128,
+        # K/V repeated in HBM; then each part by itself.
+        small = ((128, 128),)
+        time_variant("bookkeeping_only", name, shape, f32, small, True,
+                     all_kernels)
+        time_variant("operands_alone", name, shape, bf16, small, True,
+                     all_kernels)
+        time_variant("gqa_alone", name, shape, f32, small, False,
+                     all_kernels)
+        for kernel, tile in zip(all_kernels, best):
+            time_variant("blocks_alone", name, shape, f32, (tile,), True,
+                         (kernel,))
+        # What the clamped index maps are worth, at the table's tiles.
+        keep = fa._last_kept_k, fa._first_kept_q
+        fa._last_kept_k = lambda i, bq, bk: 1 << 30
+        fa._first_kept_q = lambda j, bq, bk: 0
+        time_variant("no_clamp", name, shape, bf16, (None,), False,
+                     all_kernels)
+        fa._last_kept_k, fa._first_kept_q = keep
+
+
+if __name__ == "__main__":
+    main()

@@ -1,0 +1,86 @@
+"""The flash kernels through the real Mosaic compiler, without a chip.
+
+Interpret mode (every other flash test) says nothing about Mosaic lowering:
+block shapes, VMEM, layouts. The TPU compiler is installed in the sandbox and
+compiles for a chip that is described and not attached, so the kernels of the
+benchmark's two GPT cells (and the small shapes ``chip_smoke.py`` runs) are
+compiled here at their real sizes with the tiles the block table gives them.
+Nothing runs; a compile that passes is not a chip run.
+
+All such tests live in this one file: the worker that gets it loads libtpu
+and holds its lock until it exits.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from horovod_tpu.compression import quantize
+from horovod_tpu.ops import flash_attention as fa
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # A compile for a described chip is written to the persistent cache but
+    # cannot be read back without one; keep these out of it.
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture
+def mosaic(monkeypatch):
+    """Compile as a program on the chip does: through Mosaic, and without
+    the 64-bit types tests/conftest.py turns on (Mosaic has no float64, and
+    under them every Python constant in a kernel becomes one)."""
+    monkeypatch.setattr(quantize, "_pallas_backend_enabled", lambda *_: True)
+    with jax.enable_x64(False):
+        yield
+
+
+# (B*H, B*Hkv, S, D, dtype, causal): the two cells; chip_smoke's legs.
+SHAPES = {
+    "starcoder2-3b_s4096": (48, 4, 4096, 128, jnp.bfloat16, True),
+    "starcoder2-3b_s512": (384, 32, 512, 128, jnp.bfloat16, True),
+    "smoke_d64": (16, 16, 1024, 64, jnp.bfloat16, True),
+    "encoder_f32_s384": (8, 8, 384, 64, jnp.float32, False),
+}
+
+
+@pytest.mark.parametrize("kernel", ["fwd", "dkdv", "dq"])
+@pytest.mark.parametrize("shape", list(SHAPES))
+def test_kernel_compiles_for_v5e(one_chip, mosaic, shape, kernel):
+    bh, bkv, s, d, dtype, causal = SHAPES[shape]
+
+    def sds(*dims, dt=dtype):
+        return jax.ShapeDtypeStruct(dims, dt, sharding=one_chip)
+
+    q, k = sds(bh, s, d), sds(bkv, s, d)
+    scale = d ** -0.5
+    if kernel == "fwd":
+        f = lambda q, k, v: fa._fwd_call(q, k, v, scale, causal, s)
+        args = (q, k, k)
+    elif kernel == "dkdv":
+        f = lambda *a: fa._dkdv_call(*a, scale, causal, s)
+        rows = sds(bh, 1, s, dt=jnp.float32)
+        args = (q, k, k, q, rows, rows)
+    else:
+        f = lambda *a: fa._dq_call(*a, scale, causal, s)
+        cols = sds(bh, s, 128, dt=jnp.float32)
+        args = (q, k, k, q, cols, cols)
+    text = jax.jit(f).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text and f"hvd_flash_{kernel}" in text
