@@ -14,13 +14,16 @@ math needs:
   shard_map + XLA collectives over ICI, not hand-written NCCL).
 * **sp** — activations are sequence-sharded; attention is ring attention
   (``ppermute`` ring) or Ulysses (all-to-all), per config.
-* **ep** — optional MoE blocks route tokens to experts over the ep axis
-  (:mod:`horovod_tpu.parallel.moe`).
+* **ep** — optional expert blocks (dropless top-k, SiLU-gated experts) hold
+  their experts over the ep axis (:mod:`horovod_tpu.parallel.moe`).
 * **dp** — gradient averaging comes from autodiff under shard_map(check_vma):
   dp-invariant params get their grad psum inserted automatically;
   ``DistributedOptimizer`` then only normalizes.
 
-bfloat16 activations / fp32 params+accumulators, RoPE, pre-norm RMSNorm.
+bfloat16 activations / fp32 params+accumulators, RoPE, pre-norm RMSNorm;
+optionally an RMSNorm on the whole query and key projections (``qk_norm``).
+With expert blocks the loss carries the router's two auxiliary terms
+(:func:`loss_and_aux`).
 """
 
 from __future__ import annotations
@@ -56,11 +59,20 @@ class GPTConfig:
     # (ulysses_flash = Ulysses head/sequence exchange with the fused Pallas
     # flash kernel as the per-device full-sequence attention)
     attention: str = "ring"
-    # MoE (active when moe_every > 0): every moe_every-th block is a switch
-    # layer with num_experts experts.
+    # Experts (active when moe_every > 0): every moe_every-th block's
+    # feed-forward is the dropless expert layer of ``parallel/moe.py``:
+    # num_experts SiLU-gated experts of width mlp_dim, experts_per_token of
+    # them a token. The loss adds the layers' summed load-balance and router
+    # z terms under these coefficients.
     moe_every: int = 0
     num_experts: int = 8
-    capacity_factor: float = 1.25
+    experts_per_token: int = 1
+    load_balance_coef: float = 0.0
+    router_z_coef: float = 0.0
+    # RMSNorm over the whole query and the whole key projection (all heads
+    # together), before the rotary embedding.
+    qk_norm: bool = False
+    norm_eps: float = 1e-6
     # Per-block rematerialization (jax.checkpoint) — the TPU lever trading
     # FLOPs for HBM so long sequences fit: "none" stores every block
     # activation; "full" stores only block inputs and recomputes the rest
@@ -112,10 +124,14 @@ def init_params(rng, cfg: GPTConfig) -> dict:
             "wo": dense(ks[3], (H, D, E), H * D),
             "mlp_norm": jnp.ones((E,), jnp.float32),
         }
+        if cfg.qk_norm:
+            layer["q_norm"] = jnp.ones((H, D), jnp.float32)
+            layer["k_norm"] = jnp.ones((Hkv, D), jnp.float32)
         if _is_moe(cfg, i):
             n_exp = cfg.num_experts
             layer["moe"] = {
-                "gate": dense(ks[4], (E, n_exp), E),
+                "router": dense(ks[4], (E, n_exp), E),
+                "w_gate": dense(ks[7], (n_exp, E, M), E),
                 "w_up": dense(ks[5], (n_exp, E, M), E),
                 "w_down": dense(ks[6], (n_exp, M, E), M),
             }
@@ -145,9 +161,13 @@ def param_specs(cfg: GPTConfig) -> dict:
             "wo": P(tp, None, None),
             "mlp_norm": P(),
         }
+        if cfg.qk_norm:
+            layer["q_norm"] = P(tp, None)
+            layer["k_norm"] = P(tp, None)
         if _is_moe(cfg, i):
             layer["moe"] = {
-                "gate": P(),
+                "router": P(),
+                "w_gate": P(ep, None, tp),
                 "w_up": P(ep, None, tp),
                 "w_down": P(ep, tp, None),
             }
@@ -158,10 +178,20 @@ def param_specs(cfg: GPTConfig) -> dict:
     return specs
 
 
-def _rmsnorm(x, w, dtype):
+def _rmsnorm(x, w, dtype, eps):
     x32 = x.astype(jnp.float32)
     var = jnp.mean(x32 * x32, axis=-1, keepdims=True)
-    return (x32 * lax.rsqrt(var + 1e-6) * w).astype(dtype)
+    return (x32 * lax.rsqrt(var + eps) * w).astype(dtype)
+
+
+def _projection_norm(x, w, cfg: GPTConfig):
+    """RMSNorm of ``[B, S, heads, D]`` over all heads together, the heads
+    possibly sharded over tp."""
+    x32 = x.astype(jnp.float32)
+    total = _tp_psum(jnp.sum(x32 * x32, axis=(-2, -1), keepdims=True), cfg)
+    width = x.shape[-2] * x.shape[-1] * _axis_size(cfg.tp_axis)
+    return (x32 * lax.rsqrt(total / width + cfg.norm_eps) * w).astype(
+        cfg.dtype)
 
 
 def _tp_psum(x, cfg: GPTConfig):
@@ -209,14 +239,19 @@ def _attention(cfg: GPTConfig, q, k, v):
 
 
 def _block(cfg: GPTConfig, layer_params, x, positions):
+    """One decoder block: ``(x, aux)``, ``aux`` the expert layer's auxiliary
+    terms (``parallel/moe.py``) or None for a dense block."""
     # The scopes sit inside the function ``jax.checkpoint`` wraps, so the
     # recomputed copy of a block carries them too (``forward`` has the rest).
     lp = layer_params
     with jax.named_scope("attn"):
-        h = _rmsnorm(x, lp["attn_norm"], cfg.dtype)
+        h = _rmsnorm(x, lp["attn_norm"], cfg.dtype, cfg.norm_eps)
         q = jnp.einsum("bse,ehd->bshd", h, lp["wq"].astype(cfg.dtype))
         k = jnp.einsum("bse,ehd->bshd", h, lp["wk"].astype(cfg.dtype))
         v = jnp.einsum("bse,ehd->bshd", h, lp["wv"].astype(cfg.dtype))
+        if cfg.qk_norm:
+            q = _projection_norm(q, lp["q_norm"], cfg)
+            k = _projection_norm(k, lp["k_norm"], cfg)
         q = rope(q, positions)
         k = rope(k, positions)
         attn = _attention(cfg, q, k, v)
@@ -225,19 +260,20 @@ def _block(cfg: GPTConfig, layer_params, x, positions):
 
     if "moe" in lp:
         with jax.named_scope("moe"):
-            h = _rmsnorm(x, lp["mlp_norm"], cfg.dtype)
-            from ..parallel.moe import switch_moe
-            out, _aux = switch_moe(
-                h, lp["moe"]["gate"], lp["moe"]["w_up"], lp["moe"]["w_down"],
-                axis=cfg.ep_axis, tp_axis=cfg.tp_axis,
-                capacity_factor=cfg.capacity_factor, dtype=cfg.dtype)
-            return x + out
+            h = _rmsnorm(x, lp["mlp_norm"], cfg.dtype, cfg.norm_eps)
+            from ..parallel.moe import moe_layer
+            m = lp["moe"]
+            out, aux = moe_layer(
+                h, m["router"], m["w_gate"], m["w_up"], m["w_down"],
+                top_k=cfg.experts_per_token, axis=cfg.ep_axis,
+                tp_axis=cfg.tp_axis, dtype=cfg.dtype)
+            return x + out, aux
     with jax.named_scope("mlp"):
-        h = _rmsnorm(x, lp["mlp_norm"], cfg.dtype)
+        h = _rmsnorm(x, lp["mlp_norm"], cfg.dtype, cfg.norm_eps)
         up = jnp.einsum("bse,em->bsm", h, lp["w_up"].astype(cfg.dtype))
         up = jax.nn.gelu(up)
         down = jnp.einsum("bsm,me->bse", up, lp["w_down"].astype(cfg.dtype))
-        return x + _tp_psum(down, cfg)
+        return x + _tp_psum(down, cfg), None
 
 
 def _block_fn(cfg: GPTConfig):
@@ -255,36 +291,58 @@ def _block_fn(cfg: GPTConfig):
                      "(expected 'none', 'full' or 'dots')")
 
 
-def forward(params, tokens, positions, cfg: GPTConfig):
-    """Logits ``[B, S_local, vocab]`` (fp32). ``tokens``/``positions`` are this
-    rank's sequence shard (global positions) when sp is active."""
+def _forward(params, tokens, positions, cfg: GPTConfig):
+    """``(logits, [aux of each expert block])``."""
     # Scopes name the program's parts in every instruction's ``op_name``:
     # ``embed``, ``layer<i>`` (with ``attn`` and ``mlp`` or ``moe`` inside,
-    # from ``_block``), ``head``; ``loss_fn`` adds ``loss``. A device trace
+    # from ``_block``; ``moe`` holds ``router``, ``dispatch``, ``experts``,
+    # ``combine``), ``head``; ``loss_and_aux`` adds ``loss``. A device trace
     # is read by them (PERF.md section 3).
     with jax.named_scope("embed"):
         x = params["embed"].astype(cfg.dtype)[tokens]
     block = _block_fn(cfg)
+    auxes = []
     for i, lp in enumerate(params["layers"]):
         with jax.named_scope(f"layer{i}"):
-            x = block(cfg, lp, x, positions)
+            x, aux = block(cfg, lp, x, positions)
+        if aux is not None:
+            auxes.append(aux)
     with jax.named_scope("head"):
-        x = _rmsnorm(x, params["out_norm"], cfg.dtype)
+        x = _rmsnorm(x, params["out_norm"], cfg.dtype, cfg.norm_eps)
         return jnp.einsum(
             "bse,ev->bsv", x,
-            params["lm_head"].astype(cfg.dtype)).astype(jnp.float32)
+            params["lm_head"].astype(cfg.dtype)).astype(jnp.float32), auxes
+
+
+def forward(params, tokens, positions, cfg: GPTConfig):
+    """Logits ``[B, S_local, vocab]`` (fp32). ``tokens``/``positions`` are this
+    rank's sequence shard (global positions) when sp is active."""
+    return _forward(params, tokens, positions, cfg)[0]
 
 
 def loss_fn(params, tokens, targets, positions, cfg: GPTConfig,
             ignore_index: int = -1):
-    """Mean next-token cross-entropy over all *global* target tokens.
+    """The training loss: :func:`loss_and_aux` without its parts."""
+    return loss_and_aux(params, tokens, targets, positions, cfg,
+                        ignore_index)[0]
+
+
+def loss_and_aux(params, tokens, targets, positions, cfg: GPTConfig,
+                 ignore_index: int = -1):
+    """``(loss, aux)``: mean next-token cross-entropy over all *global*
+    target tokens, plus, with expert blocks, ``load_balance_coef`` times
+    their summed load-balance terms and ``router_z_coef`` times their summed
+    router z terms (each a local-batch estimate, averaged over dp as the loss
+    is; ``jax.value_and_grad(..., has_aux=True)`` takes the pair).
 
     ``targets`` is sequence-sharded like ``tokens`` (shift done globally by the
     caller, so shard boundaries need no neighbor exchange); positions with
     ``ignore_index`` are masked out. Averages over sp so every rank returns the
-    identical global-mean loss.
+    identical global-mean loss. ``aux`` holds ``cross_entropy`` and, with
+    expert blocks, ``load_balance``, ``router_z`` (the sums over blocks) and
+    ``counts`` ``[blocks, experts]``, tokens per expert.
     """
-    logits = forward(params, tokens, positions, cfg)
+    logits, auxes = _forward(params, tokens, positions, cfg)
     with jax.named_scope("loss"):
         mask = (targets != ignore_index)
         safe_targets = jnp.where(mask, targets, 0)
@@ -302,7 +360,24 @@ def loss_fn(params, tokens, targets, positions, cfg: GPTConfig,
             if _axis_bound(ax):
                 num = lax.psum(num, ax)
                 den = lax.psum(den, ax)
-        return num / jnp.maximum(den, 1.0)
+        loss = num / jnp.maximum(den, 1.0)
+    if not auxes:
+        return loss, {"cross_entropy": loss}
+    with jax.named_scope("aux_loss"):
+        aux = {"cross_entropy": loss,
+               "load_balance": sum(a["load_balance"] for a in auxes),
+               "router_z": sum(a["router_z"] for a in auxes),
+               "counts": jnp.stack([a["counts"] for a in auxes])}
+        # An ep group routes its tokens together, so its ranks hold the same
+        # terms; sp ranks hold their own sequence shard's.
+        for ax, all_counts in ((cfg.sp_axis, lax.psum),
+                               (cfg.ep_axis, lax.pmax)):
+            if _axis_bound(ax):
+                aux["load_balance"] = lax.pmean(aux["load_balance"], ax)
+                aux["router_z"] = lax.pmean(aux["router_z"], ax)
+                aux["counts"] = all_counts(aux["counts"], ax)
+        return (loss + cfg.load_balance_coef * aux["load_balance"]
+                + cfg.router_z_coef * aux["router_z"]), aux
 
 
 def data_specs(cfg: GPTConfig) -> Tuple[P, P]:

@@ -1,20 +1,37 @@
-"""Expert parallelism: top-1 (switch) mixture-of-experts over a mesh axis.
+"""The expert layer: dropless top-k mixture of SiLU-gated experts.
 
 No reference analog — Horovod ships no expert parallelism; SURVEY.md §2.7 notes
 ``hvd.alltoall`` (``operations.cc:1055-1116``) is the enabling primitive users
-would build expert routing on. This module is that composition, TPU-native:
-capacity-bounded one-hot dispatch (static shapes, MXU-friendly einsums — the
-Mesh-TensorFlow/Switch pattern, *not* data-dependent gather loops), a tiled
-``lax.all_to_all`` to move token slots to their expert's owning device, local
-expert FFNs (optionally tensor-parallel on the hidden dim), and the reverse
-all-to-all + weighted combine.
+would build expert routing on. This module is that layer, TPU-native. For
+tokens ``h`` ``[T, d]``, router ``W_r`` ``[d, E]`` and experts ``W_gate,e``,
+``W_up,e`` ``[d, m]``, ``W_down,e`` ``[m, d]``:
 
-Layout: activations arrive with the batch sharded over (dp, ep) — each ep rank
-routes *its* tokens; experts are sharded over ep (each rank owns
-``num_experts / ep_size`` experts). Gradients: the dispatch mask is
-non-differentiable (stop-grad semantics of one-hot-of-argmax); the gate
-gradient flows through the combine-weight multiplier, the standard switch
-estimator.
+    r = h W_r (float32)        p = softmax(r)
+    S_t = the k largest of p_t (ties to the lower index)
+    y_t = sum_{e in S_t} p_{t,e} W_down,e( silu(W_gate,e h_t) * (W_up,e h_t) )
+
+The weights ``p_{t,e}`` are not renormalised over ``S_t``. **No token is
+dropped, whatever the routing**, and every shape is static: the ``T k``
+token-expert pairs are sorted by expert, the tokens' rows gathered once in
+that order, the three expert matrices applied as grouped matmuls over the
+per-expert counts (``lax.ragged_dot``, which XLA's TPU backend compiles to a
+grouped-matmul kernel of its own: ``ragged-dot-*`` in a device trace), and
+the rows put back in token order and summed under their weights. Both
+permutations are gathers in the forward and in the backward pass
+(:func:`_permute`).
+
+With ``axis`` bound (expert parallelism) every rank holds ``E / n`` experts
+and the batch rides ``(dp, ep)``: the group's tokens are all-gathered, each
+rank routes all of them, applies its own experts to the rows routed to them
+(sorted first; rows of other ranks' experts stay zero) and a ``psum_scatter``
+hands each rank the sum for its own tokens. At ``k`` of 8 over 4 ranks a
+token's experts lie on 3.6 ranks on average, so this moves what an
+all-to-all would and needs no capacity. ``tp_axis`` shards the experts'
+width ``m``; the partial sums meet in one ``psum`` after the combine.
+
+Gradients: the choice ``S_t`` is not differentiable; the router learns
+through the weights ``p_{t,e}`` and through the two auxiliary terms returned
+beside ``y`` (the load-balance term's token fractions are constants).
 """
 
 from __future__ import annotations
@@ -23,82 +40,114 @@ from typing import Any, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax import lax
 
+from .axes import axis_bound as _axis_bound, axis_size as _axis_size
 
-from .axes import axis_size as _axis_size
+GROUPED_MATMUL = "ragged_dot"
 
 
-def switch_moe(x, gate_w, w_up, w_down, axis: Optional[str] = None,
-               tp_axis: Optional[str] = None, capacity_factor: float = 1.25,
-               dtype: Any = jnp.bfloat16) -> Tuple[jnp.ndarray, dict]:
-    """Top-1 switch MoE layer.
+@jax.custom_vjp
+def _permute(x, perm, inv):
+    """``x[perm]`` for a permutation of rows and its inverse: the cotangent
+    goes back as the gather ``g[inv]``, where autodiff would scatter-add."""
+    return x[perm]
+
+
+def _permute_fwd(x, perm, inv):
+    return x[perm], inv
+
+
+def _permute_bwd(inv, g):
+    return g[inv], None, None
+
+
+_permute.defvjp(_permute_fwd, _permute_bwd)
+
+
+def moe_layer(x, router_w, w_gate, w_up, w_down, top_k: int,
+              axis: Optional[str] = None, tp_axis: Optional[str] = None,
+              dtype: Any = jnp.bfloat16) -> Tuple[jnp.ndarray, dict]:
+    """Dropless top-``top_k`` expert layer (module docstring has the math).
 
     Args:
-      x: ``[B, S, d]`` activations (this rank's batch/sequence shard).
-      gate_w: ``[d, num_experts]`` router weights (replicated, fp32).
-      w_up: ``[experts_local, d, m_local]`` expert up-projections — the ep-axis
-        shard of the global ``[num_experts, d, m]`` tensor (and tp shard of m).
+      x: ``[..., d]`` activations (this rank's batch/sequence shard).
+      router_w: ``[d, num_experts]`` router weights (replicated, fp32).
+      w_gate, w_up: ``[experts_local, d, m_local]`` — the ep-axis shard of
+        the global ``[num_experts, d, m]`` tensors (and tp shard of ``m``).
       w_down: ``[experts_local, m_local, d]``.
+      top_k: experts per token.
       axis: expert-parallel mesh axis (None/unbound ⇒ all experts local).
-      tp_axis: tensor-parallel axis sharding the expert hidden dim, if any.
-      capacity_factor: per-expert slot budget multiplier; tokens over capacity
-        are dropped (standard switch semantics).
+      tp_axis: tensor-parallel axis sharding the expert width, if any.
 
-    Returns ``(out [B, S, d], aux)`` with ``aux['load_balance_loss']`` (the
-    Switch-Transformer auxiliary) and ``aux['dropped_fraction']``.
+    Returns ``(y, aux)``, ``y`` shaped and typed (``dtype``) as the
+    activations, and over the tokens routed together (this rank's, or the ep
+    group's): ``aux["load_balance"]`` = ``E sum_e f_e P_e`` with ``f_e`` the
+    share of tokens whose ``S_t`` holds ``e`` and ``P_e = mean_t p_{t,e}``;
+    ``aux["router_z"]`` = ``mean_t logsumexp(r_t)^2``; ``aux["counts"]``
+    ``[E]`` int32, tokens per expert.
     """
-    B, S, d = x.shape
-    n_ep = _axis_size(axis)
+    d = x.shape[-1]
+    ep = _axis_bound(axis)
     experts_local = w_up.shape[0]
-    num_experts = experts_local * n_ep
+    num_experts = experts_local * _axis_size(axis)
+    from .. import runtime
+    recorder = runtime.recorder()
+    if recorder is not None:
+        recorder.note_moe_layer(num_experts, top_k, _axis_size(axis),
+                                GROUPED_MATMUL)
 
-    T = B * S
-    xt = x.reshape(T, d)
-    logits = xt.astype(jnp.float32) @ gate_w.astype(jnp.float32)  # [T, E]
-    probs = jax.nn.softmax(logits, axis=-1)
-    expert = jnp.argmax(probs, axis=-1)                            # [T]
-    gate_prob = jnp.max(probs, axis=-1)                            # [T]
+    xt = x.reshape(-1, d)
+    if ep:
+        xt = lax.all_gather(xt, axis, axis=0, tiled=True)
+    T = xt.shape[0]
 
-    capacity = int(np.ceil(T * capacity_factor / num_experts))
-    onehot = jax.nn.one_hot(expert, num_experts, dtype=jnp.float32)  # [T, E]
-    # Slot index of each token within its expert's capacity buffer.
-    pos = jnp.cumsum(onehot, axis=0) - onehot                        # [T, E]
-    keep = onehot * (pos < capacity)                                 # [T, E]
-    slot = jax.nn.one_hot(
-        jnp.sum(pos * onehot, axis=-1).astype(jnp.int32), capacity,
-        dtype=jnp.float32)                                           # [T, C]
-    dispatch = jnp.einsum("te,tc->tec", keep, slot)                  # [T, E, C]
-    combine = dispatch * gate_prob[:, None, None]
+    with jax.named_scope("router"):
+        logits = jnp.dot(xt.astype(jnp.float32), router_w.astype(jnp.float32),
+                         precision=lax.Precision.HIGHEST)            # [T, E]
+        probs = jax.nn.softmax(logits, axis=-1)
+        top_p, top_e = lax.top_k(probs, top_k)                       # [T, k]
+        counts = jnp.sum(jax.nn.one_hot(top_e, num_experts, dtype=jnp.int32),
+                         axis=(0, 1))                                # [E]
+        load_balance = num_experts * jnp.sum(
+            counts.astype(jnp.float32) / T * jnp.mean(probs, axis=0))
+        router_z = jnp.mean(jnp.square(jax.nn.logsumexp(logits, axis=-1)))
 
-    # [E, C, d]: expert-major token slots, still on the source rank.
-    slots = jnp.einsum("tec,td->ecd", dispatch.astype(dtype),
-                       xt.astype(dtype))
-    if n_ep > 1:
-        # Scatter experts to their owners, gathering every peer's slots for
-        # our local experts: [E, C, d] -> [E/n_ep, n_ep*C, d].
-        slots = lax.all_to_all(slots, axis, split_axis=0, concat_axis=1,
-                               tiled=True)
+    with jax.named_scope("dispatch"):
+        expert_of_pair, group_sizes, mine = top_e.reshape(-1), counts, None
+        if ep:
+            # This rank's experts first in the order: their rows are then
+            # the first sum(group_sizes) of the T k. Rows of other ranks'
+            # experts lie outside every group: a grouped matmul leaves them
+            # unwritten, so they are held at zero, and with them their
+            # cotangents.
+            first = lax.axis_index(axis) * experts_local
+            expert_of_pair = (expert_of_pair - first) % num_experts
+            group_sizes = lax.dynamic_slice(counts, (first,),
+                                            (experts_local,))
+            mine = (jnp.arange(T * top_k) < jnp.sum(group_sizes))[:, None]
+        order = jnp.argsort(expert_of_pair, stable=True)
+        inv = jnp.argsort(order)
+        rows = _permute(jnp.repeat(xt.astype(dtype), top_k, axis=0),
+                        order, inv)                                  # [Tk, d]
 
-    up = jnp.einsum("ecd,edm->ecm", slots, w_up.astype(dtype))
-    up = jax.nn.gelu(up)
-    out_slots = jnp.einsum("ecm,emd->ecd", up, w_down.astype(dtype))
-    if tp_axis is not None and _axis_size(tp_axis) > 1:
-        out_slots = lax.psum(out_slots, tp_axis)  # row-parallel hidden dim
+    def grouped(lhs, w):
+        out = lax.ragged_dot(lhs, w.astype(dtype), group_sizes)
+        return out if mine is None else jnp.where(mine, out, 0)
 
-    if n_ep > 1:
-        # Return each peer's processed slots: [E/n_ep, n_ep*C, d] -> [E, C, d].
-        out_slots = lax.all_to_all(out_slots, axis, split_axis=1,
-                                   concat_axis=0, tiled=True)
+    with jax.named_scope("experts"):
+        if mine is not None:
+            rows = jnp.where(mine, rows, 0)
+        hidden = jax.nn.silu(grouped(rows, w_gate)) * grouped(rows, w_up)
+        out_rows = grouped(hidden, w_down)                           # [Tk, d]
 
-    out = jnp.einsum("tec,ecd->td", combine.astype(dtype), out_slots)
-
-    # Switch aux: num_experts * sum_e mean_prob_e * fraction_routed_e
-    # (local-batch estimate; replicated params make it consistent under grad).
-    frac = jnp.mean(onehot, axis=0)
-    mean_prob = jnp.mean(probs, axis=0)
-    lb_loss = num_experts * jnp.sum(frac * mean_prob)
-    dropped = 1.0 - jnp.sum(keep) / jnp.maximum(jnp.sum(onehot), 1.0)
-    return out.reshape(B, S, d), {"load_balance_loss": lb_loss,
-                                  "dropped_fraction": dropped}
+    with jax.named_scope("combine"):
+        back = _permute(out_rows, inv, order).reshape(T, top_k, d)
+        y = jnp.sum(back.astype(jnp.float32) * top_p[:, :, None], axis=1)
+        if _axis_bound(tp_axis):
+            y = lax.psum(y, tp_axis)        # row-parallel expert width
+        if ep:
+            y = lax.psum_scatter(y, axis, scatter_dimension=0, tiled=True)
+        y = y.astype(dtype)
+    return y.reshape(x.shape), {"load_balance": load_balance,
+                                "router_z": router_z, "counts": counts}

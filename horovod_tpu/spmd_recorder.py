@@ -84,6 +84,8 @@ class SpmdRecorder:
         self.placed_bytes = 0
         # (kernel, block_q, block_k, operand_dtype, kv_group) -> traces
         self.flash_kernels: collections.Counter = collections.Counter()
+        # (experts, top_k, ep, grouped_matmul) -> traces
+        self.moe_layers: collections.Counter = collections.Counter()
         # function -> the argument signatures run_step has traced it with
         self._signatures: dict = {}
         self._cause: dict = {}          # function -> cause of the next compile
@@ -157,6 +159,13 @@ class SpmdRecorder:
             self.flash_kernels[(kernel, block_q, block_k, operand_dtype,
                                 kv_group)] += 1
 
+    def note_moe_layer(self, experts: int, top_k: int, ep: int,
+                       grouped_matmul: str) -> None:
+        """``parallel/moe.py`` calls this while JAX traces an expert layer:
+        what it routes over, and which grouped matmul it got."""
+        with self._lock:
+            self.moe_layers[(experts, top_k, ep, grouped_matmul)] += 1
+
     def note_placed(self, nbytes: int) -> None:
         self.placed_calls += 1
         self.placed_bytes += nbytes
@@ -173,6 +182,7 @@ class SpmdRecorder:
             compiles = sorted(self.compiles.items())
             hits, misses = self.cache_hits, self.cache_misses
             flash = sorted(self.flash_kernels.items())
+            moe = sorted(self.moe_layers.items())
         counts, seconds = [], []
         for (function, stage), (count, secs) in compiles:
             labels = {"function": function, "stage": stage}
@@ -206,6 +216,14 @@ class SpmdRecorder:
                        "block_k": str(bk), "operand_dtype": dtype,
                        "kv_group": str(group)}, float(count))
                  for (kernel, bq, bk, dtype, group), count in flash]),
+            "hvdtpu_spmd_moe_layer_traces_total": family(
+                "counter", "Times JAX traced an expert layer (the recomputed "
+                "copy of a block counts again), by the experts it routes "
+                "over, the experts per token, the size of the expert-parallel "
+                "axis and the grouped matmul it uses.",
+                [("", {"experts": str(experts), "top_k": str(top_k),
+                       "ep": str(ep), "grouped_matmul": gmm}, float(count))
+                 for (experts, top_k, ep, gmm), count in moe]),
             "hvdtpu_spmd_shard_batch_calls_total": family(
                 "counter", "Calls of hvd.shard_batch.",
                 [("", {}, float(self.placed_calls))]),

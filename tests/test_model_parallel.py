@@ -2,6 +2,8 @@
 parity against single-device (unsharded) execution of the same math.
 """
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -10,37 +12,78 @@ from jax.sharding import PartitionSpec as P
 
 import horovod_tpu as hvd
 from horovod_tpu.models import gpt
-from horovod_tpu.parallel.moe import switch_moe
+from horovod_tpu.parallel.moe import moe_layer
 from horovod_tpu.parallel.pipeline import pipeline_apply, stage_partition
 
 
-def test_switch_moe_expert_parallel_matches_local(make_runtime):
+@pytest.mark.parametrize("top_k", [1, 2])
+def test_moe_layer_expert_parallel_matches_local(make_runtime, top_k):
+    """Experts over ep=4, the batch over ep: output, both auxiliary terms,
+    counts and every gradient equal the all-experts-local layer's on the
+    whole batch. Dropless either way, whatever the routing."""
     make_runtime(mesh_shape={"ep": 4}, devices=jax.devices()[:4])
-    d, m, n_exp = 16, 32, 4
-    rng = jax.random.PRNGKey(0)
-    ks = jax.random.split(rng, 4)
+    d, m, n_exp = 16, 32, 8
+    ks = jax.random.split(jax.random.PRNGKey(0), 5)
     x = jax.random.normal(ks[0], (4, 8, d), jnp.float32)
-    gate = jax.random.normal(ks[1], (d, n_exp), jnp.float32)
+    router = jax.random.normal(ks[1], (d, n_exp), jnp.float32)
+    w_gate = jax.random.normal(ks[4], (n_exp, d, m), jnp.float32) / 4
     w_up = jax.random.normal(ks[2], (n_exp, d, m), jnp.float32) / 4
-    w_down = jax.random.normal(ks[3], (m, d), jnp.float32) / 6
-    w_down = jnp.broadcast_to(w_down, (n_exp, m, d))
-    # capacity_factor = n_exp guarantees no token drops, so local and
-    # expert-parallel routing compute identical math.
-    kw = dict(capacity_factor=float(n_exp), dtype=jnp.float32)
+    w_down = jax.random.normal(ks[3], (n_exp, m, d), jnp.float32) / 6
+    args = (x, router, w_gate, w_up, w_down)
 
-    expected, aux = switch_moe(x, gate, w_up, w_down, axis=None, **kw)
-    assert float(aux["dropped_fraction"]) == 0.0
+    def loss(axis):
+        def f(x, *w):
+            y, aux = moe_layer(x, *w, top_k=top_k, axis=axis,
+                               dtype=jnp.float32)
+            total = jnp.sum(y * jnp.cos(y))
+            if axis is not None:
+                total = jax.lax.psum(total, axis)
+                # Every rank of the group holds the same terms.
+                aux = {k: jax.lax.pmax(v, axis) if k == "counts"
+                       else jax.lax.pmean(v, axis) for k, v in aux.items()}
+            return total + aux["load_balance"] + aux["router_z"], (y, aux)
+        return f
 
-    def body(x, gate, w_up, w_down):
-        out, aux = switch_moe(x, gate, w_up, w_down, axis="ep", **kw)
-        return out
+    grad = lambda axis: jax.value_and_grad(  # noqa: E731
+        loss(axis), argnums=(0, 1, 2, 3, 4), has_aux=True)
+    (want_loss, (want_y, want_aux)), want_grads = grad(None)(*args)
+    experts = P("ep")
+    (got_loss, (got_y, got_aux)), got_grads = jax.shard_map(
+        grad("ep"), mesh=hvd.mesh(),
+        in_specs=(P("ep"), P(), experts, experts, experts),
+        out_specs=((P(), (P("ep"), P())),
+                   (P("ep"), P(), experts, experts, experts)))(*args)
+    np.testing.assert_allclose(got_loss, want_loss, rtol=1e-5)
+    np.testing.assert_allclose(got_y, want_y, rtol=1e-5, atol=1e-5)
+    np.testing.assert_array_equal(got_aux["counts"], want_aux["counts"])
+    assert int(got_aux["counts"].sum()) == 32 * top_k
+    for key in ("load_balance", "router_z"):
+        np.testing.assert_allclose(got_aux[key], want_aux[key], rtol=1e-5)
+    for g, w in zip(got_grads, want_grads):
+        np.testing.assert_allclose(g, w, rtol=1e-4,
+                                   atol=1e-5 * float(jnp.abs(w).max()))
 
+
+def test_moe_layer_tensor_parallel_expert_width(make_runtime):
+    """ep=2 x tp=2: the experts over ep, their width over tp."""
+    make_runtime(mesh_shape={"ep": 2, "tp": 2}, devices=jax.devices()[:4])
+    d, m, n_exp = 16, 32, 4
+    ks = jax.random.split(jax.random.PRNGKey(1), 5)
+    x = jax.random.normal(ks[0], (4, 8, d), jnp.float32)
+    router = jax.random.normal(ks[1], (d, n_exp), jnp.float32)
+    w_gate = jax.random.normal(ks[4], (n_exp, d, m), jnp.float32) / 4
+    w_up = jax.random.normal(ks[2], (n_exp, d, m), jnp.float32) / 4
+    w_down = jax.random.normal(ks[3], (n_exp, m, d), jnp.float32) / 6
+    want, _ = moe_layer(x, router, w_gate, w_up, w_down, top_k=2,
+                        dtype=jnp.float32)
     got = jax.shard_map(
-        body, mesh=hvd.mesh(),
-        in_specs=(P("ep"), P(), P("ep"), P("ep")),
-        out_specs=P("ep"))(x, gate, w_up, w_down)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(expected),
-                               rtol=1e-5, atol=1e-5)
+        lambda *a: moe_layer(*a, top_k=2, axis="ep", tp_axis="tp",
+                             dtype=jnp.float32)[0],
+        mesh=hvd.mesh(),
+        in_specs=(P("ep"), P(), P("ep", None, "tp"), P("ep", None, "tp"),
+                  P("ep", "tp", None)),
+        out_specs=P("ep"))(x, router, w_gate, w_up, w_down)
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
 
 
 def test_pipeline_matches_sequential(make_runtime):
@@ -223,28 +266,55 @@ def test_gpt_gqa_dense_matches_flash(make_runtime, kv_heads):
                                    rtol=5e-4, atol=5e-5)
 
 
-def test_gpt_moe_ep_forward_parity(make_runtime):
-    """dp=2 x ep=2 x sp=2 MoE-GPT == single-device forward (no drops)."""
+@pytest.mark.parametrize("top_k", [1, 2])
+def test_gpt_moe_ep_parity(make_runtime, top_k):
+    """dp=2 x ep=2 x sp=2 sparse GPT == single-device: the logits, and the
+    loss with its auxiliary terms where they are the same estimate (the
+    unsharded run routes all 64 tokens together; a dp x sp shard routes its
+    ep group's 16, so the two auxiliary terms are compared at ep alone)."""
     make_runtime(mesh_shape={"dp": 2, "ep": 2, "sp": 2})
     cfg = gpt.GPTConfig(vocab_size=64, num_layers=2, num_heads=4,
                         head_dim=8, embed_dim=32, mlp_dim=64,
                         dtype=jnp.float32, tp_axis=None, attention="ring",
-                        moe_every=2, num_experts=4, capacity_factor=4.0)
+                        ep_axis="ep", moe_every=2, num_experts=4,
+                        experts_per_token=top_k, load_balance_coef=0.01,
+                        router_z_coef=0.001)
     params = gpt.init_params(jax.random.PRNGKey(7), cfg)
     B, S = 4, 16
     tokens = jax.random.randint(jax.random.PRNGKey(8), (B, S), 0, 64)
+    targets = jnp.roll(tokens, -1, axis=1).at[:, -1].set(-1)
     positions = jnp.broadcast_to(jnp.arange(S), (B, S))
 
     expected = gpt.forward(params, tokens, positions, cfg)
 
+    data = P(("dp", "ep"), "sp")
     step = hvd.run_step(
         lambda p, t, pos: gpt.forward(p, t, pos, cfg),
-        in_specs=(gpt.param_specs(cfg), P(("dp", "ep"), "sp"),
-                  P(("dp", "ep"), "sp")),
-        out_specs=P(("dp", "ep"), "sp"))
+        in_specs=(gpt.param_specs(cfg), data, data), out_specs=data)
     got = step(params, tokens, positions)
     np.testing.assert_allclose(np.asarray(got), np.asarray(expected),
                                rtol=2e-4, atol=2e-4)
+
+    # Experts over ep alone: every rank's group is the whole batch, so the
+    # loss, both auxiliary terms and the counts are the unsharded ones, and
+    # the gradients (the experts' are ep shards) too.
+    make_runtime(mesh_shape={"ep": 4}, devices=jax.devices()[:4])
+    cfg = dataclasses.replace(cfg, attention="dense", sp_axis=None)
+    value_and_grad = jax.value_and_grad(
+        lambda p, *d: gpt.loss_and_aux(p, *d, cfg), has_aux=True)
+    (want, want_aux), want_grads = value_and_grad(
+        params, tokens, targets, positions)
+    specs = gpt.param_specs(cfg)
+    (loss, aux), grads = hvd.run_step(
+        value_and_grad, in_specs=(specs, P("ep"), P("ep"), P("ep")),
+        out_specs=((P(), P()), specs))(params, tokens, targets, positions)
+    np.testing.assert_allclose(loss, want, rtol=1e-5)
+    np.testing.assert_array_equal(aux["counts"], want_aux["counts"])
+    for key in ("cross_entropy", "load_balance", "router_z"):
+        np.testing.assert_allclose(aux[key], want_aux[key], rtol=1e-5)
+    for g, w in zip(jax.tree.leaves(grads), jax.tree.leaves(want_grads)):
+        np.testing.assert_allclose(g, w, rtol=2e-4,
+                                   atol=2e-5 * float(jnp.abs(w).max()))
 
 
 @pytest.mark.parametrize("remat", ["full", "dots"])

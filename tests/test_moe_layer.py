@@ -1,0 +1,259 @@
+"""The expert layer (``parallel/moe.py``) and the sparse decoder built on it
+(``models/gpt.py`` with OLMoE's block) against the plain every-expert-on-
+every-token reference the benchmark keeps (``benchmarks/reference/
+gpt_moe_dp.py``): float32, tiny sizes, seeded."""
+
+import os
+import re
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+import pytest
+from jax.sharding import PartitionSpec as P
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+import horovod_tpu as hvd  # noqa: E402
+from horovod_tpu.models import gpt  # noqa: E402
+from horovod_tpu.observability import sample_value  # noqa: E402
+from horovod_tpu.parallel.moe import moe_layer  # noqa: E402
+
+from benchmarks import flops_moe  # noqa: E402
+from benchmarks.reference import gpt_moe_dp as reference  # noqa: E402
+
+T, D, M, E = 48, 16, 24, 16
+
+
+def layer_inputs(seed=0, experts=E):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 5)
+    return (jax.random.normal(ks[0], (T, D)),
+            jax.random.normal(ks[1], (D, experts)),
+            jax.random.normal(ks[2], (experts, D, M)) / 4,
+            jax.random.normal(ks[3], (experts, D, M)) / 4,
+            jax.random.normal(ks[4], (experts, M, D)) / 5)
+
+
+def program(top_k):
+    def f(h, *w):
+        y, aux = moe_layer(h, *w, top_k=top_k, dtype=jnp.float32)
+        # A loss that weighs every output and both auxiliary terms.
+        return (jnp.sum(y * jnp.cos(y)) + aux["load_balance"]
+                + aux["router_z"]), (y, aux)
+    return f
+
+
+def plain(top_k):
+    def f(h, *w):
+        y, load_balance, router_z, counts = reference.expert_layer(
+            h, *w, top_k)
+        return (jnp.sum(y * jnp.cos(y)) + load_balance + router_z), (
+            y, {"load_balance": load_balance, "router_z": router_z,
+                "counts": counts})
+    return f
+
+
+def assert_layer_is_the_reference(args, top_k):
+    wrt = tuple(range(5))
+    (_, (y, aux)), grads = jax.value_and_grad(
+        program(top_k), argnums=wrt, has_aux=True)(*args)
+    (_, (y_ref, aux_ref)), grads_ref = jax.value_and_grad(
+        plain(top_k), argnums=wrt, has_aux=True)(*args)
+    np.testing.assert_allclose(y, y_ref, rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(aux["load_balance"], aux_ref["load_balance"],
+                               rtol=1e-5)
+    np.testing.assert_allclose(aux["router_z"], aux_ref["router_z"],
+                               rtol=1e-5)
+    np.testing.assert_array_equal(aux["counts"],
+                                  np.asarray(aux_ref["counts"], np.int32))
+    assert int(aux["counts"].sum()) == T * top_k        # nothing dropped
+    for name, g, g_ref in zip(("h", "W_r", "W_gate", "W_up", "W_down"),
+                              grads, grads_ref):
+        np.testing.assert_allclose(
+            g, g_ref, rtol=1e-5, atol=1e-5 * float(jnp.abs(g_ref).max()),
+            err_msg=name)
+    return aux["counts"]
+
+
+@pytest.mark.parametrize("top_k", [1, 2, 8])
+def test_layer_matches_the_reference(top_k):
+    assert_layer_is_the_reference(layer_inputs(top_k), top_k)
+
+
+def test_one_expert_taking_every_token_drops_none():
+    """A router whose columns are equal ties every probability, ties go to
+    the lower index, so every token's two experts are experts 0 and 1: a
+    capacity would overflow; the grouped layer computes all 96 rows."""
+    h, router, *w = layer_inputs(3)
+    router = jnp.broadcast_to(router[:, :1], router.shape)
+    counts = assert_layer_is_the_reference((h, router, *w), 2)
+    assert counts.tolist() == [T, T] + [0] * (E - 2)
+
+
+def olmoe(**kw):
+    base = dict(vocab_size=64, num_layers=2, num_heads=4, num_kv_heads=4,
+                head_dim=8, embed_dim=32, mlp_dim=16, dtype=jnp.float32,
+                tp_axis=None, sp_axis=None, attention="dense", moe_every=1,
+                num_experts=8, experts_per_token=2, load_balance_coef=0.01,
+                router_z_coef=0.001, qk_norm=True, norm_eps=1e-5)
+    return gpt.GPTConfig(**{**base, **kw})
+
+
+def olmoe_batch(cfg, batch=2, seq=32, seed=1):
+    rng = np.random.default_rng(seed)
+    tokens = rng.integers(0, cfg.vocab_size, (batch, seq), dtype=np.int32)
+    targets = np.roll(tokens, -1, -1)
+    targets[:, -1] = -1
+    positions = np.broadcast_to(np.arange(seq, dtype=np.int32),
+                                (batch, seq)).copy()
+    return tokens, targets, positions
+
+
+def olmoe_params(cfg, seed=0):
+    params = gpt.init_params(jax.random.PRNGKey(seed), cfg)
+    # Norm weights off one, so that a norm applied to the wrong axis shows.
+    key = jax.random.PRNGKey(seed + 1)
+    for lp in params["layers"]:
+        for name in ("q_norm", "k_norm", "attn_norm", "mlp_norm"):
+            key, sub = jax.random.split(key)
+            lp[name] = 1.0 + 0.1 * jax.random.normal(sub, lp[name].shape)
+    return params
+
+
+def loss_and_grads(cfg, params, data):
+    return jax.value_and_grad(
+        lambda p: gpt.loss_and_aux(p, *data, cfg), has_aux=True)(params)
+
+
+def test_sparse_decoder_matches_the_reference():
+    cfg = olmoe()
+    params, data = olmoe_params(cfg), olmoe_batch(cfg)
+    (loss, aux), grads = loss_and_grads(cfg, params, data)
+    (want, parts), grads_ref = jax.value_and_grad(
+        lambda p: reference.shard_loss(
+            p, *data, top_k=2, norm_eps=1e-5, load_balance_coef=0.01,
+            router_z_coef=0.001), has_aux=True)(params)
+    np.testing.assert_allclose(loss, want, rtol=1e-5)
+    for key in ("cross_entropy", "load_balance", "router_z"):
+        np.testing.assert_allclose(aux[key], parts[key], rtol=1e-5)
+    np.testing.assert_array_equal(aux["counts"], parts["counts"])
+    # The auxiliary terms are in the loss, under their coefficients.
+    np.testing.assert_allclose(
+        loss, aux["cross_entropy"] + 0.01 * aux["load_balance"]
+        + 0.001 * aux["router_z"], rtol=1e-6)
+    assert float(aux["load_balance"]) > 1.5     # two layers, about 1 each
+    flat, _ = jax.tree_util.tree_flatten_with_path(grads)
+    for (path, g), g_ref in zip(flat, jax.tree.leaves(grads_ref)):
+        np.testing.assert_allclose(
+            g, g_ref, rtol=2e-4, atol=2e-5 * float(jnp.abs(g_ref).max()),
+            err_msg=jax.tree_util.keystr(path))
+
+
+@pytest.mark.parametrize("change", [dict(attention="flash"),
+                                    dict(remat="full")])
+def test_sparse_decoder_flash_and_remat_change_nothing(change):
+    base, other = olmoe(), olmoe(**change)
+    params, data = olmoe_params(base), olmoe_batch(base, seq=128)
+    (l0, a0), g0 = loss_and_grads(base, params, data)
+    (l1, a1), g1 = loss_and_grads(other, params, data)
+    np.testing.assert_allclose(l1, l0, rtol=1e-5)
+    np.testing.assert_array_equal(a1["counts"], a0["counts"])
+    for a, b in zip(jax.tree.leaves(g1), jax.tree.leaves(g0)):
+        np.testing.assert_allclose(a, b, rtol=5e-4,
+                                   atol=5e-5 * float(jnp.abs(b).max()))
+
+
+def test_dense_decoder_has_no_auxiliary_terms():
+    cfg = gpt.GPTConfig(vocab_size=64, num_layers=1, num_heads=2, head_dim=8,
+                        embed_dim=16, mlp_dim=32, dtype=jnp.float32,
+                        tp_axis=None, sp_axis=None, attention="dense")
+    params = gpt.init_params(jax.random.PRNGKey(0), cfg)
+    data = olmoe_batch(cfg, seq=16)
+    loss, aux = gpt.loss_and_aux(params, *data, cfg)
+    assert set(aux) == {"cross_entropy"}
+    assert float(loss) == float(gpt.loss_fn(params, *data, cfg))
+    assert "moe" not in params["layers"][0]
+    assert "q_norm" not in params["layers"][0]
+
+
+def test_qk_norm_over_tensor_parallel_heads(make_runtime):
+    """The q/k norm is over all heads together: with the heads over tp its
+    mean square is summed across the ranks."""
+    make_runtime(mesh_shape={"tp": 2}, devices=jax.devices()[:2])
+    cfg = olmoe(tp_axis="tp")
+    params, data = olmoe_params(cfg), olmoe_batch(cfg)
+    want = gpt.forward(params, data[0], data[2], olmoe())
+    got = hvd.run_step(
+        lambda p, t, pos: gpt.forward(p, t, pos, cfg),
+        in_specs=(gpt.param_specs(cfg), P(), P()), out_specs=P())(
+            params, data[0], data[2])
+    np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-4)
+
+
+def sparse_step(remat):
+    cfg = olmoe(attention="flash", remat=remat, num_layers=1)
+    opt = hvd.DistributedOptimizer(optax.adamw(1e-3))
+
+    def _train_step(params, opt_state, data):
+        loss, grads = jax.value_and_grad(
+            lambda p: gpt.loss_fn(p, *data, cfg))(params)
+        updates, opt_state = opt.update(grads, opt_state, params)
+        return (optax.apply_updates(params, updates), opt_state,
+                hvd.allreduce(loss, op=hvd.Average))
+
+    step = hvd.run_step(
+        _train_step,
+        in_specs=(hvd.REPLICATED, hvd.REPLICATED, hvd.batch_spec(0)),
+        out_specs=hvd.REPLICATED)
+    params = hvd.replicate(gpt.init_params(jax.random.PRNGKey(0), cfg))
+    data = hvd.shard_batch(olmoe_batch(cfg, batch=4, seq=128))
+    return step, params, hvd.replicate(opt.init(params)), data
+
+
+def test_compiled_step_carries_the_expert_layers_scopes(make_runtime):
+    make_runtime(devices=jax.devices()[:4])
+    step, *args = sparse_step("full")
+    # The grouped matmul is one primitive (JAX expands it when it lowers for
+    # the CPU; the TPU's compiler makes it a kernel).
+    assert "ragged_dot" in str(jax.make_jaxpr(step)(*args))
+    names = set(re.findall(r'op_name="([^"]*)"',
+                           step.lower(*args).compile().as_text()))
+
+    def some(*parts):
+        return any(all(p in n for p in parts) for n in names)
+
+    for scope in ("router", "dispatch", "experts", "combine"):
+        assert some("jvp(layer0)", f"/moe/{scope}/"), scope
+        assert some("transpose(jvp(layer0))", f"/moe/{scope}/"), scope
+        assert some(f"rematted_computation/moe/{scope}/"), scope
+    assert some("jvp(aux_loss)")
+    assert not some("/mlp/")
+
+
+def test_metrics_count_the_expert_layers_trace(make_runtime):
+    make_runtime(devices=jax.devices()[:1])
+    h, *w = layer_inputs()
+    jax.jit(lambda h, *w: moe_layer(h, *w, top_k=2, dtype=jnp.float32)[0])(
+        h, *w)
+    fams = hvd.metrics()
+    assert fams["hvdtpu_spmd_moe_layer_traces_total"]["type"] == "counter"
+    assert sample_value(
+        fams, "hvdtpu_spmd_moe_layer_traces_total", experts=str(E),
+        top_k="2", ep="1", grouped_matmul="ragged_dot") == 1.0
+
+
+def test_operation_count_by_hand():
+    # One token: the router's 16x8 matrix and 2 experts of three 16x24
+    # matrices, two operations a multiply-accumulate.
+    assert flops_moe.expert_layer_forward_flops(16, 8, 24, 2) \
+        == 2 * 16 * 8 + 2 * 3 * 2 * 16 * 24 == 4864
+    # A token trained at S=3 with one head of 4: q, k, v, o 4 * 2 * 16 * 4;
+    # attention over (3 + 1) / 2 keys, 4 * 4 operations a key; the head
+    # 2 * 16 * 10; forward and backward three times that.
+    assert flops_moe.moe_train_flops(
+        3, 1, 16, 1, 1, 4, experts=8, width=24, top_k=2, vocab=10) \
+        == 3 * (512 + 32 + 4864 + 320)
