@@ -35,6 +35,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
 from ..ops import collectives as C
@@ -75,9 +76,12 @@ class GPTConfig:
     norm_eps: float = 1e-6
     # Per-block rematerialization (jax.checkpoint) — the TPU lever trading
     # FLOPs for HBM so long sequences fit: "none" stores every block
-    # activation; "full" stores only block inputs and recomputes the rest
-    # in backward; "dots" additionally saves matmul outputs (recompute only
-    # the cheap elementwise work).
+    # activation; "full" stores a block's input and what is dear to make
+    # again (``SAVED_NAMES``: the flash kernel's output and log-sum-exp, the
+    # dense feed-forward's pre-activation) and recomputes the rest in
+    # backward: in bfloat16 2E + 2HD + 4H + 2M bytes a token a layer where
+    # the input alone is 2E (an expert block: no 2M); "dots" instead saves
+    # every matmul output (recompute only the cheap elementwise work).
     remat: str = "none"                      # "none" | "full" | "dots"
 
     @property
@@ -271,9 +275,38 @@ def _block(cfg: GPTConfig, layer_params, x, positions):
     with jax.named_scope("mlp"):
         h = _rmsnorm(x, lp["mlp_norm"], cfg.dtype, cfg.norm_eps)
         up = jnp.einsum("bse,em->bsm", h, lp["w_up"].astype(cfg.dtype))
-        up = jax.nn.gelu(up)
+        up = jax.nn.gelu(checkpoint_name(up, "ffn_pre_activation"))
         down = jnp.einsum("bsm,me->bse", up, lp["w_down"].astype(cfg.dtype))
         return x + _tp_psum(down, cfg), None
+
+
+# What ``remat="full"`` keeps from a block's forward pass beside its input:
+# the values whose recomputation is a kernel or the block's widest matmul,
+# named where they are born. The flash kernel's output and log-sum-exp
+# (``ops/flash_attention.py``; with either missing the kernel runs again) and
+# the dense feed-forward's pre-activation (``_block``). A block that produces
+# none of a name keeps nothing under it. Norms, rotary, projections,
+# activation and the whole expert layer stay recomputed: a grouped matmul's
+# output kept from the forward to the backward pass came back wrong on the
+# v5e at 8,192 rows and right at 65,536 (PERF.md, Findings, PR 26), so
+# ``parallel/moe.py`` names nothing.
+SAVED_NAMES = ("flash_out", "flash_lse", "ffn_pre_activation")
+_save_names = jax.checkpoint_policies.save_only_these_names(*SAVED_NAMES)
+
+
+def _full_policy(prim, *avals, **params):
+    """``save_only_these_names(*SAVED_NAMES)``, telling ``hvd.metrics()``
+    the bytes of each value it keeps (while JAX splits a block into its
+    forward and backward parts: trace time, nothing per step)."""
+    saved = _save_names(prim, *avals, **params)
+    if saved:
+        from .. import runtime
+        recorder = runtime.recorder()
+        if recorder is not None:
+            recorder.note_remat_saved(
+                "full", params["name"],
+                avals[0].size * avals[0].dtype.itemsize)
+    return saved
 
 
 def _block_fn(cfg: GPTConfig):
@@ -282,7 +315,8 @@ def _block_fn(cfg: GPTConfig):
     if cfg.remat == "none":
         return _block
     if cfg.remat == "full":
-        return jax.checkpoint(_block, static_argnums=(0,))
+        return jax.checkpoint(_block, static_argnums=(0,),
+                              policy=_full_policy)
     if cfg.remat == "dots":
         return jax.checkpoint(
             _block, static_argnums=(0,),

@@ -128,10 +128,11 @@ def render_exposition(families: dict) -> str:
     return "\n".join(out) + ("\n" if out else "")
 
 
-def sample_value(parsed: dict, name: str, suffix: str = "",
+def sample_value(parsed: dict, name: str, /, suffix: str = "",
                  **labels) -> Optional[float]:
     """First sample of ``name`` whose labels include ``labels`` (None if
-    absent) — convenience for tests and the driver summary."""
+    absent) — convenience for tests and the driver summary. The family's
+    name is positional only: a label may itself be called ``name``."""
     fam = parsed.get(name)
     if not fam:
         return None

@@ -66,6 +66,7 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -534,10 +535,14 @@ def _flash_bhsd(q, k, v, sm_scale, causal, kv_len, forced):
 
 def _flash_bhsd_fwd(q, k, v, sm_scale, causal, kv_len, forced):
     o, lse = _fwd_call(q, k, v, sm_scale, causal, kv_len, forced)
+    # Named, both, so that a ``jax.checkpoint`` around the caller can keep
+    # them (``models/gpt.py::SAVED_NAMES``) and not run this kernel a second
+    # time for the backward pass; outside a checkpoint a name is an identity.
+    o = checkpoint_name(o, "flash_out")
     # Residual carries ONE lane of the lane-replicated stats: holding the
     # [bh, s, 128] form across the whole fwd->bwd interval would cost 128x
     # the logical bytes per layer; the backward re-broadcasts transiently.
-    return o, (q, k, v, o, lse[..., 0])
+    return o, (q, k, v, o, checkpoint_name(lse[..., 0], "flash_lse"))
 
 
 def _flash_bhsd_bwd(sm_scale, causal, kv_len, forced, res, do):

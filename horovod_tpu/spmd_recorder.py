@@ -86,6 +86,8 @@ class SpmdRecorder:
         self.flash_kernels: collections.Counter = collections.Counter()
         # (experts, top_k, ep, grouped_matmul) -> traces
         self.moe_layers: collections.Counter = collections.Counter()
+        # (remat mode, name) -> bytes the checkpointed blocks keep
+        self.remat_saved: collections.Counter = collections.Counter()
         # function -> the argument signatures run_step has traced it with
         self._signatures: dict = {}
         self._cause: dict = {}          # function -> cause of the next compile
@@ -166,6 +168,12 @@ class SpmdRecorder:
         with self._lock:
             self.moe_layers[(experts, top_k, ep, grouped_matmul)] += 1
 
+    def note_remat_saved(self, mode: str, name: str, nbytes: int) -> None:
+        """``models/gpt.py``'s checkpoint policy calls this while JAX splits
+        a block into its forward and backward parts: a value it keeps."""
+        with self._lock:
+            self.remat_saved[(mode, name)] += nbytes
+
     def note_placed(self, nbytes: int) -> None:
         self.placed_calls += 1
         self.placed_bytes += nbytes
@@ -183,6 +191,7 @@ class SpmdRecorder:
             hits, misses = self.cache_hits, self.cache_misses
             flash = sorted(self.flash_kernels.items())
             moe = sorted(self.moe_layers.items())
+            saved = sorted(self.remat_saved.items())
         counts, seconds = [], []
         for (function, stage), (count, secs) in compiles:
             labels = {"function": function, "stage": stage}
@@ -224,6 +233,13 @@ class SpmdRecorder:
                 [("", {"experts": str(experts), "top_k": str(top_k),
                        "ep": str(ep), "grouped_matmul": gmm}, float(count))
                  for (experts, top_k, ep, gmm), count in moe]),
+            "hvdtpu_spmd_remat_saved_bytes_total": family(
+                "counter", "Bytes a checkpointed block hands from its "
+                "forward to its backward pass beside its input, by remat "
+                "mode and the name the value carries; one block for each "
+                "that JAX splits (layers alike share one).",
+                [("", {"mode": mode, "name": name}, float(nbytes))
+                 for (mode, name), nbytes in saved]),
             "hvdtpu_spmd_shard_batch_calls_total": family(
                 "counter", "Calls of hvd.shard_batch.",
                 [("", {}, float(self.placed_calls))]),

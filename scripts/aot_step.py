@@ -1,0 +1,106 @@
+#!/usr/bin/env python3
+"""Compile a benchmark cell's real training step for a v5e that is described
+and not attached, in the CPU sandbox: what the compiler says of its memory and
+how many of each named kernel the compiled program holds. Nothing runs and no
+time is taken; a compile that passes is not a chip run.
+
+    python3 scripts/aot_step.py starcoder2-3b_s4096 olmoe-1b-7b_s4096
+
+The step is the job's own (``benchmarks/jobs/*.py``: ``hvd.run_step`` over
+``DistributedOptimizer``, state donated), lowered on shapes alone. One JSON
+line a cell; ``--repo DIR`` compiles another checkout's program (a copy of
+the parent commit) under this script.
+"""
+
+from __future__ import annotations
+
+import argparse
+import importlib
+import json
+import os
+import re
+import sys
+import time
+
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
+KERNELS = ("hvd_flash_fwd", "hvd_flash_dkdv", "hvd_flash_dq",
+           "ragged-dot-none")
+GIB = 2.0 ** 30
+
+
+def compile_cell(name: str, root: str) -> dict:
+    import jax
+    from jax.sharding import NamedSharding
+    import horovod_tpu as hvd
+
+    from benchmarks import run
+
+    bench = run.load_json(root, "BENCHMARK.json")
+    cell = run.find(bench["workloads"], name, "workload")
+    config = run.load_json(root, run.find(
+        bench["configs"], cell["config"], "config")["file"])
+    traffic = run.load_json(run.HERE, "traffic", cell["traffic"] + ".json")
+    job = importlib.import_module(
+        "benchmarks.jobs." + config["job"]).Job(config, traffic, seed=0)
+
+    replicated = NamedSharding(hvd.mesh(), hvd.REPLICATED)
+
+    def shapes(f, *args):
+        return jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                           sharding=replicated),
+            jax.eval_shape(f, *args))
+
+    params = shapes(job.init_params, jax.random.PRNGKey(0))
+    opt_state = shapes(job.opt.init, params)
+    data = tuple(
+        jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=NamedSharding(
+            hvd.mesh(), hvd.batch_spec(0)))
+        for x in job.host_batches(1)[0])
+    t0 = time.time()
+    compiled = job.step.lower(params, opt_state, data).compile()
+    seconds = time.time() - t0
+    m = compiled.memory_analysis()
+    text = compiled.as_text()
+    return {
+        "cell": name, "compile_s": round(seconds, 1),
+        "arguments_gib": round(m.argument_size_in_bytes / GIB, 3),
+        "temporaries_gib": round(m.temp_size_in_bytes / GIB, 3),
+        # What the step holds at once; outputs that alias donated
+        # arguments are written where those were read.
+        "total_gib": round((m.argument_size_in_bytes + m.temp_size_in_bytes
+                            + m.output_size_in_bytes
+                            - m.alias_size_in_bytes) / GIB, 3),
+        "calls": {k: len(re.findall(
+            r"^\s*(?:ROOT )?%?[\w.-]*" + re.escape(k) + r"[\w.-]* = ",
+            text, re.M)) for k in KERNELS},
+    }
+
+
+def main() -> int:
+    parser = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    parser.add_argument("cells", nargs="+")
+    parser.add_argument("--repo", default=os.path.dirname(
+        os.path.dirname(os.path.abspath(__file__))))
+    args = parser.parse_args()
+    root = os.path.abspath(args.repo)
+    sys.path.insert(0, root)
+
+    import jax
+    from jax.experimental import topologies
+    jax.config.update("jax_enable_compilation_cache", False)
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+    import horovod_tpu as hvd
+    from horovod_tpu.compression import quantize
+    quantize._pallas_backend_enabled = lambda *_: True   # through Mosaic
+    hvd.init(devices=topo.devices[:1])
+    for name in args.cells:
+        print(json.dumps(compile_cell(name, root)), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -3,6 +3,8 @@
 every-token reference the benchmark keeps (``benchmarks/reference/
 gpt_moe_dp.py``): float32, tiny sizes, seeded."""
 
+import collections
+import dataclasses
 import os
 import re
 import sys
@@ -165,6 +167,91 @@ def test_sparse_decoder_flash_and_remat_change_nothing(change):
     for a, b in zip(jax.tree.leaves(g1), jax.tree.leaves(g0)):
         np.testing.assert_allclose(a, b, rtol=5e-4,
                                    atol=5e-5 * float(jnp.abs(b).max()))
+
+
+def ops_of(jaxpr, out=None):
+    """Equations by primitive in a jaxpr and every jaxpr under it; a Pallas
+    kernel under its own name, a matmul under its output's shape too."""
+    out = collections.Counter() if out is None else out
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            out[eqn.params["name"]] += 1
+            continue
+        out[eqn.primitive.name] += 1
+        if eqn.primitive.name == "dot_general":
+            out["dot_general", eqn.outvars[0].aval.shape] += 1
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            ops_of(sub, out)
+    return out
+
+
+@pytest.mark.parametrize("feed_forward", ["dense", "sparse"])
+def test_full_remat_keeps_what_is_dear_to_make_again(feed_forward):
+    """Under ``remat="full"`` a block keeps the flash kernel's output and
+    log-sum-exp and the dense feed-forward's pre-activation: the
+    differentiated step holds no second flash forward and no second up
+    projection. The expert layer keeps nothing (a grouped matmul's output
+    kept across the passes came back wrong on the chip: PERF.md, Findings,
+    PR 26): its three grouped matmuls run again. The numbers are
+    ``"none"``'s."""
+    kind = dict(moe_every=0, mlp_dim=48) if feed_forward == "dense" else {}
+    full = olmoe(attention="flash", remat="full", **kind)
+    none = dataclasses.replace(full, remat="none")
+    batch, seq = 2, 128
+    params, data = olmoe_params(full), olmoe_batch(full, batch, seq)
+
+    ops = ops_of(jax.make_jaxpr(
+        lambda p: loss_and_grads(full, p, data))(params).jaxpr)
+    assert ops["hvd_flash_fwd"] == full.num_layers
+    assert ops["hvd_flash_dkdv"] == ops["hvd_flash_dq"] == full.num_layers
+    if feed_forward == "dense":
+        # The up projection, and the backward pass's product with the down
+        # projection's matrix, which has the same shape.
+        assert ops["dot_general", (batch, seq, full.mlp_dim)] \
+            == 2 * full.num_layers
+    else:
+        # Three forward, three again, two each backward.
+        assert ops["ragged_dot_general"] == 12 * full.num_layers
+
+    (l0, a0), g0 = loss_and_grads(none, params, data)
+    (l1, a1), g1 = loss_and_grads(full, params, data)
+    np.testing.assert_allclose(l1, l0, rtol=1e-6)
+    for a, b in zip(jax.tree.leaves((a1, g1)), jax.tree.leaves((a0, g0))):
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
+
+
+def test_metrics_count_what_a_checkpointed_block_keeps(make_runtime):
+    make_runtime(devices=jax.devices()[:1])
+    family = "hvdtpu_spmd_remat_saved_bytes_total"
+    # Shapes no other test of this file traces: JAX splits a block it has
+    # split before from its cache, without asking the policy.
+    batch, seq = 3, 256
+    sparse = olmoe(attention="flash", remat="none", num_layers=1,
+                   num_experts=4)
+    data = olmoe_batch(sparse, batch, seq)
+
+    def trace(cfg):
+        jax.make_jaxpr(lambda p: loss_and_grads(cfg, p, data))(
+            gpt.init_params(jax.random.PRNGKey(0), cfg))
+
+    trace(sparse)
+    assert hvd.metrics()[family]["samples"] == []
+
+    trace(dataclasses.replace(sparse, remat="full"))
+    trace(dataclasses.replace(sparse, remat="full", moe_every=0))
+    fams = hvd.metrics()
+    assert fams[family]["type"] == "counter"
+    assert {labels["name"] for _, labels, _ in fams[family]["samples"]} \
+        == set(gpt.SAVED_NAMES)
+    tokens, f32 = batch * seq, 4
+    heads = sparse.num_heads * sparse.head_dim
+    # Two blocks split, a flash pair each; the dense block's up projection
+    # (the expert block keeps nothing of its feed-forward).
+    want = {"flash_out": 2 * tokens * heads * f32,
+            "flash_lse": 2 * tokens * sparse.num_heads * f32,
+            "ffn_pre_activation": tokens * sparse.mlp_dim * f32}
+    for name, nbytes in want.items():
+        assert sample_value(fams, family, mode="full", name=name) == nbytes
 
 
 def test_dense_decoder_has_no_auxiliary_terms():
