@@ -29,7 +29,12 @@ def main():
     parser.add_argument("--seq-len", type=int, default=256)
     parser.add_argument("--steps", type=int, default=5)
     parser.add_argument("--attention", default="ring",
-                        choices=["ring", "ulysses", "dense"])
+                        choices=["ring", "ulysses"],
+                        help="how attention crosses the sp axis: 'ring' "
+                             "passes K/V blocks round it (ppermute); "
+                             "'ulysses' trades sequence for heads "
+                             "(all-to-all; needs heads divisible by tp*sp) "
+                             "and runs the flash kernel on each device")
     args = parser.parse_args()
 
     hvd.init(mesh_shape={"dp": args.dp, "tp": args.tp, "sp": args.sp})

@@ -51,10 +51,8 @@ from .parallel.strategy import (autotune_hierarchical, choose_hierarchical,
                                 save_hierarchical_decisions)
 
 # Sequence/context parallelism (TPU-first; no reference analog — SURVEY.md §2.7).
-from .parallel.ring_attention import (ring_attention, ring_attention_p,
-                                      make_ring_attention)
-from .parallel.ulysses import (ulysses_attention, ulysses_attention_p,
-                               make_ulysses_attention)
+from .parallel.ring_attention import ring_attention, ring_attention_p
+from .parallel.ulysses import ulysses_attention, ulysses_attention_p
 # Fused (flash) causal attention Pallas kernel (TPU-first extension).
 from .ops.flash_attention import flash_attention
 

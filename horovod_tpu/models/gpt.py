@@ -13,7 +13,10 @@ math needs:
   are row-parallel followed by one ``psum`` each (Megatron pattern, but via
   shard_map + XLA collectives over ICI, not hand-written NCCL).
 * **sp** — activations are sequence-sharded; attention is ring attention
-  (``ppermute`` ring) or Ulysses (all-to-all), per config.
+  (``ppermute`` ring) or Ulysses (all-to-all, the flash kernel on each
+  device), per config. Without a bound sp axis attention is the flash kernel
+  (:mod:`horovod_tpu.ops.flash_attention`) unless the config asks for the
+  dense reference by name; ``_attention`` holds the whole rule.
 * **ep** — optional expert blocks (dropless top-k, SiLU-gated experts) hold
   their experts over the ep axis (:mod:`horovod_tpu.parallel.moe`).
 * **dp** — gradient averaging comes from autodiff under shard_map(check_vma):
@@ -38,8 +41,10 @@ from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
-from ..ops import collectives as C
-from .transformer import default_attention, rope
+from ..ops.attention import default_attention, repeat_kv_heads, rope
+from ..ops.flash_attention import flash_attention
+from ..parallel.ring_attention import ring_attention_p
+from ..parallel.ulysses import ulysses_attention_p
 
 
 @dataclasses.dataclass(frozen=True)
@@ -56,9 +61,9 @@ class GPTConfig:
     tp_axis: Optional[str] = "tp"
     sp_axis: Optional[str] = "sp"
     ep_axis: Optional[str] = None
-    # "ring" | "ulysses" | "dense" | "flash" | "ulysses_flash"
-    # (ulysses_flash = Ulysses head/sequence exchange with the fused Pallas
-    # flash kernel as the per-device full-sequence attention)
+    # "flash" | "dense" | "ring" | "ulysses": the table in ``_attention``
+    # (the flash kernel on each device, except under "dense", the reference;
+    # "ring" and "ulysses" cross a bound sp axis, the other two refuse one).
     attention: str = "ring"
     # Experts (active when moe_every > 0): every moe_every-th block's
     # feed-forward is the dropless expert layer of ``parallel/moe.py``:
@@ -204,42 +209,47 @@ def _tp_psum(x, cfg: GPTConfig):
     return x
 
 
+_ATTENTION_KINDS = ("flash", "dense", "ring", "ulysses")
+
+
 def _attention(cfg: GPTConfig, q, k, v):
-    """Dispatch to the configured context-parallel attention. Falls back to
-    dense attention when the sp axis is not bound (single-device parity).
-    ``attention="flash"`` uses the fused Pallas kernel
-    (:mod:`horovod_tpu.ops.flash_attention`) — no S x S logits tensor in
-    HBM; local (non-sp) attention only."""
-    sp = cfg.sp_axis
-    if cfg.attention == "flash":
-        if _axis_bound(sp):
-            raise ValueError(
-                "attention='flash' is local attention; with a bound sp "
-                "axis use 'ring', 'ulysses', or 'ulysses_flash' (the "
-                "flash kernel as Ulysses' per-device attention)")
-        from ..ops.flash_attention import flash_attention
+    """Which attention runs: the one place that decides, and this table is
+    the whole rule. No row falls back to another.
+
+    ============  ==================  ===============================
+    attention     sp axis not bound   sp axis bound
+    ============  ==================  ===============================
+    ``flash``     flash kernel        ValueError
+    ``dense``     dense reference     ValueError
+    ``ring``      flash kernel        ``ring_attention_p``
+    ``ulysses``   flash kernel        ``ulysses_attention_p`` (flash
+                                      kernel on each device)
+    ============  ==================  ===============================
+
+    ``flash`` and ``dense`` attend the sequence a rank holds, so under a
+    bound sp axis they would attend a shard to itself. ``dense`` is
+    :func:`horovod_tpu.ops.attention.default_attention`, S x S logits and
+    all: the reference the tests compare against."""
+    kind, sp = cfg.attention, cfg.sp_axis
+    if kind not in _ATTENTION_KINDS:
+        raise ValueError(f"unknown attention {kind!r} "
+                         f"(expected one of {_ATTENTION_KINDS})")
+    if not _axis_bound(sp):
+        if kind == "dense":
+            # The reference takes equal head counts (ring and Ulysses tile
+            # K/V up themselves; the flash kernels read them as they are).
+            return default_attention(q, repeat_kv_heads(k, q.shape[2]),
+                                     repeat_kv_heads(v, q.shape[2]),
+                                     causal=True)
         return flash_attention(q, k, v, causal=True)
-    if cfg.attention == "ulysses_flash":
-        from ..ops.flash_attention import flash_attention
-        if not _axis_bound(sp):
-            return flash_attention(q, k, v, causal=True)
-        from ..parallel.ulysses import ulysses_attention_p
-        return ulysses_attention_p(q, k, v, causal=True, axis=sp,
-                                   attn_fn=flash_attention)
-    if not _axis_bound(sp) or cfg.attention == "dense":
-        # GQA: the plain path takes equal head counts, so the key and value
-        # heads tile up here as ring and Ulysses do themselves (the flash
-        # kernels read them at their own head count).
-        from ..ops.flash_attention import repeat_kv_heads
-        return default_attention(q, repeat_kv_heads(k, q.shape[2]),
-                                 repeat_kv_heads(v, q.shape[2]), causal=True)
-    if cfg.attention == "ring":
-        from ..parallel.ring_attention import ring_attention_p
+    if kind == "ring":
         return ring_attention_p(q, k, v, causal=True, axis=sp)
-    if cfg.attention == "ulysses":
-        from ..parallel.ulysses import ulysses_attention_p
+    if kind == "ulysses":
         return ulysses_attention_p(q, k, v, causal=True, axis=sp)
-    raise ValueError(f"unknown attention {cfg.attention!r}")
+    raise ValueError(
+        f"attention={kind!r} is local attention: under the bound "
+        f"{sp!r} axis each rank would attend its own sequence shard only; "
+        "use 'ring' or 'ulysses'")
 
 
 def _block(cfg: GPTConfig, layer_params, x, positions):

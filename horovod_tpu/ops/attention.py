@@ -1,0 +1,56 @@
+"""What every attention path shares: the dense reference, the rotary
+embedding and grouped-query head tiling. Pure ``jax.numpy``.
+
+The flash kernels (:mod:`horovod_tpu.ops.flash_attention`), ring attention
+and Ulysses (:mod:`horovod_tpu.parallel`) and the GPT block
+(:mod:`horovod_tpu.models.gpt`) import from here, and the tests hold each
+of them to :func:`default_attention`. A new attention kind (a window, a
+segment mask) starts with its reference in this file.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+
+def default_attention(q, k, v, causal: bool = True):
+    """Plain softmax attention. q/k/v: [B, S, H, D]. Computed in fp32 softmax.
+
+    Materializes the ``[B, H, S, S]`` float32 logits: the reference the other
+    paths are tested against, not a path to train long sequences on."""
+    scale = 1.0 / np.sqrt(q.shape[-1])
+    logits = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) * scale
+    if causal:
+        qlen, klen = logits.shape[-2], logits.shape[-1]
+        mask = jnp.tril(jnp.ones((qlen, klen), dtype=bool), klen - qlen)
+        logits = jnp.where(mask, logits, jnp.finfo(jnp.float32).min)
+    probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
+    return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
+
+
+def rope(x, positions):
+    """Rotary position embedding. x: [B, S, H, D]; positions: [B, S]."""
+    d = x.shape[-1]
+    half = d // 2
+    freqs = 1.0 / (10000.0 ** (jnp.arange(half, dtype=jnp.float32) / half))
+    angles = positions[..., None].astype(jnp.float32) * freqs  # [B, S, half]
+    cos = jnp.cos(angles)[:, :, None, :].astype(x.dtype)
+    sin = jnp.sin(angles)[:, :, None, :].astype(x.dtype)
+    x1, x2 = x[..., :half], x[..., half:]
+    return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
+
+
+def repeat_kv_heads(k, n_q_heads: int):
+    """Grouped-query attention: tile K/V heads up to the query head count
+    (the compact heads are what cross the wire; the repeat is local).
+    Shared by ring, Ulysses and dense attention; the flash kernels read
+    K/V at their own head count instead."""
+    n_kv = k.shape[2]
+    if n_kv == n_q_heads:
+        return k
+    if n_q_heads % n_kv:
+        raise ValueError(
+            f"query heads ({n_q_heads}) not a multiple of kv heads ({n_kv})")
+    return jnp.repeat(k, n_q_heads // n_kv, axis=2)

@@ -3,7 +3,7 @@
 The reference is a collective-communication library and ships no attention
 kernels; this is a TPU-first extension for the GPT / long-context path
 (SURVEY.md §2.7: long-context is in scope for the rebuild). Plain attention
-(``models/transformer.py default_attention``) materializes the full
+(``ops/attention.py default_attention``) materializes the full
 ``[B, H, S, S]`` fp32 logits tensor in HBM — at S=4096 that is ~2 GB per
 layer per pass, which is exactly the HBM-bandwidth wall flash attention
 exists to avoid. Algorithm: FlashAttention online-softmax tiling
@@ -103,20 +103,6 @@ def _use_interpret() -> bool:
     # everything else (the CPU-mesh tests) runs the interpreter.
     from ..compression.quantize import _pallas_backend_enabled
     return not _pallas_backend_enabled(None)
-
-
-def repeat_kv_heads(k, n_q_heads: int):
-    """Grouped-query attention: tile K/V heads up to the query head count
-    (the compact heads are what cross the wire; the repeat is local).
-    Shared by ring, Ulysses and dense attention; the flash kernels read
-    K/V at their own head count instead."""
-    n_kv = k.shape[2]
-    if n_kv == n_q_heads:
-        return k
-    if n_q_heads % n_kv:
-        raise ValueError(
-            f"query heads ({n_q_heads}) not a multiple of kv heads ({n_kv})")
-    return jnp.repeat(k, n_q_heads // n_kv, axis=2)
 
 
 def vmem_estimate(kernel: str, block_q: int, block_k: int, d: int,

@@ -6,7 +6,5 @@ from .optimizer import (DistributedOptimizer, DistributedGradientTape,  # noqa: 
                         broadcast_optimizer_state)
 from .adasum import adasum_p, adasum_reference  # noqa: F401
 from .sharded_optimizer import ShardedDistributedOptimizer  # noqa: F401
-from .ring_attention import (ring_attention, ring_attention_p,  # noqa: F401
-                             make_ring_attention)
-from .ulysses import (ulysses_attention, ulysses_attention_p,  # noqa: F401
-                      make_ulysses_attention)
+from .ring_attention import ring_attention, ring_attention_p  # noqa: F401
+from .ulysses import ulysses_attention, ulysses_attention_p  # noqa: F401

@@ -18,7 +18,7 @@ for any other layout.
 
 from __future__ import annotations
 
-from typing import Callable, Optional
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -27,9 +27,8 @@ from jax import lax
 
 from .. import runtime
 from ..ops import collectives as C
-# Shared with flash attention; ops is the lower layer, so parallel imports
-# from it.
-from ..ops.flash_attention import repeat_kv_heads as _repeat_kv_heads
+# ops is the lower layer, so parallel imports from it (and never from models).
+from ..ops.attention import repeat_kv_heads as _repeat_kv_heads
 
 SP_AXIS = "sp"
 
@@ -41,8 +40,8 @@ def _default_axis(axis: Optional[str]) -> Optional[str]:
 
     Deliberately NOT the data-parallel axis: ringing over dp would attend
     queries against other ranks' K/V from different *batch* elements and
-    silently produce garbage. Returns None when no axis applies (callers
-    either raise or fall back to plain attention)."""
+    silently produce garbage. Returns None when no axis applies (the
+    caller raises)."""
     if axis is not None:
         return axis
     try:
@@ -163,16 +162,3 @@ def ring_attention(q, k, v, causal: bool = True, axis: Optional[str] = None,
         mesh=mesh, in_specs=(seq_spec,) * 3 + (P(ax),) * 2,
         out_specs=seq_spec)
     return mapped(q, k, v, q_positions, kv_positions)
-
-
-def make_ring_attention(axis: Optional[str] = None) -> Callable:
-    """Adapter producing an ``attn_fn(q, k, v, causal=True)`` for
-    :class:`horovod_tpu.models.Transformer`. Falls back to plain attention when
-    the mesh axis is not bound (e.g. single-device eval of the same model)."""
-    def attn_fn(q, k, v, causal: bool = True):
-        ax = _default_axis(axis)
-        if ax is not None and C.in_named_trace(ax):
-            return ring_attention_p(q, k, v, causal=causal, axis=ax)
-        from ..models.transformer import default_attention
-        return default_attention(q, k, v, causal=causal)
-    return attn_fn

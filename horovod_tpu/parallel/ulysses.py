@@ -18,10 +18,11 @@ from typing import Callable, Optional
 import jax
 from jax import lax
 
-from .. import runtime  # noqa: F401  (re-exported context for callers)
+from .. import runtime
 from ..ops import collectives as C
-from .ring_attention import _default_axis, _require_axis
-from ..ops.flash_attention import repeat_kv_heads as _repeat_kv_heads
+from ..ops.attention import repeat_kv_heads as _repeat_kv_heads
+from ..ops.flash_attention import flash_attention
+from .ring_attention import _require_axis
 
 
 def _heads_first(x, ax: str):
@@ -36,15 +37,17 @@ def _seq_first(x, ax: str):
 
 def ulysses_attention_p(q, k, v, causal: bool = True,
                         axis: Optional[str] = None,
-                        attn_fn: Optional[Callable] = None):
+                        attn_fn: Callable = flash_attention):
     """In-step Ulysses attention over mesh axis ``axis``.
 
     Args:
       q, k, v: ``[B, S_shard, H, D]`` sequence-sharded blocks; ``H`` must be
         divisible by the mesh-axis size (heads are scattered across it).
       attn_fn: inner full-sequence attention, signature
-        ``(q, k, v, causal=...)``; default plain softmax attention. A Pallas
-        flash kernel drops in here unchanged.
+        ``(q, k, v, causal=...)``; default the flash kernel
+        (:func:`horovod_tpu.ops.flash_attention.flash_attention`): each
+        device holds the whole sequence, so a dense inner would hold its
+        S x S logits. Tests pass the dense reference here.
     """
     ax = _require_axis(axis, "ulysses_attention_p")
     n = lax.axis_size(ax)
@@ -52,9 +55,6 @@ def ulysses_attention_p(q, k, v, causal: bool = True,
         raise ValueError(
             f"Ulysses needs heads ({q.shape[2]}) divisible by the "
             f"'{ax}' axis size ({n}); use ring_attention otherwise")
-    if attn_fn is None:
-        from ..models.transformer import default_attention
-        attn_fn = default_attention
     # GQA: repeat K/V heads up to the query head count *before* the exchange so
     # the head scatter keeps query head i aligned with its kv group (jnp.repeat
     # is a block repeat, matching head i -> kv head i // group). Costs alltoall
@@ -67,7 +67,7 @@ def ulysses_attention_p(q, k, v, causal: bool = True,
 
 
 def ulysses_attention(q, k, v, causal: bool = True, axis: Optional[str] = None,
-                      attn_fn: Optional[Callable] = None):
+                      attn_fn: Callable = flash_attention):
     """Ulysses attention, in-step or eager (shard_maps itself when the mesh
     axis is not bound — mirrors :func:`ring_attention`)."""
     ax = _require_axis(axis, "ulysses_attention")
@@ -82,20 +82,3 @@ def ulysses_attention(q, k, v, causal: bool = True, axis: Optional[str] = None,
                                             attn_fn=attn_fn),
         mesh=mesh, in_specs=(seq_spec,) * 3, out_specs=seq_spec)
     return mapped(q, k, v)
-
-
-def make_ulysses_attention(axis: Optional[str] = None,
-                           attn_fn: Optional[Callable] = None) -> Callable:
-    """Adapter producing an ``attn_fn(q, k, v, causal=True)`` for
-    :class:`horovod_tpu.models.Transformer` (falls back to the inner attention
-    when the mesh axis is not bound)."""
-    def fn(q, k, v, causal: bool = True):
-        ax = _default_axis(axis)
-        if ax is not None and C.in_named_trace(ax):
-            return ulysses_attention_p(q, k, v, causal=causal, axis=ax,
-                                       attn_fn=attn_fn)
-        if attn_fn is not None:
-            return attn_fn(q, k, v, causal=causal)
-        from ..models.transformer import default_attention
-        return default_attention(q, k, v, causal=causal)
-    return fn

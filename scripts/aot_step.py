@@ -1,8 +1,9 @@
 #!/usr/bin/env python3
 """Compile a benchmark cell's real training step for a v5e that is described
-and not attached, in the CPU sandbox: what the compiler says of its memory and
-how many of each named kernel the compiled program holds. Nothing runs and no
-time is taken; a compile that passes is not a chip run.
+and not attached, in the CPU sandbox: a sha256 of the lowered StableHLO, what
+the compiler says of its memory and how many of each named kernel the
+compiled program holds. Nothing runs and no time is taken; a compile that
+passes is not a chip run.
 
     python3 scripts/aot_step.py starcoder2-3b_s4096 olmoe-1b-7b_s4096
 
@@ -15,6 +16,8 @@ the parent commit) under this script.
 from __future__ import annotations
 
 import argparse
+import base64
+import hashlib
 import importlib
 import json
 import os
@@ -28,6 +31,29 @@ os.environ.setdefault("TPU_LOG_DIR", "disabled")
 KERNELS = ("hvd_flash_fwd", "hvd_flash_dkdv", "hvd_flash_dq",
            "ragged-dot-none")
 GIB = 2.0 ** 30
+
+
+_KERNEL_BODY = re.compile(r'(?<=\\22body\\22: \\22)[A-Za-z0-9+/=]+(?=\\22)')
+
+
+def program_text(lowered) -> str:
+    """The lowered StableHLO without source locations. The text has none of
+    its own, but a Mosaic kernel travels in it as its module's bytes, which
+    hold the file and line of every operation of the kernel's source: each
+    is replaced by the module's assembly printed without them."""
+    from jax._src.interpreters import mlir
+    from jax._src.lib import tpu
+    from jax._src.lib.mlir import ir
+    context = mlir.make_ir_context()
+    tpu.register_dialect(context)
+    context.allow_unregistered_dialects = True
+
+    def assembly(match):
+        with context:
+            module = ir.Module.parse(base64.b64decode(match.group(0)))
+            return module.operation.get_asm(enable_debug_info=False)
+
+    return _KERNEL_BODY.sub(assembly, lowered.as_text())
 
 
 def compile_cell(name: str, root: str) -> dict:
@@ -59,13 +85,17 @@ def compile_cell(name: str, root: str) -> dict:
         jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=NamedSharding(
             hvd.mesh(), hvd.batch_spec(0)))
         for x in job.host_batches(1)[0])
+    lowered = job.step.lower(params, opt_state, data)
     t0 = time.time()
-    compiled = job.step.lower(params, opt_state, data).compile()
+    compiled = lowered.compile()
     seconds = time.time() - t0
     m = compiled.memory_analysis()
     text = compiled.as_text()
     return {
         "cell": name, "compile_s": round(seconds, 1),
+        # Equal on two checkouts, the step is the same program on both.
+        "stablehlo_sha256": hashlib.sha256(
+            program_text(lowered).encode()).hexdigest(),
         "arguments_gib": round(m.argument_size_in_bytes / GIB, 3),
         "temporaries_gib": round(m.temp_size_in_bytes / GIB, 3),
         # What the step holds at once; outputs that alias donated

@@ -136,7 +136,7 @@ DENSE_CASES = (
 def check_against_dense(cases=DENSE_CASES):
     """The compiled kernels against dense attention: GQA, causal and not,
     an unaligned length, tiles the table gives and unequal forced ones."""
-    from horovod_tpu.models.transformer import default_attention
+    from horovod_tpu.ops.attention import default_attention, repeat_kv_heads
     for dtype, s, causal, blocks in cases:
         ks = jax.random.split(jax.random.PRNGKey(s), 4)
         q = jax.random.normal(ks[0], (1, s, 4, 128), dtype) * 0.5
@@ -155,7 +155,7 @@ def check_against_dense(cases=DENSE_CASES):
             q, k, v = (x.astype(jnp.float32) for x in (q, k, v))
             with jax.default_matmul_precision("highest"):
                 return default_attention(
-                    q, fa.repeat_kv_heads(k, 4), fa.repeat_kv_heads(v, 4),
+                    q, repeat_kv_heads(k, 4), repeat_kv_heads(v, 4),
                     causal=causal)
         got = jax.jit(jax.value_and_grad(
             lambda *a: loss(flash, *a), argnums=(0, 1, 2)))(q, k, v)

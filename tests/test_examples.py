@@ -46,8 +46,6 @@ def _run_example(name, args, timeout=420):
     ("estimator_parquet.py", ["--epochs", "2"], None),
     ("torch_estimator_train.py", ["--epochs", "4", "--rows", "256"],
      "torch estimator ok"),
-    ("bert_mlm.py", ["--steps", "25", "--batch", "16", "--seq", "32"],
-     "bert mlm ok"),
     ("hierarchical_cross_slice.py", ["--steps", "2"],
      "hierarchical cross-slice training ok"),
     ("jax_synthetic_benchmark.py",

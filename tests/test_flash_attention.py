@@ -1,7 +1,7 @@
 """Flash attention Pallas kernels vs the plain softmax reference.
 
 Runs under interpret mode on the CPU mesh (pallas_call(interpret=True)):
-values AND gradients must match models.transformer.default_attention, which
+values AND gradients must match ops.attention.default_attention, which
 is itself validated against hand math elsewhere. NOTE interpret mode does
 not validate Mosaic lowering — on-chip validation happens via the bench
 kernel microbench (same policy as the quantize kernels).
@@ -13,7 +13,7 @@ import numpy as np
 import pytest
 
 import horovod_tpu as hvd
-from horovod_tpu.models.transformer import default_attention
+from horovod_tpu.ops.attention import default_attention, repeat_kv_heads
 from horovod_tpu.observability import sample_value
 from horovod_tpu.ops import flash_attention as fa
 from horovod_tpu.ops.flash_attention import flash_attention
@@ -100,8 +100,8 @@ def _value_and_grads(fn, q, k, v, w, **kw):
 
 def _dense_on_repeated_heads(q, k, v, causal):
     h = q.shape[2]
-    return default_attention(q, fa.repeat_kv_heads(k, h),
-                             fa.repeat_kv_heads(v, h), causal=causal)
+    return default_attention(q, repeat_kv_heads(k, h),
+                             repeat_kv_heads(v, h), causal=causal)
 
 
 # Forced tiles: unequal blocks put the diagonal through a tile's interior,

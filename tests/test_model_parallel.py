@@ -172,6 +172,13 @@ def test_stage_partition():
         stage_partition(7, 2)
 
 
+def _reference(cfg):
+    """``cfg`` for an unsharded run that gives an expected value: the dense
+    reference by name (unsharded, "ring" and "ulysses" are the flash kernel,
+    which is what some of these tests hold to the reference)."""
+    return dataclasses.replace(cfg, attention="dense", sp_axis=None)
+
+
 @pytest.mark.parametrize("attention", ["ring", "ulysses"])
 def test_gpt_tp_sp_dp_forward_parity(make_runtime, attention):
     """dp=2 x tp=2 x sp=2 sharded forward == single-device forward."""
@@ -184,7 +191,7 @@ def test_gpt_tp_sp_dp_forward_parity(make_runtime, attention):
     tokens = jax.random.randint(jax.random.PRNGKey(6), (B, S), 0, 64)
     positions = jnp.broadcast_to(jnp.arange(S), (B, S))
 
-    expected = gpt.forward(params, tokens, positions, cfg)  # unsharded
+    expected = gpt.forward(params, tokens, positions, _reference(cfg))
 
     step = hvd.run_step(
         lambda p, t, pos: gpt.forward(p, t, pos, cfg),
@@ -285,7 +292,7 @@ def test_gpt_moe_ep_parity(make_runtime, top_k):
     targets = jnp.roll(tokens, -1, axis=1).at[:, -1].set(-1)
     positions = jnp.broadcast_to(jnp.arange(S), (B, S))
 
-    expected = gpt.forward(params, tokens, positions, cfg)
+    expected = gpt.forward(params, tokens, positions, _reference(cfg))
 
     data = P(("dp", "ep"), "sp")
     step = hvd.run_step(
@@ -299,7 +306,7 @@ def test_gpt_moe_ep_parity(make_runtime, top_k):
     # loss, both auxiliary terms and the counts are the unsharded ones, and
     # the gradients (the experts' are ep shards) too.
     make_runtime(mesh_shape={"ep": 4}, devices=jax.devices()[:4])
-    cfg = dataclasses.replace(cfg, attention="dense", sp_axis=None)
+    cfg = _reference(cfg)
     value_and_grad = jax.value_and_grad(
         lambda p, *d: gpt.loss_and_aux(p, *d, cfg), has_aux=True)
     (want, want_aux), want_grads = value_and_grad(
@@ -373,12 +380,9 @@ def test_gpt_loss_and_grads_replicated(make_runtime):
     targets = jnp.roll(tokens, -1, axis=1).at[:, -1].set(-1)
     positions = jnp.broadcast_to(jnp.arange(S), (B, S))
 
-    def ref():
-        return gpt.loss_fn(params, tokens, targets, positions, cfg)
-
-    expected_loss = ref()
-    expected_grads = jax.grad(
-        lambda p: gpt.loss_fn(p, tokens, targets, positions, cfg))(params)
+    expected_loss, expected_grads = jax.value_and_grad(
+        lambda p: gpt.loss_fn(p, tokens, targets, positions,
+                              _reference(cfg)))(params)
 
     def body(p, t, tg, pos):
         # Per-dp-shard loss; average over dp to the global mean.

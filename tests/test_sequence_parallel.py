@@ -5,15 +5,17 @@ Test shapes follow the reference's op-test pattern (SURVEY.md §4): correctness
 vs. a local model of the computation, plus gradient correctness.
 """
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import PartitionSpec as P
 
 import horovod_tpu as hvd
-from horovod_tpu.models.transformer import Transformer, default_attention
-from horovod_tpu.parallel.ring_attention import make_ring_attention
-from horovod_tpu.parallel.ulysses import make_ulysses_attention
+from horovod_tpu.models import gpt
+from horovod_tpu.ops.attention import default_attention
 
 
 def _qkv(rng, batch=2, seq=32, heads=4, kv_heads=None, dim=8,
@@ -60,7 +62,6 @@ def test_ring_attention_gradients(make_runtime):
 
     expected = jax.grad(ref_loss, argnums=(0, 1, 2))(q, k, v)
 
-    from jax.sharding import PartitionSpec as P
     spec = P(None, "sp")
 
     def body(q, k, v):
@@ -79,7 +80,8 @@ def test_ulysses_matches_reference(make_runtime, causal):
     make_runtime(mesh_shape={"sp": 8})
     q, k, v = _qkv(jax.random.PRNGKey(3), heads=8)
     expected = default_attention(q, k, v, causal=causal)
-    got = hvd.ulysses_attention(q, k, v, causal=causal, axis="sp")
+    got = hvd.ulysses_attention(q, k, v, causal=causal, axis="sp",
+                                attn_fn=default_attention)
     np.testing.assert_allclose(np.asarray(got), np.asarray(expected),
                                rtol=1e-5, atol=1e-5)
 
@@ -90,7 +92,8 @@ def test_ulysses_gqa(make_runtime):
     kr = jnp.repeat(k, 4, axis=2)
     vr = jnp.repeat(v, 4, axis=2)
     expected = default_attention(q, kr, vr, causal=True)
-    got = hvd.ulysses_attention(q, k, v, causal=True, axis="sp")
+    got = hvd.ulysses_attention(q, k, v, causal=True, axis="sp",
+                                attn_fn=default_attention)
     np.testing.assert_allclose(np.asarray(got), np.asarray(expected),
                                rtol=1e-5, atol=1e-5)
 
@@ -110,76 +113,73 @@ def test_ulysses_head_divisibility_error(make_runtime):
         hvd.ulysses_attention(q, k, v, axis="sp")
 
 
-@pytest.mark.parametrize("attn_name", ["ring", "ulysses"])
-def test_transformer_sequence_parallel_forward(make_runtime, attn_name):
-    """Full model forward under sequence sharding == unsharded forward."""
-    make_runtime(mesh_shape={"sp": 8})
-    seq = 32
-    make = make_ring_attention if attn_name == "ring" else make_ulysses_attention
-    model_sp = Transformer(vocab_size=64, num_layers=2, num_heads=8,
-                           head_dim=8, embed_dim=32, mlp_dim=64,
-                           dtype=jnp.float32, attn_fn=make(axis="sp"))
-    model_ref = Transformer(vocab_size=64, num_layers=2, num_heads=8,
-                            head_dim=8, embed_dim=32, mlp_dim=64,
-                            dtype=jnp.float32)
-    tokens = jax.random.randint(jax.random.PRNGKey(5), (2, seq), 0, 64)
+def _tiny_gpt(attention, seq, batch=2):
+    cfg = gpt.GPTConfig(vocab_size=64, num_layers=2, num_heads=8,
+                        head_dim=8, embed_dim=32, mlp_dim=64,
+                        dtype=jnp.float32, tp_axis=None, sp_axis="sp",
+                        attention=attention)
+    params = gpt.init_params(jax.random.PRNGKey(0), cfg)
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (batch, seq), 0,
+                                cfg.vocab_size)
+    targets = jnp.roll(tokens, -1, axis=1).at[:, -1].set(-1)
     positions = jnp.broadcast_to(jnp.arange(seq), tokens.shape)
-    params = model_ref.init(jax.random.PRNGKey(6), tokens, positions)
-    expected = model_ref.apply(params, tokens, positions)
+    return cfg, params, (tokens, targets, positions)
 
-    from jax.sharding import PartitionSpec as P
-    step = hvd.run_step(
-        lambda p, t, pos: model_sp.apply(p, t, pos),
-        in_specs=(hvd.REPLICATED, P(None, "sp"), P(None, "sp")),
-        out_specs=P(None, "sp"))
-    got = step(params, tokens, positions)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(expected),
+
+def _reference(cfg):
+    """The same model on one device, through the dense reference by name."""
+    return dataclasses.replace(cfg, attention="dense", sp_axis=None)
+
+
+@pytest.mark.parametrize("attention", ["ring", "ulysses"])
+def test_gpt_sequence_parallel_forward(make_runtime, attention):
+    """Full model under sequence sharding == unsharded: the logits, and the
+    loss with its gradient (Ulysses runs the flash kernel on each device)."""
+    make_runtime(mesh_shape={"sp": 8})
+    cfg, params, (tokens, targets, positions) = _tiny_gpt(attention, seq=32)
+    ref = _reference(cfg)
+    expected = gpt.forward(params, tokens, positions, ref)
+    want_loss, want_grads = jax.value_and_grad(
+        lambda p: gpt.loss_fn(p, tokens, targets, positions, ref))(params)
+
+    seq = P(None, "sp")
+    logits = hvd.run_step(
+        lambda p, t, pos: gpt.forward(p, t, pos, cfg),
+        in_specs=(hvd.REPLICATED, seq, seq), out_specs=seq)(
+            params, tokens, positions)
+    np.testing.assert_allclose(np.asarray(logits), np.asarray(expected),
                                rtol=2e-4, atol=2e-4)
+    loss, grads = hvd.run_step(
+        jax.value_and_grad(lambda p, *d: gpt.loss_fn(p, *d, cfg)),
+        in_specs=(hvd.REPLICATED, seq, seq, seq),
+        out_specs=hvd.REPLICATED)(params, tokens, targets, positions)
+    np.testing.assert_allclose(float(loss), float(want_loss), rtol=1e-5)
+    for g, w in zip(jax.tree.leaves(grads), jax.tree.leaves(want_grads)):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   rtol=5e-4, atol=5e-5)
 
 
 def test_ulysses_with_flash_inner_matches_reference(make_runtime):
-    """Flash kernel as Ulysses' per-device full-sequence attention
-    (attention="ulysses_flash" in GPT): values must match dense attention
+    """Ulysses' default per-device attention is the flash kernel (what
+    attention="ulysses" runs in GPT): values must match dense attention
     (interpret mode here; Mosaic-compiled on TPU)."""
-    from horovod_tpu.ops.flash_attention import flash_attention
     make_runtime(mesh_shape={"sp": 8})
     q, k, v = _qkv(jax.random.PRNGKey(11), heads=8)
     expected = default_attention(q, k, v, causal=True)
-    got = hvd.ulysses_attention(q, k, v, causal=True, axis="sp",
-                                attn_fn=flash_attention)
+    got = hvd.ulysses_attention(q, k, v, causal=True, axis="sp")
     np.testing.assert_allclose(np.asarray(got), np.asarray(expected),
                                rtol=2e-3, atol=2e-3)
 
 
 def test_gpt_ulysses_flash_matches_dense(make_runtime):
-    """GPT forward parity: attention="ulysses_flash" under a bound sp axis
-    equals the dense single-device computation."""
-    import dataclasses
-
-    from jax.sharding import PartitionSpec as P
-
-    from horovod_tpu.models import gpt
+    """GPT loss parity: attention="ulysses" under a bound sp axis (the flash
+    kernel inside) equals the dense single-device computation."""
     make_runtime(mesh_shape={"sp": 8})
-    cfg = gpt.GPTConfig(vocab_size=64, num_layers=2, num_heads=8,
-                        head_dim=8, embed_dim=32, mlp_dim=64,
-                        dtype=jnp.float32, tp_axis=None, sp_axis="sp",
-                        attention="ulysses_flash")
-    params = gpt.init_params(jax.random.PRNGKey(0), cfg)
-    B, S = 2, 16
-    tokens = jax.random.randint(jax.random.PRNGKey(1), (B, S), 0,
-                                cfg.vocab_size)
-    targets = jnp.roll(tokens, -1, axis=1).at[:, -1].set(-1)
-    positions = jnp.broadcast_to(jnp.arange(S), (B, S))
-
-    def body(p, t, tg, pos):
-        return gpt.loss_fn(p, t, tg, pos, cfg)
-
+    cfg, params, data = _tiny_gpt("ulysses", seq=16)
+    seq = P(None, "sp")
     loss_sp = jax.shard_map(
-        body, mesh=hvd.mesh(),
-        in_specs=(P(), P(None, "sp"), P(None, "sp"), P(None, "sp")),
-        out_specs=P())(params, tokens, targets, positions)
-
-    cfg_dense = dataclasses.replace(cfg, sp_axis=None, attention="dense")
-    loss_dense = gpt.loss_fn(params, tokens, targets, positions, cfg_dense)
+        lambda p, *d: gpt.loss_fn(p, *d, cfg), mesh=hvd.mesh(),
+        in_specs=(P(), seq, seq, seq), out_specs=P())(params, *data)
+    loss_dense = gpt.loss_fn(params, *data, _reference(cfg))
     np.testing.assert_allclose(float(loss_sp), float(loss_dense),
                                rtol=2e-3, atol=2e-3)

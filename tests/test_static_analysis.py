@@ -21,6 +21,7 @@ Four layers, one gate each:
 No clang, jax, or network required anywhere in this file.
 """
 
+import ast
 import os
 import re
 import shutil
@@ -311,3 +312,51 @@ class TestClangTargets:
         if shutil.which(tool) is None:
             assert "SKIPPED" in out, \
                 f"make {target} without {tool} must say SKIPPED:\n{out}"
+
+
+def _imported_modules(path):
+    """Every module a file imports, as an absolute dotted name: at module
+    level or inside a function, ``import x`` or ``from x import y``
+    (relative ones resolved against the file's package; ``from . import y``
+    gives ``<package>.y``)."""
+    package = os.path.relpath(os.path.dirname(path), REPO).split(os.sep)
+    found = set()
+    with open(path) as f:
+        tree = ast.parse(f.read(), path)
+    for node in ast.walk(tree):
+        if isinstance(node, ast.Import):
+            found.update(alias.name for alias in node.names)
+        elif isinstance(node, ast.ImportFrom):
+            base = package[:len(package) - node.level + 1] if node.level \
+                else []
+            module = ".".join(base + ([node.module] if node.module else []))
+            found.add(module)
+            found.update(f"{module}.{alias.name}" for alias in node.names)
+    return found
+
+
+def _python_files(*parts):
+    top = os.path.join(REPO, *parts)
+    return [os.path.join(d, name) for d, _, names in os.walk(top)
+            for name in names if name.endswith(".py")]
+
+
+class TestLayering:
+    """models/ stands on parallel/ and ops/, never the other way round: a
+    mask change is made in ``ops/attention.py``, the kernels and one row of
+    ``gpt._attention``, and nothing below ``models/`` has to follow it."""
+
+    @pytest.mark.parametrize("layer", ["ops", "parallel"])
+    def test_nothing_below_models_imports_it(self, layer):
+        files = _python_files("horovod_tpu", layer)
+        assert files, layer
+        up = {os.path.relpath(f, REPO): sorted(
+            m for m in _imported_modules(f)
+            if m.startswith("horovod_tpu.models")) for f in files}
+        assert not {f: m for f, m in up.items() if m}
+
+    def test_the_attention_reference_stands_alone(self):
+        """Pure ``jax.numpy``: nothing of this package, no flax."""
+        path = os.path.join(REPO, "horovod_tpu", "ops", "attention.py")
+        roots = {m.split(".")[0] for m in _imported_modules(path)}
+        assert roots <= {"__future__", "jax", "numpy"}, roots
