@@ -83,10 +83,12 @@ class GPTConfig:
     # FLOPs for HBM so long sequences fit: "none" stores every block
     # activation; "full" stores a block's input and what is dear to make
     # again (``SAVED_NAMES``: the flash kernel's output and log-sum-exp, the
-    # dense feed-forward's pre-activation) and recomputes the rest in
-    # backward: in bfloat16 2E + 2HD + 4H + 2M bytes a token a layer where
-    # the input alone is 2E (an expert block: no 2M); "dots" instead saves
-    # every matmul output (recompute only the cheap elementwise work).
+    # dense feed-forward's pre-activation, the expert layer's matrices in
+    # the compute dtype) and recomputes the rest in backward: in bfloat16
+    # 2E + 2HD + 4H + 2M bytes a token a layer where the input alone is 2E
+    # (an expert block: no 2M, and 6 bytes an expert parameter a layer);
+    # "dots" instead saves every matmul output (recompute only the cheap
+    # elementwise work).
     remat: str = "none"                      # "none" | "full" | "dots"
 
     @property
@@ -291,16 +293,19 @@ def _block(cfg: GPTConfig, layer_params, x, positions):
 
 
 # What ``remat="full"`` keeps from a block's forward pass beside its input:
-# the values whose recomputation is a kernel or the block's widest matmul,
-# named where they are born. The flash kernel's output and log-sum-exp
-# (``ops/flash_attention.py``; with either missing the kernel runs again) and
-# the dense feed-forward's pre-activation (``_block``). A block that produces
-# none of a name keeps nothing under it. Norms, rotary, projections,
-# activation and the whole expert layer stay recomputed: a grouped matmul's
-# output kept from the forward to the backward pass came back wrong on the
-# v5e at 8,192 rows and right at 65,536 (PERF.md, Findings, PR 26), so
-# ``parallel/moe.py`` names nothing.
-SAVED_NAMES = ("flash_out", "flash_lse", "ffn_pre_activation")
+# the values whose recomputation is a kernel, the block's widest matmul or a
+# pass over memory that buys nothing, named where they are born. The flash
+# kernel's output and log-sum-exp (``ops/flash_attention.py``; with either
+# missing the kernel runs again), the dense feed-forward's pre-activation
+# (``_block``), and the expert layer's three matrices in the compute dtype
+# (``parallel/moe.py``: a cast's output). A block that produces none of a
+# name keeps nothing under it. Norms, rotary, projections, the router, the
+# experts' sorted rows, gate and up products and activation stay recomputed:
+# the expert layer names nothing that lies in the sort's order, which the
+# backward pass makes again and which one near-tie in the recomputed router
+# shifts (``parallel/moe.py``'s docstring; PERF.md, Findings, PR 28).
+SAVED_NAMES = ("flash_out", "flash_lse", "ffn_pre_activation",
+               "moe_expert_matrices")
 _save_names = jax.checkpoint_policies.save_only_these_names(*SAVED_NAMES)
 
 

@@ -32,6 +32,23 @@ width ``m``; the partial sums meet in one ``psum`` after the combine.
 Gradients: the choice ``S_t`` is not differentiable; the router learns
 through the weights ``p_{t,e}`` and through the two auxiliary terms returned
 beside ``y`` (the load-balance term's token fractions are constants).
+
+Under ``jax.checkpoint`` (``models/gpt.py``, ``remat="full"``) the layer makes
+again, for its backward pass, the router, the sort, the sorted rows, and the
+gate and up products with their activation, and nothing else. The down
+projection and the weighted sum have a backward pass of their own
+(:func:`_down_and_combine`) that needs no expert's output, so their
+recomputation is dead code; and the three expert tensors in the compute dtype
+carry a name a checkpoint policy can keep (``checkpoint_name``:
+``"moe_expert_matrices"``, 6 bytes an expert parameter in bfloat16, in
+``gpt.SAVED_NAMES``), so the cast is made once. **Nothing whose rows lie in
+the sort's order is named**: the backward pass makes the router again, on
+the chip not always to the forward's choices (XLA is free to round its input
+otherwise in the two passes), and when one near-tie falls the other way every
+row behind it in the sort moves by one. A buffer kept in the forward's order
+and read in the recomputed one gives gradients that are wrong by their own
+size (PERF.md, Findings, PR 28; the top-k's two outputs kept with it hold the
+order still).
 """
 
 from __future__ import annotations
@@ -41,7 +58,9 @@ from typing import Any, Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
+from ..ops.collectives import pvary
 from .axes import axis_bound as _axis_bound, axis_size as _axis_size
 
 GROUPED_MATMUL = "ragged_dot"
@@ -63,6 +82,66 @@ def _permute_bwd(inv, g):
 
 
 _permute.defvjp(_permute_fwd, _permute_bwd)
+
+
+def _grouped(lhs, w, group_sizes, mine):
+    """``lhs``'s rows times their groups' matrices; with ``mine`` (expert
+    parallelism) the rows outside every group, which the grouped matmul
+    leaves unwritten, at zero."""
+    out = lax.ragged_dot(lhs, w, group_sizes)
+    return out if mine is None else jnp.where(mine, out, 0)
+
+
+@jax.custom_vjp
+def _down_and_combine(hidden, w_down, top_p, order, inv, group_sizes, mine):
+    """``y_t = sum_j p_{t,j} (hidden W_down)[inv[t k + j]]``, ``[T, d]``
+    float32: the down projection of the sorted rows, the rows put back in
+    token order and summed under their weights in float32.
+
+    Its backward pass needs the experts' outputs for one thing, the weights'
+    gradient ``<g_t, (hidden W_down)_row>``, and takes that number as
+    ``<g_row W_down^T, hidden_row>`` from the product it makes anyway for
+    ``hidden``'s gradient: under ``jax.checkpoint`` the down projection and
+    the gather that puts its rows back are not made again."""
+    return _down_and_combine_fwd(hidden, w_down, top_p, order, inv,
+                                 group_sizes, mine)[0]
+
+
+def _down_and_combine_fwd(hidden, w_down, top_p, order, inv, group_sizes,
+                          mine):
+    with jax.named_scope("experts"):
+        out_rows = _grouped(hidden, w_down, group_sizes, mine)       # [Tk, d]
+    with jax.named_scope("combine"):
+        back = out_rows[inv].reshape(*top_p.shape, -1)
+        y = jnp.sum(back.astype(jnp.float32) * top_p[:, :, None], axis=1)
+    return y, (hidden, w_down, top_p, order, inv, group_sizes, mine)
+
+
+def _down_and_combine_bwd(residuals, g):
+    hidden, w_down, top_p, order, inv, group_sizes, mine = residuals
+    top_k = top_p.shape[1]
+    with jax.named_scope("combine"):
+        g_rows = g.astype(hidden.dtype)[order // top_k]              # [Tk, d]
+        p_rows = top_p.reshape(-1)[order][:, None]
+        if mine is not None:
+            g_rows = jnp.where(mine, g_rows, 0)
+    with jax.named_scope("experts"):
+        # Both products by the grouped matmul's own transpose rules.
+        u, = jax.linear_transpose(
+            lambda h: lax.ragged_dot(h, w_down, group_sizes), hidden)(g_rows)
+        if mine is not None:
+            u = jnp.where(mine, u, 0)
+        d_w_down, = jax.linear_transpose(
+            lambda w: lax.ragged_dot((p_rows * hidden).astype(hidden.dtype),
+                                     w, group_sizes), w_down)(g_rows)
+        d_hidden = (p_rows * u).astype(hidden.dtype)
+    with jax.named_scope("combine"):
+        d_top_p = jnp.sum(u.astype(top_p.dtype) * hidden.astype(top_p.dtype),
+                          axis=1)[inv].reshape(top_p.shape)
+    return d_hidden, d_w_down, d_top_p, None, None, None, None
+
+
+_down_and_combine.defvjp(_down_and_combine_fwd, _down_and_combine_bwd)
 
 
 def moe_layer(x, router_w, w_gate, w_up, w_down, top_k: int,
@@ -131,19 +210,21 @@ def moe_layer(x, router_w, w_gate, w_up, w_down, top_k: int,
         rows = _permute(jnp.repeat(xt.astype(dtype), top_k, axis=0),
                         order, inv)                                  # [Tk, d]
 
-    def grouped(lhs, w):
-        out = lax.ragged_dot(lhs, w.astype(dtype), group_sizes)
-        return out if mine is None else jnp.where(mine, out, 0)
-
     with jax.named_scope("experts"):
+        w_gate, w_up, w_down = (
+            checkpoint_name(w.astype(dtype), "moe_expert_matrices")
+            for w in (w_gate, w_up, w_down))
         if mine is not None:
             rows = jnp.where(mine, rows, 0)
-        hidden = jax.nn.silu(grouped(rows, w_gate)) * grouped(rows, w_up)
-        out_rows = grouped(hidden, w_down)                           # [Tk, d]
-
+        hidden = (jax.nn.silu(_grouped(rows, w_gate, group_sizes, mine))
+                  * _grouped(rows, w_up, group_sizes, mine))
+    if _axis_bound(tp_axis):
+        # Each tp rank's share of the weights' gradient is a sum over its
+        # part of the width; autodiff adds them where this cast is.
+        top_p = pvary(top_p, tp_axis)
+    y = _down_and_combine(hidden, w_down, top_p, order, inv, group_sizes,
+                          mine)
     with jax.named_scope("combine"):
-        back = _permute(out_rows, inv, order).reshape(T, top_k, d)
-        y = jnp.sum(back.astype(jnp.float32) * top_p[:, :, None], axis=1)
         if _axis_bound(tp_axis):
             y = lax.psum(y, tp_axis)        # row-parallel expert width
         if ep:

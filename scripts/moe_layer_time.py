@@ -5,8 +5,12 @@
 
 At the ``olmoe-1b-7b_s4096`` cell's shapes (8192 tokens of 2048, 64 experts
 of 1024, 8 a token, bfloat16) it jits and times, host clock around
-``block_until_ready``: the layer's forward pass; forward and backward; the
-three grouped matmuls on sorted rows alone, forward and with their backward;
+``block_until_ready``: the layer's forward pass; forward and backward;
+forward and backward under ``jax.checkpoint``, as a ``remat="full"`` block
+runs the layer, keeping nothing and keeping what ``models/gpt.py::
+SAVED_NAMES`` lists (the layer's share of a change to that list, before the
+cell is run); the three grouped matmuls on sorted rows alone, forward and
+with their backward;
 the two row permutations alone; and the layer against the every-expert-on-
 every-token reference on 1024 tokens. One JSON line a row, also appended to
 ``chiprun_out/moe_layer_time.jsonl``. ``--skew`` routes every token to the
@@ -29,6 +33,7 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 from jax import lax  # noqa: E402
 
+from horovod_tpu.models import gpt  # noqa: E402
 from horovod_tpu.parallel import moe  # noqa: E402
 
 
@@ -87,12 +92,25 @@ def main() -> int:
         return jnp.sum(moe._permute(rows, perm, inv).astype(jnp.float32)
                        * rows.astype(jnp.float32))
 
+    def checkpointed(*names):
+        """ms of the layer's forward and backward with these names kept.
+        The value is asked for with the gradients: without it nothing needs
+        the first forward pass and XLA drops it."""
+        kept = jax.checkpoint(
+            layer, policy=jax.checkpoint_policies.save_only_these_names(
+                *names))
+        return timed(jax.jit(jax.value_and_grad(
+            kept, argnums=(0, 1, 2, 3, 4), has_aux=True)), h, *weights)
+
     out = {"tokens": T, "embed": d, "width": m, "experts": E, "top_k": k,
            "skew": args.skew, "device_kind": device.device_kind,
            "busiest_over_mean": float(counts.max() * E / counts.sum()),
            "layer_fwd_ms": timed(jax.jit(layer), h, *weights),
            "layer_fwd_bwd_ms": timed(jax.jit(jax.grad(
                layer, argnums=(0, 1, 2, 3, 4), has_aux=True)), h, *weights),
+           "layer_checkpointed_fwd_bwd_ms": {
+               "nothing": checkpointed(),
+               "SAVED_NAMES": checkpointed(*gpt.SAVED_NAMES)},
            "experts_fwd_ms": timed(jax.jit(experts), rows_in, *weights[1:]),
            "experts_fwd_bwd_ms": timed(jax.jit(jax.grad(
                experts, argnums=(0, 1, 2, 3))), rows_in, *weights[1:]),

@@ -16,11 +16,20 @@ from horovod_tpu.parallel.moe import moe_layer
 from horovod_tpu.parallel.pipeline import pipeline_apply, stage_partition
 
 
+def _checkpointed(f):
+    """``f`` as a ``remat="full"`` block runs it: what ``gpt.SAVED_NAMES``
+    lists kept, the rest made again for the backward pass."""
+    return jax.checkpoint(f, policy=gpt._full_policy)
+
+
+@pytest.mark.parametrize("remat", ["none", "full"])
 @pytest.mark.parametrize("top_k", [1, 2])
-def test_moe_layer_expert_parallel_matches_local(make_runtime, top_k):
+def test_moe_layer_expert_parallel_matches_local(make_runtime, top_k, remat):
     """Experts over ep=4, the batch over ep: output, both auxiliary terms,
     counts and every gradient equal the all-experts-local layer's on the
-    whole batch. Dropless either way, whatever the routing."""
+    whole batch. Dropless either way, whatever the routing; checkpointed
+    (the rows outside every group are zero in the combine's own backward
+    pass too) or not."""
     make_runtime(mesh_shape={"ep": 4}, devices=jax.devices()[:4])
     d, m, n_exp = 16, 32, 8
     ks = jax.random.split(jax.random.PRNGKey(0), 5)
@@ -42,7 +51,7 @@ def test_moe_layer_expert_parallel_matches_local(make_runtime, top_k):
                 aux = {k: jax.lax.pmax(v, axis) if k == "counts"
                        else jax.lax.pmean(v, axis) for k, v in aux.items()}
             return total + aux["load_balance"] + aux["router_z"], (y, aux)
-        return f
+        return _checkpointed(f) if axis and remat == "full" else f
 
     grad = lambda axis: jax.value_and_grad(  # noqa: E731
         loss(axis), argnums=(0, 1, 2, 3, 4), has_aux=True)
@@ -64,8 +73,11 @@ def test_moe_layer_expert_parallel_matches_local(make_runtime, top_k):
                                    atol=1e-5 * float(jnp.abs(w).max()))
 
 
-def test_moe_layer_tensor_parallel_expert_width(make_runtime):
-    """ep=2 x tp=2: the experts over ep, their width over tp."""
+@pytest.mark.parametrize("remat", ["none", "full"])
+def test_moe_layer_tensor_parallel_expert_width(make_runtime, remat):
+    """ep=2 x tp=2: the experts over ep, their width over tp. The output and
+    every gradient are the local layer's: the routing weights' is a sum over
+    the whole width, which each tp rank holds a part of."""
     make_runtime(mesh_shape={"ep": 2, "tp": 2}, devices=jax.devices()[:4])
     d, m, n_exp = 16, 32, 4
     ks = jax.random.split(jax.random.PRNGKey(1), 5)
@@ -74,16 +86,29 @@ def test_moe_layer_tensor_parallel_expert_width(make_runtime):
     w_gate = jax.random.normal(ks[4], (n_exp, d, m), jnp.float32) / 4
     w_up = jax.random.normal(ks[2], (n_exp, d, m), jnp.float32) / 4
     w_down = jax.random.normal(ks[3], (n_exp, m, d), jnp.float32) / 6
-    want, _ = moe_layer(x, router, w_gate, w_up, w_down, top_k=2,
-                        dtype=jnp.float32)
-    got = jax.shard_map(
-        lambda *a: moe_layer(*a, top_k=2, axis="ep", tp_axis="tp",
-                             dtype=jnp.float32)[0],
-        mesh=hvd.mesh(),
-        in_specs=(P("ep"), P(), P("ep", None, "tp"), P("ep", None, "tp"),
-                  P("ep", "tp", None)),
-        out_specs=P("ep"))(x, router, w_gate, w_up, w_down)
+    args = (x, router, w_gate, w_up, w_down)
+
+    def loss(axis, tp_axis):
+        def f(x, *w):
+            y, _ = moe_layer(x, *w, top_k=2, axis=axis, tp_axis=tp_axis,
+                             dtype=jnp.float32)
+            total = jnp.sum(y * jnp.cos(y))
+            return (jax.lax.psum(total, axis) if axis else total), y
+        return _checkpointed(f) if axis and remat == "full" else f
+
+    grad = lambda *axes: jax.value_and_grad(  # noqa: E731
+        loss(*axes), argnums=(0, 1, 2, 3, 4), has_aux=True)
+    (want_loss, want), want_grads = grad(None, None)(*args)
+    specs = (P("ep"), P(), P("ep", None, "tp"), P("ep", None, "tp"),
+             P("ep", "tp", None))
+    (got_loss, got), got_grads = jax.shard_map(
+        grad("ep", "tp"), mesh=hvd.mesh(), in_specs=specs,
+        out_specs=((P(), P("ep")), specs))(*args)
+    np.testing.assert_allclose(got_loss, want_loss, rtol=1e-5)
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+    for g, w in zip(got_grads, want_grads):
+        np.testing.assert_allclose(g, w, rtol=1e-4,
+                                   atol=1e-5 * float(jnp.abs(w).max()))
 
 
 def test_pipeline_matches_sequential(make_runtime):

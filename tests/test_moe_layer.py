@@ -23,6 +23,7 @@ if ROOT not in sys.path:
 import horovod_tpu as hvd  # noqa: E402
 from horovod_tpu.models import gpt  # noqa: E402
 from horovod_tpu.observability import sample_value  # noqa: E402
+from horovod_tpu.parallel import moe  # noqa: E402
 from horovod_tpu.parallel.moe import moe_layer  # noqa: E402
 
 from benchmarks import flops_moe  # noqa: E402
@@ -94,6 +95,61 @@ def test_one_expert_taking_every_token_drops_none():
     router = jnp.broadcast_to(router[:, :1], router.shape)
     counts = assert_layer_is_the_reference((h, router, *w), 2)
     assert counts.tolist() == [T, T] + [0] * (E - 2)
+
+
+@pytest.mark.parametrize("top_k, skew", [(1, False), (2, False), (8, False),
+                                         (2, True)])
+def test_down_and_combine_has_autodiffs_gradients(top_k, skew):
+    """The layer's tail (down projection, rows back in token order, weighted
+    sum) has a backward pass of its own, which takes the weights' gradient
+    from the hidden rows and never from the experts' outputs: held here to
+    autodiff of the plain formula, a matrix a row and no grouped matmul.
+
+    What that buys shows in ``test_full_remat_keeps_what_is_dear_to_make_
+    again[sparse]``: the backward pass has no use for the recomputed down
+    projection, JAX drops it from the checkpointed block's jaxpr as dead code
+    (the rule's forward is inlined there like any other code), and a layer's
+    count of grouped matmuls is 11 in the jaxpr as in the compiled step."""
+    ks = jax.random.split(jax.random.PRNGKey(top_k), 5)
+    f32 = jnp.float32
+    scores = jax.random.normal(ks[0], (T, E), f32)
+    if skew:        # every token to experts 0 and 1: ties to the lower index
+        scores = jnp.zeros((T, E), f32)
+    top_p, top_e = jax.lax.top_k(jax.nn.softmax(scores), top_k)
+    top_p = top_p * jax.random.uniform(ks[1], top_p.shape, f32, minval=0.5)
+    order = jnp.argsort(top_e.reshape(-1), stable=True)
+    inv = jnp.argsort(order)
+    expert_of_row = top_e.reshape(-1)[order]
+    counts = jnp.bincount(expert_of_row, length=E).astype(jnp.int32)
+    if skew:
+        assert counts.tolist() == [T, T] + [0] * (E - 2)
+    hidden = jax.random.normal(ks[2], (T * top_k, M), f32)
+    w_down = jax.random.normal(ks[3], (E, M, D), f32) / 5
+    weigh = jax.random.normal(ks[4], (T, D), f32)
+
+    def program(hidden, w_down, top_p):
+        return moe._down_and_combine(hidden, w_down, top_p, order, inv,
+                                     counts, None)
+
+    def plain(hidden, w_down, top_p):
+        out_rows = jnp.einsum("rm,rmd->rd", hidden, w_down[expert_of_row])
+        return jnp.sum(out_rows[inv].reshape(T, top_k, D)
+                       * top_p[:, :, None], axis=1)
+
+    wrt = (0, 1, 2)
+    loss = lambda f: lambda *a: jnp.sum(f(*a) * weigh)       # noqa: E731
+    with jax.default_matmul_precision("highest"):
+        y = program(hidden, w_down, top_p)
+        y_ref = plain(hidden, w_down, top_p)
+        grads = jax.grad(loss(program), argnums=wrt)(hidden, w_down, top_p)
+        grads_ref = jax.grad(loss(plain), argnums=wrt)(hidden, w_down, top_p)
+    np.testing.assert_allclose(y, y_ref, rtol=1e-5, atol=1e-5)
+    for name, g, g_ref in zip(("hidden", "w_down", "top_p"), grads,
+                              grads_ref):
+        assert g.shape == g_ref.shape and g.dtype == g_ref.dtype, name
+        np.testing.assert_allclose(
+            g, g_ref, rtol=1e-5, atol=1e-5 * float(jnp.abs(g_ref).max()),
+            err_msg=name)
 
 
 def olmoe(**kw):
@@ -190,10 +246,12 @@ def test_full_remat_keeps_what_is_dear_to_make_again(feed_forward):
     """Under ``remat="full"`` a block keeps the flash kernel's output and
     log-sum-exp and the dense feed-forward's pre-activation: the
     differentiated step holds no second flash forward and no second up
-    projection. The expert layer keeps nothing (a grouped matmul's output
-    kept across the passes came back wrong on the chip: PERF.md, Findings,
-    PR 26): its three grouped matmuls run again. The numbers are
-    ``"none"``'s."""
+    projection. The expert layer keeps its matrices in the compute dtype and
+    nothing that lies in the sort's order (the backward pass makes the
+    routing again, and on the chip not always to the same choices: PERF.md,
+    Findings, PR 28): the gate and up products run again, the down product
+    does not, because the backward pass of ``moe._down_and_combine`` has no
+    use for it. The numbers are ``"none"``'s."""
     kind = dict(moe_every=0, mlp_dim=48) if feed_forward == "dense" else {}
     full = olmoe(attention="flash", remat="full", **kind)
     none = dataclasses.replace(full, remat="none")
@@ -210,8 +268,8 @@ def test_full_remat_keeps_what_is_dear_to_make_again(feed_forward):
         assert ops["dot_general", (batch, seq, full.mlp_dim)] \
             == 2 * full.num_layers
     else:
-        # Three forward, three again, two each backward.
-        assert ops["ragged_dot_general"] == 12 * full.num_layers
+        # Three forward, gate and up again, two each backward.
+        assert ops["ragged_dot_general"] == 11 * full.num_layers
 
     (l0, a0), g0 = loss_and_grads(none, params, data)
     (l1, a1), g1 = loss_and_grads(full, params, data)
@@ -245,11 +303,14 @@ def test_metrics_count_what_a_checkpointed_block_keeps(make_runtime):
         == set(gpt.SAVED_NAMES)
     tokens, f32 = batch * seq, 4
     heads = sparse.num_heads * sparse.head_dim
-    # Two blocks split, a flash pair each; the dense block's up projection
-    # (the expert block keeps nothing of its feed-forward).
+    # Two blocks split, a flash pair each; the dense block's up projection;
+    # the expert block's three matrices an expert (float32 here: the cast
+    # that carries the name is to the compute dtype).
     want = {"flash_out": 2 * tokens * heads * f32,
             "flash_lse": 2 * tokens * sparse.num_heads * f32,
-            "ffn_pre_activation": tokens * sparse.mlp_dim * f32}
+            "ffn_pre_activation": tokens * sparse.mlp_dim * f32,
+            "moe_expert_matrices": (3 * sparse.num_experts * sparse.embed_dim
+                                    * sparse.mlp_dim * f32)}
     for name, nbytes in want.items():
         assert sample_value(fams, family, mode="full", name=name) == nbytes
 
@@ -316,7 +377,11 @@ def test_compiled_step_carries_the_expert_layers_scopes(make_runtime):
     for scope in ("router", "dispatch", "experts", "combine"):
         assert some("jvp(layer0)", f"/moe/{scope}/"), scope
         assert some("transpose(jvp(layer0))", f"/moe/{scope}/"), scope
+    # The recomputed pass: the router, the sort, the gate and up products.
+    # Nothing of the combine: its backward pass needs no expert's output.
+    for scope in ("router", "dispatch", "experts"):
         assert some(f"rematted_computation/moe/{scope}/"), scope
+    assert not some("rematted_computation/moe/combine/")
     assert some("jvp(aux_loss)")
     assert not some("/mlp/")
 
