@@ -27,6 +27,16 @@ bfloat16 activations / fp32 params+accumulators, RoPE, pre-norm RMSNorm;
 optionally an RMSNorm on the whole query and key projections (``qk_norm``).
 With expert blocks the loss carries the router's two auxiliary terms
 (:func:`loss_and_aux`).
+
+A layer's mixer follows its kind (``GPTConfig.layer_kinds``): ``"attention"``
+as above, or ``"ssm"``, a Mamba-2 state-space mixer (input projection,
+causal depthwise convolution, the chunked scan of
+:mod:`horovod_tpu.ops.ssd`, gated RMSNorm, output projection). A
+state-space layer runs on the sequence and the heads one rank holds: under a
+bound tp or sp axis it raises (``_ssm_mixer``). The dense feed-forward may
+be SiLU-gated, the rotary embedding left out, the head the embedding's
+transpose, and the embedding, the attention logits, each residual branch and
+the logits scaled by a constant.
 """
 
 from __future__ import annotations
@@ -43,6 +53,7 @@ from jax.sharding import PartitionSpec as P
 
 from ..ops.attention import default_attention, repeat_kv_heads, rope
 from ..ops.flash_attention import flash_attention
+from ..ops.ssd import causal_conv1d, ssd_chunked
 from ..parallel.ring_attention import ring_attention_p
 from ..parallel.ulysses import ulysses_attention_p
 
@@ -84,16 +95,57 @@ class GPTConfig:
     # activation; "full" stores a block's input and what is dear to make
     # again (``SAVED_NAMES``: the flash kernel's output and log-sum-exp, the
     # dense feed-forward's pre-activation, the expert layer's matrices in
-    # the compute dtype) and recomputes the rest in backward: in bfloat16
+    # the compute dtype, a state-space scan's output) and recomputes the
+    # rest in backward: in bfloat16
     # 2E + 2HD + 4H + 2M bytes a token a layer where the input alone is 2E
     # (an expert block: no 2M, and 6 bytes an expert parameter a layer);
     # "dots" instead saves every matmul output (recompute only the cheap
     # elementwise work).
     remat: str = "none"                      # "none" | "full" | "dots"
+    # Each layer's mixer, ``"attention"`` or ``"ssm"``, one entry a layer;
+    # None is attention throughout. A state-space mixer has ssm_heads heads
+    # of ssm_head_dim, a state of ssm_state a head, ssm_groups groups of
+    # heads that share B and C, a convolution of ssm_conv taps and a scan in
+    # chunks of ssm_chunk tokens.
+    layer_kinds: Optional[Tuple[str, ...]] = None
+    ssm_heads: int = 8
+    ssm_head_dim: int = 64
+    ssm_state: int = 128
+    ssm_groups: int = 1
+    ssm_conv: int = 4
+    ssm_chunk: int = 256
+    # The dense feed-forward as silu(gate) * up (three matrices) instead of
+    # gelu(up) (two).
+    gated_mlp: bool = False
+    # False: no position embedding on q and k.
+    rope: bool = True
+    # The head is the embedding's transpose: one parameter receives the
+    # gather's and the head's gradient.
+    tie_embeddings: bool = False
+    # Constants on the embedding, on the attention logits (None: one over
+    # the square root of head_dim), on each residual branch, and dividing
+    # the logits.
+    embedding_multiplier: float = 1.0
+    attention_multiplier: Optional[float] = None
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
 
     @property
     def kv_heads(self) -> int:
         return self.num_kv_heads or self.num_heads
+
+    def kind(self, layer: int) -> str:
+        return "attention" if self.layer_kinds is None \
+            else self.layer_kinds[layer]
+
+    @property
+    def ssm_inner(self) -> int:
+        return self.ssm_heads * self.ssm_head_dim
+
+    @property
+    def ssm_conv_dim(self) -> int:
+        """The convolved channels: x, B and C side by side."""
+        return self.ssm_inner + 2 * self.ssm_groups * self.ssm_state
 
 
 from ..parallel.axes import axis_size as _axis_size, axis_bound as _axis_bound
@@ -101,6 +153,46 @@ from ..parallel.axes import axis_size as _axis_size, axis_bound as _axis_bound
 
 def _is_moe(cfg: GPTConfig, layer: int) -> bool:
     return cfg.moe_every > 0 and (layer + 1) % cfg.moe_every == 0
+
+
+LAYER_KINDS = ("attention", "ssm")
+
+
+def _check_kinds(cfg: GPTConfig) -> None:
+    kinds = cfg.layer_kinds
+    if kinds is not None and (len(kinds) != cfg.num_layers
+                              or set(kinds) - set(LAYER_KINDS)):
+        raise ValueError(
+            f"layer_kinds must name one of {LAYER_KINDS} for each of the "
+            f"{cfg.num_layers} layers, got {kinds!r}")
+
+
+def _init_ssm(key, cfg: GPTConfig, dense) -> dict:
+    """A state-space mixer's parameters, initialised as the published
+    Mamba-2 code does: ``A`` uniform in [1, 16], the step size log-uniform
+    in [1e-3, 1e-1] (``dt_bias`` its inverse soft-plus), the skip at one,
+    the convolution as torch's ``Conv1d`` (uniform within one over the
+    square root of its taps)."""
+    E, H, inner = cfg.embed_dim, cfg.ssm_heads, cfg.ssm_inner
+    ks = jax.random.split(key, 6)
+    dt = jnp.exp(jax.random.uniform(ks[2], (H,), jnp.float32)
+                 * float(np.log(1e-1) - np.log(1e-3)) + float(np.log(1e-3)))
+    dt = jnp.maximum(dt, 1e-4)
+    bound = 1.0 / float(np.sqrt(cfg.ssm_conv))
+    return {
+        "in_proj": dense(ks[0], (E, inner + cfg.ssm_conv_dim + H), E),
+        "conv_w": jax.random.uniform(
+            ks[1], (cfg.ssm_conv, cfg.ssm_conv_dim), jnp.float32,
+            -bound, bound),
+        "conv_b": jax.random.uniform(ks[5], (cfg.ssm_conv_dim,), jnp.float32,
+                                     -bound, bound),
+        "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+        "A_log": jnp.log(jax.random.uniform(ks[3], (H,), jnp.float32,
+                                            1.0, 16.0)),
+        "D": jnp.ones((H,), jnp.float32),
+        "norm": jnp.ones((inner,), jnp.float32),
+        "out_proj": dense(ks[4], (inner, E), inner),
+    }
 
 
 def init_params(rng, cfg: GPTConfig) -> dict:
@@ -117,27 +209,34 @@ def init_params(rng, cfg: GPTConfig) -> dict:
         return (jax.random.normal(key, shape, jnp.float32) /
                 float(np.sqrt(fan_in)))
 
+    _check_kinds(cfg)
     keys = jax.random.split(rng, 2 + cfg.num_layers)
     params: dict = {
         "embed": jax.random.normal(keys[0], (cfg.vocab_size, E),
                                    jnp.float32) * 0.02,
         "out_norm": jnp.ones((E,), jnp.float32),
-        "lm_head": dense(keys[1], (E, cfg.vocab_size), E),
         "layers": [],
     }
+    if not cfg.tie_embeddings:
+        params["lm_head"] = dense(keys[1], (E, cfg.vocab_size), E)
     for i in range(cfg.num_layers):
         ks = jax.random.split(keys[2 + i], 8)
-        layer = {
-            "attn_norm": jnp.ones((E,), jnp.float32),
-            "wq": dense(ks[0], (E, H, D), E),
-            "wk": dense(ks[1], (E, Hkv, D), E),
-            "wv": dense(ks[2], (E, Hkv, D), E),
-            "wo": dense(ks[3], (H, D, E), H * D),
-            "mlp_norm": jnp.ones((E,), jnp.float32),
-        }
-        if cfg.qk_norm:
-            layer["q_norm"] = jnp.ones((H, D), jnp.float32)
-            layer["k_norm"] = jnp.ones((Hkv, D), jnp.float32)
+        if cfg.kind(i) == "ssm":
+            layer = {"ssm_norm": jnp.ones((E,), jnp.float32),
+                     "ssm": _init_ssm(ks[0], cfg, dense),
+                     "mlp_norm": jnp.ones((E,), jnp.float32)}
+        else:
+            layer = {
+                "attn_norm": jnp.ones((E,), jnp.float32),
+                "wq": dense(ks[0], (E, H, D), E),
+                "wk": dense(ks[1], (E, Hkv, D), E),
+                "wv": dense(ks[2], (E, Hkv, D), E),
+                "wo": dense(ks[3], (H, D, E), H * D),
+                "mlp_norm": jnp.ones((E,), jnp.float32),
+            }
+            if cfg.qk_norm:
+                layer["q_norm"] = jnp.ones((H, D), jnp.float32)
+                layer["k_norm"] = jnp.ones((Hkv, D), jnp.float32)
         if _is_moe(cfg, i):
             n_exp = cfg.num_experts
             layer["moe"] = {
@@ -147,6 +246,8 @@ def init_params(rng, cfg: GPTConfig) -> dict:
                 "w_down": dense(ks[6], (n_exp, M, E), M),
             }
         else:
+            if cfg.gated_mlp:
+                layer["w_gate"] = dense(ks[7], (E, M), E)
             layer["w_up"] = dense(ks[5], (E, M), E)
             layer["w_down"] = dense(ks[6], (M, E), M)
         params["layers"].append(layer)
@@ -155,26 +256,35 @@ def init_params(rng, cfg: GPTConfig) -> dict:
 
 def param_specs(cfg: GPTConfig) -> dict:
     """PartitionSpec pytree matching :func:`init_params` — tp shards heads and
-    MLP hidden; ep shards experts; everything else replicated."""
+    MLP hidden; ep shards experts; everything else replicated, a state-space
+    mixer included (it refuses a bound tp axis)."""
+    _check_kinds(cfg)
     tp, ep = cfg.tp_axis, cfg.ep_axis
     specs: dict = {
         "embed": P(),
         "out_norm": P(),
-        "lm_head": P(),
         "layers": [],
     }
+    if not cfg.tie_embeddings:
+        specs["lm_head"] = P()
     for i in range(cfg.num_layers):
-        layer = {
-            "attn_norm": P(),
-            "wq": P(None, tp, None),
-            "wk": P(None, tp, None),
-            "wv": P(None, tp, None),
-            "wo": P(tp, None, None),
-            "mlp_norm": P(),
-        }
-        if cfg.qk_norm:
-            layer["q_norm"] = P(tp, None)
-            layer["k_norm"] = P(tp, None)
+        if cfg.kind(i) == "ssm":
+            layer = {"ssm_norm": P(), "mlp_norm": P(), "ssm": {
+                name: P() for name in (
+                    "in_proj", "conv_w", "conv_b", "dt_bias", "A_log", "D",
+                    "norm", "out_proj")}}
+        else:
+            layer = {
+                "attn_norm": P(),
+                "wq": P(None, tp, None),
+                "wk": P(None, tp, None),
+                "wv": P(None, tp, None),
+                "wo": P(tp, None, None),
+                "mlp_norm": P(),
+            }
+            if cfg.qk_norm:
+                layer["q_norm"] = P(tp, None)
+                layer["k_norm"] = P(tp, None)
         if _is_moe(cfg, i):
             layer["moe"] = {
                 "router": P(),
@@ -183,6 +293,8 @@ def param_specs(cfg: GPTConfig) -> dict:
                 "w_down": P(ep, tp, None),
             }
         else:
+            if cfg.gated_mlp:
+                layer["w_gate"] = P(None, tp)
             layer["w_up"] = P(None, tp)
             layer["w_down"] = P(tp, None)
         specs["layers"].append(layer)
@@ -254,25 +366,85 @@ def _attention(cfg: GPTConfig, q, k, v):
         "use 'ring' or 'ulysses'")
 
 
+def _ssm_mixer(cfg: GPTConfig, p, h):
+    """A Mamba-2 mixer on normed activations ``h`` ``[B, S, E]``: ``[z | xBC
+    | dt] = h W_in``; ``xBC`` through the causal depthwise convolution and
+    SiLU, split into ``x``, ``B``, ``C``; ``dt = softplus(dt + dt_bias)``,
+    ``A = -exp(A_log)``, both float32; the chunked scan; ``RMSNorm(y *
+    silu(z))`` over the whole inner width; ``W_out``. The scan starts every
+    sequence a rank holds from a zero state and the norm runs over the heads
+    it holds, so a bound sp or tp axis is refused by name."""
+    for axis in (cfg.sp_axis, cfg.tp_axis):
+        if _axis_bound(axis):
+            raise ValueError(
+                f"a state-space layer runs on one rank's whole sequence and "
+                f"all its heads: the {axis!r} axis is bound (sp would scan "
+                "each sequence shard from a zero state, tp would norm a "
+                "shard of the heads); bind neither")
+    batch, seq = h.shape[:2]
+    heads, inner = cfg.ssm_heads, cfg.ssm_inner
+    groups, state = cfg.ssm_groups, cfg.ssm_state
+    with jax.named_scope("in_proj"):
+        zxbcdt = jnp.einsum("bse,ef->bsf", h, p["in_proj"].astype(cfg.dtype))
+        z, xbc, dt = jnp.split(
+            zxbcdt, [inner, inner + cfg.ssm_conv_dim], axis=-1)
+    with jax.named_scope("conv"):
+        xbc = jax.nn.silu(causal_conv1d(xbc, p["conv_w"], p["conv_b"])
+                          ).astype(cfg.dtype)
+        x, b_in, c_in = jnp.split(
+            xbc, [inner, inner + groups * state], axis=-1)
+    with jax.named_scope("scan"):
+        dt = jax.nn.softplus(dt.astype(jnp.float32) + p["dt_bias"])
+        y, _ = ssd_chunked(
+            x.reshape(batch, seq, heads, cfg.ssm_head_dim), dt,
+            -jnp.exp(p["A_log"]), b_in.reshape(batch, seq, groups, state),
+            c_in.reshape(batch, seq, groups, state), p["D"],
+            chunk=cfg.ssm_chunk, dtype=cfg.dtype)
+        y = checkpoint_name(y, "ssm_scan_out")
+    with jax.named_scope("gate_norm"):
+        gated = y.reshape(batch, seq, inner).astype(jnp.float32) \
+            * jax.nn.silu(z.astype(jnp.float32))
+        y = _rmsnorm(gated, p["norm"], cfg.dtype, cfg.norm_eps)
+    with jax.named_scope("out_proj"):
+        return jnp.einsum("bsf,fe->bse", y, p["out_proj"].astype(cfg.dtype))
+
+
+def _residual(cfg: GPTConfig, x, branch):
+    if cfg.residual_multiplier != 1.0:
+        branch = branch * cfg.residual_multiplier
+    return x + branch
+
+
 def _block(cfg: GPTConfig, layer_params, x, positions):
     """One decoder block: ``(x, aux)``, ``aux`` the expert layer's auxiliary
     terms (``parallel/moe.py``) or None for a dense block."""
     # The scopes sit inside the function ``jax.checkpoint`` wraps, so the
     # recomputed copy of a block carries them too (``forward`` has the rest).
     lp = layer_params
-    with jax.named_scope("attn"):
-        h = _rmsnorm(x, lp["attn_norm"], cfg.dtype, cfg.norm_eps)
-        q = jnp.einsum("bse,ehd->bshd", h, lp["wq"].astype(cfg.dtype))
-        k = jnp.einsum("bse,ehd->bshd", h, lp["wk"].astype(cfg.dtype))
-        v = jnp.einsum("bse,ehd->bshd", h, lp["wv"].astype(cfg.dtype))
-        if cfg.qk_norm:
-            q = _projection_norm(q, lp["q_norm"], cfg)
-            k = _projection_norm(k, lp["k_norm"], cfg)
-        q = rope(q, positions)
-        k = rope(k, positions)
-        attn = _attention(cfg, q, k, v)
-        o = jnp.einsum("bshd,hde->bse", attn, lp["wo"].astype(cfg.dtype))
-        x = x + _tp_psum(o, cfg)
+    if "ssm" in lp:
+        with jax.named_scope("ssm"):
+            h = _rmsnorm(x, lp["ssm_norm"], cfg.dtype, cfg.norm_eps)
+            x = _residual(cfg, x, _ssm_mixer(cfg, lp["ssm"], h))
+    else:
+        with jax.named_scope("attn"):
+            h = _rmsnorm(x, lp["attn_norm"], cfg.dtype, cfg.norm_eps)
+            q = jnp.einsum("bse,ehd->bshd", h, lp["wq"].astype(cfg.dtype))
+            k = jnp.einsum("bse,ehd->bshd", h, lp["wk"].astype(cfg.dtype))
+            v = jnp.einsum("bse,ehd->bshd", h, lp["wv"].astype(cfg.dtype))
+            if cfg.qk_norm:
+                q = _projection_norm(q, lp["q_norm"], cfg)
+                k = _projection_norm(k, lp["k_norm"], cfg)
+            if cfg.rope:
+                q = rope(q, positions)
+                k = rope(k, positions)
+            if cfg.attention_multiplier is not None:
+                # Every attention here scales its logits by one over the
+                # square root of head_dim: the rest goes onto q.
+                q = q * (cfg.attention_multiplier
+                         * float(np.sqrt(cfg.head_dim)))
+            attn = _attention(cfg, q, k, v)
+            o = jnp.einsum("bshd,hde->bse", attn, lp["wo"].astype(cfg.dtype))
+            x = _residual(cfg, x, _tp_psum(o, cfg))
 
     if "moe" in lp:
         with jax.named_scope("moe"):
@@ -283,13 +455,18 @@ def _block(cfg: GPTConfig, layer_params, x, positions):
                 h, m["router"], m["w_gate"], m["w_up"], m["w_down"],
                 top_k=cfg.experts_per_token, axis=cfg.ep_axis,
                 tp_axis=cfg.tp_axis, dtype=cfg.dtype)
-            return x + out, aux
+            return _residual(cfg, x, out), aux
     with jax.named_scope("mlp"):
         h = _rmsnorm(x, lp["mlp_norm"], cfg.dtype, cfg.norm_eps)
         up = jnp.einsum("bse,em->bsm", h, lp["w_up"].astype(cfg.dtype))
-        up = jax.nn.gelu(checkpoint_name(up, "ffn_pre_activation"))
+        up = checkpoint_name(up, "ffn_pre_activation")
+        if "w_gate" in lp:
+            gate = jnp.einsum("bse,em->bsm", h, lp["w_gate"].astype(cfg.dtype))
+            up = jax.nn.silu(gate) * up
+        else:
+            up = jax.nn.gelu(up)
         down = jnp.einsum("bsm,me->bse", up, lp["w_down"].astype(cfg.dtype))
-        return x + _tp_psum(down, cfg), None
+        return _residual(cfg, x, _tp_psum(down, cfg)), None
 
 
 # What ``remat="full"`` keeps from a block's forward pass beside its input:
@@ -297,15 +474,22 @@ def _block(cfg: GPTConfig, layer_params, x, positions):
 # pass over memory that buys nothing, named where they are born. The flash
 # kernel's output and log-sum-exp (``ops/flash_attention.py``; with either
 # missing the kernel runs again), the dense feed-forward's pre-activation
-# (``_block``), and the expert layer's three matrices in the compute dtype
-# (``parallel/moe.py``: a cast's output). A block that produces none of a
-# name keeps nothing under it. Norms, rotary, projections, the router, the
-# experts' sorted rows, gate and up products and activation stay recomputed:
-# the expert layer names nothing that lies in the sort's order, which the
-# backward pass makes again and which one near-tie in the recomputed router
-# shifts (``parallel/moe.py``'s docstring; PERF.md, Findings, PR 28).
+# (``_block``; of a gated one the up product, the gate is made again), the
+# expert layer's three matrices in the compute dtype (``parallel/moe.py``: a
+# cast's output), and the state-space scan's output (``_ssm_mixer``: 2 H P
+# bytes a token a layer; with it the gated norm, the output projection and
+# the rest of the block are made again without the scan's output product,
+# and on the chip the step needs less memory at its peak than without it:
+# PERF.md, Findings, PR 29). A block that produces none of a name keeps
+# nothing under it. Norms, rotary, projections (a state-space mixer's input
+# projection too), the convolution, the scan's decays and chunk states, the
+# router, the experts' sorted rows, gate and up products and activation stay
+# recomputed: the expert layer names nothing that lies in the sort's order,
+# which the backward pass makes again and which one near-tie in the
+# recomputed router shifts (``parallel/moe.py``'s docstring; PERF.md,
+# Findings, PR 28).
 SAVED_NAMES = ("flash_out", "flash_lse", "ffn_pre_activation",
-               "moe_expert_matrices")
+               "moe_expert_matrices", "ssm_scan_out")
 _save_names = jax.checkpoint_policies.save_only_these_names(*SAVED_NAMES)
 
 
@@ -343,12 +527,15 @@ def _block_fn(cfg: GPTConfig):
 def _forward(params, tokens, positions, cfg: GPTConfig):
     """``(logits, [aux of each expert block])``."""
     # Scopes name the program's parts in every instruction's ``op_name``:
-    # ``embed``, ``layer<i>`` (with ``attn`` and ``mlp`` or ``moe`` inside,
-    # from ``_block``; ``moe`` holds ``router``, ``dispatch``, ``experts``,
-    # ``combine``), ``head``; ``loss_and_aux`` adds ``loss``. A device trace
-    # is read by them (PERF.md section 3).
+    # ``embed``, ``layer<i>`` (with ``attn`` or ``ssm`` and ``mlp`` or
+    # ``moe`` inside, from ``_block``; ``ssm`` holds ``in_proj``, ``conv``,
+    # ``scan``, ``gate_norm``, ``out_proj``; ``moe`` holds ``router``,
+    # ``dispatch``, ``experts``, ``combine``), ``head``; ``loss_and_aux``
+    # adds ``loss``. A device trace is read by them (PERF.md section 3).
     with jax.named_scope("embed"):
         x = params["embed"].astype(cfg.dtype)[tokens]
+        if cfg.embedding_multiplier != 1.0:
+            x = x * cfg.embedding_multiplier
     block = _block_fn(cfg)
     auxes = []
     for i, lp in enumerate(params["layers"]):
@@ -358,9 +545,16 @@ def _forward(params, tokens, positions, cfg: GPTConfig):
             auxes.append(aux)
     with jax.named_scope("head"):
         x = _rmsnorm(x, params["out_norm"], cfg.dtype, cfg.norm_eps)
-        return jnp.einsum(
-            "bse,ev->bsv", x,
-            params["lm_head"].astype(cfg.dtype)).astype(jnp.float32), auxes
+        if cfg.tie_embeddings:
+            logits = jnp.einsum("bse,ve->bsv", x,
+                                params["embed"].astype(cfg.dtype))
+        else:
+            logits = jnp.einsum("bse,ev->bsv", x,
+                                params["lm_head"].astype(cfg.dtype))
+        logits = logits.astype(jnp.float32)
+        if cfg.logits_scaling != 1.0:
+            logits = logits / cfg.logits_scaling
+        return logits, auxes
 
 
 def forward(params, tokens, positions, cfg: GPTConfig):

@@ -86,6 +86,8 @@ class SpmdRecorder:
         self.flash_kernels: collections.Counter = collections.Counter()
         # (experts, top_k, ep, grouped_matmul) -> traces
         self.moe_layers: collections.Counter = collections.Counter()
+        # (heads, head_dim, state, groups, chunk) -> traces
+        self.ssm_layers: collections.Counter = collections.Counter()
         # (remat mode, name) -> bytes the checkpointed blocks keep
         self.remat_saved: collections.Counter = collections.Counter()
         # function -> the argument signatures run_step has traced it with
@@ -168,6 +170,13 @@ class SpmdRecorder:
         with self._lock:
             self.moe_layers[(experts, top_k, ep, grouped_matmul)] += 1
 
+    def note_ssm_layer(self, heads: int, head_dim: int, state: int,
+                       groups: int, chunk: int) -> None:
+        """``ops/ssd.py`` calls this while JAX traces a chunked state-space
+        scan: the shapes it runs at."""
+        with self._lock:
+            self.ssm_layers[(heads, head_dim, state, groups, chunk)] += 1
+
     def note_remat_saved(self, mode: str, name: str, nbytes: int) -> None:
         """``models/gpt.py``'s checkpoint policy calls this while JAX splits
         a block into its forward and backward parts: a value it keeps."""
@@ -191,6 +200,7 @@ class SpmdRecorder:
             hits, misses = self.cache_hits, self.cache_misses
             flash = sorted(self.flash_kernels.items())
             moe = sorted(self.moe_layers.items())
+            ssm = sorted(self.ssm_layers.items())
             saved = sorted(self.remat_saved.items())
         counts, seconds = [], []
         for (function, stage), (count, secs) in compiles:
@@ -233,6 +243,15 @@ class SpmdRecorder:
                 [("", {"experts": str(experts), "top_k": str(top_k),
                        "ep": str(ep), "grouped_matmul": gmm}, float(count))
                  for (experts, top_k, ep, gmm), count in moe]),
+            "hvdtpu_spmd_ssm_layer_traces_total": family(
+                "counter", "Times JAX traced a chunked state-space scan (the "
+                "recomputed copy of a block counts again), by its heads, "
+                "their size, the state's size, the groups that share B and "
+                "C, and the chunk.",
+                [("", {"heads": str(heads), "head_dim": str(head_dim),
+                       "state": str(state), "groups": str(groups),
+                       "chunk": str(chunk)}, float(count))
+                 for (heads, head_dim, state, groups, chunk), count in ssm]),
             "hvdtpu_spmd_remat_saved_bytes_total": family(
                 "counter", "Bytes a checkpointed block hands from its "
                 "forward to its backward pass beside its input, by remat "
