@@ -70,7 +70,8 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .pallas_util import out_vma as _out_vma
+from .pallas_util import div as _div, out_vma as _out_vma, rem as _rem, \
+    use_interpret as _use_interpret
 
 _PAD = 128    # the sequence is padded to this many rows, whatever the block
 _LANES = 128  # TPU lane width: softmax stats ride lane-replicated [*, 128]
@@ -96,13 +97,6 @@ VMEM_LIMIT_BYTES = 64 * 1024 * 1024
 _CANDIDATES = (1024, 512, 256, 128)
 
 _NT = (((1,), (1,)), ((), ()))  # a · bᵀ: contract the last dim of both
-
-
-def _use_interpret() -> bool:
-    # Same gate as the quantize kernels: compiled on the TPU backend only;
-    # everything else (the CPU-mesh tests) runs the interpreter.
-    from ..compression.quantize import _pallas_backend_enabled
-    return not _pallas_backend_enabled(None)
 
 
 def vmem_estimate(kernel: str, block_q: int, block_k: int, d: int,
@@ -154,17 +148,6 @@ def _compiler_params():
     return pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "arbitrary"),
         vmem_limit_bytes=VMEM_LIMIT_BYTES)
-
-
-def _div(x, n: int):
-    """Grid indices are int32 and not negative: lax's truncating division
-    with the divisor in the index's dtype (a Python int would be int64
-    under ``jax_enable_x64``, which the tests set)."""
-    return jax.lax.div(x, jnp.asarray(n, x.dtype))
-
-
-def _rem(x, n: int):
-    return jax.lax.rem(x, jnp.asarray(n, x.dtype))
 
 
 def _lanes(x, n: int):

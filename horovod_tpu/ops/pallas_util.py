@@ -3,6 +3,7 @@
 from __future__ import annotations
 
 import jax
+import jax.numpy as jnp
 
 
 def out_vma(*args) -> frozenset:
@@ -16,3 +17,22 @@ def out_vma(*args) -> frozenset:
     for a in args:
         vma |= getattr(jax.typeof(a), "vma", frozenset())
     return vma
+
+
+def use_interpret() -> bool:
+    """Whether a kernel runs in Pallas interpret mode: the same gate as the
+    quantize kernels, compiled through Mosaic on the TPU backend only;
+    everything else (the CPU-mesh tests) runs the interpreter."""
+    from ..compression.quantize import _pallas_backend_enabled
+    return not _pallas_backend_enabled(None)
+
+
+def div(x, n: int):
+    """Grid indices are int32 and not negative: lax's truncating division
+    with the divisor in the index's dtype (a Python int would be int64
+    under ``jax_enable_x64``, which the tests set)."""
+    return jax.lax.div(x, jnp.asarray(n, x.dtype))
+
+
+def rem(x, n: int):
+    return jax.lax.rem(x, jnp.asarray(n, x.dtype))

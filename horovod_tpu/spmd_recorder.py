@@ -88,6 +88,8 @@ class SpmdRecorder:
         self.moe_layers: collections.Counter = collections.Counter()
         # (heads, head_dim, state, groups, chunk) -> traces
         self.ssm_layers: collections.Counter = collections.Counter()
+        # (kernel, chunk, heads_per_block, operand_dtype) -> traces
+        self.ssd_kernels: collections.Counter = collections.Counter()
         # (remat mode, name) -> bytes the checkpointed blocks keep
         self.remat_saved: collections.Counter = collections.Counter()
         # function -> the argument signatures run_step has traced it with
@@ -177,6 +179,14 @@ class SpmdRecorder:
         with self._lock:
             self.ssm_layers[(heads, head_dim, state, groups, chunk)] += 1
 
+    def note_ssd_kernel(self, kernel: str, chunk: int, heads_per_block: int,
+                        operand_dtype: str) -> None:
+        """``ops/ssd.py`` calls this while JAX traces one of the scan's
+        ``pallas_call``s: which tiling the call got."""
+        with self._lock:
+            self.ssd_kernels[(kernel, chunk, heads_per_block,
+                              operand_dtype)] += 1
+
     def note_remat_saved(self, mode: str, name: str, nbytes: int) -> None:
         """``models/gpt.py``'s checkpoint policy calls this while JAX splits
         a block into its forward and backward parts: a value it keeps."""
@@ -201,6 +211,7 @@ class SpmdRecorder:
             flash = sorted(self.flash_kernels.items())
             moe = sorted(self.moe_layers.items())
             ssm = sorted(self.ssm_layers.items())
+            ssd = sorted(self.ssd_kernels.items())
             saved = sorted(self.remat_saved.items())
         counts, seconds = [], []
         for (function, stage), (count, secs) in compiles:
@@ -252,6 +263,15 @@ class SpmdRecorder:
                        "state": str(state), "groups": str(groups),
                        "chunk": str(chunk)}, float(count))
                  for (heads, head_dim, state, groups, chunk), count in ssm]),
+            "hvdtpu_spmd_ssd_kernel_traces_total": family(
+                "counter", "Times JAX traced one of the state-space scan's "
+                "within-chunk kernels, by kernel and the tiling the call "
+                "got: the chunk, the heads a grid cell holds, the MXU "
+                "operands' dtype.",
+                [("", {"kernel": kernel, "chunk": str(chunk),
+                       "heads_per_block": str(hb), "operand_dtype": dtype},
+                  float(count))
+                 for (kernel, chunk, hb, dtype), count in ssd]),
             "hvdtpu_spmd_remat_saved_bytes_total": family(
                 "counter", "Bytes a checkpointed block hands from its "
                 "forward to its backward pass beside its input, by remat "

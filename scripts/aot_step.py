@@ -29,7 +29,7 @@ os.environ.setdefault("JAX_PLATFORMS", "cpu")
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
 
 KERNELS = ("hvd_flash_fwd", "hvd_flash_dkdv", "hvd_flash_dq",
-           "ragged-dot-none")
+           "hvd_ssd_fwd", "hvd_ssd_bwd", "ragged-dot-none")
 GIB = 2.0 ** 30
 
 
@@ -56,7 +56,7 @@ def program_text(lowered) -> str:
     return _KERNEL_BODY.sub(assembly, lowered.as_text())
 
 
-def compile_cell(name: str, root: str) -> dict:
+def compile_cell(name: str, root: str, hlo_dir: str | None = None) -> dict:
     import jax
     from jax.sharding import NamedSharding
     import horovod_tpu as hvd
@@ -91,6 +91,10 @@ def compile_cell(name: str, root: str) -> dict:
     seconds = time.time() - t0
     m = compiled.memory_analysis()
     text = compiled.as_text()
+    if hlo_dir:
+        os.makedirs(hlo_dir, exist_ok=True)
+        with open(os.path.join(hlo_dir, name + ".hlo.txt"), "w") as f:
+            f.write(text)
     return {
         "cell": name, "compile_s": round(seconds, 1),
         # Equal on two checkouts, the step is the same program on both.
@@ -114,6 +118,9 @@ def main() -> int:
     parser.add_argument("cells", nargs="+")
     parser.add_argument("--repo", default=os.path.dirname(
         os.path.dirname(os.path.abspath(__file__))))
+    parser.add_argument("--hlo", metavar="DIR", help="write each cell's "
+                        "compiled HLO text to DIR/<cell>.hlo.txt (which "
+                        "copies XLA put around a kernel, and in what layout)")
     args = parser.parse_args()
     root = os.path.abspath(args.repo)
     sys.path.insert(0, root)
@@ -128,7 +135,7 @@ def main() -> int:
     quantize._pallas_backend_enabled = lambda *_: True   # through Mosaic
     hvd.init(devices=topo.devices[:1])
     for name in args.cells:
-        print(json.dumps(compile_cell(name, root)), flush=True)
+        print(json.dumps(compile_cell(name, root, args.hlo)), flush=True)
     return 0
 
 

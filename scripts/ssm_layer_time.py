@@ -1,0 +1,124 @@
+#!/usr/bin/env python3
+"""Time one state-space block alone on the chip, as a checkpointed step runs
+it, and split its time by scope.
+
+    chiprun --chips 1 -- python scripts/ssm_layer_time.py [--repo DIR] [--trace]
+
+At the ``granite-4.0-h-micro_s4096`` cell's shapes (2 x 4096 tokens of 2048;
+64 heads of 64, state 128, one group, chunk 256; a gated feed-forward of
+8192; bfloat16) it builds ``--layers`` Mamba-2 blocks of ``models/gpt.py``
+under ``remat="full"`` and times, host clock around ``block_until_ready``,
+their forward pass and forward with backward (the gradient of every
+parameter and of the input). ``--trace`` also writes a device trace of four
+calls of each under ``chiprun_out/ssm_layer_trace/<tag>[_fwd]/`` and prints
+``benchmarks/scope_reduce.py``'s split of it: what XLA puts around the
+scan's kernels (copies, layout changes) shows there and in no kernel's own
+time. ``--repo DIR`` times another checkout (a copy of the parent commit) by
+the same script. One JSON line, also appended to
+``chiprun_out/ssm_layer_time.jsonl``.
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import os
+import sys
+import time
+
+HERE = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def timed(fn, *args, reps: int = 10) -> float:
+    """ms a call: the mean of ``reps`` calls after two warm ones."""
+    import jax
+    for _ in range(2):
+        jax.block_until_ready(fn(*args))
+    t0 = time.perf_counter()
+    for _ in range(reps):
+        out = fn(*args)
+    jax.block_until_ready(out)
+    return 1e3 * (time.perf_counter() - t0) / reps
+
+
+def main() -> int:
+    parser = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    parser.add_argument("--repo", default=HERE)
+    parser.add_argument("--tag", default="change")
+    parser.add_argument("--layers", type=int, default=2)
+    parser.add_argument("--batch", type=int, default=2)
+    parser.add_argument("--seq", type=int, default=4096)
+    parser.add_argument("--embed", type=int, default=2048)
+    parser.add_argument("--mlp", type=int, default=8192)
+    parser.add_argument("--heads", type=int, default=64)
+    parser.add_argument("--head-dim", type=int, default=64)
+    parser.add_argument("--state", type=int, default=128)
+    parser.add_argument("--groups", type=int, default=1)
+    parser.add_argument("--chunk", type=int, default=256)
+    parser.add_argument("--trace", action="store_true")
+    args = parser.parse_args()
+    root = os.path.abspath(args.repo)
+    sys.path.insert(0, root)
+
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu.models import gpt
+
+    device = jax.devices()[0]
+    print(f"platform: {device.platform} device_kind: {device.device_kind} "
+          f"repo: {root}", flush=True)
+    cfg = gpt.GPTConfig(
+        vocab_size=256, num_layers=args.layers, num_heads=32, num_kv_heads=8,
+        head_dim=64, embed_dim=args.embed, mlp_dim=args.mlp,
+        dtype=jnp.bfloat16, tp_axis=None, sp_axis=None, attention="flash",
+        norm_eps=1e-5, remat="full", gated_mlp=True, rope=False,
+        tie_embeddings=True, residual_multiplier=0.22,
+        layer_kinds=("ssm",) * args.layers, ssm_heads=args.heads,
+        ssm_head_dim=args.head_dim, ssm_state=args.state,
+        ssm_groups=args.groups, ssm_conv=4, ssm_chunk=args.chunk)
+    layers = gpt.init_params(jax.random.PRNGKey(0), cfg)["layers"]
+    x = jax.random.normal(jax.random.PRNGKey(1),
+                          (args.batch, args.seq, args.embed), jnp.bfloat16)
+    positions = jnp.broadcast_to(jnp.arange(args.seq, dtype=jnp.int32),
+                                 (args.batch, args.seq))
+    block = gpt._block_fn(cfg)
+
+    def blocks(layers, x):
+        for i, lp in enumerate(layers):
+            with jax.named_scope(f"layer{i}"):
+                x, _ = block(cfg, lp, x, positions)
+        return jnp.sum(jnp.sin(x.astype(jnp.float32)))
+
+    fwd = jax.jit(blocks)
+    both = jax.jit(jax.value_and_grad(blocks, argnums=(0, 1)))
+    out = {"tag": args.tag, "layers": args.layers,
+           "device_kind": device.device_kind,
+           "fwd_ms_a_layer": timed(fwd, layers, x) / args.layers,
+           "fwd_bwd_ms_a_layer": timed(both, layers, x) / args.layers}
+    line = json.dumps(out)
+    print(line, flush=True)
+    os.makedirs(os.path.join(HERE, "chiprun_out"), exist_ok=True)
+    with open(os.path.join(HERE, "chiprun_out", "ssm_layer_time.jsonl"),
+              "a") as f:
+        f.write(line + "\n")
+    if args.trace:
+        sys.path.insert(0, HERE)
+        from benchmarks import scope_reduce, trace_reduce
+        log_dir = os.path.join(HERE, "chiprun_out", "ssm_layer_trace",
+                               args.tag)
+        with jax.profiler.trace(log_dir):
+            for _ in range(4):
+                jax.block_until_ready(both(layers, x))
+        print(scope_reduce.describe(trace_reduce.find_xplane(log_dir)),
+              flush=True)
+        with jax.profiler.trace(log_dir + "_fwd"):
+            for _ in range(4):
+                jax.block_until_ready(fwd(layers, x))
+        print(scope_reduce.describe(
+            trace_reduce.find_xplane(log_dir + "_fwd")), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

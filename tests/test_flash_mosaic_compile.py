@@ -1,4 +1,5 @@
-"""The flash kernels through the real Mosaic compiler, without a chip.
+"""The flash kernels, and the state-space scan's, through the real Mosaic
+compiler, without a chip.
 
 Interpret mode (every other flash test) says nothing about Mosaic lowering:
 block shapes, VMEM, layouts. The TPU compiler is installed in the sandbox and
@@ -20,6 +21,7 @@ from jax.sharding import SingleDeviceSharding
 
 from horovod_tpu.compression import quantize
 from horovod_tpu.ops import flash_attention as fa
+from horovod_tpu.ops import ssd
 
 
 @pytest.fixture(scope="module")
@@ -84,3 +86,32 @@ def test_kernel_compiles_for_v5e(one_chip, mosaic, shape, kernel):
         args = (q, k, k, q, cols, cols)
     text = jax.jit(f).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text and f"hvd_flash_{kernel}" in text
+
+
+# (B, S, H, P, N, G, Q, dtype): the granite-4.0-h-micro_s4096 cell's scan;
+# a small one with two groups, four heads a group and a one-tile chunk.
+SSD_SHAPES = {
+    "granite-4.0-h-micro_s4096": (2, 4096, 64, 64, 128, 1, 256, jnp.bfloat16),
+    "small_two_groups": (1, 512, 8, 32, 128, 2, 128, jnp.bfloat16),
+}
+
+
+@pytest.mark.parametrize("kernel", ["fwd", "bwd"])
+@pytest.mark.parametrize("shape", list(SSD_SHAPES))
+def test_ssd_kernel_compiles_for_v5e(one_chip, mosaic, shape, kernel):
+    batch, seq, heads, width, state, groups, chunk, dtype = SSD_SHAPES[shape]
+
+    def sds(*dims, dt=dtype):
+        return jax.ShapeDtypeStruct(dims, dt, sharding=one_chip)
+
+    x, b_in = sds(batch, seq, heads, width), sds(batch, seq, groups, state)
+    row = sds(batch, seq, heads, dt=jnp.float32)
+    args = (x, row, b_in, b_in, row,
+            sds(batch, seq // chunk, heads, width, chunk, dt=jnp.float32),
+            sds(heads, dt=jnp.float32))
+    if kernel == "fwd":
+        f = ssd._fwd_call
+    else:
+        f, args = ssd._bwd_call, args + (x,)
+    text = jax.jit(f).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text and f"hvd_ssd_{kernel}" in text

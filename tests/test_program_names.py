@@ -21,6 +21,7 @@ from horovod_tpu.compression import pallas_kernels as pk
 from horovod_tpu.models import gpt
 from horovod_tpu.observability import parse_prometheus_text, sample_value
 from horovod_tpu.ops import flash_attention as fa
+from horovod_tpu.ops import ssd
 
 CFG = dict(vocab_size=64, num_layers=2, num_heads=2, num_kv_heads=1,
            head_dim=16, embed_dim=32, mlp_dim=64, tp_axis=None, sp_axis=None,
@@ -33,10 +34,17 @@ def spmd4(make_runtime):
     return make_runtime(devices=jax.devices()[:4])
 
 
-def gpt_step(remat: str):
+# A state-space mixer in layer 1 (the scan's kernels in interpret mode).
+HYBRID = dict(layer_kinds=("attention", "ssm"), ssm_heads=4, ssm_head_dim=8,
+              ssm_state=8, ssm_groups=1, ssm_conv=4, ssm_chunk=16, rope=False)
+# An expert layer in every block.
+SPARSE = dict(moe_every=1, num_experts=4, experts_per_token=2)
+
+
+def gpt_step(remat: str, **more):
     """A tiny GPT training step under run_step + DistributedOptimizer, its
     state and a batch for the 4-device mesh."""
-    cfg = gpt.GPTConfig(remat=remat, **CFG)
+    cfg = gpt.GPTConfig(remat=remat, **{**CFG, **more})
     opt = hvd.DistributedOptimizer(optax.adamw(1e-3))
 
     def _train_step(params, opt_state, data):
@@ -89,6 +97,39 @@ def test_compiled_step_carries_the_scopes(spmd4, remat):
     assert not some("noname")
 
 
+def test_hybrid_step_holds_the_scan_kernels_under_their_scope(spmd4):
+    """The scan's two kernels sit under ``layer<i>/ssm/scan`` (where
+    ``ssm_scan_ms`` looks), the forward one in the forward pass alone: a
+    checkpointed block that keeps the scan's output does not run it again.
+    The counter says which tiling each got."""
+    step, *args = gpt_step("full", **HYBRID)
+    text = step.lower(*args).as_text(debug_info=True)
+    scopes = set(re.findall(r'loc\("([^"]*)/hvd_ssd_(fwd|bwd)/', text))
+    assert {kernel for _, kernel in scopes} == {"fwd", "bwd"}
+    for scope, kernel in scopes:
+        assert scope.endswith("/ssm/scan") and "layer1" in scope, scope
+        assert "layer0" not in scope
+        assert ("transpose(jvp(layer1))" in scope) == (kernel == "bwd"), scope
+        assert "rematted_computation" not in scope, scope
+    samples = {tuple(sorted(labels.items())): count for _, labels, count in
+               hvd.metrics()["hvdtpu_spmd_ssd_kernel_traces_total"]["samples"]}
+    # JAX traces the forward kernel again for the block's recomputed copy,
+    # and drops it there: nothing in the backward pass needs its output.
+    assert samples == {
+        (("chunk", "16"), ("heads_per_block", "4"), ("kernel", kernel),
+         ("operand_dtype", "float32")): traces
+        for kernel, traces in ((ssd.KERNEL_FWD, 2.0), (ssd.KERNEL_BWD, 1.0))}
+
+
+@pytest.mark.parametrize("more", [{}, SPARSE], ids=["dense", "sparse"])
+def test_a_step_without_a_state_space_layer_traces_no_scan_kernel(spmd4,
+                                                                  more):
+    step, *args = gpt_step("full", **more)
+    assert "hvd_ssd" not in step.lower(*args).as_text(debug_info=True)
+    assert not hvd.metrics()[
+        "hvdtpu_spmd_ssd_kernel_traces_total"]["samples"]
+
+
 def test_in_step_collective_scope_uses_the_callers_name(spmd4):
     def body(x):
         return (hvd.allreduce(x, name="loss_avg"), hvd.allgather(x),
@@ -113,6 +154,17 @@ def _flash(grad: bool):
     return jax.make_jaxpr(lambda q: fa.flash_attention(q, q, q))(q)
 
 
+def _scan(grad: bool):
+    x = jnp.ones((1, 32, 2, 8), jnp.float32)
+    b = jnp.ones((1, 32, 1, 4), jnp.float32)
+
+    def scan(x):
+        return ssd.ssd_chunked(x, x[..., 0], -jnp.ones(2), b, b, jnp.ones(2),
+                               chunk=16, dtype=jnp.float32)[0].sum()
+
+    return jax.make_jaxpr(jax.grad(scan) if grad else scan)(x)
+
+
 FLAT = jnp.linspace(-1.0, 1.0, 512 * 4, dtype=jnp.float32)
 LEVELS = jnp.linspace(0.0, 1.0, 8, dtype=jnp.float32)
 Q8 = jnp.zeros((4, 512), jnp.uint8)
@@ -123,6 +175,8 @@ MN = jnp.zeros((4,), jnp.float32)
     ("hvd_flash_fwd", lambda: _flash(False)),
     ("hvd_flash_dkdv", lambda: _flash(True)),
     ("hvd_flash_dq", lambda: _flash(True)),
+    ("hvd_ssd_fwd", lambda: _scan(False)),
+    ("hvd_ssd_bwd", lambda: _scan(True)),
     ("hvd_maxmin_quantize", lambda: jax.make_jaxpr(
         lambda x: pk.maxmin_quantize_pallas(x, 4, 512, True))(FLAT)),
     # TPU-only (pltpu.prng_* has no CPU lowering), but it traces anywhere.
@@ -141,13 +195,15 @@ MN = jnp.zeros((4,), jnp.float32)
         lambda q: pk.norm_dequantize_pallas(q, LEVELS, MN, True))(Q8)),
 ])
 def test_kernel_names(name, make):
-    """The nine names the benchmark's readers match as strings."""
+    """The eleven names the benchmark's readers match as strings."""
     assert re.search(rf"\bname={name}\b", str(make())), name
 
 
 def test_kernel_name_constants():
     assert (fa.KERNEL_FWD, fa.KERNEL_DKDV, fa.KERNEL_DQ) == (
         "hvd_flash_fwd", "hvd_flash_dkdv", "hvd_flash_dq")
+    # benchmarks/jobs/gpt_hybrid_dp.py matches ``^hvd_ssd_``.
+    assert (ssd.KERNEL_FWD, ssd.KERNEL_BWD) == ("hvd_ssd_fwd", "hvd_ssd_bwd")
     assert {v for k, v in vars(pk).items() if k.startswith("KERNEL_")} == {
         "hvd_maxmin_quantize", "hvd_maxmin_quantize_stochastic",
         "hvd_maxmin_dequantize", "hvd_maxmin_dequantize_sum",
