@@ -29,14 +29,22 @@ With expert blocks the loss carries the router's two auxiliary terms
 (:func:`loss_and_aux`).
 
 A layer's mixer follows its kind (``GPTConfig.layer_kinds``): ``"attention"``
-as above, or ``"ssm"``, a Mamba-2 state-space mixer (input projection,
+as above, ``"ssm"``, a Mamba-2 state-space mixer (input projection,
 causal depthwise convolution, the chunked scan of
-:mod:`horovod_tpu.ops.ssd`, gated RMSNorm, output projection). A
-state-space layer runs on the sequence and the heads one rank holds: under a
-bound tp or sp axis it raises (``_ssm_mixer``). The dense feed-forward may
-be SiLU-gated, the rotary embedding left out, the head the embedding's
-transpose, and the embedding, the attention logits, each residual branch and
-the logits scaled by a constant.
+:mod:`horovod_tpu.ops.ssd`, gated RMSNorm, output projection), or ``"gdn"``,
+a gated-delta-rule linear-attention mixer (input projections, the same
+convolution, the chunked scan of :mod:`horovod_tpu.ops.gated_delta`, an
+RMSNorm a head and then the gate, output projection). A recurrent layer runs
+on the sequence and the heads one rank holds: under a bound tp or sp axis it
+raises (``_ssm_mixer``, ``_gdn_mixer``). The dense feed-forward may be
+SiLU-gated, the rotary embedding left out, given another base or only the
+first dimensions of a head, q and k normed a head, attention's output gated
+by a sigmoid of a doubled query projection, every norm's weight centred at
+zero (``1 + w``), the head the embedding's transpose, and the embedding, the
+attention logits, each residual branch and the logits scaled by a constant.
+An expert block may hold a share of its router's experts
+(``experts_held``, ``first_expert``: ``parallel/moe.py``), renormalise a
+token's weights and add a gated shared expert every token goes through.
 """
 
 from __future__ import annotations
@@ -53,6 +61,7 @@ from jax.sharding import PartitionSpec as P
 
 from ..ops.attention import default_attention, repeat_kv_heads, rope
 from ..ops.flash_attention import flash_attention
+from ..ops.gated_delta import gated_delta_chunked
 from ..ops.ssd import causal_conv1d, ssd_chunked
 from ..parallel.ring_attention import ring_attention_p
 from ..parallel.ulysses import ulysses_attention_p
@@ -86,10 +95,25 @@ class GPTConfig:
     experts_per_token: int = 1
     load_balance_coef: float = 0.0
     router_z_coef: float = 0.0
+    # A rank's share of an expert-parallel deployment, run alone: the block
+    # holds experts first_expert to first_expert + experts_held of the
+    # router's num_experts (None: all of them) and returns their part of the
+    # sum. A token's experts_per_token weights divided by their sum. A
+    # SiLU-gated expert of width shared_expert_dim (0: none) that every
+    # token goes through, under a sigmoid gate of its own.
+    experts_held: Optional[int] = None
+    first_expert: int = 0
+    renormalize_experts: bool = False
+    shared_expert_dim: int = 0
     # RMSNorm over the whole query and the whole key projection (all heads
-    # together), before the rotary embedding.
+    # together), before the rotary embedding; qk_head_norm: over each head
+    # instead, one weight of head_dim for all heads.
     qk_norm: bool = False
+    qk_head_norm: bool = False
     norm_eps: float = 1e-6
+    # Norm weights enter as 1 + w and start at zero (every norm but the
+    # recurrent mixers' gated ones and the whole-projection qk_norm).
+    norm_zero_centered: bool = False
     # Per-block rematerialization (jax.checkpoint) — the TPU lever trading
     # FLOPs for HBM so long sequences fit: "none" stores every block
     # activation; "full" stores a block's input and what is dear to make
@@ -102,11 +126,11 @@ class GPTConfig:
     # "dots" instead saves every matmul output (recompute only the cheap
     # elementwise work).
     remat: str = "none"                      # "none" | "full" | "dots"
-    # Each layer's mixer, ``"attention"`` or ``"ssm"``, one entry a layer;
-    # None is attention throughout. A state-space mixer has ssm_heads heads
-    # of ssm_head_dim, a state of ssm_state a head, ssm_groups groups of
-    # heads that share B and C, a convolution of ssm_conv taps and a scan in
-    # chunks of ssm_chunk tokens.
+    # Each layer's mixer, ``"attention"``, ``"ssm"`` or ``"gdn"``, one entry
+    # a layer; None is attention throughout. A state-space mixer has
+    # ssm_heads heads of ssm_head_dim, a state of ssm_state a head,
+    # ssm_groups groups of heads that share B and C, a convolution of
+    # ssm_conv taps and a scan in chunks of ssm_chunk tokens.
     layer_kinds: Optional[Tuple[str, ...]] = None
     ssm_heads: int = 8
     ssm_head_dim: int = 64
@@ -114,11 +138,27 @@ class GPTConfig:
     ssm_groups: int = 1
     ssm_conv: int = 4
     ssm_chunk: int = 256
+    # A gated-delta-rule mixer has gdn_key_heads query/key heads of
+    # gdn_key_dim and gdn_value_heads value heads of gdn_value_dim (value
+    # head h reads key head h // (value heads / key heads)), a convolution
+    # of gdn_conv taps and a scan in chunks of gdn_chunk tokens.
+    gdn_key_heads: int = 4
+    gdn_value_heads: int = 8
+    gdn_key_dim: int = 64
+    gdn_value_dim: int = 64
+    gdn_conv: int = 4
+    gdn_chunk: int = 64
     # The dense feed-forward as silu(gate) * up (three matrices) instead of
     # gelu(up) (two).
     gated_mlp: bool = False
-    # False: no position embedding on q and k.
+    # False: no position embedding on q and k. Else at base rope_theta on
+    # the first rotary_dim dimensions of a head (None: all).
     rope: bool = True
+    rope_theta: float = 10000.0
+    rotary_dim: Optional[int] = None
+    # wq is twice as wide a head, [q | gate], and attention's output is
+    # multiplied by sigmoid(gate) before the output projection.
+    attention_gate: bool = False
     # The head is the embedding's transpose: one parameter receives the
     # gather's and the head's gradient.
     tie_embeddings: bool = False
@@ -147,6 +187,19 @@ class GPTConfig:
         """The convolved channels: x, B and C side by side."""
         return self.ssm_inner + 2 * self.ssm_groups * self.ssm_state
 
+    @property
+    def gdn_key_inner(self) -> int:
+        return self.gdn_key_heads * self.gdn_key_dim
+
+    @property
+    def gdn_value_inner(self) -> int:
+        return self.gdn_value_heads * self.gdn_value_dim
+
+    @property
+    def gdn_conv_dim(self) -> int:
+        """The convolved channels: q, k and v side by side."""
+        return 2 * self.gdn_key_inner + self.gdn_value_inner
+
 
 from ..parallel.axes import axis_size as _axis_size, axis_bound as _axis_bound
 
@@ -155,7 +208,7 @@ def _is_moe(cfg: GPTConfig, layer: int) -> bool:
     return cfg.moe_every > 0 and (layer + 1) % cfg.moe_every == 0
 
 
-LAYER_KINDS = ("attention", "ssm")
+LAYER_KINDS = ("attention", "ssm", "gdn")
 
 
 def _check_kinds(cfg: GPTConfig) -> None:
@@ -195,6 +248,41 @@ def _init_ssm(key, cfg: GPTConfig, dense) -> dict:
     }
 
 
+def _init_gdn(key, cfg: GPTConfig, dense) -> dict:
+    """A gated-delta-rule mixer's parameters, initialised as the published
+    Qwen3-Next code does (as remembered): ``A`` uniform in (0, 16],
+    ``dt_bias`` at one, the gated norm's weight at one, the convolution as
+    torch's ``Conv1d`` without a bias."""
+    E, Hv = cfg.embed_dim, cfg.gdn_value_heads
+    ks = jax.random.split(key, 5)
+    bound = 1.0 / float(np.sqrt(cfg.gdn_conv))
+    return {
+        # [q | k | v | z] and [b | a]
+        "in_proj": dense(ks[0], (E, cfg.gdn_conv_dim + cfg.gdn_value_inner),
+                         E),
+        "in_proj_ba": dense(ks[1], (E, 2 * Hv), E),
+        "conv_w": jax.random.uniform(
+            ks[2], (cfg.gdn_conv, cfg.gdn_conv_dim), jnp.float32,
+            -bound, bound),
+        "dt_bias": jnp.ones((Hv,), jnp.float32),
+        "A_log": jnp.log(jnp.maximum(jax.random.uniform(
+            ks[3], (Hv,), jnp.float32, 0.0, 16.0), 1e-4)),
+        "norm": jnp.ones((cfg.gdn_value_dim,), jnp.float32),
+        "out_proj": dense(ks[4], (cfg.gdn_value_inner, E),
+                          cfg.gdn_value_inner),
+    }
+
+
+def _held(cfg: GPTConfig) -> int:
+    """Experts an expert block's matrices hold."""
+    held = cfg.num_experts if cfg.experts_held is None else cfg.experts_held
+    if cfg.ep_axis is not None and held != cfg.num_experts:
+        raise ValueError(
+            "experts_held is one rank's share run without the mesh; with "
+            f"ep_axis={cfg.ep_axis!r} the axis divides the experts itself")
+    return held
+
+
 def init_params(rng, cfg: GPTConfig) -> dict:
     """Global-shape parameter pytree (plain dicts; fp32).
 
@@ -209,12 +297,16 @@ def init_params(rng, cfg: GPTConfig) -> dict:
         return (jax.random.normal(key, shape, jnp.float32) /
                 float(np.sqrt(fan_in)))
 
+    def norm(shape):
+        return jnp.zeros(shape, jnp.float32) if cfg.norm_zero_centered \
+            else jnp.ones(shape, jnp.float32)
+
     _check_kinds(cfg)
     keys = jax.random.split(rng, 2 + cfg.num_layers)
     params: dict = {
         "embed": jax.random.normal(keys[0], (cfg.vocab_size, E),
                                    jnp.float32) * 0.02,
-        "out_norm": jnp.ones((E,), jnp.float32),
+        "out_norm": norm((E,)),
         "layers": [],
     }
     if not cfg.tie_embeddings:
@@ -222,29 +314,46 @@ def init_params(rng, cfg: GPTConfig) -> dict:
     for i in range(cfg.num_layers):
         ks = jax.random.split(keys[2 + i], 8)
         if cfg.kind(i) == "ssm":
-            layer = {"ssm_norm": jnp.ones((E,), jnp.float32),
+            layer = {"ssm_norm": norm((E,)),
                      "ssm": _init_ssm(ks[0], cfg, dense),
-                     "mlp_norm": jnp.ones((E,), jnp.float32)}
+                     "mlp_norm": norm((E,))}
+        elif cfg.kind(i) == "gdn":
+            layer = {"gdn_norm": norm((E,)),
+                     "gdn": _init_gdn(ks[0], cfg, dense),
+                     "mlp_norm": norm((E,))}
         else:
             layer = {
-                "attn_norm": jnp.ones((E,), jnp.float32),
-                "wq": dense(ks[0], (E, H, D), E),
+                "attn_norm": norm((E,)),
+                "wq": dense(ks[0], (E, H, 2 * D if cfg.attention_gate else D),
+                            E),
                 "wk": dense(ks[1], (E, Hkv, D), E),
                 "wv": dense(ks[2], (E, Hkv, D), E),
                 "wo": dense(ks[3], (H, D, E), H * D),
-                "mlp_norm": jnp.ones((E,), jnp.float32),
+                "mlp_norm": norm((E,)),
             }
             if cfg.qk_norm:
                 layer["q_norm"] = jnp.ones((H, D), jnp.float32)
                 layer["k_norm"] = jnp.ones((Hkv, D), jnp.float32)
+            elif cfg.qk_head_norm:
+                layer["q_norm"] = norm((D,))
+                layer["k_norm"] = norm((D,))
         if _is_moe(cfg, i):
-            n_exp = cfg.num_experts
+            n_exp, held = cfg.num_experts, _held(cfg)
             layer["moe"] = {
                 "router": dense(ks[4], (E, n_exp), E),
-                "w_gate": dense(ks[7], (n_exp, E, M), E),
-                "w_up": dense(ks[5], (n_exp, E, M), E),
-                "w_down": dense(ks[6], (n_exp, M, E), M),
+                "w_gate": dense(ks[7], (held, E, M), E),
+                "w_up": dense(ks[5], (held, E, M), E),
+                "w_down": dense(ks[6], (held, M, E), M),
             }
+            if cfg.shared_expert_dim:
+                sk = jax.random.split(jax.random.fold_in(ks[4], 1), 4)
+                Ms = cfg.shared_expert_dim
+                layer["moe"]["shared"] = {
+                    "w_gate": dense(sk[0], (E, Ms), E),
+                    "w_up": dense(sk[1], (E, Ms), E),
+                    "w_down": dense(sk[2], (Ms, E), Ms),
+                    "gate": dense(sk[3], (E,), E),
+                }
         else:
             if cfg.gated_mlp:
                 layer["w_gate"] = dense(ks[7], (E, M), E)
@@ -273,6 +382,11 @@ def param_specs(cfg: GPTConfig) -> dict:
                 name: P() for name in (
                     "in_proj", "conv_w", "conv_b", "dt_bias", "A_log", "D",
                     "norm", "out_proj")}}
+        elif cfg.kind(i) == "gdn":
+            layer = {"gdn_norm": P(), "mlp_norm": P(), "gdn": {
+                name: P() for name in (
+                    "in_proj", "in_proj_ba", "conv_w", "dt_bias", "A_log",
+                    "norm", "out_proj")}}
         else:
             layer = {
                 "attn_norm": P(),
@@ -285,13 +399,21 @@ def param_specs(cfg: GPTConfig) -> dict:
             if cfg.qk_norm:
                 layer["q_norm"] = P(tp, None)
                 layer["k_norm"] = P(tp, None)
+            elif cfg.qk_head_norm:
+                layer["q_norm"] = P()
+                layer["k_norm"] = P()
         if _is_moe(cfg, i):
+            _held(cfg)
             layer["moe"] = {
                 "router": P(),
                 "w_gate": P(ep, None, tp),
                 "w_up": P(ep, None, tp),
                 "w_down": P(ep, tp, None),
             }
+            if cfg.shared_expert_dim:
+                layer["moe"]["shared"] = {
+                    "w_gate": P(None, tp), "w_up": P(None, tp),
+                    "w_down": P(tp, None), "gate": P()}
         else:
             if cfg.gated_mlp:
                 layer["w_gate"] = P(None, tp)
@@ -301,10 +423,18 @@ def param_specs(cfg: GPTConfig) -> dict:
     return specs
 
 
-def _rmsnorm(x, w, dtype, eps):
+def _rmsnorm(x, w, dtype, eps, zero_centered: bool = False):
     x32 = x.astype(jnp.float32)
     var = jnp.mean(x32 * x32, axis=-1, keepdims=True)
+    if zero_centered:
+        w = 1.0 + w
     return (x32 * lax.rsqrt(var + eps) * w).astype(dtype)
+
+
+def _norm(cfg: GPTConfig, x, w):
+    """The model's RMSNorm: over the last axis, ``1 + w`` if the
+    configuration centres its weights at zero."""
+    return _rmsnorm(x, w, cfg.dtype, cfg.norm_eps, cfg.norm_zero_centered)
 
 
 def _projection_norm(x, w, cfg: GPTConfig):
@@ -366,6 +496,16 @@ def _attention(cfg: GPTConfig, q, k, v):
         "use 'ring' or 'ulysses'")
 
 
+def _refuse_bound_axes(cfg: GPTConfig, what: str) -> None:
+    for axis in (cfg.sp_axis, cfg.tp_axis):
+        if _axis_bound(axis):
+            raise ValueError(
+                f"a {what} layer runs on one rank's whole sequence and "
+                f"all its heads: the {axis!r} axis is bound (sp would scan "
+                "each sequence shard from a zero state, tp would hold a "
+                "shard of the heads); bind neither")
+
+
 def _ssm_mixer(cfg: GPTConfig, p, h):
     """A Mamba-2 mixer on normed activations ``h`` ``[B, S, E]``: ``[z | xBC
     | dt] = h W_in``; ``xBC`` through the causal depthwise convolution and
@@ -374,13 +514,7 @@ def _ssm_mixer(cfg: GPTConfig, p, h):
     silu(z))`` over the whole inner width; ``W_out``. The scan starts every
     sequence a rank holds from a zero state and the norm runs over the heads
     it holds, so a bound sp or tp axis is refused by name."""
-    for axis in (cfg.sp_axis, cfg.tp_axis):
-        if _axis_bound(axis):
-            raise ValueError(
-                f"a state-space layer runs on one rank's whole sequence and "
-                f"all its heads: the {axis!r} axis is bound (sp would scan "
-                "each sequence shard from a zero state, tp would norm a "
-                "shard of the heads); bind neither")
+    _refuse_bound_axes(cfg, "state-space")
     batch, seq = h.shape[:2]
     heads, inner = cfg.ssm_heads, cfg.ssm_inner
     groups, state = cfg.ssm_groups, cfg.ssm_state
@@ -409,6 +543,65 @@ def _ssm_mixer(cfg: GPTConfig, p, h):
         return jnp.einsum("bsf,fe->bse", y, p["out_proj"].astype(cfg.dtype))
 
 
+def _gdn_mixer(cfg: GPTConfig, p, h):
+    """A gated-delta-rule mixer on normed activations ``h`` ``[B, S, E]``:
+    ``[q | k | v | z] = h W_qkvz``, ``[b | a] = h W_ba``; ``[q | k | v]``
+    through the causal depthwise convolution (no bias) and SiLU; ``q`` and
+    ``k`` L2-normalised a head, ``q`` over the root of its size besides;
+    ``beta = sigmoid(b)``, ``g = -exp(A_log) softplus(a + dt_bias)``, both
+    float32, one a value head; the chunked scan
+    (:func:`horovod_tpu.ops.gated_delta.gated_delta_chunked`); an RMSNorm a
+    value head (one plain weight of the head's size) and **then** the gate
+    ``silu(z)``, where Mamba-2 gates first; ``W_out``. A bound sp or tp axis
+    is refused by name, as for a state-space layer."""
+    _refuse_bound_axes(cfg, "gated-delta-rule")
+    batch, seq = h.shape[:2]
+    f32 = jnp.float32
+    key_heads, heads = cfg.gdn_key_heads, cfg.gdn_value_heads
+    key_inner, conv_dim = cfg.gdn_key_inner, cfg.gdn_conv_dim
+    with jax.named_scope("in_proj"):
+        qkvz = jnp.einsum("bse,ef->bsf", h, p["in_proj"].astype(cfg.dtype))
+        qkv, z = jnp.split(qkvz, [conv_dim], axis=-1)
+        ba = jnp.einsum("bse,ef->bsf", h, p["in_proj_ba"].astype(cfg.dtype))
+    with jax.named_scope("conv"):
+        qkv = jax.nn.silu(causal_conv1d(qkv, p["conv_w"], None)
+                          ).astype(cfg.dtype)
+        q, k, v = jnp.split(qkv, [key_inner, 2 * key_inner], axis=-1)
+    with jax.named_scope("scan"):
+        def unit(t):
+            t = t.reshape(batch, seq, key_heads, cfg.gdn_key_dim).astype(f32)
+            return t * lax.rsqrt(jnp.sum(t * t, axis=-1, keepdims=True)
+                                 + 1e-6)
+
+        b, a = jnp.split(ba.astype(f32), 2, axis=-1)
+        g = -jnp.exp(p["A_log"]) * jax.nn.softplus(a + p["dt_bias"])
+        o, _ = gated_delta_chunked(
+            (unit(q) * float(cfg.gdn_key_dim) ** -0.5).astype(cfg.dtype),
+            unit(k).astype(cfg.dtype),
+            v.reshape(batch, seq, heads, cfg.gdn_value_dim), g,
+            jax.nn.sigmoid(b), chunk=cfg.gdn_chunk, dtype=cfg.dtype)
+        o = checkpoint_name(o, "gdn_scan_out")
+    with jax.named_scope("gate_norm"):
+        y = _rmsnorm(o, p["norm"], f32, cfg.norm_eps) * jax.nn.silu(
+            z.reshape(o.shape).astype(f32))
+        y = y.reshape(batch, seq, cfg.gdn_value_inner).astype(cfg.dtype)
+    with jax.named_scope("out_proj"):
+        return jnp.einsum("bsf,fe->bse", y, p["out_proj"].astype(cfg.dtype))
+
+
+def _shared_expert(cfg: GPTConfig, p, h):
+    """The expert every token goes through: ``sigmoid(<h, w_g>) W_down
+    (silu(W_gate h) * W_up h)``."""
+    gate = jnp.einsum("bse,em->bsm", h, p["w_gate"].astype(cfg.dtype))
+    up = jnp.einsum("bse,em->bsm", h, p["w_up"].astype(cfg.dtype))
+    down = _tp_psum(jnp.einsum("bsm,me->bse", jax.nn.silu(gate) * up,
+                               p["w_down"].astype(cfg.dtype)), cfg)
+    open_ = jax.nn.sigmoid(jnp.einsum(
+        "bse,e->bs", h, p["gate"].astype(cfg.dtype),
+        preferred_element_type=jnp.float32))
+    return (down.astype(jnp.float32) * open_[..., None]).astype(cfg.dtype)
+
+
 def _residual(cfg: GPTConfig, x, branch):
     if cfg.residual_multiplier != 1.0:
         branch = branch * cfg.residual_multiplier
@@ -423,41 +616,58 @@ def _block(cfg: GPTConfig, layer_params, x, positions):
     lp = layer_params
     if "ssm" in lp:
         with jax.named_scope("ssm"):
-            h = _rmsnorm(x, lp["ssm_norm"], cfg.dtype, cfg.norm_eps)
+            h = _norm(cfg, x, lp["ssm_norm"])
             x = _residual(cfg, x, _ssm_mixer(cfg, lp["ssm"], h))
+    elif "gdn" in lp:
+        with jax.named_scope("gdn"):
+            h = _norm(cfg, x, lp["gdn_norm"])
+            x = _residual(cfg, x, _gdn_mixer(cfg, lp["gdn"], h))
     else:
         with jax.named_scope("attn"):
-            h = _rmsnorm(x, lp["attn_norm"], cfg.dtype, cfg.norm_eps)
+            h = _norm(cfg, x, lp["attn_norm"])
             q = jnp.einsum("bse,ehd->bshd", h, lp["wq"].astype(cfg.dtype))
             k = jnp.einsum("bse,ehd->bshd", h, lp["wk"].astype(cfg.dtype))
             v = jnp.einsum("bse,ehd->bshd", h, lp["wv"].astype(cfg.dtype))
+            if cfg.attention_gate:
+                q, gate = jnp.split(q, 2, axis=-1)
             if cfg.qk_norm:
                 q = _projection_norm(q, lp["q_norm"], cfg)
                 k = _projection_norm(k, lp["k_norm"], cfg)
+            elif cfg.qk_head_norm:
+                q = _norm(cfg, q, lp["q_norm"])
+                k = _norm(cfg, k, lp["k_norm"])
             if cfg.rope:
-                q = rope(q, positions)
-                k = rope(k, positions)
+                q = rope(q, positions, cfg.rope_theta, cfg.rotary_dim)
+                k = rope(k, positions, cfg.rope_theta, cfg.rotary_dim)
             if cfg.attention_multiplier is not None:
                 # Every attention here scales its logits by one over the
                 # square root of head_dim: the rest goes onto q.
                 q = q * (cfg.attention_multiplier
                          * float(np.sqrt(cfg.head_dim)))
             attn = _attention(cfg, q, k, v)
+            if cfg.attention_gate:
+                attn = (attn.astype(jnp.float32) * jax.nn.sigmoid(
+                    gate.astype(jnp.float32))).astype(cfg.dtype)
             o = jnp.einsum("bshd,hde->bse", attn, lp["wo"].astype(cfg.dtype))
             x = _residual(cfg, x, _tp_psum(o, cfg))
 
     if "moe" in lp:
         with jax.named_scope("moe"):
-            h = _rmsnorm(x, lp["mlp_norm"], cfg.dtype, cfg.norm_eps)
+            h = _norm(cfg, x, lp["mlp_norm"])
             from ..parallel.moe import moe_layer
             m = lp["moe"]
             out, aux = moe_layer(
                 h, m["router"], m["w_gate"], m["w_up"], m["w_down"],
                 top_k=cfg.experts_per_token, axis=cfg.ep_axis,
-                tp_axis=cfg.tp_axis, dtype=cfg.dtype)
+                tp_axis=cfg.tp_axis, dtype=cfg.dtype,
+                first_expert=cfg.first_expert,
+                renormalize=cfg.renormalize_experts)
+            if "shared" in m:
+                with jax.named_scope("shared"):
+                    out = out + _shared_expert(cfg, m["shared"], h)
             return _residual(cfg, x, out), aux
     with jax.named_scope("mlp"):
-        h = _rmsnorm(x, lp["mlp_norm"], cfg.dtype, cfg.norm_eps)
+        h = _norm(cfg, x, lp["mlp_norm"])
         up = jnp.einsum("bse,em->bsm", h, lp["w_up"].astype(cfg.dtype))
         up = checkpoint_name(up, "ffn_pre_activation")
         if "w_gate" in lp:
@@ -480,16 +690,20 @@ def _block(cfg: GPTConfig, layer_params, x, positions):
 # bytes a token a layer; with it the gated norm, the output projection and
 # the rest of the block are made again without the scan's output product,
 # and on the chip the step needs less memory at its peak than without it:
-# PERF.md, Findings, PR 29). A block that produces none of a name keeps
-# nothing under it. Norms, rotary, projections (a state-space mixer's input
-# projection too), the convolution, the scan's decays and chunk states, the
-# router, the experts' sorted rows, gate and up products and activation stay
-# recomputed: the expert layer names nothing that lies in the sort's order,
+# PERF.md, Findings, PR 29), and the gated-delta-rule scan's output
+# (``_gdn_mixer``: 2 Hv V bytes a token a layer; that scan is plain
+# ``jax.numpy`` whose backward pass needs its insides, so most of it is made
+# again all the same, but not the products that only give the output: +5.6%
+# on the chip, PERF.md, Findings, PR 31). A block that produces none of a
+# name keeps nothing under it. Norms, rotary, projections (a recurrent
+# mixer's input projection too), the convolution, the scans' decays and
+# chunk states, the router, the experts' sorted rows, gate and up products
+# and activation stay recomputed: the expert layer names nothing that lies in the sort's order,
 # which the backward pass makes again and which one near-tie in the
 # recomputed router shifts (``parallel/moe.py``'s docstring; PERF.md,
 # Findings, PR 28).
 SAVED_NAMES = ("flash_out", "flash_lse", "ffn_pre_activation",
-               "moe_expert_matrices", "ssm_scan_out")
+               "moe_expert_matrices", "ssm_scan_out", "gdn_scan_out")
 _save_names = jax.checkpoint_policies.save_only_these_names(*SAVED_NAMES)
 
 
@@ -527,10 +741,11 @@ def _block_fn(cfg: GPTConfig):
 def _forward(params, tokens, positions, cfg: GPTConfig):
     """``(logits, [aux of each expert block])``."""
     # Scopes name the program's parts in every instruction's ``op_name``:
-    # ``embed``, ``layer<i>`` (with ``attn`` or ``ssm`` and ``mlp`` or
-    # ``moe`` inside, from ``_block``; ``ssm`` holds ``in_proj``, ``conv``,
-    # ``scan``, ``gate_norm``, ``out_proj``; ``moe`` holds ``router``,
-    # ``dispatch``, ``experts``, ``combine``), ``head``; ``loss_and_aux``
+    # ``embed``, ``layer<i>`` (with ``attn``, ``ssm`` or ``gdn`` and ``mlp``
+    # or ``moe`` inside, from ``_block``; ``ssm`` and ``gdn`` hold
+    # ``in_proj``, ``conv``, ``scan``, ``gate_norm``, ``out_proj``; ``moe``
+    # holds ``router``, ``dispatch``, ``experts``, ``combine`` and
+    # ``shared``), ``head``; ``loss_and_aux``
     # adds ``loss``. A device trace is read by them (PERF.md section 3).
     with jax.named_scope("embed"):
         x = params["embed"].astype(cfg.dtype)[tokens]
@@ -544,7 +759,7 @@ def _forward(params, tokens, positions, cfg: GPTConfig):
         if aux is not None:
             auxes.append(aux)
     with jax.named_scope("head"):
-        x = _rmsnorm(x, params["out_norm"], cfg.dtype, cfg.norm_eps)
+        x = _norm(cfg, x, params["out_norm"])
         if cfg.tie_embeddings:
             logits = jnp.einsum("bse,ve->bsv", x,
                                 params["embed"].astype(cfg.dtype))

@@ -30,11 +30,21 @@ def default_attention(q, k, v, causal: bool = True):
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
 
-def rope(x, positions):
-    """Rotary position embedding. x: [B, S, H, D]; positions: [B, S]."""
+def rope(x, positions, base: float = 10000.0, rotary_dim=None):
+    """Rotary position embedding. x: [B, S, H, D]; positions: [B, S].
+    ``rotary_dim`` (None: all of ``D``) rotates the first ``rotary_dim``
+    dimensions of a head, the two halves of those against each other, and
+    leaves the rest as they are."""
     d = x.shape[-1]
+    if rotary_dim is not None and rotary_dim != d:
+        if not 0 < rotary_dim < d or rotary_dim % 2:
+            raise ValueError(f"rotary_dim must be even and at most the "
+                             f"head's {d}, got {rotary_dim}")
+        return jnp.concatenate(
+            [rope(x[..., :rotary_dim], positions, base), x[..., rotary_dim:]],
+            axis=-1)
     half = d // 2
-    freqs = 1.0 / (10000.0 ** (jnp.arange(half, dtype=jnp.float32) / half))
+    freqs = 1.0 / (base ** (jnp.arange(half, dtype=jnp.float32) / half))
     angles = positions[..., None].astype(jnp.float32) * freqs  # [B, S, half]
     cos = jnp.cos(angles)[:, :, None, :].astype(x.dtype)
     sin = jnp.sin(angles)[:, :, None, :].astype(x.dtype)

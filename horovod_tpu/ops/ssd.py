@@ -78,14 +78,15 @@ _TN = (((0,), (0,)), ((), ()))  # aᵀ · b: contract the first dim of both
 
 def causal_conv1d(u, weight, bias):
     """Causal depthwise convolution along the sequence: ``u`` ``[B, S, C]``,
-    ``weight`` ``[K, C]``, ``bias`` ``[C]`` -> float32 ``[B, S, C]`` with
-    ``out_t = bias + sum_k weight[k] u_{t - (K - 1) + k}`` and zeros before
-    the start (tap ``K - 1`` reads the token itself)."""
+    ``weight`` ``[K, C]``, ``bias`` ``[C]`` or None -> float32 ``[B, S, C]``
+    with ``out_t = bias + sum_k weight[k] u_{t - (K - 1) + k}`` and zeros
+    before the start (tap ``K - 1`` reads the token itself)."""
     taps, seq = weight.shape[0], u.shape[1]
     padded = jnp.pad(u.astype(jnp.float32), ((0, 0), (taps - 1, 0), (0, 0)))
-    out = bias.astype(jnp.float32)
+    out = None if bias is None else bias.astype(jnp.float32)
     for k in range(taps):
-        out = out + padded[:, k:k + seq] * weight[k].astype(jnp.float32)
+        tap = padded[:, k:k + seq] * weight[k].astype(jnp.float32)
+        out = tap if out is None else out + tap
     return out
 
 

@@ -10,7 +10,9 @@ tokens ``h`` ``[T, d]``, router ``W_r`` ``[d, E]`` and experts ``W_gate,e``,
     S_t = the k largest of p_t (ties to the lower index)
     y_t = sum_{e in S_t} p_{t,e} W_down,e( silu(W_gate,e h_t) * (W_up,e h_t) )
 
-The weights ``p_{t,e}`` are not renormalised over ``S_t``. **No token is
+The weights ``p_{t,e}`` are not renormalised over ``S_t`` unless
+``renormalize`` asks for ``p_{t,e} / sum_{e' in S_t} p_{t,e'}`` (the
+gradient flows through the sum). **No token is
 dropped, whatever the routing**, and every shape is static: the ``T k``
 token-expert pairs are sorted by expert, the tokens' rows gathered once in
 that order, the three expert matrices applied as grouped matmuls over the
@@ -28,6 +30,18 @@ hands each rank the sum for its own tokens. At ``k`` of 8 over 4 ranks a
 token's experts lie on 3.6 ranks on average, so this moves what an
 all-to-all would and needs no capacity. ``tp_axis`` shards the experts'
 width ``m``; the partial sums meet in one ``psum`` after the combine.
+
+**A rank's share without the mesh.** With no ``axis`` bound and fewer expert
+matrices than the router is wide, the layer holds experts ``first_expert``
+to ``first_expert + experts_local`` of ``E``: one rank's share of an
+expert-parallel deployment, run alone. It routes over all ``E`` (weights,
+auxiliary terms and counts are the whole router's), sorts its own experts'
+rows first exactly as the bound branch does, and returns ``sum_{e in S_t,
+e held}``: the partial sum that rank would hand to the exchange. Nothing
+stands in for the absent ranks or for the exchange; the shares' outputs add
+up to the whole layer's (``tests/test_moe_layer.py``). It still sorts and
+gathers all ``T k`` rows though only ``experts_local / E`` of them are its
+own.
 
 Gradients: the choice ``S_t`` is not differentiable; the router learns
 through the weights ``p_{t,e}`` and through the two auxiliary terms returned
@@ -146,7 +160,8 @@ _down_and_combine.defvjp(_down_and_combine_fwd, _down_and_combine_bwd)
 
 def moe_layer(x, router_w, w_gate, w_up, w_down, top_k: int,
               axis: Optional[str] = None, tp_axis: Optional[str] = None,
-              dtype: Any = jnp.bfloat16) -> Tuple[jnp.ndarray, dict]:
+              dtype: Any = jnp.bfloat16, first_expert: int = 0,
+              renormalize: bool = False) -> Tuple[jnp.ndarray, dict]:
     """Dropless top-``top_k`` expert layer (module docstring has the math).
 
     Args:
@@ -158,6 +173,9 @@ def moe_layer(x, router_w, w_gate, w_up, w_down, top_k: int,
       top_k: experts per token.
       axis: expert-parallel mesh axis (None/unbound ⇒ all experts local).
       tp_axis: tensor-parallel axis sharding the expert width, if any.
+      first_expert: with no ``axis`` bound and ``experts_local <
+        num_experts``, the first expert held (static).
+      renormalize: divide a token's ``top_k`` weights by their sum.
 
     Returns ``(y, aux)``, ``y`` shaped and typed (``dtype``) as the
     activations, and over the tokens routed together (this rank's, or the ep
@@ -169,12 +187,23 @@ def moe_layer(x, router_w, w_gate, w_up, w_down, top_k: int,
     d = x.shape[-1]
     ep = _axis_bound(axis)
     experts_local = w_up.shape[0]
-    num_experts = experts_local * _axis_size(axis)
+    num_experts = router_w.shape[1]
+    if ep and experts_local * _axis_size(axis) != num_experts:
+        raise ValueError(
+            f"expert layer: {_axis_size(axis)} ranks of {experts_local} "
+            f"experts under a router {num_experts} wide")
+    if not ep and not 0 <= first_expert <= num_experts - experts_local:
+        raise ValueError(
+            f"expert layer: experts {first_expert} to "
+            f"{first_expert + experts_local} of a router {num_experts} wide")
+    # Some experts are elsewhere: on the axis's other ranks, or on ranks
+    # this program does not run.
+    share = ep or experts_local < num_experts
     from .. import runtime
     recorder = runtime.recorder()
     if recorder is not None:
         recorder.note_moe_layer(num_experts, top_k, _axis_size(axis),
-                                GROUPED_MATMUL)
+                                GROUPED_MATMUL, experts_local)
 
     xt = x.reshape(-1, d)
     if ep:
@@ -186,6 +215,8 @@ def moe_layer(x, router_w, w_gate, w_up, w_down, top_k: int,
                          precision=lax.Precision.HIGHEST)            # [T, E]
         probs = jax.nn.softmax(logits, axis=-1)
         top_p, top_e = lax.top_k(probs, top_k)                       # [T, k]
+        if renormalize:
+            top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
         counts = jnp.sum(jax.nn.one_hot(top_e, num_experts, dtype=jnp.int32),
                          axis=(0, 1))                                # [E]
         load_balance = num_experts * jnp.sum(
@@ -194,13 +225,14 @@ def moe_layer(x, router_w, w_gate, w_up, w_down, top_k: int,
 
     with jax.named_scope("dispatch"):
         expert_of_pair, group_sizes, mine = top_e.reshape(-1), counts, None
-        if ep:
+        if share:
             # This rank's experts first in the order: their rows are then
             # the first sum(group_sizes) of the T k. Rows of other ranks'
             # experts lie outside every group: a grouped matmul leaves them
             # unwritten, so they are held at zero, and with them their
             # cotangents.
-            first = lax.axis_index(axis) * experts_local
+            first = lax.axis_index(axis) * experts_local if ep \
+                else first_expert
             expert_of_pair = (expert_of_pair - first) % num_experts
             group_sizes = lax.dynamic_slice(counts, (first,),
                                             (experts_local,))

@@ -84,10 +84,12 @@ class SpmdRecorder:
         self.placed_bytes = 0
         # (kernel, block_q, block_k, operand_dtype, kv_group) -> traces
         self.flash_kernels: collections.Counter = collections.Counter()
-        # (experts, top_k, ep, grouped_matmul) -> traces
+        # (experts, top_k, ep, grouped_matmul, held) -> traces
         self.moe_layers: collections.Counter = collections.Counter()
         # (heads, head_dim, state, groups, chunk) -> traces
         self.ssm_layers: collections.Counter = collections.Counter()
+        # (key_heads, value_heads, key_dim, value_dim, chunk) -> traces
+        self.gdn_layers: collections.Counter = collections.Counter()
         # (kernel, chunk, heads_per_block, operand_dtype) -> traces
         self.ssd_kernels: collections.Counter = collections.Counter()
         # (remat mode, name) -> bytes the checkpointed blocks keep
@@ -166,11 +168,12 @@ class SpmdRecorder:
                                 kv_group)] += 1
 
     def note_moe_layer(self, experts: int, top_k: int, ep: int,
-                       grouped_matmul: str) -> None:
+                       grouped_matmul: str, held: int) -> None:
         """``parallel/moe.py`` calls this while JAX traces an expert layer:
-        what it routes over, and which grouped matmul it got."""
+        what it routes over, how many of those experts this rank holds, and
+        which grouped matmul it got."""
         with self._lock:
-            self.moe_layers[(experts, top_k, ep, grouped_matmul)] += 1
+            self.moe_layers[(experts, top_k, ep, grouped_matmul, held)] += 1
 
     def note_ssm_layer(self, heads: int, head_dim: int, state: int,
                        groups: int, chunk: int) -> None:
@@ -178,6 +181,14 @@ class SpmdRecorder:
         scan: the shapes it runs at."""
         with self._lock:
             self.ssm_layers[(heads, head_dim, state, groups, chunk)] += 1
+
+    def note_gdn_layer(self, key_heads: int, value_heads: int, key_dim: int,
+                       value_dim: int, chunk: int) -> None:
+        """``ops/gated_delta.py`` calls this while JAX traces a chunked
+        gated-delta-rule scan: the shapes it runs at."""
+        with self._lock:
+            self.gdn_layers[(key_heads, value_heads, key_dim, value_dim,
+                             chunk)] += 1
 
     def note_ssd_kernel(self, kernel: str, chunk: int, heads_per_block: int,
                         operand_dtype: str) -> None:
@@ -211,6 +222,7 @@ class SpmdRecorder:
             flash = sorted(self.flash_kernels.items())
             moe = sorted(self.moe_layers.items())
             ssm = sorted(self.ssm_layers.items())
+            gdn = sorted(self.gdn_layers.items())
             ssd = sorted(self.ssd_kernels.items())
             saved = sorted(self.remat_saved.items())
         counts, seconds = [], []
@@ -250,10 +262,12 @@ class SpmdRecorder:
                 "counter", "Times JAX traced an expert layer (the recomputed "
                 "copy of a block counts again), by the experts it routes "
                 "over, the experts per token, the size of the expert-parallel "
-                "axis and the grouped matmul it uses.",
+                "axis, the grouped matmul it uses and the experts this rank "
+                "holds.",
                 [("", {"experts": str(experts), "top_k": str(top_k),
-                       "ep": str(ep), "grouped_matmul": gmm}, float(count))
-                 for (experts, top_k, ep, gmm), count in moe]),
+                       "ep": str(ep), "grouped_matmul": gmm,
+                       "held": str(held)}, float(count))
+                 for (experts, top_k, ep, gmm, held), count in moe]),
             "hvdtpu_spmd_ssm_layer_traces_total": family(
                 "counter", "Times JAX traced a chunked state-space scan (the "
                 "recomputed copy of a block counts again), by its heads, "
@@ -263,6 +277,16 @@ class SpmdRecorder:
                        "state": str(state), "groups": str(groups),
                        "chunk": str(chunk)}, float(count))
                  for (heads, head_dim, state, groups, chunk), count in ssm]),
+            "hvdtpu_spmd_gdn_layer_traces_total": family(
+                "counter", "Times JAX traced a chunked gated-delta-rule "
+                "scan (the recomputed copy of a block counts again), by its "
+                "key heads, value heads, their sizes and the chunk.",
+                [("", {"key_heads": str(key_heads),
+                       "value_heads": str(value_heads),
+                       "key_dim": str(key_dim), "value_dim": str(value_dim),
+                       "chunk": str(chunk)}, float(count))
+                 for (key_heads, value_heads, key_dim, value_dim, chunk),
+                 count in gdn]),
             "hvdtpu_spmd_ssd_kernel_traces_total": family(
                 "counter", "Times JAX traced one of the state-space scan's "
                 "within-chunk kernels, by kernel and the tiling the call "

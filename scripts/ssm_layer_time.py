@@ -1,8 +1,9 @@
 #!/usr/bin/env python3
-"""Time one state-space block alone on the chip, as a checkpointed step runs
+"""Time one recurrent block alone on the chip, as a checkpointed step runs
 it, and split its time by scope.
 
     chiprun --chips 1 -- python scripts/ssm_layer_time.py [--repo DIR] [--trace]
+    chiprun --chips 1 -- python scripts/ssm_layer_time.py --kind gdn [--trace]
 
 At the ``granite-4.0-h-micro_s4096`` cell's shapes (2 x 4096 tokens of 2048;
 64 heads of 64, state 128, one group, chunk 256; a gated feed-forward of
@@ -16,6 +17,12 @@ scan's kernels (copies, layout changes) shows there and in no kernel's own
 time. ``--repo DIR`` times another checkout (a copy of the parent commit) by
 the same script. One JSON line, also appended to
 ``chiprun_out/ssm_layer_time.jsonl``.
+
+``--kind gdn`` times gated-delta-rule blocks instead, at the
+``qwen3-next-80b-a3b_s4096`` cell's shapes (4 x 4096 tokens of 2048; 16 key
+and 32 value heads of 128, chunk 64; behind each mixer the cell's expert
+block: 32 of 512 experts of 512 held, 10 a token, a shared expert): the
+number the next change to ``ops/gated_delta.py`` is measured against first.
 """
 
 from __future__ import annotations
@@ -55,8 +62,11 @@ def main() -> int:
     parser.add_argument("--state", type=int, default=128)
     parser.add_argument("--groups", type=int, default=1)
     parser.add_argument("--chunk", type=int, default=256)
+    parser.add_argument("--kind", choices=("ssm", "gdn"), default="ssm")
     parser.add_argument("--trace", action="store_true")
     args = parser.parse_args()
+    if args.kind == "gdn" and args.batch == 2:
+        args.batch = 4
     root = os.path.abspath(args.repo)
     sys.path.insert(0, root)
 
@@ -68,15 +78,27 @@ def main() -> int:
     device = jax.devices()[0]
     print(f"platform: {device.platform} device_kind: {device.device_kind} "
           f"repo: {root}", flush=True)
-    cfg = gpt.GPTConfig(
-        vocab_size=256, num_layers=args.layers, num_heads=32, num_kv_heads=8,
-        head_dim=64, embed_dim=args.embed, mlp_dim=args.mlp,
-        dtype=jnp.bfloat16, tp_axis=None, sp_axis=None, attention="flash",
-        norm_eps=1e-5, remat="full", gated_mlp=True, rope=False,
-        tie_embeddings=True, residual_multiplier=0.22,
-        layer_kinds=("ssm",) * args.layers, ssm_heads=args.heads,
-        ssm_head_dim=args.head_dim, ssm_state=args.state,
-        ssm_groups=args.groups, ssm_conv=4, ssm_chunk=args.chunk)
+    if args.kind == "gdn":
+        cfg = gpt.GPTConfig(
+            vocab_size=256, num_layers=args.layers, num_heads=16,
+            num_kv_heads=2, head_dim=256, embed_dim=args.embed, mlp_dim=512,
+            dtype=jnp.bfloat16, tp_axis=None, sp_axis=None,
+            attention="flash", remat="full", norm_zero_centered=True,
+            layer_kinds=("gdn",) * args.layers, gdn_key_heads=16,
+            gdn_value_heads=32, gdn_key_dim=128, gdn_value_dim=128,
+            gdn_chunk=64, moe_every=1, num_experts=512, experts_held=32,
+            experts_per_token=10, renormalize_experts=True,
+            shared_expert_dim=512)
+    else:
+        cfg = gpt.GPTConfig(
+            vocab_size=256, num_layers=args.layers, num_heads=32,
+            num_kv_heads=8, head_dim=64, embed_dim=args.embed,
+            mlp_dim=args.mlp, dtype=jnp.bfloat16, tp_axis=None, sp_axis=None,
+            attention="flash", norm_eps=1e-5, remat="full", gated_mlp=True,
+            rope=False, tie_embeddings=True, residual_multiplier=0.22,
+            layer_kinds=("ssm",) * args.layers, ssm_heads=args.heads,
+            ssm_head_dim=args.head_dim, ssm_state=args.state,
+            ssm_groups=args.groups, ssm_conv=4, ssm_chunk=args.chunk)
     layers = gpt.init_params(jax.random.PRNGKey(0), cfg)["layers"]
     x = jax.random.normal(jax.random.PRNGKey(1),
                           (args.batch, args.seq, args.embed), jnp.bfloat16)
@@ -92,7 +114,7 @@ def main() -> int:
 
     fwd = jax.jit(blocks)
     both = jax.jit(jax.value_and_grad(blocks, argnums=(0, 1)))
-    out = {"tag": args.tag, "layers": args.layers,
+    out = {"tag": args.tag, "kind": args.kind, "layers": args.layers,
            "device_kind": device.device_kind,
            "fwd_ms_a_layer": timed(fwd, layers, x) / args.layers,
            "fwd_bwd_ms_a_layer": timed(both, layers, x) / args.layers}

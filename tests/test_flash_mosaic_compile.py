@@ -59,6 +59,8 @@ SHAPES = {
     "starcoder2-3b_s4096": (48, 4, 4096, 128, jnp.bfloat16, True),
     "starcoder2-3b_s512": (384, 32, 512, 128, jnp.bfloat16, True),
     "smoke_d64": (16, 16, 1024, 64, jnp.bfloat16, True),
+    # 4 sequences at 16:2 heads of 256: the widest head a cell runs.
+    "qwen3-next-80b-a3b_s4096": (64, 8, 4096, 256, jnp.bfloat16, True),
     "encoder_f32_s384": (8, 8, 384, 64, jnp.float32, False),
 }
 

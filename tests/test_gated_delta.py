@@ -1,0 +1,203 @@
+"""``ops/gated_delta.py``: the chunked (WY) form of the gated delta rule held
+to the recurrence one token a step, forward and gradients of every input.
+
+Tolerances. In float32 the two differ by the order of sums (a chunk's tokens
+are summed through ``T`` and two products, the recurrence adds one at a
+time): 2e-5 of the largest element covers it (seen: 3e-6 at 70 tokens). With
+bfloat16 MXU operands every product's inputs are rounded to 8 bits (eps
+2**-8 = 3.9e-3) and ``T``, made in float32, is rounded once before it is
+applied: 2e-2 of the largest output (seen: 6e-3). The gradient of the log
+decays is then off by 2e-3 of its norm, and by ten times that with the
+chunk's running sums of them made in bfloat16 (the last test). A ``T`` made
+in bfloat16 reads as the shipped program does while keys are nearly
+orthogonal (``A`` is small and ``T`` is rounded before it is applied
+anyway): what holds it to float32 is the equal-keys test.
+"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from horovod_tpu.ops import gated_delta
+from horovod_tpu.ops.gated_delta import (
+    gated_delta_chunked, gated_delta_sequential, unit_lower_inverse)
+
+B, HK, HV, K, V = 2, 2, 4, 16, 8
+
+
+def _inputs(seed, seq, decay=0.3, key_heads=HK):
+    rng = np.random.default_rng(seed)
+
+    def unit(t):
+        return t / np.linalg.norm(t, axis=-1, keepdims=True)
+
+    q = unit(rng.standard_normal((B, seq, key_heads, K))) / np.sqrt(K)
+    k = unit(rng.standard_normal((B, seq, key_heads, K)))
+    v = rng.standard_normal((B, seq, HV, V))
+    g = -decay * np.exp(rng.standard_normal((B, seq, HV)))
+    beta = 1 / (1 + np.exp(-rng.standard_normal((B, seq, HV))))
+    return tuple(jnp.asarray(t, jnp.float32) for t in (q, k, v, g, beta))
+
+
+def _close(got, want, tol):
+    np.testing.assert_allclose(
+        np.asarray(got, np.float32), want, rtol=0,
+        atol=tol * float(jnp.abs(want).max()) + 1e-12)
+
+
+@pytest.mark.parametrize("chunk", [16, 64])
+@pytest.mark.parametrize("seq", [64, 70, 9])
+def test_chunked_matches_sequential(chunk, seq):
+    """Two chunk sizes; lengths the chunk divides, does not divide (padded
+    with tokens that neither decay nor write), and shorter than a chunk."""
+    args = _inputs(0, seq)
+    want_o, want_s = gated_delta_sequential(*args)
+    o, s = gated_delta_chunked(*args, chunk=chunk, dtype=jnp.float32)
+    assert o.shape == want_o.shape and s.shape == (B, HV, K, V)
+    _close(o, want_o, 2e-5)
+    _close(s, want_s, 2e-5)
+
+
+@pytest.mark.parametrize("key_heads", [HV, HK, 1])
+def test_value_heads_share_key_heads(key_heads):
+    """Value head h reads key head h // (Hv / Hk): equal to repeating the
+    key heads by hand."""
+    q, k, v, g, beta = _inputs(1, 40, key_heads=key_heads)
+    rep = HV // key_heads
+    want, _ = gated_delta_sequential(
+        jnp.repeat(q, rep, axis=2), jnp.repeat(k, rep, axis=2), v, g, beta)
+    o, _ = gated_delta_chunked(q, k, v, g, beta, chunk=16, dtype=jnp.float32)
+    _close(o, want, 2e-5)
+
+
+@pytest.mark.parametrize("chunk,seq", [(16, 64), (64, 70), (16, 37)])
+@pytest.mark.parametrize("wrt", range(5), ids=["q", "k", "v", "g", "beta"])
+def test_gradients_of_every_input(chunk, seq, wrt):
+    args = _inputs(2, seq)
+    rng = np.random.default_rng(3)
+    co = jnp.asarray(rng.standard_normal((B, seq, HV, V)), jnp.float32)
+    cs = jnp.asarray(rng.standard_normal((B, HV, K, V)), jnp.float32)
+
+    def scalar(fn):
+        def f(*a):
+            o, s = fn(*a)
+            return jnp.sum(o * co) + jnp.sum(s * cs)
+        return jax.grad(f, argnums=wrt)(*args)
+
+    want = scalar(gated_delta_sequential)
+    got = scalar(lambda *a: gated_delta_chunked(*a, chunk=chunk,
+                                                dtype=jnp.float32))
+    _close(got, want, 5e-5)
+
+
+@pytest.mark.parametrize("decay,what", [(1e-3, "near one"), (8.0, "near zero")])
+def test_decays_near_one_and_near_zero(decay, what):
+    """alpha = exp(g) near 1 (the state hardly forgets: the correction does
+    all the work) and near 0 (exp(-8) and far less: ratios of running
+    products underflow unless the exponent is masked before the exp)."""
+    args = _inputs(4, 48, decay=decay)
+    want_o, want_s = gated_delta_sequential(*args)
+    o, s = gated_delta_chunked(*args, chunk=16, dtype=jnp.float32)
+    assert bool(jnp.all(jnp.isfinite(o)))
+    _close(o, want_o, 2e-5)
+    _close(s, want_s, 2e-5)
+    grads = jax.grad(lambda *a: jnp.sum(gated_delta_chunked(
+        *a, chunk=16, dtype=jnp.float32)[0] ** 2), argnums=(0, 1, 2, 3, 4))(
+            *args)
+    assert all(bool(jnp.all(jnp.isfinite(t))) for t in grads)
+
+
+def test_beta_zero_is_plain_decay():
+    """Nothing is written: an entering state only decays, and each token
+    reads it through q."""
+    q, k, v, g, _ = _inputs(5, 32)
+    start = jnp.asarray(np.random.default_rng(6).standard_normal(
+        (B, HV, K, V)), jnp.float32)
+    o, s = gated_delta_chunked(q, k, v, g, jnp.zeros_like(g), chunk=16,
+                               dtype=jnp.float32, initial_state=start)
+    total = jnp.exp(jnp.cumsum(g, axis=1))                      # [B, S, Hv]
+    _close(s, total[:, -1][..., None, None] * start, 1e-5)
+    want = jnp.einsum("bshk,bhkv->bshv", jnp.repeat(q, HV // HK, axis=2),
+                      start) * total[..., None]
+    _close(o, want, 1e-5)
+
+
+def test_alpha_one_beta_one_is_the_ungated_delta_rule():
+    """S_t = S_{t-1} + k_t (v_t - S_{t-1}^T k_t)^T: after writing, a unit key
+    reads back exactly its value."""
+    q, k, v, g, beta = _inputs(7, 32, key_heads=HV)
+    o, _ = gated_delta_chunked(k, k, v, jnp.zeros_like(g),
+                               jnp.ones_like(beta), chunk=16,
+                               dtype=jnp.float32)
+    _close(o, v, 1e-5)
+    state = np.zeros((B, HV, K, V), np.float32)
+    kn, vn = np.asarray(k), np.asarray(v)
+    for t in range(32):
+        seen = np.einsum("bhkv,bhk->bhv", state, kn[:, t])
+        state += np.einsum("bhk,bhv->bhkv", kn[:, t], vn[:, t] - seen)
+    _, s = gated_delta_chunked(q, k, v, jnp.zeros_like(g),
+                               jnp.ones_like(beta), chunk=16,
+                               dtype=jnp.float32)
+    _close(s, state, 1e-5)
+
+
+@pytest.mark.parametrize("size", [1, 2, 16, 64])
+def test_unit_lower_inverse_and_its_gradient(size):
+    rng = np.random.default_rng(8)
+    a = jnp.asarray(np.tril(rng.standard_normal((3, size, size)), -1),
+                    jnp.float32)
+    want = jnp.linalg.inv(jnp.eye(size) + a)
+    _close(unit_lower_inverse(a), want, 1e-5)
+    if size > 1:
+        co = jnp.asarray(rng.standard_normal((3, size, size)), jnp.float32)
+        got = jax.grad(lambda t: jnp.sum(unit_lower_inverse(t) * co))(a)
+        ref = jax.grad(lambda t: jnp.sum(
+            jnp.linalg.inv(jnp.eye(size) + jnp.tril(t, -1)) * co))(a)
+        _close(jnp.tril(got, -1), ref, 1e-4)
+
+
+def test_equal_keys_do_not_blow_the_inverse_up():
+    """Every key the same at alpha = beta = 1: ``A`` is all ones below the
+    diagonal, whose inverse is bidiagonal but whose powers hold binomials up
+    to 1e17; the inverse by blocks stays exact."""
+    a = jnp.tril(jnp.ones((64, 64), jnp.float32), -1)
+    want = np.eye(64, dtype=np.float32) - np.eye(64, k=-1, dtype=np.float32)
+    np.testing.assert_allclose(unit_lower_inverse(a), want, atol=1e-6)
+
+
+def test_bad_shapes_and_chunks_raise_by_name():
+    q, k, v, g, beta = _inputs(9, 16)
+    with pytest.raises(ValueError, match="power of two"):
+        gated_delta_chunked(q, k, v, g, beta, chunk=48)
+    with pytest.raises(ValueError, match="gated delta rule"):
+        gated_delta_chunked(q, k, v[:, :, :3], g, beta)
+
+
+def test_bfloat16_products_hold_and_bfloat16_running_sums_would_not(
+        monkeypatch):
+    """The shipped precision (bfloat16 MXU operands, float32 decays, sums and
+    ``T``) against the float32 recurrence, and the fault the benchmark's
+    fifth check row exists for, on the gradient of the log decays: the
+    chunk's running sums of them made in bfloat16."""
+    args = _inputs(10, 256, decay=0.05)
+
+    def g_grad(fn):
+        return jax.grad(lambda g: jnp.sum(fn(
+            args[0], args[1], args[2], g, args[4])[0].astype(jnp.float32)
+            ** 2))(args[3])
+
+    def miss(got, want):
+        return float(jnp.linalg.norm(got - want) / jnp.linalg.norm(want))
+
+    want_o, _ = gated_delta_sequential(*args)
+    want = g_grad(gated_delta_sequential)
+    o, _ = gated_delta_chunked(*args, chunk=64)
+    assert o.dtype == jnp.bfloat16
+    _close(o, want_o, 2e-2)
+    shipped = miss(g_grad(lambda *a: gated_delta_chunked(*a, chunk=64)), want)
+    real = jnp.cumsum
+    monkeypatch.setattr(jnp, "cumsum", lambda x, axis: real(
+        x.astype(jnp.bfloat16), axis=axis).astype(jnp.float32))
+    faulty = miss(g_grad(lambda *a: gated_delta_chunked(*a, chunk=64)), want)
+    assert shipped < 1e-2 < faulty, (shipped, faulty)

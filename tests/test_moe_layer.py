@@ -27,6 +27,7 @@ from horovod_tpu.parallel import moe  # noqa: E402
 from horovod_tpu.parallel.moe import moe_layer  # noqa: E402
 
 from benchmarks import flops_moe  # noqa: E402
+from benchmarks.reference import gpt_linear_moe_dp as share_reference  # noqa: E402,E501
 from benchmarks.reference import gpt_moe_dp as reference  # noqa: E402
 
 T, D, M, E = 48, 16, 24, 16
@@ -299,10 +300,10 @@ def test_metrics_count_what_a_checkpointed_block_keeps(make_runtime):
     trace(dataclasses.replace(sparse, remat="full", moe_every=0))
     fams = hvd.metrics()
     assert fams[family]["type"] == "counter"
-    # Neither block has a state-space mixer: nothing is kept under its name
-    # (tests/test_gpt_hybrid.py counts that one).
+    # Neither block has a recurrent mixer: nothing is kept under their names
+    # (tests/test_gpt_hybrid.py and test_gpt_linear_moe.py count those).
     assert {labels["name"] for _, labels, _ in fams[family]["samples"]} \
-        == set(gpt.SAVED_NAMES) - {"ssm_scan_out"}
+        == set(gpt.SAVED_NAMES) - {"ssm_scan_out", "gdn_scan_out"}
     tokens, f32 = batch * seq, 4
     heads = sparse.num_heads * sparse.head_dim
     # Two blocks split, a flash pair each; the dense block's up projection;
@@ -388,6 +389,159 @@ def test_compiled_step_carries_the_expert_layers_scopes(make_runtime):
     assert not some("/mlp/")
 
 
+def _uncut(h, router, w_gate, w_up, w_down, shared, top_k):
+    """The whole layer by the share's reference, given every expert."""
+    m = {"router": router, "w_gate": w_gate, "w_up": w_up, "w_down": w_down,
+         "shared": shared}
+    return share_reference.expert_block(h, m, top_k)
+
+
+def _shared(seed):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+    return {"w_gate": jax.random.normal(ks[0], (D, M)) / 4,
+            "w_up": jax.random.normal(ks[1], (D, M)) / 4,
+            "w_down": jax.random.normal(ks[2], (M, D)) / 5,
+            "gate": jax.random.normal(ks[3], (D,)) / 4}
+
+
+SHARES = (0, 4, 8, 12)
+
+
+def test_the_shares_add_up_to_the_uncut_layer():
+    """The cut a configuration with more experts than a chip makes: at 16
+    experts, 4 a token, renormalised, the outputs of the four shares of 4
+    with the shared expert counted once add up to what the uncut reference
+    gives for the whole layer, and so do the gradients of the router (each
+    share sees the whole router) and of the tokens; each share's own
+    experts' gradients are the uncut layer's rows."""
+    top_k = 4
+    h, router, w_gate, w_up, w_down = layer_inputs(11)
+    shared = _shared(12)
+    weigh = jnp.cos(jnp.arange(T * D, dtype=jnp.float32)).reshape(T, D)
+
+    def whole(h, router, w_gate, w_up, w_down):
+        y, load_balance, counts = _uncut(h, router, w_gate, w_up, w_down,
+                                         shared, top_k)
+        return jnp.sum(y * weigh), (y, load_balance, counts)
+
+    def share(first):
+        def f(h, router, w_gate, w_up, w_down):
+            y, aux = moe_layer(
+                h, router, w_gate[first:first + 4], w_up[first:first + 4],
+                w_down[first:first + 4], top_k=top_k, dtype=jnp.float32,
+                first_expert=first, renormalize=True)
+            return jnp.sum(y * weigh), (y, aux)
+        return f
+
+    args = (h, router, w_gate, w_up, w_down)
+    (_, (y_ref, lb_ref, counts_ref)), g_ref = jax.value_and_grad(
+        whole, argnums=range(5), has_aux=True)(*args)
+    once = share_reference.shared_expert(h, shared)
+    total, grads = once, None
+    for first in SHARES:
+        (_, (y, aux)), g = jax.value_and_grad(
+            share(first), argnums=range(5), has_aux=True)(*args)
+        # The router's terms are the whole router's on every share.
+        np.testing.assert_allclose(aux["load_balance"], lb_ref, rtol=1e-5)
+        np.testing.assert_array_equal(aux["counts"],
+                                      np.asarray(counts_ref, np.int32))
+        total = total + y
+        grads = g if grads is None else jax.tree.map(jnp.add, grads, g)
+    np.testing.assert_allclose(total, y_ref, rtol=1e-5, atol=1e-5)
+    # The uncut loss's gradient has the shared expert's part too: through h.
+    g_once = jax.grad(lambda h: jnp.sum(
+        share_reference.shared_expert(h, shared) * weigh))(h)
+    for name, got, want in zip(
+            ("h", "W_r", "W_gate", "W_up", "W_down"),
+            (grads[0] + g_once,) + tuple(grads[1:]), g_ref):
+        np.testing.assert_allclose(
+            got, want, rtol=1e-5, atol=1e-5 * float(jnp.abs(want).max()),
+            err_msg=name)
+
+
+@pytest.mark.parametrize("first", SHARES)
+def test_a_share_matches_its_reference(first):
+    """One share against the benchmark's reference given the same share:
+    output, load-balance term, counts, every gradient."""
+    top_k = 4
+    h, router, w_gate, w_up, w_down = layer_inputs(13 + first)
+    cut = tuple(w[first:first + 4] for w in (w_gate, w_up, w_down))
+
+    def got(h, router, *w):
+        y, aux = moe_layer(h, router, *w, top_k=top_k, dtype=jnp.float32,
+                           first_expert=first, renormalize=True)
+        return jnp.sum(y * jnp.cos(y)) + aux["load_balance"], (y, aux)
+
+    def want(h, router, *w):
+        m = dict(zip(("router", "w_gate", "w_up", "w_down"), (router, *w)))
+        y, load_balance, counts = share_reference.expert_block(
+            h, m, top_k, first)
+        return jnp.sum(y * jnp.cos(y)) + load_balance, (y, counts)
+
+    (_, (y, aux)), grads = jax.value_and_grad(
+        got, argnums=range(5), has_aux=True)(h, router, *cut)
+    (_, (y_ref, counts)), grads_ref = jax.value_and_grad(
+        want, argnums=range(5), has_aux=True)(h, router, *cut)
+    np.testing.assert_allclose(y, y_ref, rtol=1e-5, atol=1e-5)
+    np.testing.assert_array_equal(aux["counts"], np.asarray(counts, np.int32))
+    for g, g_ref in zip(grads, grads_ref):
+        np.testing.assert_allclose(
+            g, g_ref, rtol=1e-5, atol=1e-5 * float(jnp.abs(g_ref).max()))
+
+
+@pytest.mark.parametrize("top_k", [1, 2, 8])
+def test_renormalised_weights(top_k):
+    """``renormalize``: a token's weights sum to one, so with every expert
+    the same matrix the layer is that expert; the gradient flows through the
+    sum (the router's gradient equals autodiff of the plain formula)."""
+    h, router, w_gate, w_up, w_down = layer_inputs(20 + top_k)
+    same = tuple(jnp.broadcast_to(w[:1], w.shape)
+                 for w in (w_gate, w_up, w_down))
+    y, _ = moe_layer(h, router, *same, top_k=top_k, dtype=jnp.float32,
+                     renormalize=True)
+    one = (jax.nn.silu(h @ w_gate[0]) * (h @ w_up[0])) @ w_down[0]
+    np.testing.assert_allclose(y, one, rtol=1e-4, atol=1e-5)
+
+    def got(router):
+        y, _ = moe_layer(h, router, w_gate, w_up, w_down, top_k=top_k,
+                         dtype=jnp.float32, renormalize=True)
+        return jnp.sum(y * jnp.cos(y))
+
+    def want(router):
+        m = {"router": router, "w_gate": w_gate, "w_up": w_up,
+             "w_down": w_down}
+        y = share_reference.expert_block(h, m, top_k)[0]
+        return jnp.sum(y * jnp.cos(y))
+
+    g, g_ref = jax.grad(got)(router), jax.grad(want)(router)
+    # (At one expert a token the weight is the constant 1: both are zero.)
+    np.testing.assert_allclose(
+        g, g_ref, rtol=1e-4, atol=1e-5 * float(jnp.abs(g_ref).max()) + 2e-6)
+    # Not renormalised, the same layer's weights sum to less than one.
+    plain_y, _ = moe_layer(h, router, *same, top_k=top_k, dtype=jnp.float32)
+    if top_k < E:
+        assert float(jnp.abs(plain_y - one).max()) > 1e-3
+
+
+@pytest.mark.parametrize("first, held", [(-1, 4), (13, 4), (1, 16)])
+def test_a_share_outside_the_router_raises(first, held):
+    h, router, w_gate, w_up, w_down = layer_inputs()
+    with pytest.raises(ValueError, match="expert layer: experts"):
+        moe_layer(h, router, w_gate[:held], w_up[:held], w_down[:held],
+                  top_k=2, first_expert=first)
+
+
+def test_metrics_count_a_shares_held_experts(make_runtime):
+    make_runtime(devices=jax.devices()[:1])
+    h, router, *w = layer_inputs()
+    jax.jit(lambda h, r, *w: moe_layer(
+        h, r, *w, top_k=2, dtype=jnp.float32, first_expert=8)[0])(
+            h, router, *(t[8:12] for t in w))
+    assert sample_value(
+        hvd.metrics(), "hvdtpu_spmd_moe_layer_traces_total", experts=str(E),
+        top_k="2", ep="1", grouped_matmul="ragged_dot", held="4") == 1.0
+
+
 def test_metrics_count_the_expert_layers_trace(make_runtime):
     make_runtime(devices=jax.devices()[:1])
     h, *w = layer_inputs()
@@ -397,7 +551,8 @@ def test_metrics_count_the_expert_layers_trace(make_runtime):
     assert fams["hvdtpu_spmd_moe_layer_traces_total"]["type"] == "counter"
     assert sample_value(
         fams, "hvdtpu_spmd_moe_layer_traces_total", experts=str(E),
-        top_k="2", ep="1", grouped_matmul="ragged_dot") == 1.0
+        top_k="2", ep="1", grouped_matmul="ragged_dot",
+        held=str(E)) == 1.0
 
 
 def test_operation_count_by_hand():

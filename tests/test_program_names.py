@@ -121,6 +121,37 @@ def test_hybrid_step_holds_the_scan_kernels_under_their_scope(spmd4):
         for kernel, traces in ((ssd.KERNEL_FWD, 2.0), (ssd.KERNEL_BWD, 1.0))}
 
 
+# A gated-delta-rule mixer in layer 0, an expert block with a shared expert
+# and a share of the router's experts after both mixers.
+LINEAR = dict(layer_kinds=("gdn", "attention"), gdn_key_heads=2,
+              gdn_value_heads=4, gdn_key_dim=8, gdn_value_dim=8, gdn_chunk=16,
+              moe_every=1, num_experts=8, experts_per_token=2, experts_held=4,
+              renormalize_experts=True, shared_expert_dim=16,
+              attention_gate=True, qk_head_norm=True)
+
+
+@pytest.mark.parametrize("inner", ["in_proj", "conv", "scan", "gate_norm",
+                                   "out_proj"])
+def test_linear_attention_step_carries_the_gdn_scopes(spmd4, inner):
+    """Where ``gdn_ms``, ``gdn_scan_ms`` and ``gdn_proj_ms`` look: every part
+    of the mixer under ``layer0/gdn/<part>`` in the forward pass, in the
+    recomputed copy and in the backward pass; the shared expert under
+    ``moe/shared``, beside the router, the dispatch and the experts."""
+    names = op_names(*gpt_step("full", **LINEAR))
+
+    def some(*parts):
+        return any(all(p in n for p in parts) for n in names)
+
+    assert some("jvp(layer0)", f"/gdn/{inner}/")
+    assert some("transpose(jvp(layer0))", f"/gdn/{inner}/")
+    assert some(f"rematted_computation/gdn/{inner}/")
+    assert not some("layer1", "/gdn/")
+    for scope in ("shared", "router", "dispatch", "experts"):
+        assert some("jvp(layer0)", f"/moe/{scope}/"), scope
+        assert some("jvp(layer1)", f"/moe/{scope}/"), scope
+    assert some("transpose(jvp(layer1))", "/moe/shared/")
+
+
 @pytest.mark.parametrize("more", [{}, SPARSE], ids=["dense", "sparse"])
 def test_a_step_without_a_state_space_layer_traces_no_scan_kernel(spmd4,
                                                                   more):
