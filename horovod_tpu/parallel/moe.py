@@ -28,8 +28,10 @@ rank routes all of them, applies its own experts to the rows routed to them
 (sorted first; rows of other ranks' experts stay zero) and a ``psum_scatter``
 hands each rank the sum for its own tokens. At ``k`` of 8 over 4 ranks a
 token's experts lie on 3.6 ranks on average, so this moves what an
-all-to-all would and needs no capacity. ``tp_axis`` shards the experts'
-width ``m``; the partial sums meet in one ``psum`` after the combine.
+all-to-all would and needs no capacity: a rank whose experts draw more rows
+than the window below takes as many windows as hold them. ``tp_axis`` shards
+the experts' width ``m``; the partial sums meet in one ``psum`` after the
+combine.
 
 **A rank's share without the mesh.** With no ``axis`` bound and fewer expert
 matrices than the router is wide, the layer holds experts ``first_expert``
@@ -39,9 +41,23 @@ auxiliary terms and counts are the whole router's), sorts its own experts'
 rows first exactly as the bound branch does, and returns ``sum_{e in S_t,
 e held}``: the partial sum that rank would hand to the exchange. Nothing
 stands in for the absent ranks or for the exchange; the shares' outputs add
-up to the whole layer's (``tests/test_moe_layer.py``). It still sorts and
-gathers all ``T k`` rows though only ``experts_local / E`` of them are its
-own.
+up to the whole layer's (``tests/test_moe_layer.py``).
+
+**A share's rows.** Where some experts are elsewhere (either branch above)
+the held experts' rows are the first ``sum(group_sizes)`` of the sort's
+order, and the layer gathers, multiplies, masks and sums back to their
+tokens a window of ``R`` rows of it at a time: ``R`` = :func:`share_rows`,
+static, twice the even share ``T k experts_local / E`` up to the grouped
+matmul's tile. From the sort on no tensor has more than ``R`` rows of the
+tokens' width or the experts'. The first window holds every held row of a
+routing within twice of even; how many rows are held is known on the chip
+before any row moves, and a routing that sends more takes the next window
+too, and the next, until every held row is taken (a ``lax.while_loop`` whose
+body such a routing alone runs, a rank's own with no collective inside:
+:func:`_held_experts`). One code path for every routing; no second branch.
+Where ``R`` would be ``T k`` (every expert held, an ``ep`` axis of 2, a
+batch under a tile) the layer works on all the rows at once, as a layer
+that holds every expert does.
 
 Gradients: the choice ``S_t`` is not differentiable; the router learns
 through the weights ``p_{t,e}`` and through the two auxiliary terms returned
@@ -52,7 +68,9 @@ again, for its backward pass, the router, the sort, the sorted rows, and the
 gate and up products with their activation, and nothing else. The down
 projection and the weighted sum have a backward pass of their own
 (:func:`_down_and_combine`) that needs no expert's output, so their
-recomputation is dead code; and the three expert tensors in the compute dtype
+recomputation is dead code (a share's windows make theirs again inside the
+backward rule, from the recomputed sort, and are dead code in the
+recomputed copy altogether); and the three expert tensors in the compute dtype
 carry a name a checkpoint policy can keep (``checkpoint_name``:
 ``"moe_expert_matrices"``, 6 bytes an expert parameter in bfloat16, in
 ``gpt.SAVED_NAMES``), so the cast is made once. **Nothing whose rows lie in
@@ -67,6 +85,7 @@ order still).
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Optional, Tuple
 
 import jax
@@ -78,6 +97,23 @@ from ..ops.collectives import pvary
 from .axes import axis_bound as _axis_bound, axis_size as _axis_size
 
 GROUPED_MATMUL = "ragged_dot"
+# A rank's share works on its rows a window of this many times its even share
+# of the T k at a time. Why two: the busiest single expert of 512 reads
+# 2.1-2.25 times the mean (ledger, PR 31) and the sum over a rank's 32 experts
+# spreads far less, so an even router's rows fit one window; a router that
+# favours the rank's experts pays a window more for each even share's twice.
+SHARE_HEADROOM = 2
+ROW_TILE = 512      # the grouped matmul's tile of rows
+
+
+def share_rows(tokens: int, top_k: int, held: int, experts: int) -> int:
+    """Rows a layer holding ``held`` of ``experts`` gathers, multiplies and
+    puts back at a time for ``tokens`` tokens: ``SHARE_HEADROOM`` times the
+    even share, up to a multiple of ``ROW_TILE``, and never more than
+    ``tokens top_k``."""
+    pairs = tokens * top_k
+    even = -(-SHARE_HEADROOM * pairs * held // experts)
+    return min(pairs, -(-even // ROW_TILE) * ROW_TILE)
 
 
 @jax.custom_vjp
@@ -104,6 +140,23 @@ def _grouped(lhs, w, group_sizes, mine):
     leaves unwritten, at zero."""
     out = lax.ragged_dot(lhs, w, group_sizes)
     return out if mine is None else jnp.where(mine, out, 0)
+
+
+def _down_products_bwd(hidden, w_down, p_rows, g_rows, group_sizes, mine):
+    """The down projection's backward products for sorted rows under their
+    weights ``p_rows`` and the output's cotangent ``g_rows``: ``u = g_rows
+    W_down^T`` and the cotangents of ``hidden`` and ``W_down``, both by the
+    grouped matmul's own transpose rules."""
+    with jax.named_scope("experts"):
+        u, = jax.linear_transpose(
+            lambda h: lax.ragged_dot(h, w_down, group_sizes), hidden)(g_rows)
+        if mine is not None:
+            u = jnp.where(mine, u, 0)
+        d_w_down, = jax.linear_transpose(
+            lambda w: lax.ragged_dot((p_rows * hidden).astype(hidden.dtype),
+                                     w, group_sizes), w_down)(g_rows)
+        d_hidden = (p_rows * u).astype(hidden.dtype)
+    return u, d_hidden, d_w_down
 
 
 @jax.custom_vjp
@@ -139,16 +192,8 @@ def _down_and_combine_bwd(residuals, g):
         p_rows = top_p.reshape(-1)[order][:, None]
         if mine is not None:
             g_rows = jnp.where(mine, g_rows, 0)
-    with jax.named_scope("experts"):
-        # Both products by the grouped matmul's own transpose rules.
-        u, = jax.linear_transpose(
-            lambda h: lax.ragged_dot(h, w_down, group_sizes), hidden)(g_rows)
-        if mine is not None:
-            u = jnp.where(mine, u, 0)
-        d_w_down, = jax.linear_transpose(
-            lambda w: lax.ragged_dot((p_rows * hidden).astype(hidden.dtype),
-                                     w, group_sizes), w_down)(g_rows)
-        d_hidden = (p_rows * u).astype(hidden.dtype)
+    u, d_hidden, d_w_down = _down_products_bwd(hidden, w_down, p_rows,
+                                               g_rows, group_sizes, mine)
     with jax.named_scope("combine"):
         d_top_p = jnp.sum(u.astype(top_p.dtype) * hidden.astype(top_p.dtype),
                           axis=1)[inv].reshape(top_p.shape)
@@ -156,6 +201,168 @@ def _down_and_combine_bwd(residuals, g):
 
 
 _down_and_combine.defvjp(_down_and_combine_fwd, _down_and_combine_bwd)
+
+
+def _to_tokens(vals, pair_of_row, tokens, top_k):
+    """``[tokens, w]``, float32 at least: each token's sum of the rows
+    ``vals`` ``[R, w]`` of its pairs ``pair_of_row`` (token ``pair //
+    top_k``). Rows that are nobody's come in at zero.
+
+    A scatter-add: 2.3 ms for 20,480 float32 rows of 2048 on a v5e, against
+    9.0 ms for the same sum by gathers alone (the rows sorted by token,
+    neighbours added in doubling steps, each token's last row read; PERF.md,
+    Findings, PR 32; ``scripts/moe_layer_time.py`` times both)."""
+    wide = jnp.promote_types(vals.dtype, jnp.float32)
+    return jnp.zeros((tokens, vals.shape[1]), wide).at[
+        pair_of_row // top_k].add(vals.astype(wide))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _take(x, pair_of_row, top_k):
+    """``x[pair_of_row // top_k]``: the tokens' rows of ``R`` pairs of the
+    sort's order, with no ``[T k, d]`` copy of the batch; the cotangent goes
+    back through :func:`_to_tokens`."""
+    return x[pair_of_row // top_k]
+
+
+def _take_fwd(x, pair_of_row, top_k):
+    # Nothing of x but its length and dtype is needed again.
+    return x[pair_of_row // top_k], (pair_of_row, x[:, :0])
+
+
+def _take_bwd(top_k, residuals, g):
+    pair_of_row, like = residuals
+    return _to_tokens(g, pair_of_row, like.shape[0], top_k).astype(
+        like.dtype), None
+
+
+_take.defvjp(_take_fwd, _take_bwd)
+
+
+@jax.custom_vjp
+def _down_and_combine_window(hidden, w_down, top_p, pair_of_row,
+                             group_sizes, mine):
+    """:func:`_down_and_combine` for ``R`` rows of the sort's order alone:
+    ``y_t`` sums the token's rows among them. The same rule backward; no
+    tensor has ``T k`` rows."""
+    return _down_and_combine_window_fwd(hidden, w_down, top_p, pair_of_row,
+                                        group_sizes, mine)[0]
+
+
+def _down_and_combine_window_fwd(hidden, w_down, top_p, pair_of_row,
+                                 group_sizes, mine):
+    with jax.named_scope("experts"):
+        out_rows = _grouped(hidden, w_down, group_sizes, mine)       # [R, d]
+    with jax.named_scope("combine"):
+        p_rows = top_p.reshape(-1)[pair_of_row][:, None]
+        y = _to_tokens(out_rows.astype(jnp.float32) * p_rows, pair_of_row,
+                       *top_p.shape)
+    return y, (hidden, w_down, top_p, pair_of_row, group_sizes, mine)
+
+
+def _down_and_combine_window_bwd(residuals, g):
+    hidden, w_down, top_p, pair_of_row, group_sizes, mine = residuals
+    tokens, top_k = top_p.shape
+    with jax.named_scope("combine"):
+        g_rows = jnp.where(
+            mine, g.astype(hidden.dtype)[pair_of_row // top_k], 0)   # [R, d]
+        p_rows = top_p.reshape(-1)[pair_of_row][:, None]
+    u, d_hidden, d_w_down = _down_products_bwd(hidden, w_down, p_rows,
+                                               g_rows, group_sizes, mine)
+    with jax.named_scope("combine"):
+        d_p_rows = jnp.sum(u.astype(top_p.dtype) * hidden.astype(top_p.dtype),
+                           axis=1, keepdims=True)
+        # A row's number goes to its pair's column of its token.
+        d_top_p = _to_tokens(
+            d_p_rows * jax.nn.one_hot(pair_of_row % top_k, top_k,
+                                      dtype=top_p.dtype),
+            pair_of_row, tokens, top_k)
+    return d_hidden, d_w_down, d_top_p, None, None, None
+
+
+_down_and_combine_window.defvjp(_down_and_combine_window_fwd,
+                                _down_and_combine_window_bwd)
+
+
+@functools.partial(jax.jit, static_argnums=0)
+def _window(window_rows, lo, xt, w_gate, w_up, w_down, top_p, order,
+            group_sizes):
+    """What rows ``lo`` to ``lo + window_rows`` of the sort's order add to a
+    share's partial sum, ``[T, d]`` float32: the tokens' rows gathered, the
+    held experts applied to those of their rows that lie in the window, the
+    rows summed back to their tokens under their weights. (Jitted so that
+    JAX traces it once for the window at 0 and the loop's: a layer's second
+    trace of it cost the cell's step 0.3 s of lowering.)"""
+    with jax.named_scope("dispatch"):
+        pair_of_row = lax.dynamic_slice(order, (lo,), (window_rows,))
+        ends = jnp.cumsum(group_sizes)
+        sizes = jnp.clip(jnp.minimum(ends, lo + window_rows)
+                         - jnp.maximum(ends - group_sizes, lo), 0)
+        mine = (lo + jnp.arange(window_rows) < ends[-1])[:, None]
+        rows = _take(xt, pair_of_row, top_p.shape[1])                # [R, d]
+    with jax.named_scope("experts"):
+        rows = jnp.where(mine, rows, 0)
+        hidden = (jax.nn.silu(_grouped(rows, w_gate, sizes, mine))
+                  * _grouped(rows, w_up, sizes, mine))
+    return _down_and_combine_window(hidden, w_down, top_p, pair_of_row,
+                                    sizes, mine)
+
+
+def _windows(window_rows, group_sizes, window):
+    """``window(lo)`` for the window at 0 and, while held rows lie beyond the
+    windows taken, for the next one, added up: one window for a routing
+    whose held rows number ``window_rows`` at most, the loop's body never
+    run; ``ceil(held rows / window_rows)`` windows for any other."""
+    held = jnp.sum(group_sizes)
+
+    def more(carry):
+        return carry[0] < held
+
+    def next_window(carry):
+        lo, total = carry
+        return lo + window_rows, jax.tree.map(jnp.add, total, window(lo))
+
+    return lax.while_loop(more, next_window,
+                          (jnp.asarray(window_rows, held.dtype),
+                           window(jnp.zeros((), held.dtype))))[1]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _held_experts(window_rows, xt, w_gate, w_up, w_down, top_p, order,
+                  group_sizes):
+    """A share's partial sum ``[T, d]`` float32 for pairs sorted with the
+    held experts' first (``order``, padded to whole windows), :func:`_window`
+    by window of ``window_rows`` rows of the order until every held row is
+    taken: no token is dropped whatever the routing, and no tensor has more
+    than ``window_rows`` rows.
+
+    How many windows is known on the chip alone, so the loop is a
+    ``lax.while_loop`` in the forward and in the backward rule alike, and
+    the backward rule makes each window's forward again from the rule's
+    inputs: nothing a window makes outlives it. Windows after the first add
+    their cotangents in the cotangents' own dtypes."""
+    return _held_experts_fwd(window_rows, xt, w_gate, w_up, w_down, top_p,
+                             order, group_sizes)[0]
+
+
+def _held_experts_fwd(window_rows, *args):
+    group_sizes = args[-1]
+    y = _windows(window_rows, group_sizes,
+                 lambda lo: _window(window_rows, lo, *args))
+    return y, args
+
+
+def _held_experts_bwd(window_rows, args, g):
+    *wrt, order, group_sizes = args
+
+    def cotangents(lo):
+        return jax.vjp(lambda *a: _window(window_rows, lo, *a, order,
+                                          group_sizes), *wrt)[1](g)
+
+    return (*_windows(window_rows, group_sizes, cotangents), None, None)
+
+
+_held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
 
 
 def moe_layer(x, router_w, w_gate, w_up, w_down, top_k: int,
@@ -199,16 +406,19 @@ def moe_layer(x, router_w, w_gate, w_up, w_down, top_k: int,
     # Some experts are elsewhere: on the axis's other ranks, or on ranks
     # this program does not run.
     share = ep or experts_local < num_experts
-    from .. import runtime
-    recorder = runtime.recorder()
-    if recorder is not None:
-        recorder.note_moe_layer(num_experts, top_k, _axis_size(axis),
-                                GROUPED_MATMUL, experts_local)
-
     xt = x.reshape(-1, d)
     if ep:
         xt = lax.all_gather(xt, axis, axis=0, tiled=True)
     T = xt.shape[0]
+    # The rows the layer works on at a time: all T k, or a share's window.
+    window_rows = share_rows(T, top_k, experts_local, num_experts) if share \
+        else T * top_k
+    windowed = window_rows < T * top_k
+    from .. import runtime
+    recorder = runtime.recorder()
+    if recorder is not None:
+        recorder.note_moe_layer(num_experts, top_k, _axis_size(axis),
+                                GROUPED_MATMUL, experts_local, window_rows)
 
     with jax.named_scope("router"):
         logits = jnp.dot(xt.astype(jnp.float32), router_w.astype(jnp.float32),
@@ -236,26 +446,35 @@ def moe_layer(x, router_w, w_gate, w_up, w_down, top_k: int,
             expert_of_pair = (expert_of_pair - first) % num_experts
             group_sizes = lax.dynamic_slice(counts, (first,),
                                             (experts_local,))
-            mine = (jnp.arange(T * top_k) < jnp.sum(group_sizes))[:, None]
+            if not windowed:
+                mine = (jnp.arange(T * top_k)
+                        < jnp.sum(group_sizes))[:, None]
         order = jnp.argsort(expert_of_pair, stable=True)
-        inv = jnp.argsort(order)
-        rows = _permute(jnp.repeat(xt.astype(dtype), top_k, axis=0),
-                        order, inv)                                  # [Tk, d]
+        if not windowed:
+            inv = jnp.argsort(order)
+            rows = _permute(jnp.repeat(xt.astype(dtype), top_k, axis=0),
+                            order, inv)                              # [Tk, d]
 
     with jax.named_scope("experts"):
         w_gate, w_up, w_down = (
             checkpoint_name(w.astype(dtype), "moe_expert_matrices")
             for w in (w_gate, w_up, w_down))
-        if mine is not None:
-            rows = jnp.where(mine, rows, 0)
-        hidden = (jax.nn.silu(_grouped(rows, w_gate, group_sizes, mine))
-                  * _grouped(rows, w_up, group_sizes, mine))
+        if not windowed:
+            if mine is not None:
+                rows = jnp.where(mine, rows, 0)
+            hidden = (jax.nn.silu(_grouped(rows, w_gate, group_sizes, mine))
+                      * _grouped(rows, w_up, group_sizes, mine))
     if _axis_bound(tp_axis):
         # Each tp rank's share of the weights' gradient is a sum over its
         # part of the width; autodiff adds them where this cast is.
         top_p = pvary(top_p, tp_axis)
-    y = _down_and_combine(hidden, w_down, top_p, order, inv, group_sizes,
-                          mine)
+    if windowed:
+        y = _held_experts(
+            window_rows, xt.astype(dtype), w_gate, w_up, w_down, top_p,
+            jnp.pad(order, (0, -order.shape[0] % window_rows)), group_sizes)
+    else:
+        y = _down_and_combine(hidden, w_down, top_p, order, inv, group_sizes,
+                              mine)
     with jax.named_scope("combine"):
         if _axis_bound(tp_axis):
             y = lax.psum(y, tp_axis)        # row-parallel expert width

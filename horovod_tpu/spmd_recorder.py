@@ -84,7 +84,7 @@ class SpmdRecorder:
         self.placed_bytes = 0
         # (kernel, block_q, block_k, operand_dtype, kv_group) -> traces
         self.flash_kernels: collections.Counter = collections.Counter()
-        # (experts, top_k, ep, grouped_matmul, held) -> traces
+        # (experts, top_k, ep, grouped_matmul, held, rows) -> traces
         self.moe_layers: collections.Counter = collections.Counter()
         # (heads, head_dim, state, groups, chunk) -> traces
         self.ssm_layers: collections.Counter = collections.Counter()
@@ -168,12 +168,15 @@ class SpmdRecorder:
                                 kv_group)] += 1
 
     def note_moe_layer(self, experts: int, top_k: int, ep: int,
-                       grouped_matmul: str, held: int) -> None:
+                       grouped_matmul: str, held: int, rows: int) -> None:
         """``parallel/moe.py`` calls this while JAX traces an expert layer:
-        what it routes over, how many of those experts this rank holds, and
-        which grouped matmul it got."""
+        what it routes over, how many of those experts this rank holds,
+        which grouped matmul it got, and how many token-expert rows it
+        gathers and multiplies at a time (all ``T k``, or a share's window
+        of them)."""
         with self._lock:
-            self.moe_layers[(experts, top_k, ep, grouped_matmul, held)] += 1
+            self.moe_layers[(experts, top_k, ep, grouped_matmul, held,
+                             rows)] += 1
 
     def note_ssm_layer(self, heads: int, head_dim: int, state: int,
                        groups: int, chunk: int) -> None:
@@ -262,12 +265,13 @@ class SpmdRecorder:
                 "counter", "Times JAX traced an expert layer (the recomputed "
                 "copy of a block counts again), by the experts it routes "
                 "over, the experts per token, the size of the expert-parallel "
-                "axis, the grouped matmul it uses and the experts this rank "
-                "holds.",
+                "axis, the grouped matmul it uses, the experts this rank "
+                "holds and the token-expert rows it gathers and multiplies "
+                "at a time (all of them, or a share's window).",
                 [("", {"experts": str(experts), "top_k": str(top_k),
                        "ep": str(ep), "grouped_matmul": gmm,
-                       "held": str(held)}, float(count))
-                 for (experts, top_k, ep, gmm, held), count in moe]),
+                       "held": str(held), "rows": str(rows)}, float(count))
+                 for (experts, top_k, ep, gmm, held, rows), count in moe]),
             "hvdtpu_spmd_ssm_layer_traces_total": family(
                 "counter", "Times JAX traced a chunked state-space scan (the "
                 "recomputed copy of a block counts again), by its heads, "

@@ -16,11 +16,23 @@ every-token reference on 1024 tokens. One JSON line a row, also appended to
 ``chiprun_out/moe_layer_time.jsonl``. ``--skew`` routes every token to the
 first ``top_k`` experts (the router's columns made equal: ties go to the
 lower index).
+
+A rank's share (``--router-width`` wider than ``--experts``; the
+``qwen3-next-80b-a3b_s4096`` cell's is ``--router-width 512 --experts 32
+--first-expert 0 --tokens 16384 --top-k 10 --width 512 --renormalize``): the
+same rows for one window of the order, as many rows as the layer works on at
+a time (``moe.share_rows``), the row gather as ``moe._take``, and a row for
+each form of the sum back to tokens on ``[R, d]`` float32 rows of the run's
+own routing: ``moe._to_tokens`` and the form not chosen (PERF.md, Findings,
+PR 32). With ``--skew`` and ``--first-expert 0`` every row is the share's
+and the layer takes ``T k / R`` windows; ``--all-rows`` times the share as
+it ran before PR 32, on all the rows at once.
 """
 
 from __future__ import annotations
 
 import argparse
+import functools
 import json
 import os
 import sys
@@ -48,6 +60,61 @@ def timed(fn, *args, reps: int = 10) -> float:
     return 1e3 * (time.perf_counter() - t0) / reps
 
 
+def to_tokens_by_gathers(vals, pair_of_row, tokens, top_k):
+    """``moe._to_tokens`` without a scatter: the rows brought to token order
+    (a sort of ``R`` pair numbers and a gather of ``R`` rows), neighbours of
+    one token added in doubling steps (a token has at most ``top_k`` rows, so
+    shifts of 1, 2, 4, ... under a same-token mask finish every sum), and
+    each token's last row read (a search and a gather of ``tokens`` rows)."""
+    by_token = jnp.argsort(pair_of_row)
+    token = pair_of_row[by_token] // top_k
+    z = vals.astype(jnp.float32)[by_token]
+    shift = 1
+    while shift < top_k:
+        same = (token[shift:] == token[:-shift])[:, None]
+        z = z + jnp.pad(jnp.where(same, z[:-shift], 0), ((shift, 0), (0, 0)))
+        shift *= 2
+    last = jnp.searchsorted(token, jnp.arange(tokens), side="right") - 1
+    has = (last >= 0) & (token[jnp.maximum(last, 0)] == jnp.arange(tokens))
+    return jnp.where(has[:, None], z[jnp.maximum(last, 0)], 0)
+
+
+def to_tokens_rows(h, router, first, held, top_k, rows) -> dict:
+    """ms of each form of the sum back to tokens, on ``rows`` float32 rows
+    as wide as the tokens and as wide as ``top_k`` (the routing weights'
+    cotangent), for the pairs the layer's own routing puts first; and the
+    largest difference between the forms."""
+    tokens, d = h.shape
+    probs = jax.nn.softmax(jnp.dot(h.astype(jnp.float32), router), axis=-1)
+    top_e = lax.top_k(probs, top_k)[1].reshape(-1)
+    order = jnp.argsort((top_e - first) % router.shape[1], stable=True)
+    pair_of_row = order[:rows]
+    n = jnp.sum((top_e - first) % router.shape[1] < held)
+    out = {}
+    forms = {"scatter_add": moe._to_tokens, "gathers": to_tokens_by_gathers}
+    for width in (d, top_k):
+        vals = jax.random.normal(jax.random.PRNGKey(7), (rows, width),
+                                 jnp.float32)
+        vals = jnp.where((jnp.arange(rows) < n)[:, None], vals, 0)
+        got = {}
+        for name, form in forms.items():
+            f = jax.jit(lambda v, p, form=form: form(v, p, tokens, top_k))
+            out[f"{name}_w{width}"] = timed(f, vals, pair_of_row)
+            got[name] = f(vals, pair_of_row)
+        out[f"forms_differ_by_w{width}"] = float(jnp.max(jnp.abs(
+            got["scatter_add"] - got["gathers"])))
+    # The parts, alone: the sorts and the two gathers.
+    out["argsort_pairs"] = timed(jax.jit(
+        lambda e: jnp.argsort(e, stable=True)), top_e)
+    out["argsort_rows"] = timed(jax.jit(jnp.argsort), pair_of_row)
+    vals = jax.random.normal(jax.random.PRNGKey(8), (rows, d), jnp.float32)
+    out["gather_rows_f32"] = timed(jax.jit(lambda v, p: v[p]), vals,
+                                   jnp.argsort(pair_of_row))
+    out["gather_tokens_f32"] = timed(jax.jit(lambda v, p: v[p]), vals,
+                                     pair_of_row[:tokens] % rows)
+    return out
+
+
 def main() -> int:
     parser = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     parser.add_argument("--tokens", type=int, default=8192)
@@ -56,15 +123,27 @@ def main() -> int:
     parser.add_argument("--experts", type=int, default=64)
     parser.add_argument("--top-k", type=int, default=8)
     parser.add_argument("--skew", action="store_true")
+    parser.add_argument("--router-width", type=int, default=None,
+                        help="experts routed over (default: --experts, "
+                        "every one held)")
+    parser.add_argument("--first-expert", type=int, default=0)
+    parser.add_argument("--renormalize", action="store_true")
+    parser.add_argument("--all-rows", action="store_true",
+                        help="a share on all T k rows, as before PR 32 "
+                        "(the headroom raised until a window is every row)")
     args = parser.parse_args()
     T, d, m, E, k = (args.tokens, args.embed, args.width, args.experts,
                      args.top_k)
+    wide, first = args.router_width or E, args.first_expert
+    if args.all_rows:
+        moe.SHARE_HEADROOM = -(-wide // E)
+    R = moe.share_rows(T, k, E, wide) if E < wide else T * k
     device = jax.devices()[0]
     print(f"platform: {device.platform} device_kind: {device.device_kind}",
           flush=True)
     ks = jax.random.split(jax.random.PRNGKey(0), 6)
     h = jax.random.normal(ks[0], (T, d), jnp.bfloat16)
-    router = jax.random.normal(ks[1], (d, E), jnp.float32) / d ** 0.5
+    router = jax.random.normal(ks[1], (d, wide), jnp.float32) / d ** 0.5
     if args.skew:
         router = jnp.broadcast_to(router[:, :1], router.shape)
     w_gate = jax.random.normal(ks[2], (E, d, m), jnp.float32) / d ** 0.5
@@ -72,13 +151,22 @@ def main() -> int:
     w_down = jax.random.normal(ks[4], (E, m, d), jnp.float32) / m ** 0.5
     weights = (router, w_gate, w_up, w_down)
 
+    moe_layer = functools.partial(moe.moe_layer, top_k=k, first_expert=first,
+                                  renormalize=args.renormalize)
+
     def layer(h, *w):
-        y, aux = moe.moe_layer(h, *w, top_k=k)
+        y, aux = moe_layer(h, *w)
         return jnp.sum(y.astype(jnp.float32)) + aux["load_balance"] \
             + aux["router_z"], aux["counts"]
 
-    rows_in = jax.random.normal(ks[5], (T * k, d), jnp.bfloat16)
     _, counts = jax.jit(layer)(h, *weights)
+    counts = counts[first:first + E]
+    rows_held = int(counts.sum())
+    busiest = float(counts.max() * E / counts.sum())
+    # The rows' table works on one window: its part of every expert's rows.
+    ends = jnp.minimum(jnp.cumsum(counts), R)
+    counts = jnp.diff(ends, prepend=0).astype(counts.dtype)
+    rows_in = jax.random.normal(ks[5], (R, d), jnp.bfloat16)
     perm = jax.random.permutation(ks[5], T * k)
     inv = jnp.argsort(perm)
 
@@ -89,8 +177,11 @@ def main() -> int:
         return jnp.sum(gmm(hidden, w_down).astype(jnp.float32))
 
     def permute(rows):
-        return jnp.sum(moe._permute(rows, perm, inv).astype(jnp.float32)
-                       * rows.astype(jnp.float32))
+        if R < T * k:       # a share's window: R of the tokens' rows
+            taken = moe._take(h, perm[:R], k)
+        else:
+            taken = moe._permute(rows, perm, inv)
+        return jnp.sum(taken.astype(jnp.float32) * rows.astype(jnp.float32))
 
     def checkpointed(*names):
         """ms of the layer's forward and backward with these names kept.
@@ -103,8 +194,11 @@ def main() -> int:
             kept, argnums=(0, 1, 2, 3, 4), has_aux=True)), h, *weights)
 
     out = {"tokens": T, "embed": d, "width": m, "experts": E, "top_k": k,
+           "router_width": wide, "first_expert": first, "rows": R,
+           "rows_held": rows_held, "windows": -(-rows_held // R),
+           "all_rows": args.all_rows,
            "skew": args.skew, "device_kind": device.device_kind,
-           "busiest_over_mean": float(counts.max() * E / counts.sum()),
+           "busiest_over_mean": busiest,
            "layer_fwd_ms": timed(jax.jit(layer), h, *weights),
            "layer_fwd_bwd_ms": timed(jax.jit(jax.grad(
                layer, argnums=(0, 1, 2, 3, 4), has_aux=True)), h, *weights),
@@ -116,17 +210,26 @@ def main() -> int:
                experts, argnums=(0, 1, 2, 3))), rows_in, *weights[1:]),
            "permute_fwd_ms": timed(jax.jit(permute), rows_in),
            "permute_fwd_bwd_ms": timed(jax.jit(jax.grad(permute)), rows_in)}
-    # Least times of the grouped matmuls: 3 matrices, 2 ops a MAC, T k rows.
-    out["experts_fwd_least_ms"] = 1e3 * 3 * 2 * T * k * d * m / 197e12
+    # Least times of the grouped matmuls: 3 matrices, 2 ops a MAC, the rows
+    # the experts draw.
+    out["experts_fwd_least_ms"] = 1e3 * 3 * 2 * int(counts.sum()) * d * m \
+        / 197e12
+    if R < T * k:
+        out["to_tokens_ms"] = to_tokens_rows(h, router, first, E, k, R)
 
     # Against every expert on every token, on what that can hold.
-    from benchmarks.reference import gpt_moe_dp as reference
     n = min(T, 1024)
     with jax.default_matmul_precision("highest"):
-        want, *_ = jax.jit(lambda h, *w: reference.expert_layer(
-            h, *w, k))(h[:n].astype(jnp.float32), *weights)
-    got, _ = jax.jit(lambda h, *w: moe.moe_layer(h, *w, top_k=k))(
-        h[:n], *weights)
+        if E < wide or args.renormalize:
+            from benchmarks.reference import gpt_linear_moe_dp as reference
+            want, *_ = jax.jit(lambda h, *w: reference.expert_block(
+                h, dict(zip(("router", "w_gate", "w_up", "w_down"), w)), k,
+                first))(h[:n].astype(jnp.float32), *weights)
+        else:
+            from benchmarks.reference import gpt_moe_dp as reference
+            want, *_ = jax.jit(lambda h, *w: reference.expert_layer(
+                h, *w, k))(h[:n].astype(jnp.float32), *weights)
+    got, _ = jax.jit(moe_layer)(h[:n], *weights)
     out["max_abs_error_over_max_abs"] = float(
         jnp.max(jnp.abs(got.astype(jnp.float32) - want))
         / jnp.max(jnp.abs(want)))

@@ -111,3 +111,27 @@ def make_runtime():
         return hvd
     yield _make
     hvd.shutdown()
+
+
+@pytest.fixture
+def moe_row_tile(monkeypatch):
+    """Sets the expert layer's tile of rows (``parallel/moe.py::ROW_TILE``,
+    512) for a test, so that a share's window of rows (``moe.share_rows``)
+    is fewer than all of them at a test's few tokens. The layer reads it
+    while JAX traces; JAX keeps what it traced of a checkpointed block by the
+    block's function and shapes, and the tile is neither, so a test that
+    traces a function of the library's own under ``jax.checkpoint``
+    (``models/gpt.py``'s block) asks for ``fresh``: JAX's caches emptied at
+    the change and after the test."""
+    import jax
+    from horovod_tpu.parallel import moe
+    emptied = []
+
+    def _set(tile, fresh=False):
+        monkeypatch.setattr(moe, "ROW_TILE", tile)
+        if fresh:
+            jax.clear_caches()
+            emptied.append(True)
+    yield _set
+    if emptied:
+        jax.clear_caches()

@@ -29,6 +29,7 @@ from jax.sharding import PartitionSpec as P
 
 import horovod_tpu as hvd
 from horovod_tpu.models import gpt
+from horovod_tpu.parallel import moe as moe_module
 from benchmarks.reference import gpt_linear_moe_dp as reference
 
 TINY = dict(
@@ -96,10 +97,20 @@ def _assert_grads_agree(grads, want, tol=2e-3):
             err_msg=jax.tree_util.keystr(path))
 
 
+@pytest.mark.parametrize("tile", [512, 8])
 @pytest.mark.parametrize("remat", ["none", "full"])
-def test_model_matches_the_reference_through_run_step(make_runtime, remat):
+def test_model_matches_the_reference_through_run_step(make_runtime,
+                                                      moe_row_tile, remat,
+                                                      tile):
     """The normal path: ``hvd.run_step`` over a dp mesh, each rank its own
-    sequences; loss, auxiliary term and every gradient leaf."""
+    sequences; loss, auxiliary term and every gradient leaf. A rank's 160
+    token-expert rows are under the grouped matmul's tile of 512, so its
+    expert layers work on all of them at once; at a tile of 8 on a window
+    of 80 (``moe.share_rows``), and on the next where the held experts draw
+    more."""
+    moe_row_tile(tile, fresh=True)
+    rows = moe_module.share_rows(S, 4, 4, 16)
+    assert rows == (4 * S if tile == 512 else 2 * S)
     make_runtime(devices=jax.devices()[:2], mesh_shape={"dp": 2})
     cfg = gpt.GPTConfig(**TINY, remat=remat)
     params = _params(cfg, 1)
@@ -139,7 +150,7 @@ def test_model_matches_the_reference_through_run_step(make_runtime, remat):
     assert gdn and gdn[0][2] >= 1
     moe = [s for s in fams["hvdtpu_spmd_moe_layer_traces_total"]["samples"]
            if s[1]["experts"] == "16" and s[1]["held"] == "4"
-           and s[1]["top_k"] == "4"]
+           and s[1]["top_k"] == "4" and s[1]["rows"] == str(rows)]
     assert moe and moe[0][2] >= 1
 
 

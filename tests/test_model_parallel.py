@@ -12,6 +12,7 @@ from jax.sharding import PartitionSpec as P
 
 import horovod_tpu as hvd
 from horovod_tpu.models import gpt
+from horovod_tpu.parallel import moe
 from horovod_tpu.parallel.moe import moe_layer
 from horovod_tpu.parallel.pipeline import pipeline_apply, stage_partition
 
@@ -22,14 +23,23 @@ def _checkpointed(f):
     return jax.checkpoint(f, policy=gpt._full_policy)
 
 
+@pytest.mark.parametrize("tile", [512, 8])
 @pytest.mark.parametrize("remat", ["none", "full"])
 @pytest.mark.parametrize("top_k", [1, 2])
-def test_moe_layer_expert_parallel_matches_local(make_runtime, top_k, remat):
+def test_moe_layer_expert_parallel_matches_local(make_runtime, moe_row_tile,
+                                                 top_k, remat, tile):
     """Experts over ep=4, the batch over ep: output, both auxiliary terms,
     counts and every gradient equal the all-experts-local layer's on the
     whole batch. Dropless either way, whatever the routing; checkpointed
     (the rows outside every group are zero in the combine's own backward
-    pass too) or not."""
+    pass too) or not. At the grouped matmul's real tile these 32 tokens'
+    rows are under one tile and every rank works on all of them at once; at
+    a tile of 8 a rank works on a window of half of them
+    (``moe.share_rows``), and on the next where its two experts draw more
+    than that: each rank's loop is its own."""
+    moe_row_tile(tile)
+    assert moe.share_rows(32, top_k, 2, 8) == (
+        32 * top_k if tile == 512 else 16 * top_k)
     make_runtime(mesh_shape={"ep": 4}, devices=jax.devices()[:4])
     d, m, n_exp = 16, 32, 8
     ks = jax.random.split(jax.random.PRNGKey(0), 5)

@@ -405,17 +405,65 @@ def _shared(seed):
 
 
 SHARES = (0, 4, 8, 12)
+# How many of the T k rows a share's experts draw, against the window R the
+# layer works on at a time (``moe.share_rows``): well under it, exactly R,
+# and all of them (two windows here).
+ROUTINGS = ("under", "exact", "over")
 
 
-def test_the_shares_add_up_to_the_uncut_layer():
+@pytest.fixture
+def small_tile(moe_row_tile):
+    """At 48 tokens a window rounded up to the grouped matmul's tile of 512
+    rows is all the rows; with a tile of 8 a share of 4 experts of 16 at 4 a
+    token works on 96 rows of 192 at a time."""
+    moe_row_tile(8)
+    assert moe.share_rows(T, 4, 4, E) == 96 == T * 4 // 2
+
+
+def routed_inputs(seed, routing, first, held=4):
+    """``layer_inputs`` with the router the identity, so that the tokens are
+    the router's logits, and the share's columns raised or lowered: ``under``
+    leaves the routing to chance (the even share of the rows held on
+    average), ``exact`` sends the first half of the tokens to the held
+    experts alone and the others past them, ``over`` sends every token to
+    them."""
+    h, router, *w = layer_inputs(seed)
+    assert D == E
+    # Tokens of the usual size; the logits are eight times them.
+    h, lift = 0.25 * h, jnp.zeros((T, E)).at[:, first:first + held].set(2.5)
+    if routing == "exact":
+        h = h + jnp.where(jnp.arange(T)[:, None] < T // 2, lift, -lift)
+    elif routing == "over":
+        h = h + lift
+    return (h, 8 * jnp.eye(D, E), *w)
+
+
+def assert_rows_held(counts, routing, first, held=4, top_k=4):
+    rows = int(counts[first:first + held].sum())
+    window = moe.share_rows(T, top_k, held, E)
+    assert {"under": rows < window * 3 // 4, "exact": rows == window,
+            "over": rows == T * min(top_k, held)}[routing], (rows, window)
+    return -(-rows // window)
+
+
+def as_a_block_runs_it(f, remat):
+    """``f`` plain, or checkpointed as a ``remat="full"`` block is."""
+    return jax.checkpoint(f, policy=gpt._full_policy) if remat == "full" \
+        else f
+
+
+@pytest.mark.parametrize("routing", ROUTINGS)
+def test_the_shares_add_up_to_the_uncut_layer(small_tile, routing):
     """The cut a configuration with more experts than a chip makes: at 16
     experts, 4 a token, renormalised, the outputs of the four shares of 4
     with the shared expert counted once add up to what the uncut reference
     gives for the whole layer, and so do the gradients of the router (each
     share sees the whole router) and of the tokens; each share's own
-    experts' gradients are the uncut layer's rows."""
+    experts' gradients are the uncut layer's rows. Whatever the routing: the
+    share whose experts are favoured finds its rows in one window, fills it
+    exactly, or takes two, and the other three shares one."""
     top_k = 4
-    h, router, w_gate, w_up, w_down = layer_inputs(11)
+    h, router, w_gate, w_up, w_down = routed_inputs(11, routing, first=4)
     shared = _shared(12)
     weigh = jnp.cos(jnp.arange(T * D, dtype=jnp.float32)).reshape(T, D)
 
@@ -436,6 +484,7 @@ def test_the_shares_add_up_to_the_uncut_layer():
     args = (h, router, w_gate, w_up, w_down)
     (_, (y_ref, lb_ref, counts_ref)), g_ref = jax.value_and_grad(
         whole, argnums=range(5), has_aux=True)(*args)
+    assert_rows_held(counts_ref, routing, first=4)
     once = share_reference.shared_expert(h, shared)
     total, grads = once, None
     for first in SHARES:
@@ -459,13 +508,12 @@ def test_the_shares_add_up_to_the_uncut_layer():
             err_msg=name)
 
 
-@pytest.mark.parametrize("first", SHARES)
-def test_a_share_matches_its_reference(first):
+def assert_share_is_its_reference(inputs, first, held, top_k, remat):
     """One share against the benchmark's reference given the same share:
-    output, load-balance term, counts, every gradient."""
-    top_k = 4
-    h, router, w_gate, w_up, w_down = layer_inputs(13 + first)
-    cut = tuple(w[first:first + 4] for w in (w_gate, w_up, w_down))
+    output, load-balance term, counts, every gradient (tokens, router, the
+    three expert tensors). Returns the tokens every expert got."""
+    h, router, *w = inputs
+    share = tuple(t[first:first + held] for t in w)
 
     def got(h, router, *w):
         y, aux = moe_layer(h, router, *w, top_k=top_k, dtype=jnp.float32,
@@ -479,14 +527,181 @@ def test_a_share_matches_its_reference(first):
         return jnp.sum(y * jnp.cos(y)) + load_balance, (y, counts)
 
     (_, (y, aux)), grads = jax.value_and_grad(
-        got, argnums=range(5), has_aux=True)(h, router, *cut)
+        as_a_block_runs_it(got, remat), argnums=range(5), has_aux=True)(
+            h, router, *share)
     (_, (y_ref, counts)), grads_ref = jax.value_and_grad(
-        want, argnums=range(5), has_aux=True)(h, router, *cut)
+        want, argnums=range(5), has_aux=True)(h, router, *share)
     np.testing.assert_allclose(y, y_ref, rtol=1e-5, atol=1e-5)
     np.testing.assert_array_equal(aux["counts"], np.asarray(counts, np.int32))
     for g, g_ref in zip(grads, grads_ref):
         np.testing.assert_allclose(
             g, g_ref, rtol=1e-5, atol=1e-5 * float(jnp.abs(g_ref).max()))
+    return counts
+
+
+@pytest.mark.parametrize("remat", ["none", "full"])
+@pytest.mark.parametrize("routing", ROUTINGS)
+@pytest.mark.parametrize("first", SHARES)
+def test_a_share_matches_its_reference(small_tile, first, routing, remat):
+    """Every share of 4 of 16 experts at 4 a token, its rows in one window
+    of the order, filling it exactly, and in two, plain and checkpointed as
+    a block is."""
+    counts = assert_share_is_its_reference(
+        routed_inputs(13 + first, routing, first), first, 4, 4, remat)
+    assert_rows_held(counts, routing, first)
+
+
+@pytest.mark.parametrize("remat", ["none", "full"])
+def test_a_routing_of_four_windows_matches_its_reference(small_tile, remat):
+    """Two of 16 experts held at 2 a token: a window is 24 rows of 96, and a
+    router that sends every token to those two fills four, the last to its
+    end."""
+    top_k, first, held = 2, 6, 2
+    assert moe.share_rows(T, top_k, held, E) == 24
+    counts = assert_share_is_its_reference(
+        routed_inputs(31, "over", first, held), first, held, top_k, remat)
+    assert assert_rows_held(counts, "over", first, held, top_k) == 4
+
+
+def test_a_share_at_the_real_tile_matches_its_reference():
+    """No constant patched: 2048 tokens, 4 of 16 experts held, 4 a token:
+    the layer works on 4096 of 8192 rows at a time."""
+    top_k, first, tokens = 4, 8, 2048
+    assert moe.share_rows(tokens, top_k, 4, E) == 4096
+    ks = jax.random.split(jax.random.PRNGKey(5), 2)
+    h = jax.random.normal(ks[0], (tokens, D))
+    router = jax.random.normal(ks[1], (D, E))
+    w = tuple(t[first:first + 4] for t in layer_inputs(6)[2:])
+
+    def got(h, router, *w):
+        y, aux = moe_layer(h, router, *w, top_k=top_k, dtype=jnp.float32,
+                           first_expert=first, renormalize=True)
+        return jnp.sum(y * jnp.cos(y)), aux["counts"]
+
+    def want(h, router, *w):
+        m = dict(zip(("router", "w_gate", "w_up", "w_down"), (router, *w)))
+        y, _, counts = share_reference.expert_block(h, m, top_k, first)
+        return jnp.sum(y * jnp.cos(y)), counts
+
+    (loss, counts), grads = jax.jit(jax.value_and_grad(
+        got, argnums=range(5), has_aux=True))(h, router, *w)
+    (loss_ref, _), grads_ref = jax.jit(jax.value_and_grad(
+        want, argnums=range(5), has_aux=True))(h, router, *w)
+    assert 0 < int(counts[first:first + 4].sum()) < 4096
+    np.testing.assert_allclose(loss, loss_ref, rtol=1e-5)
+    for g, g_ref in zip(grads, grads_ref):
+        np.testing.assert_allclose(
+            g, g_ref, rtol=1e-4, atol=1e-5 * float(jnp.abs(g_ref).max()))
+
+
+@pytest.mark.parametrize("routing", ["under", "exact"])
+def test_a_windows_down_and_combine_has_autodiffs_gradients(routing):
+    """``test_down_and_combine_has_autodiffs_gradients`` for the rule that
+    serves a share's window: 96 rows of the order's 192, of which the held
+    experts (0 to 3) draw fewer, or all 96."""
+    top_k, held, window_rows = 4, 4, 96
+    ks = jax.random.split(jax.random.PRNGKey(7), 5)
+    f32 = jnp.float32
+    scores = jax.random.normal(ks[0], (T, E), f32)
+    if routing == "exact":
+        lift = jnp.zeros((T, E), f32).at[:, :held].set(10.0)
+        scores = scores + jnp.where(jnp.arange(T)[:, None] < T // 2, lift,
+                                    -lift)
+    top_p, top_e = jax.lax.top_k(jax.nn.softmax(scores), top_k)
+    order = jnp.argsort(top_e.reshape(-1), stable=True)
+    pair_of_row = order[:window_rows]
+    expert_of_row = top_e.reshape(-1)[pair_of_row]
+    counts = jnp.bincount(top_e.reshape(-1), length=E).astype(
+        jnp.int32)[:held]
+    n = int(counts.sum())
+    assert n == window_rows if routing == "exact" else n < window_rows * 3 // 4
+    mine = (jnp.arange(window_rows) < n)[:, None]
+    hidden = jax.random.normal(ks[2], (window_rows, M), f32)
+    w_down = jax.random.normal(ks[3], (held, M, D), f32) / 5
+    weigh = jax.random.normal(ks[4], (T, D), f32)
+
+    def program(hidden, w_down, top_p):
+        return moe._down_and_combine_window(hidden, w_down, top_p, pair_of_row,
+                                         counts, mine)
+
+    def plain(hidden, w_down, top_p):
+        out_rows = jnp.einsum("rm,rmd->rd", hidden,
+                              w_down[jnp.minimum(expert_of_row, held - 1)])
+        p_rows = top_p.reshape(-1)[pair_of_row][:, None]
+        return jax.ops.segment_sum(jnp.where(mine, out_rows * p_rows, 0),
+                                   pair_of_row // top_k, num_segments=T)
+
+    wrt = (0, 1, 2)
+    loss = lambda f: lambda *a: jnp.sum(f(*a) * weigh)       # noqa: E731
+    with jax.default_matmul_precision("highest"):
+        y = program(hidden, w_down, top_p)
+        y_ref = plain(hidden, w_down, top_p)
+        grads = jax.grad(loss(program), argnums=wrt)(hidden, w_down, top_p)
+        grads_ref = jax.grad(loss(plain), argnums=wrt)(hidden, w_down, top_p)
+    np.testing.assert_allclose(y, y_ref, rtol=1e-5, atol=1e-5)
+    for name, g, g_ref in zip(("hidden", "w_down", "top_p"), grads,
+                              grads_ref):
+        assert g.shape == g_ref.shape and g.dtype == g_ref.dtype, name
+        if name == "hidden":      # rows that are nobody's get no cotangent
+            g_ref = jnp.where(mine, g_ref, 0)
+        np.testing.assert_allclose(
+            g, g_ref, rtol=1e-5, atol=1e-5 * float(jnp.abs(g_ref).max()),
+            err_msg=name)
+
+
+def test_share_rows_by_hand():
+    # The cell's shape: 16,384 tokens, 10 a token, 32 of 512 experts: twice
+    # the even share of 10,240 rows, already a multiple of 512.
+    assert moe.share_rows(16384, 10, 32, 512) == 20480
+    # Up to the tile: 2 x 1000 x 8 x 3 / 64 = 750.
+    assert moe.share_rows(1000, 8, 3, 64) == 1024
+    # Never more than all the rows: every expert held; half of them (an ep
+    # axis of 2); a batch whose rows are fewer than a tile.
+    assert moe.share_rows(8192, 8, 64, 64) == 8192 * 8
+    assert moe.share_rows(8192, 8, 32, 64) == 8192 * 8
+    assert moe.share_rows(48, 4, 4, 16) == 48 * 4
+
+
+def _shapes(jaxpr, out):
+    """Every array shape in a jaxpr and the jaxprs under it."""
+    for eqn in jaxpr.eqns:
+        out.update(v.aval.shape for v in eqn.outvars)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _shapes(sub, out)
+    return out
+
+
+def test_a_share_has_no_tensor_of_all_the_rows():
+    """The cell's shape (16,384 tokens of 2048, 10 of 512 experts a token of
+    width 512, 32 held), differentiated as a checkpointed block: no tensor
+    has ``T k`` rows of the tokens' width or the experts', whatever the
+    routing (one that sends the held experts more than a window's rows takes
+    more windows); with every expert held the layer has both."""
+    tokens, d, m, experts, held, top_k = 16384, 2048, 512, 512, 32, 10
+    pairs = tokens * top_k
+    f32, bf16 = jnp.float32, jnp.bfloat16
+
+    def shapes(held):
+        args = (jax.ShapeDtypeStruct((tokens, d), bf16),
+                jax.ShapeDtypeStruct((d, experts), f32),
+                jax.ShapeDtypeStruct((held, d, m), f32),
+                jax.ShapeDtypeStruct((held, d, m), f32),
+                jax.ShapeDtypeStruct((held, m, d), f32))
+
+        def loss(h, *w):
+            y, aux = moe_layer(h, *w, top_k=top_k, renormalize=True)
+            return jnp.sum(y.astype(f32)) + aux["load_balance"]
+
+        return _shapes(jax.make_jaxpr(jax.grad(
+            as_a_block_runs_it(loss, "full"), argnums=range(5)))(
+                *args).jaxpr, set())
+
+    wide = {(pairs, d), (pairs, m)}
+    share = shapes(held)
+    assert not wide & share
+    assert {(moe.share_rows(tokens, top_k, held, experts), w)
+            for w in (d, m)} <= share
+    assert wide <= shapes(experts)
 
 
 @pytest.mark.parametrize("top_k", [1, 2, 8])
@@ -539,7 +754,8 @@ def test_metrics_count_a_shares_held_experts(make_runtime):
             h, router, *(t[8:12] for t in w))
     assert sample_value(
         hvd.metrics(), "hvdtpu_spmd_moe_layer_traces_total", experts=str(E),
-        top_k="2", ep="1", grouped_matmul="ragged_dot", held="4") == 1.0
+        top_k="2", ep="1", grouped_matmul="ragged_dot", held="4",
+        rows=str(T * 2)) == 1.0
 
 
 def test_metrics_count_the_expert_layers_trace(make_runtime):
@@ -552,7 +768,7 @@ def test_metrics_count_the_expert_layers_trace(make_runtime):
     assert sample_value(
         fams, "hvdtpu_spmd_moe_layer_traces_total", experts=str(E),
         top_k="2", ep="1", grouped_matmul="ragged_dot",
-        held=str(E)) == 1.0
+        held=str(E), rows=str(T * 2)) == 1.0
 
 
 def test_operation_count_by_hand():
