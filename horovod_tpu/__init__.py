@@ -69,7 +69,7 @@ from .checkpoint import (save_checkpoint, restore_checkpoint,
 
 # Compiled-step helpers (TPU-native).
 from .step import (run_step, data_parallel_step, shard_batch, replicate,
-                   batch_spec, REPLICATED)
+                   batch_spec, compiled_step_report, REPLICATED)
 
 from .exceptions import (HvdTpuInternalError, HostsUpdatedInterrupt,
                          TensorShapeMismatchError, TensorDtypeMismatchError,

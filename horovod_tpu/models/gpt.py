@@ -716,9 +716,10 @@ def _full_policy(prim, *avals, **params):
         from .. import runtime
         recorder = runtime.recorder()
         if recorder is not None:
-            recorder.note_remat_saved(
-                "full", params["name"],
-                avals[0].size * avals[0].dtype.itemsize)
+            recorder.note_traced(
+                "hvdtpu_spmd_remat_saved_bytes_total",
+                avals[0].size * avals[0].dtype.itemsize,
+                mode="full", name=params["name"])
     return saved
 
 

@@ -330,8 +330,10 @@ def _blocks_for(kernel, q, k, causal, forced):
                                    causal)
     recorder = runtime.recorder()
     if recorder is not None:
-        recorder.note_flash_kernel(kernel, bq, bk, jnp.dtype(q.dtype).name,
-                                   q.shape[0] // k.shape[0])
+        recorder.note_traced(
+            "hvdtpu_spmd_flash_kernel_traces_total", kernel=kernel,
+            block_q=bq, block_k=bk, operand_dtype=jnp.dtype(q.dtype).name,
+            kv_group=q.shape[0] // k.shape[0])
     return bq, bk
 
 

@@ -161,7 +161,9 @@ def gated_delta_chunked(q, k, v, g, beta, chunk: int = 64, *,
     from .. import runtime
     recorder = runtime.recorder()
     if recorder is not None:
-        recorder.note_gdn_layer(key_heads, heads, key_dim, width, chunk)
+        recorder.note_traced(
+            "hvdtpu_spmd_gdn_layer_traces_total", key_heads=key_heads,
+            value_heads=heads, key_dim=key_dim, value_dim=width, chunk=chunk)
 
     f32 = jnp.float32
     pad = (-seq) % chunk

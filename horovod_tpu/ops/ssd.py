@@ -132,7 +132,9 @@ def _tiling(kernel, x, b_in, chunk):
     from .. import runtime
     recorder = runtime.recorder()
     if recorder is not None:
-        recorder.note_ssd_kernel(kernel, chunk, hb, jnp.dtype(x.dtype).name)
+        recorder.note_traced(
+            "hvdtpu_spmd_ssd_kernel_traces_total", kernel=kernel, chunk=chunk,
+            heads_per_block=hb, operand_dtype=jnp.dtype(x.dtype).name)
     return hb, tile
 
 
@@ -521,7 +523,9 @@ def ssd_chunked(x, dt, a, b_in, c_in, d, *, chunk: int,
     from .. import runtime
     recorder = runtime.recorder()
     if recorder is not None:
-        recorder.note_ssm_layer(heads, width, state, groups, chunk)
+        recorder.note_traced(
+            "hvdtpu_spmd_ssm_layer_traces_total", heads=heads,
+            head_dim=width, state=state, groups=groups, chunk=chunk)
 
     f32 = jnp.float32
     pad = (-seq) % chunk

@@ -312,19 +312,27 @@ def _windows(window_rows, group_sizes, window):
     """``window(lo)`` for the window at 0 and, while held rows lie beyond the
     windows taken, for the next one, added up: one window for a routing
     whose held rows number ``window_rows`` at most, the loop's body never
-    run; ``ceil(held rows / window_rows)`` windows for any other."""
+    run; ``ceil(held rows / window_rows)`` windows for any other. The whole
+    is under the scope ``windows`` and each window, the one at 0 and the
+    loop's, under ``window``: a device trace counts the windows a step took
+    by them (``benchmarks/layer_metrics/moe_windows_per_step.py``)."""
     held = jnp.sum(group_sizes)
+
+    def scoped(lo):
+        with jax.named_scope("window"):
+            return window(lo)
 
     def more(carry):
         return carry[0] < held
 
     def next_window(carry):
         lo, total = carry
-        return lo + window_rows, jax.tree.map(jnp.add, total, window(lo))
+        return lo + window_rows, jax.tree.map(jnp.add, total, scoped(lo))
 
-    return lax.while_loop(more, next_window,
-                          (jnp.asarray(window_rows, held.dtype),
-                           window(jnp.zeros((), held.dtype))))[1]
+    with jax.named_scope("windows"):
+        return lax.while_loop(more, next_window,
+                              (jnp.asarray(window_rows, held.dtype),
+                               scoped(jnp.zeros((), held.dtype))))[1]
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
@@ -417,8 +425,10 @@ def moe_layer(x, router_w, w_gate, w_up, w_down, top_k: int,
     from .. import runtime
     recorder = runtime.recorder()
     if recorder is not None:
-        recorder.note_moe_layer(num_experts, top_k, _axis_size(axis),
-                                GROUPED_MATMUL, experts_local, window_rows)
+        recorder.note_traced(
+            "hvdtpu_spmd_moe_layer_traces_total", experts=num_experts,
+            top_k=top_k, ep=_axis_size(axis), grouped_matmul=GROUPED_MATMUL,
+            held=experts_local, rows=window_rows)
 
     with jax.named_scope("router"):
         logits = jnp.dot(xt.astype(jnp.float32), router_w.astype(jnp.float32),

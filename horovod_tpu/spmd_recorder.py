@@ -8,8 +8,11 @@ recorder lives in the runtime state from ``hvd.init()`` to ``hvd.shutdown()``
 Counters, always on. JAX tells its listeners (``jax.monitoring``) whenever it
 traces, lowers or compiles a function, with the function's name, and whenever
 the persistent cache hits or is written; ``hvd.init`` times its own phases;
-``hvd.shard_batch`` adds a call and its bytes. Nothing here runs on a step's
-path: the listeners fire only while JAX compiles, and ``shard_batch`` pays two
+``hvd.shard_batch`` adds a call and its bytes; code that decides a tiling or
+what a block keeps notes it while JAX traces it (``_TRACED``); and what the
+compiler made of a step is reduced when ``hvd.compiled_step_report`` asks and
+shown from then on (``_STEP_FAMILIES``). Nothing here runs on a step's path:
+the listeners fire only while JAX compiles, and ``shard_batch`` pays two
 integer additions and takes no lock.
 
 Spans, only between ``start_timeline`` and ``stop_timeline``: the ``hvd.init``
@@ -31,6 +34,8 @@ import re
 import threading
 import time
 from typing import Optional
+
+from . import hlo_report
 
 # The spans a timeline keeps at most; older ones fall out. A constant, not an
 # option: 65,536 spans are hours of steps at one shard_batch a step.
@@ -72,6 +77,83 @@ def _process_age_s() -> Optional[float]:
         return None
 
 
+# The trace-time families, name -> (help, label names): which tiling, shapes
+# or kept values a job got (``note_traced``); traces, the last one bytes.
+_TRACED = {
+    "hvdtpu_spmd_flash_kernel_traces_total": (
+        "Times JAX traced a flash attention kernel, by kernel and the tiling "
+        "the call got: block sizes, the MXU operands' dtype, query heads per "
+        "K/V head.",
+        ("kernel", "block_q", "block_k", "operand_dtype", "kv_group")),
+    "hvdtpu_spmd_moe_layer_traces_total": (
+        "Times JAX traced an expert layer (the recomputed copy of a block "
+        "counts again), by the experts it routes over, the experts per "
+        "token, the size of the expert-parallel axis, the grouped matmul it "
+        "uses, the experts this rank holds and the token-expert rows it "
+        "gathers and multiplies at a time (all of them, or a share's "
+        "window).",
+        ("experts", "top_k", "ep", "grouped_matmul", "held", "rows")),
+    "hvdtpu_spmd_ssm_layer_traces_total": (
+        "Times JAX traced a chunked state-space scan (the recomputed copy of "
+        "a block counts again), by its heads, their size, the state's size, "
+        "the groups that share B and C, and the chunk.",
+        ("heads", "head_dim", "state", "groups", "chunk")),
+    "hvdtpu_spmd_gdn_layer_traces_total": (
+        "Times JAX traced a chunked gated-delta-rule scan (the recomputed "
+        "copy of a block counts again), by its key heads, value heads, their "
+        "sizes and the chunk.",
+        ("key_heads", "value_heads", "key_dim", "value_dim", "chunk")),
+    "hvdtpu_spmd_ssd_kernel_traces_total": (
+        "Times JAX traced one of the state-space scan's within-chunk "
+        "kernels, by kernel and the tiling the call got: the chunk, the "
+        "heads a grid cell holds, the MXU operands' dtype.",
+        ("kernel", "chunk", "heads_per_block", "operand_dtype")),
+    "hvdtpu_spmd_remat_saved_bytes_total": (
+        "Bytes a checkpointed block hands from its forward to its backward "
+        "pass beside its input, by remat mode and the name the value "
+        "carries; one block for each that JAX splits (layers alike share "
+        "one).", ("mode", "name")),
+}
+
+# ---- what the compiler made of a step (hvd.compiled_step_report) -----------
+
+
+def _by_kind(report: dict) -> dict:
+    """kind -> (instructions, their result bytes or None) of a report."""
+    remat, copies = report["rematerialized"], report["parameter_copies"]
+    return {"rematerialized": (len(remat), sum(r["bytes"] for r in remat)),
+            "parameter_copy": (copies["count"], copies["bytes"]),
+            "while": (report["whiles"], None),
+            **{kind: (n, None) for kind, n in report["collectives"].items()}}
+
+
+# name -> (help, a report's samples as [(labels, value)]); gauges, by function
+_STEP_FAMILIES = {
+    "hvdtpu_spmd_step_memory_bytes": (
+        "The compiler's account of a step's memory on one device "
+        "(memory_analysis()): arguments, outputs, aliased (outputs written "
+        "where donated arguments were), temporaries, generated_code.",
+        lambda r: [({"kind": k}, v) for k, v in r["memory_bytes"].items()]),
+    "hvdtpu_spmd_step_instructions": (
+        "Instructions of the compiled step that the program did not ask "
+        "for or that cross chips: rematerialized (made again by the "
+        "compiler when short of memory), parameter_copy (copies of the "
+        "step's arguments), while, and collectives by kind after the "
+        "compiler's combiner.",
+        lambda r: [({"kind": k}, n) for k, (n, _) in _by_kind(r).items()]),
+    "hvdtpu_spmd_step_instruction_bytes": (
+        "Result bytes of the rematerialized instructions and of the "
+        "parameter copies.",
+        lambda r: [({"kind": k}, b) for k, (_, b) in _by_kind(r).items()
+                   if b is not None]),
+    "hvdtpu_spmd_step_kernels": (
+        "Mosaic kernels in the compiled step, by the name the program or "
+        "XLA gave them.",
+        lambda r: [({"kernel": k}, n) for k, n in sorted(
+            r["kernels"].items())]),
+}
+
+
 class SpmdRecorder:
     def __init__(self):
         # (function, stage) -> [count, seconds]
@@ -82,18 +164,10 @@ class SpmdRecorder:
         self.init_done_process_s: Optional[float] = None
         self.placed_calls = 0
         self.placed_bytes = 0
-        # (kernel, block_q, block_k, operand_dtype, kv_group) -> traces
-        self.flash_kernels: collections.Counter = collections.Counter()
-        # (experts, top_k, ep, grouped_matmul, held, rows) -> traces
-        self.moe_layers: collections.Counter = collections.Counter()
-        # (heads, head_dim, state, groups, chunk) -> traces
-        self.ssm_layers: collections.Counter = collections.Counter()
-        # (key_heads, value_heads, key_dim, value_dim, chunk) -> traces
-        self.gdn_layers: collections.Counter = collections.Counter()
-        # (kernel, chunk, heads_per_block, operand_dtype) -> traces
-        self.ssd_kernels: collections.Counter = collections.Counter()
-        # (remat mode, name) -> bytes the checkpointed blocks keep
-        self.remat_saved: collections.Counter = collections.Counter()
+        # family of _TRACED -> its label values -> traces (or bytes)
+        self.traced: dict = {name: collections.Counter() for name in _TRACED}
+        # function -> (its traced shapes, report): compiled_step_report's
+        self.step_reports: dict = {}
         # function -> the argument signatures run_step has traced it with
         self._signatures: dict = {}
         self._cause: dict = {}          # function -> cause of the next compile
@@ -159,53 +233,26 @@ class SpmdRecorder:
                 else "new shardings" if signature in seen else "new shapes")
             seen.add(signature)
 
-    def note_flash_kernel(self, kernel: str, block_q: int, block_k: int,
-                          operand_dtype: str, kv_group: int) -> None:
-        """``ops/flash_attention.py`` calls this while JAX traces one of its
-        ``pallas_call``s: which tiling the call got."""
+    def note_traced(self, family: str, amount: int = 1, **labels) -> None:
+        """Code that JAX is tracing calls this, never a step: one more trace
+        (or ``amount`` more bytes) of a family of ``_TRACED``."""
+        key = tuple(labels[name] for name in _TRACED[family][1])
         with self._lock:
-            self.flash_kernels[(kernel, block_q, block_k, operand_dtype,
-                                kv_group)] += 1
+            self.traced[family][key] += amount
 
-    def note_moe_layer(self, experts: int, top_k: int, ep: int,
-                       grouped_matmul: str, held: int, rows: int) -> None:
-        """``parallel/moe.py`` calls this while JAX traces an expert layer:
-        what it routes over, how many of those experts this rank holds,
-        which grouped matmul it got, and how many token-expert rows it
-        gathers and multiplies at a time (all ``T k``, or a share's window
-        of them)."""
+    def step_report(self, function: str, traced, compile_) -> dict:
+        """``hvd.compiled_step_report``'s answer for ``function`` as last
+        ``traced``: kept, or made now of what ``compile_()`` returns."""
         with self._lock:
-            self.moe_layers[(experts, top_k, ep, grouped_matmul, held,
-                             rows)] += 1
-
-    def note_ssm_layer(self, heads: int, head_dim: int, state: int,
-                       groups: int, chunk: int) -> None:
-        """``ops/ssd.py`` calls this while JAX traces a chunked state-space
-        scan: the shapes it runs at."""
-        with self._lock:
-            self.ssm_layers[(heads, head_dim, state, groups, chunk)] += 1
-
-    def note_gdn_layer(self, key_heads: int, value_heads: int, key_dim: int,
-                       value_dim: int, chunk: int) -> None:
-        """``ops/gated_delta.py`` calls this while JAX traces a chunked
-        gated-delta-rule scan: the shapes it runs at."""
-        with self._lock:
-            self.gdn_layers[(key_heads, value_heads, key_dim, value_dim,
-                             chunk)] += 1
-
-    def note_ssd_kernel(self, kernel: str, chunk: int, heads_per_block: int,
-                        operand_dtype: str) -> None:
-        """``ops/ssd.py`` calls this while JAX traces one of the scan's
-        ``pallas_call``s: which tiling the call got."""
-        with self._lock:
-            self.ssd_kernels[(kernel, chunk, heads_per_block,
-                              operand_dtype)] += 1
-
-    def note_remat_saved(self, mode: str, name: str, nbytes: int) -> None:
-        """``models/gpt.py``'s checkpoint policy calls this while JAX splits
-        a block into its forward and backward parts: a value it keeps."""
-        with self._lock:
-            self.remat_saved[(mode, name)] += nbytes
+            have = self.step_reports.get(function)
+        if have is None or have[0] is not traced:
+            t0 = time.perf_counter()
+            report = dict(hlo_report.compiled_report(compile_()),
+                          function=function)
+            report["seconds"] = time.perf_counter() - t0
+            with self._lock:
+                have = self.step_reports[function] = (traced, report)
+        return have[1]
 
     def note_placed(self, nbytes: int) -> None:
         self.placed_calls += 1
@@ -222,12 +269,9 @@ class SpmdRecorder:
         with self._lock:
             compiles = sorted(self.compiles.items())
             hits, misses = self.cache_hits, self.cache_misses
-            flash = sorted(self.flash_kernels.items())
-            moe = sorted(self.moe_layers.items())
-            ssm = sorted(self.ssm_layers.items())
-            gdn = sorted(self.gdn_layers.items())
-            ssd = sorted(self.ssd_kernels.items())
-            saved = sorted(self.remat_saved.items())
+            traced = {name: sorted(samples.items())
+                      for name, samples in self.traced.items()}
+            reports = sorted(self.step_reports.items())
         counts, seconds = [], []
         for (function, stage), (count, secs) in compiles:
             labels = {"function": function, "stage": stage}
@@ -253,60 +297,6 @@ class SpmdRecorder:
                 "made it before), mesh, compile_cache.",
                 [("", {"phase": p}, (b - a) * 1e-9)
                  for p, a, b in self.init_phases]),
-            "hvdtpu_spmd_flash_kernel_traces_total": family(
-                "counter", "Times JAX traced a flash attention kernel, by "
-                "kernel and the tiling the call got: block sizes, the MXU "
-                "operands' dtype, query heads per K/V head.",
-                [("", {"kernel": kernel, "block_q": str(bq),
-                       "block_k": str(bk), "operand_dtype": dtype,
-                       "kv_group": str(group)}, float(count))
-                 for (kernel, bq, bk, dtype, group), count in flash]),
-            "hvdtpu_spmd_moe_layer_traces_total": family(
-                "counter", "Times JAX traced an expert layer (the recomputed "
-                "copy of a block counts again), by the experts it routes "
-                "over, the experts per token, the size of the expert-parallel "
-                "axis, the grouped matmul it uses, the experts this rank "
-                "holds and the token-expert rows it gathers and multiplies "
-                "at a time (all of them, or a share's window).",
-                [("", {"experts": str(experts), "top_k": str(top_k),
-                       "ep": str(ep), "grouped_matmul": gmm,
-                       "held": str(held), "rows": str(rows)}, float(count))
-                 for (experts, top_k, ep, gmm, held, rows), count in moe]),
-            "hvdtpu_spmd_ssm_layer_traces_total": family(
-                "counter", "Times JAX traced a chunked state-space scan (the "
-                "recomputed copy of a block counts again), by its heads, "
-                "their size, the state's size, the groups that share B and "
-                "C, and the chunk.",
-                [("", {"heads": str(heads), "head_dim": str(head_dim),
-                       "state": str(state), "groups": str(groups),
-                       "chunk": str(chunk)}, float(count))
-                 for (heads, head_dim, state, groups, chunk), count in ssm]),
-            "hvdtpu_spmd_gdn_layer_traces_total": family(
-                "counter", "Times JAX traced a chunked gated-delta-rule "
-                "scan (the recomputed copy of a block counts again), by its "
-                "key heads, value heads, their sizes and the chunk.",
-                [("", {"key_heads": str(key_heads),
-                       "value_heads": str(value_heads),
-                       "key_dim": str(key_dim), "value_dim": str(value_dim),
-                       "chunk": str(chunk)}, float(count))
-                 for (key_heads, value_heads, key_dim, value_dim, chunk),
-                 count in gdn]),
-            "hvdtpu_spmd_ssd_kernel_traces_total": family(
-                "counter", "Times JAX traced one of the state-space scan's "
-                "within-chunk kernels, by kernel and the tiling the call "
-                "got: the chunk, the heads a grid cell holds, the MXU "
-                "operands' dtype.",
-                [("", {"kernel": kernel, "chunk": str(chunk),
-                       "heads_per_block": str(hb), "operand_dtype": dtype},
-                  float(count))
-                 for (kernel, chunk, hb, dtype), count in ssd]),
-            "hvdtpu_spmd_remat_saved_bytes_total": family(
-                "counter", "Bytes a checkpointed block hands from its "
-                "forward to its backward pass beside its input, by remat "
-                "mode and the name the value carries; one block for each "
-                "that JAX splits (layers alike share one).",
-                [("", {"mode": mode, "name": name}, float(nbytes))
-                 for (mode, name), nbytes in saved]),
             "hvdtpu_spmd_shard_batch_calls_total": family(
                 "counter", "Calls of hvd.shard_batch.",
                 [("", {}, float(self.placed_calls))]),
@@ -314,6 +304,16 @@ class SpmdRecorder:
                 "counter", "Host bytes hvd.shard_batch was given to place.",
                 [("", {}, float(self.placed_bytes))]),
         }
+        for name, (help_, labels) in _TRACED.items():
+            out[name] = family("counter", help_, [
+                ("", dict(zip(labels, map(str, key))), float(count))
+                for key, count in traced[name]])
+        if reports:     # only once compiled_step_report has been asked
+            for name, (help_, samples_of) in _STEP_FAMILIES.items():
+                out[name] = family("gauge", help_, [
+                    ("", {"function": function, **labels}, float(value))
+                    for function, (_, report) in reports
+                    for labels, value in samples_of(report)])
         if self.init_done_process_s is not None:
             out["hvdtpu_spmd_init_done_process_seconds"] = family(
                 "gauge", "Seconds from the start of the process to the "

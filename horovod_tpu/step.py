@@ -72,8 +72,51 @@ def run_step(fn=None, *, in_specs, out_specs, mesh=None,
             _plain_semantics.on = prev
     mapped = jax.shard_map(body, mesh=m, in_specs=in_specs,
                            out_specs=out_specs, check_vma=check_vma)
-    return jax.jit(mapped, donate_argnums=tuple(donate_argnums),
+
+    @functools.wraps(fn)
+    def traced(*a):
+        # Trace time again: what names this executable to
+        # compiled_step_report, the arguments as shapes with the shardings
+        # in_specs and the mesh give them.
+        traced.shapes = jax.tree.map(
+            lambda spec, sub: jax.tree.map(
+                lambda x: jax.ShapeDtypeStruct(
+                    x.shape, x.dtype, sharding=NamedSharding(m, spec),
+                    weak_type=x.aval.weak_type)
+                if isinstance(x, jax.core.Tracer) else x, sub),
+            in_specs, a, is_leaf=lambda spec: isinstance(spec, P))
+        return mapped(*a)
+    return jax.jit(traced, donate_argnums=tuple(donate_argnums),
                    static_argnums=static_argnums)
+
+
+def compiled_step_report(step) -> dict:
+    """What the compiler made of a :func:`run_step` function that has run, as
+    last traced: ``memory_bytes`` (``memory_analysis()``: arguments, outputs,
+    aliased, temporaries, generated_code) and, from the optimized HLO's text,
+    ``rematerialized`` (the instructions XLA made again when short of memory,
+    each with ``name``, ``opcode``, result ``bytes`` and the program's
+    ``op_name``), ``parameter_copies`` (``count``, ``bytes``), ``whiles``,
+    ``collectives`` by kind as the combiner left them, ``kernels`` (Mosaic
+    calls by name), ``instructions`` and the ``seconds`` the report took.
+
+    It lowers and compiles on the traced shapes, placed as ``in_specs`` says:
+    JAX returns the executable the step runs from its caches (no compile, no
+    new entry in ``step._cache_size()``). A caller that fed un-placed state
+    first has two executables (docs/metrics.md): this is the placed one's.
+    Made when asked, kept until the step is traced anew, and from then on
+    shown by ``hvd.metrics()`` (``hvdtpu_spmd_step_*``); never on a step's
+    path.
+    """
+    recorder = runtime.recorder()
+    shapes = getattr(getattr(step, "__wrapped__", None), "shapes", None)
+    if recorder is None or shapes is None:
+        raise ValueError(
+            "compiled_step_report: a hvd.run_step function that has run "
+            "under hvd.init() in SPMD mode, got "
+            f"{getattr(step, '__name__', step)!r}")
+    return recorder.step_report(step.__name__, shapes,
+                                lambda: step.lower(*shapes).compile())
 
 
 def data_parallel_step(train_step, donate_state: bool = True,

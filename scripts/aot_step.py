@@ -1,16 +1,18 @@
 #!/usr/bin/env python3
 """Compile a benchmark cell's real training step for a v5e that is described
-and not attached, in the CPU sandbox: a sha256 of the lowered StableHLO, what
-the compiler says of its memory and how many of each named kernel the
-compiled program holds. Nothing runs and no time is taken; a compile that
-passes is not a chip run.
+and not attached, in the CPU sandbox: a sha256 of the lowered StableHLO and
+one of the compiled program, and what the program's own report says of the
+executable (``hvd.compiled_step_report``'s reducer, so the sandbox and the
+chip count alike): its memory, how many of each named kernel it holds, what
+the compiler made again and which arguments it copies. Nothing runs and no
+time is taken; a compile that passes is not a chip run.
 
     python3 scripts/aot_step.py starcoder2-3b_s4096 olmoe-1b-7b_s4096
 
 The step is the job's own (``benchmarks/jobs/*.py``: ``hvd.run_step`` over
 ``DistributedOptimizer``, state donated), lowered on shapes alone. One JSON
 line a cell; ``--repo DIR`` compiles another checkout's program (a copy of
-the parent commit) under this script.
+the parent commit) under this script and this checkout's reducer.
 """
 
 from __future__ import annotations
@@ -19,6 +21,7 @@ import argparse
 import base64
 import hashlib
 import importlib
+import importlib.util
 import json
 import os
 import re
@@ -33,11 +36,28 @@ KERNELS = ("hvd_flash_fwd", "hvd_flash_dkdv", "hvd_flash_dq",
 GIB = 2.0 ** 30
 
 
-_KERNEL_BODY = re.compile(r'(?<=\\22body\\22: \\22)[A-Za-z0-9+/=]+(?=\\22)')
+# A Mosaic kernel's module in the StableHLO's text and in the compiled HLO's.
+_KERNEL_BODY = re.compile(
+    r'(?<=\\22body\\22: \\22)[A-Za-z0-9+/=]+(?=\\22)'
+    r'|(?<="body":")[A-Za-z0-9+/=]+(?=")')
+_METADATA = re.compile(r", metadata=\{[^{}]*\}")
 
 
-def program_text(lowered) -> str:
-    """The lowered StableHLO without source locations. The text has none of
+def this_checkouts_reducer():
+    """``horovod_tpu/hlo_report.py`` of this script's checkout, whatever
+    ``--repo`` put first on the path (the module imports nothing of the
+    package)."""
+    spec = importlib.util.spec_from_file_location(
+        "aot_step_reducer", os.path.join(
+            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+            "horovod_tpu", "hlo_report.py"))
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+def program_text(text: str) -> str:
+    """A program's text without source locations. The StableHLO has none of
     its own, but a Mosaic kernel travels in it as its module's bytes, which
     hold the file and line of every operation of the kernel's source: each
     is replaced by the module's assembly printed without them."""
@@ -53,7 +73,17 @@ def program_text(lowered) -> str:
             module = ir.Module.parse(base64.b64decode(match.group(0)))
             return module.operation.get_asm(enable_debug_info=False)
 
-    return _KERNEL_BODY.sub(assembly, lowered.as_text())
+    return _KERNEL_BODY.sub(assembly, text)
+
+
+def compiled_text(text: str) -> str:
+    """The compiled HLO without what names and locates and does not compute:
+    each instruction's ``metadata``, the stack-frame tables ahead of the
+    first computation, the kernels' source locations. Equal on two
+    checkouts, they differ in metadata alone."""
+    head, body = text.split("\n", 1)
+    body = body[re.search(r"^(%|ENTRY )", body, re.M).start():]
+    return program_text(_METADATA.sub("", head + "\n" + body))
 
 
 def compile_cell(name: str, root: str, hlo_dir: str | None = None) -> dict:
@@ -89,8 +119,9 @@ def compile_cell(name: str, root: str, hlo_dir: str | None = None) -> dict:
     t0 = time.time()
     compiled = lowered.compile()
     seconds = time.time() - t0
-    m = compiled.memory_analysis()
     text = compiled.as_text()
+    report = this_checkouts_reducer().compiled_report(compiled)
+    m = report["memory_bytes"]
     if hlo_dir:
         os.makedirs(hlo_dir, exist_ok=True)
         with open(os.path.join(hlo_dir, name + ".hlo.txt"), "w") as f:
@@ -99,17 +130,20 @@ def compile_cell(name: str, root: str, hlo_dir: str | None = None) -> dict:
         "cell": name, "compile_s": round(seconds, 1),
         # Equal on two checkouts, the step is the same program on both.
         "stablehlo_sha256": hashlib.sha256(
-            program_text(lowered).encode()).hexdigest(),
-        "arguments_gib": round(m.argument_size_in_bytes / GIB, 3),
-        "temporaries_gib": round(m.temp_size_in_bytes / GIB, 3),
+            program_text(lowered.as_text()).encode()).hexdigest(),
+        # Equal too, the compiler made the same of it.
+        "compiled_sha256": hashlib.sha256(
+            compiled_text(text).encode()).hexdigest(),
+        "instructions": report["instructions"],
+        "arguments_gib": round(m["arguments"] / GIB, 3),
+        "temporaries_gib": round(m["temporaries"] / GIB, 3),
         # What the step holds at once; outputs that alias donated
         # arguments are written where those were read.
-        "total_gib": round((m.argument_size_in_bytes + m.temp_size_in_bytes
-                            + m.output_size_in_bytes
-                            - m.alias_size_in_bytes) / GIB, 3),
-        "calls": {k: len(re.findall(
-            r"^\s*(?:ROOT )?%?[\w.-]*" + re.escape(k) + r"[\w.-]* = ",
-            text, re.M)) for k in KERNELS},
+        "total_gib": round((m["arguments"] + m["temporaries"] + m["outputs"]
+                            - m["aliased"]) / GIB, 3),
+        "calls": {k: report["kernels"].get(k, 0) for k in KERNELS},
+        "rematerialized": report["rematerialized"],
+        "parameter_copies": report["parameter_copies"],
     }
 
 

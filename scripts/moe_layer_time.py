@@ -26,7 +26,12 @@ each form of the sum back to tokens on ``[R, d]`` float32 rows of the run's
 own routing: ``moe._to_tokens`` and the form not chosen (PERF.md, Findings,
 PR 32). With ``--skew`` and ``--first-expert 0`` every row is the share's
 and the layer takes ``T k / R`` windows; ``--all-rows`` times the share as
-it ran before PR 32, on all the rows at once.
+it ran before PR 32, on all the rows at once. ``--trace`` also profiles four
+calls of the checkpointed layer's forward and backward and prints the windows
+a call's forward rule took as the benchmark's ``moe_windows_per_step`` counts
+them in a device trace (``windows_traced``, beside ``windows`` from the
+counts: 1.0 for the cell's share under an even routing, 8.0 under
+``--skew``).
 """
 
 from __future__ import annotations
@@ -131,6 +136,7 @@ def main() -> int:
     parser.add_argument("--all-rows", action="store_true",
                         help="a share on all T k rows, as before PR 32 "
                         "(the headroom raised until a window is every row)")
+    parser.add_argument("--trace", action="store_true")
     args = parser.parse_args()
     T, d, m, E, k = (args.tokens, args.embed, args.width, args.experts,
                      args.top_k)
@@ -155,7 +161,8 @@ def main() -> int:
                                   renormalize=args.renormalize)
 
     def layer(h, *w):
-        y, aux = moe_layer(h, *w)
+        with jax.named_scope("moe"):        # as models/gpt.py::_block calls it
+            y, aux = moe_layer(h, *w)
         return jnp.sum(y.astype(jnp.float32)) + aux["load_balance"] \
             + aux["router_z"], aux["counts"]
 
@@ -184,15 +191,16 @@ def main() -> int:
         return jnp.sum(taken.astype(jnp.float32) * rows.astype(jnp.float32))
 
     def checkpointed(*names):
-        """ms of the layer's forward and backward with these names kept.
+        """The layer's forward and backward with these names kept, jitted.
         The value is asked for with the gradients: without it nothing needs
         the first forward pass and XLA drops it."""
         kept = jax.checkpoint(
             layer, policy=jax.checkpoint_policies.save_only_these_names(
                 *names))
-        return timed(jax.jit(jax.value_and_grad(
-            kept, argnums=(0, 1, 2, 3, 4), has_aux=True)), h, *weights)
+        return jax.jit(jax.value_and_grad(
+            kept, argnums=(0, 1, 2, 3, 4), has_aux=True))
 
+    as_a_block_runs_it = checkpointed(*gpt.SAVED_NAMES)
     out = {"tokens": T, "embed": d, "width": m, "experts": E, "top_k": k,
            "router_width": wide, "first_expert": first, "rows": R,
            "rows_held": rows_held, "windows": -(-rows_held // R),
@@ -203,8 +211,8 @@ def main() -> int:
            "layer_fwd_bwd_ms": timed(jax.jit(jax.grad(
                layer, argnums=(0, 1, 2, 3, 4), has_aux=True)), h, *weights),
            "layer_checkpointed_fwd_bwd_ms": {
-               "nothing": checkpointed(),
-               "SAVED_NAMES": checkpointed(*gpt.SAVED_NAMES)},
+               "nothing": timed(checkpointed(), h, *weights),
+               "SAVED_NAMES": timed(as_a_block_runs_it, h, *weights)},
            "experts_fwd_ms": timed(jax.jit(experts), rows_in, *weights[1:]),
            "experts_fwd_bwd_ms": timed(jax.jit(jax.grad(
                experts, argnums=(0, 1, 2, 3))), rows_in, *weights[1:]),
@@ -233,6 +241,20 @@ def main() -> int:
     out["max_abs_error_over_max_abs"] = float(
         jnp.max(jnp.abs(got.astype(jnp.float32) - want))
         / jnp.max(jnp.abs(want)))
+    if args.trace:
+        from benchmarks import scope_reduce, trace_reduce
+        from benchmarks.layer_metrics import moe_windows_per_step
+        calls = 4
+        log_dir = os.path.join(ROOT, "chiprun_out", "moe_layer_trace",
+                               "skew" if args.skew else "even")
+        with jax.profiler.trace(log_dir):
+            for _ in range(calls):
+                jax.block_until_ready(as_a_block_runs_it(h, *weights))
+        path = trace_reduce.find_xplane(log_dir)
+        taken = moe_windows_per_step.windows_taken(
+            trace_reduce.first_device(trace_reduce.read_xplane(path, {})),
+            scope_reduce.program_names(path))
+        out["windows_traced"] = sum(taken.values()) / calls
     line = json.dumps(out)
     print(line, flush=True)
     os.makedirs(os.path.join(ROOT, "chiprun_out"), exist_ok=True)
