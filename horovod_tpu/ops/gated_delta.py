@@ -1,5 +1,7 @@
 """The gated delta rule: a linear-attention recurrence whose state is
-*corrected* by each token, not only added to. Pure ``jax.numpy``.
+*corrected* by each token, not only added to. The chunked form's part that
+needs no state is two Pallas kernels (below); the recurrence over chunks
+and the plain forms the tests hold the rest to are ``jax.numpy``.
 
 A value head keeps a state ``S`` in ``R^{K x V}`` (keys by values), zero at
 the start of a sequence. With ``alpha_t = exp(g_t)`` in (0, 1] the decay and
@@ -33,11 +35,44 @@ enters with state ``S``::
     S' = G_last S + sum_j (G_last / G_j) k_j u_j^T
 
 ``T`` and the two products it is applied to need no state and are made for
-all chunks at once; a ``lax.scan`` over chunks carries ``S``. Decays, running
-sums and ``T`` are float32; the other products take operands in ``dtype``
-and accumulate in float32, as ``ops/ssd.py::ssd_chunked`` does.
+all chunks at once; a ``lax.scan`` over chunks carries ``S`` through three
+products a turn (XLA's). Decays, running sums and ``T`` are float32; the
+other products take operands in ``dtype`` and accumulate in float32, as
+``ops/ssd.py::ssd_chunked`` does.
 
-**Which inverse.** :func:`unit_lower_inverse` inverts ``I + A`` by blocks:
+**The kernels** (``hvd_gdn_fwd``, ``hvd_gdn_bwd``, one ``jax.custom_vjp``:
+:func:`_chunk_local`) compute everything with two chunk-length axes: ``K
+K^T`` and ``Q K^T`` (once a key head), the masked decay tile ``exp(cum_i -
+cum_j)``, ``A``, ``T`` (float32 throughout, rounded to ``dtype`` once before
+it is applied), ``u_own = T (beta V)`` (float32), ``w = T (beta G K)``,
+``attn = (Q K^T) * decay`` and the two scaled copies the recurrence reads,
+``q G`` and ``k G_last / G``. No ``[B, c, H, Q, Q]`` float32 tensor reaches
+HBM. The operands stay as the mixer has them, tokens by channels: a grid
+cell is ``chunks_per_block`` chunks of one sequence (walked in a loop), one
+key head (a ``[Q, K]`` block of ``q`` and of ``k`` a chunk) and the value
+heads that read it (their ``[Q, V]`` blocks side by side in ``v``); the
+float32 running sums ``cum`` and ``beta`` come as ``[B, S, Hv]`` and a
+head's column is picked by a masked sum along the lanes. The outputs are
+written in the recurrence's order, ``[c, B, Hv, Q, .]``. The backward kernel
+takes the same inputs as its only residuals, makes ``A`` and ``T`` again in
+VMEM, forms ``dT`` from the cotangents of ``u_own`` and ``w``, applies ``dA
+= -T^T dT T^T`` (float32, the highest precision) and returns ``dq`` and
+``dk`` (summed over the key head's value heads), ``dv`` (rounded once to
+``dtype``), ``d cum`` and ``d beta`` (float32, a row a head ``[B, c, Hk, 2
+Hv / Hk, Q]``, turned back outside). Off the TPU the kernels run in Pallas
+interpret mode; on it a shape they do not tile raises (:func:`_tiling`).
+
+**The inverse in VMEM** (:func:`_inverse_in_vmem`): the diagonal blocks of
+``_SUBSTITUTE`` = 32 rows by forward substitution on the vector unit (a
+column a step, exact float32), then :func:`_inverse`'s rounds from there up:
+at a chunk of 64, one round of two ``[64, 64]`` products on the MXU at the
+highest precision. Timed on the v5e at the benchmark cell's shape (PERF.md,
+Findings, PR 34): every round a product 14.0 ms a layer a pass, blocks of 8
+/ 16 / 32 by substitution 9.9 / 7.9 / 7.1, substitution alone 8.4.
+
+**Which inverse, and why by blocks.** :func:`unit_lower_inverse` is the
+plain form the tests hold the kernels' ``T`` to. It inverts ``I + A`` by
+blocks:
 the inverse of ``[[M11, 0], [M21, M22]]`` is ``[[M11^-1, 0], [-M22^-1 M21
 M11^-1, M22^-1]]``, from 1 x 1 blocks up, doubling: ``log2(chunk) - 1``
 rounds of two batched float32 products on the whole ``[chunk, chunk]``
@@ -51,16 +86,27 @@ backward pass is its own (``dA = -T^T dT T^T``) and keeps ``T`` alone.
 
 from __future__ import annotations
 
+import functools
 from typing import Any
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from .ssd import _varying_like
+from .pallas_util import out_vma as _out_vma, use_interpret as _use_interpret
+from .ssd import _NEG_INF, _NT, _SUBLANES, _TN, _always, _varying_like
 
+# The kernels' names in the compiled program and in a device trace; the
+# benchmark's readers match ``^hvd_gdn_`` (tests/test_program_names.py).
+KERNEL_FWD = "hvd_gdn_fwd"
+KERNEL_BWD = "hvd_gdn_bwd"
 _HI = lax.Precision.HIGHEST
+_LANES = 128      # a key or value head's size is a multiple of the lane width
+_MAX_CHUNKS = 4   # chunks a grid cell, at most
+_SUBSTITUTE = 32  # rows of the inverse's diagonal blocks made by substitution
 
 
 def _check(q, k, v, g, beta):
@@ -143,6 +189,363 @@ def _inverse_bwd(inv, g):
 unit_lower_inverse.defvjp(_inverse_fwd, _inverse_bwd)
 
 
+def _inverse_in_vmem(a):
+    """``(I + a)^{-1}`` of one ``[n, n]`` float32 matrix as a kernel can
+    trace it (masks from ``iota``, no captured constant), full float32
+    throughout. The diagonal blocks of ``_SUBSTITUTE`` rows by forward
+    substitution on the vector unit, a column a step: ``(I + a) X = I``
+    with ``X`` starting as ``I``; at step ``j`` row ``j`` is final and ``X_i
+    -= a_ij X_j`` for the rows below it in its block (eight-row tiles, those
+    above ``j`` skipped). Then :func:`_inverse`'s rounds from that block
+    size up, every product on the MXU at the highest precision.
+    ``_SUBSTITUTE`` is a multiple of eight, or 1: no substitution, every
+    round a product."""
+    size = a.shape[-1]
+    block, tile = min(_SUBSTITUTE, size), min(8, size)
+    rows = lax.broadcasted_iota(jnp.int32, (size, size), 0)
+    cols = lax.broadcasted_iota(jnp.int32, (size, size), 1)
+
+    def lower_left(shift):
+        """Block size ``b = 2 ** shift``: :func:`_inverse`'s mask."""
+        return ((rows >> (shift + 1)) == (cols >> (shift + 1))) \
+            & (((rows >> shift) & 1) == 1) & (((cols >> shift) & 1) == 0)
+
+    inv = jnp.where(rows == cols, 1.0, 0.0).astype(a.dtype)
+    at = [slice(t * tile, (t + 1) * tile) for t in range(size // tile)]
+    x, a_t = [inv[at_t] for at_t in at], [a[at_t] for at_t in at]
+    for j in range(size):
+        x_j = x[j // tile][j % tile:j % tile + 1]
+        for t in range((j + 1) // tile, (j // block + 1) * block // tile):
+            x[t] = x[t] - a_t[t][:, j:j + 1] * x_j
+    inv = jnp.concatenate(x, axis=0)
+    for shift in range(block.bit_length() - 1, size.bit_length() - 1):
+        left = jnp.dot(inv, jnp.where(lower_left(shift), a, 0.0),
+                       precision=_HI, preferred_element_type=a.dtype)
+        inv = inv - jnp.dot(left, inv, precision=_HI,
+                            preferred_element_type=a.dtype)
+    return inv
+
+
+def chunks_per_block(n_chunks: int) -> int:
+    """Chunks a grid cell of the kernels walks: the largest divisor of a
+    sequence's chunks, ``_MAX_CHUNKS`` at most."""
+    return next(nc for nc in range(min(_MAX_CHUNKS, n_chunks), 0, -1)
+                if n_chunks % nc == 0)
+
+
+def _tiling(kernel, q, v, chunk):
+    """``(chunks a grid cell, value heads a key head)`` of a call on ``q``
+    ``[B, S, Hk, K]`` and ``v`` ``[B, S, Hv, V]``; and, trace time only, the
+    record of it behind ``hvd.metrics()``. Compiled for the TPU, a shape the
+    kernels do not tile raises here, by name."""
+    key_dim, width = q.shape[3], v.shape[3]
+    rep = v.shape[2] // q.shape[2]
+    if not _use_interpret() and (
+            key_dim % _LANES or width % _LANES or chunk % _SUBLANES):
+        raise ValueError(
+            f"{kernel} does not tile chunk={chunk}, key_dim={key_dim}, "
+            f"value_dim={width}: it needs key and value heads whose sizes "
+            f"are multiples of {_LANES} and a chunk that is a multiple of "
+            f"{_SUBLANES}")
+    from .. import runtime
+    recorder = runtime.recorder()
+    if recorder is not None:
+        recorder.note_traced(
+            "hvdtpu_spmd_gdn_kernel_traces_total", kernel=kernel, chunk=chunk,
+            heads_per_block=rep, operand_dtype=jnp.dtype(q.dtype).name)
+    return chunks_per_block(q.shape[1] // chunk), rep
+
+
+def _row_sum(t):
+    """``[Q, X]`` summed along the lanes: a column ``[Q, 1]``."""
+    return jnp.sum(t, axis=1, keepdims=True)
+
+
+class _Chunk:
+    """What both kernels make of one chunk of one key head: ``q``, ``k`` in
+    the operand dtype and float32, ``K K^T`` and ``Q K^T`` (float32, made
+    once for the key head's value heads) and the two triangular masks."""
+
+    def __init__(self, q_ref, k_ref, at):
+        f32 = jnp.float32
+        self.q, self.k = q_ref[0, at, :], k_ref[0, at, :]
+        self.qf, self.kf = self.q.astype(f32), self.k.astype(f32)
+        self.kk = lax.dot_general(self.k, self.k, _NT,
+                                  preferred_element_type=f32)
+        self.qk = lax.dot_general(self.q, self.k, _NT,
+                                  preferred_element_type=f32)
+        size = self.q.shape[0]
+        rows = lax.broadcasted_iota(jnp.int32, (size, size), 0)
+        cols = lax.broadcasted_iota(jnp.int32, (size, size), 1)
+        self.lower, self.strictly = rows >= cols, rows > cols
+        self.diagonal = rows == cols
+
+    def column(self, block, head):
+        """Column ``head`` (a traced index) of ``block`` ``[Q, Hv]``, ``[Q,
+        1]``: Mosaic slices no lane at an index it cannot see."""
+        lane = lax.broadcasted_iota(jnp.int32, block.shape, 1)
+        return _row_sum(jnp.where(lane == head, block, 0.0))
+
+    def as_row(self, column):
+        """A column ``[Q, 1]`` as a row ``[1, Q]``, through the diagonal."""
+        return jnp.sum(jnp.where(self.diagonal, column, 0.0), axis=0,
+                       keepdims=True)
+
+    def head(self, cum_ref, beta_ref, at, head):
+        """Value head ``head``'s float32 parts: the running sums as a column
+        ``[Q, 1]`` and ``beta`` likewise, ``exp(cum_i - cum_j)`` kept where
+        ``j <= i`` (the mask on the exponent: above the diagonal the
+        difference is positive and may overflow), ``exp(cum)`` and
+        ``exp(cum_last - cum)`` as columns, ``A`` and ``T = (I + A)^-1``."""
+        cum = self.column(cum_ref[0, at, :], head)
+        beta = self.column(beta_ref[0, at, :], head)
+        decay = jnp.exp(jnp.where(self.lower, cum - self.as_row(cum),
+                                  _NEG_INF))
+        a = jnp.where(self.strictly, self.kk * decay * beta, 0.0)
+        size = cum.shape[0]
+        return beta, decay, jnp.exp(cum), \
+            jnp.exp(cum[size - 1:size, :] - cum), a, _inverse_in_vmem(a)
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, cum_ref, beta_ref, u_ref, w_ref,
+                attn_ref, qin_ref, kout_ref, *, nc: int, rep: int,
+                chunk: int, width: int):
+    """A grid cell: ``nc`` chunks of one sequence, one key head and its
+    ``rep`` value heads. ``u = T (beta V)`` float32, ``w = T (beta G K)``,
+    ``attn = (Q K^T) * decay``, ``q G`` and ``k G_last / G``: what the
+    recurrence over chunks reads, in its order ``[c, B, Hv, Q, .]``."""
+    f32, dtype = jnp.float32, q_ref.dtype
+    first = pl.program_id(2) * rep
+
+    @_always
+    def _chunks():
+        def one(n, carry):
+            at = pl.ds(pl.multiple_of(n * chunk, chunk), chunk)
+            c = _Chunk(q_ref, k_ref, at)
+            for r in range(rep):
+                beta, decay, grown, to_end, _, t = c.head(
+                    cum_ref, beta_ref, at, first + r)
+                t = t.astype(dtype)
+                v = v_ref[0, at, r * width:(r + 1) * width].astype(f32)
+                u_ref[n, 0, r] = jnp.dot(t, (v * beta).astype(dtype),
+                                         preferred_element_type=f32)
+                w_ref[n, 0, r] = jnp.dot(
+                    t, (c.kf * (beta * grown)).astype(dtype),
+                    preferred_element_type=f32).astype(dtype)
+                attn_ref[n, 0, r] = (c.qk * decay).astype(dtype)
+                qin_ref[n, 0, r] = (c.qf * grown).astype(dtype)
+                kout_ref[n, 0, r] = (c.kf * to_end).astype(dtype)
+            return carry
+
+        lax.fori_loop(0, nc, one, 0)
+
+
+def _bwd_kernel(q_ref, k_ref, v_ref, cum_ref, beta_ref, du_ref, dw_ref,
+                dattn_ref, dqin_ref, dkout_ref, dq_ref, dk_ref, dv_ref,
+                drows_ref, *, nc: int, rep: int, chunk: int, width: int):
+    """The forward's cotangents on the same grid cell. ``A`` and ``T`` are
+    made again from the inputs; ``dT = du (beta V)^T + dw (beta G K)^T``,
+    ``dA = -T^T dT T^T`` (float32, the highest precision), and from ``dA``
+    and ``d attn`` the cotangents of ``K K^T`` and ``Q K^T`` (summed over
+    the key head's value heads here, then through their products into
+    ``dq`` and ``dk``), of ``beta`` and of the running sums: ``M = dA * A +
+    d attn * attn`` summed along its rows for ``d cum_i`` and down its
+    columns against ``d cum_j``. ``drows`` holds a row a head of ``d cum``
+    and then a row a head of ``d beta``."""
+    f32, dtype = jnp.float32, q_ref.dtype
+    first = pl.program_id(2) * rep
+
+    @_always
+    def _chunks():
+        row_at = lax.broadcasted_iota(jnp.int32, (2 * rep, chunk), 0)
+        is_last = lax.broadcasted_iota(jnp.int32, (chunk, 1), 0) == chunk - 1
+
+        def one(n, carry):
+            at = pl.ds(pl.multiple_of(n * chunk, chunk), chunk)
+            c = _Chunk(q_ref, k_ref, at)
+            dkk = dqk = jnp.zeros((chunk, chunk), f32)
+            dq = dk = jnp.zeros(c.qf.shape, f32)
+            drows = jnp.zeros((2 * rep, chunk), f32)
+            for r in range(rep):
+                beta, decay, grown, to_end, a, t32 = c.head(
+                    cum_ref, beta_ref, at, first + r)
+                t = t32.astype(dtype)
+                lanes = slice(r * width, (r + 1) * width)
+                v = v_ref[0, at, lanes].astype(f32)
+                du = du_ref[n, 0, r].astype(dtype)
+                dw = dw_ref[n, 0, r]
+                written = beta * grown
+                dt = lax.dot_general(du, (v * beta).astype(dtype), _NT,
+                                     preferred_element_type=f32) \
+                    + lax.dot_general(dw, (c.kf * written).astype(dtype),
+                                      _NT, preferred_element_type=f32)
+                dvb = lax.dot_general(t, du, _TN, preferred_element_type=f32)
+                dkb = lax.dot_general(t, dw, _TN, preferred_element_type=f32)
+                dv_ref[0, at, lanes] = (dvb * beta).astype(dv_ref.dtype)
+                through_k = _row_sum(dkb * c.kf)
+                dk = dk + dkb * written
+                dbeta = _row_sum(dvb * v) + through_k * grown
+                dcum = through_k * written
+                # dA = -T^T dT T^T, kept strictly below the diagonal.
+                da = -lax.dot_general(
+                    lax.dot_general(t32, dt, _TN, precision=_HI,
+                                    preferred_element_type=f32),
+                    t32, _NT, precision=_HI, preferred_element_type=f32)
+                weighted = jnp.where(c.strictly, da, 0.0) * decay
+                dkk = dkk + weighted * beta
+                dbeta = dbeta + _row_sum(weighted * c.kk)
+                dattn = dattn_ref[n, 0, r].astype(f32)
+                dqk = dqk + dattn * decay
+                m = da * a + dattn * (c.qk * decay)
+                dqin = dqin_ref[n, 0, r].astype(f32)
+                dq = dq + dqin * grown
+                dkout = dkout_ref[n, 0, r].astype(f32)
+                dk = dk + dkout * to_end
+                to_last = _row_sum(dkout * c.kf) * to_end
+                dcum = dcum + _row_sum(m) + _row_sum(dqin * c.qf) * grown \
+                    - to_last + jnp.where(
+                        is_last, jnp.sum(to_last, axis=0, keepdims=True), 0.0)
+                drows = jnp.where(
+                    row_at == r,
+                    c.as_row(dcum) - jnp.sum(m, axis=0, keepdims=True), drows)
+                drows = jnp.where(row_at == rep + r, c.as_row(dbeta), drows)
+            dkk, dqk = dkk.astype(dtype), dqk.astype(dtype)
+            dk = dk + jnp.dot(dkk, c.k, preferred_element_type=f32) \
+                + lax.dot_general(dkk, c.k, _TN, preferred_element_type=f32) \
+                + lax.dot_general(dqk, c.q, _TN, preferred_element_type=f32)
+            dq = dq + jnp.dot(dqk, c.k, preferred_element_type=f32)
+            dq_ref[0, at, :] = dq.astype(dq_ref.dtype)
+            dk_ref[0, at, :] = dk.astype(dk_ref.dtype)
+            drows_ref[0, n, 0] = drows
+            return carry
+
+        lax.fori_loop(0, nc, one, 0)
+
+
+def _plan(kernel, body, q, k, v, cum, beta):
+    """What both calls share: the operands as the kernels read them, the
+    block specs by name on the grid ``(batch, block of chunks, key head)``,
+    and ``pallas_call``'s other arguments. Everything stays as the mixer
+    has it, tokens by channels: ``q``, ``k`` ``[B, S, Hk K]`` (a key head's
+    chunk is a ``[Q, K]`` block), ``v`` ``[B, S, Hv V]``, the float32
+    running sums and ``beta`` ``[B, S, Hv]`` (every head's, a chunk's rows:
+    fetched once for a block of chunks, the key heads walk it). ``scan`` is
+    a tensor in the recurrence's order ``[c, B, Hv, Q, .]``, ``rows`` the
+    backward's ``d cum | d beta`` ``[B, c, Hk, 2 rep, Q]``."""
+    batch, seq, key_heads, key_dim = q.shape
+    heads, width = v.shape[2:]
+    n_chunks, chunk = cum.shape[1:3]
+    nc, rep = _tiling(kernel, q, v, chunk)
+    args = (q.reshape(batch, seq, -1), k.reshape(batch, seq, -1),
+            v.reshape(batch, seq, -1), cum.reshape(batch, seq, heads),
+            beta.reshape(batch, seq, heads))
+
+    def tokens(lanes, walk=True):
+        return pl.BlockSpec((1, nc * chunk, lanes),
+                            lambda b, c, h: (b, c, h if walk else 0))
+
+    def scan(last):
+        return pl.BlockSpec((nc, 1, rep, chunk, last),
+                            lambda b, c, h: (c, b, h, 0, 0))
+
+    specs = {"key": tokens(key_dim), "value": tokens(rep * width),
+             "heads": tokens(heads, walk=False),
+             "rows": pl.BlockSpec((1, nc, 1, 2 * rep, chunk),
+                                  lambda b, c, h: (b, c, h, 0, 0)),
+             "scan_k": scan(key_dim), "scan_v": scan(width),
+             "scan_q": scan(chunk)}
+    call = dict(
+        grid=(batch, n_chunks // nc, key_heads),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel")),
+        interpret=_use_interpret(), name=kernel)
+    body = functools.partial(body, nc=nc, rep=rep, chunk=chunk, width=width)
+    return args, specs, body, call
+
+
+_FWD_SPECS = ("key", "key", "value", "heads", "heads")
+_SCAN_SPECS = ("scan_v", "scan_k", "scan_q", "scan_k", "scan_k")
+
+
+def _scan_shapes(q, v, cum, vma):
+    """``u`` (float32), ``w``, ``attn``, ``q G``, ``k G_last / G`` in the
+    recurrence's order."""
+    lead = (cum.shape[1], q.shape[0], v.shape[2], cum.shape[2])
+    return [jax.ShapeDtypeStruct(lead + (last,), dtype, vma=vma)
+            for last, dtype in (
+                (v.shape[3], jnp.float32), (q.shape[3], q.dtype),
+                (cum.shape[2], q.dtype), (q.shape[3], q.dtype),
+                (q.shape[3], q.dtype))]
+
+
+@functools.partial(jax.jit, inline=True)
+def _fwd_call(q, k, v, cum, beta):
+    """(Jitted inline, as :func:`_bwd_call` is: a kernel's body, 1,100
+    equations of unrolled substitution, is traced once for a shape, and a
+    block's recomputed copy and the next layers re-bind it under their own
+    scopes; 0.4 s of a job's set-up on the benchmark's host.)
+
+    ``q``, ``k`` ``[B, S, Hk, K]`` and ``v`` ``[B, S, Hv, V]`` in the
+    operand dtype, ``S`` a whole number of chunks; float32 ``cum`` (the
+    running sum of the log decays inside each chunk) and ``beta`` ``[B, c,
+    Q, Hv]`` -> what needs no state, ``[c, B, Hv, Q, .]``: ``u_own = T (beta
+    V)`` float32, ``w = T (beta G K)``, ``attn = (Q K^T) * decay``, ``q G``
+    and ``k G_last / G`` in the operand dtype."""
+    args, specs, body, call = _plan(KERNEL_FWD, _fwd_kernel, q, k, v, cum,
+                                    beta)
+    return pl.pallas_call(
+        body, in_specs=[specs[name] for name in _FWD_SPECS],
+        out_specs=[specs[name] for name in _SCAN_SPECS],
+        out_shape=_scan_shapes(q, v, cum, _out_vma(*args)), **call)(*args)
+
+
+@functools.partial(jax.jit, inline=True)
+def _bwd_call(q, k, v, cum, beta, du, dw, dattn, dqin, dkout):
+    """The cotangents of :func:`_fwd_call`'s inputs for those of its
+    outputs: ``dq``, ``dk``, ``dv`` in the operand dtype (rounded once),
+    ``d cum`` and ``d beta`` float32."""
+    args, specs, body, call = _plan(KERNEL_BWD, _bwd_kernel, q, k, v, cum,
+                                    beta)
+    args += (du, dw, dattn, dqin, dkout)
+    vma = _out_vma(*args)
+    rep = v.shape[2] // q.shape[2]
+    n_chunks, chunk = cum.shape[1:3]
+    dq, dk, dv, drows = pl.pallas_call(
+        body, in_specs=[specs[name] for name in _FWD_SPECS + _SCAN_SPECS],
+        out_specs=[specs[name] for name in ("key", "key", "value", "rows")],
+        out_shape=[jax.ShapeDtypeStruct(t.shape, q.dtype, vma=vma)
+                   for t in args[:3]]
+        + [jax.ShapeDtypeStruct(
+            (q.shape[0], n_chunks, q.shape[2], 2 * rep, chunk), jnp.float32,
+            vma=vma)], **call)(*args)
+    # [B, c, Hk, cum | beta, rep, Q] -> two of [B, c, Q, Hv]
+    drows = drows.reshape(drows.shape[:3] + (2, rep, chunk))
+    dcum, dbeta = (drows[:, :, :, i].transpose(0, 1, 4, 2, 3)
+                   .reshape(cum.shape) for i in range(2))
+    return (dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape),
+            dcum, dbeta)
+
+
+@jax.custom_vjp
+def _chunk_local(q, k, v, cum, beta):
+    """The WY form's part that needs no state, through the kernels: see
+    :func:`_fwd_call`."""
+    return tuple(_fwd_call(q, k, v, cum, beta))
+
+
+def _chunk_local_fwd(*inputs):
+    # The residuals are the inputs alone: the backward kernel makes A and T
+    # again in VMEM.
+    return tuple(_fwd_call(*inputs)), inputs
+
+
+def _chunk_local_bwd(inputs, cotangents):
+    return _bwd_call(*inputs, *cotangents)
+
+
+_chunk_local.defvjp(_chunk_local_fwd, _chunk_local_bwd)
+
+
 def gated_delta_chunked(q, k, v, g, beta, chunk: int = 64, *,
                         dtype: Any = jnp.bfloat16, initial_state=None):
     """The recurrence in chunks of ``chunk`` tokens (a power of two).
@@ -157,7 +560,6 @@ def gated_delta_chunked(q, k, v, g, beta, chunk: int = 64, *,
                          f"got {chunk}")
     batch, seq, key_heads, key_dim = k.shape
     heads, width = v.shape[2:]
-    rep = heads // key_heads
     from .. import runtime
     recorder = runtime.recorder()
     if recorder is not None:
@@ -174,40 +576,15 @@ def gated_delta_chunked(q, k, v, g, beta, chunk: int = 64, *,
     n_chunks = (seq + pad) // chunk
 
     def chunked(t):
-        """``[B, S, H, ...]`` -> ``[B, c, H, Q, ...]``."""
-        t = t.reshape((batch, n_chunks, chunk) + t.shape[2:])
-        return jnp.moveaxis(t, 2, 3)
+        """``[B, S, H]`` -> ``[B, c, Q, H]``."""
+        return t.astype(f32).reshape(batch, n_chunks, chunk, heads)
 
-    def by_value_head(t):
-        return jnp.repeat(t, rep, axis=2) if rep > 1 else t
-
-    q, k, v = (chunked(t.astype(dtype)) for t in (q, k, v))
-    beta = chunked(beta.astype(f32))                        # [B, c, H, Q]
-    cum = jnp.cumsum(chunked(g.astype(f32)), axis=-1)       # log G_t
-    last = cum[..., -1:]
-    # G_t / G_j for j <= t; the exponent is masked, not the exponential, so
-    # nothing above the diagonal overflows.
-    below = jnp.tril(jnp.ones((chunk, chunk), bool))
-    decay = jnp.exp(jnp.where(below, cum[..., :, None] - cum[..., None, :],
-                              -jnp.inf))                    # [B, c, H, Q, Q]
-    kk = jnp.einsum("bchik,bchjk->bchij", k, k, preferred_element_type=f32)
-    qk = jnp.einsum("bchik,bchjk->bchij", q, k, preferred_element_type=f32)
-    strictly = jnp.tril(jnp.ones((chunk, chunk), bool), -1)
-    a = jnp.where(strictly,
-                  by_value_head(kk) * decay * beta[..., None], 0.0)
-    t_inv = unit_lower_inverse(a).astype(dtype)
-    attn = (by_value_head(qk) * decay).astype(dtype)
-    kv = by_value_head(k).astype(f32)                       # [B, c, H, Q, K]
-    qv = by_value_head(q).astype(f32)
-    # T (beta V), T (beta G K): what needs no state.
-    u_own = jnp.einsum("bchij,bchjv->bchiv", t_inv,
-                       (v.astype(f32) * beta[..., None]).astype(dtype),
-                       preferred_element_type=f32)
-    w = jnp.einsum("bchij,bchjk->bchik", t_inv,
-                   (kv * (beta * jnp.exp(cum))[..., None]).astype(dtype),
-                   preferred_element_type=f32).astype(dtype)
-    q_in = (qv * jnp.exp(cum)[..., None]).astype(dtype)
-    k_out = (kv * jnp.exp(last - cum)[..., None]).astype(dtype)
+    cum = jnp.cumsum(chunked(g), axis=2)                    # log G_t
+    # The kernels: K K^T, Q K^T, the decays, A, T and T's two products stay
+    # in VMEM; out come the recurrence's operands, a chunk a turn.
+    u_own, w, attn, q_in, k_out = _chunk_local(
+        q.astype(dtype), k.astype(dtype), v.astype(dtype), cum,
+        chunked(beta))
 
     def one_chunk(state, now):
         u_c, w_c, q_c, k_c, attn_c, decay_c = now
@@ -218,7 +595,7 @@ def gated_delta_chunked(q, k, v, g, beta, chunk: int = 64, *,
                        preferred_element_type=f32) \
             + jnp.einsum("bhij,bhjv->bhiv", attn_c, u,
                          preferred_element_type=f32)
-        state = decay_c[..., None] * state + jnp.einsum(
+        state = decay_c[..., None, None] * state + jnp.einsum(
             "bhik,bhiv->bhkv", k_c, u, preferred_element_type=f32)
         return state, o.astype(dtype)
 
@@ -226,8 +603,8 @@ def gated_delta_chunked(q, k, v, g, beta, chunk: int = 64, *,
         if initial_state is None else initial_state.astype(f32)
     final, o = lax.scan(
         one_chunk, _varying_like(start, u_own),
-        tuple(jnp.moveaxis(t, 1, 0) for t in
-              (u_own, w, q_in, k_out, attn, jnp.exp(last))))
+        (u_own, w, q_in, k_out, attn,
+         jnp.moveaxis(jnp.exp(cum[:, :, -1]), 1, 0)))
     # [c, B, H, Q, V] -> [B, S, H, V]
     o = jnp.moveaxis(o, 0, 1).swapaxes(2, 3)
     return o.reshape(batch, n_chunks * chunk, heads, width)[:, :seq], final

@@ -108,6 +108,12 @@ _TRACED = {
         "kernels, by kernel and the tiling the call got: the chunk, the "
         "heads a grid cell holds, the MXU operands' dtype.",
         ("kernel", "chunk", "heads_per_block", "operand_dtype")),
+    "hvdtpu_spmd_gdn_kernel_traces_total": (
+        "Times JAX traced one of the gated delta rule's chunk-local "
+        "kernels, by kernel and the tiling the call got: the chunk, the "
+        "value heads a grid cell holds (those of one key head), the MXU "
+        "operands' dtype.",
+        ("kernel", "chunk", "heads_per_block", "operand_dtype")),
     "hvdtpu_spmd_remat_saved_bytes_total": (
         "Bytes a checkpointed block hands from its forward to its backward "
         "pass beside its input, by remat mode and the name the value "

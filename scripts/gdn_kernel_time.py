@@ -1,0 +1,194 @@
+#!/usr/bin/env python3
+"""Time the gated delta rule's chunk-local kernels alone on the chip, and hold
+the compiled kernels to the ``jax.numpy`` expression they replaced.
+
+    chiprun --chips 1 -- python scripts/gdn_kernel_time.py [--substitute 1 8 16 64] [--chunks-per-block 2 4 8]
+
+At the ``qwen3-next-80b-a3b_s4096`` cell's shapes (4 x 4096 tokens, 16 key and
+32 value heads of 128, chunk 64, bfloat16) it jits and times, host clock
+around ``block_until_ready``: ``hvd_gdn_fwd``; ``hvd_gdn_bwd``; the chunk-local
+part forward and backward through the ``custom_vjp``; the same through the
+plain expression (XLA writes the ``[chunk, chunk]`` tensors to HBM, the
+inverse is ``unit_lower_inverse``); and ``gated_delta_chunked`` whole, forward
+and backward, so that the recurrence over chunks is the difference. The
+kernels' values and gradients are compared with the plain expression's
+(relative to the largest value). ``--substitute`` forces the rows of the
+inverse's diagonal blocks made by substitution (1: every round a product;
+the chunk: no product) and ``--chunks-per-block`` the chunks a grid cell
+walks: the sources of ``ops/gated_delta.py::_SUBSTITUTE`` and
+``_MAX_CHUNKS``. One JSON line a row, also appended to
+``chiprun_out/gdn_kernel_time.jsonl``.
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import os
+import sys
+import time
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+
+from horovod_tpu.ops import gated_delta as gd  # noqa: E402
+
+
+def timed(fn, *args, reps: int = 10) -> float:
+    """ms a call: the mean of ``reps`` calls after two warm ones."""
+    for _ in range(2):
+        jax.block_until_ready(fn(*args))
+    t0 = time.perf_counter()
+    for _ in range(reps):
+        out = fn(*args)
+    jax.block_until_ready(out)
+    return 1e3 * (time.perf_counter() - t0) / reps
+
+
+def plain_chunk_local(q, k, v, cum, beta):
+    """What ``gated_delta_chunked`` computed before the kernels, on their
+    arguments (``ops/gated_delta.py::_fwd_call``) and in their outputs'
+    order: ``[B, c, H, Q, Q]`` float32 decays, ``K K^T``, ``A`` and the
+    inverse's rounds through HBM, autodiff's backward."""
+    f32, dtype = jnp.float32, q.dtype
+    batch, n_chunks, chunk, heads = cum.shape
+    rep = heads // q.shape[2]
+
+    def chunked(t):
+        t = t.reshape((batch, n_chunks, chunk) + t.shape[2:])
+        return jnp.moveaxis(t, 2, 3)
+
+    def by_value_head(t):
+        return jnp.repeat(t, rep, axis=2) if rep > 1 else t
+
+    q, k, v = chunked(q), chunked(k), chunked(v)
+    cum, beta = cum.swapaxes(2, 3), beta.swapaxes(2, 3)     # [B, c, H, Q]
+    below = jnp.tril(jnp.ones((chunk, chunk), bool))
+    decay = jnp.exp(jnp.where(below, cum[..., :, None] - cum[..., None, :],
+                              -jnp.inf))
+    kk = jnp.einsum("bchik,bchjk->bchij", k, k, preferred_element_type=f32)
+    qk = jnp.einsum("bchik,bchjk->bchij", q, k, preferred_element_type=f32)
+    a = jnp.where(jnp.tril(jnp.ones((chunk, chunk), bool), -1),
+                  by_value_head(kk) * decay * beta[..., None], 0.0)
+    t_inv = gd.unit_lower_inverse(a).astype(dtype)
+    attn = (by_value_head(qk) * decay).astype(dtype)
+    kv, qv = by_value_head(k).astype(f32), by_value_head(q).astype(f32)
+    u_own = jnp.einsum("bchij,bchjv->bchiv", t_inv,
+                       (v.astype(f32) * beta[..., None]).astype(dtype),
+                       preferred_element_type=f32)
+    w = jnp.einsum("bchij,bchjk->bchik", t_inv,
+                   (kv * (beta * jnp.exp(cum))[..., None]).astype(dtype),
+                   preferred_element_type=f32).astype(dtype)
+    q_in = (qv * jnp.exp(cum)[..., None]).astype(dtype)
+    k_out = (kv * jnp.exp(cum[..., -1:] - cum)[..., None]).astype(dtype)
+    return tuple(jnp.moveaxis(t, 1, 0) for t in (u_own, w, attn, q_in, k_out))
+
+
+def rel(got, want) -> float:
+    got, want = (t.astype(jnp.float32) for t in (got, want))
+    return float(jnp.abs(got - want).max() / jnp.abs(want).max())
+
+
+NAMES = ("u_own", "w", "attn", "q_in", "k_out")
+INPUTS = ("q", "k", "v", "cum", "beta")
+
+
+def main() -> int:
+    parser = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    parser.add_argument("--batch", type=int, default=4)
+    parser.add_argument("--seq", type=int, default=4096)
+    parser.add_argument("--key-heads", type=int, default=16)
+    parser.add_argument("--value-heads", type=int, default=32)
+    parser.add_argument("--key-dim", type=int, default=128)
+    parser.add_argument("--value-dim", type=int, default=128)
+    parser.add_argument("--chunk", type=int, default=64)
+    parser.add_argument("--substitute", type=int, nargs="*",
+                        default=[gd._SUBSTITUTE])
+    parser.add_argument("--chunks-per-block", type=int, nargs="*",
+                        default=[gd._MAX_CHUNKS])
+    args = parser.parse_args()
+    B, S, Hk, Hv, K, V, Q = (args.batch, args.seq, args.key_heads,
+                             args.value_heads, args.key_dim, args.value_dim,
+                             args.chunk)
+    device = jax.devices()[0]
+    print(f"platform: {device.platform} device_kind: {device.device_kind}",
+          flush=True)
+    c = S // Q
+    ks = jax.random.split(jax.random.PRNGKey(0), 11)
+    dtype, f32 = jnp.bfloat16, jnp.float32
+
+    def unit(t):
+        return t / jnp.linalg.norm(t, axis=-1, keepdims=True)
+
+    q = (unit(jax.random.normal(ks[0], (B, S, Hk, K))) * K ** -0.5
+         ).astype(dtype)
+    k = unit(jax.random.normal(ks[1], (B, S, Hk, K))).astype(dtype)
+    v = jax.random.normal(ks[2], (B, S, Hv, V), dtype)
+    g = -0.3 * jnp.exp(jax.random.normal(ks[3], (B, S, Hv)))
+    beta = jax.nn.sigmoid(jax.random.normal(ks[4], (B, S, Hv))) \
+        .reshape(B, c, Q, Hv)
+    cum = jnp.cumsum(g.reshape(B, c, Q, Hv), axis=2)
+    inputs = (q, k, v, cum, beta)
+    like = jax.eval_shape(gd._fwd_call, *inputs)
+    cts = tuple(jax.random.normal(key, t.shape, f32).astype(t.dtype)
+                for key, t in zip(ks[5:10], like))
+
+    os.makedirs(os.path.join(ROOT, "chiprun_out"), exist_ok=True)
+    out = open(os.path.join(ROOT, "chiprun_out", "gdn_kernel_time.jsonl"),
+               "a")
+
+    def row(**kw):
+        line = json.dumps(kw)
+        print(line, flush=True)
+        out.write(line + "\n")
+        out.flush()
+
+    def both(f):
+        # The cotangents are arguments: closed over, 0.8 GB of them would be
+        # constants of the program.
+        def loss(*t):
+            return sum(jnp.sum(o.astype(f32) * ct.astype(f32))
+                       for o, ct in zip(f(*t[:5]), t[5:]))
+        return jax.jit(jax.value_and_grad(loss, argnums=tuple(range(5))))
+
+    plain = both(plain_chunk_local)
+    want = jax.jit(plain_chunk_local)(*inputs)
+    _, want_g = plain(*inputs, *cts)
+    row(what="plain", fwd_ms=timed(jax.jit(plain_chunk_local), *inputs),
+        fwd_bwd_ms=timed(plain, *inputs, *cts))
+    shipped = gd._SUBSTITUTE, gd._MAX_CHUNKS
+    for substitute in args.substitute:
+        for chunks in args.chunks_per_block:
+            gd._SUBSTITUTE, gd._MAX_CHUNKS = substitute, chunks
+            jax.clear_caches()  # the calls are jitted: trace them anew
+            fwd = jax.jit(lambda *t: gd._fwd_call(*t))
+            bwd = jax.jit(lambda *t: gd._bwd_call(*t))
+            kernels = both(lambda *t: gd._chunk_local(*t))
+            got = fwd(*inputs)
+            _, got_g = kernels(*inputs, *cts)
+            row(what="kernels", substitute=substitute,
+                chunks_per_block=gd.chunks_per_block(c),
+                fwd_ms=timed(fwd, *inputs), bwd_ms=timed(bwd, *inputs, *cts),
+                fwd_bwd_ms=timed(kernels, *inputs, *cts),
+                out_rel={n: rel(o, w) for n, o, w in zip(NAMES, got, want)},
+                grad_rel={n: rel(o, w)
+                          for n, o, w in zip(INPUTS, got_g, want_g)})
+    gd._SUBSTITUTE, gd._MAX_CHUNKS = shipped
+
+    def whole(q, k, v, g, beta):
+        o, final = gd.gated_delta_chunked(q, k, v, g, beta, chunk=Q,
+                                          dtype=dtype)
+        return jnp.sum(jnp.sin(o.astype(f32))) + jnp.sum(final)
+
+    scan = (q, k, v, g, beta.reshape(B, S, Hv))
+    row(what="gated_delta_chunked", fwd_ms=timed(jax.jit(whole), *scan),
+        fwd_bwd_ms=timed(jax.jit(jax.value_and_grad(
+            whole, argnums=tuple(range(5)))), *scan))
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

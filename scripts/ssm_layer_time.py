@@ -23,6 +23,10 @@ the same script. One JSON line, also appended to
 and 32 value heads of 128, chunk 64; behind each mixer the cell's expert
 block: 32 of 512 experts of 512 held, 10 a token, a shared expert): the
 number the next change to ``ops/gated_delta.py`` is measured against first.
+With ``--trace`` the split's innermost-scope rows ``hvd_gdn_fwd`` and
+``hvd_gdn_bwd`` are the chunk-local kernels and the row ``scan`` what XLA
+runs around them (the recurrence over chunks, the norms, the running sums);
+``scripts/gdn_kernel_time.py`` times the kernels alone.
 """
 
 from __future__ import annotations

@@ -1,5 +1,5 @@
-"""The flash kernels, and the state-space scan's, through the real Mosaic
-compiler, without a chip.
+"""The flash kernels, the state-space scan's and the gated delta rule's,
+through the real Mosaic compiler, without a chip.
 
 Interpret mode (every other flash test) says nothing about Mosaic lowering:
 block shapes, VMEM, layouts. The TPU compiler is installed in the sandbox and
@@ -21,7 +21,7 @@ from jax.sharding import SingleDeviceSharding
 
 from horovod_tpu.compression import quantize
 from horovod_tpu.ops import flash_attention as fa
-from horovod_tpu.ops import ssd
+from horovod_tpu.ops import gated_delta, ssd
 
 
 @pytest.fixture(scope="module")
@@ -117,3 +117,33 @@ def test_ssd_kernel_compiles_for_v5e(one_chip, mosaic, shape, kernel):
         f, args = ssd._bwd_call, args + (x,)
     text = jax.jit(f).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text and f"hvd_ssd_{kernel}" in text
+
+
+# (B, S, Hk, Hv, K, V, Q, dtype): the qwen3-next-80b-a3b_s4096 cell's scan; a
+# small one with a value head a key head and two chunks a sequence.
+GDN_SHAPES = {
+    "qwen3-next-80b-a3b_s4096": (4, 4096, 16, 32, 128, 128, 64, jnp.bfloat16),
+    "small_one_head_a_key": (1, 128, 2, 2, 128, 128, 64, jnp.bfloat16),
+}
+
+
+@pytest.mark.parametrize("kernel", ["fwd", "bwd"])
+@pytest.mark.parametrize("shape", list(GDN_SHAPES))
+def test_gdn_kernel_compiles_for_v5e(one_chip, mosaic, shape, kernel):
+    batch, seq, key_heads, heads, key_dim, width, chunk, dtype = \
+        GDN_SHAPES[shape]
+
+    def sds(*dims, dt=dtype):
+        return jax.ShapeDtypeStruct(dims, dt, sharding=one_chip)
+
+    q = sds(batch, seq, key_heads, key_dim)
+    row = sds(batch, seq // chunk, chunk, heads, dt=jnp.float32)
+    args = (q, q, sds(batch, seq, heads, width), row, row)
+    if kernel == "fwd":
+        f = gated_delta._fwd_call
+    else:
+        f = gated_delta._bwd_call
+        args += tuple(sds(*t.shape, dt=t.dtype) for t in jax.eval_shape(
+            gated_delta._fwd_call, *args))
+    text = jax.jit(f).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text and f"hvd_gdn_{kernel}" in text

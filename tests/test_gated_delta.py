@@ -12,6 +12,14 @@ chunk's running sums of them made in bfloat16 (the last test). A ``T`` made
 in bfloat16 reads as the shipped program does while keys are nearly
 orthogonal (``A`` is small and ``T`` is rounded before it is applied
 anyway): what holds it to float32 is the equal-keys test.
+
+Since PR 34 everything of the chunked form with two chunk-length axes (``K
+K^T``, the decays, ``A``, ``T`` and ``T``'s two products) is two Pallas
+kernels, which run here in interpret mode: every test of
+``gated_delta_chunked`` above and below goes through them. The ``cell``
+cases repeat the comparison at the benchmark cell's head layout, and the
+kernels' own inverse (substitution inside diagonal blocks, then products) is
+held to ``unit_lower_inverse`` directly.
 """
 
 import jax
@@ -22,28 +30,57 @@ import pytest
 from horovod_tpu.ops import gated_delta
 from horovod_tpu.ops.gated_delta import (
     gated_delta_chunked, gated_delta_sequential, unit_lower_inverse)
+from horovod_tpu.ops.pallas_util import use_interpret
 
 B, HK, HV, K, V = 2, 2, 4, 16, 8
 
 
-def _inputs(seed, seq, decay=0.3, key_heads=HK):
+def _inputs(seed, seq, decay=0.3, key_heads=HK, batch=B, heads=HV,
+            key_dim=K, width=V):
     rng = np.random.default_rng(seed)
 
     def unit(t):
         return t / np.linalg.norm(t, axis=-1, keepdims=True)
 
-    q = unit(rng.standard_normal((B, seq, key_heads, K))) / np.sqrt(K)
-    k = unit(rng.standard_normal((B, seq, key_heads, K)))
-    v = rng.standard_normal((B, seq, HV, V))
-    g = -decay * np.exp(rng.standard_normal((B, seq, HV)))
-    beta = 1 / (1 + np.exp(-rng.standard_normal((B, seq, HV))))
+    q = unit(rng.standard_normal((batch, seq, key_heads, key_dim))) \
+        / np.sqrt(key_dim)
+    k = unit(rng.standard_normal((batch, seq, key_heads, key_dim)))
+    v = rng.standard_normal((batch, seq, heads, width))
+    g = -decay * np.exp(rng.standard_normal((batch, seq, heads)))
+    beta = 1 / (1 + np.exp(-rng.standard_normal((batch, seq, heads))))
     return tuple(jnp.asarray(t, jnp.float32) for t in (q, k, v, g, beta))
+
+
+# The benchmark cell's head layout: two value heads a key head, key and
+# value heads of 128, chunks of 64, a length the chunk does not divide
+# (three chunks: the kernels' grid cell takes all three).
+CELL = dict(batch=1, key_heads=1, heads=2, key_dim=128, width=128)
+CELL_SEQ = 150
 
 
 def _close(got, want, tol):
     np.testing.assert_allclose(
         np.asarray(got, np.float32), want, rtol=0,
         atol=tol * float(jnp.abs(want).max()) + 1e-12)
+
+
+def _gradient_matches(args, seed, chunk, wrt):
+    """The gradient of input ``wrt`` of a random linear form of the output
+    and the final state, chunked against sequential."""
+    rng = np.random.default_rng(seed)
+    like_o, like_s = jax.eval_shape(gated_delta_sequential, *args)
+    co = jnp.asarray(rng.standard_normal(like_o.shape), jnp.float32)
+    cs = jnp.asarray(rng.standard_normal(like_s.shape), jnp.float32)
+
+    def scalar(fn):
+        def f(*a):
+            o, s = fn(*a)
+            return jnp.sum(o * co) + jnp.sum(s * cs)
+        return jax.grad(f, argnums=wrt)(*args)
+
+    _close(scalar(lambda *a: gated_delta_chunked(*a, chunk=chunk,
+                                                 dtype=jnp.float32)),
+           scalar(gated_delta_sequential), 5e-5)
 
 
 @pytest.mark.parametrize("chunk", [16, 64])
@@ -57,6 +94,52 @@ def test_chunked_matches_sequential(chunk, seq):
     assert o.shape == want_o.shape and s.shape == (B, HV, K, V)
     _close(o, want_o, 2e-5)
     _close(s, want_s, 2e-5)
+
+
+def test_the_kernels_are_the_path():
+    """No flag chooses: off the TPU the chunk-local part runs as the two
+    kernels in interpret mode, forward and backward."""
+    assert use_interpret()
+    args = _inputs(0, 32)
+    text = str(jax.make_jaxpr(jax.grad(lambda *a: gated_delta_chunked(
+        *a, chunk=16, dtype=jnp.float32)[0].sum(), argnums=(0, 3)))(*args))
+    assert "name=hvd_gdn_fwd" in text and "name=hvd_gdn_bwd" in text
+
+
+def test_cell_layout_matches_sequential():
+    args = _inputs(11, CELL_SEQ, **CELL)
+    want_o, want_s = gated_delta_sequential(*args)
+    o, s = gated_delta_chunked(*args, chunk=64, dtype=jnp.float32)
+    _close(o, want_o, 2e-5)
+    _close(s, want_s, 2e-5)
+
+
+@pytest.mark.parametrize("wrt", range(5), ids=["q", "k", "v", "g", "beta"])
+def test_cell_layout_gradients_of_every_input(wrt):
+    _gradient_matches(_inputs(12, CELL_SEQ, **CELL), 13, 64, wrt)
+
+
+@pytest.mark.parametrize("what", ["beta zero", "alpha one"])
+def test_cell_layout_beta_zero_and_alpha_one(what):
+    """The two degenerate cases at the cell's layout: nothing written (the
+    entering state only decays), and the ungated delta rule (a unit key reads
+    back its value)."""
+    q, k, v, g, beta = _inputs(14, 128, **CELL)
+    if what == "beta zero":
+        start = jnp.asarray(np.random.default_rng(15).standard_normal(
+            (1, 2, 128, 128)), jnp.float32)
+        o, s = gated_delta_chunked(q, k, v, g, jnp.zeros_like(g), chunk=64,
+                                   dtype=jnp.float32, initial_state=start)
+        total = jnp.exp(jnp.cumsum(g, axis=1))
+        _close(s, total[:, -1][..., None, None] * start, 1e-5)
+        _close(o, jnp.einsum("bshk,bhkv->bshv", jnp.repeat(q, 2, axis=2),
+                             start) * total[..., None], 1e-5)
+    else:
+        k2 = jnp.repeat(k, 2, axis=2)
+        o, _ = gated_delta_chunked(k2, k2, v, jnp.zeros_like(g),
+                                   jnp.ones_like(beta), chunk=64,
+                                   dtype=jnp.float32)
+        _close(o, v, 1e-5)
 
 
 @pytest.mark.parametrize("key_heads", [HV, HK, 1])
@@ -74,21 +157,7 @@ def test_value_heads_share_key_heads(key_heads):
 @pytest.mark.parametrize("chunk,seq", [(16, 64), (64, 70), (16, 37)])
 @pytest.mark.parametrize("wrt", range(5), ids=["q", "k", "v", "g", "beta"])
 def test_gradients_of_every_input(chunk, seq, wrt):
-    args = _inputs(2, seq)
-    rng = np.random.default_rng(3)
-    co = jnp.asarray(rng.standard_normal((B, seq, HV, V)), jnp.float32)
-    cs = jnp.asarray(rng.standard_normal((B, HV, K, V)), jnp.float32)
-
-    def scalar(fn):
-        def f(*a):
-            o, s = fn(*a)
-            return jnp.sum(o * co) + jnp.sum(s * cs)
-        return jax.grad(f, argnums=wrt)(*args)
-
-    want = scalar(gated_delta_sequential)
-    got = scalar(lambda *a: gated_delta_chunked(*a, chunk=chunk,
-                                                dtype=jnp.float32))
-    _close(got, want, 5e-5)
+    _gradient_matches(_inputs(2, seq), 3, chunk, wrt)
 
 
 @pytest.mark.parametrize("decay,what", [(1e-3, "near one"), (8.0, "near zero")])
@@ -164,6 +233,53 @@ def test_equal_keys_do_not_blow_the_inverse_up():
     a = jnp.tril(jnp.ones((64, 64), jnp.float32), -1)
     want = np.eye(64, dtype=np.float32) - np.eye(64, k=-1, dtype=np.float32)
     np.testing.assert_allclose(unit_lower_inverse(a), want, atol=1e-6)
+
+
+@pytest.mark.parametrize("substitute", [1, 8, 32, 64])
+@pytest.mark.parametrize("size", [16, 64])
+def test_the_kernels_inverse_is_unit_lower_inverse(monkeypatch, size,
+                                                   substitute):
+    """The inverse as the kernels make it (diagonal blocks of ``substitute``
+    rows by forward substitution, then ``_inverse``'s rounds; 32 ships)
+    against the plain form, on a random matrix and on the equal-keys one."""
+    monkeypatch.setattr(gated_delta, "_SUBSTITUTE", substitute)
+    rng = np.random.default_rng(16)
+    a = jnp.asarray(np.tril(rng.standard_normal((size, size)), -1),
+                    jnp.float32)
+    _close(gated_delta._inverse_in_vmem(a), unit_lower_inverse(a), 1e-5)
+    ones = jnp.tril(jnp.ones((size, size), jnp.float32), -1)
+    want = np.eye(size, dtype=np.float32) \
+        - np.eye(size, k=-1, dtype=np.float32)
+    np.testing.assert_allclose(gated_delta._inverse_in_vmem(ones), want,
+                               atol=1e-6)
+
+
+@pytest.mark.parametrize("noise, dtype, tol", [
+    (0.0, jnp.float32, 1e-5), (0.05, jnp.float32, 1e-4),
+    (0.0, jnp.bfloat16, 5e-2)], ids=["equal", "nearly equal", "bfloat16"])
+def test_equal_keys_through_the_kernels(noise, dtype, tol):
+    """Every key the same at alpha = beta = 1, through the kernel path:
+    ``A`` is all ones below the diagonal, each token unwrites the one before
+    and the output is ``<q, k> v_t``; an inverse by powers is off by orders
+    of magnitude. Keys a twentieth apart make ``A``'s entries 0.99...: the
+    same inverse with its arithmetic in bfloat16 reads 1.7e-2 here, the
+    float32 one 1e-6 (all ones are exact in any type, so the first case
+    cannot see that). With bfloat16 operands the keys' own rounding shows
+    (3.7e-2 seen)."""
+    seq = 128
+    rng = np.random.default_rng(17)
+    k = rng.standard_normal(128) \
+        + noise * rng.standard_normal((1, seq, 1, 128))
+    k = jnp.asarray(k / np.linalg.norm(k, axis=-1, keepdims=True),
+                    jnp.float32)
+    v = jnp.asarray(rng.standard_normal((1, seq, 2, 128)), jnp.float32)
+    zeros, ones = jnp.zeros((1, seq, 2)), jnp.ones((1, seq, 2))
+    want, want_s = gated_delta_sequential(k, k, v, zeros, ones)
+    if not noise:
+        _close(want, v, 1e-5)
+    o, s = gated_delta_chunked(k, k, v, zeros, ones, chunk=64, dtype=dtype)
+    _close(o, want, tol)
+    _close(s, want_s, tol)
 
 
 def test_bad_shapes_and_chunks_raise_by_name():

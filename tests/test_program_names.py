@@ -21,7 +21,7 @@ from horovod_tpu.compression import pallas_kernels as pk
 from horovod_tpu.models import gpt
 from horovod_tpu.observability import parse_prometheus_text, sample_value
 from horovod_tpu.ops import flash_attention as fa
-from horovod_tpu.ops import ssd
+from horovod_tpu.ops import gated_delta, ssd
 
 CFG = dict(vocab_size=64, num_layers=2, num_heads=2, num_kv_heads=1,
            head_dim=16, embed_dim=32, mlp_dim=64, tp_axis=None, sp_axis=None,
@@ -152,13 +152,46 @@ def test_linear_attention_step_carries_the_gdn_scopes(spmd4, inner):
     assert some("transpose(jvp(layer1))", "/moe/shared/")
 
 
+def test_linear_attention_step_holds_the_gdn_kernels_under_their_scope(
+        spmd4):
+    """The chunk-local kernels sit under ``layer<i>/gdn/scan`` (where
+    ``gdn_scan_ms`` looks): the forward one in the forward pass and in the
+    recomputed copy (the recurrence's backward pass reads its outputs), the
+    backward one in the backward pass. The counter says which tiling each
+    got, with its four labels: each kernel is traced once for a shape (the
+    calls are jitted inline, so the recomputed copy and further layers of
+    the same shape re-bind the traced kernel), which is why this test scans
+    at a chunk no other test of this file does."""
+    step, *args = gpt_step("full", **{**LINEAR, "gdn_chunk": 32})
+    text = step.lower(*args).as_text(debug_info=True)
+    scopes = set(re.findall(r'loc\("([^"]*)/hvd_gdn_(fwd|bwd)/', text))
+    assert {kernel for _, kernel in scopes} == {"fwd", "bwd"}
+    for scope, kernel in scopes:
+        assert scope.endswith("/gdn/scan") and "layer0" in scope, scope
+        assert "layer1" not in scope
+        assert ("transpose(jvp(layer0))" in scope
+                and "rematted_computation" not in scope) == (kernel == "bwd"), \
+            scope
+    assert any("rematted_computation" in scope for scope, _ in scopes)
+    family = hvd.metrics()["hvdtpu_spmd_gdn_kernel_traces_total"]
+    samples = {tuple(sorted(labels.items())): count
+               for _, labels, count in family["samples"]}
+    assert samples == {
+        (("chunk", "32"), ("heads_per_block", "2"), ("kernel", kernel),
+         ("operand_dtype", "float32")): traces
+        for kernel, traces in ((gated_delta.KERNEL_FWD, 1.0),
+                               (gated_delta.KERNEL_BWD, 1.0))}
+
+
 @pytest.mark.parametrize("more", [{}, SPARSE], ids=["dense", "sparse"])
 def test_a_step_without_a_state_space_layer_traces_no_scan_kernel(spmd4,
                                                                   more):
     step, *args = gpt_step("full", **more)
-    assert "hvd_ssd" not in step.lower(*args).as_text(debug_info=True)
-    assert not hvd.metrics()[
-        "hvdtpu_spmd_ssd_kernel_traces_total"]["samples"]
+    text = step.lower(*args).as_text(debug_info=True)
+    assert "hvd_ssd" not in text and "hvd_gdn" not in text
+    for family in ("hvdtpu_spmd_ssd_kernel_traces_total",
+                   "hvdtpu_spmd_gdn_kernel_traces_total"):
+        assert not hvd.metrics()[family]["samples"]
 
 
 def test_in_step_collective_scope_uses_the_callers_name(spmd4):
@@ -196,6 +229,18 @@ def _scan(grad: bool):
     return jax.make_jaxpr(jax.grad(scan) if grad else scan)(x)
 
 
+def _delta(grad: bool):
+    q = jnp.ones((1, 32, 1, 8), jnp.float32)
+    g = -jnp.ones((1, 32, 2), jnp.float32)
+
+    def scan(v):
+        return gated_delta.gated_delta_chunked(
+            q, q, v, g, -g, chunk=16, dtype=jnp.float32)[0].sum()
+
+    return jax.make_jaxpr(jax.grad(scan) if grad else scan)(
+        jnp.ones((1, 32, 2, 8), jnp.float32))
+
+
 FLAT = jnp.linspace(-1.0, 1.0, 512 * 4, dtype=jnp.float32)
 LEVELS = jnp.linspace(0.0, 1.0, 8, dtype=jnp.float32)
 Q8 = jnp.zeros((4, 512), jnp.uint8)
@@ -208,6 +253,8 @@ MN = jnp.zeros((4,), jnp.float32)
     ("hvd_flash_dq", lambda: _flash(True)),
     ("hvd_ssd_fwd", lambda: _scan(False)),
     ("hvd_ssd_bwd", lambda: _scan(True)),
+    ("hvd_gdn_fwd", lambda: _delta(False)),
+    ("hvd_gdn_bwd", lambda: _delta(True)),
     ("hvd_maxmin_quantize", lambda: jax.make_jaxpr(
         lambda x: pk.maxmin_quantize_pallas(x, 4, 512, True))(FLAT)),
     # TPU-only (pltpu.prng_* has no CPU lowering), but it traces anywhere.
@@ -226,7 +273,7 @@ MN = jnp.zeros((4,), jnp.float32)
         lambda q: pk.norm_dequantize_pallas(q, LEVELS, MN, True))(Q8)),
 ])
 def test_kernel_names(name, make):
-    """The eleven names the benchmark's readers match as strings."""
+    """The thirteen names the benchmark's readers match as strings."""
     assert re.search(rf"\bname={name}\b", str(make())), name
 
 
@@ -235,6 +282,9 @@ def test_kernel_name_constants():
         "hvd_flash_fwd", "hvd_flash_dkdv", "hvd_flash_dq")
     # benchmarks/jobs/gpt_hybrid_dp.py matches ``^hvd_ssd_``.
     assert (ssd.KERNEL_FWD, ssd.KERNEL_BWD) == ("hvd_ssd_fwd", "hvd_ssd_bwd")
+    # benchmarks/jobs/gpt_linear_moe_dp.py matches ``^hvd_gdn_``.
+    assert (gated_delta.KERNEL_FWD, gated_delta.KERNEL_BWD) == (
+        "hvd_gdn_fwd", "hvd_gdn_bwd")
     assert {v for k, v in vars(pk).items() if k.startswith("KERNEL_")} == {
         "hvd_maxmin_quantize", "hvd_maxmin_quantize_stochastic",
         "hvd_maxmin_dequantize", "hvd_maxmin_dequantize_sum",
