@@ -44,12 +44,27 @@ zero (``1 + w``), the head the embedding's transpose, and the embedding, the
 attention logits, each residual branch and the logits scaled by a constant.
 An expert block may hold a share of its router's experts
 (``experts_held``, ``first_expert``: ``parallel/moe.py``), renormalise a
-token's weights and add a gated shared expert every token goes through.
+token's weights, add a shared expert every token goes through (under a
+sigmoid gate of its own or as it is), be as wide as ``expert_dim`` where the
+dense feed-forward is ``mlp_dim``, and score with a sigmoid under a
+selection bias that is state, not a parameter (``router_bias``,
+:func:`update_router_bias`, :func:`trainable`).
+
+**What each layer is, is said once**: ``GPTConfig.plan``, one
+:class:`LayerSpec` a layer (the mixer, an attention layer's window and
+whether the rotary embedding applies to it, the feed-forward's kind), either
+given outright (``GPTConfig.layers``: dense layers before expert layers,
+window attention beside full) or resolved from ``layer_kinds``, ``moe_every``
+and ``gated_mlp`` in :func:`layer_plan` and nowhere else. ``init_params``,
+``param_specs`` and ``_block`` read the plan, never which keys a layer's
+parameters hold. With ``post_norm`` each branch is normed after as well as
+before it is added (``x + N2(f(N1(x)))``).
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Optional, Tuple
 
 import jax
@@ -65,6 +80,25 @@ from ..ops.gated_delta import gated_delta_chunked
 from ..ops.ssd import causal_conv1d, ssd_chunked
 from ..parallel.ring_attention import ring_attention_p
 from ..parallel.ulysses import ulysses_attention_p
+
+
+MIXERS = ("attention", "ssm", "gdn")
+FEED_FORWARDS = ("dense", "gated", "experts")
+
+
+@dataclasses.dataclass(frozen=True)
+class LayerSpec:
+    """One layer of the stack: its mixer (one of ``MIXERS``), for an
+    attention mixer the ``window`` (a query sees itself and the ``window -
+    1`` keys before it; None: every key before it) and whether the rotary
+    embedding applies (``GPTConfig.rope_theta``, ``rotary_dim``), and its
+    feed-forward (one of ``FEED_FORWARDS``: two matrices and a GELU, three
+    and a SiLU gate, or the expert block with what ``GPTConfig`` says of
+    experts)."""
+    mixer: str = "attention"
+    window: Optional[int] = None
+    rope: bool = True
+    ff: str = "dense"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -169,14 +203,49 @@ class GPTConfig:
     attention_multiplier: Optional[float] = None
     residual_multiplier: float = 1.0
     logits_scaling: float = 1.0
+    # The layers said outright, one LayerSpec each; None: resolved from
+    # layer_kinds, moe_every, gated_mlp and rope (``layer_plan``). What only
+    # a per-layer description can say (a window on some attention layers,
+    # the rotary embedding on some, dense layers before expert layers) is
+    # said here and has no field of its own.
+    layers: Optional[Tuple[LayerSpec, ...]] = None
+    # An expert's width (None: mlp_dim, which stays the dense layers').
+    expert_dim: Optional[int] = None
+    # A norm after each branch as well as before: x + N2(f(N1(x))).
+    post_norm: bool = False
+    # False: the shared expert is added as it is, with no gate of its own.
+    shared_expert_gate: bool = True
+    # The router's scores, "softmax" or "sigmoid"; router_bias: a bias
+    # [num_experts] added to the scores for the choice of experts alone,
+    # kept beside the router's matrix but no parameter (no gradient reaches
+    # it; ``trainable`` keeps the optimizer off it, ``update_router_bias``
+    # moves it from the step's token counts); route_scale multiplies a
+    # token's weights.
+    router_score: str = "softmax"
+    router_bias: bool = False
+    route_scale: float = 1.0
+    # ``loss_and_aux``'s parts also hold what each expert block's router
+    # read and gave (``router_inputs``, ``router_logits``): a check holds
+    # the float32 product to a reference fed the same activations, which
+    # no norm or count of the step can (a step that drops them costs
+    # nothing: the compiler removes what nobody reads).
+    router_probe: bool = False
 
     @property
     def kv_heads(self) -> int:
         return self.num_kv_heads or self.num_heads
 
+    @property
+    def plan(self) -> Tuple[LayerSpec, ...]:
+        """What each layer is (:func:`layer_plan`)."""
+        return layer_plan(self)
+
     def kind(self, layer: int) -> str:
-        return "attention" if self.layer_kinds is None \
-            else self.layer_kinds[layer]
+        return self.plan[layer].mixer
+
+    @property
+    def expert_width(self) -> int:
+        return self.expert_dim or self.mlp_dim
 
     @property
     def ssm_inner(self) -> int:
@@ -204,20 +273,42 @@ class GPTConfig:
 from ..parallel.axes import axis_size as _axis_size, axis_bound as _axis_bound
 
 
-def _is_moe(cfg: GPTConfig, layer: int) -> bool:
-    return cfg.moe_every > 0 and (layer + 1) % cfg.moe_every == 0
-
-
-LAYER_KINDS = ("attention", "ssm", "gdn")
-
-
-def _check_kinds(cfg: GPTConfig) -> None:
+@functools.lru_cache(maxsize=None)
+def layer_plan(cfg: GPTConfig) -> Tuple[LayerSpec, ...]:
+    """One :class:`LayerSpec` a layer: ``cfg.layers`` where it is given,
+    else what ``layer_kinds`` (the mixers; None: attention throughout),
+    ``moe_every`` (every ``moe_every``-th block's feed-forward is the expert
+    block), ``gated_mlp`` (the other blocks') and ``rope`` say. The one
+    place those inputs are read."""
     kinds = cfg.layer_kinds
     if kinds is not None and (len(kinds) != cfg.num_layers
-                              or set(kinds) - set(LAYER_KINDS)):
+                              or set(kinds) - set(MIXERS)):
         raise ValueError(
-            f"layer_kinds must name one of {LAYER_KINDS} for each of the "
+            f"layer_kinds must name one of {MIXERS} for each of the "
             f"{cfg.num_layers} layers, got {kinds!r}")
+    if cfg.layers is None:
+        return tuple(LayerSpec(
+            mixer="attention" if kinds is None else kinds[i], rope=cfg.rope,
+            ff="experts" if cfg.moe_every > 0
+            and (i + 1) % cfg.moe_every == 0
+            else "gated" if cfg.gated_mlp else "dense")
+            for i in range(cfg.num_layers))
+    if kinds is not None or cfg.moe_every:
+        raise ValueError("layers says each layer outright: leave "
+                         "layer_kinds and moe_every unset beside it")
+    plan = tuple(cfg.layers)
+    if len(plan) != cfg.num_layers or any(
+            not isinstance(spec, LayerSpec) or spec.mixer not in MIXERS
+            or spec.ff not in FEED_FORWARDS
+            or (spec.window is not None
+                and (spec.mixer != "attention" or spec.window < 1))
+            for spec in plan):
+        raise ValueError(
+            f"layers must hold a LayerSpec (mixer one of {MIXERS}, "
+            f"feed-forward one of {FEED_FORWARDS}, a window of at least one "
+            f"key on attention alone) for each of the {cfg.num_layers} "
+            f"layers, got {plan!r}")
+    return plan
 
 
 def _init_ssm(key, cfg: GPTConfig, dense) -> dict:
@@ -301,7 +392,7 @@ def init_params(rng, cfg: GPTConfig) -> dict:
         return jnp.zeros(shape, jnp.float32) if cfg.norm_zero_centered \
             else jnp.ones(shape, jnp.float32)
 
-    _check_kinds(cfg)
+    plan = cfg.plan
     keys = jax.random.split(rng, 2 + cfg.num_layers)
     params: dict = {
         "embed": jax.random.normal(keys[0], (cfg.vocab_size, E),
@@ -311,13 +402,13 @@ def init_params(rng, cfg: GPTConfig) -> dict:
     }
     if not cfg.tie_embeddings:
         params["lm_head"] = dense(keys[1], (E, cfg.vocab_size), E)
-    for i in range(cfg.num_layers):
+    for i, spec in enumerate(plan):
         ks = jax.random.split(keys[2 + i], 8)
-        if cfg.kind(i) == "ssm":
+        if spec.mixer == "ssm":
             layer = {"ssm_norm": norm((E,)),
                      "ssm": _init_ssm(ks[0], cfg, dense),
                      "mlp_norm": norm((E,))}
-        elif cfg.kind(i) == "gdn":
+        elif spec.mixer == "gdn":
             layer = {"gdn_norm": norm((E,)),
                      "gdn": _init_gdn(ks[0], cfg, dense),
                      "mlp_norm": norm((E,))}
@@ -337,14 +428,20 @@ def init_params(rng, cfg: GPTConfig) -> dict:
             elif cfg.qk_head_norm:
                 layer["q_norm"] = norm((D,))
                 layer["k_norm"] = norm((D,))
-        if _is_moe(cfg, i):
-            n_exp, held = cfg.num_experts, _held(cfg)
+        if cfg.post_norm:
+            layer["mixer_post_norm"] = norm((E,))
+            layer["mlp_post_norm"] = norm((E,))
+        if spec.ff == "experts":
+            n_exp, held, Mx = cfg.num_experts, _held(cfg), cfg.expert_width
             layer["moe"] = {
                 "router": dense(ks[4], (E, n_exp), E),
-                "w_gate": dense(ks[7], (held, E, M), E),
-                "w_up": dense(ks[5], (held, E, M), E),
-                "w_down": dense(ks[6], (held, M, E), M),
+                "w_gate": dense(ks[7], (held, E, Mx), E),
+                "w_up": dense(ks[5], (held, E, Mx), E),
+                "w_down": dense(ks[6], (held, Mx, E), Mx),
             }
+            if cfg.router_bias:
+                layer["moe"]["router_bias"] = jnp.zeros((n_exp,),
+                                                        jnp.float32)
             if cfg.shared_expert_dim:
                 sk = jax.random.split(jax.random.fold_in(ks[4], 1), 4)
                 Ms = cfg.shared_expert_dim
@@ -352,10 +449,11 @@ def init_params(rng, cfg: GPTConfig) -> dict:
                     "w_gate": dense(sk[0], (E, Ms), E),
                     "w_up": dense(sk[1], (E, Ms), E),
                     "w_down": dense(sk[2], (Ms, E), Ms),
-                    "gate": dense(sk[3], (E,), E),
                 }
+                if cfg.shared_expert_gate:
+                    layer["moe"]["shared"]["gate"] = dense(sk[3], (E,), E)
         else:
-            if cfg.gated_mlp:
+            if spec.ff == "gated":
                 layer["w_gate"] = dense(ks[7], (E, M), E)
             layer["w_up"] = dense(ks[5], (E, M), E)
             layer["w_down"] = dense(ks[6], (M, E), M)
@@ -366,8 +464,8 @@ def init_params(rng, cfg: GPTConfig) -> dict:
 def param_specs(cfg: GPTConfig) -> dict:
     """PartitionSpec pytree matching :func:`init_params` — tp shards heads and
     MLP hidden; ep shards experts; everything else replicated, a state-space
-    mixer included (it refuses a bound tp axis)."""
-    _check_kinds(cfg)
+    mixer included (it refuses a bound tp axis) and the router's selection
+    bias (state every rank holds whole)."""
     tp, ep = cfg.tp_axis, cfg.ep_axis
     specs: dict = {
         "embed": P(),
@@ -376,13 +474,13 @@ def param_specs(cfg: GPTConfig) -> dict:
     }
     if not cfg.tie_embeddings:
         specs["lm_head"] = P()
-    for i in range(cfg.num_layers):
-        if cfg.kind(i) == "ssm":
+    for spec in cfg.plan:
+        if spec.mixer == "ssm":
             layer = {"ssm_norm": P(), "mlp_norm": P(), "ssm": {
                 name: P() for name in (
                     "in_proj", "conv_w", "conv_b", "dt_bias", "A_log", "D",
                     "norm", "out_proj")}}
-        elif cfg.kind(i) == "gdn":
+        elif spec.mixer == "gdn":
             layer = {"gdn_norm": P(), "mlp_norm": P(), "gdn": {
                 name: P() for name in (
                     "in_proj", "in_proj_ba", "conv_w", "dt_bias", "A_log",
@@ -402,7 +500,10 @@ def param_specs(cfg: GPTConfig) -> dict:
             elif cfg.qk_head_norm:
                 layer["q_norm"] = P()
                 layer["k_norm"] = P()
-        if _is_moe(cfg, i):
+        if cfg.post_norm:
+            layer["mixer_post_norm"] = P()
+            layer["mlp_post_norm"] = P()
+        if spec.ff == "experts":
             _held(cfg)
             layer["moe"] = {
                 "router": P(),
@@ -410,12 +511,16 @@ def param_specs(cfg: GPTConfig) -> dict:
                 "w_up": P(ep, None, tp),
                 "w_down": P(ep, tp, None),
             }
+            if cfg.router_bias:
+                layer["moe"]["router_bias"] = P()
             if cfg.shared_expert_dim:
                 layer["moe"]["shared"] = {
                     "w_gate": P(None, tp), "w_up": P(None, tp),
-                    "w_down": P(tp, None), "gate": P()}
+                    "w_down": P(tp, None)}
+                if cfg.shared_expert_gate:
+                    layer["moe"]["shared"]["gate"] = P()
         else:
-            if cfg.gated_mlp:
+            if spec.ff == "gated":
                 layer["w_gate"] = P(None, tp)
             layer["w_up"] = P(None, tp)
             layer["w_down"] = P(tp, None)
@@ -456,9 +561,13 @@ def _tp_psum(x, cfg: GPTConfig):
 _ATTENTION_KINDS = ("flash", "dense", "ring", "ulysses")
 
 
-def _attention(cfg: GPTConfig, q, k, v):
+def _attention(cfg: GPTConfig, q, k, v, window: Optional[int] = None):
     """Which attention runs: the one place that decides, and this table is
-    the whole rule. No row falls back to another.
+    the whole rule. No row falls back to another. ``window`` (a layer's,
+    ``LayerSpec.window``) goes to whichever runs: the flash kernels and the
+    dense reference take it, Ulysses hands it to the kernel it calls, and
+    ring attention under a bound sp axis refuses a window layer by name
+    (its hops wholly outside the band are not skipped yet).
 
     ============  ==================  ===============================
     attention     sp axis not bound   sp axis bound
@@ -484,12 +593,19 @@ def _attention(cfg: GPTConfig, q, k, v):
             # K/V up themselves; the flash kernels read them as they are).
             return default_attention(q, repeat_kv_heads(k, q.shape[2]),
                                      repeat_kv_heads(v, q.shape[2]),
-                                     causal=True)
-        return flash_attention(q, k, v, causal=True)
+                                     causal=True, window=window)
+        return flash_attention(q, k, v, causal=True, window=window)
     if kind == "ring":
+        if window is not None:
+            raise ValueError(
+                f"attention='ring' under the bound {sp!r} axis has no "
+                f"window: a layer with window={window} would pass every "
+                "hop, those wholly outside its band too; use 'ulysses'")
         return ring_attention_p(q, k, v, causal=True, axis=sp)
     if kind == "ulysses":
-        return ulysses_attention_p(q, k, v, causal=True, axis=sp)
+        return ulysses_attention_p(
+            q, k, v, causal=True, axis=sp,
+            attn_fn=functools.partial(flash_attention, window=window))
     raise ValueError(
         f"attention={kind!r} is local attention: under the bound "
         f"{sp!r} axis each rank would attend its own sequence shard only; "
@@ -590,12 +706,14 @@ def _gdn_mixer(cfg: GPTConfig, p, h):
 
 
 def _shared_expert(cfg: GPTConfig, p, h):
-    """The expert every token goes through: ``sigmoid(<h, w_g>) W_down
-    (silu(W_gate h) * W_up h)``."""
+    """The expert every token goes through: ``W_down (silu(W_gate h) * W_up
+    h)``, under ``sigmoid(<h, w_g>)`` where the configuration gates it."""
     gate = jnp.einsum("bse,em->bsm", h, p["w_gate"].astype(cfg.dtype))
     up = jnp.einsum("bse,em->bsm", h, p["w_up"].astype(cfg.dtype))
     down = _tp_psum(jnp.einsum("bsm,me->bse", jax.nn.silu(gate) * up,
                                p["w_down"].astype(cfg.dtype)), cfg)
+    if not cfg.shared_expert_gate:
+        return down
     open_ = jax.nn.sigmoid(jnp.einsum(
         "bse,e->bs", h, p["gate"].astype(cfg.dtype),
         preferred_element_type=jnp.float32))
@@ -608,75 +726,111 @@ def _residual(cfg: GPTConfig, x, branch):
     return x + branch
 
 
-def _block(cfg: GPTConfig, layer_params, x, positions):
-    """One decoder block: ``(x, aux)``, ``aux`` the expert layer's auxiliary
-    terms (``parallel/moe.py``) or None for a dense block."""
+def _attention_mixer(cfg: GPTConfig, spec: LayerSpec, lp, h, positions):
+    """Softmax attention on normed activations ``h``: the projections, the
+    norms of q and k, the rotary embedding where ``spec.rope`` says so, the
+    attention ``_attention`` picks under ``spec.window``, the output gate
+    and the output projection."""
+    q = jnp.einsum("bse,ehd->bshd", h, lp["wq"].astype(cfg.dtype))
+    k = jnp.einsum("bse,ehd->bshd", h, lp["wk"].astype(cfg.dtype))
+    v = jnp.einsum("bse,ehd->bshd", h, lp["wv"].astype(cfg.dtype))
+    if cfg.attention_gate:
+        q, gate = jnp.split(q, 2, axis=-1)
+    if cfg.qk_norm:
+        q = _projection_norm(q, lp["q_norm"], cfg)
+        k = _projection_norm(k, lp["k_norm"], cfg)
+    elif cfg.qk_head_norm:
+        q = _norm(cfg, q, lp["q_norm"])
+        k = _norm(cfg, k, lp["k_norm"])
+    if spec.rope:
+        q = rope(q, positions, cfg.rope_theta, cfg.rotary_dim)
+        k = rope(k, positions, cfg.rope_theta, cfg.rotary_dim)
+    if cfg.attention_multiplier is not None:
+        # Every attention here scales its logits by one over the
+        # square root of head_dim: the rest goes onto q.
+        q = q * (cfg.attention_multiplier
+                 * float(np.sqrt(cfg.head_dim)))
+    attn = _attention(cfg, q, k, v, spec.window)
+    if cfg.attention_gate:
+        attn = (attn.astype(jnp.float32) * jax.nn.sigmoid(
+            gate.astype(jnp.float32))).astype(cfg.dtype)
+    o = jnp.einsum("bshd,hde->bse", attn, lp["wo"].astype(cfg.dtype))
+    return _tp_psum(o, cfg)
+
+
+def _dense_ff(cfg: GPTConfig, spec: LayerSpec, lp, h):
+    up = jnp.einsum("bse,em->bsm", h, lp["w_up"].astype(cfg.dtype))
+    up = checkpoint_name(up, "ffn_pre_activation")
+    if spec.ff == "gated":
+        gate = jnp.einsum("bse,em->bsm", h, lp["w_gate"].astype(cfg.dtype))
+        up = jax.nn.silu(gate) * up
+    else:
+        up = jax.nn.gelu(up)
+    down = jnp.einsum("bsm,me->bse", up, lp["w_down"].astype(cfg.dtype))
+    return _tp_psum(down, cfg)
+
+
+def _expert_ff(cfg: GPTConfig, m, h):
+    """``(y, aux)`` of the expert block ``m`` on normed activations."""
+    from ..parallel.moe import moe_layer
+    out, aux = moe_layer(
+        h, m["router"], m["w_gate"], m["w_up"], m["w_down"],
+        top_k=cfg.experts_per_token, axis=cfg.ep_axis,
+        tp_axis=cfg.tp_axis, dtype=cfg.dtype,
+        first_expert=cfg.first_expert,
+        renormalize=cfg.renormalize_experts, score=cfg.router_score,
+        bias=m["router_bias"] if cfg.router_bias else None,
+        scale=cfg.route_scale, probe=cfg.router_probe)
+    if cfg.shared_expert_dim:
+        with jax.named_scope("shared"):
+            out = out + _shared_expert(cfg, m["shared"], h)
+    return out, aux
+
+
+_RECURRENT_MIXERS = {"ssm": _ssm_mixer, "gdn": _gdn_mixer}
+
+
+def _block(cfg: GPTConfig, spec: LayerSpec, layer_params, x, positions):
+    """One decoder block, as ``spec`` says it is: ``(x, aux)``, ``aux`` the
+    expert layer's auxiliary terms (``parallel/moe.py``) or None for a dense
+    block."""
     # The scopes sit inside the function ``jax.checkpoint`` wraps, so the
     # recomputed copy of a block carries them too (``forward`` has the rest).
+    # A window layer's mixer is under ``attn_window``, a full one's under
+    # ``attn``: a device trace tells their flash kernels apart by it.
     lp = layer_params
-    if "ssm" in lp:
-        with jax.named_scope("ssm"):
-            h = _norm(cfg, x, lp["ssm_norm"])
-            x = _residual(cfg, x, _ssm_mixer(cfg, lp["ssm"], h))
-    elif "gdn" in lp:
-        with jax.named_scope("gdn"):
-            h = _norm(cfg, x, lp["gdn_norm"])
-            x = _residual(cfg, x, _gdn_mixer(cfg, lp["gdn"], h))
-    else:
-        with jax.named_scope("attn"):
-            h = _norm(cfg, x, lp["attn_norm"])
-            q = jnp.einsum("bse,ehd->bshd", h, lp["wq"].astype(cfg.dtype))
-            k = jnp.einsum("bse,ehd->bshd", h, lp["wk"].astype(cfg.dtype))
-            v = jnp.einsum("bse,ehd->bshd", h, lp["wv"].astype(cfg.dtype))
-            if cfg.attention_gate:
-                q, gate = jnp.split(q, 2, axis=-1)
-            if cfg.qk_norm:
-                q = _projection_norm(q, lp["q_norm"], cfg)
-                k = _projection_norm(k, lp["k_norm"], cfg)
-            elif cfg.qk_head_norm:
-                q = _norm(cfg, q, lp["q_norm"])
-                k = _norm(cfg, k, lp["k_norm"])
-            if cfg.rope:
-                q = rope(q, positions, cfg.rope_theta, cfg.rotary_dim)
-                k = rope(k, positions, cfg.rope_theta, cfg.rotary_dim)
-            if cfg.attention_multiplier is not None:
-                # Every attention here scales its logits by one over the
-                # square root of head_dim: the rest goes onto q.
-                q = q * (cfg.attention_multiplier
-                         * float(np.sqrt(cfg.head_dim)))
-            attn = _attention(cfg, q, k, v)
-            if cfg.attention_gate:
-                attn = (attn.astype(jnp.float32) * jax.nn.sigmoid(
-                    gate.astype(jnp.float32))).astype(cfg.dtype)
-            o = jnp.einsum("bshd,hde->bse", attn, lp["wo"].astype(cfg.dtype))
-            x = _residual(cfg, x, _tp_psum(o, cfg))
 
-    if "moe" in lp:
+    def after(branch, key):
+        if not cfg.post_norm:
+            return branch
+        with jax.named_scope("post_norm"):
+            return _norm(cfg, branch, lp[key])
+
+    if spec.mixer == "attention":
+        with jax.named_scope("attn" if spec.window is None
+                             else "attn_window"):
+            h = _norm(cfg, x, lp["attn_norm"])
+            x = _residual(cfg, x, after(
+                _attention_mixer(cfg, spec, lp, h, positions),
+                "mixer_post_norm"))
+    else:
+        # A recurrent mixer's scope, its parameters' key and its norm's
+        # (``<mixer>_norm``) carry its name.
+        with jax.named_scope(spec.mixer):
+            h = _norm(cfg, x, lp[spec.mixer + "_norm"])
+            x = _residual(cfg, x, after(
+                _RECURRENT_MIXERS[spec.mixer](cfg, lp[spec.mixer], h),
+                "mixer_post_norm"))
+
+    if spec.ff == "experts":
         with jax.named_scope("moe"):
             h = _norm(cfg, x, lp["mlp_norm"])
-            from ..parallel.moe import moe_layer
-            m = lp["moe"]
-            out, aux = moe_layer(
-                h, m["router"], m["w_gate"], m["w_up"], m["w_down"],
-                top_k=cfg.experts_per_token, axis=cfg.ep_axis,
-                tp_axis=cfg.tp_axis, dtype=cfg.dtype,
-                first_expert=cfg.first_expert,
-                renormalize=cfg.renormalize_experts)
-            if "shared" in m:
-                with jax.named_scope("shared"):
-                    out = out + _shared_expert(cfg, m["shared"], h)
-            return _residual(cfg, x, out), aux
+            out, aux = _expert_ff(cfg, lp["moe"], h)
+            return _residual(cfg, x, after(out, "mlp_post_norm")), aux
     with jax.named_scope("mlp"):
         h = _norm(cfg, x, lp["mlp_norm"])
-        up = jnp.einsum("bse,em->bsm", h, lp["w_up"].astype(cfg.dtype))
-        up = checkpoint_name(up, "ffn_pre_activation")
-        if "w_gate" in lp:
-            gate = jnp.einsum("bse,em->bsm", h, lp["w_gate"].astype(cfg.dtype))
-            up = jax.nn.silu(gate) * up
-        else:
-            up = jax.nn.gelu(up)
-        down = jnp.einsum("bsm,me->bse", up, lp["w_down"].astype(cfg.dtype))
-        return _residual(cfg, x, _tp_psum(down, cfg)), None
+        return _residual(cfg, x, after(_dense_ff(cfg, spec, lp, h),
+                                       "mlp_post_norm")), None
 
 
 # What ``remat="full"`` keeps from a block's forward pass beside its input:
@@ -724,16 +878,17 @@ def _full_policy(prim, *avals, **params):
 
 
 def _block_fn(cfg: GPTConfig):
-    """The per-layer apply, optionally wrapped in ``jax.checkpoint``
-    (cfg is a frozen dataclass, so it rides static_argnums)."""
+    """The per-layer apply ``(cfg, spec, layer_params, x, positions)``,
+    optionally wrapped in ``jax.checkpoint`` (cfg and a layer's spec are
+    frozen dataclasses, so they ride static_argnums)."""
     if cfg.remat == "none":
         return _block
     if cfg.remat == "full":
-        return jax.checkpoint(_block, static_argnums=(0,),
+        return jax.checkpoint(_block, static_argnums=(0, 1),
                               policy=_full_policy)
     if cfg.remat == "dots":
         return jax.checkpoint(
-            _block, static_argnums=(0,),
+            _block, static_argnums=(0, 1),
             policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable)
     raise ValueError(f"unknown remat mode {cfg.remat!r} "
                      "(expected 'none', 'full' or 'dots')")
@@ -742,8 +897,10 @@ def _block_fn(cfg: GPTConfig):
 def _forward(params, tokens, positions, cfg: GPTConfig):
     """``(logits, [aux of each expert block])``."""
     # Scopes name the program's parts in every instruction's ``op_name``:
-    # ``embed``, ``layer<i>`` (with ``attn``, ``ssm`` or ``gdn`` and ``mlp``
-    # or ``moe`` inside, from ``_block``; ``ssm`` and ``gdn`` hold
+    # ``embed``, ``layer<i>`` (with ``attn``, ``attn_window`` (an attention
+    # layer with a window), ``ssm`` or ``gdn`` and ``mlp`` or ``moe``
+    # inside, from ``_block``, each with ``post_norm`` where the
+    # configuration norms a branch after it too; ``ssm`` and ``gdn`` hold
     # ``in_proj``, ``conv``, ``scan``, ``gate_norm``, ``out_proj``; ``moe``
     # holds ``router``, ``dispatch``, ``experts``, ``combine`` and
     # ``shared``), ``head``; ``loss_and_aux``
@@ -754,9 +911,10 @@ def _forward(params, tokens, positions, cfg: GPTConfig):
             x = x * cfg.embedding_multiplier
     block = _block_fn(cfg)
     auxes = []
-    for i, lp in enumerate(params["layers"]):
+    for i, (spec, lp) in enumerate(zip(cfg.plan, params["layers"],
+                                       strict=True)):
         with jax.named_scope(f"layer{i}"):
-            x, aux = block(cfg, lp, x, positions)
+            x, aux = block(cfg, spec, lp, x, positions)
         if aux is not None:
             auxes.append(aux)
     with jax.named_scope("head"):
@@ -799,7 +957,10 @@ def loss_and_aux(params, tokens, targets, positions, cfg: GPTConfig,
     ``ignore_index`` are masked out. Averages over sp so every rank returns the
     identical global-mean loss. ``aux`` holds ``cross_entropy`` and, with
     expert blocks, ``load_balance``, ``router_z`` (the sums over blocks) and
-    ``counts`` ``[blocks, experts]``, tokens per expert.
+    ``counts`` ``[blocks, experts]``, tokens per expert; under
+    ``cfg.router_probe`` also ``router_inputs`` ``[blocks, T, d]`` and
+    ``router_logits`` ``[blocks, T, experts]`` (float32), this rank's (an ep
+    group's) tokens as each block's router read them and what it gave.
     """
     logits, auxes = _forward(params, tokens, positions, cfg)
     with jax.named_scope("loss"):
@@ -827,6 +988,11 @@ def loss_and_aux(params, tokens, targets, positions, cfg: GPTConfig,
                "load_balance": sum(a["load_balance"] for a in auxes),
                "router_z": sum(a["router_z"] for a in auxes),
                "counts": jnp.stack([a["counts"] for a in auxes])}
+        if cfg.router_probe:
+            aux["router_inputs"] = jnp.stack(
+                [a["router_input"] for a in auxes])
+            aux["router_logits"] = jnp.stack(
+                [a["router_logits"] for a in auxes])
         # An ep group routes its tokens together, so its ranks hold the same
         # terms; sp ranks hold their own sequence shard's.
         for ax, all_counts in ((cfg.sp_axis, lax.psum),
@@ -847,3 +1013,32 @@ def data_specs(cfg: GPTConfig) -> Tuple[P, P]:
     dp = runtime.dp_axis()
     batch_axes = (dp, cfg.ep_axis) if cfg.ep_axis else dp
     return P(batch_axes, cfg.sp_axis), P(batch_axes, cfg.sp_axis)
+
+
+def trainable(params) -> dict:
+    """A tree of booleans like ``params``: False on the leaves that are
+    state and no parameter, the routers' selection biases. For
+    ``optax.masked(optimizer, gpt.trainable)``: the optimizer then neither
+    moves nor decays them (AdamW's decay would, at a zero gradient)."""
+    return jax.tree_util.tree_map_with_path(
+        lambda path, _: getattr(path[-1], "key", None) != "router_bias",
+        params)
+
+
+def update_router_bias(params, counts, rate: float) -> dict:
+    """``params`` with every expert block's selection bias moved towards an
+    even load, after an optimizer step: with ``c_e`` the tokens expert ``e``
+    of the block got in that step over all data-parallel ranks (``counts``
+    ``[expert blocks, experts]``, ``aux["counts"]`` summed over ranks), ``d_e
+    = rate * sign(mean(c) - c_e)`` and ``b <- b + d - mean(d)``. Outside the
+    loss: no gradient is involved."""
+    layers, block = [], 0
+    for lp in params["layers"]:
+        if "moe" in lp and "router_bias" in lp["moe"]:
+            c = counts[block].astype(jnp.float32)
+            d = rate * jnp.sign(jnp.mean(c) - c)
+            lp = {**lp, "moe": {**lp["moe"], "router_bias":
+                                lp["moe"]["router_bias"] + d - jnp.mean(d)}}
+        block += "moe" in lp
+        layers.append(lp)
+    return {**params, "layers": layers}

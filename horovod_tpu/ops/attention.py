@@ -15,16 +15,23 @@ import jax.numpy as jnp
 import numpy as np
 
 
-def default_attention(q, k, v, causal: bool = True):
+def default_attention(q, k, v, causal: bool = True, window=None):
     """Plain softmax attention. q/k/v: [B, S, H, D]. Computed in fp32 softmax.
+    ``window`` (causal only): a query sees itself and the ``window - 1`` keys
+    before it, ``0 <= i - j < window``.
 
     Materializes the ``[B, H, S, S]`` float32 logits: the reference the other
     paths are tested against, not a path to train long sequences on."""
     scale = 1.0 / np.sqrt(q.shape[-1])
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) * scale
+    if window is not None and not causal:
+        raise ValueError("a window is a causal band")
     if causal:
         qlen, klen = logits.shape[-2], logits.shape[-1]
         mask = jnp.tril(jnp.ones((qlen, klen), dtype=bool), klen - qlen)
+        if window is not None:
+            mask = mask & ~jnp.tril(jnp.ones((qlen, klen), dtype=bool),
+                                    klen - qlen - window)
         logits = jnp.where(mask, logits, jnp.finfo(jnp.float32).min)
     probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)

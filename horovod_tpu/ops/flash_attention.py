@@ -1,4 +1,5 @@
-"""Fused (flash) causal attention — Pallas TPU kernels.
+"""Fused (flash) attention, causal, banded or bidirectional — Pallas TPU
+kernels.
 
 The reference is a collective-communication library and ships no attention
 kernels; this is a TPU-first extension for the GPT / long-context path
@@ -37,12 +38,20 @@ Design notes (TPU):
   ``[block_q, 128]``, and the output accumulator) carried across k-steps
   in VMEM scratch and written on the final visit — VMEM use is
   O(block x D + block_q x block_k) regardless of sequence length.
-* Causal mode skips the tiles wholly above the diagonal (``pl.when``: no
-  FLOPs) and masks every tile it computes (masking only those the diagonal
+* Which pairs are kept is one description, :class:`Mask`: causal, a causal
+  band (``window``: a query sees itself and the ``window - 1`` keys before
+  it) or none. Causal mode skips the tiles wholly above the diagonal, a
+  window besides those wholly below the band (``pl.when``: no FLOPs), and
+  every tile computed is masked (masking only those the diagonal
   crosses measured no cheaper). A skipped step fetches nothing either: the
-  index maps of the streamed operands clamp to the last (forward, dQ) or
-  first (dKdV) kept tile, so a skipped step names the block already held
-  and the pipeline issues no DMA. Tail padding is free (a real query row
+  index maps of the streamed operands clamp to the nearest kept tile of the
+  row (forward, dQ) or column (dKdV), so a skipped step names a block
+  already held and the pipeline issues no DMA. A query row whose band has
+  not begun in the first tile its block visits sees a tile of masked
+  scores there; the running maximum's next rise wipes what that added (the
+  row's own diagonal tile always follows). The grid is the causal one: a
+  skipped step still costs its fraction of a microsecond. Tail padding is
+  free (a real query row
   never attends a key beyond itself). Bidirectional mode (``causal=False``,
   encoder models) computes every block and masks the padded key columns,
   where there are any. Any sequence length works in both.
@@ -61,7 +70,9 @@ Design notes (TPU):
 
 from __future__ import annotations
 
+import dataclasses
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -162,46 +173,129 @@ def _lanes(x, n: int):
     return jnp.broadcast_to(x[:, :1], (x.shape[0], n))
 
 
-def _mask_tile(s, q_start, k_start, causal: bool, kv_len: int,
+@dataclasses.dataclass(frozen=True)
+class Mask:
+    """Which (query, key) pairs attention keeps: the one description the
+    three kernels read, pair by pair (``keep``), tile by tile (``tile_kept``)
+    and as the range of tiles a row or column of the grid visits
+    (``k_blocks``, ``q_blocks``: what the index maps clamp to, so that a
+    skipped step names a block already held and nothing is fetched for it).
+    A new kind of mask (same-document: ROADMAP Reach 1) is a field here and
+    a clause in each of these, not a flag threaded beside ``causal``.
+
+    ``causal``: ``k <= q``. ``window`` (causal only): besides, ``q - k <
+    window``, a query sees itself and the ``window - 1`` keys before it.
+    Static: it rides the kernels' partial arguments and the custom VJP's
+    non-differentiable ones."""
+    causal: bool = True
+    window: Optional[int] = None
+
+    def __post_init__(self):
+        if self.window is not None and (not self.causal or self.window < 1):
+            raise ValueError("a window is a causal band of at least one "
+                             f"key, got {self!r}")
+
+    @property
+    def name(self) -> str:
+        return "window" if self.window is not None \
+            else "causal" if self.causal else "full"
+
+    def needs_masking(self, kv_len: int, s_pad: int) -> bool:
+        """Every kept tile is masked alike (masking only the tiles the
+        diagonal crosses measured no cheaper on the v5e: PERF.md, Findings,
+        PR 24); a bidirectional call without padding has nothing to mask."""
+        return self.causal or kv_len != s_pad
+
+    def keep(self, q_pos, k_pos, kv_len: int):
+        """The pairs kept, elementwise. Causal masks also cover the tail
+        padding for free (a real query row never attends a key at or beyond
+        its own position's pad); a bidirectional mask must shut the padded
+        key columns out explicitly (``k_pos >= kv_len``), or every query
+        would attend the zero-filled tail."""
+        if not self.causal:
+            return k_pos < kv_len
+        keep = q_pos >= k_pos
+        if self.window is not None:
+            keep = keep & (q_pos - k_pos < self.window)
+        return keep
+
+    def tile_kept(self, qi, kj, block_q: int, block_k: int):
+        """Whether the tile at block indices ``(qi, kj)`` holds any kept
+        pair."""
+        if not self.causal:
+            # Trivially-true predicate, NOT an unguarded body: interpret
+            # mode's vma tracing (CPU-mesh shard_map) only standardizes the
+            # block-fetch slice's varying axes along the pl.when path — an
+            # unguarded body trips "dynamic_slice requires varying manual
+            # axes to match". Compiled Mosaic folds the constant predicate.
+            return kj >= 0
+        # The tile's last query row reaches its first key.
+        kept = (qi + 1) * block_q - 1 >= kj * block_k
+        if self.window is not None:
+            # Its first query row still sees its last key.
+            kept = kept & ((kj + 1) * block_k + self.window - 2
+                           >= qi * block_q)
+        return kept
+
+    def k_blocks(self, i, block_q: int, block_k: int):
+        """``(first, last)`` k block that query block ``i`` attends, either
+        None where the grid's own end is the bound."""
+        if not self.causal:
+            return None, None
+        first = None if self.window is None else _div(
+            jnp.maximum(i * block_q - (self.window - 1), 0), block_k)
+        return first, _div((i + 1) * block_q - 1, block_k)
+
+    def q_blocks(self, j, block_q: int, block_k: int):
+        """``(first, last)`` q block that attends k block ``j``."""
+        if not self.causal:
+            return None, None
+        last = None if self.window is None else _div(
+            (j + 1) * block_k + self.window - 2, block_q)
+        return _div(j * block_k, block_q), last
+
+    def tiles(self, n_q: int, n_k: int, block_q: int, block_k: int) -> dict:
+        """How a ``n_q x n_k`` grid's tiles fall: ``kept`` (computed),
+        ``skipped`` (wholly above the diagonal) and ``skipped_band``
+        (wholly below the band: what a window saves of the causal
+        triangle's). Python integers, for the trace-time counter."""
+        out = {"kept": 0, "skipped": 0, "skipped_band": 0}
+        for i in range(n_q):
+            for j in range(n_k):
+                if self.tile_kept(i, j, block_q, block_k):
+                    out["kept"] += 1
+                elif j * block_k > (i + 1) * block_q - 1:
+                    out["skipped"] += 1
+                else:
+                    out["skipped_band"] += 1
+        return out
+
+
+def _clamp(index, first, last):
+    """``index`` held inside ``[first, last]`` (either may be None): what an
+    index map names on a skipped step."""
+    if last is not None:
+        index = jnp.minimum(index, last)
+    if first is not None:
+        index = jnp.maximum(index, first)
+    return index
+
+
+def _mask_tile(s, q_start, k_start, mask: Mask, kv_len: int,
                transposed: bool = False):
     """Mask a score tile whose first query row and key column are at global
     positions ``q_start``/``k_start``; ``transposed`` tiles are [keys,
-    queries]. Causal mode masks above the diagonal — which also covers the
-    tail padding for free (a real query row never attends a key at or
-    beyond its own position's pad). Non-causal mode must mask the padded
-    key columns explicitly (``k_pos >= kv_len``), or every query would
-    attend the zero-filled tail."""
+    queries]."""
     q_dim, k_dim = (1, 0) if transposed else (0, 1)
     k_pos = k_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, k_dim)
-    if causal:
-        q_pos = q_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, q_dim)
-        return jnp.where(q_pos >= k_pos, s, _NEG_INF)
-    return jnp.where(k_pos < kv_len, s, _NEG_INF)
-
-
-def _needs_mask(causal: bool, kv_len: int, s_pad: int) -> bool:
-    """Every kept tile is masked alike (masking only the tiles the diagonal
-    crosses measured no cheaper on the v5e: PERF.md, Findings, PR 24); a
-    bidirectional call without padding has nothing to mask."""
-    return causal or kv_len != s_pad
-
-
-def _kept(qi, kj, block_q: int, block_k: int, causal: bool):
-    """Whether the tile at block indices ``(qi, kj)`` holds any kept pair."""
-    if causal:
-        # The tile's last query row reaches its first key.
-        return (qi + 1) * block_q - 1 >= kj * block_k
-    # Trivially-true predicate, NOT an unguarded body: interpret mode's vma
-    # tracing (CPU-mesh shard_map) only standardizes the block-fetch
-    # slice's varying axes along the pl.when path — an unguarded body trips
-    # "dynamic_slice requires varying manual axes to match". Compiled
-    # Mosaic folds the constant predicate.
-    return kj >= 0
+    q_pos = q_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, q_dim) \
+        if mask.causal else None
+    return jnp.where(mask.keep(q_pos, k_pos, kv_len), s, _NEG_INF)
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
                 *, sm_scale: float, block_q: int, block_k: int,
-                n_k_blocks: int, causal: bool, kv_len: int, mask: bool):
+                n_k_blocks: int, mask: Mask, kv_len: int, masked: bool):
     qi = pl.program_id(1)
     kj = pl.program_id(2)
     d = acc_scr.shape[-1]
@@ -217,8 +311,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
         s = jax.lax.dot_general(q_ref[0], k_ref[0], _NT,
                                 preferred_element_type=jnp.float32)
         s = s * sm_scale                                 # [BQ, BK] float32
-        if mask:
-            s = _mask_tile(s, qi * block_q, kj * block_k, causal, kv_len)
+        if masked:
+            s = _mask_tile(s, qi * block_q, kj * block_k, mask, kv_len)
         m_prev = m_scr[:]                                # [BQ, 128]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - _lanes(m_new, block_k))
@@ -228,7 +322,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
         acc_scr[:] = acc_scr[:] * _lanes(alpha, d) + jnp.dot(
             p.astype(v.dtype), v, preferred_element_type=jnp.float32)
 
-    pl.when(_kept(qi, kj, block_q, block_k, causal))(_step)
+    pl.when(mask.tile_kept(qi, kj, block_q, block_k))(_step)
 
     @pl.when(kj == n_k_blocks - 1)
     def _finish():
@@ -246,7 +340,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
 def _dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                  dk_ref, dv_ref, dk_scr, dv_scr, *, sm_scale: float,
                  block_q: int, block_k: int, n_q_blocks: int, n_steps: int,
-                 causal: bool, kv_len: int, mask: bool):
+                 mask: Mask, kv_len: int, masked: bool):
     kj = pl.program_id(1)
     step = pl.program_id(2)        # (query head of the group, q block)
     qi = _rem(step, n_q_blocks)
@@ -264,8 +358,8 @@ def _dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         st = jax.lax.dot_general(k_ref[0], q, _NT,
                                  preferred_element_type=jnp.float32)
         st = st * sm_scale
-        if mask:
-            st = _mask_tile(st, qi * block_q, kj * block_k, causal, kv_len,
+        if masked:
+            st = _mask_tile(st, qi * block_q, kj * block_k, mask, kv_len,
                             transposed=True)
         pt = jnp.exp(st - lse_ref[0])
         dv_scr[:] = dv_scr[:] + jnp.dot(
@@ -276,7 +370,7 @@ def _dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk_scr[:] = dk_scr[:] + jnp.dot(
             dst.astype(q.dtype), q, preferred_element_type=jnp.float32)
 
-    pl.when(_kept(qi, kj, block_q, block_k, causal))(_step)
+    pl.when(mask.tile_kept(qi, kj, block_q, block_k))(_step)
 
     @pl.when(step == n_steps - 1)
     def _finish():
@@ -286,7 +380,7 @@ def _dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
                dq_scr, *, sm_scale: float, block_q: int, block_k: int,
-               n_k_blocks: int, causal: bool, kv_len: int, mask: bool):
+               n_k_blocks: int, mask: Mask, kv_len: int, masked: bool):
     qi = pl.program_id(1)
     kj = pl.program_id(2)
 
@@ -299,8 +393,8 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
         s = jax.lax.dot_general(q_ref[0], k, _NT,
                                 preferred_element_type=jnp.float32)
         s = s * sm_scale
-        if mask:
-            s = _mask_tile(s, qi * block_q, kj * block_k, causal, kv_len)
+        if masked:
+            s = _mask_tile(s, qi * block_q, kj * block_k, mask, kv_len)
         p = jnp.exp(s - _lanes(lse_ref[0], block_k))
         dp = jax.lax.dot_general(do_ref[0], v_ref[0], _NT,
                                  preferred_element_type=jnp.float32)
@@ -308,7 +402,7 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
         dq_scr[:] = dq_scr[:] + jnp.dot(
             ds.astype(k.dtype), k, preferred_element_type=jnp.float32)
 
-    pl.when(_kept(qi, kj, block_q, block_k, causal))(_step)
+    pl.when(mask.tile_kept(qi, kj, block_q, block_k))(_step)
 
     @pl.when(kj == n_k_blocks - 1)
     def _finish():
@@ -322,56 +416,50 @@ def _pad_seq(x):
     return x
 
 
-def _blocks_for(kernel, q, k, causal, forced):
+def _blocks_for(kernel, q, k, mask: Mask, forced):
     """The call's tile, forced or from the table; and, trace time only, the
-    record of it behind ``hvd.metrics()``."""
+    record of it and of the tiles its grid keeps and skips behind
+    ``hvd.metrics()``."""
     from .. import runtime
     bq, bk = forced or block_sizes(kernel, q.shape[1], q.shape[2], q.dtype,
-                                   causal)
+                                   mask.causal)
     recorder = runtime.recorder()
     if recorder is not None:
         recorder.note_traced(
             "hvdtpu_spmd_flash_kernel_traces_total", kernel=kernel,
             block_q=bq, block_k=bk, operand_dtype=jnp.dtype(q.dtype).name,
             kv_group=q.shape[0] // k.shape[0])
+        for tiles, n in mask.tiles(q.shape[1] // bq, q.shape[1] // bk,
+                                   bq, bk).items():
+            recorder.note_traced(
+                "hvdtpu_spmd_flash_tiles_total", n, kernel=kernel,
+                mask=mask.name, tiles=tiles, seq=q.shape[1])
     return bq, bk
 
 
-def _last_kept_k(i, block_q: int, block_k: int):
-    """Causal: the last k block query block ``i`` attends."""
-    return _div((i + 1) * block_q - 1, block_k)
-
-
-def _first_kept_q(j, block_q: int, block_k: int):
-    """Causal: the first q block that attends k block ``j``."""
-    return _div(j * block_k, block_q)
-
-
-def _kv_map(group: int, block_q: int, block_k: int, causal: bool):
+def _kv_map(group: int, block_q: int, block_k: int, mask: Mask):
     """Index map of K and V on a ``(bh, q_block, k_block)`` grid: the K/V
-    head of query head ``b``; a skipped causal step re-names the last kept
+    head of query head ``b``; a skipped step re-names the nearest kept
     block, so the pipeline issues no DMA for it."""
     def kv_map(b, i, j):
-        if causal:
-            j = jnp.minimum(j, _last_kept_k(i, block_q, block_k))
+        j = _clamp(j, *mask.k_blocks(i, block_q, block_k))
         return _div(b, group), j, 0
     return kv_map
 
 
-def _fwd_call(q, k, v, sm_scale, causal, kv_len, forced=None):
+def _fwd_call(q, k, v, sm_scale, mask, kv_len, forced=None):
     """q: [B*H, S, D], k/v: [B*Hkv, S, D] (S already padded; ``kv_len`` is
-    the real key count before padding). Returns (o, lse), lse
-    lane-replicated [B*H, S, 128]."""
+    the real key count before padding; ``mask`` a :class:`Mask`). Returns (o, lse), lse lane-replicated [B*H, S, 128]."""
     bh, s, d = q.shape
     group = bh // k.shape[0]
-    bq, bk = _blocks_for(KERNEL_FWD, q, k, causal, forced)
+    bq, bk = _blocks_for(KERNEL_FWD, q, k, mask, forced)
     n_q, n_k = s // bq, s // bk
 
-    kv_map = _kv_map(group, bq, bk, causal)
+    kv_map = _kv_map(group, bq, bk, mask)
     kernel = functools.partial(_fwd_kernel, sm_scale=sm_scale, block_q=bq,
-                               block_k=bk, n_k_blocks=n_k, causal=causal,
+                               block_k=bk, n_k_blocks=n_k, mask=mask,
                                kv_len=kv_len,
-                               mask=_needs_mask(causal, kv_len, s))
+                               masked=mask.needs_masking(kv_len, s))
     return pl.pallas_call(
         kernel,
         grid=(bh, n_q, n_k),
@@ -402,21 +490,19 @@ def _fwd_call(q, k, v, sm_scale, causal, kv_len, forced=None):
     )(q, k, v)
 
 
-def _dkdv_call(q, k, v, do, lse, delta, sm_scale, causal, kv_len,
+def _dkdv_call(q, k, v, do, lse, delta, sm_scale, mask, kv_len,
                forced=None):
     """dK, dV at the K/V head count. ``lse``/``delta``: [B*H, 1, S] rows."""
     bh, s, d = q.shape
     bkv = k.shape[0]
     group = bh // bkv
-    bq, bk = _blocks_for(KERNEL_DKDV, q, k, causal, forced)
+    bq, bk = _blocks_for(KERNEL_DKDV, q, k, mask, forced)
     n_q, n_k = s // bq, s // bk
 
     def q_block(b, j, t):
         # Step t of a k block: query head t // n_q of the group, q block
-        # t % n_q; a skipped (earlier) q block re-names the first kept one.
-        i = _rem(t, n_q)
-        if causal:
-            i = jnp.maximum(i, _first_kept_q(j, bq, bk))
+        # t % n_q; a skipped q block re-names the nearest kept one.
+        i = _clamp(_rem(t, n_q), *mask.q_blocks(j, bq, bk))
         return b * group + _div(t, n_q), i
 
     def q_map(b, j, t):
@@ -428,9 +514,9 @@ def _dkdv_call(q, k, v, do, lse, delta, sm_scale, causal, kv_len,
 
     kernel = functools.partial(_dkdv_kernel, sm_scale=sm_scale, block_q=bq,
                                block_k=bk, n_q_blocks=n_q,
-                               n_steps=group * n_q, causal=causal,
+                               n_steps=group * n_q, mask=mask,
                                kv_len=kv_len,
-                               mask=_needs_mask(causal, kv_len, s))
+                               masked=mask.needs_masking(kv_len, s))
     vma = _out_vma(q, k, v, do)
     return pl.pallas_call(
         kernel,
@@ -461,22 +547,22 @@ def _dkdv_call(q, k, v, do, lse, delta, sm_scale, causal, kv_len,
     )(q, k, v, do, lse, delta)
 
 
-def _dq_call(q, k, v, do, lse, delta, sm_scale, causal, kv_len, forced=None):
+def _dq_call(q, k, v, do, lse, delta, sm_scale, mask, kv_len, forced=None):
     """dQ. ``lse``/``delta``: lane-replicated [B*H, S, 128]."""
     bh, s, d = q.shape
     group = bh // k.shape[0]
-    bq, bk = _blocks_for(KERNEL_DQ, q, k, causal, forced)
+    bq, bk = _blocks_for(KERNEL_DQ, q, k, mask, forced)
     n_q, n_k = s // bq, s // bk
 
-    kv_map = _kv_map(group, bq, bk, causal)
+    kv_map = _kv_map(group, bq, bk, mask)
 
     def q_map(b, i, j):
         return b, i, 0
 
     kernel = functools.partial(_dq_kernel, sm_scale=sm_scale, block_q=bq,
-                               block_k=bk, n_k_blocks=n_k, causal=causal,
+                               block_k=bk, n_k_blocks=n_k, mask=mask,
                                kv_len=kv_len,
-                               mask=_needs_mask(causal, kv_len, s))
+                               masked=mask.needs_masking(kv_len, s))
     return pl.pallas_call(
         kernel,
         grid=(bh, n_q, n_k),
@@ -499,13 +585,13 @@ def _dq_call(q, k, v, do, lse, delta, sm_scale, causal, kv_len, forced=None):
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash_bhsd(q, k, v, sm_scale, causal, kv_len, forced):
-    o, _ = _fwd_call(q, k, v, sm_scale, causal, kv_len, forced)
+def _flash_bhsd(q, k, v, sm_scale, mask, kv_len, forced):
+    o, _ = _fwd_call(q, k, v, sm_scale, mask, kv_len, forced)
     return o
 
 
-def _flash_bhsd_fwd(q, k, v, sm_scale, causal, kv_len, forced):
-    o, lse = _fwd_call(q, k, v, sm_scale, causal, kv_len, forced)
+def _flash_bhsd_fwd(q, k, v, sm_scale, mask, kv_len, forced):
+    o, lse = _fwd_call(q, k, v, sm_scale, mask, kv_len, forced)
     # Named, both, so that a ``jax.checkpoint`` around the caller can keep
     # them (``models/gpt.py::SAVED_NAMES``) and not run this kernel a second
     # time for the backward pass; outside a checkpoint a name is an identity.
@@ -516,7 +602,7 @@ def _flash_bhsd_fwd(q, k, v, sm_scale, causal, kv_len, forced):
     return o, (q, k, v, o, checkpoint_name(lse[..., 0], "flash_lse"))
 
 
-def _flash_bhsd_bwd(sm_scale, causal, kv_len, forced, res, do):
+def _flash_bhsd_bwd(sm_scale, mask, kv_len, forced, res, do):
     q, k, v, o, lse = res
     bh, s, _ = q.shape
     # delta_i = rowsum(dO_i * O_i) — tiny elementwise pass, XLA fuses it.
@@ -526,18 +612,19 @@ def _flash_bhsd_bwd(sm_scale, causal, kv_len, forced, res, do):
     # whose sublane dim is 1 — see _fwd_kernel), transiently: the residual
     # holds one float a row.
     dk, dv = _dkdv_call(q, k, v, do, lse[:, None, :], delta[:, None, :],
-                        sm_scale, causal, kv_len, forced)
+                        sm_scale, mask, kv_len, forced)
     dq = _dq_call(q, k, v, do,
                   jnp.broadcast_to(lse[..., None], (bh, s, _LANES)),
                   jnp.broadcast_to(delta[..., None], (bh, s, _LANES)),
-                  sm_scale, causal, kv_len, forced)
+                  sm_scale, mask, kv_len, forced)
     return dq, dk, dv
 
 
 _flash_bhsd.defvjp(_flash_bhsd_fwd, _flash_bhsd_bwd)
 
 
-def flash_attention(q, k, v, causal: bool = True, *, _blocks=None):
+def flash_attention(q, k, v, causal: bool = True, *,
+                    window: Optional[int] = None, _blocks=None):
     """Fused attention. q: ``[B, S, H, D]`` (the layout the GPT blocks
     use); k/v: ``[B, S, Hkv, D]`` where ``Hkv`` may divide ``H``
     (grouped-query attention: the kernels read K/V head ``h // group`` for
@@ -546,10 +633,15 @@ def flash_attention(q, k, v, causal: bool = True, *, _blocks=None):
 
     ``causal=True`` (decoder) skips the tiles above the diagonal;
     ``causal=False`` (encoder/bidirectional) computes all blocks with the
-    tail padding masked out of the key axis. Tile sizes and the MXU
-    operands' dtype follow the call's shapes and dtype (``block_sizes``);
-    ``_blocks=(block_q, block_k)`` forces one tile on all three kernels,
-    for the tests and the sweep.
+    tail padding masked out of the key axis. ``window`` (static, causal
+    only): a query sees itself and the ``window - 1`` keys before it, and
+    the tiles wholly below that band are skipped as those above the
+    diagonal are, neither multiplied nor fetched, in all three kernels; a
+    window of the sequence's length or more is the causal program, unchanged
+    (:class:`Mask`). Tile sizes and the MXU operands' dtype follow the
+    call's shapes and dtype (``block_sizes``); ``_blocks=(block_q,
+    block_k)`` forces one tile on all three kernels, for the tests and the
+    sweep.
     """
     b, s, h, d = q.shape
     if k.shape[2] != v.shape[2] or h % k.shape[2]:
@@ -562,10 +654,12 @@ def flash_attention(q, k, v, causal: bool = True, *, _blocks=None):
             raise ValueError(f"blocks {_blocks} must be multiples of {_PAD} "
                              f"that divide the padded length {s_pad}")
     sm_scale = 1.0 / float(np.sqrt(d))
+    mask = Mask(bool(causal),
+                None if window is None or window >= s else int(window))
 
     def to_bhsd(x):
         return _pad_seq(x.transpose(0, 2, 1, 3).reshape(-1, s, d))
 
-    o = _flash_bhsd(to_bhsd(q), to_bhsd(k), to_bhsd(v), sm_scale,
-                    bool(causal), s, _blocks)
+    o = _flash_bhsd(to_bhsd(q), to_bhsd(k), to_bhsd(v), sm_scale, mask, s,
+                    _blocks)
     return o[:, :s, :].reshape(b, h, s, d).transpose(0, 2, 1, 3)

@@ -159,7 +159,7 @@ def _always(body):
     """Run ``body`` under a predicate that always holds, NOT unguarded:
     interpret mode inside a ``shard_map`` matches the varying axes of a
     block's fetch only along a ``pl.when`` path
-    (``flash_attention._kept``); compiled, Mosaic folds the constant."""
+    (``flash_attention.Mask.tile_kept``); compiled, Mosaic folds the constant."""
     pl.when(pl.program_id(2) >= 0)(body)
 
 

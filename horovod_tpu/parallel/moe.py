@@ -12,7 +12,17 @@ tokens ``h`` ``[T, d]``, router ``W_r`` ``[d, E]`` and experts ``W_gate,e``,
 
 The weights ``p_{t,e}`` are not renormalised over ``S_t`` unless
 ``renormalize`` asks for ``p_{t,e} / sum_{e' in S_t} p_{t,e'}`` (the
-gradient flows through the sum). **No token is
+gradient flows through the sum). With ``score="sigmoid"`` the scores are
+independent gates and the choice may lean on a bias that is no parameter:
+
+    p = sigmoid(r)             S_t = the k largest of p_t + b
+    w_{t,e} = scale * p_{t,e} / (sum_{e' in S_t} p_{t,e'} + 1e-20)
+
+(``bias`` ``b`` ``[E]`` enters the choice alone, so no gradient reaches it:
+the caller moves it from the counts the layer returns, outside the loss,
+``models/gpt.py::update_router_bias``; ``scale`` multiplies the weights
+under either score; the division is ``renormalize``'s.) Everything after
+the choice of experts is one code path for both. **No token is
 dropped, whatever the routing**, and every shape is static: the ``T k``
 token-expert pairs are sorted by expert, the tokens' rows gathered once in
 that order, the three expert matrices applied as grouped matmuls over the
@@ -376,7 +386,9 @@ _held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
 def moe_layer(x, router_w, w_gate, w_up, w_down, top_k: int,
               axis: Optional[str] = None, tp_axis: Optional[str] = None,
               dtype: Any = jnp.bfloat16, first_expert: int = 0,
-              renormalize: bool = False) -> Tuple[jnp.ndarray, dict]:
+              renormalize: bool = False, score: str = "softmax",
+              bias=None, scale: float = 1.0,
+              probe: bool = False) -> Tuple[jnp.ndarray, dict]:
     """Dropless top-``top_k`` expert layer (module docstring has the math).
 
     Args:
@@ -391,14 +403,26 @@ def moe_layer(x, router_w, w_gate, w_up, w_down, top_k: int,
       first_expert: with no ``axis`` bound and ``experts_local <
         num_experts``, the first expert held (static).
       renormalize: divide a token's ``top_k`` weights by their sum.
+      score: ``"softmax"`` over the router's outputs or ``"sigmoid"`` of
+        each.
+      bias: ``[num_experts]`` float32 added to the scores for the choice of
+        experts alone (None: none); no gradient reaches it.
+      scale: a constant on a token's weights.
+      probe: ``aux`` also holds what the router read and gave, for a check
+        that holds its product to a reference fed the same activations.
 
     Returns ``(y, aux)``, ``y`` shaped and typed (``dtype``) as the
     activations, and over the tokens routed together (this rank's, or the ep
     group's): ``aux["load_balance"]`` = ``E sum_e f_e P_e`` with ``f_e`` the
     share of tokens whose ``S_t`` holds ``e`` and ``P_e = mean_t p_{t,e}``;
     ``aux["router_z"]`` = ``mean_t logsumexp(r_t)^2``; ``aux["counts"]``
-    ``[E]`` int32, tokens per expert.
+    ``[E]`` int32, tokens per expert; with ``probe``, ``aux["router_input"]``
+    ``[T, d]`` float32 (the router's product's own operand) and
+    ``aux["router_logits"]`` ``[T, E]`` float32 (``r``).
     """
+    if score not in ("softmax", "sigmoid"):
+        raise ValueError(f"expert layer: score {score!r} is neither "
+                         "'softmax' nor 'sigmoid'")
     d = x.shape[-1]
     ep = _axis_bound(axis)
     experts_local = w_up.shape[0]
@@ -428,15 +452,35 @@ def moe_layer(x, router_w, w_gate, w_up, w_down, top_k: int,
         recorder.note_traced(
             "hvdtpu_spmd_moe_layer_traces_total", experts=num_experts,
             top_k=top_k, ep=_axis_size(axis), grouped_matmul=GROUPED_MATMUL,
-            held=experts_local, rows=window_rows)
+            held=experts_local, rows=window_rows, score=score,
+            bias=int(bias is not None))
 
     with jax.named_scope("router"):
-        logits = jnp.dot(xt.astype(jnp.float32), router_w.astype(jnp.float32),
+        # The product's own operand: a probe hands out this value and not
+        # ``xt`` (the compiler may feed the product the activations before
+        # their rounding to ``xt``'s type; then it feeds the probe the same).
+        router_in = xt.astype(jnp.float32)
+        logits = jnp.dot(router_in, router_w.astype(jnp.float32),
                          precision=lax.Precision.HIGHEST)            # [T, E]
-        probs = jax.nn.softmax(logits, axis=-1)
-        top_p, top_e = lax.top_k(probs, top_k)                       # [T, k]
+        probs = jax.nn.softmax(logits, axis=-1) if score == "softmax" \
+            else jax.nn.sigmoid(logits)
+        if bias is None:
+            top_p, top_e = lax.top_k(probs, top_k)                   # [T, k]
+        else:
+            # The bias leans the choice and is in nothing else: the weights
+            # are the scores' own, picked out by a one-hot product (exact;
+            # XLA's gather of [T, k] from [T, E] and its scatter back took
+            # 1.4 ms a layer a pass on the v5e, my chip run, PR 35).
+            top_e = lax.top_k(probs + lax.stop_gradient(bias), top_k)[1]
+            top_p = jnp.sum(jax.nn.one_hot(top_e, num_experts,
+                                           dtype=probs.dtype)
+                            * probs[:, None, :], axis=-1)
         if renormalize:
-            top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+            total = jnp.sum(top_p, axis=-1, keepdims=True)
+            # Sigmoid scores can all be nothing; a softmax's k largest not.
+            top_p = top_p / (total + 1e-20 if score == "sigmoid" else total)
+        if scale != 1.0:
+            top_p = top_p * scale
         counts = jnp.sum(jax.nn.one_hot(top_e, num_experts, dtype=jnp.int32),
                          axis=(0, 1))                                # [E]
         load_balance = num_experts * jnp.sum(
@@ -491,5 +535,8 @@ def moe_layer(x, router_w, w_gate, w_up, w_down, top_k: int,
         if ep:
             y = lax.psum_scatter(y, axis, scatter_dimension=0, tiled=True)
         y = y.astype(dtype)
-    return y.reshape(x.shape), {"load_balance": load_balance,
-                                "router_z": router_z, "counts": counts}
+    aux = {"load_balance": load_balance, "router_z": router_z,
+           "counts": counts}
+    if probe:
+        aux.update(router_input=router_in, router_logits=logits)
+    return y.reshape(x.shape), aux

@@ -85,14 +85,23 @@ _TRACED = {
         "the call got: block sizes, the MXU operands' dtype, query heads per "
         "K/V head.",
         ("kernel", "block_q", "block_k", "operand_dtype", "kv_group")),
+    "hvdtpu_spmd_flash_tiles_total": (
+        "Tiles of the grids of the flash attention kernels JAX traced, by "
+        "kernel, the mask's kind (causal, window, full), the padded length "
+        "and what became of the tile: kept (computed), skipped (wholly "
+        "above the diagonal) or skipped_band (wholly below a window's band). "
+        "One grid of (q blocks x k blocks) a trace; every head walks it.",
+        ("kernel", "mask", "tiles", "seq")),
     "hvdtpu_spmd_moe_layer_traces_total": (
         "Times JAX traced an expert layer (the recomputed copy of a block "
         "counts again), by the experts it routes over, the experts per "
         "token, the size of the expert-parallel axis, the grouped matmul it "
-        "uses, the experts this rank holds and the token-expert rows it "
+        "uses, the experts this rank holds, the token-expert rows it "
         "gathers and multiplies at a time (all of them, or a share's "
-        "window).",
-        ("experts", "top_k", "ep", "grouped_matmul", "held", "rows")),
+        "window), the router's score function and whether a selection "
+        "bias leans its choice.",
+        ("experts", "top_k", "ep", "grouped_matmul", "held", "rows",
+         "score", "bias")),
     "hvdtpu_spmd_ssm_layer_traces_total": (
         "Times JAX traced a chunked state-space scan (the recomputed copy of "
         "a block counts again), by its heads, their size, the state's size, "

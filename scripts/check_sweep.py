@@ -9,11 +9,21 @@ what else the job kept of the check: tokens per expert, choices moved); the
 last line gives each row's largest error. This is where the tolerances at the
 top of a ``benchmarks/jobs/*.py`` come from. Lines are appended to
 ``chiprun_out/check_sweep.jsonl``.
+
+``--variant NAME`` runs the same check on a program with one of its
+mechanisms left out, on the shipped program's parameters (``VARIANTS``: the
+band ignored, the selection bias out
+of the choice or never updated, the weights' constant, the norms after the branches or the
+shared expert dropped, the router's product in one bfloat16 pass), against
+the untouched reference: what a job's tolerances must catch. A variant
+changes the job's ``GPTConfig`` after the job is built, before its step is
+traced; a job without the field it needs fails by name.
 """
 
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import importlib
 import json
 import os
@@ -23,12 +33,50 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
 
+def _router_in_bfloat16():
+    """The expert layer with the router's float32 product at the backend's
+    default precision (on the TPU one bfloat16 pass of the MXU) where the
+    program asks for the highest: ``parallel/moe.py`` sees a ``jax.numpy``
+    whose ``dot`` takes no notice of ``precision``."""
+    import jax.numpy as jnp
+    from jax import lax
+    from horovod_tpu.parallel import moe
+
+    class OnePass:
+        def __getattr__(self, name):
+            return getattr(jnp, name)
+
+        @staticmethod
+        def dot(a, b, precision=None, **kw):
+            return jnp.dot(a, b, precision=lax.Precision.DEFAULT, **kw)
+
+    moe.jnp = OnePass()
+
+
+# name -> what it does to a job already built (its step not yet traced)
+VARIANTS = {
+    "full_causal": lambda job: _replace(job, layers=tuple(
+        dataclasses.replace(spec, window=None) for spec in job.cfg.plan)),
+    "no_bias": lambda job: _replace(job, router_bias=False),
+    "no_bias_update": lambda job: setattr(job, "bias_rate", 0.0),
+    "no_route_scale": lambda job: _replace(job, route_scale=1.0),
+    "no_post_norm": lambda job: _replace(job, post_norm=False),
+    "no_shared": lambda job: _replace(job, shared_expert_dim=0),
+    "router_bf16": lambda job: _router_in_bfloat16(),
+}
+
+
+def _replace(job, **fields) -> None:
+    job.cfg = dataclasses.replace(job.cfg, **fields)
+
+
 def main() -> int:
     parser = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     parser.add_argument("--workload", required=True)
     parser.add_argument("--seeds", type=int, default=10)
     parser.add_argument("--first-seed", type=int, default=2147484000)
     parser.add_argument("--rehearsal", action="store_true")
+    parser.add_argument("--variant", choices=sorted(VARIANTS))
     args = parser.parse_args()
     from benchmarks import run
 
@@ -61,8 +109,11 @@ def main() -> int:
               "a") as out:
         for seed in range(args.first_seed, args.first_seed + args.seeds):
             job = jobs.Job(config, traffic, seed)
+            if args.variant:
+                job.state()         # the shipped program's, made before
+                VARIANTS[args.variant](job)
             line = {"workload": args.workload, "seed": seed,
-                    "rehearsal": args.rehearsal}
+                    "rehearsal": args.rehearsal, "variant": args.variant}
             for what, got, want, rtol in job.check()():
                 err = abs(got - want) / abs(want)
                 line[what] = {"program": got, "reference": want, "rel": err,
@@ -81,7 +132,7 @@ def main() -> int:
             for array in jax.live_arrays():
                 array.delete()
         last = {"workload": args.workload, "seeds": args.seeds,
-                "largest_rel": worst}
+                "variant": args.variant, "largest_rel": worst}
         print(json.dumps(last), flush=True)
         out.write(json.dumps(last) + "\n")
     hvd.shutdown()

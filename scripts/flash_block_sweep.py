@@ -93,9 +93,9 @@ def time_parent(name, shape, dtype):
 def time_variant(label, name, shape, dtype, tiles, group_in_hbm, kernels):
     b, s, h, hkv, d = shape
     q, k, v, do = operands(shape, dtype, group_in_hbm)
-    sc = 1.0 / d ** 0.5
+    sc, causal = 1.0 / d ** 0.5, fa.Mask()
     o, lse = jax.jit(lambda q, k, v: fa._fwd_call(
-        q, k, v, sc, True, s, None))(q, k, v)
+        q, k, v, sc, causal, s, None))(q, k, v)
     lse = lse[..., 0]
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), -1)
     rows = (lse[:, None, :], delta[:, None, :])
@@ -108,13 +108,13 @@ def time_variant(label, name, shape, dtype, tiles, group_in_hbm, kernels):
             if t is not None and (s % t[0] or s % t[1]):
                 continue
             if kernel == "fwd":
-                f = lambda q, k, v: fa._fwd_call(q, k, v, sc, True, s, t)
+                f = lambda q, k, v: fa._fwd_call(q, k, v, sc, causal, s, t)
                 args = (q, k, v)
             elif kernel == "dkdv":
-                f = lambda *a: fa._dkdv_call(*a, sc, True, s, t)
+                f = lambda *a: fa._dkdv_call(*a, sc, causal, s, t)
                 args = (q, k, v, do, *rows)
             else:
-                f = lambda *a: fa._dq_call(*a, sc, True, s, t)
+                f = lambda *a: fa._dq_call(*a, sc, causal, s, t)
                 args = (q, k, v, do, *cols)
             try:
                 ms = timed(f, *args)
@@ -213,12 +213,11 @@ def main():
             time_variant("blocks_alone", name, shape, f32, (tile,), True,
                          (kernel,))
         # What the clamped index maps are worth, at the table's tiles.
-        keep = fa._last_kept_k, fa._first_kept_q
-        fa._last_kept_k = lambda i, bq, bk: 1 << 30
-        fa._first_kept_q = lambda j, bq, bk: 0
+        keep = fa.Mask.k_blocks, fa.Mask.q_blocks
+        fa.Mask.k_blocks = fa.Mask.q_blocks = lambda *a: (None, None)
         time_variant("no_clamp", name, shape, bf16, (None,), False,
                      all_kernels)
-        fa._last_kept_k, fa._first_kept_q = keep
+        fa.Mask.k_blocks, fa.Mask.q_blocks = keep
 
 
 if __name__ == "__main__":

@@ -111,9 +111,9 @@ def main() -> int:
     block = gpt._block_fn(cfg)
 
     def blocks(layers, x):
-        for i, lp in enumerate(layers):
+        for i, (spec, lp) in enumerate(zip(cfg.plan, layers)):
             with jax.named_scope(f"layer{i}"):
-                x, _ = block(cfg, lp, x, positions)
+                x, _ = block(cfg, spec, lp, x, positions)
         return jnp.sum(jnp.sin(x.astype(jnp.float32)))
 
     fwd = jax.jit(blocks)

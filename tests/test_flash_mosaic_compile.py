@@ -69,6 +69,7 @@ SHAPES = {
 @pytest.mark.parametrize("shape", list(SHAPES))
 def test_kernel_compiles_for_v5e(one_chip, mosaic, shape, kernel):
     bh, bkv, s, d, dtype, causal = SHAPES[shape]
+    mask = fa.Mask(causal=causal)
 
     def sds(*dims, dt=dtype):
         return jax.ShapeDtypeStruct(dims, dt, sharding=one_chip)
@@ -76,14 +77,14 @@ def test_kernel_compiles_for_v5e(one_chip, mosaic, shape, kernel):
     q, k = sds(bh, s, d), sds(bkv, s, d)
     scale = d ** -0.5
     if kernel == "fwd":
-        f = lambda q, k, v: fa._fwd_call(q, k, v, scale, causal, s)
+        f = lambda q, k, v: fa._fwd_call(q, k, v, scale, mask, s)
         args = (q, k, k)
     elif kernel == "dkdv":
-        f = lambda *a: fa._dkdv_call(*a, scale, causal, s)
+        f = lambda *a: fa._dkdv_call(*a, scale, mask, s)
         rows = sds(bh, 1, s, dt=jnp.float32)
         args = (q, k, k, q, rows, rows)
     else:
-        f = lambda *a: fa._dq_call(*a, scale, causal, s)
+        f = lambda *a: fa._dq_call(*a, scale, mask, s)
         cols = sds(bh, s, 128, dt=jnp.float32)
         args = (q, k, k, q, cols, cols)
     text = jax.jit(f).lower(*args).compile().as_text()

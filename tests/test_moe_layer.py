@@ -782,3 +782,156 @@ def test_operation_count_by_hand():
     assert flops_moe.moe_train_flops(
         3, 1, 16, 1, 1, 4, experts=8, width=24, top_k=2, vocab=10) \
         == 3 * (512 + 32 + 4864 + 320)
+
+
+# ---- the sigmoid router under a selection bias (Trinity-Mini's) -------------
+
+from benchmarks.reference import gpt_window_moe_dp as window_reference  # noqa: E402
+
+ROUTE_SCALE = 2.826
+
+
+def _sigmoid_inputs(seed):
+    h, router, w_gate, w_up, w_down = layer_inputs(seed)
+    ks = jax.random.split(jax.random.PRNGKey(seed + 100), 4)
+    block = {"router": router, "w_gate": w_gate, "w_up": w_up,
+             "w_down": w_down,
+             # Wide enough to change a third of the tokens' choices.
+             "router_bias": 0.2 * jax.random.normal(ks[0], (E,)),
+             "shared": {"w_gate": jax.random.normal(ks[1], (D, M)) / 4,
+                        "w_up": jax.random.normal(ks[2], (D, M)) / 4,
+                        "w_down": jax.random.normal(ks[3], (M, D)) / 5}}
+    return h, block
+
+
+def _sigmoid_layer(h, block, top_k, first=0, held=E, bias=True):
+    return moe_layer(
+        h, block["router"], *(block[k][first:first + held]
+                              for k in ("w_gate", "w_up", "w_down")),
+        top_k=top_k, dtype=jnp.float32, first_expert=first, renormalize=True,
+        score="sigmoid", bias=block["router_bias"] if bias else None,
+        scale=ROUTE_SCALE)
+
+
+@pytest.mark.parametrize("top_k", [1, 4])
+def test_sigmoid_router_with_bias_and_constant_matches_the_reference(top_k):
+    """``s = sigmoid(h W_r)``, the choice on ``s + b``, the weights ``scale
+    s / (sum of the chosen s + 1e-20)``: outputs, counts and every gradient
+    against the plain every-expert-on-every-token reference; nothing reaches
+    the bias."""
+    h, block = _sigmoid_inputs(21)
+    weigh = jnp.cos(jnp.arange(T * D, dtype=jnp.float32)).reshape(T, D)
+
+    def program(h, block):
+        y, aux = _sigmoid_layer(h, block, top_k)
+        return jnp.sum(y * weigh), (y, aux["counts"])
+
+    def plain(h, block):
+        # The reference's block adds its shared expert; the layer has none.
+        y, counts = window_reference.expert_block(h, block, top_k,
+                                                  ROUTE_SCALE)
+        y = y - window_reference.gated_ff(
+            h, *(block["shared"][k] for k in ("w_gate", "w_up", "w_down")))
+        return jnp.sum(y * weigh), (y, counts)
+
+    (_, (y, counts)), g = jax.value_and_grad(
+        program, argnums=(0, 1), has_aux=True)(h, block)
+    (_, (y_ref, counts_ref)), g_ref = jax.value_and_grad(
+        plain, argnums=(0, 1), has_aux=True)(h, block)
+    np.testing.assert_allclose(y, y_ref, rtol=2e-5, atol=2e-6)
+    np.testing.assert_array_equal(counts, np.asarray(counts_ref, np.int32))
+    g[1].pop("shared"), g_ref[1].pop("shared")
+    jax.tree.map(lambda a, b: np.testing.assert_allclose(
+        a, b, rtol=2e-4, atol=2e-6), g, g_ref)
+    assert not np.any(np.asarray(g[1]["router_bias"]))
+    # The bias is in the choice: without it other experts are chosen ...
+    plain_counts = _sigmoid_layer(h, block, top_k, bias=False)[1]["counts"]
+    assert np.abs(np.asarray(plain_counts) - np.asarray(counts)).sum() > 0
+    # ... and in nothing else: a token's weights are its scores', and add
+    # up to the constant.
+    scores = jax.nn.sigmoid(h @ block["router"])
+    _, chosen = jax.lax.top_k(scores + block["router_bias"], top_k)
+    picked = jnp.take_along_axis(scores, chosen, axis=-1)
+    np.testing.assert_allclose(
+        jnp.sum(ROUTE_SCALE * picked / jnp.sum(picked, -1, keepdims=True),
+                axis=-1), ROUTE_SCALE, rtol=1e-6)
+
+
+def test_eight_shares_of_a_sigmoid_router_add_up_to_the_uncut_layer():
+    """Trinity-Mini's cut: eight ranks hold two of the 16 experts each, every
+    rank routes over all 16 under the same bias, and the ranks' partial sums
+    with the ungated shared expert counted once add up to the uncut layer's,
+    as do the gradients of the tokens and of the router."""
+    top_k = 4
+    h, block = _sigmoid_inputs(31)
+    weigh = jnp.sin(jnp.arange(T * D, dtype=jnp.float32)).reshape(T, D)
+
+    def whole(h, block):
+        y, counts = window_reference.expert_block(h, block, top_k,
+                                                  ROUTE_SCALE)
+        return jnp.sum(y * weigh), (y, counts)
+
+    def share(first):
+        def f(h, block):
+            y, aux = _sigmoid_layer(h, block, top_k, first=first, held=2)
+            return jnp.sum(y * weigh), (y, aux["counts"])
+        return f
+
+    def shared_once(h, block):
+        y = window_reference.gated_ff(
+            h, *(block["shared"][k] for k in ("w_gate", "w_up", "w_down")))
+        return jnp.sum(y * weigh), y
+
+    (_, (y_ref, counts_ref)), g_ref = jax.value_and_grad(
+        whole, argnums=(0, 1), has_aux=True)(h, block)
+    (_, total), grads = jax.value_and_grad(
+        shared_once, argnums=(0, 1), has_aux=True)(h, block)
+    for first in range(0, E, 2):
+        (_, (y, counts)), g = jax.value_and_grad(
+            share(first), argnums=(0, 1), has_aux=True)(h, block)
+        np.testing.assert_array_equal(counts,
+                                      np.asarray(counts_ref, np.int32))
+        total = total + y
+        grads = jax.tree.map(jnp.add, grads, g)
+    np.testing.assert_allclose(total, y_ref, rtol=2e-5, atol=2e-5)
+    jax.tree.map(lambda a, b: np.testing.assert_allclose(
+        a, b, rtol=5e-4, atol=2e-5), grads, g_ref)
+
+
+def test_probe_returns_what_the_router_read_and_gave():
+    """``probe``: the router's product's float32 operand (the activations,
+    flattened to tokens) and its float32 outputs; without it ``aux`` has
+    neither."""
+    h, block = _sigmoid_inputs(23)
+    args = (h.astype(jnp.bfloat16).reshape(2, T // 2, D), block["router"],
+            block["w_gate"], block["w_up"], block["w_down"])
+    kw = dict(top_k=2, score="sigmoid", bias=block["router_bias"])
+    y, aux = moe_layer(*args, probe=True, **kw)
+    y_plain, plain = moe_layer(*args, **kw)
+    assert set(aux) - set(plain) == {"router_input", "router_logits"}
+    np.testing.assert_array_equal(np.asarray(y, np.float32),
+                                  np.asarray(y_plain, np.float32))
+    assert aux["router_input"].dtype == jnp.float32
+    np.testing.assert_array_equal(
+        np.asarray(aux["router_input"]),
+        np.asarray(args[0].reshape(T, D), np.float32))
+    assert aux["router_logits"].dtype == jnp.float32
+    np.testing.assert_allclose(
+        aux["router_logits"], window_reference.router_logits(
+            aux["router_input"], block["router"]), rtol=1e-6, atol=1e-6)
+
+
+def test_an_unknown_score_raises():
+    h, block = _sigmoid_inputs(1)
+    with pytest.raises(ValueError, match="neither 'softmax' nor 'sigmoid'"):
+        moe_layer(h, block["router"], block["w_gate"], block["w_up"],
+                  block["w_down"], top_k=2, dtype=jnp.float32, score="tanh")
+
+
+def test_metrics_name_the_routers_kind(make_runtime):
+    make_runtime(devices=jax.devices()[:1])
+    h, block = _sigmoid_inputs(2)
+    jax.jit(lambda h, b: _sigmoid_layer(h, b, 2)[0])(h, block)
+    assert sample_value(
+        hvd.metrics(), "hvdtpu_spmd_moe_layer_traces_total", experts=str(E),
+        top_k="2", score="sigmoid", bias="1") == 1.0

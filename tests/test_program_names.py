@@ -208,6 +208,27 @@ def test_in_step_collective_scope_uses_the_callers_name(spmd4):
         assert scope in text, scope
 
 
+def test_window_step_holds_its_flash_kernels_under_a_scope_of_their_own(
+        spmd4):
+    """A window layer's three flash kernels sit under ``layer<i>/attn_window``
+    (where ``flash_window_ms`` looks) and a full layer's under
+    ``layer<i>/attn``, forward and backward; the kernels' names are the same
+    three. A branch normed after it too has ``post_norm`` inside its scope."""
+    plan = (gpt.LayerSpec(window=8), gpt.LayerSpec())
+    step, *args = gpt_step("full", layers=plan, post_norm=True)
+    text = step.lower(*args).as_text(debug_info=True)
+    scopes = set(re.findall(r'loc\("([^"]*)/hvd_flash_(fwd|dkdv|dq)/', text))
+    by_layer = {("layer0", "attn_window"): set(), ("layer1", "attn"): set()}
+    for scope, kernel in scopes:
+        layer = re.search(r"layer\d", scope).group(0)
+        by_layer[(layer, scope.rsplit("/", 1)[-1])].add(kernel)
+    assert all(kernels == {"fwd", "dkdv", "dq"}
+               for kernels in by_layer.values()), by_layer
+    for scope in ("attn_window/post_norm", "attn/post_norm",
+                  "mlp/post_norm"):
+        assert scope in text, scope
+
+
 # ---- (b) kernel names --------------------------------------------------------
 
 def _flash(grad: bool):
