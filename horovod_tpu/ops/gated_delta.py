@@ -1,7 +1,9 @@
 """The gated delta rule: a linear-attention recurrence whose state is
-*corrected* by each token, not only added to. The chunked form's part that
-needs no state is two Pallas kernels (below); the recurrence over chunks
-and the plain forms the tests hold the rest to are ``jax.numpy``.
+*corrected* by each token, not only added to. The chunked form is four
+Pallas kernels (below): two for the part that needs no state, two for the
+recurrence over chunks, which keep a head's state in VMEM from a sequence's
+first chunk to its last. The plain forms the tests hold them to are
+``jax.numpy``.
 
 A value head keeps a state ``S`` in ``R^{K x V}`` (keys by values), zero at
 the start of a sequence. With ``alpha_t = exp(g_t)`` in (0, 1] the decay and
@@ -35,13 +37,14 @@ enters with state ``S``::
     S' = G_last S + sum_j (G_last / G_j) k_j u_j^T
 
 ``T`` and the two products it is applied to need no state and are made for
-all chunks at once; a ``lax.scan`` over chunks carries ``S`` through three
-products a turn (XLA's). Decays, running sums and ``T`` are float32; the
-other products take operands in ``dtype`` and accumulate in float32, as
-``ops/ssd.py::ssd_chunked`` does.
+all chunks at once; the recurrence then carries ``S`` through the chunks in
+order, three lines a chunk. Decays, running sums, ``T`` and the state are
+float32; the other products take operands in ``dtype`` and accumulate in
+float32, as ``ops/ssd.py::ssd_chunked`` does.
 
-**The kernels** (``hvd_gdn_fwd``, ``hvd_gdn_bwd``, one ``jax.custom_vjp``:
-:func:`_chunk_local`) compute everything with two chunk-length axes: ``K
+**The chunk-local kernels** (``hvd_gdn_fwd``, ``hvd_gdn_bwd``, one
+``jax.custom_vjp``: :func:`_chunk_local`) compute everything with two
+chunk-length axes: ``K
 K^T`` and ``Q K^T`` (once a key head), the masked decay tile ``exp(cum_i -
 cum_j)``, ``A``, ``T`` (float32 throughout, rounded to ``dtype`` once before
 it is applied), ``u_own = T (beta V)`` (float32), ``w = T (beta G K)``,
@@ -61,6 +64,25 @@ VMEM, forms ``dT`` from the cotangents of ``u_own`` and ``w``, applies ``dA
 ``dtype``), ``d cum`` and ``d beta`` (float32, a row a head ``[B, c, Hk, 2
 Hv / Hk, Q]``, turned back outside). Off the TPU the kernels run in Pallas
 interpret mode; on it a shape they do not tile raises (:func:`_tiling`).
+
+**The recurrence's kernels** (``hvd_gdn_rec_fwd``, ``hvd_gdn_rec_bwd``, a
+second ``jax.custom_vjp``: :func:`_recurrence`) read those outputs from HBM,
+a chunk's last decay a head and the state a sequence starts from. A grid
+cell is ``_REC_CHUNKS`` chunks of one sequence (a loop) and ``_REC_HEADS``
+value heads, whose float32 states ``[K, V]`` sit side by side in a VMEM
+scratch; the grid's last axis walks a sequence's blocks of chunks in order.
+A chunk: ``u = u_own - w S``, ``o = (q G) S + attn u``, ``S' = G_last S + (k
+G_last / G)^T u`` (two products: ``w`` and ``q G`` stacked over ``S``,
+``attn`` and the transposed ``k G_last / G`` stacked over ``u``), and ``o``
+goes straight to its place in ``[B, S, Hv V]``. The forward pass runs the
+kernel with those two outputs; the rule's forward also keeps each chunk's
+entering state in ``dtype`` ``[c, B, Hv, K, V]``, the backward kernel's only
+residual beside the inputs. That one turns the last axis round (the index
+maps read block ``last - c``), carries ``dS`` float32 from the final state's
+cotangent to the initial state's, makes ``u`` again and returns the
+cotangents of the five operands (what ``hvd_gdn_bwd`` takes) and of the
+last decays. Both are bound by their bytes on a v5e (PERF.md, Findings, PR
+36: the kernels with their products taken out take as long).
 
 **The inverse in VMEM** (:func:`_inverse_in_vmem`): the diagonal blocks of
 ``_SUBSTITUTE`` = 32 rows by forward substitution on the vector unit (a
@@ -103,10 +125,15 @@ from .ssd import _NEG_INF, _NT, _SUBLANES, _TN, _always, _varying_like
 # benchmark's readers match ``^hvd_gdn_`` (tests/test_program_names.py).
 KERNEL_FWD = "hvd_gdn_fwd"
 KERNEL_BWD = "hvd_gdn_bwd"
+KERNEL_REC_FWD = "hvd_gdn_rec_fwd"
+KERNEL_REC_BWD = "hvd_gdn_rec_bwd"
 _HI = lax.Precision.HIGHEST
 _LANES = 128      # a key or value head's size is a multiple of the lane width
 _MAX_CHUNKS = 4   # chunks a grid cell, at most
 _SUBSTITUTE = 32  # rows of the inverse's diagonal blocks made by substitution
+_REC_HEADS = 8    # value heads a grid cell of the recurrence, at most
+_REC_CHUNKS = 4   # chunks a grid cell of the recurrence walks, at most
+_REC_VMEM = 64 << 20  # the recurrence's blocks, twice: 10-16 MiB of a v5e's 128
 
 
 def _check(q, k, v, g, beta):
@@ -226,20 +253,20 @@ def _inverse_in_vmem(a):
     return inv
 
 
+def _divisor(n: int, most: int) -> int:
+    """The largest divisor of ``n``, ``most`` at most."""
+    return next(d for d in range(min(most, n), 0, -1) if n % d == 0)
+
+
 def chunks_per_block(n_chunks: int) -> int:
-    """Chunks a grid cell of the kernels walks: the largest divisor of a
-    sequence's chunks, ``_MAX_CHUNKS`` at most."""
-    return next(nc for nc in range(min(_MAX_CHUNKS, n_chunks), 0, -1)
-                if n_chunks % nc == 0)
+    """Chunks a grid cell of the chunk-local kernels walks."""
+    return _divisor(n_chunks, _MAX_CHUNKS)
 
 
-def _tiling(kernel, q, v, chunk):
-    """``(chunks a grid cell, value heads a key head)`` of a call on ``q``
-    ``[B, S, Hk, K]`` and ``v`` ``[B, S, Hv, V]``; and, trace time only, the
-    record of it behind ``hvd.metrics()``. Compiled for the TPU, a shape the
-    kernels do not tile raises here, by name."""
-    key_dim, width = q.shape[3], v.shape[3]
-    rep = v.shape[2] // q.shape[2]
+def _tiling(kernel, key_dim, width, chunk, heads_per_block, dtype):
+    """Compiled for the TPU, a shape the kernels do not tile raises here, by
+    name; and, trace time only, the record of a call's tiling behind
+    ``hvd.metrics()``."""
     if not _use_interpret() and (
             key_dim % _LANES or width % _LANES or chunk % _SUBLANES):
         raise ValueError(
@@ -252,8 +279,8 @@ def _tiling(kernel, q, v, chunk):
     if recorder is not None:
         recorder.note_traced(
             "hvdtpu_spmd_gdn_kernel_traces_total", kernel=kernel, chunk=chunk,
-            heads_per_block=rep, operand_dtype=jnp.dtype(q.dtype).name)
-    return chunks_per_block(q.shape[1] // chunk), rep
+            heads_per_block=heads_per_block,
+            operand_dtype=jnp.dtype(dtype).name)
 
 
 def _row_sum(t):
@@ -435,7 +462,8 @@ def _plan(kernel, body, q, k, v, cum, beta):
     batch, seq, key_heads, key_dim = q.shape
     heads, width = v.shape[2:]
     n_chunks, chunk = cum.shape[1:3]
-    nc, rep = _tiling(kernel, q, v, chunk)
+    nc, rep = chunks_per_block(n_chunks), heads // key_heads
+    _tiling(kernel, key_dim, width, chunk, rep, q.dtype)
     args = (q.reshape(batch, seq, -1), k.reshape(batch, seq, -1),
             v.reshape(batch, seq, -1), cum.reshape(batch, seq, heads),
             beta.reshape(batch, seq, heads))
@@ -546,6 +574,252 @@ def _chunk_local_bwd(inputs, cotangents):
 _chunk_local.defvjp(_chunk_local_fwd, _chunk_local_bwd)
 
 
+def _along(row, r: int, lanes: int):
+    """Lane ``r`` of ``row`` ``[1, X]`` along ``lanes`` lanes, ``[1,
+    lanes]``. (Through a select: Mosaic broadcasts a ``[1, 1]`` along one
+    axis at a time, and two plain broadcasts fold into one.)"""
+    lane = lax.broadcasted_iota(jnp.int32, (1, lanes), 1)
+    return jnp.where(lane >= 0, row[:, r:r + 1], 0.0)
+
+
+def _rec_fwd_kernel(u_ref, w_ref, attn_ref, qin_ref, kout_ref, decay_ref,
+                    start_ref, o_ref, final_ref, *rest, nc: int, hb: int,
+                    chunk: int, width: int):
+    """A grid cell: ``nc`` chunks of one sequence and ``hb`` value heads,
+    whose float32 states ``[hb, K, V]`` stay in the scratch from a
+    sequence's first block of chunks to its last (the grid's last axis, in
+    order). A chunk: ``u = u_own - w S``, ``o = (q G) S + attn u``, ``S' =
+    G_last S + (k G_last / G)^T u``; ``o`` goes to its place in ``[B, S, Hv
+    V]``. With a third output (the rule's forward) each chunk's entering
+    state is kept in the operand dtype."""
+    f32, dtype = jnp.float32, w_ref.dtype
+    enter_ref, state = rest if len(rest) == 2 else (None,) + rest
+    block = pl.program_id(2)
+
+    @pl.when(block == 0)
+    def _start():
+        state[...] = start_ref[0]
+
+    @_always
+    def _chunks():
+        def one(n, carry):
+            rows = pl.ds(pl.multiple_of(n * chunk, chunk), chunk)
+            decays = decay_ref[0, 0, pl.ds(block * nc + n, 1), :]  # [1, hb]
+            for r in range(hb):
+                entering = state[r]
+                s = entering.astype(dtype)
+                if enter_ref is not None:
+                    enter_ref[n, 0, r] = s
+                # Two products a chunk, not four: w and q G stacked over S,
+                # attn and (k G_last / G)^T stacked over u (a product's cost
+                # here is loading the operand that stays).
+                on_s = jnp.dot(
+                    jnp.concatenate([w_ref[n, 0, r], qin_ref[n, 0, r]]), s,
+                    preferred_element_type=f32)
+                u = (u_ref[n, 0, r] - on_s[:chunk]).astype(dtype)
+                on_u = jnp.dot(
+                    jnp.concatenate([attn_ref[n, 0, r],
+                                     kout_ref[n, 0, r].T]), u,
+                    preferred_element_type=f32)
+                o_ref[0, rows, r * width:(r + 1) * width] = \
+                    (on_s[chunk:] + on_u[:chunk]).astype(o_ref.dtype)
+                state[r] = _along(decays, r, width) * entering + on_u[chunk:]
+            return carry
+
+        lax.fori_loop(0, nc, one, 0)
+
+    @pl.when(block == pl.num_programs(2) - 1)
+    def _final():
+        final_ref[0] = state[...]
+
+
+def _rec_bwd_kernel(u_ref, w_ref, attn_ref, qin_ref, kout_ref, decay_ref,
+                    enter_ref, do_ref, dfinal_ref, du_ref, dw_ref, dattn_ref,
+                    dqin_ref, dkout_ref, ddecay_ref, dstart_ref, dstate, *,
+                    nc: int, hb: int, chunk: int, width: int):
+    """The forward's grid cell, the blocks of chunks and the chunks inside
+    one walked from the last to the first (the index maps turn the grid's
+    last axis round). The scratch carries ``dS`` float32 from the final
+    state's cotangent to the initial state's. A chunk makes ``u`` again from
+    its kept entering state ``S`` and, with ``dS'`` the cotangent of the
+    state it left: ``du = attn^T do + (k G_last / G) dS'``, ``d attn = do
+    u^T``, ``d (k G_last / G) = u dS'^T``, ``d (q G) = do S^T``, ``dw = -du
+    S^T``, ``d G_last = <dS', S>`` and ``dS = G_last dS' + (q G)^T do - w^T
+    du``."""
+    f32, dtype = jnp.float32, w_ref.dtype
+    block, last = pl.program_id(2), pl.num_programs(2) - 1
+
+    @pl.when(block == 0)
+    def _start():
+        dstate[...] = dfinal_ref[0]
+
+    @_always
+    def _chunks():
+        lane = lax.broadcasted_iota(jnp.int32, (1, hb), 1)
+
+        def one(i, carry):
+            n = nc - 1 - i
+            at = pl.ds((last - block) * nc + n, 1)
+            rows = pl.ds(pl.multiple_of(n * chunk, chunk), chunk)
+            decays = decay_ref[0, 0, at, :]                      # [1, hb]
+            ddecays = jnp.zeros((1, hb), f32)
+            for r in range(hb):
+                s, left = enter_ref[n, 0, r], dstate[r]
+                w, qin = w_ref[n, 0, r], qin_ref[n, 0, r]
+                do = do_ref[0, rows, r * width:(r + 1) * width]
+                ds_out = left.astype(dtype)
+                u = (u_ref[n, 0, r] - jnp.dot(
+                    w, s, preferred_element_type=f32)).astype(dtype)
+                du = lax.dot_general(attn_ref[n, 0, r], do, _TN,
+                                     preferred_element_type=f32) \
+                    + jnp.dot(kout_ref[n, 0, r], ds_out,
+                              preferred_element_type=f32)
+                du_ref[n, 0, r] = du
+                du = du.astype(dtype)
+                dattn_ref[n, 0, r] = lax.dot_general(
+                    do, u, _NT, preferred_element_type=f32).astype(dtype)
+                dkout_ref[n, 0, r] = lax.dot_general(
+                    u, ds_out, _NT, preferred_element_type=f32).astype(dtype)
+                dqin_ref[n, 0, r] = lax.dot_general(
+                    do, s, _NT, preferred_element_type=f32).astype(dtype)
+                dw_ref[n, 0, r] = -lax.dot_general(
+                    du, s, _NT, preferred_element_type=f32).astype(dtype)
+                ddecays = jnp.where(
+                    lane == r, jnp.sum(_row_sum(left * s.astype(f32)),
+                                       axis=0, keepdims=True), ddecays)
+                dstate[r] = _along(decays, r, width) * left \
+                    + lax.dot_general(qin, do, _TN,
+                                      preferred_element_type=f32) \
+                    - lax.dot_general(w, du, _TN, preferred_element_type=f32)
+            ddecay_ref[0, 0, at, :] = ddecays
+            return carry
+
+        lax.fori_loop(0, nc, one, 0)
+
+    @pl.when(block == last)
+    def _final():
+        dstart_ref[0] = dstate[...]
+
+
+def _rec_plan(kernel, body, u_own, w, backward: bool):
+    """What the recurrence's two calls share: the tiling, the block specs by
+    name on the grid ``(batch, block of value heads, block of chunks)``, the
+    last axis in order (backward, the index maps read block ``last - c``),
+    and ``pallas_call``'s other arguments. ``scan`` is a tensor in the
+    recurrence's order ``[c, B, Hv, Q, .]``, ``entering`` the kept states
+    ``[c, B, Hv, K, V]``, ``tokens`` the output or its cotangent ``[B, S, Hv
+    V]``, ``state`` an initial or final state ``[B, Hv, K, V]`` and
+    ``decay`` a chunk's last decay a head, a grid cell's heads side by side:
+    ``[B, Hv / hb, c, hb]`` (:func:`_by_head_block`)."""
+    n_chunks, batch, heads, chunk, width = u_own.shape
+    key_dim = w.shape[-1]
+    hb, nc = _divisor(heads, _REC_HEADS), _divisor(n_chunks, _REC_CHUNKS)
+    _tiling(kernel, key_dim, width, chunk, hb, w.dtype)
+    blocks = n_chunks // nc
+
+    def at(c):
+        return blocks - 1 - c if backward else c
+
+    def scan(*last):
+        return pl.BlockSpec((nc, 1, hb) + last,
+                            lambda b, h, c: (at(c), b, h, 0, 0))
+
+    specs = {
+        "scan_v": scan(chunk, width), "scan_k": scan(chunk, key_dim),
+        "scan_q": scan(chunk, chunk), "entering": scan(key_dim, width),
+        "tokens": pl.BlockSpec((1, nc * chunk, hb * width),
+                               lambda b, h, c: (b, at(c), h)),
+        "state": pl.BlockSpec((1, hb, key_dim, width),
+                              lambda b, h, c: (b, h, 0, 0)),
+        "decay": pl.BlockSpec((1, 1, n_chunks, hb),
+                              lambda b, h, c: (b, h, 0, 0))}
+    call = dict(
+        grid=(batch, heads // hb, blocks),
+        scratch_shapes=[pltpu.VMEM((hb, key_dim, width), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_REC_VMEM),
+        interpret=_use_interpret(), name=kernel)
+    body = functools.partial(body, nc=nc, hb=hb, chunk=chunk, width=width)
+    return hb, specs, body, call
+
+
+def _by_head_block(decay, hb):
+    """``[B, c, Hv]`` -> ``[B, Hv / hb, c, hb]``: a grid cell's block of it
+    is the whole of the last two axes."""
+    batch, n_chunks, heads = decay.shape
+    return decay.reshape(batch, n_chunks, heads // hb, hb).swapaxes(1, 2)
+
+
+_REC_SPECS = _SCAN_SPECS + ("decay",)
+
+
+@functools.partial(jax.jit, inline=True, static_argnames="keep")
+def _rec_fwd_call(u_own, w, attn, q_in, k_out, decay, start, *, keep: bool):
+    """The chunk-local kernels' outputs ``[c, B, Hv, Q, .]``, a chunk's last
+    decay a head ``[B, c, Hv]`` and the float32 state a sequence starts
+    from ``[B, Hv, K, V]`` -> ``o`` ``[B, S, Hv V]`` in the operand dtype,
+    the float32 state after the last chunk and, with ``keep``, each chunk's
+    entering state ``[c, B, Hv, K, V]`` in the operand dtype."""
+    hb, specs, body, call = _rec_plan(KERNEL_REC_FWD, _rec_fwd_kernel, u_own,
+                                      w, backward=False)
+    args = (u_own, w, attn, q_in, k_out, _by_head_block(decay, hb), start)
+    vma = _out_vma(*args)
+    n_chunks, batch, heads, chunk, width = u_own.shape
+    out = [("tokens", (batch, n_chunks * chunk, heads * width), w.dtype),
+           ("state", start.shape, jnp.float32)]
+    if keep:
+        out.append(("entering", (n_chunks, batch) + start.shape[1:],
+                    w.dtype))
+    return pl.pallas_call(
+        body, in_specs=[specs[name] for name in _REC_SPECS + ("state",)],
+        out_specs=[specs[name] for name, _, _ in out],
+        out_shape=[jax.ShapeDtypeStruct(shape, dtype, vma=vma)
+                   for _, shape, dtype in out], **call)(*args)
+
+
+@functools.partial(jax.jit, inline=True)
+def _rec_bwd_call(u_own, w, attn, q_in, k_out, decay, entering, do, dfinal):
+    """The cotangents of :func:`_rec_fwd_call`'s inputs for those of ``o``
+    and the final state, the operands' own shapes and dtypes."""
+    hb, specs, body, call = _rec_plan(KERNEL_REC_BWD, _rec_bwd_kernel, u_own,
+                                      w, backward=True)
+    by_block = _by_head_block(decay, hb)
+    args = (u_own, w, attn, q_in, k_out, by_block, entering, do, dfinal)
+    vma = _out_vma(*args)
+    *scan, ddecay, dstart = pl.pallas_call(
+        body,
+        in_specs=[specs[name] for name in _REC_SPECS + (
+            "entering", "tokens", "state")],
+        out_specs=[specs[name] for name in _REC_SPECS + ("state",)],
+        out_shape=[jax.ShapeDtypeStruct(t.shape, t.dtype, vma=vma)
+                   for t in (u_own, w, attn, q_in, k_out, by_block, dfinal)],
+        **call)(*args)
+    return (*scan, ddecay.swapaxes(1, 2).reshape(decay.shape), dstart)
+
+
+@jax.custom_vjp
+def _recurrence(u_own, w, attn, q_in, k_out, decay, start):
+    """The recurrence over chunks through the kernels: ``(o, final)``, see
+    :func:`_rec_fwd_call`."""
+    return tuple(_rec_fwd_call(u_own, w, attn, q_in, k_out, decay, start,
+                               keep=False))
+
+
+def _recurrence_fwd(*inputs):
+    # The residuals: the inputs but the initial state, and the entering
+    # states the forward kernel keeps.
+    o, final, entering = _rec_fwd_call(*inputs, keep=True)
+    return (o, final), inputs[:6] + (entering,)
+
+
+def _recurrence_bwd(kept, cotangents):
+    return _rec_bwd_call(*kept, *cotangents)
+
+
+_recurrence.defvjp(_recurrence_fwd, _recurrence_bwd)
+
+
 def gated_delta_chunked(q, k, v, g, beta, chunk: int = 64, *,
                         dtype: Any = jnp.bfloat16, initial_state=None):
     """The recurrence in chunks of ``chunk`` tokens (a power of two).
@@ -560,13 +834,6 @@ def gated_delta_chunked(q, k, v, g, beta, chunk: int = 64, *,
                          f"got {chunk}")
     batch, seq, key_heads, key_dim = k.shape
     heads, width = v.shape[2:]
-    from .. import runtime
-    recorder = runtime.recorder()
-    if recorder is not None:
-        recorder.note_traced(
-            "hvdtpu_spmd_gdn_layer_traces_total", key_heads=key_heads,
-            value_heads=heads, key_dim=key_dim, value_dim=width, chunk=chunk)
-
     f32 = jnp.float32
     pad = (-seq) % chunk
     if pad:
@@ -574,6 +841,13 @@ def gated_delta_chunked(q, k, v, g, beta, chunk: int = 64, *,
             jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
             for t in (q, k, v, g, beta))
     n_chunks = (seq + pad) // chunk
+    from .. import runtime
+    recorder = runtime.recorder()
+    if recorder is not None:
+        recorder.note_traced(
+            "hvdtpu_spmd_gdn_layer_traces_total", key_heads=key_heads,
+            value_heads=heads, key_dim=key_dim, value_dim=width, chunk=chunk,
+            recurrence="kernel", chunks=n_chunks)
 
     def chunked(t):
         """``[B, S, H]`` -> ``[B, c, Q, H]``."""
@@ -586,25 +860,11 @@ def gated_delta_chunked(q, k, v, g, beta, chunk: int = 64, *,
         q.astype(dtype), k.astype(dtype), v.astype(dtype), cum,
         chunked(beta))
 
-    def one_chunk(state, now):
-        u_c, w_c, q_c, k_c, attn_c, decay_c = now
-        s = state.astype(dtype)
-        u = (u_c - jnp.einsum("bhik,bhkv->bhiv", w_c, s,
-                              preferred_element_type=f32)).astype(dtype)
-        o = jnp.einsum("bhik,bhkv->bhiv", q_c, s,
-                       preferred_element_type=f32) \
-            + jnp.einsum("bhij,bhjv->bhiv", attn_c, u,
-                         preferred_element_type=f32)
-        state = decay_c[..., None, None] * state + jnp.einsum(
-            "bhik,bhiv->bhkv", k_c, u, preferred_element_type=f32)
-        return state, o.astype(dtype)
-
     start = jnp.zeros((batch, heads, key_dim, width), f32) \
         if initial_state is None else initial_state.astype(f32)
-    final, o = lax.scan(
-        one_chunk, _varying_like(start, u_own),
-        (u_own, w, q_in, k_out, attn,
-         jnp.moveaxis(jnp.exp(cum[:, :, -1]), 1, 0)))
-    # [c, B, H, Q, V] -> [B, S, H, V]
-    o = jnp.moveaxis(o, 0, 1).swapaxes(2, 3)
+    # The recurrence's kernels: a head's state stays in VMEM through the
+    # sequence's chunks; o comes out as [B, S, Hv V].
+    o, final = _recurrence(u_own, w, attn, q_in, k_out,
+                           jnp.exp(cum[:, :, -1]),
+                           _varying_like(start, u_own))
     return o.reshape(batch, n_chunks * chunk, heads, width)[:, :seq], final

@@ -110,18 +110,20 @@ _TRACED = {
     "hvdtpu_spmd_gdn_layer_traces_total": (
         "Times JAX traced a chunked gated-delta-rule scan (the recomputed "
         "copy of a block counts again), by its key heads, value heads, their "
-        "sizes and the chunk.",
-        ("key_heads", "value_heads", "key_dim", "value_dim", "chunk")),
+        "sizes and the chunk, what the recurrence over chunks ran as and "
+        "over how many chunks a sequence.",
+        ("key_heads", "value_heads", "key_dim", "value_dim", "chunk",
+         "recurrence", "chunks")),
     "hvdtpu_spmd_ssd_kernel_traces_total": (
         "Times JAX traced one of the state-space scan's within-chunk "
         "kernels, by kernel and the tiling the call got: the chunk, the "
         "heads a grid cell holds, the MXU operands' dtype.",
         ("kernel", "chunk", "heads_per_block", "operand_dtype")),
     "hvdtpu_spmd_gdn_kernel_traces_total": (
-        "Times JAX traced one of the gated delta rule's chunk-local "
-        "kernels, by kernel and the tiling the call got: the chunk, the "
-        "value heads a grid cell holds (those of one key head), the MXU "
-        "operands' dtype.",
+        "Times JAX traced one of the gated delta rule's kernels (the "
+        "chunk-local pair, the recurrence over chunks' pair), by kernel and "
+        "the tiling the call got: the chunk, the value heads a grid cell "
+        "holds, the MXU operands' dtype.",
         ("kernel", "chunk", "heads_per_block", "operand_dtype")),
     "hvdtpu_spmd_remat_saved_bytes_total": (
         "Bytes a checkpointed block hands from its forward to its backward "

@@ -1,8 +1,9 @@
 #!/usr/bin/env python3
-"""Time the gated delta rule's chunk-local kernels alone on the chip, and hold
-the compiled kernels to the ``jax.numpy`` expression they replaced.
+"""Time the gated delta rule's kernels alone on the chip, and hold the
+compiled kernels to the ``jax.numpy`` expressions they replaced.
 
     chiprun --chips 1 -- python scripts/gdn_kernel_time.py [--substitute 1 8 16 64] [--chunks-per-block 2 4 8]
+        [--rec-heads 4 8] [--rec-chunks 1 2 4] [--rows chunk_local recurrence whole]
 
 At the ``qwen3-next-80b-a3b_s4096`` cell's shapes (4 x 4096 tokens, 16 key and
 32 value heads of 128, chunk 64, bfloat16) it jits and times, host clock
@@ -10,8 +11,14 @@ around ``block_until_ready``: ``hvd_gdn_fwd``; ``hvd_gdn_bwd``; the chunk-local
 part forward and backward through the ``custom_vjp``; the same through the
 plain expression (XLA writes the ``[chunk, chunk]`` tensors to HBM, the
 inverse is ``unit_lower_inverse``); and ``gated_delta_chunked`` whole, forward
-and backward, so that the recurrence over chunks is the difference. The
-kernels' values and gradients are compared with the plain expression's
+and backward. Then the recurrence over chunks on the forward kernel's
+outputs: the ``lax.scan`` ``gated_delta_chunked`` ran before PR 36 (kept
+here, :func:`scan_recurrence`), forward and with autodiff's backward, against
+``hvd_gdn_rec_fwd`` (as the forward pass runs it and as the rule's forward
+does, entering states kept) and ``hvd_gdn_rec_bwd``, alone and through the
+``custom_vjp``; ``--rec-heads`` and ``--rec-chunks`` force the value heads and
+the chunks a grid cell holds (the sources of ``_REC_HEADS``, ``_REC_CHUNKS``).
+The kernels' values and gradients are compared with the plain expressions'
 (relative to the largest value). ``--substitute`` forces the rows of the
 inverse's diagonal blocks made by substitution (1: every round a product;
 the chunk: no product) and ``--chunks-per-block`` the chunks a grid cell
@@ -87,12 +94,40 @@ def plain_chunk_local(q, k, v, cum, beta):
     return tuple(jnp.moveaxis(t, 1, 0) for t in (u_own, w, attn, q_in, k_out))
 
 
+def scan_recurrence(u_own, w, attn, q_in, k_out, decay, start):
+    """The recurrence over chunks as ``gated_delta_chunked`` ran it before
+    PR 36, on the kernels' arguments (``ops/gated_delta.py::_rec_fwd_call``)
+    and in their outputs' order: a ``lax.scan`` whose turn is three batched
+    products, the state through HBM, autodiff's backward."""
+    f32, dtype = jnp.float32, w.dtype
+
+    def one_chunk(state, now):
+        u_c, w_c, q_c, k_c, attn_c, decay_c = now
+        s = state.astype(dtype)
+        u = (u_c - jnp.einsum("bhik,bhkv->bhiv", w_c, s,
+                              preferred_element_type=f32)).astype(dtype)
+        o = jnp.einsum("bhik,bhkv->bhiv", q_c, s,
+                       preferred_element_type=f32) \
+            + jnp.einsum("bhij,bhjv->bhiv", attn_c, u,
+                         preferred_element_type=f32)
+        state = decay_c[..., None, None] * state + jnp.einsum(
+            "bhik,bhiv->bhkv", k_c, u, preferred_element_type=f32)
+        return state, o.astype(dtype)
+
+    final, o = jax.lax.scan(one_chunk, start, (
+        u_own, w, q_in, k_out, attn, jnp.moveaxis(decay, 1, 0)))
+    # [c, B, H, Q, V] -> [B, S, H V]
+    o = jnp.moveaxis(o, 0, 1).swapaxes(2, 3)
+    return o.reshape(o.shape[0], -1, o.shape[3] * o.shape[4]), final
+
+
 def rel(got, want) -> float:
     got, want = (t.astype(jnp.float32) for t in (got, want))
     return float(jnp.abs(got - want).max() / jnp.abs(want).max())
 
 
 NAMES = ("u_own", "w", "attn", "q_in", "k_out")
+REC_INPUTS = NAMES + ("decay", "start")
 INPUTS = ("q", "k", "v", "cum", "beta")
 
 
@@ -109,6 +144,12 @@ def main() -> int:
                         default=[gd._SUBSTITUTE])
     parser.add_argument("--chunks-per-block", type=int, nargs="*",
                         default=[gd._MAX_CHUNKS])
+    parser.add_argument("--rec-heads", type=int, nargs="*",
+                        default=[gd._REC_HEADS])
+    parser.add_argument("--rec-chunks", type=int, nargs="*",
+                        default=[gd._REC_CHUNKS])
+    parser.add_argument("--rows", nargs="*",
+                        default=["chunk_local", "recurrence", "whole"])
     args = parser.parse_args()
     B, S, Hk, Hv, K, V, Q = (args.batch, args.seq, args.key_heads,
                              args.value_heads, args.key_dim, args.value_dim,
@@ -117,7 +158,7 @@ def main() -> int:
     print(f"platform: {device.platform} device_kind: {device.device_kind}",
           flush=True)
     c = S // Q
-    ks = jax.random.split(jax.random.PRNGKey(0), 11)
+    ks = jax.random.split(jax.random.PRNGKey(0), 14)
     dtype, f32 = jnp.bfloat16, jnp.float32
 
     def unit(t):
@@ -146,47 +187,93 @@ def main() -> int:
         out.write(line + "\n")
         out.flush()
 
-    def both(f):
+    def both(f, n=5):
         # The cotangents are arguments: closed over, 0.8 GB of them would be
         # constants of the program.
         def loss(*t):
             return sum(jnp.sum(o.astype(f32) * ct.astype(f32))
-                       for o, ct in zip(f(*t[:5]), t[5:]))
-        return jax.jit(jax.value_and_grad(loss, argnums=tuple(range(5))))
+                       for o, ct in zip(f(*t[:n]), t[n:]))
+        return jax.jit(jax.value_and_grad(loss, argnums=tuple(range(n))))
 
-    plain = both(plain_chunk_local)
-    want = jax.jit(plain_chunk_local)(*inputs)
-    _, want_g = plain(*inputs, *cts)
-    row(what="plain", fwd_ms=timed(jax.jit(plain_chunk_local), *inputs),
-        fwd_bwd_ms=timed(plain, *inputs, *cts))
-    shipped = gd._SUBSTITUTE, gd._MAX_CHUNKS
-    for substitute in args.substitute:
-        for chunks in args.chunks_per_block:
-            gd._SUBSTITUTE, gd._MAX_CHUNKS = substitute, chunks
-            jax.clear_caches()  # the calls are jitted: trace them anew
-            fwd = jax.jit(lambda *t: gd._fwd_call(*t))
-            bwd = jax.jit(lambda *t: gd._bwd_call(*t))
-            kernels = both(lambda *t: gd._chunk_local(*t))
-            got = fwd(*inputs)
-            _, got_g = kernels(*inputs, *cts)
-            row(what="kernels", substitute=substitute,
-                chunks_per_block=gd.chunks_per_block(c),
-                fwd_ms=timed(fwd, *inputs), bwd_ms=timed(bwd, *inputs, *cts),
-                fwd_bwd_ms=timed(kernels, *inputs, *cts),
-                out_rel={n: rel(o, w) for n, o, w in zip(NAMES, got, want)},
-                grad_rel={n: rel(o, w)
-                          for n, o, w in zip(INPUTS, got_g, want_g)})
-    gd._SUBSTITUTE, gd._MAX_CHUNKS = shipped
+    if "chunk_local" in args.rows:
+        plain = both(plain_chunk_local)
+        want = jax.jit(plain_chunk_local)(*inputs)
+        _, want_g = plain(*inputs, *cts)
+        row(what="plain", fwd_ms=timed(jax.jit(plain_chunk_local), *inputs),
+            fwd_bwd_ms=timed(plain, *inputs, *cts))
+        del plain
+        shipped = gd._SUBSTITUTE, gd._MAX_CHUNKS
+        for substitute in args.substitute:
+            for chunks in args.chunks_per_block:
+                gd._SUBSTITUTE, gd._MAX_CHUNKS = substitute, chunks
+                jax.clear_caches()  # the calls are jitted: trace them anew
+                fwd = jax.jit(lambda *t: gd._fwd_call(*t))
+                bwd = jax.jit(lambda *t: gd._bwd_call(*t))
+                kernels = both(lambda *t: gd._chunk_local(*t))
+                got = fwd(*inputs)
+                _, got_g = kernels(*inputs, *cts)
+                row(what="kernels", substitute=substitute,
+                    chunks_per_block=gd.chunks_per_block(c),
+                    fwd_ms=timed(fwd, *inputs),
+                    bwd_ms=timed(bwd, *inputs, *cts),
+                    fwd_bwd_ms=timed(kernels, *inputs, *cts),
+                    out_rel={n: rel(o, w)
+                             for n, o, w in zip(NAMES, got, want)},
+                    grad_rel={n: rel(o, w)
+                              for n, o, w in zip(INPUTS, got_g, want_g)})
+        gd._SUBSTITUTE, gd._MAX_CHUNKS = shipped
+        del want, want_g, got, got_g
+        jax.clear_caches()
+
+    if "recurrence" in args.rows:
+        rec = tuple(jax.jit(gd._fwd_call)(*inputs)) + (
+            jnp.exp(cum[:, :, -1]),
+            0.1 * jax.random.normal(ks[11], (B, Hv, K, V), f32))
+        rec_cts = (jax.random.normal(ks[12], (B, S, Hv * V), dtype),
+                   jax.random.normal(ks[13], (B, Hv, K, V), f32))
+        scan = both(scan_recurrence, 7)
+        want = jax.jit(scan_recurrence)(*rec)
+        _, want_g = scan(*rec, *rec_cts)
+        row(what="scan", fwd_ms=timed(jax.jit(scan_recurrence), *rec),
+            fwd_bwd_ms=timed(scan, *rec, *rec_cts))
+        del scan
+        shipped = gd._REC_HEADS, gd._REC_CHUNKS
+        for heads in args.rec_heads:
+            for chunks in args.rec_chunks:
+                gd._REC_HEADS, gd._REC_CHUNKS = heads, chunks
+                jax.clear_caches()
+                fwd = jax.jit(lambda *t: gd._rec_fwd_call(*t, keep=False))
+                keep = jax.jit(lambda *t: gd._rec_fwd_call(*t, keep=True))
+                bwd = jax.jit(lambda *t: gd._rec_bwd_call(*t))
+                kernels = both(lambda *t: gd._recurrence(*t), 7)
+                got = fwd(*rec)
+                entering = keep(*rec)[2]
+                _, got_g = kernels(*rec, *rec_cts)
+                out_rel = {n: rel(o, w) for n, o, w in zip(
+                    ("o", "final"), got, want)}
+                grad_rel = {n: rel(o, w) for n, o, w in zip(
+                    REC_INPUTS, got_g, want_g)}
+                row(what="recurrence", rec_heads=heads, rec_chunks=chunks,
+                    fwd_ms=timed(fwd, *rec), fwd_keep_ms=timed(keep, *rec),
+                    bwd_ms=timed(bwd, *rec[:6], entering, *rec_cts),
+                    fwd_bwd_ms=timed(kernels, *rec, *rec_cts),
+                    out_rel=out_rel, grad_rel=grad_rel,
+                    largest_rel=max(*out_rel.values(), *grad_rel.values()))
+                del entering, got, got_g
+        gd._REC_HEADS, gd._REC_CHUNKS = shipped
+        del rec, rec_cts, want, want_g
+        jax.clear_caches()
 
     def whole(q, k, v, g, beta):
         o, final = gd.gated_delta_chunked(q, k, v, g, beta, chunk=Q,
                                           dtype=dtype)
         return jnp.sum(jnp.sin(o.astype(f32))) + jnp.sum(final)
 
-    scan = (q, k, v, g, beta.reshape(B, S, Hv))
-    row(what="gated_delta_chunked", fwd_ms=timed(jax.jit(whole), *scan),
-        fwd_bwd_ms=timed(jax.jit(jax.value_and_grad(
-            whole, argnums=tuple(range(5)))), *scan))
+    if "whole" in args.rows:
+        scan = (q, k, v, g, beta.reshape(B, S, Hv))
+        row(what="gated_delta_chunked", fwd_ms=timed(jax.jit(whole), *scan),
+            fwd_bwd_ms=timed(jax.jit(jax.value_and_grad(
+                whole, argnums=tuple(range(5)))), *scan))
     return 0
 
 

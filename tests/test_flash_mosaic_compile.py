@@ -12,6 +12,7 @@ All such tests live in this one file: the worker that gets it loads libtpu
 and holds its lock until it exits.
 """
 
+import functools
 import os
 
 import jax
@@ -128,7 +129,7 @@ GDN_SHAPES = {
 }
 
 
-@pytest.mark.parametrize("kernel", ["fwd", "bwd"])
+@pytest.mark.parametrize("kernel", ["fwd", "bwd", "rec_fwd", "rec_bwd"])
 @pytest.mark.parametrize("shape", list(GDN_SHAPES))
 def test_gdn_kernel_compiles_for_v5e(one_chip, mosaic, shape, kernel):
     batch, seq, key_heads, heads, key_dim, width, chunk, dtype = \
@@ -137,14 +138,30 @@ def test_gdn_kernel_compiles_for_v5e(one_chip, mosaic, shape, kernel):
     def sds(*dims, dt=dtype):
         return jax.ShapeDtypeStruct(dims, dt, sharding=one_chip)
 
+    def like(f, *args, **kw):
+        return tuple(sds(*t.shape, dt=t.dtype)
+                     for t in jax.eval_shape(functools.partial(f, **kw),
+                                             *args))
+
     q = sds(batch, seq, key_heads, key_dim)
     row = sds(batch, seq // chunk, chunk, heads, dt=jnp.float32)
     args = (q, q, sds(batch, seq, heads, width), row, row)
+    scan = like(gated_delta._fwd_call, *args)
     if kernel == "fwd":
         f = gated_delta._fwd_call
+    elif kernel == "bwd":
+        f, args = gated_delta._bwd_call, args + scan
     else:
-        f = gated_delta._bwd_call
-        args += tuple(sds(*t.shape, dt=t.dtype) for t in jax.eval_shape(
-            gated_delta._fwd_call, *args))
+        # The recurrence over chunks reads the chunk-local kernel's outputs,
+        # a chunk's last decay a head and the state a sequence starts from.
+        state = sds(batch, heads, key_dim, width, dt=jnp.float32)
+        args = scan + (sds(batch, seq // chunk, heads, dt=jnp.float32),
+                       state)
+        f = functools.partial(gated_delta._rec_fwd_call, keep=True)
+        if kernel == "rec_bwd":
+            o, _, entering = like(gated_delta._rec_fwd_call, *args,
+                                  keep=True)
+            f, args = gated_delta._rec_bwd_call, args[:6] + (entering, o,
+                                                             state)
     text = jax.jit(f).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text and f"hvd_gdn_{kernel}" in text

@@ -20,7 +20,16 @@ kernels, which run here in interpret mode: every test of
 cases repeat the comparison at the benchmark cell's head layout, and the
 kernels' own inverse (substitution inside diagonal blocks, then products) is
 held to ``unit_lower_inverse`` directly.
+
+Since PR 36 the recurrence over chunks is two more kernels
+(``hvd_gdn_rec_fwd``, ``hvd_gdn_rec_bwd``: the state in a scratch from a
+sequence's first chunk to its last), so every test here runs them too; the
+``recurrence`` cases take a grid of two blocks of value heads and, at chunks
+of 32, two blocks of chunks, an initial state and a cotangent on the final
+one.
 """
+
+import inspect
 
 import jax
 import jax.numpy as jnp
@@ -103,7 +112,53 @@ def test_the_kernels_are_the_path():
     args = _inputs(0, 32)
     text = str(jax.make_jaxpr(jax.grad(lambda *a: gated_delta_chunked(
         *a, chunk=16, dtype=jnp.float32)[0].sum(), argnums=(0, 3)))(*args))
-    assert "name=hvd_gdn_fwd" in text and "name=hvd_gdn_bwd" in text
+    for kernel in ("fwd", "bwd", "rec_fwd", "rec_bwd"):
+        assert f"name=hvd_gdn_{kernel}" in text
+    assert "lax.scan" not in inspect.getsource(gated_delta_chunked)
+
+
+# Sixteen value heads, two a key head: two of the recurrence's blocks of
+# eight. 170 tokens are six chunks of 32 (two blocks of three) or three of
+# 64 (one block), the last one padded either way.
+RECURRENCE = dict(batch=1, key_heads=8, heads=16, key_dim=16, width=8)
+RECURRENCE_SEQ = 170
+
+
+@pytest.mark.parametrize("chunk", [32, 64])
+@pytest.mark.parametrize("what", ["o and final", "q", "k", "v", "g", "beta",
+                                  "initial_state"])
+def test_recurrence_kernels_match_sequential(chunk, what):
+    """``o``, the final state and the gradient of every input, the initial
+    state's too, of a linear form of both outputs."""
+    rng = np.random.default_rng(18)
+    args = _inputs(19, RECURRENCE_SEQ, **RECURRENCE) + (jnp.asarray(
+        0.5 * rng.standard_normal((1, 16, 16, 8)), jnp.float32),)
+    assert gated_delta._divisor(16, gated_delta._REC_HEADS) == 8
+    assert gated_delta._divisor(-(-RECURRENCE_SEQ // chunk),
+                                gated_delta._REC_CHUNKS) == 3
+
+    def chunked(*a):
+        return gated_delta_chunked(*a[:5], chunk=chunk, dtype=jnp.float32,
+                                   initial_state=a[5])
+
+    def sequential(*a):
+        return gated_delta_sequential(*a[:5], initial_state=a[5])
+
+    if what == "o and final":
+        for got, want in zip(chunked(*args), sequential(*args)):
+            _close(got, want, 2e-5)
+        return
+    co, cs = (jnp.asarray(rng.standard_normal(t.shape), jnp.float32)
+              for t in jax.eval_shape(sequential, *args))
+    wrt = ["q", "k", "v", "g", "beta", "initial_state"].index(what)
+
+    def gradient(fn):
+        def f(*a):
+            o, s = fn(*a)
+            return jnp.sum(o * co) + jnp.sum(s * cs)
+        return jax.grad(f, argnums=wrt)(*args)
+
+    _close(gradient(chunked), gradient(sequential), 5e-5)
 
 
 def test_cell_layout_matches_sequential():

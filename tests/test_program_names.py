@@ -154,33 +154,43 @@ def test_linear_attention_step_carries_the_gdn_scopes(spmd4, inner):
 
 def test_linear_attention_step_holds_the_gdn_kernels_under_their_scope(
         spmd4):
-    """The chunk-local kernels sit under ``layer<i>/gdn/scan`` (where
-    ``gdn_scan_ms`` looks): the forward one in the forward pass and in the
-    recomputed copy (the recurrence's backward pass reads its outputs), the
-    backward one in the backward pass. The counter says which tiling each
-    got, with its four labels: each kernel is traced once for a shape (the
-    calls are jitted inline, so the recomputed copy and further layers of
-    the same shape re-bind the traced kernel), which is why this test scans
-    at a chunk no other test of this file does."""
+    """The scan's kernels, the chunk-local pair and the recurrence's, sit
+    under ``layer<i>/gdn/scan`` (where ``gdn_scan_ms`` looks): the forward
+    ones in the forward pass and in the recomputed copy (the recurrence's
+    backward pass reads the chunk-local kernel's outputs and the entering
+    states), the backward ones in the backward pass. The counter says which
+    tiling each got, with its four labels: each kernel is traced once for a
+    shape (the calls are jitted inline, so the recomputed copy and further
+    layers of the same shape re-bind the traced kernel; the recurrence's
+    forward twice, as the forward pass runs it and as the rule's forward,
+    which keeps the entering states), which is why this test scans at a
+    chunk no other test of this file does."""
     step, *args = gpt_step("full", **{**LINEAR, "gdn_chunk": 32})
     text = step.lower(*args).as_text(debug_info=True)
-    scopes = set(re.findall(r'loc\("([^"]*)/hvd_gdn_(fwd|bwd)/', text))
-    assert {kernel for _, kernel in scopes} == {"fwd", "bwd"}
+    scopes = set(re.findall(
+        r'loc\("([^"]*)/hvd_gdn_(fwd|bwd|rec_fwd|rec_bwd)/', text))
+    assert {kernel for _, kernel in scopes} == {"fwd", "bwd", "rec_fwd",
+                                                "rec_bwd"}
     for scope, kernel in scopes:
         assert scope.endswith("/gdn/scan") and "layer0" in scope, scope
         assert "layer1" not in scope
         assert ("transpose(jvp(layer0))" in scope
-                and "rematted_computation" not in scope) == (kernel == "bwd"), \
-            scope
-    assert any("rematted_computation" in scope for scope, _ in scopes)
+                and "rematted_computation" not in scope) \
+            == kernel.endswith("bwd"), scope
+    for kernel in ("fwd", "rec_fwd"):
+        assert any("rematted_computation" in scope
+                   for scope, name in scopes if name == kernel)
     family = hvd.metrics()["hvdtpu_spmd_gdn_kernel_traces_total"]
     samples = {tuple(sorted(labels.items())): count
                for _, labels, count in family["samples"]}
     assert samples == {
-        (("chunk", "32"), ("heads_per_block", "2"), ("kernel", kernel),
+        (("chunk", "32"), ("heads_per_block", heads), ("kernel", kernel),
          ("operand_dtype", "float32")): traces
-        for kernel, traces in ((gated_delta.KERNEL_FWD, 1.0),
-                               (gated_delta.KERNEL_BWD, 1.0))}
+        for kernel, heads, traces in (
+            (gated_delta.KERNEL_FWD, "2", 1.0),
+            (gated_delta.KERNEL_BWD, "2", 1.0),
+            (gated_delta.KERNEL_REC_FWD, "4", 2.0),
+            (gated_delta.KERNEL_REC_BWD, "4", 1.0))}
 
 
 @pytest.mark.parametrize("more", [{}, SPARSE], ids=["dense", "sparse"])
