@@ -33,7 +33,8 @@ as above, ``"ssm"``, a Mamba-2 state-space mixer (input projection,
 causal depthwise convolution, the chunked scan of
 :mod:`horovod_tpu.ops.ssd`, gated RMSNorm, output projection), or ``"gdn"``,
 a gated-delta-rule linear-attention mixer (input projections, the same
-convolution, the chunked scan of :mod:`horovod_tpu.ops.gated_delta`, an
+convolution, the chunked scan of :mod:`horovod_tpu.ops.gated_delta` at key
+and value heads of any size, the writing strength a sigmoid or twice one, an
 RMSNorm a head and then the gate, output projection). A recurrent layer runs
 on the sequence and the heads one rank holds: under a bound tp or sp axis it
 raises (``_ssm_mixer``, ``_gdn_mixer``). The dense feed-forward may be
@@ -57,8 +58,11 @@ given outright (``GPTConfig.layers``: dense layers before expert layers,
 window attention beside full) or resolved from ``layer_kinds``, ``moe_every``
 and ``gated_mlp`` in :func:`layer_plan` and nowhere else. ``init_params``,
 ``param_specs`` and ``_block`` read the plan, never which keys a layer's
-parameters hold. With ``post_norm`` each branch is normed after as well as
-before it is added (``x + N2(f(N1(x)))``).
+parameters hold. **Where a block's norms sit is said once too**:
+:func:`norm_placement` resolves ``GPTConfig.norms`` (``"pre"``: ``x +
+f(N(x))``, the default; ``"pre_post"``: ``x + N2(f(N1(x)))``; ``"post"``: ``x
++ N(f(x))``, no norm before a branch) and the older ``post_norm`` into ``(a
+norm before each branch, a norm after it)``, which those three read.
 """
 
 from __future__ import annotations
@@ -175,13 +179,17 @@ class GPTConfig:
     # A gated-delta-rule mixer has gdn_key_heads query/key heads of
     # gdn_key_dim and gdn_value_heads value heads of gdn_value_dim (value
     # head h reads key head h // (value heads / key heads)), a convolution
-    # of gdn_conv taps and a scan in chunks of gdn_chunk tokens.
+    # of gdn_conv taps and a scan in chunks of gdn_chunk tokens. The writing
+    # strength beta is sigmoid(b), or with gdn_allow_neg_eigval twice that:
+    # a token's transition I - beta k k^T then has its eigenvalue along the
+    # key in (-1, 1) and not (0, 1).
     gdn_key_heads: int = 4
     gdn_value_heads: int = 8
     gdn_key_dim: int = 64
     gdn_value_dim: int = 64
     gdn_conv: int = 4
     gdn_chunk: int = 64
+    gdn_allow_neg_eigval: bool = False
     # The dense feed-forward as silu(gate) * up (three matrices) instead of
     # gelu(up) (two).
     gated_mlp: bool = False
@@ -211,8 +219,14 @@ class GPTConfig:
     layers: Optional[Tuple[LayerSpec, ...]] = None
     # An expert's width (None: mlp_dim, which stays the dense layers').
     expert_dim: Optional[int] = None
-    # A norm after each branch as well as before: x + N2(f(N1(x))).
+    # A norm after each branch as well as before: x + N2(f(N1(x))). The
+    # older way to say norms="pre_post" (``norm_placement``).
     post_norm: bool = False
+    # Where a block's RMSNorms sit, one of ``NORMS``: "pre" (before each
+    # branch; what None means without post_norm), "pre_post" (before and
+    # after) or "post" (after alone: x + N(f(x))). The norm before the head
+    # is there in all three.
+    norms: Optional[str] = None
     # False: the shared expert is added as it is, with no gate of its own.
     shared_expert_gate: bool = True
     # The router's scores, "softmax" or "sigmoid"; router_bias: a bias
@@ -311,6 +325,27 @@ def layer_plan(cfg: GPTConfig) -> Tuple[LayerSpec, ...]:
     return plan
 
 
+# placement -> (a norm before each branch, a norm after it)
+NORMS = {"pre": (True, False), "pre_post": (True, True),
+         "post": (False, True)}
+
+
+def norm_placement(cfg: GPTConfig) -> Tuple[bool, bool]:
+    """``(before, after)``: whether a block norms each branch's input
+    (parameters ``attn_norm`` / ``ssm_norm`` / ``gdn_norm`` and
+    ``mlp_norm``) and its output (``mixer_post_norm``, ``mlp_post_norm``).
+    ``cfg.norms`` says it; the older ``post_norm`` resolves here, beside
+    the plan, and nowhere else (both given: ``ValueError``)."""
+    if cfg.norms is None:
+        return NORMS["pre_post" if cfg.post_norm else "pre"]
+    if cfg.post_norm or cfg.norms not in NORMS:
+        raise ValueError(
+            f"norms must be one of {tuple(NORMS)} with post_norm left "
+            f"unset beside it, got norms={cfg.norms!r}, "
+            f"post_norm={cfg.post_norm}")
+    return NORMS[cfg.norms]
+
+
 def _init_ssm(key, cfg: GPTConfig, dense) -> dict:
     """A state-space mixer's parameters, initialised as the published
     Mamba-2 code does: ``A`` uniform in [1, 16], the step size log-uniform
@@ -364,6 +399,15 @@ def _init_gdn(key, cfg: GPTConfig, dense) -> dict:
     }
 
 
+def _norm_names(spec: LayerSpec, before: bool, after: bool) -> list:
+    """The keys of a layer's norms over the residual stream: before the
+    mixer (the key carries the mixer's name: ``attn_norm``, ``ssm_norm``,
+    ``gdn_norm``) and the feed-forward, after each."""
+    mixer = "attn" if spec.mixer == "attention" else spec.mixer
+    return ([mixer + "_norm", "mlp_norm"] if before else []) \
+        + (["mixer_post_norm", "mlp_post_norm"] if after else [])
+
+
 def _held(cfg: GPTConfig) -> int:
     """Experts an expert block's matrices hold."""
     held = cfg.num_experts if cfg.experts_held is None else cfg.experts_held
@@ -393,6 +437,7 @@ def init_params(rng, cfg: GPTConfig) -> dict:
             else jnp.ones(shape, jnp.float32)
 
     plan = cfg.plan
+    before, after = norm_placement(cfg)
     keys = jax.random.split(rng, 2 + cfg.num_layers)
     params: dict = {
         "embed": jax.random.normal(keys[0], (cfg.vocab_size, E),
@@ -405,22 +450,16 @@ def init_params(rng, cfg: GPTConfig) -> dict:
     for i, spec in enumerate(plan):
         ks = jax.random.split(keys[2 + i], 8)
         if spec.mixer == "ssm":
-            layer = {"ssm_norm": norm((E,)),
-                     "ssm": _init_ssm(ks[0], cfg, dense),
-                     "mlp_norm": norm((E,))}
+            layer = {"ssm": _init_ssm(ks[0], cfg, dense)}
         elif spec.mixer == "gdn":
-            layer = {"gdn_norm": norm((E,)),
-                     "gdn": _init_gdn(ks[0], cfg, dense),
-                     "mlp_norm": norm((E,))}
+            layer = {"gdn": _init_gdn(ks[0], cfg, dense)}
         else:
             layer = {
-                "attn_norm": norm((E,)),
                 "wq": dense(ks[0], (E, H, 2 * D if cfg.attention_gate else D),
                             E),
                 "wk": dense(ks[1], (E, Hkv, D), E),
                 "wv": dense(ks[2], (E, Hkv, D), E),
                 "wo": dense(ks[3], (H, D, E), H * D),
-                "mlp_norm": norm((E,)),
             }
             if cfg.qk_norm:
                 layer["q_norm"] = jnp.ones((H, D), jnp.float32)
@@ -428,9 +467,8 @@ def init_params(rng, cfg: GPTConfig) -> dict:
             elif cfg.qk_head_norm:
                 layer["q_norm"] = norm((D,))
                 layer["k_norm"] = norm((D,))
-        if cfg.post_norm:
-            layer["mixer_post_norm"] = norm((E,))
-            layer["mlp_post_norm"] = norm((E,))
+        for name in _norm_names(spec, before, after):
+            layer[name] = norm((E,))
         if spec.ff == "experts":
             n_exp, held, Mx = cfg.num_experts, _held(cfg), cfg.expert_width
             layer["moe"] = {
@@ -474,25 +512,24 @@ def param_specs(cfg: GPTConfig) -> dict:
     }
     if not cfg.tie_embeddings:
         specs["lm_head"] = P()
+    before, after = norm_placement(cfg)
     for spec in cfg.plan:
         if spec.mixer == "ssm":
-            layer = {"ssm_norm": P(), "mlp_norm": P(), "ssm": {
+            layer = {"ssm": {
                 name: P() for name in (
                     "in_proj", "conv_w", "conv_b", "dt_bias", "A_log", "D",
                     "norm", "out_proj")}}
         elif spec.mixer == "gdn":
-            layer = {"gdn_norm": P(), "mlp_norm": P(), "gdn": {
+            layer = {"gdn": {
                 name: P() for name in (
                     "in_proj", "in_proj_ba", "conv_w", "dt_bias", "A_log",
                     "norm", "out_proj")}}
         else:
             layer = {
-                "attn_norm": P(),
                 "wq": P(None, tp, None),
                 "wk": P(None, tp, None),
                 "wv": P(None, tp, None),
                 "wo": P(tp, None, None),
-                "mlp_norm": P(),
             }
             if cfg.qk_norm:
                 layer["q_norm"] = P(tp, None)
@@ -500,9 +537,8 @@ def param_specs(cfg: GPTConfig) -> dict:
             elif cfg.qk_head_norm:
                 layer["q_norm"] = P()
                 layer["k_norm"] = P()
-        if cfg.post_norm:
-            layer["mixer_post_norm"] = P()
-            layer["mlp_post_norm"] = P()
+        for name in _norm_names(spec, before, after):
+            layer[name] = P()
         if spec.ff == "experts":
             _held(cfg)
             layer["moe"] = {
@@ -664,9 +700,11 @@ def _gdn_mixer(cfg: GPTConfig, p, h):
     ``[q | k | v | z] = h W_qkvz``, ``[b | a] = h W_ba``; ``[q | k | v]``
     through the causal depthwise convolution (no bias) and SiLU; ``q`` and
     ``k`` L2-normalised a head, ``q`` over the root of its size besides;
-    ``beta = sigmoid(b)``, ``g = -exp(A_log) softplus(a + dt_bias)``, both
-    float32, one a value head; the chunked scan
-    (:func:`horovod_tpu.ops.gated_delta.gated_delta_chunked`); an RMSNorm a
+    ``beta = sigmoid(b)``, or ``2 sigmoid(b)`` under
+    ``cfg.gdn_allow_neg_eigval``, ``g = -exp(A_log) softplus(a + dt_bias)``,
+    both float32, one a value head; the chunked scan
+    (:func:`horovod_tpu.ops.gated_delta.gated_delta_chunked`, which takes
+    key and value heads of any size); an RMSNorm a
     value head (one plain weight of the head's size) and **then** the gate
     ``silu(z)``, where Mamba-2 gates first; ``W_out``. A bound sp or tp axis
     is refused by name, as for a state-space layer."""
@@ -691,11 +729,14 @@ def _gdn_mixer(cfg: GPTConfig, p, h):
 
         b, a = jnp.split(ba.astype(f32), 2, axis=-1)
         g = -jnp.exp(p["A_log"]) * jax.nn.softplus(a + p["dt_bias"])
+        beta_max = 2 if cfg.gdn_allow_neg_eigval else 1
         o, _ = gated_delta_chunked(
             (unit(q) * float(cfg.gdn_key_dim) ** -0.5).astype(cfg.dtype),
             unit(k).astype(cfg.dtype),
             v.reshape(batch, seq, heads, cfg.gdn_value_dim), g,
-            jax.nn.sigmoid(b), chunk=cfg.gdn_chunk, dtype=cfg.dtype)
+            jax.nn.sigmoid(b) if beta_max == 1
+            else float(beta_max) * jax.nn.sigmoid(b),
+            chunk=cfg.gdn_chunk, dtype=cfg.dtype, beta_max=beta_max)
         o = checkpoint_name(o, "gdn_scan_out")
     with jax.named_scope("gate_norm"):
         y = _rmsnorm(o, p["norm"], f32, cfg.norm_eps) * jax.nn.silu(
@@ -799,9 +840,13 @@ def _block(cfg: GPTConfig, spec: LayerSpec, layer_params, x, positions):
     # A window layer's mixer is under ``attn_window``, a full one's under
     # ``attn``: a device trace tells their flash kernels apart by it.
     lp = layer_params
+    norm_before, norm_after = norm_placement(cfg)
+
+    def before(key):
+        return _norm(cfg, x, lp[key]) if norm_before else x
 
     def after(branch, key):
-        if not cfg.post_norm:
+        if not norm_after:
             return branch
         with jax.named_scope("post_norm"):
             return _norm(cfg, branch, lp[key])
@@ -809,7 +854,7 @@ def _block(cfg: GPTConfig, spec: LayerSpec, layer_params, x, positions):
     if spec.mixer == "attention":
         with jax.named_scope("attn" if spec.window is None
                              else "attn_window"):
-            h = _norm(cfg, x, lp["attn_norm"])
+            h = before("attn_norm")
             x = _residual(cfg, x, after(
                 _attention_mixer(cfg, spec, lp, h, positions),
                 "mixer_post_norm"))
@@ -817,18 +862,18 @@ def _block(cfg: GPTConfig, spec: LayerSpec, layer_params, x, positions):
         # A recurrent mixer's scope, its parameters' key and its norm's
         # (``<mixer>_norm``) carry its name.
         with jax.named_scope(spec.mixer):
-            h = _norm(cfg, x, lp[spec.mixer + "_norm"])
+            h = before(spec.mixer + "_norm")
             x = _residual(cfg, x, after(
                 _RECURRENT_MIXERS[spec.mixer](cfg, lp[spec.mixer], h),
                 "mixer_post_norm"))
 
     if spec.ff == "experts":
         with jax.named_scope("moe"):
-            h = _norm(cfg, x, lp["mlp_norm"])
+            h = before("mlp_norm")
             out, aux = _expert_ff(cfg, lp["moe"], h)
             return _residual(cfg, x, after(out, "mlp_post_norm")), aux
     with jax.named_scope("mlp"):
-        h = _norm(cfg, x, lp["mlp_norm"])
+        h = before("mlp_norm")
         return _residual(cfg, x, after(_dense_ff(cfg, spec, lp, h),
                                        "mlp_post_norm")), None
 
@@ -900,7 +945,8 @@ def _forward(params, tokens, positions, cfg: GPTConfig):
     # ``embed``, ``layer<i>`` (with ``attn``, ``attn_window`` (an attention
     # layer with a window), ``ssm`` or ``gdn`` and ``mlp`` or ``moe``
     # inside, from ``_block``, each with ``post_norm`` where the
-    # configuration norms a branch after it too; ``ssm`` and ``gdn`` hold
+    # configuration norms a branch after it (``norm_placement``); ``ssm``
+    # and ``gdn`` hold
     # ``in_proj``, ``conv``, ``scan``, ``gate_norm``, ``out_proj``; ``moe``
     # holds ``router``, ``dispatch``, ``experts``, ``combine`` and
     # ``shared``), ``head``; ``loss_and_aux``

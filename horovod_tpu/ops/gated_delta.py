@@ -7,7 +7,7 @@ first chunk to its last. The plain forms the tests hold them to are
 
 A value head keeps a state ``S`` in ``R^{K x V}`` (keys by values), zero at
 the start of a sequence. With ``alpha_t = exp(g_t)`` in (0, 1] the decay and
-``beta_t`` in [0, 1] the writing strength::
+``beta_t`` in [0, 2] the writing strength::
 
     S_t = alpha_t S_{t-1} + beta_t k_t (v_t - alpha_t S_{t-1}^T k_t)^T
     o_t = S_t^T q_t
@@ -16,7 +16,12 @@ the start of a sequence. With ``alpha_t = exp(g_t)`` in (0, 1] the decay and
 remembered; there is no network here.) The token first reads what the decayed
 state already answers for its key, and writes only the difference: with
 ``beta = 0`` the state only decays, with ``alpha = 1`` it is the ungated
-delta rule. Value head ``h`` of ``Hv`` reads key head ``h // (Hv / Hk)``.
+delta rule. A token's transition ``alpha (I - beta k k^T)`` has, for a unit
+key, the eigenvalue ``alpha (1 - beta)`` along it: in [0, 1) for ``beta`` in
+(0, 1] (a model's ``sigmoid(b)``), in (-1, 1) for ``beta`` in (0, 2) (twice
+it: "negative eigenvalues"); the arithmetic is the same, ``A``'s entries
+double and ``T`` lies further from the identity. Value head ``h`` of ``Hv``
+reads key head ``h // (Hv / Hk)``.
 ``q`` and ``k`` come as the caller made them (the model L2-normalises both
 and scales ``q``: ``models/gpt.py::_gdn_mixer``).
 
@@ -63,7 +68,23 @@ VMEM, forms ``dT`` from the cotangents of ``u_own`` and ``w``, applies ``dA
 ``dk`` (summed over the key head's value heads), ``dv`` (rounded once to
 ``dtype``), ``d cum`` and ``d beta`` (float32, a row a head ``[B, c, Hk, 2
 Hv / Hk, Q]``, turned back outside). Off the TPU the kernels run in Pallas
-interpret mode; on it a shape they do not tile raises (:func:`_tiling`).
+interpret mode; on it a chunk they do not tile raises (:func:`_tiling`).
+
+**Heads of any size.** A key head of ``K`` and a value head of ``V`` come
+and go as published; the four kernels carry a head at the next multiple of
+the lane width (``K`` 96 -> 128, ``V`` 192 -> 256: Mosaic's blocks are whole
+lane tiles), the one place that knows being :func:`gated_delta_chunked`,
+which pads ``q``, ``k``, ``v`` and the initial state with zeros
+(:func:`_to_lanes`) and drops the padded columns of ``o`` and rows and
+columns of the final state. Zeros change no product: ``K K^T`` and ``Q K^T``
+sum over the key lanes, the padded columns of ``u``, ``w``, ``q G`` and ``k
+G_last / G`` are zero, so the padded rows and columns of the state stay zero
+through the decay, the correction and the update, and the padded columns of
+``o`` are exactly zero (the tests hold that). At lane multiples nothing is
+padded and the program is the same. Everything between the kernels
+(``u_own``, ``w``, the kept entering states) is held at the padded sizes; the
+same code runs in interpret mode. ``hvdtpu_spmd_gdn_kernel_traces_total``'s
+``key_lanes`` and ``value_lanes`` say what a head occupies.
 
 **The recurrence's kernels** (``hvd_gdn_rec_fwd``, ``hvd_gdn_rec_bwd``, a
 second ``jax.custom_vjp``: :func:`_recurrence`) read those outputs from HBM,
@@ -128,12 +149,14 @@ KERNEL_BWD = "hvd_gdn_bwd"
 KERNEL_REC_FWD = "hvd_gdn_rec_fwd"
 KERNEL_REC_BWD = "hvd_gdn_rec_bwd"
 _HI = lax.Precision.HIGHEST
-_LANES = 128      # a key or value head's size is a multiple of the lane width
+_LANES = 128      # the lane width: the kernels carry a key or value head at
+                  # the next multiple of it (``_to_lanes``)
 _MAX_CHUNKS = 4   # chunks a grid cell, at most
 _SUBSTITUTE = 32  # rows of the inverse's diagonal blocks made by substitution
 _REC_HEADS = 8    # value heads a grid cell of the recurrence, at most
 _REC_CHUNKS = 4   # chunks a grid cell of the recurrence walks, at most
-_REC_VMEM = 64 << 20  # the recurrence's blocks, twice: 10-16 MiB of a v5e's 128
+_REC_VMEM = 64 << 20  # the recurrence's kernels' limit, of a v5e's 128 MiB:
+                      # half for the blocks in flight (``_rec_heads``)
 
 
 def _check(q, k, v, g, beta):
@@ -263,24 +286,37 @@ def chunks_per_block(n_chunks: int) -> int:
     return _divisor(n_chunks, _MAX_CHUNKS)
 
 
+def _to_lanes(t, *axes: int):
+    """``t`` with each of ``axes`` (the last, if none is named) padded with
+    zeros to the next multiple of the lane width; ``t`` itself where they
+    are multiples already."""
+    pad = [(0, 0)] * t.ndim
+    for axis in axes or (-1,):
+        pad[axis] = (0, -t.shape[axis] % _LANES)
+    return jnp.pad(t, pad) if any(extra for _, extra in pad) else t
+
+
 def _tiling(kernel, key_dim, width, chunk, heads_per_block, dtype):
     """Compiled for the TPU, a shape the kernels do not tile raises here, by
-    name; and, trace time only, the record of a call's tiling behind
-    ``hvd.metrics()``."""
+    name (``key_dim`` and ``width`` are what a head occupies in the kernels:
+    :func:`gated_delta_chunked` pads a head of any size to whole lane tiles,
+    so of its callers only a chunk can be refused); and, trace time only,
+    the record of a call's tiling behind ``hvd.metrics()``."""
     if not _use_interpret() and (
             key_dim % _LANES or width % _LANES or chunk % _SUBLANES):
         raise ValueError(
-            f"{kernel} does not tile chunk={chunk}, key_dim={key_dim}, "
-            f"value_dim={width}: it needs key and value heads whose sizes "
-            f"are multiples of {_LANES} and a chunk that is a multiple of "
-            f"{_SUBLANES}")
+            f"{kernel} does not tile chunk={chunk}, key_lanes={key_dim}, "
+            f"value_lanes={width}: it needs a chunk that is a multiple of "
+            f"{_SUBLANES} and heads carried at multiples of {_LANES} lanes "
+            "(gated_delta_chunked pads a head of any size to that)")
     from .. import runtime
     recorder = runtime.recorder()
     if recorder is not None:
         recorder.note_traced(
             "hvdtpu_spmd_gdn_kernel_traces_total", kernel=kernel, chunk=chunk,
             heads_per_block=heads_per_block,
-            operand_dtype=jnp.dtype(dtype).name)
+            operand_dtype=jnp.dtype(dtype).name, key_lanes=key_dim,
+            value_lanes=width)
 
 
 def _row_sum(t):
@@ -701,6 +737,24 @@ def _rec_bwd_kernel(u_ref, w_ref, attn_ref, qin_ref, kout_ref, decay_ref,
         dstart_ref[0] = dstate[...]
 
 
+def _rec_heads(heads, nc, chunk, key_dim, width, itemsize) -> int:
+    """Value heads a grid cell of the recurrence holds: the most that divide
+    ``heads``, ``_REC_HEADS`` at most, whose blocks in flight (the backward
+    call's, the larger: every operand and cotangent of ``nc`` chunks, the
+    kept entering states, the states' cotangents and the scratch; each block
+    twice, Pallas fetches the next while one is worked on) take half of
+    ``_REC_VMEM`` at most; the other half is the kernel's own values."""
+    def blocks(hb):
+        scan = nc * hb * chunk * (
+            2 * 4 * width + 2 * itemsize * (3 * key_dim + chunk))
+        states = hb * key_dim * width * (nc * itemsize + 3 * 4)
+        return scan + states + nc * chunk * hb * width * itemsize
+
+    return next(hb for hb in range(min(_REC_HEADS, heads), 0, -1)
+                if heads % hb == 0
+                and (hb == 1 or 2 * blocks(hb) <= _REC_VMEM // 2))
+
+
 def _rec_plan(kernel, body, u_own, w, backward: bool):
     """What the recurrence's two calls share: the tiling, the block specs by
     name on the grid ``(batch, block of value heads, block of chunks)``, the
@@ -713,7 +767,8 @@ def _rec_plan(kernel, body, u_own, w, backward: bool):
     ``[B, Hv / hb, c, hb]`` (:func:`_by_head_block`)."""
     n_chunks, batch, heads, chunk, width = u_own.shape
     key_dim = w.shape[-1]
-    hb, nc = _divisor(heads, _REC_HEADS), _divisor(n_chunks, _REC_CHUNKS)
+    nc = _divisor(n_chunks, _REC_CHUNKS)
+    hb = _rec_heads(heads, nc, chunk, key_dim, width, w.dtype.itemsize)
     _tiling(kernel, key_dim, width, chunk, hb, w.dtype)
     blocks = n_chunks // nc
 
@@ -821,13 +876,19 @@ _recurrence.defvjp(_recurrence_fwd, _recurrence_bwd)
 
 
 def gated_delta_chunked(q, k, v, g, beta, chunk: int = 64, *,
-                        dtype: Any = jnp.bfloat16, initial_state=None):
+                        dtype: Any = jnp.bfloat16, initial_state=None,
+                        beta_max: int = 1):
     """The recurrence in chunks of ``chunk`` tokens (a power of two).
-    Arguments as :func:`gated_delta_sequential`; ``dtype`` is the MXU
-    operands' type. Returns ``(o, state)``, ``o`` ``[B, S, Hv, V]`` in
-    ``dtype`` and the float32 state after the last token ``[B, Hv, K, V]``.
-    A length the chunk does not divide is padded with tokens that neither
-    decay (``g = 0``) nor write (``beta = 0``)."""
+    Arguments as :func:`gated_delta_sequential`, key and value heads of any
+    size; ``dtype`` is the MXU operands' type. Returns ``(o, state)``, ``o``
+    ``[B, S, Hv, V]`` in ``dtype`` and the float32 state after the last
+    token ``[B, Hv, K, V]``. A length the chunk does not divide is padded
+    with tokens that neither decay (``g = 0``) nor write (``beta = 0``); a
+    head whose size is no multiple of the lane width is carried with zeros
+    to the next one inside (the module docstring says why that is exact).
+    ``beta_max`` is what the caller's ``beta`` lies under (1: a sigmoid; 2:
+    twice one); it labels the layer's count behind ``hvd.metrics()`` and the
+    arithmetic does not read it."""
     _check(q, k, v, g, beta)
     if chunk < 1 or chunk & (chunk - 1):
         raise ValueError(f"gated delta rule: chunk must be a power of two, "
@@ -847,7 +908,7 @@ def gated_delta_chunked(q, k, v, g, beta, chunk: int = 64, *,
         recorder.note_traced(
             "hvdtpu_spmd_gdn_layer_traces_total", key_heads=key_heads,
             value_heads=heads, key_dim=key_dim, value_dim=width, chunk=chunk,
-            recurrence="kernel", chunks=n_chunks)
+            recurrence="kernel", chunks=n_chunks, beta_max=beta_max)
 
     def chunked(t):
         """``[B, S, H]`` -> ``[B, c, Q, H]``."""
@@ -855,10 +916,11 @@ def gated_delta_chunked(q, k, v, g, beta, chunk: int = 64, *,
 
     cum = jnp.cumsum(chunked(g), axis=2)                    # log G_t
     # The kernels: K K^T, Q K^T, the decays, A, T and T's two products stay
-    # in VMEM; out come the recurrence's operands, a chunk a turn.
+    # in VMEM; out come the recurrence's operands, a chunk a turn. From here
+    # to ``o`` a head is whole lane tiles.
     u_own, w, attn, q_in, k_out = _chunk_local(
-        q.astype(dtype), k.astype(dtype), v.astype(dtype), cum,
-        chunked(beta))
+        _to_lanes(q.astype(dtype)), _to_lanes(k.astype(dtype)),
+        _to_lanes(v.astype(dtype)), cum, chunked(beta))
 
     start = jnp.zeros((batch, heads, key_dim, width), f32) \
         if initial_state is None else initial_state.astype(f32)
@@ -866,5 +928,10 @@ def gated_delta_chunked(q, k, v, g, beta, chunk: int = 64, *,
     # sequence's chunks; o comes out as [B, S, Hv V].
     o, final = _recurrence(u_own, w, attn, q_in, k_out,
                            jnp.exp(cum[:, :, -1]),
-                           _varying_like(start, u_own))
-    return o.reshape(batch, n_chunks * chunk, heads, width)[:, :seq], final
+                           _varying_like(_to_lanes(start, -2, -1), u_own))
+    o = o.reshape(batch, n_chunks * chunk, heads, -1)[:, :seq]
+    if o.shape[-1] != width:
+        o = o[..., :width]
+    if final.shape[2:] != (key_dim, width):
+        final = final[:, :, :key_dim, :width]
+    return o, final

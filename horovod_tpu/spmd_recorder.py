@@ -110,10 +110,11 @@ _TRACED = {
     "hvdtpu_spmd_gdn_layer_traces_total": (
         "Times JAX traced a chunked gated-delta-rule scan (the recomputed "
         "copy of a block counts again), by its key heads, value heads, their "
-        "sizes and the chunk, what the recurrence over chunks ran as and "
-        "over how many chunks a sequence.",
+        "sizes and the chunk, what the recurrence over chunks ran as, over "
+        "how many chunks a sequence, and what the writing strength beta "
+        "lies under (1: a sigmoid; 2: twice one, negative eigenvalues).",
         ("key_heads", "value_heads", "key_dim", "value_dim", "chunk",
-         "recurrence", "chunks")),
+         "recurrence", "chunks", "beta_max")),
     "hvdtpu_spmd_ssd_kernel_traces_total": (
         "Times JAX traced one of the state-space scan's within-chunk "
         "kernels, by kernel and the tiling the call got: the chunk, the "
@@ -123,8 +124,11 @@ _TRACED = {
         "Times JAX traced one of the gated delta rule's kernels (the "
         "chunk-local pair, the recurrence over chunks' pair), by kernel and "
         "the tiling the call got: the chunk, the value heads a grid cell "
-        "holds, the MXU operands' dtype.",
-        ("kernel", "chunk", "heads_per_block", "operand_dtype")),
+        "holds, the MXU operands' dtype, and the lanes a key head and a "
+        "value head occupy in the kernel (their sizes rounded up to the "
+        "lane width: more than key_dim or value_dim is zeros).",
+        ("kernel", "chunk", "heads_per_block", "operand_dtype", "key_lanes",
+         "value_lanes")),
     "hvdtpu_spmd_remat_saved_bytes_total": (
         "Bytes a checkpointed block hands from its forward to its backward "
         "pass beside its input, by remat mode and the name the value "

@@ -14,10 +14,15 @@ top of a ``benchmarks/jobs/*.py`` come from. Lines are appended to
 mechanisms left out, on the shipped program's parameters (``VARIANTS``: the
 band ignored, the selection bias out
 of the choice or never updated, the weights' constant, the norms after the branches or the
-shared expert dropped, the router's product in one bfloat16 pass), against
+shared expert dropped, the router's product in one bfloat16 pass; for a
+linear-attention job the writing strength without its factor of two, ``q``
+scaled for another head size, the norms before the branches instead of
+after, a rotary embedding, no q/k norm, the running sums of the log decays
+in bfloat16), against
 the untouched reference: what a job's tolerances must catch. A variant
 changes the job's ``GPTConfig`` after the job is built, before its step is
-traced; a job without the field it needs fails by name.
+traced (or what the program's modules see, where no field says it); a job
+without the field it needs fails by name.
 """
 
 from __future__ import annotations
@@ -53,6 +58,51 @@ def _router_in_bfloat16():
     moe.jnp = OnePass()
 
 
+def _norms_before(job) -> None:
+    """The norms after the branches applied before them instead, the same
+    weights: the block reads each under the name a norm before a branch
+    has."""
+    from horovod_tpu.models import gpt
+
+    _replace(job, norms="pre")
+
+    def loss(params, *data):
+        layers = [{**lp, "mlp_norm": lp["mlp_post_norm"],
+                   ("attn" if "wq" in lp else "gdn") + "_norm":
+                   lp["mixer_post_norm"]} for lp in params["layers"]]
+        return gpt.loss_fn({**params, "layers": layers}, *data, job.cfg)
+
+    job._loss = loss
+
+
+def _q_scaled_for_heads_of_128() -> None:
+    """The linear mixer's ``q`` times ``1 / sqrt(128)`` (the lanes a key
+    head rides) where the model scales by one over the root of its size."""
+    from horovod_tpu.models import gpt
+
+    real = gpt.gated_delta_chunked
+    gpt.gated_delta_chunked = lambda q, *rest, **kw: real(
+        q * (q.shape[-1] / 128.0) ** 0.5, *rest, **kw)
+
+
+def _decay_sums_in_bfloat16() -> None:
+    """The chunk's running sums of the log decays made in bfloat16:
+    ``ops/gated_delta.py`` sees a ``jax.numpy`` whose ``cumsum`` rounds."""
+    import jax.numpy as jnp
+    from horovod_tpu.ops import gated_delta
+
+    class RoundedSums:
+        def __getattr__(self, name):
+            return getattr(jnp, name)
+
+        @staticmethod
+        def cumsum(x, axis):
+            return jnp.cumsum(x.astype(jnp.bfloat16), axis=axis).astype(
+                jnp.float32)
+
+    gated_delta.jnp = RoundedSums()
+
+
 # name -> what it does to a job already built (its step not yet traced)
 VARIANTS = {
     "full_causal": lambda job: _replace(job, layers=tuple(
@@ -63,6 +113,12 @@ VARIANTS = {
     "no_post_norm": lambda job: _replace(job, post_norm=False),
     "no_shared": lambda job: _replace(job, shared_expert_dim=0),
     "router_bf16": lambda job: _router_in_bfloat16(),
+    "beta_sigmoid": lambda job: _replace(job, gdn_allow_neg_eigval=False),
+    "q_scale_128": lambda job: _q_scaled_for_heads_of_128(),
+    "norms_before": _norms_before,
+    "rope": lambda job: _replace(job, rope=True),
+    "no_qk_norm": lambda job: _replace(job, qk_norm=False),
+    "decay_sums_bf16": lambda job: _decay_sums_in_bfloat16(),
 }
 
 
@@ -110,7 +166,11 @@ def main() -> int:
         for seed in range(args.first_seed, args.first_seed + args.seeds):
             job = jobs.Job(config, traffic, seed)
             if args.variant:
-                job.state()         # the shipped program's, made before
+                # The shipped program's parameters, made before; the
+                # optimizer state comes after the reference, as in run.py
+                # (16 bytes a parameter and the reference's gradient do
+                # not fit a chip together).
+                job._params
                 VARIANTS[args.variant](job)
             line = {"workload": args.workload, "seed": seed,
                     "rehearsal": args.rehearsal, "variant": args.variant}

@@ -6,7 +6,12 @@ compiled kernels to the ``jax.numpy`` expressions they replaced.
         [--rec-heads 4 8] [--rec-chunks 1 2 4] [--rows chunk_local recurrence whole]
 
 At the ``qwen3-next-80b-a3b_s4096`` cell's shapes (4 x 4096 tokens, 16 key and
-32 value heads of 128, chunk 64, bfloat16) it jits and times, host clock
+32 value heads of 128, chunk 64, bfloat16; ``--batch 1 --seq 8192 --key-heads
+30 --value-heads 30 --key-dim 96 --value-dim 192`` are the
+``olmo-hybrid-7b_s8192`` cell's: the kernels alone are then given what
+``gated_delta_chunked`` gives them, heads padded with zeros to whole lane
+tiles, 128 by 256, and the ``whole`` row the published sizes) it jits and
+times, host clock
 around ``block_until_ready``: ``hvd_gdn_fwd``; ``hvd_gdn_bwd``; the chunk-local
 part forward and backward through the ``custom_vjp``; the same through the
 plain expression (XLA writes the ``[chunk, chunk]`` tensors to HBM, the
@@ -168,6 +173,10 @@ def main() -> int:
          ).astype(dtype)
     k = unit(jax.random.normal(ks[1], (B, S, Hk, K))).astype(dtype)
     v = jax.random.normal(ks[2], (B, S, Hv, V), dtype)
+    published = (q, k, v)
+    # What the kernels are called with: a head on whole lane tiles.
+    q, k, v = (gd._to_lanes(t) for t in published)
+    K, V = q.shape[-1], v.shape[-1]
     g = -0.3 * jnp.exp(jax.random.normal(ks[3], (B, S, Hv)))
     beta = jax.nn.sigmoid(jax.random.normal(ks[4], (B, S, Hv))) \
         .reshape(B, c, Q, Hv)
@@ -270,7 +279,7 @@ def main() -> int:
         return jnp.sum(jnp.sin(o.astype(f32))) + jnp.sum(final)
 
     if "whole" in args.rows:
-        scan = (q, k, v, g, beta.reshape(B, S, Hv))
+        scan = (*published, g, beta.reshape(B, S, Hv))
         row(what="gated_delta_chunked", fwd_ms=timed(jax.jit(whole), *scan),
             fwd_bwd_ms=timed(jax.jit(jax.value_and_grad(
                 whole, argnums=tuple(range(5)))), *scan))

@@ -122,10 +122,14 @@ def test_ssd_kernel_compiles_for_v5e(one_chip, mosaic, shape, kernel):
 
 
 # (B, S, Hk, Hv, K, V, Q, dtype): the qwen3-next-80b-a3b_s4096 cell's scan; a
-# small one with a value head a key head and two chunks a sequence.
+# small one with a value head a key head and two chunks a sequence; the
+# olmo-hybrid-7b_s8192 cell's as the kernels carry it (heads of 96 by 192 at
+# 128 by 256 lanes, not square, thirty of them, 128 chunks a sequence).
 GDN_SHAPES = {
     "qwen3-next-80b-a3b_s4096": (4, 4096, 16, 32, 128, 128, 64, jnp.bfloat16),
     "small_one_head_a_key": (1, 128, 2, 2, 128, 128, 64, jnp.bfloat16),
+    "olmo-hybrid-7b_s8192_carried": (1, 8192, 30, 30, 128, 256, 64,
+                                     jnp.bfloat16),
 }
 
 
@@ -165,3 +169,29 @@ def test_gdn_kernel_compiles_for_v5e(one_chip, mosaic, shape, kernel):
                                                              state)
     text = jax.jit(f).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text and f"hvd_gdn_{kernel}" in text
+
+
+@pytest.mark.parametrize("key_dim, width", [(96, 192), (24, 40)])
+def test_gdn_scan_compiles_for_v5e_at_heads_of_any_size(one_chip, mosaic,
+                                                        key_dim, width):
+    """``gated_delta_chunked`` whole, forward and backward, at heads that are
+    no lane multiple (the olmo-hybrid-7b_s8192 cell's, and ones smaller than
+    a tile): the four kernels at padded sizes, the published sizes out."""
+    batch, seq, heads = 1, 1024, 30
+
+    def sds(*dims, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dt, sharding=one_chip)
+
+    def loss(q, k, v, g, beta):
+        o, final = gated_delta.gated_delta_chunked(q, k, v, g, beta,
+                                                   beta_max=2)
+        assert o.shape == v.shape
+        assert final.shape == (batch, heads, key_dim, width)
+        return jnp.sum(o.astype(jnp.float32)) + jnp.sum(final)
+
+    q = sds(batch, seq, heads, key_dim)
+    row = sds(batch, seq, heads, dt=jnp.float32)
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        q, q, sds(batch, seq, heads, width), row, row).compile().as_text()
+    for kernel in ("fwd", "bwd", "rec_fwd", "rec_bwd"):
+        assert f"hvd_gdn_{kernel}" in text

@@ -27,6 +27,14 @@ sequence's first chunk to its last), so every test here runs them too; the
 ``recurrence`` cases take a grid of two blocks of value heads and, at chunks
 of 32, two blocks of chunks, an initial state and a cotangent on the final
 one.
+
+Since PR 37 a head has any size: ``gated_delta_chunked`` carries it inside at
+the next multiple of the lane width, with zeros (the same code here and on
+the chip), and ``beta`` may lie in (0, 2). The ``LAYOUTS`` cases of the
+recurrence test run heads that are no lane multiple and not square (96 by
+192, one value head a key head, six heads: a count eight does not divide),
+heads smaller than a tile, thirty heads, and 128 chunks in a sequence, all
+with ``beta`` drawn in (0, 2); the padded lanes come back exactly zero.
 """
 
 import inspect
@@ -45,7 +53,7 @@ B, HK, HV, K, V = 2, 2, 4, 16, 8
 
 
 def _inputs(seed, seq, decay=0.3, key_heads=HK, batch=B, heads=HV,
-            key_dim=K, width=V):
+            key_dim=K, width=V, beta_max=1.0):
     rng = np.random.default_rng(seed)
 
     def unit(t):
@@ -56,7 +64,7 @@ def _inputs(seed, seq, decay=0.3, key_heads=HK, batch=B, heads=HV,
     k = unit(rng.standard_normal((batch, seq, key_heads, key_dim)))
     v = rng.standard_normal((batch, seq, heads, width))
     g = -decay * np.exp(rng.standard_normal((batch, seq, heads)))
-    beta = 1 / (1 + np.exp(-rng.standard_normal((batch, seq, heads))))
+    beta = beta_max / (1 + np.exp(-rng.standard_normal((batch, seq, heads))))
     return tuple(jnp.asarray(t, jnp.float32) for t in (q, k, v, g, beta))
 
 
@@ -117,25 +125,53 @@ def test_the_kernels_are_the_path():
     assert "lax.scan" not in inspect.getsource(gated_delta_chunked)
 
 
-# Sixteen value heads, two a key head: two of the recurrence's blocks of
-# eight. 170 tokens are six chunks of 32 (two blocks of three) or three of
-# 64 (one block), the last one padded either way.
-RECURRENCE = dict(batch=1, key_heads=8, heads=16, key_dim=16, width=8)
-RECURRENCE_SEQ = 170
+# name -> (what ``_inputs`` takes, tokens, chunk, value heads a grid cell of
+# the recurrence, chunks a cell). ``recurrence``: sixteen value heads, two a
+# key head: two of the recurrence's blocks of eight; 170 tokens are six
+# chunks of 32 (two blocks of three) or three of 64 (one block), the last one
+# padded either way. The others draw ``beta`` in (0, 2) and are carried at
+# padded sizes: ``96 by 192`` is the ``olmo-hybrid-7b_s8192`` cell's head
+# (no lane multiple, not square, one value head a key head) at six heads,
+# which eight does not divide, and a length the chunk does not divide;
+# ``under a tile`` heads of 24 by 40, two value heads a key head; ``thirty
+# heads`` the cell's head count (blocks of six); ``128 chunks`` the cell's
+# chunks a sequence (32 blocks of four on the recurrence's ordered axis).
+LAYOUTS = {
+    "recurrence-32": (dict(batch=1, key_heads=8, heads=16, key_dim=16,
+                           width=8), 170, 32, 8, 3),
+    "recurrence-64": (dict(batch=1, key_heads=8, heads=16, key_dim=16,
+                           width=8), 170, 64, 8, 3),
+    "96 by 192": (dict(batch=1, key_heads=6, heads=6, key_dim=96, width=192,
+                       beta_max=2.0), 150, 64, 6, 3),
+    "under a tile": (dict(batch=2, key_heads=3, heads=6, key_dim=24,
+                          width=40, beta_max=2.0), 37, 16, 6, 3),
+    "thirty heads": (dict(batch=1, key_heads=30, heads=30, key_dim=8,
+                          width=16, beta_max=2.0), 48, 16, 6, 3),
+    "128 chunks": (dict(batch=1, key_heads=1, heads=2, key_dim=8, width=8,
+                        beta_max=2.0), 2048, 16, 2, 4),
+}
 
 
-@pytest.mark.parametrize("chunk", [32, 64])
+@pytest.mark.parametrize("layout", list(LAYOUTS))
 @pytest.mark.parametrize("what", ["o and final", "q", "k", "v", "g", "beta",
                                   "initial_state"])
-def test_recurrence_kernels_match_sequential(chunk, what):
+def test_recurrence_kernels_match_sequential(monkeypatch, layout, what):
     """``o``, the final state and the gradient of every input, the initial
-    state's too, of a linear form of both outputs."""
+    state's too, of a linear form of both outputs, element by element; the
+    outputs carry the sizes given, and what the kernels carried beyond them
+    comes back exactly zero."""
+    shape, seq, chunk, heads_per_block, chunks_per_block = LAYOUTS[layout]
     rng = np.random.default_rng(18)
-    args = _inputs(19, RECURRENCE_SEQ, **RECURRENCE) + (jnp.asarray(
-        0.5 * rng.standard_normal((1, 16, 16, 8)), jnp.float32),)
-    assert gated_delta._divisor(16, gated_delta._REC_HEADS) == 8
-    assert gated_delta._divisor(-(-RECURRENCE_SEQ // chunk),
-                                gated_delta._REC_CHUNKS) == 3
+    q, k, v, g, beta = _inputs(19, seq, **shape)
+    state_shape = (v.shape[0], v.shape[2], k.shape[3], v.shape[3])
+    args = (q, k, v, g, beta, jnp.asarray(
+        0.5 * rng.standard_normal(state_shape), jnp.float32))
+    n_chunks = -(-seq // chunk)
+    lanes = [-(-n // 128) * 128 for n in state_shape[2:]]
+    assert gated_delta._rec_heads(v.shape[2], chunks_per_block, chunk,
+                                  *lanes, 4) == heads_per_block
+    assert gated_delta._divisor(n_chunks, gated_delta._REC_CHUNKS) \
+        == chunks_per_block
 
     def chunked(*a):
         return gated_delta_chunked(*a[:5], chunk=chunk, dtype=jnp.float32,
@@ -145,8 +181,22 @@ def test_recurrence_kernels_match_sequential(chunk, what):
         return gated_delta_sequential(*a[:5], initial_state=a[5])
 
     if what == "o and final":
-        for got, want in zip(chunked(*args), sequential(*args)):
-            _close(got, want, 2e-5)
+        carried = []
+        real = gated_delta._recurrence
+        monkeypatch.setattr(gated_delta, "_recurrence", lambda *a: (
+            carried.append(real(*a)) or carried[-1]))
+        got, want = chunked(*args), sequential(*args)
+        for have, ref in zip(got, want, strict=True):
+            assert have.shape == ref.shape
+            _close(have, ref, 2e-5)
+        # What the kernels carried: whole lane tiles a head, zeros beyond
+        # the sizes given.
+        (o, final), = carried
+        o = o.reshape(o.shape[:2] + (v.shape[2], -1))
+        assert o.shape[-1] == lanes[1] and final.shape[2:] == tuple(lanes)
+        assert not np.any(np.asarray(o[..., v.shape[3]:]))
+        assert not np.any(np.asarray(final[:, :, k.shape[3]:]))
+        assert not np.any(np.asarray(final[..., v.shape[3]:]))
         return
     co, cs = (jnp.asarray(rng.standard_normal(t.shape), jnp.float32)
               for t in jax.eval_shape(sequential, *args))
@@ -292,27 +342,37 @@ def test_equal_keys_do_not_blow_the_inverse_up():
 
 @pytest.mark.parametrize("substitute", [1, 8, 32, 64])
 @pytest.mark.parametrize("size", [16, 64])
+@pytest.mark.parametrize("beta", [1.0, 2.0])
 def test_the_kernels_inverse_is_unit_lower_inverse(monkeypatch, size,
-                                                   substitute):
+                                                   substitute, beta):
     """The inverse as the kernels make it (diagonal blocks of ``substitute``
     rows by forward substitution, then ``_inverse``'s rounds; 32 ships)
-    against the plain form, on a random matrix and on the equal-keys one."""
+    against the plain form, on a random matrix and on the equal-keys one, at
+    ``beta = 1`` and at ``beta = 2`` (``A``'s entries doubled: all twos
+    below the diagonal, whose inverse alternates ``-2, 2, -2`` down each
+    column)."""
     monkeypatch.setattr(gated_delta, "_SUBSTITUTE", substitute)
     rng = np.random.default_rng(16)
-    a = jnp.asarray(np.tril(rng.standard_normal((size, size)), -1),
-                    jnp.float32)
+    a = beta * jnp.asarray(np.tril(rng.standard_normal((size, size)), -1),
+                           jnp.float32)
     _close(gated_delta._inverse_in_vmem(a), unit_lower_inverse(a), 1e-5)
-    ones = jnp.tril(jnp.ones((size, size), jnp.float32), -1)
-    want = np.eye(size, dtype=np.float32) \
-        - np.eye(size, k=-1, dtype=np.float32)
-    np.testing.assert_allclose(gated_delta._inverse_in_vmem(ones), want,
+    equal = beta * jnp.tril(jnp.ones((size, size), jnp.float32), -1)
+    rows, cols = np.indices((size, size))
+    want = np.where(rows == cols, 1.0, np.where(
+        rows > cols, -beta * (1.0 - beta) ** np.maximum(rows - cols - 1, 0),
+        0.0))
+    np.testing.assert_allclose(unit_lower_inverse(equal), want, atol=1e-6)
+    np.testing.assert_allclose(gated_delta._inverse_in_vmem(equal), want,
                                atol=1e-6)
 
 
-@pytest.mark.parametrize("noise, dtype, tol", [
-    (0.0, jnp.float32, 1e-5), (0.05, jnp.float32, 1e-4),
-    (0.0, jnp.bfloat16, 5e-2)], ids=["equal", "nearly equal", "bfloat16"])
-def test_equal_keys_through_the_kernels(noise, dtype, tol):
+@pytest.mark.parametrize("noise, dtype, tol, beta", [
+    (0.0, jnp.float32, 1e-5, 1.0), (0.05, jnp.float32, 1e-4, 1.0),
+    (0.0, jnp.bfloat16, 5e-2, 1.0), (0.0, jnp.float32, 1e-4, 2.0),
+    (0.05, jnp.float32, 2e-4, 2.0)],
+    ids=["equal", "nearly equal", "bfloat16", "equal, beta 2",
+         "nearly equal, beta 2"])
+def test_equal_keys_through_the_kernels(noise, dtype, tol, beta):
     """Every key the same at alpha = beta = 1, through the kernel path:
     ``A`` is all ones below the diagonal, each token unwrites the one before
     and the output is ``<q, k> v_t``; an inverse by powers is off by orders
@@ -320,7 +380,14 @@ def test_equal_keys_through_the_kernels(noise, dtype, tol):
     same inverse with its arithmetic in bfloat16 reads 1.7e-2 here, the
     float32 one 1e-6 (all ones are exact in any type, so the first case
     cannot see that). With bfloat16 operands the keys' own rounding shows
-    (3.7e-2 seen)."""
+    (3.7e-2 seen). At ``beta = 2`` a token's transition is the reflection
+    ``I - 2 k k^T`` (eigenvalue -1 along the key): ``A`` is all twos, each
+    token overwrites the one before with the opposite sign, and ``T``'s
+    entries alternate ``-2, 2`` without growing; the outputs are alternating
+    sums of all earlier values (up to 68 here for 4 at ``beta = 1``), which
+    float32 holds to 1.4e-5 and 3.7e-5 of the largest. Nothing decays under
+    a reflection, so bfloat16 keys' rounding adds up over the 128 tokens
+    (0.4 of the largest, the sequential form in bfloat16 alike): no case."""
     seq = 128
     rng = np.random.default_rng(17)
     k = rng.standard_normal(128) \
@@ -328,9 +395,9 @@ def test_equal_keys_through_the_kernels(noise, dtype, tol):
     k = jnp.asarray(k / np.linalg.norm(k, axis=-1, keepdims=True),
                     jnp.float32)
     v = jnp.asarray(rng.standard_normal((1, seq, 2, 128)), jnp.float32)
-    zeros, ones = jnp.zeros((1, seq, 2)), jnp.ones((1, seq, 2))
+    zeros, ones = jnp.zeros((1, seq, 2)), beta * jnp.ones((1, seq, 2))
     want, want_s = gated_delta_sequential(k, k, v, zeros, ones)
-    if not noise:
+    if not noise and beta == 1.0:
         _close(want, v, 1e-5)
     o, s = gated_delta_chunked(k, k, v, zeros, ones, chunk=64, dtype=dtype)
     _close(o, want, tol)

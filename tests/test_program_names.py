@@ -159,7 +159,8 @@ def test_linear_attention_step_holds_the_gdn_kernels_under_their_scope(
     ones in the forward pass and in the recomputed copy (the recurrence's
     backward pass reads the chunk-local kernel's outputs and the entering
     states), the backward ones in the backward pass. The counter says which
-    tiling each got, with its four labels: each kernel is traced once for a
+    tiling each got, with its six labels (heads of 8 ride one lane tile of
+    128): each kernel is traced once for a
     shape (the calls are jitted inline, so the recomputed copy and further
     layers of the same shape re-bind the traced kernel; the recurrence's
     forward twice, as the forward pass runs it and as the rule's forward,
@@ -185,7 +186,8 @@ def test_linear_attention_step_holds_the_gdn_kernels_under_their_scope(
                for _, labels, count in family["samples"]}
     assert samples == {
         (("chunk", "32"), ("heads_per_block", heads), ("kernel", kernel),
-         ("operand_dtype", "float32")): traces
+         ("key_lanes", "128"), ("operand_dtype", "float32"),
+         ("value_lanes", "128")): traces
         for kernel, heads, traces in (
             (gated_delta.KERNEL_FWD, "2", 1.0),
             (gated_delta.KERNEL_BWD, "2", 1.0),
