@@ -81,7 +81,7 @@ from jax.sharding import PartitionSpec as P
 from ..ops.attention import default_attention, repeat_kv_heads, rope
 from ..ops.flash_attention import flash_attention
 from ..ops.gated_delta import gated_delta_chunked
-from ..ops.ssd import causal_conv1d, ssd_chunked
+from ..ops.ssd import causal_conv_silu, ssd_chunked
 from ..parallel.ring_attention import ring_attention_p
 from ..parallel.ulysses import ulysses_attention_p
 
@@ -672,11 +672,13 @@ def _ssm_mixer(cfg: GPTConfig, p, h):
     groups, state = cfg.ssm_groups, cfg.ssm_state
     with jax.named_scope("in_proj"):
         zxbcdt = jnp.einsum("bse,ef->bsf", h, p["in_proj"].astype(cfg.dtype))
-        z, xbc, dt = jnp.split(
+        z, _, dt = jnp.split(
             zxbcdt, [inner, inner + cfg.ssm_conv_dim], axis=-1)
     with jax.named_scope("conv"):
-        xbc = jax.nn.silu(causal_conv1d(xbc, p["conv_w"], p["conv_b"])
-                          ).astype(cfg.dtype)
+        # xBC read in place, out of the projection's output; tokens on the
+        # lanes, as the scan's kernels read x, B and C.
+        xbc = causal_conv_silu(zxbcdt, p["conv_w"], p["conv_b"], first=inner,
+                               minor="tokens")
         x, b_in, c_in = jnp.split(
             xbc, [inner, inner + groups * state], axis=-1)
     with jax.named_scope("scan"):
@@ -715,11 +717,17 @@ def _gdn_mixer(cfg: GPTConfig, p, h):
     key_inner, conv_dim = cfg.gdn_key_inner, cfg.gdn_conv_dim
     with jax.named_scope("in_proj"):
         qkvz = jnp.einsum("bse,ef->bsf", h, p["in_proj"].astype(cfg.dtype))
-        qkv, z = jnp.split(qkvz, [conv_dim], axis=-1)
+        z = qkvz[..., conv_dim:]
         ba = jnp.einsum("bse,ef->bsf", h, p["in_proj_ba"].astype(cfg.dtype))
     with jax.named_scope("conv"):
-        qkv = jax.nn.silu(causal_conv1d(qkv, p["conv_w"], None)
-                          ).astype(cfg.dtype)
+        # q, k and v read in place, out of the projection's output. Channels
+        # on the lanes where the scan's kernels read q, k and v as they
+        # leave here; where a head is carried to whole lane tiles first,
+        # that copy turns the tensor round and XLA holds it tokens-minor up
+        # to there (PERF.md, Findings, PR 38).
+        whole = cfg.gdn_key_dim % 128 == 0 and cfg.gdn_value_dim % 128 == 0
+        qkv = causal_conv_silu(qkvz, p["conv_w"], None,
+                               minor="channels" if whole else "tokens")
         q, k, v = jnp.split(qkv, [key_inner, 2 * key_inner], axis=-1)
     with jax.named_scope("scan"):
         def unit(t):

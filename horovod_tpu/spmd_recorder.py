@@ -129,6 +129,14 @@ _TRACED = {
         "lane width: more than key_dim or value_dim is zeros).",
         ("kernel", "chunk", "heads_per_block", "operand_dtype", "key_lanes",
          "value_lanes")),
+    "hvdtpu_spmd_conv_kernel_traces_total": (
+        "Times JAX traced one of the kernels of the causal depthwise "
+        "convolution in front of a scan (taps, bias and SiLU in one pass), "
+        "by kernel and what the call got from its shapes: the channels, the "
+        "taps, whether a bias is added, the operand's dtype, the tokens by "
+        "channels a grid cell holds, and which of the two is on the lanes.",
+        ("kernel", "channels", "taps", "bias", "operand_dtype", "tile",
+         "minor")),
     "hvdtpu_spmd_remat_saved_bytes_total": (
         "Bytes a checkpointed block hands from its forward to its backward "
         "pass beside its input, by remat mode and the name the value "

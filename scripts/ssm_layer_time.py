@@ -26,7 +26,15 @@ number the next change to ``ops/gated_delta.py`` is measured against first.
 With ``--trace`` the split's innermost-scope rows ``hvd_gdn_fwd`` and
 ``hvd_gdn_bwd`` are the chunk-local kernels and the row ``scan`` what XLA
 runs around them (the recurrence over chunks, the norms, the running sums);
-``scripts/gdn_kernel_time.py`` times the kernels alone.
+``scripts/gdn_kernel_time.py`` times the kernels alone. ``--kind gdn_dense``
+is the ``olmo-hybrid-7b_s8192`` cell's block (1 x 8192 tokens of 3840; 30
+key and 30 value heads of 96 by 192, ``beta`` in (0, 2); the norm after each
+branch; a gated feed-forward of 11008).
+
+With ``--trace`` every kind also prints ``conv_ms_a_layer``: the mixers'
+``conv`` scope (the causal depthwise convolution, its SiLU and the split
+after it: ``ops/ssd.py::causal_conv_silu``, the kernels ``hvd_conv_fwd`` and
+``hvd_conv_bwd``) forward, recomputed and backward, ms a layer a call.
 """
 
 from __future__ import annotations
@@ -52,6 +60,19 @@ def timed(fn, *args, reps: int = 10) -> float:
     return 1e3 * (time.perf_counter() - t0) / reps
 
 
+def conv_scope_ms(path: str, calls: int, layers: int) -> dict:
+    """ms a layer a call of chip 0's operations under a mixer's ``conv``
+    scope in the trace at ``path``, by the pass they run in."""
+    from benchmarks import scope_reduce, trace_reduce
+    names = scope_reduce.program_names(path)
+    ms = {"forward": 0.0, "recomputation": 0.0, "backward": 0.0}
+    for op in trace_reduce.first_device(trace_reduce.read_xplane(path, {})):
+        op_name, cls = names.get(op.name, ("", "unscoped"))
+        if cls in ms and "conv" in scope_reduce.scope_of(op_name):
+            ms[cls] += 1e3 * (op.end - op.start) / (calls * layers)
+    return ms
+
+
 def main() -> int:
     parser = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     parser.add_argument("--repo", default=HERE)
@@ -66,11 +87,18 @@ def main() -> int:
     parser.add_argument("--state", type=int, default=128)
     parser.add_argument("--groups", type=int, default=1)
     parser.add_argument("--chunk", type=int, default=256)
-    parser.add_argument("--kind", choices=("ssm", "gdn"), default="ssm")
+    parser.add_argument("--kind", choices=("ssm", "gdn", "gdn_dense"),
+                        default="ssm")
     parser.add_argument("--trace", action="store_true")
+    parser.add_argument(
+        "--conv-minor", choices=("channels", "tokens"),
+        help="force the axis the convolution's kernels take on the lanes "
+        "(the mixers pick it; this is how their pick was timed)")
     args = parser.parse_args()
     if args.kind == "gdn" and args.batch == 2:
         args.batch = 4
+    if args.kind == "gdn_dense":
+        args.batch, args.seq, args.embed, args.mlp = 1, 8192, 3840, 11008
     root = os.path.abspath(args.repo)
     sys.path.insert(0, root)
 
@@ -82,6 +110,10 @@ def main() -> int:
     device = jax.devices()[0]
     print(f"platform: {device.platform} device_kind: {device.device_kind} "
           f"repo: {root}", flush=True)
+    if args.conv_minor:
+        from horovod_tpu.ops import ssd
+        gpt.causal_conv_silu = lambda *a, minor=None, **k: \
+            ssd.causal_conv_silu(*a, minor=args.conv_minor, **k)
     if args.kind == "gdn":
         cfg = gpt.GPTConfig(
             vocab_size=256, num_layers=args.layers, num_heads=16,
@@ -93,6 +125,16 @@ def main() -> int:
             gdn_chunk=64, moe_every=1, num_experts=512, experts_held=32,
             experts_per_token=10, renormalize_experts=True,
             shared_expert_dim=512)
+    elif args.kind == "gdn_dense":
+        cfg = gpt.GPTConfig(
+            vocab_size=256, num_layers=args.layers, num_heads=30,
+            num_kv_heads=30, head_dim=128, embed_dim=args.embed,
+            mlp_dim=args.mlp, dtype=jnp.bfloat16, tp_axis=None, sp_axis=None,
+            attention="flash", remat="full", norm_eps=1e-6, norms="post",
+            qk_norm=True, rope=False, gated_mlp=True,
+            layer_kinds=("gdn",) * args.layers, gdn_key_heads=30,
+            gdn_value_heads=30, gdn_key_dim=96, gdn_value_dim=192,
+            gdn_chunk=64, gdn_allow_neg_eigval=True, tie_embeddings=False)
     else:
         cfg = gpt.GPTConfig(
             vocab_size=256, num_layers=args.layers, num_heads=32,
@@ -143,6 +185,11 @@ def main() -> int:
                 jax.block_until_ready(fwd(layers, x))
         print(scope_reduce.describe(
             trace_reduce.find_xplane(log_dir + "_fwd")), flush=True)
+        print(json.dumps({
+            "tag": args.tag, "kind": args.kind, "conv_ms_a_layer": {
+                name: round(ms, 4) for name, ms in conv_scope_ms(
+                    trace_reduce.find_xplane(log_dir), 4,
+                    args.layers).items()}}), flush=True)
     return 0
 
 

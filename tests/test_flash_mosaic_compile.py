@@ -195,3 +195,41 @@ def test_gdn_scan_compiles_for_v5e_at_heads_of_any_size(one_chip, mosaic,
         q, q, sds(batch, seq, heads, width), row, row).compile().as_text()
     for kernel in ("fwd", "bwd", "rec_fwd", "rec_bwd"):
         assert f"hvd_gdn_{kernel}" in text
+
+
+# (B, S, C, bias, the axis the mixer asks for on the lanes): the convolution
+# in front of the scan in the three recurrent cells, and a tensor no tile
+# divides (a float32 one: the halo of 16 tokens is two of its tiles).
+CONV_SHAPES = {
+    "qwen3-next-80b-a3b_s4096": (4, 4096, 8192, False, "channels",
+                                 jnp.bfloat16),
+    "olmo-hybrid-7b_s8192": (1, 8192, 11520, False, "channels", jnp.bfloat16),
+    "olmo-hybrid-7b_s8192_tokens": (1, 8192, 11520, False, "tokens",
+                                    jnp.bfloat16),
+    "granite-4.0-h-micro_s4096": (2, 4096, 4352, True, "tokens",
+                                  jnp.bfloat16),
+    "granite-4.0-h-micro_s4096_channels": (2, 4096, 4352, True, "channels",
+                                           jnp.bfloat16),
+    "ragged_float32": (2, 1000, 200, True, "channels", jnp.float32),
+    "ragged_float32_tokens": (2, 1000, 200, True, "tokens", jnp.float32),
+}
+
+
+@pytest.mark.parametrize("kernel", ["fwd", "bwd"])
+@pytest.mark.parametrize("shape", list(CONV_SHAPES))
+def test_conv_kernel_compiles_for_v5e(one_chip, mosaic, shape, kernel):
+    batch, seq, channels, bias, minor, dtype = CONV_SHAPES[shape]
+
+    def sds(*dims, dt=jnp.float32):
+        return jax.ShapeDtypeStruct(dims, dt, sharding=one_chip)
+
+    u = sds(batch, seq, channels, dt=dtype)
+    args = (u, sds(4, channels), sds(channels) if bias else None)
+    if kernel == "fwd":
+        f = ssd._conv_fwd_call
+    else:
+        f, args = ssd._conv_bwd_call, args + (u,)
+    text = jax.jit(functools.partial(
+        f, first=0, tokens_minor=minor == "tokens")).lower(*args).compile() \
+        .as_text()
+    assert "tpu_custom_call" in text and f"hvd_conv_{kernel}" in text

@@ -195,14 +195,56 @@ def test_linear_attention_step_holds_the_gdn_kernels_under_their_scope(
             (gated_delta.KERNEL_REC_BWD, "4", 1.0))}
 
 
+# A state of 16 and value heads of 16: convolutions of 64 channels with a bias
+# and of 96 without one, which no other test of this file traces (the calls
+# are jitted inline: a kernel is traced, and counted, once for a shape).
+@pytest.mark.parametrize("more, layer, mixer, labels", [
+    ({**HYBRID, "ssm_state": 16}, "layer1", "ssm",
+     dict(channels="64", bias="true", tile="128x64", minor="tokens")),
+    ({**LINEAR, "gdn_value_dim": 16}, "layer0", "gdn",
+     dict(channels="96", bias="false", tile="128x96", minor="tokens")),
+], ids=["hybrid", "linear"])
+def test_recurrent_step_holds_the_conv_kernels_under_its_scope(
+        spmd4, more, layer, mixer, labels):
+    """The convolution's two kernels sit under ``layer<i>/<mixer>/conv``
+    (where ``ssm_ms``, ``gdn_ms`` and the passes' metrics find them, and
+    where no scan's metric does): the forward one in the forward pass and in
+    the recomputed copy (the scan's backward pass needs its output), the
+    backward one in the backward pass. The counter says which form a step
+    compiled: a state-space mixer asks for tokens on the lanes, and so does
+    a gated-delta-rule mixer whose heads (8 and 16 wide here) are carried to
+    whole lane tiles before its scan; one with heads of 128 asks for
+    channels."""
+    step, *args = gpt_step("full", **more)
+    text = step.lower(*args).as_text(debug_info=True)
+    scopes = set(re.findall(r'loc\("([^"]*)/hvd_conv_(fwd|bwd)/', text))
+    assert {kernel for _, kernel in scopes} == {"fwd", "bwd"}
+    for scope, kernel in scopes:
+        assert scope.endswith(f"/{mixer}/conv") and layer in scope, scope
+        assert ("transpose(jvp(" in scope
+                and "rematted_computation" not in scope) \
+            == (kernel == "bwd"), scope
+    assert any("rematted_computation" in scope for scope, _ in scopes)
+    assert any("transpose(jvp(" not in scope for scope, _ in scopes)
+    family = hvd.metrics()["hvdtpu_spmd_conv_kernel_traces_total"]
+    samples = {tuple(sorted(labels.items())): count
+               for _, labels, count in family["samples"]}
+    assert samples == {
+        tuple(sorted(dict(labels, kernel=kernel, taps="4",
+                          operand_dtype="float32").items())): 1.0
+        for kernel in (ssd.CONV_KERNEL_FWD, ssd.CONV_KERNEL_BWD)}
+
+
 @pytest.mark.parametrize("more", [{}, SPARSE], ids=["dense", "sparse"])
 def test_a_step_without_a_state_space_layer_traces_no_scan_kernel(spmd4,
                                                                   more):
     step, *args = gpt_step("full", **more)
     text = step.lower(*args).as_text(debug_info=True)
     assert "hvd_ssd" not in text and "hvd_gdn" not in text
+    assert "hvd_conv" not in text
     for family in ("hvdtpu_spmd_ssd_kernel_traces_total",
-                   "hvdtpu_spmd_gdn_kernel_traces_total"):
+                   "hvdtpu_spmd_gdn_kernel_traces_total",
+                   "hvdtpu_spmd_conv_kernel_traces_total"):
         assert not hvd.metrics()[family]["samples"]
 
 
@@ -274,6 +316,16 @@ def _delta(grad: bool):
         jnp.ones((1, 32, 2, 8), jnp.float32))
 
 
+def _conv(grad: bool):
+    w = jnp.ones((4, 8), jnp.float32)
+
+    def conv(u):
+        return ssd.causal_conv_silu(u, w, None).sum()
+
+    return jax.make_jaxpr(jax.grad(conv) if grad else conv)(
+        jnp.ones((1, 32, 8), jnp.float32))
+
+
 FLAT = jnp.linspace(-1.0, 1.0, 512 * 4, dtype=jnp.float32)
 LEVELS = jnp.linspace(0.0, 1.0, 8, dtype=jnp.float32)
 Q8 = jnp.zeros((4, 512), jnp.uint8)
@@ -288,6 +340,8 @@ MN = jnp.zeros((4,), jnp.float32)
     ("hvd_ssd_bwd", lambda: _scan(True)),
     ("hvd_gdn_fwd", lambda: _delta(False)),
     ("hvd_gdn_bwd", lambda: _delta(True)),
+    ("hvd_conv_fwd", lambda: _conv(False)),
+    ("hvd_conv_bwd", lambda: _conv(True)),
     ("hvd_maxmin_quantize", lambda: jax.make_jaxpr(
         lambda x: pk.maxmin_quantize_pallas(x, 4, 512, True))(FLAT)),
     # TPU-only (pltpu.prng_* has no CPU lowering), but it traces anywhere.
@@ -306,7 +360,8 @@ MN = jnp.zeros((4,), jnp.float32)
         lambda q: pk.norm_dequantize_pallas(q, LEVELS, MN, True))(Q8)),
 ])
 def test_kernel_names(name, make):
-    """The thirteen names the benchmark's readers match as strings."""
+    """The fifteen names the benchmark's readers match as strings, or (the
+    convolution's) must not."""
     assert re.search(rf"\bname={name}\b", str(make())), name
 
 
@@ -318,6 +373,13 @@ def test_kernel_name_constants():
     # benchmarks/jobs/gpt_linear_moe_dp.py matches ``^hvd_gdn_``.
     assert (gated_delta.KERNEL_FWD, gated_delta.KERNEL_BWD) == (
         "hvd_gdn_fwd", "hvd_gdn_bwd")
+    # The convolution's sit under ``ssm/conv`` and ``gdn/conv`` and belong to
+    # no scan: a name under either prefix above would be counted into
+    # ``ssm_scan_ms`` or ``gdn_scan_ms`` and their rooflines.
+    assert (ssd.CONV_KERNEL_FWD, ssd.CONV_KERNEL_BWD) == (
+        "hvd_conv_fwd", "hvd_conv_bwd")
+    for name in (ssd.CONV_KERNEL_FWD, ssd.CONV_KERNEL_BWD):
+        assert not re.match(r"^hvd_(ssd|gdn)_", name)
     assert {v for k, v in vars(pk).items() if k.startswith("KERNEL_")} == {
         "hvd_maxmin_quantize", "hvd_maxmin_quantize_stochastic",
         "hvd_maxmin_dequantize", "hvd_maxmin_dequantize_sum",
