@@ -15,6 +15,19 @@ from typing import Any, Optional
 from benchmarks import flops, trace_reduce
 
 
+def window_drift(rates) -> Optional[float]:
+    """How far a window's rate moved from its start to its end: the median
+    of the last third of its segments over the median of the first third,
+    less 1 (negative where the run slowed down). Stationary traffic reads 0
+    to the segments' own noise; a window of fewer than three segments has
+    no thirds and reads None."""
+    third = len(rates) // 3
+    if third < 1:
+        return None
+    return statistics.median(rates[-third:]) \
+        / statistics.median(rates[:third]) - 1.0
+
+
 @dataclasses.dataclass
 class RunContext:
     job: Any                    # the job object (jobs/<job>.py::Job)
@@ -28,6 +41,8 @@ class RunContext:
     memory_peak_bytes: int
     trace: Optional[trace_reduce.Trace] = None
     steps_traced: int = 0
+    rates: tuple = ()           # the untraced window's segments, samples/s,
+                                # in the order they ran
 
     def span_median_ms(self, name: str) -> Optional[float]:
         spans = self.spans.get(name)

@@ -199,6 +199,7 @@ def main(argv=None) -> int:
         else next(iter(peaks.values()))
 
     import horovod_tpu as hvd
+    from benchmarks.context import RunContext, window_drift
 
     # Every program a run uses goes to the persistent cache, however quickly
     # it compiled: a second run of the cell then compiles nothing. The cache
@@ -235,10 +236,10 @@ def main(argv=None) -> int:
     loop.take_spans()
     mark("warm-up")
     # A job may have left its reference running beside the warm-up.
-    check_ok = True
+    compared = {}
     for what, got, want, rtol in finish_check():
         err = abs(got - want) / abs(want)
-        check_ok = check_ok and err <= rtol
+        compared[what] = {"off_by": err, "limit": rtol}
         say(f"check: {what}: program {got:.6g} reference {want:.6g} "
             f"(rel {err:.2e}, allowed {rtol}) "
             f"{'ok' if err <= rtol else 'FAILED'}")
@@ -256,12 +257,16 @@ def main(argv=None) -> int:
         loop.segment()
     window_s = time.perf_counter() - t0
     spans = loop.take_spans()
-    q1, rate, q3 = quartiles(loop.rates)
+    window_rates = tuple(loop.rates)
+    q1, rate, q3 = quartiles(window_rates)
     per_chip = rate / chips
-    say(f"window {window_s:.2f} s: {len(loop.rates)} segments of "
+    drift = window_drift(window_rates)
+    say(f"window {window_s:.2f} s: {len(window_rates)} segments of "
         f"{loop.log_every} steps" + ("" if rehearsal else
         f"; {job.sample}/s/chip median {per_chip:.2f} quartiles "
-        f"{q1 / chips:.2f} {q3 / chips:.2f} (spread {(q3 - q1) / rate:.4%})"))
+        f"{q1 / chips:.2f} {q3 / chips:.2f} (spread {(q3 - q1) / rate:.4%})"
+        + ("" if drift is None else
+           f"; last third over first third of the segments {drift:+.4%}")))
 
     # ---- the traced stretch: the same loop, the profiler on --------------
     trace, steps_traced = None, 0
@@ -293,8 +298,14 @@ def main(argv=None) -> int:
     compiles = job.step._cache_size()
     placed = every_chip_used(hvd, jax.tree.leaves(loop.batch)[0],
                              (loop.state, loop.losses[-1]))
-    correct = bool(check_ok and failed == 0 and compiles == 1 and placed
-                   and all(math.isfinite(v) for v in losses))
+    # What ``correct`` rests on beside the check's rows, each as a count
+    # that has to be nought (warm-up and traced steps count too).
+    compared["losses not finite"] = {
+        "off_by": sum(not math.isfinite(v) for v in losses), "limit": 0}
+    compared["step executables beyond one"] = {"off_by": compiles - 1,
+                                               "limit": 0}
+    compared["chips not all used"] = {"off_by": int(not placed), "limit": 0}
+    correct = all(row["off_by"] <= row["limit"] for row in compared.values())
     say(f"steps {attempted} in the window, {failed} not finite; loss "
         f"{losses[0]:.4f} -> {losses[-1]:.4f}; step executables {compiles}; "
         f"every chip used: {placed}; correct: {correct}")
@@ -324,13 +335,11 @@ def main(argv=None) -> int:
     wanted = metrics_of(bench["end_to_end"], cell["name"])
     values = {job.throughput_metric: per_chip, "setup_s": setup_s}
     if args.trace:
-        from benchmarks.context import RunContext
-
         ctx = RunContext(
             job=job, chips=chips, peak=peak, throughput=rate, spans=spans,
             first_step_s=first_step_s, step_compiles=compiles,
             memory_peak_bytes=memory_peak, trace=trace,
-            steps_traced=steps_traced)
+            steps_traced=steps_traced, rates=window_rates)
         wanted = metrics_of(bench["per_layer"], cell["name"])
         values = {m["name"]: importlib.import_module(
             f"benchmarks.layer_metrics.{m['name']}").read(ctx)
@@ -359,6 +368,16 @@ def main(argv=None) -> int:
             m["name"]: {"value": values[m["name"]], "unit": m["unit"]}
             for m in wanted if values.get(m["name"]) is not None}
         result["device"] = device
+    # Every number ``correct`` rests on, beside its limit: the end of
+    # standard error and the last key of the line, which is what a record
+    # keeps of a run that was not correct. A number that is not finite goes
+    # into the line as its name: JSON has none for it.
+    for what, row in compared.items():
+        print(f"{prefix}compared: {what}: off by {row['off_by']:.3e}, limit "
+              f"{row['limit']:g}", file=sys.stderr, flush=True)
+        if not math.isfinite(row["off_by"]):
+            row["off_by"] = repr(row["off_by"])
+    result["compared"] = compared
     print(json.dumps(result), flush=True)
     return 0
 

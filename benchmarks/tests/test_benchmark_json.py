@@ -95,3 +95,39 @@ def test_metrics(bench):
         assert "setup_s" in reports and len(reports) >= 2
         assert any(cell in m.get("workloads", cells)
                    for m in bench["per_layer"])
+
+
+def entries(key: str) -> list:
+    """BENCHMARK.json's entries under ``key``, read when the tests are
+    collected: a configuration or cell that a later PR appends is a case of
+    the tests below without an edit here."""
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        return json.load(f)[key]
+
+
+@pytest.mark.parametrize("entry", entries("configs"), ids=lambda c: c["name"])
+def test_configuration_file_and_its_twin(entry):
+    """What a configuration's file says of itself, held against its entry,
+    its rehearsal twin and the files its job needs."""
+    with open(os.path.join(ROOT, entry["file"])) as f:
+        config = json.load(f)
+    with open(os.path.join(ROOT, "benchmarks", "tests", "data", "configs",
+                           os.path.basename(entry["file"]))) as f:
+        twin = json.load(f)
+    assert config["name"] == entry["name"]
+    assert config["job"] == twin["job"]
+    for base in ("jobs", "reference"):
+        assert os.path.exists(os.path.join(ROOT, "benchmarks", base,
+                                           config["job"] + ".py"))
+    # Each key cut from the published file stands there with the published
+    # value beside it, and the two differ.
+    assert config["reduced"] == entry["reduced"]
+    for key in entry["reduced"]:
+        assert config["published"][key] != config[key], key
+    if config["source"].startswith("https://"):
+        assert config["source"] == entry["source"]
+    # The twin carries the keys that were cut and the same optimizer: a rate
+    # changed in one and not in the other would rehearse another program.
+    # Why a rate is what it is, the file says itself (``assumed.optimizer``).
+    assert {"job", "optimizer", "check"} | set(entry["reduced"]) <= set(twin)
+    assert twin["optimizer"] == config["optimizer"]

@@ -182,8 +182,8 @@ def test_the_cell_and_its_metrics_are_entries():
     assert len(cell["why"]) <= 200
     assert _json("benchmarks", "traffic", "s8192_b1.json") == {
         "global_batch": 1, "seq_len": 8192, "log_every": 4}
-    assert len(bench["workloads"]) == 9
-    assert sum(w["chips"] == 4 for w in bench["workloads"]) == 1
+    # Later cells are appended: the count and the lists only grow.
+    assert len(bench["workloads"]) >= 9
     listed = {m["name"] for m in bench["per_layer"]
               if CELL in m.get("workloads", [])}
     assert {"gdn_ms", "gdn_scan_ms", "gdn_proj_ms", "gdn_scan_roofline_pct",
@@ -197,7 +197,8 @@ def test_the_cell_and_its_metrics_are_entries():
     assert fill == {
         "name": "gdn_lane_fill_pct", "unit": "%", "better": "higher",
         "source": "program_counter", "layer": fill["layer"],
-        "moves": "tok_s_chip", "workloads": [QWEN_CELL, CELL]}
+        "moves": "tok_s_chip", "workloads": fill["workloads"]}
+    assert fill["workloads"][:2] == [QWEN_CELL, CELL]
     assert fill["layer"] == next(m["layer"] for m in bench["per_layer"]
                                  if m["name"] == "gdn_ms")
     for m in bench["per_layer"]:

@@ -150,5 +150,5 @@ def test_every_new_metric_has_an_entry_and_a_reader():
     for name in NEW:
         assert entries[name]["layer"] == "Expert layer"
         assert entries[name]["moves"] == "tok_s_chip"
-        assert entries[name]["workloads"] == ["olmoe-1b-7b_s4096"]
+        assert entries[name]["workloads"][0] == "olmoe-1b-7b_s4096"
         reader(name)
