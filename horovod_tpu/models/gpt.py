@@ -81,7 +81,7 @@ from jax.sharding import PartitionSpec as P
 from ..ops.attention import default_attention, repeat_kv_heads, rope
 from ..ops.flash_attention import flash_attention
 from ..ops.gated_delta import gated_delta_chunked
-from ..ops.ssd import causal_conv_silu, ssd_chunked
+from ..ops.ssd import _varying_like, causal_conv_silu, ssd_chunked
 from ..parallel.ring_attention import ring_attention_p
 from ..parallel.ulysses import ulysses_attention_p
 
@@ -947,8 +947,10 @@ def _block_fn(cfg: GPTConfig):
                      "(expected 'none', 'full' or 'dots')")
 
 
-def _forward(params, tokens, positions, cfg: GPTConfig):
-    """``(logits, [aux of each expert block])``."""
+def _hidden(params, tokens, positions, cfg: GPTConfig):
+    """``(the normed hidden rows [B, S_local, E], [aux of each expert
+    block])``: everything before the head's matrix, which ``forward`` and
+    ``loss_and_aux`` share."""
     # Scopes name the program's parts in every instruction's ``op_name``:
     # ``embed``, ``layer<i>`` (with ``attn``, ``attn_window`` (an attention
     # layer with a window), ``ssm`` or ``gdn`` and ``mlp`` or ``moe``
@@ -972,28 +974,141 @@ def _forward(params, tokens, positions, cfg: GPTConfig):
         if aux is not None:
             auxes.append(aux)
     with jax.named_scope("head"):
-        x = _norm(cfg, x, params["out_norm"])
-        if cfg.tie_embeddings:
-            logits = jnp.einsum("bse,ve->bsv", x,
-                                params["embed"].astype(cfg.dtype))
-        else:
-            logits = jnp.einsum("bse,ev->bsv", x,
-                                params["lm_head"].astype(cfg.dtype))
-        logits = logits.astype(jnp.float32)
-        if cfg.logits_scaling != 1.0:
-            logits = logits / cfg.logits_scaling
-        return logits, auxes
+        return _norm(cfg, x, params["out_norm"]), auxes
+
+
+def _head_matrix(params, cfg: GPTConfig):
+    """The head's matrix in the compute dtype: the embedding ``[V, E]`` where
+    the two are tied, else ``lm_head`` ``[E, V]``."""
+    name = "embed" if cfg.tie_embeddings else "lm_head"
+    return params[name].astype(cfg.dtype)
+
+
+def _logits(x, w, tied: bool, scaling: float):
+    """Float32 logits of the rows ``x`` ``[..., E]``: the product in the
+    operands' dtype, read in float32, over ``scaling``."""
+    logits = jnp.einsum("...e,ve->...v" if tied else "...e,ev->...v", x, w)
+    logits = logits.astype(jnp.float32)
+    return logits / scaling if scaling != 1.0 else logits
+
+
+# The rows of one block of ``_head_loss`` follow from the token count and the
+# vocabulary alone (``head_loss_rows``): at most ``_HEAD_LOSS_MOST_ROWS``,
+# and fewer where that many rows' logits, read as float32, would pass
+# ``_HEAD_LOSS_BLOCK_BYTES`` (a vocabulary over 131,072). Why 2048: the
+# head's weight gradient is summed over the blocks in float32, 8 V E bytes
+# read and written a block for 2 R V E operations, so a block under a
+# thousand rows waits for memory on a v5e (+20 ms a step at V = 100,352),
+# and at 4096 the dense cells' steps ran 1.5% slower than at 2048 while a
+# block held twice the memory (PERF.md, Findings, PR 41).
+_HEAD_LOSS_MOST_ROWS = 2048
+_HEAD_LOSS_BLOCK_BYTES = 1 << 30
+
+
+def head_loss_rows(tokens: int, vocab: int) -> int:
+    """Rows of one block of the head-and-loss rule for ``tokens`` rows over
+    a vocabulary of ``vocab``: all of them where they fit a block (the two
+    constants above); else the largest multiple of 8 that divides
+    ``tokens``, fits and is more than half of what fits; else an even split
+    into the fewest blocks that fit (the last one is what is left)."""
+    most = max(8, min(_HEAD_LOSS_MOST_ROWS,
+                      _HEAD_LOSS_BLOCK_BYTES // (4 * vocab)))
+    if tokens <= most:
+        return tokens
+    for rows in range(most - most % 8, most // 2, -8):
+        if tokens % rows == 0:
+            return rows
+    blocks = -(-tokens // most)
+    return -(-tokens // (8 * blocks)) * 8
+
+
+def _note_head_loss(cfg: GPTConfig, tokens: int, rows: int) -> None:
+    """Trace time only: tell ``hvd.metrics()`` which blocks the rule got."""
+    from .. import runtime
+    recorder = runtime.recorder()
+    if recorder is not None:
+        recorder.note_traced(
+            "hvdtpu_spmd_head_loss_traces_total", rows_per_block=rows,
+            blocks=-(-tokens // rows), vocab=cfg.vocab_size,
+            tied=str(cfg.tie_embeddings).lower())
+
+
+def _head_loss_block(x, targets, w, tied: bool, scaling: float):
+    """One block of rows: ``(summed loss, d loss / d x [R, E], d loss / d w)``
+    at a cotangent of 1, both gradients float32. The block's logits are made
+    once and die here."""
+    with jax.named_scope("head"):
+        logits = _logits(x, w, tied, scaling)
+    with jax.named_scope("loss"):
+        valid = targets >= 0
+        hit = (lax.broadcasted_iota(jnp.int32, logits.shape, 1)
+               == targets[:, None])
+        lse = jax.nn.logsumexp(logits, axis=-1)
+        picked = jnp.sum(jnp.where(hit, logits, 0.0), axis=-1)
+        num = jnp.sum(jnp.where(valid, lse - picked, 0.0))
+        # softmax - onehot, zero on a masked row; rounded to the compute
+        # dtype once, where autodiff rounds the float32 logits' cotangent.
+        d = jnp.where(valid[:, None],
+                      jnp.exp(logits - lse[:, None]) - hit, 0.0)
+        d = (d / scaling if scaling != 1.0 else d).astype(x.dtype)
+    with jax.named_scope("head"):
+        dx = jnp.einsum("rv,ve->re" if tied else "rv,ev->re", d, w,
+                        preferred_element_type=jnp.float32)
+        dw = jnp.einsum("rv,re->ve" if tied else "rv,re->ev", d, x,
+                        preferred_element_type=jnp.float32)
+    return num, dx, dw
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _head_loss(x, w, targets, tied: bool, scaling: float, rows: int):
+    """The summed cross-entropy of the rows ``x`` ``[T, E]`` under the head
+    ``w`` against ``targets`` ``[T]`` (negative: a masked row), as one rule
+    over blocks of ``rows`` rows: a block's logits, in float32 their
+    log-sum-exp, the block's loss and ``softmax - onehot``, and from that at
+    once the block's part of ``d w`` (summed in float32) and its rows of
+    ``d x``. No ``[T, V]`` array is made or kept; the backward rule scales
+    the two gradients by the sum's cotangent."""
+    return _head_loss_fwd(x, w, targets, tied, scaling, rows)[0]
+
+
+def _head_loss_fwd(x, w, targets, tied, scaling, rows):
+    # A Python loop and no ``lax.scan``: the blocks are few, and as a
+    # ``while`` they ran 7 to 10 ms behind this on the chip (PERF.md,
+    # Findings, PR 41). The last block is the rows that are left.
+    num, dx, dw = zip(*(
+        _head_loss_block(x[i:i + rows], targets[i:i + rows], w, tied, scaling)
+        for i in range(0, x.shape[0], rows)))
+    # The empty slice hands the backward rule the compute dtype.
+    return sum(num), (jnp.concatenate(dx), sum(dw), x[:0])
+
+
+def _head_loss_bwd(tied, scaling, rows, residuals, g):
+    dx, dw, like = residuals
+    with jax.named_scope("head"):
+        # Scaled in float32 and rounded to the compute dtype once, as the
+        # products autodiff makes in that dtype are.
+        return ((g * dx).astype(like.dtype), (g * dw).astype(like.dtype),
+                None)
+
+
+_head_loss.defvjp(_head_loss_fwd, _head_loss_bwd)
 
 
 def forward(params, tokens, positions, cfg: GPTConfig):
-    """Logits ``[B, S_local, vocab]`` (fp32). ``tokens``/``positions`` are this
-    rank's sequence shard (global positions) when sp is active."""
-    return _forward(params, tokens, positions, cfg)[0]
+    """Logits ``[B, S_local, vocab]`` (fp32), for a caller that wants logits
+    (generation, evaluation, the tests): the one function here that makes
+    them whole. ``tokens``/``positions`` are this rank's sequence shard
+    (global positions) when sp is active."""
+    x, _ = _hidden(params, tokens, positions, cfg)
+    with jax.named_scope("head"):
+        return _logits(x, _head_matrix(params, cfg), cfg.tie_embeddings,
+                       cfg.logits_scaling)
 
 
 def loss_fn(params, tokens, targets, positions, cfg: GPTConfig,
             ignore_index: int = -1):
-    """The training loss: :func:`loss_and_aux` without its parts."""
+    """The training loss: :func:`loss_and_aux` without its parts (and, like
+    it, without ever holding the logits :func:`forward` returns)."""
     return loss_and_aux(params, tokens, targets, positions, cfg,
                         ignore_index)[0]
 
@@ -1015,16 +1130,25 @@ def loss_and_aux(params, tokens, targets, positions, cfg: GPTConfig,
     ``cfg.router_probe`` also ``router_inputs`` ``[blocks, T, d]`` and
     ``router_logits`` ``[blocks, T, experts]`` (float32), this rank's (an ep
     group's) tokens as each block's router read them and what it gave.
+
+    It makes no logits ``[B, S_local, vocab]``: head and loss are one rule
+    over blocks of token rows (:func:`_head_loss`, ``head_loss_rows`` rows a
+    block), equal to :func:`forward` and a float32 cross-entropy and their
+    gradients.
     """
-    logits, auxes = _forward(params, tokens, positions, cfg)
+    x, auxes = _hidden(params, tokens, positions, cfg)
+    mask = (targets != ignore_index)
+    with jax.named_scope("head"):
+        x = x.reshape(-1, x.shape[-1])
+        rows = head_loss_rows(x.shape[0], cfg.vocab_size)
+        _note_head_loss(cfg, x.shape[0], rows)
+        # A replicated matrix enters the rule as varying as the rows are: a
+        # rule's cotangent has its input's type, and the mark's transpose
+        # sums the matrix's over the ranks.
+        w = _varying_like(_head_matrix(params, cfg), x)
+    num = _head_loss(x, w, jnp.where(mask, targets, -1).reshape(-1),
+                     cfg.tie_embeddings, cfg.logits_scaling, rows)
     with jax.named_scope("loss"):
-        mask = (targets != ignore_index)
-        safe_targets = jnp.where(mask, targets, 0)
-        logp = jax.nn.log_softmax(logits, axis=-1)
-        tok_loss = -jnp.take_along_axis(logp, safe_targets[..., None],
-                                        axis=-1)[..., 0]
-        tok_loss = jnp.where(mask, tok_loss, 0.0)
-        num = jnp.sum(tok_loss)
         den = jnp.sum(mask.astype(jnp.float32))
         # The token population is sharded over sp (sequence) and, when experts
         # are parallel, over ep (batch rides (dp, ep)); reduce over both so

@@ -92,6 +92,12 @@ _TRACED = {
         "above the diagonal) or skipped_band (wholly below a window's band). "
         "One grid of (q blocks x k blocks) a trace; every head walks it.",
         ("kernel", "mask", "tiles", "seq")),
+    "hvdtpu_spmd_head_loss_traces_total": (
+        "Times JAX traced the GPT's head and loss as one rule over blocks of "
+        "token rows (models/gpt.py::_head_loss), by the rows a block holds "
+        "(from the rank's token count and the vocabulary alone), the blocks, "
+        "the vocabulary and whether the head is the embedding's transpose.",
+        ("rows_per_block", "blocks", "vocab", "tied")),
     "hvdtpu_spmd_moe_layer_traces_total": (
         "Times JAX traced an expert layer (the recomputed copy of a block "
         "counts again), by the experts it routes over, the experts per "

@@ -4,6 +4,7 @@ it, and split its time by scope.
 
     chiprun --chips 1 -- python scripts/ssm_layer_time.py [--repo DIR] [--trace]
     chiprun --chips 1 -- python scripts/ssm_layer_time.py --kind gdn [--trace]
+    chiprun --chips 1 -- python scripts/ssm_layer_time.py --kind head [--rows R ...]
 
 At the ``granite-4.0-h-micro_s4096`` cell's shapes (2 x 4096 tokens of 2048;
 64 heads of 64, state 128, one group, chunk 256; a gated feed-forward of
@@ -31,7 +32,18 @@ is the ``olmo-hybrid-7b_s8192`` cell's block (1 x 8192 tokens of 3840; 30
 key and 30 value heads of 96 by 192, ``beta`` in (0, 2); the norm after each
 branch; a gated feed-forward of 11008).
 
-With ``--trace`` every kind also prints ``conv_ms_a_layer``: the mixers'
+``--kind head`` times no block but the head and the loss alone (``--tokens``
+rows of ``--embed`` against ``--vocab``, ``--tied``, ``--scaling``; without
+``--vocab`` the four cells' shapes whose head is a sixth of the step or
+more): the form ``models/gpt.py`` had before PR 41 (whole float32 logits,
+``log_softmax``, autodiff) against ``gpt._head_loss``, the loss alone and
+the loss with the gradients of the rows and the matrix, at the rows a block
+``gpt.head_loss_rows`` gives and at each of ``--rows``. It holds
+the compiled rule to the old form's numbers (``*_err``: the largest
+difference over the old form's largest value) and exits 1 beyond
+``--tolerance``. A line a shape a block size.
+
+With ``--trace`` every other kind also prints ``conv_ms_a_layer``: the mixers'
 ``conv`` scope (the causal depthwise convolution, its SiLU and the split
 after it: ``ops/ssd.py::causal_conv_silu``, the kernels ``hvd_conv_fwd`` and
 ``hvd_conv_bwd``) forward, recomputed and backward, ms a layer a call.
@@ -40,6 +52,7 @@ after it: ``ops/ssd.py::causal_conv_silu``, the kernels ``hvd_conv_fwd`` and
 from __future__ import annotations
 
 import argparse
+import functools
 import json
 import os
 import sys
@@ -73,6 +86,77 @@ def conv_scope_ms(path: str, calls: int, layers: int) -> dict:
     return ms
 
 
+# (tokens, embed, vocab, tied, logits scaling): granite-4.0-h-micro_s4096,
+# starcoder2-3b_s4096 and _s512, olmoe-1b-7b_s4096, qwen3-next-80b-a3b_s4096.
+HEAD_SHAPES = ((8192, 2048, 100352, True, 8.0), (8192, 3072, 49152, False, 1.0),
+               (8192, 2048, 50304, False, 1.0), (16384, 2048, 18992, False, 1.0))
+
+
+def head_rows(gpt, shape, forced, tolerance: float):
+    """One line a block size for the head and the loss alone at ``shape``."""
+    import jax
+    import jax.numpy as jnp
+    tokens, embed, vocab, tied, scaling = shape
+    keys = jax.random.split(jax.random.PRNGKey(0), 3)
+    x = jax.random.normal(keys[0], (tokens, embed), jnp.bfloat16)
+    w = 0.02 * jax.random.normal(
+        keys[1], (vocab, embed) if tied else (embed, vocab), jnp.float32)
+    targets = jax.random.randint(keys[2], (tokens,), 0, vocab)
+    targets = jnp.where(jnp.arange(tokens) % 17 == 0, -1, targets)
+
+    def old(x, w):
+        logits = gpt._logits(x, w.astype(x.dtype), tied, scaling)
+        logp = jax.nn.log_softmax(logits, axis=-1)
+        picked = jnp.take_along_axis(
+            logp, jnp.maximum(targets, 0)[:, None], axis=-1)[:, 0]
+        return -jnp.sum(jnp.where(targets >= 0, picked, 0.0)) / tokens
+
+    def rule(rows, x, w):
+        return gpt._head_loss(x, w.astype(x.dtype), targets, tied, scaling,
+                              rows) / tokens
+
+    grad = functools.partial(jax.value_and_grad, argnums=(0, 1))
+    old_fwd, old_both = jax.jit(old), jax.jit(grad(old))
+    want = old_both(x, w)
+    base = {"kind": "head", "tokens": tokens, "embed": embed, "vocab": vocab,
+            "tied": tied, "scaling": scaling,
+            "old_fwd_ms": timed(old_fwd, x, w),
+            "old_fwd_bwd_ms": timed(old_both, x, w)}
+    del old_fwd, old_both
+    given = gpt.head_loss_rows(tokens, vocab)
+    for rows in dict.fromkeys([given, *forced]):
+        both = jax.jit(grad(functools.partial(rule, rows)))
+        got = both(x, w)
+        err = [float(jnp.max(jnp.abs(a.astype(jnp.float32) - b))
+                     / jnp.max(jnp.abs(b)))
+               for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(
+                   jax.tree.map(lambda t: t.astype(jnp.float32), want)))]
+        out = dict(base, rows=rows, blocks=-(-tokens // rows),
+                   given=rows == given,
+                   fwd_ms=timed(jax.jit(functools.partial(rule, rows)), x, w),
+                   fwd_bwd_ms=timed(both, x, w),
+                   loss_err=err[0], dx_err=err[1], dw_err=err[2],
+                   ok=max(err) <= tolerance)
+        yield out
+
+
+def head_main(args, gpt, device) -> int:
+    shapes = HEAD_SHAPES if args.vocab is None else (
+        (args.tokens, args.embed, args.vocab, args.tied, args.scaling),)
+    ok = True
+    os.makedirs(os.path.join(HERE, "chiprun_out"), exist_ok=True)
+    for shape in shapes:
+        for out in head_rows(gpt, shape, args.rows, args.tolerance):
+            line = json.dumps(dict(out, tag=args.tag,
+                                   device_kind=device.device_kind))
+            print(line, flush=True)
+            with open(os.path.join(HERE, "chiprun_out",
+                                   "ssm_layer_time.jsonl"), "a") as f:
+                f.write(line + "\n")
+            ok = ok and out["ok"]
+    return 0 if ok else 1
+
+
 def main() -> int:
     parser = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     parser.add_argument("--repo", default=HERE)
@@ -87,8 +171,16 @@ def main() -> int:
     parser.add_argument("--state", type=int, default=128)
     parser.add_argument("--groups", type=int, default=1)
     parser.add_argument("--chunk", type=int, default=256)
-    parser.add_argument("--kind", choices=("ssm", "gdn", "gdn_dense"),
+    parser.add_argument("--kind", choices=("ssm", "gdn", "gdn_dense", "head"),
                         default="ssm")
+    parser.add_argument("--tokens", type=int, default=8192)
+    parser.add_argument("--vocab", type=int)
+    parser.add_argument("--tied", action="store_true")
+    parser.add_argument("--scaling", type=float, default=1.0)
+    parser.add_argument("--rows", type=int, nargs="*", default=[],
+                        help="--kind head: block sizes to time beside the "
+                        "one the shapes give")
+    parser.add_argument("--tolerance", type=float, default=2e-2)
     parser.add_argument("--trace", action="store_true")
     parser.add_argument(
         "--conv-minor", choices=("channels", "tokens"),
@@ -110,6 +202,8 @@ def main() -> int:
     device = jax.devices()[0]
     print(f"platform: {device.platform} device_kind: {device.device_kind} "
           f"repo: {root}", flush=True)
+    if args.kind == "head":
+        return head_main(args, gpt, device)
     if args.conv_minor:
         from horovod_tpu.ops import ssd
         gpt.causal_conv_silu = lambda *a, minor=None, **k: \

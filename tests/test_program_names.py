@@ -97,6 +97,37 @@ def test_compiled_step_carries_the_scopes(spmd4, remat):
     assert not some("noname")
 
 
+@pytest.mark.parametrize("budget,rows,blocks", [
+    (gpt._HEAD_LOSS_BLOCK_BYTES, B * S // 4, 1), (4 * 64 * 40, 32, 4)],
+    ids=["one_block", "four_blocks"])
+def test_head_and_loss_rule_keeps_its_scopes_and_counts_its_trace(
+        spmd4, monkeypatch, budget, rows, blocks):
+    """The rule that makes head and loss in blocks of rows
+    (``gpt._head_loss``) leaves ``head`` on its three products and ``loss``
+    on the log-sum-exp and ``softmax - onehot`` (all in the forward rule:
+    ``jvp(``), and both scopes on the backward pass's scaling; the counter
+    says which blocks a rank's 128 rows over a vocabulary of 64 got."""
+    monkeypatch.setattr(gpt, "_HEAD_LOSS_BLOCK_BYTES", budget)
+    assert gpt.head_loss_rows(B * S // 4, 64) == rows
+    names = op_names(*gpt_step("full"))
+
+    def some(*parts):
+        return any(all(p in n for p in parts) for n in names)
+
+    for product in ("...e,ev->...v", "rv,ev->re", "rv,re->ev"):
+        assert some(f"jvp(head)/{product}/dot_general"), product
+        assert not some("transpose(", f"/{product}/"), product
+    for primitive in ("exp", "log", "reduce_max", "eq"):
+        assert some(f"jvp(loss)/{primitive}"), primitive
+    assert some("transpose(jvp(head))/mul")
+    assert some("transpose(jvp(loss))/div")
+    assert some("jvp(head)/rsqrt")             # the norm before the rule
+    samples = {tuple(sorted(labels.items())): count for _, labels, count in
+               hvd.metrics()["hvdtpu_spmd_head_loss_traces_total"]["samples"]}
+    assert samples == {(("blocks", str(blocks)), ("rows_per_block", str(rows)),
+                        ("tied", "false"), ("vocab", "64")): 1.0}
+
+
 def test_hybrid_step_holds_the_scan_kernels_under_their_scope(spmd4):
     """The scan's two kernels sit under ``layer<i>/ssm/scan`` (where
     ``ssm_scan_ms`` looks), the forward one in the forward pass alone: a
