@@ -27,6 +27,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..ops import pallas_util
+
 DEFAULT_BUCKET_SIZE = 512  # reference: compressor.h:11
 
 
@@ -64,11 +66,12 @@ def unpack_bits(p: jnp.ndarray, bits: int, count: int) -> jnp.ndarray:
 
 
 def _pallas_backend_enabled(override: Optional[bool]) -> bool:
-    """Shared use-Pallas gate: explicit override wins, else the backend must
-    be a TPU (the kernels have no CPU lowering outside interpret mode)."""
+    """The quantizers' use-Pallas gate: an explicit override wins, else the
+    kernel layer's platform test (the kernels have no CPU lowering outside
+    interpret mode)."""
     if override is not None:
         return override
-    return jax.default_backend() == "tpu"
+    return pallas_util.on_tpu()
 
 
 def _seed_from_key(key: Optional[jax.Array]) -> jnp.ndarray:

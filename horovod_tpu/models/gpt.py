@@ -78,10 +78,13 @@ from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
+from .. import runtime
 from ..ops.attention import default_attention, repeat_kv_heads, rope
+from ..ops.conv import causal_conv_silu
 from ..ops.flash_attention import flash_attention
 from ..ops.gated_delta import gated_delta_chunked
-from ..ops.ssd import _varying_like, causal_conv_silu, ssd_chunked
+from ..ops.pallas_util import varying_like
+from ..ops.ssd import ssd_chunked
 from ..parallel.ring_attention import ring_attention_p
 from ..parallel.ulysses import ulysses_attention_p
 
@@ -920,13 +923,10 @@ def _full_policy(prim, *avals, **params):
     forward and backward parts: trace time, nothing per step)."""
     saved = _save_names(prim, *avals, **params)
     if saved:
-        from .. import runtime
-        recorder = runtime.recorder()
-        if recorder is not None:
-            recorder.note_traced(
-                "hvdtpu_spmd_remat_saved_bytes_total",
-                avals[0].size * avals[0].dtype.itemsize,
-                mode="full", name=params["name"])
+        runtime.note_traced(
+            "hvdtpu_spmd_remat_saved_bytes_total",
+            avals[0].size * avals[0].dtype.itemsize,
+            mode="full", name=params["name"])
     return saved
 
 
@@ -1024,13 +1024,10 @@ def head_loss_rows(tokens: int, vocab: int) -> int:
 
 def _note_head_loss(cfg: GPTConfig, tokens: int, rows: int) -> None:
     """Trace time only: tell ``hvd.metrics()`` which blocks the rule got."""
-    from .. import runtime
-    recorder = runtime.recorder()
-    if recorder is not None:
-        recorder.note_traced(
-            "hvdtpu_spmd_head_loss_traces_total", rows_per_block=rows,
-            blocks=-(-tokens // rows), vocab=cfg.vocab_size,
-            tied=str(cfg.tie_embeddings).lower())
+    runtime.note_traced(
+        "hvdtpu_spmd_head_loss_traces_total", rows_per_block=rows,
+        blocks=-(-tokens // rows), vocab=cfg.vocab_size,
+        tied=str(cfg.tie_embeddings).lower())
 
 
 def _head_loss_block(x, targets, w, tied: bool, scaling: float):
@@ -1145,7 +1142,7 @@ def loss_and_aux(params, tokens, targets, positions, cfg: GPTConfig,
         # A replicated matrix enters the rule as varying as the rows are: a
         # rule's cotangent has its input's type, and the mark's transpose
         # sums the matrix's over the ranks.
-        w = _varying_like(_head_matrix(params, cfg), x)
+        w = varying_like(_head_matrix(params, cfg), x)
     num = _head_loss(x, w, jnp.where(mask, targets, -1).reshape(-1),
                      cfg.tie_embeddings, cfg.logits_scaling, rows)
     with jax.named_scope("loss"):
@@ -1187,7 +1184,6 @@ def data_specs(cfg: GPTConfig) -> Tuple[P, P]:
     """(tokens/targets spec, positions spec): batch over dp — and over ep when
     expert parallelism is on (the MoE batch rides (dp, ep), see moe.py) —
     sequence over sp."""
-    from .. import runtime
     dp = runtime.dp_axis()
     batch_axes = (dp, cfg.ep_axis) if cfg.ep_axis else dp
     return P(batch_axes, cfg.sp_axis), P(batch_axes, cfg.sp_axis)

@@ -47,6 +47,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from .. import runtime
 from ..exceptions import HvdTpuInternalError
 from ..utils import logging as log
+from .adasum import adasum_p
 
 
 class ReduceOp(enum.IntEnum):
@@ -180,7 +181,6 @@ def allreduce_p(x, op: ReduceOp = ReduceOp.SUM, axis: Optional[str] = None,
             raise ValueError(f"unknown ReduceOp {op}")
         return _apply_scale(y, postscale_factor)
     if op == ReduceOp.ADASUM:
-        from ..parallel.adasum import adasum_p
         y = adasum_p(x, axis=ax)
     elif op in (ReduceOp.SUM, ReduceOp.AVERAGE):
         y = lax.psum(x, ax)
@@ -368,7 +368,6 @@ def hierarchical_allreduce_p(x, op: ReduceOp = ReduceOp.SUM,
 
     def outer_hop(shard):
         if op == ReduceOp.ADASUM:
-            from ..parallel.adasum import adasum_p
             return adasum_p(shard, axis=outer_axis), None
         return allreduce_p(shard, op=ReduceOp.SUM, axis=outer_axis), None
 

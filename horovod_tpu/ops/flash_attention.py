@@ -62,8 +62,8 @@ Design notes (TPU):
   recomputing the probability tile from q, k and the saved row logsumexp —
   no S x S tensor is ever materialized in either direction.
 * Gate: compiled through Mosaic on the TPU backend, ``interpret=True`` on
-  every other backend (the CPU-mesh tests) — the same gate as the quantize
-  kernels (``compression/quantize.py`` ``_pallas_backend_enabled``).
+  every other backend (the CPU-mesh tests): the package's one platform test,
+  ``ops/pallas_util.py::on_tpu``.
   Interpret mode says nothing about Mosaic lowering; ``chip_smoke.py``
   leg B runs all three kernels compiled, inside a training step.
 """
@@ -81,12 +81,11 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .pallas_util import div as _div, out_vma as _out_vma, rem as _rem, \
-    use_interpret as _use_interpret
+from .. import runtime
+from .pallas_util import LANES, NEG_INF, NT, div as _div, \
+    out_vma as _out_vma, rem as _rem, use_interpret as _use_interpret
 
 _PAD = 128    # the sequence is padded to this many rows, whatever the block
-_LANES = 128  # TPU lane width: softmax stats ride lane-replicated [*, 128]
-_NEG_INF = -1e30  # large-negative instead of -inf: keeps exp/where NaN-free
 # The kernels' names in the compiled program: each becomes the name of its
 # HLO instruction, which is the name of its event in a device trace. The
 # benchmark's readers match these strings (tests/test_program_names.py).
@@ -107,8 +106,6 @@ VMEM_LIMIT_BYTES = 64 * 1024 * 1024
 # twice as fast as 256x256, though it computes the masked half too).
 _CANDIDATES = (1024, 512, 256, 128)
 
-_NT = (((1,), (1,)), ((), ()))  # a · bᵀ: contract the last dim of both
-
 
 def vmem_estimate(kernel: str, block_q: int, block_k: int, d: int,
                   itemsize: int) -> int:
@@ -116,12 +113,12 @@ def vmem_estimate(kernel: str, block_q: int, block_k: int, d: int,
     twice (the pipeline's double buffer), the scratch, and the score-sized
     temporaries of the body (float32, plus the casts to the operand dtype).
     ``d`` counts as a whole lane tile."""
-    d = -(-d // _LANES) * _LANES
+    d = -(-d // LANES) * LANES
     q_blk, k_blk = block_q * d, block_k * d
     tile = block_q * block_k
     if kernel == KERNEL_FWD:
-        blocks = (2 * q_blk + 2 * k_blk) * itemsize + block_q * _LANES * 4
-        scratch = (2 * block_q * _LANES + q_blk) * 4
+        blocks = (2 * q_blk + 2 * k_blk) * itemsize + block_q * LANES * 4
+        scratch = (2 * block_q * LANES + q_blk) * 4
         temps = tile * (3 * 4 + itemsize)
     elif kernel == KERNEL_DKDV:
         blocks = (2 * q_blk + 4 * k_blk) * itemsize + 2 * 8 * block_q * 4
@@ -129,7 +126,7 @@ def vmem_estimate(kernel: str, block_q: int, block_k: int, d: int,
         temps = tile * (4 * 4 + 2 * itemsize)
     else:
         blocks = (3 * q_blk + 2 * k_blk) * itemsize \
-            + 2 * block_q * _LANES * 4
+            + 2 * block_q * LANES * 4
         scratch = q_blk * 4
         temps = tile * (4 * 4 + 2 * itemsize)
     return 2 * blocks + scratch + temps
@@ -164,11 +161,11 @@ def _compiler_params():
 def _lanes(x, n: int):
     """A lane-replicated ``[rows, 128]`` statistic widened (or cut) to
     ``[rows, n]``."""
-    if n == _LANES:
+    if n == LANES:
         return x
-    if n % _LANES == 0:
-        return jnp.tile(x, (1, n // _LANES))
-    if n < _LANES:
+    if n % LANES == 0:
+        return jnp.tile(x, (1, n // LANES))
+    if n < LANES:
         return x[:, :n]
     return jnp.broadcast_to(x[:, :1], (x.shape[0], n))
 
@@ -290,7 +287,7 @@ def _mask_tile(s, q_start, k_start, mask: Mask, kv_len: int,
     k_pos = k_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, k_dim)
     q_pos = q_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, q_dim) \
         if mask.causal else None
-    return jnp.where(mask.keep(q_pos, k_pos, kv_len), s, _NEG_INF)
+    return jnp.where(mask.keep(q_pos, k_pos, kv_len), s, NEG_INF)
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
@@ -302,13 +299,13 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
 
     @pl.when(kj == 0)
     def _init():
-        m_scr[:] = jnp.full_like(m_scr, _NEG_INF)
+        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
     def _step():
         v = v_ref[0]                                     # [BK, D]
-        s = jax.lax.dot_general(q_ref[0], k_ref[0], _NT,
+        s = jax.lax.dot_general(q_ref[0], k_ref[0], NT,
                                 preferred_element_type=jnp.float32)
         s = s * sm_scale                                 # [BQ, BK] float32
         if masked:
@@ -355,7 +352,7 @@ def _dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         do = do_ref[0]
         # The tile transposed, [BK, BQ]: dV and dK are then plain products,
         # and the row statistics broadcast down from [1, BQ] rows.
-        st = jax.lax.dot_general(k_ref[0], q, _NT,
+        st = jax.lax.dot_general(k_ref[0], q, NT,
                                  preferred_element_type=jnp.float32)
         st = st * sm_scale
         if masked:
@@ -364,7 +361,7 @@ def _dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         pt = jnp.exp(st - lse_ref[0])
         dv_scr[:] = dv_scr[:] + jnp.dot(
             pt.astype(do.dtype), do, preferred_element_type=jnp.float32)
-        dpt = jax.lax.dot_general(v_ref[0], do, _NT,
+        dpt = jax.lax.dot_general(v_ref[0], do, NT,
                                   preferred_element_type=jnp.float32)
         dst = pt * (dpt - delta_ref[0])
         dk_scr[:] = dk_scr[:] + jnp.dot(
@@ -390,13 +387,13 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
 
     def _step():
         k = k_ref[0]
-        s = jax.lax.dot_general(q_ref[0], k, _NT,
+        s = jax.lax.dot_general(q_ref[0], k, NT,
                                 preferred_element_type=jnp.float32)
         s = s * sm_scale
         if masked:
             s = _mask_tile(s, qi * block_q, kj * block_k, mask, kv_len)
         p = jnp.exp(s - _lanes(lse_ref[0], block_k))
-        dp = jax.lax.dot_general(do_ref[0], v_ref[0], _NT,
+        dp = jax.lax.dot_general(do_ref[0], v_ref[0], NT,
                                  preferred_element_type=jnp.float32)
         ds = p * (dp - _lanes(delta_ref[0], block_k))
         dq_scr[:] = dq_scr[:] + jnp.dot(
@@ -420,20 +417,17 @@ def _blocks_for(kernel, q, k, mask: Mask, forced):
     """The call's tile, forced or from the table; and, trace time only, the
     record of it and of the tiles its grid keeps and skips behind
     ``hvd.metrics()``."""
-    from .. import runtime
     bq, bk = forced or block_sizes(kernel, q.shape[1], q.shape[2], q.dtype,
                                    mask.causal)
-    recorder = runtime.recorder()
-    if recorder is not None:
-        recorder.note_traced(
-            "hvdtpu_spmd_flash_kernel_traces_total", kernel=kernel,
-            block_q=bq, block_k=bk, operand_dtype=jnp.dtype(q.dtype).name,
-            kv_group=q.shape[0] // k.shape[0])
-        for tiles, n in mask.tiles(q.shape[1] // bq, q.shape[1] // bk,
-                                   bq, bk).items():
-            recorder.note_traced(
-                "hvdtpu_spmd_flash_tiles_total", n, kernel=kernel,
-                mask=mask.name, tiles=tiles, seq=q.shape[1])
+    runtime.note_traced(
+        "hvdtpu_spmd_flash_kernel_traces_total", kernel=kernel, block_q=bq,
+        block_k=bk, operand_dtype=jnp.dtype(q.dtype).name,
+        kv_group=q.shape[0] // k.shape[0])
+    for tiles, n in mask.tiles(q.shape[1] // bq, q.shape[1] // bk,
+                               bq, bk).items():
+        runtime.note_traced(
+            "hvdtpu_spmd_flash_tiles_total", n, kernel=kernel,
+            mask=mask.name, tiles=tiles, seq=q.shape[1])
     return bq, bk
 
 
@@ -471,17 +465,17 @@ def _fwd_call(q, k, v, sm_scale, mask, kv_len, forced=None):
         out_specs=[
             pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
             # lse rides lane-replicated [bh, s, 128] (see _fwd_kernel).
-            pl.BlockSpec((1, bq, _LANES), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, bq, LANES), lambda b, i, j: (b, i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bh, s, d), q.dtype,
                                  vma=_out_vma(q, k, v)),
-            jax.ShapeDtypeStruct((bh, s, _LANES), jnp.float32,
+            jax.ShapeDtypeStruct((bh, s, LANES), jnp.float32,
                                  vma=_out_vma(q, k, v)),
         ],
         scratch_shapes=[
-            pltpu.VMEM((bq, _LANES), jnp.float32),   # running max
-            pltpu.VMEM((bq, _LANES), jnp.float32),   # running denominator
+            pltpu.VMEM((bq, LANES), jnp.float32),   # running max
+            pltpu.VMEM((bq, LANES), jnp.float32),   # running denominator
             pltpu.VMEM((bq, d), jnp.float32),        # output accumulator
         ],
         compiler_params=_compiler_params(),
@@ -571,8 +565,8 @@ def _dq_call(q, k, v, do, lse, delta, sm_scale, mask, kv_len, forced=None):
             pl.BlockSpec((1, bk, d), kv_map),                          # k
             pl.BlockSpec((1, bk, d), kv_map),                          # v
             pl.BlockSpec((1, bq, d), q_map),                           # do
-            pl.BlockSpec((1, bq, _LANES), q_map),                      # lse
-            pl.BlockSpec((1, bq, _LANES), q_map),                      # delta
+            pl.BlockSpec((1, bq, LANES), q_map),                      # lse
+            pl.BlockSpec((1, bq, LANES), q_map),                      # delta
         ],
         out_specs=pl.BlockSpec((1, bq, d), q_map),
         out_shape=jax.ShapeDtypeStruct((bh, s, d), q.dtype,
@@ -614,8 +608,8 @@ def _flash_bhsd_bwd(sm_scale, mask, kv_len, forced, res, do):
     dk, dv = _dkdv_call(q, k, v, do, lse[:, None, :], delta[:, None, :],
                         sm_scale, mask, kv_len, forced)
     dq = _dq_call(q, k, v, do,
-                  jnp.broadcast_to(lse[..., None], (bh, s, _LANES)),
-                  jnp.broadcast_to(delta[..., None], (bh, s, _LANES)),
+                  jnp.broadcast_to(lse[..., None], (bh, s, LANES)),
+                  jnp.broadcast_to(delta[..., None], (bh, s, LANES)),
                   sm_scale, mask, kv_len, forced)
     return dq, dk, dv
 

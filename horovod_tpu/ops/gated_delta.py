@@ -139,8 +139,10 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .pallas_util import out_vma as _out_vma, use_interpret as _use_interpret
-from .ssd import _NEG_INF, _NT, _SUBLANES, _TN, _always, _varying_like
+from .. import runtime
+from .pallas_util import LANES, NEG_INF, NT, SUBLANES, TN, always, \
+    largest_divisor, out_vma as _out_vma, use_interpret as _use_interpret, \
+    varying_like
 
 # The kernels' names in the compiled program and in a device trace; the
 # benchmark's readers match ``^hvd_gdn_`` (tests/test_program_names.py).
@@ -149,8 +151,6 @@ KERNEL_BWD = "hvd_gdn_bwd"
 KERNEL_REC_FWD = "hvd_gdn_rec_fwd"
 KERNEL_REC_BWD = "hvd_gdn_rec_bwd"
 _HI = lax.Precision.HIGHEST
-_LANES = 128      # the lane width: the kernels carry a key or value head at
-                  # the next multiple of it (``_to_lanes``)
 _MAX_CHUNKS = 4   # chunks a grid cell, at most
 _SUBSTITUTE = 32  # rows of the inverse's diagonal blocks made by substitution
 _REC_HEADS = 8    # value heads a grid cell of the recurrence, at most
@@ -199,7 +199,9 @@ def _inverse(a):
     the inverses of the ``b x b`` diagonal blocks, gives ``D_2b = D_b - D_b
     L_b D_b`` with ``L_b`` the lower left ``b x b`` block of every ``2b x
     2b`` diagonal block of ``a`` (the product is ``M22^-1 M21 M11^-1`` there
-    and zero elsewhere). ``D_1 = I``, so the first round is a mask."""
+    and zero elsewhere). ``D_1 = I``, so the first round is a mask.
+    (:func:`unit_lower_inverse`'s body, and with it the reference for
+    :func:`_inverse_in_vmem`: no program path has called it since PR 34.)"""
     size = a.shape[-1]
     rows, cols = np.arange(size)[:, None], np.arange(size)[None, :]
 
@@ -221,7 +223,9 @@ def _inverse(a):
 def unit_lower_inverse(a):
     """``(I + a)^{-1}`` for ``a`` ``[..., n, n]`` strictly lower triangular
     (what lies on or above the diagonal is not read), ``n`` a power of two,
-    in ``a``'s type: the module docstring's inverse by blocks."""
+    in ``a``'s type: the module docstring's inverse by blocks. The reference
+    the tests hold :func:`_inverse_in_vmem` (the kernels' ``T``) to; no
+    program path has called it since PR 34."""
     return _inverse(a)
 
 
@@ -276,23 +280,18 @@ def _inverse_in_vmem(a):
     return inv
 
 
-def _divisor(n: int, most: int) -> int:
-    """The largest divisor of ``n``, ``most`` at most."""
-    return next(d for d in range(min(most, n), 0, -1) if n % d == 0)
-
-
 def chunks_per_block(n_chunks: int) -> int:
     """Chunks a grid cell of the chunk-local kernels walks."""
-    return _divisor(n_chunks, _MAX_CHUNKS)
+    return largest_divisor(n_chunks, _MAX_CHUNKS)
 
 
 def _to_lanes(t, *axes: int):
     """``t`` with each of ``axes`` (the last, if none is named) padded with
-    zeros to the next multiple of the lane width; ``t`` itself where they
-    are multiples already."""
+    zeros to the next multiple of the lane width, at which the kernels carry
+    a key or value head; ``t`` itself where they are multiples already."""
     pad = [(0, 0)] * t.ndim
     for axis in axes or (-1,):
-        pad[axis] = (0, -t.shape[axis] % _LANES)
+        pad[axis] = (0, -t.shape[axis] % LANES)
     return jnp.pad(t, pad) if any(extra for _, extra in pad) else t
 
 
@@ -303,20 +302,16 @@ def _tiling(kernel, key_dim, width, chunk, heads_per_block, dtype):
     so of its callers only a chunk can be refused); and, trace time only,
     the record of a call's tiling behind ``hvd.metrics()``."""
     if not _use_interpret() and (
-            key_dim % _LANES or width % _LANES or chunk % _SUBLANES):
+            key_dim % LANES or width % LANES or chunk % SUBLANES):
         raise ValueError(
             f"{kernel} does not tile chunk={chunk}, key_lanes={key_dim}, "
             f"value_lanes={width}: it needs a chunk that is a multiple of "
-            f"{_SUBLANES} and heads carried at multiples of {_LANES} lanes "
+            f"{SUBLANES} and heads carried at multiples of {LANES} lanes "
             "(gated_delta_chunked pads a head of any size to that)")
-    from .. import runtime
-    recorder = runtime.recorder()
-    if recorder is not None:
-        recorder.note_traced(
-            "hvdtpu_spmd_gdn_kernel_traces_total", kernel=kernel, chunk=chunk,
-            heads_per_block=heads_per_block,
-            operand_dtype=jnp.dtype(dtype).name, key_lanes=key_dim,
-            value_lanes=width)
+    runtime.note_traced(
+        "hvdtpu_spmd_gdn_kernel_traces_total", kernel=kernel, chunk=chunk,
+        heads_per_block=heads_per_block, operand_dtype=jnp.dtype(dtype).name,
+        key_lanes=key_dim, value_lanes=width)
 
 
 def _row_sum(t):
@@ -333,9 +328,9 @@ class _Chunk:
         f32 = jnp.float32
         self.q, self.k = q_ref[0, at, :], k_ref[0, at, :]
         self.qf, self.kf = self.q.astype(f32), self.k.astype(f32)
-        self.kk = lax.dot_general(self.k, self.k, _NT,
+        self.kk = lax.dot_general(self.k, self.k, NT,
                                   preferred_element_type=f32)
-        self.qk = lax.dot_general(self.q, self.k, _NT,
+        self.qk = lax.dot_general(self.q, self.k, NT,
                                   preferred_element_type=f32)
         size = self.q.shape[0]
         rows = lax.broadcasted_iota(jnp.int32, (size, size), 0)
@@ -363,7 +358,7 @@ class _Chunk:
         cum = self.column(cum_ref[0, at, :], head)
         beta = self.column(beta_ref[0, at, :], head)
         decay = jnp.exp(jnp.where(self.lower, cum - self.as_row(cum),
-                                  _NEG_INF))
+                                  NEG_INF))
         a = jnp.where(self.strictly, self.kk * decay * beta, 0.0)
         size = cum.shape[0]
         return beta, decay, jnp.exp(cum), \
@@ -380,7 +375,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, cum_ref, beta_ref, u_ref, w_ref,
     f32, dtype = jnp.float32, q_ref.dtype
     first = pl.program_id(2) * rep
 
-    @_always
+    @always
     def _chunks():
         def one(n, carry):
             at = pl.ds(pl.multiple_of(n * chunk, chunk), chunk)
@@ -418,7 +413,7 @@ def _bwd_kernel(q_ref, k_ref, v_ref, cum_ref, beta_ref, du_ref, dw_ref,
     f32, dtype = jnp.float32, q_ref.dtype
     first = pl.program_id(2) * rep
 
-    @_always
+    @always
     def _chunks():
         row_at = lax.broadcasted_iota(jnp.int32, (2 * rep, chunk), 0)
         is_last = lax.broadcasted_iota(jnp.int32, (chunk, 1), 0) == chunk - 1
@@ -438,12 +433,12 @@ def _bwd_kernel(q_ref, k_ref, v_ref, cum_ref, beta_ref, du_ref, dw_ref,
                 du = du_ref[n, 0, r].astype(dtype)
                 dw = dw_ref[n, 0, r]
                 written = beta * grown
-                dt = lax.dot_general(du, (v * beta).astype(dtype), _NT,
+                dt = lax.dot_general(du, (v * beta).astype(dtype), NT,
                                      preferred_element_type=f32) \
                     + lax.dot_general(dw, (c.kf * written).astype(dtype),
-                                      _NT, preferred_element_type=f32)
-                dvb = lax.dot_general(t, du, _TN, preferred_element_type=f32)
-                dkb = lax.dot_general(t, dw, _TN, preferred_element_type=f32)
+                                      NT, preferred_element_type=f32)
+                dvb = lax.dot_general(t, du, TN, preferred_element_type=f32)
+                dkb = lax.dot_general(t, dw, TN, preferred_element_type=f32)
                 dv_ref[0, at, lanes] = (dvb * beta).astype(dv_ref.dtype)
                 through_k = _row_sum(dkb * c.kf)
                 dk = dk + dkb * written
@@ -451,9 +446,9 @@ def _bwd_kernel(q_ref, k_ref, v_ref, cum_ref, beta_ref, du_ref, dw_ref,
                 dcum = through_k * written
                 # dA = -T^T dT T^T, kept strictly below the diagonal.
                 da = -lax.dot_general(
-                    lax.dot_general(t32, dt, _TN, precision=_HI,
+                    lax.dot_general(t32, dt, TN, precision=_HI,
                                     preferred_element_type=f32),
-                    t32, _NT, precision=_HI, preferred_element_type=f32)
+                    t32, NT, precision=_HI, preferred_element_type=f32)
                 weighted = jnp.where(c.strictly, da, 0.0) * decay
                 dkk = dkk + weighted * beta
                 dbeta = dbeta + _row_sum(weighted * c.kk)
@@ -474,8 +469,8 @@ def _bwd_kernel(q_ref, k_ref, v_ref, cum_ref, beta_ref, du_ref, dw_ref,
                 drows = jnp.where(row_at == rep + r, c.as_row(dbeta), drows)
             dkk, dqk = dkk.astype(dtype), dqk.astype(dtype)
             dk = dk + jnp.dot(dkk, c.k, preferred_element_type=f32) \
-                + lax.dot_general(dkk, c.k, _TN, preferred_element_type=f32) \
-                + lax.dot_general(dqk, c.q, _TN, preferred_element_type=f32)
+                + lax.dot_general(dkk, c.k, TN, preferred_element_type=f32) \
+                + lax.dot_general(dqk, c.q, TN, preferred_element_type=f32)
             dq = dq + jnp.dot(dqk, c.k, preferred_element_type=f32)
             dq_ref[0, at, :] = dq.astype(dq_ref.dtype)
             dk_ref[0, at, :] = dk.astype(dk_ref.dtype)
@@ -636,7 +631,7 @@ def _rec_fwd_kernel(u_ref, w_ref, attn_ref, qin_ref, kout_ref, decay_ref,
     def _start():
         state[...] = start_ref[0]
 
-    @_always
+    @always
     def _chunks():
         def one(n, carry):
             rows = pl.ds(pl.multiple_of(n * chunk, chunk), chunk)
@@ -689,7 +684,7 @@ def _rec_bwd_kernel(u_ref, w_ref, attn_ref, qin_ref, kout_ref, decay_ref,
     def _start():
         dstate[...] = dfinal_ref[0]
 
-    @_always
+    @always
     def _chunks():
         lane = lax.broadcasted_iota(jnp.int32, (1, hb), 1)
 
@@ -706,27 +701,27 @@ def _rec_bwd_kernel(u_ref, w_ref, attn_ref, qin_ref, kout_ref, decay_ref,
                 ds_out = left.astype(dtype)
                 u = (u_ref[n, 0, r] - jnp.dot(
                     w, s, preferred_element_type=f32)).astype(dtype)
-                du = lax.dot_general(attn_ref[n, 0, r], do, _TN,
+                du = lax.dot_general(attn_ref[n, 0, r], do, TN,
                                      preferred_element_type=f32) \
                     + jnp.dot(kout_ref[n, 0, r], ds_out,
                               preferred_element_type=f32)
                 du_ref[n, 0, r] = du
                 du = du.astype(dtype)
                 dattn_ref[n, 0, r] = lax.dot_general(
-                    do, u, _NT, preferred_element_type=f32).astype(dtype)
+                    do, u, NT, preferred_element_type=f32).astype(dtype)
                 dkout_ref[n, 0, r] = lax.dot_general(
-                    u, ds_out, _NT, preferred_element_type=f32).astype(dtype)
+                    u, ds_out, NT, preferred_element_type=f32).astype(dtype)
                 dqin_ref[n, 0, r] = lax.dot_general(
-                    do, s, _NT, preferred_element_type=f32).astype(dtype)
+                    do, s, NT, preferred_element_type=f32).astype(dtype)
                 dw_ref[n, 0, r] = -lax.dot_general(
-                    du, s, _NT, preferred_element_type=f32).astype(dtype)
+                    du, s, NT, preferred_element_type=f32).astype(dtype)
                 ddecays = jnp.where(
                     lane == r, jnp.sum(_row_sum(left * s.astype(f32)),
                                        axis=0, keepdims=True), ddecays)
                 dstate[r] = _along(decays, r, width) * left \
-                    + lax.dot_general(qin, do, _TN,
+                    + lax.dot_general(qin, do, TN,
                                       preferred_element_type=f32) \
-                    - lax.dot_general(w, du, _TN, preferred_element_type=f32)
+                    - lax.dot_general(w, du, TN, preferred_element_type=f32)
             ddecay_ref[0, 0, at, :] = ddecays
             return carry
 
@@ -767,7 +762,7 @@ def _rec_plan(kernel, body, u_own, w, backward: bool):
     ``[B, Hv / hb, c, hb]`` (:func:`_by_head_block`)."""
     n_chunks, batch, heads, chunk, width = u_own.shape
     key_dim = w.shape[-1]
-    nc = _divisor(n_chunks, _REC_CHUNKS)
+    nc = largest_divisor(n_chunks, _REC_CHUNKS)
     hb = _rec_heads(heads, nc, chunk, key_dim, width, w.dtype.itemsize)
     _tiling(kernel, key_dim, width, chunk, hb, w.dtype)
     blocks = n_chunks // nc
@@ -902,13 +897,10 @@ def gated_delta_chunked(q, k, v, g, beta, chunk: int = 64, *,
             jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
             for t in (q, k, v, g, beta))
     n_chunks = (seq + pad) // chunk
-    from .. import runtime
-    recorder = runtime.recorder()
-    if recorder is not None:
-        recorder.note_traced(
-            "hvdtpu_spmd_gdn_layer_traces_total", key_heads=key_heads,
-            value_heads=heads, key_dim=key_dim, value_dim=width, chunk=chunk,
-            recurrence="kernel", chunks=n_chunks, beta_max=beta_max)
+    runtime.note_traced(
+        "hvdtpu_spmd_gdn_layer_traces_total", key_heads=key_heads,
+        value_heads=heads, key_dim=key_dim, value_dim=width, chunk=chunk,
+        recurrence="kernel", chunks=n_chunks, beta_max=beta_max)
 
     def chunked(t):
         """``[B, S, H]`` -> ``[B, c, Q, H]``."""
@@ -928,7 +920,7 @@ def gated_delta_chunked(q, k, v, g, beta, chunk: int = 64, *,
     # sequence's chunks; o comes out as [B, S, Hv V].
     o, final = _recurrence(u_own, w, attn, q_in, k_out,
                            jnp.exp(cum[:, :, -1]),
-                           _varying_like(_to_lanes(start, -2, -1), u_own))
+                           varying_like(_to_lanes(start, -2, -1), u_own))
     o = o.reshape(batch, n_chunks * chunk, heads, -1)[:, :seq]
     if o.shape[-1] != width:
         o = o[..., :width]

@@ -103,6 +103,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
+from .. import runtime
 from ..ops.collectives import pvary
 from .axes import axis_bound as _axis_bound, axis_size as _axis_size
 
@@ -446,14 +447,11 @@ def moe_layer(x, router_w, w_gate, w_up, w_down, top_k: int,
     window_rows = share_rows(T, top_k, experts_local, num_experts) if share \
         else T * top_k
     windowed = window_rows < T * top_k
-    from .. import runtime
-    recorder = runtime.recorder()
-    if recorder is not None:
-        recorder.note_traced(
-            "hvdtpu_spmd_moe_layer_traces_total", experts=num_experts,
-            top_k=top_k, ep=_axis_size(axis), grouped_matmul=GROUPED_MATMUL,
-            held=experts_local, rows=window_rows, score=score,
-            bias=int(bias is not None))
+    runtime.note_traced(
+        "hvdtpu_spmd_moe_layer_traces_total", experts=num_experts,
+        top_k=top_k, ep=_axis_size(axis), grouped_matmul=GROUPED_MATMUL,
+        held=experts_local, rows=window_rows, score=score,
+        bias=int(bias is not None))
 
     with jax.named_scope("router"):
         # The product's own operand: a probe hands out this value and not

@@ -652,6 +652,14 @@ def recorder():
     return _state.recorder
 
 
+def note_traced(family: str, amount: int = 1, **labels) -> None:
+    """One more trace (or ``amount`` more bytes or tiles) of a trace-time
+    family of ``spmd_recorder._TRACED``, from code that JAX is tracing;
+    nothing where there is no recorder (before ``init``, process mode)."""
+    if _state.recorder is not None:
+        _state.recorder.note_traced(family, amount, **labels)
+
+
 def metrics() -> dict:
     """Live-metrics snapshot:
     ``{family: {"type", "help", "samples": [(suffix, labels, value)]}}``

@@ -168,7 +168,10 @@ def main() -> int:
                                         topology_name="v5e:2x2")
     import horovod_tpu as hvd
     from horovod_tpu.compression import quantize
-    quantize._pallas_backend_enabled = lambda *_: True   # through Mosaic
+    from horovod_tpu.ops import pallas_util
+    # Through Mosaic: the kernel layer's platform test, and what a ``--repo``
+    # from before PR 43 asked in its place.
+    pallas_util.on_tpu = quantize._pallas_backend_enabled = lambda *_: True
     hvd.init(devices=topo.devices[:1])
     for name in args.cells:
         print(json.dumps(compile_cell(name, root, args.hlo)), flush=True)

@@ -1,6 +1,6 @@
 #!/usr/bin/env python3
 """Time the causal depthwise convolution with its SiLU alone on the chip:
-``ops/ssd.py::causal_conv_silu`` (the kernels ``hvd_conv_fwd`` and
+``ops/conv.py::causal_conv_silu`` (the kernels ``hvd_conv_fwd`` and
 ``hvd_conv_bwd``) against the ``jax.numpy`` lines it replaced,
 ``silu(causal_conv1d(u, w, b)).astype(u.dtype)`` under autodiff, and hold the
 compiled kernels to them.
@@ -18,7 +18,7 @@ vjp, which XLA computes with the forward's pre-activation made again), the
 bytes a pass must move over that time as a share of 819 GB/s, and the largest
 difference from the plain lines' values and gradients as a share of their
 largest. ``--tokens-a-pass``, ``--passes`` and ``--block`` sweep the cut
-(``ops/ssd.py::_CONV_CUT``, ``_CONV_BLOCK``); without them a row is what
+(``ops/conv.py::_CONV_CUT``, ``_CONV_BLOCK``); without them a row is what
 ships. Rows go to ``chiprun_out/conv_kernel_time.jsonl``. The ``tokens`` rows
 get the tensor as ``[B, C, S]``, as a caller whose neighbours hold it so hands
 it over; which form a mixer should ask for is what its neighbours hold
@@ -70,17 +70,17 @@ def main() -> int:
     import jax
     import jax.numpy as jnp
 
-    from horovod_tpu.ops import ssd
+    from horovod_tpu.ops import conv
 
     device = jax.devices()[0]
     print(f"platform: {device.platform} device_kind: {device.device_kind}",
           flush=True)
-    shipped_cut, shipped_block = dict(ssd._CONV_CUT), ssd._CONV_BLOCK
+    shipped_cut, shipped_block = dict(conv._CONV_CUT), conv._CONV_BLOCK
     os.makedirs(os.path.join(HERE, "chiprun_out"), exist_ok=True)
     f32 = jnp.float32
 
     def plain(u, w, b):
-        return jax.nn.silu(ssd.causal_conv1d(u, w, b)).astype(u.dtype)
+        return jax.nn.silu(conv.causal_conv1d(u, w, b)).astype(u.dtype)
 
     def rel(got, want):
         got, want = got.astype(f32), want.astype(f32)
@@ -104,14 +104,14 @@ def main() -> int:
                 # The calls are jitted inline: a cut traced once is kept.
                 jax.clear_caches()
                 axis, halo, sub, per, most = shipped_cut[name == "tokens"]
-                ssd._CONV_CUT[name == "tokens"] = (
+                conv._CONV_CUT[name == "tokens"] = (
                     axis, halo, cut[0] or sub, per, cut[1] or most)
-                ssd._CONV_BLOCK = cut[2] or shipped_block
-                fn = functools.partial(ssd.causal_conv_silu, minor=name)
+                conv._CONV_BLOCK = cut[2] or shipped_block
+                fn = functools.partial(conv.causal_conv_silu, minor=name)
                 if name == "tokens":
                     # As a caller whose neighbours hold [B, C, S] hands the
                     # tensor over: the swaps either side are bitcasts.
-                    fn = lambda ut, w, b: ssd.causal_conv_silu(  # noqa: E731
+                    fn = lambda ut, w, b: conv.causal_conv_silu(  # noqa: E731
                         ut.swapaxes(1, 2), w, b, minor="tokens").swapaxes(1, 2)
             fwd = jax.jit(fn)
             bwd = jax.jit(lambda u, w, b, dy, fn=fn: jax.vjp(fn, u, w, b)[1](
@@ -119,10 +119,10 @@ def main() -> int:
             out = {"cell": cell, "form": name,
                    "device_kind": device.device_kind}
             if cut is not None:
-                plan = ssd._conv_plan("probe", seq, channels, u.dtype, 4, bias,
-                                      name == "tokens")
+                plan = conv._conv_plan("probe", seq, channels, u.dtype, 4,
+                                       bias, name == "tokens")
                 out.update(tile=f"{plan.tokens}x{plan.channels}",
-                           tokens_a_pass=plan.sub, block=ssd._CONV_BLOCK)
+                           tokens_a_pass=plan.sub, block=conv._CONV_BLOCK)
             turn = (lambda t: t.swapaxes(1, 2).copy()) if name == "tokens" \
                 else (lambda t: t)
             try:
@@ -141,8 +141,8 @@ def main() -> int:
                     out["off_by"] = [rel(g, t) for g, t in zip(got, want)]
             except Exception as e:  # a cut Mosaic refuses: the row says so
                 out["error"] = str(e)[:300]
-            ssd._CONV_CUT.update(shipped_cut)
-            ssd._CONV_BLOCK = shipped_block
+            conv._CONV_CUT.update(shipped_cut)
+            conv._CONV_BLOCK = shipped_block
             line = json.dumps(out)
             print(line, flush=True)
             with open(os.path.join(HERE, "chiprun_out",

@@ -45,7 +45,7 @@ difference over the old form's largest value) and exits 1 beyond
 
 With ``--trace`` every other kind also prints ``conv_ms_a_layer``: the mixers'
 ``conv`` scope (the causal depthwise convolution, its SiLU and the split
-after it: ``ops/ssd.py::causal_conv_silu``, the kernels ``hvd_conv_fwd`` and
+after it: ``ops/conv.py::causal_conv_silu``, the kernels ``hvd_conv_fwd`` and
 ``hvd_conv_bwd``) forward, recomputed and backward, ms a layer a call.
 """
 
@@ -205,9 +205,9 @@ def main() -> int:
     if args.kind == "head":
         return head_main(args, gpt, device)
     if args.conv_minor:
-        from horovod_tpu.ops import ssd
+        shipped = gpt.causal_conv_silu  # ops/conv.py's, whatever ``--repo``
         gpt.causal_conv_silu = lambda *a, minor=None, **k: \
-            ssd.causal_conv_silu(*a, minor=args.conv_minor, **k)
+            shipped(*a, minor=args.conv_minor, **k)
     if args.kind == "gdn":
         cfg = gpt.GPTConfig(
             vocab_size=256, num_layers=args.layers, num_heads=16,

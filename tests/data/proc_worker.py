@@ -118,7 +118,7 @@ except hvd.HvdTpuInternalError as e:
 # adasum
 v = np.zeros(4, np.float32); v[r % 4] = r + 1.0
 out = np.asarray(hvd.allreduce(v, name="ad", op=hvd.Adasum))
-from horovod_tpu.parallel.adasum import adasum_reference
+from horovod_tpu.ops.adasum import adasum_reference
 vals = []
 for i in range(n):
     w = np.zeros(4, np.float32); w[i % 4] = i + 1.0; vals.append(w)

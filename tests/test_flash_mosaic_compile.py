@@ -20,9 +20,8 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from horovod_tpu.compression import quantize
 from horovod_tpu.ops import flash_attention as fa
-from horovod_tpu.ops import gated_delta, ssd
+from horovod_tpu.ops import conv, gated_delta, pallas_util, ssd
 
 
 @pytest.fixture(scope="module")
@@ -50,7 +49,7 @@ def mosaic(monkeypatch):
     """Compile as a program on the chip does: through Mosaic, and without
     the 64-bit types tests/conftest.py turns on (Mosaic has no float64, and
     under them every Python constant in a kernel becomes one)."""
-    monkeypatch.setattr(quantize, "_pallas_backend_enabled", lambda *_: True)
+    monkeypatch.setattr(pallas_util, "on_tpu", lambda: True)
     with jax.enable_x64(False):
         yield
 
@@ -226,9 +225,9 @@ def test_conv_kernel_compiles_for_v5e(one_chip, mosaic, shape, kernel):
     u = sds(batch, seq, channels, dt=dtype)
     args = (u, sds(4, channels), sds(channels) if bias else None)
     if kernel == "fwd":
-        f = ssd._conv_fwd_call
+        f = conv._conv_fwd_call
     else:
-        f, args = ssd._conv_bwd_call, args + (u,)
+        f, args = conv._conv_bwd_call, args + (u,)
     text = jax.jit(functools.partial(
         f, first=0, tokens_minor=minor == "tokens")).lower(*args).compile() \
         .as_text()

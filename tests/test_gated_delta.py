@@ -47,7 +47,7 @@ import pytest
 from horovod_tpu.ops import gated_delta
 from horovod_tpu.ops.gated_delta import (
     gated_delta_chunked, gated_delta_sequential, unit_lower_inverse)
-from horovod_tpu.ops.pallas_util import use_interpret
+from horovod_tpu.ops.pallas_util import largest_divisor, use_interpret
 
 B, HK, HV, K, V = 2, 2, 4, 16, 8
 
@@ -170,7 +170,7 @@ def test_recurrence_kernels_match_sequential(monkeypatch, layout, what):
     lanes = [-(-n // 128) * 128 for n in state_shape[2:]]
     assert gated_delta._rec_heads(v.shape[2], chunks_per_block, chunk,
                                   *lanes, 4) == heads_per_block
-    assert gated_delta._divisor(n_chunks, gated_delta._REC_CHUNKS) \
+    assert largest_divisor(n_chunks, gated_delta._REC_CHUNKS) \
         == chunks_per_block
 
     def chunked(*a):

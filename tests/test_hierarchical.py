@@ -12,7 +12,7 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 import horovod_tpu as hvd
-from horovod_tpu.parallel.adasum import adasum_reference
+from horovod_tpu.ops.adasum import adasum_reference
 
 
 @pytest.fixture

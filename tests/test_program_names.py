@@ -21,7 +21,7 @@ from horovod_tpu.compression import pallas_kernels as pk
 from horovod_tpu.models import gpt
 from horovod_tpu.observability import parse_prometheus_text, sample_value
 from horovod_tpu.ops import flash_attention as fa
-from horovod_tpu.ops import gated_delta, ssd
+from horovod_tpu.ops import conv, gated_delta, ssd
 
 CFG = dict(vocab_size=64, num_layers=2, num_heads=2, num_kv_heads=1,
            head_dim=16, embed_dim=32, mlp_dim=64, tp_axis=None, sp_axis=None,
@@ -263,7 +263,7 @@ def test_recurrent_step_holds_the_conv_kernels_under_its_scope(
     assert samples == {
         tuple(sorted(dict(labels, kernel=kernel, taps="4",
                           operand_dtype="float32").items())): 1.0
-        for kernel in (ssd.CONV_KERNEL_FWD, ssd.CONV_KERNEL_BWD)}
+        for kernel in (conv.CONV_KERNEL_FWD, conv.CONV_KERNEL_BWD)}
 
 
 @pytest.mark.parametrize("more", [{}, SPARSE], ids=["dense", "sparse"])
@@ -350,10 +350,10 @@ def _delta(grad: bool):
 def _conv(grad: bool):
     w = jnp.ones((4, 8), jnp.float32)
 
-    def conv(u):
-        return ssd.causal_conv_silu(u, w, None).sum()
+    def summed(u):
+        return conv.causal_conv_silu(u, w, None).sum()
 
-    return jax.make_jaxpr(jax.grad(conv) if grad else conv)(
+    return jax.make_jaxpr(jax.grad(summed) if grad else summed)(
         jnp.ones((1, 32, 8), jnp.float32))
 
 
@@ -407,9 +407,9 @@ def test_kernel_name_constants():
     # The convolution's sit under ``ssm/conv`` and ``gdn/conv`` and belong to
     # no scan: a name under either prefix above would be counted into
     # ``ssm_scan_ms`` or ``gdn_scan_ms`` and their rooflines.
-    assert (ssd.CONV_KERNEL_FWD, ssd.CONV_KERNEL_BWD) == (
+    assert (conv.CONV_KERNEL_FWD, conv.CONV_KERNEL_BWD) == (
         "hvd_conv_fwd", "hvd_conv_bwd")
-    for name in (ssd.CONV_KERNEL_FWD, ssd.CONV_KERNEL_BWD):
+    for name in (conv.CONV_KERNEL_FWD, conv.CONV_KERNEL_BWD):
         assert not re.match(r"^hvd_(ssd|gdn)_", name)
     assert {v for k, v in vars(pk).items() if k.startswith("KERNEL_")} == {
         "hvd_maxmin_quantize", "hvd_maxmin_quantize_stochastic",

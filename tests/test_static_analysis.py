@@ -314,24 +314,35 @@ class TestClangTargets:
                 f"make {target} without {tool} must say SKIPPED:\n{out}"
 
 
-def _imported_modules(path):
-    """Every module a file imports, as an absolute dotted name: at module
-    level or inside a function, ``import x`` or ``from x import y``
-    (relative ones resolved against the file's package; ``from . import y``
-    gives ``<package>.y``)."""
+def _imported_names(path):
+    """``(module, name)`` of everything a file imports, at module level or
+    inside a function: ``import x`` gives ``(x, None)``, ``from x import y``
+    ``(x, y)``, the module an absolute dotted name (relative ones resolved
+    against the file's package; ``from . import y`` gives ``(<package>,
+    y)``)."""
     package = os.path.relpath(os.path.dirname(path), REPO).split(os.sep)
-    found = set()
     with open(path) as f:
         tree = ast.parse(f.read(), path)
     for node in ast.walk(tree):
         if isinstance(node, ast.Import):
-            found.update(alias.name for alias in node.names)
+            for alias in node.names:
+                yield alias.name, None
         elif isinstance(node, ast.ImportFrom):
             base = package[:len(package) - node.level + 1] if node.level \
                 else []
             module = ".".join(base + ([node.module] if node.module else []))
-            found.add(module)
-            found.update(f"{module}.{alias.name}" for alias in node.names)
+            for alias in node.names:
+                yield module, alias.name
+
+
+def _imported_modules(path):
+    """Every module a file imports, as an absolute dotted name (``from x
+    import y`` counts as ``x`` and as ``x.y``)."""
+    found = set()
+    for module, name in _imported_names(path):
+        found.add(module)
+        if name is not None:
+            found.add(f"{module}.{name}")
     return found
 
 
@@ -341,19 +352,58 @@ def _python_files(*parts):
             for name in names if name.endswith(".py")]
 
 
-class TestLayering:
-    """models/ stands on parallel/ and ops/, never the other way round: a
-    mask change is made in ``ops/attention.py``, the kernels and one row of
-    ``gpt._attention``, and nothing below ``models/`` has to follow it."""
+# The package's boxes from the floor up: every arrow between them points down.
+LAYERS = ("ops", "compression", "parallel", "models")
 
-    @pytest.mark.parametrize("layer", ["ops", "parallel"])
-    def test_nothing_below_models_imports_it(self, layer):
+
+class TestLayering:
+    """``models/ -> parallel/ -> compression/ -> ops/``, and never the other
+    way round: a mask change is made in ``ops/attention.py``, the kernels and
+    one row of ``gpt._attention``, and nothing below ``models/`` has to
+    follow it; whether a kernel compiles is ``ops/``'s to say, and
+    ``compression/`` can shrink without a cell's kernels noticing."""
+
+    @pytest.mark.parametrize("layer", LAYERS[:-1])
+    def test_a_layer_imports_nothing_above_it(self, layer):
+        """At module level or inside a function."""
+        above = tuple(f"horovod_tpu.{name}"
+                      for name in LAYERS[LAYERS.index(layer) + 1:])
         files = _python_files("horovod_tpu", layer)
         assert files, layer
         up = {os.path.relpath(f, REPO): sorted(
             m for m in _imported_modules(f)
-            if m.startswith("horovod_tpu.models")) for f in files}
+            if m.startswith(above)) for f in files}
         assert not {f: m for f, m in up.items() if m}
+
+    def test_no_kernel_module_is_built_from_anothers_private_names(self):
+        """What two files of ``ops/`` share has a public name (the Pallas
+        families': ``ops/pallas_util.py``): an edit to one file's ``_name``
+        moves no other file's kernels."""
+        taken = {os.path.relpath(f, REPO): sorted(
+            f"{module}.{name}" for module, name in _imported_names(f)
+            if module.startswith("horovod_tpu.") and name
+            and name.startswith("_"))
+            for f in _python_files("horovod_tpu", "ops")}
+        assert not {f: names for f, names in taken.items() if names}
+
+    def test_the_kernel_layer_says_itself_whether_a_kernel_compiles(
+            self, monkeypatch):
+        """On the CPU mesh the kernels run interpreted, and the answer comes
+        from ``ops/`` alone: the call imports nothing of ``compression/``
+        (which it asked until PR 43)."""
+        import builtins
+        from horovod_tpu.ops import pallas_util
+        imported, real = [], builtins.__import__
+
+        def spy(name, *args, **kwargs):
+            imported.append(name)
+            return real(name, *args, **kwargs)
+
+        monkeypatch.setattr(builtins, "__import__", spy)
+        interpret = pallas_util.use_interpret()
+        monkeypatch.undo()
+        assert interpret
+        assert not [name for name in imported if "compression" in name]
 
     def test_the_attention_reference_stands_alone(self):
         """Pure ``jax.numpy``: nothing of this package, no flax."""
