@@ -43,13 +43,24 @@ first dimensions of a head, q and k normed a head, attention's output gated
 by a sigmoid of a doubled query projection, every norm's weight centred at
 zero (``1 + w``), the head the embedding's transpose, and the embedding, the
 attention logits, each residual branch and the logits scaled by a constant.
+A ``"cca"`` mixer is softmax attention whose q and k are made in a
+compressed latent and mixed over the sequence before the heads attend
+(:func:`_cca_mixer`: two stacked causal convolutions, a q/k mean, an L2 norm
+a head under a learned key temperature, half of the value from the token
+before); it runs under the scope ``attn`` and through ``_attention`` as an
+``"attention"`` mixer does, on one rank's whole sequence and all its heads.
 An expert block may hold a share of its router's experts
 (``experts_held``, ``first_expert``: ``parallel/moe.py``), renormalise a
 token's weights, add a shared expert every token goes through (under a
 sigmoid gate of its own or as it is), be as wide as ``expert_dim`` where the
 dense feed-forward is ``mlp_dim``, and score with a sigmoid under a
 selection bias that is state, not a parameter (``router_bias``,
-:func:`update_router_bias`, :func:`trainable`).
+:func:`update_router_bias`, :func:`trainable`). Its router is one matrix
+or, under ``router_kind="mlp"``, an MLP on a down-projection that adds the
+down-projection of the expert block before it (:func:`_mlp_router`): that
+state leaves a block beside ``x`` and enters the next. Under
+``residual_scaling`` a sublayer joins the stream as ``a_r (x + b_r) + a_h
+(f(N(x)) + b_h)``, four learned vectors a sublayer (:func:`_residual`).
 
 **What each layer is, is said once**: ``GPTConfig.plan``, one
 :class:`LayerSpec` a layer (the mixer, an attention layer's window and
@@ -89,7 +100,8 @@ from ..parallel.ring_attention import ring_attention_p
 from ..parallel.ulysses import ulysses_attention_p
 
 
-MIXERS = ("attention", "ssm", "gdn")
+MIXERS = ("attention", "cca", "ssm", "gdn")
+ROUTERS = ("linear", "mlp")
 FEED_FORWARDS = ("dense", "gated", "experts")
 
 
@@ -97,7 +109,8 @@ FEED_FORWARDS = ("dense", "gated", "experts")
 class LayerSpec:
     """One layer of the stack: its mixer (one of ``MIXERS``), for an
     attention mixer the ``window`` (a query sees itself and the ``window -
-    1`` keys before it; None: every key before it) and whether the rotary
+    1`` keys before it; None: every key before it), for an attention or a
+    CCA mixer whether the rotary
     embedding applies (``GPTConfig.rope_theta``, ``rotary_dim``), and its
     feed-forward (one of ``FEED_FORWARDS``: two matrices and a GELU, three
     and a SiLU gate, or the expert block with what ``GPTConfig`` says of
@@ -247,6 +260,21 @@ class GPTConfig:
     # no norm or count of the step can (a step that drops them costs
     # nothing: the compiler removes what nobody reads).
     router_probe: bool = False
+    # A "cca" mixer's convolutions over its latent [q | k] of num_heads +
+    # kv_heads heads of head_dim: the taps of the depthwise stage and of the
+    # stage grouped by head.
+    cca_taps: Tuple[int, int] = (2, 2)
+    # The router of an expert block, one of ``ROUTERS``: "linear", one
+    # matrix [embed, experts]; "mlp": a down-projection to router_dim plus
+    # a learned vector times the down-projection of the expert block
+    # before it (none for the first), an RMSNorm, two GELU layers of
+    # router_dim and a matrix [router_dim, experts] (``_mlp_router``).
+    router_kind: str = "linear"
+    router_dim: int = 256
+    # A sublayer joins the residual stream as a_r (x + b_r) + a_h (f + b_h):
+    # four learned vectors of embed_dim a sublayer, ones and zeros at
+    # initialisation (``_residual``).
+    residual_scaling: bool = False
 
     @property
     def kv_heads(self) -> int:
@@ -286,6 +314,11 @@ class GPTConfig:
         """The convolved channels: q, k and v side by side."""
         return 2 * self.gdn_key_inner + self.gdn_value_inner
 
+    @property
+    def cca_latent(self) -> int:
+        """A CCA mixer's convolved channels: q and k side by side."""
+        return (self.num_heads + self.kv_heads) * self.head_dim
+
 
 from ..parallel.axes import axis_size as _axis_size, axis_bound as _axis_bound
 
@@ -323,8 +356,8 @@ def layer_plan(cfg: GPTConfig) -> Tuple[LayerSpec, ...]:
         raise ValueError(
             f"layers must hold a LayerSpec (mixer one of {MIXERS}, "
             f"feed-forward one of {FEED_FORWARDS}, a window of at least one "
-            f"key on attention alone) for each of the {cfg.num_layers} "
-            f"layers, got {plan!r}")
+            f"key on attention alone: a CCA layer has none yet) for each of "
+            f"the {cfg.num_layers} layers, got {plan!r}")
     return plan
 
 
@@ -402,6 +435,89 @@ def _init_gdn(key, cfg: GPTConfig, dense) -> dict:
     }
 
 
+def _init_cca(key, cfg: GPTConfig, dense) -> dict:
+    """A CCA mixer's parameters: the latent projections ``[q | k]`` and ``[v
+    of the token | v of the token before]``, the two convolutions as
+    torch's ``Conv1d`` (uniform within one over the square root of the
+    inputs a tap sums times the taps), the key heads' temperatures at zero,
+    the output projection."""
+    E, D, kv = cfg.embed_dim, cfg.head_dim, cfg.kv_heads * cfg.head_dim
+    groups, latent = cfg.num_heads + cfg.kv_heads, cfg.cca_latent
+    taps0, taps1 = cfg.cca_taps
+    if cfg.kv_heads % 2 or cfg.num_heads % cfg.kv_heads:
+        raise ValueError(
+            "a CCA mixer gives half of its key/value heads the token's "
+            "value and half the value of the token before, and a key/value "
+            f"head a whole group of query heads: {cfg.num_heads} query and "
+            f"{cfg.kv_heads} key/value heads")
+    ks = jax.random.split(key, 7)
+
+    def uniform(key, shape, fan_in):
+        bound = 1.0 / float(np.sqrt(fan_in))
+        return jax.random.uniform(key, shape, jnp.float32, -bound, bound)
+
+    return {
+        "wqk": dense(ks[0], (E, latent), E),
+        "wv": dense(ks[1], (E, kv), E),
+        "conv0_w": uniform(ks[2], (taps0, latent), taps0),
+        "conv0_b": uniform(ks[3], (latent,), taps0),
+        # [tap, group, channel in, channel out]
+        "conv1_w": uniform(ks[4], (taps1, groups, D, D), taps1 * D),
+        "conv1_b": uniform(ks[5], (latent,), taps1 * D),
+        "temp": jnp.zeros((cfg.kv_heads,), jnp.float32),
+        "wo": dense(ks[6], (cfg.num_heads * D, E), cfg.num_heads * D),
+    }
+
+
+_CCA_NAMES = ("wqk", "wv", "conv0_w", "conv0_b", "conv1_w", "conv1_b",
+              "temp", "wo")
+
+
+def _init_mlp_router(key, cfg: GPTConfig, dense, carry: bool) -> dict:
+    """An MLP router's parameters; ``carry`` (every expert block but the
+    first) the vector on the state from the block before, at one."""
+    E, R = cfg.embed_dim, cfg.router_dim
+    ks = jax.random.split(key, 4)
+
+    def zeros():
+        return jnp.zeros((R,), jnp.float32)
+
+    router = {
+        "down": dense(ks[0], (E, R), E), "down_b": zeros(),
+        "norm": jnp.ones((R,), jnp.float32),
+        "w1": dense(ks[1], (R, R), R), "b1": zeros(),
+        "w2": dense(ks[2], (R, R), R), "b2": zeros(),
+        "w3": dense(ks[3], (R, cfg.num_experts), R),
+    }
+    if carry:
+        router["carry"] = jnp.ones((R,), jnp.float32)
+    return router
+
+
+def _mlp_router_names(carry: bool) -> tuple:
+    return ("down", "down_b", "norm", "w1", "b1", "w2", "b2", "w3") \
+        + (("carry",) if carry else ())
+
+
+# A sublayer's residual scaling (``GPTConfig.residual_scaling``): on the
+# stream and on the branch, a scale at one and a bias at zero each.
+_RESIDUAL_NAMES = ("stream_scale", "stream_bias", "branch_scale",
+                   "branch_bias")
+_RESIDUAL_KEYS = ("mixer_res", "mlp_res")
+
+
+def _routers_with_carry(cfg: GPTConfig) -> list:
+    """For each layer, whether its expert block's router takes a state: an
+    MLP router's does from the expert block before it, so every one but
+    the plan's first."""
+    seen, out = False, []
+    for spec in cfg.plan:
+        experts = spec.ff == "experts" and cfg.router_kind == "mlp"
+        out.append(experts and seen)
+        seen = seen or experts
+    return out
+
+
 def _norm_names(spec: LayerSpec, before: bool, after: bool) -> list:
     """The keys of a layer's norms over the residual stream: before the
     mixer (the key carries the mixer's name: ``attn_norm``, ``ssm_norm``,
@@ -439,8 +555,12 @@ def init_params(rng, cfg: GPTConfig) -> dict:
         return jnp.zeros(shape, jnp.float32) if cfg.norm_zero_centered \
             else jnp.ones(shape, jnp.float32)
 
+    if cfg.router_kind not in ROUTERS:
+        raise ValueError(f"router_kind must be one of {ROUTERS}, got "
+                         f"{cfg.router_kind!r}")
     plan = cfg.plan
     before, after = norm_placement(cfg)
+    carries = _routers_with_carry(cfg)
     keys = jax.random.split(rng, 2 + cfg.num_layers)
     params: dict = {
         "embed": jax.random.normal(keys[0], (cfg.vocab_size, E),
@@ -456,6 +576,8 @@ def init_params(rng, cfg: GPTConfig) -> dict:
             layer = {"ssm": _init_ssm(ks[0], cfg, dense)}
         elif spec.mixer == "gdn":
             layer = {"gdn": _init_gdn(ks[0], cfg, dense)}
+        elif spec.mixer == "cca":
+            layer = {"cca": _init_cca(ks[0], cfg, dense)}
         else:
             layer = {
                 "wq": dense(ks[0], (E, H, 2 * D if cfg.attention_gate else D),
@@ -472,10 +594,18 @@ def init_params(rng, cfg: GPTConfig) -> dict:
                 layer["k_norm"] = norm((D,))
         for name in _norm_names(spec, before, after):
             layer[name] = norm((E,))
+        if cfg.residual_scaling:
+            for name in _RESIDUAL_KEYS:
+                layer[name] = {
+                    part: (jnp.ones if part.endswith("scale")
+                           else jnp.zeros)((E,), jnp.float32)
+                    for part in _RESIDUAL_NAMES}
         if spec.ff == "experts":
             n_exp, held, Mx = cfg.num_experts, _held(cfg), cfg.expert_width
             layer["moe"] = {
-                "router": dense(ks[4], (E, n_exp), E),
+                "router": dense(ks[4], (E, n_exp), E)
+                if cfg.router_kind == "linear"
+                else _init_mlp_router(ks[4], cfg, dense, carries[i]),
                 "w_gate": dense(ks[7], (held, E, Mx), E),
                 "w_up": dense(ks[5], (held, E, Mx), E),
                 "w_down": dense(ks[6], (held, Mx, E), Mx),
@@ -504,8 +634,9 @@ def init_params(rng, cfg: GPTConfig) -> dict:
 
 def param_specs(cfg: GPTConfig) -> dict:
     """PartitionSpec pytree matching :func:`init_params` — tp shards heads and
-    MLP hidden; ep shards experts; everything else replicated, a state-space
-    mixer included (it refuses a bound tp axis) and the router's selection
+    MLP hidden; ep shards experts; everything else replicated, a state-space,
+    gated-delta-rule or CCA mixer included (each refuses a bound tp axis),
+    an MLP router, the residual scaling's vectors and the router's selection
     bias (state every rank holds whole)."""
     tp, ep = cfg.tp_axis, cfg.ep_axis
     specs: dict = {
@@ -516,7 +647,7 @@ def param_specs(cfg: GPTConfig) -> dict:
     if not cfg.tie_embeddings:
         specs["lm_head"] = P()
     before, after = norm_placement(cfg)
-    for spec in cfg.plan:
+    for spec, carry in zip(cfg.plan, _routers_with_carry(cfg)):
         if spec.mixer == "ssm":
             layer = {"ssm": {
                 name: P() for name in (
@@ -527,6 +658,8 @@ def param_specs(cfg: GPTConfig) -> dict:
                 name: P() for name in (
                     "in_proj", "in_proj_ba", "conv_w", "dt_bias", "A_log",
                     "norm", "out_proj")}}
+        elif spec.mixer == "cca":
+            layer = {"cca": {name: P() for name in _CCA_NAMES}}
         else:
             layer = {
                 "wq": P(None, tp, None),
@@ -542,10 +675,14 @@ def param_specs(cfg: GPTConfig) -> dict:
                 layer["k_norm"] = P()
         for name in _norm_names(spec, before, after):
             layer[name] = P()
+        if cfg.residual_scaling:
+            for name in _RESIDUAL_KEYS:
+                layer[name] = {part: P() for part in _RESIDUAL_NAMES}
         if spec.ff == "experts":
             _held(cfg)
             layer["moe"] = {
-                "router": P(),
+                "router": P() if cfg.router_kind == "linear"
+                else {name: P() for name in _mlp_router_names(carry)},
                 "w_gate": P(ep, None, tp),
                 "w_up": P(ep, None, tp),
                 "w_down": P(ep, tp, None),
@@ -772,10 +909,22 @@ def _shared_expert(cfg: GPTConfig, p, h):
     return (down.astype(jnp.float32) * open_[..., None]).astype(cfg.dtype)
 
 
-def _residual(cfg: GPTConfig, x, branch):
+def _residual(cfg: GPTConfig, x, branch, scaling=None):
+    """The stream after a sublayer: ``x + branch`` (the branch times
+    ``residual_multiplier``), or under ``residual_scaling``, with the
+    sublayer's four vectors ``scaling``, ``a_r (x + b_r) + a_h (branch +
+    b_h)`` in float32, rounded once."""
     if cfg.residual_multiplier != 1.0:
         branch = branch * cfg.residual_multiplier
-    return x + branch
+    if scaling is None:
+        return x + branch
+    f32 = jnp.float32
+    with jax.named_scope("res_scale"):
+        return (scaling["stream_scale"]
+                * (x.astype(f32) + scaling["stream_bias"])
+                + scaling["branch_scale"]
+                * (branch.astype(f32) + scaling["branch_bias"])
+                ).astype(cfg.dtype)
 
 
 def _attention_mixer(cfg: GPTConfig, spec: LayerSpec, lp, h, positions):
@@ -810,6 +959,111 @@ def _attention_mixer(cfg: GPTConfig, spec: LayerSpec, lp, h, positions):
     return _tp_psum(o, cfg)
 
 
+def _before(t, tokens: int = 1):
+    """``t`` ``[B, S, ...]`` moved ``tokens`` later along the sequence, zeros
+    in front: position ``i`` holds what ``i - tokens`` held."""
+    if not tokens:
+        return t
+    pad = ((0, 0), (tokens, 0)) + ((0, 0),) * (t.ndim - 2)
+    return jnp.pad(t, pad)[:, :t.shape[1]]
+
+
+def _cca_mixer(cfg: GPTConfig, spec: LayerSpec, p, h, positions):
+    """A CCA mixer on normed activations ``h`` ``[B, S, E]`` (``Hq`` query
+    and ``Hk`` key/value heads of ``D``, ``G = Hq / Hk``): ``u = [q0 | k0] =
+    h W_qk``; ``u`` through a causal depthwise convolution and then a causal
+    convolution grouped by head (``Hq + Hk`` groups of ``D -> D`` channels),
+    each with a bias and neither with an activation; ``q = conv[:Hq D] + qm``
+    with ``qm_h = (q0_h + k0_{h // G}) / 2`` and ``k = conv[Hq D:] + km``
+    with ``km_g`` the mean of ``qm`` over the group's query heads; ``q`` and
+    ``k`` L2-normalised a head to length ``sqrt(D)`` (eps 1e-6 under the
+    root), ``k`` times ``exp(temp_g)``, all float32; the rotary embedding
+    where ``spec.rope`` says so; the first half of the value heads ``h
+    W_v`` of the token, the second half that of the token before it; the
+    attention ``_attention`` picks; ``W_o``. The convolutions and the value
+    read the token before on this rank, and the means and the grouped
+    stage a key/value head's whole group: a bound sp or tp axis is refused
+    by name."""
+    for axis, why in ((cfg.sp_axis, "the convolutions and the value of the "
+                       "token before would start each sequence shard from "
+                       "zeros"),
+                      (cfg.tp_axis, "the q/k means and the value's two "
+                       "halves cross the heads a rank would hold")):
+        if _axis_bound(axis):
+            raise ValueError(
+                f"a CCA layer runs on one rank's whole sequence and all its "
+                f"heads: the {axis!r} axis is bound ({why}); bind neither")
+    batch, seq = h.shape[:2]
+    f32 = jnp.float32
+    heads, kv_heads, dim = cfg.num_heads, cfg.kv_heads, cfg.head_dim
+    group, q_dim = heads // kv_heads, heads * dim
+    taps0, taps1 = cfg.cca_taps
+    rotary = (cfg.rotary_dim or dim) if spec.rope else 0
+    runtime.note_traced(
+        "hvdtpu_spmd_cca_traces_total", heads=heads, kv_heads=kv_heads,
+        head_dim=dim, taps0=taps0, taps1=taps1, rotary_dim=rotary)
+    with jax.named_scope("cca_proj"):
+        u = jnp.einsum("bse,ef->bsf", h, p["wqk"].astype(cfg.dtype))
+        hv = jnp.einsum("bse,ef->bsf", h, p["wv"].astype(cfg.dtype))
+    with jax.named_scope("cca_mix"):
+        c1 = causal_conv_silu(u, p["conv0_w"], p["conv0_b"], activation=None)
+        # The grouped stage: a product a tap, the operands in the compute
+        # dtype's values (the taps rounded to it, as every matrix here) and
+        # the sum in float32. Handed over as float32: the MXU's one pass
+        # takes them as what they are, and XLA's CPU backend cannot run a
+        # batched bfloat16 product into a float32 result.
+        c1 = c1.reshape(batch, seq, heads + kv_heads, dim).astype(f32)
+        w1 = p["conv1_w"].astype(cfg.dtype).astype(f32)
+        c2 = sum(jnp.einsum("bsgi,gio->bsgo", _before(c1, taps1 - 1 - tap),
+                            w1[tap]) for tap in range(taps1))
+        c2 = c2.reshape(batch, seq, -1) + p["conv1_b"]
+        q0 = u[..., :q_dim].astype(f32).reshape(batch, seq, kv_heads, group,
+                                                dim)
+        k0 = u[..., q_dim:].astype(f32).reshape(batch, seq, kv_heads, 1, dim)
+        qm = 0.5 * (q0 + k0)
+        q = c2[..., :q_dim] + qm.reshape(batch, seq, q_dim)
+        k = c2[..., q_dim:].reshape(batch, seq, kv_heads, dim) \
+            + jnp.mean(qm, axis=3)
+        q = q.reshape(batch, seq, heads, dim)
+
+        def unit(t):
+            return t * (float(np.sqrt(dim)) * lax.rsqrt(
+                jnp.sum(t * t, axis=-1, keepdims=True) + 1e-6))
+
+        q, k = unit(q), unit(k) * jnp.exp(p["temp"])[:, None]
+        if spec.rope:
+            q = rope(q, positions, cfg.rope_theta, cfg.rotary_dim)
+            k = rope(k, positions, cfg.rope_theta, cfg.rotary_dim)
+        half = hv.shape[-1] // 2
+        v = jnp.concatenate([hv[..., :half], _before(hv[..., half:])],
+                            axis=-1).reshape(batch, seq, kv_heads, dim)
+    attn = _attention(cfg, q.astype(cfg.dtype), k.astype(cfg.dtype), v)
+    with jax.named_scope("cca_proj"):
+        return jnp.einsum("bsf,fe->bse", attn.reshape(batch, seq, q_dim),
+                          p["wo"].astype(cfg.dtype))
+
+
+def _mlp_router(cfg: GPTConfig, r, h, state):
+    """``(the router's outputs [B, S, experts], the state [B, S, R])`` of an
+    MLP router ``r`` on normed activations ``h``, all float32 at the highest
+    precision: ``z = h W_d + b_d``, plus ``carry * state`` where the expert
+    block before handed one on (``state`` its ``z``; None for the first);
+    ``s = RMSNorm(z)``; ``W_3 gelu(W_2 gelu(W_1 s + b_1) + b_2)``, the GELU
+    exact. ``z``, before the norm, is the state for the next expert block."""
+    f32, hi = jnp.float32, lax.Precision.HIGHEST
+
+    def layer(t, w, b):
+        return jax.nn.gelu(jnp.dot(t, r[w], precision=hi) + r[b],
+                           approximate=False)
+
+    z = jnp.dot(h.astype(f32), r["down"], precision=hi) + r["down_b"]
+    if state is not None:
+        z = z + r["carry"] * state
+    s = _rmsnorm(z, r["norm"], f32, cfg.norm_eps)
+    return jnp.dot(layer(layer(s, "w1", "b1"), "w2", "b2"), r["w3"],
+                   precision=hi), z
+
+
 def _dense_ff(cfg: GPTConfig, spec: LayerSpec, lp, h):
     up = jnp.einsum("bse,em->bsm", h, lp["w_up"].astype(cfg.dtype))
     up = checkpoint_name(up, "ffn_pre_activation")
@@ -822,36 +1076,53 @@ def _dense_ff(cfg: GPTConfig, spec: LayerSpec, lp, h):
     return _tp_psum(down, cfg)
 
 
-def _expert_ff(cfg: GPTConfig, m, h):
-    """``(y, aux)`` of the expert block ``m`` on normed activations."""
+def _expert_ff(cfg: GPTConfig, m, h, router_state=None):
+    """``(y, aux, router state)`` of the expert block ``m`` on normed
+    activations: the state an MLP router hands to the next expert block
+    (``router_state``: what the one before handed to this), None under a
+    linear router."""
     from ..parallel.moe import moe_layer
+    router = dict(router_w=m["router"])
+    if cfg.router_kind == "mlp":
+        with jax.named_scope("router"):
+            logits, state = _mlp_router(cfg, m["router"], h, router_state)
+        router = dict(router_w=None, logits=logits, router_kind="mlp",
+                      router_state=router_state is not None)
+        router_state = state
     out, aux = moe_layer(
-        h, m["router"], m["w_gate"], m["w_up"], m["w_down"],
+        h, w_gate=m["w_gate"], w_up=m["w_up"], w_down=m["w_down"],
         top_k=cfg.experts_per_token, axis=cfg.ep_axis,
         tp_axis=cfg.tp_axis, dtype=cfg.dtype,
         first_expert=cfg.first_expert,
         renormalize=cfg.renormalize_experts, score=cfg.router_score,
         bias=m["router_bias"] if cfg.router_bias else None,
-        scale=cfg.route_scale, probe=cfg.router_probe)
+        scale=cfg.route_scale, probe=cfg.router_probe, **router)
     if cfg.shared_expert_dim:
         with jax.named_scope("shared"):
             out = out + _shared_expert(cfg, m["shared"], h)
-    return out, aux
+    return out, aux, router_state
 
 
 _RECURRENT_MIXERS = {"ssm": _ssm_mixer, "gdn": _gdn_mixer}
 
 
-def _block(cfg: GPTConfig, spec: LayerSpec, layer_params, x, positions):
-    """One decoder block, as ``spec`` says it is: ``(x, aux)``, ``aux`` the
-    expert layer's auxiliary terms (``parallel/moe.py``) or None for a dense
-    block."""
+def _block(cfg: GPTConfig, spec: LayerSpec, layer_params, x, positions,
+           router_state=None):
+    """One decoder block, as ``spec`` says it is: ``(x, aux, router
+    state)``, ``aux`` the expert layer's auxiliary terms
+    (``parallel/moe.py``) or None for a dense block, the router state what
+    an MLP router hands from one expert block to the next (a block without
+    one hands on what it was given; None under linear routers)."""
     # The scopes sit inside the function ``jax.checkpoint`` wraps, so the
     # recomputed copy of a block carries them too (``forward`` has the rest).
     # A window layer's mixer is under ``attn_window``, a full one's under
-    # ``attn``: a device trace tells their flash kernels apart by it.
+    # ``attn``: a device trace tells their flash kernels apart by it. A CCA
+    # layer's is under ``attn`` too, its own parts ``cca_proj`` and
+    # ``cca_mix`` inside.
     lp = layer_params
     norm_before, norm_after = norm_placement(cfg)
+    mixer_res, mlp_res = (lp[key] for key in _RESIDUAL_KEYS) \
+        if cfg.residual_scaling else (None, None)
 
     def before(key):
         return _norm(cfg, x, lp[key]) if norm_before else x
@@ -862,31 +1133,33 @@ def _block(cfg: GPTConfig, spec: LayerSpec, layer_params, x, positions):
         with jax.named_scope("post_norm"):
             return _norm(cfg, branch, lp[key])
 
-    if spec.mixer == "attention":
-        with jax.named_scope("attn" if spec.window is None
-                             else "attn_window"):
-            h = before("attn_norm")
-            x = _residual(cfg, x, after(
-                _attention_mixer(cfg, spec, lp, h, positions),
-                "mixer_post_norm"))
-    else:
-        # A recurrent mixer's scope, its parameters' key and its norm's
-        # (``<mixer>_norm``) carry its name.
-        with jax.named_scope(spec.mixer):
-            h = before(spec.mixer + "_norm")
-            x = _residual(cfg, x, after(
-                _RECURRENT_MIXERS[spec.mixer](cfg, lp[spec.mixer], h),
-                "mixer_post_norm"))
+    # A recurrent mixer's scope, its parameters' key and its norm's
+    # (``<mixer>_norm``) carry its name; so do a CCA mixer's key and norm.
+    scope = spec.mixer
+    if spec.mixer in ("attention", "cca"):
+        scope = "attn" if spec.window is None else "attn_window"
+    with jax.named_scope(scope):
+        h = before(_norm_names(spec, True, False)[0])
+        if spec.mixer == "attention":
+            branch = _attention_mixer(cfg, spec, lp, h, positions)
+        elif spec.mixer == "cca":
+            branch = _cca_mixer(cfg, spec, lp["cca"], h, positions)
+        else:
+            branch = _RECURRENT_MIXERS[spec.mixer](cfg, lp[spec.mixer], h)
+        x = _residual(cfg, x, after(branch, "mixer_post_norm"), mixer_res)
 
     if spec.ff == "experts":
         with jax.named_scope("moe"):
             h = before("mlp_norm")
-            out, aux = _expert_ff(cfg, lp["moe"], h)
-            return _residual(cfg, x, after(out, "mlp_post_norm")), aux
+            out, aux, router_state = _expert_ff(cfg, lp["moe"], h,
+                                                router_state)
+            return _residual(cfg, x, after(out, "mlp_post_norm"),
+                             mlp_res), aux, router_state
     with jax.named_scope("mlp"):
         h = before("mlp_norm")
         return _residual(cfg, x, after(_dense_ff(cfg, spec, lp, h),
-                                       "mlp_post_norm")), None
+                                       "mlp_post_norm"),
+                         mlp_res), None, router_state
 
 
 # What ``remat="full"`` keeps from a block's forward pass beside its input:
@@ -931,8 +1204,8 @@ def _full_policy(prim, *avals, **params):
 
 
 def _block_fn(cfg: GPTConfig):
-    """The per-layer apply ``(cfg, spec, layer_params, x, positions)``,
-    optionally wrapped in ``jax.checkpoint`` (cfg and a layer's spec are
+    """The per-layer apply ``(cfg, spec, layer_params, x, positions, router
+    state)``, optionally wrapped in ``jax.checkpoint`` (cfg and a layer's spec are
     frozen dataclasses, so they ride static_argnums)."""
     if cfg.remat == "none":
         return _block
@@ -957,20 +1230,24 @@ def _hidden(params, tokens, positions, cfg: GPTConfig):
     # inside, from ``_block``, each with ``post_norm`` where the
     # configuration norms a branch after it (``norm_placement``); ``ssm``
     # and ``gdn`` hold
-    # ``in_proj``, ``conv``, ``scan``, ``gate_norm``, ``out_proj``; ``moe``
-    # holds ``router``, ``dispatch``, ``experts``, ``combine`` and
-    # ``shared``), ``head``; ``loss_and_aux``
+    # ``in_proj``, ``conv``, ``scan``, ``gate_norm``, ``out_proj``; a CCA
+    # layer's ``attn`` holds ``cca_proj`` and ``cca_mix``; ``moe``
+    # holds ``router`` (an MLP router whole, its state included),
+    # ``dispatch``, ``experts``, ``combine`` and
+    # ``shared``; ``res_scale`` where the residual is scaled), ``head``;
+    # ``loss_and_aux``
     # adds ``loss``. A device trace is read by them (PERF.md section 3).
     with jax.named_scope("embed"):
         x = params["embed"].astype(cfg.dtype)[tokens]
         if cfg.embedding_multiplier != 1.0:
             x = x * cfg.embedding_multiplier
     block = _block_fn(cfg)
-    auxes = []
+    auxes, router_state = [], None
     for i, (spec, lp) in enumerate(zip(cfg.plan, params["layers"],
                                        strict=True)):
         with jax.named_scope(f"layer{i}"):
-            x, aux = block(cfg, spec, lp, x, positions)
+            x, aux, router_state = block(cfg, spec, lp, x, positions,
+                                         router_state)
         if aux is not None:
             auxes.append(aux)
     with jax.named_scope("head"):

@@ -21,8 +21,11 @@ independent gates and the choice may lean on a bias that is no parameter:
 (``bias`` ``b`` ``[E]`` enters the choice alone, so no gradient reaches it:
 the caller moves it from the counts the layer returns, outside the loss,
 ``models/gpt.py::update_router_bias``; ``scale`` multiplies the weights
-under either score; the division is ``renormalize``'s.) Everything after
-the choice of experts is one code path for both. **No token is
+under either score; the division is ``renormalize``'s.) A router that is
+more than one matrix (an MLP, a state carried from layer to layer:
+``models/gpt.py::_mlp_router``) is the caller's: it hands in ``r`` itself
+(``logits``) and the layer takes it from there. Everything after
+the choice of experts is one code path for all of them. **No token is
 dropped, whatever the routing**, and every shape is static: the ``T k``
 token-expert pairs are sorted by expert, the tokens' rows gathered once in
 that order, the three expert matrices applied as grouped matmuls over the
@@ -388,13 +391,15 @@ def moe_layer(x, router_w, w_gate, w_up, w_down, top_k: int,
               axis: Optional[str] = None, tp_axis: Optional[str] = None,
               dtype: Any = jnp.bfloat16, first_expert: int = 0,
               renormalize: bool = False, score: str = "softmax",
-              bias=None, scale: float = 1.0,
-              probe: bool = False) -> Tuple[jnp.ndarray, dict]:
+              bias=None, scale: float = 1.0, probe: bool = False,
+              logits=None, router_kind: str = "linear",
+              router_state: bool = False) -> Tuple[jnp.ndarray, dict]:
     """Dropless top-``top_k`` expert layer (module docstring has the math).
 
     Args:
       x: ``[..., d]`` activations (this rank's batch/sequence shard).
-      router_w: ``[d, num_experts]`` router weights (replicated, fp32).
+      router_w: ``[d, num_experts]`` router weights (replicated, fp32);
+        None where the caller made the router's outputs itself (``logits``).
       w_gate, w_up: ``[experts_local, d, m_local]`` — the ep-axis shard of
         the global ``[num_experts, d, m]`` tensors (and tp shard of ``m``).
       w_down: ``[experts_local, m_local, d]``.
@@ -411,6 +416,14 @@ def moe_layer(x, router_w, w_gate, w_up, w_down, top_k: int,
       scale: a constant on a token's weights.
       probe: ``aux`` also holds what the router read and gave, for a check
         that holds its product to a reference fed the same activations.
+      logits: ``[..., num_experts]`` float32, the router's outputs ``r`` for
+        ``x``'s tokens, made by the caller (``router_w`` is then None); the
+        scores, the choice, the weights, the auxiliary terms and the counts
+        are made of them here as of the layer's own product.
+      router_kind, router_state: what the caller says of the router behind
+        ``logits`` (its kind; whether it took a state from the layer
+        before), for the layer's trace record alone
+        (``hvdtpu_spmd_moe_layer_traces_total``).
 
     Returns ``(y, aux)``, ``y`` shaped and typed (``dtype``) as the
     activations, and over the tokens routed together (this rank's, or the ep
@@ -424,10 +437,13 @@ def moe_layer(x, router_w, w_gate, w_up, w_down, top_k: int,
     if score not in ("softmax", "sigmoid"):
         raise ValueError(f"expert layer: score {score!r} is neither "
                          "'softmax' nor 'sigmoid'")
+    if (router_w is None) == (logits is None):
+        raise ValueError("expert layer: the router's matrix or the router's "
+                         "outputs, one of the two")
     d = x.shape[-1]
     ep = _axis_bound(axis)
     experts_local = w_up.shape[0]
-    num_experts = router_w.shape[1]
+    num_experts = logits.shape[-1] if router_w is None else router_w.shape[1]
     if ep and experts_local * _axis_size(axis) != num_experts:
         raise ValueError(
             f"expert layer: {_axis_size(axis)} ranks of {experts_local} "
@@ -440,8 +456,12 @@ def moe_layer(x, router_w, w_gate, w_up, w_down, top_k: int,
     # this program does not run.
     share = ep or experts_local < num_experts
     xt = x.reshape(-1, d)
+    if logits is not None:
+        logits = logits.reshape(-1, num_experts).astype(jnp.float32)
     if ep:
         xt = lax.all_gather(xt, axis, axis=0, tiled=True)
+        if logits is not None:
+            logits = lax.all_gather(logits, axis, axis=0, tiled=True)
     T = xt.shape[0]
     # The rows the layer works on at a time: all T k, or a share's window.
     window_rows = share_rows(T, top_k, experts_local, num_experts) if share \
@@ -451,15 +471,17 @@ def moe_layer(x, router_w, w_gate, w_up, w_down, top_k: int,
         "hvdtpu_spmd_moe_layer_traces_total", experts=num_experts,
         top_k=top_k, ep=_axis_size(axis), grouped_matmul=GROUPED_MATMUL,
         held=experts_local, rows=window_rows, score=score,
-        bias=int(bias is not None))
+        bias=int(bias is not None), router=router_kind,
+        state=int(router_state))
 
     with jax.named_scope("router"):
         # The product's own operand: a probe hands out this value and not
         # ``xt`` (the compiler may feed the product the activations before
         # their rounding to ``xt``'s type; then it feeds the probe the same).
         router_in = xt.astype(jnp.float32)
-        logits = jnp.dot(router_in, router_w.astype(jnp.float32),
-                         precision=lax.Precision.HIGHEST)            # [T, E]
+        if logits is None:
+            logits = jnp.dot(router_in, router_w.astype(jnp.float32),
+                             precision=lax.Precision.HIGHEST)        # [T, E]
         probs = jax.nn.softmax(logits, axis=-1) if score == "softmax" \
             else jax.nn.sigmoid(logits)
         if bias is None:

@@ -104,10 +104,19 @@ _TRACED = {
         "token, the size of the expert-parallel axis, the grouped matmul it "
         "uses, the experts this rank holds, the token-expert rows it "
         "gathers and multiplies at a time (all of them, or a share's "
-        "window), the router's score function and whether a selection "
-        "bias leans its choice.",
+        "window), the router's score function, whether a selection "
+        "bias leans its choice, the router's kind (linear: the layer's own "
+        "one matrix; mlp: the caller's, its outputs handed in) and whether "
+        "it took a state from the layer before.",
         ("experts", "top_k", "ep", "grouped_matmul", "held", "rows",
-         "score", "bias")),
+         "score", "bias", "router", "state")),
+    "hvdtpu_spmd_cca_traces_total": (
+        "Times JAX traced a CCA attention mixer (latent q and k mixed by two "
+        "stacked causal convolutions; the recomputed copy of a block counts "
+        "again), by its query heads, key/value heads, their size, the taps "
+        "of the depthwise and of the grouped stage, and the dimensions of a "
+        "head the rotary embedding turns.",
+        ("heads", "kv_heads", "head_dim", "taps0", "taps1", "rotary_dim")),
     "hvdtpu_spmd_ssm_layer_traces_total": (
         "Times JAX traced a chunked state-space scan (the recomputed copy of "
         "a block counts again), by its heads, their size, the state's size, "

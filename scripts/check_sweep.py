@@ -18,7 +18,9 @@ shared expert dropped, the router's product in one bfloat16 pass; for a
 linear-attention job the writing strength without its factor of two, ``q``
 scaled for another head size, the norms before the branches instead of
 after, a rotary embedding, no q/k norm, the running sums of the log decays
-in bfloat16), against
+in bfloat16; for a CCA job the residual scaling or the routers' carried
+state left out, the rotary embedding on the whole head, the MLP router's
+products in one bfloat16 pass, the mix's means and norms in bfloat16), against
 the untouched reference: what a job's tolerances must catch. A variant
 changes the job's ``GPTConfig`` after the job is built, before its step is
 traced (or what the program's modules see, where no field says it); a job
@@ -38,14 +40,14 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
 
-def _router_in_bfloat16():
-    """The expert layer with the router's float32 product at the backend's
-    default precision (on the TPU one bfloat16 pass of the MXU) where the
-    program asks for the highest: ``parallel/moe.py`` sees a ``jax.numpy``
-    whose ``dot`` takes no notice of ``precision``."""
+def _one_pass_dots(module) -> None:
+    """``module``'s float32 products at the backend's default precision (on
+    the TPU one bfloat16 pass of the MXU) where it asks for the highest: it
+    sees a ``jax.numpy`` whose ``dot`` takes no notice of ``precision``.
+    ``parallel/moe.py``: the expert layer's own router; ``models/gpt.py``:
+    the MLP router (no other line of it calls ``dot``)."""
     import jax.numpy as jnp
     from jax import lax
-    from horovod_tpu.parallel import moe
 
     class OnePass:
         def __getattr__(self, name):
@@ -55,7 +57,19 @@ def _router_in_bfloat16():
         def dot(a, b, precision=None, **kw):
             return jnp.dot(a, b, precision=lax.Precision.DEFAULT, **kw)
 
-    moe.jnp = OnePass()
+    module.jnp = OnePass()
+
+
+def _router_in_bfloat16():
+    from horovod_tpu.parallel import moe
+
+    _one_pass_dots(moe)
+
+
+def _mlp_router_in_bfloat16() -> None:
+    from horovod_tpu.models import gpt
+
+    _one_pass_dots(gpt)
 
 
 def _norms_before(job) -> None:
@@ -103,6 +117,41 @@ def _decay_sums_in_bfloat16() -> None:
     gated_delta.jnp = RoundedSums()
 
 
+def _no_router_state() -> None:
+    """Every MLP router as a stage's first: no state from the layer
+    before."""
+    from horovod_tpu.models import gpt
+
+    real = gpt._mlp_router
+    gpt._mlp_router = lambda cfg, r, h, state: real(cfg, r, h, None)
+
+
+def _mix_in_bfloat16() -> None:
+    """A CCA mixer's means, L2 norms, temperature and rotary embedding in
+    bfloat16 where the program computes them in float32 from bfloat16
+    inputs: ``_cca_mixer`` sees a ``jax.numpy`` whose ``float32`` is
+    ``bfloat16`` (the grouped stage's sums with them)."""
+    import jax.numpy as jnp
+    from horovod_tpu.models import gpt
+
+    class Rounded:
+        float32 = jnp.bfloat16
+
+        def __getattr__(self, name):
+            return getattr(jnp, name)
+
+    real = gpt._cca_mixer
+
+    def mixer(*args):
+        gpt.jnp = Rounded()
+        try:
+            return real(*args)
+        finally:
+            gpt.jnp = jnp
+
+    gpt._cca_mixer = mixer
+
+
 # name -> what it does to a job already built (its step not yet traced)
 VARIANTS = {
     "full_causal": lambda job: _replace(job, layers=tuple(
@@ -119,6 +168,11 @@ VARIANTS = {
     "rope": lambda job: _replace(job, rope=True),
     "no_qk_norm": lambda job: _replace(job, qk_norm=False),
     "decay_sums_bf16": lambda job: _decay_sums_in_bfloat16(),
+    "no_residual_scaling": lambda job: _replace(job, residual_scaling=False),
+    "no_router_state": lambda job: _no_router_state(),
+    "whole_head_rotary": lambda job: _replace(job, rotary_dim=None),
+    "router_mlp_bf16": lambda job: _mlp_router_in_bfloat16(),
+    "cca_mix_bf16": lambda job: _mix_in_bfloat16(),
 }
 
 

@@ -249,7 +249,7 @@ def main() -> int:
     def blocks(layers, x):
         for i, (spec, lp) in enumerate(zip(cfg.plan, layers)):
             with jax.named_scope(f"layer{i}"):
-                x, _ = block(cfg, spec, lp, x, positions)
+                x, *_ = block(cfg, spec, lp, x, positions)
         return jnp.sum(jnp.sin(x.astype(jnp.float32)))
 
     fwd = jax.jit(blocks)

@@ -196,9 +196,12 @@ def test_gdn_scan_compiles_for_v5e_at_heads_of_any_size(one_chip, mosaic,
         assert f"hvd_gdn_{kernel}" in text
 
 
-# (B, S, C, bias, the axis the mixer asks for on the lanes): the convolution
-# in front of the scan in the three recurrent cells, and a tensor no tile
-# divides (a float32 one: the halo of 16 tokens is two of its tiles).
+# (B, S, C, bias, the axis the mixer asks for on the lanes, dtype; then,
+# where they are not four taps and a SiLU, the taps and whether a SiLU
+# follows): the convolution in front of the scan in the three recurrent
+# cells, a tensor no tile divides (a float32 one: the halo of 16 tokens is
+# two of its tiles), and a CCA mixer's depthwise stage, two taps and no
+# activation.
 CONV_SHAPES = {
     "qwen3-next-80b-a3b_s4096": (4, 4096, 8192, False, "channels",
                                  jnp.bfloat16),
@@ -211,24 +214,27 @@ CONV_SHAPES = {
                                            jnp.bfloat16),
     "ragged_float32": (2, 1000, 200, True, "channels", jnp.float32),
     "ragged_float32_tokens": (2, 1000, 200, True, "tokens", jnp.float32),
+    "zaya1-8b_s4096": (4, 4096, 1280, True, "channels", jnp.bfloat16, 2,
+                       False),
 }
 
 
 @pytest.mark.parametrize("kernel", ["fwd", "bwd"])
 @pytest.mark.parametrize("shape", list(CONV_SHAPES))
 def test_conv_kernel_compiles_for_v5e(one_chip, mosaic, shape, kernel):
-    batch, seq, channels, bias, minor, dtype = CONV_SHAPES[shape]
+    batch, seq, channels, bias, minor, dtype, *rest = CONV_SHAPES[shape]
+    taps, silu = rest or (4, True)
 
     def sds(*dims, dt=jnp.float32):
         return jax.ShapeDtypeStruct(dims, dt, sharding=one_chip)
 
     u = sds(batch, seq, channels, dt=dtype)
-    args = (u, sds(4, channels), sds(channels) if bias else None)
+    args = (u, sds(taps, channels), sds(channels) if bias else None)
     if kernel == "fwd":
         f = conv._conv_fwd_call
     else:
         f, args = conv._conv_bwd_call, args + (u,)
     text = jax.jit(functools.partial(
-        f, first=0, tokens_minor=minor == "tokens")).lower(*args).compile() \
-        .as_text()
+        f, first=0, tokens_minor=minor == "tokens",
+        silu=silu)).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text and f"hvd_conv_{kernel}" in text

@@ -314,6 +314,46 @@ def test_window_step_holds_its_flash_kernels_under_a_scope_of_their_own(
         assert scope in text, scope
 
 
+# A CCA mixer and an expert sublayer under an MLP router in both layers,
+# each sublayer joined under the residual scaling.
+CCA = dict(layers=(gpt.LayerSpec(mixer="cca", ff="experts"),) * 2,
+           num_heads=4, num_kv_heads=2, num_experts=4, router_kind="mlp",
+           router_dim=16, router_bias=True, residual_scaling=True,
+           rotary_dim=8, tie_embeddings=True)
+
+
+def test_cca_step_holds_its_parts_under_their_scopes(spmd4):
+    """A CCA layer's mixer lies under ``layer<i>/attn`` with its own parts
+    inside (where ``cca_ms`` and ``cca_mix_ms`` look): the latent
+    projections and ``W_o`` under ``cca_proj``, the depthwise stage's two
+    kernels and the rest of the mix under ``cca_mix``, the flash kernels
+    under ``attn`` itself; the whole MLP router under ``moe/router``; the
+    scaling of each sublayer's residual under ``res_scale``; forward, in
+    the recomputed copy of a block and backward."""
+    step, *args = gpt_step("full", **CCA)
+    text = step.lower(*args).as_text(debug_info=True)
+    scopes = set(re.findall(r'loc\("([^"]*)/hvd_conv_(fwd|bwd)/', text))
+    assert {kernel for _, kernel in scopes} == {"fwd", "bwd"}
+    assert all(scope.endswith("/attn/cca_mix") for scope, _ in scopes), scopes
+    assert any("rematted_computation" in scope for scope, _ in scopes)
+    flash = set(re.findall(r'loc\("([^"]*)/hvd_flash_(?:fwd|dkdv|dq)/', text))
+    assert flash and all(scope.endswith("/attn") for scope in flash), flash
+    names = op_names(step, *args)
+
+    def some(*parts):
+        return any(all(p in n for p in parts) for n in names)
+
+    for layer in ("layer0", "layer1"):
+        for scope in ("/attn/cca_proj/", "/attn/cca_mix/", "/attn/res_scale/",
+                      "/moe/router/", "/moe/res_scale/"):
+            assert some(f"jvp({layer})", scope), (layer, scope)
+            assert some(f"transpose(jvp({layer}))", scope), (layer, scope)
+            assert some(layer, "rematted_computation", scope), (layer, scope)
+    # The grouped stage's products and the router's are under those scopes.
+    assert some("/attn/cca_mix/", "bsgi,gio->bsgo")
+    assert some("/moe/router/", "dot_general")
+
+
 # ---- (b) kernel names --------------------------------------------------------
 
 def _flash(grad: bool):
