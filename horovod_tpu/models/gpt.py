@@ -91,6 +91,7 @@ from jax.sharding import PartitionSpec as P
 
 from .. import runtime
 from ..ops.attention import default_attention, repeat_kv_heads, rope
+from ..ops.cca import cca_mix
 from ..ops.conv import causal_conv_silu
 from ..ops.flash_attention import flash_attention
 from ..ops.gated_delta import gated_delta_chunked
@@ -978,8 +979,10 @@ def _cca_mixer(cfg: GPTConfig, spec: LayerSpec, p, h, positions):
     with ``km_g`` the mean of ``qm`` over the group's query heads; ``q`` and
     ``k`` L2-normalised a head to length ``sqrt(D)`` (eps 1e-6 under the
     root), ``k`` times ``exp(temp_g)``, all float32; the rotary embedding
-    where ``spec.rope`` says so; the first half of the value heads ``h
-    W_v`` of the token, the second half that of the token before it; the
+    where ``spec.rope`` says so (all of that, from ``u`` to ``q`` and ``k``,
+    one pass of ``ops/cca.py::cca_mix``'s kernels a direction); the first
+    half of the value heads ``h W_v`` of the token, the second half that of
+    the token before it; the
     attention ``_attention`` picks; ``W_o``. The convolutions and the value
     read the token before on this rank, and the means and the grouped
     stage a key/value head's whole group: a bound sp or tp axis is refused
@@ -994,9 +997,7 @@ def _cca_mixer(cfg: GPTConfig, spec: LayerSpec, p, h, positions):
                 f"a CCA layer runs on one rank's whole sequence and all its "
                 f"heads: the {axis!r} axis is bound ({why}); bind neither")
     batch, seq = h.shape[:2]
-    f32 = jnp.float32
     heads, kv_heads, dim = cfg.num_heads, cfg.kv_heads, cfg.head_dim
-    group, q_dim = heads // kv_heads, heads * dim
     taps0, taps1 = cfg.cca_taps
     rotary = (cfg.rotary_dim or dim) if spec.rope else 0
     runtime.note_traced(
@@ -1006,40 +1007,18 @@ def _cca_mixer(cfg: GPTConfig, spec: LayerSpec, p, h, positions):
         u = jnp.einsum("bse,ef->bsf", h, p["wqk"].astype(cfg.dtype))
         hv = jnp.einsum("bse,ef->bsf", h, p["wv"].astype(cfg.dtype))
     with jax.named_scope("cca_mix"):
-        c1 = causal_conv_silu(u, p["conv0_w"], p["conv0_b"], activation=None)
-        # The grouped stage: a product a tap, the operands in the compute
-        # dtype's values (the taps rounded to it, as every matrix here) and
-        # the sum in float32. Handed over as float32: the MXU's one pass
-        # takes them as what they are, and XLA's CPU backend cannot run a
-        # batched bfloat16 product into a float32 result.
-        c1 = c1.reshape(batch, seq, heads + kv_heads, dim).astype(f32)
-        w1 = p["conv1_w"].astype(cfg.dtype).astype(f32)
-        c2 = sum(jnp.einsum("bsgi,gio->bsgo", _before(c1, taps1 - 1 - tap),
-                            w1[tap]) for tap in range(taps1))
-        c2 = c2.reshape(batch, seq, -1) + p["conv1_b"]
-        q0 = u[..., :q_dim].astype(f32).reshape(batch, seq, kv_heads, group,
-                                                dim)
-        k0 = u[..., q_dim:].astype(f32).reshape(batch, seq, kv_heads, 1, dim)
-        qm = 0.5 * (q0 + k0)
-        q = c2[..., :q_dim] + qm.reshape(batch, seq, q_dim)
-        k = c2[..., q_dim:].reshape(batch, seq, kv_heads, dim) \
-            + jnp.mean(qm, axis=3)
-        q = q.reshape(batch, seq, heads, dim)
-
-        def unit(t):
-            return t * (float(np.sqrt(dim)) * lax.rsqrt(
-                jnp.sum(t * t, axis=-1, keepdims=True) + 1e-6))
-
-        q, k = unit(q), unit(k) * jnp.exp(p["temp"])[:, None]
-        if spec.rope:
-            q = rope(q, positions, cfg.rope_theta, cfg.rotary_dim)
-            k = rope(k, positions, cfg.rope_theta, cfg.rotary_dim)
+        q, k = cca_mix(u, p["conv0_w"], p["conv0_b"], p["conv1_w"],
+                       p["conv1_b"], p["temp"],
+                       positions if spec.rope else None, heads=heads,
+                       kv_heads=kv_heads, rope_theta=cfg.rope_theta,
+                       rotary_dim=cfg.rotary_dim)
         half = hv.shape[-1] // 2
         v = jnp.concatenate([hv[..., :half], _before(hv[..., half:])],
                             axis=-1).reshape(batch, seq, kv_heads, dim)
-    attn = _attention(cfg, q.astype(cfg.dtype), k.astype(cfg.dtype), v)
+    attn = _attention(cfg, q, k, v)
     with jax.named_scope("cca_proj"):
-        return jnp.einsum("bsf,fe->bse", attn.reshape(batch, seq, q_dim),
+        return jnp.einsum("bsf,fe->bse",
+                          attn.reshape(batch, seq, heads * dim),
                           p["wo"].astype(cfg.dtype))
 
 

@@ -1,9 +1,7 @@
 """The causal depthwise convolution in front of both recurrent scans
 (``ops/ssd.py``'s and ``ops/gated_delta.py``'s: ``models/gpt.py``'s two
-recurrent mixers), with its SiLU, and the first stage of a CCA mixer's
-convolutions, without one: :func:`causal_conv_silu` takes the taps, the
-bias, the activation (``"silu"`` or None) and the cast in one pass over the
-tensor a direction, two
+recurrent mixers), with its SiLU: :func:`causal_conv_silu` takes the taps,
+the bias, the SiLU and the cast in one pass over the tensor a direction, two
 Pallas kernels under one ``jax.custom_vjp`` (``hvd_conv_fwd``,
 ``hvd_conv_bwd``). A grid cell holds about a megabyte of
 the tensor and 16 (or 128) tokens of the cell before it, a second block of
@@ -187,9 +185,8 @@ def _conv_taps(plan: _ConvPlan, ext, lo: int, n: int, wb_ref, ch, taps: int,
 
 
 def _conv_fwd_kernel(u_ref, before_ref, wb_ref, y_ref, *, plan: _ConvPlan,
-                     taps: int, bias: bool, silu: bool):
-    """``y = silu(bias + sum_k w_k u_{t - (K - 1) + k})`` (without ``silu``:
-    the sum itself), rounded once, a
+                     taps: int, bias: bool):
+    """``y = silu(bias + sum_k w_k u_{t - (K - 1) + k})``, rounded once, a
     piece at a time: the piece and the halo before it side by side in
     float32, each tap a rotation of that along the tokens."""
     f32 = jnp.float32
@@ -203,7 +200,7 @@ def _conv_fwd_kernel(u_ref, before_ref, wb_ref, y_ref, *, plan: _ConvPlan,
              .astype(f32), u_ref[here].astype(f32)], axis=plan.axis)
         pre, _ = _conv_taps(plan, ext, plan.halo, plan.sub, wb_ref, ch, taps,
                             bias)
-        y_ref[here] = (jax.nn.silu(pre) if silu else pre).astype(y_ref.dtype)
+        y_ref[here] = jax.nn.silu(pre).astype(y_ref.dtype)
         return carry
 
     always(lambda: _conv_walk(plan, piece, 0, lambda ch, carry: None))
@@ -211,11 +208,10 @@ def _conv_fwd_kernel(u_ref, before_ref, wb_ref, y_ref, *, plan: _ConvPlan,
 
 def _conv_bwd_kernel(u_ref, before_ref, after_ref, dy_ref, dy_after_ref,
                      wb_ref, du_ref, sums_ref, *, plan: _ConvPlan, taps: int,
-                     bias: bool, silu: bool):
+                     bias: bool):
     """The forward's cotangents, a piece at a time. The pre-activation is
     made again for the piece and the halo after it (``du_t`` reads ``d pre``
-    up to ``t + K - 1``), ``d pre = dy silu'(pre)`` in float32 (without
-    ``silu``: ``dy`` itself), ``du_t =
+    up to ``t + K - 1``), ``d pre = dy silu'(pre)`` in float32, ``du_t =
     sum_k w_k d pre_{t + (K - 1) - k}`` rounded once; the taps' and the
     bias's cotangents are summed over the cell's tokens in float32, a row a
     tap and the bias's last, and over the cells outside."""
@@ -245,11 +241,8 @@ def _conv_bwd_kernel(u_ref, before_ref, after_ref, dy_ref, dy_after_ref,
             dy_ref[here].astype(f32),
             _conv_beside(plan, dy_ref, jnp.where(last, 0, dy_after_ref[edge]),
                          j, start, ch, after=True).astype(f32)], axis=axis)
-        if silu:
-            gate = jax.nn.sigmoid(pre)
-            dpre = dy * (gate * (1 + pre * (1 - gate)))
-        else:
-            dpre = dy
+        gate = jax.nn.sigmoid(pre)
+        dpre = dy * (gate * (1 + pre * (1 - gate)))
         du = None
         for k in range(taps):
             shift = taps - 1 - k
@@ -273,7 +266,7 @@ def _conv_bwd_kernel(u_ref, before_ref, after_ref, dy_ref, dy_after_ref,
 
 
 def _conv_setup(kernel, body, u, weight, bias, first: int,
-                tokens_minor: bool, silu: bool):
+                tokens_minor: bool):
     """What both calls share: the cut; the tensor as the kernels take it,
     tokens last if they are minor; the block of channels its first one lies
     in; a function that lays a further operand out like the convolution's
@@ -341,22 +334,21 @@ def _conv_setup(kernel, body, u, weight, bias, first: int,
             dimension_semantics=("parallel",) * 3),
         interpret=_use_interpret(), name=kernel)
     body = functools.partial(body, plan=plan, taps=taps,
-                             bias=bias is not None, silu=silu)
+                             bias=bias is not None)
     return plan, u, lay, unlay, wb.T if axis else wb, specs, body, call
 
 
 @functools.partial(jax.jit, inline=True,
-                   static_argnames=("first", "tokens_minor", "silu"))
-def _conv_fwd_call(u, weight, bias, *, first: int, tokens_minor: bool,
-                   silu: bool):
+                   static_argnames=("first", "tokens_minor"))
+def _conv_fwd_call(u, weight, bias, *, first: int, tokens_minor: bool):
     """``u`` ``[B, S, F]``, float32 ``weight`` ``[K, C]`` and ``bias`` ``[C]``
-    or None -> the convolution of ``u``'s channels ``first`` to ``first +
-    C``, through a SiLU if ``silu``, ``[B, S, C]`` in ``u``'s dtype. (Jitted inline, as
+    or None -> ``silu`` of the convolution of ``u``'s channels ``first`` to
+    ``first + C``, ``[B, S, C]`` in ``u``'s dtype. (Jitted inline, as
     :func:`_conv_bwd_call` is: the body is traced once for a shape, and a
     block's recomputed copy and the next layers re-bind it.)"""
     plan, u, _, unlay, wb, specs, body, call = _conv_setup(
         CONV_KERNEL_FWD, _conv_fwd_kernel, u, weight, bias, first,
-        tokens_minor, silu)
+        tokens_minor)
     shape = (u.shape[0],) + ((plan.width, plan.seq) if plan.axis
                              else (plan.seq, plan.width))
     return unlay(pl.pallas_call(
@@ -367,15 +359,14 @@ def _conv_fwd_call(u, weight, bias, *, first: int, tokens_minor: bool,
 
 
 @functools.partial(jax.jit, inline=True,
-                   static_argnames=("first", "tokens_minor", "silu"))
-def _conv_bwd_call(u, weight, bias, dy, *, first: int, tokens_minor: bool,
-                   silu: bool):
+                   static_argnames=("first", "tokens_minor"))
+def _conv_bwd_call(u, weight, bias, dy, *, first: int, tokens_minor: bool):
     """The cotangents of :func:`_conv_fwd_call`'s inputs for ``dy`` ``[B, S,
     C]`` in ``u``'s dtype: ``du`` of the convolution's channels alone, in that
     dtype too, float32 ``dw`` ``[K, C]`` and ``db`` ``[C]``."""
     plan, u, lay, unlay, wb, specs, body, call = _conv_setup(
         CONV_KERNEL_BWD, _conv_bwd_kernel, u, weight, bias, first,
-        tokens_minor, silu)
+        tokens_minor)
     taps, channels = weight.shape
     dy = lay(dy)
     vma = _out_vma(u, dy, wb)
@@ -396,23 +387,21 @@ def _conv_bwd_call(u, weight, bias, dy, *, first: int, tokens_minor: bool,
     return unlay(du), sums[:taps], sums[taps]
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def _conv_silu(u, weight, bias, first, tokens_minor, silu):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _conv_silu(u, weight, bias, first, tokens_minor):
     return _conv_fwd_call(u, weight, bias, first=first,
-                          tokens_minor=tokens_minor, silu=silu)
+                          tokens_minor=tokens_minor)
 
 
-def _conv_silu_fwd(u, weight, bias, first, tokens_minor, silu):
+def _conv_silu_fwd(u, weight, bias, first, tokens_minor):
     # The residuals are the inputs alone: the pre-activation is never kept.
-    return (_conv_silu(u, weight, bias, first, tokens_minor, silu),
-            (u, weight, bias))
+    return _conv_silu(u, weight, bias, first, tokens_minor), (u, weight, bias)
 
 
-def _conv_silu_bwd(first, tokens_minor, silu, kept, dy):
+def _conv_silu_bwd(first, tokens_minor, kept, dy):
     u, weight, bias = kept
     du, dw, db = _conv_bwd_call(u, weight, bias, dy.astype(u.dtype),
-                                first=first, tokens_minor=tokens_minor,
-                                silu=silu)
+                                first=first, tokens_minor=tokens_minor)
     beyond = u.shape[2] - first - weight.shape[1]
     if first or beyond:  # the channels the convolution did not read
         du = jnp.pad(du, ((0, 0), (0, 0), (first, beyond)))
@@ -422,14 +411,10 @@ def _conv_silu_bwd(first, tokens_minor, silu, kept, dy):
 _conv_silu.defvjp(_conv_silu_fwd, _conv_silu_bwd)
 
 
-ACTIVATIONS = ("silu", None)
-
-
 def causal_conv_silu(u, weight, bias, *, first: int = 0,
-                     minor: str = "channels", activation="silu"):
+                     minor: str = "channels"):
     """``silu(causal_conv1d(u[..., first:first + C], weight,
-    bias)).astype(u.dtype)`` (``activation=None``: without the SiLU, the
-    convolution itself) in one pass over the tensor a direction: two
+    bias)).astype(u.dtype)`` in one pass over the tensor a direction: two
     Pallas kernels under one ``jax.custom_vjp`` (``hvd_conv_fwd``,
     ``hvd_conv_bwd``) that read ``u`` ``[B, S, F]`` in its own dtype, take
     the taps ``[K, C]``, the bias and the SiLU in float32 in
@@ -446,8 +431,6 @@ def causal_conv_silu(u, weight, bias, *, first: int = 0,
     zeros and cut off again."""
     if minor not in ("channels", "tokens"):
         raise ValueError(f"minor={minor!r}: 'channels' or 'tokens'")
-    if activation not in ACTIVATIONS:
-        raise ValueError(f"activation={activation!r}: one of {ACTIVATIONS}")
     if not 0 <= first <= u.shape[2] - weight.shape[1]:
         raise ValueError(
             f"channels {first} to {first + weight.shape[1]} of {u.shape[2]}")
@@ -455,5 +438,4 @@ def causal_conv_silu(u, weight, bias, *, first: int = 0,
     weight = varying_like(weight.astype(f32), u)
     if bias is not None:
         bias = varying_like(bias.astype(f32), u)
-    return _conv_silu(u, weight, bias, first, minor == "tokens",
-                      activation == "silu")
+    return _conv_silu(u, weight, bias, first, minor == "tokens")

@@ -1,8 +1,10 @@
 """What the Pallas kernel families share, said once: the tile's widths, the
 masked exponent, the two contraction patterns, the platform test and a few
 helpers. The floor of ``ops/``: it imports nothing of the package, and every
-kernel module (``flash_attention``, ``ssd``, ``gated_delta``, ``conv``)
-imports these names from here and none from another."""
+kernel module (``flash_attention``, ``ssd``, ``gated_delta``, ``conv``,
+``cca``) imports these names from here and no kernel's part from another
+(``cca`` takes two plain references, ``conv.causal_conv1d`` and
+``attention.rope``, for the lines its kernels are held to)."""
 
 from __future__ import annotations
 
@@ -60,12 +62,13 @@ def varying_like(x, like):
     return lax.pcast(x, tuple(axes), to="varying") if axes else x
 
 
-def always(body):
-    """Run ``body`` under a predicate that always holds, NOT unguarded:
+def always(body, axis: int = 2):
+    """Run ``body`` under a predicate that always holds (of the grid's
+    ``axis``), NOT unguarded:
     interpret mode inside a ``shard_map`` matches the varying axes of a
     block's fetch only along a ``pl.when`` path
     (``flash_attention.Mask.tile_kept``); compiled, Mosaic folds the constant."""
-    pl.when(pl.program_id(2) >= 0)(body)
+    pl.when(pl.program_id(axis) >= 0)(body)
 
 
 def div(x, n: int):

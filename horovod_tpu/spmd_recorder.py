@@ -152,6 +152,17 @@ _TRACED = {
         "channels a grid cell holds, and which of the two is on the lanes.",
         ("kernel", "channels", "taps", "bias", "operand_dtype", "tile",
          "minor")),
+    "hvdtpu_spmd_cca_kernel_traces_total": (
+        "Times JAX traced one of the kernels of a CCA mixer's mix (both "
+        "convolutions, the q/k means, the L2 norms under the key "
+        "temperature and the rotary embedding in one pass over the latent a "
+        "direction), by kernel and the cut the call got from its shapes: "
+        "the tokens a grid cell and a piece of its walk hold, the query and "
+        "key heads, the lanes a head occupies (its size rounded up to the "
+        "lane width: more than head_dim is zeros), the dimensions of a head "
+        "the rotary embedding turns and the operand's dtype.",
+        ("kernel", "tokens", "rows", "heads", "kv_heads", "head_lanes",
+         "rotary_dim", "operand_dtype")),
     "hvdtpu_spmd_remat_saved_bytes_total": (
         "Bytes a checkpointed block hands from its forward to its backward "
         "pass beside its input, by remat mode and the name the value "

@@ -34,7 +34,7 @@ os.environ.setdefault("TPU_LOG_DIR", "disabled")
 KERNELS = ("hvd_flash_fwd", "hvd_flash_dkdv", "hvd_flash_dq",
            "hvd_ssd_fwd", "hvd_ssd_bwd", "hvd_gdn_fwd", "hvd_gdn_bwd",
            "hvd_gdn_rec_fwd", "hvd_gdn_rec_bwd", "hvd_conv_fwd",
-           "hvd_conv_bwd", "ragged-dot-none")
+           "hvd_conv_bwd", "hvd_cca_fwd", "hvd_cca_bwd", "ragged-dot-none")
 GIB = 2.0 ** 30
 
 

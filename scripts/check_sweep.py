@@ -20,7 +20,7 @@ scaled for another head size, the norms before the branches instead of
 after, a rotary embedding, no q/k norm, the running sums of the log decays
 in bfloat16; for a CCA job the residual scaling or the routers' carried
 state left out, the rotary embedding on the whole head, the MLP router's
-products in one bfloat16 pass, the mix's means and norms in bfloat16), against
+products in one bfloat16 pass, the mix as its plain lines in bfloat16), against
 the untouched reference: what a job's tolerances must catch. A variant
 changes the job's ``GPTConfig`` after the job is built, before its step is
 traced (or what the program's modules see, where no field says it); a job
@@ -127,12 +127,15 @@ def _no_router_state() -> None:
 
 
 def _mix_in_bfloat16() -> None:
-    """A CCA mixer's means, L2 norms, temperature and rotary embedding in
-    bfloat16 where the program computes them in float32 from bfloat16
-    inputs: ``_cca_mixer`` sees a ``jax.numpy`` whose ``float32`` is
-    ``bfloat16`` (the grouped stage's sums with them)."""
+    """A CCA mixer's means, L2 norms, temperature, rotary embedding and
+    grouped sums in bfloat16 where the program's kernels compute them in
+    float32 from bfloat16 inputs: ``_cca_mixer`` gets, in place of
+    ``ops/cca.py::cca_mix``, the plain lines the kernels are held to
+    (``cca_mix_reference``), and those see a ``jax.numpy`` whose
+    ``float32`` is ``bfloat16``."""
     import jax.numpy as jnp
     from horovod_tpu.models import gpt
+    from horovod_tpu.ops import cca
 
     class Rounded:
         float32 = jnp.bfloat16
@@ -140,16 +143,14 @@ def _mix_in_bfloat16() -> None:
         def __getattr__(self, name):
             return getattr(jnp, name)
 
-    real = gpt._cca_mixer
-
-    def mixer(*args):
-        gpt.jnp = Rounded()
+    def mix(*args, **kw):
+        cca.jnp = Rounded()
         try:
-            return real(*args)
+            return cca.cca_mix_reference(*args, **kw)
         finally:
-            gpt.jnp = jnp
+            cca.jnp = jnp
 
-    gpt._cca_mixer = mixer
+    gpt.cca_mix = mix
 
 
 # name -> what it does to a job already built (its step not yet traced)

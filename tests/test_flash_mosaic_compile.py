@@ -21,7 +21,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from horovod_tpu.ops import flash_attention as fa
-from horovod_tpu.ops import conv, gated_delta, pallas_util, ssd
+from horovod_tpu.ops import cca, conv, gated_delta, pallas_util, ssd
 
 
 @pytest.fixture(scope="module")
@@ -196,12 +196,9 @@ def test_gdn_scan_compiles_for_v5e_at_heads_of_any_size(one_chip, mosaic,
         assert f"hvd_gdn_{kernel}" in text
 
 
-# (B, S, C, bias, the axis the mixer asks for on the lanes, dtype; then,
-# where they are not four taps and a SiLU, the taps and whether a SiLU
-# follows): the convolution in front of the scan in the three recurrent
-# cells, a tensor no tile divides (a float32 one: the halo of 16 tokens is
-# two of its tiles), and a CCA mixer's depthwise stage, two taps and no
-# activation.
+# (B, S, C, bias, the axis the mixer asks for on the lanes): the convolution
+# in front of the scan in the three recurrent cells, and a tensor no tile
+# divides (a float32 one: the halo of 16 tokens is two of its tiles).
 CONV_SHAPES = {
     "qwen3-next-80b-a3b_s4096": (4, 4096, 8192, False, "channels",
                                  jnp.bfloat16),
@@ -214,27 +211,60 @@ CONV_SHAPES = {
                                            jnp.bfloat16),
     "ragged_float32": (2, 1000, 200, True, "channels", jnp.float32),
     "ragged_float32_tokens": (2, 1000, 200, True, "tokens", jnp.float32),
-    "zaya1-8b_s4096": (4, 4096, 1280, True, "channels", jnp.bfloat16, 2,
-                       False),
 }
 
 
 @pytest.mark.parametrize("kernel", ["fwd", "bwd"])
 @pytest.mark.parametrize("shape", list(CONV_SHAPES))
 def test_conv_kernel_compiles_for_v5e(one_chip, mosaic, shape, kernel):
-    batch, seq, channels, bias, minor, dtype, *rest = CONV_SHAPES[shape]
-    taps, silu = rest or (4, True)
+    batch, seq, channels, bias, minor, dtype = CONV_SHAPES[shape]
 
     def sds(*dims, dt=jnp.float32):
         return jax.ShapeDtypeStruct(dims, dt, sharding=one_chip)
 
     u = sds(batch, seq, channels, dt=dtype)
-    args = (u, sds(taps, channels), sds(channels) if bias else None)
+    args = (u, sds(4, channels), sds(channels) if bias else None)
     if kernel == "fwd":
         f = conv._conv_fwd_call
     else:
         f, args = conv._conv_bwd_call, args + (u,)
     text = jax.jit(functools.partial(
-        f, first=0, tokens_minor=minor == "tokens",
-        silu=silu)).lower(*args).compile().as_text()
+        f, first=0, tokens_minor=minor == "tokens")).lower(*args).compile() \
+        .as_text()
     assert "tpu_custom_call" in text and f"hvd_conv_{kernel}" in text
+
+
+# (B, S, query heads, key heads, head size, rotary dimensions): a CCA
+# mixer's mix at the zaya1-8b_s4096 cell's shape (taps (2, 2)), as the cell
+# runs it, with the rotary embedding on the whole head and without one.
+CCA_SHAPES = {
+    "zaya1-8b_s4096": (4, 4096, 8, 2, 128, 64),
+    "whole_head_rotary": (4, 4096, 8, 2, 128, 128),
+    "no_rotary": (4, 4096, 8, 2, 128, 0),
+}
+
+
+@pytest.mark.parametrize("kernel", ["fwd", "bwd"])
+@pytest.mark.parametrize("shape", list(CCA_SHAPES))
+def test_cca_kernel_compiles_for_v5e(one_chip, mosaic, shape, kernel):
+    batch, seq, heads, kv_heads, dim, rotary = CCA_SHAPES[shape]
+    plan = cca._plan(seq, heads, kv_heads, dim, 2, 2, rotary)
+    assert (plan.lanes, plan.seq) == (dim, seq)
+
+    def sds(*dims, dt=jnp.float32):
+        return jax.ShapeDtypeStruct(dims, dt, sharding=one_chip)
+
+    groups = heads + kv_heads
+    u = sds(batch, seq, plan.wide, dt=jnp.bfloat16)
+    args = (u, (sds(2, plan.wide), sds(plan.wide),
+                sds(2, groups, dim, dim), sds(plan.wide), sds(kv_heads)),
+            sds(batch, seq, 2 * dim) if rotary else None)
+    if kernel == "fwd":
+        f = cca._fwd_call
+    else:
+        f = cca._bwd_call
+        args += (sds(batch, seq, heads * dim, dt=jnp.bfloat16),
+                 sds(batch, seq, kv_heads * dim, dt=jnp.bfloat16))
+    text = jax.jit(functools.partial(f, plan=plan)).lower(*args).compile() \
+        .as_text()
+    assert "tpu_custom_call" in text and f"hvd_cca_{kernel}" in text

@@ -19,7 +19,6 @@ import pytest
 import horovod_tpu as hvd
 from horovod_tpu.models import gpt
 from horovod_tpu.models.gpt import LayerSpec
-from horovod_tpu.ops import conv
 
 from benchmarks.reference import gpt_cca_moe_dp as reference
 
@@ -362,45 +361,3 @@ def test_the_step_counts_its_cca_and_router_traces(spmd8):
     routers = {(labels["router"], labels["state"]) for _, labels, _ in
                fams["hvdtpu_spmd_moe_layer_traces_total"]["samples"]}
     assert routers == {("mlp", "0"), ("mlp", "1")}
-
-
-def lowered(fn, *args):
-    return jax.jit(fn).lower(*args).as_text()
-
-
-def test_the_activation_parameter_leaves_the_silu_path_as_it_was():
-    """The rule with its default is the rule with ``activation="silu"``,
-    program text and all; ``None`` is the same text less the SiLU (no
-    logistic anywhere in it), forward and backward."""
-    u = jnp.ones((1, 32, 128), jnp.bfloat16)
-    w, b = jnp.ones((2, 128)), jnp.ones((128,))
-
-    def both(**kw):
-        return lowered(jax.value_and_grad(
-            lambda u, w, b: conv.causal_conv_silu(u, w, b, **kw).astype(
-                jnp.float32).sum(), argnums=(0, 1, 2)), u, w, b)
-
-    default, silu, none = both(), both(activation="silu"), \
-        both(activation=None)
-    # (Interpreted off the TPU, a SiLU is an exponential and a division.)
-    assert default == silu and "exponential" in silu
-    assert "exponential" not in none and default != none
-    with pytest.raises(ValueError, match="activation"):
-        conv.causal_conv_silu(u, w, b, activation="gelu")
-    # And it computes the plain convolution, cotangents too.
-    key = jax.random.split(jax.random.PRNGKey(0), 3)
-    u = jax.random.normal(key[0], (2, 32, 128))
-    w, b = jax.random.normal(key[1], (2, 128)), jax.random.normal(key[2],
-                                                                  (128,))
-
-    def loss(fn):
-        return jax.value_and_grad(
-            lambda u, w, b: jnp.sum(jnp.sin(fn(u, w, b))),
-            argnums=(0, 1, 2))(u, w, b)
-
-    got = loss(lambda u, w, b: conv.causal_conv_silu(u, w, b,
-                                                     activation=None))
-    want = loss(conv.causal_conv1d)
-    for g, r in zip(jax.tree.leaves(got), jax.tree.leaves(want),
-                    strict=True):
-        np.testing.assert_allclose(g, r, rtol=1e-5, atol=1e-5)

@@ -325,17 +325,22 @@ CCA = dict(layers=(gpt.LayerSpec(mixer="cca", ff="experts"),) * 2,
 def test_cca_step_holds_its_parts_under_their_scopes(spmd4):
     """A CCA layer's mixer lies under ``layer<i>/attn`` with its own parts
     inside (where ``cca_ms`` and ``cca_mix_ms`` look): the latent
-    projections and ``W_o`` under ``cca_proj``, the depthwise stage's two
-    kernels and the rest of the mix under ``cca_mix``, the flash kernels
-    under ``attn`` itself; the whole MLP router under ``moe/router``; the
+    projections and ``W_o`` under ``cca_proj``, the mix's two kernels
+    (``hvd_cca_fwd`` in the forward pass and in the recomputed copy of a
+    block, ``hvd_cca_bwd`` in the backward pass) and the value's shift under
+    ``cca_mix``, the flash kernels under ``attn`` itself; the whole MLP router under ``moe/router``; the
     scaling of each sublayer's residual under ``res_scale``; forward, in
     the recomputed copy of a block and backward."""
     step, *args = gpt_step("full", **CCA)
     text = step.lower(*args).as_text(debug_info=True)
-    scopes = set(re.findall(r'loc\("([^"]*)/hvd_conv_(fwd|bwd)/', text))
-    assert {kernel for _, kernel in scopes} == {"fwd", "bwd"}
+    scopes = set(re.findall(r'loc\("([^"]*)/hvd_cca_(fwd|bwd)/', text))
     assert all(scope.endswith("/attn/cca_mix") for scope, _ in scopes), scopes
-    assert any("rematted_computation" in scope for scope, _ in scopes)
+    passes = {(kernel, "transpose(jvp(" in scope
+               and "rematted_computation" not in scope,
+               "rematted_computation" in scope) for scope, kernel in scopes}
+    assert passes == {("fwd", False, False), ("fwd", False, True),
+                      ("bwd", True, False)}, scopes
+    assert "hvd_conv" not in text
     flash = set(re.findall(r'loc\("([^"]*)/hvd_flash_(?:fwd|dkdv|dq)/', text))
     assert flash and all(scope.endswith("/attn") for scope in flash), flash
     names = op_names(step, *args)
@@ -349,8 +354,9 @@ def test_cca_step_holds_its_parts_under_their_scopes(spmd4):
             assert some(f"jvp({layer})", scope), (layer, scope)
             assert some(f"transpose(jvp({layer}))", scope), (layer, scope)
             assert some(layer, "rematted_computation", scope), (layer, scope)
-    # The grouped stage's products and the router's are under those scopes.
-    assert some("/attn/cca_mix/", "bsgi,gio->bsgo")
+    # The mix's kernels and the router's products are under those scopes.
+    for kernel in ("hvd_cca_fwd", "hvd_cca_bwd"):
+        assert some("/attn/cca_mix/", kernel)
     assert some("/moe/router/", "dot_general")
 
 
