@@ -174,10 +174,12 @@ class GPTConfig:
     # activation; "full" stores a block's input and what is dear to make
     # again (``SAVED_NAMES``: the flash kernel's output and log-sum-exp, the
     # dense feed-forward's pre-activation, the expert layer's matrices in
-    # the compute dtype, a state-space scan's output) and recomputes the
-    # rest in backward: in bfloat16
+    # the compute dtype, a state-space scan's output, each branch's output
+    # under a norm after the branch or a learned residual scale) and
+    # recomputes the rest in backward: in bfloat16
     # 2E + 2HD + 4H + 2M bytes a token a layer where the input alone is 2E
-    # (an expert block: no 2M, and 6 bytes an expert parameter a layer);
+    # (an expert block: no 2M, and 6 bytes an expert parameter a layer; 4E
+    # more where the branches' outputs are kept);
     # "dots" instead saves every matmul output (recompute only the cheap
     # elementwise work).
     remat: str = "none"                      # "none" | "full" | "dots"
@@ -1107,6 +1109,13 @@ def _block(cfg: GPTConfig, spec: LayerSpec, layer_params, x, positions,
         return _norm(cfg, x, lp[key]) if norm_before else x
 
     def after(branch, key):
+        # The backward pass of a norm after the branch reads the branch's
+        # value, and so does a learned scale's gradient in ``_residual``:
+        # under either the value is named (``SAVED_NAMES``), else the
+        # recomputed copy runs every branch to its last product again. A
+        # block that adds the branch and nothing else names nothing.
+        if norm_after or cfg.residual_scaling:
+            branch = checkpoint_name(branch, "branch_out")
         if not norm_after:
             return branch
         with jax.named_scope("post_norm"):
@@ -1156,16 +1165,28 @@ def _block(cfg: GPTConfig, spec: LayerSpec, layer_params, x, positions,
 # (``_gdn_mixer``: 2 Hv V bytes a token a layer; that scan is plain
 # ``jax.numpy`` whose backward pass needs its insides, so most of it is made
 # again all the same, but not the products that only give the output: +5.6%
-# on the chip, PERF.md, Findings, PR 31). A block that produces none of a
-# name keeps nothing under it. Norms, rotary, projections (a recurrent
-# mixer's input projection too), the convolution, the scans' decays and
-# chunk states, the router, the experts' sorted rows, gate and up products
-# and activation stay recomputed: the expert layer names nothing that lies in the sort's order,
-# which the backward pass makes again and which one near-tie in the
-# recomputed router shifts (``parallel/moe.py``'s docstring; PERF.md,
-# Findings, PR 28).
+# on the chip, PERF.md, Findings, PR 31), and a branch's output where the
+# block's own backward pass reads it (``_block``'s ``after``: under a norm
+# after the branch, whose backward pass reads what it normed, or a learned
+# residual scale, whose gradient is ``<g, branch + bias>``; 2 E bytes a token
+# a sublayer, 4 E a layer, in token order; without it the recomputed copy
+# runs each branch to its last product again, the feed-forward's down
+# product, a mixer's output projection and an expert sublayer whole, windows
+# and shared expert too; a block that only adds its branches names nothing
+# and keeps nothing. On the chip, PERF.md, Findings, PR 48:
+# ``trinity-mini_s8192`` +8.5% for 10 tensors of 64 MiB of which the step's
+# peak shows 0.10 GiB, ``olmo-hybrid-7b_s8192`` +3.9% for 8 of 60 MiB and
+# 0.23 GiB, ``zaya1-8b_s4096`` +4.6% for 12 of 64 MiB and 0.80 GiB). A block
+# that produces none of a name keeps nothing under it. Norms, rotary,
+# projections (a recurrent mixer's input projection too), the convolution,
+# the scans' decays and chunk states, the router, the experts' sorted rows,
+# gate and up products and activation stay recomputed: the expert layer
+# names nothing that lies in the sort's order, which the backward pass makes
+# again and which one near-tie in the recomputed router shifts
+# (``parallel/moe.py``'s docstring; PERF.md, Findings, PR 28).
 SAVED_NAMES = ("flash_out", "flash_lse", "ffn_pre_activation",
-               "moe_expert_matrices", "ssm_scan_out", "gdn_scan_out")
+               "moe_expert_matrices", "ssm_scan_out", "gdn_scan_out",
+               "branch_out")
 _save_names = jax.checkpoint_policies.save_only_these_names(*SAVED_NAMES)
 
 

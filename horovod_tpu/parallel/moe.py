@@ -78,12 +78,20 @@ beside ``y`` (the load-balance term's token fractions are constants).
 
 Under ``jax.checkpoint`` (``models/gpt.py``, ``remat="full"``) the layer makes
 again, for its backward pass, the router, the sort, the sorted rows, and the
-gate and up products with their activation, and nothing else. The down
+gate and up products with their activation, and nothing else, **so long as
+the caller's backward pass has no use for the layer's output**. The down
 projection and the weighted sum have a backward pass of their own
 (:func:`_down_and_combine`) that needs no expert's output, so their
 recomputation is dead code (a share's windows make theirs again inside the
 backward rule, from the recomputed sort, and are dead code in the
-recomputed copy altogether); and the three expert tensors in the compute dtype
+recomputed copy altogether) where the caller only adds ``y`` to its stream.
+A caller that norms ``y`` or scales it by a parameter reads ``y`` in its own
+backward pass, and then the recomputed copy runs the layer to its end, the
+down product, the sum back to tokens and every window of a share, unless
+the caller keeps ``y``: ``models/gpt.py::_block`` does, under the name
+``"branch_out"`` (``[tokens, d]`` in token order, not the sort's), exactly
+where its block has such a norm or scale. The three expert tensors in the
+compute dtype
 carry a name a checkpoint policy can keep (``checkpoint_name``:
 ``"moe_expert_matrices"``, 6 bytes an expert parameter in bfloat16, in
 ``gpt.SAVED_NAMES``), so the cast is made once. **Nothing whose rows lie in

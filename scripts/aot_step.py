@@ -4,7 +4,9 @@ and not attached, in the CPU sandbox: a sha256 of the lowered StableHLO and
 one of the compiled program, and what the program's own report says of the
 executable (``hvd.compiled_step_report``'s reducer, so the sandbox and the
 chip count alike): its memory, how many of each named kernel it holds, what
-the compiler made again and which arguments it copies. Nothing runs and no
+the compiler made again and which arguments it copies; and, from the trace,
+the bytes its checkpointed blocks keep by name (``remat_saved_bytes``: the
+job's ``hvdtpu_spmd_remat_saved_bytes_total``). Nothing runs and no
 time is taken; a compile that passes is not a chip run.
 
     python3 scripts/aot_step.py starcoder2-3b_s4096 olmoe-1b-7b_s4096
@@ -88,6 +90,14 @@ def compiled_text(text: str) -> str:
     return program_text(_METADATA.sub("", head + "\n" + body))
 
 
+def remat_saved_bytes(hvd) -> dict:
+    """``hvdtpu_spmd_remat_saved_bytes_total`` by name, as this process has
+    counted it so far (``models/gpt.py::_full_policy``, at trace time)."""
+    family = hvd.metrics().get("hvdtpu_spmd_remat_saved_bytes_total", {})
+    return {labels["name"]: value
+            for _, labels, value in family.get("samples", ())}
+
+
 def compile_cell(name: str, root: str, hlo_dir: str | None = None) -> dict:
     import jax
     from jax.sharding import NamedSharding
@@ -117,7 +127,11 @@ def compile_cell(name: str, root: str, hlo_dir: str | None = None) -> dict:
         jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=NamedSharding(
             hvd.mesh(), hvd.batch_spec(0)))
         for x in job.host_batches(1)[0])
+    kept_before = remat_saved_bytes(hvd)
     lowered = job.step.lower(params, opt_state, data)
+    kept = {name: nbytes - kept_before.get(name, 0)
+            for name, nbytes in remat_saved_bytes(hvd).items()
+            if nbytes != kept_before.get(name, 0)}
     t0 = time.time()
     compiled = lowered.compile()
     seconds = time.time() - t0
@@ -146,6 +160,9 @@ def compile_cell(name: str, root: str, hlo_dir: str | None = None) -> dict:
         "calls": {k: report["kernels"].get(k, 0) for k in KERNELS},
         "rematerialized": report["rematerialized"],
         "parameter_copies": report["parameter_copies"],
+        # What the checkpointed blocks of this cell's trace kept, by name:
+        # one block's bytes for each that JAX split (layers alike share one).
+        "remat_saved_bytes": kept,
     }
 
 

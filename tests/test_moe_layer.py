@@ -107,7 +107,7 @@ def test_down_and_combine_has_autodiffs_gradients(top_k, skew):
     autodiff of the plain formula, a matrix a row and no grouped matmul.
 
     What that buys shows in ``test_full_remat_keeps_what_is_dear_to_make_
-    again[sparse]``: the backward pass has no use for the recomputed down
+    again[sparse-adds]``: the backward pass has no use for the recomputed down
     projection, JAX drops it from the checkpointed block's jaxpr as dead code
     (the rule's forward is inlined there like any other code), and a layer's
     count of grouped matmuls is 11 in the jaxpr as in the compiled step."""
@@ -242,8 +242,30 @@ def ops_of(jaxpr, out=None):
     return out
 
 
+def step_ops(cfg, params, data):
+    """``ops_of`` the differentiated step of ``cfg``."""
+    return ops_of(jax.make_jaxpr(
+        lambda p: loss_and_grads(cfg, p, data))(params).jaxpr)
+
+
+def assert_remat_changes_no_number(full, none, params, data):
+    (l0, a0), g0 = loss_and_grads(none, params, data)
+    (l1, a1), g1 = loss_and_grads(full, params, data)
+    np.testing.assert_allclose(l1, l0, rtol=1e-6)
+    for a, b in zip(jax.tree.leaves((a1, g1)), jax.tree.leaves((a0, g0))):
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
+
+
+# What a block does with a branch beside adding it to the stream: nothing
+# (OLMoE's), a norm after it (Trinity's, Olmo's), a learned scale (ZAYA1's).
+# Under the last two the block's own backward pass reads the branch's value.
+BLOCKS = {"adds": {}, "post_norm": dict(post_norm=True),
+          "residual_scaling": dict(residual_scaling=True)}
+
+
+@pytest.mark.parametrize("block", BLOCKS)
 @pytest.mark.parametrize("feed_forward", ["dense", "sparse"])
-def test_full_remat_keeps_what_is_dear_to_make_again(feed_forward):
+def test_full_remat_keeps_what_is_dear_to_make_again(feed_forward, block):
     """Under ``remat="full"`` a block keeps the flash kernel's output and
     log-sum-exp and the dense feed-forward's pre-activation: the
     differentiated step holds no second flash forward and no second up
@@ -252,15 +274,20 @@ def test_full_remat_keeps_what_is_dear_to_make_again(feed_forward):
     routing again, and on the chip not always to the same choices: PERF.md,
     Findings, PR 28): the gate and up products run again, the down product
     does not, because the backward pass of ``moe._down_and_combine`` has no
-    use for it. The numbers are ``"none"``'s."""
+    use for it. Where the block's own backward pass has a use for a branch's
+    value (a norm after the branch, a learned residual scale) the block
+    keeps that value (``branch_out``), so no branch's last product runs
+    again there either: 11 grouped matmuls a layer and not 12, and as many
+    products ``[B, S, E]`` (the down product, the output projection and the
+    backward pass's) as a step without checkpointing holds. The numbers are
+    ``"none"``'s."""
     kind = dict(moe_every=0, mlp_dim=48) if feed_forward == "dense" else {}
-    full = olmoe(attention="flash", remat="full", **kind)
+    full = olmoe(attention="flash", remat="full", **kind, **BLOCKS[block])
     none = dataclasses.replace(full, remat="none")
     batch, seq = 2, 128
     params, data = olmoe_params(full), olmoe_batch(full, batch, seq)
 
-    ops = ops_of(jax.make_jaxpr(
-        lambda p: loss_and_grads(full, p, data))(params).jaxpr)
+    ops = step_ops(full, params, data)
     assert ops["hvd_flash_fwd"] == full.num_layers
     assert ops["hvd_flash_dkdv"] == ops["hvd_flash_dq"] == full.num_layers
     if feed_forward == "dense":
@@ -271,22 +298,60 @@ def test_full_remat_keeps_what_is_dear_to_make_again(feed_forward):
     else:
         # Three forward, gate and up again, two each backward.
         assert ops["ragged_dot_general"] == 11 * full.num_layers
+    # The stream's own shape: a block that only adds its branches makes the
+    # mixer's output projection again (the feed-forward reads the stream
+    # behind it) and not the feed-forward's last product; one that keeps
+    # its branches makes neither again.
+    again = full.num_layers if block == "adds" else 0
+    stream = "dot_general", (batch, seq, full.embed_dim)
+    assert ops[stream] == step_ops(none, params, data)[stream] + again
 
-    (l0, a0), g0 = loss_and_grads(none, params, data)
-    (l1, a1), g1 = loss_and_grads(full, params, data)
-    np.testing.assert_allclose(l1, l0, rtol=1e-6)
-    for a, b in zip(jax.tree.leaves((a1, g1)), jax.tree.leaves((a0, g0))):
-        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
+    if block != "adds":
+        # What these cases' numbers hold is the policy, not the kernels
+        # (interpreted, 2 s a run: the ``adds`` cases hold them).
+        full, none = (dataclasses.replace(cfg, attention="dense")
+                      for cfg in (full, none))
+    assert_remat_changes_no_number(full, none, params, data)
 
 
-def test_metrics_count_what_a_checkpointed_block_keeps(make_runtime):
+def test_a_kept_branch_leaves_no_window_in_the_recomputed_copy(
+        moe_row_tile):
+    """A share's windows under a norm after the branch: the forward pass
+    takes them, the backward rule takes them again by design
+    (``moe._held_experts_bwd``), and the recomputed copy between the two
+    holds none, because the block keeps the expert sublayer's output, shared
+    expert and all: two loops a layer, as without checkpointing, and the
+    grouped matmuls of two. (A shared expert under a sigmoid gate of its own
+    would make its down product again for the gate's gradient.) That the
+    numbers are ``"none"``'s: ``test_a_share_matches_its_reference`` for a
+    window under checkpointing, ``tests/test_gpt_window_moe.py`` for the
+    decoder under a norm after the branch."""
+    moe_row_tile(8)
+    full = olmoe(attention="dense", remat="full", post_norm=True,
+                 experts_held=2, first_expert=2, shared_expert_dim=16,
+                 shared_expert_gate=False, num_layers=1)
+    none = dataclasses.replace(full, remat="none")
+    batch, seq = 2, 32
+    assert moe.share_rows(batch * seq, 2, 2, 8) < batch * seq * 2
+    params, data = olmoe_params(full), olmoe_batch(full, batch, seq)
+
+    ops, plain = (step_ops(cfg, params, data) for cfg in (full, none))
+    assert ops["while"] == plain["while"] == 2 * full.num_layers
+    assert ops["ragged_dot_general"] == plain["ragged_dot_general"]
+    # Nor the shared expert's three products or any branch's last one.
+    stream = "dot_general", (batch, seq, full.embed_dim)
+    assert ops[stream] == plain[stream]
+
+
+@pytest.mark.parametrize("block", BLOCKS)
+def test_metrics_count_what_a_checkpointed_block_keeps(make_runtime, block):
     make_runtime(devices=jax.devices()[:1])
     family = "hvdtpu_spmd_remat_saved_bytes_total"
     # Shapes no other test of this file traces: JAX splits a block it has
     # split before from its cache, without asking the policy.
     batch, seq = 3, 256
     sparse = olmoe(attention="flash", remat="none", num_layers=1,
-                   num_experts=4)
+                   num_experts=4, **BLOCKS[block])
     data = olmoe_batch(sparse, batch, seq)
 
     def trace(cfg):
@@ -300,10 +365,6 @@ def test_metrics_count_what_a_checkpointed_block_keeps(make_runtime):
     trace(dataclasses.replace(sparse, remat="full", moe_every=0))
     fams = hvd.metrics()
     assert fams[family]["type"] == "counter"
-    # Neither block has a recurrent mixer: nothing is kept under their names
-    # (tests/test_gpt_hybrid.py and test_gpt_linear_moe.py count those).
-    assert {labels["name"] for _, labels, _ in fams[family]["samples"]} \
-        == set(gpt.SAVED_NAMES) - {"ssm_scan_out", "gdn_scan_out"}
     tokens, f32 = batch * seq, 4
     heads = sparse.num_heads * sparse.head_dim
     # Two blocks split, a flash pair each; the dense block's up projection;
@@ -314,8 +375,13 @@ def test_metrics_count_what_a_checkpointed_block_keeps(make_runtime):
             "ffn_pre_activation": tokens * sparse.mlp_dim * f32,
             "moe_expert_matrices": (3 * sparse.num_experts * sparse.embed_dim
                                     * sparse.mlp_dim * f32)}
-    for name, nbytes in want.items():
-        assert sample_value(fams, family, mode="full", name=name) == nbytes
+    if block != "adds":
+        # Two branches a block, where the block's backward pass reads them.
+        want["branch_out"] = 2 * 2 * tokens * sparse.embed_dim * f32
+    # Neither block has a recurrent mixer: nothing is kept under their names
+    # (tests/test_gpt_hybrid.py and test_gpt_linear_moe.py count those).
+    assert {labels["name"]: value for _, labels, value
+            in fams[family]["samples"] if labels["mode"] == "full"} == want
 
 
 def test_dense_decoder_has_no_auxiliary_terms():
