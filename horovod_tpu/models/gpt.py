@@ -49,6 +49,14 @@ compressed latent and mixed over the sequence before the heads attend
 a head under a learned key temperature, half of the value from the token
 before); it runs under the scope ``attn`` and through ``_attention`` as an
 ``"attention"`` mixer does, on one rank's whole sequence and all its heads.
+An ``"mla"`` mixer is latent attention (:func:`_mla_mixer`): a query head
+is a no-position part of ``head_dim`` beside a rotary part of
+``mla_rope_dim``, keys and values come from an RMS-normed latent of
+``mla_kv_rank`` (a key head's no-position part and a value head of
+``mla_value_dim`` each), and one rotary key a token is shared by all heads,
+so the scores are over ``head_dim + mla_rope_dim`` dimensions and the values
+of another width; it runs under ``attn`` and through ``_attention`` too, and
+a bound tp axis holds a shard of its heads.
 An expert block may hold a share of its router's experts
 (``experts_held``, ``first_expert``: ``parallel/moe.py``), renormalise a
 token's weights, add a shared expert every token goes through (under a
@@ -101,7 +109,7 @@ from ..parallel.ring_attention import ring_attention_p
 from ..parallel.ulysses import ulysses_attention_p
 
 
-MIXERS = ("attention", "cca", "ssm", "gdn")
+MIXERS = ("attention", "cca", "mla", "ssm", "gdn")
 ROUTERS = ("linear", "mlp")
 FEED_FORWARDS = ("dense", "gated", "experts")
 
@@ -110,8 +118,8 @@ FEED_FORWARDS = ("dense", "gated", "experts")
 class LayerSpec:
     """One layer of the stack: its mixer (one of ``MIXERS``), for an
     attention mixer the ``window`` (a query sees itself and the ``window -
-    1`` keys before it; None: every key before it), for an attention or a
-    CCA mixer whether the rotary
+    1`` keys before it; None: every key before it), for an attention, a
+    CCA or an MLA mixer whether the rotary
     embedding applies (``GPTConfig.rope_theta``, ``rotary_dim``), and its
     feed-forward (one of ``FEED_FORWARDS``: two matrices and a GELU, three
     and a SiLU gate, or the expert block with what ``GPTConfig`` says of
@@ -267,6 +275,14 @@ class GPTConfig:
     # kv_heads heads of head_dim: the taps of the depthwise stage and of the
     # stage grouped by head.
     cca_taps: Tuple[int, int] = (2, 2)
+    # An "mla" mixer (latent attention): a query and key head is head_dim
+    # dimensions without position beside mla_rope_dim rotary ones, the
+    # rotary key one a token for all heads; keys' no-position parts and the
+    # value heads of mla_value_dim come from an RMS-normed latent of
+    # mla_kv_rank. The query is projected straight from the stream.
+    mla_kv_rank: int = 512
+    mla_rope_dim: int = 64
+    mla_value_dim: int = 128
     # The router of an expert block, one of ``ROUTERS``: "linear", one
     # matrix [embed, experts]; "mlp": a down-projection to router_dim plus
     # a learned vector times the down-projection of the expert block
@@ -359,8 +375,8 @@ def layer_plan(cfg: GPTConfig) -> Tuple[LayerSpec, ...]:
         raise ValueError(
             f"layers must hold a LayerSpec (mixer one of {MIXERS}, "
             f"feed-forward one of {FEED_FORWARDS}, a window of at least one "
-            f"key on attention alone: a CCA layer has none yet) for each of "
-            f"the {cfg.num_layers} layers, got {plan!r}")
+            f"key on attention alone: a CCA layer has none yet, nor an MLA "
+            f"layer) for each of the {cfg.num_layers} layers, got {plan!r}")
     return plan
 
 
@@ -476,6 +492,23 @@ _CCA_NAMES = ("wqk", "wv", "conv0_w", "conv0_b", "conv1_w", "conv1_b",
               "temp", "wo")
 
 
+def _init_mla(key, cfg: GPTConfig, dense, norm) -> dict:
+    """A latent-attention mixer's parameters: the query projection ``[E, H,
+    no-position | rotary]``, the down-projection to ``[latent | the shared
+    rotary key]``, the latent's norm, the up-projection ``[rank, H, key's
+    no-position part | value]`` and the output projection."""
+    E, H, rank = cfg.embed_dim, cfg.num_heads, cfg.mla_kv_rank
+    nope, rot, value = cfg.head_dim, cfg.mla_rope_dim, cfg.mla_value_dim
+    ks = jax.random.split(key, 4)
+    return {
+        "wq": dense(ks[0], (E, H, nope + rot), E),
+        "wkv_a": dense(ks[1], (E, rank + rot), E),
+        "kv_norm": norm((rank,)),
+        "wkv_b": dense(ks[2], (rank, H, nope + value), rank),
+        "wo": dense(ks[3], (H, value, E), H * value),
+    }
+
+
 def _init_mlp_router(key, cfg: GPTConfig, dense, carry: bool) -> dict:
     """An MLP router's parameters; ``carry`` (every expert block but the
     first) the vector on the state from the block before, at one."""
@@ -581,6 +614,8 @@ def init_params(rng, cfg: GPTConfig) -> dict:
             layer = {"gdn": _init_gdn(ks[0], cfg, dense)}
         elif spec.mixer == "cca":
             layer = {"cca": _init_cca(ks[0], cfg, dense)}
+        elif spec.mixer == "mla":
+            layer = {"mla": _init_mla(ks[0], cfg, dense, norm)}
         else:
             layer = {
                 "wq": dense(ks[0], (E, H, 2 * D if cfg.attention_gate else D),
@@ -637,7 +672,9 @@ def init_params(rng, cfg: GPTConfig) -> dict:
 
 def param_specs(cfg: GPTConfig) -> dict:
     """PartitionSpec pytree matching :func:`init_params` — tp shards heads and
-    MLP hidden; ep shards experts; everything else replicated, a state-space,
+    MLP hidden (a latent-attention mixer's ``wq``, ``wkv_b`` and ``wo`` by
+    head, its down-projection and the latent's norm whole on every rank);
+    ep shards experts; everything else replicated, a state-space,
     gated-delta-rule or CCA mixer included (each refuses a bound tp axis),
     an MLP router, the residual scaling's vectors and the router's selection
     bias (state every rank holds whole)."""
@@ -663,6 +700,10 @@ def param_specs(cfg: GPTConfig) -> dict:
                     "norm", "out_proj")}}
         elif spec.mixer == "cca":
             layer = {"cca": {name: P() for name in _CCA_NAMES}}
+        elif spec.mixer == "mla":
+            layer = {"mla": {
+                "wq": P(None, tp, None), "wkv_a": P(), "kv_norm": P(),
+                "wkv_b": P(None, tp, None), "wo": P(tp, None, None)}}
         else:
             layer = {
                 "wq": P(None, tp, None),
@@ -1024,6 +1065,49 @@ def _cca_mixer(cfg: GPTConfig, spec: LayerSpec, p, h, positions):
                           p["wo"].astype(cfg.dtype))
 
 
+def _mla_mixer(cfg: GPTConfig, spec: LayerSpec, p, h, positions):
+    """Latent attention (MLA, as training runs it: keys and values
+    decompressed a head) on normed activations ``h`` ``[B, S, E]``, ``H``
+    heads, ``dn = head_dim``, ``dr = mla_rope_dim``, ``dv = mla_value_dim``,
+    ``r = mla_kv_rank``: ``q_h = [qn_h (dn) | qr_h (dr)] = h W_q``; ``[c0 (r)
+    | kr0 (dr)] = h W_kv_a``; ``c = RMSNorm(c0)``; ``[kn_h (dn) | v_h (dv)] =
+    c W_kv_b``; where ``spec.rope`` says so the rotary embedding on all
+    ``dr`` dimensions of ``qr_h`` and of ``kr0``, **one** rotary key a token
+    that every head shares; ``k_h = [kn_h | kr]``; the attention
+    ``_attention`` picks, scores over ``dn + dr`` dimensions scaled by one
+    over its root, values ``dv`` wide; ``W_o``. No bias. Under a bound tp
+    axis a rank holds a shard of the heads (``W_q``, ``W_kv_b``, ``W_o``)
+    and makes the latent and the shared key whole."""
+    nope, rot, rank = cfg.head_dim, cfg.mla_rope_dim, cfg.mla_kv_rank
+    runtime.note_traced(
+        "hvdtpu_spmd_mla_traces_total", heads=cfg.num_heads, nope_dim=nope,
+        rope_dim=rot, value_dim=cfg.mla_value_dim, kv_rank=rank,
+        q_rank="none")
+    with jax.named_scope("mla_proj"):
+        q = jnp.einsum("bse,ehd->bshd", h, p["wq"].astype(cfg.dtype))
+        a = jnp.einsum("bse,ef->bsf", h, p["wkv_a"].astype(cfg.dtype))
+        c = _norm(cfg, a[..., :rank], p["kv_norm"])
+        kv = jnp.einsum("bsr,rhd->bshd", c, p["wkv_b"].astype(cfg.dtype))
+    with jax.named_scope("mla_rope"):
+        q_rot, k_rot = q[..., nope:], a[:, :, None, rank:]
+        if spec.rope:
+            q_rot = rope(q_rot, positions, cfg.rope_theta)
+            k_rot = rope(k_rot, positions, cfg.rope_theta)
+        q = jnp.concatenate([q[..., :nope], q_rot], axis=-1)
+        k = jnp.concatenate(
+            [kv[..., :nope],
+             jnp.broadcast_to(k_rot, (*kv.shape[:3], rot))], axis=-1)
+        v = kv[..., nope:]
+        if cfg.attention_multiplier is not None:
+            # As in ``_attention_mixer``: every attention scales by one
+            # over the root of the query's width, the rest goes onto q.
+            q = q * (cfg.attention_multiplier * float(np.sqrt(nope + rot)))
+    attn = _attention(cfg, q, k, v)
+    with jax.named_scope("mla_proj"):
+        o = jnp.einsum("bshd,hde->bse", attn, p["wo"].astype(cfg.dtype))
+    return _tp_psum(o, cfg)
+
+
 def _mlp_router(cfg: GPTConfig, r, h, state):
     """``(the router's outputs [B, S, experts], the state [B, S, R])`` of an
     MLP router ``r`` on normed activations ``h``, all float32 at the highest
@@ -1099,7 +1183,8 @@ def _block(cfg: GPTConfig, spec: LayerSpec, layer_params, x, positions,
     # A window layer's mixer is under ``attn_window``, a full one's under
     # ``attn``: a device trace tells their flash kernels apart by it. A CCA
     # layer's is under ``attn`` too, its own parts ``cca_proj`` and
-    # ``cca_mix`` inside.
+    # ``cca_mix`` inside; so is a latent-attention layer's, with
+    # ``mla_proj`` and ``mla_rope``.
     lp = layer_params
     norm_before, norm_after = norm_placement(cfg)
     mixer_res, mlp_res = (lp[key] for key in _RESIDUAL_KEYS) \
@@ -1122,9 +1207,10 @@ def _block(cfg: GPTConfig, spec: LayerSpec, layer_params, x, positions,
             return _norm(cfg, branch, lp[key])
 
     # A recurrent mixer's scope, its parameters' key and its norm's
-    # (``<mixer>_norm``) carry its name; so do a CCA mixer's key and norm.
+    # (``<mixer>_norm``) carry its name; so do a CCA or MLA mixer's key and
+    # norm.
     scope = spec.mixer
-    if spec.mixer in ("attention", "cca"):
+    if spec.mixer in ("attention", "cca", "mla"):
         scope = "attn" if spec.window is None else "attn_window"
     with jax.named_scope(scope):
         h = before(_norm_names(spec, True, False)[0])
@@ -1132,6 +1218,8 @@ def _block(cfg: GPTConfig, spec: LayerSpec, layer_params, x, positions,
             branch = _attention_mixer(cfg, spec, lp, h, positions)
         elif spec.mixer == "cca":
             branch = _cca_mixer(cfg, spec, lp["cca"], h, positions)
+        elif spec.mixer == "mla":
+            branch = _mla_mixer(cfg, spec, lp["mla"], h, positions)
         else:
             branch = _RECURRENT_MIXERS[spec.mixer](cfg, lp[spec.mixer], h)
         x = _residual(cfg, x, after(branch, "mixer_post_norm"), mixer_res)
@@ -1231,7 +1319,8 @@ def _hidden(params, tokens, positions, cfg: GPTConfig):
     # configuration norms a branch after it (``norm_placement``); ``ssm``
     # and ``gdn`` hold
     # ``in_proj``, ``conv``, ``scan``, ``gate_norm``, ``out_proj``; a CCA
-    # layer's ``attn`` holds ``cca_proj`` and ``cca_mix``; ``moe``
+    # layer's ``attn`` holds ``cca_proj`` and ``cca_mix``, a
+    # latent-attention layer's ``mla_proj`` and ``mla_rope``; ``moe``
     # holds ``router`` (an MLP router whole, its state included),
     # ``dispatch``, ``experts``, ``combine`` and
     # ``shared``; ``res_scale`` where the residual is scaled), ``head``;

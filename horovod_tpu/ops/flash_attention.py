@@ -16,7 +16,13 @@ Design notes (TPU):
   head count, ``[B*Hkv, S, D]``. Grouped-query attention is an index map:
   query head ``h`` reads K/V head ``h // group``; nothing is repeated in
   HBM, and dK/dV of one K/V head accumulate over its whole group inside the
-  dKdV kernel and are written once.
+  dKdV kernel and are written once. A value head may have another width
+  than a query and key head (latent attention: scores over 192 dimensions,
+  values of 128): q, k, dQ, dK are ``D`` wide, v, o, dO, dV and the
+  accumulator ``Dv``, each a block's whole minor axis (Mosaic takes a minor
+  axis of one and a half lane tiles as it stands; nothing is padded), and
+  the logits' scale is one over the root of ``D``. With ``Dv == D`` the
+  kernels are the ones they were.
 * The tile follows the call. ``block_sizes`` gives ``(block_q, block_k)``
   per kernel from what the trace can see (padded S, D, operand dtype,
   causal): the largest candidate that divides the padded length and whose
@@ -108,41 +114,48 @@ _CANDIDATES = (1024, 512, 256, 128)
 
 
 def vmem_estimate(kernel: str, block_q: int, block_k: int, d: int,
-                  itemsize: int) -> int:
+                  itemsize: int, dv: Optional[int] = None) -> int:
     """Bytes of VMEM one grid step of ``kernel`` holds: every streamed block
     twice (the pipeline's double buffer), the scratch, and the score-sized
     temporaries of the body (float32, plus the casts to the operand dtype).
-    ``d`` counts as a whole lane tile."""
+    ``d`` is the width of a query and key head (q, k, dQ, dK), ``dv`` that of
+    a value head (v, o, dO, dV, the accumulator; None: ``d``); each counts
+    as whole lane tiles."""
     d = -(-d // LANES) * LANES
+    dv = d if dv is None else -(-dv // LANES) * LANES
     q_blk, k_blk = block_q * d, block_k * d
+    o_blk, v_blk = block_q * dv, block_k * dv
     tile = block_q * block_k
     if kernel == KERNEL_FWD:
-        blocks = (2 * q_blk + 2 * k_blk) * itemsize + block_q * LANES * 4
-        scratch = (2 * block_q * LANES + q_blk) * 4
+        blocks = (q_blk + o_blk + k_blk + v_blk) * itemsize \
+            + block_q * LANES * 4
+        scratch = (2 * block_q * LANES + o_blk) * 4
         temps = tile * (3 * 4 + itemsize)
     elif kernel == KERNEL_DKDV:
-        blocks = (2 * q_blk + 4 * k_blk) * itemsize + 2 * 8 * block_q * 4
-        scratch = 2 * k_blk * 4
+        blocks = (q_blk + o_blk + 2 * k_blk + 2 * v_blk) * itemsize \
+            + 2 * 8 * block_q * 4
+        scratch = (k_blk + v_blk) * 4
         temps = tile * (4 * 4 + 2 * itemsize)
     else:
-        blocks = (3 * q_blk + 2 * k_blk) * itemsize \
+        blocks = (2 * q_blk + o_blk + k_blk + v_blk) * itemsize \
             + 2 * block_q * LANES * 4
         scratch = q_blk * 4
         temps = tile * (4 * 4 + 2 * itemsize)
     return 2 * blocks + scratch + temps
 
 
-def block_sizes(kernel: str, s_pad: int, d: int, dtype,
-                causal: bool) -> tuple[int, int]:
+def block_sizes(kernel: str, s_pad: int, d: int, dtype, causal: bool,
+                dv: Optional[int] = None) -> tuple[int, int]:
     """``(block_q, block_k)`` of ``kernel`` for a call the trace sees as
-    padded length ``s_pad``, head size ``d``, operand ``dtype``: the
+    padded length ``s_pad``, query/key head size ``d``, value head size
+    ``dv`` (None: ``d``), operand ``dtype``: the
     largest candidate that divides ``s_pad``, halved (the key side first)
     while the VMEM estimate is over the limit the call sets. A pure
     function of its arguments."""
     del causal  # the call can see it; the table does not split on it
     itemsize = jnp.dtype(dtype).itemsize
     bq = bk = next(c for c in _CANDIDATES if s_pad % c == 0)
-    while vmem_estimate(kernel, bq, bk, d, itemsize) > VMEM_LIMIT_BYTES:
+    while vmem_estimate(kernel, bq, bk, d, itemsize, dv) > VMEM_LIMIT_BYTES:
         if bk >= bq and bk > _PAD:
             bk //= 2
         elif bq > _PAD:
@@ -413,16 +426,17 @@ def _pad_seq(x):
     return x
 
 
-def _blocks_for(kernel, q, k, mask: Mask, forced):
+def _blocks_for(kernel, q, k, v, mask: Mask, forced):
     """The call's tile, forced or from the table; and, trace time only, the
-    record of it and of the tiles its grid keeps and skips behind
-    ``hvd.metrics()``."""
+    record of it (with the width of a query/key head and of a value head)
+    and of the tiles its grid keeps and skips behind ``hvd.metrics()``."""
     bq, bk = forced or block_sizes(kernel, q.shape[1], q.shape[2], q.dtype,
-                                   mask.causal)
+                                   mask.causal, v.shape[2])
     runtime.note_traced(
         "hvdtpu_spmd_flash_kernel_traces_total", kernel=kernel, block_q=bq,
         block_k=bk, operand_dtype=jnp.dtype(q.dtype).name,
-        kv_group=q.shape[0] // k.shape[0])
+        kv_group=q.shape[0] // k.shape[0], key_dim=q.shape[2],
+        value_dim=v.shape[2])
     for tiles, n in mask.tiles(q.shape[1] // bq, q.shape[1] // bk,
                                bq, bk).items():
         runtime.note_traced(
@@ -442,11 +456,14 @@ def _kv_map(group: int, block_q: int, block_k: int, mask: Mask):
 
 
 def _fwd_call(q, k, v, sm_scale, mask, kv_len, forced=None):
-    """q: [B*H, S, D], k/v: [B*Hkv, S, D] (S already padded; ``kv_len`` is
-    the real key count before padding; ``mask`` a :class:`Mask`). Returns (o, lse), lse lane-replicated [B*H, S, 128]."""
+    """q: [B*H, S, D], k: [B*Hkv, S, D], v: [B*Hkv, S, Dv] (S already
+    padded; ``kv_len`` is the real key count before padding; ``mask`` a
+    :class:`Mask`). Returns (o [B*H, S, Dv], lse), lse lane-replicated
+    [B*H, S, 128]."""
     bh, s, d = q.shape
+    dv = v.shape[2]
     group = bh // k.shape[0]
-    bq, bk = _blocks_for(KERNEL_FWD, q, k, mask, forced)
+    bq, bk = _blocks_for(KERNEL_FWD, q, k, v, mask, forced)
     n_q, n_k = s // bq, s // bk
 
     kv_map = _kv_map(group, bq, bk, mask)
@@ -460,15 +477,15 @@ def _fwd_call(q, k, v, sm_scale, mask, kv_len, forced=None):
         in_specs=[
             pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, bk, d), kv_map),
-            pl.BlockSpec((1, bk, d), kv_map),
+            pl.BlockSpec((1, bk, dv), kv_map),
         ],
         out_specs=[
-            pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, bq, dv), lambda b, i, j: (b, i, 0)),
             # lse rides lane-replicated [bh, s, 128] (see _fwd_kernel).
             pl.BlockSpec((1, bq, LANES), lambda b, i, j: (b, i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, s, d), q.dtype,
+            jax.ShapeDtypeStruct((bh, s, dv), q.dtype,
                                  vma=_out_vma(q, k, v)),
             jax.ShapeDtypeStruct((bh, s, LANES), jnp.float32,
                                  vma=_out_vma(q, k, v)),
@@ -476,7 +493,7 @@ def _fwd_call(q, k, v, sm_scale, mask, kv_len, forced=None):
         scratch_shapes=[
             pltpu.VMEM((bq, LANES), jnp.float32),   # running max
             pltpu.VMEM((bq, LANES), jnp.float32),   # running denominator
-            pltpu.VMEM((bq, d), jnp.float32),        # output accumulator
+            pltpu.VMEM((bq, dv), jnp.float32),       # output accumulator
         ],
         compiler_params=_compiler_params(),
         interpret=_use_interpret(),
@@ -486,11 +503,13 @@ def _fwd_call(q, k, v, sm_scale, mask, kv_len, forced=None):
 
 def _dkdv_call(q, k, v, do, lse, delta, sm_scale, mask, kv_len,
                forced=None):
-    """dK, dV at the K/V head count. ``lse``/``delta``: [B*H, 1, S] rows."""
+    """dK, dV at the K/V head count (``v``, ``do`` and dV as wide as a
+    value head). ``lse``/``delta``: [B*H, 1, S] rows."""
     bh, s, d = q.shape
+    dv = v.shape[2]
     bkv = k.shape[0]
     group = bh // bkv
-    bq, bk = _blocks_for(KERNEL_DKDV, q, k, mask, forced)
+    bq, bk = _blocks_for(KERNEL_DKDV, q, k, v, mask, forced)
     n_q, n_k = s // bq, s // bk
 
     def q_block(b, j, t):
@@ -518,22 +537,22 @@ def _dkdv_call(q, k, v, do, lse, delta, sm_scale, mask, kv_len,
         in_specs=[
             pl.BlockSpec((1, bq, d), q_map),                           # q
             pl.BlockSpec((1, bk, d), lambda b, j, t: (b, j, 0)),       # k
-            pl.BlockSpec((1, bk, d), lambda b, j, t: (b, j, 0)),       # v
-            pl.BlockSpec((1, bq, d), q_map),                           # do
+            pl.BlockSpec((1, bk, dv), lambda b, j, t: (b, j, 0)),      # v
+            pl.BlockSpec((1, bq, dv), q_map),                          # do
             pl.BlockSpec((1, 1, bq), row_map),                         # lse
             pl.BlockSpec((1, 1, bq), row_map),                         # delta
         ],
         out_specs=[
             pl.BlockSpec((1, bk, d), lambda b, j, t: (b, j, 0)),
-            pl.BlockSpec((1, bk, d), lambda b, j, t: (b, j, 0)),
+            pl.BlockSpec((1, bk, dv), lambda b, j, t: (b, j, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bkv, s, d), k.dtype, vma=vma),
-            jax.ShapeDtypeStruct((bkv, s, d), v.dtype, vma=vma),
+            jax.ShapeDtypeStruct((bkv, s, dv), v.dtype, vma=vma),
         ],
         scratch_shapes=[
             pltpu.VMEM((bk, d), jnp.float32),
-            pltpu.VMEM((bk, d), jnp.float32),
+            pltpu.VMEM((bk, dv), jnp.float32),
         ],
         compiler_params=_compiler_params(),
         interpret=_use_interpret(),
@@ -542,10 +561,12 @@ def _dkdv_call(q, k, v, do, lse, delta, sm_scale, mask, kv_len,
 
 
 def _dq_call(q, k, v, do, lse, delta, sm_scale, mask, kv_len, forced=None):
-    """dQ. ``lse``/``delta``: lane-replicated [B*H, S, 128]."""
+    """dQ (``v`` and ``do`` as wide as a value head). ``lse``/``delta``:
+    lane-replicated [B*H, S, 128]."""
     bh, s, d = q.shape
+    dv = v.shape[2]
     group = bh // k.shape[0]
-    bq, bk = _blocks_for(KERNEL_DQ, q, k, mask, forced)
+    bq, bk = _blocks_for(KERNEL_DQ, q, k, v, mask, forced)
     n_q, n_k = s // bq, s // bk
 
     kv_map = _kv_map(group, bq, bk, mask)
@@ -563,8 +584,8 @@ def _dq_call(q, k, v, do, lse, delta, sm_scale, mask, kv_len, forced=None):
         in_specs=[
             pl.BlockSpec((1, bq, d), q_map),                           # q
             pl.BlockSpec((1, bk, d), kv_map),                          # k
-            pl.BlockSpec((1, bk, d), kv_map),                          # v
-            pl.BlockSpec((1, bq, d), q_map),                           # do
+            pl.BlockSpec((1, bk, dv), kv_map),                         # v
+            pl.BlockSpec((1, bq, dv), q_map),                          # do
             pl.BlockSpec((1, bq, LANES), q_map),                      # lse
             pl.BlockSpec((1, bq, LANES), q_map),                      # delta
         ],
@@ -623,7 +644,9 @@ def flash_attention(q, k, v, causal: bool = True, *,
     use); k/v: ``[B, S, Hkv, D]`` where ``Hkv`` may divide ``H``
     (grouped-query attention: the kernels read K/V head ``h // group`` for
     query head ``h``, nothing is repeated, and dK/dV come back at ``Hkv``
-    heads). Differentiable (custom VJP, flash backward).
+    heads); v may be ``[B, S, Hkv, Dv]`` with ``Dv != D``, and the output
+    is then ``[B, S, H, Dv]`` (the scores are over ``D`` and scaled by one
+    over its root). Differentiable (custom VJP, flash backward).
 
     ``causal=True`` (decoder) skips the tiles above the diagonal;
     ``causal=False`` (encoder/bidirectional) computes all blocks with the
@@ -641,6 +664,9 @@ def flash_attention(q, k, v, causal: bool = True, *,
     if k.shape[2] != v.shape[2] or h % k.shape[2]:
         raise ValueError(f"query heads ({h}) not a multiple of kv heads "
                          f"(k {k.shape[2]}, v {v.shape[2]})")
+    if k.shape[3] != d:
+        raise ValueError(f"a key head is as wide as a query head ({d}), "
+                         f"got {k.shape[3]}; a value head may differ")
     if _blocks is not None:
         _blocks = tuple(int(x) for x in _blocks)
         s_pad = s + (-s) % _PAD
@@ -652,8 +678,8 @@ def flash_attention(q, k, v, causal: bool = True, *,
                 None if window is None or window >= s else int(window))
 
     def to_bhsd(x):
-        return _pad_seq(x.transpose(0, 2, 1, 3).reshape(-1, s, d))
+        return _pad_seq(x.transpose(0, 2, 1, 3).reshape(-1, s, x.shape[3]))
 
     o = _flash_bhsd(to_bhsd(q), to_bhsd(k), to_bhsd(v), sm_scale, mask, s,
                     _blocks)
-    return o[:, :s, :].reshape(b, h, s, d).transpose(0, 2, 1, 3)
+    return o[:, :s, :].reshape(b, h, s, v.shape[3]).transpose(0, 2, 1, 3)

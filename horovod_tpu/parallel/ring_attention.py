@@ -69,7 +69,8 @@ def ring_attention_p(q, k, v, causal: bool = True,
     Args:
       q: ``[B, Sq_shard, H, D]`` query block (this rank's sequence shard).
       k, v: ``[B, Sk_shard, Hkv, D]`` key/value blocks; ``Hkv`` may divide ``H``
-        (GQA).
+        (GQA), and ``v`` may be ``Dv`` wide where ``q`` and ``k`` are ``D``
+        (latent attention): the output is then ``Dv`` wide.
       causal: apply causal masking using global positions.
       axis: mesh axis name to ring over (default: the mesh's "sp" axis; raises
         if the mesh has none — there is deliberately no dp fallback, see
@@ -97,7 +98,7 @@ def ring_attention_p(q, k, v, causal: bool = True,
     q32 = q.astype(jnp.float32) * scale
 
     # Online-softmax accumulators (flash recurrence), [B, H, Sq] layout.
-    o_acc = jnp.zeros((B, H, Sq, D), jnp.float32)
+    o_acc = jnp.zeros((B, H, Sq, v.shape[-1]), jnp.float32)
     l_acc = jnp.zeros((B, H, Sq), jnp.float32)
     m_acc = jnp.full((B, H, Sq), _NEG_INF, jnp.float32)
 

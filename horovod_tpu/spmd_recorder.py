@@ -83,8 +83,10 @@ _TRACED = {
     "hvdtpu_spmd_flash_kernel_traces_total": (
         "Times JAX traced a flash attention kernel, by kernel and the tiling "
         "the call got: block sizes, the MXU operands' dtype, query heads per "
-        "K/V head.",
-        ("kernel", "block_q", "block_k", "operand_dtype", "kv_group")),
+        "K/V head, the width of a query and key head and of a value head "
+        "(latent attention's differ).",
+        ("kernel", "block_q", "block_k", "operand_dtype", "kv_group",
+         "key_dim", "value_dim")),
     "hvdtpu_spmd_flash_tiles_total": (
         "Tiles of the grids of the flash attention kernels JAX traced, by "
         "kernel, the mask's kind (causal, window, full), the padded length "
@@ -117,6 +119,14 @@ _TRACED = {
         "of the depthwise and of the grouped stage, and the dimensions of a "
         "head the rotary embedding turns.",
         ("heads", "kv_heads", "head_dim", "taps0", "taps1", "rotary_dim")),
+    "hvdtpu_spmd_mla_traces_total": (
+        "Times JAX traced a latent attention (MLA) mixer (keys and values "
+        "from a normed latent, one rotary key a token for all heads; the "
+        "recomputed copy of a block counts again), by its heads, the "
+        "no-position and the rotary part of a query/key head, a value "
+        "head's size, the key/value latent's rank and the query latent's "
+        "(none: the query is projected straight from the stream).",
+        ("heads", "nope_dim", "rope_dim", "value_dim", "kv_rank", "q_rank")),
     "hvdtpu_spmd_ssm_layer_traces_total": (
         "Times JAX traced a chunked state-space scan (the recomputed copy of "
         "a block counts again), by its heads, their size, the state's size, "

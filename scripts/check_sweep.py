@@ -20,7 +20,9 @@ scaled for another head size, the norms before the branches instead of
 after, a rotary embedding, no q/k norm, the running sums of the log decays
 in bfloat16; for a CCA job the residual scaling or the routers' carried
 state left out, the rotary embedding on the whole head, the MLP router's
-products in one bfloat16 pass, the mix as its plain lines in bfloat16), against
+products in one bfloat16 pass, the mix as its plain lines in bfloat16; for a
+latent-attention job the logits scaled for the no-position part of a head
+alone, no rotary embedding), against
 the untouched reference: what a job's tolerances must catch. A variant
 changes the job's ``GPTConfig`` after the job is built, before its step is
 traced (or what the program's modules see, where no field says it); a job
@@ -174,6 +176,10 @@ VARIANTS = {
     "whole_head_rotary": lambda job: _replace(job, rotary_dim=None),
     "router_mlp_bf16": lambda job: _mlp_router_in_bfloat16(),
     "cca_mix_bf16": lambda job: _mix_in_bfloat16(),
+    "mla_scale_nope": lambda job: _replace(
+        job, attention_multiplier=job.cfg.head_dim ** -0.5),
+    "mla_no_rope": lambda job: _replace(job, layers=tuple(
+        dataclasses.replace(spec, rope=False) for spec in job.cfg.plan)),
 }
 
 

@@ -296,3 +296,115 @@ def test_non_causal_gradients_match_dense(s):
     for gf, gr in zip(g_flash, g_ref):
         np.testing.assert_allclose(np.asarray(gf), np.asarray(gr),
                                    rtol=2e-4, atol=2e-4)
+
+
+# ---- a value head of another width than a query/key head --------------------
+
+# (dk, dv, H, Hkv, window, causal): latent attention's 192 beside 128 (one
+# and a half lane tiles), heads smaller than a tile, grouped-query heads and
+# a band among them.
+TWO_WIDTHS = [
+    (192, 128, 1, 1, None, True),
+    (192, 128, 2, 1, 96, True),
+    (64, 32, 2, 1, None, False),
+    (32, 64, 1, 1, 100, True),
+]
+
+
+def _qkv_two_widths(s, h, hkv, dk, dv, seed, dtype=jnp.float32):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    return (jax.random.normal(ks[0], (1, s, h, dk), dtype) * 0.5,
+            jax.random.normal(ks[1], (1, s, hkv, dk), dtype) * 0.5,
+            jax.random.normal(ks[2], (1, s, hkv, dv), dtype) * 0.5)
+
+
+@pytest.mark.parametrize("dk, dv, h, hkv, window, causal", TWO_WIDTHS)
+def test_two_widths_match_dense(dk, dv, h, hkv, window, causal):
+    """Scores over ``dk`` dimensions (scaled by one over its root), values of
+    ``dv``: the output and dV are ``dv`` wide, dQ and dK ``dk``, all equal
+    to the dense reference on repeated heads."""
+    s = 200          # padded to 256: two tiles a side, the last one short
+    q, k, v = _qkv_two_widths(s, h, hkv, dk, dv, seed=dk + dv)
+    w = jax.random.normal(jax.random.PRNGKey(43), (1, s, h, dv)) * 0.1
+
+    def dense(q, k, v):
+        return default_attention(q, repeat_kv_heads(k, h),
+                                 repeat_kv_heads(v, h), causal=causal,
+                                 window=window)
+
+    got = _value_and_grads(flash_attention, q, k, v, w, causal=causal,
+                           window=window, _blocks=(128, 128))
+    want = _value_and_grads(dense, q, k, v, w)
+    assert got[0].shape == (1, s, h, dv)
+    assert [g.shape for g in got[1:]] == [q.shape, k.shape, v.shape]
+    np.testing.assert_allclose(got[0], want[0], rtol=2e-5, atol=2e-5)
+    for g, r, name in zip(got[1:], want[1:], "qkv"):
+        np.testing.assert_allclose(g, r, rtol=5e-4, atol=5e-5,
+                                   err_msg=f"d{name}")
+
+
+def test_dense_reference_takes_a_value_head_of_another_width():
+    """``default_attention`` scales by one over the root of the query's
+    width and multiplies by a ``v`` of any width: by hand on two tokens."""
+    q = jnp.asarray([[[[1.0, 0.0, 2.0]], [[0.0, 3.0, 0.0]]]])    # [1,2,1,3]
+    k = jnp.asarray([[[[1.0, 1.0, 0.0]], [[0.0, 1.0, 1.0]]]])
+    v = jnp.asarray([[[[1.0, 2.0]], [[3.0, 5.0]]]])              # [1,2,1,2]
+    out = np.asarray(default_attention(q, k, v, causal=True))
+    p = np.exp(np.asarray([3.0, 3.0]) / np.sqrt(3.0))
+    p = p / p.sum()
+    np.testing.assert_allclose(out[0, 0, 0], [1.0, 2.0], rtol=1e-6)
+    np.testing.assert_allclose(out[0, 1, 0],
+                               p[0] * np.asarray([1.0, 2.0])
+                               + p[1] * np.asarray([3.0, 5.0]), rtol=1e-6)
+
+
+def test_a_key_head_must_be_as_wide_as_a_query_head():
+    q, k, v = _qkv_two_widths(128, 2, 2, 64, 32, seed=3)
+    with pytest.raises(ValueError, match="as wide as a query head"):
+        flash_attention(q, v, v)
+
+
+@pytest.mark.parametrize("kernel", KERNELS)
+def test_block_table_counts_both_widths(kernel):
+    """Equal widths said twice are the one-width table; a wider value head
+    costs VMEM as a wider head does, each width as whole lane tiles."""
+    for d in (64, 128, 256):
+        assert fa.vmem_estimate(kernel, 512, 512, d, 2, d) \
+            == fa.vmem_estimate(kernel, 512, 512, d, 2)
+        assert fa.block_sizes(kernel, 4096, d, jnp.bfloat16, True, d) \
+            == fa.block_sizes(kernel, 4096, d, jnp.bfloat16, True)
+    both = fa.vmem_estimate(kernel, 512, 512, 192, 2, 128)
+    assert fa.vmem_estimate(kernel, 512, 512, 128, 2) < both \
+        < fa.vmem_estimate(kernel, 512, 512, 256, 2)
+    assert both == fa.vmem_estimate(kernel, 512, 512, 256, 2, 100)
+    assert fa.block_sizes(kernel, 8192, 192, jnp.bfloat16, True, 128) \
+        == (1024, 1024)
+
+
+# sha256 of the StableHLO text (no source locations) that the gradient of a
+# call with one head width lowered to at the commit before a value head
+# could have a width of its own (PR 49), interpreted kernels included. A
+# change that means to alter what such a call traces to pins these anew.
+LOWERED_BEFORE_TWO_WIDTHS = {
+    (4, 2, 64, None, "bfloat16"):
+        "b7df1a3308dfbb094cc1570a0a64ed5aa6bfbb9eb6b204d2eb273168efefb65d",
+    (2, 2, 128, 96, "float32"):
+        "251c9b2872434b42ad0182be6941cbe84c30d453ecd5b30f04af70b6bc656d24",
+}
+
+
+@pytest.mark.parametrize("case", list(LOWERED_BEFORE_TWO_WIDTHS),
+                         ids=lambda c: "-".join(map(str, c)))
+def test_equal_widths_lower_to_the_program_they_lowered_to(case):
+    import hashlib
+    h, hkv, d, window, dtype = case
+    q = jax.ShapeDtypeStruct((1, 256, h, d), jnp.dtype(dtype))
+    k = jax.ShapeDtypeStruct((1, 256, hkv, d), jnp.dtype(dtype))
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, window=window)
+                       .astype(jnp.float32))
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, k, k).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest() \
+        == LOWERED_BEFORE_TWO_WIDTHS[case]

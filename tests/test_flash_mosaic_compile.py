@@ -54,7 +54,8 @@ def mosaic(monkeypatch):
         yield
 
 
-# (B*H, B*Hkv, S, D, dtype, causal): the two cells; chip_smoke's legs.
+# (B*H, B*Hkv, S, D or (D, Dv), dtype, causal): the two cells; chip_smoke's
+# legs.
 SHAPES = {
     "starcoder2-3b_s4096": (48, 4, 4096, 128, jnp.bfloat16, True),
     "starcoder2-3b_s512": (384, 32, 512, 128, jnp.bfloat16, True),
@@ -62,6 +63,10 @@ SHAPES = {
     # 4 sequences at 16:2 heads of 256: the widest head a cell runs.
     "qwen3-next-80b-a3b_s4096": (64, 8, 4096, 256, jnp.bfloat16, True),
     "encoder_f32_s384": (8, 8, 384, 64, jnp.float32, False),
+    # 2 sequences at 16:16 latent-attention heads: scores over 192
+    # dimensions (one and a half lane tiles), values of 128.
+    "moonlight-16b-a3b_s8192": (32, 32, 8192, (192, 128), jnp.bfloat16,
+                                True),
 }
 
 
@@ -69,24 +74,26 @@ SHAPES = {
 @pytest.mark.parametrize("shape", list(SHAPES))
 def test_kernel_compiles_for_v5e(one_chip, mosaic, shape, kernel):
     bh, bkv, s, d, dtype, causal = SHAPES[shape]
+    d, dv = d if isinstance(d, tuple) else (d, d)
     mask = fa.Mask(causal=causal)
 
     def sds(*dims, dt=dtype):
         return jax.ShapeDtypeStruct(dims, dt, sharding=one_chip)
 
-    q, k = sds(bh, s, d), sds(bkv, s, d)
+    q, k, v, do = sds(bh, s, d), sds(bkv, s, d), sds(bkv, s, dv), \
+        sds(bh, s, dv)
     scale = d ** -0.5
     if kernel == "fwd":
         f = lambda q, k, v: fa._fwd_call(q, k, v, scale, mask, s)
-        args = (q, k, k)
+        args = (q, k, v)
     elif kernel == "dkdv":
         f = lambda *a: fa._dkdv_call(*a, scale, mask, s)
         rows = sds(bh, 1, s, dt=jnp.float32)
-        args = (q, k, k, q, rows, rows)
+        args = (q, k, v, do, rows, rows)
     else:
         f = lambda *a: fa._dq_call(*a, scale, mask, s)
         cols = sds(bh, s, 128, dt=jnp.float32)
-        args = (q, k, k, q, cols, cols)
+        args = (q, k, v, do, cols, cols)
     text = jax.jit(f).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text and f"hvd_flash_{kernel}" in text
 

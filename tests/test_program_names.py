@@ -360,6 +360,37 @@ def test_cca_step_holds_its_parts_under_their_scopes(spmd4):
     assert some("/moe/router/", "dot_general")
 
 
+def test_mla_step_holds_its_parts_under_their_scopes(spmd4):
+    """A latent-attention layer's mixer lies under ``layer<i>/attn`` with
+    its own parts inside (where ``mla_ms``, ``mla_proj_ms`` and
+    ``mla_rope_ms`` look): the five projections and the latent's norm under
+    ``mla_proj``, the two rotations, the shared key's broadcast and the
+    concatenations under ``mla_rope``, the flash kernels under ``attn``
+    itself; forward, in the recomputed copy of a block and backward."""
+    step, *args = gpt_step(
+        "full", layers=(gpt.LayerSpec(mixer="mla", ff="gated"),) * 2,
+        num_heads=4, mla_rope_dim=4, mla_value_dim=16, mla_kv_rank=16)
+    text = step.lower(*args).as_text(debug_info=True)
+    flash = set(re.findall(r'loc\("([^"]*)/hvd_flash_(?:fwd|dkdv|dq)/', text))
+    assert flash and all(scope.endswith("/attn") for scope in flash), flash
+    scopes = set(re.findall(r'loc\("([^"]*/attn/mla_(?:proj|rope))/', text))
+    for layer in ("layer0", "layer1"):
+        for part in ("mla_proj", "mla_rope"):
+            for where in (f"jvp({layer})", f"transpose(jvp({layer}))",
+                          "rematted_computation"):
+                assert any(layer in s and where in s
+                           and s.endswith("/attn/" + part)
+                           for s in scopes), (layer, part, where)
+    # The five products (``W_o``'s too) and the shared key's joining.
+    ops = set(re.findall(r'/attn/(mla_(?:proj|rope)/[^"]*)"', text))
+    assert {"mla_proj/bse,ehd->bshd/dot_general",
+            "mla_proj/bse,ef->bsf/dot_general",
+            "mla_proj/bsr,rhd->bshd/dot_general",
+            "mla_proj/bshd,hde->bse/dot_general", "mla_proj/rsqrt",
+            "mla_rope/concatenate", "mla_rope/cos",
+            "mla_rope/broadcast_in_dim"} <= ops, ops
+
+
 # ---- (b) kernel names --------------------------------------------------------
 
 def _flash(grad: bool):
