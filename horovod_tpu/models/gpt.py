@@ -885,7 +885,9 @@ def _gdn_mixer(cfg: GPTConfig, p, h):
     """A gated-delta-rule mixer on normed activations ``h`` ``[B, S, E]``:
     ``[q | k | v | z] = h W_qkvz``, ``[b | a] = h W_ba``; ``[q | k | v]``
     through the causal depthwise convolution (no bias) and SiLU; ``q`` and
-    ``k`` L2-normalised a head, ``q`` over the root of its size besides;
+    ``k`` L2-normalised a head, ``q`` over the root of its size besides
+    (inside the scan's chunk-local kernels, ``norm_qk``: the mixer hands
+    both over as the convolution wrote them and holds no float32 copy);
     ``beta = sigmoid(b)``, or ``2 sigmoid(b)`` under
     ``cfg.gdn_allow_neg_eigval``, ``g = -exp(A_log) softplus(a + dt_bias)``,
     both float32, one a value head; the chunked scan
@@ -914,21 +916,19 @@ def _gdn_mixer(cfg: GPTConfig, p, h):
                                minor="channels" if whole else "tokens")
         q, k, v = jnp.split(qkv, [key_inner, 2 * key_inner], axis=-1)
     with jax.named_scope("scan"):
-        def unit(t):
-            t = t.reshape(batch, seq, key_heads, cfg.gdn_key_dim).astype(f32)
-            return t * lax.rsqrt(jnp.sum(t * t, axis=-1, keepdims=True)
-                                 + 1e-6)
-
         b, a = jnp.split(ba.astype(f32), 2, axis=-1)
         g = -jnp.exp(p["A_log"]) * jax.nn.softplus(a + p["dt_bias"])
         beta_max = 2 if cfg.gdn_allow_neg_eigval else 1
+        # q and k as the convolution left them: the scan's kernels norm a
+        # head's rows in VMEM and scale q (norm_qk).
         o, _ = gated_delta_chunked(
-            (unit(q) * float(cfg.gdn_key_dim) ** -0.5).astype(cfg.dtype),
-            unit(k).astype(cfg.dtype),
+            q.reshape(batch, seq, key_heads, cfg.gdn_key_dim),
+            k.reshape(batch, seq, key_heads, cfg.gdn_key_dim),
             v.reshape(batch, seq, heads, cfg.gdn_value_dim), g,
             jax.nn.sigmoid(b) if beta_max == 1
             else float(beta_max) * jax.nn.sigmoid(b),
-            chunk=cfg.gdn_chunk, dtype=cfg.dtype, beta_max=beta_max)
+            chunk=cfg.gdn_chunk, dtype=cfg.dtype, beta_max=beta_max,
+            norm_qk=True)
         o = checkpoint_name(o, "gdn_scan_out")
     with jax.named_scope("gate_norm"):
         y = _rmsnorm(o, p["norm"], f32, cfg.norm_eps) * jax.nn.silu(

@@ -22,8 +22,19 @@ key, the eigenvalue ``alpha (1 - beta)`` along it: in [0, 1) for ``beta`` in
 it: "negative eigenvalues"); the arithmetic is the same, ``A``'s entries
 double and ``T`` lies further from the identity. Value head ``h`` of ``Hv``
 reads key head ``h // (Hv / Hk)``.
-``q`` and ``k`` come as the caller made them (the model L2-normalises both
-and scales ``q``: ``models/gpt.py::_gdn_mixer``).
+``q`` and ``k`` enter the rule L2-normalised a head, ``q`` over the root of
+the head's size besides. **Where the norm is made** is the static argument
+``norm_qk`` of both forms: without it the caller made it and the rows come
+normed; with it (what ``models/gpt.py::_gdn_mixer`` passes, and nothing
+else) the rows come raw, as the mixer's convolution wrote them, and the
+chunk-local kernels make it in VMEM: a row ``t`` of a key head's ``[Q, K]``
+block becomes ``t * rsqrt(sum(t * t) + 1e-6)`` in float32 (``q`` times
+``K^-0.5`` for the head's true ``K``), rounded to the operand dtype once,
+before ``K K^T`` and ``Q K^T``, and the backward kernel, which makes the
+normed rows again, turns its float32 ``dq`` and ``dk`` into the raw rows'
+cotangents, ``dt = r (dn - n <dn, n>)`` for ``n = t r``, before their one
+rounding. No float32 copy of ``q`` or ``k`` and no head-shaped one reaches
+HBM (PERF.md, Findings, PR 50). :func:`unit_rows` is the plain line.
 
 :func:`gated_delta_sequential` is that recurrence one token a step, float32:
 what the tests hold the rest to, not a path to train on.
@@ -49,7 +60,7 @@ float32, as ``ops/ssd.py::ssd_chunked`` does.
 
 **The chunk-local kernels** (``hvd_gdn_fwd``, ``hvd_gdn_bwd``, one
 ``jax.custom_vjp``: :func:`_chunk_local`) compute everything with two
-chunk-length axes: ``K
+chunk-length axes: the rows' norms under ``norm_qk``, ``K
 K^T`` and ``Q K^T`` (once a key head), the masked decay tile ``exp(cum_i -
 cum_j)``, ``A``, ``T`` (float32 throughout, rounded to ``dtype`` once before
 it is applied), ``u_own = T (beta V)`` (float32), ``w = T (beta G K)``,
@@ -74,8 +85,10 @@ interpret mode; on it a chunk they do not tile raises (:func:`_tiling`).
 and go as published; the four kernels carry a head at the next multiple of
 the lane width (``K`` 96 -> 128, ``V`` 192 -> 256: Mosaic's blocks are whole
 lane tiles), the one place that knows being :func:`gated_delta_chunked`,
-which pads ``q``, ``k``, ``v`` and the initial state with zeros
-(:func:`_to_lanes`) and drops the padded columns of ``o`` and rows and
+which pads ``q``, ``k`` (raw under ``norm_qk``: zeros add nothing to a row's
+sum of squares, so the norm over 128 lanes is the norm over 96), ``v`` and
+the initial state with zeros (:func:`_to_lanes`) and drops the padded
+columns of ``o`` and rows and
 columns of the final state. Zeros change no product: ``K K^T`` and ``Q K^T``
 sum over the key lanes, the padded columns of ``u``, ``w``, ``q G`` and ``k
 G_last / G`` are zero, so the padded rows and columns of the state stay zero
@@ -151,6 +164,7 @@ KERNEL_BWD = "hvd_gdn_bwd"
 KERNEL_REC_FWD = "hvd_gdn_rec_fwd"
 KERNEL_REC_BWD = "hvd_gdn_rec_bwd"
 _HI = lax.Precision.HIGHEST
+_NORM_EPS = 1e-6  # added to a row's sum of squares under ``norm_qk``
 _MAX_CHUNKS = 4   # chunks a grid cell, at most
 _SUBSTITUTE = 32  # rows of the inverse's diagonal blocks made by substitution
 _REC_HEADS = 8    # value heads a grid cell of the recurrence, at most
@@ -168,13 +182,28 @@ def _check(q, k, v, g, beta):
             f"{q.shape}, {k.shape}, {v.shape}, {g.shape}, {beta.shape}")
 
 
-def gated_delta_sequential(q, k, v, g, beta, initial_state=None):
+def unit_rows(t, scale: float = 1.0):
+    """``t`` L2-normalised along its last axis in float32, times ``scale``:
+    ``t * rsqrt(sum(t * t) + 1e-6) * scale``. The plain line the kernels'
+    norm is held to (``norm_qk``); a zero row stays zero."""
+    t = t.astype(jnp.float32)
+    return t * lax.rsqrt(jnp.sum(t * t, axis=-1, keepdims=True)
+                         + _NORM_EPS) * scale
+
+
+def gated_delta_sequential(q, k, v, g, beta, initial_state=None, *,
+                           norm_qk: bool = False):
     """The recurrence one token a step, float32. ``q``, ``k`` ``[B, S, Hk,
     K]``; ``v`` ``[B, S, Hv, V]``; ``g`` (log decay, <= 0) and ``beta``
-    ``[B, S, Hv]``. Returns ``(o [B, S, Hv, V], state [B, Hv, K, V])``."""
+    ``[B, S, Hv]``. Under ``norm_qk`` ``q`` and ``k`` come raw and are
+    L2-normalised a head here, ``q`` over the root of ``K`` besides
+    (:func:`unit_rows`), as :func:`gated_delta_chunked`'s kernels do.
+    Returns ``(o [B, S, Hv, V], state [B, Hv, K, V])``."""
     _check(q, k, v, g, beta)
     f32 = jnp.float32
     rep = v.shape[2] // k.shape[2]
+    if norm_qk:
+        q, k = unit_rows(q, q.shape[-1] ** -0.5), unit_rows(k)
     q, k = (jnp.repeat(t.astype(f32), rep, axis=2) for t in (q, k))
     v, g, beta = (t.astype(f32) for t in (v, g, beta))
 
@@ -322,11 +351,23 @@ def _row_sum(t):
 class _Chunk:
     """What both kernels make of one chunk of one key head: ``q``, ``k`` in
     the operand dtype and float32, ``K K^T`` and ``Q K^T`` (float32, made
-    once for the key head's value heads) and the two triangular masks."""
+    once for the key head's value heads) and the two triangular masks.
+    With ``q_scale`` (``norm_qk``) the blocks come raw: a row is normed in
+    float32, ``t * rsqrt(sum(t * t) + eps)``, ``q`` times ``q_scale``
+    besides, and rounded to the operand dtype once, before the products;
+    the rows' ``rsqrt`` stay for :meth:`raw_cotangents`."""
 
-    def __init__(self, q_ref, k_ref, at):
+    def __init__(self, q_ref, k_ref, at, q_scale):
         f32 = jnp.float32
         self.q, self.k = q_ref[0, at, :], k_ref[0, at, :]
+        self.raw, self.q_scale = (self.q, self.k), q_scale
+        if q_scale is not None:
+            dtype = self.q.dtype
+            q, k = self.q.astype(f32), self.k.astype(f32)
+            self.inv = (lax.rsqrt(_row_sum(q * q) + _NORM_EPS),
+                        lax.rsqrt(_row_sum(k * k) + _NORM_EPS))
+            self.q = (q * self.inv[0] * q_scale).astype(dtype)
+            self.k = (k * self.inv[1]).astype(dtype)
         self.qf, self.kf = self.q.astype(f32), self.k.astype(f32)
         self.kk = lax.dot_general(self.k, self.k, NT,
                                   preferred_element_type=f32)
@@ -337,6 +378,20 @@ class _Chunk:
         cols = lax.broadcasted_iota(jnp.int32, (size, size), 1)
         self.lower, self.strictly = rows >= cols, rows > cols
         self.diagonal = rows == cols
+
+    def raw_cotangents(self, dq, dk):
+        """The float32 cotangents of the blocks as they came, for those of
+        the normed ones: for a row ``n = t r``, ``r = rsqrt(|t|^2 + eps)``,
+        ``dt = r (dn - n <dn, n>)``, ``q``'s times its scale. The
+        cotangents themselves where the caller normed."""
+        if self.q_scale is None:
+            return dq, dk
+        out = []
+        for raw, inv, dn, scale in zip(self.raw, self.inv, (dq, dk),
+                                       (self.q_scale, 1.0)):
+            n = raw.astype(jnp.float32) * inv
+            out.append((dn - n * _row_sum(dn * n)) * (inv * scale))
+        return out
 
     def column(self, block, head):
         """Column ``head`` (a traced index) of ``block`` ``[Q, Hv]``, ``[Q,
@@ -367,7 +422,7 @@ class _Chunk:
 
 def _fwd_kernel(q_ref, k_ref, v_ref, cum_ref, beta_ref, u_ref, w_ref,
                 attn_ref, qin_ref, kout_ref, *, nc: int, rep: int,
-                chunk: int, width: int):
+                chunk: int, width: int, q_scale):
     """A grid cell: ``nc`` chunks of one sequence, one key head and its
     ``rep`` value heads. ``u = T (beta V)`` float32, ``w = T (beta G K)``,
     ``attn = (Q K^T) * decay``, ``q G`` and ``k G_last / G``: what the
@@ -379,7 +434,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, cum_ref, beta_ref, u_ref, w_ref,
     def _chunks():
         def one(n, carry):
             at = pl.ds(pl.multiple_of(n * chunk, chunk), chunk)
-            c = _Chunk(q_ref, k_ref, at)
+            c = _Chunk(q_ref, k_ref, at, q_scale)
             for r in range(rep):
                 beta, decay, grown, to_end, _, t = c.head(
                     cum_ref, beta_ref, at, first + r)
@@ -400,7 +455,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, cum_ref, beta_ref, u_ref, w_ref,
 
 def _bwd_kernel(q_ref, k_ref, v_ref, cum_ref, beta_ref, du_ref, dw_ref,
                 dattn_ref, dqin_ref, dkout_ref, dq_ref, dk_ref, dv_ref,
-                drows_ref, *, nc: int, rep: int, chunk: int, width: int):
+                drows_ref, *, nc: int, rep: int, chunk: int, width: int,
+                q_scale):
     """The forward's cotangents on the same grid cell. ``A`` and ``T`` are
     made again from the inputs; ``dT = du (beta V)^T + dw (beta G K)^T``,
     ``dA = -T^T dT T^T`` (float32, the highest precision), and from ``dA``
@@ -420,7 +476,7 @@ def _bwd_kernel(q_ref, k_ref, v_ref, cum_ref, beta_ref, du_ref, dw_ref,
 
         def one(n, carry):
             at = pl.ds(pl.multiple_of(n * chunk, chunk), chunk)
-            c = _Chunk(q_ref, k_ref, at)
+            c = _Chunk(q_ref, k_ref, at, q_scale)
             dkk = dqk = jnp.zeros((chunk, chunk), f32)
             dq = dk = jnp.zeros(c.qf.shape, f32)
             drows = jnp.zeros((2 * rep, chunk), f32)
@@ -472,6 +528,7 @@ def _bwd_kernel(q_ref, k_ref, v_ref, cum_ref, beta_ref, du_ref, dw_ref,
                 + lax.dot_general(dkk, c.k, TN, preferred_element_type=f32) \
                 + lax.dot_general(dqk, c.q, TN, preferred_element_type=f32)
             dq = dq + jnp.dot(dqk, c.k, preferred_element_type=f32)
+            dq, dk = c.raw_cotangents(dq, dk)
             dq_ref[0, at, :] = dq.astype(dq_ref.dtype)
             dk_ref[0, at, :] = dk.astype(dk_ref.dtype)
             drows_ref[0, n, 0] = drows
@@ -480,7 +537,7 @@ def _bwd_kernel(q_ref, k_ref, v_ref, cum_ref, beta_ref, du_ref, dw_ref,
         lax.fori_loop(0, nc, one, 0)
 
 
-def _plan(kernel, body, q, k, v, cum, beta):
+def _plan(kernel, body, q, k, v, cum, beta, q_scale):
     """What both calls share: the operands as the kernels read them, the
     block specs by name on the grid ``(batch, block of chunks, key head)``,
     and ``pallas_call``'s other arguments. Everything stays as the mixer
@@ -489,7 +546,9 @@ def _plan(kernel, body, q, k, v, cum, beta):
     running sums and ``beta`` ``[B, S, Hv]`` (every head's, a chunk's rows:
     fetched once for a block of chunks, the key heads walk it). ``scan`` is
     a tensor in the recurrence's order ``[c, B, Hv, Q, .]``, ``rows`` the
-    backward's ``d cum | d beta`` ``[B, c, Hk, 2 rep, Q]``."""
+    backward's ``d cum | d beta`` ``[B, c, Hk, 2 rep, Q]``. ``q_scale``:
+    what the kernels multiply the ``q`` they normed by, None where the
+    caller normed."""
     batch, seq, key_heads, key_dim = q.shape
     heads, width = v.shape[2:]
     n_chunks, chunk = cum.shape[1:3]
@@ -518,7 +577,8 @@ def _plan(kernel, body, q, k, v, cum, beta):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel")),
         interpret=_use_interpret(), name=kernel)
-    body = functools.partial(body, nc=nc, rep=rep, chunk=chunk, width=width)
+    body = functools.partial(body, nc=nc, rep=rep, chunk=chunk, width=width,
+                             q_scale=q_scale)
     return args, specs, body, call
 
 
@@ -537,8 +597,8 @@ def _scan_shapes(q, v, cum, vma):
                 (q.shape[3], q.dtype))]
 
 
-@functools.partial(jax.jit, inline=True)
-def _fwd_call(q, k, v, cum, beta):
+@functools.partial(jax.jit, inline=True, static_argnames="q_scale")
+def _fwd_call(q, k, v, cum, beta, *, q_scale=None):
     """(Jitted inline, as :func:`_bwd_call` is: a kernel's body, 1,100
     equations of unrolled substitution, is traced once for a shape, and a
     block's recomputed copy and the next layers re-bind it under their own
@@ -549,22 +609,25 @@ def _fwd_call(q, k, v, cum, beta):
     running sum of the log decays inside each chunk) and ``beta`` ``[B, c,
     Q, Hv]`` -> what needs no state, ``[c, B, Hv, Q, .]``: ``u_own = T (beta
     V)`` float32, ``w = T (beta G K)``, ``attn = (Q K^T) * decay``, ``q G``
-    and ``k G_last / G`` in the operand dtype."""
+    and ``k G_last / G`` in the operand dtype. With ``q_scale`` the kernel
+    norms the rows of ``q`` and ``k`` first (:class:`_Chunk`)."""
     args, specs, body, call = _plan(KERNEL_FWD, _fwd_kernel, q, k, v, cum,
-                                    beta)
+                                    beta, q_scale)
     return pl.pallas_call(
         body, in_specs=[specs[name] for name in _FWD_SPECS],
         out_specs=[specs[name] for name in _SCAN_SPECS],
         out_shape=_scan_shapes(q, v, cum, _out_vma(*args)), **call)(*args)
 
 
-@functools.partial(jax.jit, inline=True)
-def _bwd_call(q, k, v, cum, beta, du, dw, dattn, dqin, dkout):
+@functools.partial(jax.jit, inline=True, static_argnames="q_scale")
+def _bwd_call(q, k, v, cum, beta, du, dw, dattn, dqin, dkout, *,
+              q_scale=None):
     """The cotangents of :func:`_fwd_call`'s inputs for those of its
-    outputs: ``dq``, ``dk``, ``dv`` in the operand dtype (rounded once),
-    ``d cum`` and ``d beta`` float32."""
+    outputs: ``dq``, ``dk`` (of the raw rows under ``q_scale``: through the
+    norm in float32), ``dv`` in the operand dtype (rounded once), ``d cum``
+    and ``d beta`` float32."""
     args, specs, body, call = _plan(KERNEL_BWD, _bwd_kernel, q, k, v, cum,
-                                    beta)
+                                    beta, q_scale)
     args += (du, dw, dattn, dqin, dkout)
     vma = _out_vma(*args)
     rep = v.shape[2] // q.shape[2]
@@ -585,21 +648,21 @@ def _bwd_call(q, k, v, cum, beta, du, dw, dattn, dqin, dkout):
             dcum, dbeta)
 
 
-@jax.custom_vjp
-def _chunk_local(q, k, v, cum, beta):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _chunk_local(q_scale, q, k, v, cum, beta):
     """The WY form's part that needs no state, through the kernels: see
     :func:`_fwd_call`."""
-    return tuple(_fwd_call(q, k, v, cum, beta))
+    return tuple(_fwd_call(q, k, v, cum, beta, q_scale=q_scale))
 
 
-def _chunk_local_fwd(*inputs):
-    # The residuals are the inputs alone: the backward kernel makes A and T
-    # again in VMEM.
-    return tuple(_fwd_call(*inputs)), inputs
+def _chunk_local_fwd(q_scale, *inputs):
+    # The residuals are the inputs alone: the backward kernel makes the
+    # normed rows, A and T again in VMEM.
+    return tuple(_fwd_call(*inputs, q_scale=q_scale)), inputs
 
 
-def _chunk_local_bwd(inputs, cotangents):
-    return _bwd_call(*inputs, *cotangents)
+def _chunk_local_bwd(q_scale, inputs, cotangents):
+    return _bwd_call(*inputs, *cotangents, q_scale=q_scale)
 
 
 _chunk_local.defvjp(_chunk_local_fwd, _chunk_local_bwd)
@@ -872,10 +935,16 @@ _recurrence.defvjp(_recurrence_fwd, _recurrence_bwd)
 
 def gated_delta_chunked(q, k, v, g, beta, chunk: int = 64, *,
                         dtype: Any = jnp.bfloat16, initial_state=None,
-                        beta_max: int = 1):
+                        beta_max: int = 1, norm_qk: bool = False):
     """The recurrence in chunks of ``chunk`` tokens (a power of two).
     Arguments as :func:`gated_delta_sequential`, key and value heads of any
-    size; ``dtype`` is the MXU operands' type. Returns ``(o, state)``, ``o``
+    size; ``dtype`` is the MXU operands' type. Under ``norm_qk`` (static)
+    the chunk-local kernels norm the keys: ``q`` and ``k`` come as the
+    caller has them, in ``dtype``, each row of a head is L2-normalised in
+    VMEM in float32 (``eps`` 1e-6 under the root), ``q`` times ``K^-0.5``
+    for the head's true ``K`` besides, rounded to ``dtype`` once before the
+    products, and the gradient comes back for the raw ``q`` and ``k``
+    (:func:`unit_rows` is the plain line). Returns ``(o, state)``, ``o``
     ``[B, S, Hv, V]`` in ``dtype`` and the float32 state after the last
     token ``[B, Hv, K, V]``. A length the chunk does not divide is padded
     with tokens that neither decay (``g = 0``) nor write (``beta = 0``); a
@@ -900,18 +969,22 @@ def gated_delta_chunked(q, k, v, g, beta, chunk: int = 64, *,
     runtime.note_traced(
         "hvdtpu_spmd_gdn_layer_traces_total", key_heads=key_heads,
         value_heads=heads, key_dim=key_dim, value_dim=width, chunk=chunk,
-        recurrence="kernel", chunks=n_chunks, beta_max=beta_max)
+        recurrence="kernel", chunks=n_chunks, beta_max=beta_max,
+        qk_norm="kernel" if norm_qk else "caller")
 
     def chunked(t):
         """``[B, S, H]`` -> ``[B, c, Q, H]``."""
         return t.astype(f32).reshape(batch, n_chunks, chunk, heads)
 
     cum = jnp.cumsum(chunked(g), axis=2)                    # log G_t
-    # The kernels: K K^T, Q K^T, the decays, A, T and T's two products stay
-    # in VMEM; out come the recurrence's operands, a chunk a turn. From here
-    # to ``o`` a head is whole lane tiles.
+    # The kernels: the rows' norms (``norm_qk``), K K^T, Q K^T, the decays,
+    # A, T and T's two products stay in VMEM; out come the recurrence's
+    # operands, a chunk a turn. From here to ``o`` a head is whole lane
+    # tiles (zeros add nothing to a row's sum of squares: the scale is the
+    # true head's).
     u_own, w, attn, q_in, k_out = _chunk_local(
-        _to_lanes(q.astype(dtype)), _to_lanes(k.astype(dtype)),
+        key_dim ** -0.5 if norm_qk else None, _to_lanes(q.astype(dtype)),
+        _to_lanes(k.astype(dtype)),
         _to_lanes(v.astype(dtype)), cum, chunked(beta))
 
     start = jnp.zeros((batch, heads, key_dim, width), f32) \

@@ -136,10 +136,12 @@ _TRACED = {
         "Times JAX traced a chunked gated-delta-rule scan (the recomputed "
         "copy of a block counts again), by its key heads, value heads, their "
         "sizes and the chunk, what the recurrence over chunks ran as, over "
-        "how many chunks a sequence, and what the writing strength beta "
-        "lies under (1: a sigmoid; 2: twice one, negative eigenvalues).",
+        "how many chunks a sequence, what the writing strength beta "
+        "lies under (1: a sigmoid; 2: twice one, negative eigenvalues), and "
+        "who L2-normalises q and k (kernel: the chunk-local kernels, in "
+        "VMEM; caller: they come normed).",
         ("key_heads", "value_heads", "key_dim", "value_dim", "chunk",
-         "recurrence", "chunks", "beta_max")),
+         "recurrence", "chunks", "beta_max", "qk_norm")),
     "hvdtpu_spmd_ssd_kernel_traces_total": (
         "Times JAX traced one of the state-space scan's within-chunk "
         "kernels, by kernel and the tiling the call got: the chunk, the "

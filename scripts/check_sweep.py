@@ -93,12 +93,15 @@ def _norms_before(job) -> None:
 
 def _q_scaled_for_heads_of_128() -> None:
     """The linear mixer's ``q`` times ``1 / sqrt(128)`` (the lanes a key
-    head rides) where the model scales by one over the root of its size."""
-    from horovod_tpu.models import gpt
+    head rides) where the model scales by one over the root of its size.
+    Since PR 50 the scan's kernels apply that scale to the rows they norm:
+    ``gated_delta_chunked`` finds a ``_chunk_local`` that hands them the
+    lanes' scale in place of the head's."""
+    from horovod_tpu.ops import gated_delta
 
-    real = gpt.gated_delta_chunked
-    gpt.gated_delta_chunked = lambda q, *rest, **kw: real(
-        q * (q.shape[-1] / 128.0) ** 0.5, *rest, **kw)
+    real = gated_delta._chunk_local
+    gated_delta._chunk_local = lambda scale, q, *rest: real(
+        q.shape[-1] ** -0.5, q, *rest)
 
 
 def _decay_sums_in_bfloat16() -> None:

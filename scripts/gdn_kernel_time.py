@@ -3,7 +3,7 @@
 compiled kernels to the ``jax.numpy`` expressions they replaced.
 
     chiprun --chips 1 -- python scripts/gdn_kernel_time.py [--substitute 1 8 16 64] [--chunks-per-block 2 4 8]
-        [--rec-heads 4 8] [--rec-chunks 1 2 4] [--rows chunk_local recurrence whole]
+        [--rec-heads 4 8] [--rec-chunks 1 2 4] [--rows chunk_local recurrence whole] [--caller-norms]
 
 At the ``qwen3-next-80b-a3b_s4096`` cell's shapes (4 x 4096 tokens, 16 key and
 32 value heads of 128, chunk 64, bfloat16; ``--batch 1 --seq 8192 --key-heads
@@ -16,7 +16,12 @@ around ``block_until_ready``: ``hvd_gdn_fwd``; ``hvd_gdn_bwd``; the chunk-local
 part forward and backward through the ``custom_vjp``; the same through the
 plain expression (XLA writes the ``[chunk, chunk]`` tensors to HBM, the
 inverse is ``unit_lower_inverse``); and ``gated_delta_chunked`` whole, forward
-and backward. Then the recurrence over chunks on the forward kernel's
+and backward. Since PR 50 the chunk-local kernels norm the rows of ``q`` and
+``k`` themselves (``norm_qk``): they are given raw rows, as a mixer's
+convolution leaves them, the plain expression norms them in its own lines
+(``unit_rows``, rounded to bfloat16 before the products as the kernels
+round), and the gradients compared are the raw rows'; ``--caller-norms``
+times the kernels on normed rows instead, as they ran before. Then the recurrence over chunks on the forward kernel's
 outputs: the ``lax.scan`` ``gated_delta_chunked`` ran before PR 36 (kept
 here, :func:`scan_recurrence`), forward and with autodiff's backward, against
 ``hvd_gdn_rec_fwd`` (as the forward pass runs it and as the rule's forward
@@ -35,6 +40,7 @@ walks: the sources of ``ops/gated_delta.py::_SUBSTITUTE`` and
 from __future__ import annotations
 
 import argparse
+import functools
 import json
 import os
 import sys
@@ -60,12 +66,16 @@ def timed(fn, *args, reps: int = 10) -> float:
     return 1e3 * (time.perf_counter() - t0) / reps
 
 
-def plain_chunk_local(q, k, v, cum, beta):
+def plain_chunk_local(q, k, v, cum, beta, q_scale=None):
     """What ``gated_delta_chunked`` computed before the kernels, on their
     arguments (``ops/gated_delta.py::_fwd_call``) and in their outputs'
-    order: ``[B, c, H, Q, Q]`` float32 decays, ``K K^T``, ``A`` and the
-    inverse's rounds through HBM, autodiff's backward."""
+    order: the rows' norms where the kernels make them (``q_scale``),
+    ``[B, c, H, Q, Q]`` float32 decays, ``K K^T``, ``A`` and the inverse's
+    rounds through HBM, autodiff's backward."""
     f32, dtype = jnp.float32, q.dtype
+    if q_scale is not None:
+        q = gd.unit_rows(q, q_scale).astype(dtype)
+        k = gd.unit_rows(k).astype(dtype)
     batch, n_chunks, chunk, heads = cum.shape
     rep = heads // q.shape[2]
 
@@ -155,6 +165,8 @@ def main() -> int:
                         default=[gd._REC_CHUNKS])
     parser.add_argument("--rows", nargs="*",
                         default=["chunk_local", "recurrence", "whole"])
+    parser.add_argument("--caller-norms", action="store_true",
+                        help="normed rows in, no norm in the kernels")
     args = parser.parse_args()
     B, S, Hk, Hv, K, V, Q = (args.batch, args.seq, args.key_heads,
                              args.value_heads, args.key_dim, args.value_dim,
@@ -166,12 +178,14 @@ def main() -> int:
     ks = jax.random.split(jax.random.PRNGKey(0), 14)
     dtype, f32 = jnp.bfloat16, jnp.float32
 
-    def unit(t):
-        return t / jnp.linalg.norm(t, axis=-1, keepdims=True)
-
-    q = (unit(jax.random.normal(ks[0], (B, S, Hk, K))) * K ** -0.5
-         ).astype(dtype)
-    k = unit(jax.random.normal(ks[1], (B, S, Hk, K))).astype(dtype)
+    # The scale is the head's true size's, whatever lanes it rides.
+    q_scale = None if args.caller_norms else K ** -0.5
+    norm = dict(q_scale=q_scale)
+    q = jax.random.normal(ks[0], (B, S, Hk, K))
+    k = jax.random.normal(ks[1], (B, S, Hk, K))
+    if args.caller_norms:
+        q, k = gd.unit_rows(q, K ** -0.5), gd.unit_rows(k)
+    q, k = q.astype(dtype), k.astype(dtype)
     v = jax.random.normal(ks[2], (B, S, Hv, V), dtype)
     published = (q, k, v)
     # What the kernels are called with: a head on whole lane tiles.
@@ -205,10 +219,12 @@ def main() -> int:
         return jax.jit(jax.value_and_grad(loss, argnums=tuple(range(n))))
 
     if "chunk_local" in args.rows:
-        plain = both(plain_chunk_local)
-        want = jax.jit(plain_chunk_local)(*inputs)
+        plain_fwd = functools.partial(plain_chunk_local, **norm)
+        plain = both(plain_fwd)
+        want = jax.jit(plain_fwd)(*inputs)
         _, want_g = plain(*inputs, *cts)
-        row(what="plain", fwd_ms=timed(jax.jit(plain_chunk_local), *inputs),
+        row(what="plain", norm="caller" if args.caller_norms else "kernel",
+            fwd_ms=timed(jax.jit(plain_fwd), *inputs),
             fwd_bwd_ms=timed(plain, *inputs, *cts))
         del plain
         shipped = gd._SUBSTITUTE, gd._MAX_CHUNKS
@@ -216,9 +232,9 @@ def main() -> int:
             for chunks in args.chunks_per_block:
                 gd._SUBSTITUTE, gd._MAX_CHUNKS = substitute, chunks
                 jax.clear_caches()  # the calls are jitted: trace them anew
-                fwd = jax.jit(lambda *t: gd._fwd_call(*t))
-                bwd = jax.jit(lambda *t: gd._bwd_call(*t))
-                kernels = both(lambda *t: gd._chunk_local(*t))
+                fwd = jax.jit(lambda *t: gd._fwd_call(*t, **norm))
+                bwd = jax.jit(lambda *t: gd._bwd_call(*t, **norm))
+                kernels = both(lambda *t: gd._chunk_local(q_scale, *t))
                 got = fwd(*inputs)
                 _, got_g = kernels(*inputs, *cts)
                 row(what="kernels", substitute=substitute,
@@ -235,7 +251,8 @@ def main() -> int:
         jax.clear_caches()
 
     if "recurrence" in args.rows:
-        rec = tuple(jax.jit(gd._fwd_call)(*inputs)) + (
+        rec = tuple(jax.jit(functools.partial(gd._fwd_call, **norm))(
+            *inputs)) + (
             jnp.exp(cum[:, :, -1]),
             0.1 * jax.random.normal(ks[11], (B, Hv, K, V), f32))
         rec_cts = (jax.random.normal(ks[12], (B, S, Hv * V), dtype),
@@ -275,7 +292,8 @@ def main() -> int:
 
     def whole(q, k, v, g, beta):
         o, final = gd.gated_delta_chunked(q, k, v, g, beta, chunk=Q,
-                                          dtype=dtype)
+                                          dtype=dtype,
+                                          norm_qk=not args.caller_norms)
         return jnp.sum(jnp.sin(o.astype(f32))) + jnp.sum(final)
 
     if "whole" in args.rows:

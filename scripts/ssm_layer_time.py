@@ -25,8 +25,10 @@ and 32 value heads of 128, chunk 64; behind each mixer the cell's expert
 block: 32 of 512 experts of 512 held, 10 a token, a shared expert): the
 number the next change to ``ops/gated_delta.py`` is measured against first.
 With ``--trace`` the split's innermost-scope rows ``hvd_gdn_fwd`` and
-``hvd_gdn_bwd`` are the chunk-local kernels and the row ``scan`` what XLA
-runs around them (the recurrence over chunks, the norms, the running sums);
+``hvd_gdn_bwd`` are the chunk-local kernels (since PR 50 with the L2 norms
+of ``q`` and ``k`` inside: ``--repo`` a copy of an older commit times the
+norms as XLA ran them around the kernels) and the row ``scan`` what XLA
+runs around them (the running sums, the recurrence's small operands);
 ``scripts/gdn_kernel_time.py`` times the kernels alone. ``--kind gdn_dense``
 is the ``olmo-hybrid-7b_s8192`` cell's block (1 x 8192 tokens of 3840; 30
 key and 30 value heads of 96 by 192, ``beta`` in (0, 2); the norm after each

@@ -127,23 +127,32 @@ def test_ssd_kernel_compiles_for_v5e(one_chip, mosaic, shape, kernel):
     assert "tpu_custom_call" in text and f"hvd_ssd_{kernel}" in text
 
 
-# (B, S, Hk, Hv, K, V, Q, dtype): the qwen3-next-80b-a3b_s4096 cell's scan; a
-# small one with a value head a key head and two chunks a sequence; the
-# olmo-hybrid-7b_s8192 cell's as the kernels carry it (heads of 96 by 192 at
-# 128 by 256 lanes, not square, thirty of them, 128 chunks a sequence).
+# (B, S, Hk, Hv, K, V, Q, dtype, the key head's true size): the
+# qwen3-next-80b-a3b_s4096 cell's scan; a small one with a value head a key
+# head and two chunks a sequence; the olmo-hybrid-7b_s8192 cell's as the
+# kernels carry it (heads of 96 by 192 at 128 by 256 lanes, not square,
+# thirty of them, 128 chunks a sequence).
 GDN_SHAPES = {
-    "qwen3-next-80b-a3b_s4096": (4, 4096, 16, 32, 128, 128, 64, jnp.bfloat16),
-    "small_one_head_a_key": (1, 128, 2, 2, 128, 128, 64, jnp.bfloat16),
+    "qwen3-next-80b-a3b_s4096": (4, 4096, 16, 32, 128, 128, 64, jnp.bfloat16,
+                                 128),
+    "small_one_head_a_key": (1, 128, 2, 2, 128, 128, 64, jnp.bfloat16, 128),
     "olmo-hybrid-7b_s8192_carried": (1, 8192, 30, 30, 128, 256, 64,
-                                     jnp.bfloat16),
+                                     jnp.bfloat16, 96),
 }
 
 
-@pytest.mark.parametrize("kernel", ["fwd", "bwd", "rec_fwd", "rec_bwd"])
+@pytest.mark.parametrize("kernel", ["fwd", "bwd", "rec_fwd", "rec_bwd",
+                                    "fwd_caller_norms", "bwd_caller_norms"])
 @pytest.mark.parametrize("shape", list(GDN_SHAPES))
 def test_gdn_kernel_compiles_for_v5e(one_chip, mosaic, shape, kernel):
-    batch, seq, key_heads, heads, key_dim, width, chunk, dtype = \
+    """``fwd`` and ``bwd`` as a model's step runs them, the rows of ``q``
+    and ``k`` normed in the kernels (``q_scale`` from the head's true size);
+    ``*_caller_norms`` the same kernels on keys that come normed."""
+    batch, seq, key_heads, heads, key_dim, width, chunk, dtype, true_dim = \
         GDN_SHAPES[shape]
+    kernel, _, caller_norms = kernel.partition("_caller_norms")
+    q_scale = None if caller_norms or kernel.startswith("rec") \
+        else true_dim ** -0.5
 
     def sds(*dims, dt=dtype):
         return jax.ShapeDtypeStruct(dims, dt, sharding=one_chip)
@@ -158,9 +167,10 @@ def test_gdn_kernel_compiles_for_v5e(one_chip, mosaic, shape, kernel):
     args = (q, q, sds(batch, seq, heads, width), row, row)
     scan = like(gated_delta._fwd_call, *args)
     if kernel == "fwd":
-        f = gated_delta._fwd_call
+        f = functools.partial(gated_delta._fwd_call, q_scale=q_scale)
     elif kernel == "bwd":
-        f, args = gated_delta._bwd_call, args + scan
+        f = functools.partial(gated_delta._bwd_call, q_scale=q_scale)
+        args += scan
     else:
         # The recurrence over chunks reads the chunk-local kernel's outputs,
         # a chunk's last decay a head and the state a sequence starts from.
@@ -177,20 +187,25 @@ def test_gdn_kernel_compiles_for_v5e(one_chip, mosaic, shape, kernel):
     assert "tpu_custom_call" in text and f"hvd_gdn_{kernel}" in text
 
 
+@pytest.mark.parametrize("norm_qk", [True, False],
+                         ids=["kernels norm", "caller norms"])
 @pytest.mark.parametrize("key_dim, width", [(96, 192), (24, 40)])
 def test_gdn_scan_compiles_for_v5e_at_heads_of_any_size(one_chip, mosaic,
-                                                        key_dim, width):
+                                                        key_dim, width,
+                                                        norm_qk):
     """``gated_delta_chunked`` whole, forward and backward, at heads that are
     no lane multiple (the olmo-hybrid-7b_s8192 cell's, and ones smaller than
-    a tile): the four kernels at padded sizes, the published sizes out."""
+    a tile): the four kernels at padded sizes, the published sizes out; with
+    the keys' norm in the chunk-local kernels, as a mixer asks, and
+    without."""
     batch, seq, heads = 1, 1024, 30
 
     def sds(*dims, dt=jnp.bfloat16):
         return jax.ShapeDtypeStruct(dims, dt, sharding=one_chip)
 
     def loss(q, k, v, g, beta):
-        o, final = gated_delta.gated_delta_chunked(q, k, v, g, beta,
-                                                   beta_max=2)
+        o, final = gated_delta.gated_delta_chunked(
+            q, k, v, g, beta, beta_max=2, norm_qk=norm_qk)
         assert o.shape == v.shape
         assert final.shape == (batch, heads, key_dim, width)
         return jnp.sum(o.astype(jnp.float32)) + jnp.sum(final)

@@ -35,8 +35,18 @@ recurrence test run heads that are no lane multiple and not square (96 by
 192, one value head a key head, six heads: a count eight does not divide),
 heads smaller than a tile, thirty heads, and 128 chunks in a sequence, all
 with ``beta`` drawn in (0, 2); the padded lanes come back exactly zero.
+
+Since PR 50 the chunk-local kernels norm the rows of ``q`` and ``k``
+themselves under ``norm_qk`` (what a model's mixer asks for): the ``NORMED``
+cases feed raw rows, of any length, and hold the outputs and the gradient of
+every input, **of the raw rows**, to the recurrence fed rows normed outside
+by the plain line (``unit_rows``), at heads of 128 by 128 and of 96 by 192
+carried on lanes, in float32 and with bfloat16 operands; then the rows the
+chunk's padding adds, a zero row inside a chunk, and the scale of ``q``,
+which is the true head's and not the lanes'.
 """
 
+import functools
 import inspect
 
 import jax
@@ -46,7 +56,8 @@ import pytest
 
 from horovod_tpu.ops import gated_delta
 from horovod_tpu.ops.gated_delta import (
-    gated_delta_chunked, gated_delta_sequential, unit_lower_inverse)
+    gated_delta_chunked, gated_delta_sequential, unit_lower_inverse,
+    unit_rows)
 from horovod_tpu.ops.pallas_util import largest_divisor, use_interpret
 
 B, HK, HV, K, V = 2, 2, 4, 16, 8
@@ -439,3 +450,178 @@ def test_bfloat16_products_hold_and_bfloat16_running_sums_would_not(
         x.astype(jnp.bfloat16), axis=axis).astype(jnp.float32))
     faulty = miss(g_grad(lambda *a: gated_delta_chunked(*a, chunk=64)), want)
     assert shipped < 1e-2 < faulty, (shipped, faulty)
+
+
+# name -> (what ``_inputs`` takes, tokens): the Qwen cell's head (two value
+# heads a key head) and the Olmo cell's (no lane multiple: the kernels norm
+# a row over 128 lanes, 32 of them zeros), ``beta`` in (0, 2) there; 150
+# tokens are two chunks and 22 tokens of a third, the rest padding.
+NORMED = {
+    "128 by 128": (dict(batch=1, key_heads=1, heads=2, key_dim=128,
+                        width=128), 150),
+    "96 by 192": (dict(batch=1, key_heads=2, heads=2, key_dim=96, width=192,
+                       beta_max=2.0), 150),
+}
+INPUT_NAMES = ("q", "k", "v", "g", "beta")
+# Of the largest element: outputs, gradients. float32: the file's own (seen:
+# 1.1e-6 at most). bfloat16 operands: the shipped-precision test's 2e-2 for
+# both (seen: outputs 7.3e-3, gradients 6.8e-3 at most, the raw rows' 4.6e-3).
+NORMED_TOL = {"float32": (2e-5, 5e-5), "bfloat16": (2e-2, 2e-2)}
+
+
+def _raw_inputs(seed, seq, dtype, **shape):
+    """``_inputs`` with ``q`` and ``k`` as a convolution leaves them: each
+    row of another length (a lognormal factor around the root of the head's
+    size), rounded to ``dtype`` so that both sides read the same rows."""
+    q, k, *rest = _inputs(seed, seq, **shape)
+    rng = np.random.default_rng(seed + 1)
+
+    def raw(t, mean):
+        length = mean * np.exp(0.5 * rng.standard_normal(t.shape[:3] + (1,)))
+        return (t * jnp.asarray(length, jnp.float32)).astype(dtype).astype(
+            jnp.float32)
+
+    return (raw(q, q.shape[-1]), raw(k, q.shape[-1] ** 0.5), *rest)
+
+
+def _normed_outside(q, k, *rest):
+    """The parent's formula: the plain line on each, then the recurrence."""
+    return gated_delta_sequential(unit_rows(q, q.shape[-1] ** -0.5),
+                                  unit_rows(k), *rest)
+
+
+def _outputs_and_gradients(fn, args, seed=21):
+    """``(o, final, dq, dk, dv, dg, dbeta)`` of a random linear form of both
+    outputs, float32."""
+    rng = np.random.default_rng(seed)
+    co, cs = (jnp.asarray(rng.standard_normal(t.shape), jnp.float32)
+              for t in jax.eval_shape(fn, *args))
+
+    def form(*a):
+        o, s = fn(*a)
+        o = o.astype(jnp.float32)
+        return jnp.sum(o * co) + jnp.sum(s * cs), (o, s)
+
+    (_, outs), grads = jax.value_and_grad(
+        form, argnums=tuple(range(5)), has_aux=True)(*args)
+    return outs + grads
+
+
+@functools.lru_cache(maxsize=None)
+def _normed_case(layout, dtype):
+    """One run of each side a case, shared by its seven comparisons."""
+    shape, seq = NORMED[layout]
+    args = _raw_inputs(22, seq, jnp.dtype(dtype), **shape)
+    return (_outputs_and_gradients(functools.partial(
+        gated_delta_chunked, chunk=64, dtype=jnp.dtype(dtype), norm_qk=True),
+        args), _outputs_and_gradients(_normed_outside, args), args)
+
+
+@pytest.mark.parametrize("dtype", list(NORMED_TOL))
+@pytest.mark.parametrize("layout", list(NORMED))
+@pytest.mark.parametrize("what", ("o and final",) + INPUT_NAMES)
+def test_kernels_norm_matches_rows_normed_outside(layout, dtype, what):
+    """The chunked rule with the kernels' norm against the recurrence fed
+    rows normed outside: the outputs, and the gradient of every input, those
+    of ``q`` and ``k`` with respect to the raw rows (through the norm: the
+    kernels' own ``dt = r (dn - n <dn, n>)`` against autodiff of the plain
+    line)."""
+    got, want, args = _normed_case(layout, dtype)
+    tol_out, tol_grad = NORMED_TOL[dtype]
+    if what == "o and final":
+        _close(got[0], want[0], tol_out)
+        _close(got[1], want[1], tol_out)
+        # The recurrence's own ``norm_qk`` is the plain line before it.
+        for have, ref in zip(gated_delta_sequential(*args, norm_qk=True),
+                             want[:2], strict=True):
+            _close(have, ref, 1e-6)
+        return
+    at = 2 + INPUT_NAMES.index(what)
+    assert got[at].shape == args[at - 2].shape
+    _close(got[at], want[at], tol_grad)
+
+
+@pytest.mark.parametrize("dtype", list(NORMED_TOL))
+def test_kernels_norm_leaves_padding_rows_zero(dtype):
+    """A length the chunk does not divide: the rows the padding adds are
+    zero going in and the kernels' norm leaves them zero (``0 *
+    rsqrt(eps)``): the rows of ``w``, ``q G`` and ``k G_last / G`` and the
+    rows and columns of ``attn`` they own are exactly zero, and every
+    cotangent the backward kernel returns is finite, theirs too."""
+    shape, seq = NORMED["96 by 192"]
+    dtype = jnp.dtype(dtype)
+    q, k, v, g, beta = _raw_inputs(23, seq, dtype, **shape)
+    chunk, pad = 64, -seq % 64
+    assert pad
+
+    def padded(t, lanes=False):
+        t = jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
+        return gated_delta._to_lanes(t.astype(dtype)) if lanes else t
+
+    def chunked(t):
+        return padded(t).reshape(1, -1, chunk, t.shape[-1])
+
+    args = (padded(q, True), padded(k, True), padded(v, True),
+            jnp.cumsum(chunked(g), axis=2), chunked(beta))
+    q_scale = q.shape[-1] ** -0.5
+    outs = gated_delta._fwd_call(*args, q_scale=q_scale)
+    rows = slice(seq % chunk, None)
+    for name, t in zip(("u_own", "w", "attn", "q_in", "k_out"), outs):
+        assert not np.any(np.asarray(t[-1, :, :, rows], np.float32)), name
+    assert not np.any(np.asarray(outs[2][-1, ..., rows], np.float32))
+    rng = np.random.default_rng(24)
+    cts = tuple(jnp.asarray(rng.standard_normal(t.shape), jnp.float32)
+                .astype(t.dtype) for t in outs)
+    grads = gated_delta._bwd_call(*args, *cts, q_scale=q_scale)
+    for name, t in zip(INPUT_NAMES, grads):
+        assert bool(jnp.all(jnp.isfinite(t.astype(jnp.float32)))), name
+    # The whole rule drops them: every gradient has its input's shape.
+    whole = _outputs_and_gradients(functools.partial(
+        gated_delta_chunked, chunk=chunk, dtype=dtype, norm_qk=True),
+        (q, k, v, g, beta))
+    assert all(bool(jnp.all(jnp.isfinite(t))) for t in whole)
+
+
+@pytest.mark.parametrize("zeroed", ["q", "k", "q and k"])
+def test_a_zero_row_inside_a_chunk_stays_zero_under_the_kernels_norm(zeroed):
+    """A raw row of zeros (a token the convolution silenced) norms to zero
+    under ``eps``, not to NaN: the outputs and every gradient are finite and
+    the reference's, the zero row's own gradient (``rsqrt(eps)`` times its
+    cotangent) included."""
+    shape, seq = NORMED["128 by 128"]
+    q, k, *rest = _raw_inputs(25, seq, jnp.float32, **shape)
+    if "q" in zeroed:
+        q = q.at[:, 70].set(0.0)
+    if "k" in zeroed:
+        k = k.at[:, 9].set(0.0).at[:, 70].set(0.0)
+    args = (q, k, *rest)
+    got = _outputs_and_gradients(functools.partial(
+        gated_delta_chunked, chunk=64, dtype=jnp.float32, norm_qk=True), args)
+    want = _outputs_and_gradients(_normed_outside, args)
+    assert all(bool(jnp.all(jnp.isfinite(t))) for t in got)
+    for have, ref, tol in zip(got, want, (2e-5,) * 2 + (5e-5,) * 5,
+                              strict=True):
+        _close(have, ref, tol)
+
+
+@pytest.mark.parametrize("scaled_by", ["the head", "the lanes"])
+def test_q_scale_is_the_true_heads_not_the_lanes(monkeypatch, scaled_by):
+    """A head of 96 rides 128 lanes; ``q``'s scale under the kernels' norm
+    is ``96^-0.5``. The planted fault, the scale read from what the kernels
+    carry (``128^-0.5``, 13% low), must miss the reference: this test sees
+    it."""
+    shape, seq = NORMED["96 by 192"]
+    args = _raw_inputs(26, seq, jnp.float32, **shape)
+    if scaled_by == "the lanes":
+        real = gated_delta._chunk_local
+        monkeypatch.setattr(
+            gated_delta, "_chunk_local", lambda scale, q, *rest: real(
+                q.shape[-1] ** -0.5, q, *rest))
+    o, _ = gated_delta_chunked(*args, chunk=64, dtype=jnp.float32,
+                               norm_qk=True)
+    want, _ = _normed_outside(*args)
+    miss = float(jnp.abs(o - want).max() / jnp.abs(want).max())
+    if scaled_by == "the head":
+        assert miss < 2e-5, miss
+    else:
+        assert miss > 5e-2, miss

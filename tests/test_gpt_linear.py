@@ -28,6 +28,7 @@ from jax.sharding import PartitionSpec as P
 
 import horovod_tpu as hvd
 from horovod_tpu.models import gpt
+from horovod_tpu.ops import gated_delta
 from benchmarks.reference import gpt_linear_dp as reference
 
 TINY = dict(
@@ -142,7 +143,7 @@ def test_model_matches_the_reference_through_run_step(make_runtime, remat):
               if s[1] == {"key_heads": "5", "value_heads": "5",
                           "key_dim": "12", "value_dim": "24", "chunk": "16",
                           "recurrence": "kernel", "chunks": "3",
-                          "beta_max": "2"}]
+                          "beta_max": "2", "qk_norm": "kernel"}]
     assert layers and layers[0][2] >= 1
     # A head of 12 by 24 rides a lane tile each way in the kernels (those
     # this runtime saw traced: a kernel's inline-jitted call is traced once
@@ -208,6 +209,84 @@ def test_every_small_parameter_of_the_mixer_reaches_the_loss(leaf):
     _, grads = _loss_and_grad(cfg, _params(cfg, 4), _data(3))
     for layer in (0, 1, 2):
         assert float(jnp.abs(grads["layers"][layer]["gdn"][leaf]).max()) > 0
+
+
+def _mixer_and_input(dtype, seed=5):
+    # (A stream of 48: no other tensor of the mixer is as wide as q.)
+    cfg = gpt.GPTConfig(**{**TINY, "dtype": dtype, "embed_dim": 48})
+    p = _params(cfg, seed)["layers"][0]["gdn"]
+    h = jax.random.normal(jax.random.PRNGKey(seed), (B, S, cfg.embed_dim),
+                          dtype)
+    return cfg, p, h
+
+
+def _shapes_in(jaxpr):
+    """``(shape, dtype)`` of every value a jaxpr makes, at any depth but a
+    kernel's own body (its values are blocks in VMEM)."""
+    for eqn in jaxpr.eqns:
+        for var in eqn.outvars:
+            yield tuple(var.aval.shape), var.aval.dtype
+        if eqn.primitive.name == "pallas_call":
+            continue
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _shapes_in(sub)
+
+
+@pytest.mark.parametrize("passes", ["forward", "backward"])
+def test_the_mixer_holds_no_float32_copy_of_q_or_k(passes):
+    """Since PR 50 the scan's kernels norm ``q`` and ``k``: between the
+    convolution and the scan, and between the scan's backward kernel and the
+    convolution's, a bfloat16 mixer makes no float32 ``[B, S, Hk, K]``
+    tensor (nor one flattened to ``[B, S, Hk K]``), forward or backward."""
+    cfg, p, h = _mixer_and_input(jnp.bfloat16)
+
+    def mixer(p, h):
+        return gpt._gdn_mixer(cfg, p, h).astype(jnp.float32).sum()
+
+    jaxpr = jax.make_jaxpr(
+        mixer if passes == "forward" else jax.grad(mixer, argnums=(0, 1)))(
+            p, h).jaxpr
+    made = set(_shapes_in(jaxpr))
+    heads = (B, S, cfg.gdn_key_heads, cfg.gdn_key_dim)
+    assert (heads, jnp.dtype(jnp.bfloat16)) in made   # the kernels' q and k
+    for shape in (heads, (B, S, cfg.gdn_key_inner)):
+        assert (shape, jnp.dtype(jnp.float32)) not in made, shape
+    assert "name=hvd_gdn_" + ("bwd" if passes == "backward" else "fwd") \
+        in str(jaxpr)
+
+
+def test_the_kernels_norm_is_the_parents_formula(monkeypatch):
+    """The mixer with the norm in the scan's kernels against the formula it
+    had before PR 50 (``q`` and ``k`` to heads, L2-normalised in float32,
+    ``q`` over the root of the head's 12, then the scan on normed rows): the
+    layer's output and the gradient of every parameter and of the input, to
+    the file's tolerances."""
+    cfg, p, h = _mixer_and_input(jnp.float32)
+    co = jax.random.normal(jax.random.PRNGKey(6), h.shape, jnp.float32)
+
+    def both():
+        def form(p, h):
+            out = gpt._gdn_mixer(cfg, p, h)
+            return jnp.sum(out * co), out
+
+        with jax.default_matmul_precision("highest"):
+            (_, out), grads = jax.value_and_grad(
+                form, argnums=(0, 1), has_aux=True)(p, h)
+        return out, grads
+
+    out, grads = both()
+    shipped = gpt.gated_delta_chunked
+
+    def normed_outside(q, k, *rest, norm_qk, **kw):
+        assert norm_qk
+        return shipped(
+            gated_delta.unit_rows(q, cfg.gdn_key_dim ** -0.5).astype(q.dtype),
+            gated_delta.unit_rows(k).astype(k.dtype), *rest, **kw)
+
+    monkeypatch.setattr(gpt, "gated_delta_chunked", normed_outside)
+    want_out, want = both()
+    _assert_grads_agree(out, want_out, tol=1e-5)
+    _assert_grads_agree(grads, want)
 
 
 BASE = dict(vocab_size=64, num_layers=2, num_heads=4, head_dim=8,

@@ -147,7 +147,7 @@ def test_model_matches_the_reference_through_run_step(make_runtime,
            if s[1] == {"key_heads": "2", "value_heads": "4", "key_dim": "8",
                        "value_dim": "8", "chunk": "16",
                        "recurrence": "kernel", "chunks": "3",
-                       "beta_max": "1"}]
+                       "beta_max": "1", "qk_norm": "kernel"}]
     # A checkpointed block of a shape traced before comes from JAX's cache.
     assert gdn and gdn[0][2] >= 1
     moe = [s for s in fams["hvdtpu_spmd_moe_layer_traces_total"]["samples"]
