@@ -215,9 +215,15 @@ _STEP_FAMILIES = {
                    if b is not None]),
     "hvdtpu_spmd_step_kernels": (
         "Mosaic kernels in the compiled step, by the name the program or "
-        "XLA gave them.",
-        lambda r: [({"kernel": k}, n) for k, n in sorted(
-            r["kernels"].items())]),
+        "XLA gave them, the pass they run in (forward, recomputation, "
+        "backward; none: no name of the program places the kernel) and what "
+        "placed them there (own: the kernel's own op_name; operands: the "
+        "name of the instruction that makes its rows, for a kernel XLA "
+        "names itself).",
+        lambda r: [(dict(zip(("kernel", "pass", "placed_by"), key)), n)
+                   for key, n in sorted(collections.Counter(
+                       (c["kernel"], c["pass"], c["placed_by"])
+                       for c in r["kernel_calls"]).items())]),
 }
 
 

@@ -98,7 +98,10 @@ def compiled_step_report(step) -> dict:
     each with ``name``, ``opcode``, result ``bytes`` and the program's
     ``op_name``), ``parameter_copies`` (``count``, ``bytes``), ``whiles``,
     ``collectives`` by kind as the combiner left them, ``kernels`` (Mosaic
-    calls by name), ``instructions`` and the ``seconds`` the report took.
+    calls by name), ``kernel_calls`` (each of those calls with the pass and
+    the scope of the program it runs in, the grouped matmuls XLA names itself
+    included: ``hlo_report.reduce_hlo``), ``instructions`` and the ``seconds``
+    the report took.
 
     It lowers and compiles on the traced shapes, placed as ``in_specs`` says:
     JAX returns the executable the step runs from its caches (no compile, no

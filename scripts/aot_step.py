@@ -3,8 +3,9 @@
 and not attached, in the CPU sandbox: a sha256 of the lowered StableHLO and
 one of the compiled program, and what the program's own report says of the
 executable (``hvd.compiled_step_report``'s reducer, so the sandbox and the
-chip count alike): its memory, how many of each named kernel it holds, what
-the compiler made again and which arguments it copies; and, from the trace,
+chip count alike): its memory, the Mosaic kernels it holds by name and by the
+pass each runs in (``kernel_calls``), what the compiler made again and which
+arguments it copies; and, from the trace,
 the bytes its checkpointed blocks keep by name (``remat_saved_bytes``: the
 job's ``hvdtpu_spmd_remat_saved_bytes_total``). Nothing runs and no
 time is taken; a compile that passes is not a chip run.
@@ -21,6 +22,7 @@ from __future__ import annotations
 
 import argparse
 import base64
+import collections
 import hashlib
 import importlib
 import importlib.util
@@ -33,10 +35,6 @@ import time
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
 
-KERNELS = ("hvd_flash_fwd", "hvd_flash_dkdv", "hvd_flash_dq",
-           "hvd_ssd_fwd", "hvd_ssd_bwd", "hvd_gdn_fwd", "hvd_gdn_bwd",
-           "hvd_gdn_rec_fwd", "hvd_gdn_rec_bwd", "hvd_conv_fwd",
-           "hvd_conv_bwd", "hvd_cca_fwd", "hvd_cca_bwd", "ragged-dot-none")
 GIB = 2.0 ** 30
 
 
@@ -98,6 +96,15 @@ def remat_saved_bytes(hvd) -> dict:
             for _, labels, value in family.get("samples", ())}
 
 
+def calls_by_pass(kernel_calls: list) -> dict:
+    """kernel -> pass -> calls in the compiled step."""
+    out: dict = {}
+    for call in kernel_calls:
+        out.setdefault(call["kernel"], collections.Counter())[
+            call["pass"]] += 1
+    return out
+
+
 def compile_cell(name: str, root: str, hlo_dir: str | None = None) -> dict:
     import jax
     from jax.sharding import NamedSharding
@@ -157,7 +164,12 @@ def compile_cell(name: str, root: str, hlo_dir: str | None = None) -> dict:
         # arguments are written where those were read.
         "total_gib": round((m["arguments"] + m["temporaries"] + m["outputs"]
                             - m["aliased"]) / GIB, 3),
-        "calls": {k: report["kernels"].get(k, 0) for k in KERNELS},
+        "calls": calls_by_pass(report["kernel_calls"]),
+        # Those the report placed by what they read: XLA's own kernels,
+        # which carry no name of the program's.
+        "calls_placed_by_operands": collections.Counter(
+            call["kernel"] for call in report["kernel_calls"]
+            if call["placed_by"] == "operands"),
         "rematerialized": report["rematerialized"],
         "parameter_copies": report["parameter_copies"],
         # What the checkpointed blocks of this cell's trace kept, by name:

@@ -3,9 +3,15 @@ optimized HLO's text on a canned module, ``hvd.compiled_step_report`` on a real
 ``run_step`` function over the virtual CPU mesh (the executable that ran, from
 JAX's caches; the gauges behind ``hvd.metrics()`` only once asked), and the
 ``windows`` / ``window`` scopes a device trace counts an expert layer's windows
-by."""
+by. And places its Mosaic kernels (ISSUE 51): the pass of an ``op_name``, and
+``kernel_calls`` on text cut from three cells' compiled steps (``data/hlo/``:
+the lines a placement reads, the kernels' bodies and layout attributes taken
+out), where XLA names the grouped matmuls itself."""
 
+import collections
+import os
 import re
+import types
 
 import jax
 import jax.numpy as jnp
@@ -82,6 +88,20 @@ def test_the_reducer_counts_what_the_compiler_added():
     # Mosaic kernels by name; XLA's other custom calls are none.
     assert report["kernels"] == {"ragged-dot-none": 1, "hvd_flash_fwd": 1}
     assert report["instructions"] == 28
+    # Each of them placed: XLA's kernel by the name the windowed layer's jit
+    # composed for it, in the loop's body; the one without metadata by the
+    # rows it reads.
+    assert report["kernel_calls"] == [
+        {"instruction": "ragged-dot-none.2", "kernel": "ragged-dot-none",
+         "op_name": "jit(_train_step)/jvp(layer0)/moe/windows/while/body/"
+                    "window/jit(_window)/ragged-dot-none",
+         "pass": "forward", "placed_by": "own", "loop": True,
+         "scope": "layer0/moe/windows/while/body/window/_window"},
+        {"instruction": "hvd_flash_fwd.3", "kernel": "hvd_flash_fwd",
+         "op_name": "jit(_train_step)/jvp(layer0)/moe/windows/while/body/"
+                    "window/jit(_window)/ragged-dot-none",
+         "pass": "forward", "placed_by": "operands", "loop": True,
+         "scope": "layer0/moe/windows/while/body/window/_window"}]
 
 
 @pytest.mark.parametrize("type_text, nbytes", [
@@ -92,6 +112,116 @@ def test_the_reducer_counts_what_the_compiler_added():
     ("token[]", 0)])
 def test_result_bytes(type_text, nbytes):
     assert hlo_report._result_bytes(type_text) == nbytes
+
+
+# ---- every Mosaic kernel in a pass and a scope -------------------------------
+
+STEP = "jit(_train_step)/"
+BLOCK_BACKWARD = STEP + "transpose(jvp(layer0))/jvp(layer0)/checkpoint/"
+WINDOW = "moe/windows/while/body/window/"
+
+
+@pytest.mark.parametrize("op_name, want", [
+    (STEP + "jvp(layer0)/moe/dispatch/gather", "forward"),
+    (BLOCK_BACKWARD + "rematted_computation/moe/dispatch/gather",
+     "recomputation"),
+    (BLOCK_BACKWARD + "moe/combine/gather", "backward"),
+    # JAX's own primitive in the backward pass, on a copy of the matrices a
+    # recomputed product reads: why a kernel goes by its rows.
+    (STEP + "transpose(jvp(layer0))/jvp(layer0)/remat2", "backward"),
+    (STEP + "transpose(jvp(head))/dot_general", "backward"),
+    # A windowed layer's kernels carry names of their own: the forward rule's,
+    # the backward rule's, and the backward rule's forward made again.
+    (STEP + "jvp(layer0)/" + WINDOW + "jit(_window)/ragged-dot-none",
+     "forward"),
+    (BLOCK_BACKWARD + WINDOW + "transpose(jvp(jit(_window)))/ragged-dot-none",
+     "backward"),
+    (BLOCK_BACKWARD + WINDOW + "jvp(jit(_window))/ragged-dot-none",
+     "recomputation"),
+    ("ragged-dot-none", "none"), (STEP + "hvd_optimizer/mul", "none"),
+    ("params['w']", "none"), ("", "none")])
+def test_pass_of(op_name, want):
+    assert hlo_report.pass_of(op_name) == want
+
+
+def calls_of(fixture):
+    with open(os.path.join(os.path.dirname(__file__), "data", "hlo",
+                           fixture + ".hlo.txt")) as f:
+        report = hlo_report.reduce_hlo(f)
+    return {c["instruction"]: c for c in report["kernel_calls"]}, report
+
+
+def passes(calls, kernel="ragged-dot-none", **where):
+    return collections.Counter(
+        c["pass"] for c in calls.values() if c["kernel"] == kernel
+        and all(c[k] == v for k, v in where.items()))
+
+
+def test_bare_kernels_are_placed_by_the_rows_they_read():
+    """OLMoE's block: XLA names all 11 grouped matmuls "ragged-dot-none" and
+    nothing else; 3 forward, gate and up made again, 6 backward."""
+    calls, report = calls_of("olmoe_block")
+    assert report["kernels"] == {"ragged-dot-none": 11,
+                                 "ragged-dot-metadata": 3}
+    assert passes(calls) == {"forward": 3, "recomputation": 2, "backward": 6}
+    assert {(c["placed_by"], c["loop"]) for c in calls.values()} \
+        == {("operands", False)}
+    first = calls["ragged-dot-none.9"]
+    assert first["op_name"] == STEP + "jvp(layer0)/moe/dispatch/gather"
+    assert (first["pass"], first["scope"]) == ("forward",
+                                               "layer0/moe/dispatch")
+    # A recomputed product whose matrices come through the "remat2" copy.
+    assert calls["ragged-dot-none.7"]["pass"] == "recomputation"
+    # The matrices' gradients read recomputed rows and are backward.
+    for gradient in ("ragged-dot-none", "ragged-dot-none.1",
+                     "ragged-dot-none.2"):
+        assert calls[gradient]["pass"] == "backward", gradient
+    assert "rematted_computation" in calls["ragged-dot-none.1"]["op_name"]
+    # The kernels that lay out the groups have no rows: by their first operand.
+    assert passes(calls, "ragged-dot-metadata") == {"forward": 1,
+                                                    "recomputation": 2}
+
+
+def test_a_windowed_layers_kernels_are_placed_by_their_own_names():
+    """One layer of Moonlight's: the window at 0 and the loop's body, in the
+    forward rule and in the backward rule, which makes each window's forward
+    again (``parallel/moe.py::_held_experts_bwd``)."""
+    calls, _ = calls_of("moonlight_layer")
+    assert {c["placed_by"] for c in calls.values()} == {"own"}
+    for loop in (False, True):
+        assert passes(calls, loop=loop) == {
+            "forward": 3, "recomputation": 2, "backward": 6}, loop
+        assert passes(calls, "ragged-dot-metadata", loop=loop) == {
+            "forward": 1, "backward": 2}, loop
+    assert {c["scope"] for c in calls.values()} == {
+        "layer1/moe/windows/window/_window",
+        "layer1/moe/windows/while/body/window/_window"}
+
+
+def test_a_read_of_a_fusion_goes_by_the_output_it_reads():
+    """ZAYA1's layer 3: one fusion makes the recomputed rows and the backward
+    product's, and it and both reads carry the first's name."""
+    calls, _ = calls_of("zaya1_fusion")
+    assert calls["ragged-dot-none.34"]["pass"] == "recomputation"
+    assert calls["ragged-dot-none.36"]["pass"] == "backward"
+    assert calls["ragged-dot-none.36"]["scope"] == "layer3/moe/combine/_where"
+
+
+def test_a_kernel_nothing_places_reads_none():
+    calls = hlo_report.reduce_hlo('''HloModule jit_step
+
+ENTRY %main.3 (x.1: bf16[64,8]) -> bf16[64,8] {
+  %x.1 = bf16[64,8]{1,0} parameter(0), metadata={op_name="x"}
+  %copy.1 = bf16[64,8]{0,1} copy(%x.1)
+  %ragged-dot-none.1 = bf16[64,8]{1,0} custom-call(%copy.1), custom_call_target="tpu_custom_call", metadata={op_name="ragged-dot-none"}
+  ROOT %hvd_flash_fwd.1 = bf16[64,8]{1,0} custom-call(%copy.1), custom_call_target="tpu_custom_call", metadata={op_name="jit(step)/layer0/attn/hvd_flash_fwd/pallas_call"}
+}
+'''.splitlines())["kernel_calls"]
+    assert [(c["pass"], c["placed_by"], c["scope"], c["loop"])
+            for c in calls] == [
+        ("none", "operands", "", False),
+        # A step that differentiates nothing: the kernel's own scopes, no pass.
+        ("none", "operands", "layer0/attn/hvd_flash_fwd", False)]
 
 
 # ---- a real run_step function on the virtual mesh ---------------------------
@@ -194,6 +324,32 @@ def test_report_is_kept_until_the_step_is_traced_anew(spmd4):
         hvd.metrics(), "hvdtpu_spmd_step_memory_bytes",
         function="_reported_step", kind="arguments") \
         == second["memory_bytes"]["arguments"]
+
+
+def test_the_kernels_gauge_says_pass_and_placement(spmd4):
+    """The family's labels, on the canned module handed in as an executable's
+    text."""
+    from horovod_tpu import runtime
+
+    canned = types.SimpleNamespace(
+        as_text=lambda: HLO, memory_analysis=lambda: types.SimpleNamespace(
+            argument_size_in_bytes=0, output_size_in_bytes=0,
+            alias_size_in_bytes=0, temp_size_in_bytes=0,
+            generated_code_size_in_bytes=0))
+    report = runtime.recorder().step_report("_canned", object(),
+                                            lambda: canned)
+    samples = hvd.metrics()["hvdtpu_spmd_step_kernels"]["samples"]
+    assert [(labels, value) for _, labels, value in samples] == [
+        ({"function": "_canned", "kernel": "hvd_flash_fwd",
+          "pass": "forward", "placed_by": "operands"}, 1.0),
+        ({"function": "_canned", "kernel": "ragged-dot-none",
+          "pass": "forward", "placed_by": "own"}, 1.0)]
+    # Summed over the two new labels it is the count by kernel it was.
+    by_kernel = collections.Counter()
+    for _, labels, value in samples:
+        by_kernel[labels["kernel"]] += value
+    assert by_kernel == report["kernels"]
+    assert parse_prometheus_text(hvd.metrics_dump()) == hvd.metrics()
 
 
 @pytest.mark.parametrize("what", ["never run", "not a run_step function"])
