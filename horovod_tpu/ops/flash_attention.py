@@ -16,7 +16,7 @@ Design notes (TPU):
   head count, ``[B*Hkv, S, D]``. Grouped-query attention is an index map:
   query head ``h`` reads K/V head ``h // group``; nothing is repeated in
   HBM, and dK/dV of one K/V head accumulate over its whole group inside the
-  dKdV kernel and are written once. A value head may have another width
+  backward kernel and are written once. A value head may have another width
   than a query and key head (latent attention: scores over 192 dimensions,
   values of 128): q, k, dQ, dK are ``D`` wide, v, o, dO, dV and the
   accumulator ``Dv``, each a block's whole minor axis (Mosaic takes a minor
@@ -61,17 +61,32 @@ Design notes (TPU):
   never attends a key beyond itself). Bidirectional mode (``causal=False``,
   encoder models) computes every block and masks the padded key columns,
   where there are any. Any sequence length works in both.
-* Backward = two kernels, same streaming structure: dKdV walks
-  ``(b*hkv, k_block, group x q_block)`` on the transposed score tile
-  (``k·qᵀ``, so dV and dK are plain products; the row statistics come as
-  ``[1, block_q]`` rows), dQ walks ``(bh, q_block, k_block)``, each
-  recomputing the probability tile from q, k and the saved row logsumexp —
+* Backward = one kernel where a K/V head's dK and dV fit VMEM (every shape
+  a benchmark cell runs; ``backward_is_fused``): it walks
+  ``(b*hkv, group x q_block, k_block)``, the dQ kernel's order with the dKdV
+  kernel's walk over the group's query heads, on the transposed score tile
+  (``k·qᵀ``, so dV and dK are plain products and the row statistics come as
+  ``[1, block_q]`` rows), and makes each kept tile once: five products
+  (``k·qᵀ``, ``pᵀ·dO``, ``v·dOᵀ``, ``dsᵀ·q`` and, for dQ, ``kᵀ·dsᵀ``
+  summed as ``dQᵀ`` over the row's k blocks) where two kernels made seven.
+  dK and dV of the head's whole sequence are float32 sums in VMEM scratch,
+  indexed by the k block and written out, scaled and cast, on the head's
+  last step; every sum runs in the order the pair's ran, so the gradients
+  are the pair's. In the compiled program it carries the dKdV kernel's
+  name (the benchmark's readers of the backward pass match it). A sequence
+  too long for that (somewhere past 16k rows at heads of 128) keeps the
+  two kernels, same streaming structure: dKdV walks
+  ``(b*hkv, k_block, group x q_block)``, dQ walks
+  ``(bh, q_block, k_block)`` on the tile as the forward has it, with the
+  statistics lane-replicated ``[block_q, 128]``; each
+  recomputes the probability tile from q, k and the saved row logsumexp —
   no S x S tensor is ever materialized in either direction.
 * Gate: compiled through Mosaic on the TPU backend, ``interpret=True`` on
   every other backend (the CPU-mesh tests): the package's one platform test,
   ``ops/pallas_util.py::on_tpu``.
   Interpret mode says nothing about Mosaic lowering; ``chip_smoke.py``
-  leg B runs all three kernels compiled, inside a training step.
+  leg B runs the forward and the one backward kernel compiled, inside a
+  training step.
 """
 
 from __future__ import annotations
@@ -114,13 +129,17 @@ _CANDIDATES = (1024, 512, 256, 128)
 
 
 def vmem_estimate(kernel: str, block_q: int, block_k: int, d: int,
-                  itemsize: int, dv: Optional[int] = None) -> int:
+                  itemsize: int, dv: Optional[int] = None,
+                  fused_rows: Optional[int] = None) -> int:
     """Bytes of VMEM one grid step of ``kernel`` holds: every streamed block
     twice (the pipeline's double buffer), the scratch, and the score-sized
     temporaries of the body (float32, plus the casts to the operand dtype).
     ``d`` is the width of a query and key head (q, k, dQ, dK), ``dv`` that of
     a value head (v, o, dO, dV, the accumulator; None: ``d``); each counts
-    as whole lane tiles."""
+    as whole lane tiles. ``fused_rows`` (``KERNEL_DKDV`` only): the padded
+    length, for the kernel that makes dQ too and holds dK and dV of a K/V
+    head's whole sequence, the float32 sums and the blocks they leave
+    through; None: the dKdV kernel of the pair."""
     d = -(-d // LANES) * LANES
     dv = d if dv is None else -(-dv // LANES) * LANES
     q_blk, k_blk = block_q * d, block_k * d
@@ -131,6 +150,12 @@ def vmem_estimate(kernel: str, block_q: int, block_k: int, d: int,
             + block_q * LANES * 4
         scratch = (2 * block_q * LANES + o_blk) * 4
         temps = tile * (3 * 4 + itemsize)
+    elif kernel == KERNEL_DKDV and fused_rows is not None:
+        whole = fused_rows * (d + dv)
+        blocks = (2 * q_blk + o_blk + k_blk + v_blk + whole) * itemsize \
+            + 2 * 8 * block_q * 4
+        scratch = (q_blk + whole) * 4
+        temps = tile * (4 * 4 + 2 * itemsize)
     elif kernel == KERNEL_DKDV:
         blocks = (q_blk + o_blk + 2 * k_blk + 2 * v_blk) * itemsize \
             + 2 * 8 * block_q * 4
@@ -165,9 +190,24 @@ def block_sizes(kernel: str, s_pad: int, d: int, dtype, causal: bool,
     return bq, bk
 
 
-def _compiler_params():
+def backward_is_fused(block_q: int, block_k: int, s_pad: int, d: int, dtype,
+                      dv: Optional[int] = None) -> bool:
+    """Whether a call's backward pass is the one kernel that makes dQ, dK
+    and dV from one score tile: where the float32 sums of a K/V head's whole
+    dK and dV fit the VMEM the call asks for beside the tile
+    (``vmem_estimate``; somewhere past 16k rows at heads of 128 they do
+    not, and dKdV and dQ stay a kernel each). A pure function of its
+    arguments, as ``block_sizes`` is."""
+    return vmem_estimate(KERNEL_DKDV, block_q, block_k, d,
+                         jnp.dtype(dtype).itemsize, dv,
+                         fused_rows=s_pad) <= VMEM_LIMIT_BYTES
+
+
+def _compiler_params(carried_over: int = 1):
+    """The last ``carried_over`` grid axes carry sums in VMEM scratch."""
     return pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "arbitrary"),
+        dimension_semantics=("parallel",) * (3 - carried_over)
+        + ("arbitrary",) * carried_over,
         vmem_limit_bytes=VMEM_LIMIT_BYTES)
 
 
@@ -419,6 +459,65 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
         dq_ref[0] = (dq_scr[:] * sm_scale).astype(dq_ref.dtype)
 
 
+def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                dq_ref, dk_ref, dv_ref, dq_scr, dk_scr, dv_scr, *,
+                sm_scale: float, block_q: int, block_k: int,
+                n_q_blocks: int, n_k_blocks: int, n_steps: int, mask: Mask,
+                kv_len: int, masked: bool):
+    """dQ, dK and dV from one score tile: the dKdV kernel's transposed tile
+    and its walk over the group's query heads, on the dQ kernel's grid. dK
+    and dV of the K/V head's whole sequence are float32 sums in VMEM."""
+    step = pl.program_id(1)        # (query head of the group, q block)
+    kj = pl.program_id(2)
+    qi = _rem(step, n_q_blocks)
+    first, last = kj == 0, kj == n_k_blocks - 1
+
+    @pl.when(first & (step == 0))
+    def _init_head():
+        dk_scr[:] = jnp.zeros_like(dk_scr)
+        dv_scr[:] = jnp.zeros_like(dv_scr)
+
+    @pl.when(first)
+    def _init_row():
+        dq_scr[:] = jnp.zeros_like(dq_scr)
+
+    def _step():
+        q = q_ref[0]                                     # [BQ, D]
+        k = k_ref[0]                                     # [BK, D]
+        do = do_ref[0]
+        rows = pl.ds(pl.multiple_of(kj * block_k, block_k), block_k)
+        st = jax.lax.dot_general(k, q, NT,
+                                 preferred_element_type=jnp.float32)
+        st = st * sm_scale                               # [BK, BQ] float32
+        if masked:
+            st = _mask_tile(st, qi * block_q, kj * block_k, mask, kv_len,
+                            transposed=True)
+        pt = jnp.exp(st - lse_ref[0])
+        dv_scr[rows, :] = dv_scr[rows, :] + jnp.dot(
+            pt.astype(do.dtype), do, preferred_element_type=jnp.float32)
+        dpt = jax.lax.dot_general(v_ref[0], do, NT,
+                                  preferred_element_type=jnp.float32)
+        dst = (pt * (dpt - delta_ref[0])).astype(q.dtype)
+        dk_scr[rows, :] = dk_scr[rows, :] + jnp.dot(
+            dst, q, preferred_element_type=jnp.float32)
+        # The one product over the tile's first axis, dQ = dstᵀ·k, summed
+        # transposed, kᵀ·dst: the [BK, D] side is the one turned, not the
+        # tile (as fast or faster at every cell's shape: PERF.md, PR 52).
+        dq_scr[:] = dq_scr[:] + jnp.dot(
+            k.T, dst, preferred_element_type=jnp.float32)
+
+    pl.when(mask.tile_kept(qi, kj, block_q, block_k))(_step)
+
+    @pl.when(last)
+    def _finish_row():
+        dq_ref[0] = (dq_scr[:].T * sm_scale).astype(dq_ref.dtype)
+
+    @pl.when(last & (step == n_steps - 1))
+    def _finish_head():
+        dk_ref[0] = (dk_scr[:] * sm_scale).astype(dk_ref.dtype)
+        dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
+
+
 def _pad_seq(x):
     pad = (-x.shape[1]) % _PAD
     if pad:
@@ -426,17 +525,19 @@ def _pad_seq(x):
     return x
 
 
-def _blocks_for(kernel, q, k, v, mask: Mask, forced):
+def _blocks_for(kernel, q, k, v, mask: Mask, forced, dq: str = "own"):
     """The call's tile, forced or from the table; and, trace time only, the
-    record of it (with the width of a query/key head and of a value head)
-    and of the tiles its grid keeps and skips behind ``hvd.metrics()``."""
+    record of it (with the width of a query/key head and of a value head,
+    and where dQ is made: ``fused`` on a dKdV kernel that makes it too,
+    ``own`` on the pair's two, ``none`` on the forward) and of the tiles
+    its grid keeps and skips behind ``hvd.metrics()``."""
     bq, bk = forced or block_sizes(kernel, q.shape[1], q.shape[2], q.dtype,
                                    mask.causal, v.shape[2])
     runtime.note_traced(
         "hvdtpu_spmd_flash_kernel_traces_total", kernel=kernel, block_q=bq,
         block_k=bk, operand_dtype=jnp.dtype(q.dtype).name,
         kv_group=q.shape[0] // k.shape[0], key_dim=q.shape[2],
-        value_dim=v.shape[2])
+        value_dim=v.shape[2], dq=dq)
     for tiles, n in mask.tiles(q.shape[1] // bq, q.shape[1] // bk,
                                bq, bk).items():
         runtime.note_traced(
@@ -463,7 +564,7 @@ def _fwd_call(q, k, v, sm_scale, mask, kv_len, forced=None):
     bh, s, d = q.shape
     dv = v.shape[2]
     group = bh // k.shape[0]
-    bq, bk = _blocks_for(KERNEL_FWD, q, k, v, mask, forced)
+    bq, bk = _blocks_for(KERNEL_FWD, q, k, v, mask, forced, dq="none")
     n_q, n_k = s // bq, s // bk
 
     kv_map = _kv_map(group, bq, bk, mask)
@@ -599,6 +700,76 @@ def _dq_call(q, k, v, do, lse, delta, sm_scale, mask, kv_len, forced=None):
     )(q, k, v, do, lse, delta)
 
 
+def _bwd_call(q, k, v, do, lse, delta, sm_scale, mask, kv_len, forced=None):
+    """dQ at the query head count, dK and dV at the K/V head count, from one
+    kernel (``hvd_flash_dkdv`` in the compiled program: what the
+    benchmark's readers of the backward pass match). ``lse``/``delta``:
+    [B*H, 1, S] rows."""
+    bh, s, d = q.shape
+    dv = v.shape[2]
+    bkv = k.shape[0]
+    group = bh // bkv
+    bq, bk = _blocks_for(KERNEL_DKDV, q, k, v, mask, forced, dq="fused")
+    n_q, n_k = s // bq, s // bk
+
+    def q_block(b, t):
+        # Step t of a K/V head: query head t // n_q of its group, q block
+        # t % n_q.
+        return b * group + _div(t, n_q), _rem(t, n_q)
+
+    def q_map(b, t, j):
+        return (*q_block(b, t), 0)
+
+    def row_map(b, t, j):
+        head, i = q_block(b, t)
+        return head, 0, i
+
+    def kv_map(b, t, j):
+        # A skipped step re-names the nearest kept block of the row.
+        return b, _clamp(j, *mask.k_blocks(_rem(t, n_q), bq, bk)), 0
+
+    def whole_map(b, t, j):
+        return b, 0, 0
+
+    kernel = functools.partial(_bwd_kernel, sm_scale=sm_scale, block_q=bq,
+                               block_k=bk, n_q_blocks=n_q, n_k_blocks=n_k,
+                               n_steps=group * n_q, mask=mask, kv_len=kv_len,
+                               masked=mask.needs_masking(kv_len, s))
+    vma = _out_vma(q, k, v, do)
+    return pl.pallas_call(
+        kernel,
+        grid=(bkv, group * n_q, n_k),
+        in_specs=[
+            pl.BlockSpec((1, bq, d), q_map),                           # q
+            pl.BlockSpec((1, bk, d), kv_map),                          # k
+            pl.BlockSpec((1, bk, dv), kv_map),                         # v
+            pl.BlockSpec((1, bq, dv), q_map),                          # do
+            pl.BlockSpec((1, 1, bq), row_map),                         # lse
+            pl.BlockSpec((1, 1, bq), row_map),                         # delta
+        ],
+        out_specs=[
+            pl.BlockSpec((1, bq, d), q_map),
+            # A K/V head's whole dK and dV: the index is constant over the
+            # two inner axes, so they leave VMEM once, on its last step.
+            pl.BlockSpec((1, s, d), whole_map),
+            pl.BlockSpec((1, s, dv), whole_map),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((bh, s, d), q.dtype, vma=vma),
+            jax.ShapeDtypeStruct((bkv, s, d), k.dtype, vma=vma),
+            jax.ShapeDtypeStruct((bkv, s, dv), v.dtype, vma=vma),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((d, bq), jnp.float32),       # dQ of the row, transposed
+            pltpu.VMEM((s, d), jnp.float32),        # the K/V head's dK
+            pltpu.VMEM((s, dv), jnp.float32),       # ... and dV
+        ],
+        compiler_params=_compiler_params(carried_over=2),
+        interpret=_use_interpret(),
+        name=KERNEL_DKDV,
+    )(q, k, v, do, lse, delta)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
 def _flash_bhsd(q, k, v, sm_scale, mask, kv_len, forced):
     o, _ = _fwd_call(q, k, v, sm_scale, mask, kv_len, forced)
@@ -619,15 +790,20 @@ def _flash_bhsd_fwd(q, k, v, sm_scale, mask, kv_len, forced):
 
 def _flash_bhsd_bwd(sm_scale, mask, kv_len, forced, res, do):
     q, k, v, o, lse = res
-    bh, s, _ = q.shape
+    bh, s, d = q.shape
     # delta_i = rowsum(dO_i * O_i) — tiny elementwise pass, XLA fuses it.
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
-    # The statistics enter dKdV as [bh, 1, s] rows (its tile is transposed)
-    # and dQ lane-replicated [bh, s, 128] (Mosaic rejects vector blocks
-    # whose sublane dim is 1 — see _fwd_kernel), transiently: the residual
-    # holds one float a row.
-    dk, dv = _dkdv_call(q, k, v, do, lse[:, None, :], delta[:, None, :],
-                        sm_scale, mask, kv_len, forced)
+    # The statistics enter a transposed tile as [bh, 1, s] rows.
+    rows = lse[:, None, :], delta[:, None, :]
+    tile = forced or block_sizes(KERNEL_DKDV, s, d, q.dtype, mask.causal,
+                                 v.shape[2])
+    if backward_is_fused(*tile, s, d, q.dtype, v.shape[2]):
+        return _bwd_call(q, k, v, do, *rows, sm_scale, mask, kv_len, tile)
+    # Too long for a head's dK and dV to stay in VMEM: a kernel each. dQ
+    # reads the statistics lane-replicated [bh, s, 128] (Mosaic rejects
+    # vector blocks whose sublane dim is 1 — see _fwd_kernel), transiently:
+    # the residual holds one float a row.
+    dk, dv = _dkdv_call(q, k, v, do, *rows, sm_scale, mask, kv_len, forced)
     dq = _dq_call(q, k, v, do,
                   jnp.broadcast_to(lse[..., None], (bh, s, LANES)),
                   jnp.broadcast_to(delta[..., None], (bh, s, LANES)),
@@ -653,11 +829,11 @@ def flash_attention(q, k, v, causal: bool = True, *,
     tail padding masked out of the key axis. ``window`` (static, causal
     only): a query sees itself and the ``window - 1`` keys before it, and
     the tiles wholly below that band are skipped as those above the
-    diagonal are, neither multiplied nor fetched, in all three kernels; a
+    diagonal are, neither multiplied nor fetched, in every kernel; a
     window of the sequence's length or more is the causal program, unchanged
     (:class:`Mask`). Tile sizes and the MXU operands' dtype follow the
     call's shapes and dtype (``block_sizes``); ``_blocks=(block_q,
-    block_k)`` forces one tile on all three kernels, for the tests and the
+    block_k)`` forces one tile on every kernel, for the tests and the
     sweep.
     """
     b, s, h, d = q.shape

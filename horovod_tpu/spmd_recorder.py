@@ -84,9 +84,12 @@ _TRACED = {
         "Times JAX traced a flash attention kernel, by kernel and the tiling "
         "the call got: block sizes, the MXU operands' dtype, query heads per "
         "K/V head, the width of a query and key head and of a value head "
-        "(latent attention's differ).",
+        "(latent attention's differ), and where dQ is made: fused (this "
+        "hvd_flash_dkdv is the whole backward pass), own (dKdV and dQ a "
+        "kernel each: a K/V head's dK and dV do not fit VMEM), none (the "
+        "forward).",
         ("kernel", "block_q", "block_k", "operand_dtype", "kv_group",
-         "key_dim", "value_dim")),
+         "key_dim", "value_dim", "dq")),
     "hvdtpu_spmd_flash_tiles_total": (
         "Tiles of the grids of the flash attention kernels JAX traced, by "
         "kernel, the mask's kind (causal, window, full), the padded length "

@@ -9,6 +9,14 @@ operand dtypes and K/V head counts, timed on the host clock around
 of microseconds). Also holds the compiled kernels to dense attention once.
 
     chiprun --chips 1 -- python scripts/flash_block_sweep.py [--quick]
+    chiprun --chips 1 -- python scripts/flash_block_sweep.py --bwd [cell ...]
+
+``--bwd`` times the backward pass alone at a benchmark cell's shape
+(``BWD_SHAPES``: Moonlight's two widths and Trinity's window among them), as
+the one kernel that makes dQ, dK and dV and as the pair it replaced (dKdV,
+dQ and the lane-replicated statistics XLA makes for dQ), over three tiles,
+and holds the one kernel's gradients to the pair's there (PERF.md, Findings,
+PR 52).
 
 One JSON object a line on stdout and in ``chiprun_out/flash_sweep.jsonl``.
 Works on a tree that still has the fixed-tile kernels (``--parent``): there
@@ -38,6 +46,20 @@ CELLS = ("s4096", "s512")
 TILES = ((128, 128), (256, 256), (256, 512), (512, 256), (512, 512),
          (512, 1024), (1024, 512), (1024, 1024), (1024, 2048), (2048, 1024),
          (512, 2048), (2048, 512))
+# The backward pass at a cell's shape: (B, S, H, Hkv, D, Dv, window).
+BWD_SHAPES = {
+    "moonlight-16b-a3b_s8192": (2, 8192, 16, 16, 192, 128, None),
+    "trinity-mini_s8192": (2, 8192, 32, 4, 128, 128, None),
+    "trinity-mini_s8192_window": (2, 8192, 32, 4, 128, 128, 2048),
+    "starcoder2-3b_s4096": (2, 4096, 24, 2, 128, 128, None),
+    "zaya1-8b_s4096": (4, 4096, 8, 2, 128, 128, None),
+    "qwen3-next-80b-a3b_s4096": (4, 4096, 16, 2, 256, 256, None),
+    "olmo-hybrid-7b_s8192": (1, 8192, 30, 30, 128, 128, None),
+    "granite-4.0-h-micro_s4096": (2, 4096, 32, 8, 64, 64, None),
+    "starcoder2-3b_s512": (16, 512, 24, 2, 128, 128, None),
+    "olmoe-1b-7b_s4096": (2, 4096, 16, 16, 128, 128, None),
+}
+BWD_TILES = ((1024, 1024), (512, 1024), (1024, 512))
 OUT = os.path.join("chiprun_out", "flash_sweep.jsonl")
 KERNELS = {"fwd": fa.KERNEL_FWD, "dkdv": fa.KERNEL_DKDV, "dq": fa.KERNEL_DQ}
 
@@ -62,6 +84,10 @@ def timed(fn, *args, iters: int = 10) -> float:
         jax.block_until_ready(out)
         windows.append((time.perf_counter() - t0) / iters * 1e3)
     return sorted(windows)[1]
+
+
+def max_abs(x) -> float:
+    return float(jnp.max(jnp.abs(x.astype(jnp.float32))))
 
 
 def operands(shape, dtype, group_in_hbm: bool, seed: int = 0):
@@ -126,6 +152,61 @@ def time_variant(label, name, shape, dtype, tiles, group_in_hbm, kernels):
                  table=t is None, ms=ms)
 
 
+def time_bwd(name, tiles=BWD_TILES, dtype=jnp.bfloat16):
+    """The backward pass at cell ``name``'s shape: the fused kernel beside
+    the pair (dKdV, dQ and the statistics' broadcast to lanes that dQ
+    reads), ms a call with ``delta`` made in both, and the largest
+    difference of their gradients."""
+    b, s, h, hkv, d, dv, window = BWD_SHAPES[name]
+    ks = jax.random.split(jax.random.PRNGKey(0), 4)
+    q = jax.random.normal(ks[0], (b * h, s, d), dtype) * 0.5
+    k = jax.random.normal(ks[1], (b * hkv, s, d), dtype) * 0.5
+    v = jax.random.normal(ks[2], (b * hkv, s, dv), dtype) * 0.5
+    do = jax.random.normal(ks[3], (b * h, s, dv), dtype) * 0.5
+    sc, mask = 1.0 / d ** 0.5, fa.Mask(True, window)
+    o, lse = jax.jit(lambda q, k, v: fa._fwd_call(
+        q, k, v, sc, mask, s, None))(q, k, v)
+    lse = lse[..., 0]
+
+    def stats(o, do):
+        delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), -1)
+        return lse[:, None, :], delta[:, None, :]
+
+    def lanes(x):
+        return jnp.broadcast_to(x[:, 0, :, None], (*x.shape[::2], 128))
+
+    for t in tiles:
+        if s % t[0] or s % t[1]:
+            t = (min(t[0], s), min(t[1], s))
+
+        def fused(q, k, v, o, do):
+            return fa._bwd_call(q, k, v, do, *stats(o, do), sc, mask, s, t)
+
+        def pair(q, k, v, o, do):
+            rows = stats(o, do)
+            dk, dv_ = fa._dkdv_call(q, k, v, do, *rows, sc, mask, s, t)
+            dq = fa._dq_call(q, k, v, do, *map(lanes, rows), sc, mask, s, t)
+            return dq, dk, dv_
+
+        row = dict(bwd=name, tile=list(t), dtype=jnp.dtype(dtype).name,
+                   fits=fa.backward_is_fused(*t, s, d, dtype, dv))
+        try:
+            row["fused_ms"] = timed(fused, q, k, v, o, do)
+            row["pair_ms"] = timed(pair, q, k, v, o, do)
+            got = jax.jit(fused)(q, k, v, o, do)
+            want = jax.jit(pair)(q, k, v, o, do)
+            names = ("dq", "dk", "dv")
+            row["max_abs_diff"] = dict(zip(names, (
+                max_abs(a.astype(jnp.float32) - b.astype(jnp.float32))
+                for a, b in zip(got, want))))
+            row["max_abs"] = dict(zip(names, map(max_abs, want)))
+        except Exception as e:  # a tile Mosaic refuses is a result too
+            row["error"] = str(e)[:300]
+        emit(**row)
+        if t == (s, s):
+            break
+
+
 DENSE_CASES = (
     (jnp.float32, 1024, True, None), (jnp.float32, 1024, True, (256, 512)),
     (jnp.float32, 1024, True, (512, 256)), (jnp.float32, 600, False, None),
@@ -161,11 +242,9 @@ def check_against_dense(cases=DENSE_CASES):
             lambda *a: loss(flash, *a), argnums=(0, 1, 2)))(q, k, v)
         want = jax.jit(jax.value_and_grad(
             lambda *a: loss(dense, *a), argnums=(0, 1, 2)))(q, k, v)
-        errs = [float(jnp.max(jnp.abs(a.astype(jnp.float32)
-                                      - b.astype(jnp.float32))))
+        errs = [max_abs(a.astype(jnp.float32) - b.astype(jnp.float32))
                 for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want))]
-        scale = [float(jnp.max(jnp.abs(b.astype(jnp.float32))))
-                 for b in jax.tree.leaves(want)]
+        scale = [max_abs(b) for b in jax.tree.leaves(want)]
         emit(check="dense", dtype=jnp.dtype(dtype).name, s=s, causal=causal,
              blocks=blocks, max_abs_err=dict(zip(("loss", "dq", "dk", "dv"),
                                                  errs)),
@@ -178,6 +257,9 @@ def main():
                     help="the tree has the fixed-tile kernels")
     ap.add_argument("--quick", action="store_true",
                     help="the table's choice only, no sweep")
+    ap.add_argument("--bwd", nargs="*", metavar="CELL", default=None,
+                    help="the backward pass alone, one kernel beside the "
+                         "pair, at these cells' shapes (none named: all)")
     args = ap.parse_args()
     dev = jax.devices()[0]
     emit(platform=dev.platform, device_kind=dev.device_kind)
@@ -191,6 +273,10 @@ def main():
             time_parent(name, shape, bf16)
         return
     check_against_dense()
+    if args.bwd is not None:
+        for name in args.bwd or BWD_SHAPES:
+            time_bwd(name)
+        return
     for name, shape in SHAPES.items():
         time_variant("table", name, shape, bf16, (None,), False, all_kernels)
         if args.quick:

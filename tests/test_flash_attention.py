@@ -239,12 +239,165 @@ def test_metrics_name_the_tiling(make_runtime):
     fams = hvd.metrics()
     fam = fams["hvdtpu_spmd_flash_kernel_traces_total"]
     assert fam["type"] == "counter"
-    for kernel in KERNELS:
+    # The backward pass is one kernel under the dKdV kernel's name, and the
+    # entry says that it made dQ too.
+    for kernel, dq in ((fa.KERNEL_FWD, "none"), (fa.KERNEL_DKDV, "fused")):
         labels = {"kernel": kernel, "block_q": "128", "block_k": "256",
-                  "operand_dtype": "bfloat16", "kv_group": "2"}
+                  "operand_dtype": "bfloat16", "kv_group": "2", "dq": dq}
         assert sample_value(
             fams, "hvdtpu_spmd_flash_kernel_traces_total", **labels) >= 1, \
             (kernel, fam["samples"])
+    assert sample_value(fams, "hvdtpu_spmd_flash_kernel_traces_total",
+                        kernel=fa.KERNEL_DQ) is None, fam["samples"]
+
+
+# ---- the backward pass as one kernel ----------------------------------------
+
+def _grads(q, k, v, w, **kw):
+    """dQ, dK, dV of ``sum(flash_attention(q, k, v) * w)``, as float32."""
+    return _value_and_grads(flash_attention, q, k, v, w, **kw)[1:]
+
+
+# causal, window, the sequence (200 pads to 256: the bidirectional call's
+# padded key columns are masked, and its padded query rows are real rows of
+# the dKdV sums that must add nothing).
+MODES = {"causal": (True, None, 256), "window": (True, 100, 256),
+         "bidirectional_padded": (False, None, 200)}
+
+
+F32, BF16 = jnp.float32, jnp.bfloat16
+# Query heads a K/V head, the key and value widths, the mask, the dtype:
+# every group, pair of widths and mask in both dtypes, and each group under
+# each mask.
+FUSED_CASES = [
+    (1, 32, 32, "causal", F32), (1, 192, 128, "causal", F32),
+    (1, 192, 128, "window", BF16), (1, 32, 32, "bidirectional_padded", BF16),
+    (4, 192, 128, "causal", BF16), (4, 32, 32, "window", F32),
+    (4, 192, 128, "bidirectional_padded", F32),
+    (12, 32, 32, "causal", BF16), (12, 192, 128, "window", F32),
+    (12, 192, 128, "bidirectional_padded", BF16),
+]
+
+
+@pytest.mark.parametrize(
+    "group, dk, dv, mode, dtype", FUSED_CASES,
+    ids=lambda x: getattr(x, "__name__", None) or str(x))
+def test_fused_backward_matches_dense(group, dk, dv, mode, dtype):
+    """dQ, dK and dV of the one backward kernel against the dense
+    reference's on repeated heads, at tiles that are not square (the
+    diagonal through a tile's interior, a K/V head's sums indexed by a k
+    block that is not a q block), over a whole group of query heads."""
+    causal, window, s = MODES[mode]
+    blocks = (128, 256) if group == 4 else (256, 128)
+    q, k, v = _qkv_two_widths(s, group, 1, dk, dv, seed=group + dk,
+                              dtype=dtype)
+    w = jax.random.normal(jax.random.PRNGKey(47), (1, s, group, dv)) * 0.1
+
+    def dense(q, k, v):
+        return default_attention(q, repeat_kv_heads(k, group),
+                                 repeat_kv_heads(v, group), causal=causal,
+                                 window=window)
+
+    assert fa.backward_is_fused(*blocks, 256, dk, dtype, dv)
+    got = _grads(q, k, v, w, causal=causal, window=window, _blocks=blocks)
+    want = _value_and_grads(dense, q, k, v, w)[1:]
+    if dtype == jnp.float32:
+        for g, r, name in zip(got, want, "qkv"):
+            np.testing.assert_allclose(g, r, rtol=5e-4, atol=5e-5,
+                                       err_msg=f"d{name}")
+        return
+    exact = _value_and_grads(
+        dense, *(x.astype(jnp.float32) for x in (q, k, v)), w)[1:]
+    for g, r, e, name in zip(got, want, exact, "qkv"):
+        tol = 4 * BF16_EPS * np.abs(e).max()
+        np.testing.assert_allclose(g, r, rtol=0, atol=tol,
+                                   err_msg=f"d{name}")
+        assert np.abs(g - e).max() <= np.abs(r - e).max() + tol, name
+
+
+@pytest.mark.parametrize("mode, blocks, dtype", [
+    *((mode, blocks, (F32, BF16)[(i + j) % 2])
+      for i, mode in enumerate(MODES)
+      for j, blocks in enumerate([(128, 256), (256, 128)])),
+    ("causal", None, BF16)],
+    ids=lambda x: getattr(x, "__name__", None) or str(x))
+def test_fused_backward_equals_the_pair_at_equal_tiles(monkeypatch, mode,
+                                                       blocks, dtype):
+    """One score tile where the pair made two: dK and dV are the same
+    float32 sums in the same order, bit for bit; dQ's product contracts the
+    tile's other axis and agrees to float32 rounding (in bfloat16 that may
+    turn the one rounding of the result: a last place of its own). Tiles not
+    square either way under each mask, and the table's."""
+    causal, window, s = MODES[mode]
+    s = s + 256                  # two k blocks a row at the widest tile
+    q, k, v = _qkv_two_widths(s, 4, 2, 64, 32, seed=s, dtype=dtype)
+    w = jax.random.normal(jax.random.PRNGKey(53), (1, s, 4, 32)) * 0.1
+    kw = dict(causal=causal, window=window, _blocks=blocks)
+    dq, dk, dv = _grads(q, k, v, w, **kw)
+    # The backward pass as a dKdV and a dQ kernel whatever the shape.
+    monkeypatch.setattr(fa, "backward_is_fused", lambda *a: False)
+    dq_pair, dk_pair, dv_pair = _grads(q, k, v, w, **kw)
+    np.testing.assert_array_equal(dk, dk_pair)
+    np.testing.assert_array_equal(dv, dv_pair)
+    np.testing.assert_allclose(
+        dq, dq_pair, atol=1e-6 * np.abs(dq_pair).max(),
+        rtol=1e-5 if dtype == jnp.float32 else 2 * BF16_EPS)
+
+
+def test_backward_over_the_vmem_limit_takes_the_pair_and_says_so(
+        monkeypatch, make_runtime):
+    """A sequence whose dK and dV sums do not fit the VMEM a call asks for
+    beside the tile keeps a kernel each for dKdV and dQ (here the limit is
+    brought down to the sequence), the counter says which, and the
+    gradients are the dense reference's either way."""
+    make_runtime(devices=jax.devices()[:1])
+    s, limit = 512, 3 * 1024 * 1024
+    monkeypatch.setattr(fa, "VMEM_LIMIT_BYTES", limit)
+    tile = fa.block_sizes(fa.KERNEL_DKDV, s, 32, jnp.float32, True)
+    assert tile == fa.block_sizes(fa.KERNEL_DKDV, 256, 32, jnp.float32, True)
+    assert fa.vmem_estimate(fa.KERNEL_DKDV, *tile, 32, 4) <= limit
+    assert not fa.backward_is_fused(*tile, s, 32, jnp.float32)
+    assert fa.backward_is_fused(*tile, 256, 32, jnp.float32)
+
+    def traced():
+        return {(labels["kernel"], labels["dq"]) for _, labels, _ in
+                hvd.metrics()["hvdtpu_spmd_flash_kernel_traces_total"]
+                ["samples"]}
+
+    for length, backward in (
+            (s, {(fa.KERNEL_DKDV, "own"), (fa.KERNEL_DQ, "own")}),
+            (256, {(fa.KERNEL_DKDV, "fused")})):
+        q, k, v = _qkv(1, length, 2, 32, seed=59)
+        k, v = k[:, :, :1], v[:, :, :1]
+        w = jax.random.normal(jax.random.PRNGKey(61), q.shape) * 0.1
+        before = traced()
+        got = _grads(q, k, v, w)
+        assert traced() - before - {(fa.KERNEL_FWD, "none")} == backward
+        want = _value_and_grads(_dense_on_repeated_heads, q, k, v, w,
+                                causal=True)[1:]
+        for g, r, name in zip(got, want, "qkv"):
+            np.testing.assert_allclose(g, r, rtol=5e-4, atol=5e-5,
+                                       err_msg=f"d{name}")
+
+
+@pytest.mark.parametrize("s, d, dv, fused", [
+    (512, 128, 128, True), (4096, 128, 128, True), (4096, 64, 64, True),
+    (4096, 256, 256, True), (8192, 128, 128, True), (8192, 192, 128, True),
+    (16384, 128, 128, True), (32768, 128, 128, False),
+    (16384, 256, 256, False)])
+def test_which_lengths_take_the_fused_backward(s, d, dv, fused):
+    """Every benchmark cell's shape does (the longest at the widest head:
+    8192 rows of 192 beside 128); past 16k rows at heads of 128 a head's dK
+    and dV no longer fit, and the estimate counts them, the float32 sums
+    and, twice, the blocks they leave through, on top of at most what a dQ
+    kernel's step holds."""
+    tile = fa.block_sizes(fa.KERNEL_DKDV, s, d, jnp.bfloat16, True, dv)
+    assert fa.backward_is_fused(*tile, s, d, jnp.bfloat16, dv) == fused
+    whole = s * (-(-d // 128) + -(-dv // 128)) * 128 * (4 + 2 * 2)
+    estimate = fa.vmem_estimate(fa.KERNEL_DKDV, *tile, d, 2, dv,
+                                fused_rows=s)
+    assert whole <= estimate \
+        <= whole + fa.vmem_estimate(fa.KERNEL_DQ, *tile, d, 2, dv)
 
 
 def test_grouped_query_attention():
@@ -381,30 +534,43 @@ def test_block_table_counts_both_widths(kernel):
         == (1024, 1024)
 
 
-# sha256 of the StableHLO text (no source locations) that the gradient of a
-# call with one head width lowered to at the commit before a value head
-# could have a width of its own (PR 49), interpreted kernels included. A
+# sha256 of the StableHLO text (no source locations) that a call with one
+# head width lowers to, interpreted kernels included. ``forward``: what the
+# output alone lowered to at the commit before a value head could have a
+# width of its own (PR 49) and at every commit since, the one that made the
+# backward pass one kernel (PR 52) included: that PR left the forward kernel
+# alone. ``gradient``: what the gradient lowers to since PR 52 (before it,
+# with a dKdV and a dQ kernel, b7df1a33...efb65d and 251c9b28...56d24). A
 # change that means to alter what such a call traces to pins these anew.
-LOWERED_BEFORE_TWO_WIDTHS = {
-    (4, 2, 64, None, "bfloat16"):
-        "b7df1a3308dfbb094cc1570a0a64ed5aa6bfbb9eb6b204d2eb273168efefb65d",
-    (2, 2, 128, 96, "float32"):
-        "251c9b2872434b42ad0182be6941cbe84c30d453ecd5b30f04af70b6bc656d24",
+LOWERED = {
+    (4, 2, 64, None, "bfloat16"): dict(
+        forward="36607613775a8ecb9d5e3767d7ef7ed6"
+                "03665a7d8b6e70fafef6c5981d842723",
+        gradient="130bc696fdacfb997093f900d641bc79"
+                 "faca65d6062d2bc4d282c6fb4d8fe30c"),
+    (2, 2, 128, 96, "float32"): dict(
+        forward="d40641ff57c91b0fe400c396aff8238d"
+                "c64e100b66fbddf8d39febc06007d496",
+        gradient="e92ada637d10f683dc820f6c139914bb"
+                 "4f2021fd4e2ff31055b5fa091155bcbf"),
 }
 
 
-@pytest.mark.parametrize("case", list(LOWERED_BEFORE_TWO_WIDTHS),
+@pytest.mark.parametrize("what", ["forward", "gradient"])
+@pytest.mark.parametrize("case", list(LOWERED),
                          ids=lambda c: "-".join(map(str, c)))
-def test_equal_widths_lower_to_the_program_they_lowered_to(case):
+def test_equal_widths_lower_to_the_program_they_lowered_to(case, what):
     import hashlib
     h, hkv, d, window, dtype = case
     q = jax.ShapeDtypeStruct((1, 256, h, d), jnp.dtype(dtype))
     k = jax.ShapeDtypeStruct((1, 256, hkv, d), jnp.dtype(dtype))
 
-    def loss(q, k, v):
-        return jnp.sum(flash_attention(q, k, v, window=window)
-                       .astype(jnp.float32))
+    def out(q, k, v):
+        return flash_attention(q, k, v, window=window)
 
-    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, k, k).as_text()
-    assert hashlib.sha256(text.encode()).hexdigest() \
-        == LOWERED_BEFORE_TWO_WIDTHS[case]
+    def loss(q, k, v):
+        return jnp.sum(out(q, k, v).astype(jnp.float32))
+
+    fn = out if what == "forward" else jax.grad(loss, argnums=(0, 1, 2))
+    text = jax.jit(fn).lower(q, k, k).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest() == LOWERED[case][what]

@@ -54,8 +54,8 @@ def mosaic(monkeypatch):
         yield
 
 
-# (B*H, B*Hkv, S, D or (D, Dv), dtype, causal): the two cells; chip_smoke's
-# legs.
+# (B*H, B*Hkv, S, D or (D, Dv), dtype, causal[, window]): the two cells;
+# chip_smoke's legs.
 SHAPES = {
     "starcoder2-3b_s4096": (48, 4, 4096, 128, jnp.bfloat16, True),
     "starcoder2-3b_s512": (384, 32, 512, 128, jnp.bfloat16, True),
@@ -68,14 +68,25 @@ SHAPES = {
     "moonlight-16b-a3b_s8192": (32, 32, 8192, (192, 128), jnp.bfloat16,
                                 True),
 }
+# The backward pass as one kernel: besides those, 2 sequences at 32:4 heads
+# with and without the window of 2048, and 4 sequences at 8:2 heads.
+FUSED_SHAPES = {
+    **SHAPES,
+    "trinity-mini_s8192": (64, 8, 8192, 128, jnp.bfloat16, True),
+    "trinity-mini_s8192_window": (64, 8, 8192, 128, jnp.bfloat16, True,
+                                  2048),
+    "zaya1-8b_s4096": (32, 8, 4096, 128, jnp.bfloat16, True),
+}
 
 
-@pytest.mark.parametrize("kernel", ["fwd", "dkdv", "dq"])
-@pytest.mark.parametrize("shape", list(SHAPES))
+@pytest.mark.parametrize("shape, kernel", [
+    *((shape, kernel) for shape in SHAPES
+      for kernel in ("fwd", "dkdv", "dq")),
+    *((shape, "fused") for shape in FUSED_SHAPES)])
 def test_kernel_compiles_for_v5e(one_chip, mosaic, shape, kernel):
-    bh, bkv, s, d, dtype, causal = SHAPES[shape]
+    bh, bkv, s, d, dtype, causal, *window = FUSED_SHAPES[shape]
     d, dv = d if isinstance(d, tuple) else (d, d)
-    mask = fa.Mask(causal=causal)
+    mask = fa.Mask(causal, *window)
 
     def sds(*dims, dt=dtype):
         return jax.ShapeDtypeStruct(dims, dt, sharding=one_chip)
@@ -86,16 +97,22 @@ def test_kernel_compiles_for_v5e(one_chip, mosaic, shape, kernel):
     if kernel == "fwd":
         f = lambda q, k, v: fa._fwd_call(q, k, v, scale, mask, s)
         args = (q, k, v)
-    elif kernel == "dkdv":
-        f = lambda *a: fa._dkdv_call(*a, scale, mask, s)
+    elif kernel in ("dkdv", "fused"):
+        # The one kernel that makes dQ too carries the dKdV kernel's name.
+        call = fa._dkdv_call if kernel == "dkdv" else fa._bwd_call
+        f = lambda *a: call(*a, scale, mask, s)
         rows = sds(bh, 1, s, dt=jnp.float32)
         args = (q, k, v, do, rows, rows)
+        if kernel == "fused":
+            tile = fa.block_sizes(fa.KERNEL_DKDV, s, d, dtype, causal, dv)
+            assert fa.backward_is_fused(*tile, s, d, dtype, dv)
     else:
         f = lambda *a: fa._dq_call(*a, scale, mask, s)
         cols = sds(bh, s, 128, dt=jnp.float32)
         args = (q, k, v, do, cols, cols)
     text = jax.jit(f).lower(*args).compile().as_text()
-    assert "tpu_custom_call" in text and f"hvd_flash_{kernel}" in text
+    name = "dkdv" if kernel == "fused" else kernel
+    assert "tpu_custom_call" in text and f"hvd_flash_{name}" in text
 
 
 # (B, S, H, P, N, G, Q, dtype): the granite-4.0-h-micro_s4096 cell's scan;

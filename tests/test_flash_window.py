@@ -1,7 +1,9 @@
-"""The three flash kernels under a causal band (a sliding window) against
+"""The flash kernels under a causal band (a sliding window) against
 ``default_attention`` under the same band: outputs and all three gradients
-element by element, the tiles each grid keeps counted against the formula,
-and the one mask description (``fa.Mask``) the kernels read.
+element by element (the backward pass as the one kernel every shape here
+takes, and as the pair of dKdV and dQ it replaced), the tiles each grid keeps
+counted against the formula, and the one mask description (``fa.Mask``) the
+kernels read.
 
 Interpret mode on the CPU (as ``test_flash_attention.py``): it says nothing
 of Mosaic lowering, which ``test_flash_mosaic_compile.py`` holds.
@@ -101,6 +103,32 @@ def test_grouped_query_heads_under_a_band():
     _assert_close(*_both(256, 70, (128, 128), h=4, hkv=1, seed=8))
 
 
+# sequence, window, forced tile, query heads a K/V head: the band inside one
+# tile, across tiles that are not square either way, a group of twelve.
+BANDS = [(512, 200, (256, 128), 1), (512, 200, (128, 256), 4),
+         (384, 100, (128, 128), 12), (512, 300, None, 2)]
+
+
+@pytest.mark.parametrize("s,window,blocks,group", BANDS)
+def test_band_backward_as_one_kernel_and_as_the_pair(monkeypatch, s, window,
+                                                     blocks, group):
+    """Under a band the one backward kernel skips the tiles the pair skips
+    and clamps the K/V blocks it names as the dQ kernel does: both match the
+    dense reference; dK and dV are the pair's bit for bit (the same float32
+    sums in the same order), dQ to float32 rounding (its product contracts
+    the tile's other axis)."""
+    one, want = _both(s, window, blocks, h=group, hkv=1, seed=13)
+    _assert_close(one, want)
+    # What a sequence too long for a head's dK and dV in VMEM keeps.
+    monkeypatch.setattr(fa, "backward_is_fused", lambda *a: False)
+    pair, _ = _both(s, window, blocks, h=group, hkv=1, seed=13)
+    _assert_close(pair, want)
+    for name, a, b in zip(("dk", "dv"), one[2:], pair[2:]):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b), name)
+    np.testing.assert_allclose(np.asarray(one[1]), np.asarray(pair[1]),
+                               rtol=1e-5, atol=1e-7)
+
+
 @pytest.mark.parametrize("s,window", [(200, 60), (300, 129), (130, 128)])
 def test_padded_length_under_a_band(s, window):
     # The kernels pad to 128 rows; the padded keys lie inside a padded
@@ -191,8 +219,12 @@ def test_band_pairs_by_hand():
     assert mask.sum() == flops_window.band_pairs(64, 10)
 
 
-def test_metrics_count_the_tiles_kept_and_skipped(make_runtime):
+@pytest.mark.parametrize("backward", ["fused", "pair"])
+def test_metrics_count_the_tiles_kept_and_skipped(make_runtime, monkeypatch,
+                                                  backward):
     make_runtime(devices=jax.devices()[:1])
+    if backward == "pair":
+        monkeypatch.setattr(fa, "backward_is_fused", lambda *a: False)
     q, k, v, _ = _qkv(1, 512, 2, 1, 32)
     jax.grad(lambda q: jnp.sum(flash_attention(
         q, k, v, window=200, _blocks=(128, 128))))(q)
@@ -200,16 +232,21 @@ def test_metrics_count_the_tiles_kept_and_skipped(make_runtime):
         q, k, v, _blocks=(128, 128))))(q)
     fams = hvd.metrics()
     assert fams["hvdtpu_spmd_flash_tiles_total"]["type"] == "counter"
+
+    def count(kernel, mask, tiles):
+        return sample_value(fams, "hvdtpu_spmd_flash_tiles_total",
+                            kernel=kernel, mask=mask, tiles=tiles,
+                            seq="512")
+
     # 4 x 4 tiles: the triangle keeps 10; a band of 200 keeps the diagonal,
-    # the one below it and the one below that (4 + 3 + 2).
-    for kernel in KERNELS:
-        def count(mask, tiles):
-            return sample_value(fams, "hvdtpu_spmd_flash_tiles_total",
-                                kernel=kernel, mask=mask, tiles=tiles,
-                                seq="512")
-        assert count("window", "kept") == 9
-        assert count("window", "skipped_band") == 1
-        assert count("window", "skipped") == 6
-        assert count("causal", "kept") == 10
-        assert count("causal", "skipped") == 6
-        assert count("causal", "skipped_band") == 0
+    # the one below it and the one below that (4 + 3 + 2). The one backward
+    # kernel walks the grid once, under the dKdV kernel's name.
+    for kernel in KERNELS[:2 if backward == "fused" else 3]:
+        assert count(kernel, "window", "kept") == 9
+        assert count(kernel, "window", "skipped_band") == 1
+        assert count(kernel, "window", "skipped") == 6
+        assert count(kernel, "causal", "kept") == 10
+        assert count(kernel, "causal", "skipped") == 6
+        assert count(kernel, "causal", "skipped_band") == 0
+    if backward == "fused":
+        assert count(fa.KERNEL_DQ, "window", "kept") is None

@@ -380,11 +380,14 @@ def test_the_step_counts_its_mla_and_flash_traces(spmd8):
     assert dict(mla) == dict(heads=str(HEADS), nope_dim=str(NOPE),
                              rope_dim=str(ROT), value_dim=str(VALUE),
                              kv_rank=str(RANK), q_rank="none")
-    widths = {(labels["kernel"], labels["key_dim"], labels["value_dim"])
+    widths = {(labels["kernel"], labels["key_dim"], labels["value_dim"],
+               labels["dq"])
               for _, labels, _ in
               fams["hvdtpu_spmd_flash_kernel_traces_total"]["samples"]}
-    assert widths == {(kernel, str(NOPE + ROT), str(VALUE)) for kernel in
-                      ("hvd_flash_fwd", "hvd_flash_dkdv", "hvd_flash_dq")}
+    # The backward pass is the one kernel that makes dQ too.
+    assert widths == {(kernel, str(NOPE + ROT), str(VALUE), dq)
+                      for kernel, dq in (("hvd_flash_fwd", "none"),
+                                         ("hvd_flash_dkdv", "fused"))}
     # A checkpointed block keeps the flash output at the value head's
     # width, the sequence padded to 128 rows (a block traced once counts
     # once, however many layers share its trace).

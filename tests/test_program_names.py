@@ -8,6 +8,7 @@ import json
 import re
 import threading
 import time
+from unittest import mock
 
 import jax
 import jax.numpy as jnp
@@ -295,10 +296,12 @@ def test_in_step_collective_scope_uses_the_callers_name(spmd4):
 
 def test_window_step_holds_its_flash_kernels_under_a_scope_of_their_own(
         spmd4):
-    """A window layer's three flash kernels sit under ``layer<i>/attn_window``
+    """A window layer's flash kernels sit under ``layer<i>/attn_window``
     (where ``flash_window_ms`` looks) and a full layer's under
     ``layer<i>/attn``, forward and backward; the kernels' names are the same
-    three. A branch normed after it too has ``post_norm`` inside its scope."""
+    two, the backward pass one kernel under the dKdV kernel's name (no
+    ``hvd_flash_dq`` where a head's dK and dV fit VMEM). A branch normed
+    after it too has ``post_norm`` inside its scope."""
     plan = (gpt.LayerSpec(window=8), gpt.LayerSpec())
     step, *args = gpt_step("full", layers=plan, post_norm=True)
     text = step.lower(*args).as_text(debug_info=True)
@@ -307,7 +310,7 @@ def test_window_step_holds_its_flash_kernels_under_a_scope_of_their_own(
     for scope, kernel in scopes:
         layer = re.search(r"layer\d", scope).group(0)
         by_layer[(layer, scope.rsplit("/", 1)[-1])].add(kernel)
-    assert all(kernels == {"fwd", "dkdv", "dq"}
+    assert all(kernels == {"fwd", "dkdv"}
                for kernels in by_layer.values()), by_layer
     for scope in ("attn_window/post_norm", "attn/post_norm",
                   "mlp/post_norm"):
@@ -393,11 +396,15 @@ def test_mla_step_holds_its_parts_under_their_scopes(spmd4):
 
 # ---- (b) kernel names --------------------------------------------------------
 
-def _flash(grad: bool):
+def _flash(grad: bool, fused: bool = True):
+    """``fused``: the backward pass as the one kernel the shape takes; not:
+    as the pair a sequence too long for a head's dK and dV in VMEM keeps."""
     q = jnp.ones((1, 128, 2, 16), jnp.float32)
     if grad:
-        return jax.make_jaxpr(jax.grad(
-            lambda q: fa.flash_attention(q, q, q).sum()))(q)
+        with mock.patch.object(fa, "VMEM_LIMIT_BYTES",
+                               fa.VMEM_LIMIT_BYTES if fused else 2 ** 18):
+            return jax.make_jaxpr(jax.grad(
+                lambda q: fa.flash_attention(q, q, q).sum()))(q)
     return jax.make_jaxpr(lambda q: fa.flash_attention(q, q, q))(q)
 
 
@@ -443,7 +450,7 @@ MN = jnp.zeros((4,), jnp.float32)
 @pytest.mark.parametrize("name, make", [
     ("hvd_flash_fwd", lambda: _flash(False)),
     ("hvd_flash_dkdv", lambda: _flash(True)),
-    ("hvd_flash_dq", lambda: _flash(True)),
+    ("hvd_flash_dq", lambda: _flash(True, fused=False)),
     ("hvd_ssd_fwd", lambda: _scan(False)),
     ("hvd_ssd_bwd", lambda: _scan(True)),
     ("hvd_gdn_fwd", lambda: _delta(False)),
