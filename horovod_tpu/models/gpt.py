@@ -17,7 +17,7 @@ math needs:
   device), per config. Without a bound sp axis attention is the flash kernel
   (:mod:`horovod_tpu.ops.flash_attention`) unless the config asks for the
   dense reference by name; ``_attention`` holds the whole rule.
-* **ep** — optional expert blocks (dropless top-k, SiLU-gated experts) hold
+* **ep** — optional expert blocks (dropless top-k, gated experts) hold
   their experts over the ep axis (:mod:`horovod_tpu.parallel.moe`).
 * **dp** — gradient averaging comes from autodiff under shard_map(check_vma):
   dp-invariant params get their grad psum inserted automatically;
@@ -66,7 +66,11 @@ selection bias that is state, not a parameter (``router_bias``,
 :func:`update_router_bias`, :func:`trainable`). Its router is one matrix
 or, under ``router_kind="mlp"``, an MLP on a down-projection that adds the
 down-projection of the expert block before it (:func:`_mlp_router`): that
-state leaves a block beside ``x`` and enters the next. Under
+state leaves a block beside ``x`` and enters the next. A linear router
+reads what the experts read or, under ``router_reads="block_input"``, the
+stream as it enters the block, un-normed, before the mixer
+(:func:`_early_router`); the experts' gate is SiLU or, under
+``expert_activation="relu"``, ReLU. Under
 ``residual_scaling`` a sublayer joins the stream as ``a_r (x + b_r) + a_h
 (f(N(x)) + b_h)``, four learned vectors a sublayer (:func:`_residual`).
 
@@ -111,6 +115,7 @@ from ..parallel.ulysses import ulysses_attention_p
 
 MIXERS = ("attention", "cca", "mla", "ssm", "gdn")
 ROUTERS = ("linear", "mlp")
+ROUTER_READS = ("ff_input", "block_input")
 FEED_FORWARDS = ("dense", "gated", "experts")
 
 
@@ -150,9 +155,9 @@ class GPTConfig:
     attention: str = "ring"
     # Experts (active when moe_every > 0): every moe_every-th block's
     # feed-forward is the dropless expert layer of ``parallel/moe.py``:
-    # num_experts SiLU-gated experts of width mlp_dim, experts_per_token of
-    # them a token. The loss adds the layers' summed load-balance and router
-    # z terms under these coefficients.
+    # num_experts gated experts (``expert_activation``) of width mlp_dim,
+    # experts_per_token of them a token. The loss adds the layers' summed
+    # load-balance and router z terms under these coefficients.
     moe_every: int = 0
     num_experts: int = 8
     experts_per_token: int = 1
@@ -162,8 +167,8 @@ class GPTConfig:
     # holds experts first_expert to first_expert + experts_held of the
     # router's num_experts (None: all of them) and returns their part of the
     # sum. A token's experts_per_token weights divided by their sum. A
-    # SiLU-gated expert of width shared_expert_dim (0: none) that every
-    # token goes through, under a sigmoid gate of its own.
+    # gated expert of width shared_expert_dim (0: none) that every token
+    # goes through, under a sigmoid gate of its own.
     experts_held: Optional[int] = None
     first_expert: int = 0
     renormalize_experts: bool = False
@@ -290,6 +295,15 @@ class GPTConfig:
     # router_dim and a matrix [router_dim, experts] (``_mlp_router``).
     router_kind: str = "linear"
     router_dim: int = 256
+    # What an expert block's router reads, one of ``ROUTER_READS``:
+    # "ff_input", what the experts read (the normed stream after the mixer);
+    # "block_input": the stream as it enters the block, un-normed, before
+    # the mixer runs (``_block``: the product under the scope
+    # ``moe/router_early``; a linear router alone).
+    router_reads: str = "ff_input"
+    # The experts' gate, one of ``parallel/moe.py::ACTIVATIONS``: "silu" or
+    # "relu". A shared expert takes the same.
+    expert_activation: str = "silu"
     # A sublayer joins the residual stream as a_r (x + b_r) + a_h (f + b_h):
     # four learned vectors of embed_dim a sublayer, ones and zeros at
     # initialisation (``_residual``).
@@ -939,11 +953,14 @@ def _gdn_mixer(cfg: GPTConfig, p, h):
 
 
 def _shared_expert(cfg: GPTConfig, p, h):
-    """The expert every token goes through: ``W_down (silu(W_gate h) * W_up
-    h)``, under ``sigmoid(<h, w_g>)`` where the configuration gates it."""
+    """The expert every token goes through: ``W_down (act(W_gate h) * W_up
+    h)`` (``act`` the routed experts', ``cfg.expert_activation``), under
+    ``sigmoid(<h, w_g>)`` where the configuration gates it."""
+    from ..parallel.moe import ACTIVATIONS
     gate = jnp.einsum("bse,em->bsm", h, p["w_gate"].astype(cfg.dtype))
     up = jnp.einsum("bse,em->bsm", h, p["w_up"].astype(cfg.dtype))
-    down = _tp_psum(jnp.einsum("bsm,me->bse", jax.nn.silu(gate) * up,
+    act = ACTIVATIONS[cfg.expert_activation]
+    down = _tp_psum(jnp.einsum("bsm,me->bse", act(gate) * up,
                                p["w_down"].astype(cfg.dtype)), cfg)
     if not cfg.shared_expert_gate:
         return down
@@ -1141,14 +1158,39 @@ def _dense_ff(cfg: GPTConfig, spec: LayerSpec, lp, h):
     return _tp_psum(down, cfg)
 
 
-def _expert_ff(cfg: GPTConfig, m, h, router_state=None):
+def _early_router(cfg: GPTConfig, m, x):
+    """``(operand, logits)`` of an expert block's linear router on the
+    stream ``x`` as it enters the block (``router_reads="block_input"``):
+    ``float32(x) W_r`` at the highest precision, before the mixer runs; the
+    operand is ``x`` as it came."""
+    if cfg.router_reads not in ROUTER_READS:
+        raise ValueError(f"router_reads must be one of {ROUTER_READS}, got "
+                         f"{cfg.router_reads!r}")
+    if cfg.router_kind != "linear":
+        raise ValueError(
+            "router_reads='block_input' is a linear router's: an "
+            f"{cfg.router_kind!r} router that reads the block's input is "
+            "not implemented")
+    # The probe's operand is ``x`` in the stream's own type, the values the
+    # checkpoint keeps (``_block`` puts a barrier on it).
+    with jax.named_scope("moe"), jax.named_scope("router_early"):
+        return x, jnp.dot(x.astype(jnp.float32),
+                          m["router"].astype(jnp.float32),
+                          precision=lax.Precision.HIGHEST)
+
+
+def _expert_ff(cfg: GPTConfig, m, h, router_state=None, early=None):
     """``(y, aux, router state)`` of the expert block ``m`` on normed
     activations: the state an MLP router hands to the next expert block
     (``router_state``: what the one before handed to this), None under a
-    linear router."""
+    linear router. ``early``: :func:`_early_router`'s pair, where the
+    router read the block's input and not ``h``."""
     from ..parallel.moe import moe_layer
     router = dict(router_w=m["router"])
-    if cfg.router_kind == "mlp":
+    if early is not None:
+        router = dict(router_w=None, logits=early[1],
+                      router_kind="linear_early")
+    elif cfg.router_kind == "mlp":
         with jax.named_scope("router"):
             logits, state = _mlp_router(cfg, m["router"], h, router_state)
         router = dict(router_w=None, logits=logits, router_kind="mlp",
@@ -1161,7 +1203,14 @@ def _expert_ff(cfg: GPTConfig, m, h, router_state=None):
         first_expert=cfg.first_expert,
         renormalize=cfg.renormalize_experts, score=cfg.router_score,
         bias=m["router_bias"] if cfg.router_bias else None,
-        scale=cfg.route_scale, probe=cfg.router_probe, **router)
+        scale=cfg.route_scale, probe=cfg.router_probe,
+        activation=cfg.expert_activation, **router)
+    if early is not None and cfg.router_probe:
+        # The probe's operand is what the early product read, not ``h``.
+        read = early[0].reshape(-1, early[0].shape[-1])
+        if _axis_bound(cfg.ep_axis):
+            read = lax.all_gather(read, cfg.ep_axis, axis=0, tiled=True)
+        aux = {**aux, "router_input": read}
     if cfg.shared_expert_dim:
         with jax.named_scope("shared"):
             out = out + _shared_expert(cfg, m["shared"], h)
@@ -1206,6 +1255,20 @@ def _block(cfg: GPTConfig, spec: LayerSpec, layer_params, x, positions,
         with jax.named_scope("post_norm"):
             return _norm(cfg, branch, lp[key])
 
+    early = None
+    if spec.ff == "experts" and cfg.router_reads != "ff_input":
+        # A router that reads the block's input gives its outputs here,
+        # before the mixer; they cross it to the expert sublayer below. The
+        # barrier makes the block's input one value: without it XLA gave the
+        # forward router another copy of the stream (the block before's last
+        # sum, fused in and rounded otherwise) than the one the checkpoint
+        # keeps and the recomputed router reads, and on the chip 106 and 164
+        # of 8192 tokens chose other experts in the two passes of two layers
+        # (PERF.md, Findings, PR 53); behind it both passes' outputs agree to
+        # float32 rounding and no choice differs.
+        x = lax.optimization_barrier(x)
+        early = _early_router(cfg, lp["moe"], x)
+
     # A recurrent mixer's scope, its parameters' key and its norm's
     # (``<mixer>_norm``) carry its name; so do a CCA or MLA mixer's key and
     # norm.
@@ -1228,7 +1291,7 @@ def _block(cfg: GPTConfig, spec: LayerSpec, layer_params, x, positions,
         with jax.named_scope("moe"):
             h = before("mlp_norm")
             out, aux, router_state = _expert_ff(cfg, lp["moe"], h,
-                                                router_state)
+                                                router_state, early)
             return _residual(cfg, x, after(out, "mlp_post_norm"),
                              mlp_res), aux, router_state
     with jax.named_scope("mlp"):
@@ -1321,7 +1384,9 @@ def _hidden(params, tokens, positions, cfg: GPTConfig):
     # ``in_proj``, ``conv``, ``scan``, ``gate_norm``, ``out_proj``; a CCA
     # layer's ``attn`` holds ``cca_proj`` and ``cca_mix``, a
     # latent-attention layer's ``mla_proj`` and ``mla_rope``; ``moe``
-    # holds ``router`` (an MLP router whole, its state included),
+    # holds ``router`` (an MLP router whole, its state included; the
+    # product of a router that reads the block's input is under
+    # ``router_early``, before the mixer's scope),
     # ``dispatch``, ``experts``, ``combine`` and
     # ``shared``; ``res_scale`` where the residual is scaled), ``head``;
     # ``loss_and_aux``
@@ -1491,8 +1556,10 @@ def loss_and_aux(params, tokens, targets, positions, cfg: GPTConfig,
     expert blocks, ``load_balance``, ``router_z`` (the sums over blocks) and
     ``counts`` ``[blocks, experts]``, tokens per expert; under
     ``cfg.router_probe`` also ``router_inputs`` ``[blocks, T, d]`` and
-    ``router_logits`` ``[blocks, T, experts]`` (float32), this rank's (an ep
-    group's) tokens as each block's router read them and what it gave.
+    ``router_logits`` ``[blocks, T, experts]`` (float32; the inputs of a
+    router that reads the block's input in the stream's type), this rank's
+    (an ep group's) tokens as each block's router read them and what it
+    gave.
 
     It makes no logits ``[B, S_local, vocab]``: head and loss are one rule
     over blocks of token rows (:func:`_head_loss`, ``head_loss_rows`` rows a

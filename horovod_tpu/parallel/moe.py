@@ -1,4 +1,5 @@
-"""The expert layer: dropless top-k mixture of SiLU-gated experts.
+"""The expert layer: dropless top-k mixture of gated experts (SiLU-gated,
+or ReLU-gated where the caller says ``activation="relu"``).
 
 No reference analog — Horovod ships no expert parallelism; SURVEY.md §2.7 notes
 ``hvd.alltoall`` (``operations.cc:1055-1116``) is the enabling primitive users
@@ -8,10 +9,13 @@ tokens ``h`` ``[T, d]``, router ``W_r`` ``[d, E]`` and experts ``W_gate,e``,
 
     r = h W_r (float32)        p = softmax(r)
     S_t = the k largest of p_t (ties to the lower index)
-    y_t = sum_{e in S_t} p_{t,e} W_down,e( silu(W_gate,e h_t) * (W_up,e h_t) )
+    y_t = sum_{e in S_t} p_{t,e} W_down,e( act(W_gate,e h_t) * (W_up,e h_t) )
 
-The weights ``p_{t,e}`` are not renormalised over ``S_t`` unless
-``renormalize`` asks for ``p_{t,e} / sum_{e' in S_t} p_{t,e'}`` (the
+``act`` is ``silu`` unless ``activation`` names another of
+:data:`ACTIVATIONS` (``"relu"``: ``max(0, .)`` with ``relu'(0) = 0``); it is
+said once and the un-windowed branch, a share's windows and their backward
+rule all take it from there (:func:`_gated`). The weights ``p_{t,e}`` are
+not renormalised over ``S_t`` unless ``renormalize`` asks for ``p_{t,e} / sum_{e' in S_t} p_{t,e'}`` (the
 gradient flows through the sum). With ``score="sigmoid"`` the scores are
 independent gates and the choice may lean on a bias that is no parameter:
 
@@ -24,7 +28,9 @@ the caller moves it from the counts the layer returns, outside the loss,
 under either score; the division is ``renormalize``'s.) A router that is
 more than one matrix (an MLP, a state carried from layer to layer:
 ``models/gpt.py::_mlp_router``) is the caller's: it hands in ``r`` itself
-(``logits``) and the layer takes it from there. Everything after
+(``logits``) and the layer takes it from there; so is a router that reads
+something else than the experts do (the block's input before its mixer:
+``models/gpt.py::_block``, ``router_reads="block_input"``). Everything after
 the choice of experts is one code path for all of them. **No token is
 dropped, whatever the routing**, and every shape is static: the ``T k``
 token-expert pairs are sorted by expert, the tokens' rows gathered once in
@@ -126,6 +132,10 @@ GROUPED_MATMUL = "ragged_dot"
 # favours the rank's experts pays a window more for each even share's twice.
 SHARE_HEADROOM = 2
 ROW_TILE = 512      # the grouped matmul's tile of rows
+# The experts' gate, by the name a caller gives (``moe_layer``'s
+# ``activation``, ``GPTConfig.expert_activation``). ``jax.nn.relu``'s
+# derivative at 0 is 0.
+ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
 
 
 def share_rows(tokens: int, top_k: int, held: int, experts: int) -> int:
@@ -162,6 +172,14 @@ def _grouped(lhs, w, group_sizes, mine):
     leaves unwritten, at zero."""
     out = lax.ragged_dot(lhs, w, group_sizes)
     return out if mine is None else jnp.where(mine, out, 0)
+
+
+def _gated(activation, rows, w_gate, w_up, group_sizes, mine):
+    """``act(rows W_gate) * (rows W_up)`` by groups: the experts' hidden
+    rows, the one place the activation is applied (``activation`` one of
+    :data:`ACTIVATIONS`)."""
+    return (ACTIVATIONS[activation](_grouped(rows, w_gate, group_sizes, mine))
+            * _grouped(rows, w_up, group_sizes, mine))
 
 
 def _down_products_bwd(hidden, w_down, p_rows, g_rows, group_sizes, mine):
@@ -306,9 +324,9 @@ _down_and_combine_window.defvjp(_down_and_combine_window_fwd,
                                 _down_and_combine_window_bwd)
 
 
-@functools.partial(jax.jit, static_argnums=0)
-def _window(window_rows, lo, xt, w_gate, w_up, w_down, top_p, order,
-            group_sizes):
+@functools.partial(jax.jit, static_argnums=(0, 1))
+def _window(window_rows, activation, lo, xt, w_gate, w_up, w_down, top_p,
+            order, group_sizes):
     """What rows ``lo`` to ``lo + window_rows`` of the sort's order add to a
     share's partial sum, ``[T, d]`` float32: the tokens' rows gathered, the
     held experts applied to those of their rows that lie in the window, the
@@ -324,8 +342,7 @@ def _window(window_rows, lo, xt, w_gate, w_up, w_down, top_p, order,
         rows = _take(xt, pair_of_row, top_p.shape[1])                # [R, d]
     with jax.named_scope("experts"):
         rows = jnp.where(mine, rows, 0)
-        hidden = (jax.nn.silu(_grouped(rows, w_gate, sizes, mine))
-                  * _grouped(rows, w_up, sizes, mine))
+        hidden = _gated(activation, rows, w_gate, w_up, sizes, mine)
     return _down_and_combine_window(hidden, w_down, top_p, pair_of_row,
                                     sizes, mine)
 
@@ -357,9 +374,9 @@ def _windows(window_rows, group_sizes, window):
                                scoped(jnp.zeros((), held.dtype))))[1]
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _held_experts(window_rows, xt, w_gate, w_up, w_down, top_p, order,
-                  group_sizes):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _held_experts(window_rows, activation, xt, w_gate, w_up, w_down, top_p,
+                  order, group_sizes):
     """A share's partial sum ``[T, d]`` float32 for pairs sorted with the
     held experts' first (``order``, padded to whole windows), :func:`_window`
     by window of ``window_rows`` rows of the order until every held row is
@@ -371,23 +388,23 @@ def _held_experts(window_rows, xt, w_gate, w_up, w_down, top_p, order,
     the backward rule makes each window's forward again from the rule's
     inputs: nothing a window makes outlives it. Windows after the first add
     their cotangents in the cotangents' own dtypes."""
-    return _held_experts_fwd(window_rows, xt, w_gate, w_up, w_down, top_p,
-                             order, group_sizes)[0]
+    return _held_experts_fwd(window_rows, activation, xt, w_gate, w_up,
+                             w_down, top_p, order, group_sizes)[0]
 
 
-def _held_experts_fwd(window_rows, *args):
+def _held_experts_fwd(window_rows, activation, *args):
     group_sizes = args[-1]
     y = _windows(window_rows, group_sizes,
-                 lambda lo: _window(window_rows, lo, *args))
+                 lambda lo: _window(window_rows, activation, lo, *args))
     return y, args
 
 
-def _held_experts_bwd(window_rows, args, g):
+def _held_experts_bwd(window_rows, activation, args, g):
     *wrt, order, group_sizes = args
 
     def cotangents(lo):
-        return jax.vjp(lambda *a: _window(window_rows, lo, *a, order,
-                                          group_sizes), *wrt)[1](g)
+        return jax.vjp(lambda *a: _window(window_rows, activation, lo, *a,
+                                          order, group_sizes), *wrt)[1](g)
 
     return (*_windows(window_rows, group_sizes, cotangents), None, None)
 
@@ -401,7 +418,8 @@ def moe_layer(x, router_w, w_gate, w_up, w_down, top_k: int,
               renormalize: bool = False, score: str = "softmax",
               bias=None, scale: float = 1.0, probe: bool = False,
               logits=None, router_kind: str = "linear",
-              router_state: bool = False) -> Tuple[jnp.ndarray, dict]:
+              router_state: bool = False,
+              activation: str = "silu") -> Tuple[jnp.ndarray, dict]:
     """Dropless top-``top_k`` expert layer (module docstring has the math).
 
     Args:
@@ -432,6 +450,7 @@ def moe_layer(x, router_w, w_gate, w_up, w_down, top_k: int,
         ``logits`` (its kind; whether it took a state from the layer
         before), for the layer's trace record alone
         (``hvdtpu_spmd_moe_layer_traces_total``).
+      activation: the experts' gate, one of :data:`ACTIVATIONS` (static).
 
     Returns ``(y, aux)``, ``y`` shaped and typed (``dtype``) as the
     activations, and over the tokens routed together (this rank's, or the ep
@@ -439,12 +458,16 @@ def moe_layer(x, router_w, w_gate, w_up, w_down, top_k: int,
     share of tokens whose ``S_t`` holds ``e`` and ``P_e = mean_t p_{t,e}``;
     ``aux["router_z"]`` = ``mean_t logsumexp(r_t)^2``; ``aux["counts"]``
     ``[E]`` int32, tokens per expert; with ``probe``, ``aux["router_input"]``
-    ``[T, d]`` float32 (the router's product's own operand) and
+    ``[T, d]`` float32 (the router's product's own operand; ``x`` where the
+    caller handed in ``logits``, whatever its product read) and
     ``aux["router_logits"]`` ``[T, E]`` float32 (``r``).
     """
     if score not in ("softmax", "sigmoid"):
         raise ValueError(f"expert layer: score {score!r} is neither "
                          "'softmax' nor 'sigmoid'")
+    if activation not in ACTIVATIONS:
+        raise ValueError(f"expert layer: activation {activation!r} is none "
+                         f"of {tuple(ACTIVATIONS)}")
     if (router_w is None) == (logits is None):
         raise ValueError("expert layer: the router's matrix or the router's "
                          "outputs, one of the two")
@@ -480,7 +503,7 @@ def moe_layer(x, router_w, w_gate, w_up, w_down, top_k: int,
         top_k=top_k, ep=_axis_size(axis), grouped_matmul=GROUPED_MATMUL,
         held=experts_local, rows=window_rows, score=score,
         bias=int(bias is not None), router=router_kind,
-        state=int(router_state))
+        state=int(router_state), activation=activation)
 
     with jax.named_scope("router"):
         # The product's own operand: a probe hands out this value and not
@@ -544,16 +567,17 @@ def moe_layer(x, router_w, w_gate, w_up, w_down, top_k: int,
         if not windowed:
             if mine is not None:
                 rows = jnp.where(mine, rows, 0)
-            hidden = (jax.nn.silu(_grouped(rows, w_gate, group_sizes, mine))
-                      * _grouped(rows, w_up, group_sizes, mine))
+            hidden = _gated(activation, rows, w_gate, w_up, group_sizes,
+                            mine)
     if _axis_bound(tp_axis):
         # Each tp rank's share of the weights' gradient is a sum over its
         # part of the width; autodiff adds them where this cast is.
         top_p = pvary(top_p, tp_axis)
     if windowed:
         y = _held_experts(
-            window_rows, xt.astype(dtype), w_gate, w_up, w_down, top_p,
-            jnp.pad(order, (0, -order.shape[0] % window_rows)), group_sizes)
+            window_rows, activation, xt.astype(dtype), w_gate, w_up, w_down,
+            top_p, jnp.pad(order, (0, -order.shape[0] % window_rows)),
+            group_sizes)
     else:
         y = _down_and_combine(hidden, w_down, top_p, order, inv, group_sizes,
                               mine)

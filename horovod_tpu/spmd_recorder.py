@@ -111,10 +111,12 @@ _TRACED = {
         "gathers and multiplies at a time (all of them, or a share's "
         "window), the router's score function, whether a selection "
         "bias leans its choice, the router's kind (linear: the layer's own "
-        "one matrix; mlp: the caller's, its outputs handed in) and whether "
-        "it took a state from the layer before.",
+        "one matrix; mlp: the caller's, its outputs handed in; "
+        "linear_early: the caller's one matrix on the block's input, before "
+        "the mixer), whether it took a state from the layer before, and the "
+        "experts' gate (silu or relu).",
         ("experts", "top_k", "ep", "grouped_matmul", "held", "rows",
-         "score", "bias", "router", "state")),
+         "score", "bias", "router", "state", "activation")),
     "hvdtpu_spmd_cca_traces_total": (
         "Times JAX traced a CCA attention mixer (latent q and k mixed by two "
         "stacked causal convolutions; the recomputed copy of a block counts "

@@ -22,7 +22,10 @@ in bfloat16; for a CCA job the residual scaling or the routers' carried
 state left out, the rotary embedding on the whole head, the MLP router's
 products in one bfloat16 pass, the mix as its plain lines in bfloat16; for a
 latent-attention job the logits scaled for the no-position part of a head
-alone, no rotary embedding), against
+alone, no rotary embedding; for an early-routed job the router fed what the
+experts read, SiLU for ReLU, the rotary embedding on every layer, the six
+weights not renormalised, the early router's product in one bfloat16 pass,
+the parameters rounded to bfloat16), against
 the untouched reference: what a job's tolerances must catch. A variant
 changes the job's ``GPTConfig`` after the job is built, before its step is
 traced (or what the program's modules see, where no field says it); a job
@@ -47,7 +50,8 @@ def _one_pass_dots(module) -> None:
     the TPU one bfloat16 pass of the MXU) where it asks for the highest: it
     sees a ``jax.numpy`` whose ``dot`` takes no notice of ``precision``.
     ``parallel/moe.py``: the expert layer's own router; ``models/gpt.py``:
-    the MLP router (no other line of it calls ``dot``)."""
+    the MLP router and the router that reads the block's input (no other
+    line of it calls ``dot``)."""
     import jax.numpy as jnp
     from jax import lax
 
@@ -72,6 +76,28 @@ def _mlp_router_in_bfloat16() -> None:
     from horovod_tpu.models import gpt
 
     _one_pass_dots(gpt)
+
+
+def _parameters_in_bfloat16(job) -> None:
+    """The state held in bfloat16: the step reads parameters rounded to it
+    and its updated parameters are rounded to it again (an update smaller
+    than a parameter's last bit is lost), where the configuration states
+    float32 master parameters."""
+    import jax
+    import jax.numpy as jnp
+
+    real = job._step_with_aux
+
+    def rounded(tree):
+        return jax.tree.map(
+            lambda p: p.astype(jnp.bfloat16).astype(p.dtype), tree)
+
+    def step(params, opt_state, data):
+        (params, opt_state, loss), aux = real(rounded(params), opt_state,
+                                              data)
+        return (rounded(params), opt_state, loss), aux
+
+    job._step_with_aux = step
 
 
 def _norms_before(job) -> None:
@@ -183,6 +209,13 @@ VARIANTS = {
         job, attention_multiplier=job.cfg.head_dim ** -0.5),
     "mla_no_rope": lambda job: _replace(job, layers=tuple(
         dataclasses.replace(spec, rope=False) for spec in job.cfg.plan)),
+    "router_after_mixer": lambda job: _replace(job, router_reads="ff_input"),
+    "silu": lambda job: _replace(job, expert_activation="silu"),
+    "rope_everywhere": lambda job: _replace(job, layers=tuple(
+        dataclasses.replace(spec, rope=True) for spec in job.cfg.plan)),
+    "no_renormalize": lambda job: _replace(job, renormalize_experts=False),
+    "router_early_bf16": lambda job: _mlp_router_in_bfloat16(),
+    "params_bf16": _parameters_in_bfloat16,
 }
 
 
@@ -224,6 +257,7 @@ def main() -> int:
     hvd.init()
     jobs = importlib.import_module(f"benchmarks.jobs.{config['job']}")
     worst: dict = {}
+    correct = 0
     os.makedirs(os.path.join(ROOT, "chiprun_out"), exist_ok=True)
     with open(os.path.join(ROOT, "chiprun_out", "check_sweep.jsonl"),
               "a") as out:
@@ -243,6 +277,13 @@ def main() -> int:
                 line[what] = {"program": got, "reference": want, "rel": err,
                               "allowed": rtol}
                 worst[what] = max(worst.get(what, 0.0), err)
+            # ``run.py``'s own rule on these rows: off by no more than the
+            # limit, each of them.
+            line["missed"] = [what for what, row in line.items()
+                              if isinstance(row, dict)
+                              and not row["rel"] <= row["allowed"]]
+            line["correct"] = not line["missed"]
+            correct += line["correct"]
             counts = getattr(job, "expert_counts", None)
             if counts is not None:
                 line["busiest_over_mean"] = float(
@@ -256,7 +297,8 @@ def main() -> int:
             for array in jax.live_arrays():
                 array.delete()
         last = {"workload": args.workload, "seeds": args.seeds,
-                "variant": args.variant, "largest_rel": worst}
+                "variant": args.variant, "largest_rel": worst,
+                "seeds_correct": correct}
         print(json.dumps(last), flush=True)
         out.write(json.dumps(last) + "\n")
     hvd.shutdown()

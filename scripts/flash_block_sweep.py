@@ -10,6 +10,7 @@ of microseconds). Also holds the compiled kernels to dense attention once.
 
     chiprun --chips 1 -- python scripts/flash_block_sweep.py [--quick]
     chiprun --chips 1 -- python scripts/flash_block_sweep.py --bwd [cell ...]
+    chiprun --chips 1 -- python scripts/flash_block_sweep.py --dense-long
 
 ``--bwd`` times the backward pass alone at a benchmark cell's shape
 (``BWD_SHAPES``: Moonlight's two widths and Trinity's window among them), as
@@ -17,6 +18,12 @@ the one kernel that makes dQ, dK and dV and as the pair it replaced (dKdV,
 dQ and the lane-replicated statistics XLA makes for dQ), over three tiles,
 and holds the one kernel's gradients to the pair's there (PERF.md, Findings,
 PR 52).
+
+``--dense-long`` holds the forward kernel and the one backward kernel to
+``default_attention`` at the deepest shape a cell runs them at
+(``LONG_SHAPE``: 16,384 rows at 28:4 heads of 128, under the causal mask and
+under a 4096-key band), the dense side one query head at a time in float32
+(PERF.md, Findings, PR 53).
 
 One JSON object a line on stdout and in ``chiprun_out/flash_sweep.jsonl``.
 Works on a tree that still has the fixed-tile kernels (``--parent``): there
@@ -60,6 +67,10 @@ BWD_SHAPES = {
     "olmoe-1b-7b_s4096": (2, 4096, 16, 16, 128, 128, None),
 }
 BWD_TILES = ((1024, 1024), (512, 1024), (1024, 512))
+# ``smallthinker-21b-a3b_s16384``'s attention: (B, S, H, Hkv, D), the full
+# layer's mask and the window layers'.
+LONG_SHAPE = (1, 16384, 28, 4, 128)
+LONG_WINDOWS = (None, 4096)
 OUT = os.path.join("chiprun_out", "flash_sweep.jsonl")
 KERNELS = {"fwd": fa.KERNEL_FWD, "dkdv": fa.KERNEL_DKDV, "dq": fa.KERNEL_DQ}
 
@@ -251,6 +262,70 @@ def check_against_dense(cases=DENSE_CASES):
              max_abs=dict(zip(("loss", "dq", "dk", "dv"), scale)))
 
 
+def check_long_against_dense(shape=LONG_SHAPE, windows=LONG_WINDOWS,
+                             dtype=jnp.bfloat16):
+    """The compiled forward and (one) backward kernel through
+    ``flash_attention`` at ``shape`` against ``default_attention`` on the
+    same bfloat16 operands in float32 at the highest precision. An ``S x S``
+    float32 matrix a head is 1 GiB at 16,384 rows, so the dense side is made
+    one query head at a time and a K/V head's gradient summed over its
+    group. Errors are largest differences beside the dense side's largest
+    value; the kernels round their probabilities to bfloat16 for the MXU,
+    so 2**-8 of a value is the size to expect."""
+    from horovod_tpu.ops.attention import default_attention
+    b, s, h, hkv, d = shape
+    group = h // hkv
+    ks = jax.random.split(jax.random.PRNGKey(s), 4)
+    q = jax.random.normal(ks[0], (b, s, h, d), dtype) * 0.5
+    k = jax.random.normal(ks[1], (b, s, hkv, d), dtype) * 0.5
+    v = jax.random.normal(ks[2], (b, s, hkv, d), dtype) * 0.5
+    w = jax.random.normal(ks[3], q.shape, jnp.float32)
+    for window in windows:
+        tile = fa.block_sizes(fa.KERNEL_DKDV, s, d, dtype, True)
+        flash = jax.jit(jax.value_and_grad(
+            lambda q, k, v: jnp.sum(fa.flash_attention(
+                q, k, v, causal=True, window=window).astype(jnp.float32)
+                * w), argnums=(0, 1, 2)))
+
+        @jax.jit
+        def dense_head(q1, k1, v1, w1):
+            """One query head: its output weighed and summed, dq, and its
+            part of dk and dv."""
+            def loss(q1, k1, v1):
+                with jax.default_matmul_precision("highest"):
+                    return jnp.sum(default_attention(
+                        q1, k1, v1, causal=True, window=window) * w1)
+            return jax.value_and_grad(loss, argnums=(0, 1, 2))(
+                *(x.astype(jnp.float32) for x in (q1, k1, v1)))
+
+        got = jax.device_get(flash(q, k, v))
+        want_loss = 0.0
+        dq = np.zeros(q.shape, np.float32)
+        dk, dv = (np.zeros(k.shape, np.float32) for _ in range(2))
+        for head in range(h):
+            kv = slice(head // group, head // group + 1)
+            val, (gq, gk, gv) = dense_head(q[:, :, head:head + 1], k[:, :, kv],
+                                           v[:, :, kv], w[:, :, head:head + 1])
+            want_loss += float(val)
+            dq[:, :, head:head + 1] = np.asarray(gq)
+            dk[:, :, kv] += np.asarray(gk)
+            dv[:, :, kv] += np.asarray(gv)
+        names = ("dq", "dk", "dv")
+        emit(check="dense_long", shape=list(shape), window=window,
+             dtype=jnp.dtype(dtype).name, tile=list(tile),
+             backward_is_fused=fa.backward_is_fused(*tile, s, d, dtype, d),
+             loss=[float(got[0]), want_loss],
+             max_abs_err={n: float(np.max(np.abs(
+                 np.asarray(a, np.float32) - b_)))
+                 for n, a, b_ in zip(names, got[1], (dq, dk, dv))},
+             # A norm of the difference over the dense side's norm.
+             rel_l2_err={n: float(np.linalg.norm(
+                 np.asarray(a, np.float32) - b_) / np.linalg.norm(b_))
+                 for n, a, b_ in zip(names, got[1], (dq, dk, dv))},
+             max_abs={n: float(np.max(np.abs(b_)))
+                      for n, b_ in zip(names, (dq, dk, dv))})
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--parent", action="store_true",
@@ -260,6 +335,10 @@ def main():
     ap.add_argument("--bwd", nargs="*", metavar="CELL", default=None,
                     help="the backward pass alone, one kernel beside the "
                          "pair, at these cells' shapes (none named: all)")
+    ap.add_argument("--dense-long", action="store_true",
+                    help="the forward and the one backward kernel against "
+                         "dense attention at 16,384 rows, 28:4 heads, "
+                         "causal and under a 4096 band, and nothing else")
     args = ap.parse_args()
     dev = jax.devices()[0]
     emit(platform=dev.platform, device_kind=dev.device_kind)
@@ -271,6 +350,9 @@ def main():
     if args.parent:
         for name, shape in SHAPES.items():
             time_parent(name, shape, bf16)
+        return
+    if args.dense_long:
+        check_long_against_dense()
         return
     check_against_dense()
     if args.bwd is not None:

@@ -276,6 +276,8 @@ FUSED_CASES = [
     (4, 192, 128, "bidirectional_padded", F32),
     (12, 32, 32, "causal", BF16), (12, 192, 128, "window", F32),
     (12, 192, 128, "bidirectional_padded", BF16),
+    # 28:4 heads of 128: a group that is no power of two.
+    (7, 128, 128, "causal", BF16), (7, 128, 128, "window", F32),
 ]
 
 
@@ -313,6 +315,21 @@ def test_fused_backward_matches_dense(group, dk, dv, mode, dtype):
         np.testing.assert_allclose(g, r, rtol=0, atol=tol,
                                    err_msg=f"d{name}")
         assert np.abs(g - e).max() <= np.abs(r - e).max() + tol, name
+
+
+@pytest.mark.parametrize("mode", ["causal", "window"])
+def test_a_group_of_seven_matches_dense_forward(mode):
+    """28:4 heads of 128 (seven query heads a K/V head) under the causal
+    mask and under a band, two sequences: the forward kernel's outputs."""
+    causal, window, s = MODES[mode]
+    q, k, v = _qkv_two_widths(s, 28, 4, 128, 128, seed=7)
+    q, k, v = (jnp.concatenate([x, 0.5 * x[:, ::-1]]) for x in (q, k, v))
+    got = flash_attention(q, k, v, causal=causal, window=window,
+                          _blocks=(128, 128))
+    want = default_attention(q, repeat_kv_heads(k, 28),
+                             repeat_kv_heads(v, 28), causal=causal,
+                             window=window)
+    np.testing.assert_allclose(got, want, rtol=5e-4, atol=5e-5)
 
 
 @pytest.mark.parametrize("mode, blocks, dtype", [

@@ -69,19 +69,29 @@ SHAPES = {
                                 True),
 }
 # The backward pass as one kernel: besides those, 2 sequences at 32:4 heads
-# with and without the window of 2048, and 4 sequences at 8:2 heads.
+# with and without the window of 2048, 4 sequences at 8:2 heads, and one
+# sequence of 16,384 tokens at 28:4 heads (a group of 7) with and without the
+# window of 4096: the deepest shape ``backward_is_fused`` admits at heads of
+# 128 (the whole sequence's dQ in VMEM).
+LONGEST = {
+    "smallthinker-21b-a3b_s16384": (28, 4, 16384, 128, jnp.bfloat16, True),
+    "smallthinker-21b-a3b_s16384_window": (28, 4, 16384, 128, jnp.bfloat16,
+                                           True, 4096),
+}
 FUSED_SHAPES = {
     **SHAPES,
     "trinity-mini_s8192": (64, 8, 8192, 128, jnp.bfloat16, True),
     "trinity-mini_s8192_window": (64, 8, 8192, 128, jnp.bfloat16, True,
                                   2048),
     "zaya1-8b_s4096": (32, 8, 4096, 128, jnp.bfloat16, True),
+    **LONGEST,
 }
 
 
 @pytest.mark.parametrize("shape, kernel", [
     *((shape, kernel) for shape in SHAPES
       for kernel in ("fwd", "dkdv", "dq")),
+    *((shape, "fwd") for shape in LONGEST),
     *((shape, "fused") for shape in FUSED_SHAPES)])
 def test_kernel_compiles_for_v5e(one_chip, mosaic, shape, kernel):
     bh, bkv, s, d, dtype, causal, *window = FUSED_SHAPES[shape]

@@ -1003,3 +1003,145 @@ def test_metrics_name_the_routers_kind(make_runtime):
     assert sample_value(
         hvd.metrics(), "hvdtpu_spmd_moe_layer_traces_total", experts=str(E),
         top_k="2", score="sigmoid", bias="1") == 1.0
+
+
+# --- ReLU-gated experts and a router whose outputs the caller made ---------
+# (SmallThinker's layer: the block's input gives the outputs, before
+# attention; the layer takes them as ``logits``.)
+
+from benchmarks.reference import gpt_prerouted_moe_dp as prerouted_reference  # noqa: E402,E501
+
+
+def _prerouted(seed, routing="under", first=4):
+    """``(what the experts read, the router's outputs made elsewhere, the
+    block)``: the outputs are from other activations than the experts
+    read."""
+    logits, _, *w = routed_inputs(seed, routing, first)
+    h = jax.random.normal(jax.random.PRNGKey(seed + 100), (T, D))
+    return h, 8 * logits, dict(zip(("w_gate", "w_up", "w_down"), w))
+
+
+def _matrices(block, first=0, held=E):
+    return tuple(block[k][first:first + held]
+                 for k in ("w_gate", "w_up", "w_down"))
+
+
+def _relu_layer(h, logits, block, top_k, first=0, held=E, **kw):
+    return moe_layer(
+        h, None, *_matrices(block, first, held), top_k=top_k,
+        dtype=jnp.float32, first_expert=first, renormalize=True,
+        logits=logits, activation="relu", **kw)
+
+
+@pytest.mark.parametrize("remat", ["none", "full"])
+@pytest.mark.parametrize("first,held,routing", [
+    (0, E, "under"), (4, 4, "under"), (4, 4, "over")],
+    ids=["un-windowed", "one-window", "two-windows"])
+def test_relu_experts_match_the_dense_sum(small_tile, first, held, routing,
+                                          remat):
+    """``activation="relu"`` on all the rows at once and on a share's
+    windows (one, and two through the loop's body and the backward rule's)
+    against every held expert on every token: the output and the gradients
+    of the tokens, of the caller's outputs and of the three expert
+    tensors."""
+    top_k = 4
+    h, logits, block = _prerouted(41, routing, first)
+    share = {k: v[first:first + held] for k, v in block.items()}
+
+    kw = dict(top_k=top_k, dtype=jnp.float32, first_expert=first,
+              renormalize=True)
+
+    def got(h, logits, share):
+        y, aux = moe_layer(h, None, *_matrices(share), logits=logits,
+                           activation="relu", **kw)
+        return jnp.sum(y * jnp.cos(y)), (y, aux["counts"])
+
+    def want(h, logits, share):
+        y, counts = prerouted_reference.expert_block(h, logits, share, top_k,
+                                                     first)
+        return jnp.sum(y * jnp.cos(y)), (y, counts)
+
+    (_, (y, counts)), grads = jax.value_and_grad(
+        as_a_block_runs_it(got, remat), argnums=(0, 1, 2), has_aux=True)(
+            h, logits, share)
+    (_, (y_ref, counts_ref)), grads_ref = jax.value_and_grad(
+        want, argnums=(0, 1, 2), has_aux=True)(h, logits, share)
+    np.testing.assert_allclose(y, y_ref, rtol=1e-5, atol=1e-5)
+    np.testing.assert_array_equal(counts, np.asarray(counts_ref, np.int32))
+    if held < E:
+        assert_rows_held(counts, routing, first)
+    jax.tree.map(lambda g, r: np.testing.assert_allclose(
+        g, r, rtol=1e-5, atol=1e-5 * float(jnp.abs(r).max())),
+        grads, grads_ref)
+    # SiLU in its place is another layer.
+    silu = moe_layer(h, None, *_matrices(share), logits=logits, **kw)[0]
+    assert not np.allclose(silu, y_ref, rtol=1e-2, atol=1e-2)
+
+
+@pytest.mark.parametrize("routing", ROUTINGS)
+def test_four_relu_shares_of_a_callers_router_add_up_to_the_uncut_layer(
+        small_tile, routing):
+    """SmallThinker's cut: four ranks hold four of the 16 experts each,
+    every rank is handed the same router outputs (made from the block's
+    input) and weighs its experts by the softmax over all the chosen, held
+    or not; the ranks' partial sums add up to the uncut reference's layer,
+    as do the gradients of the tokens and of the outputs."""
+    top_k = 4
+    h, logits, block = _prerouted(43, routing)
+    weigh = jnp.cos(jnp.arange(T * D, dtype=jnp.float32)).reshape(T, D)
+
+    def whole(h, logits):
+        y, counts = prerouted_reference.expert_block(h, logits, block, top_k)
+        return jnp.sum(y * weigh), (y, counts)
+
+    (_, (y_ref, counts_ref)), g_ref = jax.value_and_grad(
+        whole, argnums=(0, 1), has_aux=True)(h, logits)
+    total, grads = 0.0, None
+    for first in SHARES:
+        def share(h, logits):
+            y, aux = _relu_layer(h, logits, block, top_k, first, 4)
+            return jnp.sum(y * weigh), (y, aux["counts"])
+
+        (_, (y, counts)), g = jax.value_and_grad(
+            share, argnums=(0, 1), has_aux=True)(h, logits)
+        np.testing.assert_array_equal(counts,
+                                      np.asarray(counts_ref, np.int32))
+        total = total + y
+        grads = g if grads is None else jax.tree.map(jnp.add, grads, g)
+    np.testing.assert_allclose(total, y_ref, rtol=1e-5, atol=1e-5)
+    for got, want in zip(grads, g_ref):
+        np.testing.assert_allclose(
+            got, want, rtol=1e-5, atol=1e-5 * float(jnp.abs(want).max()))
+
+
+def test_relus_derivative_at_nought_is_nought():
+    """A gate product of exactly 0 passes no gradient, in the layer as in
+    the reference (``jnp.maximum``'s would pass a half)."""
+    h, logits, block = _prerouted(45)
+    block = dict(block, w_gate=jnp.zeros_like(block["w_gate"]))
+
+    def layer(w_gate):
+        return jnp.sum(_relu_layer(h, logits, dict(block, w_gate=w_gate),
+                                   2)[0])
+
+    def plain(w_gate):
+        return jnp.sum(prerouted_reference.expert_block(
+            h, logits, dict(block, w_gate=w_gate), 2)[0])
+
+    assert not np.any(np.asarray(jax.grad(layer)(block["w_gate"])))
+    assert not np.any(np.asarray(jax.grad(plain)(block["w_gate"])))
+
+
+def test_an_unknown_activation_raises_and_the_record_names_the_gate(
+        make_runtime):
+    make_runtime(devices=jax.devices()[:1])
+    h, logits, block = _prerouted(47)
+    with pytest.raises(ValueError, match="activation 'gelu' is none of"):
+        moe_layer(h, None, *_matrices(block), top_k=2, logits=logits,
+                  activation="gelu")
+    aux = jax.jit(lambda: _relu_layer(h, logits, block, 2, probe=True)[1])()
+    np.testing.assert_array_equal(aux["router_logits"],
+                                  logits.astype(jnp.float32))
+    assert sample_value(
+        hvd.metrics(), "hvdtpu_spmd_moe_layer_traces_total", experts=str(E),
+        top_k="2", activation="relu") == 1.0
