@@ -187,12 +187,14 @@ class GPTConfig:
     # activation; "full" stores a block's input and what is dear to make
     # again (``SAVED_NAMES``: the flash kernel's output and log-sum-exp, the
     # dense feed-forward's pre-activation, the expert layer's matrices in
-    # the compute dtype, a state-space scan's output, each branch's output
-    # under a norm after the branch or a learned residual scale) and
+    # the compute dtype and what fixes its routing, a state-space scan's
+    # output, each branch's output under a norm after the branch or a
+    # learned residual scale) and
     # recomputes the rest in backward: in bfloat16
     # 2E + 2HD + 4H + 2M bytes a token a layer where the input alone is 2E
-    # (an expert block: no 2M, and 6 bytes an expert parameter a layer; 4E
-    # more where the branches' outputs are kept);
+    # (an expert block: no 2M, 6 bytes an expert parameter a layer and
+    # 4 experts + 16 experts_per_token bytes a token; 4E more where the
+    # branches' outputs are kept);
     # "dots" instead saves every matmul output (recompute only the cheap
     # elementwise work).
     remat: str = "none"                      # "none" | "full" | "dots"
@@ -1328,16 +1330,27 @@ def _block(cfg: GPTConfig, spec: LayerSpec, layer_params, x, positions,
 # ``trinity-mini_s8192`` +8.5% for 10 tensors of 64 MiB of which the step's
 # peak shows 0.10 GiB, ``olmo-hybrid-7b_s8192`` +3.9% for 8 of 60 MiB and
 # 0.23 GiB, ``zaya1-8b_s4096`` +4.6% for 12 of 64 MiB and 0.80 GiB). A block
-# that produces none of a name keeps nothing under it. Norms, rotary,
+# that produces none of a name keeps nothing under it. And what fixes an
+# expert layer's routing (``parallel/moe.py``, PR 54): the router's outputs
+# ``[T, E]`` float32 (the layer's own product, an MLP router's or an early
+# router's alike), a token's chosen experts and their scores ``[T, k]``, the
+# sort's order and, un-windowed, its inverse ``[T k]``: 4 E + 12 k to 16 k
+# bytes a token a layer (33.5 + 2.0 MB a layer in the Qwen cell, the
+# dearest; 0.5 MB in ZAYA1's), so the backward pass makes no router's
+# product, no full-row sort and no argsort again and differentiates the
+# routing the forward pass used, whatever a router made again would have
+# chosen (on the chip not always the same: PERF.md, Findings, PR 53 and
+# PR 54). Norms, rotary,
 # projections (a recurrent mixer's input projection too), the convolution,
-# the scans' decays and chunk states, the router, the experts' sorted rows,
-# gate and up products and activation stay recomputed: the expert layer
-# names nothing that lies in the sort's order, which the backward pass makes
-# again and which one near-tie in the recomputed router shifts
-# (``parallel/moe.py``'s docstring; PERF.md, Findings, PR 28).
+# the scans' decays and chunk states, an MLP router's hidden rows, the
+# experts' sorted rows, gate and up products and activation stay recomputed.
+# Nothing that lies in the sort's order is named yet; such rows may be from
+# now on only because the order is kept with them (``parallel/moe.py``'s
+# docstring; PERF.md, Findings, PR 28).
 SAVED_NAMES = ("flash_out", "flash_lse", "ffn_pre_activation",
                "moe_expert_matrices", "ssm_scan_out", "gdn_scan_out",
-               "branch_out")
+               "branch_out", "moe_router_logits", "moe_top_experts",
+               "moe_top_weights", "moe_order", "moe_order_inverse")
 _save_names = jax.checkpoint_policies.save_only_these_names(*SAVED_NAMES)
 
 

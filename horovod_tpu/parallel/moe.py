@@ -82,32 +82,54 @@ Gradients: the choice ``S_t`` is not differentiable; the router learns
 through the weights ``p_{t,e}`` and through the two auxiliary terms returned
 beside ``y`` (the load-balance term's token fractions are constants).
 
-Under ``jax.checkpoint`` (``models/gpt.py``, ``remat="full"``) the layer makes
-again, for its backward pass, the router, the sort, the sorted rows, and the
-gate and up products with their activation, and nothing else, **so long as
-the caller's backward pass has no use for the layer's output**. The down
+Under ``jax.checkpoint`` (``models/gpt.py``, ``remat="full"``) **the routing
+is made once a step.** What fixes it carries names the policy keeps
+(``checkpoint_name``, all five in ``gpt.SAVED_NAMES``): the router's outputs
+``r`` (``"moe_router_logits"``, ``[T, E]`` float32, the layer's own product
+or the caller's ``logits``, after the gather under a bound ``axis``; from
+``r`` the softmax, its rule, ``router_z`` and ``load_balance`` are a pass
+over ``[T, E]`` and need no product, from the scores ``router_z`` would want
+``r`` again), a token's chosen experts and their scores before
+renormalisation (``"moe_top_experts"``, ``"moe_top_weights"``, ``[T, k]``),
+the sort's order and, where the layer works on all the rows at once, its
+inverse (``"moe_order"``, ``"moe_order_inverse"``, ``[T k]`` integers): 4 E +
+12 k to 16 k bytes a token a layer. The choice is made on
+``stop_gradient(p)`` and the weights are read at the *named* indices
+(:func:`_scores_at`): ``lax.top_k``'s own differentiation rule keeps the
+primitive's index output, which is not the named value, and the full-row
+sort would be made again for it. So the backward pass holds no router's
+product (``dW_r`` is the recomputed norm's rows times the kept outputs'
+cotangent; a caller whose router is an MLP still makes that again, for its
+own rule), no top-k and no argsort, and ``counts``, ``group_sizes``, every
+window's ``pair_of_row``, ``sizes`` and ``mine`` derive from kept values:
+**the two passes agree on every row by construction**, on every backend.
+(Before PR 54 the backward pass made the router again, on the chip not
+always to the forward's choices, since XLA is free to round its input
+otherwise in the two passes: 106 and 164 of 8192 tokens in two layers,
+PERF.md, Findings, PR 53; and when one near-tie falls the other way every
+row behind it in the sort moves by one.)
+
+What the layer still makes again for its backward pass: the sorted rows, and
+the gate and up products with their activation, and nothing else, **so long
+as the caller's backward pass has no use for the layer's output**. The down
 projection and the weighted sum have a backward pass of their own
 (:func:`_down_and_combine`) that needs no expert's output, so their
 recomputation is dead code (a share's windows make theirs again inside the
-backward rule, from the recomputed sort, and are dead code in the
-recomputed copy altogether) where the caller only adds ``y`` to its stream.
+backward rule, from the kept order, and are dead code in the recomputed copy
+altogether) where the caller only adds ``y`` to its stream.
 A caller that norms ``y`` or scales it by a parameter reads ``y`` in its own
 backward pass, and then the recomputed copy runs the layer to its end, the
 down product, the sum back to tokens and every window of a share, unless
 the caller keeps ``y``: ``models/gpt.py::_block`` does, under the name
 ``"branch_out"`` (``[tokens, d]`` in token order, not the sort's), exactly
 where its block has such a norm or scale. The three expert tensors in the
-compute dtype
-carry a name a checkpoint policy can keep (``checkpoint_name``:
-``"moe_expert_matrices"``, 6 bytes an expert parameter in bfloat16, in
-``gpt.SAVED_NAMES``), so the cast is made once. **Nothing whose rows lie in
-the sort's order is named**: the backward pass makes the router again, on
-the chip not always to the forward's choices (XLA is free to round its input
-otherwise in the two passes), and when one near-tie falls the other way every
-row behind it in the sort moves by one. A buffer kept in the forward's order
-and read in the recomputed one gives gradients that are wrong by their own
-size (PERF.md, Findings, PR 28; the top-k's two outputs kept with it hold the
-order still).
+compute dtype carry a name too (``"moe_expert_matrices"``, 6 bytes an expert
+parameter in bfloat16), so the cast is made once. **Nothing whose rows lie
+in the sort's order is named yet** (the sorted rows, the two
+pre-activations: PERF.md, Findings, PR 28, +4.9% in one cell for 0.9 GiB); a
+buffer kept in the forward's order and read in another gives gradients that
+are wrong by their own size, and such a name is safe from now on only
+because the order it lies in is kept with it.
 """
 
 from __future__ import annotations
@@ -164,6 +186,16 @@ def _permute_bwd(inv, g):
 
 
 _permute.defvjp(_permute_fwd, _permute_bwd)
+
+
+def _scores_at(probs, top_e):
+    """``probs[t, top_e[t, j]]``, ``[T, k]``: the chosen experts' scores,
+    picked out by a one-hot product (exact; XLA's gather of ``[T, k]`` from
+    ``[T, E]`` and its scatter back took 1.4 ms a layer a pass on the v5e,
+    my chip run, PR 35). Its gradient is ``lax.top_k``'s own: the cotangent
+    put back at the chosen columns."""
+    return jnp.sum(jax.nn.one_hot(top_e, probs.shape[-1], dtype=probs.dtype)
+                   * probs[:, None, :], axis=-1)
 
 
 def _grouped(lhs, w, group_sizes, mine):
@@ -513,19 +545,19 @@ def moe_layer(x, router_w, w_gate, w_up, w_down, top_k: int,
         if logits is None:
             logits = jnp.dot(router_in, router_w.astype(jnp.float32),
                              precision=lax.Precision.HIGHEST)        # [T, E]
+        logits = checkpoint_name(logits, "moe_router_logits")
         probs = jax.nn.softmax(logits, axis=-1) if score == "softmax" \
             else jax.nn.sigmoid(logits)
-        if bias is None:
-            top_p, top_e = lax.top_k(probs, top_k)                   # [T, k]
-        else:
-            # The bias leans the choice and is in nothing else: the weights
-            # are the scores' own, picked out by a one-hot product (exact;
-            # XLA's gather of [T, k] from [T, E] and its scatter back took
-            # 1.4 ms a layer a pass on the v5e, my chip run, PR 35).
-            top_e = lax.top_k(probs + lax.stop_gradient(bias), top_k)[1]
-            top_p = jnp.sum(jax.nn.one_hot(top_e, num_experts,
-                                           dtype=probs.dtype)
-                            * probs[:, None, :], axis=-1)
+        # The choice is made outside the differentiated path and kept by
+        # name, and the weights are the scores' own at the *named* indices:
+        # ``lax.top_k``'s own rule would keep its index output, which is not
+        # the named value, and a checkpointed block would sort every row
+        # again for it. The bias leans the choice and is in nothing else.
+        leaning = probs if bias is None else probs + bias
+        top_e = checkpoint_name(
+            lax.top_k(lax.stop_gradient(leaning), top_k)[1],
+            "moe_top_experts")                                       # [T, k]
+        top_p = checkpoint_name(_scores_at(probs, top_e), "moe_top_weights")
         if renormalize:
             total = jnp.sum(top_p, axis=-1, keepdims=True)
             # Sigmoid scores can all be nothing; a softmax's k largest not.
@@ -554,9 +586,10 @@ def moe_layer(x, router_w, w_gate, w_up, w_down, top_k: int,
             if not windowed:
                 mine = (jnp.arange(T * top_k)
                         < jnp.sum(group_sizes))[:, None]
-        order = jnp.argsort(expert_of_pair, stable=True)
+        order = checkpoint_name(jnp.argsort(expert_of_pair, stable=True),
+                                "moe_order")
         if not windowed:
-            inv = jnp.argsort(order)
+            inv = checkpoint_name(jnp.argsort(order), "moe_order_inverse")
             rows = _permute(jnp.repeat(xt.astype(dtype), top_k, axis=0),
                             order, inv)                              # [Tk, d]
 

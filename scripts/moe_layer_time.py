@@ -15,7 +15,10 @@ the two row permutations alone; and the layer against the every-expert-on-
 every-token reference on 1024 tokens. One JSON line a row, also appended to
 ``chiprun_out/moe_layer_time.jsonl``. ``--skew`` routes every token to the
 first ``top_k`` experts (the router's columns made equal: ties go to the
-lower index).
+lower index). ``--routing`` times the checkpointed layer alone, by what of
+its routing the checkpoint keeps (``routing_rows``: the price of the names
+PR 54 added to ``SAVED_NAMES`` and of the form the chosen scores are read
+in; two minutes a shape).
 
 A rank's share (``--router-width`` wider than ``--experts``; the
 ``qwen3-next-80b-a3b_s4096`` cell's is ``--router-width 512 --experts 32
@@ -120,6 +123,44 @@ def to_tokens_rows(h, router, first, held, top_k, rows) -> dict:
     return out
 
 
+def emit(line: str) -> int:
+    """The row printed and appended to ``chiprun_out/moe_layer_time.jsonl``."""
+    print(line, flush=True)
+    os.makedirs(os.path.join(ROOT, "chiprun_out"), exist_ok=True)
+    with open(os.path.join(ROOT, "chiprun_out", "moe_layer_time.jsonl"),
+              "a") as f:
+        f.write(line + "\n")
+    return 0
+
+
+def routing_rows(checkpointed, h, weights) -> dict:
+    """ms of the checkpointed layer's forward and backward by what of its
+    routing the checkpoint keeps: nothing of it (the router's product, the
+    full-row sort, the argsort made again: the step before PR 54), the
+    router's outputs alone, all that ``gpt.SAVED_NAMES`` lists, and the last
+    with the chosen scores read by ``take_along_axis`` in place of
+    ``moe._scores_at``'s one-hot product (the form not chosen). Each row
+    twice, in turn."""
+    routing = {name for name in gpt.SAVED_NAMES
+               if name.startswith(("moe_router", "moe_top", "moe_order"))}
+    others = [name for name in gpt.SAVED_NAMES if name not in routing]
+    rows = {"routing_made_again": checkpointed(*others),
+            "router_logits_kept": checkpointed(*others, "moe_router_logits"),
+            "SAVED_NAMES": checkpointed(*gpt.SAVED_NAMES)}
+    out = {name: [timed(f, h, *weights)] for name, f in rows.items()}
+    one_hot, moe._scores_at = moe._scores_at, lambda probs, top_e: \
+        jnp.take_along_axis(probs, top_e, axis=-1)
+    try:
+        rows["SAVED_NAMES_scores_by_gather"] = checkpointed(*gpt.SAVED_NAMES)
+        out["SAVED_NAMES_scores_by_gather"] = [timed(
+            rows["SAVED_NAMES_scores_by_gather"], h, *weights)]
+    finally:
+        moe._scores_at = one_hot
+    for name, f in rows.items():
+        out[name].append(timed(f, h, *weights))
+    return out
+
+
 def main() -> int:
     parser = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     parser.add_argument("--tokens", type=int, default=8192)
@@ -137,6 +178,9 @@ def main() -> int:
                         help="a share on all T k rows, as before PR 32 "
                         "(the headroom raised until a window is every row)")
     parser.add_argument("--trace", action="store_true")
+    parser.add_argument("--routing", action="store_true",
+                        help="only the checkpointed layer, by what of its "
+                        "routing the checkpoint keeps (routing_rows)")
     args = parser.parse_args()
     T, d, m, E, k = (args.tokens, args.embed, args.width, args.experts,
                      args.top_k)
@@ -194,13 +238,23 @@ def main() -> int:
         """The layer's forward and backward with these names kept, jitted.
         The value is asked for with the gradients: without it nothing needs
         the first forward pass and XLA drops it."""
+        # (A function of its own each time: JAX keeps a checkpointed
+        # function's trace by the function.)
         kept = jax.checkpoint(
-            layer, policy=jax.checkpoint_policies.save_only_these_names(
-                *names))
+            lambda *a: layer(*a),
+            policy=jax.checkpoint_policies.save_only_these_names(*names))
         return jax.jit(jax.value_and_grad(
             kept, argnums=(0, 1, 2, 3, 4), has_aux=True))
 
     as_a_block_runs_it = checkpointed(*gpt.SAVED_NAMES)
+    if args.routing:
+        line = json.dumps({
+            "tokens": T, "embed": d, "width": m, "experts": E, "top_k": k,
+            "router_width": wide, "rows": R, "skew": args.skew,
+            "device_kind": device.device_kind,
+            "layer_checkpointed_fwd_bwd_ms": routing_rows(
+                checkpointed, h, weights)})
+        return emit(line)
     out = {"tokens": T, "embed": d, "width": m, "experts": E, "top_k": k,
            "router_width": wide, "first_expert": first, "rows": R,
            "rows_held": rows_held, "windows": -(-rows_held // R),
@@ -255,13 +309,7 @@ def main() -> int:
             trace_reduce.first_device(trace_reduce.read_xplane(path, {})),
             scope_reduce.program_names(path))
         out["windows_traced"] = sum(taken.values()) / calls
-    line = json.dumps(out)
-    print(line, flush=True)
-    os.makedirs(os.path.join(ROOT, "chiprun_out"), exist_ok=True)
-    with open(os.path.join(ROOT, "chiprun_out", "moe_layer_time.jsonl"),
-              "a") as f:
-        f.write(line + "\n")
-    return 0
+    return emit(json.dumps(out))
 
 
 if __name__ == "__main__":

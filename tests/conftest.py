@@ -135,3 +135,22 @@ def moe_row_tile(monkeypatch):
     yield _set
     if emptied:
         jax.clear_caches()
+
+
+@pytest.fixture
+def equations_of():
+    """``equations_of(jaxpr)`` yields ``(equation, under a checkpoint's
+    equation?)`` for every equation of ``jaxpr`` and of the jaxprs inside
+    it: what a backward pass makes again is what lies under
+    ``jax.checkpoint``'s primitive (printed as ``checkpoint``, named
+    ``remat2``)."""
+    import jax
+
+    def walk(jaxpr, inside=False):
+        for eqn in jaxpr.eqns:
+            yield eqn, inside
+            within = inside or eqn.primitive.name in ("remat2", "checkpoint")
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from walk(sub, within)
+
+    return walk

@@ -271,5 +271,17 @@ def test_full_remat_keeps_the_gdn_scans_output(make_runtime):
     kept = {labels["name"]: value for _, labels, value in family["samples"]
             if labels["mode"] == "full"}
     experts = 3 * cfg.experts_held * cfg.embed_dim * cfg.mlp_dim * 4
-    assert kept == {"gdn_scan_out": 3 * 48 * cfg.gdn_value_inner * 4,
-                    "moe_expert_matrices": experts}
+    tokens, pairs = 3 * 48, 3 * 48 * cfg.experts_per_token
+    # What fixes the routing beside them; a share's windows need no inverse
+    # of the order (whose indices are 8 bytes under this suite's x64).
+    windowed = moe_module.share_rows(tokens, cfg.experts_per_token,
+                                     cfg.experts_held, cfg.num_experts) < pairs
+    index = jnp.argsort(jnp.zeros(1)).dtype.itemsize
+    assert kept == {"gdn_scan_out": tokens * cfg.gdn_value_inner * 4,
+                    "moe_expert_matrices": experts,
+                    "moe_router_logits": tokens * cfg.num_experts * 4,
+                    "moe_top_experts": pairs * 4,
+                    "moe_top_weights": pairs * 4,
+                    "moe_order": pairs * index,
+                    **({} if windowed else
+                       {"moe_order_inverse": pairs * index})}

@@ -196,10 +196,14 @@ def test_the_early_router_has_a_scope_in_every_pass_and_a_trace_record(
     params, data = seeded(cfg), batch(cfg)
     text = jax.jit(jax.grad(lambda p: gpt.loss_fn(p, *data, cfg))).lower(
         params).as_text(debug_info=True)
-    for scope in ("layer0)/moe/router_early", "layer3)/moe/router_early",
-                  "rematted_computation/moe/router_early",
-                  "layer0)/attn/", "layer1)/attn_window/"):
+    for scope in ("jvp(layer0)/moe/router_early",
+                  "jvp(layer3)/moe/router_early",
+                  "transpose(jvp(layer0))/jvp(layer0)/checkpoint/moe/"
+                  "router_early", "layer0)/attn/", "layer1)/attn_window/"):
         assert scope in text, scope
+    # Forward and backward: the block keeps the router's outputs
+    # (``moe_router_logits``) and no pass makes the product again.
+    assert "rematted_computation/moe/router_early" not in text
     # The product is made before the mixer, outside its scope.
     assert "attn/moe/router_early" not in text
     assert "attn_window/moe/router_early" not in text

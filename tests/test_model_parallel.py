@@ -83,6 +83,54 @@ def test_moe_layer_expert_parallel_matches_local(make_runtime, moe_row_tile,
                                    atol=1e-5 * float(jnp.abs(w).max()))
 
 
+@pytest.mark.parametrize("tile", [512, 8])
+def test_a_bound_ep_axis_keeps_the_groups_routing(make_runtime, moe_row_tile,
+                                                  equations_of, tile):
+    """Under a bound ``ep`` axis the checkpointed layer names what fixes the
+    routing after the gather: the policy keeps the router's outputs, the
+    chosen experts and scores and the sort's order of the group's 32 tokens
+    (each rank routes all of them), with the inverse where a rank works on
+    all the rows at once, and the part the backward pass makes again holds
+    no sort, no top-k and no router's product."""
+    moe_row_tile(tile)
+    make_runtime(mesh_shape={"ep": 4}, devices=jax.devices()[:4])
+    d, m, n_exp, top_k, tokens = 12, 32, 8, 2, 32
+    ks = jax.random.split(jax.random.PRNGKey(2), 5)
+    args = (jax.random.normal(ks[0], (4, 8, d), jnp.float32),
+            jax.random.normal(ks[1], (d, n_exp), jnp.float32),
+            jax.random.normal(ks[4], (n_exp, d, m), jnp.float32) / 4,
+            jax.random.normal(ks[2], (n_exp, d, m), jnp.float32) / 4,
+            jax.random.normal(ks[3], (n_exp, m, d), jnp.float32) / 6)
+
+    def f(x, *w):
+        y, aux = moe_layer(x, *w, top_k=top_k, axis="ep", dtype=jnp.float32)
+        return jax.lax.psum(jnp.sum(y * jnp.cos(y)), "ep") \
+            + aux["load_balance"] + aux["router_z"]
+
+    experts = P("ep")
+    specs = (P("ep"), P(), experts, experts, experts)
+    jaxpr = jax.make_jaxpr(jax.shard_map(
+        jax.grad(_checkpointed(f), argnums=(0, 1, 2, 3, 4)), mesh=hvd.mesh(),
+        in_specs=specs, out_specs=specs))(*args).jaxpr
+
+    assert [eqn.primitive.name for eqn, made_again in equations_of(jaxpr)
+            if made_again and (
+                eqn.primitive.name in ("sort", "top_k")
+                or eqn.primitive.name == "dot_general"
+                and eqn.outvars[0].aval.shape == (tokens, n_exp))] == []
+    kept = {labels["name"]: value for _, labels, value in hvd.metrics()[
+        "hvdtpu_spmd_remat_saved_bytes_total"]["samples"]}
+    index = jnp.argsort(jnp.zeros(1)).dtype.itemsize    # 8 under x64
+    want = {"moe_router_logits": tokens * n_exp * 4,
+            "moe_top_experts": tokens * top_k * 4,
+            "moe_top_weights": tokens * top_k * 4,
+            "moe_order": tokens * top_k * index,
+            "moe_expert_matrices": 3 * (n_exp // 4) * d * m * 4}
+    if tile == 512:
+        want["moe_order_inverse"] = tokens * top_k * index
+    assert kept == want
+
+
 @pytest.mark.parametrize("remat", ["none", "full"])
 def test_moe_layer_tensor_parallel_expert_width(make_runtime, remat):
     """ep=2 x tp=2: the experts over ep, their width over tp. The output and

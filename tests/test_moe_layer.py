@@ -363,27 +363,44 @@ def test_metrics_count_what_a_checkpointed_block_keeps(make_runtime, block):
     trace(sparse)
     assert hvd.metrics()[family]["samples"] == []
 
-    trace(dataclasses.replace(sparse, remat="full"))
+    def kept():
+        return {labels["name"]: value for _, labels, value
+                in hvd.metrics()[family]["samples"]
+                if labels["mode"] == "full"}
+
+    # A dense block keeps nothing under the expert layer's names.
     trace(dataclasses.replace(sparse, remat="full", moe_every=0))
+    assert not [name for name in kept() if name.startswith("moe_")]
+    trace(dataclasses.replace(sparse, remat="full"))
     fams = hvd.metrics()
     assert fams[family]["type"] == "counter"
-    tokens, f32 = batch * seq, 4
+    tokens, f32, i32 = batch * seq, 4, 4
+    pairs = tokens * sparse.experts_per_token
     heads = sparse.num_heads * sparse.head_dim
+    # (``jnp.argsort``'s indices are as wide as the process's integers: this
+    # suite runs with ``jax_enable_x64``, a job does not.)
+    index = jnp.argsort(jnp.zeros(1)).dtype.itemsize
     # Two blocks split, a flash pair each; the dense block's up projection;
     # the expert block's three matrices an expert (float32 here: the cast
-    # that carries the name is to the compute dtype).
+    # that carries the name is to the compute dtype) and what fixes its
+    # routing: the router's outputs, a token's chosen experts and their
+    # scores, the sort's order and its inverse (every expert is held).
     want = {"flash_out": 2 * tokens * heads * f32,
             "flash_lse": 2 * tokens * sparse.num_heads * f32,
             "ffn_pre_activation": tokens * sparse.mlp_dim * f32,
             "moe_expert_matrices": (3 * sparse.num_experts * sparse.embed_dim
-                                    * sparse.mlp_dim * f32)}
+                                    * sparse.mlp_dim * f32),
+            "moe_router_logits": tokens * sparse.num_experts * f32,
+            "moe_top_experts": pairs * i32,
+            "moe_top_weights": pairs * f32,
+            "moe_order": pairs * index,
+            "moe_order_inverse": pairs * index}
     if block != "adds":
         # Two branches a block, where the block's backward pass reads them.
         want["branch_out"] = 2 * 2 * tokens * sparse.embed_dim * f32
     # Neither block has a recurrent mixer: nothing is kept under their names
     # (tests/test_gpt_hybrid.py and test_gpt_linear_moe.py count those).
-    assert {labels["name"]: value for _, labels, value
-            in fams[family]["samples"] if labels["mode"] == "full"} == want
+    assert kept() == want
 
 
 def test_dense_decoder_has_no_auxiliary_terms():
@@ -1145,3 +1162,163 @@ def test_an_unknown_activation_raises_and_the_record_names_the_gate(
     assert sample_value(
         hvd.metrics(), "hvdtpu_spmd_moe_layer_traces_total", experts=str(E),
         top_k="2", activation="relu") == 1.0
+
+
+# The routing is made once a step (PR 54). A checkpointed block keeps what
+# fixes it: the router's outputs, the chosen experts and their scores, the
+# sort's order (``gpt.SAVED_NAMES``'s ``moe_router_logits``,
+# ``moe_top_experts``, ``moe_top_weights``, ``moe_order``,
+# ``moe_order_inverse``), and its backward pass reads them.
+
+ROUTING_CASES = [(rows, router) for rows in ("all_rows", "windowed")
+                 for router in ("softmax", "sigmoid_bias", "callers_logits")]
+ROUTED_WIDTH = 20           # no tensor's other extent: a product's shape tells
+
+
+def routed_once(rows, router, small_tile_of):
+    """``(f, args, tie, leaning)``: a layer of 16 experts at 3 a token on 48 tokens
+    of 20, every expert held or experts 4 to 8 with a window of half their
+    even share's twice (72 of 144 rows); the router the layer's own softmax,
+    a sigmoid under a bias, or the caller's product. ``f(x, W_r, W_gate,
+    W_up, W_down)`` is a loss over the output and both auxiliary terms.
+
+    The router's columns come in pairs a constant vector ``v`` apart (and a
+    pair's biases are equal), so a token's two scores of a pair differ by
+    ``x_t . v``, which ``args`` hold at ``+-1e-3``: with three chosen, the
+    better pair whole and the better of the next, **every token's third
+    choice is a near-tie**, and ``x + tie`` turns every one the other way
+    (``tie`` = ``-+2e-3 v / |v|^2`` a token; ``leaning(x)`` ``[T, E]`` is
+    what the choice is made on, up to a rising function)."""
+    top_k, d = 3, ROUTED_WIDTH
+    first, held = (4, 4) if rows == "windowed" else (0, E)
+    if rows == "windowed":
+        small_tile_of(8)
+        assert moe.share_rows(T, top_k, held, E) == 72 < T * top_k
+    ks = jax.random.split(jax.random.PRNGKey(54), 7)
+    v = jax.random.normal(ks[0], (d,), jnp.float32)
+    pairs = jax.random.normal(ks[1], (d, E // 2), jnp.float32)
+    router_w = jnp.stack([pairs, pairs + v[:, None]], axis=-1).reshape(d, E)
+    bias = jnp.repeat(0.2 * jax.random.normal(ks[2], (E // 2,), jnp.float32), 2)
+    x = jax.random.normal(ks[3], (T, d), jnp.float32)
+    gap = jnp.where(jnp.arange(T) % 2 == 0, 1e-3, -1e-3).astype(jnp.float32)
+    x = x + ((gap - x @ v) / (v @ v))[:, None] * v
+    tie = (-2 * gap / (v @ v))[:, None] * v
+    w = (jax.random.normal(ks[4], (held, d, M), jnp.float32) / 4,
+         jax.random.normal(ks[5], (held, d, M), jnp.float32) / 4,
+         jax.random.normal(ks[6], (held, M, d), jnp.float32) / 5)
+    how = dict(top_k=top_k, dtype=jnp.float32, first_expert=first,
+               renormalize=True)
+    if router == "sigmoid_bias":
+        how.update(score="sigmoid", bias=bias, scale=1.5)
+
+    def f(x, router_w, *w):
+        if router == "callers_logits":
+            y, aux = moe_layer(
+                x, None, *w, **how, logits=jnp.dot(
+                    x.astype(jnp.float32), router_w,
+                    precision=jax.lax.Precision.HIGHEST))
+        else:
+            y, aux = moe_layer(x, router_w, *w, **how)
+        return jnp.sum(y * jnp.cos(y)) + aux["load_balance"] \
+            + aux["router_z"]
+
+    def leaning(x):
+        r = x @ router_w
+        return jax.nn.sigmoid(r) + bias if router == "sigmoid_bias" else r
+
+    return f, (x, router_w, *w), tie, leaning
+
+
+def routing_work(equations, rematted=False):
+    """How many sorts, top-k choices and router's products (``[T, d] x [d,
+    E]``) a jaxpr's ``equations`` (``conftest.py::equations_of``) hold, all
+    of them or those under its checkpoint equations (what a backward pass
+    makes again) alone."""
+    work = collections.Counter()
+    for eqn, inside in equations:
+        if rematted and not inside:
+            continue
+        if eqn.primitive.name in ("sort", "top_k"):
+            work[eqn.primitive.name] += 1
+        if eqn.primitive.name == "dot_general" and [
+                v.aval.shape for v in eqn.invars] == [(T, ROUTED_WIDTH),
+                                                      (ROUTED_WIDTH, E)]:
+            work["router_product"] += 1
+    return dict(work)
+
+
+@pytest.mark.parametrize("rows, router", ROUTING_CASES)
+def test_a_checkpointed_layer_routes_once(moe_row_tile, equations_of, rows,
+                                          router):
+    """The gradient's jaxpr under ``remat="full"``'s policy holds the sorts,
+    the top-k and the router's product the forward pass alone needs (one
+    argsort a share's windows, two with the inverse; no rule of the backward
+    pass sorts or chooses), and none of them in the part the backward pass
+    makes again."""
+    f, args, _, _ = routed_once(rows, router, moe_row_tile)
+    forward = routing_work(equations_of(jax.make_jaxpr(f)(*args).jaxpr))
+    assert forward == {"sort": 1 if rows == "windowed" else 2, "top_k": 1,
+                       "router_product": 1}
+    wrt = tuple(range(5))
+    kept = jax.make_jaxpr(jax.grad(as_a_block_runs_it(
+        lambda *a: f(*a), "full"), argnums=wrt))(*args).jaxpr
+    assert routing_work(equations_of(kept)) == forward
+    assert routing_work(equations_of(kept), rematted=True) == {}
+    # The census sees a routing that is made again: with nothing named.
+    nothing = jax.make_jaxpr(jax.grad(jax.checkpoint(
+        lambda *a: f(*a)), argnums=wrt))(*args).jaxpr
+    assert routing_work(equations_of(nothing), rematted=True) == forward
+
+
+@pytest.mark.parametrize("rows, router", ROUTING_CASES)
+def test_kept_routing_changes_no_gradient(moe_row_tile, rows, router):
+    """Tokens, router and the three expert tensors: checkpointed with the
+    routing kept, the gradients are the plain layer's."""
+    f, args, _, _ = routed_once(rows, router, moe_row_tile)
+    wrt = tuple(range(5))
+    loss, grads = jax.value_and_grad(as_a_block_runs_it(
+        lambda *a: f(*a), "full"), argnums=wrt)(*args)
+    want_loss, want = jax.value_and_grad(f, argnums=wrt)(*args)
+    np.testing.assert_allclose(loss, want_loss, rtol=1e-6)
+    for name, g, g_ref in zip(("x", "W_r", "W_gate", "W_up", "W_down"),
+                              grads, want):
+        assert float(jnp.abs(g_ref).max()) > 0, name
+        np.testing.assert_allclose(
+            g, g_ref, rtol=1e-5, atol=1e-6 * float(jnp.abs(g_ref).max()),
+            err_msg=name)
+
+
+@pytest.mark.parametrize("rows, router", ROUTING_CASES)
+def test_the_two_passes_cannot_choose_otherwise(moe_row_tile, rows, router):
+    """The backward pass of a checkpointed layer whose recomputation reads
+    tokens a hair off the forward's (what XLA's other rounding of the
+    router's input did on the chip, PERF.md, Findings, PR 53; here the kept
+    input is moved in the pullback's residuals, by as much as turns every
+    token's near-tie) gives the forward's gradients: it differentiates the
+    forward's routing, whatever a router made again would choose. Before
+    PR 54 every token's third expert changed and the gradients were off by
+    their own size."""
+    f, args, tie, leaning = routed_once(rows, router, moe_row_tile)
+    x = args[0]
+    # The tie does turn the choice, to the other of one pair (for every
+    # token but the few whose pair's sigmoids both round to one).
+    third, turned = (np.asarray(jnp.argsort(-leaning(t), axis=-1)[:, 2])
+                     for t in (x, x + tie))
+    assert (third != turned).mean() > 0.9
+    assert (third // 2 == turned // 2).all()
+
+    kept = as_a_block_runs_it(lambda *a: f(*a), "full")
+    _, pullback = jax.vjp(kept, *args)
+    want = pullback(jnp.ones((), jnp.float32))
+    leaves, tree = jax.tree_util.tree_flatten(pullback)
+    is_x = [getattr(leaf, "shape", None) == x.shape
+            and bool((leaf == x).all()) for leaf in leaves]
+    assert sum(is_x) >= 1
+    moved = jax.tree_util.tree_unflatten(tree, [
+        leaf + tie if hit else leaf for leaf, hit in zip(leaves, is_x)])
+    got = moved(jnp.ones((), jnp.float32))
+    for name, g, g_ref in zip(("x", "W_r", "W_gate", "W_up", "W_down"),
+                              got, want):
+        np.testing.assert_allclose(
+            g, g_ref, rtol=0, atol=5e-3 * float(jnp.abs(g_ref).max()),
+            err_msg=name)
