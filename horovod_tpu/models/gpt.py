@@ -70,13 +70,24 @@ state leaves a block beside ``x`` and enters the next. A linear router
 reads what the experts read or, under ``router_reads="block_input"``, the
 stream as it enters the block, un-normed, before the mixer
 (:func:`_early_router`); the experts' gate is SiLU or, under
-``expert_activation="relu"``, ReLU. Under
+``expert_activation="relu"``, ReLU, or under ``"relu2"`` the experts (a
+shared one too) are un-gated, two matrices and a squared ReLU. With
+``moe_latent_dim`` the routed experts run in a latent narrower than the
+stream (:func:`_expert_ff`: one down-projection of the normed stream
+before the dispatch, under the scope ``moe/latent_down``, one up-projection
+of the weighted sum after the combine, ``moe/latent_up``; the router and a
+shared expert read the stream). A state-space mixer's gated norm runs over
+each of ``ssm_groups`` groups' channels, so a group of its heads is a
+smaller mixer whose parameters are slices of the whole's. Under
 ``residual_scaling`` a sublayer joins the stream as ``a_r (x + b_r) + a_h
 (f(N(x)) + b_h)``, four learned vectors a sublayer (:func:`_residual`).
 
 **What each layer is, is said once**: ``GPTConfig.plan``, one
 :class:`LayerSpec` a layer (the mixer, an attention layer's window and
-whether the rotary embedding applies to it, the feed-forward's kind), either
+whether the rotary embedding applies to it, the feed-forward's kind; **either
+sublayer may be absent**, ``mixer=None`` or ``ff=None``: the block is then
+the other one alone with its one norm, as in a stack that alternates
+mixers and feed-forwards block by block), either
 given outright (``GPTConfig.layers``: dense layers before expert layers,
 window attention beside full) or resolved from ``layer_kinds``, ``moe_every``
 and ``gated_mlp`` in :func:`layer_plan` and nowhere else. ``init_params``,
@@ -128,11 +139,20 @@ class LayerSpec:
     embedding applies (``GPTConfig.rope_theta``, ``rotary_dim``), and its
     feed-forward (one of ``FEED_FORWARDS``: two matrices and a GELU, three
     and a SiLU gate, or the expert block with what ``GPTConfig`` says of
-    experts)."""
-    mixer: str = "attention"
+    experts). **Either sublayer may be None**: the block is then the other
+    one alone, ``x + f(N(x))`` with one norm (a stack whose blocks are a
+    mixer or a feed-forward each); a layer with neither is refused
+    (:func:`layer_plan`). :attr:`sublayers` says which a block has, and
+    the parameters, the specs and ``_block`` read it there."""
+    mixer: Optional[str] = "attention"
     window: Optional[int] = None
     rope: bool = True
-    ff: str = "dense"
+    ff: Optional[str] = "dense"
+
+    @property
+    def sublayers(self) -> Tuple[bool, bool]:
+        """``(a mixer, a feed-forward)``: which sublayers the block has."""
+        return self.mixer is not None, self.ff is not None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -303,9 +323,15 @@ class GPTConfig:
     # the mixer runs (``_block``: the product under the scope
     # ``moe/router_early``; a linear router alone).
     router_reads: str = "ff_input"
-    # The experts' gate, one of ``parallel/moe.py::ACTIVATIONS``: "silu" or
-    # "relu". A shared expert takes the same.
+    # The experts' form, one of ``parallel/moe.py::ACTIVATIONS``: gated by
+    # "silu" or "relu" (three matrices an expert) or the un-gated squared
+    # ReLU "relu2" (two, no ``w_gate``). A shared expert takes the same.
     expert_activation: str = "silu"
+    # The routed experts run in a latent of this width (0: at the stream's):
+    # the router reads the normed stream, ``u = h W_down_latent`` is what the
+    # experts read, and their weighted sum goes through ``W_up_latent`` back
+    # to the stream (``_expert_ff``); a shared expert stays on the stream.
+    moe_latent_dim: int = 0
     # A sublayer joins the residual stream as a_r (x + b_r) + a_h (f + b_h):
     # four learned vectors of embed_dim a sublayer, ones and zeros at
     # initialisation (``_residual``).
@@ -383,14 +409,17 @@ def layer_plan(cfg: GPTConfig) -> Tuple[LayerSpec, ...]:
                          "layer_kinds and moe_every unset beside it")
     plan = tuple(cfg.layers)
     if len(plan) != cfg.num_layers or any(
-            not isinstance(spec, LayerSpec) or spec.mixer not in MIXERS
-            or spec.ff not in FEED_FORWARDS
+            not isinstance(spec, LayerSpec)
+            or spec.mixer not in MIXERS + (None,)
+            or spec.ff not in FEED_FORWARDS + (None,)
+            or not any(spec.sublayers)
             or (spec.window is not None
                 and (spec.mixer != "attention" or spec.window < 1))
             for spec in plan):
         raise ValueError(
             f"layers must hold a LayerSpec (mixer one of {MIXERS}, "
-            f"feed-forward one of {FEED_FORWARDS}, a window of at least one "
+            f"feed-forward one of {FEED_FORWARDS}, either of them None but "
+            f"not both, a window of at least one "
             f"key on attention alone: a CCA layer has none yet, nor an MLA "
             f"layer) for each of the {cfg.num_layers} layers, got {plan!r}")
     return plan
@@ -558,6 +587,11 @@ _RESIDUAL_NAMES = ("stream_scale", "stream_bias", "branch_scale",
 _RESIDUAL_KEYS = ("mixer_res", "mlp_res")
 
 
+def _residual_keys(spec: "LayerSpec") -> list:
+    """The keys of a layer's residual scalings: one a sublayer it has."""
+    return [key for key, has in zip(_RESIDUAL_KEYS, spec.sublayers) if has]
+
+
 def _routers_with_carry(cfg: GPTConfig) -> list:
     """For each layer, whether its expert block's router takes a state: an
     MLP router's does from the expert block before it, so every one but
@@ -573,10 +607,23 @@ def _routers_with_carry(cfg: GPTConfig) -> list:
 def _norm_names(spec: LayerSpec, before: bool, after: bool) -> list:
     """The keys of a layer's norms over the residual stream: before the
     mixer (the key carries the mixer's name: ``attn_norm``, ``ssm_norm``,
-    ``gdn_norm``) and the feed-forward, after each."""
+    ``gdn_norm``) and the feed-forward, after each; of a sublayer the layer
+    has not, none."""
     mixer = "attn" if spec.mixer == "attention" else spec.mixer
-    return ([mixer + "_norm", "mlp_norm"] if before else []) \
+    names = ([f"{mixer}_norm", "mlp_norm"] if before else []) \
         + (["mixer_post_norm", "mlp_post_norm"] if after else [])
+    return [name for name, has in zip(names, spec.sublayers * 2) if has]
+
+
+def _experts_gated(cfg: GPTConfig) -> bool:
+    """Whether an expert (a shared one too) has a gate matrix: every form
+    of ``parallel/moe.py::ACTIVATIONS`` but the un-gated ones."""
+    from ..parallel.moe import ACTIVATIONS, UNGATED
+    if cfg.expert_activation not in ACTIVATIONS:
+        raise ValueError(f"expert_activation must be one of "
+                         f"{tuple(ACTIVATIONS)}, got "
+                         f"{cfg.expert_activation!r}")
+    return cfg.expert_activation not in UNGATED
 
 
 def _held(cfg: GPTConfig) -> int:
@@ -624,7 +671,9 @@ def init_params(rng, cfg: GPTConfig) -> dict:
         params["lm_head"] = dense(keys[1], (E, cfg.vocab_size), E)
     for i, spec in enumerate(plan):
         ks = jax.random.split(keys[2 + i], 8)
-        if spec.mixer == "ssm":
+        if spec.mixer is None:
+            layer = {}
+        elif spec.mixer == "ssm":
             layer = {"ssm": _init_ssm(ks[0], cfg, dense)}
         elif spec.mixer == "gdn":
             layer = {"gdn": _init_gdn(ks[0], cfg, dense)}
@@ -649,21 +698,30 @@ def init_params(rng, cfg: GPTConfig) -> dict:
         for name in _norm_names(spec, before, after):
             layer[name] = norm((E,))
         if cfg.residual_scaling:
-            for name in _RESIDUAL_KEYS:
+            for name in _residual_keys(spec):
                 layer[name] = {
                     part: (jnp.ones if part.endswith("scale")
                            else jnp.zeros)((E,), jnp.float32)
                     for part in _RESIDUAL_NAMES}
         if spec.ff == "experts":
             n_exp, held, Mx = cfg.num_experts, _held(cfg), cfg.expert_width
+            # The experts' width in and out: the latent's, else the stream's.
+            L = cfg.moe_latent_dim or E
             layer["moe"] = {
                 "router": dense(ks[4], (E, n_exp), E)
                 if cfg.router_kind == "linear"
                 else _init_mlp_router(ks[4], cfg, dense, carries[i]),
-                "w_gate": dense(ks[7], (held, E, Mx), E),
-                "w_up": dense(ks[5], (held, E, Mx), E),
-                "w_down": dense(ks[6], (held, Mx, E), Mx),
+                "w_up": dense(ks[5], (held, L, Mx), L),
+                "w_down": dense(ks[6], (held, Mx, L), Mx),
             }
+            # Two matrices an expert in an un-gated form, the shared one too.
+            gated = _experts_gated(cfg)
+            if gated:
+                layer["moe"]["w_gate"] = dense(ks[7], (held, L, Mx), L)
+            if cfg.moe_latent_dim:
+                lk = jax.random.split(jax.random.fold_in(ks[4], 2), 2)
+                layer["moe"]["latent_down"] = dense(lk[0], (E, L), E)
+                layer["moe"]["latent_up"] = dense(lk[1], (L, E), L)
             if cfg.router_bias:
                 layer["moe"]["router_bias"] = jnp.zeros((n_exp,),
                                                         jnp.float32)
@@ -671,13 +729,15 @@ def init_params(rng, cfg: GPTConfig) -> dict:
                 sk = jax.random.split(jax.random.fold_in(ks[4], 1), 4)
                 Ms = cfg.shared_expert_dim
                 layer["moe"]["shared"] = {
-                    "w_gate": dense(sk[0], (E, Ms), E),
                     "w_up": dense(sk[1], (E, Ms), E),
                     "w_down": dense(sk[2], (Ms, E), Ms),
                 }
+                if gated:
+                    layer["moe"]["shared"]["w_gate"] = dense(sk[0], (E, Ms),
+                                                             E)
                 if cfg.shared_expert_gate:
                     layer["moe"]["shared"]["gate"] = dense(sk[3], (E,), E)
-        else:
+        elif spec.ff is not None:
             if spec.ff == "gated":
                 layer["w_gate"] = dense(ks[7], (E, M), E)
             layer["w_up"] = dense(ks[5], (E, M), E)
@@ -704,7 +764,9 @@ def param_specs(cfg: GPTConfig) -> dict:
         specs["lm_head"] = P()
     before, after = norm_placement(cfg)
     for spec, carry in zip(cfg.plan, _routers_with_carry(cfg)):
-        if spec.mixer == "ssm":
+        if spec.mixer is None:
+            layer = {}
+        elif spec.mixer == "ssm":
             layer = {"ssm": {
                 name: P() for name in (
                     "in_proj", "conv_w", "conv_b", "dt_bias", "A_log", "D",
@@ -736,26 +798,32 @@ def param_specs(cfg: GPTConfig) -> dict:
         for name in _norm_names(spec, before, after):
             layer[name] = P()
         if cfg.residual_scaling:
-            for name in _RESIDUAL_KEYS:
+            for name in _residual_keys(spec):
                 layer[name] = {part: P() for part in _RESIDUAL_NAMES}
         if spec.ff == "experts":
             _held(cfg)
             layer["moe"] = {
                 "router": P() if cfg.router_kind == "linear"
                 else {name: P() for name in _mlp_router_names(carry)},
-                "w_gate": P(ep, None, tp),
                 "w_up": P(ep, None, tp),
                 "w_down": P(ep, tp, None),
             }
+            gated = _experts_gated(cfg)
+            if gated:
+                layer["moe"]["w_gate"] = P(ep, None, tp)
+            if cfg.moe_latent_dim:
+                layer["moe"]["latent_down"] = P()
+                layer["moe"]["latent_up"] = P()
             if cfg.router_bias:
                 layer["moe"]["router_bias"] = P()
             if cfg.shared_expert_dim:
-                layer["moe"]["shared"] = {
-                    "w_gate": P(None, tp), "w_up": P(None, tp),
-                    "w_down": P(tp, None)}
+                layer["moe"]["shared"] = {"w_up": P(None, tp),
+                                          "w_down": P(tp, None)}
+                if gated:
+                    layer["moe"]["shared"]["w_gate"] = P(None, tp)
                 if cfg.shared_expert_gate:
                     layer["moe"]["shared"]["gate"] = P()
-        else:
+        elif spec.ff is not None:
             if spec.ff == "gated":
                 layer["w_gate"] = P(None, tp)
             layer["w_up"] = P(None, tp)
@@ -863,9 +931,11 @@ def _ssm_mixer(cfg: GPTConfig, p, h):
     | dt] = h W_in``; ``xBC`` through the causal depthwise convolution and
     SiLU, split into ``x``, ``B``, ``C``; ``dt = softplus(dt + dt_bias)``,
     ``A = -exp(A_log)``, both float32; the chunked scan; ``RMSNorm(y *
-    silu(z))`` over the whole inner width; ``W_out``. The scan starts every
-    sequence a rank holds from a zero state and the norm runs over the heads
-    it holds, so a bound sp or tp axis is refused by name."""
+    silu(z))`` with the mean square taken over each of ``ssm_groups``
+    groups' channels (one group: the whole inner width), so that a group of
+    heads is a mixer of its own up to ``W_out``'s sum; ``W_out``. The scan
+    starts every sequence a rank holds from a zero state and the norm runs
+    over the heads it holds, so a bound sp or tp axis is refused by name."""
     _refuse_bound_axes(cfg, "state-space")
     batch, seq = h.shape[:2]
     heads, inner = cfg.ssm_heads, cfg.ssm_inner
@@ -892,7 +962,12 @@ def _ssm_mixer(cfg: GPTConfig, p, h):
     with jax.named_scope("gate_norm"):
         gated = y.reshape(batch, seq, inner).astype(jnp.float32) \
             * jax.nn.silu(z.astype(jnp.float32))
-        y = _rmsnorm(gated, p["norm"], cfg.dtype, cfg.norm_eps)
+        # One group is the whole inner width as it lies: these reshapes are
+        # then no operation (granite's program stays as it was).
+        by_group = (groups, inner // groups) if groups > 1 else (inner,)
+        y = _rmsnorm(gated.reshape(batch, seq, *by_group),
+                     p["norm"].reshape(by_group), cfg.dtype,
+                     cfg.norm_eps).reshape(batch, seq, inner)
     with jax.named_scope("out_proj"):
         return jnp.einsum("bsf,fe->bse", y, p["out_proj"].astype(cfg.dtype))
 
@@ -955,14 +1030,14 @@ def _gdn_mixer(cfg: GPTConfig, p, h):
 
 
 def _shared_expert(cfg: GPTConfig, p, h):
-    """The expert every token goes through: ``W_down (act(W_gate h) * W_up
-    h)`` (``act`` the routed experts', ``cfg.expert_activation``), under
-    ``sigmoid(<h, w_g>)`` where the configuration gates it."""
-    from ..parallel.moe import ACTIVATIONS
-    gate = jnp.einsum("bse,em->bsm", h, p["w_gate"].astype(cfg.dtype))
-    up = jnp.einsum("bse,em->bsm", h, p["w_up"].astype(cfg.dtype))
-    act = ACTIVATIONS[cfg.expert_activation]
-    down = _tp_psum(jnp.einsum("bsm,me->bse", act(gate) * up,
+    """The expert every token goes through, in the routed experts' form
+    (``cfg.expert_activation``, ``parallel/moe.py::expert_hidden``):
+    ``W_down (act(W_gate h) * W_up h)`` or, un-gated, ``W_down act(W_up
+    h)``; under ``sigmoid(<h, w_g>)`` where the configuration gates it."""
+    from ..parallel.moe import expert_hidden
+    hidden = expert_hidden(cfg.expert_activation, lambda name: jnp.einsum(
+        "bse,em->bsm", h, p[name].astype(cfg.dtype)))
+    down = _tp_psum(jnp.einsum("bsm,me->bse", hidden,
                                p["w_down"].astype(cfg.dtype)), cfg)
     if not cfg.shared_expert_gate:
         return down
@@ -1189,17 +1264,23 @@ def _expert_ff(cfg: GPTConfig, m, h, router_state=None, early=None):
     router read the block's input and not ``h``."""
     from ..parallel.moe import moe_layer
     router = dict(router_w=m["router"])
+    if cfg.moe_latent_dim:
+        # The experts' operand, apart from the router's: made again in the
+        # backward pass, one [T, E] x [E, L] product (nothing names it).
+        with jax.named_scope("latent_down"):
+            router["expert_in"] = jnp.einsum(
+                "bse,el->bsl", h, m["latent_down"].astype(cfg.dtype))
     if early is not None:
-        router = dict(router_w=None, logits=early[1],
+        router.update(router_w=None, logits=early[1],
                       router_kind="linear_early")
     elif cfg.router_kind == "mlp":
         with jax.named_scope("router"):
             logits, state = _mlp_router(cfg, m["router"], h, router_state)
-        router = dict(router_w=None, logits=logits, router_kind="mlp",
+        router.update(router_w=None, logits=logits, router_kind="mlp",
                       router_state=router_state is not None)
         router_state = state
     out, aux = moe_layer(
-        h, w_gate=m["w_gate"], w_up=m["w_up"], w_down=m["w_down"],
+        h, w_gate=m.get("w_gate"), w_up=m["w_up"], w_down=m["w_down"],
         top_k=cfg.experts_per_token, axis=cfg.ep_axis,
         tp_axis=cfg.tp_axis, dtype=cfg.dtype,
         first_expert=cfg.first_expert,
@@ -1213,6 +1294,14 @@ def _expert_ff(cfg: GPTConfig, m, h, router_state=None, early=None):
         if _axis_bound(cfg.ep_axis):
             read = lax.all_gather(read, cfg.ep_axis, axis=0, tiled=True)
         aux = {**aux, "router_input": read}
+    if cfg.moe_latent_dim:
+        # The up-projection's weight gradient reads the experts' sum: kept
+        # by name (``SAVED_NAMES``), else the recomputed copy runs the layer
+        # to its end for it, every window of a share too.
+        out = checkpoint_name(out, "moe_latent_out")
+        with jax.named_scope("latent_up"):
+            out = jnp.einsum("bsl,le->bse", out,
+                             m["latent_up"].astype(cfg.dtype))
     if cfg.shared_expert_dim:
         with jax.named_scope("shared"):
             out = out + _shared_expert(cfg, m["shared"], h)
@@ -1226,9 +1315,11 @@ def _block(cfg: GPTConfig, spec: LayerSpec, layer_params, x, positions,
            router_state=None):
     """One decoder block, as ``spec`` says it is: ``(x, aux, router
     state)``, ``aux`` the expert layer's auxiliary terms
-    (``parallel/moe.py``) or None for a dense block, the router state what
-    an MLP router hands from one expert block to the next (a block without
-    one hands on what it was given; None under linear routers)."""
+    (``parallel/moe.py``) or None for a block without one, the router state
+    what an MLP router hands from one expert block to the next (a block
+    without one hands on what it was given; None under linear routers). A
+    block is its mixer's sublayer and then its feed-forward's, or the one
+    of the two it has (``spec.sublayers``)."""
     # The scopes sit inside the function ``jax.checkpoint`` wraps, so the
     # recomputed copy of a block carries them too (``forward`` has the rest).
     # A window layer's mixer is under ``attn_window``, a full one's under
@@ -1238,7 +1329,8 @@ def _block(cfg: GPTConfig, spec: LayerSpec, layer_params, x, positions,
     # ``mla_proj`` and ``mla_rope``.
     lp = layer_params
     norm_before, norm_after = norm_placement(cfg)
-    mixer_res, mlp_res = (lp[key] for key in _RESIDUAL_KEYS) \
+    has_mixer, has_ff = spec.sublayers
+    mixer_res, mlp_res = (lp.get(key) for key in _RESIDUAL_KEYS) \
         if cfg.residual_scaling else (None, None)
 
     def before(key):
@@ -1274,21 +1366,26 @@ def _block(cfg: GPTConfig, spec: LayerSpec, layer_params, x, positions,
     # A recurrent mixer's scope, its parameters' key and its norm's
     # (``<mixer>_norm``) carry its name; so do a CCA or MLA mixer's key and
     # norm.
-    scope = spec.mixer
-    if spec.mixer in ("attention", "cca", "mla"):
-        scope = "attn" if spec.window is None else "attn_window"
-    with jax.named_scope(scope):
-        h = before(_norm_names(spec, True, False)[0])
-        if spec.mixer == "attention":
-            branch = _attention_mixer(cfg, spec, lp, h, positions)
-        elif spec.mixer == "cca":
-            branch = _cca_mixer(cfg, spec, lp["cca"], h, positions)
-        elif spec.mixer == "mla":
-            branch = _mla_mixer(cfg, spec, lp["mla"], h, positions)
-        else:
-            branch = _RECURRENT_MIXERS[spec.mixer](cfg, lp[spec.mixer], h)
-        x = _residual(cfg, x, after(branch, "mixer_post_norm"), mixer_res)
+    if has_mixer:
+        scope = spec.mixer
+        if spec.mixer in ("attention", "cca", "mla"):
+            scope = "attn" if spec.window is None else "attn_window"
+        with jax.named_scope(scope):
+            h = before(_norm_names(spec, True, False)[0])
+            if spec.mixer == "attention":
+                branch = _attention_mixer(cfg, spec, lp, h, positions)
+            elif spec.mixer == "cca":
+                branch = _cca_mixer(cfg, spec, lp["cca"], h, positions)
+            elif spec.mixer == "mla":
+                branch = _mla_mixer(cfg, spec, lp["mla"], h, positions)
+            else:
+                branch = _RECURRENT_MIXERS[spec.mixer](cfg, lp[spec.mixer],
+                                                       h)
+            x = _residual(cfg, x, after(branch, "mixer_post_norm"),
+                          mixer_res)
 
+    if not has_ff:
+        return x, None, router_state
     if spec.ff == "experts":
         with jax.named_scope("moe"):
             h = before("mlp_norm")
@@ -1340,7 +1437,12 @@ def _block(cfg: GPTConfig, spec: LayerSpec, layer_params, x, positions,
 # product, no full-row sort and no argsort again and differentiates the
 # routing the forward pass used, whatever a router made again would have
 # chosen (on the chip not always the same: PERF.md, Findings, PR 53 and
-# PR 54). Norms, rotary,
+# PR 54). And the routed experts' weighted sum in the latent where they run
+# in one (``_expert_ff``, ``moe_latent_dim``: 2 L bytes a token a block, in
+# token order): the up-projection's weight gradient reads it, and without
+# it the recomputed copy ran every window of a share again (30 grouped
+# matmuls a step in the compiled Nemotron step for 20 with it: PERF.md,
+# Findings, PR 55). Norms, rotary,
 # projections (a recurrent mixer's input projection too), the convolution,
 # the scans' decays and chunk states, an MLP router's hidden rows, the
 # experts' sorted rows, gate and up products and activation stay recomputed.
@@ -1350,7 +1452,8 @@ def _block(cfg: GPTConfig, spec: LayerSpec, layer_params, x, positions,
 SAVED_NAMES = ("flash_out", "flash_lse", "ffn_pre_activation",
                "moe_expert_matrices", "ssm_scan_out", "gdn_scan_out",
                "branch_out", "moe_router_logits", "moe_top_experts",
-               "moe_top_weights", "moe_order", "moe_order_inverse")
+               "moe_top_weights", "moe_order", "moe_order_inverse",
+               "moe_latent_out")
 _save_names = jax.checkpoint_policies.save_only_these_names(*SAVED_NAMES)
 
 

@@ -1,5 +1,7 @@
-"""The expert layer: dropless top-k mixture of gated experts (SiLU-gated,
-or ReLU-gated where the caller says ``activation="relu"``).
+"""The expert layer: dropless top-k mixture of experts, gated (SiLU, or
+ReLU where the caller says ``activation="relu"``: three matrices an expert)
+or un-gated (``"relu2"``, the squared ReLU: two), at the tokens' width or in
+a latent of the caller's making.
 
 No reference analog — Horovod ships no expert parallelism; SURVEY.md §2.7 notes
 ``hvd.alltoall`` (``operations.cc:1055-1116``) is the enabling primitive users
@@ -12,9 +14,17 @@ tokens ``h`` ``[T, d]``, router ``W_r`` ``[d, E]`` and experts ``W_gate,e``,
     y_t = sum_{e in S_t} p_{t,e} W_down,e( act(W_gate,e h_t) * (W_up,e h_t) )
 
 ``act`` is ``silu`` unless ``activation`` names another of
-:data:`ACTIVATIONS` (``"relu"``: ``max(0, .)`` with ``relu'(0) = 0``); it is
-said once and the un-windowed branch, a share's windows and their backward
-rule all take it from there (:func:`_gated`). The weights ``p_{t,e}`` are
+:data:`ACTIVATIONS` (``"relu"``: ``max(0, .)`` with ``relu'(0) = 0``;
+``"relu2"``, one of :data:`UNGATED`: an expert is ``W_down,e relu(W_up,e
+h_t)^2``, two matrices and no ``W_gate``); the form is
+said once (:func:`expert_hidden`) and the un-windowed branch, a share's
+windows, their backward rule and the caller's shared expert all take it
+from there. **The experts' operand may be another than the router's**
+(``expert_in``): a caller whose experts run in a latent hands in ``u = h
+W`` ``[T, l]`` beside ``h``; the router, the scores and the choice are made
+of ``h``, the sorted rows, a share's windows and the grouped matmuls are
+``l`` wide, and ``y`` comes back ``[T, l]`` for the caller to project up
+(``models/gpt.py::_expert_ff``). The weights ``p_{t,e}`` are
 not renormalised over ``S_t`` unless ``renormalize`` asks for ``p_{t,e} / sum_{e' in S_t} p_{t,e'}`` (the
 gradient flows through the sum). With ``score="sigmoid"`` the scores are
 independent gates and the choice may lean on a bias that is no parameter:
@@ -154,10 +164,28 @@ GROUPED_MATMUL = "ragged_dot"
 # favours the rank's experts pays a window more for each even share's twice.
 SHARE_HEADROOM = 2
 ROW_TILE = 512      # the grouped matmul's tile of rows
-# The experts' gate, by the name a caller gives (``moe_layer``'s
-# ``activation``, ``GPTConfig.expert_activation``). ``jax.nn.relu``'s
-# derivative at 0 is 0.
-ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+# The experts' form, by the name a caller gives (``moe_layer``'s
+# ``activation``, ``GPTConfig.expert_activation``): the activation, and
+# whether it gates an up product (three matrices an expert, ``act(W_gate h)
+# * W_up h``) or is applied to the up product itself (two matrices and no
+# ``w_gate``, ``act(W_up h)``). ``jax.nn.relu``'s derivative at 0 is 0, so
+# the squared ReLU's is too.
+ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu,
+               "relu2": lambda t: jnp.square(jax.nn.relu(t))}
+UNGATED = ("relu2",)
+
+
+def expert_hidden(activation, product):
+    """An expert's hidden rows in the form ``activation`` names, from
+    ``product(name)``, the expert's input times its matrix ``name``:
+    ``act(gate) * up``, or for an un-gated form ``act(up)``. The one place
+    the form is applied: the routed experts, a share's windows, their
+    backward rules (autodiff of this) and the caller's shared expert
+    (``models/gpt.py::_shared_expert``) all come here."""
+    act = ACTIVATIONS[activation]
+    if activation in UNGATED:
+        return act(product("w_up"))
+    return act(product("w_gate")) * product("w_up")
 
 
 def share_rows(tokens: int, top_k: int, held: int, experts: int) -> int:
@@ -206,12 +234,13 @@ def _grouped(lhs, w, group_sizes, mine):
     return out if mine is None else jnp.where(mine, out, 0)
 
 
-def _gated(activation, rows, w_gate, w_up, group_sizes, mine):
-    """``act(rows W_gate) * (rows W_up)`` by groups: the experts' hidden
-    rows, the one place the activation is applied (``activation`` one of
-    :data:`ACTIVATIONS`)."""
-    return (ACTIVATIONS[activation](_grouped(rows, w_gate, group_sizes, mine))
-            * _grouped(rows, w_up, group_sizes, mine))
+def _hidden(activation, rows, w_gate, w_up, group_sizes, mine):
+    """The experts' hidden rows by groups (:func:`expert_hidden`):
+    ``act(rows W_gate) * (rows W_up)``, or ``act(rows W_up)`` in an un-gated
+    form, whose ``w_gate`` is None."""
+    matrices = {"w_gate": w_gate, "w_up": w_up}
+    return expert_hidden(activation, lambda name: _grouped(
+        rows, matrices[name], group_sizes, mine))
 
 
 def _down_products_bwd(hidden, w_down, p_rows, g_rows, group_sizes, mine):
@@ -374,7 +403,7 @@ def _window(window_rows, activation, lo, xt, w_gate, w_up, w_down, top_p,
         rows = _take(xt, pair_of_row, top_p.shape[1])                # [R, d]
     with jax.named_scope("experts"):
         rows = jnp.where(mine, rows, 0)
-        hidden = _gated(activation, rows, w_gate, w_up, sizes, mine)
+        hidden = _hidden(activation, rows, w_gate, w_up, sizes, mine)
     return _down_and_combine_window(hidden, w_down, top_p, pair_of_row,
                                     sizes, mine)
 
@@ -451,7 +480,8 @@ def moe_layer(x, router_w, w_gate, w_up, w_down, top_k: int,
               bias=None, scale: float = 1.0, probe: bool = False,
               logits=None, router_kind: str = "linear",
               router_state: bool = False,
-              activation: str = "silu") -> Tuple[jnp.ndarray, dict]:
+              activation: str = "silu",
+              expert_in=None) -> Tuple[jnp.ndarray, dict]:
     """Dropless top-``top_k`` expert layer (module docstring has the math).
 
     Args:
@@ -459,7 +489,9 @@ def moe_layer(x, router_w, w_gate, w_up, w_down, top_k: int,
       router_w: ``[d, num_experts]`` router weights (replicated, fp32);
         None where the caller made the router's outputs itself (``logits``).
       w_gate, w_up: ``[experts_local, d, m_local]`` — the ep-axis shard of
-        the global ``[num_experts, d, m]`` tensors (and tp shard of ``m``).
+        the global ``[num_experts, d, m]`` tensors (and tp shard of ``m``);
+        ``w_gate`` None for an un-gated form (``activation``). With
+        ``expert_in`` their ``d`` is its width, not ``x``'s.
       w_down: ``[experts_local, m_local, d]``.
       top_k: experts per token.
       axis: expert-parallel mesh axis (None/unbound ⇒ all experts local).
@@ -482,10 +514,15 @@ def moe_layer(x, router_w, w_gate, w_up, w_down, top_k: int,
         ``logits`` (its kind; whether it took a state from the layer
         before), for the layer's trace record alone
         (``hvdtpu_spmd_moe_layer_traces_total``).
-      activation: the experts' gate, one of :data:`ACTIVATIONS` (static).
+      activation: the experts' form, one of :data:`ACTIVATIONS` (static).
+      expert_in: ``[..., l]``, what the experts read where that is not what
+        the router reads (a projection of ``x`` to a latent of another
+        width, the caller's: ``models/gpt.py::_expert_ff``), one row for
+        each of ``x``'s; None: ``x``. The sorted rows, a share's windows and
+        ``y`` are then ``l`` wide and ``x`` is the router's operand alone.
 
-    Returns ``(y, aux)``, ``y`` shaped and typed (``dtype``) as the
-    activations, and over the tokens routed together (this rank's, or the ep
+    Returns ``(y, aux)``, ``y`` shaped as the experts' operand and typed
+    (``dtype``) as the activations, and over the tokens routed together (this rank's, or the ep
     group's): ``aux["load_balance"]`` = ``E sum_e f_e P_e`` with ``f_e`` the
     share of tokens whose ``S_t`` holds ``e`` and ``P_e = mean_t p_{t,e}``;
     ``aux["router_z"]`` = ``mean_t logsumexp(r_t)^2``; ``aux["counts"]``
@@ -500,6 +537,11 @@ def moe_layer(x, router_w, w_gate, w_up, w_down, top_k: int,
     if activation not in ACTIVATIONS:
         raise ValueError(f"expert layer: activation {activation!r} is none "
                          f"of {tuple(ACTIVATIONS)}")
+    if (w_gate is None) != (activation in UNGATED):
+        raise ValueError(
+            f"expert layer: activation {activation!r} takes "
+            f"{'no gate matrix' if activation in UNGATED else 'a gate matrix'}"
+            ", an expert is two matrices or three")
     if (router_w is None) == (logits is None):
         raise ValueError("expert layer: the router's matrix or the router's "
                          "outputs, one of the two")
@@ -519,10 +561,15 @@ def moe_layer(x, router_w, w_gate, w_up, w_down, top_k: int,
     # this program does not run.
     share = ep or experts_local < num_experts
     xt = x.reshape(-1, d)
+    # The experts' operand: the router's own unless the caller made another.
+    ut = xt if expert_in is None \
+        else expert_in.reshape(-1, expert_in.shape[-1])
     if logits is not None:
         logits = logits.reshape(-1, num_experts).astype(jnp.float32)
     if ep:
         xt = lax.all_gather(xt, axis, axis=0, tiled=True)
+        ut = xt if expert_in is None \
+            else lax.all_gather(ut, axis, axis=0, tiled=True)
         if logits is not None:
             logits = lax.all_gather(logits, axis, axis=0, tiled=True)
     T = xt.shape[0]
@@ -590,25 +637,26 @@ def moe_layer(x, router_w, w_gate, w_up, w_down, top_k: int,
                                 "moe_order")
         if not windowed:
             inv = checkpoint_name(jnp.argsort(order), "moe_order_inverse")
-            rows = _permute(jnp.repeat(xt.astype(dtype), top_k, axis=0),
-                            order, inv)                              # [Tk, d]
+            rows = _permute(jnp.repeat(ut.astype(dtype), top_k, axis=0),
+                            order, inv)                              # [Tk, l]
 
     with jax.named_scope("experts"):
         w_gate, w_up, w_down = (
-            checkpoint_name(w.astype(dtype), "moe_expert_matrices")
+            w if w is None
+            else checkpoint_name(w.astype(dtype), "moe_expert_matrices")
             for w in (w_gate, w_up, w_down))
         if not windowed:
             if mine is not None:
                 rows = jnp.where(mine, rows, 0)
-            hidden = _gated(activation, rows, w_gate, w_up, group_sizes,
-                            mine)
+            hidden = _hidden(activation, rows, w_gate, w_up, group_sizes,
+                             mine)
     if _axis_bound(tp_axis):
         # Each tp rank's share of the weights' gradient is a sum over its
         # part of the width; autodiff adds them where this cast is.
         top_p = pvary(top_p, tp_axis)
     if windowed:
         y = _held_experts(
-            window_rows, activation, xt.astype(dtype), w_gate, w_up, w_down,
+            window_rows, activation, ut.astype(dtype), w_gate, w_up, w_down,
             top_p, jnp.pad(order, (0, -order.shape[0] % window_rows)),
             group_sizes)
     else:
@@ -624,4 +672,4 @@ def moe_layer(x, router_w, w_gate, w_up, w_down, top_k: int,
            "counts": counts}
     if probe:
         aux.update(router_input=router_in, router_logits=logits)
-    return y.reshape(x.shape), aux
+    return y.reshape(x.shape if expert_in is None else expert_in.shape), aux

@@ -25,7 +25,9 @@ latent-attention job the logits scaled for the no-position part of a head
 alone, no rotary embedding; for an early-routed job the router fed what the
 experts read, SiLU for ReLU, the rotary embedding on every layer, the six
 weights not renormalised, the early router's product in one bfloat16 pass,
-the parameters rounded to bfloat16), against
+the parameters rounded to bfloat16; for a latent-expert job ReLU for its
+square, the experts fed the stream's first columns in place of the
+down-projection), against
 the untouched reference: what a job's tolerances must catch. A variant
 changes the job's ``GPTConfig`` after the job is built, before its step is
 traced (or what the program's modules see, where no field says it); a job
@@ -184,6 +186,33 @@ def _mix_in_bfloat16() -> None:
     gpt.cca_mix = mix
 
 
+def _relu_for_its_square() -> None:
+    """The un-gated experts' (and the shared expert's) activation ReLU where
+    the model squares it."""
+    import jax
+    from horovod_tpu.parallel import moe
+
+    moe.ACTIVATIONS["relu2"] = jax.nn.relu
+
+
+def _no_latent_down(job) -> None:
+    """The routed experts fed the normed stream's first ``moe_latent_dim``
+    columns as they are, where the model projects the stream down to the
+    latent: the block reads an identity's leading columns under the
+    projection's name."""
+    import jax.numpy as jnp
+
+    real = job._loss
+
+    def loss(params, *data):
+        layers = [{**lp, "moe": {**lp["moe"], "latent_down": jnp.eye(
+            *lp["moe"]["latent_down"].shape, dtype=jnp.float32)}}
+            if "moe" in lp else lp for lp in params["layers"]]
+        return real({**params, "layers": layers}, *data)
+
+    job._loss = loss
+
+
 # name -> what it does to a job already built (its step not yet traced)
 VARIANTS = {
     "full_causal": lambda job: _replace(job, layers=tuple(
@@ -216,6 +245,8 @@ VARIANTS = {
     "no_renormalize": lambda job: _replace(job, renormalize_experts=False),
     "router_early_bf16": lambda job: _mlp_router_in_bfloat16(),
     "params_bf16": _parameters_in_bfloat16,
+    "relu_not_squared": lambda job: _relu_for_its_square(),
+    "no_latent_down": _no_latent_down,
 }
 
 

@@ -270,7 +270,7 @@ def test_router_probe_hands_out_what_each_router_read_and_gave(remat):
 
 def test_config_field_count():
     # CHANGES.md says how many there were and are; a new one is said there.
-    assert len(dataclasses.fields(gpt.GPTConfig)) == 68
+    assert len(dataclasses.fields(gpt.GPTConfig)) == 69
     assert len(dataclasses.fields(LayerSpec)) == 4
 
 

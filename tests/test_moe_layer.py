@@ -1322,3 +1322,150 @@ def test_the_two_passes_cannot_choose_otherwise(moe_row_tile, rows, router):
         np.testing.assert_allclose(
             g, g_ref, rtol=0, atol=5e-3 * float(jnp.abs(g_ref).max()),
             err_msg=name)
+
+
+# --- Un-gated squared-ReLU experts in a latent narrower than the stream ----
+# (Nemotron 3's LatentMoE: the router reads the stream, the experts a
+# down-projection of it, the caller's; the layer takes it as ``expert_in``
+# and returns the weighted sum in the latent.)
+
+from benchmarks.reference import gpt_latent_moe_hybrid_dp as latent_reference  # noqa: E402,E501
+
+LATENT = 8
+
+
+def _latent_inputs(seed, routing="under", first=4):
+    """``(tokens [T, D], the block)``: a sigmoid router over the stream under
+    a bias, a down-projection to ``LATENT`` and experts of two matrices
+    there, ``routed_inputs``'s routing."""
+    h, router, _, _, _ = routed_inputs(seed, routing, first)
+    ks = jax.random.split(jax.random.PRNGKey(seed + 200), 5)
+    return h, {
+        "router": router,
+        "router_bias": 0.02 * jax.random.normal(ks[0], (E,)),
+        "latent_down": jax.random.normal(ks[1], (D, LATENT)) / 4,
+        "w_up": jax.random.normal(ks[2], (E, LATENT, M)) / 3,
+        "w_down": jax.random.normal(ks[3], (E, M, LATENT)) / 5,
+        "latent_up": jax.random.normal(ks[4], (LATENT, D)) / 3}
+
+
+def _latent_layer(h, block, top_k, first=0, held=E):
+    """The layer as ``models/gpt.py::_expert_ff`` calls it, up to the
+    up-projection: ``(the held experts' sum in the latent, aux)``."""
+    return moe_layer(
+        h, block["router"], None, block["w_up"][first:first + held],
+        block["w_down"][first:first + held], top_k=top_k, dtype=jnp.float32,
+        first_expert=first, renormalize=True, score="sigmoid",
+        bias=block["router_bias"], scale=ROUTE_SCALE, activation="relu2",
+        expert_in=h @ block["latent_down"])
+
+
+@pytest.mark.parametrize("remat", ["none", "full"])
+@pytest.mark.parametrize("first,held,routing", [
+    (0, E, "under"), (4, 4, "under"), (4, 4, "over")],
+    ids=["un-windowed", "one-window", "two-windows"])
+def test_latent_squared_relu_experts_match_the_dense_sum(
+        small_tile, first, held, routing, remat):
+    """``activation="relu2"`` (two matrices, no gate) on an operand of its
+    own, on all the rows at once and on a share's windows (one, and two
+    through the loop's body and the backward rule's), against every held
+    expert on every token: the sum in the latent, the counts, and the
+    gradients of the tokens (through the router and through the
+    projection), the router, the projection and the two expert tensors."""
+    top_k = 6
+    h, block = _latent_inputs(61, routing, first)
+    share = {**block, "w_up": block["w_up"][first:first + held],
+             "w_down": block["w_down"][first:first + held]}
+    weigh = jnp.cos(jnp.arange(T * LATENT, dtype=jnp.float32)).reshape(
+        T, LATENT)
+
+    def got(h, share):
+        y, aux = moe_layer(
+            h, share["router"], None, share["w_up"], share["w_down"],
+            top_k=top_k, dtype=jnp.float32, first_expert=first,
+            renormalize=True, score="sigmoid", bias=share["router_bias"],
+            scale=ROUTE_SCALE, activation="relu2",
+            expert_in=h @ share["latent_down"])
+        return jnp.sum(y * weigh), (y, aux["counts"])
+
+    def want(h, share):
+        y, counts = latent_reference.routed_latent(h, share, top_k,
+                                                   ROUTE_SCALE, first)
+        return jnp.sum(y * weigh), (y, counts)
+
+    (_, (y, counts)), grads = jax.value_and_grad(
+        as_a_block_runs_it(got, remat), argnums=(0, 1), has_aux=True)(
+            h, share)
+    (_, (y_ref, counts_ref)), grads_ref = jax.value_and_grad(
+        want, argnums=(0, 1), has_aux=True)(h, share)
+    assert y.shape == (T, LATENT)
+    np.testing.assert_allclose(y, y_ref, rtol=2e-5, atol=2e-5)
+    np.testing.assert_array_equal(counts, np.asarray(counts_ref, np.int32))
+    if held < E:
+        windows = assert_rows_held(counts, routing, first, top_k=top_k)
+        assert windows == (2 if routing == "over" else 1)
+    grads[1].pop("latent_up"), grads_ref[1].pop("latent_up")
+    jax.tree.map(lambda g, r: np.testing.assert_allclose(
+        g, r, rtol=2e-4, atol=2e-5 * float(jnp.abs(r).max()) + 1e-7),
+        grads, grads_ref)
+    assert not np.any(np.asarray(grads[1]["router_bias"]))
+
+
+@pytest.mark.parametrize("routing", ROUTINGS)
+def test_four_latent_shares_add_up_through_the_up_projection(small_tile,
+                                                             routing):
+    """Nemotron 3's cut: four ranks hold four of the 16 experts each, every
+    rank routes over all 16 on the stream and projects the stream to the
+    latent itself; the ranks' partial sums **in the latent**, through the
+    up-projection, with the shared expert (on the stream, un-gated) and
+    nothing else counted once, add up to the uncut reference's block, as do
+    the gradients of the tokens."""
+    top_k = 6
+    h, block = _latent_inputs(63, routing)
+    ks = jax.random.split(jax.random.PRNGKey(64), 2)
+    block["shared"] = {"w_up": jax.random.normal(ks[0], (D, 2 * M)) / 4,
+                       "w_down": jax.random.normal(ks[1], (2 * M, D)) / 6}
+    weigh = jnp.cos(jnp.arange(T * D, dtype=jnp.float32)).reshape(T, D)
+
+    def whole(h):
+        y, counts = latent_reference.expert_block(h, block, top_k,
+                                                  ROUTE_SCALE)
+        return jnp.sum(y * weigh), (y, counts)
+
+    def shared_once(h):
+        y = latent_reference.relu2_expert(h, block["shared"]["w_up"],
+                                          block["shared"]["w_down"])
+        return jnp.sum(y * weigh), y
+
+    (_, (y_ref, counts_ref)), g_ref = jax.value_and_grad(
+        whole, has_aux=True)(h)
+    (_, total), grad = jax.value_and_grad(shared_once, has_aux=True)(h)
+    for first in SHARES:
+        def share(h):
+            latent, aux = _latent_layer(h, block, top_k, first, 4)
+            y = latent @ block["latent_up"]
+            return jnp.sum(y * weigh), (y, aux["counts"])
+
+        (_, (y, counts)), g = jax.value_and_grad(share, has_aux=True)(h)
+        np.testing.assert_array_equal(counts,
+                                      np.asarray(counts_ref, np.int32))
+        total, grad = total + y, grad + g
+    np.testing.assert_allclose(total, y_ref, rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(
+        grad, g_ref, rtol=2e-4, atol=2e-5 * float(jnp.abs(g_ref).max()))
+
+
+def test_an_expert_is_two_matrices_or_three():
+    """An un-gated form takes no gate matrix and a gated one wants it; the
+    record names the form."""
+    h, block = _latent_inputs(65)
+    with pytest.raises(ValueError, match="takes no gate matrix"):
+        moe_layer(h @ block["latent_down"], None, block["w_up"],
+                  block["w_up"], block["w_down"], top_k=2, logits=h,
+                  activation="relu2")
+    with pytest.raises(ValueError, match="takes a gate matrix"):
+        moe_layer(h @ block["latent_down"], None, None, block["w_up"],
+                  block["w_down"], top_k=2, logits=h)
+    # 0 squared has the derivative 0, as ReLU's at 0 is 0.
+    np.testing.assert_array_equal(
+        jax.grad(moe.ACTIVATIONS["relu2"])(jnp.zeros(())), 0.0)
