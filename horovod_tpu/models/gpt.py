@@ -1411,11 +1411,20 @@ def _block(cfg: GPTConfig, spec: LayerSpec, layer_params, x, positions,
 # bytes a token a layer; with it the gated norm, the output projection and
 # the rest of the block are made again without the scan's output product,
 # and on the chip the step needs less memory at its peak than without it:
-# PERF.md, Findings, PR 29), and the gated-delta-rule scan's output
-# (``_gdn_mixer``: 2 Hv V bytes a token a layer; that scan is plain
-# ``jax.numpy`` whose backward pass needs its insides, so most of it is made
-# again all the same, but not the products that only give the output: +5.6%
-# on the chip, PERF.md, Findings, PR 31), and a branch's output where the
+# PERF.md, Findings, PR 29), and of the gated-delta-rule scan
+# (``_gdn_mixer``, ``ops/gated_delta.py``) its output (2 Hv V bytes a token
+# a layer: +5.6% on the chip, PERF.md, Findings, PR 31) and what its
+# backward kernels read beside their inputs: the chunk-local kernel's five
+# outputs (``gdn_scan_operands``: a value head a token the lanes' V in
+# float32 and 3 K + Q in the compute dtype, 738 MB a layer in the Qwen
+# cell, 472 in the Olmo cell; they cross HBM to the recurrence's kernels in
+# the forward pass already) and each chunk's entering state
+# (``gdn_scan_entering``, 268 MB a layer in the Qwen cell), named only
+# where no lane of a state is padding (key and value head both whole lane
+# tiles; the scan computes it from its shapes, no field here). With both
+# the recomputed copy runs neither ``hvd_gdn_fwd`` nor ``hvd_gdn_rec_fwd``;
+# with the five alone, the Olmo cell's 96 x 192 heads, it runs the second
+# (PERF.md, Findings, PR 56), and a branch's output where the
 # block's own backward pass reads it (``_block``'s ``after``: under a norm
 # after the branch, whose backward pass reads what it normed, or a learned
 # residual scale, whose gradient is ``<g, branch + bias>``; 2 E bytes a token
@@ -1444,8 +1453,10 @@ def _block(cfg: GPTConfig, spec: LayerSpec, layer_params, x, positions,
 # matmuls a step in the compiled Nemotron step for 20 with it: PERF.md,
 # Findings, PR 55). Norms, rotary,
 # projections (a recurrent mixer's input projection too), the convolution,
-# the scans' decays and chunk states, an MLP router's hidden rows, the
-# experts' sorted rows, gate and up products and activation stay recomputed.
+# the scans' decays, the state-space scan's chunk states and the
+# gated-delta-rule scan's where a head is carried padded, an MLP router's
+# hidden rows, the experts' sorted rows, gate and up products and activation
+# stay recomputed.
 # Nothing that lies in the sort's order is named yet; such rows may be from
 # now on only because the order is kept with them (``parallel/moe.py``'s
 # docstring; PERF.md, Findings, PR 28).
@@ -1453,7 +1464,7 @@ SAVED_NAMES = ("flash_out", "flash_lse", "ffn_pre_activation",
                "moe_expert_matrices", "ssm_scan_out", "gdn_scan_out",
                "branch_out", "moe_router_logits", "moe_top_experts",
                "moe_top_weights", "moe_order", "moe_order_inverse",
-               "moe_latent_out")
+               "moe_latent_out", "gdn_scan_operands", "gdn_scan_entering")
 _save_names = jax.checkpoint_policies.save_only_these_names(*SAVED_NAMES)
 
 

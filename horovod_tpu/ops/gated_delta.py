@@ -80,6 +80,13 @@ VMEM, forms ``dT`` from the cotangents of ``u_own`` and ``w``, applies ``dA
 ``dtype``), ``d cum`` and ``d beta`` (float32, a row a head ``[B, c, Hk, 2
 Hv / Hk, Q]``, turned back outside). Off the TPU the kernels run in Pallas
 interpret mode; on it a chunk they do not tile raises (:func:`_tiling`).
+The five outputs cross HBM to the recurrence, whose residuals they are, and
+**are what a checkpoint keeps**: the recurrence's rule names each
+``gdn_scan_operands``, ``models/gpt.py::SAVED_NAMES`` holds the name, and the recomputed copy of a ``remat="full"`` block does not
+run ``hvd_gdn_fwd`` (a value head a token the lanes' ``V`` in float32 and
+``3 K + Q`` in ``dtype``: 738 MB a layer in the Qwen cell; they were in HBM
+already and the backward kernels read them from there either way).
+Outside a checkpoint a name is an identity.
 
 **Heads of any size.** A key head of ``K`` and a value head of ``V`` come
 and go as published; the four kernels carry a head at the next multiple of
@@ -116,7 +123,23 @@ maps read block ``last - c``), carries ``dS`` float32 from the final state's
 cotangent to the initial state's, makes ``u`` again and returns the
 cotangents of the five operands (what ``hvd_gdn_bwd`` takes) and of the
 last decays. Both are bound by their bytes on a v5e (PERF.md, Findings, PR
-36: the kernels with their products taken out take as long).
+36: the kernels with their products taken out take as long). **The entering
+states are named for a checkpoint too** (``gdn_scan_entering``, in the
+rule's forward) **where no lane of them is
+padding**: ``K`` and ``V`` both multiples of the lane width, which
+:func:`gated_delta_chunked` sees beside the carried sizes and hands
+:func:`_recurrence` as its one static argument. With them and the five
+operands kept every output of ``hvd_gdn_rec_fwd`` is, and a checkpointed
+block runs it once a step; a head carried with zeros (96 x 192 on 128 x
+256: 44% of a kept state would be zeros) names none and its block makes the
+states again, from the kept operands. **Which values the forward kernel
+reads follows from that** (:func:`_recurrence_fwd`): ``jax.checkpoint``
+copies a kept value that the forward pass reads too through a
+``reduce_precision``, which behind a kernel is a pass over every kept byte,
+so where the states are kept the kernel reads the operands unnamed and only
+the residuals carry the name, and where they are not it reads them named,
+or the recomputed copy would want ``hvd_gdn_fwd`` for them (PERF.md,
+Findings, PR 56).
 
 **The inverse in VMEM** (:func:`_inverse_in_vmem`): the diagonal blocks of
 ``_SUBSTITUTE`` = 32 rows by forward substitution on the vector unit (a
@@ -149,6 +172,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -911,22 +935,35 @@ def _rec_bwd_call(u_own, w, attn, q_in, k_out, decay, entering, do, dfinal):
     return (*scan, ddecay.swapaxes(1, 2).reshape(decay.shape), dstart)
 
 
-@jax.custom_vjp
-def _recurrence(u_own, w, attn, q_in, k_out, decay, start):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _recurrence(keep_entering, u_own, w, attn, q_in, k_out, decay, start):
     """The recurrence over chunks through the kernels: ``(o, final)``, see
-    :func:`_rec_fwd_call`."""
+    :func:`_rec_fwd_call`. ``keep_entering`` (static) names each chunk's
+    entering state for a checkpoint around the caller to keep."""
     return tuple(_rec_fwd_call(u_own, w, attn, q_in, k_out, decay, start,
                                keep=False))
 
 
-def _recurrence_fwd(*inputs):
+def _recurrence_fwd(keep_entering, *inputs):
     # The residuals: the inputs but the initial state, and the entering
-    # states the forward kernel keeps.
-    o, final, entering = _rec_fwd_call(*inputs, keep=True)
-    return (o, final), inputs[:6] + (entering,)
+    # states the forward kernel keeps, under the names a ``jax.checkpoint``
+    # around the caller may keep them by (``models/gpt.py::SAVED_NAMES``;
+    # outside one a name is an identity). With the entering states named
+    # the checkpoint has this rule's every output, and the kernel reads the
+    # operands unnamed: ``jax.checkpoint`` copies a kept value that the
+    # forward pass reads too through a ``reduce_precision``, behind a kernel
+    # a pass over every kept byte. Without, the recomputed copy makes the
+    # states again and must find the operands kept: the kernel reads them
+    # named (the module docstring; PERF.md, Findings, PR 56).
+    scan = tuple(checkpoint_name(t, "gdn_scan_operands") for t in inputs[:5])
+    o, final, entering = _rec_fwd_call(
+        *(inputs if keep_entering else scan + inputs[5:]), keep=True)
+    if keep_entering:
+        entering = checkpoint_name(entering, "gdn_scan_entering")
+    return (o, final), scan + (inputs[5], entering)
 
 
-def _recurrence_bwd(kept, cotangents):
+def _recurrence_bwd(keep_entering, kept, cotangents):
     return _rec_bwd_call(*kept, *cotangents)
 
 
@@ -990,8 +1027,11 @@ def gated_delta_chunked(q, k, v, g, beta, chunk: int = 64, *,
     start = jnp.zeros((batch, heads, key_dim, width), f32) \
         if initial_state is None else initial_state.astype(f32)
     # The recurrence's kernels: a head's state stays in VMEM through the
-    # sequence's chunks; o comes out as [B, S, Hv V].
-    o, final = _recurrence(u_own, w, attn, q_in, k_out,
+    # sequence's chunks; o comes out as [B, S, Hv V]. Its rule names the
+    # five operands for a checkpoint to keep, and the entering states
+    # where they are worth the memory: the carried head is the true head.
+    o, final = _recurrence(key_dim % LANES == 0 and width % LANES == 0,
+                           u_own, w, attn, q_in, k_out,
                            jnp.exp(cum[:, :, -1]),
                            varying_like(_to_lanes(start, -2, -1), u_own))
     o = o.reshape(batch, n_chunks * chunk, heads, -1)[:, :seq]

@@ -35,6 +35,16 @@ the chunk: no product) and ``--chunks-per-block`` the chunks a grid cell
 walks: the sources of ``ops/gated_delta.py::_SUBSTITUTE`` and
 ``_MAX_CHUNKS``. One JSON line a row, also appended to
 ``chiprun_out/gdn_kernel_time.jsonl``.
+
+**Summing a step from these rows.** A layer of a checkpointed step
+(``remat="full"``) runs ``hvd_gdn_fwd`` once, ``hvd_gdn_rec_fwd`` once where
+the heads are whole lane tiles (the Qwen cell's) and twice where they are
+carried padded (the Olmo cell's: the recomputed copy makes the entering
+states again), ``hvd_gdn_bwd`` once and ``hvd_gdn_rec_bwd`` once: since
+PR 56 the block keeps what the first kernel writes and, unpadded, the
+entering states (``gdn_scan_operands``, ``gdn_scan_entering``). Before it
+both forward kernels ran twice a layer. The forward pass runs the
+recurrence's kernel as the rule's forward does (``fwd_keep_ms``).
 """
 
 from __future__ import annotations
@@ -271,7 +281,9 @@ def main() -> int:
                 fwd = jax.jit(lambda *t: gd._rec_fwd_call(*t, keep=False))
                 keep = jax.jit(lambda *t: gd._rec_fwd_call(*t, keep=True))
                 bwd = jax.jit(lambda *t: gd._rec_bwd_call(*t))
-                kernels = both(lambda *t: gd._recurrence(*t), 7)
+                # (No checkpoint here: the rule's one static argument,
+                # whether the entering states are named, changes nothing.)
+                kernels = both(lambda *t: gd._recurrence(False, *t), 7)
                 got = fwd(*rec)
                 entering = keep(*rec)[2]
                 _, got_g = kernels(*rec, *rec_cts)

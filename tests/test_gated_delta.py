@@ -46,6 +46,7 @@ chunk's padding adds, a zero row inside a chunk, and the scale of ``q``,
 which is the true head's and not the lanes'.
 """
 
+import collections
 import functools
 import inspect
 
@@ -233,6 +234,52 @@ def test_cell_layout_matches_sequential():
 @pytest.mark.parametrize("wrt", range(5), ids=["q", "k", "v", "g", "beta"])
 def test_cell_layout_gradients_of_every_input(wrt):
     _gradient_matches(_inputs(12, CELL_SEQ, **CELL), 13, 64, wrt)
+
+
+def _names_in(jaxpr, out=None):
+    """``checkpoint_name``'s equations by name in a jaxpr and every jaxpr
+    under it."""
+    out = collections.Counter() if out is None else out
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "name":
+            out[eqn.params["name"]] += 1
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _names_in(sub, out)
+    return out
+
+
+@pytest.mark.parametrize("heads", ["whole", "padded"])
+def test_the_names_a_checkpoint_may_keep_change_nothing_outside_one(heads):
+    """Outside a checkpoint a name is an identity: the value and every
+    input's gradient are the sequential rule's, where the entering states
+    are named (a head of whole lane tiles) and where they are not (a head
+    carried with zeros); the chunk-local kernel's five outputs are named
+    either way."""
+    # (The shapes of the cases above, whose compiled kernels these calls
+    # find again.)
+    args = _inputs(31, CELL_SEQ, **CELL) if heads == "whole" \
+        else _inputs(31, 70)
+    rng = np.random.default_rng(32)
+    like_o, like_s = jax.eval_shape(gated_delta_sequential, *args)
+    co = jnp.asarray(rng.standard_normal(like_o.shape), jnp.float32)
+    cs = jnp.asarray(rng.standard_normal(like_s.shape), jnp.float32)
+
+    def value_and_grads(fn):
+        def f(*a):
+            o, s = fn(*a)
+            return jnp.sum(o * co) + jnp.sum(s * cs)
+        return jax.value_and_grad(f, argnums=tuple(range(5)))
+
+    chunked = value_and_grads(lambda *a: gated_delta_chunked(
+        *a, chunk=64, dtype=jnp.float32))
+    value, grads = chunked(*args)
+    want_value, want = value_and_grads(gated_delta_sequential)(*args)
+    np.testing.assert_allclose(value, want_value, rtol=2e-5)
+    for got, w in zip(grads, want):
+        _close(got, w, 5e-5)
+    assert _names_in(jax.make_jaxpr(chunked)(*args).jaxpr) == {
+        "gdn_scan_operands": 5,
+        **({"gdn_scan_entering": 1} if heads == "whole" else {})}
 
 
 @pytest.mark.parametrize("what", ["beta zero", "alpha one"])

@@ -17,6 +17,7 @@ weights against the reference in float64: 7e-5 to 2e-4; the bound is
 moves the loss by 1e-4 of itself or more (the switches' test).
 """
 
+import collections
 import dataclasses
 
 import jax
@@ -151,6 +152,79 @@ def test_model_matches_the_reference_through_run_step(make_runtime, remat):
     kernels = fams["hvdtpu_spmd_gdn_kernel_traces_total"]["samples"]
     assert {(s[1]["key_lanes"], s[1]["value_lanes"]) for s in kernels} \
         <= {("128", "128")}
+
+
+# One key and one value head of a whole lane tile each way: nothing padded.
+WHOLE = dict(gdn_key_heads=1, gdn_value_heads=1, gdn_key_dim=128,
+             gdn_value_dim=128)
+
+
+def _kernels_in(jaxpr, out=None):
+    """Pallas kernels by name in a jaxpr and every jaxpr under it, and
+    under ``"rounded"`` the ``reduce_precision`` passes ``jax.checkpoint``
+    puts on tensors in the recurrence's order ``[c, B, Hv, ., .]``."""
+    out = collections.Counter() if out is None else out
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            out[eqn.params["name"]] += 1
+            continue
+        if eqn.primitive.name == "reduce_precision" \
+                and eqn.outvars[0].aval.ndim == 5:
+            out["rounded"] += 1
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _kernels_in(sub, out)
+    return out
+
+
+@pytest.mark.parametrize("heads", ["padded", "whole"])
+def test_a_checkpointed_block_runs_the_scans_forward_kernels_once(
+        make_runtime, heads):
+    """``remat="full"`` keeps what ``hvd_gdn_fwd`` writes, so the
+    recomputed copy does not run it, and each chunk's entering state where
+    no lane of it is padding, so ``hvd_gdn_rec_fwd`` runs once too; a head
+    carried with zeros makes its entering states again, from the kept
+    operands: only there does the forward pass read a kept tensor, and
+    only there does ``jax.checkpoint`` copy the five through a
+    ``reduce_precision`` (a pass over every kept byte on the chip)."""
+    make_runtime(devices=jax.devices()[:1])
+    cfg = gpt.GPTConfig(**{**TINY, **(WHOLE if heads == "whole" else {}),
+                           "num_layers": 2, "layer_kinds": ("gdn", "gdn")},
+                        remat="full")
+    layers = 2
+    data = _data(3)
+    jaxpr = jax.make_jaxpr(
+        lambda p: jax.value_and_grad(gpt.loss_fn)(p, *data, cfg))(
+            gpt.init_params(jax.random.PRNGKey(0), cfg))
+    kernels = _kernels_in(jaxpr.jaxpr)
+    assert {name: n for name, n in kernels.items()
+            if name.startswith("hvd_gdn_")} == {
+        gated_delta.KERNEL_FWD: layers, gated_delta.KERNEL_BWD: layers,
+        gated_delta.KERNEL_REC_BWD: layers,
+        gated_delta.KERNEL_REC_FWD: layers * (1 if heads == "whole" else 2)}
+    assert kernels["rounded"] == (0 if heads == "whole" else 5 * layers)
+    family = hvd.metrics()["hvdtpu_spmd_remat_saved_bytes_total"]
+    kept = {labels["name"]: value for _, labels, value in family["samples"]}
+    assert "gdn_scan_operands" in kept
+    assert ("gdn_scan_entering" in kept) == (heads == "whole")
+    if heads == "whole":
+        # [c, B, Hv, K, V] in the operand dtype, a layer.
+        assert kept["gdn_scan_entering"] == 3 * B * 128 * 128 * 4
+
+
+def test_full_remat_is_no_remat_to_the_last_gradient_leaf():
+    """The kept tensors are the tensors that would have been made again:
+    the loss and every gradient leaf under ``remat="full"`` against
+    ``remat="none"``."""
+    # (One linear layer, jitted: lowering the kernels' interpret mode is
+    # most of this test's time.)
+    tiny = {**TINY, "num_layers": 1, "layer_kinds": ("gdn",)}
+    params, data = _params(gpt.GPTConfig(**tiny), 4), _data(5)
+    (want_loss, want), (loss, grads) = (
+        jax.jit(lambda p, cfg=gpt.GPTConfig(**tiny, remat=remat):
+                _loss_and_grad(cfg, p, data))(params)
+        for remat in ("none", "full"))
+    np.testing.assert_allclose(loss, want_loss, rtol=1e-6)
+    _assert_grads_agree(grads, want)
 
 
 def test_flash_kernel_serves_the_attention_layer():

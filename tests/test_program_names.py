@@ -188,9 +188,11 @@ def test_linear_attention_step_holds_the_gdn_kernels_under_their_scope(
         spmd4):
     """The scan's kernels, the chunk-local pair and the recurrence's, sit
     under ``layer<i>/gdn/scan`` (where ``gdn_scan_ms`` looks): the forward
-    ones in the forward pass and in the recomputed copy (the recurrence's
-    backward pass reads the chunk-local kernel's outputs and the entering
-    states), the backward ones in the backward pass. The counter says which
+    ones in the forward pass, the recurrence's in the recomputed copy too
+    (its backward pass reads each chunk's entering state, which a block
+    keeps only where no lane of it is padding: these heads of 8 ride 128;
+    the chunk-local kernel's outputs are kept, and it does not run again),
+    the backward ones in the backward pass. The counter says which
     tiling each got, with its six labels (heads of 8 ride one lane tile of
     128): each kernel is traced once for a
     shape (the calls are jitted inline, so the recomputed copy and further
@@ -210,9 +212,8 @@ def test_linear_attention_step_holds_the_gdn_kernels_under_their_scope(
         assert ("transpose(jvp(layer0))" in scope
                 and "rematted_computation" not in scope) \
             == kernel.endswith("bwd"), scope
-    for kernel in ("fwd", "rec_fwd"):
-        assert any("rematted_computation" in scope
-                   for scope, name in scopes if name == kernel)
+    assert {name for scope, name in scopes
+            if "rematted_computation" in scope} == {"rec_fwd"}
     family = hvd.metrics()["hvdtpu_spmd_gdn_kernel_traces_total"]
     samples = {tuple(sorted(labels.items())): count
                for _, labels, count in family["samples"]}
