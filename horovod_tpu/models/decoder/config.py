@@ -1,0 +1,288 @@
+"""The bottom of ``models/decoder``, which imports nothing of the package:
+:class:`GPTConfig`, one :class:`LayerSpec` a layer (:func:`layer_plan`) and
+where a block's norms sit (:func:`norm_placement`). A mixer's derived shapes
+are functions of ``cfg`` in its own file (``mixers/``)."""
+
+import dataclasses
+import functools
+from typing import Any, Optional, Tuple
+
+import jax.numpy as jnp
+
+
+@dataclasses.dataclass(frozen=True)
+class LayerSpec:
+    """One layer of the stack: its mixer (a key of ``mixers.MIXERS``), for an
+    attention mixer the ``window`` (a query sees itself and the ``window -
+    1`` keys before it; None: every key before it), for an attention, a
+    CCA or an MLA mixer whether the rotary embedding applies
+    (``GPTConfig.rope_theta``, ``rotary_dim``), and its feed-forward (a key
+    of ``gpt.FEED_FORWARDS``: two matrices and a GELU, three and a SiLU
+    gate, or the expert block with what ``GPTConfig`` says of experts).
+    **Either sublayer may be None**: the block is then the other one alone,
+    ``x + f(N(x))`` with one norm (a stack whose blocks are a mixer or a
+    feed-forward each); a layer with neither is refused
+    (:func:`layer_plan`). :attr:`sublayers` says which a block has, and
+    the parameters, the specs and ``gpt._block`` read it there."""
+    mixer: Optional[str] = "attention"
+    window: Optional[int] = None
+    rope: bool = True
+    ff: Optional[str] = "dense"
+
+    @property
+    def sublayers(self) -> Tuple[bool, bool]:
+        """``(a mixer, a feed-forward)``: which sublayers the block has."""
+        return self.mixer is not None, self.ff is not None
+
+
+@dataclasses.dataclass(frozen=True)
+class GPTConfig:
+    vocab_size: int = 32000
+    num_layers: int = 4
+    num_heads: int = 8
+    num_kv_heads: Optional[int] = None      # GQA; default == num_heads
+    head_dim: int = 64
+    embed_dim: int = 512
+    mlp_dim: int = 2048
+    dtype: Any = jnp.bfloat16
+    # Mesh axis names; None disables that parallelism dimension.
+    tp_axis: Optional[str] = "tp"
+    sp_axis: Optional[str] = "sp"
+    ep_axis: Optional[str] = None
+    # "flash" | "dense" | "ring" | "ulysses": the table in ``_attention``
+    # (the flash kernel on each device, except under "dense", the reference;
+    # "ring" and "ulysses" cross a bound sp axis, the other two refuse one).
+    attention: str = "ring"
+    # Experts (active when moe_every > 0): every moe_every-th block's
+    # feed-forward is the dropless expert layer of ``parallel/moe.py``:
+    # num_experts gated experts (``expert_activation``) of width mlp_dim,
+    # experts_per_token of them a token. The loss adds the layers' summed
+    # load-balance and router z terms under these coefficients.
+    moe_every: int = 0
+    num_experts: int = 8
+    experts_per_token: int = 1
+    load_balance_coef: float = 0.0
+    router_z_coef: float = 0.0
+    # A rank's share of an expert-parallel deployment, run alone: the block
+    # holds experts first_expert to first_expert + experts_held of the
+    # router's num_experts (None: all of them) and returns their part of the
+    # sum. A token's experts_per_token weights divided by their sum. A
+    # gated expert of width shared_expert_dim (0: none) that every token
+    # goes through, under a sigmoid gate of its own.
+    experts_held: Optional[int] = None
+    first_expert: int = 0
+    renormalize_experts: bool = False
+    shared_expert_dim: int = 0
+    # RMSNorm over the whole query and the whole key projection (all heads
+    # together), before the rotary embedding; qk_head_norm: over each head
+    # instead, one weight of head_dim for all heads.
+    qk_norm: bool = False
+    qk_head_norm: bool = False
+    norm_eps: float = 1e-6
+    # Norm weights enter as 1 + w and start at zero (every norm but the
+    # recurrent mixers' gated ones and the whole-projection qk_norm).
+    norm_zero_centered: bool = False
+    # Per-block rematerialization (jax.checkpoint) — the TPU lever trading
+    # FLOPs for HBM so long sequences fit: "none" stores every block
+    # activation; "full" stores a block's input and what is dear to make
+    # again (``gpt.SAVED_NAMES``, each name declared where it is born) and
+    # recomputes the rest in backward: in bfloat16
+    # 2E + 2HD + 4H + 2M bytes a token a layer where the input alone is 2E
+    # (an expert block: no 2M, 6 bytes an expert parameter a layer and
+    # 4 experts + 16 experts_per_token bytes a token; 4E more where the
+    # branches' outputs are kept).
+    remat: str = "none"                      # "none" | "full"
+    # Each layer's mixer, ``"attention"``, ``"ssm"`` or ``"gdn"``, one entry
+    # a layer; None is attention throughout. A state-space mixer has
+    # ssm_heads heads of ssm_head_dim, a state of ssm_state a head,
+    # ssm_groups groups of heads that share B and C, a convolution of
+    # ssm_conv taps and a scan in chunks of ssm_chunk tokens.
+    layer_kinds: Optional[Tuple[str, ...]] = None
+    ssm_heads: int = 8
+    ssm_head_dim: int = 64
+    ssm_state: int = 128
+    ssm_groups: int = 1
+    ssm_conv: int = 4
+    ssm_chunk: int = 256
+    # A gated-delta-rule mixer has gdn_key_heads query/key heads of
+    # gdn_key_dim and gdn_value_heads value heads of gdn_value_dim (value
+    # head h reads key head h // (value heads / key heads)), a convolution
+    # of gdn_conv taps and a scan in chunks of gdn_chunk tokens. The writing
+    # strength beta is sigmoid(b), or with gdn_allow_neg_eigval twice that:
+    # a token's transition I - beta k k^T then has its eigenvalue along the
+    # key in (-1, 1) and not (0, 1).
+    gdn_key_heads: int = 4
+    gdn_value_heads: int = 8
+    gdn_key_dim: int = 64
+    gdn_value_dim: int = 64
+    gdn_conv: int = 4
+    gdn_chunk: int = 64
+    gdn_allow_neg_eigval: bool = False
+    # The dense feed-forward as silu(gate) * up (three matrices) instead of
+    # gelu(up) (two).
+    gated_mlp: bool = False
+    # False: no position embedding on q and k. Else at base rope_theta on
+    # the first rotary_dim dimensions of a head (None: all).
+    rope: bool = True
+    rope_theta: float = 10000.0
+    rotary_dim: Optional[int] = None
+    # wq is twice as wide a head, [q | gate], and attention's output is
+    # multiplied by sigmoid(gate) before the output projection.
+    attention_gate: bool = False
+    # The head is the embedding's transpose: one parameter receives the
+    # gather's and the head's gradient.
+    tie_embeddings: bool = False
+    # Constants on the embedding, on the attention logits (None: one over
+    # the square root of head_dim), on each residual branch, and dividing
+    # the logits.
+    embedding_multiplier: float = 1.0
+    attention_multiplier: Optional[float] = None
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
+    # The layers said outright, one LayerSpec each; None: resolved from
+    # layer_kinds, moe_every, gated_mlp and rope (``layer_plan``). What only
+    # a per-layer description can say (a window on some attention layers,
+    # the rotary embedding on some, dense layers before expert layers) is
+    # said here and has no field of its own.
+    layers: Optional[Tuple[LayerSpec, ...]] = None
+    # An expert's width (None: mlp_dim, which stays the dense layers').
+    expert_dim: Optional[int] = None
+    # A norm after each branch as well as before: x + N2(f(N1(x))). The
+    # older way to say norms="pre_post" (``norm_placement``).
+    post_norm: bool = False
+    # Where a block's RMSNorms sit, one of ``NORMS``: "pre" (before each
+    # branch; what None means without post_norm), "pre_post" (before and
+    # after) or "post" (after alone: x + N(f(x))). The norm before the head
+    # is there in all three.
+    norms: Optional[str] = None
+    # False: the shared expert is added as it is, with no gate of its own.
+    shared_expert_gate: bool = True
+    # The router's scores, "softmax" or "sigmoid"; router_bias: a bias
+    # [num_experts] added to the scores for the choice of experts alone,
+    # kept beside the router's matrix but no parameter (no gradient reaches
+    # it; ``trainable`` keeps the optimizer off it, ``update_router_bias``
+    # moves it from the step's token counts); route_scale multiplies a
+    # token's weights.
+    router_score: str = "softmax"
+    router_bias: bool = False
+    route_scale: float = 1.0
+    # ``loss_and_aux``'s parts also hold what each expert block's router
+    # read and gave (``router_inputs``, ``router_logits``): a check holds
+    # the float32 product to a reference fed the same activations, which
+    # no norm or count of the step can (a step that drops them costs
+    # nothing: the compiler removes what nobody reads).
+    router_probe: bool = False
+    # A "cca" mixer's convolutions over its latent [q | k] of num_heads +
+    # kv_heads heads of head_dim: the taps of the depthwise stage and of the
+    # stage grouped by head.
+    cca_taps: Tuple[int, int] = (2, 2)
+    # An "mla" mixer (latent attention): a query and key head is head_dim
+    # dimensions without position beside mla_rope_dim rotary ones, the
+    # rotary key one a token for all heads; keys' no-position parts and the
+    # value heads of mla_value_dim come from an RMS-normed latent of
+    # mla_kv_rank. The query is projected straight from the stream.
+    mla_kv_rank: int = 512
+    mla_rope_dim: int = 64
+    mla_value_dim: int = 128
+    # The router of an expert block, one of ``experts.ROUTERS``: "linear", one
+    # matrix [embed, experts]; "mlp": a down-projection to router_dim plus
+    # a learned vector times the down-projection of the expert block
+    # before it (none for the first), an RMSNorm, two GELU layers of
+    # router_dim and a matrix [router_dim, experts] (``experts._mlp_router``).
+    router_kind: str = "linear"
+    router_dim: int = 256
+    # What an expert block's router reads, one of ``experts.ROUTER_READS``:
+    # "ff_input", what the experts read (the normed stream after the mixer);
+    # "block_input": the stream as it enters the block, un-normed, before
+    # the mixer runs (``gpt._block``: the product under the scope
+    # ``moe/router_early``; a linear router alone).
+    router_reads: str = "ff_input"
+    # The experts' form, one of ``parallel/moe.py::ACTIVATIONS``: gated by
+    # "silu" or "relu" (three matrices an expert) or the un-gated squared
+    # ReLU "relu2" (two, no ``w_gate``). A shared expert takes the same.
+    expert_activation: str = "silu"
+    # The routed experts run in a latent of this width (0: at the stream's):
+    # the router reads the normed stream, ``u = h W_down_latent`` is what the
+    # experts read, and their weighted sum goes through ``W_up_latent`` back
+    # to the stream (``experts.apply``); a shared expert stays on the stream.
+    moe_latent_dim: int = 0
+    # A sublayer joins the residual stream as a_r (x + b_r) + a_h (f + b_h):
+    # four learned vectors of embed_dim a sublayer, ones and zeros at
+    # initialisation (``parts._residual``).
+    residual_scaling: bool = False
+
+    @property
+    def kv_heads(self) -> int:
+        return self.num_kv_heads or self.num_heads
+
+    @property
+    def plan(self) -> Tuple[LayerSpec, ...]:
+        """What each layer is (:func:`layer_plan`)."""
+        return layer_plan(self)
+
+    def kind(self, layer: int) -> str:
+        return self.plan[layer].mixer
+
+    @property
+    def expert_width(self) -> int:
+        return self.expert_dim or self.mlp_dim
+
+
+
+@functools.lru_cache(maxsize=None)
+def layer_plan(cfg: GPTConfig) -> Tuple[LayerSpec, ...]:
+    """One :class:`LayerSpec` a layer: ``cfg.layers`` where it is given,
+    else what ``layer_kinds`` (the mixers; None: attention throughout),
+    ``moe_every`` (every ``moe_every``-th block's feed-forward is the expert
+    block), ``gated_mlp`` (the other blocks') and ``rope`` say. The one
+    place those inputs are read. Whether a name is a mixer or a
+    feed-forward the tables say, at the look-up (``gpt._sublayers``)."""
+    kinds = cfg.layer_kinds
+    if kinds is not None and len(kinds) != cfg.num_layers:
+        raise ValueError(
+            f"layer_kinds must name a mixer for each of the "
+            f"{cfg.num_layers} layers, got {kinds!r}")
+    if cfg.layers is None:
+        return tuple(LayerSpec(
+            mixer="attention" if kinds is None else kinds[i], rope=cfg.rope,
+            ff="experts" if cfg.moe_every > 0
+            and (i + 1) % cfg.moe_every == 0
+            else "gated" if cfg.gated_mlp else "dense")
+            for i in range(cfg.num_layers))
+    if kinds is not None or cfg.moe_every:
+        raise ValueError("layers says each layer outright: leave "
+                         "layer_kinds and moe_every unset beside it")
+    plan = tuple(cfg.layers)
+    if len(plan) != cfg.num_layers or any(
+            not isinstance(spec, LayerSpec) or not any(spec.sublayers)
+            or (spec.window is not None
+                and (spec.mixer != "attention" or spec.window < 1))
+            for spec in plan):
+        raise ValueError(
+            f"layers must hold a LayerSpec (a mixer, a feed-forward, "
+            f"either of them None but not both, a window of at least one "
+            f"key on attention alone: a CCA layer has none yet, nor an MLA "
+            f"layer) for each of the {cfg.num_layers} layers, got {plan!r}")
+    return plan
+
+
+# placement -> (a norm before each branch, a norm after it)
+NORMS = {"pre": (True, False), "pre_post": (True, True),
+         "post": (False, True)}
+
+
+def norm_placement(cfg: GPTConfig) -> Tuple[bool, bool]:
+    """``(before, after)``: whether a block norms each branch's input
+    (parameters ``attn_norm`` / ``ssm_norm`` / ``gdn_norm`` and
+    ``mlp_norm``) and its output (``mixer_post_norm``, ``mlp_post_norm``).
+    ``cfg.norms`` says it; the older ``post_norm`` resolves here, beside
+    the plan, and nowhere else (both given: ``ValueError``)."""
+    if cfg.norms is None:
+        return NORMS["pre_post" if cfg.post_norm else "pre"]
+    if cfg.post_norm or cfg.norms not in NORMS:
+        raise ValueError(
+            f"norms must be one of {tuple(NORMS)} with post_norm left "
+            f"unset beside it, got norms={cfg.norms!r}, "
+            f"post_norm={cfg.post_norm}")
+    return NORMS[cfg.norms]
+

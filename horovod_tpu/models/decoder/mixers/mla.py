@@ -1,0 +1,93 @@
+"""The ``"mla"`` mixer: latent attention. A query head is a no-position
+part of ``head_dim`` beside a rotary part of ``mla_rope_dim``, keys and
+values come from an RMS-normed latent of ``mla_kv_rank`` (a key head's
+no-position part and a value head of ``mla_value_dim`` each), and one rotary
+key a token is shared by all heads, so the scores are over ``head_dim +
+mla_rope_dim`` dimensions and the values of another width. It runs under
+``attn`` and through ``parts._attention``; a bound tp axis holds a shard of
+its heads (``wq``, ``wkv_b`` and ``wo`` by head, the down-projection and
+the latent's norm whole on every rank)."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax.sharding import PartitionSpec as P
+
+from .... import runtime
+from ....ops.attention import rope
+from ..config import GPTConfig, LayerSpec
+from ..parts import _attention, _norm, _tp_psum, readings, subkeys
+
+KEY, NORM, SAVED_NAMES = "mla", "mla_norm", ()
+
+
+def scope(spec: LayerSpec) -> str:
+    """``attn``, its own parts ``mla_proj`` and ``mla_rope`` inside."""
+    return "attn"
+
+
+def _parameters(cfg: GPTConfig, keys=None, dense=None, norm=None) -> dict:
+    """The query projection ``[E, H, no-position | rotary]``, the
+    down-projection to ``[latent | the shared rotary key]``, the latent's
+    norm, the up-projection ``[rank, H, key's no-position part | value]``
+    and the output projection."""
+    E, H, rank, tp = cfg.embed_dim, cfg.num_heads, cfg.mla_kv_rank, cfg.tp_axis
+    nope, rot, value = cfg.head_dim, cfg.mla_rope_dim, cfg.mla_value_dim
+    k = subkeys(keys, 4)
+
+    return {
+        "wq": (P(None, tp, None),
+               lambda: dense(k(0), (E, H, nope + rot), E)),
+        "wkv_a": (P(), lambda: dense(k(1), (E, rank + rot), E)),
+        "kv_norm": (P(), lambda: norm((rank,))),
+        "wkv_b": (P(None, tp, None),
+                  lambda: dense(k(2), (rank, H, nope + value), rank)),
+        "wo": (P(tp, None, None),
+               lambda: dense(k(3), (H, value, E), H * value)),
+    }
+
+
+init, specs = readings(_parameters)
+
+
+def apply(cfg: GPTConfig, spec: LayerSpec, p, h, positions):
+    """Latent attention (MLA, as training runs it: keys and values
+    decompressed a head) on normed activations ``h`` ``[B, S, E]``, ``H``
+    heads, ``dn = head_dim``, ``dr = mla_rope_dim``, ``dv = mla_value_dim``,
+    ``r = mla_kv_rank``: ``q_h = [qn_h (dn) | qr_h (dr)] = h W_q``; ``[c0 (r)
+    | kr0 (dr)] = h W_kv_a``; ``c = RMSNorm(c0)``; ``[kn_h (dn) | v_h (dv)] =
+    c W_kv_b``; where ``spec.rope`` says so the rotary embedding on all
+    ``dr`` dimensions of ``qr_h`` and of ``kr0``, **one** rotary key a token
+    that every head shares; ``k_h = [kn_h | kr]``; the attention
+    ``_attention`` picks, scores over ``dn + dr`` dimensions scaled by one
+    over its root, values ``dv`` wide; ``W_o``. No bias. Under a bound tp
+    axis a rank holds a shard of the heads (``W_q``, ``W_kv_b``, ``W_o``)
+    and makes the latent and the shared key whole."""
+    nope, rot, rank = cfg.head_dim, cfg.mla_rope_dim, cfg.mla_kv_rank
+    runtime.note_traced(
+        "hvdtpu_spmd_mla_traces_total", heads=cfg.num_heads, nope_dim=nope,
+        rope_dim=rot, value_dim=cfg.mla_value_dim, kv_rank=rank,
+        q_rank="none")
+    with jax.named_scope("mla_proj"):
+        q = jnp.einsum("bse,ehd->bshd", h, p["wq"].astype(cfg.dtype))
+        a = jnp.einsum("bse,ef->bsf", h, p["wkv_a"].astype(cfg.dtype))
+        c = _norm(cfg, a[..., :rank], p["kv_norm"])
+        kv = jnp.einsum("bsr,rhd->bshd", c, p["wkv_b"].astype(cfg.dtype))
+    with jax.named_scope("mla_rope"):
+        q_rot, k_rot = q[..., nope:], a[:, :, None, rank:]
+        if spec.rope:
+            q_rot = rope(q_rot, positions, cfg.rope_theta)
+            k_rot = rope(k_rot, positions, cfg.rope_theta)
+        q = jnp.concatenate([q[..., :nope], q_rot], axis=-1)
+        k = jnp.concatenate(
+            [kv[..., :nope],
+             jnp.broadcast_to(k_rot, (*kv.shape[:3], rot))], axis=-1)
+        v = kv[..., nope:]
+        if cfg.attention_multiplier is not None:
+            # As in ``attention.apply``: every attention scales by one
+            # over the root of the query's width, the rest goes onto q.
+            q = q * (cfg.attention_multiplier * float(np.sqrt(nope + rot)))
+    attn = _attention(cfg, q, k, v)
+    with jax.named_scope("mla_proj"):
+        o = jnp.einsum("bshd,hde->bse", attn, p["wo"].astype(cfg.dtype))
+    return _tp_psum(o, cfg)

@@ -1,4 +1,4 @@
-"""The mix of a CCA attention mixer (``models/gpt.py::_cca_mixer``): what
+"""The mix of a CCA attention mixer (``models/decoder/mixers/cca.py``): what
 lies between the latent projection ``u = [q0 | k0]`` and attention, as one
 pass over the latent a direction. :func:`cca_mix` is two Pallas kernels
 under one ``jax.custom_vjp`` (``hvd_cca_fwd``, ``hvd_cca_bwd``);

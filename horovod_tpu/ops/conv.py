@@ -1,6 +1,6 @@
 """The causal depthwise convolution in front of both recurrent scans
-(``ops/ssd.py``'s and ``ops/gated_delta.py``'s: ``models/gpt.py``'s two
-recurrent mixers), with its SiLU: :func:`causal_conv_silu` takes the taps,
+(``ops/ssd.py``'s and ``ops/gated_delta.py``'s: ``models/decoder/mixers``'
+two recurrent ones), with its SiLU: :func:`causal_conv_silu` takes the taps,
 the bias, the SiLU and the cast in one pass over the tensor a direction, two
 Pallas kernels under one ``jax.custom_vjp`` (``hvd_conv_fwd``,
 ``hvd_conv_bwd``). A grid cell holds about a megabyte of

@@ -113,6 +113,12 @@ _PAD = 128    # the sequence is padded to this many rows, whatever the block
 KERNEL_FWD = "hvd_flash_fwd"
 KERNEL_DKDV = "hvd_flash_dkdv"
 KERNEL_DQ = "hvd_flash_dq"
+# What this module hands ``checkpoint_name``: the forward kernel's output and
+# one lane of its log-sum-exp (2 H D + 4 H bytes a token a layer in
+# bfloat16). A ``jax.checkpoint`` that keeps both (``save_only_these_names``)
+# does not run the kernel a second time for the backward pass; with either
+# missing it runs again.
+SAVED_NAMES = ("flash_out", "flash_lse")
 
 # The scoped VMEM every call asks Mosaic for (the default, 16 MiB, does not
 # hold a 512-1024-wide float32 score tile); half of a v5e core's 128 MiB.
@@ -778,9 +784,9 @@ def _flash_bhsd(q, k, v, sm_scale, mask, kv_len, forced):
 
 def _flash_bhsd_fwd(q, k, v, sm_scale, mask, kv_len, forced):
     o, lse = _fwd_call(q, k, v, sm_scale, mask, kv_len, forced)
-    # Named, both, so that a ``jax.checkpoint`` around the caller can keep
-    # them (``models/gpt.py::SAVED_NAMES``) and not run this kernel a second
-    # time for the backward pass; outside a checkpoint a name is an identity.
+    # Named, both (``SAVED_NAMES``), so that a ``jax.checkpoint`` around the
+    # caller can keep them and not run this kernel a second time for the
+    # backward pass; outside a checkpoint a name is an identity.
     o = checkpoint_name(o, "flash_out")
     # Residual carries ONE lane of the lane-replicated stats: holding the
     # [bh, s, 128] form across the whole fwd->bwd interval would cost 128x

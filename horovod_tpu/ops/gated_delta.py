@@ -25,7 +25,7 @@ reads key head ``h // (Hv / Hk)``.
 ``q`` and ``k`` enter the rule L2-normalised a head, ``q`` over the root of
 the head's size besides. **Where the norm is made** is the static argument
 ``norm_qk`` of both forms: without it the caller made it and the rows come
-normed; with it (what ``models/gpt.py::_gdn_mixer`` passes, and nothing
+normed; with it (what ``models/decoder/mixers/gdn.py`` passes, and nothing
 else) the rows come raw, as the mixer's convolution wrote them, and the
 chunk-local kernels make it in VMEM: a row ``t`` of a key head's ``[Q, K]``
 block becomes ``t * rsqrt(sum(t * t) + 1e-6)`` in float32 (``q`` times
@@ -82,7 +82,7 @@ Hv / Hk, Q]``, turned back outside). Off the TPU the kernels run in Pallas
 interpret mode; on it a chunk they do not tile raises (:func:`_tiling`).
 The five outputs cross HBM to the recurrence, whose residuals they are, and
 **are what a checkpoint keeps**: the recurrence's rule names each
-``gdn_scan_operands``, ``models/gpt.py::SAVED_NAMES`` holds the name, and the recomputed copy of a ``remat="full"`` block does not
+``gdn_scan_operands`` (:data:`SAVED_NAMES`), and the recomputed copy of a block checkpointed under a policy that keeps the name does not
 run ``hvd_gdn_fwd`` (a value head a token the lanes' ``V`` in float32 and
 ``3 K + Q`` in ``dtype``: 738 MB a layer in the Qwen cell; they were in HBM
 already and the backward kernels read them from there either way).
@@ -187,6 +187,20 @@ KERNEL_FWD = "hvd_gdn_fwd"
 KERNEL_BWD = "hvd_gdn_bwd"
 KERNEL_REC_FWD = "hvd_gdn_rec_fwd"
 KERNEL_REC_BWD = "hvd_gdn_rec_bwd"
+# What this module hands ``checkpoint_name``, for a ``jax.checkpoint`` around
+# the caller to keep: what the recurrence's backward kernels read beside
+# their inputs. The chunk-local kernel's five outputs (``gdn_scan_operands``:
+# a value head a token the lanes' V in float32 and 3 K + Q in the compute
+# dtype, 738 MB a layer in the Qwen cell, 472 in the Olmo cell; they cross
+# HBM to the recurrence's kernels in the forward pass already) and each
+# chunk's entering state (``gdn_scan_entering``, 268 MB a layer in the Qwen
+# cell), named only where no lane of a state is padding (key and value head
+# both whole lane tiles; computed here from the shapes, no caller's option).
+# With both kept the recomputed copy runs neither ``hvd_gdn_fwd`` nor
+# ``hvd_gdn_rec_fwd``; with the five alone, the Olmo cell's 96 x 192 heads,
+# it runs the second (PERF.md, Findings, PR 56). The decays and the states
+# of a head that is carried padded stay recomputed.
+SAVED_NAMES = ("gdn_scan_operands", "gdn_scan_entering")
 _HI = lax.Precision.HIGHEST
 _NORM_EPS = 1e-6  # added to a row's sum of squares under ``norm_qk``
 _MAX_CHUNKS = 4   # chunks a grid cell, at most
@@ -947,8 +961,8 @@ def _recurrence(keep_entering, u_own, w, attn, q_in, k_out, decay, start):
 def _recurrence_fwd(keep_entering, *inputs):
     # The residuals: the inputs but the initial state, and the entering
     # states the forward kernel keeps, under the names a ``jax.checkpoint``
-    # around the caller may keep them by (``models/gpt.py::SAVED_NAMES``;
-    # outside one a name is an identity). With the entering states named
+    # around the caller may keep them by (``SAVED_NAMES``; outside one a
+    # name is an identity). With the entering states named
     # the checkpoint has this rule's every output, and the kernel reads the
     # operands unnamed: ``jax.checkpoint`` copies a kept value that the
     # forward pass reads too through a ``reduce_precision``, behind a kernel

@@ -24,7 +24,7 @@ from there. **The experts' operand may be another than the router's**
 W`` ``[T, l]`` beside ``h``; the router, the scores and the choice are made
 of ``h``, the sorted rows, a share's windows and the grouped matmuls are
 ``l`` wide, and ``y`` comes back ``[T, l]`` for the caller to project up
-(``models/gpt.py::_expert_ff``). The weights ``p_{t,e}`` are
+(``models/decoder/experts.py::apply``). The weights ``p_{t,e}`` are
 not renormalised over ``S_t`` unless ``renormalize`` asks for ``p_{t,e} / sum_{e' in S_t} p_{t,e'}`` (the
 gradient flows through the sum). With ``score="sigmoid"`` the scores are
 independent gates and the choice may lean on a bias that is no parameter:
@@ -37,7 +37,7 @@ the caller moves it from the counts the layer returns, outside the loss,
 ``models/gpt.py::update_router_bias``; ``scale`` multiplies the weights
 under either score; the division is ``renormalize``'s.) A router that is
 more than one matrix (an MLP, a state carried from layer to layer:
-``models/gpt.py::_mlp_router``) is the caller's: it hands in ``r`` itself
+``models/decoder/experts.py::_mlp_router``) is the caller's: it hands in ``r`` itself
 (``logits``) and the layer takes it from there; so is a router that reads
 something else than the experts do (the block's input before its mixer:
 ``models/gpt.py::_block``, ``router_reads="block_input"``). Everything after
@@ -94,7 +94,7 @@ beside ``y`` (the load-balance term's token fractions are constants).
 
 Under ``jax.checkpoint`` (``models/gpt.py``, ``remat="full"``) **the routing
 is made once a step.** What fixes it carries names the policy keeps
-(``checkpoint_name``, all five in ``gpt.SAVED_NAMES``): the router's outputs
+(``checkpoint_name``, all five in :data:`SAVED_NAMES`): the router's outputs
 ``r`` (``"moe_router_logits"``, ``[T, E]`` float32, the layer's own product
 or the caller's ``logits``, after the gather under a bound ``axis``; from
 ``r`` the softmax, its rule, ``router_z`` and ``load_balance`` are a pass
@@ -173,6 +173,21 @@ ROW_TILE = 512      # the grouped matmul's tile of rows
 ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu,
                "relu2": lambda t: jnp.square(jax.nn.relu(t))}
 UNGATED = ("relu2",)
+# What this module hands ``checkpoint_name``, for a ``jax.checkpoint`` around
+# the caller to keep (the docstring's "Under ``jax.checkpoint``"): the three
+# expert matrices in the compute dtype, a cast's output, and what fixes the
+# routing (PR 54): the router's outputs ``[T, E]`` float32 (the layer's own
+# product or the caller's ``logits`` alike), a token's chosen experts and
+# their scores ``[T, k]``, the sort's order and, un-windowed, its inverse ``[T
+# k]``: 4 E + 12 k to 16 k bytes a token a layer (33.5 + 2.0 MB a layer in
+# the Qwen cell, the dearest; 0.5 MB in ZAYA1's). With them the backward pass
+# makes no router's product, no full-row sort and no argsort again and
+# differentiates the routing the forward pass used, whatever a router made
+# again would have chosen (on the chip not always the same: PERF.md,
+# Findings, PR 53 and PR 54). The sorted rows, the gate and up products and
+# the activation stay recomputed.
+SAVED_NAMES = ("moe_expert_matrices", "moe_router_logits", "moe_top_experts",
+               "moe_top_weights", "moe_order", "moe_order_inverse")
 
 
 def expert_hidden(activation, product):
@@ -181,7 +196,7 @@ def expert_hidden(activation, product):
     ``act(gate) * up``, or for an un-gated form ``act(up)``. The one place
     the form is applied: the routed experts, a share's windows, their
     backward rules (autodiff of this) and the caller's shared expert
-    (``models/gpt.py::_shared_expert``) all come here."""
+    (``models/decoder/experts.py::_shared_expert``) all come here."""
     act = ACTIVATIONS[activation]
     if activation in UNGATED:
         return act(product("w_up"))
@@ -517,7 +532,7 @@ def moe_layer(x, router_w, w_gate, w_up, w_down, top_k: int,
       activation: the experts' form, one of :data:`ACTIVATIONS` (static).
       expert_in: ``[..., l]``, what the experts read where that is not what
         the router reads (a projection of ``x`` to a latent of another
-        width, the caller's: ``models/gpt.py::_expert_ff``), one row for
+        width, the caller's: ``models/decoder/experts.py``), one row for
         each of ``x``'s; None: ``x``. The sorted rows, a share's windows and
         ``y`` are then ``l`` wide and ``x`` is the router's operand alone.
 

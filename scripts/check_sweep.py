@@ -51,9 +51,9 @@ def _one_pass_dots(module) -> None:
     """``module``'s float32 products at the backend's default precision (on
     the TPU one bfloat16 pass of the MXU) where it asks for the highest: it
     sees a ``jax.numpy`` whose ``dot`` takes no notice of ``precision``.
-    ``parallel/moe.py``: the expert layer's own router; ``models/gpt.py``:
-    the MLP router and the router that reads the block's input (no other
-    line of it calls ``dot``)."""
+    ``parallel/moe.py``: the expert layer's own router;
+    ``models/decoder/experts.py``: the MLP router and the router that reads
+    the block's input (no other line of it calls ``dot``)."""
     import jax.numpy as jnp
     from jax import lax
 
@@ -75,9 +75,9 @@ def _router_in_bfloat16():
 
 
 def _mlp_router_in_bfloat16() -> None:
-    from horovod_tpu.models import gpt
+    from horovod_tpu.models.decoder import experts
 
-    _one_pass_dots(gpt)
+    _one_pass_dots(experts)
 
 
 def _parameters_in_bfloat16(job) -> None:
@@ -153,21 +153,21 @@ def _decay_sums_in_bfloat16() -> None:
 def _no_router_state() -> None:
     """Every MLP router as a stage's first: no state from the layer
     before."""
-    from horovod_tpu.models import gpt
+    from horovod_tpu.models.decoder import experts
 
-    real = gpt._mlp_router
-    gpt._mlp_router = lambda cfg, r, h, state: real(cfg, r, h, None)
+    real = experts._mlp_router
+    experts._mlp_router = lambda cfg, r, h, state: real(cfg, r, h, None)
 
 
 def _mix_in_bfloat16() -> None:
     """A CCA mixer's means, L2 norms, temperature, rotary embedding and
     grouped sums in bfloat16 where the program's kernels compute them in
-    float32 from bfloat16 inputs: ``_cca_mixer`` gets, in place of
+    float32 from bfloat16 inputs: the mixer gets, in place of
     ``ops/cca.py::cca_mix``, the plain lines the kernels are held to
     (``cca_mix_reference``), and those see a ``jax.numpy`` whose
     ``float32`` is ``bfloat16``."""
     import jax.numpy as jnp
-    from horovod_tpu.models import gpt
+    from horovod_tpu.models.decoder.mixers import cca as mixer
     from horovod_tpu.ops import cca
 
     class Rounded:
@@ -183,7 +183,7 @@ def _mix_in_bfloat16() -> None:
         finally:
             cca.jnp = jnp
 
-    gpt.cca_mix = mix
+    mixer.cca_mix = mix
 
 
 def _relu_for_its_square() -> None:

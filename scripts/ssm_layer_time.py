@@ -207,9 +207,16 @@ def main() -> int:
     if args.kind == "head":
         return head_main(args, gpt, device)
     if args.conv_minor:
-        shipped = gpt.causal_conv_silu  # ops/conv.py's, whatever ``--repo``
-        gpt.causal_conv_silu = lambda *a, minor=None, **k: \
-            shipped(*a, minor=args.conv_minor, **k)
+        # ops/conv.py's, whatever ``--repo``: in the mixers' modules, or in
+        # ``models/gpt.py`` of a checkout from before PR 57.
+        def forced(shipped):
+            return lambda *a, minor=None, **k: shipped(
+                *a, minor=args.conv_minor, **k)
+
+        for module in [m for name, m in sys.modules.items()
+                       if name.startswith("horovod_tpu.models.")
+                       and hasattr(m, "causal_conv_silu")]:
+            module.causal_conv_silu = forced(module.causal_conv_silu)
     if args.kind == "gdn":
         cfg = gpt.GPTConfig(
             vocab_size=256, num_layers=args.layers, num_heads=16,

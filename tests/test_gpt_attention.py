@@ -1,5 +1,5 @@
-"""``gpt._attention``'s table, read from the traced program: which kernel and
-which exchange a value of ``GPTConfig.attention`` puts there, with the sp
+"""``gpt._attention``'s table (``models/decoder/parts.py``), read from the
+traced program: which kernel and which exchange a value of ``GPTConfig.attention`` puts there, with the sp
 axis bound and without, and that nothing but ``"dense"`` reaches the dense
 reference. Traced (``jax.make_jaxpr``), never run: the Pallas interpreter
 that the CPU lowers a kernel through keeps no kernel name.

@@ -19,6 +19,8 @@ import pytest
 import horovod_tpu as hvd
 from horovod_tpu.models import gpt
 from horovod_tpu.models.gpt import LayerSpec
+from horovod_tpu.models.decoder import experts
+from horovod_tpu.models.decoder.mixers import cca
 
 from benchmarks.reference import gpt_cca_moe_dp as reference
 
@@ -253,10 +255,10 @@ def test_q_k_v_are_the_references_and_half_the_value_is_the_token_before(
     cfg = zaya()
     p, h, positions = mixer_inputs(cfg)
     seen = []
-    monkeypatch.setattr(gpt, "_attention",
+    monkeypatch.setattr(cca, "_attention",
                         lambda cfg, q, k, v, window=None: seen.append(
                             (q, k, v)) or q)
-    q, k, v = jax.jit(lambda p, h: (gpt._cca_mixer(
+    q, k, v = jax.jit(lambda p, h: (cca.apply(
         cfg, cfg.plan[0], p, h, positions), seen[-1])[1])(p, h)
     with jax.default_matmul_precision("highest"):
         want = jax.jit(lambda h, p: reference.cca_qkv(
@@ -287,7 +289,7 @@ def test_the_mixer_is_causal():
     cfg = zaya()
     p, h, positions = mixer_inputs(cfg)
     moved = h.at[:, 20].add(1.0)
-    mixer = jax.jit(lambda h: gpt._cca_mixer(cfg, cfg.plan[0], p, h,
+    mixer = jax.jit(lambda h: cca.apply(cfg, cfg.plan[0], p, h,
                                              positions))
     out, out_moved = mixer(h), mixer(moved)
     np.testing.assert_array_equal(out[:, :20], out_moved[:, :20])
@@ -308,8 +310,8 @@ def test_the_shares_add_up_to_the_uncut_layer():
         cfg = zaya(first_expert=first)
         share = dict(uncut, **{name: uncut[name][first:first + HELD]
                                for name in ("w_gate", "w_up", "w_down")})
-        y, aux, z = jax.jit(lambda m, cfg=cfg: gpt._expert_ff(
-            cfg, m, h, state))(share)
+        y, aux, z = jax.jit(lambda m, cfg=cfg: experts.apply(
+            cfg, None, m, h, state))(share)
         total = total + y
         counts.append(aux["counts"])
     with jax.default_matmul_precision("highest"):
@@ -335,7 +337,7 @@ def test_a_bound_tp_or_sp_axis_is_refused_by_name(spmd8, change, what):
     p, h, positions = mixer_inputs(zaya())
 
     def body(h):
-        return gpt._cca_mixer(cfg, cfg.plan[0], p, h, positions)
+        return cca.apply(cfg, cfg.plan[0], p, h, positions)
 
     with pytest.raises(ValueError, match=what):
         hvd.run_step(body, in_specs=hvd.REPLICATED,

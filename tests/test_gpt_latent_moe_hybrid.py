@@ -19,6 +19,7 @@ import pytest
 
 from horovod_tpu.models import gpt
 from horovod_tpu.models.gpt import LayerSpec
+from horovod_tpu.models.decoder.mixers import attention, ssm
 
 from benchmarks.reference import gpt_latent_moe_hybrid_dp as reference
 
@@ -233,7 +234,7 @@ def mamba_share(cfg, p, group):
     channels, its heads' ``dt_bias``, ``A_log`` and ``D``, its channels of
     the gated norm and its rows of ``W_out``."""
     per = cfg.ssm_heads // cfg.ssm_groups
-    inner, n = cfg.ssm_inner, cfg.ssm_state
+    inner, n = ssm.inner(cfg), cfg.ssm_state
     wide = per * cfg.ssm_head_dim
     gn = cfg.ssm_groups * n
     chan = np.arange(group * wide, (group + 1) * wide)
@@ -254,25 +255,26 @@ def test_the_mamba_heads_shares_add_up_to_the_uncut_mixer():
     """One group of heads each, the outputs of all the shares add up to the
     uncut mixer's and to the uncut reference's: the gated norm runs over a
     group's channels, so a group is a mixer of its own up to ``W_out``'s
-    sum (under a norm over the whole inner width, ``_ssm_mixer``'s before
+    sum (under a norm over the whole inner width, the mixer's before
     PR 55, the uncut mixer is not the reference's and no sum of shares is
     it)."""
     cfg = nemotron()
     p = seeded(cfg)["layers"][0]["ssm"]
     h, _ = _mixer_inputs(cfg)
-    whole = jax.jit(lambda p, h: gpt._ssm_mixer(cfg, p, h))(p, h)
+    whole = jax.jit(lambda p, h: ssm.apply(cfg, None, p, h, None))(p, h)
     with jax.default_matmul_precision("highest"):
         want = reference.mamba_mixer(h, p, cfg.ssm_state, cfg.norm_eps)
     np.testing.assert_allclose(whole, want, rtol=2e-5, atol=2e-5)
     share_cfg = mamba_share(cfg, p, 0)[0]
-    one = jax.jit(lambda p, h: gpt._ssm_mixer(share_cfg, p, h))
+    one = jax.jit(lambda p, h: ssm.apply(share_cfg, None, p, h, None))
     total = sum(one(mamba_share(cfg, p, g)[1], h)
                 for g in range(cfg.ssm_groups))
     np.testing.assert_allclose(total, want, rtol=2e-5, atol=2e-5)
     # The share's tree is what a model of that many heads initialises.
     share = mamba_share(cfg, p, 1)[1]
-    made = jax.eval_shape(lambda: gpt._init_ssm(
-        jax.random.PRNGKey(0), share_cfg, lambda k, s, f: jnp.zeros(s)))
+    made = jax.eval_shape(lambda: ssm.init(
+        jax.random.PRNGKey(0)[None], share_cfg,
+        lambda k, s, f: jnp.zeros(s), None))
     assert jax.tree.map(lambda a: a.shape, share) \
         == jax.tree.map(lambda a: a.shape, made)
 
@@ -288,14 +290,14 @@ def test_the_attention_heads_shares_add_up_to_the_uncut_mixer():
     with jax.default_matmul_precision("highest"):
         want = reference.attention_mixer(h, lp)
     np.testing.assert_allclose(
-        gpt._attention_mixer(cfg, spec, lp, h, positions), want, rtol=2e-5,
+        attention.apply(cfg, spec, lp, h, positions), want, rtol=2e-5,
         atol=2e-5)
     group = cfg.num_heads // cfg.kv_heads
     share_cfg = dataclasses.replace(cfg, num_heads=group, num_kv_heads=1)
     total = 0.0
     for g in range(cfg.kv_heads):
         q = slice(g * group, (g + 1) * group)
-        total = total + gpt._attention_mixer(share_cfg, spec, {
+        total = total + attention.apply(share_cfg, spec, {
             "wq": lp["wq"][:, q], "wk": lp["wk"][:, g:g + 1],
             "wv": lp["wv"][:, g:g + 1], "wo": lp["wo"][q]}, h, positions)
     np.testing.assert_allclose(total, want, rtol=2e-5, atol=2e-5)

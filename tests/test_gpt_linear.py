@@ -29,6 +29,7 @@ from jax.sharding import PartitionSpec as P
 
 import horovod_tpu as hvd
 from horovod_tpu.models import gpt
+from horovod_tpu.models.decoder.mixers import gdn
 from horovod_tpu.ops import gated_delta
 from benchmarks.reference import gpt_linear_dp as reference
 
@@ -315,7 +316,7 @@ def test_the_mixer_holds_no_float32_copy_of_q_or_k(passes):
     cfg, p, h = _mixer_and_input(jnp.bfloat16)
 
     def mixer(p, h):
-        return gpt._gdn_mixer(cfg, p, h).astype(jnp.float32).sum()
+        return gdn.apply(cfg, None, p, h, None).astype(jnp.float32).sum()
 
     jaxpr = jax.make_jaxpr(
         mixer if passes == "forward" else jax.grad(mixer, argnums=(0, 1)))(
@@ -323,7 +324,7 @@ def test_the_mixer_holds_no_float32_copy_of_q_or_k(passes):
     made = set(_shapes_in(jaxpr))
     heads = (B, S, cfg.gdn_key_heads, cfg.gdn_key_dim)
     assert (heads, jnp.dtype(jnp.bfloat16)) in made   # the kernels' q and k
-    for shape in (heads, (B, S, cfg.gdn_key_inner)):
+    for shape in (heads, (B, S, gdn.key_inner(cfg))):
         assert (shape, jnp.dtype(jnp.float32)) not in made, shape
     assert "name=hvd_gdn_" + ("bwd" if passes == "backward" else "fwd") \
         in str(jaxpr)
@@ -340,7 +341,7 @@ def test_the_kernels_norm_is_the_parents_formula(monkeypatch):
 
     def both():
         def form(p, h):
-            out = gpt._gdn_mixer(cfg, p, h)
+            out = gdn.apply(cfg, None, p, h, None)
             return jnp.sum(out * co), out
 
         with jax.default_matmul_precision("highest"):
@@ -349,7 +350,7 @@ def test_the_kernels_norm_is_the_parents_formula(monkeypatch):
         return out, grads
 
     out, grads = both()
-    shipped = gpt.gated_delta_chunked
+    shipped = gdn.gated_delta_chunked
 
     def normed_outside(q, k, *rest, norm_qk, **kw):
         assert norm_qk
@@ -357,7 +358,7 @@ def test_the_kernels_norm_is_the_parents_formula(monkeypatch):
             gated_delta.unit_rows(q, cfg.gdn_key_dim ** -0.5).astype(q.dtype),
             gated_delta.unit_rows(k).astype(k.dtype), *rest, **kw)
 
-    monkeypatch.setattr(gpt, "gated_delta_chunked", normed_outside)
+    monkeypatch.setattr(gdn, "gated_delta_chunked", normed_outside)
     want_out, want = both()
     _assert_grads_agree(out, want_out, tol=1e-5)
     _assert_grads_agree(grads, want)

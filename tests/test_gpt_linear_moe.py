@@ -29,6 +29,7 @@ from jax.sharding import PartitionSpec as P
 
 import horovod_tpu as hvd
 from horovod_tpu.models import gpt
+from horovod_tpu.models.decoder.mixers import gdn
 from horovod_tpu.parallel import moe as moe_module
 from benchmarks.reference import gpt_linear_moe_dp as reference
 
@@ -282,7 +283,7 @@ def test_full_remat_keeps_the_gdn_scans_output(make_runtime):
     # the key lanes, ``attn`` on the chunk. A head of 8 by 8 rides 128
     # lanes, so no entering state is kept.
     rows = (48 // cfg.gdn_chunk) * 3 * cfg.gdn_value_heads * cfg.gdn_chunk
-    assert kept == {"gdn_scan_out": tokens * cfg.gdn_value_inner * 4,
+    assert kept == {"gdn_scan_out": tokens * gdn.value_inner(cfg) * 4,
                     "gdn_scan_operands":
                         rows * (128 + 3 * 128 + cfg.gdn_chunk) * 4,
                     "moe_expert_matrices": experts,

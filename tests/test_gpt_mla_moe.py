@@ -20,6 +20,8 @@ from jax.sharding import PartitionSpec as P
 import horovod_tpu as hvd
 from horovod_tpu.models import gpt
 from horovod_tpu.models.gpt import LayerSpec
+from horovod_tpu.models.decoder import experts
+from horovod_tpu.models.decoder.mixers import mla
 
 from benchmarks.reference import gpt_mla_moe_dp as reference
 
@@ -222,9 +224,9 @@ def _qkv(monkeypatch, cfg, p, h, positions):
     """What the mixer hands ``_attention``."""
     seen = []
     monkeypatch.setattr(
-        gpt, "_attention", lambda cfg, q, k, v, window=None: seen.append(
+        mla, "_attention", lambda cfg, q, k, v, window=None: seen.append(
             (q, k, v)) or v)
-    gpt._mla_mixer(cfg, cfg.plan[0], p, h, positions)
+    mla.apply(cfg, cfg.plan[0], p, h, positions)
     return seen[-1]
 
 
@@ -265,7 +267,7 @@ def test_the_mixer_is_the_references_scaled_by_the_whole_key_width():
     the root of ``NOPE + ROT``; over the root of ``NOPE`` it is another."""
     cfg = moonlight(attention="flash")
     p, h, positions = mixer_inputs(cfg)
-    out = jax.jit(lambda p, h: gpt._mla_mixer(cfg, cfg.plan[0], p, h,
+    out = jax.jit(lambda p, h: mla.apply(cfg, cfg.plan[0], p, h,
                                               positions))(p, h)
     shape = dict(rope_theta=cfg.rope_theta, norm_eps=cfg.norm_eps)
     with jax.default_matmul_precision("highest"):
@@ -281,7 +283,7 @@ def test_the_mixer_is_the_references_scaled_by_the_whole_key_width():
 def test_the_mixer_is_causal():
     cfg = moonlight()
     p, h, positions = mixer_inputs(cfg)
-    mixer = jax.jit(lambda h: gpt._mla_mixer(cfg, cfg.plan[0], p, h,
+    mixer = jax.jit(lambda h: mla.apply(cfg, cfg.plan[0], p, h,
                                              positions))
     out, out_moved = mixer(h), mixer(h.at[:, 20].add(1.0))
     np.testing.assert_array_equal(out[:, :20], out_moved[:, :20])
@@ -297,12 +299,12 @@ def test_the_eight_shares_add_up_to_the_uncut_layer():
     in each."""
     uncut = seeded(moonlight(experts_held=None))["layers"][1]["moe"]
     h = jax.random.normal(jax.random.PRNGKey(5), (B, S, EMBED))
-    shared = gpt._shared_expert(moonlight(), uncut["shared"], h)
+    shared = experts._shared_expert(moonlight(), uncut["shared"], h)
     total, counts = shared, []
 
     @functools.partial(jax.jit, static_argnums=0)
     def run(first, m):
-        y, aux, _ = gpt._expert_ff(moonlight(first_expert=first), m, h)
+        y, aux, _ = experts.apply(moonlight(first_expert=first), None, m, h)
         return y, aux["counts"]
 
     for first in range(0, EXPERTS, HELD):
@@ -353,10 +355,10 @@ def test_heads_over_tp_and_the_sequence_over_sp_give_the_whole_mixer(
     whole = moonlight()
     cfg = moonlight(tp_axis="tp", sp_axis="sp", attention=attention)
     p, h, positions = mixer_inputs(whole)
-    want = gpt._mla_mixer(whole, whole.plan[0], p, h, positions)
+    want = mla.apply(whole, whole.plan[0], p, h, positions)
     seq = P(None, "sp")
     got = jax.jit(jax.shard_map(
-        lambda p, h, pos: gpt._mla_mixer(cfg, cfg.plan[0], p, h, pos),
+        lambda p, h, pos: mla.apply(cfg, cfg.plan[0], p, h, pos),
         mesh=hvd.mesh(), in_specs=(gpt.param_specs(cfg)["layers"][0]["mla"],
                                    seq, seq), out_specs=seq))(p, h, positions)
     np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-6)

@@ -17,6 +17,7 @@ import pytest
 import horovod_tpu as hvd
 from horovod_tpu.models import gpt
 from horovod_tpu.models.gpt import LayerSpec
+from horovod_tpu.models.decoder import experts
 from horovod_tpu.observability import sample_value
 
 from benchmarks.reference import gpt_prerouted_moe_dp as reference
@@ -223,7 +224,7 @@ def test_a_shared_expert_takes_the_experts_gate():
     s = params["layers"][0]["moe"]["shared"]
     want = jnp.dot(jax.nn.relu(jnp.dot(h, s["w_gate"]))
                    * jnp.dot(h, s["w_up"]), s["w_down"])
-    np.testing.assert_allclose(gpt._shared_expert(cfg, s, h), want,
+    np.testing.assert_allclose(experts._shared_expert(cfg, s, h), want,
                                rtol=1e-5, atol=1e-5)
 
 

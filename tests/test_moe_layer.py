@@ -1350,7 +1350,7 @@ def _latent_inputs(seed, routing="under", first=4):
 
 
 def _latent_layer(h, block, top_k, first=0, held=E):
-    """The layer as ``models/gpt.py::_expert_ff`` calls it, up to the
+    """The layer as ``models/decoder/experts.py::apply`` calls it, up to the
     up-projection: ``(the held experts' sum in the latent, aux)``."""
     return moe_layer(
         h, block["router"], None, block["w_up"][first:first + held],

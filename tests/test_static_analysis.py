@@ -405,6 +405,74 @@ class TestLayering:
         assert interpret
         assert not [name for name in imported if "compression" in name]
 
+    # The decoder's own arrows (``models/decoder``): what each file may
+    # import of ``horovod_tpu.models``, by prefix. ``config`` nothing of the
+    # package at all; a mixer, a feed-forward or the expert block neither
+    # ``models/gpt.py`` nor another of them.
+    DECODER = "horovod_tpu.models.decoder."
+    DECODER_MAY_IMPORT = {
+        "config.py": (), "parts.py": ("config",),
+        "feed_forward.py": ("config", "parts"),
+        "experts.py": ("config", "parts"),
+        **{f"mixers/{name}.py": ("config", "parts") for name in (
+            "attention", "cca", "gdn", "mla", "ssm")},
+        "mixers/__init__.py": ("mixers",), "__init__.py": ()}
+
+    @pytest.mark.parametrize("name", sorted(DECODER_MAY_IMPORT))
+    def test_the_decoders_arrows_point_one_way(self, name):
+        """``config <- parts <- mixers/, feed_forward, experts <-
+        models/gpt.py``, at module level or inside a function."""
+        path = os.path.join(REPO, "horovod_tpu", "models", "decoder", name)
+        own = {m for m in _imported_modules(path)
+               if m.startswith("horovod_tpu.models")
+               and m != "horovod_tpu.models.decoder"}
+        allowed = tuple(self.DECODER + part
+                        for part in self.DECODER_MAY_IMPORT[name])
+        assert not sorted(m for m in own if not m.startswith(allowed))
+        if name == "config.py":
+            assert not [m for m in _imported_modules(path)
+                        if m.startswith("horovod_tpu")]
+
+    def test_every_file_of_the_decoder_has_its_arrows_said(self):
+        files = {os.path.relpath(f, os.path.join(
+            REPO, "horovod_tpu", "models", "decoder"))
+            for f in _python_files("horovod_tpu", "models", "decoder")}
+        assert files == set(self.DECODER_MAY_IMPORT)
+
+    def test_a_kept_name_is_declared_where_it_is_born(self):
+        """Every literal a file under ``horovod_tpu/`` hands
+        ``checkpoint_name`` is in that module's own ``SAVED_NAMES``
+        (``models/gpt.py``: ``BLOCK_SAVED_NAMES``, beside the gathered
+        tuple) and in no other's, a module declares no name it does not
+        give, and ``gpt.SAVED_NAMES`` is their union: fifteen names."""
+        import importlib
+        from horovod_tpu.models import gpt
+        born = {}
+        for path in _python_files("horovod_tpu"):
+            with open(path) as f:
+                tree = ast.parse(f.read(), path)
+            calls = [node for node in ast.walk(tree)
+                     if isinstance(node, ast.Call) and "checkpoint_name" in (
+                         getattr(node.func, "id", None),
+                         getattr(node.func, "attr", None))]
+            if calls:
+                names = [call.args[1] for call in calls]
+                assert all(isinstance(n, ast.Constant) for n in names), path
+                born[os.path.relpath(path, REPO)] = {n.value for n in names}
+        assert len(born) == 8, sorted(born)
+        declared = {}
+        for path in born:
+            module = importlib.import_module(
+                path[:-len(".py")].replace(os.sep, "."))
+            declared[path] = set(
+                module.BLOCK_SAVED_NAMES if module is gpt
+                else module.SAVED_NAMES)
+        assert declared == born
+        everything = [name for names in born.values() for name in names]
+        assert len(everything) == len(set(everything)) == 15
+        assert len(gpt.SAVED_NAMES) == 15
+        assert set(gpt.SAVED_NAMES) == set(everything)
+
     def test_the_attention_reference_stands_alone(self):
         """Pure ``jax.numpy``: nothing of this package, no flax."""
         path = os.path.join(REPO, "horovod_tpu", "ops", "attention.py")
