@@ -39,9 +39,18 @@ KEY, SCOPE = "moe", "moe"
 # bytes a token a block, in token order): the up-projection's weight
 # gradient reads it, and without it the recomputed copy ran every window of
 # a share again (30 grouped matmuls a step in the compiled Nemotron step for
-# 20 with it: PERF.md, Findings, PR 55). An MLP router's hidden rows stay
-# recomputed; what fixes the routing is ``parallel/moe.py``'s to name.
-SAVED_NAMES = ("moe_latent_out",)
+# 20 with it: PERF.md, Findings, PR 55). The sublayer's first products on
+# the stream (PR 59), each made once a step with its name: the latent's
+# down-projection, the routed experts' operand (2 L bytes a token a block:
+# 16.8 MB in the Nemotron cell, ``moe_latent_ms`` 13.9 -> 12.0), and the
+# shared expert's gate and up products before the activation (2 Ms bytes a
+# token each: Nemotron's one 88 MB a block, ``moe_shared_ms`` 87.3 -> 76.3
+# and ``tok_s_chip`` +5.6%; Moonlight's two 185 MB, +2.5%; Trinity's 67 MB,
+# Qwen's 34 MB: ledger, PR 58 and PR 59; PERF.md, Findings, PR 58-59). An
+# MLP router's hidden rows stay recomputed; what fixes the routing, and
+# what lies in the sort's order, is ``parallel/moe.py``'s to name.
+SAVED_NAMES = ("moe_latent_out", "moe_latent_in",
+               "moe_shared_pre_activation")
 
 
 def routers_with_carry(cfg: GPTConfig) -> list:
@@ -150,9 +159,12 @@ def _shared_expert(cfg: GPTConfig, p, h):
     """The expert every token goes through, in the routed experts' form
     (``cfg.expert_activation``, ``parallel/moe.py::expert_hidden``):
     ``W_down (act(W_gate h) * W_up h)`` or, un-gated, ``W_down act(W_up
-    h)``; under ``sigmoid(<h, w_g>)`` where the configuration gates it."""
-    hidden = expert_hidden(cfg.expert_activation, lambda name: jnp.einsum(
-        "bse,em->bsm", h, p[name].astype(cfg.dtype)))
+    h)``; under ``sigmoid(<h, w_g>)`` where the configuration gates it. The
+    products before the activation carry a name (``SAVED_NAMES``): a
+    checkpointed block makes them once."""
+    hidden = expert_hidden(cfg.expert_activation, lambda name: checkpoint_name(
+        jnp.einsum("bse,em->bsm", h, p[name].astype(cfg.dtype)),
+        "moe_shared_pre_activation"))
     down = _tp_psum(jnp.einsum("bsm,me->bse", hidden,
                                p["w_down"].astype(cfg.dtype)), cfg)
     if not cfg.shared_expert_gate:
@@ -161,7 +173,6 @@ def _shared_expert(cfg: GPTConfig, p, h):
         "bse,e->bs", h, p["gate"].astype(cfg.dtype),
         preferred_element_type=jnp.float32))
     return (down.astype(jnp.float32) * open_[..., None]).astype(cfg.dtype)
-
 
 
 def _mlp_router(cfg: GPTConfig, r, h, state):
@@ -214,11 +225,13 @@ def apply(cfg: GPTConfig, spec, m, h, router_state=None, early=None):
     router read the block's input and not ``h``."""
     router = dict(router_w=m["router"])
     if cfg.moe_latent_dim:
-        # The experts' operand, apart from the router's: made again in the
-        # backward pass, one [T, E] x [E, L] product (nothing names it).
+        # The experts' operand, apart from the router's: one [T, E] x [E, L]
+        # product, kept by name (``SAVED_NAMES``: 2 L bytes a token), so
+        # the backward pass does not make it again.
         with jax.named_scope("latent_down"):
-            router["expert_in"] = jnp.einsum(
-                "bse,el->bsl", h, m["latent_down"].astype(cfg.dtype))
+            router["expert_in"] = checkpoint_name(jnp.einsum(
+                "bse,el->bsl", h, m["latent_down"].astype(cfg.dtype)),
+                "moe_latent_in")
     if early is not None:
         router.update(router_w=None, logits=early[1],
                       router_kind="linear_early")

@@ -119,9 +119,31 @@ otherwise in the two passes: 106 and 164 of 8192 tokens in two layers,
 PERF.md, Findings, PR 53; and when one near-tie falls the other way every
 row behind it in the sort moves by one.)
 
-What the layer still makes again for its backward pass: the sorted rows, and
-the gate and up products with their activation, and nothing else, **so long
-as the caller's backward pass has no use for the layer's output**. The down
+**The first products are made once a step too, where the layer works on all
+its rows at once** (PR 59): the sorted rows the grouped matmuls read
+(``"moe_rows"``, ``[T k, l]``) and the gate and up products before the
+activation (``"moe_pre_activation"``, ``[T k, m]`` each; the one in an
+un-gated form) carry names, and the recomputed copy of a checkpointed block
+holds no gather of the rows and no grouped matmul: **9 grouped calls a
+layer, three forward and six backward**, where it held 11. They lie in the
+sort's order, and a buffer kept in the forward's order and read in another
+gives gradients that are wrong by their own size (PERF.md, Findings, PR 28,
+which ran the same names at +4.9% and could not ship them: the router made
+again chose other experts on the chip); such a name is safe only because
+the order it lies in, ``moe_order`` and its inverse, is kept with it (PR
+54). Bytes a layer in bfloat16: 2 l + 4 m a pair (OLMoE 268 + 2 x 134 MB,
+ZAYA1's share 3 x 67 MB); each pays the unfused ``reduce_precision`` pass
+``jax.checkpoint`` puts on a kept value a Mosaic kernel makes or reads
+(PERF.md, Findings, PR 56), and the gain is net of it (``tok_s_chip`` +6.6%
+in ``olmoe-1b-7b_s4096``, +4.7% in ``zaya1-8b_s4096``: ledger, PR 58 and PR
+59; PERF.md, Findings, PR 58-59). **A share's windows name nothing**: their
+rows and products live inside :func:`_held_experts`' rule, whose backward
+pass makes each window again by design (the window at 0's residuals handed
+out is ROADMAP Speed 3's open part).
+
+What the layer still makes again for its backward pass, un-windowed: the
+activation alone (a pass over ``[T k, m]``), **so long as the caller's
+backward pass has no use for the layer's output**. The down
 projection and the weighted sum have a backward pass of their own
 (:func:`_down_and_combine`) that needs no expert's output, so their
 recomputation is dead code (a share's windows make theirs again inside the
@@ -134,12 +156,7 @@ the caller keeps ``y``: ``models/gpt.py::_block`` does, under the name
 ``"branch_out"`` (``[tokens, d]`` in token order, not the sort's), exactly
 where its block has such a norm or scale. The three expert tensors in the
 compute dtype carry a name too (``"moe_expert_matrices"``, 6 bytes an expert
-parameter in bfloat16), so the cast is made once. **Nothing whose rows lie
-in the sort's order is named yet** (the sorted rows, the two
-pre-activations: PERF.md, Findings, PR 28, +4.9% in one cell for 0.9 GiB); a
-buffer kept in the forward's order and read in another gives gradients that
-are wrong by their own size, and such a name is safe from now on only
-because the order it lies in is kept with it.
+parameter in bfloat16), so the cast is made once.
 """
 
 from __future__ import annotations
@@ -184,10 +201,16 @@ UNGATED = ("relu2",)
 # makes no router's product, no full-row sort and no argsort again and
 # differentiates the routing the forward pass used, whatever a router made
 # again would have chosen (on the chip not always the same: PERF.md,
-# Findings, PR 53 and PR 54). The sorted rows, the gate and up products and
-# the activation stay recomputed.
+# Findings, PR 53 and PR 54). And, where the layer works on all its rows at
+# once (PR 59), what lies in that kept order and is dear to make again: the
+# sorted rows ``[T k, l]`` and the gate and up products before the activation
+# ``[T k, m]`` (2 l + 4 m bytes a pair in bfloat16: 537 MB in OLMoE's one
+# layer, 201 MB in each of ZAYA1's six; a checkpointed layer then holds 9
+# grouped calls for 11; ``tok_s_chip`` +6.6% and +4.7%: ledger, PR 58 and PR
+# 59). The activation stays recomputed, and so does every window of a share.
 SAVED_NAMES = ("moe_expert_matrices", "moe_router_logits", "moe_top_experts",
-               "moe_top_weights", "moe_order", "moe_order_inverse")
+               "moe_top_weights", "moe_order", "moe_order_inverse",
+               "moe_rows", "moe_pre_activation")
 
 
 def expert_hidden(activation, product):
@@ -249,13 +272,19 @@ def _grouped(lhs, w, group_sizes, mine):
     return out if mine is None else jnp.where(mine, out, 0)
 
 
-def _hidden(activation, rows, w_gate, w_up, group_sizes, mine):
+def _hidden(activation, rows, w_gate, w_up, group_sizes, mine, keep=False):
     """The experts' hidden rows by groups (:func:`expert_hidden`):
     ``act(rows W_gate) * (rows W_up)``, or ``act(rows W_up)`` in an un-gated
-    form, whose ``w_gate`` is None."""
+    form, whose ``w_gate`` is None. ``keep``: the products carry the name
+    ``"moe_pre_activation"`` (the layer's all-rows branch; a window's live
+    inside :func:`_held_experts`' rule, which makes them again by design)."""
     matrices = {"w_gate": w_gate, "w_up": w_up}
-    return expert_hidden(activation, lambda name: _grouped(
-        rows, matrices[name], group_sizes, mine))
+
+    def product(name):
+        out = _grouped(rows, matrices[name], group_sizes, mine)
+        return checkpoint_name(out, "moe_pre_activation") if keep else out
+
+    return expert_hidden(activation, product)
 
 
 def _down_products_bwd(hidden, w_down, p_rows, g_rows, group_sizes, mine):
@@ -663,8 +692,10 @@ def moe_layer(x, router_w, w_gate, w_up, w_down, top_k: int,
         if not windowed:
             if mine is not None:
                 rows = jnp.where(mine, rows, 0)
+            # In the sort's order, which is kept with them (``SAVED_NAMES``).
+            rows = checkpoint_name(rows, "moe_rows")
             hidden = _hidden(activation, rows, w_gate, w_up, group_sizes,
-                             mine)
+                             mine, keep=True)
     if _axis_bound(tp_axis):
         # Each tp rank's share of the weights' gradient is a sum over its
         # part of the width; autodiff adds them where this cast is.

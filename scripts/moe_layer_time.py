@@ -18,7 +18,11 @@ first ``top_k`` experts (the router's columns made equal: ties go to the
 lower index). ``--routing`` times the checkpointed layer alone, by what of
 its routing the checkpoint keeps (``routing_rows``: the price of the names
 PR 54 added to ``SAVED_NAMES`` and of the form the chosen scores are read
-in; two minutes a shape).
+in; two minutes a shape). ``--first-products`` does the same by what of its
+first products it keeps where the layer works on all its rows at once
+(``first_product_rows``: the price of the names PR 59 added, the sorted rows
+and the gate and up products before the activation; ZAYA1's share is
+``--router-width 16 --experts 8 --tokens 16384 --top-k 1 --width 2048``).
 
 A rank's share (``--router-width`` wider than ``--experts``; the
 ``qwen3-next-80b-a3b_s4096`` cell's is ``--router-width 512 --experts 32
@@ -133,6 +137,24 @@ def emit(line: str) -> int:
     return 0
 
 
+def first_product_rows(checkpointed, h, weights) -> dict:
+    """ms of the checkpointed layer's forward and backward by what of its
+    first products the checkpoint keeps: neither (the gather of the sorted
+    rows and the gate and up products made again: the step before PR 59),
+    the two pre-activations alone, and the sorted rows with them (all that
+    ``gpt.SAVED_NAMES`` lists). Each row twice, in turn."""
+    first = ("moe_rows", "moe_pre_activation")
+    others = [name for name in gpt.SAVED_NAMES if name not in first]
+    rows = {"first_products_made_again": checkpointed(*others),
+            "pre_activation_kept": checkpointed(*others,
+                                                "moe_pre_activation"),
+            "SAVED_NAMES": checkpointed(*gpt.SAVED_NAMES)}
+    out = {name: [timed(f, h, *weights)] for name, f in rows.items()}
+    for name, f in rows.items():
+        out[name].append(timed(f, h, *weights))
+    return out
+
+
 def routing_rows(checkpointed, h, weights) -> dict:
     """ms of the checkpointed layer's forward and backward by what of its
     routing the checkpoint keeps: nothing of it (the router's product, the
@@ -181,6 +203,10 @@ def main() -> int:
     parser.add_argument("--routing", action="store_true",
                         help="only the checkpointed layer, by what of its "
                         "routing the checkpoint keeps (routing_rows)")
+    parser.add_argument("--first-products", action="store_true",
+                        help="only the checkpointed layer, by what of its "
+                        "first products the checkpoint keeps "
+                        "(first_product_rows)")
     args = parser.parse_args()
     T, d, m, E, k = (args.tokens, args.embed, args.width, args.experts,
                      args.top_k)
@@ -247,12 +273,13 @@ def main() -> int:
             kept, argnums=(0, 1, 2, 3, 4), has_aux=True))
 
     as_a_block_runs_it = checkpointed(*gpt.SAVED_NAMES)
-    if args.routing:
+    if args.routing or args.first_products:
+        by_what_is_kept = routing_rows if args.routing else first_product_rows
         line = json.dumps({
             "tokens": T, "embed": d, "width": m, "experts": E, "top_k": k,
             "router_width": wide, "rows": R, "skew": args.skew,
             "device_kind": device.device_kind,
-            "layer_checkpointed_fwd_bwd_ms": routing_rows(
+            "layer_checkpointed_fwd_bwd_ms": by_what_is_kept(
                 checkpointed, h, weights)})
         return emit(line)
     out = {"tokens": T, "embed": d, "width": m, "experts": E, "top_k": k,

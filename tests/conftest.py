@@ -154,3 +154,20 @@ def equations_of():
                 yield from walk(sub, within)
 
     return walk
+
+
+@pytest.fixture
+def products_like(equations_of):
+    """``products_like(jaxpr, lhs, rhs)``: how many ``dot_general``
+    equations on operands of the shapes ``lhs`` and ``rhs`` a jaxpr holds,
+    ``(outside its checkpoint equations, under them)``: of a differentiated
+    step, the forward pass's and those its backward pass makes again (the
+    backward pass's own products have other operands, where no two extents
+    are alike)."""
+    def count(jaxpr, lhs, rhs):
+        found = [inside for eqn, inside in equations_of(jaxpr)
+                 if eqn.primitive.name == "dot_general"
+                 and [v.aval.shape for v in eqn.invars] == [lhs, rhs]]
+        return len(found) - sum(found), sum(found)
+
+    return count

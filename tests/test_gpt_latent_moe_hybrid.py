@@ -169,15 +169,14 @@ def test_a_layer_with_neither_sublayer_is_refused():
 def test_a_checkpointed_latent_block_routes_once(equations_of):
     """Under ``remat="full"`` what the backward pass makes again of an
     expert block holds no router's product, no top-k and no sort (PR 54's
-    kept routing, with the experts' operand apart from the router's); the
-    down-projection to the latent is made again, one product a block."""
+    kept routing, with the experts' operand apart from the router's)."""
     cfg = nemotron(pattern="EE", remat="full", experts_held=4,
                    first_expert=4)
     params, data = seeded(cfg), batch(cfg)
     jaxpr = jax.make_jaxpr(jax.grad(
         lambda p: gpt.loss_fn(p, *data, cfg)))(params).jaxpr
     tokens, stream = B * S, cfg.embed_dim
-    again = {"sort": 0, "top_k": 0, "router": 0, "latent_down": 0}
+    again = {"sort": 0, "top_k": 0, "router": 0}
     for eqn, inside in equations_of(jaxpr):
         if not inside:
             continue
@@ -188,10 +187,28 @@ def test_a_checkpointed_latent_block_routes_once(equations_of):
         elif name == "dot_general" and shapes == [
                 (tokens, stream), (stream, cfg.num_experts)]:
             again["router"] += 1
-        elif name == "dot_general" and shapes == [
-                (B, S, stream), (stream, cfg.moe_latent_dim)]:
-            again["latent_down"] += 1
-    assert again == {"sort": 0, "top_k": 0, "router": 0, "latent_down": 2}
+    assert again == {"sort": 0, "top_k": 0, "router": 0}
+
+
+@pytest.mark.parametrize("held", [16, 4])
+def test_a_checkpointed_latent_block_makes_its_first_products_once(
+        products_like, held):
+    """Under ``remat="full"`` an expert block keeps the latent's
+    down-projection and the shared expert's pre-activation
+    (``moe_latent_in``, ``moe_shared_pre_activation``, PR 59): the
+    differentiated step holds one of each a block, the forward pass's, and
+    what the backward pass makes again holds neither (before PR 59 it held
+    both, ``(2, 2)``); every expert held or a share of them."""
+    cfg = nemotron(pattern="EE", remat="full", experts_held=held,
+                   first_expert=0 if held == 16 else 4)
+    params, data = seeded(cfg), batch(cfg)
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda p: gpt.loss_fn(p, *data, cfg)))(params).jaxpr
+    stream = (B, S, cfg.embed_dim)
+    assert products_like(jaxpr, stream, (cfg.embed_dim, cfg.moe_latent_dim)) \
+        == (2, 0)
+    assert products_like(jaxpr, stream,
+                         (cfg.embed_dim, cfg.shared_expert_dim)) == (2, 0)
 
 
 # What the reference must notice: each of these is one of the model's

@@ -291,5 +291,13 @@ def test_full_remat_keeps_the_gdn_scans_output(make_runtime):
                     "moe_top_experts": pairs * 4,
                     "moe_top_weights": pairs * 4,
                     "moe_order": pairs * index,
+                    # The shared expert's gate and up products (PR 59).
+                    "moe_shared_pre_activation":
+                        2 * tokens * cfg.shared_expert_dim * 4,
+                    # A layer that works on all its rows at once keeps the
+                    # order's inverse, the sorted rows and their gate and
+                    # up products too.
                     **({} if windowed else
-                       {"moe_order_inverse": pairs * index})}
+                       {"moe_order_inverse": pairs * index,
+                        "moe_rows": pairs * cfg.embed_dim * 4,
+                        "moe_pre_activation": 2 * pairs * cfg.mlp_dim * 4})}

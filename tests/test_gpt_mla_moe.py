@@ -370,6 +370,21 @@ def test_a_window_on_an_mla_layer_is_refused():
                   * LAYERS).plan
 
 
+def test_a_checkpointed_block_makes_its_shared_experts_products_once(
+        products_like):
+    """Under ``remat="full"`` an expert block keeps its shared expert's gate
+    and up products before the activation (``moe_shared_pre_activation``,
+    PR 59): the differentiated step holds two a block, the forward pass's,
+    and none in what the backward pass makes again (before PR 59 two)."""
+    # A width no other product of the step has.
+    cfg = moonlight(**SHIPPED, shared_expert_dim=40)
+    params = gpt.init_params(jax.random.PRNGKey(0), cfg)
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda p: gpt.loss_fn(p, *batch(cfg), cfg)))(params).jaxpr
+    assert products_like(jaxpr, (B, S, EMBED), (EMBED, 40)) \
+        == (2 * (LAYERS - DENSE_LAYERS), 0)
+
+
 def test_the_step_counts_its_mla_and_flash_traces(spmd8):
     # An eps of its own: JAX keeps what it traced of a checkpointed block by
     # the block's configuration, and a kept trace notes nothing.

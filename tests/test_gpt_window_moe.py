@@ -268,6 +268,23 @@ def test_router_probe_hands_out_what_each_router_read_and_gave(remat):
     assert not np.allclose(aux["router_inputs"][0], aux["router_inputs"][1])
 
 
+def test_a_checkpointed_block_makes_its_shared_experts_products_once(
+        products_like):
+    """Under ``remat="full"`` an expert block keeps its shared expert's gate
+    and up products before the activation (``moe_shared_pre_activation``,
+    PR 59): the differentiated step holds two a block, the forward pass's,
+    and none in what the backward pass makes again (before PR 59 two; the
+    norm after the branch keeps the sublayer's output, not what the shared
+    expert's own backward pass reads)."""
+    # A width no other product of the step has.
+    cfg = trinity(remat="full", shared_expert_dim=24)
+    params = gpt.init_params(jax.random.PRNGKey(0), cfg)
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda p: gpt.loss_fn(p, *batch(cfg), cfg)))(params).jaxpr
+    assert products_like(jaxpr, (B, S, cfg.embed_dim), (cfg.embed_dim, 24)) \
+        == (2 * (len(WINDOWS) - DENSE_LAYERS), 0)
+
+
 def test_config_field_count():
     # CHANGES.md says how many there were and are; a new one is said there.
     assert len(dataclasses.fields(gpt.GPTConfig)) == 69

@@ -89,9 +89,10 @@ def test_a_bound_ep_axis_keeps_the_groups_routing(make_runtime, moe_row_tile,
     """Under a bound ``ep`` axis the checkpointed layer names what fixes the
     routing after the gather: the policy keeps the router's outputs, the
     chosen experts and scores and the sort's order of the group's 32 tokens
-    (each rank routes all of them), with the inverse where a rank works on
-    all the rows at once, and the part the backward pass makes again holds
-    no sort, no top-k and no router's product."""
+    (each rank routes all of them), with the inverse, the sorted rows and
+    their gate and up products (PR 59) where a rank works on all the rows
+    at once, and the part the backward pass makes again holds no sort, no
+    top-k and no router's product."""
     moe_row_tile(tile)
     make_runtime(mesh_shape={"ep": 4}, devices=jax.devices()[:4])
     d, m, n_exp, top_k, tokens = 12, 32, 8, 2, 32
@@ -127,7 +128,9 @@ def test_a_bound_ep_axis_keeps_the_groups_routing(make_runtime, moe_row_tile,
             "moe_order": tokens * top_k * index,
             "moe_expert_matrices": 3 * (n_exp // 4) * d * m * 4}
     if tile == 512:
-        want["moe_order_inverse"] = tokens * top_k * index
+        want.update(moe_order_inverse=tokens * top_k * index,
+                    moe_rows=tokens * top_k * d * 4,
+                    moe_pre_activation=2 * tokens * top_k * m * 4)
     assert kept == want
 
 

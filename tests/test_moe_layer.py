@@ -269,17 +269,21 @@ def test_full_remat_keeps_what_is_dear_to_make_again(feed_forward, block):
     """Under ``remat="full"`` a block keeps the flash kernel's output and
     log-sum-exp and the dense feed-forward's pre-activation: the
     differentiated step holds no second flash forward and no second up
-    projection. The expert layer keeps its matrices in the compute dtype and
-    nothing that lies in the sort's order (the backward pass makes the
-    routing again, and on the chip not always to the same choices: PERF.md,
-    Findings, PR 28): the gate and up products run again, the down product
-    does not, because the backward pass of ``moe._down_and_combine`` has no
-    use for it. Where the block's own backward pass has a use for a branch's
-    value (a norm after the branch, a learned residual scale) the block
-    keeps that value (``branch_out``), so no branch's last product runs
-    again there either: 11 grouped matmuls a layer and not 12, and as many
-    products ``[B, S, E]`` (the down product, the output projection and the
-    backward pass's) as a step without checkpointing holds. The numbers are
+    projection. The expert layer keeps its matrices in the compute dtype,
+    what fixes its routing (PR 54) and, where it works on all its rows at
+    once as here, what lies in the sort's order and is dear to make again:
+    the sorted rows and the gate and up products before the activation
+    (``moe_rows``, ``moe_pre_activation``, PR 59; safe because the order
+    they lie in is kept with them), so the gate and up products do not run
+    again; nor does the down product, because the backward pass of
+    ``moe._down_and_combine`` has no use for it. Where the block's own
+    backward pass has a use for a branch's value (a norm after the branch,
+    a learned residual scale) the block keeps that value (``branch_out``),
+    so no branch's last product runs again there either: 9 grouped matmuls
+    a layer (3 forward, 6 backward: what a step without checkpointing
+    holds; 11 before PR 59, gate and up again), and as many products ``[B,
+    S, E]`` (the down product, the output projection and the backward
+    pass's) as a step without checkpointing holds. The numbers are
     ``"none"``'s."""
     kind = dict(moe_every=0, mlp_dim=48) if feed_forward == "dense" else {}
     full = olmoe(attention="flash", remat="full", **kind, **BLOCKS[block])
@@ -298,8 +302,9 @@ def test_full_remat_keeps_what_is_dear_to_make_again(feed_forward, block):
         assert ops["dot_general", (batch, seq, full.mlp_dim)] \
             == 2 * full.num_layers
     else:
-        # Three forward, gate and up again, two each backward.
-        assert ops["ragged_dot_general"] == 11 * full.num_layers
+        # Three forward, two each backward, none again: ``"none"``'s.
+        assert ops["ragged_dot_general"] == 9 * full.num_layers \
+            == step_ops(none, params, data)["ragged_dot_general"]
     # The stream's own shape: a block that only adds its branches makes the
     # mixer's output projection again (the feed-forward reads the stream
     # behind it) and not the feed-forward's last product; one that keeps
@@ -384,7 +389,8 @@ def test_metrics_count_what_a_checkpointed_block_keeps(make_runtime, block):
     # the expert block's three matrices an expert (float32 here: the cast
     # that carries the name is to the compute dtype) and what fixes its
     # routing: the router's outputs, a token's chosen experts and their
-    # scores, the sort's order and its inverse (every expert is held).
+    # scores, the sort's order and its inverse (every expert is held), and
+    # in that order the rows the experts read and their gate and up products.
     want = {"flash_out": 2 * tokens * heads * f32,
             "flash_lse": 2 * tokens * sparse.num_heads * f32,
             "ffn_pre_activation": tokens * sparse.mlp_dim * f32,
@@ -394,7 +400,9 @@ def test_metrics_count_what_a_checkpointed_block_keeps(make_runtime, block):
             "moe_top_experts": pairs * i32,
             "moe_top_weights": pairs * f32,
             "moe_order": pairs * index,
-            "moe_order_inverse": pairs * index}
+            "moe_order_inverse": pairs * index,
+            "moe_rows": pairs * sparse.embed_dim * f32,
+            "moe_pre_activation": 2 * pairs * sparse.mlp_dim * f32}
     if block != "adds":
         # Two branches a block, where the block's backward pass reads them.
         want["branch_out"] = 2 * 2 * tokens * sparse.embed_dim * f32
@@ -465,11 +473,14 @@ def test_compiled_step_carries_the_expert_layers_scopes(make_runtime):
     for scope in ("router", "dispatch", "experts", "combine"):
         assert some("jvp(layer0)", f"/moe/{scope}/"), scope
         assert some("transpose(jvp(layer0))", f"/moe/{scope}/"), scope
-    # The recomputed pass: the router, the sort, the gate and up products.
-    # Nothing of the combine: its backward pass needs no expert's output.
-    for scope in ("router", "dispatch", "experts"):
+    # The recomputed pass: the router's scores from its kept outputs and the
+    # activation on the kept gate and up products. Nothing of the dispatch
+    # (the order and the sorted rows are kept, PR 54 and PR 59) and nothing
+    # of the combine: its backward pass needs no expert's output.
+    for scope in ("router", "experts"):
         assert some(f"rematted_computation/moe/{scope}/"), scope
-    assert not some("rematted_computation/moe/combine/")
+    for scope in ("dispatch", "combine"):
+        assert not some(f"rematted_computation/moe/{scope}/"), scope
     assert some("jvp(aux_loss)")
     assert not some("/mlp/")
 
@@ -1175,12 +1186,15 @@ ROUTING_CASES = [(rows, router) for rows in ("all_rows", "windowed")
 ROUTED_WIDTH = 20           # no tensor's other extent: a product's shape tells
 
 
-def routed_once(rows, router, small_tile_of):
+def routed_once(rows, router, small_tile_of, activation="silu"):
     """``(f, args, tie, leaning)``: a layer of 16 experts at 3 a token on 48 tokens
     of 20, every expert held or experts 4 to 8 with a window of half their
-    even share's twice (72 of 144 rows); the router the layer's own softmax,
-    a sigmoid under a bias, or the caller's product. ``f(x, W_r, W_gate,
-    W_up, W_down)`` is a loss over the output and both auxiliary terms.
+    even share's twice (72 of 144 rows; ``"share_all_rows"``: the same share
+    at the real tile, all 144 rows at once with the other experts' held at
+    zero); the router the layer's own softmax, a sigmoid under a bias, or
+    the caller's product. ``f(x, W_r, W_gate, W_up, W_down)`` is a loss over
+    the output and both auxiliary terms (no ``W_gate`` under an un-gated
+    ``activation``; :func:`names_of` names the arguments).
 
     The router's columns come in pairs a constant vector ``v`` apart (and a
     pair's biases are equal), so a token's two scores of a pair differ by
@@ -1190,10 +1204,11 @@ def routed_once(rows, router, small_tile_of):
     (``tie`` = ``-+2e-3 v / |v|^2`` a token; ``leaning(x)`` ``[T, E]`` is
     what the choice is made on, up to a rising function)."""
     top_k, d = 3, ROUTED_WIDTH
-    first, held = (4, 4) if rows == "windowed" else (0, E)
+    first, held = (0, E) if rows == "all_rows" else (4, 4)
     if rows == "windowed":
         small_tile_of(8)
-        assert moe.share_rows(T, top_k, held, E) == 72 < T * top_k
+    assert moe.share_rows(T, top_k, held, E) == (
+        72 if rows == "windowed" else T * top_k)
     ks = jax.random.split(jax.random.PRNGKey(54), 7)
     v = jax.random.normal(ks[0], (d,), jnp.float32)
     pairs = jax.random.normal(ks[1], (d, E // 2), jnp.float32)
@@ -1207,11 +1222,15 @@ def routed_once(rows, router, small_tile_of):
          jax.random.normal(ks[5], (held, d, M), jnp.float32) / 4,
          jax.random.normal(ks[6], (held, M, d), jnp.float32) / 5)
     how = dict(top_k=top_k, dtype=jnp.float32, first_expert=first,
-               renormalize=True)
+               renormalize=True, activation=activation)
     if router == "sigmoid_bias":
         how.update(score="sigmoid", bias=bias, scale=1.5)
+    if activation in moe.UNGATED:
+        w = w[1:]
 
     def f(x, router_w, *w):
+        if activation in moe.UNGATED:
+            w = (None, *w)
         if router == "callers_logits":
             y, aux = moe_layer(
                 x, None, *w, **how, logits=jnp.dot(
@@ -1227,6 +1246,13 @@ def routed_once(rows, router, small_tile_of):
         return jax.nn.sigmoid(r) + bias if router == "sigmoid_bias" else r
 
     return f, (x, router_w, *w), tie, leaning
+
+
+def names_of(args):
+    """:func:`routed_once`'s arguments by name: two matrices an expert in an
+    un-gated form."""
+    return ("x", "W_r", "W_gate", "W_up", "W_down") if len(args) == 5 \
+        else ("x", "W_r", "W_up", "W_down")
 
 
 def routing_work(equations, rematted=False):
@@ -1270,22 +1296,27 @@ def test_a_checkpointed_layer_routes_once(moe_row_tile, equations_of, rows,
     assert routing_work(equations_of(nothing), rematted=True) == forward
 
 
+def assert_checkpointing_changes_no_gradient(f, args):
+    """Every argument's gradient under ``remat="full"``'s policy is the
+    plain layer's."""
+    wrt = tuple(range(len(args)))
+    loss, grads = jax.value_and_grad(as_a_block_runs_it(
+        lambda *a: f(*a), "full"), argnums=wrt)(*args)
+    want_loss, want = jax.value_and_grad(f, argnums=wrt)(*args)
+    np.testing.assert_allclose(loss, want_loss, rtol=1e-6)
+    for name, g, g_ref in zip(names_of(args), grads, want):
+        assert float(jnp.abs(g_ref).max()) > 0, name
+        np.testing.assert_allclose(
+            g, g_ref, rtol=1e-5, atol=1e-6 * float(jnp.abs(g_ref).max()),
+            err_msg=name)
+
+
 @pytest.mark.parametrize("rows, router", ROUTING_CASES)
 def test_kept_routing_changes_no_gradient(moe_row_tile, rows, router):
     """Tokens, router and the three expert tensors: checkpointed with the
     routing kept, the gradients are the plain layer's."""
     f, args, _, _ = routed_once(rows, router, moe_row_tile)
-    wrt = tuple(range(5))
-    loss, grads = jax.value_and_grad(as_a_block_runs_it(
-        lambda *a: f(*a), "full"), argnums=wrt)(*args)
-    want_loss, want = jax.value_and_grad(f, argnums=wrt)(*args)
-    np.testing.assert_allclose(loss, want_loss, rtol=1e-6)
-    for name, g, g_ref in zip(("x", "W_r", "W_gate", "W_up", "W_down"),
-                              grads, want):
-        assert float(jnp.abs(g_ref).max()) > 0, name
-        np.testing.assert_allclose(
-            g, g_ref, rtol=1e-5, atol=1e-6 * float(jnp.abs(g_ref).max()),
-            err_msg=name)
+    assert_checkpointing_changes_no_gradient(f, args)
 
 
 @pytest.mark.parametrize("rows, router", ROUTING_CASES)
@@ -1298,7 +1329,11 @@ def test_the_two_passes_cannot_choose_otherwise(moe_row_tile, rows, router):
     forward's routing, whatever a router made again would choose. Before
     PR 54 every token's third expert changed and the gradients were off by
     their own size."""
-    f, args, tie, leaning = routed_once(rows, router, moe_row_tile)
+    assert_turned_ties_change_no_gradient(
+        *routed_once(rows, router, moe_row_tile))
+
+
+def assert_turned_ties_change_no_gradient(f, args, tie, leaning):
     x = args[0]
     # The tie does turn the choice, to the other of one pair (for every
     # token but the few whose pair's sigmoids both round to one).
@@ -1317,11 +1352,94 @@ def test_the_two_passes_cannot_choose_otherwise(moe_row_tile, rows, router):
     moved = jax.tree_util.tree_unflatten(tree, [
         leaf + tie if hit else leaf for leaf, hit in zip(leaves, is_x)])
     got = moved(jnp.ones((), jnp.float32))
-    for name, g, g_ref in zip(("x", "W_r", "W_gate", "W_up", "W_down"),
-                              got, want):
+    for name, g, g_ref in zip(names_of(args), got, want):
         np.testing.assert_allclose(
             g, g_ref, rtol=0, atol=5e-3 * float(jnp.abs(g_ref).max()),
             err_msg=name)
+
+
+# The expert layer's first products are made once a step (PR 59). Where the
+# layer works on all its rows at once, a checkpointed block keeps the sorted
+# rows and the gate and up products before the activation (``moe_rows``,
+# ``moe_pre_activation``), in the sort's order, which is kept with them; a
+# share's windows name nothing new (their rule makes each window again).
+
+KEPT_ROWS_CASES = [("all_rows", router, "silu")
+                   for router in ("softmax", "sigmoid_bias", "callers_logits")
+                   ] + [("share_all_rows", "softmax", "silu"),
+                        ("all_rows", "softmax", "relu2")]
+ROWS_NAMES = {"moe_rows", "moe_pre_activation"}
+
+
+def names_in(equations):
+    return {eqn.params["name"] for eqn, _ in equations
+            if eqn.primitive.name == "name"}
+
+
+@pytest.mark.parametrize("rows, router, activation", KEPT_ROWS_CASES)
+def test_a_checkpointed_layer_makes_its_first_products_once(
+        moe_row_tile, equations_of, rows, router, activation):
+    """The gradient's jaxpr under ``remat="full"``'s policy holds the grouped
+    matmuls of a layer that is not checkpointed, 9 (6 un-gated: three, or
+    two, forward and twice that backward), and the down product again for
+    this loss alone, which reads the layer's output in its backward pass
+    (a block that keeps its branch has not even that:
+    ``test_full_remat_keeps_what_is_dear_to_make_again``); the rows and
+    products carry the two names. With nothing named the gate and up
+    products are made again too."""
+    f, args, _, _ = routed_once(rows, router, moe_row_tile, activation)
+    first = 1 if activation in moe.UNGATED else 2
+    wrt = tuple(range(len(args)))
+
+    def step(g):
+        return jax.make_jaxpr(jax.grad(g, argnums=wrt))(*args).jaxpr
+
+    def grouped_calls(g):
+        return ops_of(step(g))["ragged_dot_general"]
+
+    assert grouped_calls(f) == 3 * (first + 1)
+    kept = as_a_block_runs_it(lambda *a: f(*a), "full")
+    assert ROWS_NAMES <= names_in(equations_of(step(kept)))
+    assert grouped_calls(kept) == 3 * (first + 1) + 1
+    assert grouped_calls(jax.checkpoint(lambda *a: f(*a))) \
+        == 3 * (first + 1) + 1 + first
+
+
+@pytest.mark.parametrize("rows, router, activation", KEPT_ROWS_CASES)
+def test_kept_rows_change_no_gradient(moe_row_tile, rows, router,
+                                      activation):
+    """Checkpointed with the sorted rows and the pre-activations kept, every
+    gradient is the plain layer's (a share's rows of other ranks' experts,
+    held at zero, among them)."""
+    f, args, _, _ = routed_once(rows, router, moe_row_tile, activation)
+    assert_checkpointing_changes_no_gradient(f, args)
+
+
+@pytest.mark.parametrize("rows, router, activation", KEPT_ROWS_CASES)
+def test_kept_rows_are_read_in_the_order_they_were_written(
+        moe_row_tile, rows, router, activation):
+    """A buffer kept in the forward's order and read in another gives
+    gradients wrong by their own size (PERF.md, Findings, PR 28): with every
+    token's near-tie turned the other way between the passes, the kept rows
+    and products are read under the kept order and the gradients are the
+    forward's."""
+    assert_turned_ties_change_no_gradient(
+        *routed_once(rows, router, moe_row_tile, activation))
+
+
+@pytest.mark.parametrize("router", ["softmax", "sigmoid_bias",
+                                    "callers_logits"])
+def test_a_shares_windows_name_nothing_in_the_sorts_order(
+        moe_row_tile, equations_of, router):
+    """A share that works a window at a time keeps what it kept before
+    PR 59: its rows and products live inside ``moe._held_experts``' rule,
+    whose backward pass makes each window again by design."""
+    f, args, _, _ = routed_once("windowed", router, moe_row_tile)
+    kept = jax.make_jaxpr(jax.grad(as_a_block_runs_it(
+        lambda *a: f(*a), "full"), argnums=tuple(range(5))))(*args).jaxpr
+    assert names_in(equations_of(kept)) == {
+        "moe_expert_matrices", "moe_router_logits", "moe_top_experts",
+        "moe_top_weights", "moe_order"}
 
 
 # --- Un-gated squared-ReLU experts in a latent narrower than the stream ----

@@ -158,7 +158,8 @@ def passes(calls, kernel="ragged-dot-none", **where):
 
 
 def test_bare_kernels_are_placed_by_the_rows_they_read():
-    """OLMoE's block: XLA names all 11 grouped matmuls "ragged-dot-none" and
+    """OLMoE's block (a text recorded before PR 59 kept the first
+    products): XLA names all 11 grouped matmuls "ragged-dot-none" and
     nothing else; 3 forward, gate and up made again, 6 backward."""
     calls, report = calls_of("olmoe_block")
     assert report["kernels"] == {"ragged-dot-none": 11,
