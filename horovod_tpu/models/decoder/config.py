@@ -23,11 +23,24 @@ class LayerSpec:
     ``x + f(N(x))`` with one norm (a stack whose blocks are a mixer or a
     feed-forward each); a layer with neither is refused
     (:func:`layer_plan`). :attr:`sublayers` says which a block has, and
-    the parameters, the specs and ``gpt._block`` read it there."""
+    the parameters, the specs and ``gpt._block`` read it there.
+
+    **What crosses layers beside the stream is said here too**: the names
+    of the values the layer's mixer hands on (``publishes``: a mixer
+    module's ``PUBLISHES`` or none of them) and of those it is handed
+    (``reads``: its ``READS``), each read value published by a layer before
+    it (:func:`layer_plan` refuses any other order; ``gpt._hidden`` carries
+    them). ``depth`` is the layer's index in the published model where a
+    constant of the layer follows from it (differential attention's
+    ``lambda_init``) and the stack here is a selection of that model's
+    layers."""
     mixer: Optional[str] = "attention"
     window: Optional[int] = None
     rope: bool = True
     ff: Optional[str] = "dense"
+    publishes: Tuple[str, ...] = ()
+    reads: Tuple[str, ...] = ()
+    depth: Optional[int] = None
 
     @property
     def sublayers(self) -> Tuple[bool, bool]:
@@ -210,6 +223,16 @@ class GPTConfig:
     # four learned vectors of embed_dim a sublayer, ones and zeros at
     # initialisation (``parts._residual``).
     residual_scaling: bool = False
+    # The norms over the residual stream (before and after a branch, before
+    # the head), one of ``NORM_KINDS``: "rms", one weight; "layer": the mean
+    # removed, a weight and a bias (``parts._norm``; eps is norm_eps).
+    norm_kind: str = "rms"
+    # An "s6" mixer (Mamba-1's selective scan) has s6_inner channels (None:
+    # twice embed_dim) of ssm_state states each, a convolution of ssm_conv
+    # taps and a step size projected through s6_dt_rank dimensions (None:
+    # embed_dim / 16); a "gmu" mixer gates the same s6_inner channels.
+    s6_inner: Optional[int] = None
+    s6_dt_rank: Optional[int] = None
 
     @property
     def kv_heads(self) -> int:
@@ -226,6 +249,11 @@ class GPTConfig:
     @property
     def expert_width(self) -> int:
         return self.expert_dim or self.mlp_dim
+
+    @property
+    def s6_channels(self) -> int:
+        """The channels an "s6" mixer scans and a "gmu" mixer gates."""
+        return self.s6_inner or 2 * self.embed_dim
 
 
 
@@ -253,19 +281,44 @@ def layer_plan(cfg: GPTConfig) -> Tuple[LayerSpec, ...]:
         raise ValueError("layers says each layer outright: leave "
                          "layer_kinds and moe_every unset beside it")
     plan = tuple(cfg.layers)
+    _check_shared_values(plan)
     if len(plan) != cfg.num_layers or any(
             not isinstance(spec, LayerSpec) or not any(spec.sublayers)
             or (spec.window is not None
-                and (spec.mixer != "attention" or spec.window < 1))
+                and (spec.mixer not in _WINDOW_MIXERS or spec.window < 1))
             for spec in plan):
         raise ValueError(
             f"layers must hold a LayerSpec (a mixer, a feed-forward, "
             f"either of them None but not both, a window of at least one "
-            f"key on attention alone: a CCA layer has none yet, nor an MLA "
-            f"layer) for each of the {cfg.num_layers} layers, got {plan!r}")
+            f"key on one of {_WINDOW_MIXERS} alone: a CCA layer has none "
+            f"yet, nor an MLA layer) for each of the {cfg.num_layers} "
+            f"layers, got {plan!r}")
     return plan
 
 
+# The mixers that take ``LayerSpec.window``.
+_WINDOW_MIXERS = ("attention", "diff_attention")
+
+
+def _check_shared_values(plan) -> None:
+    """Every value a layer reads was published by a layer before it, and a
+    name is published once."""
+    published = {}
+    for i, spec in enumerate(plan):
+        if not isinstance(spec, LayerSpec):
+            continue
+        missing = [name for name in spec.reads if name not in published]
+        if missing:
+            raise ValueError(f"layer {i} reads {missing} that no layer "
+                             "before it publishes")
+        for name in spec.publishes:
+            if name in published:
+                raise ValueError(f"layer {i} publishes {name!r}, which "
+                                 f"layer {published[name]} already does")
+            published[name] = i
+
+
+NORM_KINDS = ("rms", "layer")
 # placement -> (a norm before each branch, a norm after it)
 NORMS = {"pre": (True, False), "pre_post": (True, True),
          "post": (False, True)}

@@ -57,9 +57,20 @@ def _rmsnorm(x, w, dtype, eps, zero_centered: bool = False):
     return (x32 * lax.rsqrt(var + eps) * w).astype(dtype)
 
 
+def _layernorm(x, w, b, dtype, eps):
+    x32 = x.astype(jnp.float32)
+    x32 = x32 - jnp.mean(x32, axis=-1, keepdims=True)
+    var = jnp.mean(x32 * x32, axis=-1, keepdims=True)
+    return (x32 * lax.rsqrt(var + eps) * w + b).astype(dtype)
+
+
 def _norm(cfg: GPTConfig, x, w):
-    """The model's RMSNorm: over the last axis, ``1 + w`` if the
-    configuration centres its weights at zero."""
+    """The model's norm over the last axis: an RMSNorm, ``1 + w`` if the
+    configuration centres its weights at zero; where ``w`` is a stream
+    norm's ``{"weight", "bias"}`` (``GPTConfig.norm_kind = "layer"``), a
+    LayerNorm."""
+    if isinstance(w, dict):
+        return _layernorm(x, w["weight"], w["bias"], cfg.dtype, cfg.norm_eps)
     return _rmsnorm(x, w, cfg.dtype, cfg.norm_eps, cfg.norm_zero_centered)
 
 

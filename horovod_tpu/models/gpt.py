@@ -39,6 +39,14 @@ resolves ``GPTConfig.norms`` (``"pre"``: ``x + f(N(x))``, the default;
 older ``post_norm`` into ``(a norm before each branch, a norm after it)``.
 Under ``residual_scaling`` a sublayer joins the stream as ``a_r (x + b_r) +
 a_h (f(N(x)) + b_h)``, four learned vectors a sublayer (``parts._residual``).
+**What crosses layers beside the stream is one carry** (:func:`_hidden`): a
+mixer may hand a value on to later layers (``LayerSpec.publishes``; a
+Mamba-1 layer's scan output, a differential attention layer's keys and
+values) and a later one read it (``LayerSpec.reads``; a Gated Memory Unit,
+a cross-attention layer), and an MLP router's state from expert block to
+expert block rides the same carry; ``layer_plan`` refuses a reader with no
+producer before it. The stream's norms are RMSNorms or, under
+``norm_kind="layer"``, LayerNorms.
 """
 
 import functools
@@ -51,13 +59,13 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
 from .. import runtime
-from ..ops import flash_attention, gated_delta
+from ..ops import flash_attention, gated_delta, s6
 from ..ops.pallas_util import varying_like
 from ..parallel import moe
 from ..parallel.axes import axis_bound as _axis_bound
 from .decoder import experts, feed_forward
-from .decoder.config import (NORMS, GPTConfig, LayerSpec,  # noqa: F401
-                             layer_plan, norm_placement)
+from .decoder.config import (NORM_KINDS, NORMS, GPTConfig,  # noqa: F401
+                             LayerSpec, layer_plan, norm_placement)
 from .decoder.experts import (ROUTER_READS, ROUTERS,  # noqa: F401
                               trainable, update_router_bias)
 from .decoder.mixers import MIXERS
@@ -76,7 +84,13 @@ def _sublayers(spec: LayerSpec) -> tuple:
             f"layers / layer_kinds must name a layer's mixer one of "
             f"{tuple(MIXERS)}, its feed-forward one of "
             f"{tuple(FEED_FORWARDS)}, got {spec!r}")
-    return MIXERS.get(spec.mixer), FEED_FORWARDS.get(spec.ff)
+    mixer = MIXERS.get(spec.mixer)
+    reads = getattr(mixer, "READS", ())
+    publishes = getattr(mixer, "PUBLISHES", ())
+    if spec.reads != reads or set(spec.publishes) - set(publishes):
+        raise ValueError(f"a {spec.mixer!r} mixer reads {reads} and may "
+                         f"publish {publishes}, got {spec!r}")
+    return mixer, FEED_FORWARDS.get(spec.ff)
 
 
 # A sublayer's residual scaling (``GPTConfig.residual_scaling``): on the
@@ -115,12 +129,24 @@ def _tree(cfg: GPTConfig, rng=None) -> dict:
     def vector(init=norm):
         return init((E,)) if make else P()
 
+    if cfg.norm_kind not in NORM_KINDS:
+        raise ValueError(f"norm_kind must be one of {NORM_KINDS}, got "
+                         f"{cfg.norm_kind!r}")
+
+    def stream_norm():
+        """A norm over the residual stream: an RMSNorm's weight, or a
+        LayerNorm's weight and bias (``parts._norm`` tells them apart)."""
+        if cfg.norm_kind == "rms":
+            return vector()
+        return {"weight": vector(), "bias": vector(functools.partial(
+            jnp.zeros, dtype=jnp.float32))}
+
     before, after = norm_placement(cfg)
     keys = jax.random.split(rng, 2 + cfg.num_layers) if make else None
     tree: dict = {
         "embed": jax.random.normal(keys[0], (cfg.vocab_size, E),
                                    jnp.float32) * 0.02 if make else P(),
-        "out_norm": vector(), "layers": []}
+        "out_norm": stream_norm(), "layers": []}
     if not cfg.tie_embeddings:
         tree["lm_head"] = dense(keys[1], (E, cfg.vocab_size), E) if make \
             else P()
@@ -135,7 +161,7 @@ def _tree(cfg: GPTConfig, rng=None) -> dict:
                 else mixer.specs(cfg)
             layer.update(own if mixer.KEY is None else {mixer.KEY: own})
         for name in _norm_names(spec, before, after):
-            layer[name] = vector()
+            layer[name] = stream_norm()
         for name, has in zip(_RESIDUAL_KEYS, spec.sublayers):
             if has and cfg.residual_scaling:
                 layer[name] = {
@@ -162,15 +188,28 @@ def param_specs(cfg: GPTConfig) -> dict:
     return _tree(cfg)
 
 
+# The router state's name in the carry, beside the mixers' published names.
+_ROUTER_STATE = "router_state"
+
+
 def _block(cfg: GPTConfig, spec: LayerSpec, layer_params, x, positions,
-           router_state=None):
-    """One decoder block, as ``spec`` says it is: ``(x, aux, router
-    state)``, ``aux`` the expert layer's auxiliary terms
-    (``parallel/moe.py``) or None for a block without one, the router state
-    what an MLP router hands from one expert block to the next (a block
-    without one hands on what it was given; None under linear routers). A
-    block is its mixer's sublayer and then its feed-forward's, or the one
-    of the two it has (``spec.sublayers``)."""
+           carry=None):
+    """One decoder block, as ``spec`` says it is: ``(x, aux, handed on)``,
+    ``aux`` the expert layer's auxiliary terms (``parallel/moe.py``) or None
+    for a block without one. A block is its mixer's sublayer and then its
+    feed-forward's, or the one of the two it has (``spec.sublayers``).
+
+    ``carry`` is what the block is handed beside the stream, by name
+    (``_hidden`` holds the one carry and hands a block what it reads): the
+    values its mixer reads (``spec.reads``, published by a layer before it)
+    and, for an expert block, the router state an MLP router hands from one
+    expert block to the next. ``handed on`` is what the block adds to the
+    carry: what its mixer publishes (``spec.publishes``) and the new router
+    state. Under ``jax.checkpoint`` a read value is an input of the block
+    and a published one an output: nothing of the producer is made again
+    for a reader, and the producer's backward pass receives the sum of its
+    readers' cotangents."""
+    carry, handed_on = carry or {}, {}
     # The scopes sit inside the function ``jax.checkpoint`` wraps, so the
     # recomputed copy of a block carries them too (``forward`` has the rest).
     lp = layer_params
@@ -212,18 +251,23 @@ def _block(cfg: GPTConfig, spec: LayerSpec, layer_params, x, positions,
         with jax.named_scope(mixer.scope(spec)):
             branch = mixer.apply(
                 cfg, spec, lp if mixer.KEY is None else lp[mixer.KEY],
-                before(mixer.NORM), positions)
+                before(mixer.NORM), positions,
+                *(carry[name] for name in spec.reads))
+            if getattr(mixer, "PUBLISHES", ()):
+                branch, handed_on = branch
             x = _residual(cfg, x, after(branch, "mixer_post_norm"),
                           mixer_res)
 
     if ff is None:
-        return x, None, router_state
+        return x, None, handed_on
     with jax.named_scope(ff.SCOPE):
         out, aux, router_state = ff.apply(
             cfg, spec, lp if ff.KEY is None else lp[ff.KEY],
-            before("mlp_norm"), router_state, early)
+            before("mlp_norm"), carry.get(_ROUTER_STATE), early)
+        if router_state is not None:
+            handed_on = {**handed_on, _ROUTER_STATE: router_state}
         return _residual(cfg, x, after(out, "mlp_post_norm"),
-                         mlp_res), aux, router_state
+                         mlp_res), aux, handed_on
 
 
 # What ``remat="full"`` keeps from a block's forward pass beside its input:
@@ -243,7 +287,7 @@ def _block(cfg: GPTConfig, spec: LayerSpec, layer_params, x, positions,
 # ``olmo-hybrid-7b_s8192`` +3.9% for 8 of 60, ``zaya1-8b_s4096`` +4.6%, 12 of 64
 BLOCK_SAVED_NAMES = ("branch_out",)
 SAVED_NAMES = (flash_attention.SAVED_NAMES + gated_delta.SAVED_NAMES
-               + moe.SAVED_NAMES + BLOCK_SAVED_NAMES + sum(
+               + s6.SAVED_NAMES + moe.SAVED_NAMES + BLOCK_SAVED_NAMES + sum(
                    (part.SAVED_NAMES for part in dict.fromkeys(
                        (*MIXERS.values(), *FEED_FORWARDS.values()))), ()))
 _save_names = jax.checkpoint_policies.save_only_these_names(*SAVED_NAMES)
@@ -263,8 +307,8 @@ def _full_policy(prim, *avals, **params):
 
 
 def _block_fn(cfg: GPTConfig):
-    """The per-layer apply ``(cfg, spec, layer_params, x, positions, router
-    state)``, optionally wrapped in ``jax.checkpoint`` (cfg and a layer's spec
+    """The per-layer apply ``(cfg, spec, layer_params, x, positions,
+    carry)``, optionally wrapped in ``jax.checkpoint`` (cfg and a layer's spec
     are frozen dataclasses, so they ride static_argnums)."""
     if cfg.remat == "none":
         return _block
@@ -293,12 +337,22 @@ def _hidden(params, tokens, positions, cfg: GPTConfig):
         if cfg.embedding_multiplier != 1.0:
             x = x * cfg.embedding_multiplier
     block = _block_fn(cfg)
-    auxes, router_state = [], None
-    for i, (spec, lp) in enumerate(zip(cfg.plan, params["layers"],
-                                       strict=True)):
+    # The one carry: what crosses blocks beside the stream, by name. A block
+    # is handed what it reads of it and no more (an expert block the router
+    # state, a mixer its ``spec.reads``).
+    auxes, carry, plan = [], {}, cfg.plan
+    for i, (spec, lp) in enumerate(zip(plan, params["layers"], strict=True)):
+        wanted = spec.reads + (
+            (_ROUTER_STATE,) if FEED_FORWARDS.get(spec.ff) is experts else ())
         with jax.named_scope(f"layer{i}"):
-            x, aux, router_state = block(cfg, spec, lp, x, positions,
-                                         router_state)
+            x, aux, handed_on = block(
+                cfg, spec, lp, x, positions,
+                {name: carry[name] for name in wanted if name in carry})
+        carry.update(handed_on)
+        for name in spec.publishes:
+            runtime.note_traced(
+                "hvdtpu_spmd_shared_values_total", value=name, producer=i,
+                readers=sum(name in later.reads for later in plan[i + 1:]))
         if aux is not None:
             auxes.append(aux)
     with jax.named_scope("head"):

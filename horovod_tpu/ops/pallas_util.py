@@ -1,8 +1,8 @@
 """What the Pallas kernel families share, said once: the tile's widths, the
 masked exponent, the two contraction patterns, the platform test and a few
 helpers. The floor of ``ops/``: it imports nothing of the package, and every
-kernel module (``flash_attention``, ``ssd``, ``gated_delta``, ``conv``,
-``cca``) imports these names from here and no kernel's part from another
+kernel module (``flash_attention``, ``ssd``, ``s6``, ``gated_delta``,
+``conv``, ``cca``) imports these names from here and no kernel's part from another
 (``cca`` takes two plain references, ``conv.causal_conv1d`` and
 ``attention.rope``, for the lines its kernels are held to)."""
 

@@ -152,6 +152,31 @@ _TRACED = {
         "kernels, by kernel and the tiling the call got: the chunk, the "
         "heads a grid cell holds, the MXU operands' dtype.",
         ("kernel", "chunk", "heads_per_block", "operand_dtype")),
+    "hvdtpu_spmd_s6_traces_total": (
+        "Times JAX traced a Mamba-1 mixer (the recomputed copy of a block "
+        "counts again), by its channels, the states a channel, the rank of "
+        "the step size's projection, the convolution's taps, and whether "
+        "the layer hands its scan's output on to later layers.",
+        ("channels", "state", "dt_rank", "conv", "publishes")),
+    "hvdtpu_spmd_s6_kernel_traces_total": (
+        "Times JAX traced one of the selective scan's kernels (the state of "
+        "a block of channels in VMEM across a grid axis over tokens), by "
+        "kernel and what the call got: the tokens and channels it walks "
+        "(padded to whole blocks), the states a channel, the tokens a grid "
+        "cell, the dtype of u and y.",
+        ("kernel", "tokens", "channels", "state", "chunk", "operand_dtype")),
+    "hvdtpu_spmd_diff_attention_traces_total": (
+        "Times JAX traced a differential attention mixer (two softmax maps "
+        "a pair of heads, two calls of the attention kernels; the recomputed "
+        "copy of a block counts again), by its query pairs, key/value "
+        "pairs, a key head's size (a value head is twice that), the window "
+        "(0: none), and whether it reads an earlier layer's keys and values.",
+        ("pairs", "kv_pairs", "head_dim", "window", "cross")),
+    "hvdtpu_spmd_shared_values_total": (
+        "Times JAX traced a stack in which a layer hands a value on to later "
+        "layers beside the stream, by the value's name, the layer that "
+        "publishes it and how many later layers read it.",
+        ("value", "producer", "readers")),
     "hvdtpu_spmd_gdn_kernel_traces_total": (
         "Times JAX traced one of the gated delta rule's kernels (the "
         "chunk-local pair, the recurrence over chunks' pair), by kernel and "

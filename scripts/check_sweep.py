@@ -88,11 +88,20 @@ def _parameters_in_bfloat16(job) -> None:
     import jax
     import jax.numpy as jnp
 
-    real = job._step_with_aux
-
     def rounded(tree):
         return jax.tree.map(
             lambda p: p.astype(jnp.bfloat16).astype(p.dtype), tree)
+
+    if not hasattr(job, "_step_with_aux"):    # a job without auxiliary terms
+        whole = job._train_step
+
+        def train_step(params, opt_state, data):
+            params, opt_state, loss = whole(rounded(params), opt_state, data)
+            return rounded(params), opt_state, loss
+
+        job._train_step = train_step
+        return
+    real = job._step_with_aux
 
     def step(params, opt_state, data):
         (params, opt_state, loss), aux = real(rounded(params), opt_state,
@@ -213,7 +222,72 @@ def _no_latent_down(job) -> None:
     job._loss = loss
 
 
-# name -> what it does to a job already built (its step not yet traced)
+def _patched(module, name: str, value):
+    """``module.name`` set to ``value``; returns what sets it back (a
+    process that runs several variants in turn, ``--variants``)."""
+    real = getattr(module, name)
+    setattr(module, name, value)
+    return lambda: setattr(module, name, real)
+
+
+def _memory_after_gate():
+    """A publishing Mamba-1 layer hands on ``y * silu(z)`` where the model
+    hands on the scan's output before the gate."""
+    from horovod_tpu.models.decoder.mixers import s6
+
+    return _patched(s6, "_published", lambda y, gated: gated)
+
+
+def _kv_from_the_window_layer(job) -> None:
+    """The cross layers read the keys and values of the first differential
+    attention layer of the stack (a window layer's) where the model's read
+    the full layer's."""
+    plan = list(job.cfg.plan)
+    first, last = (i for i, spec in enumerate(plan)
+                   if spec.mixer == "diff_attention")
+    plan[first] = dataclasses.replace(plan[first], publishes=("diff_kv",))
+    plan[last] = dataclasses.replace(plan[last], publishes=())
+    _replace(job, layers=tuple(plan))
+
+
+def _no_norm_bias():
+    """The LayerNorms without their bias."""
+    from horovod_tpu.models.decoder import parts
+
+    real = parts._layernorm
+    return _patched(parts, "_layernorm", lambda x, w, b, dtype, eps: real(
+        x, w, 0.0 * b, dtype, eps))
+
+
+def _s6_decays_in_bfloat16():
+    """The selective scan's decays ``exp(dt A)`` rounded to bfloat16:
+    ``ops/s6.py``'s kernels see a ``jax.numpy`` whose ``exp`` rounds."""
+    import jax.numpy as jnp
+    from horovod_tpu.ops import s6
+
+    class RoundedDecays:
+        def __getattr__(self, name):
+            return getattr(jnp, name)
+
+        @staticmethod
+        def exp(x):
+            return jnp.exp(x).astype(jnp.bfloat16).astype(jnp.float32)
+
+    return _patched(s6, "jnp", RoundedDecays())
+
+
+def _lambda_init_of(depth_of):
+    """Every differential layer's ``lambda_init`` from ``depth_of(the
+    published index)``."""
+    from horovod_tpu.models.decoder.mixers import diff_attention
+
+    real = diff_attention.lambda_init
+    return _patched(diff_attention, "lambda_init",
+                    lambda depth: real(depth_of(depth)))
+
+
+# name -> what it does to a job already built (its step not yet traced);
+# what it returns, if anything, undoes it
 VARIANTS = {
     "full_causal": lambda job: _replace(job, layers=tuple(
         dataclasses.replace(spec, window=None) for spec in job.cfg.plan)),
@@ -247,6 +321,18 @@ VARIANTS = {
     "params_bf16": _parameters_in_bfloat16,
     "relu_not_squared": lambda job: _relu_for_its_square(),
     "no_latent_down": _no_latent_down,
+    "memory_after_gate": lambda job: _memory_after_gate(),
+    "kv_from_window_layer": _kv_from_the_window_layer,
+    "lambda_depth_local": lambda job: _replace(job, layers=tuple(
+        spec if spec.depth is None else dataclasses.replace(spec, depth=i)
+        for i, spec in enumerate(job.cfg.plan))),
+    "lambda_init_zero": lambda job: _lambda_init_of(lambda depth: 0),
+    "window_plus_one": lambda job: _replace(job, layers=tuple(
+        spec if spec.window is None
+        else dataclasses.replace(spec, window=spec.window + 1)
+        for spec in job.cfg.plan)),
+    "no_norm_bias": lambda job: _no_norm_bias(),
+    "s6_decays_bf16": lambda job: _s6_decays_in_bfloat16(),
 }
 
 
@@ -261,6 +347,11 @@ def main() -> int:
     parser.add_argument("--first-seed", type=int, default=2147484000)
     parser.add_argument("--rehearsal", action="store_true")
     parser.add_argument("--variant", choices=sorted(VARIANTS))
+    parser.add_argument(
+        "--variants", nargs="+", choices=sorted(VARIANTS), default=(),
+        help="for each seed the shipped program and then each of these, "
+        "each undone before the next (a job with a reference_cache "
+        "computes its reference once a seed)")
     args = parser.parse_args()
     from benchmarks import run
 
@@ -289,32 +380,49 @@ def main() -> int:
     jobs = importlib.import_module(f"benchmarks.jobs.{config['job']}")
     worst: dict = {}
     correct = 0
+    references: dict = {}   # --variants: a seed's reference, computed once
     os.makedirs(os.path.join(ROOT, "chiprun_out"), exist_ok=True)
     with open(os.path.join(ROOT, "chiprun_out", "check_sweep.jsonl"),
               "a") as out:
-        for seed in range(args.first_seed, args.first_seed + args.seeds):
+        for seed, variant in (
+                (seed, variant)
+                for seed in range(args.first_seed,
+                                  args.first_seed + args.seeds)
+                for variant in ((None, *args.variants) if args.variants
+                                else (args.variant,))):
+            if args.variants:
+                # A kernel's call is traced once for a shape (``jit(...,
+                # inline=True)``): a variant that changes what a kernel's
+                # module sees needs it traced anew, and so does the next.
+                jax.clear_caches()
             job = jobs.Job(config, traffic, seed)
-            if args.variant:
+            if args.variants and hasattr(job, "reference_cache"):
+                job.reference_cache = references
+            undo = None
+            if variant:
                 # The shipped program's parameters, made before; the
                 # optimizer state comes after the reference, as in run.py
                 # (16 bytes a parameter and the reference's gradient do
                 # not fit a chip together).
                 job._params
-                VARIANTS[args.variant](job)
+                undo = VARIANTS[variant](job)
             line = {"workload": args.workload, "seed": seed,
-                    "rehearsal": args.rehearsal, "variant": args.variant}
+                    "rehearsal": args.rehearsal, "variant": variant}
             for what, got, want, rtol in job.check()():
                 err = abs(got - want) / abs(want)
                 line[what] = {"program": got, "reference": want, "rel": err,
                               "allowed": rtol}
-                worst[what] = max(worst.get(what, 0.0), err)
+                if variant == args.variant:     # --variants: the shipped
+                    worst[what] = max(worst.get(what, 0.0), err)
             # ``run.py``'s own rule on these rows: off by no more than the
             # limit, each of them.
             line["missed"] = [what for what, row in line.items()
                               if isinstance(row, dict)
                               and not row["rel"] <= row["allowed"]]
             line["correct"] = not line["missed"]
-            correct += line["correct"]
+            correct += line["correct"] and variant == args.variant
+            if callable(undo):
+                undo()
             counts = getattr(job, "expert_counts", None)
             if counts is not None:
                 line["busiest_over_mean"] = float(
@@ -328,7 +436,8 @@ def main() -> int:
             for array in jax.live_arrays():
                 array.delete()
         last = {"workload": args.workload, "seeds": args.seeds,
-                "variant": args.variant, "largest_rel": worst,
+                "variant": args.variant or list(args.variants) or None,
+                "largest_rel": worst,
                 "seeds_correct": correct}
         print(json.dumps(last), flush=True)
         out.write(json.dumps(last) + "\n")

@@ -23,12 +23,13 @@ TINY = dict(vocab_size=64, num_layers=2, num_heads=4, head_dim=8,
 
 # The per-model test files' own configurations, by the file and the name
 # that builds one: between them every mixer, both routers, a shared expert,
-# a selection bias, the latent experts and the residual scaling.
+# a selection bias, the latent experts, the residual scaling, LayerNorms and
+# the values that cross layers.
 MODELS = {"trinity": "test_gpt_window_moe", "zaya": "test_gpt_cca_moe",
           "moonlight": "test_gpt_mla_moe",
           "nemotron": "test_gpt_latent_moe_hybrid",
           "smallthinker": "test_gpt_prerouted_moe",
-          "TINY": "test_gpt_linear_moe"}
+          "TINY": "test_gpt_linear_moe", "sambay12": "test_gpt_sambay"}
 
 
 def model(name):
@@ -59,7 +60,9 @@ def _same_keys(values, specs):
 
 @pytest.mark.parametrize("mixer,name", [
     ("attention", "trinity"), ("attention", "TINY"), ("cca", "zaya"),
-    ("mla", "moonlight"), ("ssm", "nemotron"), ("gdn", "TINY")])
+    ("mla", "moonlight"), ("ssm", "nemotron"), ("gdn", "TINY"),
+    ("s6", "sambay12"), ("gmu", "sambay12"), ("diff_attention", "sambay12"),
+    ("diff_cross", "sambay12")])
 def test_a_mixers_init_and_specs_hold_the_same_keys(mixer, name):
     cfg, module = model(name), gpt.MIXERS[mixer]
     assert mixer in {spec.mixer for spec in cfg.plan}
@@ -147,12 +150,13 @@ FIELDS = {
     "mla_rope_dim": 64, "mla_value_dim": 128, "router_kind": "linear",
     "router_dim": 256, "router_reads": "ff_input",
     "expert_activation": "silu", "moe_latent_dim": 0,
-    "residual_scaling": False,
+    "residual_scaling": False, "norm_kind": "rms", "s6_inner": None,
+    "s6_dt_rank": None,
 }
 
 
 def test_the_configurations_fields_are_the_frozen_list():
-    assert len(FIELDS) == 69
+    assert len(FIELDS) == 72
     assert {f.name: f.default for f in dataclasses.fields(gpt.GPTConfig)} \
         == FIELDS
     cfg = gpt.GPTConfig(num_kv_heads=2, expert_dim=48)

@@ -21,7 +21,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from horovod_tpu.ops import flash_attention as fa
-from horovod_tpu.ops import cca, conv, gated_delta, pallas_util, ssd
+from horovod_tpu.ops import cca, conv, gated_delta, pallas_util, s6, ssd
 
 
 @pytest.fixture(scope="module")
@@ -77,6 +77,13 @@ LONGEST = {
     "smallthinker-21b-a3b_s16384": (28, 4, 16384, 128, jnp.bfloat16, True),
     "smallthinker-21b-a3b_s16384_window": (28, 4, 16384, 128, jnp.bfloat16,
                                            True, 4096),
+    # One map of a differential attention layer: 20:10 pairs of heads, keys
+    # of 64 beside values of 128 (a pair's two value heads side by side),
+    # whole and under a band of 512, narrower than the 1024-wide tile.
+    "phi-4-mini-flash-reasoning_s16384": (20, 10, 16384, (64, 128),
+                                          jnp.bfloat16, True),
+    "phi-4-mini-flash-reasoning_s16384_window": (20, 10, 16384, (64, 128),
+                                                 jnp.bfloat16, True, 512),
 }
 FUSED_SHAPES = {
     **SHAPES,
@@ -281,6 +288,40 @@ def test_conv_kernel_compiles_for_v5e(one_chip, mosaic, shape, kernel):
         f, first=0, tokens_minor=minor == "tokens")).lower(*args).compile() \
         .as_text()
     assert "tpu_custom_call" in text and f"hvd_conv_{kernel}" in text
+
+
+# (B, S, channels, states, dtype): Mamba-1's selective scan at the
+# phi-4-mini-flash-reasoning_s16384 cell's shape (40 lane tiles of channels,
+# the 16 states on the sublanes, 128 blocks of 128 tokens), and a small one
+# whose channels are carried to a lane tile.
+S6_SHAPES = {
+    "phi-4-mini-flash-reasoning_s16384": (1, 16384, 5120, 16, jnp.bfloat16),
+    "small_float32": (2, 200, 72, 8, jnp.float32),
+}
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "bwd"])
+@pytest.mark.parametrize("shape", list(S6_SHAPES))
+def test_s6_kernel_compiles_for_v5e(one_chip, mosaic, shape, grad):
+    batch, seq, channels, state, dtype = S6_SHAPES[shape]
+
+    def sds(*dims, dt=jnp.float32):
+        return jax.ShapeDtypeStruct(dims, dt, sharding=one_chip)
+
+    args = (sds(batch, seq, channels, dt=dtype), sds(batch, seq, channels),
+            sds(channels, state), sds(batch, seq, state, dt=dtype),
+            sds(batch, seq, state, dt=dtype), sds(channels))
+
+    def scan(*a):
+        return s6.selective_scan(*a).astype(jnp.float32).sum()
+
+    text = jax.jit(jax.grad(scan, argnums=tuple(range(6))) if grad
+                   else scan).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text and s6.KERNEL_FWD in text
+    assert (s6.KERNEL_BWD in text) == grad
+    # The states of a sequence, [T, C, N] float32, are made nowhere.
+    assert f"f32[{batch},{seq},{channels},{state}]" not in text
+    assert f"f32[{batch},{seq},{state},{channels}]" not in text
 
 
 # (B, S, query heads, key heads, head size, rotary dimensions): a CCA

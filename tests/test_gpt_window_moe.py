@@ -226,7 +226,8 @@ def test_older_inputs_resolve_into_the_plan_in_one_place():
     (dict(layers=(LayerSpec(),) * 3), "for each of the 4"),
     (dict(layers=(LayerSpec(mixer="conv"),) * 4), "mixer one of"),
     (dict(layers=(LayerSpec(ff="experts_shared"),) * 4), "feed-forward one"),
-    (dict(layers=(LayerSpec(mixer="ssm", window=4),) * 4), "on attention"),
+    (dict(layers=(LayerSpec(mixer="ssm", window=4),) * 4),
+         "on one of .*attention.* alone"),
     (dict(layers=(LayerSpec(window=0),) * 4), "at least one"),
     (dict(layers=(LayerSpec(),) * 4, moe_every=1), "leave"),
     (dict(layers=(LayerSpec(),) * 4, layer_kinds=("attention",) * 4),
@@ -287,8 +288,8 @@ def test_a_checkpointed_block_makes_its_shared_experts_products_once(
 
 def test_config_field_count():
     # CHANGES.md says how many there were and are; a new one is said there.
-    assert len(dataclasses.fields(gpt.GPTConfig)) == 69
-    assert len(dataclasses.fields(LayerSpec)) == 4
+    assert len(dataclasses.fields(gpt.GPTConfig)) == 72
+    assert len(dataclasses.fields(LayerSpec)) == 7
 
 
 def _traced(attention, sp_bound, window):

@@ -22,7 +22,7 @@ from horovod_tpu.compression import pallas_kernels as pk
 from horovod_tpu.models import gpt
 from horovod_tpu.observability import parse_prometheus_text, sample_value
 from horovod_tpu.ops import flash_attention as fa
-from horovod_tpu.ops import conv, gated_delta, ssd
+from horovod_tpu.ops import conv, gated_delta, s6, ssd
 
 CFG = dict(vocab_size=64, num_layers=2, num_heads=2, num_kv_heads=1,
            head_dim=16, embed_dim=32, mlp_dim=64, tp_axis=None, sp_axis=None,
@@ -432,6 +432,17 @@ def _delta(grad: bool):
         jnp.ones((1, 32, 2, 8), jnp.float32))
 
 
+def _selective(grad: bool):
+    u = jnp.ones((1, 32, 8), jnp.float32)
+    b = jnp.ones((1, 32, 8), jnp.float32)
+
+    def scan(u):
+        return s6.selective_scan(u, u, -jnp.ones((8, 8)), b, b, jnp.ones(8),
+                                 chunk=16).sum()
+
+    return jax.make_jaxpr(jax.grad(scan) if grad else scan)(u)
+
+
 def _conv(grad: bool):
     w = jnp.ones((4, 8), jnp.float32)
 
@@ -458,6 +469,8 @@ MN = jnp.zeros((4,), jnp.float32)
     ("hvd_gdn_bwd", lambda: _delta(True)),
     ("hvd_conv_fwd", lambda: _conv(False)),
     ("hvd_conv_bwd", lambda: _conv(True)),
+    ("hvd_s6_fwd", lambda: _selective(False)),
+    ("hvd_s6_bwd", lambda: _selective(True)),
     ("hvd_maxmin_quantize", lambda: jax.make_jaxpr(
         lambda x: pk.maxmin_quantize_pallas(x, 4, 512, True))(FLAT)),
     # TPU-only (pltpu.prng_* has no CPU lowering), but it traces anywhere.
@@ -476,7 +489,7 @@ MN = jnp.zeros((4,), jnp.float32)
         lambda q: pk.norm_dequantize_pallas(q, LEVELS, MN, True))(Q8)),
 ])
 def test_kernel_names(name, make):
-    """The fifteen names the benchmark's readers match as strings, or (the
+    """The seventeen names the benchmark's readers match as strings, or (the
     convolution's) must not."""
     assert re.search(rf"\bname={name}\b", str(make())), name
 
@@ -495,11 +508,103 @@ def test_kernel_name_constants():
     assert (conv.CONV_KERNEL_FWD, conv.CONV_KERNEL_BWD) == (
         "hvd_conv_fwd", "hvd_conv_bwd")
     for name in (conv.CONV_KERNEL_FWD, conv.CONV_KERNEL_BWD):
-        assert not re.match(r"^hvd_(ssd|gdn)_", name)
+        assert not re.match(r"^hvd_(ssd|gdn|s6)_", name)
+    # benchmarks/jobs/gpt_sambay_dp.py matches ``^hvd_s6_``; no reader of
+    # ``^hvd_ssd_`` (Mamba-2's scan) or of the convolution's names may count
+    # the selective scan's kernels into another scan.
+    assert (s6.KERNEL_FWD, s6.KERNEL_BWD) == ("hvd_s6_fwd", "hvd_s6_bwd")
+    for name in (s6.KERNEL_FWD, s6.KERNEL_BWD):
+        assert not re.match(r"^hvd_(ssd|gdn|conv|flash)_", name)
     assert {v for k, v in vars(pk).items() if k.startswith("KERNEL_")} == {
         "hvd_maxmin_quantize", "hvd_maxmin_quantize_stochastic",
         "hvd_maxmin_dequantize", "hvd_maxmin_dequantize_sum",
         "hvd_norm_quantize", "hvd_norm_dequantize"}
+
+
+# Mamba-1, a window layer, the two producers and their readers: the six
+# kinds of layer of the decoder-hybrid-decoder stack, two heads in one pair.
+SAMBAY = dict(
+    num_layers=6, num_heads=2, num_kv_heads=2, rope=None, tie_embeddings=True,
+    norm_kind="layer", s6_inner=16, s6_dt_rank=2, ssm_state=8, ssm_conv=4,
+    layers=(
+        gpt.LayerSpec(mixer="s6", rope=False, ff="gated"),
+        gpt.LayerSpec(mixer="diff_attention", window=8, rope=False,
+                      ff="gated", depth=1),
+        gpt.LayerSpec(mixer="s6", rope=False, ff="gated",
+                      publishes=("s6_scan",)),
+        gpt.LayerSpec(mixer="diff_attention", rope=False, ff="gated",
+                      depth=17, publishes=("diff_kv",)),
+        gpt.LayerSpec(mixer="gmu", rope=False, ff="gated",
+                      reads=("s6_scan",)),
+        gpt.LayerSpec(mixer="diff_cross", rope=False, ff="gated", depth=19,
+                      reads=("diff_kv",))))
+
+
+def test_sambay_step_holds_its_parts_under_their_scopes(spmd4):
+    """Where ``s6_ms``, ``s6_scan_ms``, ``gmu_ms``, ``attn_cross_ms`` and
+    ``attn_diff_ms`` look: a Mamba-1 mixer's parts under ``layer<i>/s6/
+    <part>`` with the scan's kernels under ``s6/scan`` (the forward one in
+    the forward pass alone: the block keeps the scan's output and the
+    entering states) and the convolution's under ``s6/conv``; a Gated
+    Memory Unit under ``gmu``; a differential layer's two flash calls
+    straight under ``attn_window``, ``attn`` or ``attn_cross`` and its
+    combination under ``diff`` inside each; and the counters say what was
+    traced and what crosses the layers."""
+    more = {k: v for k, v in SAMBAY.items() if k != "rope"}
+    step, *args = gpt_step("full", **more)
+    text = step.lower(*args).as_text(debug_info=True)
+    scans = set(re.findall(r'loc\("([^"]*)/hvd_s6_(fwd|bwd)/', text))
+    assert {kernel for _, kernel in scans} == {"fwd", "bwd"}
+    for scope, kernel in scans:
+        assert scope.endswith("/s6/scan"), scope
+        assert re.search(r"layer[02]\b", scope), scope
+        assert ("transpose(jvp(" in scope) == (kernel == "bwd"), scope
+        assert "rematted_computation" not in scope, scope
+    assert all(scope.endswith("/s6/conv") for scope in re.findall(
+        r'loc\("([^"]*)/hvd_conv_(?:fwd|bwd)/', text))
+    flash = set(re.findall(r'loc\("([^"]*)/hvd_flash_(fwd|dkdv)/', text))
+    by_layer = {}
+    for scope, kernel in flash:
+        assert "rematted_computation" not in scope, scope
+        by_layer.setdefault(re.search(r"layer\d", scope).group(0),
+                            set()).add(scope.rsplit("/", 1)[-1])
+    assert by_layer == {"layer1": {"attn_window"}, "layer3": {"attn"},
+                        "layer5": {"attn_cross"}}
+    names = set(re.findall(r'loc\("([^"]*)"', text))     # as lowered
+
+    def some(*parts):
+        return any(all(p in n for p in parts) for n in names)
+
+    for part in ("in_proj", "x_proj", "scan", "gate", "out_proj"):
+        assert some("jvp(layer0)", f"/s6/{part}/"), part
+        assert some("transpose(jvp(layer2))", f"/s6/{part}/"), part
+    assert some("jvp(layer4)", "/gmu/") \
+        and some("transpose(jvp(layer4))", "/gmu/")
+    for layer, scope in ((1, "attn_window"), (3, "attn"), (5, "attn_cross")):
+        assert some(f"jvp(layer{layer})", f"/{scope}/diff/"), scope
+        assert some(f"transpose(jvp(layer{layer}))", f"/{scope}/diff/"), scope
+    assert not some("layer4", "/s6/") and not some("layer5", "/attn/")
+
+    def samples(family):
+        return {tuple(sorted(labels.items())): count for _, labels, count
+                in hvd.metrics()[family]["samples"]}
+
+    assert samples("hvdtpu_spmd_s6_traces_total") == {
+        (("channels", "16"), ("conv", "4"), ("dt_rank", "2"),
+         ("publishes", publishes), ("state", "8")): 1.0
+        for publishes in ("false", "true")}
+    # Each kernel traced once for the shape: the calls are jitted inline.
+    assert samples("hvdtpu_spmd_s6_kernel_traces_total") == {
+        (("channels", "128"), ("chunk", "128"), ("kernel", kernel),
+         ("operand_dtype", "float32"), ("state", "8"), ("tokens", "128")): 1.0
+        for kernel in (s6.KERNEL_FWD, s6.KERNEL_BWD)}
+    assert samples("hvdtpu_spmd_diff_attention_traces_total") == {
+        (("cross", cross), ("head_dim", "16"), ("kv_pairs", "1"),
+         ("pairs", "1"), ("window", window)): 1.0
+        for cross, window in (("false", "8"), ("false", "0"), ("true", "0"))}
+    assert samples("hvdtpu_spmd_shared_values_total") == {
+        (("producer", "2"), ("readers", "1"), ("value", "s6_scan")): 1.0,
+        (("producer", "3"), ("readers", "1"), ("value", "diff_kv")): 1.0}
 
 
 # ---- (c) hvd.metrics() in SPMD mode -----------------------------------------
