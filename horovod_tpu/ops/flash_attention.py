@@ -38,33 +38,37 @@ Design notes (TPU):
   v5e measured as one bfloat16 pass: PERF.md, Findings, PR 24). Everything
   else is float32 whatever the input: scores, max, exp, denominator,
   log-sum-exp, delta, accumulators.
-* Each kernel walks a 3-D grid whose innermost dimension streams the
-  contraction blocks: the forward visits ``(bh, q_block, k_block)`` with the
-  online-softmax state (running max and denominator, lane-replicated
-  ``[block_q, 128]``, and the output accumulator) carried across k-steps
-  in VMEM scratch and written on the final visit — VMEM use is
-  O(block x D + block_q x block_k) regardless of sequence length.
-* Which pairs are kept is one description, :class:`Mask`: causal, a causal
-  band (``window``: a query sees itself and the ``window - 1`` keys before
-  it) or none. Causal mode skips the tiles wholly above the diagonal, a
-  window besides those wholly below the band (``pl.when``: no FLOPs), and
-  every tile computed is masked (masking only those the diagonal
-  crosses measured no cheaper). A skipped step fetches nothing either: the
-  index maps of the streamed operands clamp to the nearest kept tile of the
-  row (forward, dQ) or column (dKdV), so a skipped step names a block
-  already held and the pipeline issues no DMA. A query row whose band has
-  not begun in the first tile its block visits sees a tile of masked
-  scores there; the running maximum's next rise wipes what that added (the
-  row's own diagonal tile always follows). The grid is the causal one: a
-  skipped step still costs its fraction of a microsecond. Tail padding is
-  free (a real query row
-  never attends a key beyond itself). Bidirectional mode (``causal=False``,
-  encoder models) computes every block and masks the padded key columns,
-  where there are any. Any sequence length works in both.
+* Each kernel's grid is a head (or a K/V head and, an axis of their own in
+  the one backward kernel, the query heads of its group), and as its last
+  axis the tiles of a head's ``q_block x k_block`` rectangle that hold a
+  kept pair, and no others. Which pairs are kept is one description, :class:`Mask`: causal, a
+  causal band (``window``: a query sees itself and the ``window - 1`` keys
+  before it) or none. The mask is static and so is the tile, so the mask
+  itself enumerates its kept tiles at trace time (``Mask.kept_tiles``), a
+  query block's tiles together with the keys ascending, and the list rides
+  into the kernel as a scalar-prefetch table in SMEM
+  (``pltpu.PrefetchScalarGridSpec``): every index map reads its block index
+  from it, and the body reads its position and whether the step opens or
+  closes its row. The forward carries the online-softmax state (running max
+  and denominator, lane-replicated ``[block_q, 128]``, and the output
+  accumulator) across a row's steps in VMEM scratch and writes on the row's
+  last, so VMEM use is O(block x D + block_q x block_k) regardless of
+  sequence length. A tile wholly above the diagonal or wholly below the
+  band is no grid step: it is neither multiplied, nor fetched, nor walked
+  (until PR 61 the grid was the rectangle and such a step cost its 0.3 µs).
+  Every tile computed is masked (masking only those the diagonal crosses
+  measured no cheaper). A query row whose band has not begun in the first
+  tile its block visits sees a tile of masked scores there; the running
+  maximum's next rise wipes what that added (the row's own diagonal tile
+  always follows). Tail padding is free (a real query row never attends a
+  key beyond itself). A bidirectional mask (``causal=False``, encoder
+  models) keeps the whole rectangle, through the same code, and masks the
+  padded key columns, where there are any. Any sequence length works in
+  both.
 * Backward = one kernel where a K/V head's dK and dV fit VMEM (every shape
   a benchmark cell runs; ``backward_is_fused``): it walks
-  ``(b*hkv, group x q_block, k_block)``, the dQ kernel's order with the dKdV
-  kernel's walk over the group's query heads, on the transposed score tile
+  ``(b*hkv, query head of the group, kept tile)``, the dQ kernel's order
+  under each query head of the group in turn, on the transposed score tile
   (``k·qᵀ``, so dV and dK are plain products and the row statistics come as
   ``[1, block_q]`` rows), and makes each kept tile once: five products
   (``k·qᵀ``, ``pᵀ·dO``, ``v·dOᵀ``, ``dsᵀ·q`` and, for dQ, ``kᵀ·dsᵀ``
@@ -75,9 +79,9 @@ Design notes (TPU):
   are the pair's. In the compiled program it carries the dKdV kernel's
   name (the benchmark's readers of the backward pass match it). A sequence
   too long for that (somewhere past 16k rows at heads of 128) keeps the
-  two kernels, same streaming structure: dKdV walks
-  ``(b*hkv, k_block, group x q_block)``, dQ walks
-  ``(bh, q_block, k_block)`` on the tile as the forward has it, with the
+  two kernels, same streaming structure: dKdV walks the kept tiles a k
+  block's column at a time (the column once a query head of the group,
+  ``_over_group``), dQ walks them as the forward does, with the
   statistics lane-replicated ``[block_q, 128]``; each
   recomputes the probability tile from q, k and the saved row logsumexp —
   no S x S tensor is ever materialized in either direction.
@@ -103,8 +107,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .. import runtime
-from .pallas_util import LANES, NEG_INF, NT, div as _div, \
-    out_vma as _out_vma, rem as _rem, use_interpret as _use_interpret
+from .pallas_util import LANES, NEG_INF, NT, always, div as _div, \
+    out_vma as _out_vma, use_interpret as _use_interpret
 
 _PAD = 128    # the sequence is padded to this many rows, whatever the block
 # The kernels' names in the compiled program: each becomes the name of its
@@ -209,12 +213,36 @@ def backward_is_fused(block_q: int, block_k: int, s_pad: int, d: int, dtype,
                          fused_rows=s_pad) <= VMEM_LIMIT_BYTES
 
 
-def _compiler_params(carried_over: int = 1):
-    """The last ``carried_over`` grid axes carry sums in VMEM scratch."""
+def _compiler_params(rank: int = 2):
+    """A grid is (a head or a K/V head, ..., the kept tiles it walks): every
+    axis after the first carries sums in VMEM scratch."""
     return pltpu.CompilerParams(
-        dimension_semantics=("parallel",) * (3 - carried_over)
-        + ("arbitrary",) * carried_over,
+        dimension_semantics=("parallel",) + ("arbitrary",) * (rank - 1),
         vmem_limit_bytes=VMEM_LIMIT_BYTES)
+
+
+# Rows of the table of kept tiles a grid walks (``Mask.kept_tiles``), and the
+# one ``_over_group`` adds for the dKdV kernel of the pair.
+TILE_Q, TILE_K, TILE_FIRST, TILE_LAST, TILE_HEAD = range(5)
+
+
+def _at(tiles: np.ndarray, ref, row: int, t):
+    """Row ``row`` of the table ``tiles`` at step ``t``, read from ``ref``
+    (the table as the kernel holds it, in SMEM); where the whole row is one
+    value, that value as a Python integer. A sequence of one tile a head is
+    such a table (every step block 0, its row's first and last), and Mosaic
+    then folds positions, the mask and the ``pl.when`` of a step as it
+    folded the program id of a grid axis of one: read from SMEM they cost a
+    512 x 512 tile's call a tenth of its time (PERF.md, Findings, PR 61)."""
+    values = tiles[row]
+    return int(values[0]) if (values == values[0]).all() else ref[row, t]
+
+
+def _tile_at(tiles: np.ndarray, ref, t):
+    """Step ``t`` of the table: its q block, its k block, and whether it is
+    its run's first and last (1 or 0)."""
+    return tuple(_at(tiles, ref, row, t)
+                 for row in (TILE_Q, TILE_K, TILE_FIRST, TILE_LAST))
 
 
 def _lanes(x, n: int):
@@ -232,12 +260,13 @@ def _lanes(x, n: int):
 @dataclasses.dataclass(frozen=True)
 class Mask:
     """Which (query, key) pairs attention keeps: the one description the
-    three kernels read, pair by pair (``keep``), tile by tile (``tile_kept``)
-    and as the range of tiles a row or column of the grid visits
-    (``k_blocks``, ``q_blocks``: what the index maps clamp to, so that a
-    skipped step names a block already held and nothing is fetched for it).
-    A new kind of mask (same-document: ROADMAP Reach 1) is a field here and
-    a clause in each of these, not a flag threaded beside ``causal``.
+    three kernels read, pair by pair (``keep``), and the only thing their
+    grids are built from: the tiles that hold a kept pair, listed at trace
+    time in the order a kernel walks them (``kept_tiles``, by ``tile_kept``).
+    A new kind of mask (same-document: ROADMAP Reach 1) is a field here: a
+    static one changes the list, a dynamic one (segment ids) is a clause in
+    ``keep`` under an unchanged grid; neither is a flag threaded beside
+    ``causal``.
 
     ``causal``: ``k <= q``. ``window`` (causal only): besides, ``q - k <
     window``, a query sees itself and the ``window - 1`` keys before it.
@@ -275,44 +304,44 @@ class Mask:
             keep = keep & (q_pos - k_pos < self.window)
         return keep
 
-    def tile_kept(self, qi, kj, block_q: int, block_k: int):
+    def tile_kept(self, qi: int, kj: int, block_q: int, block_k: int) -> bool:
         """Whether the tile at block indices ``(qi, kj)`` holds any kept
         pair."""
         if not self.causal:
-            # Trivially-true predicate, NOT an unguarded body: interpret
-            # mode's vma tracing (CPU-mesh shard_map) only standardizes the
-            # block-fetch slice's varying axes along the pl.when path — an
-            # unguarded body trips "dynamic_slice requires varying manual
-            # axes to match". Compiled Mosaic folds the constant predicate.
-            return kj >= 0
+            return True
         # The tile's last query row reaches its first key.
         kept = (qi + 1) * block_q - 1 >= kj * block_k
         if self.window is not None:
             # Its first query row still sees its last key.
-            kept = kept & ((kj + 1) * block_k + self.window - 2
-                           >= qi * block_q)
+            kept = kept and ((kj + 1) * block_k + self.window - 2
+                             >= qi * block_q)
         return kept
 
-    def k_blocks(self, i, block_q: int, block_k: int):
-        """``(first, last)`` k block that query block ``i`` attends, either
-        None where the grid's own end is the bound."""
-        if not self.causal:
-            return None, None
-        first = None if self.window is None else _div(
-            jnp.maximum(i * block_q - (self.window - 1), 0), block_k)
-        return first, _div((i + 1) * block_q - 1, block_k)
-
-    def q_blocks(self, j, block_q: int, block_k: int):
-        """``(first, last)`` q block that attends k block ``j``."""
-        if not self.causal:
-            return None, None
-        last = None if self.window is None else _div(
-            (j + 1) * block_k + self.window - 2, block_q)
-        return _div(j * block_k, block_q), last
+    def kept_tiles(self, n_q: int, n_k: int, block_q: int, block_k: int,
+                   by_column: bool = False) -> np.ndarray:
+        """The tiles of an ``n_q x n_k`` grid that hold a kept pair, in the
+        order a kernel walks them: the int32 table ``[4, T]`` its grid's
+        one axis runs over, a step a column. Rows ``TILE_Q`` and ``TILE_K``
+        are the step's q block and k block; ``TILE_FIRST`` and ``TILE_LAST``
+        say whether it opens and closes its run, where the sums a kernel
+        carries start and leave: a query block's tiles, keys ascending
+        (row-major: the forward, dQ and the one backward kernel), or with
+        ``by_column`` a key block's, queries ascending (the dKdV kernel of
+        the pair). Trace time only (Python integers in, a NumPy table
+        out). No row or column of these masks is empty: a row's output is
+        written on its last tile."""
+        tiles = [(i, j) for i in range(n_q) for j in range(n_k)
+                 if self.tile_kept(i, j, block_q, block_k)]
+        run = 1 if by_column else 0
+        tiles.sort(key=lambda tile: (tile[run], tile[1 - run]))
+        runs = [tile[run] for tile in tiles]
+        first = [a != b for a, b in zip([None] + runs, runs)]
+        return np.array([*zip(*tiles), first, first[1:] + [True]], np.int32)
 
     def tiles(self, n_q: int, n_k: int, block_q: int, block_k: int) -> dict:
-        """How a ``n_q x n_k`` grid's tiles fall: ``kept`` (computed),
-        ``skipped`` (wholly above the diagonal) and ``skipped_band``
+        """How a ``n_q x n_k`` rectangle's tiles fall: ``kept`` (computed:
+        the grid's steps), ``skipped`` (wholly above the diagonal) and
+        ``skipped_band``
         (wholly below the band: what a window saves of the causal
         triangle's). Python integers, for the trace-time counter."""
         out = {"kept": 0, "skipped": 0, "skipped_band": 0}
@@ -327,16 +356,6 @@ class Mask:
         return out
 
 
-def _clamp(index, first, last):
-    """``index`` held inside ``[first, last]`` (either may be None): what an
-    index map names on a skipped step."""
-    if last is not None:
-        index = jnp.minimum(index, last)
-    if first is not None:
-        index = jnp.maximum(index, first)
-    return index
-
-
 def _mask_tile(s, q_start, k_start, mask: Mask, kv_len: int,
                transposed: bool = False):
     """Mask a score tile whose first query row and key column are at global
@@ -349,19 +368,21 @@ def _mask_tile(s, q_start, k_start, mask: Mask, kv_len: int,
     return jnp.where(mask.keep(q_pos, k_pos, kv_len), s, NEG_INF)
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
-                *, sm_scale: float, block_q: int, block_k: int,
-                n_k_blocks: int, mask: Mask, kv_len: int, masked: bool):
-    qi = pl.program_id(1)
-    kj = pl.program_id(2)
+def _fwd_kernel(tiles_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr,
+                l_scr, acc_scr, *, tiles: np.ndarray, sm_scale: float,
+                block_q: int, block_k: int, mask: Mask, kv_len: int,
+                masked: bool):
+    t = pl.program_id(1)
+    qi, kj, first, last = _tile_at(tiles, tiles_ref, t)
     d = acc_scr.shape[-1]
 
-    @pl.when(kj == 0)
+    @pl.when(first == 1)
     def _init():
         m_scr[:] = jnp.full_like(m_scr, NEG_INF)
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
+    @functools.partial(always, axis=1)
     def _step():
         v = v_ref[0]                                     # [BK, D]
         s = jax.lax.dot_general(q_ref[0], k_ref[0], NT,
@@ -378,9 +399,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
         acc_scr[:] = acc_scr[:] * _lanes(alpha, d) + jnp.dot(
             p.astype(v.dtype), v, preferred_element_type=jnp.float32)
 
-    pl.when(mask.tile_kept(qi, kj, block_q, block_k))(_step)
-
-    @pl.when(kj == n_k_blocks - 1)
+    @pl.when(last == 1)
     def _finish():
         l = l_scr[:]
         safe_l = jnp.where(l == 0, 1.0, l)
@@ -393,19 +412,19 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
         lse_ref[0] = m_scr[:] + jnp.log(safe_l)
 
 
-def _dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                 dk_ref, dv_ref, dk_scr, dv_scr, *, sm_scale: float,
-                 block_q: int, block_k: int, n_q_blocks: int, n_steps: int,
-                 mask: Mask, kv_len: int, masked: bool):
-    kj = pl.program_id(1)
-    step = pl.program_id(2)        # (query head of the group, q block)
-    qi = _rem(step, n_q_blocks)
+def _dkdv_kernel(tiles_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                 dk_ref, dv_ref, dk_scr, dv_scr, *, tiles: np.ndarray,
+                 sm_scale: float, block_q: int, block_k: int, mask: Mask,
+                 kv_len: int, masked: bool):
+    t = pl.program_id(1)   # a tile of a k block's column, under a query head
+    qi, kj, first, last = _tile_at(tiles, tiles_ref, t)
 
-    @pl.when(step == 0)
+    @pl.when(first == 1)
     def _init():
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
+    @functools.partial(always, axis=1)
     def _step():
         q = q_ref[0]                                     # [BQ, D]
         do = do_ref[0]
@@ -426,24 +445,24 @@ def _dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk_scr[:] = dk_scr[:] + jnp.dot(
             dst.astype(q.dtype), q, preferred_element_type=jnp.float32)
 
-    pl.when(mask.tile_kept(qi, kj, block_q, block_k))(_step)
-
-    @pl.when(step == n_steps - 1)
+    @pl.when(last == 1)
     def _finish():
         dk_ref[0] = (dk_scr[:] * sm_scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
 
 
-def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-               dq_scr, *, sm_scale: float, block_q: int, block_k: int,
-               n_k_blocks: int, mask: Mask, kv_len: int, masked: bool):
-    qi = pl.program_id(1)
-    kj = pl.program_id(2)
+def _dq_kernel(tiles_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+               dq_ref, dq_scr, *, tiles: np.ndarray, sm_scale: float,
+               block_q: int, block_k: int, mask: Mask, kv_len: int,
+               masked: bool):
+    t = pl.program_id(1)
+    qi, kj, first, last = _tile_at(tiles, tiles_ref, t)
 
-    @pl.when(kj == 0)
+    @pl.when(first == 1)
     def _init():
         dq_scr[:] = jnp.zeros_like(dq_scr)
 
+    @functools.partial(always, axis=1)
     def _step():
         k = k_ref[0]
         s = jax.lax.dot_general(q_ref[0], k, NT,
@@ -458,35 +477,36 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
         dq_scr[:] = dq_scr[:] + jnp.dot(
             ds.astype(k.dtype), k, preferred_element_type=jnp.float32)
 
-    pl.when(mask.tile_kept(qi, kj, block_q, block_k))(_step)
-
-    @pl.when(kj == n_k_blocks - 1)
+    @pl.when(last == 1)
     def _finish():
         dq_ref[0] = (dq_scr[:] * sm_scale).astype(dq_ref.dtype)
 
 
-def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+def _bwd_kernel(tiles_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 dq_ref, dk_ref, dv_ref, dq_scr, dk_scr, dv_scr, *,
-                sm_scale: float, block_q: int, block_k: int,
-                n_q_blocks: int, n_k_blocks: int, n_steps: int, mask: Mask,
-                kv_len: int, masked: bool):
+                tiles: np.ndarray, group: int, sm_scale: float,
+                block_q: int, block_k: int, mask: Mask, kv_len: int,
+                masked: bool):
     """dQ, dK and dV from one score tile: the dKdV kernel's transposed tile
-    and its walk over the group's query heads, on the dQ kernel's grid. dK
+    and its walk over the group's query heads, in the dQ kernel's order. dK
     and dV of the K/V head's whole sequence are float32 sums in VMEM."""
-    step = pl.program_id(1)        # (query head of the group, q block)
-    kj = pl.program_id(2)
-    qi = _rem(step, n_q_blocks)
-    first, last = kj == 0, kj == n_k_blocks - 1
+    head, t = pl.program_id(1), pl.program_id(2)   # of the group; kept tile
+    qi, kj, first, last = _tile_at(tiles, tiles_ref, t)
 
-    @pl.when(first & (step == 0))
+    @pl.when((head == 0) & (t == 0))
     def _init_head():
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
-    @pl.when(first)
+    @pl.when(first == 1)
     def _init_row():
         dq_scr[:] = jnp.zeros_like(dq_scr)
 
+    # On the tiles' axis, as in the other kernels: a sequence of one tile a
+    # head makes it an axis of one, Mosaic folds the predicate and the step
+    # is a straight line (under the heads' axis the one-tile control's call
+    # ran 7% slower: PERF.md, Findings, PR 61).
+    @functools.partial(always, axis=2)
     def _step():
         q = q_ref[0]                                     # [BQ, D]
         k = k_ref[0]                                     # [BK, D]
@@ -512,13 +532,11 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dq_scr[:] = dq_scr[:] + jnp.dot(
             k.T, dst, preferred_element_type=jnp.float32)
 
-    pl.when(mask.tile_kept(qi, kj, block_q, block_k))(_step)
-
-    @pl.when(last)
+    @pl.when(last == 1)
     def _finish_row():
         dq_ref[0] = (dq_scr[:].T * sm_scale).astype(dq_ref.dtype)
 
-    @pl.when(last & (step == n_steps - 1))
+    @pl.when((head == group - 1) & (t == tiles.shape[1] - 1))
     def _finish_head():
         dk_ref[0] = (dk_scr[:] * sm_scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
@@ -531,35 +549,32 @@ def _pad_seq(x):
     return x
 
 
-def _blocks_for(kernel, q, k, v, mask: Mask, forced, dq: str = "own"):
-    """The call's tile, forced or from the table; and, trace time only, the
-    record of it (with the width of a query/key head and of a value head,
-    and where dQ is made: ``fused`` on a dKdV kernel that makes it too,
-    ``own`` on the pair's two, ``none`` on the forward) and of the tiles
-    its grid keeps and skips behind ``hvd.metrics()``."""
+def _tiles_for(kernel, q, k, v, mask: Mask, forced, dq: str = "own",
+               by_column: bool = False):
+    """The call's tile, forced or from the table, and the kept tiles its
+    grid walks (``Mask.kept_tiles``); and, trace time only, the record of
+    them behind ``hvd.metrics()``: the tile (with the width of a query/key
+    head and of a value head, and where dQ is made: ``fused`` on a dKdV
+    kernel that makes it too, ``own`` on the pair's two, ``none`` on the
+    forward), how the rectangle's tiles fall, and the steps a head's grid
+    walks, which is the table's length."""
     bq, bk = forced or block_sizes(kernel, q.shape[1], q.shape[2], q.dtype,
                                    mask.causal, v.shape[2])
+    n_q, n_k = q.shape[1] // bq, q.shape[1] // bk
+    tiles = mask.kept_tiles(n_q, n_k, bq, bk, by_column)
     runtime.note_traced(
         "hvdtpu_spmd_flash_kernel_traces_total", kernel=kernel, block_q=bq,
         block_k=bk, operand_dtype=jnp.dtype(q.dtype).name,
         kv_group=q.shape[0] // k.shape[0], key_dim=q.shape[2],
         value_dim=v.shape[2], dq=dq)
-    for tiles, n in mask.tiles(q.shape[1] // bq, q.shape[1] // bk,
-                               bq, bk).items():
+    for fall, n in mask.tiles(n_q, n_k, bq, bk).items():
         runtime.note_traced(
             "hvdtpu_spmd_flash_tiles_total", n, kernel=kernel,
-            mask=mask.name, tiles=tiles, seq=q.shape[1])
-    return bq, bk
-
-
-def _kv_map(group: int, block_q: int, block_k: int, mask: Mask):
-    """Index map of K and V on a ``(bh, q_block, k_block)`` grid: the K/V
-    head of query head ``b``; a skipped step re-names the nearest kept
-    block, so the pipeline issues no DMA for it."""
-    def kv_map(b, i, j):
-        j = _clamp(j, *mask.k_blocks(i, block_q, block_k))
-        return _div(b, group), j, 0
-    return kv_map
+            mask=mask.name, tiles=fall, seq=q.shape[1])
+    runtime.note_traced(
+        "hvdtpu_spmd_flash_grid_steps_total", tiles.shape[1], kernel=kernel,
+        mask=mask.name, seq=q.shape[1])
+    return bq, bk, tiles
 
 
 def _fwd_call(q, k, v, sm_scale, mask, kv_len, forced=None):
@@ -570,42 +585,66 @@ def _fwd_call(q, k, v, sm_scale, mask, kv_len, forced=None):
     bh, s, d = q.shape
     dv = v.shape[2]
     group = bh // k.shape[0]
-    bq, bk = _blocks_for(KERNEL_FWD, q, k, v, mask, forced, dq="none")
-    n_q, n_k = s // bq, s // bk
+    bq, bk, tiles = _tiles_for(KERNEL_FWD, q, k, v, mask, forced, dq="none")
 
-    kv_map = _kv_map(group, bq, bk, mask)
-    kernel = functools.partial(_fwd_kernel, sm_scale=sm_scale, block_q=bq,
-                               block_k=bk, n_k_blocks=n_k, mask=mask,
+    def q_map(b, t, ref):
+        return b, _at(tiles, ref, TILE_Q, t), 0
+
+    def kv_map(b, t, ref):
+        return _div(b, group), _at(tiles, ref, TILE_K, t), 0
+
+    kernel = functools.partial(_fwd_kernel, tiles=tiles, sm_scale=sm_scale,
+                               block_q=bq, block_k=bk, mask=mask,
                                kv_len=kv_len,
                                masked=mask.needs_masking(kv_len, s))
     return pl.pallas_call(
         kernel,
-        grid=(bh, n_q, n_k),
-        in_specs=[
-            pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bk, d), kv_map),
-            pl.BlockSpec((1, bk, dv), kv_map),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, bq, dv), lambda b, i, j: (b, i, 0)),
-            # lse rides lane-replicated [bh, s, 128] (see _fwd_kernel).
-            pl.BlockSpec((1, bq, LANES), lambda b, i, j: (b, i, 0)),
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(bh, tiles.shape[1]),
+            in_specs=[
+                pl.BlockSpec((1, bq, d), q_map),
+                pl.BlockSpec((1, bk, d), kv_map),
+                pl.BlockSpec((1, bk, dv), kv_map),
+            ],
+            out_specs=[
+                pl.BlockSpec((1, bq, dv), q_map),
+                # lse rides lane-replicated [bh, s, 128] (see _fwd_kernel).
+                pl.BlockSpec((1, bq, LANES), q_map),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((bq, LANES), jnp.float32),   # running max
+                pltpu.VMEM((bq, LANES), jnp.float32),   # running denominator
+                pltpu.VMEM((bq, dv), jnp.float32),      # output accumulator
+            ]),
         out_shape=[
             jax.ShapeDtypeStruct((bh, s, dv), q.dtype,
                                  vma=_out_vma(q, k, v)),
             jax.ShapeDtypeStruct((bh, s, LANES), jnp.float32,
                                  vma=_out_vma(q, k, v)),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((bq, LANES), jnp.float32),   # running max
-            pltpu.VMEM((bq, LANES), jnp.float32),   # running denominator
-            pltpu.VMEM((bq, dv), jnp.float32),       # output accumulator
-        ],
         compiler_params=_compiler_params(),
         interpret=_use_interpret(),
         name=KERNEL_FWD,
-    )(q, k, v)
+    )(tiles, q, k, v)
+
+
+def _over_group(tiles: np.ndarray, group: int) -> np.ndarray:
+    """A column-major table as the dKdV kernel walks it: each k block's run
+    once a query head of the group (row ``TILE_HEAD`` says which), the heads
+    the outer order as in the one backward kernel, so a k block's sums run
+    in that kernel's order; the run opens under the first head and closes
+    under the last."""
+    runs = np.split(tiles, np.flatnonzero(tiles[TILE_FIRST])[1:], axis=1)
+    out = []
+    for run in runs:
+        for head in range(group):
+            under = np.concatenate(
+                [run, np.full((1, run.shape[1]), head, np.int32)])
+            under[TILE_FIRST] &= head == 0
+            under[TILE_LAST] &= head == group - 1
+            out.append(under)
+    return np.concatenate(out, axis=1)
 
 
 def _dkdv_call(q, k, v, do, lse, delta, sm_scale, mask, kv_len,
@@ -616,55 +655,55 @@ def _dkdv_call(q, k, v, do, lse, delta, sm_scale, mask, kv_len,
     dv = v.shape[2]
     bkv = k.shape[0]
     group = bh // bkv
-    bq, bk = _blocks_for(KERNEL_DKDV, q, k, v, mask, forced)
-    n_q, n_k = s // bq, s // bk
+    bq, bk, tiles = _tiles_for(KERNEL_DKDV, q, k, v, mask, forced,
+                               by_column=True)
+    tiles = _over_group(tiles, group)
 
-    def q_block(b, j, t):
-        # Step t of a k block: query head t // n_q of the group, q block
-        # t % n_q; a skipped q block re-names the nearest kept one.
-        i = _clamp(_rem(t, n_q), *mask.q_blocks(j, bq, bk))
-        return b * group + _div(t, n_q), i
+    def q_map(b, t, ref):
+        return (b * group + _at(tiles, ref, TILE_HEAD, t),
+                _at(tiles, ref, TILE_Q, t), 0)
 
-    def q_map(b, j, t):
-        return (*q_block(b, j, t), 0)
-
-    def row_map(b, j, t):
-        head, i = q_block(b, j, t)
+    def row_map(b, t, ref):
+        head, i, _ = q_map(b, t, ref)
         return head, 0, i
 
-    kernel = functools.partial(_dkdv_kernel, sm_scale=sm_scale, block_q=bq,
-                               block_k=bk, n_q_blocks=n_q,
-                               n_steps=group * n_q, mask=mask,
+    def kv_map(b, t, ref):
+        return b, _at(tiles, ref, TILE_K, t), 0
+
+    kernel = functools.partial(_dkdv_kernel, tiles=tiles, sm_scale=sm_scale,
+                               block_q=bq, block_k=bk, mask=mask,
                                kv_len=kv_len,
                                masked=mask.needs_masking(kv_len, s))
     vma = _out_vma(q, k, v, do)
     return pl.pallas_call(
         kernel,
-        grid=(bkv, n_k, group * n_q),
-        in_specs=[
-            pl.BlockSpec((1, bq, d), q_map),                           # q
-            pl.BlockSpec((1, bk, d), lambda b, j, t: (b, j, 0)),       # k
-            pl.BlockSpec((1, bk, dv), lambda b, j, t: (b, j, 0)),      # v
-            pl.BlockSpec((1, bq, dv), q_map),                          # do
-            pl.BlockSpec((1, 1, bq), row_map),                         # lse
-            pl.BlockSpec((1, 1, bq), row_map),                         # delta
-        ],
-        out_specs=[
-            pl.BlockSpec((1, bk, d), lambda b, j, t: (b, j, 0)),
-            pl.BlockSpec((1, bk, dv), lambda b, j, t: (b, j, 0)),
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(bkv, tiles.shape[1]),
+            in_specs=[
+                pl.BlockSpec((1, bq, d), q_map),                       # q
+                pl.BlockSpec((1, bk, d), kv_map),                      # k
+                pl.BlockSpec((1, bk, dv), kv_map),                     # v
+                pl.BlockSpec((1, bq, dv), q_map),                      # do
+                pl.BlockSpec((1, 1, bq), row_map),                     # lse
+                pl.BlockSpec((1, 1, bq), row_map),                     # delta
+            ],
+            out_specs=[
+                pl.BlockSpec((1, bk, d), kv_map),
+                pl.BlockSpec((1, bk, dv), kv_map),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((bk, d), jnp.float32),
+                pltpu.VMEM((bk, dv), jnp.float32),
+            ]),
         out_shape=[
             jax.ShapeDtypeStruct((bkv, s, d), k.dtype, vma=vma),
             jax.ShapeDtypeStruct((bkv, s, dv), v.dtype, vma=vma),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((bk, d), jnp.float32),
-            pltpu.VMEM((bk, dv), jnp.float32),
-        ],
         compiler_params=_compiler_params(),
         interpret=_use_interpret(),
         name=KERNEL_DKDV,
-    )(q, k, v, do, lse, delta)
+    )(tiles, q, k, v, do, lse, delta)
 
 
 def _dq_call(q, k, v, do, lse, delta, sm_scale, mask, kv_len, forced=None):
@@ -673,37 +712,39 @@ def _dq_call(q, k, v, do, lse, delta, sm_scale, mask, kv_len, forced=None):
     bh, s, d = q.shape
     dv = v.shape[2]
     group = bh // k.shape[0]
-    bq, bk = _blocks_for(KERNEL_DQ, q, k, v, mask, forced)
-    n_q, n_k = s // bq, s // bk
+    bq, bk, tiles = _tiles_for(KERNEL_DQ, q, k, v, mask, forced)
 
-    kv_map = _kv_map(group, bq, bk, mask)
+    def q_map(b, t, ref):
+        return b, _at(tiles, ref, TILE_Q, t), 0
 
-    def q_map(b, i, j):
-        return b, i, 0
+    def kv_map(b, t, ref):
+        return _div(b, group), _at(tiles, ref, TILE_K, t), 0
 
-    kernel = functools.partial(_dq_kernel, sm_scale=sm_scale, block_q=bq,
-                               block_k=bk, n_k_blocks=n_k, mask=mask,
+    kernel = functools.partial(_dq_kernel, tiles=tiles, sm_scale=sm_scale,
+                               block_q=bq, block_k=bk, mask=mask,
                                kv_len=kv_len,
                                masked=mask.needs_masking(kv_len, s))
     return pl.pallas_call(
         kernel,
-        grid=(bh, n_q, n_k),
-        in_specs=[
-            pl.BlockSpec((1, bq, d), q_map),                           # q
-            pl.BlockSpec((1, bk, d), kv_map),                          # k
-            pl.BlockSpec((1, bk, dv), kv_map),                         # v
-            pl.BlockSpec((1, bq, dv), q_map),                          # do
-            pl.BlockSpec((1, bq, LANES), q_map),                      # lse
-            pl.BlockSpec((1, bq, LANES), q_map),                      # delta
-        ],
-        out_specs=pl.BlockSpec((1, bq, d), q_map),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(bh, tiles.shape[1]),
+            in_specs=[
+                pl.BlockSpec((1, bq, d), q_map),                       # q
+                pl.BlockSpec((1, bk, d), kv_map),                      # k
+                pl.BlockSpec((1, bk, dv), kv_map),                     # v
+                pl.BlockSpec((1, bq, dv), q_map),                      # do
+                pl.BlockSpec((1, bq, LANES), q_map),                   # lse
+                pl.BlockSpec((1, bq, LANES), q_map),                   # delta
+            ],
+            out_specs=pl.BlockSpec((1, bq, d), q_map),
+            scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct((bh, s, d), q.dtype,
                                        vma=_out_vma(q, k, v, do)),
-        scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
         compiler_params=_compiler_params(),
         interpret=_use_interpret(),
         name=KERNEL_DQ,
-    )(q, k, v, do, lse, delta)
+    )(tiles, q, k, v, do, lse, delta)
 
 
 def _bwd_call(q, k, v, do, lse, delta, sm_scale, mask, kv_len, forced=None):
@@ -715,65 +756,63 @@ def _bwd_call(q, k, v, do, lse, delta, sm_scale, mask, kv_len, forced=None):
     dv = v.shape[2]
     bkv = k.shape[0]
     group = bh // bkv
-    bq, bk = _blocks_for(KERNEL_DKDV, q, k, v, mask, forced, dq="fused")
-    n_q, n_k = s // bq, s // bk
+    bq, bk, tiles = _tiles_for(KERNEL_DKDV, q, k, v, mask, forced,
+                               dq="fused")
 
-    def q_block(b, t):
-        # Step t of a K/V head: query head t // n_q of its group, q block
-        # t % n_q.
-        return b * group + _div(t, n_q), _rem(t, n_q)
+    def q_map(b, head, t, ref):
+        return b * group + head, _at(tiles, ref, TILE_Q, t), 0
 
-    def q_map(b, t, j):
-        return (*q_block(b, t), 0)
+    def row_map(b, head, t, ref):
+        return b * group + head, 0, _at(tiles, ref, TILE_Q, t)
 
-    def row_map(b, t, j):
-        head, i = q_block(b, t)
-        return head, 0, i
+    def kv_map(b, head, t, ref):
+        return b, _at(tiles, ref, TILE_K, t), 0
 
-    def kv_map(b, t, j):
-        # A skipped step re-names the nearest kept block of the row.
-        return b, _clamp(j, *mask.k_blocks(_rem(t, n_q), bq, bk)), 0
-
-    def whole_map(b, t, j):
+    def whole_map(b, head, t, ref):
         return b, 0, 0
 
-    kernel = functools.partial(_bwd_kernel, sm_scale=sm_scale, block_q=bq,
-                               block_k=bk, n_q_blocks=n_q, n_k_blocks=n_k,
-                               n_steps=group * n_q, mask=mask, kv_len=kv_len,
+    kernel = functools.partial(_bwd_kernel, tiles=tiles, group=group,
+                               sm_scale=sm_scale, block_q=bq, block_k=bk,
+                               mask=mask, kv_len=kv_len,
                                masked=mask.needs_masking(kv_len, s))
     vma = _out_vma(q, k, v, do)
     return pl.pallas_call(
         kernel,
-        grid=(bkv, group * n_q, n_k),
-        in_specs=[
-            pl.BlockSpec((1, bq, d), q_map),                           # q
-            pl.BlockSpec((1, bk, d), kv_map),                          # k
-            pl.BlockSpec((1, bk, dv), kv_map),                         # v
-            pl.BlockSpec((1, bq, dv), q_map),                          # do
-            pl.BlockSpec((1, 1, bq), row_map),                         # lse
-            pl.BlockSpec((1, 1, bq), row_map),                         # delta
-        ],
-        out_specs=[
-            pl.BlockSpec((1, bq, d), q_map),
-            # A K/V head's whole dK and dV: the index is constant over the
-            # two inner axes, so they leave VMEM once, on its last step.
-            pl.BlockSpec((1, s, d), whole_map),
-            pl.BlockSpec((1, s, dv), whole_map),
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            # A K/V head; the query head of its group; the head's kept tile.
+            # (The last two as one axis of group x tiles cost a one-tile
+            # sequence's call 7%: PERF.md, Findings, PR 61.)
+            grid=(bkv, group, tiles.shape[1]),
+            in_specs=[
+                pl.BlockSpec((1, bq, d), q_map),                       # q
+                pl.BlockSpec((1, bk, d), kv_map),                      # k
+                pl.BlockSpec((1, bk, dv), kv_map),                     # v
+                pl.BlockSpec((1, bq, dv), q_map),                      # do
+                pl.BlockSpec((1, 1, bq), row_map),                     # lse
+                pl.BlockSpec((1, 1, bq), row_map),                     # delta
+            ],
+            out_specs=[
+                pl.BlockSpec((1, bq, d), q_map),
+                # A K/V head's whole dK and dV: the index is constant over
+                # the head's steps, so they leave VMEM once, on its last.
+                pl.BlockSpec((1, s, d), whole_map),
+                pl.BlockSpec((1, s, dv), whole_map),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((d, bq), jnp.float32),   # dQ of the row, transposed
+                pltpu.VMEM((s, d), jnp.float32),    # the K/V head's dK
+                pltpu.VMEM((s, dv), jnp.float32),   # ... and dV
+            ]),
         out_shape=[
             jax.ShapeDtypeStruct((bh, s, d), q.dtype, vma=vma),
             jax.ShapeDtypeStruct((bkv, s, d), k.dtype, vma=vma),
             jax.ShapeDtypeStruct((bkv, s, dv), v.dtype, vma=vma),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((d, bq), jnp.float32),       # dQ of the row, transposed
-            pltpu.VMEM((s, d), jnp.float32),        # the K/V head's dK
-            pltpu.VMEM((s, dv), jnp.float32),       # ... and dV
-        ],
-        compiler_params=_compiler_params(carried_over=2),
+        compiler_params=_compiler_params(rank=3),
         interpret=_use_interpret(),
         name=KERNEL_DKDV,
-    )(q, k, v, do, lse, delta)
+    )(tiles, q, k, v, do, lse, delta)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
@@ -830,12 +869,12 @@ def flash_attention(q, k, v, causal: bool = True, *,
     is then ``[B, S, H, Dv]`` (the scores are over ``D`` and scaled by one
     over its root). Differentiable (custom VJP, flash backward).
 
-    ``causal=True`` (decoder) skips the tiles above the diagonal;
+    ``causal=True`` (decoder) walks no tile above the diagonal;
     ``causal=False`` (encoder/bidirectional) computes all blocks with the
     tail padding masked out of the key axis. ``window`` (static, causal
     only): a query sees itself and the ``window - 1`` keys before it, and
-    the tiles wholly below that band are skipped as those above the
-    diagonal are, neither multiplied nor fetched, in every kernel; a
+    the tiles wholly below that band are no grid steps either, as those
+    above the diagonal are not, in every kernel; a
     window of the sequence's length or more is the causal program, unchanged
     (:class:`Mask`). Tile sizes and the MXU operands' dtype follow the
     call's shapes and dtype (``block_sizes``); ``_blocks=(block_q,

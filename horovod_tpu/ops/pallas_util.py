@@ -66,8 +66,9 @@ def always(body, axis: int = 2):
     """Run ``body`` under a predicate that always holds (of the grid's
     ``axis``), NOT unguarded:
     interpret mode inside a ``shard_map`` matches the varying axes of a
-    block's fetch only along a ``pl.when`` path
-    (``flash_attention.Mask.tile_kept``); compiled, Mosaic folds the constant."""
+    block's fetch only along a ``pl.when`` path (an unguarded body trips
+    "dynamic_slice requires varying manual axes to match"); compiled, Mosaic
+    folds the constant."""
     pl.when(pl.program_id(axis) >= 0)(body)
 
 

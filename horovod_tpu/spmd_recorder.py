@@ -95,8 +95,16 @@ _TRACED = {
         "kernel, the mask's kind (causal, window, full), the padded length "
         "and what became of the tile: kept (computed), skipped (wholly "
         "above the diagonal) or skipped_band (wholly below a window's band). "
-        "One grid of (q blocks x k blocks) a trace; every head walks it.",
+        "One rectangle of (q blocks x k blocks) a trace, the same for every "
+        "head.",
         ("kernel", "mask", "tiles", "seq")),
+    "hvdtpu_spmd_flash_grid_steps_total": (
+        "Steps a head's grid walks in the flash attention kernels JAX "
+        "traced, by kernel, the mask's kind and the padded length: the "
+        "length of the table of kept tiles the grid's one axis runs over, "
+        "so it equals hvdtpu_spmd_flash_tiles_total{tiles=kept} (no step "
+        "is a tile the mask drops).",
+        ("kernel", "mask", "seq")),
     "hvdtpu_spmd_head_loss_traces_total": (
         "Times JAX traced the GPT's head and loss as one rule over blocks of "
         "token rows (models/gpt.py::_head_loss), by the rows a block holds "

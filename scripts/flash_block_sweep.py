@@ -11,6 +11,7 @@ of microseconds). Also holds the compiled kernels to dense attention once.
     chiprun --chips 1 -- python scripts/flash_block_sweep.py [--quick]
     chiprun --chips 1 -- python scripts/flash_block_sweep.py --bwd [cell ...]
     chiprun --chips 1 -- python scripts/flash_block_sweep.py --dense-long
+    chiprun --chips 1 -- python scripts/flash_block_sweep.py --grid [cell ...] [--tile BQ BK] [--repo DIR]
 
 ``--bwd`` times the backward pass alone at a benchmark cell's shape
 (``BWD_SHAPES``: Moonlight's two widths and Trinity's window among them), as
@@ -25,6 +26,16 @@ PR 52).
 under a 4096-key band), the dense side one query head at a time in float32
 (PERF.md, Findings, PR 53).
 
+``--grid`` times the forward kernel and the one backward kernel at a cell's
+shape and mask (``BWD_SHAPES``, all or those named) at the table's tile or a
+forced one (``--tile``), beside the steps a head's grid walks and the tiles it
+keeps: with ``--repo`` a copy of the commit before PR 61, whose grid was the
+whole rectangle, the difference over the steps that did nothing is the price
+of such a step (PERF.md, Findings, PR 61).
+
+``--repo DIR`` times another checkout's kernels (a ``git archive`` of another
+commit) with this script.
+
 One JSON object a line on stdout and in ``chiprun_out/flash_sweep.jsonl``.
 Works on a tree that still has the fixed-tile kernels (``--parent``): there
 only the forward and the whole backward can be timed, at 128x128.
@@ -38,13 +49,12 @@ import os
 import sys
 import time
 
-sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from horovod_tpu.ops import flash_attention as fa
+HERE = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+fa = None     # ``horovod_tpu.ops.flash_attention`` of ``--repo``: ``main``
 
 # The two cells, and two lengths between them at the same 8192 tokens.
 SHAPES = {"s4096": (2, 4096, 24, 2, 128), "s512": (16, 512, 24, 2, 128),
@@ -53,7 +63,7 @@ CELLS = ("s4096", "s512")
 TILES = ((128, 128), (256, 256), (256, 512), (512, 256), (512, 512),
          (512, 1024), (1024, 512), (1024, 1024), (1024, 2048), (2048, 1024),
          (512, 2048), (2048, 512))
-# The backward pass at a cell's shape: (B, S, H, Hkv, D, Dv, window).
+# The attention of a cell's layers: (B, S, H, Hkv, D, Dv, window).
 BWD_SHAPES = {
     "moonlight-16b-a3b_s8192": (2, 8192, 16, 16, 192, 128, None),
     "trinity-mini_s8192": (2, 8192, 32, 4, 128, 128, None),
@@ -65,14 +75,25 @@ BWD_SHAPES = {
     "granite-4.0-h-micro_s4096": (2, 4096, 32, 8, 64, 64, None),
     "starcoder2-3b_s512": (16, 512, 24, 2, 128, 128, None),
     "olmoe-1b-7b_s4096": (2, 4096, 16, 16, 128, 128, None),
+    "smallthinker-21b-a3b_s16384": (1, 16384, 28, 4, 128, 128, None),
+    "smallthinker-21b-a3b_s16384_window": (1, 16384, 28, 4, 128, 128, 4096),
+    # One map of a differential attention layer: 20:10 pairs of heads, keys
+    # of 64 beside values of 128, whole and under the band of 512.
+    "phi-4-mini-flash-reasoning_s16384": (1, 16384, 20, 10, 64, 128, None),
+    "phi-4-mini-flash-reasoning_s16384_window": (1, 16384, 20, 10, 64, 128,
+                                                 512),
 }
 BWD_TILES = ((1024, 1024), (512, 1024), (1024, 512))
 # ``smallthinker-21b-a3b_s16384``'s attention: (B, S, H, Hkv, D), the full
 # layer's mask and the window layers'.
-LONG_SHAPE = (1, 16384, 28, 4, 128)
+LONG_SHAPE = BWD_SHAPES["smallthinker-21b-a3b_s16384"][:5]
 LONG_WINDOWS = (None, 4096)
 OUT = os.path.join("chiprun_out", "flash_sweep.jsonl")
-KERNELS = {"fwd": fa.KERNEL_FWD, "dkdv": fa.KERNEL_DKDV, "dq": fa.KERNEL_DQ}
+KERNELS = ("fwd", "dkdv", "dq")
+
+
+def kernel_name(kernel: str) -> str:
+    return getattr(fa, f"KERNEL_{kernel.upper()}")
 
 
 def emit(**row):
@@ -158,9 +179,54 @@ def time_variant(label, name, shape, dtype, tiles, group_in_hbm, kernels):
             except Exception as e:  # a tile Mosaic refuses is a result too
                 emit(**row, kernel=kernel, tile=t, error=str(e)[:300])
                 continue
-            chosen = t or fa.block_sizes(KERNELS[kernel], s, d, dtype, True)
+            chosen = t or fa.block_sizes(kernel_name(kernel), s, d, dtype,
+                                         True)
             emit(**row, kernel=kernel, tile=list(chosen),
                  table=t is None, ms=ms)
+
+
+def bwd_operands(name, dtype):
+    """q, k, v, dO as the kernels take them at ``BWD_SHAPES[name]``, the
+    logits' scale and the mask."""
+    b, s, h, hkv, d, dv, window = BWD_SHAPES[name]
+    ks = jax.random.split(jax.random.PRNGKey(0), 4)
+    q = jax.random.normal(ks[0], (b * h, s, d), dtype) * 0.5
+    k = jax.random.normal(ks[1], (b * hkv, s, d), dtype) * 0.5
+    v = jax.random.normal(ks[2], (b * hkv, s, dv), dtype) * 0.5
+    do = jax.random.normal(ks[3], (b * h, s, dv), dtype) * 0.5
+    return q, k, v, do, 1.0 / d ** 0.5, fa.Mask(True, window)
+
+
+def time_grid(name, tile=None, dtype=jnp.bfloat16):
+    """The forward kernel and the one backward kernel at cell ``name``'s
+    shape and mask, ms a call (``delta`` made in the backward's), at the
+    table's tile or ``tile``; beside them the tiles of a head's rectangle,
+    those the mask keeps, and the steps its grid walks (a tree whose grid is
+    still the rectangle has no ``Mask.kept_tiles`` and walks them all)."""
+    q, k, v, do, sc, mask = bwd_operands(name, dtype)
+    s, d, dv = q.shape[1], q.shape[2], v.shape[2]
+    bq, bk = tile or fa.block_sizes(fa.KERNEL_DKDV, s, d, dtype, True, dv)
+    tiles = mask.tiles(s // bq, s // bk, bq, bk)
+    row = dict(grid=name, tile=[bq, bk], dtype=jnp.dtype(dtype).name,
+               heads=q.shape[0], rectangle=sum(tiles.values()),
+               kept=tiles["kept"],
+               steps=tiles["kept"] if hasattr(mask, "kept_tiles")
+               else sum(tiles.values()))
+    fwd = lambda q, k, v: fa._fwd_call(q, k, v, sc, mask, s, (bq, bk))
+    o, lse = jax.jit(fwd)(q, k, v)
+    lse = lse[:, None, :, 0]
+
+    def bwd(q, k, v, o, do):
+        delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), -1)
+        return fa._bwd_call(q, k, v, do, lse, delta[:, None, :], sc, mask, s,
+                            (bq, bk))
+
+    try:
+        row["fwd_ms"] = timed(fwd, q, k, v)
+        row["bwd_ms"] = timed(bwd, q, k, v, o, do)
+    except Exception as e:  # a tile Mosaic refuses is a result too
+        row["error"] = str(e)[:300]
+    emit(**row)
 
 
 def time_bwd(name, tiles=BWD_TILES, dtype=jnp.bfloat16):
@@ -168,13 +234,8 @@ def time_bwd(name, tiles=BWD_TILES, dtype=jnp.bfloat16):
     the pair (dKdV, dQ and the statistics' broadcast to lanes that dQ
     reads), ms a call with ``delta`` made in both, and the largest
     difference of their gradients."""
-    b, s, h, hkv, d, dv, window = BWD_SHAPES[name]
-    ks = jax.random.split(jax.random.PRNGKey(0), 4)
-    q = jax.random.normal(ks[0], (b * h, s, d), dtype) * 0.5
-    k = jax.random.normal(ks[1], (b * hkv, s, d), dtype) * 0.5
-    v = jax.random.normal(ks[2], (b * hkv, s, dv), dtype) * 0.5
-    do = jax.random.normal(ks[3], (b * h, s, dv), dtype) * 0.5
-    sc, mask = 1.0 / d ** 0.5, fa.Mask(True, window)
+    q, k, v, do, sc, mask = bwd_operands(name, dtype)
+    s, d, dv = q.shape[1], q.shape[2], v.shape[2]
     o, lse = jax.jit(lambda q, k, v: fa._fwd_call(
         q, k, v, sc, mask, s, None))(q, k, v)
     lse = lse[..., 0]
@@ -339,14 +400,25 @@ def main():
                     help="the forward and the one backward kernel against "
                          "dense attention at 16,384 rows, 28:4 heads, "
                          "causal and under a 4096 band, and nothing else")
+    ap.add_argument("--grid", nargs="*", metavar="CELL", default=None,
+                    help="the forward and the one backward kernel at these "
+                         "cells' shapes and masks (none named: all), beside "
+                         "the steps a head's grid walks")
+    ap.add_argument("--tile", nargs=2, type=int, metavar=("BQ", "BK"),
+                    help="with --grid: this tile, not the table's")
+    ap.add_argument("--repo", default=HERE,
+                    help="the checkout whose kernels are timed")
     args = ap.parse_args()
+    global fa
+    sys.path.insert(0, os.path.abspath(args.repo))
+    from horovod_tpu.ops import flash_attention as fa
     dev = jax.devices()[0]
-    emit(platform=dev.platform, device_kind=dev.device_kind)
+    emit(platform=dev.platform, device_kind=dev.device_kind,
+         repo=os.path.abspath(args.repo))
     if dev.platform != "tpu":
         sys.exit("flash_block_sweep.py times kernels on a TPU; found "
                  f"{dev.platform}")
     bf16, f32 = jnp.bfloat16, jnp.float32
-    all_kernels = tuple(KERNELS)
     if args.parent:
         for name, shape in SHAPES.items():
             time_parent(name, shape, bf16)
@@ -354,38 +426,36 @@ def main():
     if args.dense_long:
         check_long_against_dense()
         return
+    if args.grid is not None:
+        for name in args.grid or BWD_SHAPES:
+            time_grid(name, args.tile and tuple(args.tile))
+        return
     check_against_dense()
     if args.bwd is not None:
         for name in args.bwd or BWD_SHAPES:
             time_bwd(name)
         return
     for name, shape in SHAPES.items():
-        time_variant("table", name, shape, bf16, (None,), False, all_kernels)
+        time_variant("table", name, shape, bf16, (None,), False, KERNELS)
         if args.quick:
             continue
-        time_variant("sweep", name, shape, bf16, TILES, False, all_kernels)
+        time_variant("sweep", name, shape, bf16, TILES, False, KERNELS)
         if name not in CELLS:
             continue
-        best = tuple(fa.block_sizes(k, shape[1], 128, bf16, True)
-                     for k in KERNELS.values())
+        best = tuple(fa.block_sizes(kernel_name(k), shape[1], 128, bf16,
+                                    True) for k in KERNELS)
         # The parts alone, from the old tile: float32 operands, 128x128,
         # K/V repeated in HBM; then each part by itself.
         small = ((128, 128),)
         time_variant("bookkeeping_only", name, shape, f32, small, True,
-                     all_kernels)
+                     KERNELS)
         time_variant("operands_alone", name, shape, bf16, small, True,
-                     all_kernels)
+                     KERNELS)
         time_variant("gqa_alone", name, shape, f32, small, False,
-                     all_kernels)
-        for kernel, tile in zip(all_kernels, best):
+                     KERNELS)
+        for kernel, tile in zip(KERNELS, best):
             time_variant("blocks_alone", name, shape, f32, (tile,), True,
                          (kernel,))
-        # What the clamped index maps are worth, at the table's tiles.
-        keep = fa.Mask.k_blocks, fa.Mask.q_blocks
-        fa.Mask.k_blocks = fa.Mask.q_blocks = lambda *a: (None, None)
-        time_variant("no_clamp", name, shape, bf16, (None,), False,
-                     all_kernels)
-        fa.Mask.k_blocks, fa.Mask.q_blocks = keep
 
 
 if __name__ == "__main__":

@@ -105,8 +105,8 @@ def _dense_on_repeated_heads(q, k, v, causal):
 
 
 # Forced tiles: unequal blocks put the diagonal through a tile's interior,
-# make the causal skip and the clamped index maps work on rectangles, and
-# (256-wide over S=200 -> 256) put the padding inside the only block.
+# make the list of kept tiles one of rectangles, and (256-wide over S=200 ->
+# 256) put the padding inside the only block.
 TILINGS = [(128, 128), (256, 128), (128, 256), (256, 256)]
 
 
@@ -361,6 +361,48 @@ def test_fused_backward_equals_the_pair_at_equal_tiles(monkeypatch, mode,
         rtol=1e-5 if dtype == jnp.float32 else 2 * BF16_EPS)
 
 
+# The mask, the sequence, the tile: four tiles a side or more, so that a
+# grid's one axis runs over rows of several tiles whose first and last steps
+# differ, over tiles the mask drops (six above the diagonal; under the band
+# of 150 three below it, whole), and over the whole rectangle of a
+# bidirectional call whose last tile holds padding.
+KEPT_TILE_GRIDS = {
+    "causal": (True, None, 512, (128, 128)),
+    "band_drops_whole_tiles": (True, 150, 512, (128, 128)),
+    "band_not_square": (True, 150, 1024, (256, 128)),
+    "bidirectional_padded": (False, None, 500, (128, 128)),
+}
+
+
+@pytest.mark.parametrize("backward", ["fused", "pair"])
+@pytest.mark.parametrize("mode", list(KEPT_TILE_GRIDS))
+def test_kept_tile_grids_match_dense(monkeypatch, mode, backward):
+    """The forward output and all three gradients against
+    ``default_attention`` on repeated heads where the grid walks a list of
+    kept tiles several rows long, for the one backward kernel and (forced)
+    the pair of dKdV, whose list runs column by column under each query
+    head of a group of two, and dQ."""
+    causal, window, s, blocks = KEPT_TILE_GRIDS[mode]
+    if backward == "pair":
+        monkeypatch.setattr(fa, "backward_is_fused", lambda *a: False)
+    q, k, v = _qkv_two_widths(s, 4, 2, 32, 32, seed=len(mode))
+    w = jax.random.normal(jax.random.PRNGKey(67), q.shape) * 0.1
+
+    def dense(q, k, v):
+        return default_attention(q, repeat_kv_heads(k, 4),
+                                 repeat_kv_heads(v, 4), causal=causal,
+                                 window=window)
+
+    got = _value_and_grads(flash_attention, q, k, v, w, causal=causal,
+                           window=window, _blocks=blocks)
+    want = _value_and_grads(dense, q, k, v, w)
+    np.testing.assert_allclose(got[0], want[0], rtol=2e-5, atol=2e-5,
+                               err_msg="forward")
+    for g, r, name in zip(got[1:], want[1:], "qkv"):
+        np.testing.assert_allclose(g, r, rtol=5e-4, atol=5e-5,
+                                   err_msg=f"d{name}")
+
+
 def test_backward_over_the_vmem_limit_takes_the_pair_and_says_so(
         monkeypatch, make_runtime):
     """A sequence whose dK and dV sums do not fit the VMEM a call asks for
@@ -552,24 +594,25 @@ def test_block_table_counts_both_widths(kernel):
 
 
 # sha256 of the StableHLO text (no source locations) that a call with one
-# head width lowers to, interpreted kernels included. ``forward``: what the
-# output alone lowered to at the commit before a value head could have a
-# width of its own (PR 49) and at every commit since, the one that made the
-# backward pass one kernel (PR 52) included: that PR left the forward kernel
-# alone. ``gradient``: what the gradient lowers to since PR 52 (before it,
-# with a dKdV and a dQ kernel, b7df1a33...efb65d and 251c9b28...56d24). A
-# change that means to alter what such a call traces to pins these anew.
+# head width lowers to, interpreted kernels included. Pinned anew by PR 61,
+# which meant to change what such a call traces to: every kernel's grid is
+# the mask's kept tiles, read from a scalar-prefetch table, where it was the
+# whole rectangle of blocks. (Before it ``forward`` had stood since before a
+# value head could have a width of its own, PR 49: 36607613...842723 and
+# d40641ff...07d496; ``gradient`` since the backward pass became one kernel,
+# PR 52: 130bc696...8fe30c and e92ada63...55bcbf.) A change that means to
+# alter what such a call traces to pins these anew.
 LOWERED = {
     (4, 2, 64, None, "bfloat16"): dict(
-        forward="36607613775a8ecb9d5e3767d7ef7ed6"
-                "03665a7d8b6e70fafef6c5981d842723",
-        gradient="130bc696fdacfb997093f900d641bc79"
-                 "faca65d6062d2bc4d282c6fb4d8fe30c"),
+        forward="3c95cf8de0618e21e09d957f0a74a8ed"
+                "cb6e4e5cbb3a34ca5dc5f0ef1a39d2cc",
+        gradient="bff3e06a472781c110c44f20367b1887"
+                 "88c29c879518817a185856f3162be42e"),
     (2, 2, 128, 96, "float32"): dict(
-        forward="d40641ff57c91b0fe400c396aff8238d"
-                "c64e100b66fbddf8d39febc06007d496",
-        gradient="e92ada637d10f683dc820f6c139914bb"
-                 "4f2021fd4e2ff31055b5fa091155bcbf"),
+        forward="e3e550643e36aa04303183f911c13827"
+                "2587636e355acd0f3d9e9221723ecde1",
+        gradient="8ad34bdc9babdc7b98e7189e39c9cc9c"
+                 "b4fadc89b8e4e965aa42892f0de3a0b9"),
 }
 
 

@@ -5,8 +5,9 @@ Interpret mode (every other flash test) says nothing about Mosaic lowering:
 block shapes, VMEM, layouts. The TPU compiler is installed in the sandbox and
 compiles for a chip that is described and not attached, so the kernels of the
 benchmark's two GPT cells (and the small shapes ``chip_smoke.py`` runs) are
-compiled here at their real sizes with the tiles the block table gives them.
-Nothing runs; a compile that passes is not a chip run.
+compiled here at their real sizes with the tiles the block table gives them,
+their grids one axis over the mask's kept tiles, read from a scalar-prefetch
+table (since PR 61). Nothing runs; a compile that passes is not a chip run.
 
 All such tests live in this one file: the worker that gets it loads libtpu
 and holds its lock until it exits.
@@ -98,6 +99,9 @@ FUSED_SHAPES = {
 @pytest.mark.parametrize("shape, kernel", [
     *((shape, kernel) for shape in SHAPES
       for kernel in ("fwd", "dkdv", "dq")),
+    # The pair under a band: the dKdV kernel's table runs column by column
+    # under each of eight query heads, the dQ kernel's row by row.
+    *(("trinity-mini_s8192_window", kernel) for kernel in ("dkdv", "dq")),
     *((shape, "fwd") for shape in LONGEST),
     *((shape, "fused") for shape in FUSED_SHAPES)])
 def test_kernel_compiles_for_v5e(one_chip, mosaic, shape, kernel):

@@ -2,8 +2,9 @@
 ``default_attention`` under the same band: outputs and all three gradients
 element by element (the backward pass as the one kernel every shape here
 takes, and as the pair of dKdV and dQ it replaced), the tiles each grid keeps
-counted against the formula, and the one mask description (``fa.Mask``) the
-kernels read.
+counted against the formula, the one mask description (``fa.Mask``) the
+kernels read, and the lists of kept tiles it makes, which are the kernels'
+grids.
 
 Interpret mode on the CPU (as ``test_flash_attention.py``): it says nothing
 of Mosaic lowering, which ``test_flash_mosaic_compile.py`` holds.
@@ -112,11 +113,11 @@ BANDS = [(512, 200, (256, 128), 1), (512, 200, (128, 256), 4),
 @pytest.mark.parametrize("s,window,blocks,group", BANDS)
 def test_band_backward_as_one_kernel_and_as_the_pair(monkeypatch, s, window,
                                                      blocks, group):
-    """Under a band the one backward kernel skips the tiles the pair skips
-    and clamps the K/V blocks it names as the dQ kernel does: both match the
-    dense reference; dK and dV are the pair's bit for bit (the same float32
-    sums in the same order), dQ to float32 rounding (its product contracts
-    the tile's other axis)."""
+    """Under a band the one backward kernel walks the tiles the pair walks
+    (the dQ kernel's list, under each query head; the dKdV kernel's is the
+    same tiles column by column): both match the dense reference; dK and dV
+    are the pair's bit for bit (the same float32 sums in the same order), dQ
+    to float32 rounding (its product contracts the tile's other axis)."""
     one, want = _both(s, window, blocks, h=group, hkv=1, seed=13)
     _assert_close(one, want)
     # What a sequence too long for a head's dK and dV in VMEM keeps.
@@ -188,24 +189,81 @@ def test_kept_tiles_follow_the_formula(s, window, bq, bk):
         assert (kept, causal) == (21, 36)
 
 
-def test_tile_predicates_and_block_ranges_agree_with_the_count():
-    """What the kernels read a tile at a time (``tile_kept``) and what the
-    index maps clamp to (``k_blocks``, ``q_blocks``) describe the same
-    band: every kept tile lies inside both ranges and the ranges' ends are
-    kept tiles."""
-    bq, bk, n = 256, 128, 1024
-    mask = fa.Mask(True, 300)
+def _pairs_kept(mask, i, j, bq, bk, kv_len):
+    """Whether tile ``(i, j)`` holds a pair ``mask.keep`` keeps, pair by
+    pair."""
+    qs = np.arange(i * bq, (i + 1) * bq)[:, None]
+    ks = np.arange(j * bk, (j + 1) * bk)[None, :]
+    return bool(np.any(mask.keep(qs, ks, kv_len)))
+
+
+# mask, sequence, tile: causal, a band and no mask at tiles that are not
+# square either way; a band narrower than a tile (Phi-4's, at a tile of
+# 1024 and at the tile that follows it); a band of one key; one tile.
+KEPT_TILE_CASES = [
+    (fa.Mask(), 1024, 256, 128), (fa.Mask(), 1024, 128, 256),
+    (fa.Mask(True, 300), 1024, 256, 128),
+    (fa.Mask(True, 300), 1024, 128, 256),
+    (fa.Mask(False), 1024, 256, 128), (fa.Mask(False), 768, 128, 256),
+    (fa.Mask(True, 512), 16384, 1024, 1024),
+    (fa.Mask(True, 512), 4096, 512, 512),
+    (fa.Mask(True, 4096), 16384, 1024, 1024),
+    (fa.Mask(True, 1), 512, 128, 128), (fa.Mask(), 512, 512, 512),
+]
+
+
+@pytest.mark.parametrize(
+    "mask, n, bq, bk", KEPT_TILE_CASES,
+    ids=lambda x: f"{x.name}{x.window or ''}" if isinstance(x, fa.Mask)
+    else str(x))
+def test_kept_tile_lists_are_the_brute_enumeration(mask, n, bq, bk):
+    """The table a grid walks (``kept_tiles``) is the tiles ``tile_kept``
+    holds, which are the tiles that hold a kept pair, each once: row-major
+    with a query block's tiles together and the keys ascending, column-major
+    with a key block's together and the queries ascending; the flags mark
+    each run's first and last tile and no other; and the count is the
+    counter's (``tiles``)."""
     n_q, n_k = n // bq, n // bk
-    kept = np.array([[bool(mask.tile_kept(jnp.int32(i), jnp.int32(j), bq, bk))
-                      for j in range(n_k)] for i in range(n_q)])
-    assert kept.sum() == mask.tiles(n_q, n_k, bq, bk)["kept"]
-    for i in range(n_q):
-        first, last = (int(x) for x in mask.k_blocks(jnp.int32(i), bq, bk))
-        assert list(np.flatnonzero(kept[i])) == list(range(first, last + 1))
-    for j in range(n_k):
-        first, last = (int(x) for x in mask.q_blocks(jnp.int32(j), bq, bk))
-        assert list(np.flatnonzero(kept[:, j])) \
-            == list(range(first, min(last, n_q - 1) + 1))
+    brute = [(i, j) for i in range(n_q) for j in range(n_k)
+             if mask.tile_kept(i, j, bq, bk)]
+    if n <= 1024:
+        assert brute == [(i, j) for i in range(n_q) for j in range(n_k)
+                         if _pairs_kept(mask, i, j, bq, bk, n)]
+    assert len(brute) == mask.tiles(n_q, n_k, bq, bk)["kept"]
+    for by_column, run in ((False, fa.TILE_Q), (True, fa.TILE_K)):
+        table = mask.kept_tiles(n_q, n_k, bq, bk, by_column)
+        assert table.dtype == np.int32 and table.shape == (4, len(brute))
+        tiles = list(zip(table[fa.TILE_Q].tolist(),
+                         table[fa.TILE_K].tolist()))
+        assert tiles == sorted(brute, key=lambda t: t[::-1] if by_column
+                               else t)
+        runs = table[run]
+        # Every row (column) of the rectangle has a run, the runs ascend,
+        # and a run's tiles are neighbours (the band has no hole).
+        assert sorted(set(runs.tolist())) \
+            == list(range(n_k if by_column else n_q))
+        first = np.r_[True, runs[1:] != runs[:-1]]
+        last = np.r_[runs[1:] != runs[:-1], True]
+        np.testing.assert_array_equal(table[fa.TILE_FIRST], first)
+        np.testing.assert_array_equal(table[fa.TILE_LAST], last)
+        other = table[fa.TILE_K if run == fa.TILE_Q else fa.TILE_Q]
+        assert np.all((np.diff(other) == 1) | first[1:])
+
+
+def test_a_k_blocks_run_is_walked_once_a_query_head_of_the_group():
+    """The dKdV kernel of the pair sums a k block over the group's query
+    heads, the heads the outer order: its table holds each column's run
+    once a head, opened under the first and closed under the last."""
+    table = fa.Mask(True, 300).kept_tiles(4, 8, 256, 128, by_column=True)
+    walked = fa._over_group(table, 3)
+    assert walked.shape == (5, 3 * table.shape[1])
+    steps = list(zip(*walked[[fa.TILE_K, fa.TILE_HEAD, fa.TILE_Q]].tolist()))
+    assert steps == sorted(steps) and len(set(steps)) == len(steps)
+    for j in range(8):
+        of_j = walked[:, walked[fa.TILE_K] == j]
+        assert of_j[fa.TILE_FIRST].tolist() == [1] + [0] * (of_j.shape[1] - 1)
+        assert of_j[fa.TILE_LAST].tolist() == [0] * (of_j.shape[1] - 1) + [1]
+    np.testing.assert_array_equal(fa._over_group(table, 1)[:4], table)
 
 
 def test_band_pairs_by_hand():
@@ -250,3 +308,57 @@ def test_metrics_count_the_tiles_kept_and_skipped(make_runtime, monkeypatch,
         assert count(kernel, "causal", "skipped_band") == 0
     if backward == "fused":
         assert count(fa.KERNEL_DQ, "window", "kept") is None
+
+
+def _pallas_grids(jaxpr):
+    """``(kernel name, grid)`` of every ``pallas_call`` in ``jaxpr``,
+    nested jaxprs included."""
+    out = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            out.append((eqn.params["name"],
+                        tuple(eqn.params["grid_mapping"].grid)))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            out.extend(_pallas_grids(sub))
+    return out
+
+
+@pytest.mark.parametrize("backward", ["fused", "pair"])
+@pytest.mark.parametrize("causal, window, s", [
+    (True, None, 512), (True, 200, 512), (True, 64, 768), (False, None, 400)])
+def test_grid_steps_are_the_kept_tiles(make_runtime, monkeypatch, backward,
+                                       causal, window, s):
+    """No grid step is a tile the mask drops: the counter of the steps a
+    head's grid walks equals the kept tiles' in every traced call, and it is
+    the grid the call was given (a head's steps, times the group's query
+    heads where a K/V head's grid walks them all)."""
+    make_runtime(devices=jax.devices()[:1])
+    if backward == "pair":
+        monkeypatch.setattr(fa, "backward_is_fused", lambda *a: False)
+    q, k, v, _ = _qkv(1, s, 4, 2, 32)
+    grad = jax.grad(lambda q, k, v: jnp.sum(flash_attention(
+        q, k, v, causal=causal, window=window, _blocks=(128, 256))),
+        argnums=(0, 1, 2))
+    grids = _pallas_grids(jax.make_jaxpr(grad)(q, k, v).jaxpr)
+    fams = hvd.metrics()
+    mask = fa.Mask(causal, window).name
+    seq = str(s + (-s) % 128)
+    kernels = KERNELS[:2 if backward == "fused" else 3]
+    assert sorted(name for name, _ in grids) == sorted(kernels)
+    for kernel in kernels:
+        labels = dict(kernel=kernel, mask=mask, seq=seq)
+        steps = sample_value(fams, "hvdtpu_spmd_flash_grid_steps_total",
+                             **labels)
+        kept = sample_value(fams, "hvdtpu_spmd_flash_tiles_total",
+                            tiles="kept", **labels)
+        assert steps == kept > 0
+        # q at 4 heads walks a head's steps; a K/V head's grid (2 of them)
+        # walks them under each of its two query heads: an axis of their
+        # own in the one backward kernel, in the pair's dKdV the
+        # column-major list once a head.
+        want = {fa.KERNEL_FWD: (4, steps), fa.KERNEL_DQ: (4, steps),
+                fa.KERNEL_DKDV: (2, 2, steps) if backward == "fused"
+                else (2, 2 * steps)}
+        assert dict(grids)[kernel] == want[kernel]
+    if not causal:
+        assert kept == (int(seq) // 128) * (int(seq) // 256)
