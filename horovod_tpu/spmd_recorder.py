@@ -12,7 +12,7 @@ the persistent cache hits or is written; ``hvd.init`` times its own phases;
 what a block keeps notes it while JAX traces it (``_TRACED``); and what the
 compiler made of a step is reduced when ``hvd.compiled_step_report`` asks and
 shown from then on (``_STEP_FAMILIES``). Nothing here runs on a step's path:
-the listeners fire only while JAX compiles, and ``shard_batch`` pays two
+the listeners fire only while JAX compiles, and ``shard_batch`` pays four
 integer additions and takes no lock.
 
 Spans, only between ``start_timeline`` and ``stop_timeline``: the ``hvd.init``
@@ -275,6 +275,7 @@ class SpmdRecorder:
         self.init_done_process_s: Optional[float] = None
         self.placed_calls = 0
         self.placed_bytes = 0
+        self.placed_leaves = {"flat": 0, "direct": 0}
         # family of _TRACED -> its label values -> traces (or bytes)
         self.traced: dict = {name: collections.Counter() for name in _TRACED}
         # function -> (its traced shapes, report): compiled_step_report's
@@ -365,9 +366,11 @@ class SpmdRecorder:
                 have = self.step_reports[function] = (traced, report)
         return have[1]
 
-    def note_placed(self, nbytes: int) -> None:
+    def note_placed(self, nbytes: int, leaves: int, flat: int) -> None:
         self.placed_calls += 1
         self.placed_bytes += nbytes
+        self.placed_leaves["flat"] += flat
+        self.placed_leaves["direct"] += leaves - flat
 
     # ---- hvd.metrics() ---------------------------------------------------
 
@@ -414,6 +417,13 @@ class SpmdRecorder:
             "hvdtpu_spmd_shard_batch_bytes_total": family(
                 "counter", "Host bytes hvd.shard_batch was given to place.",
                 [("", {}, float(self.placed_bytes))]),
+            "hvdtpu_spmd_shard_batch_leaves_total": family(
+                "counter", "Leaves hvd.shard_batch placed, by the path they "
+                "took: flat (a host array of rank 3 or more crossed as its "
+                "[N, rest] view and took its shape on the device) or direct "
+                "(jax.device_put on the leaf as it is).",
+                [("", {"path": path}, float(n))
+                 for path, n in self.placed_leaves.items()]),
         }
         for name, (help_, labels) in _TRACED.items():
             out[name] = family("counter", help_, [

@@ -136,23 +136,59 @@ def data_parallel_step(train_step, donate_state: bool = True,
                     donate_argnums=(0, 1) if donate_state else ())
 
 
+@functools.lru_cache(maxsize=32)
+def _restorer(shape: tuple, sharding: NamedSharding):
+    """The device half of :func:`shard_batch`'s flat path: ``[N, rest]`` back
+    to ``shape`` in ``sharding``, the flat array donated (no second batch is
+    held). One jitted function a shape and sharding, compiled once a dtype."""
+    def restore_batch_shape(flat):      # the name a trace and a span show
+        return flat.reshape(shape)
+    return jax.jit(restore_batch_shape, out_shardings=sharding,
+                   donate_argnums=0)
+
+
+def _crosses_flat(x, dim: int, sharding: NamedSharding) -> bool:
+    """Whether ``x`` is a host leaf whose bytes can cross in their own order:
+    a C-contiguous ``numpy`` array of rank 3 or more that is not empty, split
+    on its leading dimension over devices that are all this process's."""
+    return (dim == 0 and isinstance(x, np.ndarray) and x.ndim >= 3
+            and x.size > 0 and x.flags.c_contiguous
+            and sharding.is_fully_addressable)
+
+
 def shard_batch(batch, dim: int = 0, axis: Optional[str] = None, mesh=None):
     """Place a host batch onto the mesh, sharded on ``dim`` over the DP axis.
 
     The TPU-native replacement for per-rank data loading: one host feeds the whole
     mesh (or its local slice under multi-host jax).
+
+    The device's layout of a leaf of rank 3 or more need not be its bytes'
+    order (a v5e keeps a uint8 NHWC batch with N on the lanes), and the
+    runtime makes that layout on the host before a byte crosses: 40 to 50 ms
+    a 38.5 MB batch, which a chip with an empty queue waits for, against 8
+    (``scripts/place_time.py``). Such a leaf (``_crosses_flat``) crosses as
+    its ``[N, rest]`` view, whose layout is its own order, and takes its
+    shape on the device (``_restorer``): the same shape, dtype, sharding and
+    layout as ``jax.device_put(x, sharding)`` gives, which is what every
+    other leaf gets.
     """
     m = mesh if mesh is not None else runtime.mesh()
     sharding = NamedSharding(m, batch_spec(dim, axis))
-    # Counted always (two additions, no lock); timed, and watched until every
+    # Counted always (four additions, no lock); timed, and watched until every
     # chip has the batch, only while a timeline runs (hvd.start_timeline).
     recorder = runtime.recorder()
     timed = recorder is not None and recorder.spans is not None
     t0 = time.time_ns() if timed else 0
-    placed = jax.tree.map(lambda x: jax.device_put(x, sharding), batch)
+    leaves, treedef = jax.tree.flatten(batch)
+    flat = [_crosses_flat(x, dim, sharding) for x in leaves]
+    placed = jax.tree.unflatten(treedef, [
+        _restorer(x.shape, sharding)(jax.device_put(
+            x.reshape(x.shape[0], -1), sharding))
+        if is_flat else jax.device_put(x, sharding)
+        for x, is_flat in zip(leaves, flat)])
     if recorder is not None:
-        recorder.note_placed(sum(getattr(x, "nbytes", 0)
-                                 for x in jax.tree.leaves(batch)))
+        recorder.note_placed(sum(getattr(x, "nbytes", 0) for x in leaves),
+                             leaves=len(leaves), flat=sum(flat))
     if timed:
         t1 = time.time_ns()
         recorder.span("shard_batch", t0, t1)

@@ -720,6 +720,41 @@ def test_timeline_writes_spans_on_the_wall_clock(spmd4, tmp_path,
     assert t0 <= meta["profile_start_time"] <= t1
 
 
+def test_batch_ready_waits_for_the_restored_batch(spmd4, tmp_path,
+                                                  monkeypatch):
+    """A leaf that crosses flat (rank 3 or more) is ready when the array the
+    step takes is: the watcher is handed shard_batch's result, not the
+    ``[N, rest]`` array that crossed."""
+    waited = []
+    wait = jax.block_until_ready
+
+    def waits(tree):
+        out = wait(tree)
+        if threading.current_thread().name == spmd_recorder.WATCHER_THREAD:
+            waited.append((tree, time.time_ns()))
+        return out
+
+    monkeypatch.setattr(jax, "block_until_ready", waits)
+    images = np.arange(8 * 4 * 4 * 3, dtype=np.uint8).reshape(8, 4, 4, 3)
+    labels = np.arange(8, dtype=np.int32)
+    path = tmp_path / "timeline.json"
+    hvd.start_timeline(str(path))
+    placed = hvd.shard_batch((images, labels))
+    hvd.stop_timeline()
+    (tree, ready_ns), = waited
+    assert tree is placed
+    assert placed[0].shape == images.shape
+    m = hvd.metrics()
+    assert sample_value(m, "hvdtpu_spmd_shard_batch_leaves_total",
+                        path="flat") == 1
+    assert sample_value(m, "hvdtpu_spmd_shard_batch_leaves_total",
+                        path="direct") == 1
+    spans = {e["name"]: e["args"] for e in
+             json.loads(path.read_text())["traceEvents"]}
+    assert spans["batch_ready"]["start_ns"] == spans["shard_batch"]["end_ns"]
+    assert spans["batch_ready"]["end_ns"] >= ready_ns
+
+
 def test_compile_causes(spmd4, tmp_path):
     def _caused(x):
         return hvd.allreduce(x.sum())
