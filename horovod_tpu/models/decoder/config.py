@@ -233,6 +233,26 @@ class GPTConfig:
     # embed_dim / 16); a "gmu" mixer gates the same s6_inner channels.
     s6_inner: Optional[int] = None
     s6_dt_rank: Optional[int] = None
+    # A "kda" mixer (Kimi delta attention) has kda_heads heads with a key of
+    # kda_key_dim and a value of kda_value_dim each, a convolution of
+    # kda_conv taps and a scan in chunks of kda_chunk tokens. Its log decay
+    # is one a key channel, from a gate bounded below at kda_lower_bound,
+    # and its output goes under one sigmoid gate a head.
+    kda_heads: int = 8
+    kda_key_dim: int = 128
+    kda_value_dim: int = 128
+    kda_conv: int = 4
+    kda_chunk: int = 64
+    kda_lower_bound: float = -5.0
+    # An "mla" mixer's output times sigmoid(h W_g), one gate a head, before
+    # the output projection.
+    mla_head_gate: bool = False
+    # The router's choice limited to groups: the experts are router_groups
+    # groups, a group's score the sum of its two largest leaning scores, and
+    # a token's experts are chosen inside the router_groups_kept best groups
+    # (1 and 1: one choice over all the scores).
+    router_groups: int = 1
+    router_groups_kept: int = 1
 
     @property
     def kv_heads(self) -> int:

@@ -249,7 +249,8 @@ def apply(cfg: GPTConfig, spec, m, h, router_state=None, early=None):
         renormalize=cfg.renormalize_experts, score=cfg.router_score,
         bias=m["router_bias"] if cfg.router_bias else None,
         scale=cfg.route_scale, probe=cfg.router_probe,
-        activation=cfg.expert_activation, **router)
+        activation=cfg.expert_activation, router_groups=cfg.router_groups,
+        router_groups_kept=cfg.router_groups_kept, **router)
     if early is not None and cfg.router_probe:
         # The probe's operand is what the early product read, not ``h``.
         read = early[0].reshape(-1, early[0].shape[-1])

@@ -19,9 +19,9 @@ for the names its ``spec.publishes`` holds. ``s6`` publishes its scan's
 output to ``gmu``; ``diff_attention`` its keys and values to ``diff_cross``
 (one module's two mixers: they differ in which projections a layer has)."""
 
-from . import attention, cca, diff_attention, gdn, gmu, mla, s6, ssm
+from . import attention, cca, diff_attention, gdn, gmu, kda, mla, s6, ssm
 
 MIXERS = {"attention": attention, "cca": cca, "mla": mla, "ssm": ssm,
-          "gdn": gdn, "s6": s6, "gmu": gmu,
+          "gdn": gdn, "kda": kda, "s6": s6, "gmu": gmu,
           "diff_attention": diff_attention.SELF,
           "diff_cross": diff_attention.CROSS}

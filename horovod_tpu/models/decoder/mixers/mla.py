@@ -6,7 +6,9 @@ key a token is shared by all heads, so the scores are over ``head_dim +
 mla_rope_dim`` dimensions and the values of another width. It runs under
 ``attn`` and through ``parts._attention``; a bound tp axis holds a shard of
 its heads (``wq``, ``wkv_b`` and ``wo`` by head, the down-projection and
-the latent's norm whole on every rank)."""
+the latent's norm whole on every rank). Under ``mla_head_gate`` the
+attention's output is multiplied a head by ``sigmoid(h W_g)``, ``W_g`` ``[E,
+H]``, before the output projection (scope ``mla_gate``)."""
 
 import jax
 import jax.numpy as jnp
@@ -33,9 +35,13 @@ def _parameters(cfg: GPTConfig, keys=None, dense=None, norm=None) -> dict:
     and the output projection."""
     E, H, rank, tp = cfg.embed_dim, cfg.num_heads, cfg.mla_kv_rank, cfg.tp_axis
     nope, rot, value = cfg.head_dim, cfg.mla_rope_dim, cfg.mla_value_dim
-    k = subkeys(keys, 4)
+    k = subkeys(keys, 5 if cfg.mla_head_gate else 4)
+    # One sigmoid gate a head on the attention's output, by head under tp.
+    gate = {"w_gate": (P(None, tp), lambda: dense(k(4), (E, H), E))} \
+        if cfg.mla_head_gate else {}
 
     return {
+        **gate,
         "wq": (P(None, tp, None),
                lambda: dense(k(0), (E, H, nope + rot), E)),
         "wkv_a": (P(), lambda: dense(k(1), (E, rank + rot), E)),
@@ -60,14 +66,15 @@ def apply(cfg: GPTConfig, spec: LayerSpec, p, h, positions):
     ``dr`` dimensions of ``qr_h`` and of ``kr0``, **one** rotary key a token
     that every head shares; ``k_h = [kn_h | kr]``; the attention
     ``_attention`` picks, scores over ``dn + dr`` dimensions scaled by one
-    over its root, values ``dv`` wide; ``W_o``. No bias. Under a bound tp
+    over its root, values ``dv`` wide; under ``mla_head_gate`` times ``sigmoid((h W_g)_h)``
+    a head; ``W_o``. No bias. Under a bound tp
     axis a rank holds a shard of the heads (``W_q``, ``W_kv_b``, ``W_o``)
     and makes the latent and the shared key whole."""
     nope, rot, rank = cfg.head_dim, cfg.mla_rope_dim, cfg.mla_kv_rank
     runtime.note_traced(
         "hvdtpu_spmd_mla_traces_total", heads=cfg.num_heads, nope_dim=nope,
         rope_dim=rot, value_dim=cfg.mla_value_dim, kv_rank=rank,
-        q_rank="none")
+        q_rank="none", gate="head" if cfg.mla_head_gate else "none")
     with jax.named_scope("mla_proj"):
         q = jnp.einsum("bse,ehd->bshd", h, p["wq"].astype(cfg.dtype))
         a = jnp.einsum("bse,ef->bsf", h, p["wkv_a"].astype(cfg.dtype))
@@ -88,6 +95,13 @@ def apply(cfg: GPTConfig, spec: LayerSpec, p, h, positions):
             # over the root of the query's width, the rest goes onto q.
             q = q * (cfg.attention_multiplier * float(np.sqrt(nope + rot)))
     attn = _attention(cfg, q, k, v)
+    if cfg.mla_head_gate:
+        with jax.named_scope("mla_gate"):
+            open_ = jax.nn.sigmoid(jnp.einsum(
+                "bse,eh->bsh", h, p["w_gate"].astype(cfg.dtype),
+                preferred_element_type=jnp.float32))
+            attn = (attn.astype(jnp.float32)
+                    * open_[..., None]).astype(cfg.dtype)
     with jax.named_scope("mla_proj"):
         o = jnp.einsum("bshd,hde->bse", attn, p["wo"].astype(cfg.dtype))
     return _tp_psum(o, cfg)

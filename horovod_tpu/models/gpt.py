@@ -59,7 +59,7 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
 from .. import runtime
-from ..ops import flash_attention, gated_delta, s6
+from ..ops import flash_attention, gated_delta, kda, s6
 from ..ops.pallas_util import varying_like
 from ..parallel import moe
 from ..parallel.axes import axis_bound as _axis_bound
@@ -287,7 +287,8 @@ def _block(cfg: GPTConfig, spec: LayerSpec, layer_params, x, positions,
 # ``olmo-hybrid-7b_s8192`` +3.9% for 8 of 60, ``zaya1-8b_s4096`` +4.6%, 12 of 64
 BLOCK_SAVED_NAMES = ("branch_out",)
 SAVED_NAMES = (flash_attention.SAVED_NAMES + gated_delta.SAVED_NAMES
-               + s6.SAVED_NAMES + moe.SAVED_NAMES + BLOCK_SAVED_NAMES + sum(
+               + kda.SAVED_NAMES + s6.SAVED_NAMES + moe.SAVED_NAMES
+               + BLOCK_SAVED_NAMES + sum(
                    (part.SAVED_NAMES for part in dict.fromkeys(
                        (*MIXERS.values(), *FEED_FORWARDS.values()))), ()))
 _save_names = jax.checkpoint_policies.save_only_these_names(*SAVED_NAMES)

@@ -141,7 +141,8 @@ the residuals carry the name, and where they are not it reads them named,
 or the recomputed copy would want ``hvd_gdn_fwd`` for them (PERF.md,
 Findings, PR 56).
 
-**The inverse in VMEM** (:func:`_inverse_in_vmem`): the diagonal blocks of
+**The inverse in VMEM** (``pallas_util.unit_lower_inverse_in_vmem``, which the
+``hvd_kda_*`` kernels share): the diagonal blocks of
 ``_SUBSTITUTE`` = 32 rows by forward substitution on the vector unit (a
 column a step, exact float32), then :func:`_inverse`'s rounds from there up:
 at a chunk of 64, one round of two ``[64, 64]`` products on the MXU at the
@@ -177,9 +178,10 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .. import runtime
-from .pallas_util import LANES, NEG_INF, NT, SUBLANES, TN, always, \
-    largest_divisor, out_vma as _out_vma, use_interpret as _use_interpret, \
-    varying_like
+from .pallas_util import LANES, NEG_INF, NORM_EPS as _NORM_EPS, NT, \
+    SUBLANES, TN, always, column_as_row, largest_divisor, \
+    out_vma as _out_vma, raw_row_cotangents, row_sum as _row_sum, \
+    unit_lower_inverse_in_vmem, use_interpret as _use_interpret, varying_like
 
 # The kernels' names in the compiled program and in a device trace; the
 # benchmark's readers match ``^hvd_gdn_`` (tests/test_program_names.py).
@@ -202,7 +204,6 @@ KERNEL_REC_BWD = "hvd_gdn_rec_bwd"
 # of a head that is carried padded stay recomputed.
 SAVED_NAMES = ("gdn_scan_operands", "gdn_scan_entering")
 _HI = lax.Precision.HIGHEST
-_NORM_EPS = 1e-6  # added to a row's sum of squares under ``norm_qk``
 _MAX_CHUNKS = 4   # chunks a grid cell, at most
 _SUBSTITUTE = 32  # rows of the inverse's diagonal blocks made by substitution
 _REC_HEADS = 8    # value heads a grid cell of the recurrence, at most
@@ -268,7 +269,8 @@ def _inverse(a):
     2b`` diagonal block of ``a`` (the product is ``M22^-1 M21 M11^-1`` there
     and zero elsewhere). ``D_1 = I``, so the first round is a mask.
     (:func:`unit_lower_inverse`'s body, and with it the reference for
-    :func:`_inverse_in_vmem`: no program path has called it since PR 34.)"""
+    ``pallas_util.unit_lower_inverse_in_vmem``: no program path has called it
+    since PR 34.)"""
     size = a.shape[-1]
     rows, cols = np.arange(size)[:, None], np.arange(size)[None, :]
 
@@ -291,7 +293,8 @@ def unit_lower_inverse(a):
     """``(I + a)^{-1}`` for ``a`` ``[..., n, n]`` strictly lower triangular
     (what lies on or above the diagonal is not read), ``n`` a power of two,
     in ``a``'s type: the module docstring's inverse by blocks. The reference
-    the tests hold :func:`_inverse_in_vmem` (the kernels' ``T``) to; no
+    the tests hold ``pallas_util.unit_lower_inverse_in_vmem`` (the kernels'
+    ``T``) to; no
     program path has called it since PR 34."""
     return _inverse(a)
 
@@ -308,43 +311,6 @@ def _inverse_bwd(inv, g):
 
 
 unit_lower_inverse.defvjp(_inverse_fwd, _inverse_bwd)
-
-
-def _inverse_in_vmem(a):
-    """``(I + a)^{-1}`` of one ``[n, n]`` float32 matrix as a kernel can
-    trace it (masks from ``iota``, no captured constant), full float32
-    throughout. The diagonal blocks of ``_SUBSTITUTE`` rows by forward
-    substitution on the vector unit, a column a step: ``(I + a) X = I``
-    with ``X`` starting as ``I``; at step ``j`` row ``j`` is final and ``X_i
-    -= a_ij X_j`` for the rows below it in its block (eight-row tiles, those
-    above ``j`` skipped). Then :func:`_inverse`'s rounds from that block
-    size up, every product on the MXU at the highest precision.
-    ``_SUBSTITUTE`` is a multiple of eight, or 1: no substitution, every
-    round a product."""
-    size = a.shape[-1]
-    block, tile = min(_SUBSTITUTE, size), min(8, size)
-    rows = lax.broadcasted_iota(jnp.int32, (size, size), 0)
-    cols = lax.broadcasted_iota(jnp.int32, (size, size), 1)
-
-    def lower_left(shift):
-        """Block size ``b = 2 ** shift``: :func:`_inverse`'s mask."""
-        return ((rows >> (shift + 1)) == (cols >> (shift + 1))) \
-            & (((rows >> shift) & 1) == 1) & (((cols >> shift) & 1) == 0)
-
-    inv = jnp.where(rows == cols, 1.0, 0.0).astype(a.dtype)
-    at = [slice(t * tile, (t + 1) * tile) for t in range(size // tile)]
-    x, a_t = [inv[at_t] for at_t in at], [a[at_t] for at_t in at]
-    for j in range(size):
-        x_j = x[j // tile][j % tile:j % tile + 1]
-        for t in range((j + 1) // tile, (j // block + 1) * block // tile):
-            x[t] = x[t] - a_t[t][:, j:j + 1] * x_j
-    inv = jnp.concatenate(x, axis=0)
-    for shift in range(block.bit_length() - 1, size.bit_length() - 1):
-        left = jnp.dot(inv, jnp.where(lower_left(shift), a, 0.0),
-                       precision=_HI, preferred_element_type=a.dtype)
-        inv = inv - jnp.dot(left, inv, precision=_HI,
-                            preferred_element_type=a.dtype)
-    return inv
 
 
 def chunks_per_block(n_chunks: int) -> int:
@@ -379,11 +345,6 @@ def _tiling(kernel, key_dim, width, chunk, heads_per_block, dtype):
         "hvdtpu_spmd_gdn_kernel_traces_total", kernel=kernel, chunk=chunk,
         heads_per_block=heads_per_block, operand_dtype=jnp.dtype(dtype).name,
         key_lanes=key_dim, value_lanes=width)
-
-
-def _row_sum(t):
-    """``[Q, X]`` summed along the lanes: a column ``[Q, 1]``."""
-    return jnp.sum(t, axis=1, keepdims=True)
 
 
 class _Chunk:
@@ -424,12 +385,8 @@ class _Chunk:
         cotangents themselves where the caller normed."""
         if self.q_scale is None:
             return dq, dk
-        out = []
-        for raw, inv, dn, scale in zip(self.raw, self.inv, (dq, dk),
-                                       (self.q_scale, 1.0)):
-            n = raw.astype(jnp.float32) * inv
-            out.append((dn - n * _row_sum(dn * n)) * (inv * scale))
-        return out
+        return raw_row_cotangents(self.raw, self.inv, (dq, dk),
+                                  (self.q_scale, 1.0))
 
     def column(self, block, head):
         """Column ``head`` (a traced index) of ``block`` ``[Q, Hv]``, ``[Q,
@@ -439,8 +396,7 @@ class _Chunk:
 
     def as_row(self, column):
         """A column ``[Q, 1]`` as a row ``[1, Q]``, through the diagonal."""
-        return jnp.sum(jnp.where(self.diagonal, column, 0.0), axis=0,
-                       keepdims=True)
+        return column_as_row(self.diagonal, column)
 
     def head(self, cum_ref, beta_ref, at, head):
         """Value head ``head``'s float32 parts: the running sums as a column
@@ -455,7 +411,8 @@ class _Chunk:
         a = jnp.where(self.strictly, self.kk * decay * beta, 0.0)
         size = cum.shape[0]
         return beta, decay, jnp.exp(cum), \
-            jnp.exp(cum[size - 1:size, :] - cum), a, _inverse_in_vmem(a)
+            jnp.exp(cum[size - 1:size, :] - cum), a, \
+            unit_lower_inverse_in_vmem(a, _SUBSTITUTE)
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, cum_ref, beta_ref, u_ref, w_ref,

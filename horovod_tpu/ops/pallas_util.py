@@ -1,10 +1,12 @@
 """What the Pallas kernel families share, said once: the tile's widths, the
 masked exponent, the two contraction patterns, the platform test and a few
 helpers. The floor of ``ops/``: it imports nothing of the package, and every
-kernel module (``flash_attention``, ``ssd``, ``s6``, ``gated_delta``,
+kernel module (``flash_attention``, ``ssd``, ``s6``, ``gated_delta``, ``kda``,
 ``conv``, ``cca``) imports these names from here and no kernel's part from another
 (``cca`` takes two plain references, ``conv.causal_conv1d`` and
-``attention.rope``, for the lines its kernels are held to)."""
+``attention.rope``, and ``kda`` one, ``gated_delta.unit_rows``, for the lines
+their kernels are held to; what the two delta rules' kernels share, the
+unit-triangular inverse in VMEM and the rows' norm, is here)."""
 
 from __future__ import annotations
 
@@ -81,3 +83,70 @@ def div(x, n: int):
 
 def rem(x, n: int):
     return jax.lax.rem(x, jnp.asarray(n, x.dtype))
+
+
+def row_sum(t):
+    """``[Q, X]`` summed along the lanes: a column ``[Q, 1]``."""
+    return jnp.sum(t, axis=1, keepdims=True)
+
+
+NORM_EPS = 1e-6  # added to a row's sum of squares where a scan's kernels
+                 # L2-normalise q and k (``norm_qk``: gated_delta, kda)
+
+
+def raw_row_cotangents(raws, invs, cotangents, scales):
+    """The float32 cotangents of rows as they came, for those of the rows a
+    kernel normed in VMEM: for ``n = t r``, ``r = rsqrt(|t|^2 + eps)``
+    (``invs``), ``dt = r (dn - n <dn, n>)``, times the row's ``scale``."""
+    out = []
+    for raw, inv, dn, scale in zip(raws, invs, cotangents, scales):
+        n = raw.astype(jnp.float32) * inv
+        out.append((dn - n * row_sum(dn * n)) * (inv * scale))
+    return out
+
+
+def column_as_row(diagonal, column):
+    """A column ``[Q, 1]`` as a row ``[1, Q]``, through the ``[Q, Q]``
+    diagonal mask (Mosaic transposes no vector)."""
+    return jnp.sum(jnp.where(diagonal, column, 0.0), axis=0, keepdims=True)
+
+
+def unit_lower_inverse_in_vmem(a, substitute: int = 32):
+    """``(I + a)^{-1}`` of one ``[n, n]`` float32 matrix as a kernel can
+    trace it (masks from ``iota``, no captured constant), full float32
+    throughout, ``a`` strictly lower triangular (the gated delta rule's and
+    Kimi delta attention's ``T``: ``ops/gated_delta.py``, ``ops/kda.py``).
+    The diagonal blocks of ``substitute`` rows by forward
+    substitution on the vector unit, a column a step: ``(I + a) X = I``
+    with ``X`` starting as ``I``; at step ``j`` row ``j`` is final and ``X_i
+    -= a_ij X_j`` for the rows below it in its block (eight-row tiles, those
+    above ``j`` skipped). Then the inverse by blocks from that block
+    size up (``D_2b = D_b - D_b L_b D_b``, ``L_b`` the lower left ``b x b``
+    block of every ``2b x 2b`` diagonal block), every product on the MXU at
+    the highest precision. ``substitute`` is a multiple of eight, or 1: no
+    substitution, every round a product."""
+    size = a.shape[-1]
+    block, tile = min(substitute, size), min(8, size)
+    rows = lax.broadcasted_iota(jnp.int32, (size, size), 0)
+    cols = lax.broadcasted_iota(jnp.int32, (size, size), 1)
+
+    def lower_left(shift):
+        """Block size ``b = 2 ** shift``: the lower left blocks."""
+        return ((rows >> (shift + 1)) == (cols >> (shift + 1))) \
+            & (((rows >> shift) & 1) == 1) & (((cols >> shift) & 1) == 0)
+
+    inv = jnp.where(rows == cols, 1.0, 0.0).astype(a.dtype)
+    at = [slice(t * tile, (t + 1) * tile) for t in range(size // tile)]
+    x, a_t = [inv[at_t] for at_t in at], [a[at_t] for at_t in at]
+    for j in range(size):
+        x_j = x[j // tile][j % tile:j % tile + 1]
+        for t in range((j + 1) // tile, (j // block + 1) * block // tile):
+            x[t] = x[t] - a_t[t][:, j:j + 1] * x_j
+    inv = jnp.concatenate(x, axis=0)
+    for shift in range(block.bit_length() - 1, size.bit_length() - 1):
+        left = jnp.dot(inv, jnp.where(lower_left(shift), a, 0.0),
+                       precision=lax.Precision.HIGHEST,
+                       preferred_element_type=a.dtype)
+        inv = inv - jnp.dot(left, inv, precision=lax.Precision.HIGHEST,
+                            preferred_element_type=a.dtype)
+    return inv

@@ -226,6 +226,23 @@ def expert_hidden(activation, product):
     return act(product("w_gate")) * product("w_up")
 
 
+def kept_groups(leaning, groups: int, kept: int):
+    """The leaning scores ``[T, E]`` (float32, outside the differentiated
+    path) with every expert outside a token's ``kept`` best groups at
+    ``-inf``: the ``E`` experts are ``groups`` groups of neighbours, a
+    group's score is the sum of its two largest leaning scores, the ``kept``
+    best groups are kept (DeepSeek-V3's ``noaux_tc`` choice, as remembered).
+    A ``lax.top_k`` over the result is the choice inside the kept groups;
+    ties go to the lower index, groups and experts alike."""
+    tokens, experts = leaning.shape
+    by_group = leaning.reshape(tokens, groups, experts // groups)
+    score = jnp.sum(lax.top_k(by_group, 2)[0], axis=-1)              # [T, G]
+    best = lax.top_k(score, kept)[1]                                 # [T, kept]
+    keep = jnp.any(jax.nn.one_hot(best, groups, dtype=jnp.bool_), axis=1)
+    return jnp.where(keep[..., None], by_group, -jnp.inf).reshape(
+        tokens, experts)
+
+
 def share_rows(tokens: int, top_k: int, held: int, experts: int) -> int:
     """Rows a layer holding ``held`` of ``experts`` gathers, multiplies and
     puts back at a time for ``tokens`` tokens: ``SHARE_HEADROOM`` times the
@@ -525,7 +542,8 @@ def moe_layer(x, router_w, w_gate, w_up, w_down, top_k: int,
               logits=None, router_kind: str = "linear",
               router_state: bool = False,
               activation: str = "silu",
-              expert_in=None) -> Tuple[jnp.ndarray, dict]:
+              expert_in=None, router_groups: int = 1,
+              router_groups_kept: int = 1) -> Tuple[jnp.ndarray, dict]:
     """Dropless top-``top_k`` expert layer (module docstring has the math).
 
     Args:
@@ -564,6 +582,9 @@ def moe_layer(x, router_w, w_gate, w_up, w_down, top_k: int,
         width, the caller's: ``models/decoder/experts.py``), one row for
         each of ``x``'s; None: ``x``. The sorted rows, a share's windows and
         ``y`` are then ``l`` wide and ``x`` is the router's operand alone.
+      router_groups, router_groups_kept: the choice limited to groups
+        (:func:`kept_groups`; 1 and 1: one choice over all the scores, the
+        same program as before the arguments were).
 
     Returns ``(y, aux)``, ``y`` shaped as the experts' operand and typed
     (``dtype``) as the activations, and over the tokens routed together (this rank's, or the ep
@@ -593,6 +614,15 @@ def moe_layer(x, router_w, w_gate, w_up, w_down, top_k: int,
     ep = _axis_bound(axis)
     experts_local = w_up.shape[0]
     num_experts = logits.shape[-1] if router_w is None else router_w.shape[1]
+    if not 1 <= router_groups_kept <= router_groups \
+            or num_experts % router_groups \
+            or top_k > router_groups_kept * (num_experts // router_groups) \
+            or (router_groups > 1 and num_experts // router_groups < 2):
+        raise ValueError(
+            f"expert layer: {router_groups_kept} of {router_groups} groups "
+            f"of a router {num_experts} wide, {top_k} a token: the groups "
+            "divide the experts, hold two or more each, and those kept hold "
+            "a token's experts")
     if ep and experts_local * _axis_size(axis) != num_experts:
         raise ValueError(
             f"expert layer: {_axis_size(axis)} ranks of {experts_local} "
@@ -626,7 +656,8 @@ def moe_layer(x, router_w, w_gate, w_up, w_down, top_k: int,
         top_k=top_k, ep=_axis_size(axis), grouped_matmul=GROUPED_MATMUL,
         held=experts_local, rows=window_rows, score=score,
         bias=int(bias is not None), router=router_kind,
-        state=int(router_state), activation=activation)
+        state=int(router_state), activation=activation,
+        groups=router_groups, groups_kept=router_groups_kept)
 
     with jax.named_scope("router"):
         # The product's own operand: a probe hands out this value and not
@@ -644,10 +675,14 @@ def moe_layer(x, router_w, w_gate, w_up, w_down, top_k: int,
         # ``lax.top_k``'s own rule would keep its index output, which is not
         # the named value, and a checkpointed block would sort every row
         # again for it. The bias leans the choice and is in nothing else.
-        leaning = probs if bias is None else probs + bias
-        top_e = checkpoint_name(
-            lax.top_k(lax.stop_gradient(leaning), top_k)[1],
-            "moe_top_experts")                                       # [T, k]
+        leaning = lax.stop_gradient(
+            probs if bias is None else probs + bias)
+        if router_groups > 1:
+            with jax.named_scope("groups"):
+                leaning = kept_groups(leaning, router_groups,
+                                      router_groups_kept)
+        top_e = checkpoint_name(lax.top_k(leaning, top_k)[1],
+                                "moe_top_experts")                   # [T, k]
         top_p = checkpoint_name(_scores_at(probs, top_e), "moe_top_weights")
         if renormalize:
             total = jnp.sum(top_p, axis=-1, keepdims=True)

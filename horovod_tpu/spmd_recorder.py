@@ -121,10 +121,13 @@ _TRACED = {
         "bias leans its choice, the router's kind (linear: the layer's own "
         "one matrix; mlp: the caller's, its outputs handed in; "
         "linear_early: the caller's one matrix on the block's input, before "
-        "the mixer), whether it took a state from the layer before, and the "
-        "experts' gate (silu or relu).",
+        "the mixer), whether it took a state from the layer before, the "
+        "experts' gate (silu or relu), and the groups the router's choice "
+        "is limited to and how many of them a token keeps (1 and 1: one "
+        "choice over all the scores).",
         ("experts", "top_k", "ep", "grouped_matmul", "held", "rows",
-         "score", "bias", "router", "state", "activation")),
+         "score", "bias", "router", "state", "activation", "groups",
+         "groups_kept")),
     "hvdtpu_spmd_cca_traces_total": (
         "Times JAX traced a CCA attention mixer (latent q and k mixed by two "
         "stacked causal convolutions; the recomputed copy of a block counts "
@@ -138,8 +141,19 @@ _TRACED = {
         "recomputed copy of a block counts again), by its heads, the "
         "no-position and the rotary part of a query/key head, a value "
         "head's size, the key/value latent's rank and the query latent's "
-        "(none: the query is projected straight from the stream).",
-        ("heads", "nope_dim", "rope_dim", "value_dim", "kv_rank", "q_rank")),
+        "(none: the query is projected straight from the stream), and the "
+        "gate on the attention's output (none, or head: one sigmoid gate a "
+        "head before the output projection).",
+        ("heads", "nope_dim", "rope_dim", "value_dim", "kv_rank", "q_rank",
+         "gate")),
+    "hvdtpu_spmd_kda_traces_total": (
+        "Times JAX traced a chunked Kimi-delta-attention scan (a delta-rule "
+        "state that decays a key channel; the recomputed copy of a block "
+        "counts again), by its heads, a head's key and value size, the "
+        "chunk, the sub-block its decayed products are made in, and the "
+        "bound its gate lies above.",
+        ("heads", "key_dim", "value_dim", "chunk", "sub_chunk",
+         "lower_bound")),
     "hvdtpu_spmd_ssm_layer_traces_total": (
         "Times JAX traced a chunked state-space scan (the recomputed copy of "
         "a block counts again), by its heads, their size, the state's size, "

@@ -27,7 +27,10 @@ experts read, SiLU for ReLU, the rotary embedding on every layer, the six
 weights not renormalised, the early router's product in one bfloat16 pass,
 the parameters rounded to bfloat16; for a latent-expert job ReLU for its
 square, the experts fed the stream's first columns in place of the
-down-projection), against
+down-projection; for a Kimi-delta-attention job the decay one a head, the
+unbounded gate, the group limit left out, the head-wise gates left out, the
+scan's decays, running sums and state in bfloat16, or the decays and the
+state alone), against
 the untouched reference: what a job's tolerances must catch. A variant
 changes the job's ``GPTConfig`` after the job is built, before its step is
 traced (or what the program's modules see, where no field says it); a job
@@ -47,13 +50,14 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
 
-def _one_pass_dots(module) -> None:
+def _one_pass_dots(module):
     """``module``'s float32 products at the backend's default precision (on
     the TPU one bfloat16 pass of the MXU) where it asks for the highest: it
     sees a ``jax.numpy`` whose ``dot`` takes no notice of ``precision``.
     ``parallel/moe.py``: the expert layer's own router;
     ``models/decoder/experts.py``: the MLP router and the router that reads
-    the block's input (no other line of it calls ``dot``)."""
+    the block's input (no other line of it calls ``dot``). Returns what
+    undoes it (``--variants`` runs several in one process)."""
     import jax.numpy as jnp
     from jax import lax
 
@@ -65,19 +69,19 @@ def _one_pass_dots(module) -> None:
         def dot(a, b, precision=None, **kw):
             return jnp.dot(a, b, precision=lax.Precision.DEFAULT, **kw)
 
-    module.jnp = OnePass()
+    return _patched(module, "jnp", OnePass())
 
 
 def _router_in_bfloat16():
     from horovod_tpu.parallel import moe
 
-    _one_pass_dots(moe)
+    return _one_pass_dots(moe)
 
 
-def _mlp_router_in_bfloat16() -> None:
+def _mlp_router_in_bfloat16():
     from horovod_tpu.models.decoder import experts
 
-    _one_pass_dots(experts)
+    return _one_pass_dots(experts)
 
 
 def _parameters_in_bfloat16(job) -> None:
@@ -286,6 +290,81 @@ def _lambda_init_of(depth_of):
                     lambda depth: real(depth_of(depth)))
 
 
+def _kda_decay_a_head():
+    """A Kimi-delta-attention layer's decay made one a head, the key
+    channels' mean, where the model's is one a channel."""
+    import jax.numpy as jnp
+    from horovod_tpu.models.decoder.mixers import kda
+
+    real = kda.log_decay
+
+    def a_head(cfg, p, f):
+        g = real(cfg, p, f)
+        by_head = g.reshape(g.shape[:-1] + (cfg.kda_heads, cfg.kda_key_dim))
+        return jnp.broadcast_to(jnp.mean(by_head, axis=-1, keepdims=True),
+                                by_head.shape).reshape(g.shape)
+
+    return _patched(kda, "log_decay", a_head)
+
+
+def _kda_gate_unbounded():
+    """A Kimi-delta-attention layer's log decay in the gated delta rule's
+    form, ``-exp(A_log_h) softplus(h W_f + dt_bias)``, held at the bound the
+    scan is told of (what a sub-block carries), where the model's is
+    ``lower_bound * sigmoid(.)``."""
+    import jax
+    import jax.numpy as jnp
+    from horovod_tpu.models.decoder.mixers import kda
+
+    def unbounded(cfg, p, f):
+        a = jnp.repeat(jnp.exp(p["A_log"]), cfg.kda_key_dim)
+        return jnp.maximum(
+            -a * jax.nn.softplus(f.astype(jnp.float32) + p["dt_bias"]),
+            cfg.kda_lower_bound)
+
+    return _patched(kda, "log_decay", unbounded)
+
+
+def _no_head_gates(job):
+    """Neither mixer's output under its gate a head: the KDA layers' normed
+    output as it is, the MLA layer's attention as it is."""
+    from horovod_tpu.models.decoder.mixers import kda
+
+    _replace(job, mla_head_gate=False)
+    return _patched(kda, "head_gate", lambda y, open_: y)
+
+
+def _kda_in_bfloat16(sums: bool):
+    """Kimi delta attention's decays rounded to bfloat16 (``ops/kda.py``
+    sees a ``jax.numpy`` whose ``exp`` rounds) and the state its recurrence
+    carries from chunk to chunk, and that state's cotangent, held in
+    bfloat16; under ``sums`` the running sums of the decays' logarithms
+    too (that ``jax.numpy``'s ``dot`` at a stated precision, the triangle
+    of ones that sums the log decays and turns their cotangents round,
+    rounds as well): what the configuration states as float32, in the
+    precision below."""
+    import jax.numpy as jnp
+    from horovod_tpu.ops import kda
+
+    class RoundedDecays:
+        def __getattr__(self, name):
+            return getattr(jnp, name)
+
+        @staticmethod
+        def exp(x):
+            return jnp.exp(x).astype(jnp.bfloat16).astype(jnp.float32)
+
+        @staticmethod
+        def dot(a, b, precision=None, **kw):
+            out = jnp.dot(a, b, precision=precision, **kw)
+            return out if precision is None or not sums \
+                else out.astype(jnp.bfloat16).astype(jnp.float32)
+
+    undo = (_patched(kda, "jnp", RoundedDecays()),
+            _patched(kda, "_STATE_DTYPE", jnp.bfloat16))
+    return lambda: [back() for back in undo]
+
+
 # name -> what it does to a job already built (its step not yet traced);
 # what it returns, if anything, undoes it
 VARIANTS = {
@@ -333,6 +412,13 @@ VARIANTS = {
         for spec in job.cfg.plan)),
     "no_norm_bias": lambda job: _no_norm_bias(),
     "s6_decays_bf16": lambda job: _s6_decays_in_bfloat16(),
+    "kda_decay_a_head": lambda job: _kda_decay_a_head(),
+    "kda_gate_unbounded": lambda job: _kda_gate_unbounded(),
+    "no_groups": lambda job: _replace(job, router_groups=1,
+                                      router_groups_kept=1),
+    "no_head_gates": _no_head_gates,
+    "kda_state_bf16": lambda job: _kda_in_bfloat16(sums=True),
+    "kda_decays_state_bf16": lambda job: _kda_in_bfloat16(sums=False),
 }
 
 

@@ -29,7 +29,8 @@ MODELS = {"trinity": "test_gpt_window_moe", "zaya": "test_gpt_cca_moe",
           "moonlight": "test_gpt_mla_moe",
           "nemotron": "test_gpt_latent_moe_hybrid",
           "smallthinker": "test_gpt_prerouted_moe",
-          "TINY": "test_gpt_linear_moe", "sambay12": "test_gpt_sambay"}
+          "TINY": "test_gpt_linear_moe", "sambay12": "test_gpt_sambay",
+          "ling": "test_gpt_kda_mla_moe"}
 
 
 def model(name):
@@ -61,6 +62,7 @@ def _same_keys(values, specs):
 @pytest.mark.parametrize("mixer,name", [
     ("attention", "trinity"), ("attention", "TINY"), ("cca", "zaya"),
     ("mla", "moonlight"), ("ssm", "nemotron"), ("gdn", "TINY"),
+    ("kda", "ling"), ("mla", "ling"),
     ("s6", "sambay12"), ("gmu", "sambay12"), ("diff_attention", "sambay12"),
     ("diff_cross", "sambay12")])
 def test_a_mixers_init_and_specs_hold_the_same_keys(mixer, name):
@@ -72,7 +74,7 @@ def test_a_mixers_init_and_specs_hold_the_same_keys(mixer, name):
 
 
 @pytest.mark.parametrize("name", ["trinity", "zaya", "moonlight", "nemotron",
-                                  "smallthinker", "TINY"])
+                                  "smallthinker", "TINY", "ling"])
 def test_the_expert_blocks_init_and_specs_hold_the_same_keys(name):
     cfg, experts = model(name), gpt.FEED_FORWARDS["experts"]
     keys = jax.random.split(jax.random.PRNGKey(0), 4)
@@ -151,12 +153,15 @@ FIELDS = {
     "router_dim": 256, "router_reads": "ff_input",
     "expert_activation": "silu", "moe_latent_dim": 0,
     "residual_scaling": False, "norm_kind": "rms", "s6_inner": None,
-    "s6_dt_rank": None,
+    "s6_dt_rank": None, "kda_heads": 8, "kda_key_dim": 128,
+    "kda_value_dim": 128, "kda_conv": 4, "kda_chunk": 64,
+    "kda_lower_bound": -5.0,
+    "mla_head_gate": False, "router_groups": 1, "router_groups_kept": 1,
 }
 
 
 def test_the_configurations_fields_are_the_frozen_list():
-    assert len(FIELDS) == 72
+    assert len(FIELDS) == 81
     assert {f.name: f.default for f in dataclasses.fields(gpt.GPTConfig)} \
         == FIELDS
     cfg = gpt.GPTConfig(num_kv_heads=2, expert_dim=48)

@@ -1,5 +1,5 @@
-"""The flash kernels, the state-space scan's and the gated delta rule's,
-through the real Mosaic compiler, without a chip.
+"""The flash kernels, the state-space scan's, the gated delta rule's and Kimi
+delta attention's, through the real Mosaic compiler, without a chip.
 
 Interpret mode (every other flash test) says nothing about Mosaic lowering:
 block shapes, VMEM, layouts. The TPU compiler is installed in the sandbox and
@@ -22,7 +22,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from horovod_tpu.ops import flash_attention as fa
-from horovod_tpu.ops import cca, conv, gated_delta, pallas_util, s6, ssd
+from horovod_tpu.ops import cca, conv, gated_delta, kda, pallas_util, s6, ssd
 
 
 @pytest.fixture(scope="module")
@@ -326,6 +326,44 @@ def test_s6_kernel_compiles_for_v5e(one_chip, mosaic, shape, grad):
     # The states of a sequence, [T, C, N] float32, are made nowhere.
     assert f"f32[{batch},{seq},{channels},{state}]" not in text
     assert f"f32[{batch},{seq},{state},{channels}]" not in text
+
+
+# (B, S, heads, key and value size of a head): Kimi delta attention at the
+# ling-3.0-flash_s8192 cell's shape, 128 chunks of 64 by sub-blocks of 16,
+# and a short sequence of two heads.
+KDA_SHAPES = {
+    "ling-3.0-flash_s8192": (1, 8192, 32, 128),
+    "small": (2, 256, 2, 128),
+}
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "bwd"])
+@pytest.mark.parametrize("shape", list(KDA_SHAPES))
+def test_kda_scan_compiles_for_v5e(one_chip, mosaic, shape, grad):
+    batch, seq, heads, dim = KDA_SHAPES[shape]
+
+    def sds(*dims, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dt, sharding=one_chip)
+
+    def scan(q, k, v, g, beta):
+        o, final = kda.kda_chunked(q, k, v, g, beta, norm_qk=True)
+        return jnp.sum(o.astype(jnp.float32)) + jnp.sum(final)
+
+    q = sds(batch, seq, heads, dim)
+    text = jax.jit(jax.grad(scan, argnums=(0, 1, 2, 3, 4)) if grad
+                   else scan).lower(
+        q, q, q, sds(batch, seq, heads, dim, dt=jnp.float32),
+        sds(batch, seq, heads, dt=jnp.float32)).compile().as_text()
+    assert "tpu_custom_call" in text
+    for kernel in (kda.KERNEL_FWD, kda.KERNEL_REC_FWD):
+        assert kernel in text
+    for kernel in (kda.KERNEL_BWD, kda.KERNEL_REC_BWD):
+        assert (kernel in text) == grad
+    # Neither a chunk's float32 [Q, Q] tiles nor a state a token reach HBM.
+    chunks = seq // 64
+    assert f"f32[{batch},{chunks},{heads},64,64]" not in text
+    assert f"f32[{chunks},{batch},{heads},64,64]" not in text
+    assert f"f32[{batch},{seq},{heads},{dim},{dim}]" not in text
 
 
 # (B, S, query heads, key heads, head size, rotary dimensions): a CCA

@@ -59,7 +59,8 @@ from horovod_tpu.ops import gated_delta
 from horovod_tpu.ops.gated_delta import (
     gated_delta_chunked, gated_delta_sequential, unit_lower_inverse,
     unit_rows)
-from horovod_tpu.ops.pallas_util import largest_divisor, use_interpret
+from horovod_tpu.ops.pallas_util import (
+    largest_divisor, unit_lower_inverse_in_vmem, use_interpret)
 
 B, HK, HV, K, V = 2, 2, 4, 16, 8
 
@@ -401,27 +402,26 @@ def test_equal_keys_do_not_blow_the_inverse_up():
 @pytest.mark.parametrize("substitute", [1, 8, 32, 64])
 @pytest.mark.parametrize("size", [16, 64])
 @pytest.mark.parametrize("beta", [1.0, 2.0])
-def test_the_kernels_inverse_is_unit_lower_inverse(monkeypatch, size,
-                                                   substitute, beta):
+def test_the_kernels_inverse_is_unit_lower_inverse(size, substitute, beta):
     """The inverse as the kernels make it (diagonal blocks of ``substitute``
     rows by forward substitution, then ``_inverse``'s rounds; 32 ships)
     against the plain form, on a random matrix and on the equal-keys one, at
     ``beta = 1`` and at ``beta = 2`` (``A``'s entries doubled: all twos
     below the diagonal, whose inverse alternates ``-2, 2, -2`` down each
     column)."""
-    monkeypatch.setattr(gated_delta, "_SUBSTITUTE", substitute)
     rng = np.random.default_rng(16)
     a = beta * jnp.asarray(np.tril(rng.standard_normal((size, size)), -1),
                            jnp.float32)
-    _close(gated_delta._inverse_in_vmem(a), unit_lower_inverse(a), 1e-5)
+    _close(unit_lower_inverse_in_vmem(a, substitute), unit_lower_inverse(a),
+           1e-5)
     equal = beta * jnp.tril(jnp.ones((size, size), jnp.float32), -1)
     rows, cols = np.indices((size, size))
     want = np.where(rows == cols, 1.0, np.where(
         rows > cols, -beta * (1.0 - beta) ** np.maximum(rows - cols - 1, 0),
         0.0))
     np.testing.assert_allclose(unit_lower_inverse(equal), want, atol=1e-6)
-    np.testing.assert_allclose(gated_delta._inverse_in_vmem(equal), want,
-                               atol=1e-6)
+    np.testing.assert_allclose(unit_lower_inverse_in_vmem(equal, substitute),
+                               want, atol=1e-6)
 
 
 @pytest.mark.parametrize("noise, dtype, tol, beta", [

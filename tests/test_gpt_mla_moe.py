@@ -396,7 +396,7 @@ def test_the_step_counts_its_mla_and_flash_traces(spmd8):
             fams["hvdtpu_spmd_mla_traces_total"]["samples"]}
     assert dict(mla) == dict(heads=str(HEADS), nope_dim=str(NOPE),
                              rope_dim=str(ROT), value_dim=str(VALUE),
-                             kv_rank=str(RANK), q_rank="none")
+                             kv_rank=str(RANK), q_rank="none", gate="none")
     widths = {(labels["kernel"], labels["key_dim"], labels["value_dim"],
                labels["dq"])
               for _, labels, _ in

@@ -994,6 +994,84 @@ def test_eight_shares_of_a_sigmoid_router_add_up_to_the_uncut_layer():
         a, b, rtol=5e-4, atol=2e-5), grads, g_ref)
 
 
+from benchmarks.reference import gpt_kda_mla_moe_dp as grouped_reference  # noqa: E402,E501
+
+
+@pytest.mark.parametrize("groups, kept, top_k, first, held", [
+    (4, 2, 3, 0, E), (4, 2, 3, 4, 4), (8, 3, 4, 0, E), (2, 1, 8, 8, 8),
+    (4, 4, 3, 0, E)])
+def test_a_choice_limited_to_groups_matches_the_reference(groups, kept,
+                                                          top_k, first, held):
+    """The experts in ``groups`` groups of neighbours, a group's score the
+    sum of its two largest leaning scores, a token's experts chosen inside
+    the ``kept`` best groups: outputs, counts and every gradient against the
+    plain reference (every held expert on every token), whole and as a
+    rank's share; every chosen expert lies in a kept group; with every group
+    kept it is the plain choice."""
+    h, block = _sigmoid_inputs(41)
+    weigh = jnp.cos(jnp.arange(T * D, dtype=jnp.float32)).reshape(T, D)
+    mine = {**block, **{k: block[k][first:first + held]
+                        for k in ("w_gate", "w_up", "w_down")}}
+
+    def program(h, block):
+        y, aux = moe_layer(
+            h, block["router"], block["w_gate"], block["w_up"],
+            block["w_down"], top_k=top_k, dtype=jnp.float32,
+            first_expert=first, renormalize=True, score="sigmoid",
+            bias=block["router_bias"], scale=ROUTE_SCALE,
+            router_groups=groups, router_groups_kept=kept)
+        return jnp.sum(y * weigh), (y, aux["counts"])
+
+    def plain(h, block):
+        y, counts = grouped_reference.expert_block(
+            h, block, top_k, ROUTE_SCALE, first, groups, kept)
+        y = y - grouped_reference.gated_ff(
+            h, *(block["shared"][k] for k in ("w_gate", "w_up", "w_down")))
+        return jnp.sum(y * weigh), (y, counts)
+
+    (_, (y, counts)), g = jax.value_and_grad(
+        program, argnums=(0, 1), has_aux=True)(h, mine)
+    (_, (y_ref, counts_ref)), g_ref = jax.value_and_grad(
+        plain, argnums=(0, 1), has_aux=True)(h, mine)
+    np.testing.assert_allclose(y, y_ref, rtol=2e-5, atol=2e-6)
+    np.testing.assert_array_equal(counts, np.asarray(counts_ref, np.int32))
+    g[1].pop("shared"), g_ref[1].pop("shared")
+    jax.tree.map(lambda a, b: np.testing.assert_allclose(
+        a, b, rtol=2e-4, atol=2e-6), g, g_ref)
+    assert not np.any(np.asarray(g[1]["router_bias"]))
+    assert int(np.sum(counts)) == T * top_k
+    ungrouped = _sigmoid_layer(h, block, top_k)[1]["counts"]
+    assert (kept == groups) == bool(
+        np.array_equal(np.asarray(ungrouped), np.asarray(counts)))
+
+
+def test_groups_that_do_not_fit_the_router_raise_by_name():
+    h, block = _sigmoid_inputs(1)
+    for groups, kept, top_k in ((3, 1, 2), (4, 5, 2), (4, 1, 5), (16, 8, 2),
+                                (4, 0, 2)):
+        with pytest.raises(ValueError, match="groups of a router"):
+            moe_layer(h, block["router"], block["w_gate"], block["w_up"],
+                      block["w_down"], top_k=top_k, dtype=jnp.float32,
+                      score="sigmoid", router_groups=groups,
+                      router_groups_kept=kept)
+
+
+def test_metrics_say_the_groups_a_layer_chose_in(make_runtime):
+    make_runtime(devices=jax.devices()[:1])
+    h, router, *w = layer_inputs()
+    jax.jit(lambda h, r, *w: moe_layer(
+        h, r, *w, top_k=3, dtype=jnp.float32, score="sigmoid",
+        router_groups=4, router_groups_kept=2)[0])(h, router, *w)
+    jax.jit(lambda h, r, *w: moe_layer(
+        h, r, *w, top_k=3, dtype=jnp.float32, score="sigmoid")[0])(
+            h, router, *w)
+    for groups, kept in (("4", "2"), ("1", "1")):
+        assert sample_value(
+            hvd.metrics(), "hvdtpu_spmd_moe_layer_traces_total",
+            experts=str(E), top_k="3", score="sigmoid", groups=groups,
+            groups_kept=kept) == 1.0
+
+
 def test_probe_returns_what_the_router_read_and_gave():
     """``probe``: the router's product's float32 operand (the activations,
     flattened to tokens) and its float32 outputs; without it ``aux`` has

@@ -22,7 +22,7 @@ from horovod_tpu.compression import pallas_kernels as pk
 from horovod_tpu.models import gpt
 from horovod_tpu.observability import parse_prometheus_text, sample_value
 from horovod_tpu.ops import flash_attention as fa
-from horovod_tpu.ops import conv, gated_delta, s6, ssd
+from horovod_tpu.ops import conv, gated_delta, kda, s6, ssd
 
 CFG = dict(vocab_size=64, num_layers=2, num_heads=2, num_kv_heads=1,
            head_dim=16, embed_dim=32, mlp_dim=64, tp_axis=None, sp_axis=None,
@@ -443,6 +443,16 @@ def _selective(grad: bool):
     return jax.make_jaxpr(jax.grad(scan) if grad else scan)(u)
 
 
+def _kimi(grad: bool):
+    q = jnp.ones((1, 32, 2, 8), jnp.float32)
+
+    def scan(q):
+        return kda.kda_chunked(q, q, q, -q, q[..., 0], chunk=16, sub_chunk=8,
+                               dtype=jnp.float32)[0].sum()
+
+    return jax.make_jaxpr(jax.grad(scan) if grad else scan)(q)
+
+
 def _conv(grad: bool):
     w = jnp.ones((4, 8), jnp.float32)
 
@@ -471,6 +481,10 @@ MN = jnp.zeros((4,), jnp.float32)
     ("hvd_conv_bwd", lambda: _conv(True)),
     ("hvd_s6_fwd", lambda: _selective(False)),
     ("hvd_s6_bwd", lambda: _selective(True)),
+    ("hvd_kda_fwd", lambda: _kimi(False)),
+    ("hvd_kda_rec_fwd", lambda: _kimi(False)),
+    ("hvd_kda_bwd", lambda: _kimi(True)),
+    ("hvd_kda_rec_bwd", lambda: _kimi(True)),
     ("hvd_maxmin_quantize", lambda: jax.make_jaxpr(
         lambda x: pk.maxmin_quantize_pallas(x, 4, 512, True))(FLAT)),
     # TPU-only (pltpu.prng_* has no CPU lowering), but it traces anywhere.
@@ -489,8 +503,8 @@ MN = jnp.zeros((4,), jnp.float32)
         lambda q: pk.norm_dequantize_pallas(q, LEVELS, MN, True))(Q8)),
 ])
 def test_kernel_names(name, make):
-    """The seventeen names the benchmark's readers match as strings, or (the
-    convolution's) must not."""
+    """The twenty-one names the benchmark's readers match as strings, or
+    (the convolution's) must not."""
     assert re.search(rf"\bname={name}\b", str(make())), name
 
 
@@ -519,6 +533,78 @@ def test_kernel_name_constants():
         "hvd_maxmin_quantize", "hvd_maxmin_quantize_stochastic",
         "hvd_maxmin_dequantize", "hvd_maxmin_dequantize_sum",
         "hvd_norm_quantize", "hvd_norm_dequantize"}
+
+
+# A Kimi-delta-attention mixer in layer 0, a gated latent-attention mixer in
+# layer 1, expert blocks whose router chooses inside 2 of 4 groups.
+KIMI = dict(
+    num_kv_heads=2, kda_heads=2, kda_key_dim=8, kda_value_dim=8, kda_chunk=16,
+    mla_rope_dim=8, mla_value_dim=16, mla_kv_rank=16, mla_head_gate=True,
+    num_experts=8, experts_per_token=2, experts_held=4,
+    renormalize_experts=True, shared_expert_dim=16, shared_expert_gate=False,
+    router_score="sigmoid", router_bias=True, router_groups=4,
+    router_groups_kept=2,
+    layers=(gpt.LayerSpec(mixer="kda", ff="experts"),
+            gpt.LayerSpec(mixer="mla", ff="experts")))
+
+
+def test_kimi_step_holds_its_parts_under_their_scopes(spmd4):
+    """Where ``kda_ms``, ``kda_proj_ms``, ``kda_scan_ms`` and
+    ``moe_route_groups_ms`` look: a KDA mixer's parts under ``layer0/kda/
+    <part>`` in the forward pass, the recomputed copy and the backward pass,
+    its four kernels under ``kda/kda_scan`` (the forward pair in the
+    recomputed copy too: the block keeps nothing of the scan), the
+    convolution's under ``kda/kda_conv``; the MLA layer's gate under
+    ``attn/mla_gate``; the grouped choice under ``moe/router/groups``; and
+    the three counters say what was traced."""
+    step, *args = gpt_step("full", **KIMI)
+    text = step.lower(*args).as_text(debug_info=True)
+    scans = set(re.findall(
+        r'loc\("([^"]*)/hvd_kda_(fwd|bwd|rec_fwd|rec_bwd)/', text))
+    assert {kernel for _, kernel in scans} == {"fwd", "bwd", "rec_fwd",
+                                               "rec_bwd"}
+    for scope, kernel in scans:
+        assert scope.endswith("/kda/kda_scan") and "layer0" in scope, scope
+        assert ("transpose(jvp(layer0))" in scope
+                and "rematted_computation" not in scope) \
+            == kernel.endswith("bwd"), scope
+    assert {kernel for scope, kernel in scans
+            if "rematted_computation" in scope} == {"fwd", "rec_fwd"}
+    assert all(scope.endswith("/kda/kda_conv") for scope in re.findall(
+        r'loc\("([^"]*)/hvd_conv_(?:fwd|bwd)/', text))
+    names = set(re.findall(r'loc\("([^"]*)"', text))     # as lowered
+
+    def some(*parts):
+        return any(all(p in n for p in parts) for n in names)
+
+    for inner in ("kda_proj", "kda_conv", "kda_scan", "kda_gate"):
+        assert some("jvp(layer0)", f"/kda/{inner}/"), inner
+        assert some("transpose(jvp(layer0))", f"/kda/{inner}/"), inner
+        assert some(f"rematted_computation/kda/{inner}/"), inner
+    assert not some("layer1", "/kda/")
+    assert some("jvp(layer1)", "/attn/mla_gate/")
+    assert some("transpose(jvp(layer1))", "/attn/mla_gate/")
+    assert not some("layer0", "/mla_gate/")
+    for layer in ("layer0", "layer1"):
+        assert some(f"jvp({layer})", "/moe/router/groups/"), layer
+    fams = hvd.metrics()
+    assert sample_value(
+        fams, "hvdtpu_spmd_kda_traces_total", heads="2", key_dim="8",
+        value_dim="8", chunk="16", sub_chunk="16", lower_bound="-5.0") >= 1
+    assert sample_value(
+        fams, "hvdtpu_spmd_mla_traces_total", heads="2", nope_dim="16",
+        rope_dim="8", value_dim="16", kv_rank="16", q_rank="none",
+        gate="head") >= 1
+    assert sample_value(
+        fams, "hvdtpu_spmd_moe_layer_traces_total", experts="8", top_k="2",
+        held="4", score="sigmoid", bias="1", groups="4",
+        groups_kept="2") >= 1
+    # benchmarks/jobs/gpt_kda_mla_moe_dp.py matches ``^hvd_kda_``; the
+    # gated delta rule's reader (``^hvd_gdn_``) must not count these.
+    for name in (kda.KERNEL_FWD, kda.KERNEL_BWD, kda.KERNEL_REC_FWD,
+                 kda.KERNEL_REC_BWD):
+        assert re.match(r"^hvd_kda_", name)
+        assert not re.match(r"^hvd_(ssd|gdn|s6|conv)_", name)
 
 
 # Mamba-1, a window layer, the two producers and their readers: the six

@@ -415,8 +415,8 @@ class TestLayering:
         "feed_forward.py": ("config", "parts"),
         "experts.py": ("config", "parts"),
         **{f"mixers/{name}.py": ("config", "parts") for name in (
-            "attention", "cca", "diff_attention", "gdn", "gmu", "mla", "s6",
-            "ssm")},
+            "attention", "cca", "diff_attention", "gdn", "gmu", "kda", "mla",
+            "s6", "ssm")},
         "mixers/__init__.py": ("mixers",), "__init__.py": ()}
 
     @pytest.mark.parametrize("name", sorted(DECODER_MAY_IMPORT))
