@@ -9,6 +9,7 @@ under a bound tp or sp axis it raises, and its parameters are replicated."""
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
 from ....ops.conv import causal_conv_silu
@@ -17,10 +18,12 @@ from ..config import GPTConfig, LayerSpec
 from ..parts import _refuse_bound_axes, _rmsnorm, readings, subkeys
 
 KEY, NORM = "kda", "kda_norm"
-# Nothing of the mixer crosses a checkpoint by name: the Ling cell's chip
-# holds 11.4 GB of state, and a block's five kept tensors a layer would be
-# 0.5 GB each (PERF.md, Findings, PR 63).
-SAVED_NAMES = ()
+# The scan's output, 2 H V bytes a token a layer (67 MB in the Ling cell):
+# the gated norm and ``W_o``'s gradient read it, and without it the
+# recomputed copy runs both forward kernels for it. What the scan's backward
+# kernels read beside their inputs is ``ops/kda.py``'s to name (PERF.md,
+# Findings, PR 64).
+SAVED_NAMES = ("kda_scan_out",)
 _SUB_CHUNK = 16
 
 
@@ -113,6 +116,7 @@ def apply(cfg: GPTConfig, spec, p, h, positions):
             chunk=cfg.kda_chunk, sub_chunk=_SUB_CHUNK,
             lower_bound=cfg.kda_lower_bound,
             dtype=cfg.dtype, norm_qk=True)
+        o = checkpoint_name(o, "kda_scan_out")
     with jax.named_scope("kda_gate"):
         y = head_gate(_rmsnorm(o, p["norm"], f32, cfg.norm_eps), open_)
         y = y.reshape(batch, seq, value_inner(cfg)).astype(cfg.dtype)

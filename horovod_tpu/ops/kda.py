@@ -65,7 +65,20 @@ kernel's residual; no ``[B, S, H, K, V]`` array exists.
 Both pairs sit under **one** ``jax.custom_vjp`` (:func:`_scan`), whose
 residuals are the inputs, the five operands, the last decays and the
 entering states. Decays, running sums, ``T`` and the state are float32; the
-other products take operands in ``dtype`` and accumulate in float32."""
+other products take operands in ``dtype`` and accumulate in float32.
+
+**What a checkpoint keeps.** The rule's forward (:func:`_scan_forward`) hands
+the five operands (``kda_scan_operands``) and the entering states
+(``kda_scan_entering``) to ``checkpoint_name`` **as its residuals**, and the
+mixer names the output (``kda_scan_out``): every output of both forward
+kernels, so the recomputed copy of a block checkpointed under a policy that
+keeps the three names (:data:`SAVED_NAMES` and the mixer's) runs neither
+``hvd_kda_fwd`` nor ``hvd_kda_rec_fwd``, and the backward kernels read what
+the forward pass wrote. ``hvd_kda_rec_fwd`` reads the operands **unnamed**:
+``jax.checkpoint`` copies a kept value that the forward pass also reads
+through a ``reduce_precision``, behind a Mosaic kernel a read and a write of
+every kept byte (PERF.md, Findings, PR 56). Outside a checkpoint a name is
+an identity."""
 
 from __future__ import annotations
 
@@ -75,6 +88,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -90,10 +104,16 @@ KERNEL_FWD = "hvd_kda_fwd"
 KERNEL_BWD = "hvd_kda_bwd"
 KERNEL_REC_FWD = "hvd_kda_rec_fwd"
 KERNEL_REC_BWD = "hvd_kda_rec_bwd"
-# Nothing of the scan crosses a checkpoint by name: a checkpointed block runs
-# the forward kernels again (the Ling cell holds 11.4 GB of state beside
-# them; PERF.md, Findings, PR 63).
-SAVED_NAMES = ()
+# What this module hands ``checkpoint_name``, for a ``jax.checkpoint`` around
+# the caller to keep: what the recurrence's backward kernel reads beside the
+# scan's inputs. ``hvd_kda_fwd``'s five outputs (``kda_scan_operands``: a head
+# a token V in float32 and 3 K + Q in the compute dtype, 369 MB a layer in
+# the Ling cell) and each chunk's entering state, ``hvd_kda_rec_fwd``'s side
+# output (``kda_scan_entering``: K V in the compute dtype a head a chunk, 134
+# MB a layer). With the mixer's ``kda_scan_out`` kept too the recomputed copy
+# runs neither forward kernel (PERF.md, Findings, PR 64). The last decays (2
+# MB a layer) are made again from ``g``, which the block makes again anyway.
+SAVED_NAMES = ("kda_scan_operands", "kda_scan_entering")
 MAX_EXPONENT = 80.0  # exp of it is finite in float32 and in bfloat16
 _HI = lax.Precision.HIGHEST
 _MAX_CHUNKS = 4    # chunks a grid cell walks, at most
@@ -636,7 +656,11 @@ def _scan_forward(static, q, k, v, g, beta, start):
                          q_scale=q_scale)
     decay = _last_decays(g, chunk)
     o, final, entering = _rec_fwd_call(*operands, decay, start, sub=sub)
-    return (o, final), (q, k, v, g, beta, *operands, decay, entering)
+    # Named as residuals only: the kernel above read the operands unnamed,
+    # so the forward pass reads no kept value (the module docstring).
+    kept = tuple(checkpoint_name(t, "kda_scan_operands") for t in operands)
+    return (o, final), (q, k, v, g, beta, *kept, decay,
+                        checkpoint_name(entering, "kda_scan_entering"))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))

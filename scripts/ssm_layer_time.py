@@ -4,6 +4,7 @@ it, and split its time by scope.
 
     chiprun --chips 1 -- python scripts/ssm_layer_time.py [--repo DIR] [--trace]
     chiprun --chips 1 -- python scripts/ssm_layer_time.py --kind gdn [--trace]
+    chiprun --chips 1 -- python scripts/ssm_layer_time.py --kind kda [--trace] [--repo DIR]
     chiprun --chips 1 -- python scripts/ssm_layer_time.py --kind head [--rows R ...]
 
 At the ``granite-4.0-h-micro_s4096`` cell's shapes (2 x 4096 tokens of 2048;
@@ -34,6 +35,15 @@ is the ``olmo-hybrid-7b_s8192`` cell's block (1 x 8192 tokens of 3840; 30
 key and 30 value heads of 96 by 192, ``beta`` in (0, 2); the norm after each
 branch; a gated feed-forward of 11008).
 
+``--kind kda`` is the ``ling-3.0-flash_s8192`` cell's first block (1 x 8192
+tokens of 2560; 32 Kimi-delta-attention heads of 128 by 128, chunk 64, the
+gate bounded at -5; the dense gated feed-forward of 6144): the number the
+next change to ``ops/kda.py`` is measured against first. With ``--trace`` it
+also prints ``kda_ops_ms_a_layer``: the ``hvd_kda_*`` kernels' and the
+``reduce_precision`` passes' time and calls a layer a call of the forward
+with backward (a block that keeps what the scan's forward kernels write runs
+each once, PR 64; ``--repo`` a copy of an older commit runs them twice).
+
 ``--kind head`` times no block but the head and the loss alone (``--tokens``
 rows of ``--embed`` against ``--vocab``, ``--tied``, ``--scaling``; without
 ``--vocab`` the four cells' shapes whose head is a sixth of the step or
@@ -46,8 +56,9 @@ difference over the old form's largest value) and exits 1 beyond
 ``--tolerance``. A line a shape a block size.
 
 With ``--trace`` every other kind also prints ``conv_ms_a_layer``: the mixers'
-``conv`` scope (the causal depthwise convolution, its SiLU and the split
-after it: ``ops/conv.py::causal_conv_silu``, the kernels ``hvd_conv_fwd`` and
+``conv`` scope (``kda_conv`` in a ``"kda"`` mixer; the causal depthwise
+convolution, its SiLU and the split after it:
+``ops/conv.py::causal_conv_silu``, the kernels ``hvd_conv_fwd`` and
 ``hvd_conv_bwd``) forward, recomputed and backward, ms a layer a call.
 """
 
@@ -75,6 +86,20 @@ def timed(fn, *args, reps: int = 10) -> float:
     return 1e3 * (time.perf_counter() - t0) / reps
 
 
+def named_ops_ms(path: str, calls: int, layers: int, pattern: str) -> dict:
+    """``{name: [ms, calls]}`` a layer a call of chip 0's operations whose
+    name matches ``pattern`` in the trace at ``path``, by the name without
+    XLA's number."""
+    from benchmarks import trace_reduce
+    out: dict = {}
+    for op in trace_reduce.matching(trace_reduce.first_device(
+            trace_reduce.read_xplane(path, {})), pattern):
+        row = out.setdefault(trace_reduce.group_name(op.name), [0.0, 0.0])
+        row[0] += 1e3 * (op.end - op.start) / (calls * layers)
+        row[1] += 1 / (calls * layers)
+    return {name: [round(ms, 4), n] for name, (ms, n) in sorted(out.items())}
+
+
 def conv_scope_ms(path: str, calls: int, layers: int) -> dict:
     """ms a layer a call of chip 0's operations under a mixer's ``conv``
     scope in the trace at ``path``, by the pass they run in."""
@@ -83,7 +108,8 @@ def conv_scope_ms(path: str, calls: int, layers: int) -> dict:
     ms = {"forward": 0.0, "recomputation": 0.0, "backward": 0.0}
     for op in trace_reduce.first_device(trace_reduce.read_xplane(path, {})):
         op_name, cls = names.get(op.name, ("", "unscoped"))
-        if cls in ms and "conv" in scope_reduce.scope_of(op_name):
+        if cls in ms and any(scope.endswith("conv") for scope
+                             in scope_reduce.scope_of(op_name)):
             ms[cls] += 1e3 * (op.end - op.start) / (calls * layers)
     return ms
 
@@ -173,8 +199,8 @@ def main() -> int:
     parser.add_argument("--state", type=int, default=128)
     parser.add_argument("--groups", type=int, default=1)
     parser.add_argument("--chunk", type=int, default=256)
-    parser.add_argument("--kind", choices=("ssm", "gdn", "gdn_dense", "head"),
-                        default="ssm")
+    parser.add_argument("--kind", choices=("ssm", "gdn", "gdn_dense", "kda",
+                                           "head"), default="ssm")
     parser.add_argument("--tokens", type=int, default=8192)
     parser.add_argument("--vocab", type=int)
     parser.add_argument("--tied", action="store_true")
@@ -193,6 +219,8 @@ def main() -> int:
         args.batch = 4
     if args.kind == "gdn_dense":
         args.batch, args.seq, args.embed, args.mlp = 1, 8192, 3840, 11008
+    if args.kind == "kda":
+        args.batch, args.seq, args.embed, args.mlp = 1, 8192, 2560, 6144
     root = os.path.abspath(args.repo)
     sys.path.insert(0, root)
 
@@ -238,6 +266,15 @@ def main() -> int:
             layer_kinds=("gdn",) * args.layers, gdn_key_heads=30,
             gdn_value_heads=30, gdn_key_dim=96, gdn_value_dim=192,
             gdn_chunk=64, gdn_allow_neg_eigval=True, tie_embeddings=False)
+    elif args.kind == "kda":
+        cfg = gpt.GPTConfig(
+            vocab_size=256, num_layers=args.layers, num_heads=32,
+            head_dim=128, embed_dim=args.embed, mlp_dim=args.mlp,
+            dtype=jnp.bfloat16, tp_axis=None, sp_axis=None,
+            attention="flash", remat="full", norm_eps=1e-6,
+            layers=(gpt.LayerSpec(mixer="kda", ff="gated"),) * args.layers,
+            kda_heads=32, kda_key_dim=128, kda_value_dim=128, kda_conv=4,
+            kda_chunk=64, kda_lower_bound=-5.0)
     else:
         cfg = gpt.GPTConfig(
             vocab_size=256, num_layers=args.layers, num_heads=32,
@@ -293,6 +330,12 @@ def main() -> int:
                 name: round(ms, 4) for name, ms in conv_scope_ms(
                     trace_reduce.find_xplane(log_dir), 4,
                     args.layers).items()}}), flush=True)
+        if args.kind == "kda":
+            print(json.dumps({
+                "tag": args.tag, "kind": args.kind,
+                "kda_ops_ms_a_layer": named_ops_ms(
+                    trace_reduce.find_xplane(log_dir), 4, args.layers,
+                    r"^hvd_kda_|reduce[-_]precision")}), flush=True)
     return 0
 
 

@@ -10,6 +10,7 @@ KDA one token a step, the grouped choice written plainly): float32, tiny
 sizes, seeded.
 """
 
+import collections
 import functools
 import hashlib
 
@@ -24,6 +25,7 @@ from horovod_tpu.models import gpt
 from horovod_tpu.models.gpt import LayerSpec
 from horovod_tpu.models.decoder import experts
 from horovod_tpu.models.decoder.mixers import kda as kda_mixer
+from horovod_tpu.ops import kda as kda_ops
 from horovod_tpu.parallel.moe import kept_groups
 
 from benchmarks.reference import gpt_kda_mla_moe_dp as reference
@@ -242,6 +244,64 @@ def test_the_kda_mixer_refuses_a_bound_axis_by_name(make_runtime, axis):
     with pytest.raises(ValueError, match="Kimi-delta-attention layer runs"):
         jax.shard_map(loss, mesh=hvd_.mesh(), in_specs=P(), out_specs=P(),
                       check_vma=False)(params, *data)
+
+
+def _two_kda_layers(**kw):
+    return ling(num_layers=2, layers=(LayerSpec(mixer="kda", ff="gated"),) * 2,
+                **kw)
+
+
+def test_a_checkpointed_block_runs_the_scans_forward_kernels_once(
+        make_runtime, equations_of):
+    """``remat="full"`` keeps every output of ``hvd_kda_fwd`` and
+    ``hvd_kda_rec_fwd`` (the five operands and the entering states, named
+    as the rule's residuals, and the mixer's ``kda_scan_out``), so the
+    recomputed copy runs neither; the forward pass reads no kept tensor of
+    the recurrence's order ``[c, B, H, ., .]``, so ``jax.checkpoint`` puts
+    no ``reduce_precision`` on one (a pass over every kept byte on the
+    chip)."""
+    hvd_ = make_runtime(devices=jax.devices()[:1])
+    cfg, layers = _two_kda_layers(remat="full"), 2
+    data = _data(3)
+    jaxpr = jax.make_jaxpr(
+        lambda p: jax.value_and_grad(gpt.loss_fn)(p, *data, cfg))(
+            gpt.init_params(jax.random.PRNGKey(0), cfg))
+    equations = [eqn for eqn, _ in equations_of(jaxpr.jaxpr)]
+    assert collections.Counter(
+        eqn.params["name"] for eqn in equations
+        if eqn.primitive.name == "pallas_call"
+        and eqn.params["name"].startswith("hvd_kda_")) == dict.fromkeys(
+        (kda_ops.KERNEL_FWD, kda_ops.KERNEL_REC_FWD, kda_ops.KERNEL_BWD,
+         kda_ops.KERNEL_REC_BWD), layers)
+    assert not [eqn for eqn in equations
+                if eqn.primitive.name == "reduce_precision"
+                and eqn.outvars[0].aval.ndim == 5]
+    family = hvd_.metrics()["hvdtpu_spmd_remat_saved_bytes_total"]
+    kept = {labels["name"]: value for _, labels, value in family["samples"]}
+    # A block's bytes (layers alike share one trace), float32 throughout:
+    # the operands [c, B, H, Q, V + 3 K + Q], the entering states
+    # [c, B, H, V, K], the output [B, S, H V].
+    chunks, q = S // cfg.kda_chunk, cfg.kda_chunk
+    assert {name: kept.get(name) for name in (
+        *kda_ops.SAVED_NAMES, *kda_mixer.SAVED_NAMES)} == {
+        "kda_scan_operands": 4 * chunks * B * HEADS * q * (4 * KDA_DIM + q),
+        "kda_scan_entering": 4 * chunks * B * HEADS * KDA_DIM * KDA_DIM,
+        "kda_scan_out": 4 * B * S * HEADS * KDA_DIM}
+
+
+def test_full_remat_is_no_remat_to_the_last_gradient_leaf():
+    """The kept tensors are the tensors that would have been made again:
+    the loss and every gradient leaf under ``remat="full"`` against
+    ``remat="none"``."""
+    data = _data(5)
+    params = gpt.init_params(jax.random.PRNGKey(4), _two_kda_layers())
+    (want_loss, want), (loss, grads) = (
+        jax.jit(jax.value_and_grad(
+            lambda p, cfg=_two_kda_layers(remat=remat):
+            gpt.loss_fn(p, *data, cfg)))(params)
+        for remat in ("none", "full"))
+    assert float(loss) == float(want_loss)
+    _tree_close(grads, want, 0.0)
 
 
 # sha256 of the StableHLO text (no source locations) that Moonlight's layer

@@ -18,12 +18,14 @@ a head that is no lane multiple (interpreted here; compiled it raises by
 name).
 """
 
+import collections
 import hashlib
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.ad_checkpoint import checkpoint_name
 
 from horovod_tpu.ops import kda, pallas_util
 from horovod_tpu.ops.gated_delta import gated_delta_chunked, unit_rows
@@ -198,7 +200,51 @@ def test_the_kernels_names_and_the_trace_record(make_runtime):
         hvd.metrics(), "hvdtpu_spmd_kda_traces_total", heads="2",
         key_dim="16", value_dim="16", chunk="32", sub_chunk="8",
         lower_bound="-5.0") >= 1
-    assert kda.SAVED_NAMES == ()
+    assert kda.SAVED_NAMES == ("kda_scan_operands", "kda_scan_entering")
+
+
+def _scan_loss(q, k, v, g, beta):
+    """The mixer's use of the scan: the output under ``kda_scan_out``."""
+    o, final = kda_chunked(q, k, v, g, beta, chunk=32, sub_chunk=8,
+                           norm_qk=True)
+    o = checkpoint_name(o, "kda_scan_out")
+    return jnp.sum(jnp.sin(o.astype(jnp.float32))) + jnp.sum(final)
+
+
+def test_the_rules_residuals_carry_the_names_a_checkpoint_keeps(equations_of):
+    """The five operands and the entering states are named inside the
+    rule's forward, once each, whether or not a gradient is asked for; the
+    output's name is the caller's."""
+    def names_in(fn):
+        return collections.Counter(
+            eqn.params["name"]
+            for eqn, _ in equations_of(jax.make_jaxpr(fn)(*args).jaxpr)
+            if eqn.primitive.name == "name")
+
+    args = _inputs(2, seq=64, heads=2)
+    names = {"kda_scan_operands": 5, "kda_scan_entering": 1}
+    assert names_in(lambda *a: kda_chunked(*a, chunk=32,
+                                           sub_chunk=8)) == names
+    assert names_in(jax.grad(_scan_loss, argnums=tuple(range(5)))) == {
+        **names, "kda_scan_out": 1}
+
+
+@pytest.mark.parametrize("kept", ["the_three_names", "nothing"])
+def test_a_checkpoints_gradient_is_the_plain_one_to_the_last_bit(kept):
+    """The backward kernels read the tensors the forward kernels wrote where
+    the checkpoint keeps them by name and a second run's identical copies
+    where it keeps nothing: every input's gradient is the un-checkpointed
+    one's either way, bit for bit."""
+    args = _inputs(4, seq=96, heads=2)
+    policy = jax.checkpoint_policies.save_only_these_names(
+        *((*kda.SAVED_NAMES, "kda_scan_out")
+          if kept == "the_three_names" else ()))
+    argnums = tuple(range(5))
+    want = jax.jit(jax.grad(_scan_loss, argnums))(*args)
+    got = jax.jit(jax.grad(
+        jax.checkpoint(_scan_loss, policy=policy), argnums))(*args)
+    for name, a, b in zip("qkvgb", got, want):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b), name)
 
 
 # sha256 of the StableHLO text (no source locations) the gated delta rule's

@@ -552,8 +552,8 @@ def test_kimi_step_holds_its_parts_under_their_scopes(spmd4):
     """Where ``kda_ms``, ``kda_proj_ms``, ``kda_scan_ms`` and
     ``moe_route_groups_ms`` look: a KDA mixer's parts under ``layer0/kda/
     <part>`` in the forward pass, the recomputed copy and the backward pass,
-    its four kernels under ``kda/kda_scan`` (the forward pair in the
-    recomputed copy too: the block keeps nothing of the scan), the
+    its four kernels under ``kda/kda_scan`` (none in the recomputed copy:
+    the block keeps every output of the forward pair by name), the
     convolution's under ``kda/kda_conv``; the MLA layer's gate under
     ``attn/mla_gate``; the grouped choice under ``moe/router/groups``; and
     the three counters say what was traced."""
@@ -568,8 +568,8 @@ def test_kimi_step_holds_its_parts_under_their_scopes(spmd4):
         assert ("transpose(jvp(layer0))" in scope
                 and "rematted_computation" not in scope) \
             == kernel.endswith("bwd"), scope
-    assert {kernel for scope, kernel in scans
-            if "rematted_computation" in scope} == {"fwd", "rec_fwd"}
+    assert not [kernel for scope, kernel in scans
+                if "rematted_computation" in scope]
     assert all(scope.endswith("/kda/kda_conv") for scope in re.findall(
         r'loc\("([^"]*)/hvd_conv_(?:fwd|bwd)/', text))
     names = set(re.findall(r'loc\("([^"]*)"', text))     # as lowered
