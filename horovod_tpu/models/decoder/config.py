@@ -253,6 +253,14 @@ class GPTConfig:
     # (1 and 1: one choice over all the scores).
     router_groups: int = 1
     router_groups_kept: int = 1
+    # Training by diffusion over blocks (BD3-LM): every attention layer runs
+    # under the block-diffusion mask at this block length (a power of two;
+    # ``ops/flash_attention.py::Mask``), its mixer's scope ``attn_bd``. The
+    # rows a rank holds are then the noised copy of a sequence and, after
+    # it, the clean copy, and that doubling, the repeated positions and the
+    # targets and weights of the noised half are the caller's batch
+    # (``gpt.loss_and_aux``). None: causal attention, as ever.
+    diffusion_block: Optional[int] = None
 
     @property
     def kv_heads(self) -> int:
@@ -291,12 +299,12 @@ def layer_plan(cfg: GPTConfig) -> Tuple[LayerSpec, ...]:
             f"layer_kinds must name a mixer for each of the "
             f"{cfg.num_layers} layers, got {kinds!r}")
     if cfg.layers is None:
-        return tuple(LayerSpec(
+        return _under_diffusion(cfg, tuple(LayerSpec(
             mixer="attention" if kinds is None else kinds[i], rope=cfg.rope,
             ff="experts" if cfg.moe_every > 0
             and (i + 1) % cfg.moe_every == 0
             else "gated" if cfg.gated_mlp else "dense")
-            for i in range(cfg.num_layers))
+            for i in range(cfg.num_layers)))
     if kinds is not None or cfg.moe_every:
         raise ValueError("layers says each layer outright: leave "
                          "layer_kinds and moe_every unset beside it")
@@ -313,11 +321,26 @@ def layer_plan(cfg: GPTConfig) -> Tuple[LayerSpec, ...]:
             f"key on one of {_WINDOW_MIXERS} alone: a CCA layer has none "
             f"yet, nor an MLA layer) for each of the {cfg.num_layers} "
             f"layers, got {plan!r}")
-    return plan
+    return _under_diffusion(cfg, plan)
 
 
 # The mixers that take ``LayerSpec.window``.
 _WINDOW_MIXERS = ("attention", "diff_attention")
+
+
+def _under_diffusion(cfg: GPTConfig, plan):
+    """``plan`` as it is; under ``cfg.diffusion_block`` only if every mixer
+    is plain attention without a window: the mask lets a noised block see
+    itself and the clean past, which a band would cut and a scan over the
+    rows (a recurrent mixer, a convolution) would leak across."""
+    if cfg.diffusion_block is not None and any(
+            spec.mixer not in ("attention", None) or spec.window is not None
+            for spec in plan):
+        raise ValueError(
+            f"diffusion_block={cfg.diffusion_block} is for stacks of "
+            "'attention' mixers without a window (the block-diffusion mask "
+            f"has no band, and no other mixer takes a mask), got {plan!r}")
+    return plan
 
 
 def _check_shared_values(plan) -> None:

@@ -6,7 +6,9 @@ projections (``qk_norm``) or a head (``qk_head_norm``), the rotary embedding
 left out (``LayerSpec.rope``), given another base or only a head's first
 dimensions, the logits scaled by a constant, the output gated by a sigmoid of
 a doubled query projection (``attention_gate``), a window
-(``LayerSpec.window``). Its four matrices stay at the layer's root."""
+(``LayerSpec.window``), the block-diffusion mask over a noised and a clean
+copy of the sequence (``GPTConfig.diffusion_block``). Its four matrices stay
+at the layer's root."""
 
 import jax
 import jax.numpy as jnp
@@ -22,7 +24,8 @@ KEY, NORM, SAVED_NAMES = None, "attn_norm", ()
 
 def scope(spec: LayerSpec) -> str:
     """A window layer's mixer is under ``attn_window``, a full one's under
-    ``attn``: a device trace tells their flash kernels apart by it."""
+    ``attn`` (under the block-diffusion mask ``gpt._block`` says
+    ``attn_bd``): a device trace tells their flash kernels apart by it."""
     return "attn" if spec.window is None else "attn_window"
 
 

@@ -99,7 +99,12 @@ def _attention(cfg: GPTConfig, q, k, v, window: Optional[int] = None):
     ``LayerSpec.window``) goes to whichever runs: the flash kernels and the
     dense reference take it, Ulysses hands it to the kernel it calls, and
     ring attention under a bound sp axis refuses a window layer by name
-    (its hops wholly outside the band are not skipped yet).
+    (its hops wholly outside the band are not skipped yet). The
+    block-diffusion mask (``cfg.diffusion_block``: the sequence a rank
+    holds is the noised and the clean copy of its positions) goes to the
+    flash kernels and the dense reference alike; ring attention and Ulysses
+    under a bound sp axis refuse it by name (a shard of the rows is neither
+    half).
 
     ============  ==================  ===============================
     attention     sp axis not bound   sp axis bound
@@ -119,14 +124,23 @@ def _attention(cfg: GPTConfig, q, k, v, window: Optional[int] = None):
     if kind not in _ATTENTION_KINDS:
         raise ValueError(f"unknown attention {kind!r} "
                          f"(expected one of {_ATTENTION_KINDS})")
+    block = cfg.diffusion_block
     if not _axis_bound(sp):
         if kind == "dense":
             # The reference takes equal head counts (ring and Ulysses tile
             # K/V up themselves; the flash kernels read them as they are).
             return default_attention(q, repeat_kv_heads(k, q.shape[2]),
                                      repeat_kv_heads(v, q.shape[2]),
-                                     causal=True, window=window)
-        return flash_attention(q, k, v, causal=True, window=window)
+                                     causal=True, window=window,
+                                     block_diffusion=block)
+        return flash_attention(q, k, v, causal=True, window=window,
+                               block_diffusion=block)
+    if block is not None and kind in ("ring", "ulysses"):
+        raise ValueError(
+            f"attention={kind!r} under the bound {sp!r} axis has no "
+            f"block_diffusion mask (diffusion_block={block}): a rank's "
+            "shard of the rows is neither the noised nor the clean half; "
+            "bind no sp axis")
     if kind == "ring":
         if window is not None:
             raise ValueError(

@@ -248,7 +248,10 @@ def _block(cfg: GPTConfig, spec: LayerSpec, layer_params, x, positions,
         early = experts.early_router(cfg, lp[ff.KEY], x)
 
     if mixer is not None:
-        with jax.named_scope(mixer.scope(spec)):
+        # Under the block-diffusion mask every mixer is plain attention
+        # (``layer_plan``), and its kernels are told apart as ``attn_bd``.
+        with jax.named_scope("attn_bd" if cfg.diffusion_block is not None
+                             else mixer.scope(spec)):
             branch = mixer.apply(
                 cfg, spec, lp if mixer.KEY is None else lp[mixer.KEY],
                 before(mixer.NORM), positions,
@@ -413,10 +416,11 @@ def _note_head_loss(cfg: GPTConfig, tokens: int, rows: int) -> None:
         tied=str(cfg.tie_embeddings).lower())
 
 
-def _head_loss_block(x, targets, w, tied: bool, scaling: float):
+def _head_loss_block(x, targets, weights, w, tied: bool, scaling: float):
     """One block of rows: ``(summed loss, d loss / d x [R, E], d loss / d w)``
     at a cotangent of 1, both gradients float32. The block's logits are made
-    once and die here."""
+    once and die here. ``weights`` (float32 ``[R]`` or None: ones) multiply
+    each row's loss."""
     with jax.named_scope("head"):
         logits = _logits(x, w, tied, scaling)
     with jax.named_scope("loss"):
@@ -425,11 +429,17 @@ def _head_loss_block(x, targets, w, tied: bool, scaling: float):
                == targets[:, None])
         lse = jax.nn.logsumexp(logits, axis=-1)
         picked = jnp.sum(jnp.where(hit, logits, 0.0), axis=-1)
-        num = jnp.sum(jnp.where(valid, lse - picked, 0.0))
+        each = lse - picked
+        if weights is not None:
+            each = weights * each
+        num = jnp.sum(jnp.where(valid, each, 0.0))
         # softmax - onehot, zero on a masked row; rounded to the compute
         # dtype once, where autodiff rounds the float32 logits' cotangent.
-        d = jnp.where(valid[:, None],
-                      jnp.exp(logits - lse[:, None]) - hit, 0.0)
+        rows = valid[:, None]
+        d = jnp.exp(logits - lse[:, None]) - hit
+        if weights is not None:
+            d = weights[:, None] * d
+        d = jnp.where(rows, d, 0.0)
         d = (d / scaling if scaling != 1.0 else d).astype(x.dtype)
     with jax.named_scope("head"):
         dx = jnp.einsum("rv,ve->re" if tied else "rv,ev->re", d, w,
@@ -439,24 +449,28 @@ def _head_loss_block(x, targets, w, tied: bool, scaling: float):
     return num, dx, dw
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def _head_loss(x, w, targets, tied: bool, scaling: float, rows: int):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def _head_loss(x, w, targets, weights, tied: bool, scaling: float, rows: int):
     """The summed cross-entropy of the rows ``x`` ``[T, E]`` under the head
-    ``w`` against ``targets`` ``[T]`` (negative: a masked row), as one rule
+    ``w`` against ``targets`` ``[T]`` (negative: a masked row), each row's
+    times its float32 weight (``weights`` ``[T]``; None: ones, and the
+    program is the one without them), as one rule
     over blocks of ``rows`` rows: a block's logits, in float32 their
     log-sum-exp, the block's loss and ``softmax - onehot``, and from that at
     once the block's part of ``d w`` (summed in float32) and its rows of
     ``d x``. No ``[T, V]`` array is made or kept; the backward rule scales
     the two gradients by the sum's cotangent."""
-    return _head_loss_fwd(x, w, targets, tied, scaling, rows)[0]
+    return _head_loss_fwd(x, w, targets, weights, tied, scaling, rows)[0]
 
 
-def _head_loss_fwd(x, w, targets, tied, scaling, rows):
+def _head_loss_fwd(x, w, targets, weights, tied, scaling, rows):
     # A Python loop and no ``lax.scan``: the blocks are few, and as a
     # ``while`` they ran 7 to 10 ms behind this on the chip (PERF.md,
     # Findings, PR 41). The last block is the rows that are left.
     num, dx, dw = zip(*(
-        _head_loss_block(x[i:i + rows], targets[i:i + rows], w, tied, scaling)
+        _head_loss_block(x[i:i + rows], targets[i:i + rows],
+                         None if weights is None else weights[i:i + rows],
+                         w, tied, scaling)
         for i in range(0, x.shape[0], rows)))
     # The empty slice hands the backward rule the compute dtype.
     return sum(num), (jnp.concatenate(dx), sum(dw), x[:0])
@@ -468,7 +482,7 @@ def _head_loss_bwd(tied, scaling, rows, residuals, g):
         # Scaled in float32 and rounded to the compute dtype once, as the
         # products autodiff makes in that dtype are.
         return ((g * dx).astype(like.dtype), (g * dw).astype(like.dtype),
-                None)
+                None, None)
 
 
 _head_loss.defvjp(_head_loss_fwd, _head_loss_bwd)
@@ -486,15 +500,15 @@ def forward(params, tokens, positions, cfg: GPTConfig):
 
 
 def loss_fn(params, tokens, targets, positions, cfg: GPTConfig,
-            ignore_index: int = -1):
+            ignore_index: int = -1, weights=None, divisor=None):
     """The training loss: :func:`loss_and_aux` without its parts (and, like
     it, without ever holding the logits :func:`forward` returns)."""
     return loss_and_aux(params, tokens, targets, positions, cfg,
-                        ignore_index)[0]
+                        ignore_index, weights, divisor)[0]
 
 
 def loss_and_aux(params, tokens, targets, positions, cfg: GPTConfig,
-                 ignore_index: int = -1):
+                 ignore_index: int = -1, weights=None, divisor=None):
     """``(loss, aux)``: mean next-token cross-entropy over all *global*
     target tokens, plus, with expert blocks, ``load_balance_coef`` times
     their summed load-balance terms and ``router_z_coef`` times their summed
@@ -504,7 +518,20 @@ def loss_and_aux(params, tokens, targets, positions, cfg: GPTConfig,
     ``targets`` is sequence-sharded like ``tokens`` (shift done globally by the
     caller, so shard boundaries need no neighbor exchange); positions with
     ``ignore_index`` are masked out. Averages over sp so every rank returns the
-    identical global-mean loss. ``aux`` holds ``cross_entropy`` and, with
+    identical global-mean loss. **What a caller whose loss is not that mean
+    says, it says here** (training by diffusion over blocks): **under
+    ``cfg.diffusion_block``, and only there**, ``targets`` may be ``[B,
+    S_local / 2]``, the targets of each sequence's noised half, its first
+    rows, **whatever those rows are to predict** (a caller shifts or does
+    not), and the head multiplies those rows alone: the clean half is in
+    the stack as keys and values and never meets the head's matrix (any
+    other length raises by name);
+    ``weights`` (float32, as ``targets``) multiply each target's
+    cross-entropy (``1 / t`` of a noised token's block); ``divisor`` (a
+    number: what the sum, taken over sp and ep, is divided by, a rank's
+    data tokens; dp averaging is the caller's as ever) stands in for the
+    count of targets kept. Without the three, the program is the one it
+    was. ``aux`` holds ``cross_entropy`` and, with
     expert blocks, ``load_balance``, ``router_z`` (the sums over blocks) and
     ``counts`` ``[blocks, experts]``, tokens per expert; under
     ``cfg.router_probe`` also ``router_inputs`` ``[blocks, T, d]`` and
@@ -521,6 +548,16 @@ def loss_and_aux(params, tokens, targets, positions, cfg: GPTConfig,
     x, auxes = _hidden(params, tokens, positions, cfg)
     mask = (targets != ignore_index)
     with jax.named_scope("head"):
+        if targets.shape[1] != x.shape[1]:
+            if cfg.diffusion_block is None \
+                    or 2 * targets.shape[1] != x.shape[1]:
+                raise ValueError(
+                    f"targets of {targets.shape[1]} rows a sequence beside "
+                    f"{x.shape[1]} rows of tokens: only under "
+                    "cfg.diffusion_block may targets be fewer, and then "
+                    "the noised half's, half the rows "
+                    f"(diffusion_block={cfg.diffusion_block})")
+            x = x[:, :targets.shape[1]]
         x = x.reshape(-1, x.shape[-1])
         rows = head_loss_rows(x.shape[0], cfg.vocab_size)
         _note_head_loss(cfg, x.shape[0], rows)
@@ -529,6 +566,8 @@ def loss_and_aux(params, tokens, targets, positions, cfg: GPTConfig,
         # sums the matrix's over the ranks.
         w = varying_like(_head_matrix(params, cfg), x)
     num = _head_loss(x, w, jnp.where(mask, targets, -1).reshape(-1),
+                     None if weights is None
+                     else weights.astype(jnp.float32).reshape(-1),
                      cfg.tie_embeddings, cfg.logits_scaling, rows)
     with jax.named_scope("loss"):
         den = jnp.sum(mask.astype(jnp.float32))
@@ -540,7 +579,7 @@ def loss_and_aux(params, tokens, targets, positions, cfg: GPTConfig,
             if _axis_bound(ax):
                 num = lax.psum(num, ax)
                 den = lax.psum(den, ax)
-        loss = num / jnp.maximum(den, 1.0)
+        loss = num / (jnp.maximum(den, 1.0) if divisor is None else divisor)
     if not auxes:
         return loss, {"cross_entropy": loss}
     with jax.named_scope("aux_loss"):

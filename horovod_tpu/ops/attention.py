@@ -15,10 +15,33 @@ import jax.numpy as jnp
 import numpy as np
 
 
-def default_attention(q, k, v, causal: bool = True, window=None):
+def block_diffusion_mask(rows: int, block: int):
+    """The ``[rows, rows]`` boolean mask of training by diffusion over
+    blocks (BD3-LM, arXiv:2503.09573) on a sequence of two halves, the
+    noised copy then the clean copy of ``rows / 2`` positions in blocks of
+    ``block``: a noised query sees its own noised block, both ways, and the
+    clean blocks before its own; a clean query sees the clean blocks up to
+    its own; no clean query sees a noised key."""
+    half = rows // 2
+    if rows % 2 or block < 1 or half % block:
+        raise ValueError(f"block_diffusion={block} wants a sequence of two "
+                         f"halves of whole blocks, got {rows} rows")
+    at = np.arange(rows)
+    noised, blk = at < half, at % half // block
+    q_noised, k_noised = noised[:, None], noised[None, :]
+    q_blk, k_blk = blk[:, None], blk[None, :]
+    return (q_noised & k_noised & (k_blk == q_blk)) \
+        | (q_noised & ~k_noised & (k_blk < q_blk)) \
+        | (~q_noised & ~k_noised & (k_blk <= q_blk))
+
+
+def default_attention(q, k, v, causal: bool = True, window=None,
+                      block_diffusion=None):
     """Plain softmax attention. q/k/v: [B, S, H, D]. Computed in fp32 softmax.
     ``window`` (causal only): a query sees itself and the ``window - 1`` keys
-    before it, ``0 <= i - j < window``.
+    before it, ``0 <= i - j < window``. ``block_diffusion`` (causal only, no
+    window beside it): the sequence is a noised and a clean copy of ``S / 2``
+    positions under :func:`block_diffusion_mask` at that block length.
 
     Materializes the ``[B, H, S, S]`` float32 logits: the reference the other
     paths are tested against, not a path to train long sequences on."""
@@ -26,7 +49,16 @@ def default_attention(q, k, v, causal: bool = True, window=None):
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) * scale
     if window is not None and not causal:
         raise ValueError("a window is a causal band")
-    if causal:
+    if block_diffusion is not None:
+        if window is not None or not causal \
+                or logits.shape[-2] != logits.shape[-1]:
+            raise ValueError("block_diffusion masks a sequence against "
+                             "itself, beside neither a window nor "
+                             "causal=False")
+        logits = jnp.where(
+            block_diffusion_mask(logits.shape[-1], block_diffusion), logits,
+            jnp.finfo(jnp.float32).min)
+    elif causal:
         qlen, klen = logits.shape[-2], logits.shape[-1]
         mask = jnp.tril(jnp.ones((qlen, klen), dtype=bool), klen - qlen)
         if window is not None:

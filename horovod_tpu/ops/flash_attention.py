@@ -43,7 +43,9 @@ Design notes (TPU):
   axis the tiles of a head's ``q_block x k_block`` rectangle that hold a
   kept pair, and no others. Which pairs are kept is one description, :class:`Mask`: causal, a
   causal band (``window``: a query sees itself and the ``window - 1`` keys
-  before it) or none. The mask is static and so is the tile, so the mask
+  before it), the block-diffusion mask over a noised and a clean copy of a
+  sequence (``block_diffusion``) or none. The mask is static and so is the
+  tile, so the mask
   itself enumerates its kept tiles at trace time (``Mask.kept_tiles``), a
   query block's tiles together with the keys ascending, and the list rides
   into the kernel as a scalar-prefetch table in SMEM
@@ -136,6 +138,9 @@ VMEM_LIMIT_BYTES = 64 * 1024 * 1024
 # masked) and up to the whole of a short sequence (512x512 at S=512 is
 # twice as fast as 256x256, though it computes the masked half too).
 _CANDIDATES = (1024, 512, 256, 128)
+# Under block diffusion, a noised key's block as the clean-key clause of
+# ``Mask.keep`` reads it: past every block a query has.
+_NO_BLOCK = 1 << 30
 
 
 def vmem_estimate(kernel: str, block_q: int, block_k: int, d: int,
@@ -270,19 +275,46 @@ class Mask:
 
     ``causal``: ``k <= q``. ``window`` (causal only): besides, ``q - k <
     window``, a query sees itself and the ``window - 1`` keys before it.
+    ``block_diffusion`` (causal only, no window beside it): the mask of
+    training by diffusion over blocks (BD3-LM, arXiv:2503.09573). The
+    sequence is two halves of ``half`` rows, the noised copy ``[0, half)``
+    and the clean copy ``[half, 2 half)`` of the same ``half`` positions, in
+    blocks of ``block_diffusion`` rows (a power of two that divides
+    ``half``: the kernels shift and compare, they do not divide). A noised
+    query sees its own noised block, both ways, and the clean blocks before
+    its own; a clean query sees the clean blocks up to and including its
+    own; no clean query sees a noised key. The kept set is not inside the
+    causal triangle of the ``2 half`` square: the noised queries' tiles
+    over the clean keys lie above its diagonal. Every row and column holds
+    a kept pair (a row its own position), and rows past ``2 half`` (the
+    padding) count as clean rows of blocks no real row has.
     Static: it rides the kernels' partial arguments and the custom VJP's
     non-differentiable ones."""
     causal: bool = True
     window: Optional[int] = None
+    block_diffusion: Optional[int] = None
+    half: Optional[int] = None
 
     def __post_init__(self):
         if self.window is not None and (not self.causal or self.window < 1):
             raise ValueError("a window is a causal band of at least one "
                              f"key, got {self!r}")
+        block, half = self.block_diffusion, self.half
+        if (block is None) != (half is None):
+            raise ValueError("block_diffusion (the block) and half (the rows "
+                             f"of each copy) go together, got {self!r}")
+        if block is not None and (
+                not self.causal or self.window is not None or block < 1
+                or block & (block - 1) or half < 1 or half % block):
+            raise ValueError(
+                "block_diffusion is a block length, a power of two that "
+                "divides each half of the sequence, beside neither a window "
+                f"nor causal=False, got {self!r}")
 
     @property
     def name(self) -> str:
-        return "window" if self.window is not None \
+        return "block_diffusion" if self.block_diffusion is not None \
+            else "window" if self.window is not None \
             else "causal" if self.causal else "full"
 
     def needs_masking(self, kv_len: int, s_pad: int) -> bool:
@@ -299,16 +331,55 @@ class Mask:
         would attend the zero-filled tail."""
         if not self.causal:
             return k_pos < kv_len
+        if self.block_diffusion is not None:
+            # Two compares on the pair, the rest on a row or a column of
+            # positions (``_mask_tile`` hands this mask those, not tiles):
+            # a noised key is kept by the noised queries of its block; a
+            # clean key by the noised queries of later blocks and the clean
+            # ones of its own and later.
+            shift = self.block_diffusion.bit_length() - 1
+            noised_q, noised_k = q_pos < self.half, k_pos < self.half
+            q_block = jnp.where(noised_q, q_pos, q_pos - self.half) >> shift
+            k_block = jnp.where(noised_k, k_pos, k_pos - self.half) >> shift
+            own = jnp.where(noised_q, q_block, -2) \
+                == jnp.where(noised_k, k_block, -1)
+            return own | (jnp.where(noised_k, _NO_BLOCK, k_block)
+                          <= jnp.where(noised_q, q_block - 1, q_block))
         keep = q_pos >= k_pos
         if self.window is not None:
             keep = keep & (q_pos - k_pos < self.window)
         return keep
+
+    def _blocks_kept(self, q_first: int, q_last: int, k_first: int,
+                     k_last: int) -> bool:
+        """Block diffusion: whether queries ``[q_first, q_last]`` and keys
+        ``[k_first, k_last]`` hold a kept pair. Python integers."""
+        half, shift = self.half, self.block_diffusion.bit_length() - 1
+
+        def blocks(first, last, clean):
+            """The blocks of a range's noised or clean rows, or None."""
+            first, last = (max(first, half) - half, last - half) if clean \
+                else (first, min(last, half - 1))
+            return None if last < max(first, 0) else (first >> shift,
+                                                      last >> shift)
+
+        q_noised, q_clean = (blocks(q_first, q_last, c) for c in (False, True))
+        k_noised, k_clean = (blocks(k_first, k_last, c) for c in (False, True))
+        return bool(
+            (q_noised and k_noised and max(q_noised[0], k_noised[0])
+             <= min(q_noised[1], k_noised[1]))
+            or (q_noised and k_clean and k_clean[0] < q_noised[1])
+            or (q_clean and k_clean and k_clean[0] <= q_clean[1]))
 
     def tile_kept(self, qi: int, kj: int, block_q: int, block_k: int) -> bool:
         """Whether the tile at block indices ``(qi, kj)`` holds any kept
         pair."""
         if not self.causal:
             return True
+        if self.block_diffusion is not None:
+            return self._blocks_kept(
+                qi * block_q, (qi + 1) * block_q - 1,
+                kj * block_k, (kj + 1) * block_k - 1)
         # The tile's last query row reaches its first key.
         kept = (qi + 1) * block_q - 1 >= kj * block_k
         if self.window is not None:
@@ -343,7 +414,14 @@ class Mask:
         the grid's steps), ``skipped`` (wholly above the diagonal) and
         ``skipped_band``
         (wholly below the band: what a window saves of the causal
-        triangle's). Python integers, for the trace-time counter."""
+        triangle's); under block diffusion ``kept`` and
+        ``skipped_block_diffusion`` (every other tile of the rectangle,
+        above its diagonal or below). Python integers, for the trace-time
+        counter."""
+        if self.block_diffusion is not None:
+            kept = self.kept_tiles(n_q, n_k, block_q, block_k).shape[1]
+            return {"kept": kept,
+                    "skipped_block_diffusion": n_q * n_k - kept}
         out = {"kept": 0, "skipped": 0, "skipped_band": 0}
         for i in range(n_q):
             for j in range(n_k):
@@ -355,6 +433,17 @@ class Mask:
                     out["skipped_band"] += 1
         return out
 
+    def kept_pairs(self) -> Optional[int]:
+        """The pairs the mask keeps, where the description alone says it:
+        under block diffusion ``half ** 2 + half * block`` (clean-clean
+        ``B^2 n (n + 1) / 2``, noised-clean ``B^2 n (n - 1) / 2``,
+        noised-noised ``n B^2`` over ``n = half / B`` blocks); None for the
+        masks whose count follows the call's length, which they do not
+        hold. For the trace-time counter."""
+        if self.block_diffusion is None:
+            return None
+        return self.half * (self.half + self.block_diffusion)
+
 
 def _mask_tile(s, q_start, k_start, mask: Mask, kv_len: int,
                transposed: bool = False):
@@ -362,6 +451,15 @@ def _mask_tile(s, q_start, k_start, mask: Mask, kv_len: int,
     positions ``q_start``/``k_start``; ``transposed`` tiles are [keys,
     queries]."""
     q_dim, k_dim = (1, 0) if transposed else (0, 1)
+    if mask.block_diffusion is not None:
+        # A column of query positions against a row of key positions: what
+        # ``keep`` derives from one position alone costs a vector's work,
+        # not a tile's.
+        along = {0: (s.shape[0], 1), 1: (1, s.shape[1])}
+        return jnp.where(mask.keep(
+            q_start + jax.lax.broadcasted_iota(jnp.int32, along[q_dim], q_dim),
+            k_start + jax.lax.broadcasted_iota(jnp.int32, along[k_dim], k_dim),
+            kv_len), s, NEG_INF)
     k_pos = k_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, k_dim)
     q_pos = q_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, q_dim) \
         if mask.causal else None
@@ -574,6 +672,12 @@ def _tiles_for(kernel, q, k, v, mask: Mask, forced, dq: str = "own",
     runtime.note_traced(
         "hvdtpu_spmd_flash_grid_steps_total", tiles.shape[1], kernel=kernel,
         mask=mask.name, seq=q.shape[1])
+    if mask.kept_pairs() is not None:
+        for pairs, n in (("computed", tiles.shape[1] * bq * bk),
+                         ("kept", mask.kept_pairs())):
+            runtime.note_traced(
+                "hvdtpu_spmd_flash_pairs_total", n, kernel=kernel,
+                mask=mask.name, pairs=pairs, seq=q.shape[1])
     return bq, bk, tiles
 
 
@@ -860,7 +964,8 @@ _flash_bhsd.defvjp(_flash_bhsd_fwd, _flash_bhsd_bwd)
 
 
 def flash_attention(q, k, v, causal: bool = True, *,
-                    window: Optional[int] = None, _blocks=None):
+                    window: Optional[int] = None,
+                    block_diffusion: Optional[int] = None, _blocks=None):
     """Fused attention. q: ``[B, S, H, D]`` (the layout the GPT blocks
     use); k/v: ``[B, S, Hkv, D]`` where ``Hkv`` may divide ``H``
     (grouped-query attention: the kernels read K/V head ``h // group`` for
@@ -876,7 +981,11 @@ def flash_attention(q, k, v, causal: bool = True, *,
     the tiles wholly below that band are no grid steps either, as those
     above the diagonal are not, in every kernel; a
     window of the sequence's length or more is the causal program, unchanged
-    (:class:`Mask`). Tile sizes and the MXU operands' dtype follow the
+    (:class:`Mask`). ``block_diffusion`` (static, causal only, no window
+    beside it): the sequence is the noised and the clean copy of ``S / 2``
+    positions, one after the other, under the block-diffusion mask at that
+    block length (:class:`Mask`), and the grids walk the tiles that hold a
+    kept pair of it. Tile sizes and the MXU operands' dtype follow the
     call's shapes and dtype (``block_sizes``); ``_blocks=(block_q,
     block_k)`` forces one tile on every kernel, for the tests and the
     sweep.
@@ -895,8 +1004,15 @@ def flash_attention(q, k, v, causal: bool = True, *,
             raise ValueError(f"blocks {_blocks} must be multiples of {_PAD} "
                              f"that divide the padded length {s_pad}")
     sm_scale = 1.0 / float(np.sqrt(d))
-    mask = Mask(bool(causal),
-                None if window is None or window >= s else int(window))
+    if block_diffusion is not None:
+        if window is not None or s % 2:
+            raise ValueError(
+                f"block_diffusion={block_diffusion} takes a sequence of two "
+                f"halves and no window, got {s} rows, window={window}")
+        mask = Mask(bool(causal), None, int(block_diffusion), s // 2)
+    else:
+        mask = Mask(bool(causal),
+                    None if window is None or window >= s else int(window))
 
     def to_bhsd(x):
         return _pad_seq(x.transpose(0, 2, 1, 3).reshape(-1, s, x.shape[3]))

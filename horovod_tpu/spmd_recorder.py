@@ -92,11 +92,13 @@ _TRACED = {
          "key_dim", "value_dim", "dq")),
     "hvdtpu_spmd_flash_tiles_total": (
         "Tiles of the grids of the flash attention kernels JAX traced, by "
-        "kernel, the mask's kind (causal, window, full), the padded length "
-        "and what became of the tile: kept (computed), skipped (wholly "
-        "above the diagonal) or skipped_band (wholly below a window's band). "
-        "One rectangle of (q blocks x k blocks) a trace, the same for every "
-        "head.",
+        "kernel, the mask's kind (causal, window, block_diffusion, full), "
+        "the padded length and what became of the tile: kept (computed), "
+        "skipped (wholly above the diagonal), skipped_band (wholly below a "
+        "window's band) or, under the block-diffusion mask, "
+        "skipped_block_diffusion (every tile of the 2L x 2L rectangle that "
+        "holds no kept pair, above its diagonal or below). One rectangle of "
+        "(q blocks x k blocks) a trace, the same for every head.",
         ("kernel", "mask", "tiles", "seq")),
     "hvdtpu_spmd_flash_grid_steps_total": (
         "Steps a head's grid walks in the flash attention kernels JAX "
@@ -105,6 +107,15 @@ _TRACED = {
         "so it equals hvdtpu_spmd_flash_tiles_total{tiles=kept} (no step "
         "is a tile the mask drops).",
         ("kernel", "mask", "seq")),
+    "hvdtpu_spmd_flash_pairs_total": (
+        "Query-key pairs of one head in the flash attention kernels JAX "
+        "traced, by kernel, the mask's kind and the padded length: computed "
+        "(the pairs of the tiles the grid walks, steps x block_q x block_k) "
+        "beside kept (the pairs the mask keeps, ops/flash_attention.py::"
+        "Mask.kept_pairs): kept over computed is how full the computed "
+        "tiles are. Only for a mask whose description holds its sequence's "
+        "length (block_diffusion); the others count no pairs.",
+        ("kernel", "mask", "pairs", "seq")),
     "hvdtpu_spmd_head_loss_traces_total": (
         "Times JAX traced the GPT's head and loss as one rule over blocks of "
         "token rows (models/gpt.py::_head_loss), by the rows a block holds "

@@ -30,7 +30,9 @@ square, the experts fed the stream's first columns in place of the
 down-projection; for a Kimi-delta-attention job the decay one a head, the
 unbounded gate, the group limit left out, the head-wise gates left out, the
 scan's decays, running sums and state in bfloat16, or the decays and the
-state alone), against
+state alone; for a block-diffusion job the noised rows let see their own
+clean block, one kept tile left out of the grids' tables, or four experts a
+row where the configuration says eight), against
 the untouched reference: what a job's tolerances must catch. A variant
 changes the job's ``GPTConfig`` after the job is built, before its step is
 traced (or what the program's modules see, where no field says it); a job
@@ -234,6 +236,28 @@ def _patched(module, name: str, value):
     return lambda: setattr(module, name, real)
 
 
+def _own_clean_block():
+    """The block-diffusion mask with its second clause one block too wide:
+    a noised row sees its own clean block, the token it is to predict among
+    its keys (``benchmarks/tests/test_bd_faults.py`` plants the same)."""
+    from benchmarks.reference import gpt_bd_moe_dp as reference
+    from horovod_tpu.ops import flash_attention as fa
+
+    return _patched(fa.Mask, "keep", reference.own_clean_block_keep)
+
+
+def _tile_dropped():
+    """The block-diffusion grids' tables less one tile, the last noised
+    query tile's first clean key tile: the one control of that cell that
+    reads ``correct`` on some seeds (its job's header says what no row
+    sees)."""
+    from benchmarks.reference import gpt_bd_moe_dp as reference
+    from horovod_tpu.ops import flash_attention as fa
+
+    return _patched(fa.Mask, "tile_kept",
+                    reference.tile_dropped(fa.Mask.tile_kept))
+
+
 def _memory_after_gate():
     """A publishing Mamba-1 layer hands on ``y * silu(z)`` where the model
     hands on the scan's output before the gate."""
@@ -376,6 +400,9 @@ VARIANTS = {
     "no_post_norm": lambda job: _replace(job, post_norm=False),
     "no_shared": lambda job: _replace(job, shared_expert_dim=0),
     "router_bf16": lambda job: _router_in_bfloat16(),
+    "bd_own_clean_block": lambda job: _own_clean_block(),
+    "bd_tile_dropped": lambda job: _tile_dropped(),
+    "top_k_4": lambda job: _replace(job, experts_per_token=4),
     "beta_sigmoid": lambda job: _replace(job, gdn_allow_neg_eigval=False),
     "q_scale_128": lambda job: _q_scaled_for_heads_of_128(),
     "norms_before": _norms_before,

@@ -24,7 +24,11 @@ PR 52).
 ``default_attention`` at the deepest shape a cell runs them at
 (``LONG_SHAPE``: 16,384 rows at 28:4 heads of 128, under the causal mask and
 under a 4096-key band), the dense side one query head at a time in float32
-(PERF.md, Findings, PR 53).
+(PERF.md, Findings, PR 53); ``--dense-long sdar`` does it at 32:4 heads under
+the block-diffusion mask at blocks of 4, the ``sdar-30b-a3b-chat_s8192``
+cell's 80-tile grids (the cell's own check cannot tell a table that lacks one
+tile from a sound seed: PERF.md, Findings, PR 65; a change to that mask's
+tiling is held here first).
 
 ``--grid`` times the forward kernel and the one backward kernel at a cell's
 shape and mask (``BWD_SHAPES``, all or those named) at the table's tile or a
@@ -87,7 +91,14 @@ BWD_TILES = ((1024, 1024), (512, 1024), (1024, 512))
 # ``smallthinker-21b-a3b_s16384``'s attention: (B, S, H, Hkv, D), the full
 # layer's mask and the window layers'.
 LONG_SHAPE = BWD_SHAPES["smallthinker-21b-a3b_s16384"][:5]
-LONG_WINDOWS = (None, 4096)
+LONG_MASKS = ({"window": None}, {"window": 4096})
+# ``--dense-long CELL``: a shape and the masks (``flash_attention``'s and
+# ``default_attention``'s keywords) it is held to dense attention under.
+# ``sdar``: ``sdar-30b-a3b-chat_s8192``'s 16,384 rows under the
+# block-diffusion mask, the 80-tile grids, beside the causal mask's.
+LONG = {"smallthinker": (LONG_SHAPE, LONG_MASKS),
+        "sdar": ((1, 16384, 32, 4, 128),
+                 ({"block_diffusion": 4}, {"window": None}))}
 OUT = os.path.join("chiprun_out", "flash_sweep.jsonl")
 KERNELS = ("fwd", "dkdv", "dq")
 
@@ -323,7 +334,7 @@ def check_against_dense(cases=DENSE_CASES):
              max_abs=dict(zip(("loss", "dq", "dk", "dv"), scale)))
 
 
-def check_long_against_dense(shape=LONG_SHAPE, windows=LONG_WINDOWS,
+def check_long_against_dense(shape=LONG_SHAPE, masks=LONG_MASKS,
                              dtype=jnp.bfloat16):
     """The compiled forward and (one) backward kernel through
     ``flash_attention`` at ``shape`` against ``default_attention`` on the
@@ -341,11 +352,11 @@ def check_long_against_dense(shape=LONG_SHAPE, windows=LONG_WINDOWS,
     k = jax.random.normal(ks[1], (b, s, hkv, d), dtype) * 0.5
     v = jax.random.normal(ks[2], (b, s, hkv, d), dtype) * 0.5
     w = jax.random.normal(ks[3], q.shape, jnp.float32)
-    for window in windows:
+    for mask in masks:
         tile = fa.block_sizes(fa.KERNEL_DKDV, s, d, dtype, True)
         flash = jax.jit(jax.value_and_grad(
             lambda q, k, v: jnp.sum(fa.flash_attention(
-                q, k, v, causal=True, window=window).astype(jnp.float32)
+                q, k, v, causal=True, **mask).astype(jnp.float32)
                 * w), argnums=(0, 1, 2)))
 
         @jax.jit
@@ -355,7 +366,7 @@ def check_long_against_dense(shape=LONG_SHAPE, windows=LONG_WINDOWS,
             def loss(q1, k1, v1):
                 with jax.default_matmul_precision("highest"):
                     return jnp.sum(default_attention(
-                        q1, k1, v1, causal=True, window=window) * w1)
+                        q1, k1, v1, causal=True, **mask) * w1)
             return jax.value_and_grad(loss, argnums=(0, 1, 2))(
                 *(x.astype(jnp.float32) for x in (q1, k1, v1)))
 
@@ -372,13 +383,18 @@ def check_long_against_dense(shape=LONG_SHAPE, windows=LONG_WINDOWS,
             dk[:, :, kv] += np.asarray(gk)
             dv[:, :, kv] += np.asarray(gv)
         names = ("dq", "dk", "dv")
-        emit(check="dense_long", shape=list(shape), window=window,
+        emit(check="dense_long", shape=list(shape), mask=mask,
              dtype=jnp.dtype(dtype).name, tile=list(tile),
              backward_is_fused=fa.backward_is_fused(*tile, s, d, dtype, d),
              loss=[float(got[0]), want_loss],
              max_abs_err={n: float(np.max(np.abs(
                  np.asarray(a, np.float32) - b_)))
                  for n, a, b_ in zip(names, got[1], (dq, dk, dv))},
+             # The kernels' gradient along the dense side's, less one: a
+             # table that lacks a tile shortens it.
+             along_less_one={n: float(np.vdot(b_, np.asarray(a, np.float32))
+                                      / np.vdot(b_, b_) - 1)
+                             for n, a, b_ in zip(names, got[1], (dq, dk, dv))},
              # A norm of the difference over the dense side's norm.
              rel_l2_err={n: float(np.linalg.norm(
                  np.asarray(a, np.float32) - b_) / np.linalg.norm(b_))
@@ -396,10 +412,14 @@ def main():
     ap.add_argument("--bwd", nargs="*", metavar="CELL", default=None,
                     help="the backward pass alone, one kernel beside the "
                          "pair, at these cells' shapes (none named: all)")
-    ap.add_argument("--dense-long", action="store_true",
+    ap.add_argument("--dense-long", nargs="?", const="smallthinker",
+                    choices=sorted(LONG), metavar="CELL",
                     help="the forward and the one backward kernel against "
-                         "dense attention at 16,384 rows, 28:4 heads, "
-                         "causal and under a 4096 band, and nothing else")
+                         "dense attention at 16,384 rows and nothing else: "
+                         "28:4 heads, causal and under a 4096 band "
+                         "(smallthinker, the default), or 32:4 heads under "
+                         "the block-diffusion mask at blocks of 4 and "
+                         "causal (sdar)")
     ap.add_argument("--grid", nargs="*", metavar="CELL", default=None,
                     help="the forward and the one backward kernel at these "
                          "cells' shapes and masks (none named: all), beside "
@@ -424,7 +444,7 @@ def main():
             time_parent(name, shape, bf16)
         return
     if args.dense_long:
-        check_long_against_dense()
+        check_long_against_dense(*LONG[args.dense_long])
         return
     if args.grid is not None:
         for name in args.grid or BWD_SHAPES:

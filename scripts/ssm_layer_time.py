@@ -140,8 +140,8 @@ def head_rows(gpt, shape, forced, tolerance: float):
         return -jnp.sum(jnp.where(targets >= 0, picked, 0.0)) / tokens
 
     def rule(rows, x, w):
-        return gpt._head_loss(x, w.astype(x.dtype), targets, tied, scaling,
-                              rows) / tokens
+        return gpt._head_loss(x, w.astype(x.dtype), targets, None, tied,
+                              scaling, rows) / tokens
 
     grad = functools.partial(jax.value_and_grad, argnums=(0, 1))
     old_fwd, old_both = jax.jit(old), jax.jit(grad(old))

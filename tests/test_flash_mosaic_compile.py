@@ -55,8 +55,8 @@ def mosaic(monkeypatch):
         yield
 
 
-# (B*H, B*Hkv, S, D or (D, Dv), dtype, causal[, window]): the two cells;
-# chip_smoke's legs.
+# (B*H, B*Hkv, S, D or (D, Dv), dtype, causal[, the rest of ``Mask``]): the
+# two cells; chip_smoke's legs.
 SHAPES = {
     "starcoder2-3b_s4096": (48, 4, 4096, 128, jnp.bfloat16, True),
     "starcoder2-3b_s512": (384, 32, 512, 128, jnp.bfloat16, True),
@@ -85,6 +85,12 @@ LONGEST = {
                                           jnp.bfloat16, True),
     "phi-4-mini-flash-reasoning_s16384_window": (20, 10, 16384, (64, 128),
                                                  jnp.bfloat16, True, 512),
+    # The noised and the clean copy of 8,192 positions at 32:4 heads under
+    # the block-diffusion mask in blocks of 4 (no window; the mask's block
+    # and half): 80 of 256 tiles, the noised queries' over the clean keys
+    # above the diagonal.
+    "sdar-30b-a3b-chat_s8192": (32, 4, 16384, 128, jnp.bfloat16, True, None,
+                                4, 8192),
 }
 FUSED_SHAPES = {
     **SHAPES,
@@ -105,9 +111,9 @@ FUSED_SHAPES = {
     *((shape, "fwd") for shape in LONGEST),
     *((shape, "fused") for shape in FUSED_SHAPES)])
 def test_kernel_compiles_for_v5e(one_chip, mosaic, shape, kernel):
-    bh, bkv, s, d, dtype, causal, *window = FUSED_SHAPES[shape]
+    bh, bkv, s, d, dtype, causal, *rest = FUSED_SHAPES[shape]
     d, dv = d if isinstance(d, tuple) else (d, d)
-    mask = fa.Mask(causal, *window)
+    mask = fa.Mask(causal, *rest)
 
     def sds(*dims, dt=dtype):
         return jax.ShapeDtypeStruct(dims, dt, sharding=one_chip)

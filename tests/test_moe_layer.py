@@ -27,6 +27,7 @@ from horovod_tpu.parallel import moe  # noqa: E402
 from horovod_tpu.parallel.moe import moe_layer  # noqa: E402
 
 from benchmarks import flops_moe  # noqa: E402
+from benchmarks.reference import gpt_bd_moe_dp as bd_reference  # noqa: E402
 from benchmarks.reference import gpt_linear_moe_dp as share_reference  # noqa: E402,E501
 from benchmarks.reference import gpt_moe_dp as reference  # noqa: E402
 
@@ -485,13 +486,6 @@ def test_compiled_step_carries_the_expert_layers_scopes(make_runtime):
     assert not some("/mlp/")
 
 
-def _uncut(h, router, w_gate, w_up, w_down, shared, top_k):
-    """The whole layer by the share's reference, given every expert."""
-    m = {"router": router, "w_gate": w_gate, "w_up": w_up, "w_down": w_down,
-         "shared": shared}
-    return share_reference.expert_block(h, m, top_k)
-
-
 def _shared(seed):
     ks = jax.random.split(jax.random.PRNGKey(seed), 4)
     return {"w_gate": jax.random.normal(ks[0], (D, M)) / 4,
@@ -548,42 +542,59 @@ def as_a_block_runs_it(f, remat):
         else f
 
 
+# The cuts whose shares are added up: Qwen3-Next's and Trinity's (four
+# shares of 4 of 16 experts beside a shared expert that every chip computes
+# alike, counted once) and SDAR's (eight shares, 2 of 16 experts each, no
+# shared expert), each against the reference its benchmark job keeps.
+CUTS = {"four-shares-and-a-shared-expert": (4, True, share_reference),
+        "eight-shares": (2, False, bd_reference)}
+
+
+@pytest.mark.parametrize("cut", CUTS)
 @pytest.mark.parametrize("routing", ROUTINGS)
-def test_the_shares_add_up_to_the_uncut_layer(small_tile, routing):
+def test_the_shares_add_up_to_the_uncut_layer(small_tile, routing, cut):
     """The cut a configuration with more experts than a chip makes: at 16
-    experts, 4 a token, renormalised, the outputs of the four shares of 4
-    with the shared expert counted once add up to what the uncut reference
+    experts, 4 a token, renormalised, the outputs of the shares (four of 4,
+    or eight of 2) with the shared expert, where there is one, counted once
+    add up to what the uncut reference
     gives for the whole layer, and so do the gradients of the router (each
     share sees the whole router) and of the tokens; each share's own
     experts' gradients are the uncut layer's rows. Whatever the routing: the
     share whose experts are favoured finds its rows in one window, fills it
-    exactly, or takes two, and the other three shares one."""
+    exactly, or takes two, and the other shares one."""
     top_k = 4
-    h, router, w_gate, w_up, w_down = routed_inputs(11, routing, first=4)
+    held, has_shared, reference = CUTS[cut]
+    h, router, w_gate, w_up, w_down = routed_inputs(11, routing, first=4,
+                                                    held=held)
     shared = _shared(12)
     weigh = jnp.cos(jnp.arange(T * D, dtype=jnp.float32)).reshape(T, D)
 
     def whole(h, router, w_gate, w_up, w_down):
-        y, load_balance, counts = _uncut(h, router, w_gate, w_up, w_down,
-                                         shared, top_k)
+        m = {"router": router, "w_gate": w_gate, "w_up": w_up,
+             "w_down": w_down, **({"shared": shared} if has_shared else {})}
+        y, load_balance, counts = reference.expert_block(h, m, top_k)
         return jnp.sum(y * weigh), (y, load_balance, counts)
 
     def share(first):
         def f(h, router, w_gate, w_up, w_down):
             y, aux = moe_layer(
-                h, router, w_gate[first:first + 4], w_up[first:first + 4],
-                w_down[first:first + 4], top_k=top_k, dtype=jnp.float32,
-                first_expert=first, renormalize=True)
+                h, router, w_gate[first:first + held],
+                w_up[first:first + held], w_down[first:first + held],
+                top_k=top_k, dtype=jnp.float32, first_expert=first,
+                renormalize=True)
             return jnp.sum(y * weigh), (y, aux)
         return f
+
+    def once(h):
+        return share_reference.shared_expert(h, shared) if has_shared \
+            else jnp.zeros_like(h)
 
     args = (h, router, w_gate, w_up, w_down)
     (_, (y_ref, lb_ref, counts_ref)), g_ref = jax.value_and_grad(
         whole, argnums=range(5), has_aux=True)(*args)
-    assert_rows_held(counts_ref, routing, first=4)
-    once = share_reference.shared_expert(h, shared)
-    total, grads = once, None
-    for first in SHARES:
+    assert_rows_held(counts_ref, routing, first=4, held=held)
+    total, grads = once(h), None
+    for first in range(0, E, held):
         (_, (y, aux)), g = jax.value_and_grad(
             share(first), argnums=range(5), has_aux=True)(*args)
         # The router's terms are the whole router's on every share.
@@ -594,8 +605,7 @@ def test_the_shares_add_up_to_the_uncut_layer(small_tile, routing):
         grads = g if grads is None else jax.tree.map(jnp.add, grads, g)
     np.testing.assert_allclose(total, y_ref, rtol=1e-5, atol=1e-5)
     # The uncut loss's gradient has the shared expert's part too: through h.
-    g_once = jax.grad(lambda h: jnp.sum(
-        share_reference.shared_expert(h, shared) * weigh))(h)
+    g_once = jax.grad(lambda h: jnp.sum(once(h) * weigh))(h)
     for name, got, want in zip(
             ("h", "W_r", "W_gate", "W_up", "W_down"),
             (grads[0] + g_once,) + tuple(grads[1:]), g_ref):

@@ -318,6 +318,73 @@ def test_window_step_holds_its_flash_kernels_under_a_scope_of_their_own(
         assert scope in text, scope
 
 
+def test_block_diffusion_step_holds_its_flash_kernels_under_attn_bd(spmd4):
+    """Under ``diffusion_block`` every attention mixer lies under
+    ``layer<i>/attn_bd`` (a reader tells these flash kernels from a causal
+    layer's by it), forward and backward, the same two kernel names; and
+    the counters say what the mask's grids walk and how full their tiles
+    are: where ``flash_bd_tiles_kept_pct`` and ``flash_bd_tile_fill_pct``
+    look. The head multiplies the noised half's rows alone."""
+    half, block = S // 2, 4
+    cfg = gpt.GPTConfig(remat="full", diffusion_block=block, **CFG)
+    opt = hvd.DistributedOptimizer(optax.adamw(1e-3))
+
+    def _train_step(params, opt_state, data):
+        tokens, targets, positions, weights = data
+        loss, grads = jax.value_and_grad(lambda p: gpt.loss_fn(
+            p, tokens, targets, positions, cfg, -1, weights,
+            targets.size))(params)
+        updates, opt_state = opt.update(grads, opt_state, params)
+        return (optax.apply_updates(params, updates), opt_state,
+                hvd.allreduce(loss, op=hvd.Average))
+
+    step = hvd.run_step(
+        _train_step,
+        in_specs=(hvd.REPLICATED, hvd.REPLICATED, hvd.batch_spec(0)),
+        out_specs=hvd.REPLICATED)
+    params = hvd.replicate(gpt.init_params(jax.random.PRNGKey(0), cfg))
+    rng = np.random.default_rng(0)
+    tokens = rng.integers(0, cfg.vocab_size, (B, S), dtype=np.int32)
+    positions = np.tile(np.arange(half, dtype=np.int32), (B, 2))
+    data = hvd.shard_batch((
+        tokens, tokens[:, half:].copy(), positions,
+        rng.uniform(1, 10, (B, half)).astype(np.float32)))
+    text = step.lower(params, hvd.replicate(opt.init(params)),
+                      data).as_text(debug_info=True)
+    scopes = set(re.findall(r'loc\("([^"]*)/hvd_flash_(fwd|dkdv|dq)/', text))
+    assert {kernel for _, kernel in scopes} == {"fwd", "dkdv"}
+    assert all(scope.endswith("/attn_bd") for scope, _ in scopes), scopes
+    assert {re.search(r"layer\d", scope).group(0)
+            for scope, _ in scopes} == {"layer0", "layer1"}
+
+    def samples(family, **want):
+        return {tuple(sorted((k, v) for k, v in labels.items()
+                             if k not in want)): count
+                for _, labels, count in hvd.metrics()[family]["samples"]
+                if all(labels[k] == v for k, v in want.items())}
+
+    # One tile of 128 x 128 holds a head's whole 2 L x 2 L rectangle here;
+    # a kernel is traced once or twice a shape (the forward under the rule
+    # and under its recomputed copy), each trace one rectangle.
+    tiles = samples("hvdtpu_spmd_flash_tiles_total", mask="block_diffusion")
+    pairs = samples("hvdtpu_spmd_flash_pairs_total", mask="block_diffusion")
+    for kernel in (fa.KERNEL_FWD, fa.KERNEL_DKDV):
+        at = (("kernel", kernel), ("seq", str(S)))
+        traces = tiles.pop((*at, ("tiles", "kept")))
+        assert traces in (1.0, 2.0)
+        assert tiles.pop((*at, ("tiles", "skipped_block_diffusion"))) == 0.0
+        assert pairs.pop((at[0], ("pairs", "computed"), at[1])) \
+            == traces * S * S
+        assert pairs.pop((at[0], ("pairs", "kept"), at[1])) \
+            == traces * half * (half + block)
+    assert not tiles and not pairs
+    assert not samples("hvdtpu_spmd_flash_tiles_total", mask="causal")
+    # B / 4 sequences a rank, half of their rows each.
+    assert samples("hvdtpu_spmd_head_loss_traces_total") == {
+        (("blocks", "1"), ("rows_per_block", str(B // 4 * half)),
+         ("tied", "false"), ("vocab", "64")): 1.0}
+
+
 # A CCA mixer and an expert sublayer under an MLP router in both layers,
 # each sublayer joined under the residual scaling.
 CCA = dict(layers=(gpt.LayerSpec(mixer="cca", ff="experts"),) * 2,
