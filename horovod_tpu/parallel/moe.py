@@ -136,19 +136,38 @@ ZAYA1's share 3 x 67 MB); each pays the unfused ``reduce_precision`` pass
 ``jax.checkpoint`` puts on a kept value a Mosaic kernel makes or reads
 (PERF.md, Findings, PR 56), and the gain is net of it (``tok_s_chip`` +6.6%
 in ``olmoe-1b-7b_s4096``, +4.7% in ``zaya1-8b_s4096``: ledger, PR 58 and PR
-59; PERF.md, Findings, PR 58-59). **A share's windows name nothing**: their
-rows and products live inside :func:`_held_experts`' rule, whose backward
-pass makes each window again by design (the window at 0's residuals handed
-out is ROADMAP Speed 3's open part).
+59; PERF.md, Findings, PR 58-59).
+
+**A share's window at 0 is made once a step too** (PR 66). The window at 0,
+the only one a routing within twice of even takes, hands what its forward
+pass made to :func:`_held_experts`' backward rule as residuals under the same
+two names: its gathered and masked rows ``[R, l]`` and its gate and up
+products before the activation ``[R, m]`` each, named inside the rule's
+forward and not on a value the forward pass reads afterwards (the
+convention of ``ops/kda.py::_scan_forward``: no ``reduce_precision`` pass
+lands on the forward's own path). The backward rule of that window applies
+the activation again and goes on from there (:func:`_kept_window_bwd`): no
+gather of the rows and no gate or up product a second time, **9 grouped
+calls for the window at 0 where the rule held 11**. They lie in the sort's
+order, whose first ``R`` entries the window reads in both passes from the
+kept ``moe_order``. Bytes a layer in bfloat16, ``R (2 l + 4 m)``: Qwen 126 MB,
+SDAR 235, Moonlight 239, Trinity 268, SmallThinker 403 (Nemotron's latent
+and Ling's share under 50). **The loop's windows name nothing**: a routing
+that sends a share more than ``R`` rows takes them, how many is known on the
+chip alone, and the backward rule makes each of them again from the rule's
+inputs by design; nothing of theirs outlives them. Outside ``jax.checkpoint``
+the names are inert and the residuals live from the forward pass to the
+backward, as autodiff's own would.
 
 What the layer still makes again for its backward pass, un-windowed: the
 activation alone (a pass over ``[T k, m]``), **so long as the caller's
 backward pass has no use for the layer's output**. The down
 projection and the weighted sum have a backward pass of their own
 (:func:`_down_and_combine`) that needs no expert's output, so their
-recomputation is dead code (a share's windows make theirs again inside the
-backward rule, from the kept order, and are dead code in the recomputed copy
-altogether) where the caller only adds ``y`` to its stream.
+recomputation is dead code (a share's window at 0 applies its activation
+again inside the backward rule, the loop's windows their whole forward, and
+all of them are dead code in the recomputed copy altogether) where the
+caller only adds ``y`` to its stream.
 A caller that norms ``y`` or scales it by a parameter reads ``y`` in its own
 backward pass, and then the recomputed copy runs the layer to its end, the
 down product, the sum back to tokens and every window of a share, unless
@@ -207,7 +226,12 @@ UNGATED = ("relu2",)
 # ``[T k, m]`` (2 l + 4 m bytes a pair in bfloat16: 537 MB in OLMoE's one
 # layer, 201 MB in each of ZAYA1's six; a checkpointed layer then holds 9
 # grouped calls for 11; ``tok_s_chip`` +6.6% and +4.7%: ledger, PR 58 and PR
-# 59). The activation stays recomputed, and so does every window of a share.
+# 59). A share's window at 0 keeps the same two of its ``R`` rows, as the
+# residuals of ``_held_experts``' rule (PR 66: ``R (2 l + 4 m)`` bytes a
+# layer, Qwen 126 MB, SDAR 235, Moonlight 239, Trinity 268, SmallThinker 403;
+# its backward rule then holds 9 grouped calls for 11). The activation stays
+# recomputed, and so does every window of the loop, which no even routing
+# runs.
 SAVED_NAMES = ("moe_expert_matrices", "moe_router_logits", "moe_top_experts",
                "moe_top_weights", "moe_order", "moe_order_inverse",
                "moe_rows", "moe_pre_activation")
@@ -292,16 +316,31 @@ def _grouped(lhs, w, group_sizes, mine):
 def _hidden(activation, rows, w_gate, w_up, group_sizes, mine, keep=False):
     """The experts' hidden rows by groups (:func:`expert_hidden`):
     ``act(rows W_gate) * (rows W_up)``, or ``act(rows W_up)`` in an un-gated
-    form, whose ``w_gate`` is None. ``keep``: the products carry the name
-    ``"moe_pre_activation"`` (the layer's all-rows branch; a window's live
-    inside :func:`_held_experts`' rule, which makes them again by design)."""
-    matrices = {"w_gate": w_gate, "w_up": w_up}
+    form, whose ``w_gate`` is None; and the products it was made of, before
+    the activation, by their matrix's name. ``keep``: the products carry the
+    name ``"moe_pre_activation"`` (the layer's all-rows branch; a window at
+    0's are named as :func:`_held_experts`' residuals)."""
+    matrices, products = {"w_gate": w_gate, "w_up": w_up}, {}
 
     def product(name):
         out = _grouped(rows, matrices[name], group_sizes, mine)
-        return checkpoint_name(out, "moe_pre_activation") if keep else out
+        products[name] = checkpoint_name(out, "moe_pre_activation") if keep \
+            else out
+        return products[name]
 
-    return expert_hidden(activation, product)
+    return expert_hidden(activation, product), products
+
+
+def _grouped_bwd(lhs, w, group_sizes, mine, g):
+    """The cotangents of :func:`_grouped`'s ``lhs`` and ``w`` for its
+    output's, both by the grouped matmul's own transpose rules."""
+    if mine is not None:
+        g = jnp.where(mine, g, 0)
+    d_lhs, = jax.linear_transpose(
+        lambda t: lax.ragged_dot(t, w, group_sizes), lhs)(g)
+    d_w, = jax.linear_transpose(
+        lambda t: lax.ragged_dot(lhs, t, group_sizes), w)(g)
+    return d_lhs, d_w
 
 
 def _down_products_bwd(hidden, w_down, p_rows, g_rows, group_sizes, mine):
@@ -446,32 +485,83 @@ _down_and_combine_window.defvjp(_down_and_combine_window_fwd,
                                 _down_and_combine_window_bwd)
 
 
+def _window_rows(window_rows, lo, order, group_sizes):
+    """Rows ``lo`` to ``lo + window_rows`` of the sort's order: their pairs
+    ``[R]``, how many of them each held expert has ``[experts_local]``, and
+    which are a held expert's at all ``[R, 1]``."""
+    pair_of_row = lax.dynamic_slice(order, (lo,), (window_rows,))
+    ends = jnp.cumsum(group_sizes)
+    sizes = jnp.clip(jnp.minimum(ends, lo + window_rows)
+                     - jnp.maximum(ends - group_sizes, lo), 0)
+    mine = (lo + jnp.arange(window_rows) < ends[-1])[:, None]
+    return pair_of_row, sizes, mine
+
+
 @functools.partial(jax.jit, static_argnums=(0, 1))
 def _window(window_rows, activation, lo, xt, w_gate, w_up, w_down, top_p,
             order, group_sizes):
     """What rows ``lo`` to ``lo + window_rows`` of the sort's order add to a
     share's partial sum, ``[T, d]`` float32: the tokens' rows gathered, the
     held experts applied to those of their rows that lie in the window, the
-    rows summed back to their tokens under their weights. (Jitted so that
-    JAX traces it once for the window at 0 and the loop's: a layer's second
-    trace of it cost the cell's step 0.3 s of lowering.)"""
+    rows summed back to their tokens under their weights; and, for the
+    window at 0's backward rule, what is dear to make again: the gathered
+    and masked rows ``[R, l]`` and the gate and up products before the
+    activation ``[R, m]`` by their matrix's name, which a loop's window
+    drops. (Jitted so that JAX traces it once for the window at 0 and
+    the loop's: a layer's second trace of it cost the cell's step 0.3 s of
+    lowering.)"""
     with jax.named_scope("dispatch"):
-        pair_of_row = lax.dynamic_slice(order, (lo,), (window_rows,))
-        ends = jnp.cumsum(group_sizes)
-        sizes = jnp.clip(jnp.minimum(ends, lo + window_rows)
-                         - jnp.maximum(ends - group_sizes, lo), 0)
-        mine = (lo + jnp.arange(window_rows) < ends[-1])[:, None]
+        pair_of_row, sizes, mine = _window_rows(window_rows, lo, order,
+                                                group_sizes)
         rows = _take(xt, pair_of_row, top_p.shape[1])                # [R, d]
     with jax.named_scope("experts"):
         rows = jnp.where(mine, rows, 0)
-        hidden = _hidden(activation, rows, w_gate, w_up, sizes, mine)
+        hidden, products = _hidden(activation, rows, w_gate, w_up, sizes,
+                                   mine)
     return _down_and_combine_window(hidden, w_down, top_p, pair_of_row,
-                                    sizes, mine)
+                                    sizes, mine), (rows, products)
 
 
-def _windows(window_rows, group_sizes, window):
-    """``window(lo)`` for the window at 0 and, while held rows lie beyond the
-    windows taken, for the next one, added up: one window for a routing
+@functools.partial(jax.jit, static_argnums=(0, 1))
+def _kept_window_bwd(window_rows, activation, lo, args, kept, g):
+    """The cotangents of :func:`_window`'s ``xt``, ``w_gate``, ``w_up``,
+    ``w_down`` and ``top_p`` for its sum's cotangent ``g``, from the rows
+    and products its forward pass made (``kept``): the activation applied
+    again, then the down product's rule, the gate and up products'
+    transposes and :func:`_take`'s rule. No gather of the rows and no gate
+    or up product. (Jitted as :func:`_window` is, for one trace a shape and
+    because XLA's grouped-matmul kernels keep the program's ``op_name`` only
+    inside a called function: bare, a device trace reads their 38 ms a step
+    of Moonlight's as unscoped and not as backward; my chip run, PR 66.)"""
+    xt, w_gate, w_up, w_down, top_p, order, group_sizes = args
+    rows, products = kept
+    with jax.named_scope("dispatch"):
+        pair_of_row, sizes, mine = _window_rows(window_rows, lo, order,
+                                                group_sizes)
+    with jax.named_scope("experts"):
+        hidden, hidden_bwd = jax.vjp(
+            lambda made: expert_hidden(activation, made.__getitem__),
+            products)
+    d_hidden, d_w_down, d_top_p, *_ = _down_and_combine_window_bwd(
+        (hidden, w_down, top_p, pair_of_row, sizes, mine), g)
+    with jax.named_scope("experts"):
+        matrices = {"w_gate": w_gate, "w_up": w_up}
+        d_w, d_rows = dict.fromkeys(matrices), []
+        for name, d in hidden_bwd(d_hidden)[0].items():
+            d_lhs, d_w[name] = _grouped_bwd(rows, matrices[name], sizes, mine,
+                                            d)
+            d_rows.append(d_lhs)
+        d_rows = jnp.where(mine, functools.reduce(jnp.add, d_rows), 0)
+    with jax.named_scope("dispatch"):
+        d_xt = _to_tokens(d_rows, pair_of_row, xt.shape[0],
+                          top_p.shape[1]).astype(xt.dtype)
+    return d_xt, d_w["w_gate"], d_w["w_up"], d_w_down, d_top_p
+
+
+def _windows(window_rows, group_sizes, first, window):
+    """``first(0)``'s sum for the window at 0 and, while held rows lie
+    beyond the windows taken, ``window(lo)`` for the next one, added up,
+    beside whatever else ``first`` hands back: one window for a routing
     whose held rows number ``window_rows`` at most, the loop's body never
     run; ``ceil(held rows / window_rows)`` windows for any other. The whole
     is under the scope ``windows`` and each window, the one at 0 and the
@@ -479,21 +569,23 @@ def _windows(window_rows, group_sizes, window):
     by them (``benchmarks/layer_metrics/moe_windows_per_step.py``)."""
     held = jnp.sum(group_sizes)
 
-    def scoped(lo):
+    def scoped(one, lo):
         with jax.named_scope("window"):
-            return window(lo)
+            return one(lo)
 
     def more(carry):
         return carry[0] < held
 
     def next_window(carry):
         lo, total = carry
-        return lo + window_rows, jax.tree.map(jnp.add, total, scoped(lo))
+        return lo + window_rows, jax.tree.map(jnp.add, total,
+                                              scoped(window, lo))
 
     with jax.named_scope("windows"):
-        return lax.while_loop(more, next_window,
-                              (jnp.asarray(window_rows, held.dtype),
-                               scoped(jnp.zeros((), held.dtype))))[1]
+        total, rest = scoped(first, jnp.zeros((), held.dtype))
+        return lax.while_loop(
+            more, next_window,
+            (jnp.asarray(window_rows, held.dtype), total))[1], rest
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
@@ -506,29 +598,48 @@ def _held_experts(window_rows, activation, xt, w_gate, w_up, w_down, top_p,
     than ``window_rows`` rows.
 
     How many windows is known on the chip alone, so the loop is a
-    ``lax.while_loop`` in the forward and in the backward rule alike, and
-    the backward rule makes each window's forward again from the rule's
-    inputs: nothing a window makes outlives it. Windows after the first add
-    their cotangents in the cotangents' own dtypes."""
+    ``lax.while_loop`` in the forward and in the backward rule alike. **The
+    window at 0, the only one an even routing takes, is made once a step**:
+    its gathered rows and its gate and up products are the rule's residuals
+    under the names ``"moe_rows"`` and ``"moe_pre_activation"``, which a
+    checkpointed block keeps (:data:`SAVED_NAMES`), and its backward pass
+    reads them (:func:`_kept_window_bwd`). The loop's windows make their
+    forward again from the rule's inputs: nothing of theirs outlives them.
+    Windows after the first add their cotangents in the cotangents' own
+    dtypes."""
     return _held_experts_fwd(window_rows, activation, xt, w_gate, w_up,
                              w_down, top_p, order, group_sizes)[0]
 
 
 def _held_experts_fwd(window_rows, activation, *args):
-    group_sizes = args[-1]
-    y = _windows(window_rows, group_sizes,
-                 lambda lo: _window(window_rows, activation, lo, *args))
-    return y, args
+    def first(lo):
+        # Named as residuals only, here and not inside the jitted window:
+        # the forward pass reads no kept value, and a checkpoint's policy
+        # meets the names in the block's own jaxpr.
+        y, (rows, products) = _window(window_rows, activation, lo, *args)
+        return y, (checkpoint_name(rows, "moe_rows"),
+                   {name: checkpoint_name(made, "moe_pre_activation")
+                    for name, made in products.items()})
+
+    y, kept = _windows(
+        window_rows, args[-1], first,
+        lambda lo: _window(window_rows, activation, lo, *args)[0])
+    return y, (args, kept)
 
 
-def _held_experts_bwd(window_rows, activation, args, g):
+def _held_experts_bwd(window_rows, activation, residuals, g):
+    args, kept = residuals
     *wrt, order, group_sizes = args
 
     def cotangents(lo):
         return jax.vjp(lambda *a: _window(window_rows, activation, lo, *a,
-                                          order, group_sizes), *wrt)[1](g)
+                                          order, group_sizes)[0], *wrt)[1](g)
 
-    return (*_windows(window_rows, group_sizes, cotangents), None, None)
+    return (*_windows(
+        window_rows, group_sizes,
+        lambda lo: (_kept_window_bwd(window_rows, activation, lo, args,
+                                     kept, g), None),
+        cotangents)[0], None, None)
 
 
 _held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
@@ -729,8 +840,8 @@ def moe_layer(x, router_w, w_gate, w_up, w_down, top_k: int,
                 rows = jnp.where(mine, rows, 0)
             # In the sort's order, which is kept with them (``SAVED_NAMES``).
             rows = checkpoint_name(rows, "moe_rows")
-            hidden = _hidden(activation, rows, w_gate, w_up, group_sizes,
-                             mine, keep=True)
+            hidden, _ = _hidden(activation, rows, w_gate, w_up, group_sizes,
+                                mine, keep=True)
     if _axis_bound(tp_axis):
         # Each tp rank's share of the weights' gradient is a sum over its
         # part of the width; autodiff adds them where this cast is.

@@ -275,8 +275,8 @@ def test_full_remat_keeps_the_gdn_scans_output(make_runtime):
     tokens, pairs = 3 * 48, 3 * 48 * cfg.experts_per_token
     # What fixes the routing beside them; a share's windows need no inverse
     # of the order (whose indices are 8 bytes under this suite's x64).
-    windowed = moe_module.share_rows(tokens, cfg.experts_per_token,
-                                     cfg.experts_held, cfg.num_experts) < pairs
+    window = moe_module.share_rows(tokens, cfg.experts_per_token,
+                                   cfg.experts_held, cfg.num_experts)
     index = jnp.argsort(jnp.zeros(1)).dtype.itemsize
     # What ``hvd_gdn_fwd`` writes, a chunk a turn ``[c, B, Hv, Q, .]``:
     # ``u_own`` on the value lanes, ``w``, ``q G`` and ``k G_last / G`` on
@@ -294,10 +294,10 @@ def test_full_remat_keeps_the_gdn_scans_output(make_runtime):
                     # The shared expert's gate and up products (PR 59).
                     "moe_shared_pre_activation":
                         2 * tokens * cfg.shared_expert_dim * 4,
-                    # A layer that works on all its rows at once keeps the
-                    # order's inverse, the sorted rows and their gate and
-                    # up products too.
-                    **({} if windowed else
-                       {"moe_order_inverse": pairs * index,
-                        "moe_rows": pairs * cfg.embed_dim * 4,
-                        "moe_pre_activation": 2 * pairs * cfg.mlp_dim * 4})}
+                    # The sorted rows and their gate and up products: all
+                    # of them where the layer works on all its rows at once,
+                    # with the order's inverse, else the window at 0's.
+                    "moe_rows": window * cfg.embed_dim * 4,
+                    "moe_pre_activation": 2 * window * cfg.mlp_dim * 4,
+                    **({"moe_order_inverse": pairs * index}
+                       if window == pairs else {})}

@@ -91,7 +91,8 @@ def test_a_bound_ep_axis_keeps_the_groups_routing(make_runtime, moe_row_tile,
     chosen experts and scores and the sort's order of the group's 32 tokens
     (each rank routes all of them), with the inverse, the sorted rows and
     their gate and up products (PR 59) where a rank works on all the rows
-    at once, and the part the backward pass makes again holds no sort, no
+    at once, the window at 0's rows and products (PR 66) where it works a
+    window at a time, and the part the backward pass makes again holds no sort, no
     top-k and no router's product."""
     moe_row_tile(tile)
     make_runtime(mesh_shape={"ep": 4}, devices=jax.devices()[:4])
@@ -127,20 +128,30 @@ def test_a_bound_ep_axis_keeps_the_groups_routing(make_runtime, moe_row_tile,
             "moe_top_weights": tokens * top_k * 4,
             "moe_order": tokens * top_k * index,
             "moe_expert_matrices": 3 * (n_exp // 4) * d * m * 4}
+    rows = moe.share_rows(tokens, top_k, n_exp // 4, n_exp)
+    assert rows == (tokens * top_k if tile == 512 else 32)
+    want.update(moe_rows=rows * d * 4, moe_pre_activation=2 * rows * m * 4)
     if tile == 512:
-        want.update(moe_order_inverse=tokens * top_k * index,
-                    moe_rows=tokens * top_k * d * 4,
-                    moe_pre_activation=2 * tokens * top_k * m * 4)
+        want.update(moe_order_inverse=tokens * top_k * index)
     assert kept == want
 
 
 @pytest.mark.parametrize("remat", ["none", "full"])
-def test_moe_layer_tensor_parallel_expert_width(make_runtime, remat):
-    """ep=2 x tp=2: the experts over ep, their width over tp. The output and
-    every gradient are the local layer's: the routing weights' is a sum over
-    the whole width, which each tp rank holds a part of."""
-    make_runtime(mesh_shape={"ep": 2, "tp": 2}, devices=jax.devices()[:4])
-    d, m, n_exp = 16, 32, 4
+@pytest.mark.parametrize("ep, tile", [(2, 512), (4, 8)],
+                         ids=["all_rows", "windowed"])
+def test_moe_layer_tensor_parallel_expert_width(make_runtime, moe_row_tile,
+                                                remat, ep, tile):
+    """ep x tp=2: the experts over ep, their width over tp, a rank working
+    on all the rows at once (ep=2) or a window of 32 of the 64 at a time
+    (ep=4; the window at 0's backward rule reads its kept rows and products,
+    PR 66). The output and every gradient are the local layer's: the routing
+    weights' is a sum over the whole width, which each tp rank holds a part
+    of, and so is the tokens' through a window's rows."""
+    moe_row_tile(tile)
+    make_runtime(mesh_shape={"ep": ep, "tp": 2},
+                 devices=jax.devices()[:2 * ep])
+    d, m, n_exp = 16, 32, 2 * ep
+    assert (moe.share_rows(32, 2, 2, n_exp) < 64) == (ep == 4)
     ks = jax.random.split(jax.random.PRNGKey(1), 5)
     x = jax.random.normal(ks[0], (4, 8, d), jnp.float32)
     router = jax.random.normal(ks[1], (d, n_exp), jnp.float32)

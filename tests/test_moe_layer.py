@@ -227,19 +227,23 @@ def test_sparse_decoder_flash_and_remat_change_nothing(change):
                                    atol=5e-5 * float(jnp.abs(b).max()))
 
 
-def ops_of(jaxpr, out=None):
-    """Equations by primitive in a jaxpr and every jaxpr under it; a Pallas
-    kernel under its own name, a matmul under its output's shape too."""
+def ops_of(jaxpr, out=None, outside=()):
+    """Equations by primitive in a jaxpr and every jaxpr under it (but those
+    of the primitives ``outside``, which are not counted either); a Pallas
+    kernel under its own name, a matmul or a gather under its output's shape
+    too."""
     out = collections.Counter() if out is None else out
     for eqn in jaxpr.eqns:
+        if eqn.primitive.name in outside:
+            continue
         if eqn.primitive.name == "pallas_call":
             out[eqn.params["name"]] += 1
             continue
         out[eqn.primitive.name] += 1
-        if eqn.primitive.name == "dot_general":
-            out["dot_general", eqn.outvars[0].aval.shape] += 1
+        if eqn.primitive.name in ("dot_general", "gather"):
+            out[eqn.primitive.name, eqn.outvars[0].aval.shape] += 1
         for sub in jax.core.jaxprs_in_params(eqn.params):
-            ops_of(sub, out)
+            ops_of(sub, out, outside)
     return out
 
 
@@ -1274,7 +1278,8 @@ ROUTING_CASES = [(rows, router) for rows in ("all_rows", "windowed")
 ROUTED_WIDTH = 20           # no tensor's other extent: a product's shape tells
 
 
-def routed_once(rows, router, small_tile_of, activation="silu"):
+def routed_once(rows, router, small_tile_of, activation="silu",
+                reads_output=True):
     """``(f, args, tie, leaning)``: a layer of 16 experts at 3 a token on 48 tokens
     of 20, every expert held or experts 4 to 8 with a window of half their
     even share's twice (72 of 144 rows; ``"share_all_rows"``: the same share
@@ -1326,8 +1331,9 @@ def routed_once(rows, router, small_tile_of, activation="silu"):
                     precision=jax.lax.Precision.HIGHEST))
         else:
             y, aux = moe_layer(x, router_w, *w, **how)
-        return jnp.sum(y * jnp.cos(y)) + aux["load_balance"] \
-            + aux["router_z"]
+        by = jnp.cos(y) if reads_output else jnp.cos(
+            jnp.arange(y.size, dtype=y.dtype).reshape(y.shape))
+        return jnp.sum(y * by) + aux["load_balance"] + aux["router_z"]
 
     def leaning(x):
         r = x @ router_w
@@ -1446,17 +1452,22 @@ def assert_turned_ties_change_no_gradient(f, args, tie, leaning):
             err_msg=name)
 
 
-# The expert layer's first products are made once a step (PR 59). Where the
-# layer works on all its rows at once, a checkpointed block keeps the sorted
-# rows and the gate and up products before the activation (``moe_rows``,
-# ``moe_pre_activation``), in the sort's order, which is kept with them; a
-# share's windows name nothing new (their rule makes each window again).
+# The expert layer's first products are made once a step (PR 59; a share's
+# window at 0 since PR 66). A checkpointed block keeps the sorted rows and the
+# gate and up products before the activation (``moe_rows``,
+# ``moe_pre_activation``), in the sort's order, which is kept with them: all
+# the rows of a layer that works on them at once, the window at 0's of a
+# share (the residuals of ``moe._held_experts``' rule). The loop's windows,
+# which no even routing runs, name nothing: their rule makes each again.
 
-KEPT_ROWS_CASES = [("all_rows", router, "silu")
+KEPT_ROWS_CASES = [(rows, router, "silu")
+                   for rows in ("all_rows", "windowed")
                    for router in ("softmax", "sigmoid_bias", "callers_logits")
                    ] + [("share_all_rows", "softmax", "silu"),
-                        ("all_rows", "softmax", "relu2")]
+                        ("all_rows", "softmax", "relu2"),
+                        ("windowed", "softmax", "relu2")]
 ROWS_NAMES = {"moe_rows", "moe_pre_activation"}
+WINDOW = 72                 # ``routed_once``'s, where it is windowed
 
 
 def names_in(equations):
@@ -1464,17 +1475,27 @@ def names_in(equations):
             if eqn.primitive.name == "name"}
 
 
+def first_window_work(jaxpr):
+    """``(grouped matmuls, gathers of a window's rows: the tokens' rows, the
+    output's cotangent's)`` of a jaxpr outside its loops: of a windowed layer
+    the window at 0's, of any other all it has."""
+    ops = ops_of(jaxpr, outside=("while",))
+    return ops["ragged_dot_general"], ops["gather", (WINDOW, ROUTED_WIDTH)]
+
+
 @pytest.mark.parametrize("rows, router, activation", KEPT_ROWS_CASES)
 def test_a_checkpointed_layer_makes_its_first_products_once(
         moe_row_tile, equations_of, rows, router, activation):
     """The gradient's jaxpr under ``remat="full"``'s policy holds the grouped
     matmuls of a layer that is not checkpointed, 9 (6 un-gated: three, or
-    two, forward and twice that backward), and the down product again for
-    this loss alone, which reads the layer's output in its backward pass
-    (a block that keeps its branch has not even that:
-    ``test_full_remat_keeps_what_is_dear_to_make_again``); the rows and
-    products carry the two names. With nothing named the gate and up
-    products are made again too."""
+    two, forward and twice that backward; of a windowed layer those of the
+    window at 0), and for this loss alone, which reads the layer's output in
+    its backward pass, what makes the output again: the down product, from
+    the kept products, where the layer works on all its rows at once, and
+    the whole window where it does not (a block that keeps its branch has
+    not even that: ``test_full_remat_keeps_what_is_dear_to_make_again``);
+    the rows and products carry the two names. With nothing named the gate
+    and up products are made again too, all rows at once."""
     f, args, _, _ = routed_once(rows, router, moe_row_tile, activation)
     first = 1 if activation in moe.UNGATED else 2
     wrt = tuple(range(len(args)))
@@ -1483,14 +1504,16 @@ def test_a_checkpointed_layer_makes_its_first_products_once(
         return jax.make_jaxpr(jax.grad(g, argnums=wrt))(*args).jaxpr
 
     def grouped_calls(g):
-        return ops_of(step(g))["ragged_dot_general"]
+        return first_window_work(step(g))[0]
 
     assert grouped_calls(f) == 3 * (first + 1)
     kept = as_a_block_runs_it(lambda *a: f(*a), "full")
     assert ROWS_NAMES <= names_in(equations_of(step(kept)))
-    assert grouped_calls(kept) == 3 * (first + 1) + 1
-    assert grouped_calls(jax.checkpoint(lambda *a: f(*a))) \
-        == 3 * (first + 1) + 1 + first
+    again = first + 1 if rows == "windowed" else 1
+    assert grouped_calls(kept) == 3 * (first + 1) + again
+    if rows != "windowed":
+        assert grouped_calls(jax.checkpoint(lambda *a: f(*a))) \
+            == 3 * (first + 1) + 1 + first
 
 
 @pytest.mark.parametrize("rows, router, activation", KEPT_ROWS_CASES)
@@ -1515,19 +1538,42 @@ def test_kept_rows_are_read_in_the_order_they_were_written(
         *routed_once(rows, router, moe_row_tile, activation))
 
 
-@pytest.mark.parametrize("router", ["softmax", "sigmoid_bias",
-                                    "callers_logits"])
-def test_a_shares_windows_name_nothing_in_the_sorts_order(
-        moe_row_tile, equations_of, router):
-    """A share that works a window at a time keeps what it kept before
-    PR 59: its rows and products live inside ``moe._held_experts``' rule,
-    whose backward pass makes each window again by design."""
-    f, args, _, _ = routed_once("windowed", router, moe_row_tile)
-    kept = jax.make_jaxpr(jax.grad(as_a_block_runs_it(
-        lambda *a: f(*a), "full"), argnums=tuple(range(5))))(*args).jaxpr
+@pytest.mark.parametrize("router, activation", [
+    ("softmax", "silu"), ("sigmoid_bias", "silu"), ("callers_logits", "silu"),
+    ("softmax", "relu2")])
+def test_a_shares_window_at_0_is_made_once(moe_row_tile, equations_of,
+                                           router, activation):
+    """A share that works a window at a time, in a block that only adds its
+    output to its stream: under ``remat="full"``'s policy the gradient's
+    jaxpr names the window at 0's rows and products beside what fixes the
+    routing, and outside the loop it holds the grouped matmuls of a layer
+    that is not checkpointed (9, 6 un-gated) and two gathers of a window's
+    rows, the tokens' in the forward pass and the output's cotangent's in
+    the backward pass: no rows gathered and no gate or up product made again
+    (with nothing named: a third gather and the products again). The loop's
+    body, which no even routing runs, still makes its own forward again."""
+    f, args, _, _ = routed_once("windowed", router, moe_row_tile, activation,
+                                reads_output=False)
+    first = 1 if activation in moe.UNGATED else 2
+    wrt = tuple(range(len(args)))
+
+    def step(g):
+        return jax.make_jaxpr(jax.grad(g, argnums=wrt))(*args).jaxpr
+
+    plain = 3 * (first + 1), 2
+    assert first_window_work(step(f)) == plain
+    kept = step(as_a_block_runs_it(lambda *a: f(*a), "full"))
     assert names_in(equations_of(kept)) == {
         "moe_expert_matrices", "moe_router_logits", "moe_top_experts",
-        "moe_top_weights", "moe_order"}
+        "moe_top_weights", "moe_order"} | ROWS_NAMES
+    assert first_window_work(kept) == plain
+    assert first_window_work(step(jax.checkpoint(lambda *a: f(*a)))) == (
+        3 * (first + 1) + first, 3)
+    loops = [eqn for eqn, _ in equations_of(kept)
+             if eqn.primitive.name == "while"]
+    assert len(loops) == 2
+    again = ops_of(loops[1].params["body_jaxpr"].jaxpr)["ragged_dot_general"]
+    assert again >= 2 * (first + 1) + first
 
 
 # --- Un-gated squared-ReLU experts in a latent narrower than the stream ----

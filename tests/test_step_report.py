@@ -184,9 +184,10 @@ def test_bare_kernels_are_placed_by_the_rows_they_read():
 
 
 def test_a_windowed_layers_kernels_are_placed_by_their_own_names():
-    """One layer of Moonlight's: the window at 0 and the loop's body, in the
-    forward rule and in the backward rule, which makes each window's forward
-    again (``parallel/moe.py::_held_experts_bwd``)."""
+    """One layer of Moonlight's (a text recorded before PR 66 kept the
+    window at 0's first products): the window at 0 and the loop's body, in
+    the forward rule and in the backward rule, which made each window's
+    forward again (``parallel/moe.py::_held_experts_bwd``)."""
     calls, _ = calls_of("moonlight_layer")
     assert {c["placed_by"] for c in calls.values()} == {"own"}
     for loop in (False, True):
@@ -197,6 +198,30 @@ def test_a_windowed_layers_kernels_are_placed_by_their_own_names():
     assert {c["scope"] for c in calls.values()} == {
         "layer1/moe/windows/window/_window",
         "layer1/moe/windows/while/body/window/_window"}
+
+
+def test_a_kept_window_at_0s_backward_kernels_carry_their_rules_name():
+    """One layer of Moonlight's since PR 66 (``scripts/aot_step.py
+    moonlight-16b-a3b_s8192 --hlo``, cut to layer 1's grouped matmuls): the
+    backward rule's window at 0 is no ``_window`` made again but the jitted
+    ``_kept_window_bwd``, whose name its kernels carry as the windows' carry
+    ``_window``'s, six grouped matmuls and two layouts of the groups, all
+    backward and none made again; the loop's body keeps its gate and up
+    products' second run; nothing is placed by what it reads or left
+    unplaced."""
+    calls, report = calls_of("moonlight_layer_kept")
+    assert report["kernels"] == {"ragged-dot-none": 20,
+                                 "ragged-dot-metadata": 6}
+    assert {c["placed_by"] for c in calls.values()} == {"own"}
+    assert passes(calls, loop=False) == {"forward": 3, "backward": 6}
+    assert passes(calls, loop=True) == {
+        "forward": 3, "recomputation": 2, "backward": 6}
+    for loop in (False, True):
+        assert passes(calls, "ragged-dot-metadata", loop=loop) == {
+            "forward": 1, "backward": 2}, loop
+    assert {c["scope"] for c in calls.values()
+            if not c["loop"] and c["pass"] == "backward"} == {
+        "layer1/moe/windows/window/_kept_window_bwd"}
 
 
 def test_a_read_of_a_fusion_goes_by_the_output_it_reads():
@@ -393,12 +418,18 @@ def some(names, *parts):
 def test_a_windowed_layer_names_its_windows(moe_row_tile, grad):
     """Where ``moe_windows_per_step`` looks: the window at 0 under
     ``moe/windows/window``, the loop's under ``moe/windows/while/body/
-    window``, in the forward rule and in the backward rule alike."""
+    window``, in the forward rule and in the backward rule alike. The
+    backward rule's window at 0 reads what the forward's made (PR 66): it is
+    ``_kept_window_bwd`` and no ``_window`` made again; the loop's is."""
     moe_row_tile(8)         # 4 of 16 experts held: windows of 24 of 96 rows
     names = lowered_layer(4, grad)
     rules = ["jvp(moe)/", "transpose(jvp(moe))/"] if grad else ["moe/"]
     for rule in rules:
-        assert some(names, rule + "windows/window/", "jit(_window)"), rule
+        backward = rule.startswith("transpose")
+        assert some(names, rule + "windows/window/", "jit(_kept_window_bwd)"
+                    if backward else "jit(_window)"), rule
+        assert backward == (not some(names, rule + "windows/window/",
+                                     "jit(_window)")), rule
         assert some(names, rule + "windows/while/body/window/",
                     "jit(_window)"), rule
         assert some(names, rule + "windows/while/cond"), rule
