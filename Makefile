@@ -34,9 +34,12 @@ tidy analyze:
 native check check-tsan check-asan check-ubsan tsan asan ubsan clean:
 	$(MAKE) -C $(NATIVE) $(subst native,all,$@)
 
-# Tier-1 test suite (ROADMAP.md).
+# Tier-1 test suite, as the driver runs it (ROADMAP.md, Design 8): six
+# workers, a file to a worker. On one process it takes over an hour.
 test:
-	JAX_PLATFORMS=cpu python3 -m pytest tests/ -q -m 'not slow'
+	JAX_PLATFORMS=cpu ALLOW_MULTIPLE_LIBTPU_LOAD=1 python3 -m pytest tests/ -q \
+	  -m 'not slow' --continue-on-collection-errors -p no:cacheprovider \
+	  -p xdist -n 6 --dist loadfile -p no:randomly
 
 .PHONY: lint invariants threadroles ruff tidy analyze native check \
         check-tsan check-asan check-ubsan tsan asan ubsan clean test

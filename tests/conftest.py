@@ -53,7 +53,46 @@ def free_port() -> int:
     return port
 
 
-def launch_world(n: int, script: str, extra_env=None, timeout=180):
+def wait_world(procs, timeout=150, grace=30):
+    """Waits for every process of a world against one deadline and returns
+    ``[(returncode, stdout, stderr)]`` in the processes' order, every pipe
+    read as it fills. Once a process has failed the others have ``grace``
+    seconds left: a world that lost a rank fails over within seconds or
+    waits for it for ever. Past the deadline, or when the test is
+    interrupted (``_limit`` below), whatever still runs is killed, so no
+    worker outlives its test; a killed process reads ``-9`` with its stderr
+    headed ``[killed after timeout]``."""
+    import threading
+    import time
+    outputs = [None] * len(procs)
+
+    def read(i, p):
+        outputs[i] = p.communicate()
+
+    readers = [threading.Thread(target=read, args=(i, p), daemon=True)
+               for i, p in enumerate(procs)]
+    for reader in readers:
+        reader.start()
+    deadline = time.monotonic() + timeout
+    try:
+        while any(p.poll() is None for p in procs):
+            if any(p.poll() for p in procs):
+                deadline = min(deadline, time.monotonic() + grace)
+            if time.monotonic() >= deadline:
+                break
+            time.sleep(0.05)
+    finally:
+        stalled = [p for p in procs if p.poll() is None]
+        for p in stalled:
+            p.kill()
+        for reader in readers:
+            reader.join()
+    return [(-9, out, f"[killed after timeout]\n{err}") if p in stalled
+            else (p.returncode, out, err)
+            for p, (out, err) in zip(procs, outputs)]
+
+
+def launch_world(n: int, script: str, extra_env=None, timeout=150):
     """Spawn an n-rank process-mode world running ``script``; returns
     [(returncode, stdout, stderr)] per rank (SURVEY.md §4: multi-node tested
     as multi-process on localhost)."""
@@ -72,24 +111,67 @@ def launch_world(n: int, script: str, extra_env=None, timeout=180):
         procs.append(subprocess.Popen([sys.executable, script], env=env,
                                       stdout=subprocess.PIPE,
                                       stderr=subprocess.PIPE, text=True))
-    results = []
-    try:
-        for p in procs:
-            out, err = p.communicate(timeout=timeout)
-            results.append((p.returncode, out, err))
-    finally:
-        for p in procs:  # never leak hung workers past the test
-            if p.poll() is None:
-                p.kill()
-                out, err = p.communicate()
-                results.append((-9, out, f"[killed after timeout]\n{err}"))
-    return results
+    return wait_world(procs, timeout)
 
 
 def assert_all_ok(results):
     for r, (rc, out, err) in enumerate(results):
         assert rc == 0, f"rank {r} failed:\n{err}\n{out}"
         assert "ALL OK" in out
+
+
+# Every test's limit, in seconds: what rightly takes longer is the ``slow``
+# tier's. Every ``timeout=`` of a tier-1 test stays under it, so that a
+# stalled world is killed and reported by ``wait_world`` with its ranks'
+# stderr, and only a wait nothing else bounds ends here.
+TEST_LIMIT_S = 180
+
+
+def _kill_descendants(pid):
+    """SIGKILL to every process below ``pid`` (Linux's ``/proc``)."""
+    import signal
+    try:
+        for task in os.listdir(f"/proc/{pid}/task"):
+            with open(f"/proc/{pid}/task/{task}/children") as f:
+                for child in map(int, f.read().split()):
+                    _kill_descendants(child)
+                    os.kill(child, signal.SIGKILL)
+    except OSError:     # gone meanwhile
+        pass
+
+
+@pytest.fixture(autouse=True)
+def _limit(request):
+    """A test that runs past ``TEST_LIMIT_S`` fails alone: every thread's
+    stack goes to stderr, the processes the test left running are killed
+    (a wait inside the library, ``run_elastic``'s for one, stops no worker
+    of its own when it is interrupted) and the test fails by name of the
+    limit, where a hang used to cost the whole run its clock. An xdist
+    worker runs its tests on the main thread, which is where Python delivers
+    a signal. The ``slow`` tier, which no timed run includes, is left to its
+    own waits."""
+    import faulthandler
+    import signal
+    import sys
+    if request.node.get_closest_marker("slow"):
+        yield
+        return
+    limit = TEST_LIMIT_S
+
+    def expired(signum, frame):
+        try:
+            faulthandler.dump_traceback(file=sys.stderr)
+        except (AttributeError, OSError, ValueError):   # captured, no fd
+            faulthandler.dump_traceback(file=sys.__stderr__)
+        _kill_descendants(os.getpid())
+        pytest.fail(f"the test ran past its limit of {limit} s "
+                    "(tests/conftest.py::TEST_LIMIT_S)")
+
+    before = signal.signal(signal.SIGALRM, expired)
+    signal.setitimer(signal.ITIMER_REAL, limit)
+    yield
+    signal.setitimer(signal.ITIMER_REAL, 0)
+    signal.signal(signal.SIGALRM, before)
 
 
 @pytest.fixture

@@ -69,8 +69,10 @@ def _conv_case(dtype, bias, channels, seq, batch, taps, minor):
     dy = jax.random.normal(ks[3], u.shape).astype(dtype)
 
     def both(fn):
-        y, vjp = jax.vjp(fn, u, w, b)
-        return (y,) + tuple(g for g in vjp(dy) if g is not None)
+        def run(u, w, b):
+            y, vjp = jax.vjp(fn, u, w, b)
+            return (y,) + tuple(g for g in vjp(dy) if g is not None)
+        return jax.jit(run)(u, w, b)
 
     got = both(lambda u, w, b: conv.causal_conv_silu(u, w, b, minor=minor))
     want = both(_plain_conv_silu)
@@ -200,8 +202,10 @@ def test_conv_silu_reads_its_channels_out_of_a_wider_tensor(
     dy = jax.random.normal(ks[3], (2, seq, channels))
 
     def both(fn):
-        y, vjp = jax.vjp(fn, u, w, b)
-        return (y,) + vjp(dy)
+        def run(u, w, b):
+            y, vjp = jax.vjp(fn, u, w, b)
+            return (y,) + vjp(dy)
+        return jax.jit(run)(u, w, b)
 
     got = both(lambda u, w, b: conv.causal_conv_silu(
         u, w, b, first=first, minor=minor))

@@ -19,7 +19,7 @@ EXAMPLES = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "examples")
 
 
-def _run_example(name, args, timeout=420):
+def _run_example(name, args, timeout=150):
     # The shared worker env (CPU platform at interpreter start, repo on
     # PYTHONPATH) + the virtual 8-device mesh.
     env = subprocess_env()
@@ -88,7 +88,7 @@ def test_elastic_example_kill_restart(tmp_path):
            "--crash-at-epoch", "2", "--crash-marker", str(marker)]
 
     first = subprocess.run(cmd, capture_output=True, text=True,
-                           timeout=240, env=env)
+                           timeout=150, env=env)
     assert first.returncode != 0, \
         f"injected crash did not fail the job:\n{first.stdout[-1000:]}"
     assert marker.exists()
@@ -97,7 +97,7 @@ def test_elastic_example_kill_restart(tmp_path):
         "no durable commit written before the crash"
 
     second = subprocess.run(cmd, capture_output=True, text=True,
-                            timeout=240, env=env)
+                            timeout=150, env=env)
     assert second.returncode == 0, \
         f"restart failed:\n{second.stdout[-1500:]}\n{second.stderr[-1500:]}"
     assert "resumed from durable commit: epoch 2" in second.stdout, \

@@ -51,10 +51,10 @@ def test_gradients_match_dense():
     def loss(fn, q, k, v):
         return jnp.sum(fn(q, k, v, causal=True) * w)
 
-    g_flash = jax.grad(lambda *a: loss(flash_attention, *a),
-                       argnums=(0, 1, 2))(q, k, v)
-    g_ref = jax.grad(lambda *a: loss(default_attention, *a),
-                     argnums=(0, 1, 2))(q, k, v)
+    g_flash = jax.jit(jax.grad(lambda *a: loss(flash_attention, *a),
+                       argnums=(0, 1, 2)))(q, k, v)
+    g_ref = jax.jit(jax.grad(lambda *a: loss(default_attention, *a),
+                     argnums=(0, 1, 2)))(q, k, v)
     for gf, gr, name in zip(g_flash, g_ref, "qkv"):
         np.testing.assert_allclose(
             np.asarray(gf), np.asarray(gr), rtol=5e-4, atol=5e-5,
@@ -70,8 +70,8 @@ def test_gradients_match_unaligned():
     def s_ref(q, k, v):
         return jnp.sum(default_attention(q, k, v) ** 2)
 
-    g_flash = jax.grad(s_flash, argnums=(0, 1, 2))(q, k, v)
-    g_ref = jax.grad(s_ref, argnums=(0, 1, 2))(q, k, v)
+    g_flash = jax.jit(jax.grad(s_flash, argnums=(0, 1, 2)))(q, k, v)
+    g_ref = jax.jit(jax.grad(s_ref, argnums=(0, 1, 2)))(q, k, v)
     for gf, gr in zip(g_flash, g_ref):
         np.testing.assert_allclose(np.asarray(gf), np.asarray(gr),
                                    rtol=5e-4, atol=5e-5)
@@ -93,8 +93,8 @@ def _value_and_grads(fn, q, k, v, w, **kw):
         out = fn(q, k, v, **kw)
         return jnp.sum(out.astype(jnp.float32) * w), out
 
-    (_, out), grads = jax.value_and_grad(loss, argnums=(0, 1, 2),
-                                         has_aux=True)(q, k, v)
+    (_, out), grads = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2),
+                                         has_aux=True))(q, k, v)
     return [np.asarray(x, np.float32) for x in (out, *grads)]
 
 
@@ -501,7 +501,7 @@ def test_non_causal_gradients_match_dense(s):
     def loss(fn):
         def inner(a, b, c):
             return jnp.sum(fn(a, b, c, causal=False) * w)
-        return jax.grad(inner, argnums=(0, 1, 2))(q, k, v)
+        return jax.jit(jax.grad(inner, argnums=(0, 1, 2)))(q, k, v)
 
     g_flash = loss(flash_attention)
     g_ref = loss(default_attention)

@@ -53,8 +53,9 @@ def test_kernels_agree_with_the_dense_path(heads, kv_heads, block, length):
 
     np.testing.assert_allclose(flash(q, k, v), dense(q, k, v), atol=2e-5)
     w = jax.random.normal(jax.random.PRNGKey(1), q.shape, jnp.float32)
-    got, want = (jax.grad(lambda *a: jnp.sum(f(*a) * w), argnums=(0, 1, 2))(
-        q, k, v) for f in (flash, dense))
+    got, want = (jax.jit(jax.grad(
+        lambda *a: jnp.sum(f(*a) * w), argnums=(0, 1, 2)))(q, k, v)
+        for f in (flash, dense))
     for name, g, r in zip("qkv", got, want):
         np.testing.assert_allclose(g, r, atol=1e-4, err_msg=f"d{name}")
 

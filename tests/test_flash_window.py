@@ -47,8 +47,8 @@ def _both(s, window, blocks, h=2, hkv=2, d=32, seed=0):
                                             _blocks=blocks)
     dense = lambda q, k, v: _dense(q, k, v, window)
     return tuple(
-        (fn(q, k, v), *jax.grad(lambda *a: jnp.sum(fn(*a) * w),
-                                argnums=(0, 1, 2))(q, k, v))
+        (fn(q, k, v), *jax.jit(jax.grad(lambda *a: jnp.sum(fn(*a) * w),
+                                        argnums=(0, 1, 2)))(q, k, v))
         for fn in (flash, dense))
 
 

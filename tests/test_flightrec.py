@@ -22,6 +22,7 @@ import textwrap
 import pytest
 
 from conftest import subprocess_env as _subprocess_env
+from conftest import wait_world
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -347,8 +348,7 @@ class TestPostmortemKill:
             procs.append(subprocess.Popen(
                 [sys.executable, str(script)], env=env,
                 stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True))
-        results = [p.communicate(timeout=120) for p in procs]
-        return [(p.returncode,) + r for p, r in zip(procs, results)]
+        return wait_world(procs, timeout=120)
 
     def test_kill_yields_postmortem_verdict(self, tmp_path):
         results = self._run_kill_world(tmp_path)

@@ -94,9 +94,12 @@ def _close(got, want, tol):
         atol=tol * float(jnp.abs(want).max()) + 1e-12)
 
 
-def _gradient_matches(args, seed, chunk, wrt):
-    """The gradient of input ``wrt`` of a random linear form of the output
-    and the final state, chunked against sequential."""
+@functools.cache
+def _gradients(inputs_seed, seq, seed, chunk, **shape):
+    """The gradients of every input of a random linear form of the output
+    and the final state, ``(chunked, sequential)``: one run of each side,
+    shared by the cases that each hold one input's."""
+    args = _inputs(inputs_seed, seq, **shape)
     rng = np.random.default_rng(seed)
     like_o, like_s = jax.eval_shape(gated_delta_sequential, *args)
     co = jnp.asarray(rng.standard_normal(like_o.shape), jnp.float32)
@@ -106,11 +109,17 @@ def _gradient_matches(args, seed, chunk, wrt):
         def f(*a):
             o, s = fn(*a)
             return jnp.sum(o * co) + jnp.sum(s * cs)
-        return jax.grad(f, argnums=wrt)(*args)
+        return jax.grad(f, argnums=tuple(range(5)))(*args)
 
-    _close(scalar(lambda *a: gated_delta_chunked(*a, chunk=chunk,
-                                                 dtype=jnp.float32)),
-           scalar(gated_delta_sequential), 5e-5)
+    return (scalar(lambda *a: gated_delta_chunked(*a, chunk=chunk,
+                                                  dtype=jnp.float32)),
+            scalar(gated_delta_sequential))
+
+
+def _gradient_matches(wrt, *case, **shape):
+    """The gradient of input ``wrt``, chunked against sequential."""
+    got, want = _gradients(*case, **shape)
+    _close(got[wrt], want[wrt], 5e-5)
 
 
 @pytest.mark.parametrize("chunk", [16, 64])
@@ -165,6 +174,37 @@ LAYOUTS = {
 }
 
 
+@functools.cache
+def _recurrence_case(layout):
+    """A layout's six inputs, the initial state among them, and both sides'
+    gradients of every one of them of a linear form of both outputs: one
+    run of each side, shared by the layout's seven cases."""
+    shape, seq, chunk, _, _ = LAYOUTS[layout]
+    rng = np.random.default_rng(18)
+    q, k, v, g, beta = _inputs(19, seq, **shape)
+    state_shape = (v.shape[0], v.shape[2], k.shape[3], v.shape[3])
+    args = (q, k, v, g, beta, jnp.asarray(
+        0.5 * rng.standard_normal(state_shape), jnp.float32))
+
+    def chunked(*a):
+        return gated_delta_chunked(*a[:5], chunk=chunk, dtype=jnp.float32,
+                                   initial_state=a[5])
+
+    def sequential(*a):
+        return gated_delta_sequential(*a[:5], initial_state=a[5])
+
+    co, cs = (jnp.asarray(rng.standard_normal(t.shape), jnp.float32)
+              for t in jax.eval_shape(sequential, *args))
+
+    def gradients(fn):
+        def f(*a):
+            o, s = fn(*a)
+            return jnp.sum(o * co) + jnp.sum(s * cs)
+        return jax.grad(f, argnums=tuple(range(6)))(*args)
+
+    return args, chunked, sequential, gradients(chunked), gradients(sequential)
+
+
 @pytest.mark.parametrize("layout", list(LAYOUTS))
 @pytest.mark.parametrize("what", ["o and final", "q", "k", "v", "g", "beta",
                                   "initial_state"])
@@ -174,24 +214,15 @@ def test_recurrence_kernels_match_sequential(monkeypatch, layout, what):
     outputs carry the sizes given, and what the kernels carried beyond them
     comes back exactly zero."""
     shape, seq, chunk, heads_per_block, chunks_per_block = LAYOUTS[layout]
-    rng = np.random.default_rng(18)
-    q, k, v, g, beta = _inputs(19, seq, **shape)
+    args, chunked, sequential, got_grads, want_grads = _recurrence_case(layout)
+    q, k, v, g, beta, _ = args
     state_shape = (v.shape[0], v.shape[2], k.shape[3], v.shape[3])
-    args = (q, k, v, g, beta, jnp.asarray(
-        0.5 * rng.standard_normal(state_shape), jnp.float32))
     n_chunks = -(-seq // chunk)
     lanes = [-(-n // 128) * 128 for n in state_shape[2:]]
     assert gated_delta._rec_heads(v.shape[2], chunks_per_block, chunk,
                                   *lanes, 4) == heads_per_block
     assert largest_divisor(n_chunks, gated_delta._REC_CHUNKS) \
         == chunks_per_block
-
-    def chunked(*a):
-        return gated_delta_chunked(*a[:5], chunk=chunk, dtype=jnp.float32,
-                                   initial_state=a[5])
-
-    def sequential(*a):
-        return gated_delta_sequential(*a[:5], initial_state=a[5])
 
     if what == "o and final":
         carried = []
@@ -211,17 +242,8 @@ def test_recurrence_kernels_match_sequential(monkeypatch, layout, what):
         assert not np.any(np.asarray(final[:, :, k.shape[3]:]))
         assert not np.any(np.asarray(final[..., v.shape[3]:]))
         return
-    co, cs = (jnp.asarray(rng.standard_normal(t.shape), jnp.float32)
-              for t in jax.eval_shape(sequential, *args))
     wrt = ["q", "k", "v", "g", "beta", "initial_state"].index(what)
-
-    def gradient(fn):
-        def f(*a):
-            o, s = fn(*a)
-            return jnp.sum(o * co) + jnp.sum(s * cs)
-        return jax.grad(f, argnums=wrt)(*args)
-
-    _close(gradient(chunked), gradient(sequential), 5e-5)
+    _close(got_grads[wrt], want_grads[wrt], 5e-5)
 
 
 def test_cell_layout_matches_sequential():
@@ -234,7 +256,7 @@ def test_cell_layout_matches_sequential():
 
 @pytest.mark.parametrize("wrt", range(5), ids=["q", "k", "v", "g", "beta"])
 def test_cell_layout_gradients_of_every_input(wrt):
-    _gradient_matches(_inputs(12, CELL_SEQ, **CELL), 13, 64, wrt)
+    _gradient_matches(wrt, 12, CELL_SEQ, 13, 64, **CELL)
 
 
 def _names_in(jaxpr, out=None):
@@ -321,7 +343,7 @@ def test_value_heads_share_key_heads(key_heads):
 @pytest.mark.parametrize("chunk,seq", [(16, 64), (64, 70), (16, 37)])
 @pytest.mark.parametrize("wrt", range(5), ids=["q", "k", "v", "g", "beta"])
 def test_gradients_of_every_input(chunk, seq, wrt):
-    _gradient_matches(_inputs(2, seq), 3, chunk, wrt)
+    _gradient_matches(wrt, 2, seq, 3, chunk)
 
 
 @pytest.mark.parametrize("decay,what", [(1e-3, "near one"), (8.0, "near zero")])
@@ -384,9 +406,10 @@ def test_unit_lower_inverse_and_its_gradient(size):
     _close(unit_lower_inverse(a), want, 1e-5)
     if size > 1:
         co = jnp.asarray(rng.standard_normal((3, size, size)), jnp.float32)
-        got = jax.grad(lambda t: jnp.sum(unit_lower_inverse(t) * co))(a)
-        ref = jax.grad(lambda t: jnp.sum(
-            jnp.linalg.inv(jnp.eye(size) + jnp.tril(t, -1)) * co))(a)
+        got = jax.jit(jax.grad(
+            lambda t: jnp.sum(unit_lower_inverse(t) * co)))(a)
+        ref = jax.jit(jax.grad(lambda t: jnp.sum(
+            jnp.linalg.inv(jnp.eye(size) + jnp.tril(t, -1)) * co)))(a)
         _close(jnp.tril(got, -1), ref, 1e-4)
 
 

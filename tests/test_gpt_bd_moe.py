@@ -8,6 +8,7 @@ half alone) against the plain reference the benchmark keeps
 configuration, float32, seeded. And that nothing leaks: no noised row sees
 its own clean block, no clean row a noised token."""
 
+import functools
 import json
 import os
 import sys
@@ -90,12 +91,12 @@ def test_decoder_matches_the_reference(attention, remat):
     """Loss, its parts, every parameter's gradient."""
     cfg = twin(attention=attention, remat=remat)
     params, data = seeded(cfg), noised()
-    (loss, aux), grads = jax.value_and_grad(
-        lambda p: program_loss(cfg, p, data), has_aux=True)(params)
+    (loss, aux), grads = jax.jit(jax.value_and_grad(
+        lambda p: program_loss(cfg, p, data), has_aux=True))(params)
     with jax.default_matmul_precision("highest"):
-        (ref_loss, ref), ref_grads = jax.value_and_grad(
+        (ref_loss, ref), ref_grads = jax.jit(jax.value_and_grad(
             lambda p: reference.shard_loss(p, *data, load_balance_coef=COEF,
-                                           **MODEL), has_aux=True)(params)
+                                           **MODEL), has_aux=True))(params)
     np.testing.assert_allclose(loss, ref_loss, rtol=1e-5)
     np.testing.assert_allclose(aux["cross_entropy"], ref["cross_entropy"],
                                rtol=1e-5)
@@ -114,11 +115,12 @@ def test_first_adamw_step_matches_the_reference():
     params, data = seeded(cfg), noised()
     lr, decay, eps = 1e-3, 1e-2, 1e-8
     opt = optax.adamw(lr, eps=eps, weight_decay=decay)
-    grads = jax.grad(lambda p: program_loss(cfg, p, data)[0])(params)
-    updates, _ = opt.update(grads, opt.init(params), params)
+    grads = jax.jit(jax.grad(lambda p: program_loss(cfg, p, data)[0]))(params)
+    updates, _ = jax.jit(lambda g, p: opt.update(g, opt.init(p), p))(
+        grads, params)
     with jax.default_matmul_precision("highest"):
-        ref_grads = jax.grad(lambda p: reference.shard_loss(
-            p, *data, load_balance_coef=COEF, **MODEL)[0])(params)
+        ref_grads = jax.jit(jax.grad(lambda p: reference.shard_loss(
+            p, *data, load_balance_coef=COEF, **MODEL)[0]))(params)
     np.testing.assert_allclose(
         float(optax.global_norm(updates)),
         reference.adamw_first_update_norm(params, ref_grads, lr, decay, eps),
@@ -163,18 +165,28 @@ def test_the_reference_notices(change):
             params, tokens, np.pad(targets, ((0, 0), (0, L)),
                                    constant_values=-1), positions, cfg, -1,
             np.pad(weights, ((0, 0), (0, L))), targets.size),
-    }.get(change, lambda: program_loss(cfg, params, data))()[0]
+    }.get(change, lambda: program_loss(cfg, params, data))
+    got = jax.jit(got)()[0]
     assert masked.any() and not masked.all()
-    with jax.default_matmul_precision("highest"):
-        want = reference.shard_loss(
-            seeded(twin()), *data, load_balance_coef=COEF, **MODEL)[0]
+    want = _reference_loss()
     assert abs(float(got) - float(want)) > 1e-3 * float(want), (got, want)
 
 
+@functools.cache
+def _reference_loss():
+    """The reference's loss on ``seeded(twin())`` and ``noised()``, which no
+    change of ``test_the_reference_notices`` moves: made once for the nine."""
+    with jax.default_matmul_precision("highest"):
+        return jax.jit(lambda p: reference.shard_loss(
+            p, *noised(), load_balance_coef=COEF, **MODEL)[0])(seeded(twin()))
+
+
+@functools.partial(jax.jit, static_argnums=0)
 def _noised_logits(cfg, params, tokens, positions):
     return gpt.forward(params, tokens, positions, cfg)[:, :L]
 
 
+@functools.partial(jax.jit, static_argnums=0)
 def _hidden(cfg, params, tokens, positions):
     return gpt._hidden(params, tokens, positions, cfg)[0]
 
@@ -228,8 +240,9 @@ def test_the_references_logits_do_not_leak_either():
     other[:, at] = (other[:, at] + 1) % MASK_ID
     model = {k: v for k, v in MODEL.items()}
     with jax.default_matmul_precision("highest"):
-        base, moved = (reference.noised_logits(params, t, positions, **model)
-                       for t in (tokens, other))
+        logits = jax.jit(lambda t: reference.noised_logits(
+            params, t, positions, **model))
+        base, moved = logits(tokens), logits(other)
         np.testing.assert_allclose(
             base, _noised_logits(cfg, params, tokens, positions), atol=2e-4)
     np.testing.assert_array_equal(moved[:, :(b + 1) * BLOCK],
@@ -296,14 +309,14 @@ def test_without_weights_the_loss_is_what_it_was():
     positions = jnp.broadcast_to(jnp.arange(48), (2, 48))
     f = lambda p, *more: gpt.loss_fn(p, tokens, targets, positions, cfg,
                                      *more)
-    loss, grads = jax.value_and_grad(f)(params)
-    want, want_grads = jax.value_and_grad(_before)(
-        params, tokens, targets, positions, cfg)
+    loss, grads = jax.jit(jax.value_and_grad(f))(params)
+    want, want_grads = jax.jit(jax.value_and_grad(
+        lambda p: _before(p, tokens, targets, positions, cfg)))(params)
     np.testing.assert_allclose(loss, want, rtol=1e-6)
     assert_trees_close(grads, want_grads, rtol=1e-4, atol=1e-7)
     kept = float(jnp.sum(targets != -1))
-    same, same_grads = jax.value_and_grad(f)(
-        params, -1, jnp.ones(targets.shape), kept)
+    same, same_grads = jax.jit(jax.value_and_grad(
+        lambda p: f(p, -1, jnp.ones(targets.shape), kept)))(params)
     np.testing.assert_allclose(same, loss, rtol=1e-6)
     assert_trees_close(same_grads, grads, rtol=1e-5, atol=1e-8)
     # Weights scale a target's term and its gradient; the divisor divides.
@@ -338,8 +351,8 @@ def test_the_reference_in_blocks_is_the_reference_whole(monkeypatch):
     params, data = seeded(twin()), noised()
     def loss_and_grad():
         with jax.default_matmul_precision("highest"):
-            return jax.value_and_grad(lambda p: reference.shard_loss(
-                p, *data, load_balance_coef=COEF, **MODEL)[0])(params)
+            return jax.jit(jax.value_and_grad(lambda p: reference.shard_loss(
+                p, *data, load_balance_coef=COEF, **MODEL)[0]))(params)
     whole, whole_grad = loss_and_grad()
     monkeypatch.setattr(reference, "LOGIT_ELEMENTS", 8 * 2 * L)
     monkeypatch.setattr(reference, "HEAD_ROWS", 16)

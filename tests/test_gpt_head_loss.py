@@ -244,8 +244,8 @@ def test_sharded_global_mean_is_the_unsharded_one(make_runtime, axis, budget):
     positions = np.broadcast_to(np.arange(16, dtype=np.int32), (4, 16))
     ref = dataclasses.replace(cfg, attention="dense", sp_axis=None,
                               ep_axis=None)
-    want, want_grads = jax.value_and_grad(
-        lambda p: dense_loss(p, tokens, targets, positions, ref))(params)
+    want, want_grads = jax.jit(jax.value_and_grad(
+        lambda p: dense_loss(p, tokens, targets, positions, ref)))(params)
 
     data = P(None, "sp") if axis == "sp" else P("ep")
     specs = gpt.param_specs(cfg)

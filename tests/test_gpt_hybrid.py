@@ -15,6 +15,7 @@ left on moves the logits by 0.3 of the largest or more (the last tests).
 """
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -50,6 +51,8 @@ def _data(seed=0, vocab=128, shape=(B, S)):
     return tokens, targets, positions
 
 
+# One program a configuration and shape, not one an operation.
+@functools.partial(jax.jit, static_argnums=0)
 def _loss_and_grad(cfg, params, data):
     with jax.default_matmul_precision("highest"):
         return jax.value_and_grad(gpt.loss_fn)(params, *data, cfg)
@@ -57,9 +60,10 @@ def _loss_and_grad(cfg, params, data):
 
 def _reference(cfg, params, data):
     with jax.default_matmul_precision("highest"):
-        return jax.value_and_grad(lambda p: reference.shard_loss(
+        # A program of its own a call: a test may swap the reference's mixer.
+        return jax.jit(jax.value_and_grad(lambda p: reference.shard_loss(
             p, *data[:2], norm_eps=cfg.norm_eps, ssm_state=cfg.ssm_state,
-            **SCALARS))(params)
+            **SCALARS)))(params)
 
 
 @pytest.mark.parametrize("groups", [1, 2])
@@ -146,9 +150,9 @@ def test_each_scalar_and_the_rotary_switch_move_the_logits(change):
     params = gpt.init_params(jax.random.PRNGKey(5), cfg)
     tokens, _, positions = _data(4)
     with jax.default_matmul_precision("highest"):
-        logits = gpt.forward(params, tokens, positions, cfg)
-        other = gpt.forward(params, tokens, positions,
-                            dataclasses.replace(cfg, **change))
+        logits, other = (
+            jax.jit(lambda p: gpt.forward(p, tokens, positions, c))(params)
+            for c in (cfg, dataclasses.replace(cfg, **change)))
     # Float32 noise is 1e-6 of the largest logit; the least of the five
     # changes (the rotary embedding, one layer in three) moves them by 0.3.
     assert float(jnp.abs(other - logits).max()) \

@@ -19,6 +19,7 @@ moves the loss by 1e-4 of itself or more (the switches' test).
 
 import collections
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -75,12 +76,23 @@ def _params(cfg, seed):
     return jax.tree_util.tree_map_with_path(off_one, params)
 
 
+# One program a configuration and shape, not one an operation.
+@functools.partial(jax.jit, static_argnums=0)
 def _loss_and_grad(cfg, params, data):
     with jax.default_matmul_precision("highest"):
         return jax.value_and_grad(
             lambda p: gpt.loss_fn(p, *data, cfg))(params)
 
 
+@functools.partial(jax.jit, static_argnums=0)
+def _loss(cfg, params, data):
+    """The loss alone, for a case that holds no gradient: no backward pass
+    to compile."""
+    with jax.default_matmul_precision("highest"):
+        return gpt.loss_fn(params, *data, cfg)
+
+
+@functools.partial(jax.jit, static_argnums=0)
 def _reference(cfg, params, data):
     with jax.default_matmul_precision("highest"):
         return jax.value_and_grad(lambda p: reference.shard_loss(
@@ -97,8 +109,25 @@ def _assert_grads_agree(grads, want, tol=2e-3):
             err_msg=jax.tree_util.keystr(path))
 
 
+@pytest.fixture(scope="module")
+def two_shards():
+    """What ``remat`` does not change of the ``run_step`` test: the weights,
+    the batch, and the reference a shard at a time, averaged as the exchange
+    does."""
+    cfg = gpt.GPTConfig(**TINY)
+    params, data = _params(cfg, 1), _data(0)
+    want_loss, want_grads = 0.0, None
+    for s in range(2):
+        l, g = _reference(cfg, params, tuple(x[s:s + 1] for x in data))
+        want_loss += float(l) / 2
+        want_grads = g if want_grads is None else jax.tree.map(
+            jnp.add, want_grads, g)
+    return params, data, want_loss, jax.tree.map(lambda g: g / 2, want_grads)
+
+
 @pytest.mark.parametrize("remat", ["none", "full"])
-def test_model_matches_the_reference_through_run_step(make_runtime, remat):
+def test_model_matches_the_reference_through_run_step(make_runtime,
+                                                      two_shards, remat):
     """The normal path: ``hvd.run_step`` over a dp mesh, each rank its own
     sequence, ``hvd.DistributedOptimizer`` over AdamW: the loss, every
     gradient leaf as the optimizer received it (its first moment after the
@@ -106,8 +135,7 @@ def test_model_matches_the_reference_through_run_step(make_runtime, remat):
     first step; and what the program counted of its scans."""
     make_runtime(devices=jax.devices()[:2], mesh_shape={"dp": 2})
     cfg = gpt.GPTConfig(**TINY, remat=remat)
-    params = _params(cfg, 1)
-    data = _data(0)
+    params, data, want_loss, want_grads = two_shards
     opt = hvd.DistributedOptimizer(optax.adamw(
         ADAMW["lr"], b1=ADAMW["b1"], b2=ADAMW["b2"], eps=ADAMW["eps"],
         weight_decay=ADAMW["weight_decay"]))
@@ -125,14 +153,6 @@ def test_model_matches_the_reference_through_run_step(make_runtime, remat):
                             hvd.batch_spec(0)),
             out_specs=hvd.REPLICATED)(
                 params, opt.init(params), hvd.shard_batch(data))
-    # The reference, a shard at a time, averaged as the exchange does.
-    want_loss, want_grads = 0.0, None
-    for s in range(2):
-        l, g = _reference(cfg, params, tuple(x[s:s + 1] for x in data))
-        want_loss += float(l) / 2
-        want_grads = g if want_grads is None else jax.tree.map(
-            jnp.add, want_grads, g)
-    want_grads = jax.tree.map(lambda g: g / 2, want_grads)
     np.testing.assert_allclose(loss, want_loss, rtol=1e-6)
     _assert_grads_agree(jax.tree.map(lambda m: m / (1 - ADAMW["b1"]), mu),
                         want_grads)
@@ -273,7 +293,7 @@ def test_each_mechanism_left_out_misses_the_reference(change):
     loss, _ = _loss_and_grad(cfg, params, data)
     np.testing.assert_allclose(loss, want, rtol=1e-6)
     other = dataclasses.replace(cfg, **change)
-    missed, _ = _loss_and_grad(other, _without(params, cfg, change), data)
+    missed = _loss(other, _without(params, cfg, change), data)
     assert abs(float(missed) - float(want)) > 1e-4 * abs(float(want)), change
 
 
@@ -345,8 +365,8 @@ def test_the_kernels_norm_is_the_parents_formula(monkeypatch):
             return jnp.sum(out * co), out
 
         with jax.default_matmul_precision("highest"):
-            (_, out), grads = jax.value_and_grad(
-                form, argnums=(0, 1), has_aux=True)(p, h)
+            (_, out), grads = jax.jit(jax.value_and_grad(
+                form, argnums=(0, 1), has_aux=True))(p, h)
         return out, grads
 
     out, grads = both()

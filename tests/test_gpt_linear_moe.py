@@ -20,6 +20,7 @@ float32's 1e-6 (the switches' test).
 """
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -73,12 +74,24 @@ def _params(cfg, seed):
     return jax.tree_util.tree_map_with_path(off_zero, params)
 
 
+# One program a configuration and shape, not one an operation: no test that
+# calls these two sets the expert layer's tile, which ``jit`` would not see.
+@functools.partial(jax.jit, static_argnums=0)
 def _loss_and_grad(cfg, params, data):
     with jax.default_matmul_precision("highest"):
         return jax.value_and_grad(
             lambda p: gpt.loss_and_aux(p, *data, cfg), has_aux=True)(params)
 
 
+@functools.partial(jax.jit, static_argnums=0)
+def _loss(cfg, params, data):
+    """The loss alone, for a case that holds no gradient: no backward pass
+    to compile."""
+    with jax.default_matmul_precision("highest"):
+        return gpt.loss_and_aux(params, *data, cfg)[0]
+
+
+@functools.partial(jax.jit, static_argnums=0)
 def _reference(cfg, params, data):
     with jax.default_matmul_precision("highest"):
         return jax.value_and_grad(lambda p: reference.shard_loss(
@@ -87,6 +100,25 @@ def _reference(cfg, params, data):
             first_expert=cfg.first_expert, key_dim=cfg.gdn_key_dim,
             rope_theta=cfg.rope_theta, rotary_dim=cfg.rotary_dim),
             has_aux=True)(params)
+
+
+@pytest.fixture(scope="module")
+def two_shards():
+    """What neither the tile nor ``remat`` changes of the ``run_step``
+    test: the weights, the batch, and the reference a shard at a time,
+    averaged as the exchange does."""
+    cfg = gpt.GPTConfig(**TINY)
+    params, data = _params(cfg, 1), _data(0)
+    want_loss, want_lb, want_counts, want_grads = 0.0, 0.0, 0.0, None
+    for s in range(2):
+        shard = tuple(x[s:s + 1] for x in data)
+        (l, parts), g = _reference(cfg, params, shard)
+        want_loss += float(l) / 2
+        want_lb += float(parts["load_balance"]) / 2
+        want_counts = want_counts + parts["counts"]
+        want_grads = g if want_grads is None else jax.tree.map(
+            jnp.add, want_grads, g)
+    return params, data, (want_loss, want_lb, want_counts, want_grads)
 
 
 def _assert_grads_agree(grads, want, tol=2e-3):
@@ -101,7 +133,8 @@ def _assert_grads_agree(grads, want, tol=2e-3):
 @pytest.mark.parametrize("tile", [512, 8])
 @pytest.mark.parametrize("remat", ["none", "full"])
 def test_model_matches_the_reference_through_run_step(make_runtime,
-                                                      moe_row_tile, remat,
+                                                      moe_row_tile,
+                                                      two_shards, remat,
                                                       tile):
     """The normal path: ``hvd.run_step`` over a dp mesh, each rank its own
     sequences; loss, auxiliary term and every gradient leaf. A rank's 160
@@ -114,8 +147,7 @@ def test_model_matches_the_reference_through_run_step(make_runtime,
     assert rows == (4 * S if tile == 512 else 2 * S)
     make_runtime(devices=jax.devices()[:2], mesh_shape={"dp": 2})
     cfg = gpt.GPTConfig(**TINY, remat=remat)
-    params = _params(cfg, 1)
-    data = _data(0)
+    params, data, (want_loss, want_lb, want_counts, want_grads) = two_shards
 
     def body(p, batch):
         (loss, aux), grads = jax.value_and_grad(
@@ -128,16 +160,6 @@ def test_model_matches_the_reference_through_run_step(make_runtime,
         loss, load_balance, counts, grads = hvd.run_step(
             body, in_specs=(hvd.REPLICATED, hvd.batch_spec(0)),
             out_specs=hvd.REPLICATED)(params, hvd.shard_batch(data))
-    # The reference, a shard at a time, averaged as the exchange does.
-    want_loss, want_lb, want_counts, want_grads = 0.0, 0.0, 0.0, None
-    for s in range(2):
-        shard = tuple(x[s:s + 1] for x in data)
-        (l, parts), g = _reference(cfg, params, shard)
-        want_loss += float(l) / 2
-        want_lb += float(parts["load_balance"]) / 2
-        want_counts = want_counts + parts["counts"]
-        want_grads = g if want_grads is None else jax.tree.map(
-            jnp.add, want_grads, g)
     np.testing.assert_allclose(loss, want_loss, rtol=1e-6)
     np.testing.assert_allclose(load_balance, want_lb, rtol=1e-6)
     np.testing.assert_array_equal(counts, want_counts)
@@ -198,7 +220,7 @@ def test_each_switch_turned_off_misses_the_reference(change):
             layer.pop("q_norm", None), layer.pop("k_norm", None)
         if "shared_expert_dim" in change:
             layer["moe"].pop("shared")
-    (missed, _), _ = _loss_and_grad(other, tree, data)
+    missed = _loss(other, tree, data)
     assert abs(float(missed) - float(want)) > 2e-5 * abs(float(want)), change
 
 

@@ -8,6 +8,7 @@ gpt_prerouted_moe_dp.py``): float32, tiny sizes, seeded, the normal
 """
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -141,11 +142,20 @@ def test_the_references_blocks_of_rows_change_no_number(monkeypatch):
         "no-window", "not-renormalised", "another-share"])
 def test_each_mechanism_left_out_misses_the_reference(change):
     cfg = smallthinker(**change)
-    params, data = seeded(smallthinker()), batch(smallthinker())
-    loss = gpt.loss_fn(params, *data, cfg)
-    with jax.default_matmul_precision("highest"):
-        ref_loss, _ = reference_loss(smallthinker(), params, data)
+    params, data, ref_loss = _shipped_case()
+    loss = jax.jit(lambda p: gpt.loss_fn(p, *data, cfg))(params)
     assert abs(float(loss) - float(ref_loss)) > 1e-4 * float(ref_loss)
+
+
+@functools.cache
+def _shipped_case():
+    """The shipped configuration's weights and batch and the reference's
+    loss on them, which no change of the test above moves: made once."""
+    params, data = seeded(smallthinker()), batch(smallthinker())
+    with jax.default_matmul_precision("highest"):
+        ref_loss, _ = jax.jit(
+            lambda p: reference_loss(smallthinker(), p, data))(params)
+    return params, data, ref_loss
 
 
 def test_router_probe_hands_out_the_blocks_input():
@@ -156,10 +166,10 @@ def test_router_probe_hands_out_the_blocks_input():
     cfg = smallthinker(remat="full")
     probed = dataclasses.replace(cfg, router_probe=True)
     params, data = seeded(cfg), batch(cfg)
-    (loss, aux), grad = jax.value_and_grad(
-        lambda p: gpt.loss_and_aux(p, *data, probed), has_aux=True)(params)
-    plain, plain_grad = jax.value_and_grad(
-        lambda p: gpt.loss_fn(p, *data, cfg))(params)
+    (loss, aux), grad = jax.jit(jax.value_and_grad(
+        lambda p: gpt.loss_and_aux(p, *data, probed), has_aux=True))(params)
+    plain, plain_grad = jax.jit(jax.value_and_grad(
+        lambda p: gpt.loss_fn(p, *data, cfg)))(params)
     np.testing.assert_array_equal(loss, plain)
     assert_trees_close(grad, plain_grad, rtol=0, atol=0)
     assert aux["router_inputs"].shape == (len(WINDOWS), B * S, cfg.embed_dim)
@@ -245,8 +255,8 @@ def test_an_early_router_under_a_bound_ep_axis_is_the_unsharded_block(
                                   for x in batch(whole))
     value_and_grad = jax.value_and_grad(
         lambda p, *d: gpt.loss_and_aux(p, *d, cfg), has_aux=True)
-    (want, want_aux), want_grads = jax.value_and_grad(
-        lambda p, *d: gpt.loss_and_aux(p, *d, whole), has_aux=True)(
+    (want, want_aux), want_grads = jax.jit(jax.value_and_grad(
+        lambda p, *d: gpt.loss_and_aux(p, *d, whole), has_aux=True))(
             params, tokens, targets, positions)
     specs = gpt.param_specs(cfg)
     (loss, aux), grads = hvd.run_step(

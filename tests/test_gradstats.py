@@ -326,7 +326,7 @@ def _bitwise_world(algo, comp, np_=2):
                    "HVDTPU_COMPRESSION": comp,
                    "HVDTPU_COMPRESSION_MIN_BYTES": "1024",
                    "HVDTPU_GRADCHECK_SAMPLE": "1"},
-        timeout=240)
+        timeout=150)
     assert_all_ok(results)
 
 
@@ -353,7 +353,7 @@ def test_corrupt_divergence_4rank_acceptance():
                    "TEST_GRAD_EXPECT_DIVERGENCE": "2",
                    "HVDTPU_CHAOS": "rank2:corrupt@op=3",
                    "HVDTPU_GRADCHECK_SAMPLE": "1"},
-        timeout=300)
+        timeout=150)
     assert_all_ok(results)
 
 
@@ -370,7 +370,7 @@ def test_nancheck_abort_postmortem_acceptance(tmp_path):
         [sys.executable, "-m", "horovod_tpu.runner.launch", "-np", "2",
          "--postmortem", str(pm), sys.executable,
          os.path.join(DATA, "grad_worker.py")],
-        env=env, capture_output=True, text=True, timeout=300)
+        env=env, capture_output=True, text=True, timeout=150)
     out = proc.stdout + proc.stderr
     assert proc.returncode != 0, out  # the JOB must fail
     assert "saw the expected NaN abort" in out, out
@@ -398,7 +398,7 @@ def test_gradz_per_layer_snr_int4_acceptance(tmp_path):
                    "HVDTPU_COMPRESSION": "int4",
                    "HVDTPU_COMPRESSION_MIN_BYTES": "1024",
                    "HVDTPU_GRAD_PROFILE_DIR": str(gp)},
-        timeout=240)
+        timeout=150)
     assert_all_ok(results)
     from horovod_tpu.gradstats import merge_profile_dir
     merged, found = merge_profile_dir(str(gp))
@@ -433,7 +433,7 @@ def test_reshape_reset_visible_2rank():
         extra_env={"TEST_GRAD_ITERS": "2", "TEST_GRAD_RESHAPE": "1",
                    "HVDTPU_COMPRESSION": "int8",
                    "HVDTPU_COMPRESSION_MIN_BYTES": "1024"},
-        timeout=240)
+        timeout=150)
     assert_all_ok(results)
     assert any("error-feedback residual reset for 'reshape/w'" in err
                for _rc, _out, err in results), \

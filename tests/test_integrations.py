@@ -251,7 +251,7 @@ class TestRayExecutor:
         executor smoke."""
         import subprocess
         import sys
-        from conftest import free_port, subprocess_env
+        from conftest import free_port, subprocess_env, wait_world
 
         worker = os.path.join(os.path.dirname(__file__), "data",
                               "ray_task_worker.py")
@@ -261,9 +261,8 @@ class TestRayExecutor:
             [sys.executable, worker, str(r), str(n), str(port)],
             env=subprocess_env(), stdout=subprocess.PIPE,
             stderr=subprocess.PIPE, text=True) for r in range(n)]
-        for r, p in enumerate(procs):
-            out, err = p.communicate(timeout=180)
-            assert p.returncode == 0, f"rank {r}:\n{err}\n{out}"
+        for r, (rc, out, err) in enumerate(wait_world(procs)):
+            assert rc == 0, f"rank {r}:\n{err}\n{out}"
             assert "ALL OK" in out
 
     def test_create_settings(self, monkeypatch):

@@ -56,7 +56,7 @@ def _grads(fn, args, weights, **kw):
         return jnp.sum(o.astype(jnp.float32) * weights[0]) \
             + jnp.sum(final * weights[1])
 
-    return jax.grad(loss, argnums=tuple(range(len(args))))(*args)
+    return jax.jit(jax.grad(loss, argnums=tuple(range(len(args)))))(*args)
 
 
 CASES = {

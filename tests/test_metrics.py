@@ -517,7 +517,7 @@ def test_metrics_4rank_compressed(tmp_path):
             "HVDTPU_METRICS_PORT": str(base),
             "HVDTPU_COMPRESSION": "int8",
             "TEST_TIMELINE_PATH": str(tmp_path / "tl"),
-        }, timeout=240)
+        }, timeout=150)
     assert_all_ok(results)
 
 
@@ -657,7 +657,7 @@ def test_hvdrun_metrics_flags_and_aggregator(tmp_path):
 
     t = threading.Thread(target=poll_driver)
     t.start()
-    out, err = proc.communicate(timeout=180)
+    out, err = proc.communicate(timeout=150)
     t.join(timeout=10)
     assert proc.returncode == 0, err
     # Scrape URLs printed at launch.

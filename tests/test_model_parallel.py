@@ -65,13 +65,13 @@ def test_moe_layer_expert_parallel_matches_local(make_runtime, moe_row_tile,
 
     grad = lambda axis: jax.value_and_grad(  # noqa: E731
         loss(axis), argnums=(0, 1, 2, 3, 4), has_aux=True)
-    (want_loss, (want_y, want_aux)), want_grads = grad(None)(*args)
+    (want_loss, (want_y, want_aux)), want_grads = jax.jit(grad(None))(*args)
     experts = P("ep")
-    (got_loss, (got_y, got_aux)), got_grads = jax.shard_map(
+    (got_loss, (got_y, got_aux)), got_grads = jax.jit(jax.shard_map(
         grad("ep"), mesh=hvd.mesh(),
         in_specs=(P("ep"), P(), experts, experts, experts),
         out_specs=((P(), (P("ep"), P())),
-                   (P("ep"), P(), experts, experts, experts)))(*args)
+                   (P("ep"), P(), experts, experts, experts))))(*args)
     np.testing.assert_allclose(got_loss, want_loss, rtol=1e-5)
     np.testing.assert_allclose(got_y, want_y, rtol=1e-5, atol=1e-5)
     np.testing.assert_array_equal(got_aux["counts"], want_aux["counts"])
@@ -170,12 +170,12 @@ def test_moe_layer_tensor_parallel_expert_width(make_runtime, moe_row_tile,
 
     grad = lambda *axes: jax.value_and_grad(  # noqa: E731
         loss(*axes), argnums=(0, 1, 2, 3, 4), has_aux=True)
-    (want_loss, want), want_grads = grad(None, None)(*args)
+    (want_loss, want), want_grads = jax.jit(grad(None, None))(*args)
     specs = (P("ep"), P(), P("ep", None, "tp"), P("ep", None, "tp"),
              P("ep", "tp", None))
-    (got_loss, got), got_grads = jax.shard_map(
+    (got_loss, got), got_grads = jax.jit(jax.shard_map(
         grad("ep", "tp"), mesh=hvd.mesh(), in_specs=specs,
-        out_specs=((P(), P("ep")), specs))(*args)
+        out_specs=((P(), P("ep")), specs)))(*args)
     np.testing.assert_allclose(got_loss, want_loss, rtol=1e-5)
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
     for g, w in zip(got_grads, want_grads):
@@ -197,9 +197,9 @@ def test_pipeline_matches_sequential(make_runtime):
     for s in range(n_stages):
         expected = stage(W[s], expected)
 
-    got = jax.shard_map(
+    got = jax.jit(jax.shard_map(
         lambda w, x: pipeline_apply(stage, w, x, axis="pp"),
-        mesh=hvd.mesh(), in_specs=(P("pp"), P()), out_specs=P())(W, x)
+        mesh=hvd.mesh(), in_specs=(P("pp"), P()), out_specs=P()))(W, x)
     np.testing.assert_allclose(np.asarray(got), np.asarray(expected),
                                rtol=1e-5, atol=1e-5)
 
@@ -220,7 +220,7 @@ def test_pipeline_gradients_match_sequential(make_runtime):
             h = stage(W[s], h)
         return jnp.sum(h ** 2)
 
-    expected = jax.grad(ref_loss)(W)
+    expected = jax.jit(jax.grad(ref_loss))(W)
 
     def pp_loss(W):
         out = pipeline_apply(stage, W, x, axis="pp")
@@ -230,8 +230,8 @@ def test_pipeline_gradients_match_sequential(make_runtime):
         g = jax.grad(pp_loss)(W)
         return g
 
-    got = jax.shard_map(body, mesh=hvd.mesh(), in_specs=(P("pp"),),
-                        out_specs=P("pp"))(W)
+    got = jax.jit(jax.shard_map(body, mesh=hvd.mesh(), in_specs=(P("pp"),),
+                        out_specs=P("pp")))(W)
     np.testing.assert_allclose(np.asarray(got), np.asarray(expected),
                                rtol=1e-4, atol=1e-4)
 
@@ -254,8 +254,8 @@ def test_pipeline_remat_gradients_match(make_runtime):
             out = pipeline_apply(stage, W, x, axis="pp", remat=remat)
             return jnp.sum(out ** 2)
 
-        return jax.shard_map(jax.grad(loss), mesh=hvd.mesh(),
-                             in_specs=(P("pp"),), out_specs=P("pp"))(W)
+        return jax.jit(jax.shard_map(jax.grad(loss), mesh=hvd.mesh(),
+                             in_specs=(P("pp"),), out_specs=P("pp")))(W)
 
     np.testing.assert_allclose(np.asarray(grad_of(True)),
                                np.asarray(grad_of(False)),
@@ -288,7 +288,8 @@ def test_gpt_tp_sp_dp_forward_parity(make_runtime, attention):
     tokens = jax.random.randint(jax.random.PRNGKey(6), (B, S), 0, 64)
     positions = jnp.broadcast_to(jnp.arange(S), (B, S))
 
-    expected = gpt.forward(params, tokens, positions, _reference(cfg))
+    expected = jax.jit(
+        lambda p: gpt.forward(p, tokens, positions, _reference(cfg)))(params)
 
     step = hvd.run_step(
         lambda p, t, pos: gpt.forward(p, t, pos, cfg),
@@ -315,8 +316,8 @@ def test_gpt_flash_attention_matches_dense(make_runtime):
     positions = jnp.broadcast_to(jnp.arange(S), (B, S))
 
     def loss_grads(cfg):
-        return jax.value_and_grad(
-            lambda p: gpt.loss_fn(p, tokens, targets, positions, cfg))(
+        return jax.jit(jax.value_and_grad(
+            lambda p: gpt.loss_fn(p, tokens, targets, positions, cfg)))(
                 params)
 
     l_d, g_d = loss_grads(cfg_dense)
@@ -358,8 +359,8 @@ def test_gpt_gqa_dense_matches_flash(make_runtime, kv_heads):
     positions = jnp.broadcast_to(jnp.arange(S), (B, S))
 
     def loss_grads(cfg):
-        return jax.value_and_grad(
-            lambda p: gpt.loss_fn(p, tokens, targets, positions, cfg))(
+        return jax.jit(jax.value_and_grad(
+            lambda p: gpt.loss_fn(p, tokens, targets, positions, cfg)))(
                 params)
 
     l_d, g_d = loss_grads(cfg_dense)
@@ -389,7 +390,8 @@ def test_gpt_moe_ep_parity(make_runtime, top_k):
     targets = jnp.roll(tokens, -1, axis=1).at[:, -1].set(-1)
     positions = jnp.broadcast_to(jnp.arange(S), (B, S))
 
-    expected = gpt.forward(params, tokens, positions, _reference(cfg))
+    expected = jax.jit(
+        lambda p: gpt.forward(p, tokens, positions, _reference(cfg)))(params)
 
     data = P(("dp", "ep"), "sp")
     step = hvd.run_step(
@@ -406,7 +408,7 @@ def test_gpt_moe_ep_parity(make_runtime, top_k):
     cfg = _reference(cfg)
     value_and_grad = jax.value_and_grad(
         lambda p, *d: gpt.loss_and_aux(p, *d, cfg), has_aux=True)
-    (want, want_aux), want_grads = value_and_grad(
+    (want, want_aux), want_grads = jax.jit(value_and_grad)(
         params, tokens, targets, positions)
     specs = gpt.param_specs(cfg)
     (loss, aux), grads = hvd.run_step(
@@ -477,9 +479,9 @@ def test_gpt_loss_and_grads_replicated(make_runtime):
     targets = jnp.roll(tokens, -1, axis=1).at[:, -1].set(-1)
     positions = jnp.broadcast_to(jnp.arange(S), (B, S))
 
-    expected_loss, expected_grads = jax.value_and_grad(
+    expected_loss, expected_grads = jax.jit(jax.value_and_grad(
         lambda p: gpt.loss_fn(p, tokens, targets, positions,
-                              _reference(cfg)))(params)
+                              _reference(cfg))))(params)
 
     def body(p, t, tg, pos):
         # Per-dp-shard loss; average over dp to the global mean.

@@ -64,10 +64,10 @@ def plain(top_k):
 
 def assert_layer_is_the_reference(args, top_k):
     wrt = tuple(range(5))
-    (_, (y, aux)), grads = jax.value_and_grad(
-        program(top_k), argnums=wrt, has_aux=True)(*args)
-    (_, (y_ref, aux_ref)), grads_ref = jax.value_and_grad(
-        plain(top_k), argnums=wrt, has_aux=True)(*args)
+    (_, (y, aux)), grads = jax.jit(jax.value_and_grad(
+        program(top_k), argnums=wrt, has_aux=True))(*args)
+    (_, (y_ref, aux_ref)), grads_ref = jax.jit(jax.value_and_grad(
+        plain(top_k), argnums=wrt, has_aux=True))(*args)
     np.testing.assert_allclose(y, y_ref, rtol=1e-5, atol=1e-5)
     np.testing.assert_allclose(aux["load_balance"], aux_ref["load_balance"],
                                rtol=1e-5)
@@ -143,8 +143,10 @@ def test_down_and_combine_has_autodiffs_gradients(top_k, skew):
     with jax.default_matmul_precision("highest"):
         y = program(hidden, w_down, top_p)
         y_ref = plain(hidden, w_down, top_p)
-        grads = jax.grad(loss(program), argnums=wrt)(hidden, w_down, top_p)
-        grads_ref = jax.grad(loss(plain), argnums=wrt)(hidden, w_down, top_p)
+        grads = jax.jit(jax.grad(loss(program), argnums=wrt))(
+            hidden, w_down, top_p)
+        grads_ref = jax.jit(jax.grad(loss(plain), argnums=wrt))(
+            hidden, w_down, top_p)
     np.testing.assert_allclose(y, y_ref, rtol=1e-5, atol=1e-5)
     for name, g, g_ref in zip(("hidden", "w_down", "top_p"), grads,
                               grads_ref):
@@ -184,19 +186,25 @@ def olmoe_params(cfg, seed=0):
     return params
 
 
-def loss_and_grads(cfg, params, data):
+def traced_loss_and_grads(cfg, params, data):
     return jax.value_and_grad(
         lambda p: gpt.loss_and_aux(p, *data, cfg), has_aux=True)(params)
+
+
+# One program a configuration; what sets the expert layer's tile, which
+# ``jit`` would not see, takes ``traced_loss_and_grads`` under a trace of
+# its own.
+loss_and_grads = jax.jit(traced_loss_and_grads, static_argnums=0)
 
 
 def test_sparse_decoder_matches_the_reference():
     cfg = olmoe()
     params, data = olmoe_params(cfg), olmoe_batch(cfg)
     (loss, aux), grads = loss_and_grads(cfg, params, data)
-    (want, parts), grads_ref = jax.value_and_grad(
+    (want, parts), grads_ref = jax.jit(jax.value_and_grad(
         lambda p: reference.shard_loss(
             p, *data, top_k=2, norm_eps=1e-5, load_balance_coef=0.01,
-            router_z_coef=0.001), has_aux=True)(params)
+            router_z_coef=0.001), has_aux=True))(params)
     np.testing.assert_allclose(loss, want, rtol=1e-5)
     for key in ("cross_entropy", "load_balance", "router_z"):
         np.testing.assert_allclose(aux[key], parts[key], rtol=1e-5)
@@ -250,7 +258,7 @@ def ops_of(jaxpr, out=None, outside=()):
 def step_ops(cfg, params, data):
     """``ops_of`` the differentiated step of ``cfg``."""
     return ops_of(jax.make_jaxpr(
-        lambda p: loss_and_grads(cfg, p, data))(params).jaxpr)
+        lambda p: traced_loss_and_grads(cfg, p, data))(params).jaxpr)
 
 
 def assert_remat_changes_no_number(full, none, params, data):
@@ -296,7 +304,7 @@ def test_full_remat_keeps_what_is_dear_to_make_again(feed_forward, block):
     batch, seq = 2, 128
     params, data = olmoe_params(full), olmoe_batch(full, batch, seq)
 
-    ops = step_ops(full, params, data)
+    ops, plain = (step_ops(cfg, params, data) for cfg in (full, none))
     assert ops["hvd_flash_fwd"] == full.num_layers
     # The backward pass is one kernel a layer, under the dKdV kernel's name.
     assert ops["hvd_flash_dkdv"] == full.num_layers
@@ -309,14 +317,14 @@ def test_full_remat_keeps_what_is_dear_to_make_again(feed_forward, block):
     else:
         # Three forward, two each backward, none again: ``"none"``'s.
         assert ops["ragged_dot_general"] == 9 * full.num_layers \
-            == step_ops(none, params, data)["ragged_dot_general"]
+            == plain["ragged_dot_general"]
     # The stream's own shape: a block that only adds its branches makes the
     # mixer's output projection again (the feed-forward reads the stream
     # behind it) and not the feed-forward's last product; one that keeps
     # its branches makes neither again.
     again = full.num_layers if block == "adds" else 0
     stream = "dot_general", (batch, seq, full.embed_dim)
-    assert ops[stream] == step_ops(none, params, data)[stream] + again
+    assert ops[stream] == plain[stream] + again
 
     if block != "adds":
         # What these cases' numbers hold is the policy, not the kernels
@@ -367,7 +375,7 @@ def test_metrics_count_what_a_checkpointed_block_keeps(make_runtime, block):
     data = olmoe_batch(sparse, batch, seq)
 
     def trace(cfg):
-        jax.make_jaxpr(lambda p: loss_and_grads(cfg, p, data))(
+        jax.make_jaxpr(lambda p: traced_loss_and_grads(cfg, p, data))(
             gpt.init_params(jax.random.PRNGKey(0), cfg))
 
     trace(sparse)
@@ -594,13 +602,13 @@ def test_the_shares_add_up_to_the_uncut_layer(small_tile, routing, cut):
             else jnp.zeros_like(h)
 
     args = (h, router, w_gate, w_up, w_down)
-    (_, (y_ref, lb_ref, counts_ref)), g_ref = jax.value_and_grad(
-        whole, argnums=range(5), has_aux=True)(*args)
+    (_, (y_ref, lb_ref, counts_ref)), g_ref = jax.jit(jax.value_and_grad(
+        whole, argnums=range(5), has_aux=True))(*args)
     assert_rows_held(counts_ref, routing, first=4, held=held)
     total, grads = once(h), None
     for first in range(0, E, held):
-        (_, (y, aux)), g = jax.value_and_grad(
-            share(first), argnums=range(5), has_aux=True)(*args)
+        (_, (y, aux)), g = jax.jit(jax.value_and_grad(
+            share(first), argnums=range(5), has_aux=True))(*args)
         # The router's terms are the whole router's on every share.
         np.testing.assert_allclose(aux["load_balance"], lb_ref, rtol=1e-5)
         np.testing.assert_array_equal(aux["counts"],
@@ -609,7 +617,7 @@ def test_the_shares_add_up_to_the_uncut_layer(small_tile, routing, cut):
         grads = g if grads is None else jax.tree.map(jnp.add, grads, g)
     np.testing.assert_allclose(total, y_ref, rtol=1e-5, atol=1e-5)
     # The uncut loss's gradient has the shared expert's part too: through h.
-    g_once = jax.grad(lambda h: jnp.sum(once(h) * weigh))(h)
+    g_once = jax.jit(jax.grad(lambda h: jnp.sum(once(h) * weigh)))(h)
     for name, got, want in zip(
             ("h", "W_r", "W_gate", "W_up", "W_down"),
             (grads[0] + g_once,) + tuple(grads[1:]), g_ref):
@@ -636,11 +644,11 @@ def assert_share_is_its_reference(inputs, first, held, top_k, remat):
             h, m, top_k, first)
         return jnp.sum(y * jnp.cos(y)) + load_balance, (y, counts)
 
-    (_, (y, aux)), grads = jax.value_and_grad(
-        as_a_block_runs_it(got, remat), argnums=range(5), has_aux=True)(
+    (_, (y, aux)), grads = jax.jit(jax.value_and_grad(
+        as_a_block_runs_it(got, remat), argnums=range(5), has_aux=True))(
             h, router, *share)
-    (_, (y_ref, counts)), grads_ref = jax.value_and_grad(
-        want, argnums=range(5), has_aux=True)(h, router, *share)
+    (_, (y_ref, counts)), grads_ref = jax.jit(jax.value_and_grad(
+        want, argnums=range(5), has_aux=True))(h, router, *share)
     np.testing.assert_allclose(y, y_ref, rtol=1e-5, atol=1e-5)
     np.testing.assert_array_equal(aux["counts"], np.asarray(counts, np.int32))
     for g, g_ref in zip(grads, grads_ref):
@@ -746,8 +754,10 @@ def test_a_windows_down_and_combine_has_autodiffs_gradients(routing):
     with jax.default_matmul_precision("highest"):
         y = program(hidden, w_down, top_p)
         y_ref = plain(hidden, w_down, top_p)
-        grads = jax.grad(loss(program), argnums=wrt)(hidden, w_down, top_p)
-        grads_ref = jax.grad(loss(plain), argnums=wrt)(hidden, w_down, top_p)
+        grads = jax.jit(jax.grad(loss(program), argnums=wrt))(
+            hidden, w_down, top_p)
+        grads_ref = jax.jit(jax.grad(loss(plain), argnums=wrt))(
+            hidden, w_down, top_p)
     np.testing.assert_allclose(y, y_ref, rtol=1e-5, atol=1e-5)
     for name, g, g_ref in zip(("hidden", "w_down", "top_p"), grads,
                               grads_ref):
@@ -838,7 +848,7 @@ def test_renormalised_weights(top_k):
         y = share_reference.expert_block(h, m, top_k)[0]
         return jnp.sum(y * jnp.cos(y))
 
-    g, g_ref = jax.grad(got)(router), jax.grad(want)(router)
+    g, g_ref = jax.jit(jax.grad(got))(router), jax.jit(jax.grad(want))(router)
     # (At one expert a token the weight is the constant 1: both are zero.)
     np.testing.assert_allclose(
         g, g_ref, rtol=1e-4, atol=1e-5 * float(jnp.abs(g_ref).max()) + 2e-6)
@@ -944,10 +954,10 @@ def test_sigmoid_router_with_bias_and_constant_matches_the_reference(top_k):
             h, *(block["shared"][k] for k in ("w_gate", "w_up", "w_down")))
         return jnp.sum(y * weigh), (y, counts)
 
-    (_, (y, counts)), g = jax.value_and_grad(
-        program, argnums=(0, 1), has_aux=True)(h, block)
-    (_, (y_ref, counts_ref)), g_ref = jax.value_and_grad(
-        plain, argnums=(0, 1), has_aux=True)(h, block)
+    (_, (y, counts)), g = jax.jit(jax.value_and_grad(
+        program, argnums=(0, 1), has_aux=True))(h, block)
+    (_, (y_ref, counts_ref)), g_ref = jax.jit(jax.value_and_grad(
+        plain, argnums=(0, 1), has_aux=True))(h, block)
     np.testing.assert_allclose(y, y_ref, rtol=2e-5, atol=2e-6)
     np.testing.assert_array_equal(counts, np.asarray(counts_ref, np.int32))
     g[1].pop("shared"), g_ref[1].pop("shared")
@@ -992,13 +1002,13 @@ def test_eight_shares_of_a_sigmoid_router_add_up_to_the_uncut_layer():
             h, *(block["shared"][k] for k in ("w_gate", "w_up", "w_down")))
         return jnp.sum(y * weigh), y
 
-    (_, (y_ref, counts_ref)), g_ref = jax.value_and_grad(
-        whole, argnums=(0, 1), has_aux=True)(h, block)
-    (_, total), grads = jax.value_and_grad(
-        shared_once, argnums=(0, 1), has_aux=True)(h, block)
+    (_, (y_ref, counts_ref)), g_ref = jax.jit(jax.value_and_grad(
+        whole, argnums=(0, 1), has_aux=True))(h, block)
+    (_, total), grads = jax.jit(jax.value_and_grad(
+        shared_once, argnums=(0, 1), has_aux=True))(h, block)
     for first in range(0, E, 2):
-        (_, (y, counts)), g = jax.value_and_grad(
-            share(first), argnums=(0, 1), has_aux=True)(h, block)
+        (_, (y, counts)), g = jax.jit(jax.value_and_grad(
+            share(first), argnums=(0, 1), has_aux=True))(h, block)
         np.testing.assert_array_equal(counts,
                                       np.asarray(counts_ref, np.int32))
         total = total + y
@@ -1043,10 +1053,10 @@ def test_a_choice_limited_to_groups_matches_the_reference(groups, kept,
             h, *(block["shared"][k] for k in ("w_gate", "w_up", "w_down")))
         return jnp.sum(y * weigh), (y, counts)
 
-    (_, (y, counts)), g = jax.value_and_grad(
-        program, argnums=(0, 1), has_aux=True)(h, mine)
-    (_, (y_ref, counts_ref)), g_ref = jax.value_and_grad(
-        plain, argnums=(0, 1), has_aux=True)(h, mine)
+    (_, (y, counts)), g = jax.jit(jax.value_and_grad(
+        program, argnums=(0, 1), has_aux=True))(h, mine)
+    (_, (y_ref, counts_ref)), g_ref = jax.jit(jax.value_and_grad(
+        plain, argnums=(0, 1), has_aux=True))(h, mine)
     np.testing.assert_allclose(y, y_ref, rtol=2e-5, atol=2e-6)
     np.testing.assert_array_equal(counts, np.asarray(counts_ref, np.int32))
     g[1].pop("shared"), g_ref[1].pop("shared")
@@ -1181,11 +1191,11 @@ def test_relu_experts_match_the_dense_sum(small_tile, first, held, routing,
                                                      first)
         return jnp.sum(y * jnp.cos(y)), (y, counts)
 
-    (_, (y, counts)), grads = jax.value_and_grad(
-        as_a_block_runs_it(got, remat), argnums=(0, 1, 2), has_aux=True)(
+    (_, (y, counts)), grads = jax.jit(jax.value_and_grad(
+        as_a_block_runs_it(got, remat), argnums=(0, 1, 2), has_aux=True))(
             h, logits, share)
-    (_, (y_ref, counts_ref)), grads_ref = jax.value_and_grad(
-        want, argnums=(0, 1, 2), has_aux=True)(h, logits, share)
+    (_, (y_ref, counts_ref)), grads_ref = jax.jit(jax.value_and_grad(
+        want, argnums=(0, 1, 2), has_aux=True))(h, logits, share)
     np.testing.assert_allclose(y, y_ref, rtol=1e-5, atol=1e-5)
     np.testing.assert_array_equal(counts, np.asarray(counts_ref, np.int32))
     if held < E:
@@ -1214,16 +1224,16 @@ def test_four_relu_shares_of_a_callers_router_add_up_to_the_uncut_layer(
         y, counts = prerouted_reference.expert_block(h, logits, block, top_k)
         return jnp.sum(y * weigh), (y, counts)
 
-    (_, (y_ref, counts_ref)), g_ref = jax.value_and_grad(
-        whole, argnums=(0, 1), has_aux=True)(h, logits)
+    (_, (y_ref, counts_ref)), g_ref = jax.jit(jax.value_and_grad(
+        whole, argnums=(0, 1), has_aux=True))(h, logits)
     total, grads = 0.0, None
     for first in SHARES:
         def share(h, logits):
             y, aux = _relu_layer(h, logits, block, top_k, first, 4)
             return jnp.sum(y * weigh), (y, aux["counts"])
 
-        (_, (y, counts)), g = jax.value_and_grad(
-            share, argnums=(0, 1), has_aux=True)(h, logits)
+        (_, (y, counts)), g = jax.jit(jax.value_and_grad(
+            share, argnums=(0, 1), has_aux=True))(h, logits)
         np.testing.assert_array_equal(counts,
                                       np.asarray(counts_ref, np.int32))
         total = total + y
@@ -1394,9 +1404,9 @@ def assert_checkpointing_changes_no_gradient(f, args):
     """Every argument's gradient under ``remat="full"``'s policy is the
     plain layer's."""
     wrt = tuple(range(len(args)))
-    loss, grads = jax.value_and_grad(as_a_block_runs_it(
-        lambda *a: f(*a), "full"), argnums=wrt)(*args)
-    want_loss, want = jax.value_and_grad(f, argnums=wrt)(*args)
+    loss, grads = jax.jit(jax.value_and_grad(as_a_block_runs_it(
+        lambda *a: f(*a), "full"), argnums=wrt))(*args)
+    want_loss, want = jax.jit(jax.value_and_grad(f, argnums=wrt))(*args)
     np.testing.assert_allclose(loss, want_loss, rtol=1e-6)
     for name, g, g_ref in zip(names_of(args), grads, want):
         assert float(jnp.abs(g_ref).max()) > 0, name
@@ -1645,11 +1655,11 @@ def test_latent_squared_relu_experts_match_the_dense_sum(
                                                    ROUTE_SCALE, first)
         return jnp.sum(y * weigh), (y, counts)
 
-    (_, (y, counts)), grads = jax.value_and_grad(
-        as_a_block_runs_it(got, remat), argnums=(0, 1), has_aux=True)(
+    (_, (y, counts)), grads = jax.jit(jax.value_and_grad(
+        as_a_block_runs_it(got, remat), argnums=(0, 1), has_aux=True))(
             h, share)
-    (_, (y_ref, counts_ref)), grads_ref = jax.value_and_grad(
-        want, argnums=(0, 1), has_aux=True)(h, share)
+    (_, (y_ref, counts_ref)), grads_ref = jax.jit(jax.value_and_grad(
+        want, argnums=(0, 1), has_aux=True))(h, share)
     assert y.shape == (T, LATENT)
     np.testing.assert_allclose(y, y_ref, rtol=2e-5, atol=2e-5)
     np.testing.assert_array_equal(counts, np.asarray(counts_ref, np.int32))
@@ -1689,16 +1699,18 @@ def test_four_latent_shares_add_up_through_the_up_projection(small_tile,
                                           block["shared"]["w_down"])
         return jnp.sum(y * weigh), y
 
-    (_, (y_ref, counts_ref)), g_ref = jax.value_and_grad(
-        whole, has_aux=True)(h)
-    (_, total), grad = jax.value_and_grad(shared_once, has_aux=True)(h)
+    (_, (y_ref, counts_ref)), g_ref = jax.jit(jax.value_and_grad(
+        whole, has_aux=True))(h)
+    (_, total), grad = jax.jit(jax.value_and_grad(
+        shared_once, has_aux=True))(h)
     for first in SHARES:
         def share(h):
             latent, aux = _latent_layer(h, block, top_k, first, 4)
             y = latent @ block["latent_up"]
             return jnp.sum(y * weigh), (y, aux["counts"])
 
-        (_, (y, counts)), g = jax.value_and_grad(share, has_aux=True)(h)
+        (_, (y, counts)), g = jax.jit(jax.value_and_grad(
+            share, has_aux=True))(h)
         np.testing.assert_array_equal(counts,
                                       np.asarray(counts_ref, np.int32))
         total, grad = total + y, grad + g

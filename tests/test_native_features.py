@@ -90,6 +90,6 @@ def test_native_unit_tests():
     native = os.path.abspath(os.path.join(DATA, "..", "..", "horovod_tpu",
                                           "native"))
     r = subprocess.run(["make", "-C", native, "check"], capture_output=True,
-                       text=True, timeout=300)
+                       text=True, timeout=150)
     assert r.returncode == 0, f"{r.stdout}\n{r.stderr}"
     assert "ALL OK" in r.stdout

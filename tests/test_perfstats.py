@@ -18,7 +18,8 @@ import time
 
 import pytest
 
-from conftest import assert_all_ok, free_port, launch_world, subprocess_env
+from conftest import (assert_all_ok, free_port, launch_world, subprocess_env,
+                      wait_world)
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 DATA = os.path.join(REPO, "tests", "data")
@@ -361,7 +362,7 @@ def test_perf_4rank_chaos_delay_acceptance(tmp_path):
         extra_env={"TEST_PERF_ITERS": "60",
                    "HVDTPU_PERF_MIN_SAMPLES": "5",
                    "HVDTPU_PERF_PROFILE_DIR": str(clean_dir)},
-        timeout=240)
+        timeout=150)
     assert_all_ok(results)
 
     # Delayed run: rank 2 sleeps 1.5 s inside an allreduce mid-run. The
@@ -401,7 +402,7 @@ def test_perf_4rank_chaos_delay_acceptance(tmp_path):
             stderr=subprocess.PIPE, text=True))
 
     straggler_seen = None
-    deadline = time.monotonic() + 180
+    deadline = time.monotonic() + 150
     try:
         while time.monotonic() < deadline:
             if all(p.poll() is not None for p in procs):
@@ -421,15 +422,10 @@ def test_perf_4rank_chaos_delay_acceptance(tmp_path):
                     straggler_seen = s
                     break
             time.sleep(0.25)
-        outs = []
-        for p in procs:
-            out, err = p.communicate(timeout=180)
-            outs.append((p.returncode, out, err))
-    finally:
-        for p in procs:
-            if p.poll() is None:
-                p.kill()
-                p.communicate()
+    except BaseException:
+        wait_world(procs, timeout=0)    # kills what runs
+        raise
+    outs = wait_world(procs, timeout=deadline - time.monotonic())
     for rc, out, err in outs:
         assert rc == 0, f"worker failed: {err[-2000:]}"
         assert "ALL OK" in out

@@ -84,7 +84,7 @@ def _npo2_world(n, algo, shm):
             "HVDTPU_GRADCHECK_SAMPLE": "1",
             "HVDTPU_SHM": shm,
         },
-        timeout=240)
+        timeout=150)
     for r, (rc, out, err) in enumerate(results):
         assert rc == 0, f"rank {r} failed:\n{err}\n{out}"
         assert "ALL OK" in out
@@ -113,7 +113,7 @@ def test_npo2_world_bitwise_large(algo, n):
 # (HVDTPU_GRADCHECK_SAMPLE=1) asserts the bitwise cross-rank invariant on
 # the gathered outputs — under compression that is the quantize-once
 # owner-code guarantee, the op-level claim this PR ships.
-def _rsag_world(n, shm, comp, timeout=240):
+def _rsag_world(n, shm, comp, timeout=150):
     results = _launch_world(
         n, os.path.join(REPO, "tests", "data", "rsag_worker.py"),
         extra_env={
@@ -158,7 +158,7 @@ def test_reducescatter_allgather_npo2_large(comp):
 # compressed tolerance, world-bitwise outputs over a lossless CRC channel
 # AND via the divergence probe (broadcast outputs are fingerprinted), the
 # grouped-enqueue ctrl-frame reduction, and raw/wire timeline args.
-def _ba_world(n, shm, comp, timeout=240, tmp_path=None):
+def _ba_world(n, shm, comp, timeout=150, tmp_path=None):
     extra = {
         "TEST_BA_ITERS": "2",
         "HVDTPU_SHM": shm,
@@ -220,7 +220,7 @@ def test_hierarchical_allreduce_two_hosts():
     sees two hosts). Every rank must produce the exact flat result."""
     import subprocess
 
-    from conftest import free_port, subprocess_env
+    from conftest import free_port, subprocess_env, wait_world
 
     worker = os.path.join(REPO, "tests", "data", "algo_worker.py")
     port = free_port()
@@ -241,18 +241,7 @@ def test_hierarchical_allreduce_two_hosts():
         procs.append(subprocess.Popen([sys.executable, worker], env=env,
                                       stdout=subprocess.PIPE,
                                       stderr=subprocess.PIPE, text=True))
-    results = []
-    try:
-        for p in procs:
-            out, err = p.communicate(timeout=180)
-            results.append((p.returncode, out, err))
-    finally:
-        for p in procs:
-            if p.poll() is None:
-                p.kill()
-                out, err = p.communicate()
-                results.append((-9, out, f"[killed after timeout]\n{err}"))
-    for r, (rc, out, err) in enumerate(results):
+    for r, (rc, out, err) in enumerate(wait_world(procs)):
         assert rc == 0, f"rank {r} failed:\n{err}\n{out}"
         assert "ALL OK" in out
 
@@ -301,7 +290,7 @@ def test_hvdrun_cli(tmp_path):
     rc = subprocess.run(
         [sys.executable, "-m", "horovod_tpu.runner.launch", "-np", "2",
          "--timeline", str(timeline), sys.executable, WORKER],
-        env=_subprocess_env(), capture_output=True, text=True, timeout=180)
+        env=_subprocess_env(), capture_output=True, text=True, timeout=150)
     assert rc.returncode == 0, rc.stderr
     import json
     events = json.load(open(f"{timeline}.0.json"))
@@ -446,7 +435,7 @@ def test_spmd_multihost_bootstrap():
     import subprocess
     import sys
 
-    from conftest import free_port, subprocess_env
+    from conftest import free_port, subprocess_env, wait_world
 
     port = free_port()
     worker = os.path.join(REPO, "tests", "data", "spmd_multihost_worker.py")
@@ -462,12 +451,6 @@ def test_spmd_multihost_bootstrap():
         procs.append(subprocess.Popen(
             [sys.executable, worker], env=env, stdout=subprocess.PIPE,
             stderr=subprocess.PIPE, text=True))
-    try:
-        for i, p in enumerate(procs):
-            out, err = p.communicate(timeout=240)
-            assert p.returncode == 0, f"process {i}:\n{err}\n{out}"
-            assert "ALL OK" in out
-    finally:
-        for p in procs:
-            if p.poll() is None:
-                p.kill()
+    for i, (rc, out, err) in enumerate(wait_world(procs)):
+        assert rc == 0, f"process {i}:\n{err}\n{out}"
+        assert "ALL OK" in out

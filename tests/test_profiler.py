@@ -17,7 +17,8 @@ import time
 import numpy as np
 import pytest
 
-from conftest import assert_all_ok, free_port, launch_world, subprocess_env
+from conftest import (assert_all_ok, free_port, launch_world, subprocess_env,
+                      wait_world)
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -314,10 +315,9 @@ class TestProfilerChaosKill:
             procs.append(subprocess.Popen(
                 [sys.executable, str(script)], env=env,
                 stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True))
-        results = [p.communicate(timeout=120) for p in procs]
-        rcs = [p.returncode for p in procs]
-        assert rcs[1] == -9, results[1]  # chaos SIGKILL landed
-        assert "SURVIVOR FAILED OVER" in results[0][0], results
+        results = wait_world(procs, timeout=120)
+        assert results[1][0] == -9, results[1]  # chaos SIGKILL landed
+        assert "SURVIVOR FAILED OVER" in results[0][1], results
 
         # Survivor's whole-job profile intact (SIGPROF fired through the
         # abort cascade and the flight dump); the dead rank never reached

@@ -162,6 +162,14 @@ LINEAR = dict(layer_kinds=("gdn", "attention"), gdn_key_heads=2,
               attention_gate=True, qk_head_norm=True)
 
 
+@functools.cache
+def _linear_step_names():
+    """The compiled linear-attention step's names, made once for the five
+    cases that each look for one part of the mixer (under the runtime of
+    the case that asks first; a set of strings outlives it)."""
+    return op_names(*gpt_step("full", **LINEAR))
+
+
 @pytest.mark.parametrize("inner", ["in_proj", "conv", "scan", "gate_norm",
                                    "out_proj"])
 def test_linear_attention_step_carries_the_gdn_scopes(spmd4, inner):
@@ -169,7 +177,7 @@ def test_linear_attention_step_carries_the_gdn_scopes(spmd4, inner):
     of the mixer under ``layer0/gdn/<part>`` in the forward pass, in the
     recomputed copy and in the backward pass; the shared expert under
     ``moe/shared``, beside the router, the dispatch and the experts."""
-    names = op_names(*gpt_step("full", **LINEAR))
+    names = _linear_step_names()
 
     def some(*parts):
         return any(all(p in n for p in parts) for n in names)

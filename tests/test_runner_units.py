@@ -336,3 +336,71 @@ class TestPreflight:
         # All free: passes silently.
         check_metrics_ports(["localhost", "127.0.0.1"], base,
                             aggregator_port=base + 2)
+
+
+def test_a_test_past_its_limit_fails_alone(tmp_path):
+    """``conftest._limit``: a test that never ends fails by the limit's name
+    with every thread's stack on stderr, the process it left is killed, and
+    the file's next test runs."""
+    import os
+    import subprocess
+    import sys
+
+    import conftest
+
+    case = tmp_path / "test_sleeper.py"
+    case.write_text(
+        "import subprocess, sys, time\n"
+        "import conftest\n"
+        "conftest.TEST_LIMIT_S = 1\n"
+        "def test_sleeps():\n"
+        "    global child\n"
+        "    child = subprocess.Popen([sys.executable, '-c',\n"
+        "                              'import time; time.sleep(60)'])\n"
+        "    time.sleep(60)  # the sleeping line\n"
+        "def test_after():\n"
+        "    assert child.wait(timeout=30) == -9\n")
+    env = conftest.subprocess_env()
+    env["PYTHONPATH"] = os.pathsep.join(
+        [os.path.dirname(conftest.__file__), env["PYTHONPATH"]])
+    run = subprocess.run(
+        [sys.executable, "-m", "pytest", str(case), "-q", "-p", "conftest",
+         "-p", "no:cacheprovider", "-p", "no:randomly", "--rootdir",
+         str(tmp_path)],
+        env=env, cwd=tmp_path, capture_output=True, text=True, timeout=120)
+    text = run.stdout + run.stderr
+    assert run.returncode == 1, text
+    assert "1 failed, 1 passed" in text, text
+    assert "ran past its limit of 1 s" in text
+    # pytest's own traceback and faulthandler's dump both name the line.
+    assert "time.sleep(60)  # the sleeping line" in text
+    assert 'test_sleeper.py", line 8 in test_sleeps' in text
+
+
+def test_a_world_that_lost_a_rank_is_not_waited_on_to_the_deadline():
+    """``conftest.wait_world``: results in the ranks' order, pipes read past
+    their buffers, a rank's failure leaves the others ``grace`` seconds, and
+    what is killed says so."""
+    import subprocess
+    import sys
+    import time
+
+    import conftest
+
+    def world(*bodies):
+        return [subprocess.Popen([sys.executable, "-c", body],
+                                 stdout=subprocess.PIPE,
+                                 stderr=subprocess.PIPE, text=True)
+                for body in bodies]
+
+    (rc0, out0, _), (rc1, _, err1) = conftest.wait_world(world(
+        "print('a' * 200000)",
+        "import sys; sys.stderr.write('b' * 300000)"), timeout=60)
+    assert (rc0, len(out0), rc1, len(err1)) == (0, 200001, 0, 300000)
+    began = time.monotonic()
+    failed, stalled = conftest.wait_world(world(
+        "import sys; sys.exit(3)", "import time; time.sleep(120)"),
+        timeout=60, grace=1)
+    assert time.monotonic() - began < 30
+    assert failed[0] == 3 and stalled[0] == -9
+    assert stalled[2].startswith("[killed after timeout]")

@@ -16,7 +16,7 @@ import subprocess
 
 import pytest
 
-from conftest import assert_all_ok, launch_world
+from conftest import assert_all_ok, launch_world, wait_world
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 NATIVE = os.path.join(REPO, "horovod_tpu", "native")
@@ -82,7 +82,7 @@ def test_tsan_native_unit_tests():
     lanes + compressed-leader hierarchical) and the wire quantizer's
     round-trip/EF kernels."""
     r = subprocess.run(["make", "-C", NATIVE, "check-tsan"],
-                       capture_output=True, text=True, timeout=600)
+                       capture_output=True, text=True, timeout=150)
     assert r.returncode == 0, f"{r.stdout[-2000:]}\n{r.stderr[-2000:]}"
     assert "ALL OK" in r.stdout
     for line in (r.stdout + r.stderr).splitlines():
@@ -132,17 +132,7 @@ def test_tsan_pipelined_allreduce():
             env={**os.environ, "LD_PRELOAD": rt,
                  "TSAN_OPTIONS": "exitcode=66 report_thread_leaks=0"},
             stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True))
-    results = []
-    try:
-        for p in procs:
-            out, err = p.communicate(timeout=240)
-            results.append((p.returncode, out, err))
-    finally:
-        for p in procs:
-            if p.poll() is None:
-                p.kill()
-                out, err = p.communicate()
-                results.append((-9, out, f"[killed after timeout]\n{err}"))
+    results = wait_world(procs)
     for rank, (rc, out, err) in enumerate(results):
         assert rc == 0, f"rank {rank} rc={rc}:\n{err[-2000:]}\n{out[-500:]}"
         for line in err.splitlines():

@@ -33,7 +33,8 @@ def test_ring_attention_matches_reference(make_runtime, causal):
     make_runtime(mesh_shape={"sp": 8})
     q, k, v = _qkv(jax.random.PRNGKey(0))
     expected = default_attention(q, k, v, causal=causal)
-    got = hvd.ring_attention(q, k, v, causal=causal, axis="sp")
+    got = jax.jit(lambda *a: hvd.ring_attention(*a, causal=causal,
+                                                axis="sp"))(q, k, v)
     np.testing.assert_allclose(np.asarray(got), np.asarray(expected),
                                rtol=1e-5, atol=1e-5)
 
@@ -44,7 +45,8 @@ def test_ring_attention_gqa(make_runtime):
     kr = jnp.repeat(k, 2, axis=2)
     vr = jnp.repeat(v, 2, axis=2)
     expected = default_attention(q, kr, vr, causal=True)
-    got = hvd.ring_attention(q, k, v, causal=True, axis="sp")
+    got = jax.jit(lambda *a: hvd.ring_attention(*a, causal=True,
+                                                axis="sp"))(q, k, v)
     np.testing.assert_allclose(np.asarray(got), np.asarray(expected),
                                rtol=1e-5, atol=1e-5)
 
@@ -60,7 +62,7 @@ def test_ring_attention_gradients(make_runtime):
         return jnp.sum(hvd.ring_attention_p(q, k, v, causal=True,
                                             axis="sp") ** 2)
 
-    expected = jax.grad(ref_loss, argnums=(0, 1, 2))(q, k, v)
+    expected = jax.jit(jax.grad(ref_loss, argnums=(0, 1, 2)))(q, k, v)
 
     spec = P(None, "sp")
 
@@ -68,8 +70,8 @@ def test_ring_attention_gradients(make_runtime):
         g = jax.grad(ring_loss, argnums=(0, 1, 2))(q, k, v)
         return g
 
-    got = jax.shard_map(body, mesh=hvd.mesh(), in_specs=(spec,) * 3,
-                        out_specs=(spec,) * 3)(q, k, v)
+    got = jax.jit(jax.shard_map(body, mesh=hvd.mesh(), in_specs=(spec,) * 3,
+                                out_specs=(spec,) * 3))(q, k, v)
     for g, e in zip(got, expected):
         np.testing.assert_allclose(np.asarray(g), np.asarray(e),
                                    rtol=1e-4, atol=1e-4)
@@ -138,9 +140,10 @@ def test_gpt_sequence_parallel_forward(make_runtime, attention):
     make_runtime(mesh_shape={"sp": 8})
     cfg, params, (tokens, targets, positions) = _tiny_gpt(attention, seq=32)
     ref = _reference(cfg)
-    expected = gpt.forward(params, tokens, positions, ref)
-    want_loss, want_grads = jax.value_and_grad(
-        lambda p: gpt.loss_fn(p, tokens, targets, positions, ref))(params)
+    expected = jax.jit(lambda p: gpt.forward(p, tokens, positions, ref))(
+        params)
+    want_loss, want_grads = jax.jit(jax.value_and_grad(
+        lambda p: gpt.loss_fn(p, tokens, targets, positions, ref)))(params)
 
     seq = P(None, "sp")
     logits = hvd.run_step(
@@ -177,9 +180,10 @@ def test_gpt_ulysses_flash_matches_dense(make_runtime):
     make_runtime(mesh_shape={"sp": 8})
     cfg, params, data = _tiny_gpt("ulysses", seq=16)
     seq = P(None, "sp")
-    loss_sp = jax.shard_map(
+    loss_sp = jax.jit(jax.shard_map(
         lambda p, *d: gpt.loss_fn(p, *d, cfg), mesh=hvd.mesh(),
-        in_specs=(P(), seq, seq, seq), out_specs=P())(params, *data)
-    loss_dense = gpt.loss_fn(params, *data, _reference(cfg))
+        in_specs=(P(), seq, seq, seq), out_specs=P()))(params, *data)
+    loss_dense = jax.jit(
+        lambda p: gpt.loss_fn(p, *data, _reference(cfg)))(params)
     np.testing.assert_allclose(float(loss_sp), float(loss_dense),
                                rtol=2e-3, atol=2e-3)

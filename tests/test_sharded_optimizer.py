@@ -244,7 +244,7 @@ class TestZero1ProcessMode:
                                        "HVDTPU_READ_DEADLINE_SECONDS": "60",
                                        "TEST_ZERO1_STEPS": "5",
                                    },
-                                   timeout=240)
+                                   timeout=150)
             load_flaked = any(rc != 0 and "liveness deadline" in (err + out)
                               for rc, out, err in results)
             if load_flaked and attempt == 0:

@@ -9,7 +9,7 @@ import sys
 import numpy as np
 import pytest
 
-from conftest import subprocess_env
+from conftest import subprocess_env, wait_world
 
 def _has_pyspark() -> bool:
     try:
@@ -53,9 +53,8 @@ def test_spark_task_rendezvous_without_spark():
             [sys.executable, WORKER, str(r), str(n), str(server.port)],
             env=subprocess_env(), stdout=subprocess.PIPE,
             stderr=subprocess.PIPE, text=True) for r in range(n)]
-        for r, p in enumerate(procs):
-            out, err = p.communicate(timeout=180)
-            assert p.returncode == 0, f"rank {r}:\n{err}\n{out}"
+        for r, (rc, out, err) in enumerate(wait_world(procs)):
+            assert rc == 0, f"rank {r}:\n{err}\n{out}"
             assert "ALL OK" in out
     finally:
         server.stop()
@@ -343,9 +342,8 @@ def test_spark_elastic_task_rendezvous_without_spark():
             env=subprocess_env(), stdout=subprocess.PIPE,
             stderr=subprocess.PIPE, text=True) for i in range(2)]
         outs = []
-        for i, p in enumerate(procs):
-            out, err = p.communicate(timeout=180)
-            assert p.returncode == 0, f"worker {i}:\n{err}\n{out}"
+        for i, (rc, out, err) in enumerate(wait_world(procs)):
+            assert rc == 0, f"worker {i}:\n{err}\n{out}"
             assert "ALL OK" in out
             outs.append(out)
         assert any("size=2" in o for o in outs)
@@ -385,9 +383,8 @@ def test_spark_elastic_scale_up_mid_run():
             [sys.executable, worker, "2", str(drv.port)], env=env,
             stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True))
         outs = []
-        for i, p in enumerate(procs):
-            out, err = p.communicate(timeout=240)
-            assert p.returncode == 0, f"worker {i}:\n{err}\n{out}"
+        for i, (rc, out, err) in enumerate(wait_world(procs)):
+            assert rc == 0, f"worker {i}:\n{err}\n{out}"
             assert "ALL OK" in out
             outs.append(out)
         # Everyone finished in the grown world.

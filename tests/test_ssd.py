@@ -51,9 +51,11 @@ def _rel(got, want):
 @pytest.mark.parametrize("groups", [1, 2])
 def test_chunked_scan_matches_the_recurrence(seq, groups):
     args, start = _inputs(seq, seq=seq, groups=groups)
-    y, final = ssd.ssd_chunked(*args, chunk=CHUNK, dtype=jnp.float32,
-                               initial_state=start)
-    want_y, want_final = ssd.ssd_sequential(*args, initial_state=start)
+    y, final = jax.jit(lambda *a: ssd.ssd_chunked(
+        *a[:-1], chunk=CHUNK, dtype=jnp.float32, initial_state=a[-1]))(
+            *args, start)
+    want_y, want_final = jax.jit(lambda *a: ssd.ssd_sequential(
+        *a[:-1], initial_state=a[-1]))(*args, start)
     assert y.shape == want_y.shape == args[0].shape
     assert _rel(y, want_y) < 2e-5
     # Padded rows (dt = 0) leave the state alone: the state after the last
@@ -74,8 +76,8 @@ def test_chunked_scan_gradients_match_the_recurrence(seq):
     def chunked(*a, **kw):
         return ssd.ssd_chunked(*a, chunk=CHUNK, dtype=jnp.float32, **kw)
 
-    got = jax.grad(scalar(chunked), argnums=range(7))(*args, start)
-    want = jax.grad(scalar(ssd.ssd_sequential), argnums=range(7))(
+    got = jax.jit(jax.grad(scalar(chunked), argnums=range(7)))(*args, start)
+    want = jax.jit(jax.grad(scalar(ssd.ssd_sequential), argnums=range(7)))(
         *args, start)
     for name, g, w in zip(("x", "dt", "A", "B", "C", "D", "initial state"),
                           got, want):
@@ -140,8 +142,9 @@ def test_kernels_match_the_recurrence_in_values_and_every_gradient(
     assert y.dtype == dtype
     assert _rel(y.astype(jnp.float32), want_y) < tol
     assert _rel(final, want_final) < tol
-    got = jax.grad(_loss(chunked), argnums=range(7))(*args, start)
-    want = jax.grad(_loss(ssd.ssd_sequential), argnums=range(7))(*args, start)
+    got = jax.jit(jax.grad(_loss(chunked), argnums=range(7)))(*args, start)
+    want = jax.jit(jax.grad(_loss(ssd.ssd_sequential), argnums=range(7)))(
+        *args, start)
     for name, g, w in zip(NAMES, got, want):
         assert bool(jnp.all(jnp.isfinite(g))), name
         assert _rel(g, w) < tol, name
@@ -170,8 +173,9 @@ def test_kernels_at_the_cells_own_tile(dtype, tol):
         f(*args, initial_state=start) for f in (chunked, ssd.ssd_sequential))
     assert _rel(y.astype(jnp.float32), want_y) < tol
     assert _rel(final, want_final) < tol
-    got = jax.grad(_loss(chunked), argnums=range(7))(*args, start)
-    want = jax.grad(_loss(ssd.ssd_sequential), argnums=range(7))(*args, start)
+    got = jax.jit(jax.grad(_loss(chunked), argnums=range(7)))(*args, start)
+    want = jax.jit(jax.grad(_loss(ssd.ssd_sequential), argnums=range(7)))(
+        *args, start)
     for name, g, w in zip(NAMES, got, want):
         assert _rel(g, w) < tol, name
 
@@ -228,8 +232,8 @@ def test_the_kernels_read_the_running_sums_in_float32():
     assert _rel(worse, want) > 100 * exact
 
     def d_cum(f, inputs):
-        return jax.grad(lambda cum: jnp.sum(jnp.sin(f(
-            *inputs[:4], cum, *inputs[5:]).astype(jnp.float32))))(inputs[4])
+        return jax.jit(jax.grad(lambda cum: jnp.sum(jnp.sin(f(
+            *inputs[:4], cum, *inputs[5:]).astype(jnp.float32)))))(inputs[4])
 
     inputs = _kernel_inputs(5, jnp.bfloat16)
     assert _rel(d_cum(ssd._scan_output, inputs),

@@ -303,7 +303,7 @@ class TestClangTargets:
     @pytest.mark.parametrize("target", ["analyze", "tidy"])
     def test_target_exits_zero(self, target):
         r = subprocess.run(["make", "-C", NATIVE, target],
-                           capture_output=True, text=True, timeout=600)
+                           capture_output=True, text=True, timeout=150)
         assert r.returncode == 0, \
             f"make {target} failed:\n{r.stdout}\n{r.stderr}"
         out = r.stdout + r.stderr

@@ -16,7 +16,7 @@ WORKER = os.path.join(REPO, "tests", "data", "torch_worker.py")
 
 @pytest.mark.parametrize("n", [2, 3])
 def test_torch_surface_multiprocess(n):
-    assert_all_ok(launch_world(n, WORKER, timeout=240))
+    assert_all_ok(launch_world(n, WORKER, timeout=150))
 
 
 class TestSingleProcess:

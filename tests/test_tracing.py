@@ -18,7 +18,8 @@ import pytest
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 DATA = os.path.join(REPO, "tests", "data")
 
-from conftest import free_port, launch_world, subprocess_env  # noqa: E402
+from conftest import (free_port, launch_world, subprocess_env,  # noqa: E402
+                      wait_world)
 
 from horovod_tpu.trace_analysis import (build_report, diff_reports,  # noqa: E402
                                         format_report, load_trace_dir,
@@ -211,7 +212,7 @@ def test_hvdrun_trace_end_to_end(tmp_path):
          "--trace", str(trace_dir), "--trace-sample", "1",
          sys.executable, os.path.join(DATA, "trace_worker.py")],
         env=dict(subprocess_env(), TEST_TRACE_ITERS="2"),
-        capture_output=True, text=True, timeout=180)
+        capture_output=True, text=True, timeout=150)
     assert rc.returncode == 0, rc.stderr
     assert (trace_dir / "trace.0.json").exists()
     assert (trace_dir / "trace.1.json").exists()
@@ -321,16 +322,6 @@ def test_timeline_pins_shm_tcp_zc_tag(tmp_path):
             [sys.executable, os.path.join(DATA, "trace_tag_worker.py")],
             env=env, stdout=subprocess.PIPE, stderr=subprocess.PIPE,
             text=True))
-    results = []
-    try:
-        for p in procs:
-            out, err = p.communicate(timeout=180)
-            results.append((p.returncode, out, err))
-    finally:
-        for p in procs:
-            if p.poll() is None:
-                p.kill()
-                p.communicate()
-    for r, (rc, out, err) in enumerate(results):
+    for r, (rc, out, err) in enumerate(wait_world(procs)):
         assert rc == 0, f"rank {r} failed:\n{err}\n{out}"
         assert "ALL OK" in out

@@ -160,7 +160,7 @@ def _run_training(mode):
         extra_env={
             "HVDTPU_COMPRESSION": mode,
             "HVDTPU_COMPRESSION_MIN_BYTES": "512",
-        }, timeout=300)
+        }, timeout=150)
     assert_all_ok(results)
     for _rc, out, _err in results:
         for line in out.splitlines():
