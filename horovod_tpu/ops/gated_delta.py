@@ -65,28 +65,39 @@ K^T`` and ``Q K^T`` (once a key head), the masked decay tile ``exp(cum_i -
 cum_j)``, ``A``, ``T`` (float32 throughout, rounded to ``dtype`` once before
 it is applied), ``u_own = T (beta V)`` (float32), ``w = T (beta G K)``,
 ``attn = (Q K^T) * decay`` and the two scaled copies the recurrence reads,
-``q G`` and ``k G_last / G``. No ``[B, c, H, Q, Q]`` float32 tensor reaches
-HBM. The operands stay as the mixer has them, tokens by channels: a grid
-cell is ``chunks_per_block`` chunks of one sequence (walked in a loop), one
-key head (a ``[Q, K]`` block of ``q`` and of ``k`` a chunk) and the value
-heads that read it (their ``[Q, V]`` blocks side by side in ``v``); the
-float32 running sums ``cum`` and ``beta`` come as ``[B, S, Hv]`` and a
-head's column is picked by a masked sum along the lanes. The outputs are
-written in the recurrence's order, ``[c, B, Hv, Q, .]``. The backward kernel
-takes the same inputs as its only residuals, makes ``A`` and ``T`` again in
-VMEM, forms ``dT`` from the cotangents of ``u_own`` and ``w``, applies ``dA
-= -T^T dT T^T`` (float32, the highest precision) and returns ``dq`` and
-``dk`` (summed over the key head's value heads), ``dv`` (rounded once to
-``dtype``), ``d cum`` and ``d beta`` (float32, a row a head ``[B, c, Hk, 2
-Hv / Hk, Q]``, turned back outside). Off the TPU the kernels run in Pallas
-interpret mode; on it a chunk they do not tile raises (:func:`_tiling`).
-The five outputs cross HBM to the recurrence, whose residuals they are, and
-**are what a checkpoint keeps**: the recurrence's rule names each
-``gdn_scan_operands`` (:data:`SAVED_NAMES`), and the recomputed copy of a block checkpointed under a policy that keeps the name does not
-run ``hvd_gdn_fwd`` (a value head a token the lanes' ``V`` in float32 and
-``3 K + Q`` in ``dtype``: 738 MB a layer in the Qwen cell; they were in HBM
-already and the backward kernels read them from there either way).
-Outside a checkpoint a name is an identity.
+``q G`` and ``k G_last / G``. The one ``[Q, Q]`` float32 tensor that
+reaches HBM is ``T`` itself (below). The operands stay as the mixer has
+them, tokens by channels: a grid cell is ``chunks_per_block`` chunks of one
+sequence (walked in a loop), one key head (a ``[Q, K]`` block of ``q`` and
+of ``k`` a chunk) and the value heads that read it (their ``[Q, V]`` blocks
+side by side in ``v``); the float32 running sums ``cum`` and ``beta`` come
+as ``[B, S, Hv]`` and a head's column is picked by a masked sum along the
+lanes. The outputs are written in the recurrence's order, ``[c, B, Hv, Q,
+.]``. **The inverse is made once a step**: the forward kernel writes the
+float32 ``T`` it holds, before its rounding, as a sixth output, and the
+backward kernel reads it and holds no inverse (PR 68; the inverse was 4.8
+of that kernel's 12.0 ms a layer at the Qwen cell's shape). In HBM a
+chunk's ``T`` lies with its lower 32 rows beside its upper 32, ``[c, B, Hv,
+32, 128]`` at a chunk of 64 (:func:`_pack_t`: as it stands a ``[64, 64]``
+float32 tile would be half padding there); the layout is the two kernels'
+own and nothing else reads it. The backward kernel takes the forward's
+inputs and that ``T`` as its only residuals, makes the normed rows, the
+decays and ``A`` again in VMEM, forms ``dT`` from the cotangents of
+``u_own`` and ``w``, applies ``dA = -T^T dT T^T`` (float32, the highest
+precision) and returns ``dq`` and ``dk`` (summed over the key head's value
+heads), ``dv`` (rounded once to ``dtype``), ``d cum`` and ``d beta``
+(float32, a row a head ``[B, c, Hk, 2 Hv / Hk, Q]``, turned back outside).
+Off the TPU the kernels run in Pallas interpret mode; on it a chunk they do
+not tile raises (:func:`_tiling`). The five operands cross HBM to the
+recurrence, whose residuals they are, and with ``T`` **are what a
+checkpoint keeps**: the recurrence's rule names each of the five
+``gdn_scan_operands`` (:data:`SAVED_NAMES`) and the chunk-local rule's
+forward names ``T`` the same, as its residual alone, so the recomputed copy
+of a block checkpointed under a policy that keeps the name does not run
+``hvd_gdn_fwd`` (a value head a token the lanes' ``V`` and ``Q`` in float32
+and ``3 K + Q`` in ``dtype``: 872 MB a layer in the Qwen cell, 134 of them
+``T``; the five were in HBM already and the backward kernels read them from
+there either way). Outside a checkpoint a name is an identity.
 
 **Heads of any size.** A key head of ``K`` and a value head of ``V`` come
 and go as published; the four kernels carry a head at the next multiple of
@@ -141,8 +152,9 @@ the residuals carry the name, and where they are not it reads them named,
 or the recomputed copy would want ``hvd_gdn_fwd`` for them (PERF.md,
 Findings, PR 56).
 
-**The inverse in VMEM** (``pallas_util.unit_lower_inverse_in_vmem``, which the
-``hvd_kda_*`` kernels share): the diagonal blocks of
+**The inverse in VMEM** (``pallas_util.unit_lower_inverse_in_vmem``, which
+``hvd_kda_fwd`` shares; the forward kernels alone call it since PR 68): the
+diagonal blocks of
 ``_SUBSTITUTE`` = 32 rows by forward substitution on the vector unit (a
 column a step, exact float32), then :func:`_inverse`'s rounds from there up:
 at a chunk of 64, one round of two ``[64, 64]`` products on the MXU at the
@@ -191,10 +203,11 @@ KERNEL_REC_FWD = "hvd_gdn_rec_fwd"
 KERNEL_REC_BWD = "hvd_gdn_rec_bwd"
 # What this module hands ``checkpoint_name``, for a ``jax.checkpoint`` around
 # the caller to keep: what the recurrence's backward kernels read beside
-# their inputs. The chunk-local kernel's five outputs (``gdn_scan_operands``:
+# their inputs. The chunk-local kernel's six outputs (``gdn_scan_operands``:
 # a value head a token the lanes' V in float32 and 3 K + Q in the compute
-# dtype, 738 MB a layer in the Qwen cell, 472 in the Olmo cell; they cross
-# HBM to the recurrence's kernels in the forward pass already) and each
+# dtype, which cross HBM to the recurrence's kernels in the forward pass
+# already, and for ``hvd_gdn_bwd`` alone T, Q more in float32, PR 68: 738 +
+# 134 MB a layer in the Qwen cell, 472 + 63 in the Olmo cell) and each
 # chunk's entering state (``gdn_scan_entering``, 268 MB a layer in the Qwen
 # cell), named only where no lane of a state is padding (key and value head
 # both whole lane tiles; computed here from the shapes, no caller's option).
@@ -403,7 +416,8 @@ class _Chunk:
         ``[Q, 1]`` and ``beta`` likewise, ``exp(cum_i - cum_j)`` kept where
         ``j <= i`` (the mask on the exponent: above the diagonal the
         difference is positive and may overflow), ``exp(cum)`` and
-        ``exp(cum_last - cum)`` as columns, ``A`` and ``T = (I + A)^-1``."""
+        ``exp(cum_last - cum)`` as columns, and ``A``. (``T = (I + A)^-1``
+        is the forward kernel's to make and the backward's to read.)"""
         cum = self.column(cum_ref[0, at, :], head)
         beta = self.column(beta_ref[0, at, :], head)
         decay = jnp.exp(jnp.where(self.lower, cum - self.as_row(cum),
@@ -411,17 +425,43 @@ class _Chunk:
         a = jnp.where(self.strictly, self.kk * decay * beta, 0.0)
         size = cum.shape[0]
         return beta, decay, jnp.exp(cum), \
-            jnp.exp(cum[size - 1:size, :] - cum), a, \
-            unit_lower_inverse_in_vmem(a, _SUBSTITUTE)
+            jnp.exp(cum[size - 1:size, :] - cum), a
+
+
+def _t_pack(chunk: int) -> int:
+    """Blocks of ``T``'s rows that lie side by side in HBM: a ``[64, 64]``
+    float32 tile as it stands is half padding there (the lanes are 128), so
+    its lower 32 rows go beside its upper 32, ``[32, 128]``. As many as fill
+    the lanes and leave whole eight-row tiles; 1 (``T`` as it is) from a
+    chunk of 128 up and under 16."""
+    return max(1, min(LANES // chunk, chunk // 8))
+
+
+def _pack_t(t):
+    """``T`` ``[Q, Q]`` as the kernels keep it, ``[Q / pack, pack Q]``."""
+    pack = _t_pack(t.shape[0])
+    rows = t.shape[0] // pack
+    return t if pack == 1 else jnp.concatenate(
+        [t[i * rows:(i + 1) * rows] for i in range(pack)], axis=1)
+
+
+def unpack_t(kept, chunk: int):
+    """:func:`_pack_t` undone, ``[..., Q / pack, pack Q]`` -> ``[..., Q,
+    Q]``: in the backward kernel, and on the whole array where a test reads
+    what the forward kernel wrote."""
+    return kept if kept.shape[-1] == chunk else jnp.concatenate(
+        [kept[..., i:i + chunk] for i in range(0, kept.shape[-1], chunk)],
+        axis=-2)
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, cum_ref, beta_ref, u_ref, w_ref,
-                attn_ref, qin_ref, kout_ref, *, nc: int, rep: int,
+                attn_ref, qin_ref, kout_ref, t_ref, *, nc: int, rep: int,
                 chunk: int, width: int, q_scale):
     """A grid cell: ``nc`` chunks of one sequence, one key head and its
     ``rep`` value heads. ``u = T (beta V)`` float32, ``w = T (beta G K)``,
     ``attn = (Q K^T) * decay``, ``q G`` and ``k G_last / G``: what the
-    recurrence over chunks reads, in its order ``[c, B, Hv, Q, .]``."""
+    recurrence over chunks reads, in its order ``[c, B, Hv, Q, .]``; and the
+    float32 ``T`` itself, for the backward kernel (:func:`_pack_t`)."""
     f32, dtype = jnp.float32, q_ref.dtype
     first = pl.program_id(2) * rep
 
@@ -431,8 +471,10 @@ def _fwd_kernel(q_ref, k_ref, v_ref, cum_ref, beta_ref, u_ref, w_ref,
             at = pl.ds(pl.multiple_of(n * chunk, chunk), chunk)
             c = _Chunk(q_ref, k_ref, at, q_scale)
             for r in range(rep):
-                beta, decay, grown, to_end, _, t = c.head(
+                beta, decay, grown, to_end, a = c.head(
                     cum_ref, beta_ref, at, first + r)
+                t = unit_lower_inverse_in_vmem(a, _SUBSTITUTE)
+                t_ref[n, 0, r] = _pack_t(t)
                 t = t.astype(dtype)
                 v = v_ref[0, at, r * width:(r + 1) * width].astype(f32)
                 u_ref[n, 0, r] = jnp.dot(t, (v * beta).astype(dtype),
@@ -449,11 +491,12 @@ def _fwd_kernel(q_ref, k_ref, v_ref, cum_ref, beta_ref, u_ref, w_ref,
 
 
 def _bwd_kernel(q_ref, k_ref, v_ref, cum_ref, beta_ref, du_ref, dw_ref,
-                dattn_ref, dqin_ref, dkout_ref, dq_ref, dk_ref, dv_ref,
+                dattn_ref, dqin_ref, dkout_ref, t_ref, dq_ref, dk_ref, dv_ref,
                 drows_ref, *, nc: int, rep: int, chunk: int, width: int,
                 q_scale):
-    """The forward's cotangents on the same grid cell. ``A`` and ``T`` are
-    made again from the inputs; ``dT = du (beta V)^T + dw (beta G K)^T``,
+    """The forward's cotangents on the same grid cell. ``A`` is made again
+    from the inputs and ``T`` is read as the forward kernel wrote it (no
+    inverse here); ``dT = du (beta V)^T + dw (beta G K)^T``,
     ``dA = -T^T dT T^T`` (float32, the highest precision), and from ``dA``
     and ``d attn`` the cotangents of ``K K^T`` and ``Q K^T`` (summed over
     the key head's value heads here, then through their products into
@@ -476,8 +519,9 @@ def _bwd_kernel(q_ref, k_ref, v_ref, cum_ref, beta_ref, du_ref, dw_ref,
             dq = dk = jnp.zeros(c.qf.shape, f32)
             drows = jnp.zeros((2 * rep, chunk), f32)
             for r in range(rep):
-                beta, decay, grown, to_end, a, t32 = c.head(
+                beta, decay, grown, to_end, a = c.head(
                     cum_ref, beta_ref, at, first + r)
+                t32 = unpack_t(t_ref[n, 0, r], chunk)
                 t = t32.astype(dtype)
                 lanes = slice(r * width, (r + 1) * width)
                 v = v_ref[0, at, lanes].astype(f32)
@@ -540,7 +584,8 @@ def _plan(kernel, body, q, k, v, cum, beta, q_scale):
     chunk is a ``[Q, K]`` block), ``v`` ``[B, S, Hv V]``, the float32
     running sums and ``beta`` ``[B, S, Hv]`` (every head's, a chunk's rows:
     fetched once for a block of chunks, the key heads walk it). ``scan`` is
-    a tensor in the recurrence's order ``[c, B, Hv, Q, .]``, ``rows`` the
+    a tensor in the recurrence's order ``[c, B, Hv, Q, .]`` (``scan_t``: the
+    kept ``T``, :func:`_pack_t`), ``rows`` the
     backward's ``d cum | d beta`` ``[B, c, Hk, 2 rep, Q]``. ``q_scale``:
     what the kernels multiply the ``q`` they normed by, None where the
     caller normed."""
@@ -557,16 +602,18 @@ def _plan(kernel, body, q, k, v, cum, beta, q_scale):
         return pl.BlockSpec((1, nc * chunk, lanes),
                             lambda b, c, h: (b, c, h if walk else 0))
 
-    def scan(last):
-        return pl.BlockSpec((nc, 1, rep, chunk, last),
+    def scan(last, rows=chunk):
+        return pl.BlockSpec((nc, 1, rep, rows, last),
                             lambda b, c, h: (c, b, h, 0, 0))
 
+    pack = _t_pack(chunk)
     specs = {"key": tokens(key_dim), "value": tokens(rep * width),
              "heads": tokens(heads, walk=False),
              "rows": pl.BlockSpec((1, nc, 1, 2 * rep, chunk),
                                   lambda b, c, h: (b, c, h, 0, 0)),
              "scan_k": scan(key_dim), "scan_v": scan(width),
-             "scan_q": scan(chunk)}
+             "scan_q": scan(chunk),
+             "scan_t": scan(pack * chunk, chunk // pack)}
     call = dict(
         grid=(batch, n_chunks // nc, key_heads),
         compiler_params=pltpu.CompilerParams(
@@ -583,52 +630,60 @@ _SCAN_SPECS = ("scan_v", "scan_k", "scan_q", "scan_k", "scan_k")
 
 def _scan_shapes(q, v, cum, vma):
     """``u`` (float32), ``w``, ``attn``, ``q G``, ``k G_last / G`` in the
-    recurrence's order."""
-    lead = (cum.shape[1], q.shape[0], v.shape[2], cum.shape[2])
-    return [jax.ShapeDtypeStruct(lead + (last,), dtype, vma=vma)
+    recurrence's order, and the kept float32 ``T`` in the same."""
+    chunk = cum.shape[2]
+    lead, pack = (cum.shape[1], q.shape[0], v.shape[2]), _t_pack(chunk)
+    return [jax.ShapeDtypeStruct(lead + last, dtype, vma=vma)
             for last, dtype in (
-                (v.shape[3], jnp.float32), (q.shape[3], q.dtype),
-                (cum.shape[2], q.dtype), (q.shape[3], q.dtype),
-                (q.shape[3], q.dtype))]
+                ((chunk, v.shape[3]), jnp.float32),
+                ((chunk, q.shape[3]), q.dtype), ((chunk, chunk), q.dtype),
+                ((chunk, q.shape[3]), q.dtype),
+                ((chunk, q.shape[3]), q.dtype),
+                ((chunk // pack, pack * chunk), jnp.float32))]
 
 
 @functools.partial(jax.jit, inline=True, static_argnames="q_scale")
 def _fwd_call(q, k, v, cum, beta, *, q_scale=None):
-    """(Jitted inline, as :func:`_bwd_call` is: a kernel's body, 1,100
-    equations of unrolled substitution, is traced once for a shape, and a
-    block's recomputed copy and the next layers re-bind it under their own
-    scopes; 0.4 s of a job's set-up on the benchmark's host.)
+    """(Jitted inline, as :func:`_bwd_call` is: a kernel's body, this one's
+    1,100 equations, most of them unrolled substitution, is traced once for
+    a shape, and a block's recomputed copy and the next layers re-bind it
+    under their own scopes; 0.4 s of a job's set-up on the benchmark's
+    host.)
 
     ``q``, ``k`` ``[B, S, Hk, K]`` and ``v`` ``[B, S, Hv, V]`` in the
     operand dtype, ``S`` a whole number of chunks; float32 ``cum`` (the
     running sum of the log decays inside each chunk) and ``beta`` ``[B, c,
     Q, Hv]`` -> what needs no state, ``[c, B, Hv, Q, .]``: ``u_own = T (beta
     V)`` float32, ``w = T (beta G K)``, ``attn = (Q K^T) * decay``, ``q G``
-    and ``k G_last / G`` in the operand dtype. With ``q_scale`` the kernel
+    and ``k G_last / G`` in the operand dtype, and last the float32 ``T``
+    for :func:`_bwd_call` alone, ``[c, B, Hv, Q / 2, 2 Q]`` at a chunk of 64
+    (:func:`_pack_t`). With ``q_scale`` the kernel
     norms the rows of ``q`` and ``k`` first (:class:`_Chunk`)."""
     args, specs, body, call = _plan(KERNEL_FWD, _fwd_kernel, q, k, v, cum,
                                     beta, q_scale)
     return pl.pallas_call(
         body, in_specs=[specs[name] for name in _FWD_SPECS],
-        out_specs=[specs[name] for name in _SCAN_SPECS],
+        out_specs=[specs[name] for name in _SCAN_SPECS + ("scan_t",)],
         out_shape=_scan_shapes(q, v, cum, _out_vma(*args)), **call)(*args)
 
 
 @functools.partial(jax.jit, inline=True, static_argnames="q_scale")
-def _bwd_call(q, k, v, cum, beta, du, dw, dattn, dqin, dkout, *,
+def _bwd_call(q, k, v, cum, beta, du, dw, dattn, dqin, dkout, t, *,
               q_scale=None):
-    """The cotangents of :func:`_fwd_call`'s inputs for those of its
-    outputs: ``dq``, ``dk`` (of the raw rows under ``q_scale``: through the
+    """The cotangents of :func:`_fwd_call`'s inputs for those of its first
+    five outputs, given its sixth (``t``: the forward's ``T``, as written):
+    ``dq``, ``dk`` (of the raw rows under ``q_scale``: through the
     norm in float32), ``dv`` in the operand dtype (rounded once), ``d cum``
     and ``d beta`` float32."""
     args, specs, body, call = _plan(KERNEL_BWD, _bwd_kernel, q, k, v, cum,
                                     beta, q_scale)
-    args += (du, dw, dattn, dqin, dkout)
+    args += (du, dw, dattn, dqin, dkout, t)
     vma = _out_vma(*args)
     rep = v.shape[2] // q.shape[2]
     n_chunks, chunk = cum.shape[1:3]
     dq, dk, dv, drows = pl.pallas_call(
-        body, in_specs=[specs[name] for name in _FWD_SPECS + _SCAN_SPECS],
+        body, in_specs=[specs[name] for name in _FWD_SPECS + _SCAN_SPECS
+                        + ("scan_t",)],
         out_specs=[specs[name] for name in ("key", "key", "value", "rows")],
         out_shape=[jax.ShapeDtypeStruct(t.shape, q.dtype, vma=vma)
                    for t in args[:3]]
@@ -646,18 +701,27 @@ def _bwd_call(q, k, v, cum, beta, du, dw, dattn, dqin, dkout, *,
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
 def _chunk_local(q_scale, q, k, v, cum, beta):
     """The WY form's part that needs no state, through the kernels: see
-    :func:`_fwd_call`."""
-    return tuple(_fwd_call(q, k, v, cum, beta, q_scale=q_scale))
+    :func:`_fwd_call` (its first five outputs: the recurrence reads no
+    ``T``)."""
+    return tuple(_fwd_call(q, k, v, cum, beta, q_scale=q_scale)[:5])
 
 
 def _chunk_local_fwd(q_scale, *inputs):
-    # The residuals are the inputs alone: the backward kernel makes the
-    # normed rows, A and T again in VMEM.
-    return tuple(_fwd_call(*inputs, q_scale=q_scale)), inputs
+    # The residuals: the inputs (the backward kernel makes the normed rows
+    # and A again in VMEM) and the forward kernel's T, which it reads and
+    # makes no inverse. T is named for a checkpoint as the kernel's other
+    # outputs are (``SAVED_NAMES``), here and as a residual only: no value
+    # of the forward pass reads it, so no ``reduce_precision`` pass lands
+    # on it, and a recomputed copy that finds all six kept runs no
+    # ``hvd_gdn_fwd``.
+    *operands, t = _fwd_call(*inputs, q_scale=q_scale)
+    return tuple(operands), inputs + (
+        checkpoint_name(t, "gdn_scan_operands"),)
 
 
-def _chunk_local_bwd(q_scale, inputs, cotangents):
-    return _bwd_call(*inputs, *cotangents, q_scale=q_scale)
+def _chunk_local_bwd(q_scale, kept, cotangents):
+    *inputs, t = kept
+    return _bwd_call(*inputs, *cotangents, t, q_scale=q_scale)
 
 
 _chunk_local.defvjp(_chunk_local_fwd, _chunk_local_bwd)

@@ -48,10 +48,16 @@ the decayed ``K K^T`` and ``Q K^T`` by sub-blocks, ``A``, ``T`` (float32:
 ``pallas_util.unit_lower_inverse_in_vmem``, shared with the gated delta
 rule), ``u_own = T (beta V)`` (float32), ``w = T (beta K Gamma)``, ``attn``,
 ``q Gamma`` and ``k Gamma_last / Gamma`` come out in the recurrence's order
-``[c, B, H, Q, .]``; no ``[B, c, H, Q, Q]`` float32 array reaches HBM. The
-backward kernel makes all of it again from the inputs and returns ``dq``,
-``dk`` (of the raw rows under ``norm_qk``), ``dv``, ``dg`` (float32, a
-channel) and ``d beta``.
+``[c, B, H, Q, .]``. **The inverse is made once a step** (PR 68): the
+forward kernel writes the float32 ``T`` it holds, before its rounding, as a
+sixth output, a chunk's lower 32 rows beside its upper 32 (``[c, B, H, 32,
+128]`` at a chunk of 64, :func:`_pack_t`: a ``[64, 64]`` float32 tile as it
+stands would be half padding in HBM; the layout is the two kernels' own),
+and the backward kernel reads it and holds no inverse (8.5 -> 5.1 ms a
+layer at the Ling cell's shape): it makes everything else again from the
+inputs and returns ``dq``, ``dk`` (of the raw rows under ``norm_qk``),
+``dv``, ``dg`` (float32, a channel) and ``d beta``. ``T`` is the one ``[Q,
+Q]`` float32 array that reaches HBM.
 
 **The recurrence's kernels** (``hvd_kda_rec_fwd``, ``hvd_kda_rec_bwd``): a
 grid cell is a few chunks and a few heads, whose float32 states stay in a
@@ -63,12 +69,12 @@ keeps each chunk's entering state in the operand dtype, the backward
 kernel's residual; no ``[B, S, H, K, V]`` array exists.
 
 Both pairs sit under **one** ``jax.custom_vjp`` (:func:`_scan`), whose
-residuals are the inputs, the five operands, the last decays and the
+residuals are the inputs, the five operands, ``T``, the last decays and the
 entering states. Decays, running sums, ``T`` and the state are float32; the
 other products take operands in ``dtype`` and accumulate in float32.
 
 **What a checkpoint keeps.** The rule's forward (:func:`_scan_forward`) hands
-the five operands (``kda_scan_operands``) and the entering states
+the five operands and ``T`` (``kda_scan_operands``) and the entering states
 (``kda_scan_entering``) to ``checkpoint_name`` **as its residuals**, and the
 mixer names the output (``kda_scan_out``): every output of both forward
 kernels, so the recomputed copy of a block checkpointed under a policy that
@@ -106,9 +112,10 @@ KERNEL_REC_FWD = "hvd_kda_rec_fwd"
 KERNEL_REC_BWD = "hvd_kda_rec_bwd"
 # What this module hands ``checkpoint_name``, for a ``jax.checkpoint`` around
 # the caller to keep: what the recurrence's backward kernel reads beside the
-# scan's inputs. ``hvd_kda_fwd``'s five outputs (``kda_scan_operands``: a head
+# scan's inputs. ``hvd_kda_fwd``'s six outputs (``kda_scan_operands``: a head
 # a token V in float32 and 3 K + Q in the compute dtype, 369 MB a layer in
-# the Ling cell) and each chunk's entering state, ``hvd_kda_rec_fwd``'s side
+# the Ling cell, and for ``hvd_kda_bwd`` alone T, Q more in float32, 67 MB a
+# layer, PR 68) and each chunk's entering state, ``hvd_kda_rec_fwd``'s side
 # output (``kda_scan_entering``: K V in the compute dtype a head a chunk, 134
 # MB a layer). With the mixer's ``kda_scan_out`` kept too the recomputed copy
 # runs neither forward kernel (PERF.md, Findings, PR 64). The last decays (2
@@ -180,7 +187,8 @@ class _Chunk:
     ``cum`` of the log decays, ``beta`` as a column, ``Gamma`` (``grown``)
     and ``Gamma_last / Gamma`` (``to_end``), the decayed ``K K^T`` and ``Q
     K^T`` by sub-blocks (``blocks`` keeps each row sub-block's scales and
-    scaled operands for the backward pass), ``A`` and ``T``."""
+    scaled operands for the backward pass) and ``A``. (``T = (I + A)^-1``
+    is the forward kernel's to make and the backward's to read.)"""
 
     def __init__(self, q_ref, k_ref, g_ref, beta_ref, at, head, q_scale,
                  sub: int):
@@ -223,7 +231,6 @@ class _Chunk:
         self.kk = jnp.concatenate(kk, axis=0)
         self.qk = jnp.where(self.lower, jnp.concatenate(qk, axis=0), 0.0)
         self.a = jnp.where(self.strictly, self.kk * self.beta, 0.0)
-        self.t = unit_lower_inverse_in_vmem(self.a)
 
     def raw_cotangents(self, dq, dk):
         """The float32 cotangents of the rows as they came, for those of the
@@ -235,10 +242,39 @@ class _Chunk:
                                   (self.q_scale, 1.0))
 
 
+def _t_pack(chunk: int) -> int:
+    """Blocks of ``T``'s rows that lie side by side in HBM: a ``[64, 64]``
+    float32 tile as it stands is half padding there (the lanes are 128), so
+    its lower 32 rows go beside its upper 32, ``[32, 128]``. As many as fill
+    the lanes and leave whole eight-row tiles; 1 (``T`` as it is) from a
+    chunk of 128 up and under 16. (``ops/gated_delta.py`` keeps its ``T``
+    so; the layout is each pair of kernels' own.)"""
+    return max(1, min(LANES // chunk, chunk // 8))
+
+
+def _pack_t(t):
+    """``T`` ``[Q, Q]`` as the kernels keep it, ``[Q / pack, pack Q]``."""
+    pack = _t_pack(t.shape[0])
+    rows = t.shape[0] // pack
+    return t if pack == 1 else jnp.concatenate(
+        [t[i * rows:(i + 1) * rows] for i in range(pack)], axis=1)
+
+
+def unpack_t(kept, chunk: int):
+    """:func:`_pack_t` undone, ``[..., Q / pack, pack Q]`` -> ``[..., Q,
+    Q]``: in the backward kernel, and on the whole array where a test reads
+    what the forward kernel wrote."""
+    return kept if kept.shape[-1] == chunk else jnp.concatenate(
+        [kept[..., i:i + chunk] for i in range(0, kept.shape[-1], chunk)],
+        axis=-2)
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, u_ref, w_ref, attn_ref,
-                qin_ref, kout_ref, *, nc: int, chunk: int, sub: int, q_scale):
+                qin_ref, kout_ref, t_ref, *, nc: int, chunk: int, sub: int,
+                q_scale):
     """A grid cell: ``nc`` chunks of one sequence, one head. What the
-    recurrence reads, in its order ``[c, B, H, Q, .]``."""
+    recurrence reads, in its order ``[c, B, H, Q, .]``, and the float32
+    ``T`` for the backward kernel (:func:`_pack_t`)."""
     f32, dtype = jnp.float32, q_ref.dtype
     head = pl.program_id(2)
 
@@ -247,7 +283,9 @@ def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, u_ref, w_ref, attn_ref,
         def one(n, carry):
             at = pl.ds(pl.multiple_of(n * chunk, chunk), chunk)
             c = _Chunk(q_ref, k_ref, g_ref, beta_ref, at, head, q_scale, sub)
-            t = c.t.astype(dtype)
+            t = unit_lower_inverse_in_vmem(c.a)
+            t_ref[n, 0, 0] = _pack_t(t)
+            t = t.astype(dtype)
             v = v_ref[0, at, :].astype(f32)
             u_ref[n, 0, 0] = jnp.dot(t, (v * c.beta).astype(dtype),
                                      preferred_element_type=f32)
@@ -263,11 +301,12 @@ def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, u_ref, w_ref, attn_ref,
 
 
 def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, du_ref, dw_ref,
-                dattn_ref, dqin_ref, dkout_ref, dlast_ref, dq_ref, dk_ref,
-                dv_ref, dg_ref, dbeta_ref, *, nc: int, chunk: int, sub: int,
-                q_scale):
-    """The forward's cotangents on the same grid cell. Everything is made
-    again from the inputs; ``dT = du (beta V)^T + dw (beta K Gamma)^T``,
+                dattn_ref, dqin_ref, dkout_ref, dlast_ref, t_ref, dq_ref,
+                dk_ref, dv_ref, dg_ref, dbeta_ref, *, nc: int, chunk: int,
+                sub: int, q_scale):
+    """The forward's cotangents on the same grid cell. Everything but ``T``
+    is made again from the inputs, and ``T`` is read as the forward kernel
+    wrote it (no inverse here); ``dT = du (beta V)^T + dw (beta K Gamma)^T``,
     ``dA = -T^T dT T^T``; the decayed products' cotangents go back through
     each row sub-block's scaled operands, and a running sum's cotangent is
     a row's ``<d row, row>`` less a column's. ``dlast`` is the cotangent of
@@ -281,7 +320,8 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, du_ref, dw_ref,
         def one(n, carry):
             at = pl.ds(pl.multiple_of(n * chunk, chunk), chunk)
             c = _Chunk(q_ref, k_ref, g_ref, beta_ref, at, head, q_scale, sub)
-            t = c.t.astype(dtype)
+            t32 = unpack_t(t_ref[n, 0, 0], chunk)
+            t = t32.astype(dtype)
             v = v_ref[0, at, :].astype(f32)
             du = du_ref[n, 0, 0].astype(dtype)
             dw = dw_ref[n, 0, 0]
@@ -298,9 +338,9 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, du_ref, dw_ref,
             dk = dkb * written
             dcum = through_k * written
             da = -lax.dot_general(
-                lax.dot_general(c.t, dt, TN, precision=_HI,
+                lax.dot_general(t32, dt, TN, precision=_HI,
                                 preferred_element_type=f32),
-                c.t, NT, precision=_HI, preferred_element_type=f32)
+                t32, NT, precision=_HI, preferred_element_type=f32)
             da = jnp.where(c.strictly, da, 0.0)
             dbeta = dbeta + row_sum(da * c.kk)
             dkk = (da * c.beta).astype(dtype)
@@ -348,7 +388,8 @@ def _plan(kernel, body, q, v, chunk, sub, q_scale):
     head's chunk is a ``[Q, K]`` block of ``q``, ``k`` and ``g`` ``[B, S, H
     K]`` and a ``[Q, V]`` block of ``v``; ``beta`` ``[B, S, H]`` comes whole
     and a head's column is picked by a masked sum along the lanes. ``scan``
-    is a tensor in the recurrence's order ``[c, B, H, Q, .]``, ``last`` a
+    is a tensor in the recurrence's order ``[c, B, H, Q, .]`` (``scan_t``:
+    the kept ``T``, :func:`_pack_t`), ``last`` a
     chunk's row a head ``[B, c, 1, H K]``, ``rows`` the backward's ``d
     beta`` ``[B, c, H, 1, Q]``."""
     batch, seq, heads, key_dim = q.shape
@@ -361,9 +402,11 @@ def _plan(kernel, body, q, v, chunk, sub, q_scale):
         return pl.BlockSpec((1, nc * chunk, lanes),
                             lambda b, c, h: (b, c, h if walk else 0))
 
-    def scan(last):
-        return pl.BlockSpec((nc, 1, 1, chunk, last),
+    def scan(last, rows=chunk):
+        return pl.BlockSpec((nc, 1, 1, rows, last),
                             lambda b, c, h: (c, b, h, 0, 0))
+
+    pack = _t_pack(chunk)
 
     specs = {"key": tokens(key_dim), "value": tokens(width),
              "heads": tokens(heads, walk=False),
@@ -372,7 +415,8 @@ def _plan(kernel, body, q, v, chunk, sub, q_scale):
              "rows": pl.BlockSpec((1, nc, 1, 1, chunk),
                                   lambda b, c, h: (b, c, h, 0, 0)),
              "scan_k": scan(key_dim), "scan_v": scan(width),
-             "scan_q": scan(chunk)}
+             "scan_q": scan(chunk),
+             "scan_t": scan(pack * chunk, chunk // pack)}
     call = dict(
         grid=(batch, n_chunks // nc, heads),
         compiler_params=pltpu.CompilerParams(
@@ -399,40 +443,46 @@ def _fwd_call(q, k, v, g, beta, *, chunk, sub, q_scale):
     operand dtype, ``S`` a whole number of chunks; float32 ``g`` ``[B, S, H,
     K]`` and ``beta`` ``[B, S, H]`` -> ``u_own = T (beta V)`` float32, ``w =
     T (beta K Gamma)``, ``attn``, ``q Gamma`` and ``k Gamma_last / Gamma``
-    in the operand dtype, ``[c, B, H, Q, .]``."""
+    in the operand dtype, ``[c, B, H, Q, .]``, and last the float32 ``T``
+    for :func:`_bwd_call` alone, ``[c, B, H, Q / 2, 2 Q]`` at a chunk of 64
+    (:func:`_pack_t`)."""
     specs, body, call = _plan(KERNEL_FWD, _fwd_kernel, q, v, chunk, sub,
                               q_scale)
     args = (_flat(q), _flat(k), _flat(v), _flat(g), beta)
     batch, seq, heads, key_dim = q.shape
-    lead = (seq // chunk, batch, heads, chunk)
+    lead, pack = (seq // chunk, batch, heads), _t_pack(chunk)
     vma = out_vma(*args)
     return pl.pallas_call(
         body, in_specs=[specs[name] for name in _FWD_SPECS],
-        out_specs=[specs[name] for name in _SCAN_SPECS],
-        out_shape=[jax.ShapeDtypeStruct(lead + (last,), dtype, vma=vma)
+        out_specs=[specs[name] for name in _SCAN_SPECS + ("scan_t",)],
+        out_shape=[jax.ShapeDtypeStruct(lead + last, dtype, vma=vma)
                    for last, dtype in (
-                       (v.shape[3], jnp.float32), (key_dim, q.dtype),
-                       (chunk, q.dtype), (key_dim, q.dtype),
-                       (key_dim, q.dtype))], **call)(*args)
+                       ((chunk, v.shape[3]), jnp.float32),
+                       ((chunk, key_dim), q.dtype), ((chunk, chunk), q.dtype),
+                       ((chunk, key_dim), q.dtype),
+                       ((chunk, key_dim), q.dtype),
+                       ((chunk // pack, pack * chunk), jnp.float32))],
+        **call)(*args)
 
 
 @functools.partial(jax.jit, inline=True,
                    static_argnames=("chunk", "sub", "q_scale"))
-def _bwd_call(q, k, v, g, beta, du, dw, dattn, dqin, dkout, dlast, *, chunk,
-              sub, q_scale):
-    """The cotangents of :func:`_fwd_call`'s inputs for those of its outputs
-    and of the chunks' last decays (``dlast`` ``[B, c, 1, H K]``): ``dq``,
+def _bwd_call(q, k, v, g, beta, du, dw, dattn, dqin, dkout, dlast, t, *,
+              chunk, sub, q_scale):
+    """The cotangents of :func:`_fwd_call`'s inputs for those of its first
+    five outputs and of the chunks' last decays (``dlast`` ``[B, c, 1, H
+    K]``), given its sixth (``t``: the forward's ``T``, as written): ``dq``,
     ``dk``, ``dv`` in the operand dtype, ``dg`` and ``d beta`` float32."""
     specs, body, call = _plan(KERNEL_BWD, _bwd_kernel, q, v, chunk, sub,
                               q_scale)
     args = (_flat(q), _flat(k), _flat(v), _flat(g), beta, du, dw, dattn,
-            dqin, dkout, dlast)
+            dqin, dkout, dlast, t)
     batch, seq, heads, _ = q.shape
     vma = out_vma(*args)
     dq, dk, dv, dg, dbeta = pl.pallas_call(
         body,
         in_specs=[specs[name] for name in _FWD_SPECS + _SCAN_SPECS
-                  + ("last",)],
+                  + ("last", "scan_t")],
         out_specs=[specs[name] for name in ("key", "key", "value", "key",
                                             "rows")],
         out_shape=[jax.ShapeDtypeStruct(t.shape, t.dtype, vma=vma)
@@ -652,13 +702,15 @@ def _last_decays(g, chunk):
 
 def _scan_forward(static, q, k, v, g, beta, start):
     chunk, sub, q_scale = static
-    operands = _fwd_call(q, k, v, g, beta, chunk=chunk, sub=sub,
-                         q_scale=q_scale)
+    *operands, t = _fwd_call(q, k, v, g, beta, chunk=chunk, sub=sub,
+                             q_scale=q_scale)
     decay = _last_decays(g, chunk)
     o, final, entering = _rec_fwd_call(*operands, decay, start, sub=sub)
-    # Named as residuals only: the kernel above read the operands unnamed,
-    # so the forward pass reads no kept value (the module docstring).
-    kept = tuple(checkpoint_name(t, "kda_scan_operands") for t in operands)
+    # Named as residuals only: the kernel above read the operands unnamed
+    # and nothing of the forward pass reads T, so the forward pass reads no
+    # kept value (the module docstring).
+    kept = tuple(checkpoint_name(x, "kda_scan_operands")
+                 for x in (*operands, t))
     return (o, final), (q, k, v, g, beta, *kept, decay,
                         checkpoint_name(entering, "kda_scan_entering"))
 
@@ -674,13 +726,13 @@ def _scan(static, q, k, v, g, beta, start):
 
 def _scan_bwd(static, kept, cotangents):
     chunk, sub, q_scale = static
-    q, k, v, g, beta, *operands, decay, entering = kept
+    q, k, v, g, beta, *operands, t, decay, entering = kept
     *d_operands, d_decay, d_start = _rec_bwd_call(
         *operands, decay, entering, *cotangents, sub=sub)
     # Gamma_last = exp(c_last): the kernel is handed the cotangent of the
     # decay itself and multiplies by Gamma_last where it has it.
-    return (*_bwd_call(q, k, v, g, beta, *d_operands, d_decay, chunk=chunk,
-                       sub=sub, q_scale=q_scale), d_start)
+    return (*_bwd_call(q, k, v, g, beta, *d_operands, d_decay, t,
+                       chunk=chunk, sub=sub, q_scale=q_scale), d_start)
 
 
 _scan.defvjp(_scan_forward, _scan_bwd)

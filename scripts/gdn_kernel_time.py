@@ -12,8 +12,9 @@ At the ``qwen3-next-80b-a3b_s4096`` cell's shapes (4 x 4096 tokens, 16 key and
 ``gated_delta_chunked`` gives them, heads padded with zeros to whole lane
 tiles, 128 by 256, and the ``whole`` row the published sizes) it jits and
 times, host clock
-around ``block_until_ready``: ``hvd_gdn_fwd``; ``hvd_gdn_bwd``; the chunk-local
-part forward and backward through the ``custom_vjp``; the same through the
+around ``block_until_ready``: ``hvd_gdn_fwd``; ``hvd_gdn_bwd``, handed the
+float32 ``T`` that forward call wrote (since PR 68 it makes no inverse); the
+chunk-local part forward and backward through the ``custom_vjp``; the same through the
 plain expression (XLA writes the ``[chunk, chunk]`` tensors to HBM, the
 inverse is ``unit_lower_inverse``); and ``gated_delta_chunked`` whole, forward
 and backward. Since PR 50 the chunk-local kernels norm the rows of ``q`` and
@@ -31,7 +32,8 @@ the chunks a grid cell holds (the sources of ``_REC_HEADS``, ``_REC_CHUNKS``).
 The kernels' values and gradients are compared with the plain expressions'
 (relative to the largest value). ``--substitute`` forces the rows of the
 inverse's diagonal blocks made by substitution (1: every round a product;
-the chunk: no product) and ``--chunks-per-block`` the chunks a grid cell
+the chunk: no product; the forward kernel's alone since PR 68, the backward
+row no longer moves with it) and ``--chunks-per-block`` the chunks a grid cell
 walks: the sources of ``ops/gated_delta.py::_SUBSTITUTE`` and
 ``_MAX_CHUNKS``. One JSON line a row, also appended to
 ``chiprun_out/gdn_kernel_time.jsonl``.
@@ -44,7 +46,12 @@ states again), ``hvd_gdn_bwd`` once and ``hvd_gdn_rec_bwd`` once: since
 PR 56 the block keeps what the first kernel writes and, unpadded, the
 entering states (``gdn_scan_operands``, ``gdn_scan_entering``). Before it
 both forward kernels ran twice a layer. The forward pass runs the
-recurrence's kernel as the rule's forward does (``fwd_keep_ms``).
+recurrence's kernel as the rule's forward does (``fwd_keep_ms``). Since
+PR 68 what the first kernel writes includes ``T`` (134 MB a layer at the
+default shape, 63 at the Olmo cell's, kept with the five operands), the
+inverse is made once a layer a step, in ``fwd_ms``, and ``bwd_ms`` holds
+none: 11.99 -> 7.22 ms at the default shape, 10.17 -> 6.49 at the Olmo
+cell's (PERF.md, Findings, PR 68).
 """
 
 from __future__ import annotations
@@ -206,7 +213,7 @@ def main() -> int:
         .reshape(B, c, Q, Hv)
     cum = jnp.cumsum(g.reshape(B, c, Q, Hv), axis=2)
     inputs = (q, k, v, cum, beta)
-    like = jax.eval_shape(gd._fwd_call, *inputs)
+    like = jax.eval_shape(gd._fwd_call, *inputs)[:5]
     cts = tuple(jax.random.normal(key, t.shape, f32).astype(t.dtype)
                 for key, t in zip(ks[5:10], like))
 
@@ -250,7 +257,7 @@ def main() -> int:
                 row(what="kernels", substitute=substitute,
                     chunks_per_block=gd.chunks_per_block(c),
                     fwd_ms=timed(fwd, *inputs),
-                    bwd_ms=timed(bwd, *inputs, *cts),
+                    bwd_ms=timed(bwd, *inputs, *cts, got[5]),
                     fwd_bwd_ms=timed(kernels, *inputs, *cts),
                     out_rel={n: rel(o, w)
                              for n, o, w in zip(NAMES, got, want)},
@@ -262,7 +269,7 @@ def main() -> int:
 
     if "recurrence" in args.rows:
         rec = tuple(jax.jit(functools.partial(gd._fwd_call, **norm))(
-            *inputs)) + (
+            *inputs))[:5] + (
             jnp.exp(cum[:, :, -1]),
             0.1 * jax.random.normal(ks[11], (B, Hv, K, V), f32))
         rec_cts = (jax.random.normal(ks[12], (B, S, Hv * V), dtype),

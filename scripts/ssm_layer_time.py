@@ -43,6 +43,9 @@ also prints ``kda_ops_ms_a_layer``: the ``hvd_kda_*`` kernels' and the
 ``reduce_precision`` passes' time and calls a layer a call of the forward
 with backward (a block that keeps what the scan's forward kernels write runs
 each once, PR 64; ``--repo`` a copy of an older commit runs them twice).
+Since PR 68 what ``hvd_kda_fwd`` writes and the block keeps includes the
+float32 ``T``, which the rule hands ``hvd_kda_bwd``: that row holds no
+inverse (8.48 -> 5.14 ms a layer, ``hvd_kda_fwd`` 4.72 either way).
 
 ``--kind head`` times no block but the head and the loss alone (``--tokens``
 rows of ``--embed`` against ``--vocab``, ``--tied``, ``--scaling``; without

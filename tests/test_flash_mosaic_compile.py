@@ -191,7 +191,9 @@ GDN_SHAPES = {
 def test_gdn_kernel_compiles_for_v5e(one_chip, mosaic, shape, kernel):
     """``fwd`` and ``bwd`` as a model's step runs them, the rows of ``q``
     and ``k`` normed in the kernels (``q_scale`` from the head's true size);
-    ``*_caller_norms`` the same kernels on keys that come normed."""
+    ``*_caller_norms`` the same kernels on keys that come normed. The
+    forward kernel's sixth output and the backward's last input is the
+    float32 ``T``, a chunk's ``[64, 64]`` kept as ``[32, 128]`` (PR 68)."""
     batch, seq, key_heads, heads, key_dim, width, chunk, dtype, true_dim = \
         GDN_SHAPES[shape]
     kernel, _, caller_norms = kernel.partition("_caller_norms")
@@ -219,8 +221,8 @@ def test_gdn_kernel_compiles_for_v5e(one_chip, mosaic, shape, kernel):
         # The recurrence over chunks reads the chunk-local kernel's outputs,
         # a chunk's last decay a head and the state a sequence starts from.
         state = sds(batch, heads, key_dim, width, dt=jnp.float32)
-        args = scan + (sds(batch, seq // chunk, heads, dt=jnp.float32),
-                       state)
+        args = scan[:5] + (sds(batch, seq // chunk, heads, dt=jnp.float32),
+                           state)
         f = functools.partial(gated_delta._rec_fwd_call, keep=True)
         if kernel == "rec_bwd":
             o, _, entering = like(gated_delta._rec_fwd_call, *args,
@@ -229,6 +231,9 @@ def test_gdn_kernel_compiles_for_v5e(one_chip, mosaic, shape, kernel):
                                                              state)
     text = jax.jit(f).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text and f"hvd_gdn_{kernel}" in text
+    kept_t = f"f32[{seq // chunk},{batch},{heads},{chunk // 2},{2 * chunk}]"
+    assert (kept_t in text) == (kernel in ("fwd", "bwd"))
+    assert f"f32[{seq // chunk},{batch},{heads},{chunk},{chunk}]" not in text
 
 
 @pytest.mark.parametrize("norm_qk", [True, False],
@@ -365,10 +370,13 @@ def test_kda_scan_compiles_for_v5e(one_chip, mosaic, shape, grad):
         assert kernel in text
     for kernel in (kda.KERNEL_BWD, kda.KERNEL_REC_BWD):
         assert (kernel in text) == grad
-    # Neither a chunk's float32 [Q, Q] tiles nor a state a token reach HBM.
+    # Of a chunk's float32 [Q, Q] tiles T alone reaches HBM, with the
+    # backward pass to read it and no lane of it padding (PR 68); a state a
+    # token does not.
     chunks = seq // 64
     assert f"f32[{batch},{chunks},{heads},64,64]" not in text
     assert f"f32[{chunks},{batch},{heads},64,64]" not in text
+    assert f"f32[{chunks},{batch},{heads},32,128]" in text
     assert f"f32[{batch},{seq},{heads},{dim},{dim}]" not in text
 
 

@@ -44,6 +44,13 @@ by the plain line (``unit_rows``), at heads of 128 by 128 and of 96 by 192
 carried on lanes, in float32 and with bfloat16 operands; then the rows the
 chunk's padding adds, a zero row inside a chunk, and the scale of ``q``,
 which is the true head's and not the lanes'.
+
+Since PR 68 the forward chunk-local kernel writes the float32 ``T`` it holds
+and the backward kernel reads it and makes no inverse: the ``T_CASES`` hold
+what reaches HBM, through the kernels' own packing, to ``unit_lower_inverse``
+of the plain ``A`` (nearly equal keys among them), and a checkpointed
+gradient is the plain one bit for bit with the names kept and with nothing
+kept, at both head layouts.
 """
 
 import collections
@@ -54,6 +61,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.ad_checkpoint import checkpoint_name
 
 from horovod_tpu.ops import gated_delta
 from horovod_tpu.ops.gated_delta import (
@@ -276,7 +284,7 @@ def test_the_names_a_checkpoint_may_keep_change_nothing_outside_one(heads):
     """Outside a checkpoint a name is an identity: the value and every
     input's gradient are the sequential rule's, where the entering states
     are named (a head of whole lane tiles) and where they are not (a head
-    carried with zeros); the chunk-local kernel's five outputs are named
+    carried with zeros); the chunk-local kernel's six outputs are named
     either way."""
     # (The shapes of the cases above, whose compiled kernels these calls
     # find again.)
@@ -300,9 +308,97 @@ def test_the_names_a_checkpoint_may_keep_change_nothing_outside_one(heads):
     np.testing.assert_allclose(value, want_value, rtol=2e-5)
     for got, w in zip(grads, want):
         _close(got, w, 5e-5)
+    # (The chunk-local kernel's sixth output, ``T``, is the chunk-local
+    # rule's residual under the five's name: PR 68.)
     assert _names_in(jax.make_jaxpr(chunked)(*args).jaxpr) == {
-        "gdn_scan_operands": 5,
+        "gdn_scan_operands": 6,
         **({"gdn_scan_entering": 1} if heads == "whole" else {})}
+
+
+@pytest.mark.parametrize("kept", ["the_names", "nothing"])
+@pytest.mark.parametrize("heads", ["whole", "padded"])
+def test_a_checkpoints_gradient_is_the_plain_one_to_the_last_bit(
+        heads, kept, equations_of):
+    """The backward kernels read the tensors the forward kernels wrote,
+    ``T`` among them, where the checkpoint keeps them by name and a second
+    run's identical copies where it keeps nothing: every input's gradient
+    is the un-checkpointed one's either way, bit for bit, at a head of
+    whole lane tiles and at one carried with zeros. With the names kept
+    (the module's and the mixer's for the output, ``gdn_scan_out``) the
+    recomputed copy holds no ``hvd_gdn_fwd``."""
+    args = _inputs(33, CELL_SEQ, **CELL) if heads == "whole" \
+        else _inputs(33, 70)
+
+    def loss(*a):
+        o, final = gated_delta_chunked(*a, chunk=64)
+        o = checkpoint_name(o, "gdn_scan_out")
+        return jnp.sum(jnp.sin(o.astype(jnp.float32))) + jnp.sum(final)
+
+    policy = jax.checkpoint_policies.save_only_these_names(
+        *((*gated_delta.SAVED_NAMES, "gdn_scan_out")
+          if kept == "the_names" else ()))
+    argnums = tuple(range(5))
+    plain = jax.jit(jax.grad(loss, argnums))
+    checkpointed = jax.jit(jax.grad(jax.checkpoint(loss, policy=policy),
+                                    argnums))
+    for name, a, b in zip(INPUT_NAMES, checkpointed(*args), plain(*args)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b), name)
+    assert sum(
+        eqn.primitive.name == "pallas_call"
+        and eqn.params["name"] == gated_delta.KERNEL_FWD
+        for eqn, _ in equations_of(jax.make_jaxpr(checkpointed)(
+            *args).jaxpr)) == (1 if kept == "the_names" else 2)
+
+
+# name -> (``_inputs``' keywords, tokens, chunk, the kept ``T``'s last two
+# axes): the Qwen cell's grid cell (two value heads a key head, four chunks),
+# the Olmo cell's (one value head a key head, four chunks), and a chunk of
+# 16, whose tile is still under the lanes' width.
+T_CASES = {
+    "two_value_heads_a_key_head": (CELL, 256, 64, (32, 128)),
+    "one_value_head_a_key_head": (
+        dict(batch=2, key_heads=2, heads=2, key_dim=16, width=8,
+             beta_max=2.0), 256, 64, (32, 128)),
+    "nearly_equal_keys": (CELL, 128, 64, (32, 128)),
+    "chunk_of_16": (dict(batch=1, key_heads=1, heads=2, key_dim=16, width=8),
+                    64, 16, (8, 32)),
+}
+
+
+@pytest.mark.parametrize("case", list(T_CASES))
+def test_the_t_the_forward_kernel_writes_is_unit_lower_inverse_of_a(case):
+    """``hvd_gdn_fwd``'s sixth output, read back through the kernels' own
+    packing (a ``[64, 64]`` float32 tile kept as ``[32, 128]``: no lane of
+    it padding), against the plain inverse of the plain ``A``; keys a
+    twentieth apart at ``alpha = beta = 1`` make ``A``'s entries 0.99...
+    and ``T`` nearly bidiagonal, which an inverse with its arithmetic in
+    bfloat16 misses by 1.7e-2."""
+    shape, seq, chunk, kept_shape = T_CASES[case]
+    q, k, v, g, beta = _inputs(34, seq, **shape)
+    if case == "nearly_equal_keys":
+        rng = np.random.default_rng(35)
+        k = rng.standard_normal(128) \
+            + 0.05 * rng.standard_normal((1, seq, 1, 128))
+        k = jnp.asarray(k / np.linalg.norm(k, axis=-1, keepdims=True),
+                        jnp.float32)
+        g, beta = jnp.zeros_like(g), jnp.ones_like(beta)
+    batch, heads = v.shape[0], v.shape[2]
+    rep = heads // k.shape[2]
+    by_chunk = (batch, seq // chunk, chunk, heads)
+    cum = jnp.cumsum(g.reshape(by_chunk), axis=2)
+    kept = gated_delta._fwd_call(q, k, v, cum, beta.reshape(by_chunk))[5]
+    assert kept.dtype == jnp.float32
+    assert kept.shape == (seq // chunk, batch, heads) + kept_shape
+    # The plain A, [c, B, Hv, Q, Q]: beta_t (G_t / G_j) <k_t, k_j>, j < t.
+    keys = jnp.repeat(k, rep, axis=2).reshape(by_chunk + k.shape[3:])
+    kk = jnp.einsum("bcihk,bcjhk->cbhij", keys, keys,
+                    precision=jax.lax.Precision.HIGHEST)
+    rows = jnp.moveaxis(cum, (1, 3), (0, 2))                # [c, B, Hv, Q]
+    a = jnp.tril(kk * jnp.exp(rows[..., :, None] - rows[..., None, :])
+                 * jnp.moveaxis(beta.reshape(by_chunk), (1, 3),
+                                (0, 2))[..., None], -1)
+    _close(gated_delta.unpack_t(kept, chunk),
+           unit_lower_inverse(a), 1e-5)
 
 
 @pytest.mark.parametrize("what", ["beta zero", "alpha one"])

@@ -254,8 +254,8 @@ def _two_kda_layers(**kw):
 def test_a_checkpointed_block_runs_the_scans_forward_kernels_once(
         make_runtime, equations_of):
     """``remat="full"`` keeps every output of ``hvd_kda_fwd`` and
-    ``hvd_kda_rec_fwd`` (the five operands and the entering states, named
-    as the rule's residuals, and the mixer's ``kda_scan_out``), so the
+    ``hvd_kda_rec_fwd`` (the five operands, ``T`` and the entering states,
+    named as the rule's residuals, and the mixer's ``kda_scan_out``), so the
     recomputed copy runs neither; the forward pass reads no kept tensor of
     the recurrence's order ``[c, B, H, ., .]``, so ``jax.checkpoint`` puts
     no ``reduce_precision`` on one (a pass over every kept byte on the
@@ -279,12 +279,13 @@ def test_a_checkpointed_block_runs_the_scans_forward_kernels_once(
     family = hvd_.metrics()["hvdtpu_spmd_remat_saved_bytes_total"]
     kept = {labels["name"]: value for _, labels, value in family["samples"]}
     # A block's bytes (layers alike share one trace), float32 throughout:
-    # the operands [c, B, H, Q, V + 3 K + Q], the entering states
-    # [c, B, H, V, K], the output [B, S, H V].
+    # the operands [c, B, H, Q, V + 3 K + Q] and T, Q more a row (PR 68),
+    # the entering states [c, B, H, V, K], the output [B, S, H V].
     chunks, q = S // cfg.kda_chunk, cfg.kda_chunk
     assert {name: kept.get(name) for name in (
         *kda_ops.SAVED_NAMES, *kda_mixer.SAVED_NAMES)} == {
-        "kda_scan_operands": 4 * chunks * B * HEADS * q * (4 * KDA_DIM + q),
+        "kda_scan_operands":
+            4 * chunks * B * HEADS * q * (4 * KDA_DIM + 2 * q),
         "kda_scan_entering": 4 * chunks * B * HEADS * KDA_DIM * KDA_DIM,
         "kda_scan_out": 4 * B * S * HEADS * KDA_DIM}
 

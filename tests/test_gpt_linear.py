@@ -200,7 +200,8 @@ def _kernels_in(jaxpr, out=None):
 @pytest.mark.parametrize("heads", ["padded", "whole"])
 def test_a_checkpointed_block_runs_the_scans_forward_kernels_once(
         make_runtime, heads):
-    """``remat="full"`` keeps what ``hvd_gdn_fwd`` writes, so the
+    """``remat="full"`` keeps what ``hvd_gdn_fwd`` writes (its five
+    operands and, for ``hvd_gdn_bwd`` alone, ``T``), so the
     recomputed copy does not run it, and each chunk's entering state where
     no lane of it is padding, so ``hvd_gdn_rec_fwd`` runs once too; a head
     carried with zeros makes its entering states again, from the kept
@@ -225,7 +226,13 @@ def test_a_checkpointed_block_runs_the_scans_forward_kernels_once(
     assert kernels["rounded"] == (0 if heads == "whole" else 5 * layers)
     family = hvd.metrics()["hvdtpu_spmd_remat_saved_bytes_total"]
     kept = {labels["name"]: value for _, labels, value in family["samples"]}
-    assert "gdn_scan_operands" in kept
+    # A block's bytes, float32 here: [c, B, Hv, Q, .] with u_own on the
+    # value lanes, w, q G and k G_last / G on the key lanes, attn and T a
+    # chunk's width each. T is no operand of the forward pass, so the five
+    # ``rounded`` above stay five.
+    chunks, hv, q = -(-S // cfg.gdn_chunk), cfg.gdn_value_heads, cfg.gdn_chunk
+    assert kept["gdn_scan_operands"] \
+        == chunks * B * hv * q * (4 * 128 + 2 * q) * 4
     assert ("gdn_scan_entering" in kept) == (heads == "whole")
     if heads == "whole":
         # [c, B, Hv, K, V] in the operand dtype, a layer.

@@ -302,12 +302,13 @@ def test_full_remat_keeps_the_gdn_scans_output(make_runtime):
     index = jnp.argsort(jnp.zeros(1)).dtype.itemsize
     # What ``hvd_gdn_fwd`` writes, a chunk a turn ``[c, B, Hv, Q, .]``:
     # ``u_own`` on the value lanes, ``w``, ``q G`` and ``k G_last / G`` on
-    # the key lanes, ``attn`` on the chunk. A head of 8 by 8 rides 128
-    # lanes, so no entering state is kept.
+    # the key lanes, ``attn`` on the chunk, and for its backward kernel
+    # alone ``T``, a chunk's width a row too (PR 68). A head of 8 by 8
+    # rides 128 lanes, so no entering state is kept.
     rows = (48 // cfg.gdn_chunk) * 3 * cfg.gdn_value_heads * cfg.gdn_chunk
     assert kept == {"gdn_scan_out": tokens * gdn.value_inner(cfg) * 4,
                     "gdn_scan_operands":
-                        rows * (128 + 3 * 128 + cfg.gdn_chunk) * 4,
+                        rows * (128 + 3 * 128 + 2 * cfg.gdn_chunk) * 4,
                     "moe_expert_matrices": experts,
                     "moe_router_logits": tokens * cfg.num_experts * 4,
                     "moe_top_experts": pairs * 4,

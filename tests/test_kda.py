@@ -15,7 +15,9 @@ length the chunk does not divide, rows normed by the kernels (``norm_qk``)
 and by the caller, a gate that sits at its lower bound for a whole chunk
 (every exponent the sub-blocks were made for), heads of 128 (the cell's) and
 a head that is no lane multiple (interpreted here; compiled it raises by
-name).
+name). Since PR 68 ``hvd_kda_fwd`` writes the float32 ``T`` and ``hvd_kda_bwd``
+reads it: the ``T_CASES`` hold what reaches HBM to ``unit_lower_inverse`` of
+the plain ``A``.
 """
 
 import collections
@@ -27,7 +29,7 @@ import numpy as np
 import pytest
 from jax.ad_checkpoint import checkpoint_name
 
-from horovod_tpu.ops import kda, pallas_util
+from horovod_tpu.ops import gated_delta, kda, pallas_util
 from horovod_tpu.ops.gated_delta import gated_delta_chunked, unit_rows
 from horovod_tpu.ops.kda import kda_chunked, kda_sequential
 
@@ -212,9 +214,10 @@ def _scan_loss(q, k, v, g, beta):
 
 
 def test_the_rules_residuals_carry_the_names_a_checkpoint_keeps(equations_of):
-    """The five operands and the entering states are named inside the
-    rule's forward, once each, whether or not a gradient is asked for; the
-    output's name is the caller's."""
+    """The five operands, ``T`` under their name (the chunk-local
+    kernel's sixth output, PR 68) and the entering states are named inside
+    the rule's forward, once each, whether or not a gradient is asked for;
+    the output's name is the caller's."""
     def names_in(fn):
         return collections.Counter(
             eqn.params["name"]
@@ -222,7 +225,7 @@ def test_the_rules_residuals_carry_the_names_a_checkpoint_keeps(equations_of):
             if eqn.primitive.name == "name")
 
     args = _inputs(2, seq=64, heads=2)
-    names = {"kda_scan_operands": 5, "kda_scan_entering": 1}
+    names = {"kda_scan_operands": 6, "kda_scan_entering": 1}
     assert names_in(lambda *a: kda_chunked(*a, chunk=32,
                                            sub_chunk=8)) == names
     assert names_in(jax.grad(_scan_loss, argnums=tuple(range(5)))) == {
@@ -230,34 +233,89 @@ def test_the_rules_residuals_carry_the_names_a_checkpoint_keeps(equations_of):
 
 
 @pytest.mark.parametrize("kept", ["the_three_names", "nothing"])
-def test_a_checkpoints_gradient_is_the_plain_one_to_the_last_bit(kept):
-    """The backward kernels read the tensors the forward kernels wrote where
+def test_a_checkpoints_gradient_is_the_plain_one_to_the_last_bit(
+        kept, equations_of):
+    """The backward kernels read the tensors the forward kernels wrote,
+    ``T`` among them, where
     the checkpoint keeps them by name and a second run's identical copies
     where it keeps nothing: every input's gradient is the un-checkpointed
-    one's either way, bit for bit."""
+    one's either way, bit for bit. With the names kept the recomputed copy
+    holds no ``hvd_kda_fwd``."""
     args = _inputs(4, seq=96, heads=2)
     policy = jax.checkpoint_policies.save_only_these_names(
         *((*kda.SAVED_NAMES, "kda_scan_out")
           if kept == "the_three_names" else ()))
     argnums = tuple(range(5))
     want = jax.jit(jax.grad(_scan_loss, argnums))(*args)
-    got = jax.jit(jax.grad(
-        jax.checkpoint(_scan_loss, policy=policy), argnums))(*args)
-    for name, a, b in zip("qkvgb", got, want):
+    checkpointed = jax.jit(jax.grad(
+        jax.checkpoint(_scan_loss, policy=policy), argnums))
+    for name, a, b in zip("qkvgb", checkpointed(*args), want):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b), name)
+    assert sum(
+        eqn.primitive.name == "pallas_call"
+        and eqn.params["name"] == kda.KERNEL_FWD
+        for eqn, _ in equations_of(jax.make_jaxpr(checkpointed)(
+            *args).jaxpr)) == (1 if kept == "the_three_names" else 2)
+
+
+# name -> (``_inputs``' keywords, ``_fwd_call``'s, the kept ``T``'s last two
+# axes): the Ling cell's chunk and sub-block at two heads and four chunks a
+# grid cell, keys a twentieth apart that hardly decay, and a chunk of 32,
+# whose tile is still under the lanes' width.
+T_CASES = {
+    "cell_chunk": (dict(seq=256), dict(chunk=64, sub=16), (32, 128)),
+    "nearly_equal_keys": (dict(seq=128, lower_bound=-1e-3),
+                          dict(chunk=64, sub=16), (32, 128)),
+    "chunk_of_32": (dict(seq=64), dict(chunk=32, sub=8), (8, 128)),
+}
+
+
+@pytest.mark.parametrize("case", list(T_CASES))
+def test_the_t_the_forward_kernel_writes_is_unit_lower_inverse_of_a(case):
+    """``hvd_kda_fwd``'s sixth output, read back through the kernels' own
+    packing (a ``[64, 64]`` float32 tile kept as ``[32, 128]``: no lane of
+    it padding), against the plain inverse of ``A`` made in float64 from
+    the same rows (``beta_t <k_t Gamma_t, k_j / Gamma_j>``, no sub-blocks);
+    nearly equal keys at ``beta = 1`` make ``A``'s entries 0.99... and
+    ``T`` nearly bidiagonal."""
+    shape, how, kept_shape = T_CASES[case]
+    q, k, v, g, beta = _inputs(5, **shape)
+    k = unit_rows(k)
+    if case == "nearly_equal_keys":
+        rng = np.random.default_rng(6)
+        k = unit_rows(jnp.asarray(rng.standard_normal(k.shape[-1]) + 0.05
+                                  * rng.standard_normal(k.shape), jnp.float32))
+        beta = jnp.ones_like(beta)
+    kept = kda._fwd_call(q, k, v, g, beta, q_scale=None, **how)[5]
+    batch, seq, heads, _ = k.shape
+    chunk = how["chunk"]
+    assert kept.dtype == jnp.float32
+    assert kept.shape == (seq // chunk, batch, heads) + kept_shape
+    by_chunk = (batch, seq // chunk, chunk, heads, -1)
+    keys = np.asarray(k, np.float64).reshape(by_chunk)
+    cum = np.cumsum(np.asarray(g, np.float64).reshape(by_chunk), axis=2)
+    a = np.einsum("bcihk,bcjhk->cbhij", keys * np.exp(cum),
+                  keys * np.exp(-cum)) * np.moveaxis(
+        np.asarray(beta, np.float64).reshape(by_chunk[:4]), (1, 3),
+        (0, 2))[..., None]
+    _close(kda.unpack_t(kept, chunk),
+           gated_delta.unit_lower_inverse(
+               jnp.asarray(np.tril(a, -1), jnp.float32)), 1e-5)
 
 
 # sha256 of the StableHLO text (no source locations) the gated delta rule's
 # gradient lowers to at the two cells' head layouts (Qwen3-Next's whole lane
-# tiles, Olmo's 96 x 192 carried on lanes), interpreted kernels included, as
-# the parent of PR 63 lowered it: that PR moved the inverse in VMEM to
-# ``pallas_util`` for ``ops/kda.py`` to share, and the scalar form stays the
-# program it was. A change that means to alter it pins these anew.
+# tiles, Olmo's 96 x 192 carried on lanes), interpreted kernels included.
+# First pinned as the parent of PR 63 lowered it (that PR moved the inverse in
+# VMEM to ``pallas_util`` for ``ops/kda.py`` to share, and the scalar form
+# stayed the program it was); pinned anew by PR 68, which meant to alter it:
+# ``hvd_gdn_fwd`` writes ``T`` and ``hvd_gdn_bwd`` reads it and holds no
+# inverse. A change that means to alter it pins these anew.
 GDN_LOWERED = {
-    (1, 2, 128, 128): "08ae3acaa6b2484b23ee8cc9570f7849"
-                      "c7491962bd55b6c4ec7ae939dee59deb",
-    (2, 2, 96, 192): "84599de5a63e54f24e01a21386c01fcf"
-                     "b8258c9ab16daccafff0cd8620ce83d6",
+    (1, 2, 128, 128): "ddab0b16d9e16405188df2183c242fd6"
+                      "9d725d7f26164135b40d84830099b5c0",
+    (2, 2, 96, 192): "a71f4e99a3bad62f8236d07663e352d7"
+                     "90d69b26aa8c42e6782ed07b1261405e",
 }
 
 
