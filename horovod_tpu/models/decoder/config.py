@@ -261,6 +261,16 @@ class GPTConfig:
     # targets and weights of the noised half are the caller's batch
     # (``gpt.loss_and_aux``). None: causal attention, as ever.
     diffusion_block: Optional[int] = None
+    # A looped stack: the layers run loop_passes times a step with the same
+    # parameters, the norm before the head at the end of every pass, its
+    # output both what the head and a learned exit gate (``exit_gate``: a
+    # vector of embed_dim and a bias) read at that pass and what the next
+    # pass starts from (``gpt._passes``). The loss is then the expected
+    # cross-entropy under the gate's exit distribution over the passes less
+    # exit_entropy_coef times that distribution's entropy, a token
+    # (``gpt.loss_and_aux``). 1: each layer once, no gate, as ever.
+    loop_passes: int = 1
+    exit_entropy_coef: float = 0.0
 
     @property
     def kv_heads(self) -> int:
@@ -299,7 +309,7 @@ def layer_plan(cfg: GPTConfig) -> Tuple[LayerSpec, ...]:
             f"layer_kinds must name a mixer for each of the "
             f"{cfg.num_layers} layers, got {kinds!r}")
     if cfg.layers is None:
-        return _under_diffusion(cfg, tuple(LayerSpec(
+        return _under_loop(cfg, tuple(LayerSpec(
             mixer="attention" if kinds is None else kinds[i], rope=cfg.rope,
             ff="experts" if cfg.moe_every > 0
             and (i + 1) % cfg.moe_every == 0
@@ -321,11 +331,45 @@ def layer_plan(cfg: GPTConfig) -> Tuple[LayerSpec, ...]:
             f"key on one of {_WINDOW_MIXERS} alone: a CCA layer has none "
             f"yet, nor an MLA layer) for each of the {cfg.num_layers} "
             f"layers, got {plan!r}")
-    return _under_diffusion(cfg, plan)
+    return _under_loop(cfg, plan)
 
 
 # The mixers that take ``LayerSpec.window``.
 _WINDOW_MIXERS = ("attention", "diff_attention")
+
+
+def _under_loop(cfg: GPTConfig, plan):
+    """``plan`` as :func:`_under_diffusion` leaves it; with more than one
+    pass only where nothing but the stream crosses layers and the rows are
+    one copy of the sequence. What the one carry would hand from a pass to
+    the next (a published value, an MLP router's state), and which half a
+    pass's output would be under the block-diffusion mask, no configuration
+    says, and none is guessed."""
+    plan = _under_diffusion(cfg, plan)
+    if cfg.loop_passes == 1:
+        return plan
+    if cfg.loop_passes < 1:
+        raise ValueError(f"loop_passes must be at least 1, got "
+                         f"{cfg.loop_passes}")
+    for i, spec in enumerate(plan):
+        for field in ("publishes", "reads"):
+            if getattr(spec, field):
+                raise ValueError(
+                    f"loop_passes={cfg.loop_passes} beside layer {i}'s "
+                    f"{field}={getattr(spec, field)}: what a pass hands the "
+                    "next beside the stream is not said")
+    if cfg.router_kind == "mlp" and any(spec.ff == "experts"
+                                        for spec in plan):
+        raise ValueError(
+            f"loop_passes={cfg.loop_passes} beside router_kind='mlp': the "
+            "router's state crosses expert blocks, and what the last block "
+            "of a pass hands the first of the next is not said")
+    if cfg.diffusion_block is not None:
+        raise ValueError(
+            f"loop_passes={cfg.loop_passes} beside diffusion_block="
+            f"{cfg.diffusion_block}: a pass's output is rows of both copies "
+            "of the sequence, and which the next pass reads is not said")
+    return plan
 
 
 def _under_diffusion(cfg: GPTConfig, plan):

@@ -47,8 +47,14 @@ a cross-attention layer), and an MLP router's state from expert block to
 expert block rides the same carry; ``layer_plan`` refuses a reader with no
 producer before it. The stream's norms are RMSNorms or, under
 ``norm_kind="layer"``, LayerNorms.
+**A looped stack** (``GPTConfig.loop_passes`` > 1, :func:`_passes`) runs the
+same layers that many times a step, the norm before the head at the end of
+every pass and carried on; a head and a learned exit gate read every pass's
+state, and the loss is the exit distribution's expected cross-entropy less
+``exit_entropy_coef`` times its entropy (:func:`_exit_distribution`).
 """
 
+import contextlib
 import functools
 
 import jax
@@ -150,6 +156,13 @@ def _tree(cfg: GPTConfig, rng=None) -> dict:
     if not cfg.tie_embeddings:
         tree["lm_head"] = dense(keys[1], (E, cfg.vocab_size), E) if make \
             else P()
+    if cfg.loop_passes > 1:
+        # The exit gate of a looped stack: lambda = sigmoid(w . h + b) on a
+        # pass's normed state, float32.
+        tree["exit_gate"] = {
+            "w": jax.random.normal(jax.random.fold_in(keys[1], 1), (E,),
+                                   jnp.float32) * 0.02 if make else P(),
+            "b": jnp.zeros((), jnp.float32) if make else P()}
     for i, (spec, carry) in enumerate(zip(
             cfg.plan, experts.routers_with_carry(cfg))):
         # A mixer gets the layer's first four keys, a feed-forward the rest.
@@ -323,10 +336,14 @@ def _block_fn(cfg: GPTConfig):
                      "(expected 'none' or 'full')")
 
 
-def _hidden(params, tokens, positions, cfg: GPTConfig):
-    """``(the normed hidden rows [B, S_local, E], [aux of each expert
-    block])``: everything before the head's matrix, which ``forward`` and
-    ``loss_and_aux`` share."""
+def _passes(params, tokens, positions, cfg: GPTConfig):
+    """``([the normed hidden rows [B, S_local, E] at the end of each pass],
+    [aux of each expert block, every pass's])``: everything before the
+    head's matrix. One pass, one state, unless the stack is looped
+    (``cfg.loop_passes``): then the same ``params["layers"]`` run that many
+    times, the norm before the head at the end of **every** pass, its output
+    both what the head and the exit gate read at that pass and what the next
+    pass starts from."""
     # Scopes name the program's parts in every instruction's ``op_name``:
     # ``embed``, ``layer<i>`` (inside, from ``_block``: the mixer's
     # ``scope(spec)`` and the feed-forward's ``SCOPE``, ``mlp`` or ``moe``,
@@ -334,33 +351,63 @@ def _hidden(params, tokens, positions, cfg: GPTConfig):
     # norms a branch after it, ``res_scale`` where the residual is scaled;
     # the product of a router that reads the block's input under
     # ``moe/router_early``, before the mixer's scope), ``head``;
-    # ``loss_and_aux`` adds ``loss``. A device trace is read by them
+    # ``loss_and_aux`` adds ``loss``. A looped stack puts ``pass<t>`` around
+    # a pass's ``layer<i>`` and ``head``. A device trace is read by them
     # (PERF.md section 3).
     with jax.named_scope("embed"):
         x = params["embed"].astype(cfg.dtype)[tokens]
         if cfg.embedding_multiplier != 1.0:
             x = x * cfg.embedding_multiplier
     block = _block_fn(cfg)
-    # The one carry: what crosses blocks beside the stream, by name. A block
-    # is handed what it reads of it and no more (an expert block the router
-    # state, a mixer its ``spec.reads``).
-    auxes, carry, plan = [], {}, cfg.plan
-    for i, (spec, lp) in enumerate(zip(plan, params["layers"], strict=True)):
-        wanted = spec.reads + (
-            (_ROUTER_STATE,) if FEED_FORWARDS.get(spec.ff) is experts else ())
-        with jax.named_scope(f"layer{i}"):
-            x, aux, handed_on = block(
-                cfg, spec, lp, x, positions,
-                {name: carry[name] for name in wanted if name in carry})
-        carry.update(handed_on)
-        for name in spec.publishes:
-            runtime.note_traced(
-                "hvdtpu_spmd_shared_values_total", value=name, producer=i,
-                readers=sum(name in later.reads for later in plan[i + 1:]))
-        if aux is not None:
-            auxes.append(aux)
-    with jax.named_scope("head"):
-        return _norm(cfg, x, params["out_norm"]), auxes
+    plan, passes = cfg.plan, cfg.loop_passes
+    layers, out_norm = params["layers"], params["out_norm"]
+    if passes > 1:
+        runtime.note_traced("hvdtpu_spmd_loop_passes_total",
+                            passes=passes, layers=len(plan))
+        # A replicated parameter read in every pass is marked varying as the
+        # rows are once, here: the mark's transpose is the all-reduce of its
+        # gradient, and a mark a use would make one a pass.
+        layers, out_norm = jax.tree.map(
+            lambda p: varying_like(p, x), (layers, out_norm))
+    states, auxes = [], []
+    for t in range(passes):
+        with jax.named_scope(f"pass{t}") if passes > 1 \
+                else contextlib.nullcontext():
+            # The one carry: what crosses blocks beside the stream, by name.
+            # A block is handed what it reads of it and no more (an expert
+            # block the router state, a mixer its ``spec.reads``). Nothing
+            # of it crosses passes (``layer_plan`` refuses what would).
+            carry = {}
+            for i, (spec, lp) in enumerate(zip(plan, layers, strict=True)):
+                wanted = spec.reads + (
+                    (_ROUTER_STATE,) if FEED_FORWARDS.get(spec.ff) is experts
+                    else ())
+                with jax.named_scope(f"layer{i}"):
+                    x, aux, handed_on = block(
+                        cfg, spec, lp, x, positions,
+                        {name: carry[name] for name in wanted
+                         if name in carry})
+                carry.update(handed_on)
+                for name in spec.publishes:
+                    runtime.note_traced(
+                        "hvdtpu_spmd_shared_values_total", value=name,
+                        producer=i, readers=sum(
+                            name in later.reads for later in plan[i + 1:]))
+                if aux is not None:
+                    auxes.append(aux)
+            with jax.named_scope("head"):
+                states.append(_norm(cfg, x, out_norm))
+            # The next pass starts from the normed state the head and the
+            # gate read, not from the stream the norm read.
+            x = states[-1]
+    return states, auxes
+
+
+def _hidden(params, tokens, positions, cfg: GPTConfig):
+    """``(the last pass's normed hidden rows, the auxes)`` of
+    :func:`_passes`: what a caller that wants logits reads."""
+    states, auxes = _passes(params, tokens, positions, cfg)
+    return states[-1], auxes
 
 
 def _head_matrix(params, cfg: GPTConfig):
@@ -389,6 +436,16 @@ def _logits(x, w, tied: bool, scaling: float):
 # block held twice the memory (PERF.md, Findings, PR 41).
 _HEAD_LOSS_MOST_ROWS = 2048
 _HEAD_LOSS_BLOCK_BYTES = 1 << 30
+# Up to this many blocks are left to the scheduler, as they were measured
+# (PR 41: 4 and 8 blocks, the one-pass cells'); beyond it a block waits for
+# the weight gradient's sum over the blocks before it (``_head_loss_fwd``).
+# The one-pass cells with the chain always on (my chip run, PR 69, one
+# traced pair a cell): ``starcoder2-3b_s4096`` (4 blocks) 298.56 | 298.63 ms
+# a step and 12.885 | 12.849 GiB, ``trinity-mini_s8192`` (8 blocks) 475.47 |
+# 474.65 ms and 13.722 | 13.490 GiB: the time holds and a little memory
+# falls, so one path would serve every cell; it changes every one-pass
+# program, which is a PR of its own (ROADMAP, Speed 19).
+_HEAD_LOSS_FREE_BLOCKS = 8
 
 
 def head_loss_rows(tokens: int, vocab: int) -> int:
@@ -417,8 +474,9 @@ def _note_head_loss(cfg: GPTConfig, tokens: int, rows: int) -> None:
 
 
 def _head_loss_block(x, targets, weights, w, tied: bool, scaling: float):
-    """One block of rows: ``(summed loss, d loss / d x [R, E], d loss / d w)``
-    at a cotangent of 1, both gradients float32. The block's logits are made
+    """One block of rows: ``(summed loss, d loss / d x [R, E], d loss / d w,
+    each row's un-weighted cross-entropy [R], zero on a masked row)`` at a
+    cotangent of 1, both gradients float32. The block's logits are made
     once and die here. ``weights`` (float32 ``[R]`` or None: ones) multiply
     each row's loss."""
     with jax.named_scope("head"):
@@ -430,9 +488,9 @@ def _head_loss_block(x, targets, weights, w, tied: bool, scaling: float):
         lse = jax.nn.logsumexp(logits, axis=-1)
         picked = jnp.sum(jnp.where(hit, logits, 0.0), axis=-1)
         each = lse - picked
-        if weights is not None:
-            each = weights * each
-        num = jnp.sum(jnp.where(valid, each, 0.0))
+        num = jnp.sum(jnp.where(
+            valid, each if weights is None else weights * each, 0.0))
+        each = jnp.where(valid, each, 0.0)
         # softmax - onehot, zero on a masked row; rounded to the compute
         # dtype once, where autodiff rounds the float32 logits' cotangent.
         rows = valid[:, None]
@@ -446,20 +504,31 @@ def _head_loss_block(x, targets, weights, w, tied: bool, scaling: float):
                         preferred_element_type=jnp.float32)
         dw = jnp.einsum("rv,re->ve" if tied else "rv,re->ev", d, x,
                         preferred_element_type=jnp.float32)
-    return num, dx, dw
+    return num, dx, dw, each
+
+
+def _head_loss(x, w, targets, weights, tied: bool, scaling: float, rows: int):
+    """The sum of :func:`_head_loss_rows` alone."""
+    return _head_loss_rows(x, w, targets, weights, tied, scaling, rows)[0]
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
-def _head_loss(x, w, targets, weights, tied: bool, scaling: float, rows: int):
-    """The summed cross-entropy of the rows ``x`` ``[T, E]`` under the head
-    ``w`` against ``targets`` ``[T]`` (negative: a masked row), each row's
-    times its float32 weight (``weights`` ``[T]``; None: ones, and the
-    program is the one without them), as one rule
-    over blocks of ``rows`` rows: a block's logits, in float32 their
+def _head_loss_rows(x, w, targets, weights, tied: bool, scaling: float,
+                    rows: int):
+    """``(sum, each)``: the summed cross-entropy of the rows ``x`` ``[T, E]``
+    under the head ``w`` against ``targets`` ``[T]`` (negative: a masked
+    row), each row's times its float32 weight (``weights`` ``[T]``; None:
+    ones, and the program is the one without them), as one rule over blocks
+    of ``rows`` rows: a block's logits, in float32 their
     log-sum-exp, the block's loss and ``softmax - onehot``, and from that at
     once the block's part of ``d w`` (summed in float32) and its rows of
     ``d x``. No ``[T, V]`` array is made or kept; the backward rule scales
-    the two gradients by the sum's cotangent."""
+    the two gradients by the sum's cotangent, and **``weights`` that are no
+    constants** (a looped stack's exit probabilities) receive that cotangent
+    times each row's cross-entropy. ``each`` is those cross-entropies,
+    float32 ``[T]``, un-weighted, zero on a masked row: **a reading, through
+    which no gradient goes** (the rule drops its cotangent; a caller that
+    differentiates stops it)."""
     return _head_loss_fwd(x, w, targets, weights, tied, scaling, rows)[0]
 
 
@@ -467,25 +536,73 @@ def _head_loss_fwd(x, w, targets, weights, tied, scaling, rows):
     # A Python loop and no ``lax.scan``: the blocks are few, and as a
     # ``while`` they ran 7 to 10 ms behind this on the chip (PERF.md,
     # Findings, PR 41). The last block is the rows that are left.
-    num, dx, dw = zip(*(
-        _head_loss_block(x[i:i + rows], targets[i:i + rows],
-                         None if weights is None else weights[i:i + rows],
-                         w, tied, scaling)
-        for i in range(0, x.shape[0], rows)))
-    # The empty slice hands the backward rule the compute dtype.
-    return sum(num), (jnp.concatenate(dx), sum(dw), x[:0])
+    chained = -(-x.shape[0] // rows) > _HEAD_LOSS_FREE_BLOCKS
+    num, dx, dw, each = [], [], [], []
+    for i in range(0, x.shape[0], rows):
+        xb = x[i:i + rows]
+        if chained and dw:
+            # Many blocks (a looped stack's passes x rows): a block's rows
+            # wait for the sum of the weight gradient over the blocks before
+            # it, or the scheduler makes every block's logits first and
+            # holds them all (16 x 192 MB at 32,768 rows of 49,152: PERF.md,
+            # Findings, PR 69).
+            xb, dw[0] = lax.optimization_barrier((xb, dw[0]))
+        num_b, dx_b, dw_b, each_b = _head_loss_block(
+            xb, targets[i:i + rows],
+            None if weights is None else weights[i:i + rows],
+            w, tied, scaling)
+        num.append(num_b)
+        dx.append(dx_b)
+        each.append(each_b)
+        # Chained, ``dw`` holds the running sum alone.
+        if chained and dw:
+            dw[0] = dw[0] + dw_b
+        else:
+            dw.append(dw_b)
+    each = jnp.concatenate(each)
+    # The empty slice hands the backward rule the compute dtype; the rows'
+    # cross-entropies are kept for the weights' cotangent, where there are
+    # weights (a caller's constants take none, and the compiler drops it).
+    return (sum(num), each), (jnp.concatenate(dx), sum(dw), x[:0],
+                              None if weights is None else each)
 
 
-def _head_loss_bwd(tied, scaling, rows, residuals, g):
-    dx, dw, like = residuals
+def _head_loss_bwd(tied, scaling, rows, residuals, cotangents):
+    dx, dw, like, each = residuals
+    g, _ = cotangents
     with jax.named_scope("head"):
         # Scaled in float32 and rounded to the compute dtype once, as the
         # products autodiff makes in that dtype are.
         return ((g * dx).astype(like.dtype), (g * dw).astype(like.dtype),
-                None, None)
+                None, None if each is None else g * each)
 
 
-_head_loss.defvjp(_head_loss_fwd, _head_loss_bwd)
+_head_loss_rows.defvjp(_head_loss_fwd, _head_loss_bwd)
+
+
+def _exit_scores(gate, x, passes: int):
+    """The exit gate's scores ``w . h^t + b``, float32 ``[passes, rows]``,
+    from the passes' normed rows ``x`` ``[passes * rows, E]``, pass after
+    pass. A float32 sum over the lanes, no matrix product: one column would
+    idle the MXU, and its default precision is one bfloat16 pass."""
+    return (jnp.sum(x.astype(jnp.float32) * gate["w"], axis=-1)
+            + gate["b"]).reshape(passes, -1)
+
+
+def _exit_distribution(score):
+    """A looped stack's exit distribution over its passes, a token, float32:
+    ``(p [passes, rows], its entropy [rows])`` from the gate's scores. The
+    gate is ``lambda^t = sigmoid(score^t)``; ``p^1 = lambda^1``, ``p^t =
+    lambda^t prod_{j<t} (1 - lambda^j)`` and the last pass takes the mass
+    that is left, ``prod_{j<T} (1 - lambda^j)`` (``lambda^T`` is not read),
+    so a token's ``p`` add up to 1. In logarithms throughout: a gate that
+    has closed gives ``p log p`` its limit and no ``0 * inf``."""
+    log_stay = jax.nn.log_sigmoid(-score)            # log(1 - lambda)
+    stayed = jnp.cumsum(log_stay, axis=0) - log_stay      # over j < t
+    log_p = jnp.concatenate(
+        [jax.nn.log_sigmoid(score[:-1]) + stayed[:-1], stayed[-1:]])
+    p = jnp.exp(log_p)
+    return p, -jnp.sum(p * log_p, axis=0)
 
 
 def forward(params, tokens, positions, cfg: GPTConfig):
@@ -540,13 +657,33 @@ def loss_and_aux(params, tokens, targets, positions, cfg: GPTConfig,
     (an ep group's) tokens as each block's router read them and what it
     gave.
 
+    **A looped stack** (``cfg.loop_passes`` = T > 1; it takes neither
+    ``weights`` nor ``divisor``): every pass's rows go through the head in
+    one call of the rule, T x the rows, each row's cross-entropy ``l^t_i``
+    weighted by that token's exit probability at that pass
+    (:func:`_exit_distribution`; the weights are functions of the gate's
+    parameters and of the hidden states, and the rule hands them their
+    cotangent), and the loss is ``1/N sum_i [sum_t p^t_i l^t_i -
+    exit_entropy_coef H(p_i)]`` over the N targets kept, no stop-gradient
+    anywhere. ``aux`` then holds ``cross_entropy`` (the expected one, the
+    first term), ``pass_losses`` ``[T]`` (each pass's mean ``l^t``),
+    ``exit_probs`` ``[T]`` (the mean ``p^t``) and ``exit_entropy`` (the mean
+    ``H``). The gate, the distribution, the entropy and their backward pass
+    lie under the scope ``exit``.
+
     It makes no logits ``[B, S_local, vocab]``: head and loss are one rule
     over blocks of token rows (:func:`_head_loss`, ``head_loss_rows`` rows a
     block), equal to :func:`forward` and a float32 cross-entropy and their
     gradients.
     """
-    x, auxes = _hidden(params, tokens, positions, cfg)
+    states, auxes = _passes(params, tokens, positions, cfg)
+    x, passes = states[-1], cfg.loop_passes
     mask = (targets != ignore_index)
+    if passes > 1 and (weights is not None or divisor is not None):
+        raise ValueError(
+            f"loop_passes={passes}: the rows' weights are the exit "
+            "distribution's and the divisor the targets kept; a caller "
+            "gives neither")
     with jax.named_scope("head"):
         if targets.shape[1] != x.shape[1]:
             if cfg.diffusion_block is None \
@@ -559,16 +696,37 @@ def loss_and_aux(params, tokens, targets, positions, cfg: GPTConfig,
                     f"(diffusion_block={cfg.diffusion_block})")
             x = x[:, :targets.shape[1]]
         x = x.reshape(-1, x.shape[-1])
+        if passes > 1:
+            # Every pass's rows through the head's one rule, pass after pass.
+            x = jnp.concatenate(
+                [state.reshape(x.shape) for state in states])
         rows = head_loss_rows(x.shape[0], cfg.vocab_size)
         _note_head_loss(cfg, x.shape[0], rows)
         # A replicated matrix enters the rule as varying as the rows are: a
         # rule's cotangent has its input's type, and the mark's transpose
         # sums the matrix's over the ranks.
         w = varying_like(_head_matrix(params, cfg), x)
-    num = _head_loss(x, w, jnp.where(mask, targets, -1).reshape(-1),
-                     None if weights is None
-                     else weights.astype(jnp.float32).reshape(-1),
-                     cfg.tie_embeddings, cfg.logits_scaling, rows)
+    flat_targets = jnp.where(mask, targets, -1).reshape(-1)
+    if passes == 1:
+        num = _head_loss(x, w, flat_targets,
+                         None if weights is None
+                         else weights.astype(jnp.float32).reshape(-1),
+                         cfg.tie_embeddings, cfg.logits_scaling, rows)
+        sums = {}
+    else:
+        kept = mask.reshape(-1).astype(jnp.float32)
+        with jax.named_scope("exit"):
+            probs, entropy = _exit_distribution(
+                _exit_scores(params["exit_gate"], x, passes))
+        num, each = _head_loss_rows(
+            x, w, jnp.tile(flat_targets, passes), probs.reshape(-1),
+            cfg.tie_embeddings, cfg.logits_scaling, rows)
+        with jax.named_scope("exit"):
+            # Sums over the targets kept; a masked row's ``each`` is zero.
+            sums = {"exit_entropy": jnp.sum(kept * entropy),
+                    "exit_probs": jnp.sum(kept * probs, axis=1),
+                    "pass_losses": jnp.sum(
+                        lax.stop_gradient(each).reshape(passes, -1), axis=1)}
     with jax.named_scope("loss"):
         den = jnp.sum(mask.astype(jnp.float32))
         # The token population is sharded over sp (sequence) and, when experts
@@ -579,11 +737,22 @@ def loss_and_aux(params, tokens, targets, positions, cfg: GPTConfig,
             if _axis_bound(ax):
                 num = lax.psum(num, ax)
                 den = lax.psum(den, ax)
+                if sums:
+                    sums = lax.psum(sums, ax)
         loss = num / (jnp.maximum(den, 1.0) if divisor is None else divisor)
+    parts = {"cross_entropy": loss}
+    if sums:
+        with jax.named_scope("exit"):
+            # The expected cross-entropy, each pass's own mean, the mean exit
+            # distribution and its mean entropy; the loss less the entropy's
+            # share.
+            parts.update({name: total / jnp.maximum(den, 1.0)
+                          for name, total in sums.items()})
+            loss = loss - cfg.exit_entropy_coef * parts["exit_entropy"]
     if not auxes:
-        return loss, {"cross_entropy": loss}
+        return loss, parts
     with jax.named_scope("aux_loss"):
-        aux = {"cross_entropy": loss,
+        aux = {**parts,
                "load_balance": sum(a["load_balance"] for a in auxes),
                "router_z": sum(a["router_z"] for a in auxes),
                "counts": jnp.stack([a["counts"] for a in auxes])}

@@ -210,6 +210,12 @@ _TRACED = {
         "layers beside the stream, by the value's name, the layer that "
         "publishes it and how many later layers read it.",
         ("value", "producer", "readers")),
+    "hvdtpu_spmd_loop_passes_total": (
+        "Times JAX traced a looped stack (models/gpt.py::_passes: the same "
+        "layers run more than once a step, GPTConfig.loop_passes), by its "
+        "passes and the layers a pass runs: passes x layers block "
+        "applications a forward pass. A stack that runs once counts nothing.",
+        ("passes", "layers")),
     "hvdtpu_spmd_gdn_kernel_traces_total": (
         "Times JAX traced one of the gated delta rule's kernels (the "
         "chunk-local pair, the recurrence over chunks' pair), by kernel and "

@@ -157,12 +157,12 @@ FIELDS = {
     "kda_value_dim": 128, "kda_conv": 4, "kda_chunk": 64,
     "kda_lower_bound": -5.0,
     "mla_head_gate": False, "router_groups": 1, "router_groups_kept": 1,
-    "diffusion_block": None,
+    "diffusion_block": None, "loop_passes": 1, "exit_entropy_coef": 0.0,
 }
 
 
 def test_the_configurations_fields_are_the_frozen_list():
-    assert len(FIELDS) == 82
+    assert len(FIELDS) == 84
     assert {f.name: f.default for f in dataclasses.fields(gpt.GPTConfig)} \
         == FIELDS
     cfg = gpt.GPTConfig(num_kv_heads=2, expert_dim=48)

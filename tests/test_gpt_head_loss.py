@@ -191,6 +191,55 @@ def test_a_cotangent_other_than_one_scales_both_gradients(factor, dtype):
     assert_close(grads, want, dtype)
 
 
+@pytest.mark.parametrize("masked", ["none", "some", "a_block"])
+@pytest.mark.parametrize("rows", [48, 16, 4],
+                         ids=["one_block", "three_blocks", "twelve_chained"])
+@pytest.mark.parametrize("tied", [False, True], ids=["untied", "tied"])
+def test_weights_that_are_no_constants_receive_their_cotangent(
+        tied, rows, masked):
+    """The rule against ``jax.grad`` of a plain float32 weighted
+    cross-entropy, at a cotangent other than one: the rows, the matrix and
+    **the weights** (``g`` times each row's cross-entropy, nothing on a
+    masked row), whatever the blocks: twelve are past
+    ``_HEAD_LOSS_FREE_BLOCKS``, where a block waits for the weight
+    gradient's sum over the blocks before it. ``each`` is those
+    cross-entropies, and carries no gradient."""
+    assert -(-48 // rows) > gpt._HEAD_LOSS_FREE_BLOCKS or rows > 4
+    rng = np.random.default_rng(rows)
+    x = jnp.asarray(rng.normal(size=(48, 16)), jnp.float32)
+    w = jnp.asarray(rng.normal(size=(VOCAB, 16) if tied else (16, VOCAB)),
+                    jnp.float32)
+    weights = jnp.asarray(rng.uniform(0.1, 2.0, 48), jnp.float32)
+    targets = jnp.asarray(batch(24, masked)[1].reshape(-1))
+
+    def plain(x, w, weights):
+        logp = jax.nn.log_softmax(gpt._logits(x, w, tied, 2.0))
+        picked = jnp.take_along_axis(
+            logp, jnp.where(targets >= 0, targets, 0)[:, None], axis=-1)[:, 0]
+        return -2.5 * jnp.sum(jnp.where(targets >= 0, weights * picked, 0.0))
+
+    def rule(x, w, weights):
+        total, each = gpt._head_loss_rows(x, w, targets, weights, tied, 2.0,
+                                          rows)
+        # ``each`` is a reading: what it is multiplied by here reaches no
+        # gradient.
+        return 2.5 * total + 0.0 * jnp.sum(each), each
+
+    (got, each), grads = jax.jit(jax.value_and_grad(
+        rule, argnums=(0, 1, 2), has_aux=True))(x, w, weights)
+    want, want_grads = jax.jit(jax.value_and_grad(
+        plain, argnums=(0, 1, 2)))(x, w, weights)
+    np.testing.assert_allclose(got, want, rtol=2e-6)
+    for g, wg in zip(grads, want_grads):
+        np.testing.assert_allclose(g, wg, rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(grads[2], 2.5 * each, rtol=1e-6)
+    assert not np.asarray(grads[2])[np.asarray(targets) < 0].any()
+    # The sum alone is the rule's sum, and constants take no cotangent.
+    np.testing.assert_allclose(
+        gpt._head_loss(x, w, targets, weights, tied, 2.0, rows) * 2.5, want,
+        rtol=2e-6)
+
+
 @pytest.mark.parametrize("budget", [SMALL, gpt._HEAD_LOSS_BLOCK_BYTES],
                          ids=["three_blocks", "one_block"])
 def test_with_the_expert_terms_added_under_has_aux(budget):

@@ -299,7 +299,7 @@ def test_a_checkpointed_block_makes_its_shared_experts_products_once(
 
 def test_config_field_count():
     # CHANGES.md says how many there were and are; a new one is said there.
-    assert len(dataclasses.fields(gpt.GPTConfig)) == 82
+    assert len(dataclasses.fields(gpt.GPTConfig)) == 84
     assert len(dataclasses.fields(LayerSpec)) == 7
 
 

@@ -393,6 +393,57 @@ def test_block_diffusion_step_holds_its_flash_kernels_under_attn_bd(spmd4):
          ("tied", "false"), ("vocab", "64")): 1.0}
 
 
+def test_looped_step_holds_its_passes_and_its_exit_under_their_scopes(spmd4):
+    """A looped stack puts ``pass<t>`` around ``layer<i>`` and around the
+    norm at the end of the pass (``head``): a reader that looks for ``attn``
+    or ``mlp`` finds them as before (``scope_reduce.scope_of``), the flash
+    kernels lie under ``pass<t>/layer<i>/attn``. The exit gate's product,
+    the distribution, the entropy and their backward pass lie under ``exit``
+    (where ``loop_exit_ms`` looks) and not under ``head`` or ``loss``
+    (``loop_head_ms``); the counter says passes and layers
+    (``loop_block_calls``); the head's rule is traced once, over passes x
+    the rows."""
+    from benchmarks import scope_reduce
+
+    names = op_names(*gpt_step(
+        "full", loop_passes=3, exit_entropy_coef=0.1, norms="pre_post",
+        gated_mlp=True))
+
+    def some(*parts):
+        return any(all(p in n for p in parts) for n in names)
+
+    for t in range(3):
+        for scope in ("layer0/attn", "layer1/mlp", "layer1/mlp/post_norm",
+                      "head/"):
+            assert some(f"jvp(pass{t})/{scope}"), (t, scope)
+        assert some(f"transpose(jvp(pass{t}))/layer0", "/attn/")
+        assert some(f"transpose(jvp(pass{t}))", "rematted_computation/mlp")
+    assert not some("pass3")
+    assert some("jvp(exit)/") and some("transpose(jvp(exit))")
+    # (A reducer's own computation carries a name without the step's prefix,
+    # and a parameter's is its path: neither is an operation's.)
+    exits = [scope_reduce.scope_of(n) for n in names
+             if n.startswith("jit(") and "(exit)" in n]
+    assert exits and all(
+        "exit" in scopes and not {"head", "loss"} & set(scopes)
+        for scopes in exits), exits
+    for primitive in ("log", "exp", "cumsum", "reduce_sum"):
+        assert some("jvp(exit)", primitive), primitive
+    assert some("jvp(head)/...e,ev->...v/dot_general")
+    found = [scope_reduce.scope_of(n) for n in names
+             if n.startswith("jit(") and "jvp(pass2)/layer1/attn" in n]
+    assert found and all(scopes[:3] == ["pass2", "layer1", "attn"]
+                         for scopes in found), found
+    samples = {tuple(sorted(labels.items())): count for _, labels, count in
+               hvd.metrics()["hvdtpu_spmd_loop_passes_total"]["samples"]}
+    assert samples == {(("layers", "2"), ("passes", "3")): 1.0}
+    # Three passes of a rank's 128 rows in the rule's one call.
+    head = {tuple(sorted(labels.items())) for _, labels, _ in
+            hvd.metrics()["hvdtpu_spmd_head_loss_traces_total"]["samples"]}
+    assert head == {(("blocks", "1"), ("rows_per_block", str(3 * B * S // 4)),
+                     ("tied", "false"), ("vocab", "64"))}
+
+
 # A CCA mixer and an expert sublayer under an MLP router in both layers,
 # each sublayer joined under the residual scaling.
 CCA = dict(layers=(gpt.LayerSpec(mixer="cca", ff="experts"),) * 2,
