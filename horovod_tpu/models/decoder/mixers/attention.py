@@ -13,9 +13,11 @@ at the layer's root."""
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental.layout import Layout, with_layout_constraint
 from jax.sharding import PartitionSpec as P
 
 from ....ops.attention import rope
+from ....ops.pallas_util import LANES
 from ..config import GPTConfig, LayerSpec
 from ..parts import _attention, _norm, _projection_norm, _tp_psum, readings
 
@@ -51,6 +53,22 @@ def _parameters(cfg: GPTConfig, keys=None, dense=None, norm=None) -> dict:
 init, specs = readings(_parameters)
 
 
+@jax.custom_vjp
+def _cotangent_sequence_minor(q):
+    """``q``, ``[B, S, H, D]``, whose cotangent comes back with the sequence
+    minor in memory (``[B, H, D, S]``): the layout in which XLA's product
+    ``dq x wq -> dh``, a contraction over ``(h, d)``, reads it fastest, and
+    the one it had while ``flash_attention`` copied every cotangent
+    (``apply``)."""
+    return q
+
+
+_cotangent_sequence_minor.defvjp(
+    lambda q: (q, None),
+    lambda _, dq: (with_layout_constraint(
+        dq, Layout(major_to_minor=(0, 2, 3, 1))),))
+
+
 def apply(cfg: GPTConfig, spec: LayerSpec, lp, h, positions):
     """Softmax attention on normed activations ``h``: the projections, the
     norms of q and k, the rotary embedding where ``spec.rope`` says so, the
@@ -75,7 +93,33 @@ def apply(cfg: GPTConfig, spec: LayerSpec, lp, h, positions):
         # square root of head_dim: the rest goes onto q.
         q = q * (cfg.attention_multiplier
                  * float(np.sqrt(cfg.head_dim)))
-    attn = _attention(cfg, q, k, v, spec.window)
+    # The turn between these products' ``[B, S, H, D]`` and the flash
+    # kernels' ``[B, H, S, D]`` is this mixer's to place. Merged to
+    # ``[B*H, S, D]`` (``flash_attention``'s default) it is a copy of q, k, v,
+    # the output and every cotangent at a batch of two or more, and nothing
+    # at a batch of one, where the merge is a bitcast to XLA. Left at rank 4
+    # (``heads_major``), XLA folds it into the neighbours, which here are
+    # elementwise (the rotary embedding, the norms, a bias, the gate) but
+    # for the products that contract over ``(h, d)`` and read ``[B, H, S, D]``
+    # with the sequence between the two, at 2.5 times the compiler's estimate
+    # for the sequence-minor read: the output projection, which reads the
+    # kernels' output (cheaper than the copies all the same: Ouro), and q's
+    # projection backward, ``dq x wq -> dh``, which reads dq through the
+    # rotary embedding's transpose alone unless a head's norm, a reduction
+    # over ``d`` in float32, stands between and turns it anyway. Without one
+    # the cotangent is asked for sequence-minor, one copy kept of the eight:
+    # 24:2 heads at 4096 rows lose 2.1% of their tokens a second without it
+    # and gain 1.4% with it. Heads of one lane tile alone. Narrower ones are
+    # outside ISSUE 70's scope and unmeasured (the kernels take any width at
+    # rank 4; Granite's estimate reads -0.2%). Wider ones were measured and
+    # lost: Qwen's 16:2 heads of 256, one layer in four, 1.03% of a step, and
+    # not to a layout: XLA's memory-space assignment stopped prefetching
+    # three other layers' output-projection weights in the re-scheduled
+    # program (PERF.md, Findings, PR 70 and section 7).
+    heads_major = q.shape[0] > 1 and cfg.head_dim == LANES
+    if heads_major and not cfg.qk_head_norm:
+        q = _cotangent_sequence_minor(q)
+    attn = _attention(cfg, q, k, v, spec.window, heads_major=heads_major)
     if cfg.attention_gate:
         attn = (attn.astype(jnp.float32) * jax.nn.sigmoid(
             gate.astype(jnp.float32))).astype(cfg.dtype)

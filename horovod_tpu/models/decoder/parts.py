@@ -93,9 +93,13 @@ def _tp_psum(x, cfg: GPTConfig):
 _ATTENTION_KINDS = ("flash", "dense", "ring", "ulysses")
 
 
-def _attention(cfg: GPTConfig, q, k, v, window: Optional[int] = None):
+def _attention(cfg: GPTConfig, q, k, v, window: Optional[int] = None,
+               heads_major: bool = False):
     """Which attention runs: the one place that decides, and this table is
-    the whole rule. No row falls back to another. ``window`` (a layer's,
+    the whole rule. No row falls back to another. ``heads_major`` is a
+    mixer's word on the layouts round the flash kernels where they run on
+    the sequence a rank holds (``flash_attention``); no other row reads it.
+    ``window`` (a layer's,
     ``LayerSpec.window``) goes to whichever runs: the flash kernels and the
     dense reference take it, Ulysses hands it to the kernel it calls, and
     ring attention under a bound sp axis refuses a window layer by name
@@ -134,7 +138,8 @@ def _attention(cfg: GPTConfig, q, k, v, window: Optional[int] = None):
                                      causal=True, window=window,
                                      block_diffusion=block)
         return flash_attention(q, k, v, causal=True, window=window,
-                               block_diffusion=block)
+                               block_diffusion=block,
+                               heads_major=heads_major)
     if block is not None and kind in ("ring", "ulysses"):
         raise ValueError(
             f"attention={kind!r} under the bound {sp!r} axis has no "

@@ -13,7 +13,13 @@ exists to avoid. Algorithm: FlashAttention online-softmax tiling
 Design notes (TPU):
 
 * Layout: q, o, dO, dQ are ``[B*H, S, D]``; k, v, dK, dV stay at their own
-  head count, ``[B*Hkv, S, D]``. Grouped-query attention is an index map:
+  head count, ``[B*Hkv, S, D]``; or, where ``flash_attention``'s caller
+  asks (``heads_major``), the same at rank 4, ``[B, H, S, D]`` and
+  ``[B, Hkv, S, D]``: the same bytes in the same order, reached from a
+  model's ``[B, S, H, D]`` by a transpose alone, which XLA folds into the
+  layouts of the array's producers and consumers, where the merge to rank 3
+  costs a copy of every operand, output and cotangent (``_dims``; PERF.md,
+  Findings, PR 70). Grouped-query attention is an index map:
   query head ``h`` reads K/V head ``h // group``; nothing is repeated in
   HBM, and dK/dV of one K/V head accumulate over its whole group inside the
   backward kernel and are written once. A value head may have another width
@@ -110,7 +116,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from .. import runtime
 from .pallas_util import LANES, NEG_INF, NT, always, div as _div, \
-    out_vma as _out_vma, use_interpret as _use_interpret
+    out_vma as _out_vma, rem as _rem, use_interpret as _use_interpret
 
 _PAD = 128    # the sequence is padded to this many rows, whatever the block
 # The kernels' names in the compiled program: each becomes the name of its
@@ -641,10 +647,52 @@ def _bwd_kernel(tiles_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def _pad_seq(x):
-    pad = (-x.shape[1]) % _PAD
+    """Pads the rows, an operand's last axis but one, to ``_PAD``."""
+    pad = (-x.shape[-2]) % _PAD
     if pad:
-        x = jnp.pad(x, ((0, 0), (0, pad), (0, 0)))
+        x = jnp.pad(x, ((0, 0),) * (x.ndim - 2) + ((0, pad), (0, 0)))
     return x
+
+
+def _dims(q, k, v):
+    """``(B*H, B*Hkv, S, D, Dv, H, Hkv)`` of a call's operands, which come
+    one of two ways. Rank 3, a head a row of the first axis: q
+    ``[B*H, S, D]``, k ``[B*Hkv, S, D]``, v ``[B*Hkv, S, Dv]``, and ``H``
+    and ``Hkv`` are None. Rank 4: q ``[B, H, S, D]``, k ``[B, Hkv, S, D]``,
+    v ``[B, Hkv, S, Dv]``, the same bytes in the same order. What differs
+    is what XLA sees round the call: a model's ``[B, S, H, D]`` turned to
+    rank 4 is a transpose alone, which layout assignment folds into the
+    layout of whatever wrote the array; merged to rank 3 it is a reshape
+    too, and at a batch of two or more the layout does not cross it and the
+    turn is a ``copy`` (PERF.md, Findings, PR 70). Outputs and cotangents
+    come as their operands do; the row statistics are ``[B*H, ...]`` both
+    ways."""
+    if q.ndim == 3:
+        return q.shape[0], k.shape[0], *q.shape[1:], v.shape[2], None, None
+    b, h, s, d = q.shape
+    return b * h, b * k.shape[1], s, d, v.shape[3], h, k.shape[1]
+
+
+def _head_block(rows: int, width: int, heads: Optional[int], at):
+    """The block of ``rows`` rows, ``width`` wide, of one head:
+    ``at(*grid indices)`` gives ``(head, block)``, the head counted over
+    ``B * heads`` as a grid's first axis counts it. ``heads`` None: of a
+    rank-3 operand ``[B*heads, S, width]``; otherwise of ``[B, heads, S,
+    width]``, the batch axis squeezed, so a kernel's ref is ``[1, rows,
+    width]`` both ways."""
+    if heads is None:
+        return pl.BlockSpec((1, rows, width), lambda *g: (*at(*g), 0))
+
+    def index(*g):
+        head, block = at(*g)
+        return _div(head, heads), _rem(head, heads), block, 0
+
+    return pl.BlockSpec((None, 1, rows, width), index)
+
+
+def _shape_of(n: int, s: int, d: int, heads: Optional[int]):
+    """``n = B * heads`` heads of ``s`` rows of ``d`` as an output comes."""
+    return (n, s, d) if heads is None else (n // heads, heads, s, d)
 
 
 def _tiles_for(kernel, q, k, v, mask: Mask, forced, dq: str = "own",
@@ -656,46 +704,44 @@ def _tiles_for(kernel, q, k, v, mask: Mask, forced, dq: str = "own",
     kernel that makes it too, ``own`` on the pair's two, ``none`` on the
     forward), how the rectangle's tiles fall, and the steps a head's grid
     walks, which is the table's length."""
-    bq, bk = forced or block_sizes(kernel, q.shape[1], q.shape[2], q.dtype,
-                                   mask.causal, v.shape[2])
-    n_q, n_k = q.shape[1] // bq, q.shape[1] // bk
+    bh, bkv, s, d, dv = _dims(q, k, v)[:5]
+    bq, bk = forced or block_sizes(kernel, s, d, q.dtype, mask.causal, dv)
+    n_q, n_k = s // bq, s // bk
     tiles = mask.kept_tiles(n_q, n_k, bq, bk, by_column)
     runtime.note_traced(
         "hvdtpu_spmd_flash_kernel_traces_total", kernel=kernel, block_q=bq,
         block_k=bk, operand_dtype=jnp.dtype(q.dtype).name,
-        kv_group=q.shape[0] // k.shape[0], key_dim=q.shape[2],
-        value_dim=v.shape[2], dq=dq)
+        kv_group=bh // bkv, key_dim=d, value_dim=dv, dq=dq)
     for fall, n in mask.tiles(n_q, n_k, bq, bk).items():
         runtime.note_traced(
             "hvdtpu_spmd_flash_tiles_total", n, kernel=kernel,
-            mask=mask.name, tiles=fall, seq=q.shape[1])
+            mask=mask.name, tiles=fall, seq=s)
     runtime.note_traced(
         "hvdtpu_spmd_flash_grid_steps_total", tiles.shape[1], kernel=kernel,
-        mask=mask.name, seq=q.shape[1])
+        mask=mask.name, seq=s)
     if mask.kept_pairs() is not None:
         for pairs, n in (("computed", tiles.shape[1] * bq * bk),
                          ("kept", mask.kept_pairs())):
             runtime.note_traced(
                 "hvdtpu_spmd_flash_pairs_total", n, kernel=kernel,
-                mask=mask.name, pairs=pairs, seq=q.shape[1])
+                mask=mask.name, pairs=pairs, seq=s)
     return bq, bk, tiles
 
 
 def _fwd_call(q, k, v, sm_scale, mask, kv_len, forced=None):
-    """q: [B*H, S, D], k: [B*Hkv, S, D], v: [B*Hkv, S, Dv] (S already
-    padded; ``kv_len`` is the real key count before padding; ``mask`` a
-    :class:`Mask`). Returns (o [B*H, S, Dv], lse), lse lane-replicated
-    [B*H, S, 128]."""
-    bh, s, d = q.shape
-    dv = v.shape[2]
-    group = bh // k.shape[0]
+    """q: [B*H, S, D], k: [B*Hkv, S, D], v: [B*Hkv, S, Dv], or each at
+    rank 4 (``_dims``; S already padded; ``kv_len`` is the real key count
+    before padding; ``mask`` a :class:`Mask`). Returns (o, lse): o as q
+    comes, ``Dv`` wide; lse lane-replicated [B*H, S, 128] either way."""
+    bh, bkv, s, d, dv, h, hkv = _dims(q, k, v)
+    group = bh // bkv
     bq, bk, tiles = _tiles_for(KERNEL_FWD, q, k, v, mask, forced, dq="none")
 
-    def q_map(b, t, ref):
-        return b, _at(tiles, ref, TILE_Q, t), 0
+    def q_at(b, t, ref):
+        return b, _at(tiles, ref, TILE_Q, t)
 
-    def kv_map(b, t, ref):
-        return _div(b, group), _at(tiles, ref, TILE_K, t), 0
+    def kv_at(b, t, ref):
+        return _div(b, group), _at(tiles, ref, TILE_K, t)
 
     kernel = functools.partial(_fwd_kernel, tiles=tiles, sm_scale=sm_scale,
                                block_q=bq, block_k=bk, mask=mask,
@@ -707,14 +753,14 @@ def _fwd_call(q, k, v, sm_scale, mask, kv_len, forced=None):
             num_scalar_prefetch=1,
             grid=(bh, tiles.shape[1]),
             in_specs=[
-                pl.BlockSpec((1, bq, d), q_map),
-                pl.BlockSpec((1, bk, d), kv_map),
-                pl.BlockSpec((1, bk, dv), kv_map),
+                _head_block(bq, d, h, q_at),
+                _head_block(bk, d, hkv, kv_at),
+                _head_block(bk, dv, hkv, kv_at),
             ],
             out_specs=[
-                pl.BlockSpec((1, bq, dv), q_map),
+                _head_block(bq, dv, h, q_at),
                 # lse rides lane-replicated [bh, s, 128] (see _fwd_kernel).
-                pl.BlockSpec((1, bq, LANES), q_map),
+                _head_block(bq, LANES, None, q_at),
             ],
             scratch_shapes=[
                 pltpu.VMEM((bq, LANES), jnp.float32),   # running max
@@ -722,7 +768,7 @@ def _fwd_call(q, k, v, sm_scale, mask, kv_len, forced=None):
                 pltpu.VMEM((bq, dv), jnp.float32),      # output accumulator
             ]),
         out_shape=[
-            jax.ShapeDtypeStruct((bh, s, dv), q.dtype,
+            jax.ShapeDtypeStruct(_shape_of(bh, s, dv, h), q.dtype,
                                  vma=_out_vma(q, k, v)),
             jax.ShapeDtypeStruct((bh, s, LANES), jnp.float32,
                                  vma=_out_vma(q, k, v)),
@@ -754,25 +800,24 @@ def _over_group(tiles: np.ndarray, group: int) -> np.ndarray:
 def _dkdv_call(q, k, v, do, lse, delta, sm_scale, mask, kv_len,
                forced=None):
     """dK, dV at the K/V head count (``v``, ``do`` and dV as wide as a
-    value head). ``lse``/``delta``: [B*H, 1, S] rows."""
-    bh, s, d = q.shape
-    dv = v.shape[2]
-    bkv = k.shape[0]
+    value head), at the rank of k and v (``_dims``). ``lse``/``delta``:
+    [B*H, 1, S] rows either way."""
+    bh, bkv, s, d, dv, h, hkv = _dims(q, k, v)
     group = bh // bkv
     bq, bk, tiles = _tiles_for(KERNEL_DKDV, q, k, v, mask, forced,
                                by_column=True)
     tiles = _over_group(tiles, group)
 
-    def q_map(b, t, ref):
+    def q_at(b, t, ref):
         return (b * group + _at(tiles, ref, TILE_HEAD, t),
-                _at(tiles, ref, TILE_Q, t), 0)
+                _at(tiles, ref, TILE_Q, t))
 
     def row_map(b, t, ref):
-        head, i, _ = q_map(b, t, ref)
+        head, i = q_at(b, t, ref)
         return head, 0, i
 
-    def kv_map(b, t, ref):
-        return b, _at(tiles, ref, TILE_K, t), 0
+    def kv_at(b, t, ref):
+        return b, _at(tiles, ref, TILE_K, t)
 
     kernel = functools.partial(_dkdv_kernel, tiles=tiles, sm_scale=sm_scale,
                                block_q=bq, block_k=bk, mask=mask,
@@ -785,24 +830,25 @@ def _dkdv_call(q, k, v, do, lse, delta, sm_scale, mask, kv_len,
             num_scalar_prefetch=1,
             grid=(bkv, tiles.shape[1]),
             in_specs=[
-                pl.BlockSpec((1, bq, d), q_map),                       # q
-                pl.BlockSpec((1, bk, d), kv_map),                      # k
-                pl.BlockSpec((1, bk, dv), kv_map),                     # v
-                pl.BlockSpec((1, bq, dv), q_map),                      # do
+                _head_block(bq, d, h, q_at),                           # q
+                _head_block(bk, d, hkv, kv_at),                        # k
+                _head_block(bk, dv, hkv, kv_at),                       # v
+                _head_block(bq, dv, h, q_at),                          # do
                 pl.BlockSpec((1, 1, bq), row_map),                     # lse
                 pl.BlockSpec((1, 1, bq), row_map),                     # delta
             ],
             out_specs=[
-                pl.BlockSpec((1, bk, d), kv_map),
-                pl.BlockSpec((1, bk, dv), kv_map),
+                _head_block(bk, d, hkv, kv_at),
+                _head_block(bk, dv, hkv, kv_at),
             ],
             scratch_shapes=[
                 pltpu.VMEM((bk, d), jnp.float32),
                 pltpu.VMEM((bk, dv), jnp.float32),
             ]),
         out_shape=[
-            jax.ShapeDtypeStruct((bkv, s, d), k.dtype, vma=vma),
-            jax.ShapeDtypeStruct((bkv, s, dv), v.dtype, vma=vma),
+            jax.ShapeDtypeStruct(_shape_of(bkv, s, d, hkv), k.dtype, vma=vma),
+            jax.ShapeDtypeStruct(_shape_of(bkv, s, dv, hkv), v.dtype,
+                                 vma=vma),
         ],
         compiler_params=_compiler_params(),
         interpret=_use_interpret(),
@@ -811,18 +857,18 @@ def _dkdv_call(q, k, v, do, lse, delta, sm_scale, mask, kv_len,
 
 
 def _dq_call(q, k, v, do, lse, delta, sm_scale, mask, kv_len, forced=None):
-    """dQ (``v`` and ``do`` as wide as a value head). ``lse``/``delta``:
-    lane-replicated [B*H, S, 128]."""
-    bh, s, d = q.shape
-    dv = v.shape[2]
-    group = bh // k.shape[0]
+    """dQ (``v`` and ``do`` as wide as a value head), at q's rank
+    (``_dims``). ``lse``/``delta``: lane-replicated [B*H, S, 128] either
+    way."""
+    bh, bkv, s, d, dv, h, hkv = _dims(q, k, v)
+    group = bh // bkv
     bq, bk, tiles = _tiles_for(KERNEL_DQ, q, k, v, mask, forced)
 
-    def q_map(b, t, ref):
-        return b, _at(tiles, ref, TILE_Q, t), 0
+    def q_at(b, t, ref):
+        return b, _at(tiles, ref, TILE_Q, t)
 
-    def kv_map(b, t, ref):
-        return _div(b, group), _at(tiles, ref, TILE_K, t), 0
+    def kv_at(b, t, ref):
+        return _div(b, group), _at(tiles, ref, TILE_K, t)
 
     kernel = functools.partial(_dq_kernel, tiles=tiles, sm_scale=sm_scale,
                                block_q=bq, block_k=bk, mask=mask,
@@ -834,16 +880,16 @@ def _dq_call(q, k, v, do, lse, delta, sm_scale, mask, kv_len, forced=None):
             num_scalar_prefetch=1,
             grid=(bh, tiles.shape[1]),
             in_specs=[
-                pl.BlockSpec((1, bq, d), q_map),                       # q
-                pl.BlockSpec((1, bk, d), kv_map),                      # k
-                pl.BlockSpec((1, bk, dv), kv_map),                     # v
-                pl.BlockSpec((1, bq, dv), q_map),                      # do
-                pl.BlockSpec((1, bq, LANES), q_map),                   # lse
-                pl.BlockSpec((1, bq, LANES), q_map),                   # delta
+                _head_block(bq, d, h, q_at),                           # q
+                _head_block(bk, d, hkv, kv_at),                        # k
+                _head_block(bk, dv, hkv, kv_at),                       # v
+                _head_block(bq, dv, h, q_at),                          # do
+                _head_block(bq, LANES, None, q_at),                    # lse
+                _head_block(bq, LANES, None, q_at),                    # delta
             ],
-            out_specs=pl.BlockSpec((1, bq, d), q_map),
+            out_specs=_head_block(bq, d, h, q_at),
             scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)]),
-        out_shape=jax.ShapeDtypeStruct((bh, s, d), q.dtype,
+        out_shape=jax.ShapeDtypeStruct(_shape_of(bh, s, d, h), q.dtype,
                                        vma=_out_vma(q, k, v, do)),
         compiler_params=_compiler_params(),
         interpret=_use_interpret(),
@@ -854,26 +900,24 @@ def _dq_call(q, k, v, do, lse, delta, sm_scale, mask, kv_len, forced=None):
 def _bwd_call(q, k, v, do, lse, delta, sm_scale, mask, kv_len, forced=None):
     """dQ at the query head count, dK and dV at the K/V head count, from one
     kernel (``hvd_flash_dkdv`` in the compiled program: what the
-    benchmark's readers of the backward pass match). ``lse``/``delta``:
-    [B*H, 1, S] rows."""
-    bh, s, d = q.shape
-    dv = v.shape[2]
-    bkv = k.shape[0]
+    benchmark's readers of the backward pass match), each at its operand's
+    rank (``_dims``). ``lse``/``delta``: [B*H, 1, S] rows either way."""
+    bh, bkv, s, d, dv, h, hkv = _dims(q, k, v)
     group = bh // bkv
     bq, bk, tiles = _tiles_for(KERNEL_DKDV, q, k, v, mask, forced,
                                dq="fused")
 
-    def q_map(b, head, t, ref):
-        return b * group + head, _at(tiles, ref, TILE_Q, t), 0
+    def q_at(b, head, t, ref):
+        return b * group + head, _at(tiles, ref, TILE_Q, t)
 
     def row_map(b, head, t, ref):
         return b * group + head, 0, _at(tiles, ref, TILE_Q, t)
 
-    def kv_map(b, head, t, ref):
-        return b, _at(tiles, ref, TILE_K, t), 0
+    def kv_at(b, head, t, ref):
+        return b, _at(tiles, ref, TILE_K, t)
 
-    def whole_map(b, head, t, ref):
-        return b, 0, 0
+    def whole_at(b, head, t, ref):
+        return b, 0
 
     kernel = functools.partial(_bwd_kernel, tiles=tiles, group=group,
                                sm_scale=sm_scale, block_q=bq, block_k=bk,
@@ -889,19 +933,19 @@ def _bwd_call(q, k, v, do, lse, delta, sm_scale, mask, kv_len, forced=None):
             # sequence's call 7%: PERF.md, Findings, PR 61.)
             grid=(bkv, group, tiles.shape[1]),
             in_specs=[
-                pl.BlockSpec((1, bq, d), q_map),                       # q
-                pl.BlockSpec((1, bk, d), kv_map),                      # k
-                pl.BlockSpec((1, bk, dv), kv_map),                     # v
-                pl.BlockSpec((1, bq, dv), q_map),                      # do
+                _head_block(bq, d, h, q_at),                           # q
+                _head_block(bk, d, hkv, kv_at),                        # k
+                _head_block(bk, dv, hkv, kv_at),                       # v
+                _head_block(bq, dv, h, q_at),                          # do
                 pl.BlockSpec((1, 1, bq), row_map),                     # lse
                 pl.BlockSpec((1, 1, bq), row_map),                     # delta
             ],
             out_specs=[
-                pl.BlockSpec((1, bq, d), q_map),
+                _head_block(bq, d, h, q_at),
                 # A K/V head's whole dK and dV: the index is constant over
                 # the head's steps, so they leave VMEM once, on its last.
-                pl.BlockSpec((1, s, d), whole_map),
-                pl.BlockSpec((1, s, dv), whole_map),
+                _head_block(s, d, hkv, whole_at),
+                _head_block(s, dv, hkv, whole_at),
             ],
             scratch_shapes=[
                 pltpu.VMEM((d, bq), jnp.float32),   # dQ of the row, transposed
@@ -909,9 +953,10 @@ def _bwd_call(q, k, v, do, lse, delta, sm_scale, mask, kv_len, forced=None):
                 pltpu.VMEM((s, dv), jnp.float32),   # ... and dV
             ]),
         out_shape=[
-            jax.ShapeDtypeStruct((bh, s, d), q.dtype, vma=vma),
-            jax.ShapeDtypeStruct((bkv, s, d), k.dtype, vma=vma),
-            jax.ShapeDtypeStruct((bkv, s, dv), v.dtype, vma=vma),
+            jax.ShapeDtypeStruct(_shape_of(bh, s, d, h), q.dtype, vma=vma),
+            jax.ShapeDtypeStruct(_shape_of(bkv, s, d, hkv), k.dtype, vma=vma),
+            jax.ShapeDtypeStruct(_shape_of(bkv, s, dv, hkv), v.dtype,
+                                 vma=vma),
         ],
         compiler_params=_compiler_params(rank=3),
         interpret=_use_interpret(),
@@ -939,25 +984,25 @@ def _flash_bhsd_fwd(q, k, v, sm_scale, mask, kv_len, forced):
 
 def _flash_bhsd_bwd(sm_scale, mask, kv_len, forced, res, do):
     q, k, v, o, lse = res
-    bh, s, d = q.shape
+    bh, _, s, d, dv = _dims(q, k, v)[:5]
     # delta_i = rowsum(dO_i * O_i) — tiny elementwise pass, XLA fuses it.
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
+    delta = delta.reshape(bh, s)   # at rank 4 [B, H, S]: floats, as they lie
     # The statistics enter a transposed tile as [bh, 1, s] rows.
     rows = lse[:, None, :], delta[:, None, :]
-    tile = forced or block_sizes(KERNEL_DKDV, s, d, q.dtype, mask.causal,
-                                 v.shape[2])
-    if backward_is_fused(*tile, s, d, q.dtype, v.shape[2]):
+    tile = forced or block_sizes(KERNEL_DKDV, s, d, q.dtype, mask.causal, dv)
+    if backward_is_fused(*tile, s, d, q.dtype, dv):
         return _bwd_call(q, k, v, do, *rows, sm_scale, mask, kv_len, tile)
     # Too long for a head's dK and dV to stay in VMEM: a kernel each. dQ
     # reads the statistics lane-replicated [bh, s, 128] (Mosaic rejects
     # vector blocks whose sublane dim is 1 — see _fwd_kernel), transiently:
     # the residual holds one float a row.
-    dk, dv = _dkdv_call(q, k, v, do, *rows, sm_scale, mask, kv_len, forced)
+    dk, dv_ = _dkdv_call(q, k, v, do, *rows, sm_scale, mask, kv_len, forced)
     dq = _dq_call(q, k, v, do,
                   jnp.broadcast_to(lse[..., None], (bh, s, LANES)),
                   jnp.broadcast_to(delta[..., None], (bh, s, LANES)),
                   sm_scale, mask, kv_len, forced)
-    return dq, dk, dv
+    return dq, dk, dv_
 
 
 _flash_bhsd.defvjp(_flash_bhsd_fwd, _flash_bhsd_bwd)
@@ -965,7 +1010,8 @@ _flash_bhsd.defvjp(_flash_bhsd_fwd, _flash_bhsd_bwd)
 
 def flash_attention(q, k, v, causal: bool = True, *,
                     window: Optional[int] = None,
-                    block_diffusion: Optional[int] = None, _blocks=None):
+                    block_diffusion: Optional[int] = None,
+                    heads_major: bool = False, _blocks=None):
     """Fused attention. q: ``[B, S, H, D]`` (the layout the GPT blocks
     use); k/v: ``[B, S, Hkv, D]`` where ``Hkv`` may divide ``H``
     (grouped-query attention: the kernels read K/V head ``h // group`` for
@@ -989,6 +1035,20 @@ def flash_attention(q, k, v, causal: bool = True, *,
     call's shapes and dtype (``block_sizes``); ``_blocks=(block_q,
     block_k)`` forces one tile on every kernel, for the tests and the
     sweep.
+
+    ``heads_major`` is the caller's, because the layouts round the call are
+    the caller's: the kernels read ``[B, H, S, D]``, and the turn from the
+    model's ``[B, S, H, D]`` is either merged to ``[B*H, S, D]`` here (the
+    default, every call until PR 70), which at a batch of two or more XLA
+    cannot carry a layout across, so every operand, output and cotangent is
+    turned in a ``copy`` of its own; or, ``heads_major=True``, left a
+    transpose at rank 4 (``_dims``), which XLA folds into whatever wrote the
+    array and whatever reads the output. Folded, the turn does not vanish:
+    it moves into those neighbours, cheap in an elementwise fusion and dear
+    in a product that contracts over ``(h, d)`` with ``s`` between them in
+    memory, so a caller asks for it knowing its neighbours
+    (``models/decoder/mixers/attention.py``; PERF.md, Findings, PR 70). The
+    values, the kernels' bodies, grids and names are the same both ways.
     """
     b, s, h, d = q.shape
     if k.shape[2] != v.shape[2] or h % k.shape[2]:
@@ -1014,9 +1074,15 @@ def flash_attention(q, k, v, causal: bool = True, *,
         mask = Mask(bool(causal),
                     None if window is None or window >= s else int(window))
 
+    dv = v.shape[3]
+    runtime.note_traced(
+        "hvdtpu_spmd_flash_layout_traces_total",
+        layout="rank4" if heads_major else "rank3", head_dim=d, batch=b)
+
     def to_bhsd(x):
-        return _pad_seq(x.transpose(0, 2, 1, 3).reshape(-1, s, x.shape[3]))
+        x = x.transpose(0, 2, 1, 3)
+        return _pad_seq(x if heads_major else x.reshape(-1, s, x.shape[3]))
 
     o = _flash_bhsd(to_bhsd(q), to_bhsd(k), to_bhsd(v), sm_scale, mask, s,
                     _blocks)
-    return o[:, :s, :].reshape(b, h, s, v.shape[3]).transpose(0, 2, 1, 3)
+    return o[..., :s, :].reshape(b, h, s, dv).transpose(0, 2, 1, 3)

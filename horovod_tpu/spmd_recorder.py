@@ -116,6 +116,17 @@ _TRACED = {
         "tiles are. Only for a mask whose description holds its sequence's "
         "length (block_diffusion); the others count no pairs.",
         ("kernel", "mask", "pairs", "seq")),
+    "hvdtpu_spmd_flash_layout_traces_total": (
+        "Times JAX traced a call of flash_attention, by how its operands "
+        "reach the kernels: rank4 (the caller asked, heads_major: q, k, v, "
+        "every output and cotangent as [B, H, S, D], a transpose of the "
+        "model's array that XLA folds into the layouts round the call; the "
+        "attention mixer asks at two or more sequences of heads of one "
+        "lane tile) or rank3 (the default: merged to [B*H, S, D] besides, "
+        "a reshape no layout crosses at a batch of two or more, so each is "
+        "turned in a copy of its own), with the width of a query and key "
+        "head and the batch.",
+        ("layout", "head_dim", "batch")),
     "hvdtpu_spmd_head_loss_traces_total": (
         "Times JAX traced the GPT's head and loss as one rule over blocks of "
         "token rows (models/gpt.py::_head_loss), by the rows a block holds "

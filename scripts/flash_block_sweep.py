@@ -11,7 +11,7 @@ of microseconds). Also holds the compiled kernels to dense attention once.
     chiprun --chips 1 -- python scripts/flash_block_sweep.py [--quick]
     chiprun --chips 1 -- python scripts/flash_block_sweep.py --bwd [cell ...]
     chiprun --chips 1 -- python scripts/flash_block_sweep.py --dense-long
-    chiprun --chips 1 -- python scripts/flash_block_sweep.py --grid [cell ...] [--tile BQ BK] [--repo DIR]
+    chiprun --chips 1 -- python scripts/flash_block_sweep.py --grid [cell ...] [--tile BQ BK] [--layout rank3|rank4] [--repo DIR]
 
 ``--bwd`` times the backward pass alone at a benchmark cell's shape
 (``BWD_SHAPES``: Moonlight's two widths and Trinity's window among them), as
@@ -35,7 +35,14 @@ shape and mask (``BWD_SHAPES``, all or those named) at the table's tile or a
 forced one (``--tile``), beside the steps a head's grid walks and the tiles it
 keeps: with ``--repo`` a copy of the commit before PR 61, whose grid was the
 whole rectangle, the difference over the steps that did nothing is the price
-of such a step (PERF.md, Findings, PR 61).
+of such a step (PERF.md, Findings, PR 61). ``--layout rank4`` hands the two
+kernels their operands as ``[B, H, S, D]`` (what ``flash_attention`` hands
+them where its caller asks since PR 70, ``heads_major``, as the attention
+mixer does at two or more sequences of heads of one lane tile: the same bytes in the same order, a
+batch axis squeezed out of every block),
+``rank3`` (the default) merged to ``[B*H, S, D]``: the two side by side at a
+shape say what the other index map costs a kernel (0.2-0.6% at the cells'
+shapes: PERF.md, Findings, PR 70). The flag is this script's.
 
 ``--repo DIR`` times another checkout's kernels (a ``git archive`` of another
 commit) with this script.
@@ -67,7 +74,9 @@ CELLS = ("s4096", "s512")
 TILES = ((128, 128), (256, 256), (256, 512), (512, 256), (512, 512),
          (512, 1024), (1024, 512), (1024, 1024), (1024, 2048), (2048, 1024),
          (512, 2048), (2048, 512))
-# The attention of a cell's layers: (B, S, H, Hkv, D, Dv, window).
+# The attention of a cell's layers: (B, S, H, Hkv, D, Dv, window[, the block
+# and the half of a block-diffusion mask]): what ``fa.Mask`` takes after
+# ``causal``.
 BWD_SHAPES = {
     "moonlight-16b-a3b_s8192": (2, 8192, 16, 16, 192, 128, None),
     "trinity-mini_s8192": (2, 8192, 32, 4, 128, 128, None),
@@ -86,6 +95,9 @@ BWD_SHAPES = {
     "phi-4-mini-flash-reasoning_s16384": (1, 16384, 20, 10, 64, 128, None),
     "phi-4-mini-flash-reasoning_s16384_window": (1, 16384, 20, 10, 64, 128,
                                                  512),
+    "ouro-2.6b_s4096": (2, 4096, 16, 16, 128, 128, None),
+    # One row of 16,384, the noised and the clean copy, in blocks of 4.
+    "sdar-30b-a3b-chat_s8192": (1, 16384, 32, 4, 128, 128, None, 4, 8192),
 }
 BWD_TILES = ((1024, 1024), (512, 1024), (1024, 512))
 # ``smallthinker-21b-a3b_s16384``'s attention: (B, S, H, Hkv, D), the full
@@ -196,30 +208,37 @@ def time_variant(label, name, shape, dtype, tiles, group_in_hbm, kernels):
                  table=t is None, ms=ms)
 
 
-def bwd_operands(name, dtype):
-    """q, k, v, dO as the kernels take them at ``BWD_SHAPES[name]``, the
-    logits' scale and the mask."""
-    b, s, h, hkv, d, dv, window = BWD_SHAPES[name]
+def bwd_operands(name, dtype, layout="rank3"):
+    """q, k, v, dO as the kernels take them at ``BWD_SHAPES[name]``, a head
+    a row of the first axis or (``rank4``) ``[B, H, S, D]``; the logits'
+    scale and the mask."""
+    b, s, h, hkv, d, dv, *mask = BWD_SHAPES[name]
     ks = jax.random.split(jax.random.PRNGKey(0), 4)
-    q = jax.random.normal(ks[0], (b * h, s, d), dtype) * 0.5
-    k = jax.random.normal(ks[1], (b * hkv, s, d), dtype) * 0.5
-    v = jax.random.normal(ks[2], (b * hkv, s, dv), dtype) * 0.5
-    do = jax.random.normal(ks[3], (b * h, s, dv), dtype) * 0.5
-    return q, k, v, do, 1.0 / d ** 0.5, fa.Mask(True, window)
+
+    def operand(key, heads, width):
+        shape = (b * heads, s, width) if layout == "rank3" \
+            else (b, heads, s, width)
+        return jax.random.normal(key, shape, dtype) * 0.5
+
+    q, k = operand(ks[0], h, d), operand(ks[1], hkv, d)
+    v, do = operand(ks[2], hkv, dv), operand(ks[3], h, dv)
+    return q, k, v, do, 1.0 / d ** 0.5, fa.Mask(True, *mask)
 
 
-def time_grid(name, tile=None, dtype=jnp.bfloat16):
+def time_grid(name, tile=None, dtype=jnp.bfloat16, layout="rank3"):
     """The forward kernel and the one backward kernel at cell ``name``'s
     shape and mask, ms a call (``delta`` made in the backward's), at the
     table's tile or ``tile``; beside them the tiles of a head's rectangle,
     those the mask keeps, and the steps its grid walks (a tree whose grid is
-    still the rectangle has no ``Mask.kept_tiles`` and walks them all)."""
-    q, k, v, do, sc, mask = bwd_operands(name, dtype)
-    s, d, dv = q.shape[1], q.shape[2], v.shape[2]
+    still the rectangle has no ``Mask.kept_tiles`` and walks them all).
+    ``layout``: how the operands lie (``bwd_operands``)."""
+    b, s, h, hkv, d, dv = BWD_SHAPES[name][:6]
+    q, k, v, do, sc, mask = bwd_operands(name, dtype, layout)
     bq, bk = tile or fa.block_sizes(fa.KERNEL_DKDV, s, d, dtype, True, dv)
     tiles = mask.tiles(s // bq, s // bk, bq, bk)
-    row = dict(grid=name, tile=[bq, bk], dtype=jnp.dtype(dtype).name,
-               heads=q.shape[0], rectangle=sum(tiles.values()),
+    row = dict(grid=name, layout=layout, tile=[bq, bk],
+               dtype=jnp.dtype(dtype).name,
+               heads=b * h, rectangle=sum(tiles.values()),
                kept=tiles["kept"],
                steps=tiles["kept"] if hasattr(mask, "kept_tiles")
                else sum(tiles.values()))
@@ -229,8 +248,8 @@ def time_grid(name, tile=None, dtype=jnp.bfloat16):
 
     def bwd(q, k, v, o, do):
         delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), -1)
-        return fa._bwd_call(q, k, v, do, lse, delta[:, None, :], sc, mask, s,
-                            (bq, bk))
+        return fa._bwd_call(q, k, v, do, lse,
+                            delta.reshape(b * h, 1, s), sc, mask, s, (bq, bk))
 
     try:
         row["fwd_ms"] = timed(fwd, q, k, v)
@@ -426,6 +445,11 @@ def main():
                          "the steps a head's grid walks")
     ap.add_argument("--tile", nargs=2, type=int, metavar=("BQ", "BK"),
                     help="with --grid: this tile, not the table's")
+    ap.add_argument("--layout", nargs="+", choices=("rank3", "rank4"),
+                    default=["rank3"],
+                    help="with --grid: how the operands come, merged to "
+                         "[B*H, S, D] or [B, H, S, D] (both: side by side "
+                         "at each shape; rank4 needs a tree since PR 70)")
     ap.add_argument("--repo", default=HERE,
                     help="the checkout whose kernels are timed")
     args = ap.parse_args()
@@ -448,7 +472,9 @@ def main():
         return
     if args.grid is not None:
         for name in args.grid or BWD_SHAPES:
-            time_grid(name, args.tile and tuple(args.tile))
+            for layout in args.layout:
+                time_grid(name, args.tile and tuple(args.tile),
+                          layout=layout)
         return
     check_against_dense()
     if args.bwd is not None:

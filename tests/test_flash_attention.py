@@ -186,6 +186,117 @@ def test_bf16_matches_dense_in_bf16(causal):
         assert np.abs(g - e).max() <= np.abs(r - e).max() + tol, name
 
 
+# ---- the entry a call takes (PR 70) ------------------------------------------
+
+def _layouts_traced():
+    """``{(layout, head_dim, batch)}`` of the calls traced so far."""
+    fam = hvd.metrics().get("hvdtpu_spmd_flash_layout_traces_total",
+                            {"samples": []})
+    return {(labels["layout"], int(labels["head_dim"]), int(labels["batch"]))
+            for _, labels, _ in fam["samples"]}
+
+
+# batch, H, Hkv, D, Dv, window, how the caller asks for the operands
+# (``heads_major``: rank4, ``[B, H, S, D]``; the default: rank3, merged to
+# ``[B*H, S, D]``). The kernels take either at any batch, grouping and width:
+# the benchmark cells' 32:4, 28:4, 16:16 and 24:2 heads of 128, 16:2 of 256,
+# and the widths no cell takes at rank 4 yet (64, 192 beside 128, 64 beside
+# 128), at batches of one, two and four.
+ENTRY_CASES = [
+    (1, 32, 4, 128, 128, None, "rank3"),
+    (1, 32, 4, 128, 128, None, "rank4"),
+    (2, 32, 4, 128, 128, None, "rank4"),
+    (4, 28, 4, 128, 128, None, "rank4"),
+    (2, 32, 4, 128, 128, 100, "rank4"),
+    (2, 16, 16, 128, 128, None, "rank4"),
+    (2, 24, 2, 128, 128, None, "rank3"),
+    (2, 24, 2, 128, 128, None, "rank4"),
+    (1, 4, 4, 256, 256, None, "rank3"),
+    (2, 8, 4, 256, 256, 150, "rank4"),
+    (4, 16, 2, 256, 256, None, "rank3"),
+    (4, 16, 2, 256, 256, None, "rank4"),
+    (2, 4, 4, 256, 128, None, "rank4"),
+    (1, 8, 2, 64, 64, None, "rank3"),
+    (2, 8, 2, 64, 64, 100, "rank3"),
+    (2, 8, 2, 64, 64, 100, "rank4"),
+    (4, 4, 4, 64, 64, None, "rank3"),
+    (2, 2, 2, 192, 128, None, "rank3"),
+    (2, 2, 2, 192, 128, None, "rank4"),
+    (1, 2, 1, 192, 128, 100, "rank3"),
+    (2, 4, 2, 64, 128, None, "rank3"),
+    (2, 2, 1, 128, 64, None, "rank4"),
+]
+
+
+@pytest.mark.parametrize(
+    "batch, h, hkv, dk, dv, window, layout", ENTRY_CASES,
+    ids=lambda x: str(x))
+def test_either_entry_matches_dense(make_runtime, batch, h, hkv, dk, dv,
+                                    window, layout):
+    """The output and all three gradients against ``default_attention`` on
+    repeated heads through the entry the caller asks for, at batches of
+    one, two and four (200 rows: padded to 256, two tiles a side), and the
+    counter says which entry it was."""
+    make_runtime(devices=jax.devices()[:1])
+    s = 200
+    ks = jax.random.split(jax.random.PRNGKey(batch + h + dk), 4)
+    q = jax.random.normal(ks[0], (batch, s, h, dk)) * 0.5
+    k = jax.random.normal(ks[1], (batch, s, hkv, dk)) * 0.5
+    v = jax.random.normal(ks[2], (batch, s, hkv, dv)) * 0.5
+    w = jax.random.normal(ks[3], (batch, s, h, dv)) * 0.1
+
+    def dense(q, k, v):
+        return default_attention(q, repeat_kv_heads(k, h),
+                                 repeat_kv_heads(v, h), causal=True,
+                                 window=window)
+
+    got = _value_and_grads(flash_attention, q, k, v, w, window=window,
+                           heads_major=layout == "rank4", _blocks=(128, 128))
+    want = _value_and_grads(dense, q, k, v, w)
+    assert _layouts_traced() == {(layout, dk, batch)}
+    assert [g.shape for g in got] == [w.shape, q.shape, k.shape, v.shape]
+    np.testing.assert_allclose(got[0], want[0], rtol=2e-5, atol=2e-5)
+    for g, r, name in zip(got[1:], want[1:], "qkv"):
+        np.testing.assert_allclose(g, r, rtol=5e-4, atol=5e-5,
+                                   err_msg=f"d{name}")
+
+
+@pytest.mark.parametrize("batch", [1, 2, 4])
+def test_the_pair_of_backward_kernels_through_either_entry(monkeypatch, batch):
+    """A sequence too long for the one backward kernel keeps the pair, and
+    the pair takes rank-4 operands (a batch of two and of four) as the one
+    kernel does: dK and dV bit for bit, dQ to float32 rounding, at 12:4
+    heads of 128 under a band."""
+    q, _, _ = _qkv(batch, 384, 12, 128, seed=71)
+    _, k, v = _qkv(batch, 384, 4, 128, seed=73)
+    w = jax.random.normal(jax.random.PRNGKey(79), q.shape) * 0.1
+    kw = dict(window=150, heads_major=batch > 1, _blocks=(128, 128))
+    dq, dk, dv = _grads(q, k, v, w, **kw)
+    monkeypatch.setattr(fa, "backward_is_fused", lambda *a: False)
+    dq_pair, dk_pair, dv_pair = _grads(q, k, v, w, **kw)
+    np.testing.assert_array_equal(dk, dk_pair)
+    np.testing.assert_array_equal(dv, dv_pair)
+    np.testing.assert_allclose(dq, dq_pair, rtol=1e-5,
+                               atol=1e-6 * np.abs(dq_pair).max())
+
+
+def test_the_entry_holds_a_transpose_and_no_merge_where_asked():
+    """What ``heads_major`` is for: the traced call holds no reshape of an
+    operand, at any width (XLA's layout assignment crosses a transpose and
+    not a merge of two axes: PERF.md, Findings, PR 70); by default the
+    operands are merged as before."""
+    def reshapes(d, heads_major):
+        q = jax.ShapeDtypeStruct((2, 256, 4, d), jnp.bfloat16)
+        jaxpr = jax.make_jaxpr(lambda q: jax.grad(lambda q: jnp.sum(
+            flash_attention(q, q, q, heads_major=heads_major).astype(
+                jnp.float32)))(q))(q)
+        return [eqn for eqn in jaxpr.eqns if eqn.primitive.name == "reshape"
+                and eqn.invars[0].aval.dtype == jnp.bfloat16]
+
+    for d in (64, 128, 256):
+        assert not reshapes(d, True) and len(reshapes(d, False)) >= 4
+
+
 # ---- the block table (a pure function) --------------------------------------
 
 KERNELS = (fa.KERNEL_FWD, fa.KERNEL_DKDV, fa.KERNEL_DQ)
@@ -594,25 +705,58 @@ def test_block_table_counts_both_widths(kernel):
 
 
 # sha256 of the StableHLO text (no source locations) that a call with one
-# head width lowers to, interpreted kernels included. Pinned anew by PR 61,
-# which meant to change what such a call traces to: every kernel's grid is
-# the mask's kept tiles, read from a scalar-prefetch table, where it was the
-# whole rectangle of blocks. (Before it ``forward`` had stood since before a
-# value head could have a width of its own, PR 49: 36607613...842723 and
-# d40641ff...07d496; ``gradient`` since the backward pass became one kernel,
-# PR 52: 130bc696...8fe30c and e92ada63...55bcbf.) A change that means to
-# alter what such a call traces to pins these anew.
+# head width lowers to, interpreted kernels included, by (batch, H, Hkv, D,
+# window, dtype). Pinned anew by PR 61, which meant to change what such a
+# call traces to: every kernel's grid is the mask's kept tiles, read from a
+# scalar-prefetch table, where it was the whole rectangle of blocks. (Before
+# it ``forward`` had stood since before a value head could have a width of
+# its own, PR 49: 36607613...842723 and d40641ff...07d496; ``gradient`` since
+# the backward pass became one kernel, PR 52: 130bc696...8fe30c and
+# e92ada63...55bcbf.) **PR 70 gave the entry a second form, which a caller
+# asks for** (``heads_major``: the operands reach the kernels as
+# ``[B, H, S, D]`` and are not merged to ``[B*H, S, D]``), **and meant to
+# change nothing else**. So the last case, the one with a seventh field, is
+# PR 70's own, and the six before it (one sequence at heads of 64 and of 128
+# as PR 61 pinned them; two at heads of 64, of 192, at 8:2 and 8:4 heads of
+# 128, new here) are what the parent commit lowers them to, computed there:
+# a call that does not ask lowers to the parent's program to the hash. A
+# change that means to alter what such a call traces to pins these anew.
 LOWERED = {
-    (4, 2, 64, None, "bfloat16"): dict(
+    (1, 4, 2, 64, None, "bfloat16"): dict(
         forward="3c95cf8de0618e21e09d957f0a74a8ed"
                 "cb6e4e5cbb3a34ca5dc5f0ef1a39d2cc",
         gradient="bff3e06a472781c110c44f20367b1887"
                  "88c29c879518817a185856f3162be42e"),
-    (2, 2, 128, 96, "float32"): dict(
+    (1, 2, 2, 128, 96, "float32"): dict(
         forward="e3e550643e36aa04303183f911c13827"
                 "2587636e355acd0f3d9e9221723ecde1",
         gradient="8ad34bdc9babdc7b98e7189e39c9cc9c"
                  "b4fadc89b8e4e965aa42892f0de3a0b9"),
+    (2, 8, 2, 64, None, "bfloat16"): dict(
+        forward="08b80b81a5665560e5cb1f0da685dcb7"
+                "fd96d406aa116225b0da6209431ab0e9",
+        gradient="f184472aeaac19760e808507dd2dec1b"
+                 "27ba4d17eb6a55776f1aec39f9489611"),
+    (2, 2, 2, 192, 100, "bfloat16"): dict(
+        forward="4923aa1d3ab8e7db9be409a44f1db587"
+                "877dce7dda07f98b12296e20da8ed00a",
+        gradient="8bac6d0637c13ae7e44b00d86db64310"
+                 "8ef9b3f4c3152455ce3d4939d6c0fc0c"),
+    (2, 8, 2, 128, None, "bfloat16"): dict(
+        forward="dc6a22c023683fa3301fd21e68e01008"
+                "b997e0aa5cfe943b64f22c76bc9d443b",
+        gradient="7f75fbc8f2513a550615f49c4b7f650f"
+                 "af9c808240eebaf55a600b873285a1c2"),
+    (2, 8, 4, 128, None, "bfloat16"): dict(
+        forward="08bb41aaf2e2f21b8a9e18387fd65897"
+                "cea72f1ac543a344148347408fbf8469",
+        gradient="546db422382acf56bc8d24c6ee2d18d6"
+                 "5d3c97e87a2b1eaf249e7becba8f6cf8"),
+    (2, 8, 4, 128, None, "bfloat16", "heads_major"): dict(
+        forward="218a23855a6e712c13402bd85b53a6f9"
+                "707b6c7bf276eec447542bbdba8ef749",
+        gradient="2edbe3703ca74f8187c5ded4aed0c589"
+                 "a4816851578dc9f3a5079548c2013843"),
 }
 
 
@@ -621,12 +765,13 @@ LOWERED = {
                          ids=lambda c: "-".join(map(str, c)))
 def test_equal_widths_lower_to_the_program_they_lowered_to(case, what):
     import hashlib
-    h, hkv, d, window, dtype = case
-    q = jax.ShapeDtypeStruct((1, 256, h, d), jnp.dtype(dtype))
-    k = jax.ShapeDtypeStruct((1, 256, hkv, d), jnp.dtype(dtype))
+    b, h, hkv, d, window, dtype, *asked = case
+    q = jax.ShapeDtypeStruct((b, 256, h, d), jnp.dtype(dtype))
+    k = jax.ShapeDtypeStruct((b, 256, hkv, d), jnp.dtype(dtype))
 
     def out(q, k, v):
-        return flash_attention(q, k, v, window=window)
+        return flash_attention(q, k, v, window=window,
+                               heads_major=bool(asked))
 
     def loss(q, k, v):
         return jnp.sum(out(q, k, v).astype(jnp.float32))

@@ -60,19 +60,61 @@ def test_kernels_agree_with_the_dense_path(heads, kv_heads, block, length):
         np.testing.assert_allclose(g, r, atol=1e-4, err_msg=f"d{name}")
 
 
-def test_the_pair_of_backward_kernels_agrees_too():
+# batch, H, Hkv, D, the entry a caller asks for (PR 70: ``heads_major`` hands
+# the kernels [B, H, S, D]; the default merges to [B*H, S, D]).
+BY_ENTRY = [(1, 8, 4, 128, False), (2, 4, 4, 128, True), (4, 8, 4, 128, True),
+            (2, 4, 4, 256, True), (2, 8, 2, 128, False), (2, 8, 2, 128, True),
+            (2, 4, 4, 64, False), (2, 4, 4, 64, True)]
+
+
+@pytest.mark.parametrize("length", [128, 96])
+@pytest.mark.parametrize("batch, heads, kv_heads, dim, heads_major", BY_ENTRY)
+def test_kernels_agree_with_the_dense_path_through_either_entry(
+        batch, heads, kv_heads, dim, heads_major, length):
+    """The same comparison through either entry at batches of one, two and
+    four, in blocks of 4 (the ``sdar-30b-a3b-chat_s8192`` cell's), the halves'
+    boundary on a tile's edge and inside the first tile."""
+    q, k, v = _qkv(length, heads, kv_heads, dim=dim, batch=batch, seed=dim)
+
+    def flash(q, k, v):
+        return fa.flash_attention(q, k, v, block_diffusion=4,
+                                  heads_major=heads_major,
+                                  _blocks=(128, 128))
+
+    def dense(q, k, v):
+        return default_attention(q, repeat_kv_heads(k, heads),
+                                 repeat_kv_heads(v, heads),
+                                 block_diffusion=4)
+
+    w = jax.random.normal(jax.random.PRNGKey(1), q.shape, jnp.float32)
+
+    def both(f):
+        return jax.jit(jax.value_and_grad(
+            lambda *a: (lambda o: (jnp.sum(o * w), o))(f(*a)),
+            argnums=(0, 1, 2), has_aux=True))(q, k, v)
+
+    ((_, out), got), ((_, ref), want) = both(flash), both(dense)
+    np.testing.assert_allclose(out, ref, atol=4e-5)
+    for name, g, r in zip("qkv", got, want):
+        np.testing.assert_allclose(g, r, atol=2e-4, err_msg=f"d{name}")
+
+
+@pytest.mark.parametrize("rank", [3, 4])
+def test_the_pair_of_backward_kernels_agrees_too(rank):
     """Where a head's dK and dV would not fit VMEM the backward pass is the
     dKdV kernel, its table column by column, and the dQ kernel: the same
-    numbers as the one kernel under this mask."""
+    numbers as the one kernel under this mask, on operands merged to
+    ``[B*H, S, D]`` and at rank 4, ``[B, H, S, D]``."""
     q, k, v = _qkv(256, 4, 2)
     s, (bh, bkv) = 512, (8, 4)
     mask = fa.Mask(block_diffusion=4, half=256)
-    to = lambda x: x.transpose(0, 2, 1, 3).reshape(-1, s, x.shape[3])
+    to = lambda x: x.transpose(0, 2, 1, 3).reshape(
+        (-1, s, x.shape[3]) if rank == 3 else (2, -1, s, x.shape[3]))
     q, k, v = to(q), to(k), to(v)
     scale = q.shape[-1] ** -0.5
     o, lse = fa._fwd_call(q, k, v, scale, mask, s, (128, 128))
     do = jax.random.normal(jax.random.PRNGKey(2), o.shape, jnp.float32)
-    delta = jnp.sum(do * o, axis=-1)
+    delta = jnp.sum(do * o, axis=-1).reshape(bh, s)
     rows = lse[:, None, :, 0], delta[:, None, :]
     dq, dk, dv = fa._bwd_call(q, k, v, do, *rows, scale, mask, s, (128, 128))
     dk2, dv2 = fa._dkdv_call(q, k, v, do, *rows, scale, mask, s, (128, 128))

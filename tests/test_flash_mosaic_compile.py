@@ -98,25 +98,27 @@ FUSED_SHAPES = {
     "trinity-mini_s8192_window": (64, 8, 8192, 128, jnp.bfloat16, True,
                                   2048),
     "zaya1-8b_s4096": (32, 8, 4096, 128, jnp.bfloat16, True),
+    # Two sequences at 16:16 heads: the looped cell's.
+    "ouro-2.6b_s4096": (32, 32, 4096, 128, jnp.bfloat16, True),
     **LONGEST,
 }
 
 
-@pytest.mark.parametrize("shape, kernel", [
-    *((shape, kernel) for shape in SHAPES
-      for kernel in ("fwd", "dkdv", "dq")),
-    # The pair under a band: the dKdV kernel's table runs column by column
-    # under each of eight query heads, the dQ kernel's row by row.
-    *(("trinity-mini_s8192_window", kernel) for kernel in ("dkdv", "dq")),
-    *((shape, "fwd") for shape in LONGEST),
-    *((shape, "fused") for shape in FUSED_SHAPES)])
-def test_kernel_compiles_for_v5e(one_chip, mosaic, shape, kernel):
-    bh, bkv, s, d, dtype, causal, *rest = FUSED_SHAPES[shape]
+def _compile_flash(one_chip, kernel, bh, bkv, s, d, dtype, causal, *rest,
+                   batch=None):
+    """One of the four calls compiled at ``[B*H, S, D]`` operands or, with
+    ``batch``, at the same heads at rank 4, ``[B, H, S, D]`` (what the entry
+    hands the calls where its caller asks since PR 70)."""
     d, dv = d if isinstance(d, tuple) else (d, d)
     mask = fa.Mask(causal, *rest)
 
-    def sds(*dims, dt=dtype):
+    def sds(n, rows, width, dt=dtype):
+        dims = (n, rows, width) if batch is None \
+            else (batch, n // batch, rows, width)
         return jax.ShapeDtypeStruct(dims, dt, sharding=one_chip)
+
+    def stat(*dims):
+        return jax.ShapeDtypeStruct(dims, jnp.float32, sharding=one_chip)
 
     q, k, v, do = sds(bh, s, d), sds(bkv, s, d), sds(bkv, s, dv), \
         sds(bh, s, dv)
@@ -128,18 +130,65 @@ def test_kernel_compiles_for_v5e(one_chip, mosaic, shape, kernel):
         # The one kernel that makes dQ too carries the dKdV kernel's name.
         call = fa._dkdv_call if kernel == "dkdv" else fa._bwd_call
         f = lambda *a: call(*a, scale, mask, s)
-        rows = sds(bh, 1, s, dt=jnp.float32)
+        rows = stat(bh, 1, s)
         args = (q, k, v, do, rows, rows)
         if kernel == "fused":
             tile = fa.block_sizes(fa.KERNEL_DKDV, s, d, dtype, causal, dv)
             assert fa.backward_is_fused(*tile, s, d, dtype, dv)
     else:
         f = lambda *a: fa._dq_call(*a, scale, mask, s)
-        cols = sds(bh, s, 128, dt=jnp.float32)
+        cols = stat(bh, s, 128)
         args = (q, k, v, do, cols, cols)
-    text = jax.jit(f).lower(*args).compile().as_text()
+    lowered = jax.jit(f).lower(*args)
+    text = lowered.compile().as_text()
     name = "dkdv" if kernel == "fused" else kernel
     assert "tpu_custom_call" in text and f"hvd_flash_{name}" in text
+    return lowered
+
+
+@pytest.mark.parametrize("shape, kernel", [
+    *((shape, kernel) for shape in SHAPES
+      for kernel in ("fwd", "dkdv", "dq")),
+    # The pair under a band: the dKdV kernel's table runs column by column
+    # under each of eight query heads, the dQ kernel's row by row.
+    *(("trinity-mini_s8192_window", kernel) for kernel in ("dkdv", "dq")),
+    *((shape, "fwd") for shape in LONGEST),
+    *((shape, "fused") for shape in FUSED_SHAPES)])
+def test_kernel_compiles_for_v5e(one_chip, mosaic, shape, kernel):
+    _compile_flash(one_chip, kernel, *FUSED_SHAPES[shape])
+
+
+# The four calls at rank 4, ``[B, H, S, D]`` (a block ``(squeezed, 1, rows,
+# D)`` at ``(b, h, tile, 0)``, a grouped-query key at ``h // group``; the
+# whole dK and dV of a K/V head a block ``(squeezed, 1, S, D)`` at ``(b, hkv,
+# 0, 0)``), which since PR 70 the entry hands them where its caller asks
+# (``heads_major``): a row of ``FUSED_SHAPES`` and its batch. What the
+# attention mixer asks for: two sequences at 32:4 heads, whole and under the
+# band, two at 16:16, two at 24:2 of 4096 rows and sixteen of 512; and,
+# which no mixer asks for yet, four at 16:2 heads of 256, the
+# block-diffusion cell's one row of 16,384, one of 16,384 at a group of
+# seven, and two sequences at heads of 64.
+RANK_4 = {
+    "trinity-mini_s8192": 2, "trinity-mini_s8192_window": 2,
+    "ouro-2.6b_s4096": 2, "sdar-30b-a3b-chat_s8192": 1,
+    "qwen3-next-80b-a3b_s4096": 4, "smallthinker-21b-a3b_s16384_window": 1,
+    "starcoder2-3b_s4096": 2, "starcoder2-3b_s512": 16, "smoke_d64": 2,
+}
+
+
+@pytest.mark.parametrize("kernel", ["fwd", "fused", "dkdv", "dq"])
+@pytest.mark.parametrize("shape", list(RANK_4))
+def test_kernel_compiles_for_v5e_at_rank_4(one_chip, mosaic, shape, kernel):
+    """The forward, the one backward kernel and the pair through the
+    rank-4 index maps: a block Mosaic refuses fails here and not on the
+    chip. The program holds no merged operand."""
+    batch = RANK_4[shape]
+    bh, _, s, d, *_ = FUSED_SHAPES[shape]
+    lowered = _compile_flash(one_chip, kernel, *FUSED_SHAPES[shape],
+                             batch=batch)
+    text = lowered.as_text()
+    assert f"tensor<{batch}x{bh // batch}x{s}x{d}xbf16>" in text
+    assert f"tensor<{bh}x{s}x{d}xbf16>" not in text
 
 
 # (B, S, H, P, N, G, Q, dtype): the granite-4.0-h-micro_s4096 cell's scan;

@@ -40,10 +40,12 @@ def _dense(q, k, v, window):
                              causal=True, window=window)
 
 
-def _both(s, window, blocks, h=2, hkv=2, d=32, seed=0):
+def _both(s, window, blocks, h=2, hkv=2, d=32, seed=0, b=1,
+          heads_major=False):
     """``((out, dq, dk, dv) of the kernels, the same of the reference)``."""
-    q, k, v, w = _qkv(1, s, h, hkv, d, seed)
+    q, k, v, w = _qkv(b, s, h, hkv, d, seed)
     flash = lambda q, k, v: flash_attention(q, k, v, window=window,
+                                            heads_major=heads_major,
                                             _blocks=blocks)
     dense = lambda q, k, v: _dense(q, k, v, window)
     return tuple(
@@ -102,6 +104,48 @@ def test_a_mask_is_a_static_value_with_a_name():
 def test_grouped_query_heads_under_a_band():
     _assert_close(*_both(384, 150, (128, 128), h=4, hkv=2, seed=7))
     _assert_close(*_both(256, 70, (128, 128), h=4, hkv=1, seed=8))
+
+
+# batch, sequence, window, forced tile, H, Hkv, D: the band through either
+# entry (PR 70: a caller's ``heads_major`` hands the kernels ``[B, H, S, D]``,
+# the default merges to ``[B*H, S, D]``) at batches of one, two and four, the
+# benchmark cells' groups (12, 8, 7) among them, the band inside one tile,
+# across whole tiles and across tiles that are not square.
+BANDS_BY_ENTRY = [
+    (1, 384, 100, (128, 128), 24, 2, 128),
+    (2, 512, 200, (256, 128), 32, 4, 128),
+    (4, 384, 150, (128, 128), 28, 4, 128),
+    (2, 512, 128, (128, 256), 8, 4, 256),
+    (4, 300, 129, None, 4, 4, 256),
+    (2, 384, 150, (128, 128), 24, 2, 128),
+    (2, 384, 100, (128, 128), 8, 2, 64),
+    (4, 300, 129, None, 4, 4, 64),
+]
+
+
+@pytest.mark.parametrize("heads_major", [False, True],
+                         ids=["rank3", "rank4"])
+@pytest.mark.parametrize("b,s,window,blocks,h,hkv,d", BANDS_BY_ENTRY)
+def test_band_matches_dense_through_either_entry(b, s, window, blocks, h, hkv,
+                                                 d, heads_major):
+    _assert_close(*_both(s, window, blocks, h=h, hkv=hkv, d=d, seed=17, b=b,
+                         heads_major=heads_major))
+
+
+@pytest.mark.parametrize("d", [64, 128])
+def test_band_backward_as_the_pair_at_a_batch_of_two(monkeypatch, d):
+    """The pair of dKdV and dQ under a band through either entry at two
+    sequences: dK and dV the one kernel's bit for bit, dQ to float32
+    rounding, all three the dense reference's."""
+    args = dict(h=8, hkv=4, d=d, seed=19, b=2, heads_major=d == 128)
+    one, want = _both(384, 150, (128, 128), **args)
+    monkeypatch.setattr(fa, "backward_is_fused", lambda *a: False)
+    pair, _ = _both(384, 150, (128, 128), **args)
+    _assert_close(pair, want)
+    for name, x, y in zip(("dk", "dv"), one[2:], pair[2:]):
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y), name)
+    np.testing.assert_allclose(np.asarray(one[1]), np.asarray(pair[1]),
+                               rtol=1e-5, atol=1e-7)
 
 
 # sequence, window, forced tile, query heads a K/V head: the band inside one

@@ -80,3 +80,75 @@ def test_unknown_attention_raises(make_runtime, attention, sp_bound):
     make_runtime(mesh_shape={"dp": 4, "sp": 2})
     with pytest.raises(ValueError, match=f"unknown attention '{attention}'"):
         _trace(attention, sp_bound)
+
+
+# ---- where the turn to the kernels' layout goes (PR 70) --------------------
+
+# batch, head_dim, a head's norm on q and k -> what the attention mixer asks of
+# ``flash_attention`` (``heads_major``: rank4) and whether it asks for q's
+# cotangent sequence-minor (a ``layout_constraint`` in the backward pass):
+# rank 4 at a batch of two or more with heads of one lane tile, and the
+# cotangent pinned there unless a head's norm stands between it and q's
+# projection.
+TURNS = [
+    (1, 128, False, "rank3", False),
+    (2, 128, False, "rank4", True),
+    (4, 128, False, "rank4", True),
+    (2, 128, True, "rank4", False),
+    (1, 128, True, "rank3", False),
+    (2, 64, False, "rank3", False),
+    (2, 64, True, "rank3", False),
+    (2, 256, False, "rank3", False),
+    (4, 256, True, "rank3", False),
+]
+
+
+@pytest.mark.parametrize("batch, head_dim, head_norm, layout, pinned", TURNS)
+def test_the_mixer_places_the_turn(make_runtime, batch, head_dim, head_norm,
+                                   layout, pinned):
+    """The attention mixer's choice by what it can see (its batch, its
+    heads' width, its own norms), read from the counter and the traced
+    gradient; and the loss and every parameter's gradient are the dense
+    reference's whichever way the turn goes."""
+    import dataclasses
+    import numpy as np
+    make_runtime(devices=jax.devices()[:1])
+    rows = 256
+    cfg = gpt.GPTConfig(vocab_size=64, num_layers=1, num_heads=4,
+                        num_kv_heads=2, head_dim=head_dim, embed_dim=32,
+                        mlp_dim=64, dtype=jnp.float32, tp_axis=None,
+                        sp_axis=None, attention="flash",
+                        qk_head_norm=head_norm)
+    params = gpt.init_params(jax.random.PRNGKey(0), cfg)
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (batch, rows), 0, 64)
+    positions = jnp.broadcast_to(jnp.arange(rows), (batch, rows))
+
+    def loss(p, cfg):
+        out = gpt.forward(p, tokens, positions, cfg)
+        return jnp.mean(out.astype(jnp.float32) ** 2)
+
+    flash = jax.grad(lambda p: loss(p, cfg))
+    assert ("layout_constraint" in str(jax.make_jaxpr(flash)(params))) \
+        == pinned
+    fam = hvd.metrics()["hvdtpu_spmd_flash_layout_traces_total"]
+    assert {(labels["layout"], int(labels["head_dim"]), int(labels["batch"]))
+            for _, labels, _ in fam["samples"]} == {(layout, head_dim, batch)}
+    dense = jax.grad(lambda p: loss(
+        p, dataclasses.replace(cfg, attention="dense")))
+    got, want = jax.jit(flash)(params), jax.jit(dense)(params)
+    for g, r in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(g, r, rtol=1e-4,
+                                   atol=1e-5 * float(jnp.abs(r).max()) + 1e-9)
+
+
+@pytest.mark.parametrize("mixer", ["cca", "mla", "diff_attention"])
+def test_the_other_mixers_have_not_asked(mixer):
+    """CCA's q and k come out of a Mosaic kernel of its own, MLA's heads are
+    192 beside 128 and a differential layer's keys 64 beside 128: unmeasured
+    at rank 4 (PERF.md, section 7), so their files do not name
+    ``heads_major`` and their cells keep the parent's program."""
+    import importlib
+    import inspect
+    module = importlib.import_module(
+        f"horovod_tpu.models.decoder.mixers.{mixer}")
+    assert "heads_major" not in inspect.getsource(module)
