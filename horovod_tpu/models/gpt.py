@@ -436,16 +436,6 @@ def _logits(x, w, tied: bool, scaling: float):
 # block held twice the memory (PERF.md, Findings, PR 41).
 _HEAD_LOSS_MOST_ROWS = 2048
 _HEAD_LOSS_BLOCK_BYTES = 1 << 30
-# Up to this many blocks are left to the scheduler, as they were measured
-# (PR 41: 4 and 8 blocks, the one-pass cells'); beyond it a block waits for
-# the weight gradient's sum over the blocks before it (``_head_loss_fwd``).
-# The one-pass cells with the chain always on (my chip run, PR 69, one
-# traced pair a cell): ``starcoder2-3b_s4096`` (4 blocks) 298.56 | 298.63 ms
-# a step and 12.885 | 12.849 GiB, ``trinity-mini_s8192`` (8 blocks) 475.47 |
-# 474.65 ms and 13.722 | 13.490 GiB: the time holds and a little memory
-# falls, so one path would serve every cell; it changes every one-pass
-# program, which is a PR of its own (ROADMAP, Speed 19).
-_HEAD_LOSS_FREE_BLOCKS = 8
 
 
 def head_loss_rows(tokens: int, vocab: int) -> int:
@@ -536,17 +526,16 @@ def _head_loss_fwd(x, w, targets, weights, tied, scaling, rows):
     # A Python loop and no ``lax.scan``: the blocks are few, and as a
     # ``while`` they ran 7 to 10 ms behind this on the chip (PERF.md,
     # Findings, PR 41). The last block is the rows that are left.
-    chained = -(-x.shape[0] // rows) > _HEAD_LOSS_FREE_BLOCKS
-    num, dx, dw, each = [], [], [], []
+    num, dx, each, dw = [], [], [], None
     for i in range(0, x.shape[0], rows):
         xb = x[i:i + rows]
-        if chained and dw:
-            # Many blocks (a looped stack's passes x rows): a block's rows
-            # wait for the sum of the weight gradient over the blocks before
-            # it, or the scheduler makes every block's logits first and
-            # holds them all (16 x 192 MB at 32,768 rows of 49,152: PERF.md,
-            # Findings, PR 69).
-            xb, dw[0] = lax.optimization_barrier((xb, dw[0]))
+        if dw is not None:
+            # A block's rows wait for the sum of the weight gradient over the
+            # blocks before it, or the scheduler makes every block's logits
+            # first and holds them all (16 x 192 MB at 32,768 rows of 49,152:
+            # PR 69). At 4 and 8 blocks the wait costs 0.1 to 0.4 ms a step
+            # or wins 2.4 (free | chained, PERF.md, Findings, PR 71).
+            xb, dw = lax.optimization_barrier((xb, dw))
         num_b, dx_b, dw_b, each_b = _head_loss_block(
             xb, targets[i:i + rows],
             None if weights is None else weights[i:i + rows],
@@ -554,16 +543,12 @@ def _head_loss_fwd(x, w, targets, weights, tied, scaling, rows):
         num.append(num_b)
         dx.append(dx_b)
         each.append(each_b)
-        # Chained, ``dw`` holds the running sum alone.
-        if chained and dw:
-            dw[0] = dw[0] + dw_b
-        else:
-            dw.append(dw_b)
+        dw = dw_b if dw is None else dw + dw_b
     each = jnp.concatenate(each)
     # The empty slice hands the backward rule the compute dtype; the rows'
     # cross-entropies are kept for the weights' cotangent, where there are
     # weights (a caller's constants take none, and the compiler drops it).
-    return (sum(num), each), (jnp.concatenate(dx), sum(dw), x[:0],
+    return (sum(num), each), (jnp.concatenate(dx), dw, x[:0],
                               None if weights is None else each)
 
 

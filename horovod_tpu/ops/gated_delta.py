@@ -78,8 +78,8 @@ float32 ``T`` it holds, before its rounding, as a sixth output, and the
 backward kernel reads it and holds no inverse (PR 68; the inverse was 4.8
 of that kernel's 12.0 ms a layer at the Qwen cell's shape). In HBM a
 chunk's ``T`` lies with its lower 32 rows beside its upper 32, ``[c, B, Hv,
-32, 128]`` at a chunk of 64 (:func:`_pack_t`: as it stands a ``[64, 64]``
-float32 tile would be half padding there); the layout is the two kernels'
+32, 128]`` at a chunk of 64 (``pallas_util.pack_t``: as it stands a ``[64,
+64]`` float32 tile would be half padding there); the layout is the kernels'
 own and nothing else reads it. The backward kernel takes the forward's
 inputs and that ``T`` as its only residuals, makes the normed rows, the
 decays and ``A`` again in VMEM, forms ``dT`` from the cotangents of
@@ -105,7 +105,7 @@ the lane width (``K`` 96 -> 128, ``V`` 192 -> 256: Mosaic's blocks are whole
 lane tiles), the one place that knows being :func:`gated_delta_chunked`,
 which pads ``q``, ``k`` (raw under ``norm_qk``: zeros add nothing to a row's
 sum of squares, so the norm over 128 lanes is the norm over 96), ``v`` and
-the initial state with zeros (:func:`_to_lanes`) and drops the padded
+the initial state with zeros (``pallas_util.to_lanes``) and drops the padded
 columns of ``o`` and rows and
 columns of the final state. Zeros change no product: ``K K^T`` and ``Q K^T``
 sum over the key lanes, the padded columns of ``u``, ``w``, ``q G`` and ``k
@@ -192,8 +192,9 @@ from jax.experimental.pallas import tpu as pltpu
 from .. import runtime
 from .pallas_util import LANES, NEG_INF, NORM_EPS as _NORM_EPS, NT, \
     SUBLANES, TN, always, column_as_row, largest_divisor, \
-    out_vma as _out_vma, raw_row_cotangents, row_sum as _row_sum, \
-    unit_lower_inverse_in_vmem, use_interpret as _use_interpret, varying_like
+    out_vma as _out_vma, pack_t, raw_row_cotangents, row_sum as _row_sum, \
+    t_pack, to_lanes, unit_lower_inverse_in_vmem, unpack_t, \
+    use_interpret as _use_interpret, varying_like
 
 # The kernels' names in the compiled program and in a device trace; the
 # benchmark's readers match ``^hvd_gdn_`` (tests/test_program_names.py).
@@ -331,16 +332,6 @@ def chunks_per_block(n_chunks: int) -> int:
     return largest_divisor(n_chunks, _MAX_CHUNKS)
 
 
-def _to_lanes(t, *axes: int):
-    """``t`` with each of ``axes`` (the last, if none is named) padded with
-    zeros to the next multiple of the lane width, at which the kernels carry
-    a key or value head; ``t`` itself where they are multiples already."""
-    pad = [(0, 0)] * t.ndim
-    for axis in axes or (-1,):
-        pad[axis] = (0, -t.shape[axis] % LANES)
-    return jnp.pad(t, pad) if any(extra for _, extra in pad) else t
-
-
 def _tiling(kernel, key_dim, width, chunk, heads_per_block, dtype):
     """Compiled for the TPU, a shape the kernels do not tile raises here, by
     name (``key_dim`` and ``width`` are what a head occupies in the kernels:
@@ -428,32 +419,6 @@ class _Chunk:
             jnp.exp(cum[size - 1:size, :] - cum), a
 
 
-def _t_pack(chunk: int) -> int:
-    """Blocks of ``T``'s rows that lie side by side in HBM: a ``[64, 64]``
-    float32 tile as it stands is half padding there (the lanes are 128), so
-    its lower 32 rows go beside its upper 32, ``[32, 128]``. As many as fill
-    the lanes and leave whole eight-row tiles; 1 (``T`` as it is) from a
-    chunk of 128 up and under 16."""
-    return max(1, min(LANES // chunk, chunk // 8))
-
-
-def _pack_t(t):
-    """``T`` ``[Q, Q]`` as the kernels keep it, ``[Q / pack, pack Q]``."""
-    pack = _t_pack(t.shape[0])
-    rows = t.shape[0] // pack
-    return t if pack == 1 else jnp.concatenate(
-        [t[i * rows:(i + 1) * rows] for i in range(pack)], axis=1)
-
-
-def unpack_t(kept, chunk: int):
-    """:func:`_pack_t` undone, ``[..., Q / pack, pack Q]`` -> ``[..., Q,
-    Q]``: in the backward kernel, and on the whole array where a test reads
-    what the forward kernel wrote."""
-    return kept if kept.shape[-1] == chunk else jnp.concatenate(
-        [kept[..., i:i + chunk] for i in range(0, kept.shape[-1], chunk)],
-        axis=-2)
-
-
 def _fwd_kernel(q_ref, k_ref, v_ref, cum_ref, beta_ref, u_ref, w_ref,
                 attn_ref, qin_ref, kout_ref, t_ref, *, nc: int, rep: int,
                 chunk: int, width: int, q_scale):
@@ -461,7 +426,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, cum_ref, beta_ref, u_ref, w_ref,
     ``rep`` value heads. ``u = T (beta V)`` float32, ``w = T (beta G K)``,
     ``attn = (Q K^T) * decay``, ``q G`` and ``k G_last / G``: what the
     recurrence over chunks reads, in its order ``[c, B, Hv, Q, .]``; and the
-    float32 ``T`` itself, for the backward kernel (:func:`_pack_t`)."""
+    float32 ``T`` itself, for the backward kernel (``pallas_util.pack_t``)."""
     f32, dtype = jnp.float32, q_ref.dtype
     first = pl.program_id(2) * rep
 
@@ -474,7 +439,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, cum_ref, beta_ref, u_ref, w_ref,
                 beta, decay, grown, to_end, a = c.head(
                     cum_ref, beta_ref, at, first + r)
                 t = unit_lower_inverse_in_vmem(a, _SUBSTITUTE)
-                t_ref[n, 0, r] = _pack_t(t)
+                t_ref[n, 0, r] = pack_t(t)
                 t = t.astype(dtype)
                 v = v_ref[0, at, r * width:(r + 1) * width].astype(f32)
                 u_ref[n, 0, r] = jnp.dot(t, (v * beta).astype(dtype),
@@ -585,7 +550,7 @@ def _plan(kernel, body, q, k, v, cum, beta, q_scale):
     running sums and ``beta`` ``[B, S, Hv]`` (every head's, a chunk's rows:
     fetched once for a block of chunks, the key heads walk it). ``scan`` is
     a tensor in the recurrence's order ``[c, B, Hv, Q, .]`` (``scan_t``: the
-    kept ``T``, :func:`_pack_t`), ``rows`` the
+    kept ``T``, ``pallas_util.pack_t``), ``rows`` the
     backward's ``d cum | d beta`` ``[B, c, Hk, 2 rep, Q]``. ``q_scale``:
     what the kernels multiply the ``q`` they normed by, None where the
     caller normed."""
@@ -606,7 +571,7 @@ def _plan(kernel, body, q, k, v, cum, beta, q_scale):
         return pl.BlockSpec((nc, 1, rep, rows, last),
                             lambda b, c, h: (c, b, h, 0, 0))
 
-    pack = _t_pack(chunk)
+    pack = t_pack(chunk)
     specs = {"key": tokens(key_dim), "value": tokens(rep * width),
              "heads": tokens(heads, walk=False),
              "rows": pl.BlockSpec((1, nc, 1, 2 * rep, chunk),
@@ -632,7 +597,7 @@ def _scan_shapes(q, v, cum, vma):
     """``u`` (float32), ``w``, ``attn``, ``q G``, ``k G_last / G`` in the
     recurrence's order, and the kept float32 ``T`` in the same."""
     chunk = cum.shape[2]
-    lead, pack = (cum.shape[1], q.shape[0], v.shape[2]), _t_pack(chunk)
+    lead, pack = (cum.shape[1], q.shape[0], v.shape[2]), t_pack(chunk)
     return [jax.ShapeDtypeStruct(lead + last, dtype, vma=vma)
             for last, dtype in (
                 ((chunk, v.shape[3]), jnp.float32),
@@ -657,7 +622,7 @@ def _fwd_call(q, k, v, cum, beta, *, q_scale=None):
     V)`` float32, ``w = T (beta G K)``, ``attn = (Q K^T) * decay``, ``q G``
     and ``k G_last / G`` in the operand dtype, and last the float32 ``T``
     for :func:`_bwd_call` alone, ``[c, B, Hv, Q / 2, 2 Q]`` at a chunk of 64
-    (:func:`_pack_t`). With ``q_scale`` the kernel
+    (``pallas_util.pack_t``). With ``q_scale`` the kernel
     norms the rows of ``q`` and ``k`` first (:class:`_Chunk`)."""
     args, specs, body, call = _plan(KERNEL_FWD, _fwd_kernel, q, k, v, cum,
                                     beta, q_scale)
@@ -1055,9 +1020,9 @@ def gated_delta_chunked(q, k, v, g, beta, chunk: int = 64, *,
     # tiles (zeros add nothing to a row's sum of squares: the scale is the
     # true head's).
     u_own, w, attn, q_in, k_out = _chunk_local(
-        key_dim ** -0.5 if norm_qk else None, _to_lanes(q.astype(dtype)),
-        _to_lanes(k.astype(dtype)),
-        _to_lanes(v.astype(dtype)), cum, chunked(beta))
+        key_dim ** -0.5 if norm_qk else None, to_lanes(q.astype(dtype)),
+        to_lanes(k.astype(dtype)),
+        to_lanes(v.astype(dtype)), cum, chunked(beta))
 
     start = jnp.zeros((batch, heads, key_dim, width), f32) \
         if initial_state is None else initial_state.astype(f32)
@@ -1068,7 +1033,7 @@ def gated_delta_chunked(q, k, v, g, beta, chunk: int = 64, *,
     o, final = _recurrence(key_dim % LANES == 0 and width % LANES == 0,
                            u_own, w, attn, q_in, k_out,
                            jnp.exp(cum[:, :, -1]),
-                           varying_like(_to_lanes(start, -2, -1), u_own))
+                           varying_like(to_lanes(start, -2, -1), u_own))
     o = o.reshape(batch, n_chunks * chunk, heads, -1)[:, :seq]
     if o.shape[-1] != width:
         o = o[..., :width]

@@ -51,8 +51,8 @@ rule), ``u_own = T (beta V)`` (float32), ``w = T (beta K Gamma)``, ``attn``,
 ``[c, B, H, Q, .]``. **The inverse is made once a step** (PR 68): the
 forward kernel writes the float32 ``T`` it holds, before its rounding, as a
 sixth output, a chunk's lower 32 rows beside its upper 32 (``[c, B, H, 32,
-128]`` at a chunk of 64, :func:`_pack_t`: a ``[64, 64]`` float32 tile as it
-stands would be half padding in HBM; the layout is the two kernels' own),
+128]`` at a chunk of 64, ``pallas_util.pack_t``: a ``[64, 64]`` float32 tile
+as it stands would be half padding in HBM; the layout is the kernels' own),
 and the backward kernel reads it and holds no inverse (8.5 -> 5.1 ms a
 layer at the Ling cell's shape): it makes everything else again from the
 inputs and returns ``dq``, ``dk`` (of the raw rows under ``norm_qk``),
@@ -101,8 +101,9 @@ from jax.experimental.pallas import tpu as pltpu
 from .. import runtime
 from .gated_delta import unit_rows
 from .pallas_util import LANES, NORM_EPS, NT, SUBLANES, TN, always, \
-    column_as_row, largest_divisor, out_vma, raw_row_cotangents, row_sum, \
-    unit_lower_inverse_in_vmem, use_interpret, varying_like
+    column_as_row, largest_divisor, out_vma, pack_t, raw_row_cotangents, \
+    row_sum, t_pack, unit_lower_inverse_in_vmem, unpack_t, use_interpret, \
+    varying_like
 
 # The kernels' names in the compiled program and in a device trace; the
 # benchmark's readers match ``^hvd_kda_`` (tests/test_program_names.py).
@@ -242,39 +243,12 @@ class _Chunk:
                                   (self.q_scale, 1.0))
 
 
-def _t_pack(chunk: int) -> int:
-    """Blocks of ``T``'s rows that lie side by side in HBM: a ``[64, 64]``
-    float32 tile as it stands is half padding there (the lanes are 128), so
-    its lower 32 rows go beside its upper 32, ``[32, 128]``. As many as fill
-    the lanes and leave whole eight-row tiles; 1 (``T`` as it is) from a
-    chunk of 128 up and under 16. (``ops/gated_delta.py`` keeps its ``T``
-    so; the layout is each pair of kernels' own.)"""
-    return max(1, min(LANES // chunk, chunk // 8))
-
-
-def _pack_t(t):
-    """``T`` ``[Q, Q]`` as the kernels keep it, ``[Q / pack, pack Q]``."""
-    pack = _t_pack(t.shape[0])
-    rows = t.shape[0] // pack
-    return t if pack == 1 else jnp.concatenate(
-        [t[i * rows:(i + 1) * rows] for i in range(pack)], axis=1)
-
-
-def unpack_t(kept, chunk: int):
-    """:func:`_pack_t` undone, ``[..., Q / pack, pack Q]`` -> ``[..., Q,
-    Q]``: in the backward kernel, and on the whole array where a test reads
-    what the forward kernel wrote."""
-    return kept if kept.shape[-1] == chunk else jnp.concatenate(
-        [kept[..., i:i + chunk] for i in range(0, kept.shape[-1], chunk)],
-        axis=-2)
-
-
 def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, u_ref, w_ref, attn_ref,
                 qin_ref, kout_ref, t_ref, *, nc: int, chunk: int, sub: int,
                 q_scale):
     """A grid cell: ``nc`` chunks of one sequence, one head. What the
     recurrence reads, in its order ``[c, B, H, Q, .]``, and the float32
-    ``T`` for the backward kernel (:func:`_pack_t`)."""
+    ``T`` for the backward kernel (``pallas_util.pack_t``)."""
     f32, dtype = jnp.float32, q_ref.dtype
     head = pl.program_id(2)
 
@@ -284,7 +258,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, u_ref, w_ref, attn_ref,
             at = pl.ds(pl.multiple_of(n * chunk, chunk), chunk)
             c = _Chunk(q_ref, k_ref, g_ref, beta_ref, at, head, q_scale, sub)
             t = unit_lower_inverse_in_vmem(c.a)
-            t_ref[n, 0, 0] = _pack_t(t)
+            t_ref[n, 0, 0] = pack_t(t)
             t = t.astype(dtype)
             v = v_ref[0, at, :].astype(f32)
             u_ref[n, 0, 0] = jnp.dot(t, (v * c.beta).astype(dtype),
@@ -389,7 +363,7 @@ def _plan(kernel, body, q, v, chunk, sub, q_scale):
     K]`` and a ``[Q, V]`` block of ``v``; ``beta`` ``[B, S, H]`` comes whole
     and a head's column is picked by a masked sum along the lanes. ``scan``
     is a tensor in the recurrence's order ``[c, B, H, Q, .]`` (``scan_t``:
-    the kept ``T``, :func:`_pack_t`), ``last`` a
+    the kept ``T``, ``pallas_util.pack_t``), ``last`` a
     chunk's row a head ``[B, c, 1, H K]``, ``rows`` the backward's ``d
     beta`` ``[B, c, H, 1, Q]``."""
     batch, seq, heads, key_dim = q.shape
@@ -406,7 +380,7 @@ def _plan(kernel, body, q, v, chunk, sub, q_scale):
         return pl.BlockSpec((nc, 1, 1, rows, last),
                             lambda b, c, h: (c, b, h, 0, 0))
 
-    pack = _t_pack(chunk)
+    pack = t_pack(chunk)
 
     specs = {"key": tokens(key_dim), "value": tokens(width),
              "heads": tokens(heads, walk=False),
@@ -445,12 +419,12 @@ def _fwd_call(q, k, v, g, beta, *, chunk, sub, q_scale):
     T (beta K Gamma)``, ``attn``, ``q Gamma`` and ``k Gamma_last / Gamma``
     in the operand dtype, ``[c, B, H, Q, .]``, and last the float32 ``T``
     for :func:`_bwd_call` alone, ``[c, B, H, Q / 2, 2 Q]`` at a chunk of 64
-    (:func:`_pack_t`)."""
+    (``pallas_util.pack_t``)."""
     specs, body, call = _plan(KERNEL_FWD, _fwd_kernel, q, v, chunk, sub,
                               q_scale)
     args = (_flat(q), _flat(k), _flat(v), _flat(g), beta)
     batch, seq, heads, key_dim = q.shape
-    lead, pack = (seq // chunk, batch, heads), _t_pack(chunk)
+    lead, pack = (seq // chunk, batch, heads), t_pack(chunk)
     vma = out_vma(*args)
     return pl.pallas_call(
         body, in_specs=[specs[name] for name in _FWD_SPECS],

@@ -2,11 +2,12 @@
 masked exponent, the two contraction patterns, the platform test and a few
 helpers. The floor of ``ops/``: it imports nothing of the package, and every
 kernel module (``flash_attention``, ``ssd``, ``s6``, ``gated_delta``, ``kda``,
-``conv``, ``cca``) imports these names from here and no kernel's part from another
-(``cca`` takes two plain references, ``conv.causal_conv1d`` and
+``conv``, ``cca``) imports these names from here and no kernel's part from
+another (``cca`` takes two plain references, ``conv.causal_conv1d`` and
 ``attention.rope``, and ``kda`` one, ``gated_delta.unit_rows``, for the lines
 their kernels are held to; what the two delta rules' kernels share, the
-unit-triangular inverse in VMEM and the rows' norm, is here)."""
+unit-triangular inverse in VMEM, its packing in HBM and the rows' norm, is
+here)."""
 
 from __future__ import annotations
 
@@ -53,6 +54,16 @@ def use_interpret() -> bool:
 def largest_divisor(n: int, most: int) -> int:
     """The largest divisor of ``n``, ``most`` at most."""
     return next(d for d in range(min(most, n), 0, -1) if n % d == 0)
+
+
+def to_lanes(t, *axes: int):
+    """``t`` with each of ``axes`` (the last, if none is named) padded with
+    zeros to the next multiple of the lane width, at which the kernels carry
+    a key or value head; ``t`` itself where they are multiples already."""
+    pad = [(0, 0)] * t.ndim
+    for axis in axes or (-1,):
+        pad[axis] = (0, -t.shape[axis] % LANES)
+    return jnp.pad(t, pad) if any(extra for _, extra in pad) else t
 
 
 def varying_like(x, like):
@@ -150,3 +161,30 @@ def unit_lower_inverse_in_vmem(a, substitute: int = 32):
         inv = inv - jnp.dot(left, inv, precision=lax.Precision.HIGHEST,
                             preferred_element_type=a.dtype)
     return inv
+
+
+def t_pack(chunk: int) -> int:
+    """Blocks of ``T``'s rows that lie side by side in HBM (the delta rules'
+    kept inverse, ``ops/gated_delta.py`` and ``ops/kda.py``): a ``[64, 64]``
+    float32 tile as it stands is half padding there (the lanes are 128), so
+    its lower 32 rows go beside its upper 32, ``[32, 128]``. As many as fill
+    the lanes and leave whole eight-row tiles; 1 (``T`` as it is) from a
+    chunk of 128 up and under 16."""
+    return max(1, min(LANES // chunk, chunk // 8))
+
+
+def pack_t(t):
+    """``T`` ``[Q, Q]`` as the kernels keep it, ``[Q / pack, pack Q]``."""
+    pack = t_pack(t.shape[0])
+    rows = t.shape[0] // pack
+    return t if pack == 1 else jnp.concatenate(
+        [t[i * rows:(i + 1) * rows] for i in range(pack)], axis=1)
+
+
+def unpack_t(kept, chunk: int):
+    """:func:`pack_t` undone, ``[..., Q / pack, pack Q]`` -> ``[..., Q,
+    Q]``: in the backward kernel, and on the whole array where a test reads
+    what the forward kernel wrote."""
+    return kept if kept.shape[-1] == chunk else jnp.concatenate(
+        [kept[..., i:i + chunk] for i in range(0, kept.shape[-1], chunk)],
+        axis=-2)

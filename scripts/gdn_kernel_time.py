@@ -70,6 +70,7 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
 from horovod_tpu.ops import gated_delta as gd  # noqa: E402
+from horovod_tpu.ops.pallas_util import to_lanes  # noqa: E402
 
 
 def timed(fn, *args, reps: int = 10) -> float:
@@ -206,7 +207,7 @@ def main() -> int:
     v = jax.random.normal(ks[2], (B, S, Hv, V), dtype)
     published = (q, k, v)
     # What the kernels are called with: a head on whole lane tiles.
-    q, k, v = (gd._to_lanes(t) for t in published)
+    q, k, v = (to_lanes(t) for t in published)
     K, V = q.shape[-1], v.shape[-1]
     g = -0.3 * jnp.exp(jax.random.normal(ks[3], (B, S, Hv)))
     beta = jax.nn.sigmoid(jax.random.normal(ks[4], (B, S, Hv))) \

@@ -68,7 +68,8 @@ from horovod_tpu.ops.gated_delta import (
     gated_delta_chunked, gated_delta_sequential, unit_lower_inverse,
     unit_rows)
 from horovod_tpu.ops.pallas_util import (
-    largest_divisor, unit_lower_inverse_in_vmem, use_interpret)
+    largest_divisor, to_lanes, unit_lower_inverse_in_vmem, unpack_t,
+    use_interpret)
 
 B, HK, HV, K, V = 2, 2, 4, 16, 8
 
@@ -397,8 +398,7 @@ def test_the_t_the_forward_kernel_writes_is_unit_lower_inverse_of_a(case):
     a = jnp.tril(kk * jnp.exp(rows[..., :, None] - rows[..., None, :])
                  * jnp.moveaxis(beta.reshape(by_chunk), (1, 3),
                                 (0, 2))[..., None], -1)
-    _close(gated_delta.unpack_t(kept, chunk),
-           unit_lower_inverse(a), 1e-5)
+    _close(unpack_t(kept, chunk), unit_lower_inverse(a), 1e-5)
 
 
 @pytest.mark.parametrize("what", ["beta zero", "alpha one"])
@@ -722,7 +722,7 @@ def test_kernels_norm_leaves_padding_rows_zero(dtype):
 
     def padded(t, lanes=False):
         t = jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
-        return gated_delta._to_lanes(t.astype(dtype)) if lanes else t
+        return to_lanes(t.astype(dtype)) if lanes else t
 
     def chunked(t):
         return padded(t).reshape(1, -1, chunk, t.shape[-1])

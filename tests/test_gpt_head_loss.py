@@ -177,6 +177,21 @@ def test_the_rule_takes_no_option():
     assert "environ" not in source and "cfg" not in source
 
 
+@pytest.mark.parametrize("blocks", list(BLOCKS))
+def test_every_block_after_the_first_waits_for_the_one_before(blocks):
+    """The blocks have one schedule, at every count of them: a block's rows
+    wait for the running sum of the weight gradient over the blocks before
+    it, so the rule's forward holds a barrier a block but the first, and
+    none where the tokens fit one block."""
+    seq, _, rows, count = BLOCKS[blocks]
+    x, w = jnp.zeros((BATCH * seq, 16)), jnp.zeros((16, VOCAB))
+    targets = jnp.zeros((BATCH * seq,), jnp.int32)
+    jaxpr = jax.make_jaxpr(lambda x, w: gpt._head_loss_fwd(
+        x, w, targets, None, False, 1.0, rows))(x, w)
+    assert sum(eqn.primitive.name == "optimization_barrier"
+               for eqn in jaxpr.eqns) == count - 1
+
+
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
 @pytest.mark.parametrize("factor", [3.5, -0.25])
 def test_a_cotangent_other_than_one_scales_both_gradients(factor, dtype):
@@ -200,11 +215,8 @@ def test_weights_that_are_no_constants_receive_their_cotangent(
     """The rule against ``jax.grad`` of a plain float32 weighted
     cross-entropy, at a cotangent other than one: the rows, the matrix and
     **the weights** (``g`` times each row's cross-entropy, nothing on a
-    masked row), whatever the blocks: twelve are past
-    ``_HEAD_LOSS_FREE_BLOCKS``, where a block waits for the weight
-    gradient's sum over the blocks before it. ``each`` is those
-    cross-entropies, and carries no gradient."""
-    assert -(-48 // rows) > gpt._HEAD_LOSS_FREE_BLOCKS or rows > 4
+    masked row), whatever the blocks. ``each`` is those cross-entropies, and
+    carries no gradient."""
     rng = np.random.default_rng(rows)
     x = jnp.asarray(rng.normal(size=(48, 16)), jnp.float32)
     w = jnp.asarray(rng.normal(size=(VOCAB, 16) if tied else (16, VOCAB)),

@@ -298,7 +298,7 @@ def test_the_t_the_forward_kernel_writes_is_unit_lower_inverse_of_a(case):
                   keys * np.exp(-cum)) * np.moveaxis(
         np.asarray(beta, np.float64).reshape(by_chunk[:4]), (1, 3),
         (0, 2))[..., None]
-    _close(kda.unpack_t(kept, chunk),
+    _close(pallas_util.unpack_t(kept, chunk),
            gated_delta.unit_lower_inverse(
                jnp.asarray(np.tril(a, -1), jnp.float32)), 1e-5)
 
