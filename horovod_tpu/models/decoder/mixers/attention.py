@@ -110,8 +110,11 @@ def apply(cfg: GPTConfig, spec: LayerSpec, lp, h, positions):
     # the cotangent is asked for sequence-minor, one copy kept of the eight:
     # 24:2 heads at 4096 rows lose 2.1% of their tokens a second without it
     # and gain 1.4% with it. Heads of one lane tile alone. Narrower ones are
-    # outside ISSUE 70's scope and unmeasured (the kernels take any width at
-    # rank 4; Granite's estimate reads -0.2%). Wider ones were measured and
+    # unmeasured (the kernels take any width at rank 4; Granite's estimate
+    # reads -0.2%). Of the other mixers the latent-attention one asks for
+    # itself since PR 72 (``mixers/mla.py``: 192 beside 128, q's value kept
+    # sequence-minor and no cotangent pinned); the CCA and differential
+    # ones have not. Wider ones were measured and
     # lost: Qwen's 16:2 heads of 256, one layer in four, 1.03% of a step, and
     # not to a layout: XLA's memory-space assignment stopped prefetching
     # three other layers' output-projection weights in the re-scheduled

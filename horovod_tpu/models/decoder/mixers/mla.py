@@ -8,11 +8,27 @@ mla_rope_dim`` dimensions and the values of another width. It runs under
 its heads (``wq``, ``wkv_b`` and ``wo`` by head, the down-projection and
 the latent's norm whole on every rank). Under ``mla_head_gate`` the
 attention's output is multiplied a head by ``sigmoid(h W_g)``, ``W_g`` ``[E,
-H]``, before the output projection (scope ``mla_gate``)."""
+H]``, before the output projection (scope ``mla_gate``).
+
+The turn between its ``[B, S, H, D]`` and the flash kernels' ``[B, H, S, D]``
+is this mixer's to place, as it is the attention mixer's
+(``mixers/attention.py::apply`` says what the two forms cost): at a batch of
+two or more it asks for ``heads_major``, and XLA folds the turn of k, v, the
+output and every cotangent into ``mla_rope``'s fusions, v's slice, the head
+gate and ``W_o``'s product, where the merged entry made a copy of each; at a
+batch of one the merge is a bitcast and it asks for nothing. q alone keeps
+its copy (``_value_sequence_minor``): folded, the concatenation wrote
+192-wide tiles of the kernels' layout at twice a copy's price, and the step
+XLA scheduled round it no longer prefetched the shared expert's input, 10 ms
+a step in two products outside the mixer, so all of it folded lost 2.0% of
+Moonlight's tokens a second where this form gains 0.7%; q's cotangent pinned
+as the attention mixer pins it lost the same prefetch (PERF.md, Findings,
+PR 72)."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental.layout import Layout, with_layout_constraint
 from jax.sharding import PartitionSpec as P
 
 from .... import runtime
@@ -56,6 +72,23 @@ def _parameters(cfg: GPTConfig, keys=None, dense=None, norm=None) -> dict:
 init, specs = readings(_parameters)
 
 
+def _sequence_minor(q):
+    return with_layout_constraint(q, Layout(major_to_minor=(0, 2, 3, 1)))
+
+
+@jax.custom_vjp
+def _value_sequence_minor(q):
+    """``q``, ``[B, S, H, D]``, written with the sequence minor in memory
+    (``[B, H, D, S]``, the layout XLA gave it under the merged entry, no
+    lane of a 192-wide head padded), so that one copy turns it into the
+    kernels' layout; its cotangent is left to fold (``apply``)."""
+    return _sequence_minor(q)
+
+
+_value_sequence_minor.defvjp(lambda q: (_sequence_minor(q), None),
+                             lambda _, dq: (dq,))
+
+
 def apply(cfg: GPTConfig, spec: LayerSpec, p, h, positions):
     """Latent attention (MLA, as training runs it: keys and values
     decompressed a head) on normed activations ``h`` ``[B, S, E]``, ``H``
@@ -94,7 +127,10 @@ def apply(cfg: GPTConfig, spec: LayerSpec, p, h, positions):
             # As in ``attention.apply``: every attention scales by one
             # over the root of the query's width, the rest goes onto q.
             q = q * (cfg.attention_multiplier * float(np.sqrt(nope + rot)))
-    attn = _attention(cfg, q, k, v)
+    heads_major = q.shape[0] > 1
+    if heads_major:
+        q = _value_sequence_minor(q)
+    attn = _attention(cfg, q, k, v, heads_major=heads_major)
     if cfg.mla_head_gate:
         with jax.named_scope("mla_gate"):
             open_ = jax.nn.sigmoid(jnp.einsum(

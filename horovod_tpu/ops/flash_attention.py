@@ -1046,9 +1046,12 @@ def flash_attention(q, k, v, causal: bool = True, *,
     array and whatever reads the output. Folded, the turn does not vanish:
     it moves into those neighbours, cheap in an elementwise fusion and dear
     in a product that contracts over ``(h, d)`` with ``s`` between them in
-    memory, so a caller asks for it knowing its neighbours
-    (``models/decoder/mixers/attention.py``; PERF.md, Findings, PR 70). The
-    values, the kernels' bodies, grids and names are the same both ways.
+    memory, so a caller asks for it knowing its neighbours: the attention
+    mixer at heads of one lane tile, the latent-attention mixer at its 192
+    beside 128, each at a batch of two or more
+    (``models/decoder/mixers/attention.py``, ``mixers/mla.py``; PERF.md,
+    Findings, PR 70 and PR 72). The values, the kernels' bodies, grids and
+    names are the same both ways.
     """
     b, s, h, d = q.shape
     if k.shape[2] != v.shape[2] or h % k.shape[2]:

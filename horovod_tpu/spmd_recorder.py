@@ -122,7 +122,8 @@ _TRACED = {
         "every output and cotangent as [B, H, S, D], a transpose of the "
         "model's array that XLA folds into the layouts round the call; the "
         "attention mixer asks at two or more sequences of heads of one "
-        "lane tile) or rank3 (the default: merged to [B*H, S, D] besides, "
+        "lane tile, the latent-attention mixer at two or more sequences) "
+        "or rank3 (the default: merged to [B*H, S, D] besides, "
         "a reshape no layout crosses at a batch of two or more, so each is "
         "turned in a copy of its own), with the width of a query and key "
         "head and the batch.",

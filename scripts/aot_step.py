@@ -7,7 +7,9 @@ chip count alike): its memory, the Mosaic kernels it holds by name and by the
 pass each runs in (``kernel_calls``), what the compiler made again and which
 arguments it copies; and, from the trace,
 the bytes its checkpointed blocks keep by name (``remat_saved_bytes``: the
-job's ``hvdtpu_spmd_remat_saved_bytes_total``). Nothing runs and no
+job's ``hvdtpu_spmd_remat_saved_bytes_total``) and the layout in which its
+flash kernels get their operands (``flash_layouts``: the job's
+``hvdtpu_spmd_flash_layout_traces_total``). Nothing runs and no
 time is taken; a compile that passes is not a chip run.
 
     python3 scripts/aot_step.py starcoder2-3b_s4096 olmoe-1b-7b_s4096
@@ -96,6 +98,21 @@ def remat_saved_bytes(hvd) -> dict:
             for _, labels, value in family.get("samples", ())}
 
 
+def flash_layouts(hvd) -> dict:
+    """``hvdtpu_spmd_flash_layout_traces_total`` as ``"rank4 d192 b2" ->
+    calls traced``, so far: how q, k and v reach the flash kernels, which is
+    their caller's word (``flash_attention(heads_major=)``)."""
+    family = hvd.metrics().get("hvdtpu_spmd_flash_layout_traces_total", {})
+    return {"{layout} d{head_dim} b{batch}".format(**labels): value
+            for _, labels, value in family.get("samples", ())}
+
+
+def counted_since(before: dict, now: dict) -> dict:
+    """What one cell's trace added to a counter this process keeps."""
+    return {name: value - before.get(name, 0) for name, value in now.items()
+            if value != before.get(name, 0)}
+
+
 def calls_by_pass(kernel_calls: list) -> dict:
     """kernel -> pass -> calls in the compiled step."""
     out: dict = {}
@@ -134,11 +151,10 @@ def compile_cell(name: str, root: str, hlo_dir: str | None = None) -> dict:
         jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=NamedSharding(
             hvd.mesh(), hvd.batch_spec(0)))
         for x in job.host_batches(1)[0])
-    kept_before = remat_saved_bytes(hvd)
+    kept_before, layouts_before = remat_saved_bytes(hvd), flash_layouts(hvd)
     lowered = job.step.lower(params, opt_state, data)
-    kept = {name: nbytes - kept_before.get(name, 0)
-            for name, nbytes in remat_saved_bytes(hvd).items()
-            if nbytes != kept_before.get(name, 0)}
+    kept = counted_since(kept_before, remat_saved_bytes(hvd))
+    layouts = counted_since(layouts_before, flash_layouts(hvd))
     t0 = time.time()
     compiled = lowered.compile()
     seconds = time.time() - t0
@@ -175,6 +191,8 @@ def compile_cell(name: str, root: str, hlo_dir: str | None = None) -> dict:
         # What the checkpointed blocks of this cell's trace kept, by name:
         # one block's bytes for each that JAX split (layers alike share one).
         "remat_saved_bytes": kept,
+        # How this cell's trace handed q, k, v to the flash kernels.
+        "flash_layouts": layouts,
     }
 
 

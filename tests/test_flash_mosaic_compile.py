@@ -164,15 +164,17 @@ def test_kernel_compiles_for_v5e(one_chip, mosaic, shape, kernel):
 # 0, 0)``), which since PR 70 the entry hands them where its caller asks
 # (``heads_major``): a row of ``FUSED_SHAPES`` and its batch. What the
 # attention mixer asks for: two sequences at 32:4 heads, whole and under the
-# band, two at 16:16, two at 24:2 of 4096 rows and sixteen of 512; and,
-# which no mixer asks for yet, four at 16:2 heads of 256, the
-# block-diffusion cell's one row of 16,384, one of 16,384 at a group of
+# band, two at 16:16, two at 24:2 of 4096 rows and sixteen of 512; what the
+# latent-attention mixer asks for since PR 72: two at 16:16 heads of 192
+# beside 128; and, which no mixer asks for yet, four at 16:2 heads of 256,
+# the block-diffusion cell's one row of 16,384, one of 16,384 at a group of
 # seven, and two sequences at heads of 64.
 RANK_4 = {
     "trinity-mini_s8192": 2, "trinity-mini_s8192_window": 2,
     "ouro-2.6b_s4096": 2, "sdar-30b-a3b-chat_s8192": 1,
     "qwen3-next-80b-a3b_s4096": 4, "smallthinker-21b-a3b_s16384_window": 1,
     "starcoder2-3b_s4096": 2, "starcoder2-3b_s512": 16, "smoke_d64": 2,
+    "moonlight-16b-a3b_s8192": 2,
 }
 
 
@@ -184,6 +186,7 @@ def test_kernel_compiles_for_v5e_at_rank_4(one_chip, mosaic, shape, kernel):
     chip. The program holds no merged operand."""
     batch = RANK_4[shape]
     bh, _, s, d, *_ = FUSED_SHAPES[shape]
+    d = d[0] if isinstance(d, tuple) else d     # q is a key head wide
     lowered = _compile_flash(one_chip, kernel, *FUSED_SHAPES[shape],
                              batch=batch)
     text = lowered.as_text()

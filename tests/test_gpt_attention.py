@@ -103,22 +103,14 @@ TURNS = [
 ]
 
 
-@pytest.mark.parametrize("batch, head_dim, head_norm, layout, pinned", TURNS)
-def test_the_mixer_places_the_turn(make_runtime, batch, head_dim, head_norm,
-                                   layout, pinned):
-    """The attention mixer's choice by what it can see (its batch, its
-    heads' width, its own norms), read from the counter and the traced
-    gradient; and the loss and every parameter's gradient are the dense
+def _the_turn(cfg, batch):
+    """``(whether the traced gradient pins a layout, the flash entry's
+    layouts by the counter)`` of ``cfg``'s one layer at ``batch`` sequences
+    of 256 rows; and the loss and every parameter's gradient are the dense
     reference's whichever way the turn goes."""
     import dataclasses
     import numpy as np
-    make_runtime(devices=jax.devices()[:1])
     rows = 256
-    cfg = gpt.GPTConfig(vocab_size=64, num_layers=1, num_heads=4,
-                        num_kv_heads=2, head_dim=head_dim, embed_dim=32,
-                        mlp_dim=64, dtype=jnp.float32, tp_axis=None,
-                        sp_axis=None, attention="flash",
-                        qk_head_norm=head_norm)
     params = gpt.init_params(jax.random.PRNGKey(0), cfg)
     tokens = jax.random.randint(jax.random.PRNGKey(1), (batch, rows), 0, 64)
     positions = jnp.broadcast_to(jnp.arange(rows), (batch, rows))
@@ -128,25 +120,61 @@ def test_the_mixer_places_the_turn(make_runtime, batch, head_dim, head_norm,
         return jnp.mean(out.astype(jnp.float32) ** 2)
 
     flash = jax.grad(lambda p: loss(p, cfg))
-    assert ("layout_constraint" in str(jax.make_jaxpr(flash)(params))) \
-        == pinned
+    pinned = "layout_constraint" in str(jax.make_jaxpr(flash)(params))
     fam = hvd.metrics()["hvdtpu_spmd_flash_layout_traces_total"]
-    assert {(labels["layout"], int(labels["head_dim"]), int(labels["batch"]))
-            for _, labels, _ in fam["samples"]} == {(layout, head_dim, batch)}
+    layouts = {(labels["layout"], int(labels["head_dim"]),
+                int(labels["batch"])) for _, labels, _ in fam["samples"]}
     dense = jax.grad(lambda p: loss(
         p, dataclasses.replace(cfg, attention="dense")))
     got, want = jax.jit(flash)(params), jax.jit(dense)(params)
     for g, r in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         np.testing.assert_allclose(g, r, rtol=1e-4,
                                    atol=1e-5 * float(jnp.abs(r).max()) + 1e-9)
+    return pinned, layouts
 
 
-@pytest.mark.parametrize("mixer", ["cca", "mla", "diff_attention"])
+@pytest.mark.parametrize("batch, head_dim, head_norm, layout, pinned", TURNS)
+def test_the_mixer_places_the_turn(make_runtime, batch, head_dim, head_norm,
+                                   layout, pinned):
+    """The attention mixer's choice by what it can see (its batch, its
+    heads' width, its own norms), read from the counter and the traced
+    gradient."""
+    make_runtime(devices=jax.devices()[:1])
+    cfg = gpt.GPTConfig(vocab_size=64, num_layers=1, num_heads=4,
+                        num_kv_heads=2, head_dim=head_dim, embed_dim=32,
+                        mlp_dim=64, dtype=jnp.float32, tp_axis=None,
+                        sp_axis=None, attention="flash",
+                        qk_head_norm=head_norm)
+    assert _the_turn(cfg, batch) == (pinned, {(layout, head_dim, batch)})
+
+
+@pytest.mark.parametrize("rotary", [True, False], ids=["rope", "no-rope"])
+@pytest.mark.parametrize("batch, layout",
+                         [(1, "rank3"), (2, "rank4"), (4, "rank4")])
+def test_the_mla_mixer_places_the_turn(make_runtime, batch, layout, rotary):
+    """The latent-attention mixer's choice by its batch alone (PR 72): rank
+    4 at two or more sequences whatever its rotations, a key head ``head_dim
+    + mla_rope_dim`` wide beside a value head of another width, and there
+    q's value pinned sequence-minor (the one ``layout_constraint``: q keeps
+    its copy; PERF.md, Findings, PR 72)."""
+    make_runtime(devices=jax.devices()[:1])
+    nope, rot = 32, 16
+    cfg = gpt.GPTConfig(vocab_size=64, num_layers=1, num_heads=4,
+                        head_dim=nope, mla_rope_dim=rot, mla_value_dim=24,
+                        mla_kv_rank=16, embed_dim=32, mlp_dim=64,
+                        dtype=jnp.float32, tp_axis=None, sp_axis=None,
+                        attention="flash", layers=(gpt.LayerSpec(
+                            mixer="mla", rope=rotary, ff="gated"),))
+    assert _the_turn(cfg, batch) == (batch > 1,
+                                     {(layout, nope + rot, batch)})
+
+
+@pytest.mark.parametrize("mixer", ["cca", "diff_attention"])
 def test_the_other_mixers_have_not_asked(mixer):
-    """CCA's q and k come out of a Mosaic kernel of its own, MLA's heads are
-    192 beside 128 and a differential layer's keys 64 beside 128: unmeasured
-    at rank 4 (PERF.md, section 7), so their files do not name
-    ``heads_major`` and their cells keep the parent's program."""
+    """CCA's q and k come out of a Mosaic kernel of its own and a
+    differential layer's keys are 64 beside 128: unmeasured at rank 4
+    (PERF.md, section 7), so their files do not name ``heads_major`` and
+    their cells keep the parent's program."""
     import importlib
     import inspect
     module = importlib.import_module(

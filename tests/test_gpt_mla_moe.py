@@ -224,7 +224,7 @@ def _qkv(monkeypatch, cfg, p, h, positions):
     """What the mixer hands ``_attention``."""
     seen = []
     monkeypatch.setattr(
-        mla, "_attention", lambda cfg, q, k, v, window=None: seen.append(
+        mla, "_attention", lambda cfg, q, k, v, **layout: seen.append(
             (q, k, v)) or v)
     mla.apply(cfg, cfg.plan[0], p, h, positions)
     return seen[-1]
